@@ -13,7 +13,7 @@ shape-driven default — one FLOP per output element (the fused
 elementwise/VPU floor) and bytes = inputs read + outputs written.  The
 byte model deliberately gives NO fusion credit, so it is an upper bound
 on HBM traffic; `tools/hlo_analysis.py` measures the post-fusion truth
-and the roofline evidence capture compares the two.
+and its `roofline` mode compares the two.
 
 Predicted step time is the roofline ceiling
     t = max(t_compute, t_memory),  t_compute = Σ flops_d / peak_d,
@@ -77,21 +77,23 @@ def chip_spec(name: Optional[str] = None) -> dict:
     return {"chip": name, **CHIP_SPECS[name]}
 
 
-def detect_chip(default: str = "v5e") -> str:
-    """Map the live backend's device_kind onto a spec name; falls back
-    to `default` (no backend, unknown kind, CPU)."""
-    try:
-        import jax
+def detect_chip() -> str:
+    """Map the live backend's device_kind onto a spec name.  CPU maps to
+    `cpu-host` (the analyzers' plumbing spec); a live device kind with no
+    row in CHIP_SPECS raises — pricing an unknown chip at another chip's
+    peaks would be a number about nothing."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return default
+    kind = jax.devices()[0].device_kind
+    squashed = kind.lower().replace(" ", "").replace("lite", "e")
     for name in ("v6e", "v5p", "v5e", "v4"):
-        if name in kind.replace(" ", "").replace("lite", "e"):
+        if name in squashed:
             return name
-    if "cpu" in kind or "host" in kind:
+    if "cpu" in squashed or "host" in squashed:
         return "cpu-host"
-    return default
+    raise ValueError(
+        f"unknown device kind {kind!r}: no CHIP_SPECS row "
+        f"(have: {sorted(CHIP_SPECS)}); pass the chip explicitly")
 
 
 # ---------------------------------------------------------------------------

@@ -1157,7 +1157,7 @@ def mode_plan_equivalence(name: str, batch_size: int = 8) -> dict:
 def plan_equivalence_report(names: Optional[Sequence[str]] = None,
                             batch_size: int = 8) -> List[dict]:
     """The 11-mode plan-equivalence sweep (tools/hlo_analysis.py
-    `equiv` mode emits this as JSON; the evidence daemon queues it)."""
+    `equiv` mode emits this as JSON)."""
     from ..parallel import modes as pmodes
 
     return [mode_plan_equivalence(n, batch_size=batch_size)

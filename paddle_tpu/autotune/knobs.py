@@ -81,9 +81,9 @@ def platform(init: bool = False) -> Tuple[str, str]:
         import jax
 
         if not init:
-            from jax._src import xla_bridge
+            from ..framework.place import backend_initialized
 
-            if not getattr(xla_bridge, "_backends", None):
+            if not backend_initialized():
                 return ("unknown", "none")
         return (jax.devices()[0].device_kind, jax.default_backend())
     except Exception:

@@ -2,8 +2,7 @@
 
 Reuses bench.py's ``_timed_loop`` discipline — warmup runs first (the
 compile is never timed), then ``repeats`` passes of ``iters`` steps
-each, completion by VALUE fetch (the only barrier a degraded transport
-must honor), best-of-N as the capability number with every pass
+each, completion by VALUE fetch, best-of-N as the capability number with every pass
 recorded (median is the honest steady-state headline; the spread
 between them is exactly the 6.97-vs-9.89 ms LSTM ambiguity, so both are
 first-class fields).  Donation is the executor's: program runners step
@@ -112,6 +111,16 @@ class TimedMeasurer:
                 f"process, but workload {workload.name!r} is not a "
                 f"registered name the child could rebuild (saved-model "
                 f"spaces must not carry xla_flags values)")
+        from ..framework.place import holds_accelerator
+
+        if holds_accelerator():
+            raise RuntimeError(
+                f"flag candidate {candidate.digest} needs a fresh process "
+                f"on the accelerator, but this process already holds it "
+                f"(a chip belongs to one process at a time; the child "
+                f"would fail or hang).  Tune XLA-flag candidates from a "
+                f"parent that has not touched JAX, or drop xla_flags "
+                f"from the space")
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flags).strip()
         spec = json.dumps({"params": candidate.params,

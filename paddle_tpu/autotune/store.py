@@ -19,7 +19,7 @@ integrity layer (paddle_tpu/compiler.py):
     (the next ``paddle tune`` simply re-measures).
 
 The module is deliberately free of jax imports so the store itself is
-loadable anywhere (the evidence daemon, tests without a backend); the
+loadable anywhere (tests without a backend); the
 platform tag is supplied by callers (``knobs.platform()``).
 
 Layout: one file per entry under ``$PADDLE_TPU_AUTOTUNE_CACHE`` (default

@@ -541,7 +541,7 @@ def cmd_attribute(args) -> int:
     the static cost model, publishes the op_pred_vs_measured gauges,
     and emits ONE bench-schema artifact line.  --profile additionally
     captures a jax.profiler trace of jitted steps with the op identity
-    scopes threaded (the on-chip `op_attribution` evidence capture);
+    scopes threaded (the on-chip path);
     --update-calibration feeds the table into the calibration store the
     autotune prior consumes."""
     import json as _json

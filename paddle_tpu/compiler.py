@@ -1,221 +1,16 @@
-"""Program → pure JAX callable (the AOT face of the executor), plus the
-persistent compile-cache integrity layer.
+"""Program → pure JAX callable (the AOT face of the executor).
 
 Gives external tooling (serving, graft entry, export) a functional handle on a
 program: `build_callable` returns (fn, state) where `fn(state, feeds) ->
 {fetch_name: array}` is pure and jittable — the same lowering Executor.run
 jits internally.
-
-**Compile-cache integrity** (`install_compile_cache_integrity`): jax's
-LRUCache writes entries with a plain ``write_bytes`` — a process killed
-mid-write leaves a truncated executable that every later process
-deserializes into a heap-corrupting abort, identically, forever (the
-"poisoned cache" crash run_tests.sh used to dodge with
-PADDLE_TPU_NO_COMPILE_CACHE=1 retries).  The layer fixes it at the source:
-
-  * **writes are atomic** — the sealed entry lands in a temp file in the
-    cache dir and is published by ``os.replace``;
-  * **entries are sealed** — a magic prefix + sha256 content digest wraps
-    the serialized executable;
-  * **reads verify** — a digest mismatch (truncation, bit rot, a foreign
-    unsealed entry) EVICTS the file and reports a cache miss, so XLA
-    recompiles instead of aborting the process.
-
-Installed by the executor's `_enable_compilation_cache`; everything here
-degrades to the unwrapped cache if jax's private layout drifts.
-
-The seal format is paddle_tpu-private: an unsealed (vanilla-jax) entry
-reads as corrupt and is evicted, and a sealed entry would fail — not
-miss — in an unsealed jax reader.  That is safe ONLY because
-`_enable_compilation_cache` always points jax at a `pdtpu-*` namespaced
-subdirectory this package owns; never install the integrity layer over
-a cache directory shared with non-paddle_tpu jax processes.
 """
 
 from __future__ import annotations
 
-import hashlib
-import os
-from typing import Dict, List, Optional
-
 from .framework.executor import Executor, _lower_ops
 from .framework.scope import global_scope
 from .ops.registry import EmitContext
-
-# ---------------------------------------------------------------------------
-# persistent compile-cache integrity
-
-# version-stamped magic so a future layout change invalidates cleanly
-_SEAL_MAGIC = b"pdtpu-cc1\x00"
-_SEAL_LEN = len(_SEAL_MAGIC) + 32  # magic + sha256
-
-
-def seal_cache_entry(val: bytes) -> bytes:
-    return _SEAL_MAGIC + hashlib.sha256(val).digest() + val
-
-
-def unseal_cache_entry(raw: bytes) -> Optional[bytes]:
-    """Payload bytes if `raw` is a sealed entry with a valid digest,
-    else None (corrupt, truncated, or written by an unsealed producer)."""
-    if raw is None or len(raw) < _SEAL_LEN \
-            or not raw.startswith(_SEAL_MAGIC):
-        return None
-    body = raw[_SEAL_LEN:]
-    if hashlib.sha256(body).digest() != raw[len(_SEAL_MAGIC):_SEAL_LEN]:
-        return None
-    return body
-
-
-class _IntegrityCache:
-    """CacheInterface wrapper: digest-verified get, atomic sealed put.
-    Every outcome is counted in the metrics registry
-    (``compile_cache_total{result=hit|miss|evicted_corrupt}``) — the
-    PR 12 integrity layer's behavior was previously observable only by
-    its absence of crashes."""
-
-    def __init__(self, inner):
-        self._inner = inner
-
-    def _count(self, result: str):
-        from .observability.metrics import REGISTRY
-
-        REGISTRY.counter(
-            "compile_cache_total",
-            "persistent XLA compile-cache reads by outcome").inc(
-            result=result)
-
-    def get(self, key: str):
-        raw = self._inner.get(key)
-        if raw is None:
-            self._count("miss")
-            return None
-        val = unseal_cache_entry(raw)
-        if val is None:
-            # corrupt or unsealed entry: evict so (a) this process
-            # recompiles instead of aborting on poisoned bytes and (b)
-            # the recompile's put is not refused by put's exists() check
-            self._evict(key)
-            self._count("evicted_corrupt")
-            return None
-        self._count("hit")
-        return val
-
-    def put(self, key: str, val: bytes):
-        from .observability.metrics import REGISTRY
-
-        sealed = seal_cache_entry(val)
-        if not self._atomic_put(key, sealed):
-            self._inner.put(key, sealed)  # still sealed, just not atomic
-        REGISTRY.counter(
-            "compile_cache_puts_total",
-            "persistent XLA compile-cache entries written").inc()
-
-    # -- plumbing -------------------------------------------------------
-    def _paths(self, key):
-        path = getattr(self._inner, "path", None)
-        if path is None:
-            return None, None
-        try:
-            import jax._src.lru_cache as lru
-
-            suffix = getattr(lru, "_CACHE_SUFFIX", "-cache")
-            asuffix = getattr(lru, "_ATIME_SUFFIX", "-atime")
-        except Exception:
-            suffix, asuffix = "-cache", "-atime"
-        return path / f"{key}{suffix}", path / f"{key}{asuffix}"
-
-    def _locked(self):
-        import contextlib
-
-        lock = getattr(self._inner, "lock", None)
-        if getattr(self._inner, "eviction_enabled", False) \
-                and lock is not None:
-            return lock
-        return contextlib.nullcontext()
-
-    def _evict(self, key: str):
-        cache_path, atime_path = self._paths(key)
-        if cache_path is None:
-            return
-        try:
-            with self._locked():
-                for p in (cache_path, atime_path):
-                    try:
-                        os.remove(p)
-                    except OSError:
-                        pass
-        except Exception:
-            pass
-
-    def _atomic_put(self, key: str, sealed: bytes) -> bool:
-        """Replicate LRUCache.put with a tmp+rename publish.  Returns
-        False on any layout surprise so the caller can fall back."""
-        cache_path, atime_path = self._paths(key)
-        if cache_path is None:
-            return False
-        # the temp name must NOT end in the "-cache" suffix: LRUCache's
-        # eviction globs *-cache and reads each entry's companion atime
-        # file, so suffix-matching debris from a killed writer would
-        # poison every later put with FileNotFoundError — exactly the
-        # failure class this layer exists to close
-        tmp = cache_path.parent / \
-            f"{cache_path.name}.pdtpu-tmp-{os.getpid()}"
-        try:
-            import time
-
-            with self._locked():
-                if cache_path.exists():
-                    return True
-                if hasattr(self._inner, "_evict_if_needed"):
-                    self._inner._evict_if_needed(
-                        additional_size=len(sealed))
-                tmp.write_bytes(sealed)
-                # atime BEFORE publish: eviction reads every published
-                # entry's atime companion, so a kill between the two
-                # writes must orphan an invisible atime file, never a
-                # visible entry with no atime (which would fail every
-                # later put with FileNotFoundError)
-                atime_path.write_bytes(
-                    time.time_ns().to_bytes(8, "little"))
-                os.replace(tmp, cache_path)
-            return True
-        except Exception:
-            for p in (tmp, atime_path):
-                try:
-                    os.remove(p)
-                except OSError:
-                    pass
-            return False
-
-
-_integrity_installed = False
-
-
-def install_compile_cache_integrity():
-    """Wrap jax's persistent compilation cache with the integrity layer
-    (idempotent; safe to call before the cache is initialized — the
-    wrapper intercepts whatever `_get_cache` later constructs)."""
-    global _integrity_installed
-    if _integrity_installed:
-        return
-    import jax._src.compilation_cache as cc
-
-    orig_get = cc._get_cache
-    wrappers: Dict[int, _IntegrityCache] = {}
-
-    def _get_cache_with_integrity(backend):
-        inner = orig_get(backend)
-        if inner is None or isinstance(inner, _IntegrityCache):
-            return inner
-        w = wrappers.get(id(inner))
-        if w is None or w._inner is not inner:
-            w = _IntegrityCache(inner)
-            wrappers.clear()  # reset_cache() swapped the instance
-            wrappers[id(inner)] = w
-        return w
-
-    cc._get_cache = _get_cache_with_integrity
-    _integrity_installed = True
 
 
 def build_callable(program, fetch_list, scope=None, feed_names=None,

@@ -33,10 +33,10 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """One accelerator chip. Falls back to the default JAX backend's device
-    `device_id` — under a CPU-only test environment this is a host device, so
-    programs written against TPUPlace still run (the reference's WITH_GPU=OFF
-    stub story, paddle/cuda/include/stub/)."""
+    """TPU chip `device_id` of this process: ``jax.devices("tpu")[device_id]``.
+    A process with no TPU, or fewer than `device_id + 1` of them, has no such
+    place, and resolving it raises — a program written against TPUPlace
+    never runs somewhere else under that name."""
 
     def __init__(self, device_id: int = 0):
         self.device_id = device_id
@@ -44,8 +44,18 @@ class TPUPlace(Place):
     def jax_device(self):
         import jax
 
-        devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        try:
+            devs = jax.devices("tpu")
+        except RuntimeError as e:
+            raise RuntimeError(
+                f"{self!r}: this process has no TPU backend (default "
+                f"backend {jax.default_backend()!r} with "
+                f"{jax.device_count()} device(s)): {e}") from e
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                f"{self!r}: this process has {len(devs)} TPU device(s), "
+                f"ids 0..{len(devs) - 1}")
+        return devs[self.device_id]
 
     def __repr__(self):
         return f"TPUPlace({self.device_id})"
@@ -54,6 +64,22 @@ class TPUPlace(Place):
 # Alias: code ported from the reference may say CUDAPlace; on this framework it
 # means "the accelerator" (TPU).
 CUDAPlace = TPUPlace
+
+
+def backend_initialized() -> bool:
+    """Whether this process has initialised a JAX backend.  On a TPU
+    machine that is the moment it takes the chip: a chip belongs to one
+    process at a time, so a child started afterwards that needs the chip
+    fails or hangs.  The one place that asks JAX's private bridge."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def holds_accelerator() -> bool:
+    """True once this process has initialised a non-CPU backend (asking
+    never initialises one)."""
+    return backend_initialized() and has_accelerator()
 
 
 @functools.lru_cache(maxsize=None)

@@ -578,9 +578,12 @@ def get_places(device_count=None, device_type=None):
     from ..framework.place import CPUPlace, TPUPlace, default_place
     import jax
 
-    kind = device_type or default_place().kind
+    if device_type is None:
+        on_chip = isinstance(default_place(), TPUPlace)
+    else:
+        on_chip = device_type in ("tpu", "gpu", "cuda")
     n = device_count or len(jax.devices())
-    if kind in ("tpu", "gpu", "cuda"):
+    if on_chip:
         return [TPUPlace(i) for i in range(n)]
     return [CPUPlace() for _ in range(n)]
 

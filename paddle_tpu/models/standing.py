@@ -3,8 +3,8 @@
 fit-a-line, recognize-digits, the small decoder LM, and the autotune
 LSTM — the fixed set of programs every calibration layer measures:
 tools/pred_vs_measured.py (program-level ratios), ``paddle attribute``
-(the per-op attribution table), and the evidence-daemon captures all
-build from HERE, so the ratios, the per-op factors, and the sweep's
+(the per-op attribution table) and the autotune sweep all build from
+HERE, so the ratios, the per-op factors, and the sweep's
 rank errors describe the SAME descs.
 
 Each builder mutates the default main/startup programs (callers

@@ -13,8 +13,7 @@ materializes the error ratios
     pred_vs_measured_peak_ratio{program=...}       = predicted/measured
 
 which :func:`artifact_rows` emits in the bench.py artifact schema so
-``tools/render_results.py`` (and the autotuner of ROADMAP #3) can read
-the cost model's error per round without bespoke plumbing.
+the cost model's error can be read per round without bespoke plumbing.
 
 Ratio convention: predicted/measured, matching the ISSUE text — 1.0 is a
 perfect model, >1 the static model over-prices, <1 it under-prices.
@@ -191,9 +190,9 @@ def report() -> List[dict]:
 
 
 def artifact_rows() -> List[dict]:
-    """report() in the bench.py artifact schema — the rows
-    tools/render_results.py (and the book-model/small-LM acceptance
-    artifact) consume.  Skips programs with no measurement yet."""
+    """report() in the bench.py artifact schema — the rows the
+    book-model/small-LM acceptance artifact consumes.  Skips programs
+    with no measurement yet."""
     from .metrics import artifact_metric
 
     out = []

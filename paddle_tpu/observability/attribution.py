@@ -10,8 +10,8 @@ Three pieces, one table:
     no-op context and the lowering hot path pays one attribute check
     per op per TRACE (never per step).
   * **capture** — :func:`capture_profile` runs steps under
-    ``jax.profiler.trace`` (Perfetto output — the on-chip
-    ``op_attribution`` evidence capture) and best-effort parses the
+    ``jax.profiler.trace`` (Perfetto output — the on-chip path) and
+    best-effort parses the
     scope-named events back into per-op durations;
     :func:`attribute_cpu` is the deterministic CPU fallback oracle:
     segment-timed eager execution over the hazard-respecting

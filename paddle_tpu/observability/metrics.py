@@ -27,8 +27,8 @@ Design points:
     docs/observability.md.
 
 This module is deliberately stdlib-only and free of package-relative
-imports so out-of-tree consumers (tools/evidence_daemon.py, which must
-not drag in jax) can load it straight from its file path.
+imports so consumers that must not drag in jax can load it straight
+from its file path.
 """
 
 from __future__ import annotations

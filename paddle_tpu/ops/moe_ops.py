@@ -112,9 +112,8 @@ def _moe_sharded(ctx, x, gate_w, wi, wo, mesh, token_axes, factor, act):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import get_shard_map
+    from jax import shard_map
 
-    shard_map = get_shard_map()
     n_members = 1
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     for a in token_axes:

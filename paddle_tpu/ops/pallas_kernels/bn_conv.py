@@ -185,8 +185,6 @@ def bn_conv3x3_fwd_v2(x, gamma, beta, mean, var, w_hwio, r=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ._common import compiler_params as _pk_compiler_params
-
     N, H, W, K = x.shape
     Ho, Wo = H // stride, W // stride
     O = w_hwio.shape[-1]
@@ -218,7 +216,7 @@ def bn_conv3x3_fwd_v2(x, gamma, beta, mean, var, w_hwio, r=None,
             pltpu.VMEM((H + 2, W + 2, K), w_hwio.dtype)],
         # j must be sequential on a Megacore part: the scratch prep at
         # j==0 is reused by every later j of the same image
-        compiler_params=_pk_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -32,9 +32,8 @@ def _snap_block(block: int, T: int, tile: int = 128) -> int:
     (a seq len of 1536 must not fail the bk=1024 default — it runs at
     bk=768).  The tile floor enforces the (8,128)-divisible Mosaic block
     contract for every dtype the kernels accept: an unaligned divisor
-    (ADVICE r4: T=10880 snapped block_q=512 to 340) would pass tracing,
-    fail Mosaic at execution, and runtime_disable would then black out ALL
-    fused kernels process-wide.  Returns 0 when no aligned divisor exists;
+    (ADVICE r4: T=10880 snapped block_q=512 to 340) would pass tracing
+    and fail Mosaic at execution.  Returns 0 when no aligned divisor exists;
     callers raise at trace time, and the dispatch gates (T % 128 == 0 with
     default blocks >= 128) never reach that case."""
     # the 128 floor is deliberately stricter than the (8,128) sublane
@@ -166,8 +165,6 @@ def _fwd_grid(B, H, T, D, bq, bk, causal, with_lse, dtype, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ._common import compiler_params as _pk_compiler_params
-
     nk = T // bk
 
     if causal:
@@ -202,10 +199,11 @@ def _fwd_grid(B, H, T, D, bq, bk, causal, with_lse, dtype, interpret,
         # dimension — on a Megacore part a "parallel" i could split that
         # block's writeback across cores and clobber slices, so i must be
         # sequential ("arbitrary") whenever the lse output exists
-        compiler_params=_pk_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "arbitrary" if with_lse else "parallel",
                 "arbitrary")),
+        name="flash_fwd",
         interpret=interpret,
     )
 
@@ -358,8 +356,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ._common import compiler_params as _pk_compiler_params
-
     B, H, T, D = q.shape
     bq, bk = _snap_blocks(block_q, block_k, T, interpret)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -400,8 +396,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_pk_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qf, kf, vf, dof, lse3, delta3)
 
@@ -427,8 +424,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_pk_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(qf, kf, vf, dof, lse3, delta3)
     rs = lambda a: a.reshape(B, H, T, D)

@@ -88,6 +88,7 @@ def gru_forward(x_proj, h0, w, lengths, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H), x_proj.dtype),
         ],
         scratch_shapes=[_vmem()((B, H), jnp.float32)],
+        name="gru_fwd",
         interpret=interpret,
     )(xt, mask.T, h0, w)
     return jnp.moveaxis(hs, 0, 1), hT
@@ -193,6 +194,7 @@ def gru_backward(x_proj, h0, w, lengths, hs, dhs, interpret: bool = False):
         scratch_shapes=[
             _vmem()((B, H), jnp.float32),
         ],
+        name="gru_bwd",
         interpret=interpret,
     )(tm(x_proj), mask.T, tm(h_prev), tm(dhs), w)
     return jnp.moveaxis(dx_t, 0, 1), dh0, dw.astype(w.dtype)

@@ -112,6 +112,7 @@ def lstm_forward(x_proj, h0, c0, w, lengths, interpret: bool = False):
             _vmem()((B, H), jnp.float32),
             _vmem()((B, H), jnp.float32),
         ],
+        name="lstm_fwd",
         interpret=interpret,
     )(xt, mt, h0, c0, w)
     return jnp.moveaxis(hs, 0, 1), jnp.moveaxis(cs, 0, 1), hT, cT
@@ -280,6 +281,7 @@ def lstm_backward(x_proj, h0, c0, w, lengths, hs, cs, dhs, dcs,
             _vmem()((B, H), jnp.float32),
             _vmem()((B, H), jnp.float32),
         ],
+        name="lstm_bwd",
         interpret=interpret,
     )(tm(x_proj), mask.T, tm(h_prev), tm(c_prev), tm(dhs), tm(dcs), w)
     return jnp.moveaxis(dx_t, 0, 1), dh0, dc0, dw.astype(w.dtype)

@@ -46,7 +46,11 @@ A MULTI-QUERY pair (``paged_attention_mq_ref`` / ``paged_attention_mq``)
 generalizes the same walk to a Q-block of C rows per slot — the chunked-
 prefill and speculative-verify attention, where row c is causally masked
 to key positions <= q_starts[n] + c.  Same grid, same clamped page walk;
-only the scratch widens to C rows.
+the scratch holds C rows.  There is ONE kernel body: ``paged_attention``
+is the multi-query kernel at C=1 with ``q_starts = ctx_lens - 1``.  A
+separate single-row body contracted a rank-2 ``q [nh, dh]`` against the
+rank-3 page ``[nh, ps, dh]``, which the Mosaic lowering refuses (a
+batched matmul needs a non-contracting dim on both sides).
 """
 
 from __future__ import annotations
@@ -126,110 +130,6 @@ def paged_attention_mq_ref(q, k_pages, v_pages, page_table, ctx_lens,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _kernel_body(pt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                 m_sc, l_sc, acc_sc, *, scale: float, ps: int):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    n = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[...] = jnp.full(m_sc.shape, -1e30, dtype=jnp.float32)
-        l_sc[...] = jnp.zeros(l_sc.shape, dtype=jnp.float32)
-        acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
-
-    cl = cl_ref[n]
-    n_pages = (cl + ps - 1) // ps
-
-    def _compute():
-        q = q_ref[0]  # [nh, dh] input dtype — full-rate MXU
-        k = k_ref[0]  # [nh, ps, dh]
-        v = v_ref[0]
-        # batched over heads: s[h, t] = q[h] . k[h, t]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [nh, ps]
-        pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < cl, s, -1e30)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * corr + p.sum(axis=-1)
-        m_sc[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)  # [nh, dh]
-        acc_sc[...] = acc_sc[...] * corr[:, None] + pv
-
-    pl.when(j < n_pages)(_compute)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        # ctx_lens >= 1 guarantees page 0 computed, so l > 0 here
-        o_ref[0] = (acc_sc[...] / l_sc[...][:, None]).astype(o_ref.dtype)
-
-
-def paged_attention(q, k_pages, v_pages, page_table, ctx_lens, scale=None,
-                    interpret: bool = False):
-    """Pallas paged-attention decode kernel (see module docstring).
-
-    Grid (N, maxp) with the page walk innermost so the pipeline
-    double-buffers page DMAs against the MXU GEMMs; the K/V index maps
-    read the scalar-prefetched page table, clamping past-the-end steps
-    to the sequence's last valid page (free re-fetch, compute skipped)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from ._common import compiler_params
-
-    N, nh, dh = q.shape
-    ps = k_pages.shape[2]
-    maxp = page_table.shape[1]
-    s = scale if scale is not None else 1.0 / (dh ** 0.5)
-    pt = page_table.astype(jnp.int32)
-    cl = ctx_lens.astype(jnp.int32)
-
-    def q_idx(n, j, pt_ref, cl_ref):
-        return (n, 0, 0)
-
-    def kv_idx(n, j, pt_ref, cl_ref):
-        n_pages = (cl_ref[n] + ps - 1) // ps
-        return (pt_ref[n, jnp.minimum(j, n_pages - 1)], 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N, maxp),
-        in_specs=[
-            pl.BlockSpec((1, nh, dh), q_idx),
-            pl.BlockSpec((1, nh, ps, dh), kv_idx),
-            pl.BlockSpec((1, nh, ps, dh), kv_idx),
-        ],
-        out_specs=pl.BlockSpec((1, nh, dh), q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((nh,), jnp.float32),
-            pltpu.VMEM((nh,), jnp.float32),
-            pltpu.VMEM((nh, dh), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_body, scale=s, ps=ps),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, nh, dh), q.dtype),
-        # the page walk accumulates into shared per-n scratch: j must stay
-        # sequential; n iterations are independent
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(pt, cl, q, k_pages, v_pages)
-
-
 def _mq_kernel_body(pt_ref, cl_ref, q0_ref, q_ref, k_ref, v_ref, o_ref,
                     m_sc, l_sc, acc_sc, *, scale: float, ps: int):
     import jax
@@ -287,15 +187,16 @@ def paged_attention_mq(q, k_pages, v_pages, page_table, ctx_lens, q_starts,
     ragged page walk with a Q-block of C rows per slot (contract in
     paged_attention_mq_ref).  This is the chunked-prefill / speculative-
     verify step's attention: C positions score against the whole paged
-    context in one walk, with NO dense gather of the pool — same grid
-    (N, maxp), same scalar-prefetched clamped page walk, scratch widened
-    to C query rows."""
+    context in one walk, with NO dense gather of the pool.
+
+    Grid (N, maxp) with the page walk innermost so the pipeline
+    double-buffers page DMAs against the MXU GEMMs; the K/V index maps
+    read the scalar-prefetched page table, clamping past-the-end steps
+    to the sequence's last valid page (free re-fetch, compute skipped)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    from ._common import compiler_params
 
     N, nh, C, dh = q.shape
     ps = k_pages.shape[2]
@@ -331,10 +232,26 @@ def paged_attention_mq(q, k_pages, v_pages, page_table, ctx_lens, q_starts,
         functools.partial(_mq_kernel_body, scale=s, ps=ps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, nh, C, dh), q.dtype),
-        compiler_params=compiler_params(
+        # the page walk accumulates into shared per-n scratch: j must stay
+        # sequential; n iterations are independent
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_attention_mq",
         interpret=interpret,
     )(pt, cl, q0, q, k_pages, v_pages)
+
+
+def paged_attention(q, k_pages, v_pages, page_table, ctx_lens, scale=None,
+                    interpret: bool = False):
+    """Pallas paged-attention decode kernel (see module docstring): the
+    multi-query page walk with one query row per slot, the row sitting at
+    the last attended position."""
+    import jax.numpy as jnp
+
+    out = paged_attention_mq(
+        q[:, :, None, :], k_pages, v_pages, page_table, ctx_lens,
+        ctx_lens.astype(jnp.int32) - 1, scale=scale, interpret=interpret)
+    return out[:, :, 0, :]
 
 
 def paged_dispatch_ok(ctx, page_size: int, head_dim: int) -> bool:

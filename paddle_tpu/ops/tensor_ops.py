@@ -351,7 +351,7 @@ def save_op(ctx, ins, attrs):
     value rides out of the compiled step as a reserved fetch; the executor
     writes `file_path` right after the step completes.  (io_callback would
     put the write inside the program, but host callbacks are not available
-    on every PJRT backend — e.g. tunneled TPUs.)"""
+    on every PJRT backend.)"""
     if getattr(ctx, "sub_depth", 0) > 0:
         raise NotImplementedError(
             "save op inside a control-flow sub-block: its value cannot "

@@ -136,37 +136,3 @@ def make_hybrid_mesh(ici_axes: Dict[str, int],
     # else simulated DCN: contiguous chunks stand in for slices
     arr = np.asarray(devices).reshape(sizes)
     return Mesh(arr, axis_names=names)
-
-
-def get_shard_map():
-    """Version-portable shard_map import (moved to jax.* in 0.8).
-
-    The replication-check kwarg was renamed check_rep → check_vma across
-    versions; callers pass `check_vma` and this shim adapts it to whatever
-    the installed jax accepts."""
-    import inspect
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-    try:
-        params = inspect.signature(shard_map).parameters
-        has_vma = "check_vma" in params
-        has_rep = "check_rep" in params
-    except (TypeError, ValueError):  # pragma: no cover
-        return shard_map
-    if has_vma:
-        return shard_map
-
-    def adapted(f=None, **kw):  # pragma: no cover - exercised on old jax only
-        if "check_vma" in kw:
-            val = kw.pop("check_vma")
-            if has_rep:
-                kw["check_rep"] = val
-        if f is None:
-            return lambda g: shard_map(g, **kw)
-        return shard_map(f, **kw)
-
-    return adapted

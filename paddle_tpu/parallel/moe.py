@@ -75,9 +75,9 @@ def build_moe_train_step(mesh, d_model: int, d_hidden: int, capacity: int,
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from .mesh import get_shard_map, pspec as P
+    from jax import shard_map
 
-    shard_map = get_shard_map()
+    from .mesh import pspec as P
 
     @partial(shard_map, mesh=mesh,
              in_specs=({"wi": P("ep"), "wo": P("ep"), "gate": P()},

@@ -98,9 +98,9 @@ def build_pipeline_train_step(mesh, n_micro: int, width: int,
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from .mesh import get_shard_map, pspec as P
+    from jax import shard_map
 
-    shard_map = get_shard_map()
+    from .mesh import pspec as P
 
     pp = mesh.shape["pp"]
     dp = mesh.shape.get("dp", 1)

@@ -38,7 +38,6 @@ import numpy as np
 from ..framework.executor import _lower_ops
 from ..framework.scope import global_scope
 from ..ops.registry import EmitContext
-from .mesh import get_shard_map
 
 def split_stages(block) -> List[list]:
     """Partition the block's ops at pipeline_stage markers (markers and
@@ -286,7 +285,7 @@ class ProgramPipeline:
     def _compile(self, feed_shapes):
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from .mesh import pspec as P
 
         batch = next(iter(feed_shapes.values()))[0]
@@ -305,7 +304,6 @@ class ProgramPipeline:
                      for s in range(self.pp)]
         n_micro, pp = self.n_micro, self.pp
         fwd_perm = [(s, s + 1) for s in range(pp - 1)]
-        shard_map = get_shard_map()
         feeds_spec = P(None, "dp") if dp > 1 else P()
 
         @partial(shard_map, mesh=self.mesh,

@@ -467,9 +467,8 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sp",
     `zigzag_permutation` and passing `pre_permuted=True` per layer."""
     import jax
 
-    from .mesh import get_shard_map
+    from jax import shard_map
 
-    shard_map = get_shard_map()
     from .mesh import pspec as P
 
     d = q.shape[-1]
@@ -603,11 +602,10 @@ def ulysses_attention(q, k, v, mesh, axis_name: str = "sp",
     flash kernel — the training custom_vjp pair when `is_train`."""
     import functools
 
-    from .mesh import get_shard_map, pspec as P
+    from jax import shard_map
 
-    from .mesh import axis_size
+    from .mesh import axis_size, pspec as P
 
-    shard_map = get_shard_map()
     S = axis_size(mesh, axis_name)
     if q.shape[1] % S:
         raise ValueError(

@@ -376,24 +376,48 @@ class ServingEngine:
             self.scheduler.finish(req, now=now)
             self.finished[req.rid] = req
 
+    def _slot_feed(self, prefix: str, slots) -> dict:
+        """The decode-side feeds over all `num_slots` lanes: each
+        (slot, request) in `slots` feeds its last token at its context
+        length; every other lane is idle (masked, null page)."""
+        N = self.num_slots
+        tok = np.zeros((N, 1), np.int64)
+        ctx = np.zeros((N, 1), np.int64)
+        act = np.zeros((N, 1), np.int64)
+        for slot, r in slots:
+            tok[slot, 0] = r.generated[-1]
+            ctx[slot, 0] = r.ctx_len
+            act[slot, 0] = 1
+        return {f"{prefix}.tok": tok, f"{prefix}.ctx": ctx,
+                f"{prefix}.act": act,
+                f"{prefix}.pt": self.cache.page_table_i64()}
+
+    def _chunk_feed(self, chunks) -> dict:
+        """The mixed program's chunk-lane feeds: lane j prefills
+        `chunks[j] = (request, chunk_len)`; the other lanes idle."""
+        K, C = self.chunk_lanes, self.chunk_size
+        ctok = np.zeros((K, C, 1), np.int64)
+        cctx = np.zeros((K, 1), np.int64)
+        cclen = np.zeros((K, 1), np.int64)
+        cpt = np.zeros((K, self.max_pages), np.int64)
+        for j, (r, cl) in enumerate(chunks):
+            prefix = r.prompt + r.generated
+            ctok[j, :cl, 0] = prefix[r.ctx_len:r.ctx_len + cl]
+            cctx[j, 0] = r.ctx_len
+            cclen[j, 0] = cl
+            cpt[j] = self.cache.page_table[r.slot]
+        return {f"{self._pfx}.m.ctok": ctok, f"{self._pfx}.m.cctx": cctx,
+                f"{self._pfx}.m.cclen": cclen, f"{self._pfx}.m.cpt": cpt}
+
     def _decode(self):
         if not self.scheduler.active:
             return
         with _TRC.span("serve.decode",
                        active=len(self.scheduler.active)):
-            N = self.num_slots
-            tok = np.zeros((N, 1), np.int64)
-            ctx = np.zeros((N, 1), np.int64)
-            act = np.zeros((N, 1), np.int64)
-            for slot, r in self.scheduler.active.items():
-                tok[slot, 0] = r.generated[-1]
-                ctx[slot, 0] = r.ctx_len
-                act[slot, 0] = 1
             (nxt,) = self._exe.run(
                 self._decode_prog,
-                feed={f"{self._pfx}.tok": tok, f"{self._pfx}.ctx": ctx,
-                      f"{self._pfx}.act": act,
-                      f"{self._pfx}.pt": self.cache.page_table_i64()},
+                feed=self._slot_feed(self._pfx,
+                                     self.scheduler.active.items()),
                 fetch_list=[self._decode_fetch])
             nxt = np.asarray(nxt)
             now = self._clock()
@@ -475,39 +499,15 @@ class ServingEngine:
             self._steps += 1
             return self.scheduler.outstanding() > 0
 
-        N, K, C = self.num_slots, self.chunk_lanes, self.chunk_size
-        tok = np.zeros((N, 1), np.int64)
-        ctx = np.zeros((N, 1), np.int64)
-        act = np.zeros((N, 1), np.int64)
-        for slot, r in decoding:
-            tok[slot, 0] = r.generated[-1]
-            ctx[slot, 0] = r.ctx_len
-            act[slot, 0] = 1
-        ctok = np.zeros((K, C, 1), np.int64)
-        cctx = np.zeros((K, 1), np.int64)
-        cclen = np.zeros((K, 1), np.int64)
-        cpt = np.zeros((K, self.max_pages), np.int64)
-        chunk_of: List[tuple] = []
-        for j, r in enumerate(lanes):
-            prefix = r.prompt + r.generated
-            cl = min(C, r.prefill_target - r.ctx_len)
-            ctok[j, :cl, 0] = prefix[r.ctx_len:r.ctx_len + cl]
-            cctx[j, 0] = r.ctx_len
-            cclen[j, 0] = cl
-            cpt[j] = self.cache.page_table[r.slot]
-            chunk_of.append((r, cl))
+        chunk_of: List[tuple] = [
+            (r, min(self.chunk_size, r.prefill_target - r.ctx_len))
+            for r in lanes]
         with _TRC.span("serve.mixed_step", lanes=len(lanes),
                        decoding=len(decoding)):
             (nxt, cnxt) = self._exe.run(
                 self._mixed_prog,
-                feed={f"{self._pfx}.m.tok": tok,
-                      f"{self._pfx}.m.ctx": ctx,
-                      f"{self._pfx}.m.act": act,
-                      f"{self._pfx}.m.pt": self.cache.page_table_i64(),
-                      f"{self._pfx}.m.ctok": ctok,
-                      f"{self._pfx}.m.cctx": cctx,
-                      f"{self._pfx}.m.cclen": cclen,
-                      f"{self._pfx}.m.cpt": cpt},
+                feed={**self._slot_feed(f"{self._pfx}.m", decoding),
+                      **self._chunk_feed(chunk_of)},
                 fetch_list=[self._mixed_decode_fetch,
                             self._mixed_chunk_fetch])
         nxt, cnxt = np.asarray(nxt), np.asarray(cnxt)
@@ -601,6 +601,24 @@ class ServingEngine:
             out.update(self._spec.programs())
         for b, (prog, _) in sorted(self._prefill_progs.items()):
             out[f"prefill_{b}"] = prog
+        return out
+
+    def optimized_hlo(self) -> Dict[str, str]:
+        """Post-optimization HLO text of the steady-state programs at the
+        shapes the engine runs them — {"decode": ..., "mixed": ...} (the
+        latter under v2/spec).  What a chip smoke reads to see which
+        attention path the compiler was actually handed; all-idle feeds,
+        so it shares run()'s executables (Executor.optimized_hlo)."""
+        out = {"decode": self._exe.optimized_hlo(
+            self._decode_prog, feed=self._slot_feed(self._pfx, ()),
+            fetch_list=[self._decode_fetch])}
+        if self._mixed_prog is not None:
+            out["mixed"] = self._exe.optimized_hlo(
+                self._mixed_prog,
+                feed={**self._slot_feed(f"{self._pfx}.m", ()),
+                      **self._chunk_feed(())},
+                fetch_list=[self._mixed_decode_fetch,
+                            self._mixed_chunk_fetch])
         return out
 
     def hbm_report(self) -> dict:

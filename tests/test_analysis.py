@@ -1100,7 +1100,7 @@ def test_analyze_cli_on_saved_model(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# repo_lint: CompilerParams rename-shim guard
+# repo_lint
 
 
 def test_repo_lint_ptv_docs_drift_guard(tmp_path):
@@ -1127,33 +1127,6 @@ def test_repo_lint_ptv_docs_drift_guard(tmp_path):
     assert any("undocumented verifier rule: PTV002" in f
                for f in findings), findings
     assert any("stale rule doc: PTV099" in f for f in findings), findings
-
-
-def test_repo_lint_flags_direct_compiler_params(tmp_path):
-    rl = _repo_lint_module()
-
-    pkg = tmp_path / "paddle_tpu" / "ops" / "pallas_kernels"
-    pkg.mkdir(parents=True)
-    for d in (tmp_path / "paddle_tpu", tmp_path / "paddle_tpu" / "ops",
-              pkg):
-        (d / "__init__.py").write_text("")
-    # assembled so THIS test file never matches the guard itself
-    cls_new = "TPUCompiler" + "Params"
-    cls_old = "Compiler" + "Params"
-    # the blessed site: only _common.py may name the class
-    (pkg / "_common.py").write_text(
-        "def compiler_params(**kw):\n"
-        f"    return {cls_new}(**kw)\n")
-    assert rl.lint(str(tmp_path)) == []
-    (pkg / "rogue_kernel.py").write_text(
-        f"params = pltpu.{cls_new}(dimension_semantics=())\n")
-    findings = rl.lint(str(tmp_path))
-    assert any("direct CompilerParams construction" in f
-               and "rogue_kernel.py" in f for f in findings), findings
-    # the old spelling is caught too
-    (pkg / "rogue_kernel.py").write_text(
-        f"params = pltpu.{cls_old}()\n")
-    assert any("rogue_kernel.py:1" in f for f in rl.lint(str(tmp_path)))
 
 
 def test_repo_lint_flags_partition_spec_in_parallel(tmp_path):

@@ -9,11 +9,8 @@ tests/test_serving.py):
     known flaky native XLA-CPU tracer crash — retry it;
   * a real failure (0 < rc < 128) propagates immediately;
   * the LAST attempt runs with PADDLE_TPU_NO_COMPILE_CACHE=1 as a
-    belt-and-braces fallback.  The compile-cache integrity layer
-    (paddle_tpu/compiler.py) already evicts corrupt entries at the source,
-    so cacheless retry is no longer load-bearing for truncated-entry
-    poisoning — it remains for the residual class the digest cannot see
-    (a well-formed entry whose AOT code the host still cannot run).
+    belt-and-braces fallback (a well-formed cache entry whose AOT code
+    the host still cannot run).
 
 Usage:
     python tools/cache_guard.py [--attempts N] [--fresh-dir DIR]... -- cmd...

@@ -11,8 +11,7 @@ Modes:
   --smoke    1 scenario (worker_kill) x 1 seed — the run_tests.sh fast
              tier gate, <30s on CPU
   --matrix   all 5 scenarios x --seeds seeds + the 16k-context
-             fit-because-remat admission demo — the evidence-daemon
-             capture
+             fit-because-remat admission demo
 
 Emits one JSON artifact (stdout line + optional --out file); exits 1 if
 any cell fails its proof.

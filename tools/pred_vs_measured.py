@@ -16,8 +16,8 @@ ratios:
 The chip spec defaults to the DETECTED backend (cpu-host on the CPU
 mesh), so a CPU run prices the roofline against the CPU's numbers: its
 step-time ratio measures dispatch overhead on microscopic models, not
-model error — the on-chip capture (evidence daemon: `pred_vs_measured`)
-is the number ROADMAP #3 tunes against.  Peak ratios are meaningful on
+model error — a run of this tool on the chip is the number to tune
+against.  Peak ratios are meaningful on
 both (XLA's buffer assignment is the same machinery).
 
 Flags:

@@ -1,17 +1,14 @@
 """Repository hygiene lint (the fast CI tier in run_tests.sh).
 
-Three classes of rot this repo has actually accumulated:
+Classes of rot this repo has actually accumulated:
 
   1. orphaned bytecode — a ``__pycache__/*.pyc`` whose source module was
      deleted (paddle_tpu/observability/ shipped exactly this: sources
      removed, compiled ghosts left importable-looking);
   2. packages missing ``__init__.py`` — a directory of .py modules under
      the package tree that Python will not treat as a package;
-  3. direct ``TPUCompilerParams``/``CompilerParams`` construction —
-     jax renamed the pltpu class across releases (7 seed pallas tests
-     failed on it); every kernel must go through
-     ``ops/pallas_kernels/_common.compiler_params()``, which resolves
-     the name at runtime.  Only _common.py may touch the class.
+  3. (retired with the jax-version shim it guarded; the numbers of the
+     other rules are cited elsewhere and stay.)
   4. ``PartitionSpec`` literals inside ``paddle_tpu/parallel/`` outside
      ``mesh.py`` — specs must stay RULE-DERIVED (minted by
      ``mesh.pspec``/``named``/``replicated``) so the sharding analyzer
@@ -82,40 +79,12 @@ import os
 import re
 import sys
 
+# the root-level scripts the line rules police
+_ROOT_SCRIPTS = ("bench.py", "chip_smoke.py", "__graft_entry__.py")
 # directory names whose contents are never package code
 _SKIP_DIRS = {".git", "__pycache__", "node_modules", ".venv"}
 # top-level trees exempt from the missing-__init__ rule
 _NO_INIT_OK = {"tests", "docs"}
-
-# the rename-shim regression guard: constructing either class name
-# directly bakes one jax release's spelling into a kernel.  The pattern
-# is assembled so this file does not flag itself.
-_COMPILER_PARAMS_RE = re.compile(
-    r"\b(?:TPU)?Compiler" + r"Params\s*\(")
-_COMPILER_PARAMS_OK = os.path.join(
-    "paddle_tpu", "ops", "pallas_kernels", "_common.py")
-
-
-def _check_compiler_params(root, dirpath, filenames, findings):
-    for fname in filenames:
-        if not fname.endswith(".py"):
-            continue
-        path = os.path.join(dirpath, fname)
-        rel = os.path.relpath(path, root)
-        if rel == _COMPILER_PARAMS_OK:
-            continue
-        try:
-            with open(path, encoding="utf-8", errors="replace") as f:
-                for i, line in enumerate(f, 1):
-                    if _COMPILER_PARAMS_RE.search(line):
-                        findings.append(
-                            f"direct CompilerParams construction: "
-                            f"{rel}:{i} (use ops/pallas_kernels/"
-                            f"_common.compiler_params() — the class "
-                            f"name changes across jax releases)")
-        except OSError:
-            pass
-
 
 # the rule-derived-specs guard: PartitionSpec named (constructed OR
 # imported, aliasing included) anywhere in parallel/ except the mint
@@ -263,9 +232,8 @@ def _check_perf_counter(root, dirpath, filenames, findings):
         if rel in _PERF_COUNTER_OK or rel == os.path.join(
                 "tools", "repo_lint.py"):
             continue
-        # top-level scan covers bench.py; skip other root scripts that
-        # are not ours to police (none today, but the rule is scoped)
-        if top == "" and fname not in ("bench.py", "__graft_entry__.py"):
+        # the top-level scan covers _ROOT_SCRIPTS only
+        if top == "" and fname not in _ROOT_SCRIPTS:
             continue
         try:
             with open(path, encoding="utf-8", errors="replace") as f:
@@ -362,7 +330,7 @@ def _check_knob_env(root, dirpath, filenames, findings):
         rel = os.path.relpath(path, root)
         if rel == os.path.join("tools", "repo_lint.py"):
             continue
-        if top == "" and fname not in ("bench.py", "__graft_entry__.py"):
+        if top == "" and fname not in _ROOT_SCRIPTS:
             continue
         try:
             with open(path, encoding="utf-8", errors="replace") as f:
@@ -567,7 +535,6 @@ def lint(root: str):
                     f"(only __pycache__, no sources)")
             dirnames[:] = []
             continue
-        _check_compiler_params(root, dirpath, filenames, findings)
         _check_partition_spec(root, dirpath, filenames, findings)
         _check_mode_dispatch(root, dirpath, filenames, findings)
         _check_page_table(root, dirpath, filenames, findings)

@@ -61,8 +61,7 @@ def polarity(name: str, unit: str) -> int:
 
 def load_rows(path: str) -> Dict[str, dict]:
     """metric name -> row, from a file of bench-schema JSON lines.
-    ``extra_metrics`` rows are hoisted to top level (last write wins,
-    matching render_results.py's reading of the same files)."""
+    ``extra_metrics`` rows are hoisted to top level (last write wins)."""
     rows: Dict[str, dict] = {}
     with open(path) as f:
         for line in f:

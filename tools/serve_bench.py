@@ -13,8 +13,7 @@ Five modes (`--scheduler`):
          admission with preemption);
   ab     BOTH, over the same request spec AND a prefix-heavy workload
          (shared system prompt, Zipf-distributed suffixes), with a
-         token-identity cross-check on every completed request — the
-         comparison artifact the evidence daemon queues as `serve_v2`.
+         token-identity cross-check on every completed request.
          Headline = v2 standard-workload tokens/s; `vs_baseline` = its
          gain over fifo at the SAME load and pool.
   spec   the ISSUE 18 speculative engine vs the v2 autoregressive
