@@ -1,0 +1,18 @@
+"""dispatch_writeback_ms.train — median host milliseconds one Executor.run
+call of the traced slice spends in `pdtpu.executor.writeback`: putting every
+written state array back into the scope (and writing the files of `save`
+ops).  From the program's own spans in the profiler trace
+(reduce/program_spans.py); None where it has none."""
+
+LAYER = "executors"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_span"
+MOVES = "train_samples_per_s"
+
+
+def read(run):
+    from harness import load_module
+
+    return load_module("reduce", "program_spans").child_ms(
+        run, "executor.writeback")

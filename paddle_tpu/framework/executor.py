@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -44,6 +45,22 @@ _MET_STEPS = _MET.counter("executor_steps_total",
 _MET_PROG_CACHE = _MET.counter(
     "executor_program_cache_total",
     "executable-cache lookups by Executor.run")
+_MET_COMPILE_S = _MET.counter(
+    "executor_compile_seconds_total",
+    "seconds JAX spent tracing, lowering and compiling (or fetching from "
+    "the persistent cache) inside Executor.run, by phase")
+_MET_JAX_COMPILES = _MET.counter(
+    "executor_jax_compiles_total",
+    "XLA compiles inside Executor.run; cached=1 came from the persistent "
+    "cache")
+
+# jax.monitoring's duration events of one compile -> the counter's phase
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_PERSISTENT_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 # ops the lowerer skips: pure-desc markers with no computation
 _NOOP_TYPES = ("feed", "fetch")
@@ -120,11 +137,65 @@ def _enable_compilation_cache():
     jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
 
 
+def _on_jax_compile_seconds(event, duration, **kw):
+    """jax.monitoring duration listener: what JAX compiles while this
+    thread is inside Executor._dispatch is the program's, and goes to the
+    counters and, where a sink records spans, onto the innermost open one
+    (`executor.execute`, as a rule); a compile outside a dispatch (a
+    reference, a test's own jit) is not.  JAX calls listeners only when
+    something compiles, so a steady step pays nothing.  `trace` counts a
+    nested jit's tracing twice, inside its caller's, as every sum of
+    these events does."""
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    cached = False
+    if phase == "backend":  # one per XLA compile, and its last event
+        cached, _compiling.hit = getattr(_compiling, "hit", False), False
+    if not getattr(_compiling, "dispatches", 0):
+        return
+    _MET_COMPILE_S.inc(duration, phase=phase)
+    if phase == "backend":
+        _MET_JAX_COMPILES.inc(cached="1" if cached else "0")
+    sp = _TRC.current()
+    if sp is None:
+        return
+    sp.note(compile_s=sp.args.get("compile_s", 0.0) + duration)
+    if phase == "backend":
+        sp.note(jax_compiles=sp.args.get("jax_compiles", 0) + 1)
+
+
+def _on_jax_event(event, **kw):
+    """jax.monitoring event listener: a persistent-cache hit precedes the
+    `backend` duration of the compile it served, on the same thread."""
+    if event == _PERSISTENT_CACHE_HIT:
+        _compiling.hit = True
+
+
+# per thread: `dispatches`, how many Executor._dispatch calls are open, and
+# `hit`, whether the compile in progress came from the persistent cache
+_compiling = threading.local()
+_listening = False
+
+
+def _listen_to_jax_compiles():
+    """Register the two callbacks with jax.monitoring, once a process."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax.monitoring as mon
+
+    mon.register_event_duration_secs_listener(_on_jax_compile_seconds)
+    mon.register_event_listener(_on_jax_event)
+
+
 class Executor:
     """fluid.Executor equivalent (python executor.py:70 / pybind.cc:424)."""
 
     def __init__(self, place: Optional[Place] = None):
         _enable_compilation_cache()
+        _listen_to_jax_compiles()
         self.place = place if place is not None else default_place()
         self._cache: Dict[tuple, _Compiled] = {}
         self._load_paths: Dict[tuple, tuple] = {}
@@ -314,61 +385,117 @@ class Executor:
             self._verify_program(program, block_id, sorted(feed),
                                  fetch_names)
 
-        block = program.blocks[block_id]
-        feed_vals = self._prepare_feeds(block, feed)
+        return self._dispatch(program, block_id, feed, fetch_names, scope,
+                              return_numpy, rng_step, 1, None, t_run0)
 
-        # autotune winner pickup (autotune/integration.py): a persisted
-        # `paddle tune` winner for this exact (program digest, feed
-        # signature, device, backend) re-applies its program-level
-        # decisions (attrs-only remat marks) BEFORE the cache key is
-        # computed, so the tuned executable is what gets cached.  One
-        # memoized lookup per program version; an empty store is a
-        # single scandir; PADDLE_TPU_AUTOTUNE=0 disables.
-        if block_id == 0:
-            from ..autotune.integration import maybe_apply_program_winner
+    def _dispatch(self, *args):
+        """_dispatch_under_spans, marked on the thread for the compile
+        listener: the counters need no span to know whose a compile is."""
+        _compiling.dispatches = getattr(_compiling, "dispatches", 0) + 1
+        try:
+            return self._dispatch_under_spans(*args)
+        finally:
+            _compiling.dispatches -= 1
 
-            maybe_apply_program_winner(program, feed_vals)
-
-        key = self._cache_key(program, block_id, feed_vals, fetch_names)
-        # the load-file signature lives beside the entry, not in the key: a
-        # rewritten load file must *replace* the stale executable, not leak
-        # an unbounded trail of dead cache entries
-        load_sig = self._load_file_sig(program)
-        entry = self._cache.get(key)
-        compiled_now = entry is None or entry[0] != load_sig
-        if compiled_now:
-            with _TRC.span("executor.compile", ops=len(block.ops)):
-                compiled = self._compile(program, block_id, feed_vals,
-                                         fetch_names)
-            self._cache[key] = (load_sig, compiled)
-        else:
-            compiled = entry[1]
-        _MET_PROG_CACHE.inc(result="miss" if compiled_now else "hit")
-
+    def _dispatch_under_spans(self, program, block_id, feed, fetch_names,
+                              scope, return_numpy, rng_step, k, fetch_every,
+                              t_run0):
+        """One dispatch of `k` steps under its spans, shared by run() (k=1)
+        and the fused path of _run_loop(): a root `executor.run` and under
+        it `executor.prepare` (feeds, autotune winner, cache key, load-file
+        signature, cache lookup; `executor.build` inside it when the key
+        is new), `executor.donate`, `executor.rng`, `executor.execute`
+        (the jitted call: JAX traces, lowers and compiles in its first
+        one), `executor.writeback` and, for `return_numpy`,
+        `executor.fetch`.  All carry `step`, the executor's counter at
+        the dispatch's first step."""
         import jax
 
-        # telemetry: the DONATION phase — pinning the donated (rw) and
-        # read-only state buffers into device memory before the step
-        with _TRC.span("executor.donate", feeds=len(feed)) as sp_don:
-            state_w, state_r = self._pin_state(compiled, scope, block)
-            sp_don.note(donated=len(state_w), reads=len(state_r))
+        step = self._step
+        block = program.blocks[block_id]
+        with _TRC.span("executor.run", step=step, k=k,
+                       program=program._cache_token) as sp_run:
+            with _TRC.span("executor.prepare", step=step):
+                feed_vals = self._prepare_feeds(block, feed, stacked=k > 1)
+                if k > 1:
+                    from . import step_loop
 
-        rng = jax.random.fold_in(
-            self._rng_key(program.random_seed),
-            self._step if rng_step is None else int(rng_step)
-        )
-        self._step += 1
+                    step_loop.check_stacked(feed_vals, k)
+                # autotune winner pickup (autotune/integration.py): a
+                # persisted `paddle tune` winner for this exact (program
+                # digest, feed signature, device, backend) re-applies its
+                # program-level decisions (attrs-only remat marks) BEFORE
+                # the cache key is computed, so the tuned executable is
+                # what gets cached.  One memoized lookup per program
+                # version; an empty store is a single scandir;
+                # PADDLE_TPU_AUTOTUNE=0 disables.
+                if block_id == 0:
+                    from ..autotune.integration import (
+                        maybe_apply_program_winner)
 
-        with _TRC.span("executor.execute", cache_hit=not compiled_now), \
-                self._device_scope():
-            fetches, new_state = compiled.fn(state_w, state_r, feed_vals,
-                                             rng)
-        with _TRC.span("executor.writeback", written=len(new_state)):
-            for n, v in new_state.items():
-                scope.set(n, v)
-            if compiled.save_specs:
-                import os
+                    maybe_apply_program_winner(program, feed_vals)
 
+                key = self._cache_key(program, block_id, feed_vals,
+                                      fetch_names)
+                if k > 1:
+                    key += ("loop", k, fetch_every)
+                # the load-file signature lives beside the entry, not in
+                # the key: a rewritten load file must *replace* the stale
+                # executable, not leak an unbounded trail of dead entries
+                load_sig = self._load_file_sig(program)
+                entry = self._cache.get(key)
+                compiled_now = entry is None or entry[0] != load_sig
+                if compiled_now:
+                    # the desc analysis and the jax.jit wrapper; nothing
+                    # compiles before the wrapper's first call
+                    with _TRC.span("executor.build", step=step,
+                                   ops=len(block.ops)):
+                        if k == 1:
+                            compiled = self._compile(
+                                program, block_id, feed_vals, fetch_names)
+                        else:
+                            compiled = self._compile_loop(
+                                program, block_id, feed_vals, fetch_names,
+                                k, fetch_every)
+                    self._cache[key] = (load_sig, compiled)
+                else:
+                    compiled = entry[1]
+                _MET_PROG_CACHE.inc(result="miss" if compiled_now else "hit")
+            sp_run.note(cache_hit=not compiled_now)
+
+            # the DONATION phase: pinning the donated (rw) and read-only
+            # state buffers into device memory before the step
+            with _TRC.span("executor.donate", step=step,
+                           feeds=len(feed)) as sp_don:
+                state_w, state_r = self._pin_state(compiled, scope, block)
+                sp_don.note(donated=len(state_w), reads=len(state_r))
+
+            with _TRC.span("executor.rng", step=step):
+                first = step if rng_step is None else int(rng_step)
+                key0 = self._rng_key(program.random_seed)
+                if k == 1:
+                    rng = (jax.random.fold_in(key0, first),)
+                else:
+                    # the loop folds (base key, step index) per step ON
+                    # DEVICE - bitwise the same stream as K sequential
+                    # host-side fold_ins
+                    rng = (key0, np.int32(first))
+            self._step += k
+
+            with _TRC.span("executor.execute", step=step,
+                           cache_hit=not compiled_now), \
+                    self._device_scope():
+                fetches, new_state = compiled.fn(state_w, state_r,
+                                                 feed_vals, *rng)
+            with _TRC.span("executor.writeback", step=step,
+                           written=len(new_state)):
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                # the donated buffers are dead and the scope has let go of
+                # them: dropping the last references here puts the cost of
+                # freeing a few hundred arrays inside the span, not after
+                # the root at the frame's exit
+                del state_w, state_r
                 for i, (path, overwrite) in enumerate(compiled.save_specs):
                     if os.path.exists(path) and not overwrite:
                         raise IOError(
@@ -382,22 +509,27 @@ class Executor:
                         np.save(f,
                                 np.asarray(fetches[f"{_SAVE_PREFIX}{i}"]),
                                 allow_pickle=False)
-        if self.check_nan_inf:
-            # FLAGS_check_nan_inf analog (reference executor.cc:26,120-128):
-            # scan fetches + updated state for non-finite values
-            for n, v in list(fetches.items()) + list(new_state.items()):
-                arr = np.asarray(v)
-                if np.issubdtype(arr.dtype, np.floating) and not np.all(
-                        np.isfinite(arr)):
-                    raise FloatingPointError(
-                        f"non-finite values in {n!r} after step {self._step}")
-        _MET_STEPS.inc()
-        # predicted-vs-measured: tracked programs record this step's wall
-        # time (observability/accounting.py; cheap no-op for the rest)
-        _acct.on_step(program, _monotime() - t_run0, compiled_now)
-        if return_numpy:
-            return [as_numpy(fetches[n]) for n in fetch_names]
-        return [fetches[n] for n in fetch_names]
+            if self.check_nan_inf:
+                # FLAGS_check_nan_inf analog (reference executor.cc:26,
+                # 120-128): scan fetches + updated state for non-finite
+                # values
+                for n, v in list(fetches.items()) + list(new_state.items()):
+                    arr = np.asarray(v)
+                    if np.issubdtype(arr.dtype, np.floating) and not np.all(
+                            np.isfinite(arr)):
+                        raise FloatingPointError(
+                            f"non-finite values in {n!r} after step "
+                            f"{self._step}")
+            _MET_STEPS.inc()
+            # predicted-vs-measured: tracked programs record this step's
+            # wall time (observability/accounting.py; cheap no-op for the
+            # rest)
+            _acct.on_step(program, _monotime() - t_run0, compiled_now)
+            if not return_numpy:
+                return [fetches[n] for n in fetch_names]
+            with _TRC.span("executor.fetch", step=step,
+                           fetches=len(fetch_names)):
+                return [as_numpy(fetches[n]) for n in fetch_names]
 
     # ------------------------------------------------------------------
     def _device_scope(self):
@@ -493,12 +625,11 @@ class Executor:
             safety = step_loop.safety_report(program, block_id)
             self._loop_safety[skey] = safety
 
-        block = program.blocks[block_id]
-        feed_vals = self._prepare_feeds(block, feed, stacked=True)
-        step_loop.check_stacked(feed_vals, k)
-
         if not safety["safe"]:
             step_loop.warn_unsafe(k, safety)
+            feed_vals = self._prepare_feeds(program.blocks[block_id], feed,
+                                            stacked=True)
+            step_loop.check_stacked(feed_vals, k)
             per_step = []
             for i, feeds_i in enumerate(step_loop.split_feeds(feed_vals, k)):
                 per_step.append(self.run(
@@ -517,57 +648,8 @@ class Executor:
             return [jnp.stack([outs[j] for outs in per_step])
                     for j in range(len(fetch_names))]
 
-        if block_id == 0:
-            from ..autotune.integration import maybe_apply_program_winner
-
-            maybe_apply_program_winner(program, feed_vals)
-
-        key = self._cache_key(program, block_id, feed_vals, fetch_names) \
-            + ("loop", k, fetch_every)
-        load_sig = self._load_file_sig(program)
-        entry = self._cache.get(key)
-        compiled_now = entry is None or entry[0] != load_sig
-        if compiled_now:
-            with _TRC.span("executor.compile", ops=len(block.ops),
-                           loop_k=k):
-                compiled = self._compile_loop(program, block_id, feed_vals,
-                                              fetch_names, k, fetch_every)
-            self._cache[key] = (load_sig, compiled)
-        else:
-            compiled = entry[1]
-        _MET_PROG_CACHE.inc(result="miss" if compiled_now else "hit")
-
-        import jax
-
-        with _TRC.span("executor.donate", feeds=len(feed)) as sp_don:
-            state_w, state_r = self._pin_state(compiled, scope, block)
-            sp_don.note(donated=len(state_w), reads=len(state_r))
-
-        # the loop folds (base key, step index) per step ON DEVICE —
-        # bitwise the same stream as K sequential host-side fold_ins
-        rng_base = self._rng_key(program.random_seed)
-        step0 = np.int32(self._step if rng_step is None else int(rng_step))
-        self._step += k
-
-        with _TRC.span("executor.execute", cache_hit=not compiled_now,
-                       loop_k=k), self._device_scope():
-            fetches, new_state = compiled.fn(
-                state_w, state_r, feed_vals, rng_base, step0)
-        with _TRC.span("executor.writeback", written=len(new_state)):
-            for n, v in new_state.items():
-                scope.set(n, v)
-        if self.check_nan_inf:
-            for n, v in list(fetches.items()) + list(new_state.items()):
-                arr = np.asarray(v)
-                if np.issubdtype(arr.dtype, np.floating) and not np.all(
-                        np.isfinite(arr)):
-                    raise FloatingPointError(
-                        f"non-finite values in {n!r} after step {self._step}")
-        _MET_STEPS.inc()
-        _acct.on_step(program, _monotime() - t_run0, compiled_now)
-        if return_numpy:
-            return [as_numpy(fetches[n]) for n in fetch_names]
-        return [fetches[n] for n in fetch_names]
+        return self._dispatch(program, block_id, feed, fetch_names, scope,
+                              return_numpy, rng_step, k, fetch_every, t_run0)
 
     # ------------------------------------------------------------------
     def _verify_program(self, program, block_id, feed_names, fetch_names):

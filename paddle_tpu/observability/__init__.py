@@ -47,7 +47,6 @@ from .metrics import (  # noqa: F401
     validate_snapshot,
 )
 from .tracing import (  # noqa: F401
-    NOOP_SPAN,
     TRACER,
     Tracer,
     chrome_envelope,
@@ -57,7 +56,8 @@ from .tracing import (  # noqa: F401
 
 
 def span(name: str, cat: str = "pdtpu", **args):
-    """Open a span on the global tracer (no-op singleton when off)."""
+    """Open a span on the global tracer (the shared no-op while neither
+    the ring nor a profiler session records)."""
     return TRACER.span(name, cat=cat, **args)
 
 

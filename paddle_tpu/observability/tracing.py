@@ -1,107 +1,133 @@
-"""Structured step tracing: nested spans in a bounded ring buffer,
-exportable as Chrome/Perfetto trace-event JSON (ISSUE 13).
+"""Structured step tracing: one span, two sinks (ISSUE 13, ISSUE 24).
 
 The executor, serving engine, and training service open spans around
-their phases (compile vs execute vs donation, admission vs prefill-chunk
-vs decode, lease/rollback events); a trace window is then ONE artifact a
-human opens in https://ui.perfetto.dev (or chrome://tracing) instead of
-a scatter of per-tool print statements.
+their phases (prepare vs execute vs donation, admission vs prefill-chunk
+vs decode, lease/rollback events).  Every span goes to two places:
 
-Cost model:
+  * **the profiler's trace, whenever a profiler session is active** -
+    each span is then also a
+    ``jax.profiler.TraceAnnotation("pdtpu." + name, id=, parent=, **args)``
+    (a C++ ``TraceMe``).  Inside ``jax.profiler.start_trace`` ..
+    ``stop_trace`` the program's spans land in the ``.xplane.pb`` on the
+    device events' clock with nothing to switch on (open it, or its
+    Perfetto export, and they sit over the device lines); outside a
+    session none is built.
+  * **the ring, when enabled** - one dict appended to a
+    ``deque(maxlen=capacity)``, exportable as Chrome/Perfetto
+    trace-event JSON: the operator's window (``paddle trace``, the
+    ``/trace`` endpoint) of a long-lived service, in bounded memory,
+    without a profiler.  Off by default (``PADDLE_TPU_TRACE=1`` or
+    ``enable()``).
 
-  * **disabled (default)** — ``span()`` returns a module-level no-op
-    singleton: no allocation, no clock read, one attribute check.  The
-    hot serving/executor paths stay instrumented at all times because
-    the instrumentation is free until someone turns it on;
-  * **enabled** — one clock read per span edge plus one dict append into
-    a ``deque(maxlen=capacity)`` ring: a long-lived service traces
-    forever in bounded memory, keeping the most recent window.
+With the ring off and no session, nothing would read a span, and
+``Tracer.span`` hands out one shared stateless object: the cost of the
+instrumentation that stays in the hot serving/executor paths at all
+times is that of asking ``TraceAnnotation.is_enabled()``, the flag a
+``TraceMe`` itself checks (PERF.md, PR 24, has the numbers).
 
-Nesting is tracked per thread (a stack of open spans) so exported
-events carry a ``depth`` arg and parent names, and Chrome's flame view
-reconstructs the hierarchy from ts/dur containment per tid.
-
-Stdlib-only and free of package-relative imports (file-loadable by
-tools that must not import the framework).
+A span that a sink records has an ``id`` (process-wide, from 1) and its
+``parent``'s id (0 for a root): the innermost recorded span open on the
+same thread when it was entered.  Spans of one unit of work share the
+identifier their call site passes (``step`` for ``executor.*``, ``rid``
+for a request).
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import threading
 import time
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 _clock = time.perf_counter
+_ids = itertools.count(1)  # next() on a count is atomic under the GIL
+# whether a profiler session is recording: what a TraceMe asks itself
+# before it records, asked here before one is built
+_session_active = _Annotation.is_enabled
+ANNOTATION_PREFIX = "pdtpu."
 
 
 class _NoopSpan:
-    """The disabled-path span: a shared, stateless context manager."""
+    """What `Tracer.span` returns while no sink records: stateless, so
+    one instance serves every call site and thread."""
 
     __slots__ = ()
+
+    def note(self, **kw):
+        return self
 
     def __enter__(self):
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, exc_type, exc, tb):
         return False
 
-    def note(self, **kw):  # post-hoc args are dropped when disabled
-        return self
 
-
-NOOP_SPAN = _NoopSpan()
+_NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_tid",
-                 "_depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "id", "parent", "_up",
+                 "_ann", "_noted", "_t0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = self._noted = None
 
     def note(self, **kw):
-        """Attach args discovered after entry (e.g. admitted count)."""
-        if self.args:
-            self.args.update(kw)
-        else:
-            self.args = kw
+        """Attach args discovered after entry (e.g. admitted count).  The
+        annotation gets them once, when the span closes, so a key noted
+        twice holds its last value in both sinks."""
+        self.args.update(kw)
+        if self._ann is not None:
+            if self._noted is None:
+                self._noted = kw
+            else:
+                self._noted.update(kw)
         return self
 
     def __enter__(self):
         tr = self._tracer
-        stack = tr._stack()
-        self._depth = len(stack)
-        stack.append(self.name)
-        self._tid = threading.get_ident()
-        self._t0 = _clock()
+        local = tr._local
+        up = self._up = getattr(local, "open", None)
+        local.open = self
+        self.id = next(_ids)
+        self.parent = up.id if up is not None else 0
+        if _session_active():
+            self._ann = _Annotation(ANNOTATION_PREFIX + self.name,
+                                    id=self.id, parent=self.parent,
+                                    **self.args)
+            self._ann.__enter__()
+        self._t0 = _clock() if tr.enabled else None
         return self
 
-    def __exit__(self, exc_type, *exc):
-        t1 = _clock()
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            if self._noted is not None:
+                self._ann.set_metadata(**self._noted)
+            self._ann.__exit__(exc_type, exc, tb)
         tr = self._tracer
-        stack = tr._stack()
-        if stack and stack[-1] == self.name:
-            stack.pop()
-        args = self.args or {}
-        if self._depth:
-            args = dict(args)
-            args["depth"] = self._depth
-        if exc_type is not None:
-            args = dict(args)
-            args["error"] = exc_type.__name__
-        tr._record({
-            "name": self.name, "cat": self.cat, "ph": "X",
-            "ts": round((self._t0 - tr._epoch) * 1e6, 3),
-            "dur": round((t1 - self._t0) * 1e6, 3),
-            "pid": tr._pid, "tid": self._tid,
-            **({"args": args} if args else {}),
-        })
+        tr._local.open = self._up
+        if self._t0 is not None:
+            t1 = _clock()
+            args = dict(self.args, id=self.id, parent=self.parent)
+            if exc_type is not None:
+                args["error"] = exc_type.__name__
+            tr._record({
+                "name": self.name, "cat": self.cat, "ph": "X",
+                "ts": round((self._t0 - tr._epoch) * 1e6, 3),
+                "dur": round((t1 - self._t0) * 1e6, 3),
+                "pid": tr._pid, "tid": threading.get_ident(),
+                "args": args,
+            })
         return False
 
 
@@ -109,15 +135,9 @@ class Tracer:
     """Bounded-ring span recorder with Chrome trace-event export."""
 
     def __init__(self, enabled: Optional[bool] = None,
-                 capacity: Optional[int] = None):
+                 capacity: int = 65536):
         if enabled is None:
             enabled = os.environ.get("PADDLE_TPU_TRACE", "0") == "1"
-        if capacity is None:
-            try:
-                capacity = int(os.environ.get(
-                    "PADDLE_TPU_TRACE_CAPACITY", "65536"))
-            except ValueError:
-                capacity = 65536
         self.enabled = bool(enabled)
         self.capacity = max(1, int(capacity))
         self._ring = collections.deque(maxlen=self.capacity)
@@ -128,11 +148,17 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, cat: str = "pdtpu", **args):
-        """Open a span context.  Disabled -> the shared no-op singleton
-        (zero allocation: the identity is asserted in tests)."""
-        if not self.enabled:
-            return NOOP_SPAN
-        return _Span(self, name, cat, args or None)
+        """A span context: a TraceAnnotation while a profiler session is
+        active, a ring event while the tracer is enabled; while neither
+        is, the shared no-op."""
+        if not self.enabled and not _session_active():
+            return _NOOP
+        return _Span(self, name, cat, args)
+
+    def current(self) -> Optional[_Span]:
+        """The innermost recorded span open on the calling thread, or
+        None."""
+        return getattr(self._local, "open", None)
 
     def instant(self, name: str, cat: str = "pdtpu", **args):
         """A point event (lease grant, rollback, fault injection...)."""
@@ -144,12 +170,6 @@ class Tracer:
             "pid": self._pid, "tid": threading.get_ident(),
             **({"args": args} if args else {}),
         })
-
-    def _stack(self) -> list:
-        s = getattr(self._local, "stack", None)
-        if s is None:
-            s = self._local.stack = []
-        return s
 
     def _record(self, ev: dict):
         with self._lock:

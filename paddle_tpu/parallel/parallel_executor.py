@@ -31,6 +31,7 @@ import numpy as np
 from ..framework.core import np_dtype
 from ..framework.executor import Executor
 from ..framework.scope import global_scope
+from ..observability.tracing import TRACER as _TRC
 from ..ops.registry import EmitContext
 from . import mesh as mesh_lib
 from .mesh import make_mesh
@@ -179,13 +180,16 @@ class ParallelExecutor(Executor):
         program = program if program is not None else default_main_program()
         scope = scope if scope is not None else global_scope()
         block = program.blocks[block_id]
-        # pre-shard all scope state the block touches
-        names = set()
-        for op in block.ops:
-            names.update(op.input_names())
-            names.update(op.output_names())
-        self._distribute_state(
-            program, scope, [n for n in names if scope.has(n)])
+        # pre-shard all scope state the block touches: every call walks
+        # the block's ops, before (and so outside) Executor.run's spans
+        with _TRC.span("executor.distribute", step=self._step,
+                       ops=len(block.ops)):
+            names = set()
+            for op in block.ops:
+                names.update(op.input_names())
+                names.update(op.output_names())
+            self._distribute_state(
+                program, scope, [n for n in names if scope.has(n)])
         return super().run(program, feed, fetch_list, scope, return_numpy,
                            block_id, verify=verify, rng_step=rng_step,
                            steps_per_dispatch=steps_per_dispatch,

@@ -136,14 +136,27 @@ def test_artifact_metric_namespace_rules():
 # tracing
 
 
-def test_disabled_span_is_the_shared_noop_singleton():
+def test_disabled_span_is_one_shared_noop_and_an_enabled_one_links():
+    """Ring off and no profiler session: nothing would read a span, so
+    every call gets the same stateless object and the thread's chain of
+    open spans stays empty.  With the ring on, spans link: each has its
+    id and its parent's, and `current()` finds the innermost."""
     t = trc.Tracer(enabled=False)
-    s1 = t.span("a")
-    s2 = t.span("b", k=1)
-    # zero-allocation fast path: the SAME stateless object every time
-    assert s1 is s2 is trc.NOOP_SPAN
-    with s1:
-        pass
+    s1 = t.span("a", step=4)
+    assert s1 is t.span("b", k=1)
+    with s1 as inside:
+        assert inside.note(seen=2) is inside and t.current() is None
+    t.enable()
+    with t.span("a", step=4) as s1:
+        assert t.current() is s1 and s1._ann is None
+        with t.span("b", k=1) as s2:
+            assert s2.parent == s1.id > 0 and s1.parent == 0
+            assert t.current() is s2
+            assert s2.note(seen=2).args == {"k": 1, "seen": 2}
+        assert t.current() is s1
+    assert t.current() is None and len(t.events()) == 2
+    t.disable()
+    t.reset()
     t.instant("x")
     assert t.events() == []
 
@@ -158,15 +171,22 @@ def test_ring_buffer_bound_keeps_newest():
     assert evs[0]["name"] == "s12" and evs[-1]["name"] == "s19"
 
 
-def test_span_nesting_depth_and_containment():
+def test_span_nesting_id_parent_and_containment():
     t = trc.Tracer(enabled=True)
-    with t.span("outer"):
-        with t.span("inner", detail=1):
+    with t.span("outer", step=7):
+        with t.span("inner", detail=1, step=7):
             pass
-    inner, outer = t.events()  # completion order: inner first
+        with t.span("second", step=7):
+            pass
+    inner, second, outer = t.events()  # completion order: children first
     assert inner["name"] == "inner" and outer["name"] == "outer"
-    assert inner["args"]["depth"] == 1
-    assert "depth" not in outer.get("args", {})
+    ids = [e["args"]["id"] for e in (outer, inner, second)]
+    assert len(set(ids)) == 3 and min(ids) > 0
+    assert outer["args"]["parent"] == 0
+    assert inner["args"]["parent"] == second["args"]["parent"] == ids[0]
+    # the unit of work's identifier is what the call sites pass
+    assert {e["args"]["step"] for e in (outer, inner, second)} == {7}
+    assert inner["args"]["detail"] == 1 and "depth" not in inner["args"]
     # child interval inside the parent interval, same thread track
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
@@ -210,10 +230,10 @@ def test_span_error_annotation_and_stack_hygiene():
             raise RuntimeError("x")
     (ev,) = t.events()
     assert ev["args"]["error"] == "RuntimeError"
-    # the per-thread stack unwound: a following span is depth 0
+    # the thread's chain of open spans unwound: a following span is a root
     with t.span("after"):
         pass
-    assert "depth" not in t.events()[-1].get("args", {})
+    assert t.events()[-1]["args"]["parent"] == 0 and t.current() is None
 
 
 # ---------------------------------------------------------------------------
@@ -242,12 +262,14 @@ def test_executor_phase_spans_and_step_counters():
     assert obs.REGISTRY.counter("executor_steps_total").value() \
         == before + 2
     names = [e["name"] for e in obs.TRACER.events()]
-    for want in ("executor.compile", "executor.donate",
-                 "executor.execute", "executor.writeback"):
+    for want in ("executor.run", "executor.prepare", "executor.build",
+                 "executor.donate", "executor.rng", "executor.execute",
+                 "executor.writeback", "executor.fetch"):
         assert want in names, (want, names)
-    # second run hits the executable cache: exactly one compile span
+    # second run hits the executable cache: exactly one build span
     # for the train program (+1 for startup)
-    assert names.count("executor.compile") == 2
+    assert names.count("executor.build") == 2
+    assert names.count("executor.run") == 3
     hits = obs.REGISTRY.counter("executor_program_cache_total")
     assert hits.value(result="hit") >= 1.0
 

@@ -130,7 +130,7 @@ def main(argv=None) -> int:
         assert not problems, f"telemetry artifact schema: {problems}"
         assert not obs.validate_chrome_trace(trace_obj)
         names = {e["name"] for e in events}
-        for want in ("executor.compile", "executor.execute",
+        for want in ("executor.build", "executor.execute",
                      "executor.donate", "executor.writeback",
                      "predvmeas.step"):
             assert want in names, f"missing span {want}: {sorted(names)}"
