@@ -157,7 +157,17 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                 if ctx.is_test:
                     out = fa.flash_attention(q, k, v, causal=causal)
                 else:
-                    out = fa.make_flash_train(causal=causal)(q, k, v)
+                    # the forward kernel once a layer: the forward op
+                    # keeps (out, lse), and its grad op's re-emission
+                    # differentiates through them with no second launch
+                    train = fa.make_flash_train(causal=causal)
+                    kept = ctx.kept_for_grad()
+                    if kept is not None:
+                        out = train.from_saved(q, k, v, *kept)
+                    else:
+                        out, lse = train.with_lse(q, k, v)
+                        ctx.keep_for_grad(attrs, [out], (out, lse))
+                    ctx.kernel_forward(reused=kept is not None)
         if out is None:
             out = ra.attention(q, k, v, causal=causal)
     return {"Out": [out]}

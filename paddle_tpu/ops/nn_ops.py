@@ -283,6 +283,7 @@ def bn_act_conv1x1(ctx, ins, attrs):
         args = (x2, scale.astype(jnp.float32), bias.astype(jnp.float32),
                 mean.astype(jnp.float32), var.astype(jnp.float32), w2)
         out2 = f(*args, r2) if r2 is not None else f(*args)
+        ctx.kernel_forward(reused=False)
     if out2 is None:
         sdt = jnp.float64 if x2.dtype == jnp.float64 else jnp.float32
         out2 = bmm.bn_matmul_reference(
@@ -332,6 +333,7 @@ def bn_act_conv3x3(ctx, ins, attrs):
                 mean.astype(jnp.float32), var.astype(jnp.float32),
                 bcv._w_hwio(w))
         out = f(*args, res) if res is not None else f(*args)
+        ctx.kernel_forward(reused=False)
     else:
         # the reference derives its stats dtype from x and casts params
         out = bcv.bn_conv3x3_reference(x, scale, bias, mean, var, w,

@@ -442,7 +442,16 @@ def make_flash_train(causal: bool = False, scale=None, interpret=False,
     jax.vjp like the recurrence kernels).  Memoized per
     (causal, scale, interpret, blocks): emitters call this on every trace,
     and a fresh wrapper each time would defeat jit's function-identity
-    caching (ADVICE r2)."""
+    caching (ADVICE r2).
+
+    The returned function carries the pair a forward op and its grad op
+    split between them, so the forward kernel runs once a layer and not
+    again when generic_grad re-emits the op under jax.vjp (two Mosaic
+    calls are not merged by XLA's CSE the way a re-emitted HLO forward
+    is): `.with_lse(q, k, v) -> (out, lse)` is the same forward handing
+    out its logsumexp, and `.from_saved(q, k, v, out, lse) -> out`
+    launches nothing forward and differentiates as the flash backward on
+    the saved pair.  scaled_dot_product_attention uses both."""
     key = (causal, scale, interpret, block_q, block_k)
     cached = _TRAIN_CACHE.get(key)
     if cached is not None:
@@ -466,5 +475,33 @@ def make_flash_train(causal: bool = False, scale=None, interpret=False,
         return flash_attention_bwd(q, k, v, out, lse, do, **kw)
 
     attn.defvjp(fwd, bwd)
+
+    @jax.custom_vjp
+    def with_lse(q, k, v):
+        return flash_attention_fwd(q, k, v, **kw)
+
+    def with_lse_fwd(q, k, v):
+        out, lse = flash_attention_fwd(q, k, v, **kw)
+        return (out, lse), (q, k, v, out, lse)
+
+    def with_lse_bwd(res, cts):
+        # lse leaves as a residual for `from_saved`, never as a value the
+        # loss depends on: its cotangent is dropped
+        return bwd(res, cts[0])
+
+    with_lse.defvjp(with_lse_fwd, with_lse_bwd)
+
+    @jax.custom_vjp
+    def from_saved(q, k, v, out, lse):
+        return out
+
+    def from_saved_fwd(q, k, v, out, lse):
+        return out, (q, k, v, out, lse)
+
+    def from_saved_bwd(res, do):
+        return bwd(res, do) + (None, None)
+
+    from_saved.defvjp(from_saved_fwd, from_saved_bwd)
+    attn.with_lse, attn.from_saved = with_lse, from_saved
     _TRAIN_CACHE[key] = attn
     return attn

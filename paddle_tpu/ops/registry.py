@@ -19,7 +19,10 @@ under ``jax.vjp`` and applies the output cotangents.  The recomputed forward
 subgraph is CSE'd/fused by XLA (or acts as rematerialization, which is usually a
 win on TPU where HBM bandwidth, not FLOPs, is the bottleneck).  Ops that want a
 cheaper analytic backward (using their saved outputs) register a custom grad
-emitter; stateful/optimizer ops register ``grad=None``.
+emitter; stateful/optimizer ops register ``grad=None``.  A Pallas kernel is the
+exception to "CSE'd": two Mosaic calls are never merged, so an emitter that runs
+one keeps what its backward needs on the EmitContext (``keep_for_grad``) and the
+re-trace differentiates through that instead of launching the forward again.
 """
 
 from __future__ import annotations
@@ -28,6 +31,8 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+
+from ..observability.metrics import REGISTRY as _MET
 
 
 @dataclass
@@ -181,6 +186,11 @@ class EmitContext:
         # >0 while lowering a control-flow sub-block (while/cond body): ops
         # whose values must escape to the host (save) cannot live there
         self.sub_depth = 0
+        # __uid__ -> (the forward op's outputs, what its emitter kept for
+        # its grad op): see keep_for_grad
+        self._kept = {}
+        # the _Replay of the forward op generic_grad is re-emitting now
+        self._replay = None
 
     def rng(self, attrs) -> "object":
         """Deterministic per-op PRNG key: base key folded with the op's uid.
@@ -191,6 +201,32 @@ class EmitContext:
 
         uid = int(attrs.get("__uid__", 0))
         return jax.random.fold_in(self.key, uid)
+
+    def keep_for_grad(self, attrs, outs, saved):
+        """A forward emitter that ran an opaque kernel (a Pallas custom
+        call) keeps what its backward needs, for generic_grad to hand back
+        when it re-emits the op under jax.vjp in this trace.  XLA's CSE
+        merges a re-emitted forward made of plain HLO with the first; it
+        does not merge two Mosaic calls, so without this the kernel's
+        forward runs twice.  `outs` are the op's outputs as it returns
+        them: the grad op must receive those very values for `saved` to
+        be its.  Ignored inside a re-emission."""
+        if self._replay is None:
+            self._kept[int(attrs.get("__uid__", 0))] = (tuple(outs), saved)
+
+    def kept_for_grad(self):
+        """Inside generic_grad's re-emission: what this op's forward
+        emission kept in this trace, else None (no grad op around the
+        call, another trace, a `__remat__` grad op, or nothing kept)."""
+        return self._replay.saved if self._replay is not None else None
+
+    def kernel_forward(self, reused: bool):
+        """An emitter that took a Pallas custom_vjp path says whether its
+        grad op's re-emission `reused` kept results or launches the
+        kernel's forward again; generic_grad counts it
+        (executor_grad_kernel_forward_total).  Nothing outside one."""
+        if self._replay is not None:
+            self._replay.kernel_forward_reused = bool(reused)
 
     def target_platform(self) -> str:
         """Platform ('tpu'/'cpu'/...) of the device(s) this trace will run
@@ -205,10 +241,28 @@ class EmitContext:
         return jax.default_backend()
 
 
+class _Replay:
+    """generic_grad's word to the forward emitter it re-emits (`saved`:
+    what that op's forward emission kept, or None), and the emitter's word
+    back (`kernel_forward_reused`: None unless it took a kernel path)."""
+
+    __slots__ = ("saved", "kernel_forward_reused")
+
+    def __init__(self, saved):
+        self.saved = saved
+        self.kernel_forward_reused = None
+
+
 # ---------------------------------------------------------------------------
 # Generic grad: maker + emitter
 
 GRAD_SUFFIX = "@GRAD"
+
+_MET_GRAD_KERNEL_FORWARD = _MET.counter(
+    "executor_grad_kernel_forward_total",
+    "grad ops traced whose forward emitter took a Pallas custom_vjp path; "
+    "reused=1 used the forward op's kept results, reused=0 emitted the "
+    "kernel's forward a second time")
 
 
 def default_grad_maker(op, requires_grad):
@@ -299,12 +353,30 @@ def _generic_grad_emit(ctx, ins, attrs):
                 flat.append(o)
         return flat
 
+    # what the forward op's emitter kept for this grad op (keep_for_grad),
+    # if the outputs it returned then are the very values this op receives
+    kept = ctx._kept.pop(int(attrs.get("__uid__", 0)), None)
+    fwd_outs = [o for s in out_slots for o in ins.get(s, [])]
+    saved = None
+    if kept is not None and len(kept[0]) == len(fwd_outs) and all(
+            a is b for a, b in zip(kept[0], fwd_outs)):
+        saved = kept[1]
+
     if attrs.get("__remat__"):
         # memory_optimize: force recompute-in-backward instead of XLA CSE
         # sharing activations with the forward pass (trades FLOPs for HBM)
         fwd_fn = jax.checkpoint(fwd_fn)
+        saved = None
 
-    primal_outs, vjp_fn = jax.vjp(fwd_fn, diff_vals)
+    replay = _Replay(saved)
+    outer, ctx._replay = ctx._replay, replay
+    try:
+        primal_outs, vjp_fn = jax.vjp(fwd_fn, diff_vals)
+    finally:
+        ctx._replay = outer
+    if replay.kernel_forward_reused is not None:
+        _MET_GRAD_KERNEL_FORWARD.inc(
+            op=fwd_type, reused=str(int(replay.kernel_forward_reused)))
 
     # Cotangents: grad inputs `<slot>@GRAD`; missing / non-diff outputs → zeros.
     cts = []

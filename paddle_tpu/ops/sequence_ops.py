@@ -277,6 +277,7 @@ def lstm(ctx, ins, attrs):
                 hs, cs, _, _ = plstm.lstm_forward(xk, h0, c0, w, lengths)
             else:
                 hs, cs = plstm.make_lstm_train()(xk, h0, c0, w, lengths)
+                ctx.kernel_forward(reused=False)
             if rev:
                 # scan convention: reversed pads carry the initial state
                 hs = _rev(hs, lengths, pad_fill=h0)
@@ -356,6 +357,7 @@ def gru(ctx, ins, attrs):
                 hs, _ = pgru.gru_forward(xk, h0, w, lengths)
             else:
                 hs = pgru.make_gru_train()(xk, h0, w, lengths)
+                ctx.kernel_forward(reused=False)
             if rev:
                 hs = _rev(hs, lengths, pad_fill=h0)
             return {"Hidden": [hs]}

@@ -1,0 +1,257 @@
+"""The flash forward runs once a layer: the forward op keeps (out, lse) on
+the EmitContext and its generic_grad differentiates through them instead
+of launching `flash_fwd` a second time (ops/registry.py keep_for_grad,
+ops/pallas_kernels/flash_attention.py make_flash_train).
+
+On the CPU the Pallas path is reached as tests/test_pallas_kernels.py does
+it: the emit context claims a TPU target and the kernels run in interpret
+mode.  The AOT test compiles the real kernels for a described v5e."""
+
+import os
+import re
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import observability as obs
+from paddle_tpu.ops import registry as reg
+from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+COUNTER = "executor_grad_kernel_forward_total"
+SDPA = "scaled_dot_product_attention"
+T, DIM, HEADS = 128, 32, 2
+
+
+def _counter() -> dict:
+    """{(op, reused): count} of the counter's series."""
+    fam = obs.REGISTRY.snapshot()["families"].get(COUNTER)
+    return {(s["labels"]["op"], s["labels"]["reused"]): s["value"]
+            for s in (fam["series"] if fam else [])}
+
+
+@pytest.fixture
+def pallas_on_cpu(monkeypatch):
+    """Every trace claims a TPU target and the flash kernels interpret;
+    returns the list of forward-kernel launches traced."""
+    launches = []
+    real_train, real_fwd = fa.make_flash_train, fa.flash_attention_fwd
+
+    def spy_fwd(q, k, v, **kw):
+        launches.append(q.shape)
+        return real_fwd(q, k, v, **kw)
+
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(fa, "flash_attention_fwd", spy_fwd)
+    monkeypatch.setattr(
+        fa, "make_flash_train",
+        lambda causal=False, scale=None, interpret=False:
+        real_train(causal=causal, interpret=True, block_q=64, block_k=64))
+    # the memo holds closures over the real forward: start from none, and
+    # leave none behind that holds the spy
+    monkeypatch.setattr(fa, "_TRAIN_CACHE", {})
+    return launches
+
+
+def _attention_block(remat=False):
+    """x -> multi-head attention -> mean -> SGD; the loss, the parameters'
+    gradient names and a feed."""
+    fluid.reset()
+    x = fluid.layers.data("x", shape=[T, DIM], dtype="float32")
+    y = fluid.layers.multi_head_attention(x, x, x, HEADS, causal=True)
+    loss = fluid.layers.mean(y * y)
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    main = fluid.default_main_program()
+    if remat:
+        for op in main.global_block().ops:
+            if (op.type == "generic_grad"
+                    and op.attrs["__fwd_type__"] == SDPA):
+                op.attrs["__remat__"] = True
+    grads = [p.name + "@GRAD" for p in main.global_block().all_parameters()]
+    feed = {"x": np.random.RandomState(7).randn(2, T, DIM)
+            .astype(np.float32)}
+    return loss, grads, feed
+
+
+def _step(remat=False):
+    loss, grads, feed = _attention_block(remat)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    got = exe.run(feed=feed, fetch_list=[loss] + grads)
+    assert len(grads) == 4  # Wq, Wk, Wv, Wo
+    return [np.asarray(g) for g in got]
+
+
+def test_saved_pair_gives_the_fallbacks_bits(pallas_on_cpu, monkeypatch):
+    """Loss and every parameter gradient are equal to the last bit with
+    the kept pair and without it, and each path is the one it claims."""
+    with_pair = _step()
+    assert len(pallas_on_cpu) == 1, pallas_on_cpu
+    assert _counter() == {(SDPA, "1"): 1.0}
+
+    del pallas_on_cpu[:]
+    # the table emptied before the grad op: nothing is ever kept
+    monkeypatch.setattr(reg.EmitContext, "keep_for_grad",
+                        lambda self, attrs, outs, saved: None)
+    fallback = _step()
+    assert len(pallas_on_cpu) == 2, pallas_on_cpu
+    assert _counter() == {(SDPA, "0"): 1.0}
+
+    assert np.isfinite(with_pair[0]) and np.abs(with_pair[1]).max() > 0
+    for a, b in zip(with_pair, fallback):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+def test_pair_of_another_value_is_not_used(pallas_on_cpu, monkeypatch):
+    """The kept pair is the grad op's only when the forward output the
+    grad op receives IS the one kept with it."""
+    real = reg.EmitContext.keep_for_grad
+    monkeypatch.setattr(
+        reg.EmitContext, "keep_for_grad",
+        lambda self, attrs, outs, saved:
+        real(self, attrs, [o + 0 for o in outs], saved))
+    _step()
+    assert len(pallas_on_cpu) == 2
+    assert _counter() == {(SDPA, "0"): 1.0}
+
+
+def test_remat_grad_op_still_recomputes(pallas_on_cpu):
+    """`__remat__` asks for the forward again in the backward: the kept
+    pair is left alone, and the gradients are the same numbers."""
+    plain = _step()
+    del pallas_on_cpu[:]
+    remat = _step(remat=True)
+    # jax.checkpoint traces the custom_vjp's primal as well as its rule
+    assert len(pallas_on_cpu) >= 2, pallas_on_cpu
+    assert _counter() == {(SDPA, "0"): 1.0}
+    for a, b in zip(plain, remat):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+def test_attribution_oracle_walks_the_pallas_path(pallas_on_cpu):
+    """The eager op-by-op walk shares one context a walk, so its grad op
+    finds the pair too; either way it runs and covers the block."""
+    from paddle_tpu.observability import attribution as attr
+
+    _loss, _grads, feed = _attention_block()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    table = attr.attribute_cpu(fluid.default_main_program(), feed,
+                               batch_size=2, repeats=1)
+    assert table["n_ops"] > 0 and table["coverage"] > 0.5
+    assert SDPA in table["by_type"] and "generic_grad" in table["by_type"]
+    assert len(pallas_on_cpu) == 1
+    assert _counter() == {(SDPA, "1"): 1.0}
+
+
+def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):
+    """A caller that differentiates the forward emission itself (the
+    program pipeline's jax.grad over a stage) meets a custom_vjp, as
+    before, and the flash backward's numbers."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.parallel.ring_attention import attention
+
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray((rng.randn(1, 2, T, 16) * 0.3)
+                           .astype(np.float32)) for _ in range(3))
+
+    def through_op(q, k, v):
+        ctx = reg.EmitContext(jax.random.PRNGKey(0), is_test=False)
+        out = attention_ops.scaled_dot_product_attention(
+            ctx, {"Q": [q], "K": [k], "V": [v]},
+            {"causal": True, "__uid__": 5})["Out"][0]
+        return (out * out).sum()
+
+    def dense(q, k, v):
+        out = attention(q, k, v, causal=True)
+        return (out * out).sum()
+
+    got = jax.grad(through_op, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+    assert _counter() == {}  # no grad op, nothing to count
+
+
+# ---------------------------------------------------------------------------
+# AOT: the real kernels, compiled for a described v5e
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def _compiled_step_text(loss, device, batch, seq_len) -> str:
+    """The optimized HLO of the executor's step for `device`, from shapes
+    alone (as tests/benchmarks/test_benchmark.py `_aot` compiles it)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.framework.core import np_dtype
+
+    class DescribedPlace(fluid.CPUPlace):
+        def jax_device(self):
+            return device
+
+    main = fluid.default_main_program()
+    block = main.blocks[0]
+    exe = fluid.Executor(DescribedPlace())
+    one = SingleDeviceSharding(device)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            tuple(shape), jax.dtypes.canonicalize_dtype(dtype), sharding=one)
+
+    def of_var(n):
+        v = block._find_var_recursive(n)
+        return sds(v.shape, np_dtype(v.dtype))
+
+    toks = np.zeros((batch, seq_len, 1), np.int64)
+    # the chip runs with x64 off: compile what the chip compiles
+    with jax.enable_x64(False):
+        feed_vals = exe._prepare_feeds(block, {"tokens": toks,
+                                               "targets": toks})
+        compiled = exe._compile(main, 0, feed_vals, [loss.name])
+        return compiled.fn.lower(
+            {n: of_var(n) for n in compiled.rw_state},
+            {n: of_var(n) for n in compiled.external_reads},
+            {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
+            sds((2,), np.uint32)).compile().as_text()
+
+
+def test_aot_one_forward_kernel_a_layer(v5e):
+    """A 2-layer LM at T 1024 and head size 64: the compiled step holds one
+    forward, one dq and one dkv Mosaic call a layer (it held two forwards),
+    and every grad op reused its forward op's pair."""
+    from paddle_tpu.models.transformer import build_lm_train_program
+
+    layers = 2
+    loss = build_lm_train_program(1024, vocab_size=512, dim=128,
+                                  n_layers=layers, n_heads=2)
+    text = _compiled_step_text(loss, v5e, batch=2, seq_len=1024)
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    kinds = {}
+    for name in calls:
+        for kernel in ("flash_bwd_dq", "flash_bwd_dkv", "flash_fwd"):
+            if kernel in name:
+                kinds[kernel] = kinds.get(kernel, 0) + 1
+                break
+        else:
+            kinds[name] = 1
+    assert kinds == {"flash_fwd": layers, "flash_bwd_dq": layers,
+                     "flash_bwd_dkv": layers}, calls
+    assert _counter() == {(SDPA, "1"): float(layers)}
