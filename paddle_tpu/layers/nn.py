@@ -286,18 +286,44 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out)
 
 
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """RMSNorm (ops/llm_ops.py): input over sqrt(mean of its squares over
+    the axes from `begin_norm_axis` + epsilon), times a learned gain that
+    starts at one.  No mean subtracted, no bias."""
+    helper = LayerHelper("rms_norm", name=name)
+    gain = helper.create_parameter(
+        attr=param_attr if isinstance(param_attr, dict) else {},
+        shape=[_shape_prod(input.shape[begin_norm_axis:])],
+        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(
+        "rms_norm", inputs={"X": [input.name], "Scale": [gain.name]},
+        outputs={"Y": [out.name]},
+        attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
+    return out
+
+
 # --- losses / metrics -------------------------------------------------------
 
 
 def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          param_attr=None, name=None, sp_mode="ring",
-                         sp_schedule="plain"):
+                         sp_schedule="plain", qk_norm_epsilon=None,
+                         rope_theta=None, out_param_attr=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
     scaled_dot_product_attention — sequence-parallel when the executor's
     mesh has an 'sp' axis, as ring attention (sp_mode='ring') or Ulysses
-    all-to-all head re-sharding (sp_mode='alltoall')."""
+    all-to-all head re-sharding (sp_mode='alltoall').
+
+    `qk_norm_epsilon` puts an RMSNorm with that epsilon on the whole Q and
+    the whole K projection, before the split into heads (OLMoE's QK-norm);
+    `rope_theta` rotates Q and K per head by their position (`rope` op,
+    rotate-half form) instead of relying on positions added to the
+    input.  `param_attr` is the Q, K and V projections', `out_param_attr`
+    the output projection's."""
     helper = LayerHelper("multi_head_attention", name=name)
     if sp_mode not in ("ring", "alltoall"):
         raise ValueError(f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
@@ -313,6 +339,9 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
            bias_attr=False)
     v = fc(values, D, num_flatten_dims=2, param_attr=param_attr,
            bias_attr=False)
+    if qk_norm_epsilon is not None:
+        q = rms_norm(q, begin_norm_axis=2, epsilon=qk_norm_epsilon)
+        k = rms_norm(k, begin_norm_axis=2, epsilon=qk_norm_epsilon)
 
     def split_heads(x):
         r = helper.create_tmp_variable(x.dtype)
@@ -325,7 +354,16 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          attrs={"axis": [0, 2, 1, 3]})
         return t
 
+    def rotate(x):
+        r = helper.create_tmp_variable(x.dtype)
+        helper.append_op("rope", inputs={"X": [x.name]},
+                         outputs={"Out": [r.name]},
+                         attrs={"theta": float(rope_theta)})
+        return r
+
     qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
+    if rope_theta is not None:
+        qh, kh = rotate(qh), rotate(kh)
     attn = helper.create_tmp_variable(queries.dtype)
     helper.append_op(
         "scaled_dot_product_attention",
@@ -342,7 +380,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     helper.append_op("reshape", inputs={"X": [back.name]},
                      outputs={"Out": [merged.name]},
                      attrs={"shape": [0, 0, D]})
-    out = fc(merged, D, num_flatten_dims=2, bias_attr=False)
+    out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
+             bias_attr=False)
     from .sequence import propagate_length
 
     return propagate_length(queries, out)
@@ -510,33 +549,69 @@ def auc(input, label):
 
 
 def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
-        param_attr=None, name=None):
+        param_attr=None, name=None, top_k=1, gated=False, dropless=False,
+        initializer=None):
     """Mixture-of-experts FFN layer (beyond-reference — SURVEY.md §2.16 last
     row).  `input` [N, D] tokens -> [N, D].  Expert weights are stacked
     [E, D, H]/[E, H, D]; under a ParallelExecutor whose mesh has an 'ep'
     axis they are sharded one-expert-per-member and tokens ride
-    `all_to_all` (ops/moe_ops.py)."""
+    `all_to_all` (ops/moe_ops.py).
+
+    `dropless=True` is the fine-grained form: the `top_k` largest router
+    probabilities a token, nothing dropped, `gated` experts `WO(act(WI x)
+    * (WU x))`; it returns (out, router_logits [N, E] float32, counts
+    [E]), the last two for `moe_router_loss`."""
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     d_model = input.shape[-1]
-    gate = helper.create_parameter(
-        attr=param_attr if isinstance(param_attr, dict) else {},
-        shape=[d_model, num_experts], dtype=input.dtype,
-        default_initializer=NormalInitializer(0.0, d_model ** -0.5))
-    wi = helper.create_parameter(
-        attr={}, shape=[num_experts, d_model, d_hidden], dtype=input.dtype,
-        default_initializer=NormalInitializer(0.0, d_model ** -0.5))
-    wo = helper.create_parameter(
-        attr={}, shape=[num_experts, d_hidden, d_model], dtype=input.dtype,
-        default_initializer=NormalInitializer(0.0, d_hidden ** -0.5))
+
+    def init(fan_in):
+        return initializer or NormalInitializer(0.0, fan_in ** -0.5)
+
+    def weight(shape, fan_in, attr=None):
+        return helper.create_parameter(
+            attr=attr or {}, shape=shape, dtype=input.dtype,
+            default_initializer=init(fan_in))
+
+    gate = weight([d_model, num_experts], d_model,
+                  param_attr if isinstance(param_attr, dict) else None)
+    wi = weight([num_experts, d_model, d_hidden], d_model)
+    ins = {"X": [input.name], "Gate": [gate.name], "WI": [wi.name]}
+    if gated:
+        ins["WU"] = [weight([num_experts, d_model, d_hidden], d_model).name]
+    ins["WO"] = [weight([num_experts, d_hidden, d_model], d_hidden).name]
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    outs = {"Out": [out.name]}
+    attrs = {"capacity_factor": capacity_factor, "act": act}
+    if not dropless:
+        if top_k != 1 or gated:
+            raise ValueError("layers.moe: top_k > 1 and gated experts need "
+                             "dropless=True (ops/moe_ops.py)")
+        helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)
+        return out
+    logits = helper.create_tmp_variable(
+        "float32", shape=(input.shape[0], num_experts))
+    counts = helper.create_tmp_variable("float32", shape=(num_experts,),
+                                        stop_gradient=True)
+    outs.update({"RouterLogits": [logits.name], "Counts": [counts.name]})
+    attrs.update({"top_k": int(top_k), "gated": bool(gated),
+                  "dropless": True})
+    helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)
+    return out, logits, counts
+
+
+def moe_router_loss(router_logits, counts):
+    """(load-balancing loss [1], router z-loss [1]) of one dropless expert
+    layer, from `moe`'s second and third result (ops/moe_ops.py
+    moe_router_loss has the formulas)."""
+    helper = LayerHelper("moe_router_loss")
+    balance = helper.create_tmp_variable("float32", shape=(1,))
+    z = helper.create_tmp_variable("float32", shape=(1,))
     helper.append_op(
-        "moe",
-        inputs={"X": [input.name], "Gate": [gate.name], "WI": [wi.name],
-                "WO": [wo.name]},
-        outputs={"Out": [out.name]},
-        attrs={"capacity_factor": capacity_factor, "act": act},
-    )
-    return out
+        "moe_router_loss",
+        inputs={"RouterLogits": [router_logits.name],
+                "Counts": [counts.name]},
+        outputs={"Balance": [balance.name], "ZLoss": [z.name]})
+    return balance, z
 
 
 def pipeline_stage(name=None):

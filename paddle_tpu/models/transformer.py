@@ -46,39 +46,97 @@ def _positions(tokens, dim, max_len, dtype):
 def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                mlp_ratio=4, dtype="float32", dropout_prob=0.0,
                is_test=False, remat=False, sp_mode="ring",
-               sp_schedule="zigzag"):
+               sp_schedule="zigzag", norm="layer_norm", norm_epsilon=1e-5,
+               positions="learned", rope_theta=10000.0, qk_norm=False,
+               ffn="mlp", moe=None, router_outputs=None, init_scale=None,
+               emb_init_scale=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
     with an 'sp' axis the sequence dimension shards and attention runs as
     a causal flash ring (zigzag = load-balanced) or Ulysses all-to-all;
-    single-chip they pick the fused flash kernel when eligible."""
-    emb = layers.embedding(tokens, size=[vocab_size, dim], dtype=dtype)
-    pos = _positions(tokens, dim, max_len, dtype)
-    x = layers.elementwise_add(emb, pos, axis=1)
+    single-chip they pick the fused flash kernel when eligible.
+
+    The block's kinds, GPT-2's by default: `norm` 'layer_norm' or
+    'rms_norm' (with `norm_epsilon`); `positions` 'learned' (a table added
+    to the embedding) or 'rope' (Q and K rotated per head, `rope_theta`);
+    `qk_norm` (an RMSNorm on the whole Q and K projections); `ffn` 'mlp'
+    (GELU, `mlp_ratio`) or 'moe': dropless top-k experts, `moe` =
+    {"num_experts", "d_hidden", "top_k"} and optionally "act" ('silu') and
+    "gated" (True).  An 'moe' block appends its layer's (router logits,
+    per-expert counts) to the list `router_outputs`, for the auxiliary
+    losses (`moe_lm_loss`).  `init_scale` draws every matrix (embedding,
+    projections, experts, head) from normal(0, init_scale) instead of each
+    layer's default; `emb_init_scale` gives the token embedding a scale of
+    its own."""
+    if norm not in ("layer_norm", "rms_norm"):
+        raise ValueError(f"norm {norm!r}: use 'layer_norm' or 'rms_norm'")
+    if positions not in ("learned", "rope"):
+        raise ValueError(f"positions {positions!r}: use 'learned' or 'rope'")
+    if ffn not in ("mlp", "moe"):
+        raise ValueError(f"ffn {ffn!r}: use 'mlp' or 'moe'")
+    init = (NormalInitializer(scale=init_scale) if init_scale is not None
+            else None)
+    attr = {"initializer": init} if init is not None else None
+
+    def normed(x):
+        if norm == "rms_norm":
+            return layers.rms_norm(x, begin_norm_axis=2,
+                                   epsilon=norm_epsilon)
+        return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_epsilon)
+
+    def feed_forward(h):
+        if ffn == "mlp":
+            m = layers.fc(h, dim * mlp_ratio, num_flatten_dims=2,
+                          param_attr=attr, act="gelu")
+            return layers.fc(m, dim, num_flatten_dims=2, param_attr=attr)
+        T = h.shape[1]
+        m, logits, counts = layers.moe(
+            layers.reshape(h, [-1, dim]), moe["num_experts"],
+            moe["d_hidden"], act=moe.get("act", "silu"),
+            top_k=moe["top_k"], gated=moe.get("gated", True),
+            dropless=True, initializer=init)
+        if router_outputs is not None:
+            router_outputs.append((logits, counts))
+        return layers.reshape(m, [-1, T, dim])
+
+    emb_attr = attr if emb_init_scale is None else {
+        "initializer": NormalInitializer(scale=emb_init_scale)}
+    x = layers.embedding(tokens, size=[vocab_size, dim], param_attr=emb_attr,
+                         dtype=dtype)
+    if positions == "learned":
+        x = layers.elementwise_add(x, _positions(tokens, dim, max_len,
+                                                 dtype), axis=1)
     if dropout_prob:
         x = layers.dropout(x, dropout_prob, is_test=is_test)
 
     blk = (layers.recompute if remat else contextlib.nullcontext)
     for _ in range(n_layers):
         with blk():
-            h = layers.layer_norm(x, begin_norm_axis=2)
+            h = normed(x)
             a = layers.multi_head_attention(
                 h, h, h, num_heads=n_heads, causal=True,
-                sp_mode=sp_mode, sp_schedule=sp_schedule)
+                param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
+                sp_schedule=sp_schedule,
+                qk_norm_epsilon=norm_epsilon if qk_norm else None,
+                rope_theta=rope_theta if positions == "rope" else None)
             if dropout_prob:
                 a = layers.dropout(a, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, a)
-            h = layers.layer_norm(x, begin_norm_axis=2)
-            m = layers.fc(h, dim * mlp_ratio, num_flatten_dims=2,
-                          act="gelu")
-            m = layers.fc(m, dim, num_flatten_dims=2)
+            m = feed_forward(normed(x))
             if dropout_prob:
                 m = layers.dropout(m, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, m)
 
-    x = layers.layer_norm(x, begin_norm_axis=2)
-    return layers.fc(x, vocab_size, num_flatten_dims=2, bias_attr=False)
+    return layers.fc(normed(x), vocab_size, num_flatten_dims=2,
+                     param_attr=attr, bias_attr=False)
+
+
+# decoder_lm's arguments that change the block's parameters or equations,
+# at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
+# _lm_fns) know
+_GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
+               "qk_norm": False, "ffn": "mlp"}
 
 
 def lm_loss(logits, targets, dtype="float32"):
@@ -91,6 +149,23 @@ def lm_loss(logits, targets, dtype="float32"):
         flat = layers.cast(flat, "float32")
     tgt = layers.reshape(targets, [-1, 1])
     return layers.mean(layers.softmax_with_cross_entropy(flat, tgt))
+
+
+def moe_lm_loss(logits, targets, router_outputs, dtype="float32",
+                balance_weight=0.01, z_weight=0.001):
+    """`lm_loss` plus the expert layers' auxiliary losses: balance_weight x
+    the load-balancing loss and z_weight x the router z-loss, each computed
+    per layer (`layers.moe_router_loss` on that layer's router logits and
+    counts) and averaged over the layers."""
+    total = lm_loss(logits, targets, dtype=dtype)
+    per_layer = [layers.moe_router_loss(lg, counts)
+                 for lg, counts in router_outputs]
+    n = float(len(per_layer))
+    for which, weight in ((0, balance_weight), (1, z_weight)):
+        aux = layers.sums([pair[which] for pair in per_layer])
+        total = layers.elementwise_add(
+            total, layers.scale(aux, scale=weight / n))
+    return total
 
 
 class DecoderLM:
@@ -115,6 +190,7 @@ class DecoderLM:
         self.max_len, self.mlp_ratio = max_len, mlp_ratio
         self.dtype = dtype
         self._params = None
+        self._block = {}
 
     def logits(self, tokens, **kw):
         from ..framework.core import default_main_program
@@ -132,8 +208,10 @@ class DecoderLM:
 
         new = [v for n, v in block.vars.items()
                if n not in before and isinstance(v, Parameter)]
+        self._block = {k: kw[k] for k, default in _GPT2_BLOCK.items()
+                       if kw.get(k, default) != default}
         want = 2 + self._PER_LAYER * self.n_layers + 3
-        assert len(new) == want, (len(new), want)
+        assert self._block or len(new) == want, (len(new), want)
         self._params = new
         return out
 
@@ -387,6 +465,13 @@ class DecoderLM:
         declaring them in the current program (see generate())."""
         from ..framework.core import default_main_program
 
+        if self._block:
+            raise NotImplementedError(
+                f"DecoderLM: the generation and paged-serving ops run "
+                f"GPT-2's block only (LayerNorm, learned positions, GELU "
+                f"MLP, 12 parameters a layer); this tower was built with "
+                f"{self._block} and can be trained, not served, until the "
+                f"serving twin takes the block's kinds (ROADMAP.md S1/D4)")
         p = self._params
         gb = default_main_program().global_block()
         for v in p:
@@ -421,5 +506,48 @@ def build_lm_train_program(seq_len, vocab_size=32000, dim=512,
                         max_len=seq_len, dtype=dtype, remat=remat,
                         sp_mode=sp_mode, sp_schedule=sp_schedule)
     loss = lm_loss(logits, targets, dtype=dtype)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def build_moe_lm_train_program(seq_len, vocab_size, dim, n_layers, n_heads,
+                               num_experts, expert_dim, top_k,
+                               norm_epsilon=1e-5, rope_theta=10000.0,
+                               balance_weight=0.01, z_weight=0.001,
+                               dtype="bfloat16", learning_rate=3e-4,
+                               init_scale=0.02, emb_init_scale=None,
+                               remat=False):
+    """OLMoE-shaped decoder (Muennighoff et al. 2024, arXiv:2409.02060;
+    transformers' `OlmoeForCausalLM`): RMSNorm pre-norm blocks, QK-norm,
+    rotate-half RoPE, SiLU-gated dropless top-k experts, no bias, untied
+    head; next-token loss + the two router losses; Adam.  Returns the
+    loss.  Feeds as `build_lm_train_program`.
+
+    On freshly initialised weights the router sees what the residual
+    stream holds.  With every matrix at 0.02 that is the attention's
+    running mean of the values (norm 37 / sqrt(t) against the embedding's
+    0.9), the same for neighbouring tokens: the busiest expert gets 4 to 8
+    times the mean, and Adam at 3e-4 collapses the routing onto top_k
+    experts within 16 steps (PERF.md, PR 26).  `emb_init_scale=1.0`
+    (torch.nn.Embedding's own default) keeps tokens distinct and the
+    routing balanced."""
+    from .. import optimizer as opt
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    routers = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, n_layers, n_heads, max_len=seq_len,
+        dtype=dtype, remat=remat, norm="rms_norm",
+        norm_epsilon=norm_epsilon, positions="rope", rope_theta=rope_theta,
+        qk_norm=True, ffn="moe",
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k}, router_outputs=routers,
+        init_scale=init_scale, emb_init_scale=emb_init_scale)
+    loss = moe_lm_loss(logits, targets, routers, dtype=dtype,
+                       balance_weight=balance_weight, z_weight=z_weight)
+    # the last layer's routed (token, expert) pairs, for a fetch to hold
+    # "nothing dropped" exactly: seq_len * top_k a sequence
+    layers.reduce_sum(routers[-1][1])
     opt.Adam(learning_rate=learning_rate).minimize(loss)
     return loss

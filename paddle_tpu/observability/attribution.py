@@ -112,6 +112,20 @@ def op_scope(op):
     return jax.named_scope(scope_name(op))
 
 
+def part_scope(name: str):
+    """A named part INSIDE one op's lowering (`pdtpu.<name>`, as the
+    tracer's spans are named): an emitter with several stages worth telling
+    apart in an HLO dump (the dropless `moe` op's route / permute / experts
+    / combine) wraps each in one.  It nests under the op's own scope when
+    attribution is on and never looks like one (`parse_scope` matches
+    `pdop__...` only); always on, since a scope is metadata of the traced
+    program and costs a step nothing.  Beside `op_scope`, so that named
+    scopes still have one home."""
+    import jax
+
+    return jax.named_scope("pdtpu." + name)
+
+
 def parse_scope(text: str):
     """(op_type, uid) from any string carrying a scope name, else None.
     Greedy type match + the terminal ``__u<digits>`` keeps op types with

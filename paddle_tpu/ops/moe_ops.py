@@ -1,17 +1,37 @@
 """Mixture-of-experts op, reachable from the Program IR.
 
 Beyond-reference capability (SURVEY.md §2.16 last row; the 2018 reference has
-no MoE).  Top-1 gating with static per-expert capacity so the whole layer is
-fixed-shape XLA.  Single-device: the dispatch/compute/combine runs locally
-(stacked-expert einsum).  Under a ParallelExecutor whose mesh has an 'ep'
-axis > 1, expert weights live one-expert-per-member and tokens are exchanged
-with `lax.all_to_all` over ICI (the standard TPU MoE recipe) — same
-dispatch semantics, so single-chip and ep-sharded results agree whenever no
-token is capacity-dropped."""
+no MoE).  Two forms of one op:
+
+* capacity (the default): top-1 gating with static per-expert capacity so
+  the whole layer is fixed-shape XLA.  Single-device: the
+  dispatch/compute/combine runs locally (stacked-expert einsum).  Under a
+  ParallelExecutor whose mesh has an 'ep' axis > 1, expert weights live
+  one-expert-per-member and tokens are exchanged with `lax.all_to_all` over
+  ICI (the standard TPU MoE recipe) — same dispatch semantics, so
+  single-chip and ep-sharded results agree whenever no token is
+  capacity-dropped.
+* dropless (`dropless=True`; OLMoE, Mixtral, DeepSeek-style fine-grained
+  experts): the `top_k` largest router probabilities a token, every chosen
+  (token, expert) pair computed, many experts on one chip.  The token-slots
+  are sorted by expert ONCE; the sort serves the two or three grouped
+  matmuls (`lax.ragged_dot` over the sorted rows, one group an expert) and
+  the combine.  `gated=True` makes an expert `WO(act(WI x) * (WU x))`.  The
+  router's logits and the per-expert token counts leave the op for the
+  auxiliary losses (`moe_router_loss`).  Not under an 'ep' mesh yet."""
 
 from __future__ import annotations
 
+from ..observability.attribution import part_scope
+from ..observability.metrics import REGISTRY as _MET
+from .llm_ops import wide_dtype
 from .registry import register_op
+
+_MET_MOE_LAYERS = _MET.counter(
+    "moe_layers_traced_total",
+    "dropless expert layers traced (forward emission; once a compile, not "
+    "once a step), by top_k, number of experts and the grouped matmul's "
+    "implementation")
 
 
 def _dispatch(x, gate_w, n_exp, capacity):
@@ -51,21 +71,116 @@ def _combine(back, expert, src_slot, keep, gatew, x):
 
 
 def _ffn(h_in, wi, wo, act):
+    return _act_fn(act)(h_in @ wi) @ wo
+
+
+def _act_fn(act):
     import jax
     import jax.numpy as jnp
 
-    actf = {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
-            "tanh": jnp.tanh}[act]
-    return actf(h_in @ wi) @ wo
+    return {"relu": jax.nn.relu, "gelu": jax.nn.gelu, "tanh": jnp.tanh,
+            "silu": jax.nn.silu}[act]
 
 
-@register_op("moe")
+def _route_top_k(x, gate_w, top_k):
+    """Router of the dropless form, float32 where X is bf16 (a bf16 logit's
+    rounding is the size of the gap between the k-th and the next
+    probability): -> (logits [T, E], weights [T, k], experts [T, k]).  The
+    weights are the softmax probabilities as they are, not renormalised
+    over the chosen k."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    wide = wide_dtype(x.dtype)
+    logits = jnp.dot(x.astype(wide), gate_w.astype(wide),
+                     precision=lax.Precision.HIGHEST)
+    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return logits, weights, experts
+
+
+def _moe_dropless(x, gate_w, wi, wu, wo, top_k, act):
+    """-> (out [T, D], router logits [T, E] f32, counts [E] f32).
+
+    Slot s = t * k + j is token t's j-th choice.  `order` lists the slots
+    by expert (stable, so by token within an expert), `inv` is its
+    inverse; rows move only by gathers, forward and backward."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    T, D = x.shape
+    n_exp = wi.shape[0]
+    with part_scope("moe.route"):
+        logits, weights, experts = _route_top_k(x, gate_w, top_k)
+    with part_scope("moe.permute"):
+        flat = experts.reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        counts = jnp.sum(jax.nn.one_hot(flat, n_exp, dtype=jnp.int32),
+                         axis=0)
+        xs = _gather_slots(x, order, inv, top_k)            # [T*k, D]
+    with part_scope("moe.experts"):
+        wide = logits.dtype
+        h = lax.ragged_dot(xs, wi, counts).astype(wide)
+        h = _act_fn(act)(h)
+        if wu is not None:
+            h = h * lax.ragged_dot(xs, wu, counts).astype(wide)
+        ys = lax.ragged_dot(h.astype(x.dtype), wo, counts)  # [T*k, D]
+    with part_scope("moe.combine"):
+        y = _permute_rows(ys, inv, order).reshape(T, top_k, D)
+        out = jnp.sum(y.astype(wide) * weights[..., None], axis=1)
+    return out.astype(x.dtype), logits, counts.astype(jnp.float32)
+
+
+def _permute_rows(x, perm, inv):
+    """x[perm] for a permutation `perm` with inverse `inv`.  The transpose
+    of a gather is a scatter-add; of a permutation it is the gather by the
+    inverse, which is what the backward pass runs."""
+    import jax
+
+    @jax.custom_vjp
+    def take(x, perm, inv):
+        return x[perm]
+
+    take.defvjp(lambda x, perm, inv: (x[perm], (perm, inv)),
+                lambda res, g: (_permute_rows(g, res[1], res[0]), None,
+                                None))
+    return take(x, perm, inv)
+
+
+def _gather_slots(x, order, inv, k):
+    """[T, D] -> [T*k, D]: row i is token order[i] // k.  Backward: the
+    slots' gradients back in token order (a gather by `inv`), summed over
+    each token's k."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def take(x, order, inv):
+        return x[order // k]
+
+    def bwd(res, g):
+        order, inv = res
+        gx = _permute_rows(g, inv, order).reshape(-1, k, g.shape[-1])
+        return (jnp.sum(gx.astype(wide_dtype(g.dtype)), axis=1).astype(g.dtype),
+                None, None)
+
+    take.defvjp(lambda x, order, inv: (x[order // k], (order, inv)), bwd)
+    return take(x, order, inv)
+
+
+@register_op("moe", non_diff_outputs=("Counts",))
 def moe(ctx, ins, attrs):
     """X [T, D] tokens; Gate [D, E]; WI [E, D, H]; WO [E, H, D] -> Out [T, D].
 
     attrs: capacity_factor (default 1.0), act ('relu').  Capacity is fixed
-    at trace time: ceil(tokens_per_member / E * factor)."""
-    import jax.numpy as jnp
+    at trace time: ceil(tokens_per_member / E * factor).
+
+    With `dropless` (see the module's docstring): attrs top_k (1) and gated
+    (False; then WU [E, D, H] is an input too), capacity_factor unused;
+    outputs Out, RouterLogits [T, E] float32 and Counts [E] float32 (the
+    (token, expert) pairs each expert computed; they sum to T * top_k)."""
     import math
 
     x = ins["X"][0]
@@ -74,6 +189,9 @@ def moe(ctx, ins, attrs):
     n_exp = wi.shape[0]
     factor = float(attrs.get("capacity_factor", 1.0))
     act = str(attrs.get("act", "relu"))
+    top_k = int(attrs.get("top_k", 1))
+    gated = bool(attrs.get("gated", False))
+    dropless = bool(attrs.get("dropless", False))
 
     mesh = getattr(ctx, "mesh", None)
     ep = 1
@@ -83,6 +201,29 @@ def moe(ctx, ins, attrs):
         ep = sizes.get("ep", 1)
         token_axes = tuple(a for a in ("dp", "ep")
                            if sizes.get(a, 1) > 1)
+
+    if dropless:
+        if ep > 1:
+            raise NotImplementedError(
+                "moe op: the dropless form (top_k, gated experts, many "
+                "experts a chip) does not run under an 'ep' mesh yet "
+                "(ROADMAP.md R2: 16 experts a chip over ep=4); use the "
+                "single-chip Executor, or the capacity form with experts "
+                "= ep")
+        if not 1 <= top_k <= n_exp:
+            raise ValueError(f"moe op: top_k {top_k} not in [1, {n_exp}]")
+        wu = ins["WU"][0] if gated else None
+        if not ctx.in_grad_replay():
+            _MET_MOE_LAYERS.inc(top_k=str(top_k), experts=str(n_exp),
+                                impl="ragged_dot")
+        out, logits, counts = _moe_dropless(x, gate_w, wi, wu, wo, top_k,
+                                            act)
+        return {"Out": [out], "RouterLogits": [logits], "Counts": [counts]}
+    if top_k != 1 or gated:
+        raise ValueError(
+            "moe op: top_k > 1 and gated experts exist only in the "
+            "dropless form (dropless=True); the capacity form is top-1 "
+            "with an ungated FFN")
 
     T = x.shape[0]
     if ep > 1:
@@ -100,6 +241,34 @@ def moe(ctx, ins, attrs):
     h = _ffn(send, wi, wo, act)  # [E, C, D] batched over experts
     out = _combine(h, expert, src_slot, keep, gatew, x)
     return {"Out": [out]}
+
+
+@register_op("moe_router_loss", non_diff_inputs=("Counts",))
+def moe_router_loss(ctx, ins, attrs):
+    """The two auxiliary losses of one expert layer, from what the `moe` op
+    hands out: RouterLogits [T, E] float32, Counts [E] ->
+
+      Balance [1]  E * sum_e f_e P_e, f_e = Counts_e / T (the share of
+                   tokens with e among their k), P_e the mean of
+                   softmax(logits)_e (Switch Transformer, Fedus et al.
+                   2021, arXiv:2101.03961, eq. 4, as transformers'
+                   load_balancing_loss_func extends it to top-k);
+      ZLoss [1]    the mean of logsumexp(logits)^2 (ST-MoE, Zoph et al.
+                   2022, arXiv:2202.08906, eq. 5).
+
+    Counts carries no gradient; both losses reach the router through the
+    logits."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = ins["RouterLogits"][0]
+    logits = logits.astype(wide_dtype(logits.dtype))
+    counts = ins["Counts"][0].astype(logits.dtype)
+    T, n_exp = logits.shape
+    mean_prob = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
+    balance = n_exp * jnp.sum(counts / T * mean_prob)
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return {"Balance": [balance.reshape(1)], "ZLoss": [z.reshape(1)]}
 
 
 def _moe_sharded(ctx, x, gate_w, wi, wo, mesh, token_axes, factor, act):
@@ -156,11 +325,14 @@ from .registry import register_cost, register_sharding  # noqa: E402
 
 
 def _moe_cost(ins, outs, attrs):
-    """Gate matmul (2*T*D*E) + the two expert matmuls over every routed
-    token (4*T*D*H at capacity).  Bytes override adds the all_to_all
-    dispatch/return buffers (2 x token bytes each way) — the collective
-    traffic term the per-mode ICI ledgers (tools/hlo_analysis.py
-    collectives) measure for the ep programs."""
+    """Gate matmul (2*T*D*E) + the expert matmuls over every routed token.
+    Capacity form: two matmuls (4*T*D*H at capacity), and the bytes
+    override adds the all_to_all dispatch/return buffers (2 x token bytes
+    each way) — the collective traffic term the per-mode ICI ledgers
+    (tools/hlo_analysis.py collectives) measure for the ep programs.
+    Dropless form: top_k token-slots a token, each through two matmuls or,
+    gated, three (2*D*H each); no capacity factor, and no collective (it
+    runs on one chip)."""
     x = ins.get("X", [None])[0]
     gate = ins.get("Gate", [None])[0]
     wi = ins.get("WI", [None])[0]
@@ -169,6 +341,10 @@ def _moe_cost(ins, outs, attrs):
     t, d = x.shape
     e = gate.shape[1]
     h = wi.shape[2] if len(wi.shape) == 3 else d
+    if bool(attrs.get("dropless", False)):
+        matmuls = 3 if bool(attrs.get("gated", False)) else 2
+        slots = t * int(attrs.get("top_k", 1))
+        return {"flops": 2 * t * d * e + matmuls * 2 * slots * d * h}
     factor = float(attrs.get("capacity_factor", 1.0))
     routed = int(t * max(factor, 1.0))
     flops = 2 * t * d * e + 4 * routed * d * h
@@ -184,11 +360,19 @@ def _moe_sharding(ctx, ins, outs, attrs):
     """Expert-parallel dispatch: tokens ride an all_to_all to their
     expert's member and back (2x the send buffer each direction); the
     shard_map custom path re-pays both in the backward (bwd_retrace),
-    matching the cost formula's collective_bytes above."""
+    matching the cost formula's collective_bytes above.  The dropless form
+    has no ep path (the emitter refuses the mesh), so it declares no
+    collective: tokens keep their spec, the router's logits follow the
+    tokens' leading axis, the counts are replicated."""
     x = ins.get("X", [None])[0]
     out = outs.get("Out", [None])[0]
     if x is None or out is None:
         return {}
+    if bool(attrs.get("dropless", False)):
+        lead = tuple(x.spec)[:1]
+        return {"Out": [tuple(x.spec)],
+                "RouterLogits": [lead + (None,) if lead else None],
+                "Counts": [(None,)]}
     ep = ctx.axis_size("ep")
     if ep > 1:
         ctx.collective("all-to-all", ("ep",),

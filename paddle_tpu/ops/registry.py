@@ -214,6 +214,11 @@ class EmitContext:
         if self._replay is None:
             self._kept[int(attrs.get("__uid__", 0))] = (tuple(outs), saved)
 
+    def in_grad_replay(self) -> bool:
+        """True while generic_grad re-emits a forward op under jax.vjp: an
+        emitter that counts or records its forward emission skips then."""
+        return self._replay is not None
+
     def kept_for_grad(self):
         """Inside generic_grad's re-emission: what this op's forward
         emission kept in this trace, else None (no grad op around the

@@ -110,8 +110,10 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # numerically checked in test_training_fusion.py
     # r5: +2 trig ops (sin, cos — the layers/ops.py activation surface),
     # numerically checked in test_ops_grad_sweep.py
-    assert len(diffable) == 148, (
+    # PR 26: +3 (rms_norm, rope, moe_router_loss — the OLMoE block), each
+    # numerically checked in test_llm_ops.py
+    assert len(diffable) == 151, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 148 - 11
+    assert len(checked) == 151 - 11

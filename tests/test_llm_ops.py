@@ -1,0 +1,291 @@
+"""rms_norm, rope, QK-norm and the dropless form of the `moe` op, each against
+plain jax.numpy: outputs directly, gradients through `generic_grad` (the
+numeric sweep of tests/op_test.py, and a dense evaluation under jax.grad).
+ops/llm_ops.py, ops/moe_ops.py, layers/nn.py."""
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from op_test import OpTestHarness
+
+
+def _r(*shape, lo=-1.0, hi=1.0, seed=0):
+    return np.random.RandomState(seed).uniform(lo, hi, shape)
+
+
+# ---------------------------------------------------------------------------
+# rms_norm, rope
+
+
+def test_rms_norm_output_and_grad():
+    x, g = _r(3, 5, 8), _r(8, lo=0.5, hi=1.5, seed=1)
+    want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * g
+    h = OpTestHarness("rms_norm", {"X": x, "Scale": g},
+                      {"epsilon": 1e-5, "begin_norm_axis": 2}, ["Y"])
+    h.check_output({"Y": want}, atol=1e-5)
+    h.check_grad(["X", "Scale"], output_slot="Y", max_relative_error=1e-2)
+
+
+def test_rms_norm_is_float32_inside_bf16():
+    """A bf16 input is normalised in float32 and rounded once."""
+    import jax.numpy as jnp
+
+    x = _r(4, 64).astype(np.float32)
+    xb = np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
+    want = xb / np.sqrt((xb * xb).mean(-1, keepdims=True) + 1e-5)
+    fluid.reset()
+    from paddle_tpu.ops.registry import EmitContext, get_op_info
+
+    out = get_op_info("rms_norm").emit(
+        EmitContext(None, is_test=True),
+        {"X": [jnp.asarray(x, jnp.bfloat16)],
+         "Scale": [jnp.ones((64,), jnp.bfloat16)]},
+        {"epsilon": 1e-5, "begin_norm_axis": 1})["Y"][0]
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                               rtol=2 ** -8, atol=1e-6)
+
+
+def _rope_numpy(x, theta):
+    """transformers' apply_rotary_pos_emb, written out."""
+    T, D = x.shape[-2:]
+    inv = 1.0 / theta ** (np.arange(0, D, 2) / D)
+    ang = np.arange(T)[:, None] * inv[None, :]
+    ang = np.concatenate([ang, ang], -1)
+    rot = np.concatenate([-x[..., D // 2:], x[..., :D // 2]], -1)
+    return x * np.cos(ang) + rot * np.sin(ang)
+
+
+def test_rope_output_and_grad():
+    x = _r(2, 3, 6, 8)
+    h = OpTestHarness("rope", {"X": x}, {"theta": 10000.0})
+    h.check_output({"Out": _rope_numpy(x, 10000.0)}, atol=1e-5)
+    h.check_grad(["X"], max_relative_error=1e-2)
+
+
+def test_rope_is_relative():
+    """Scores of rotated q and k depend on the distance alone: shifting
+    both positions by one leaves q_t . k_s unchanged."""
+    x = _r(1, 1, 1, 16, seed=3)
+    q = np.repeat(x, 6, axis=2)          # the same vector at 6 positions
+    rot = _rope_numpy(q, 100.0)[0, 0]
+    scores = rot @ rot.T
+    np.testing.assert_allclose(np.diag(scores, 1), scores[0, 1], rtol=1e-6)
+    np.testing.assert_allclose(np.diag(scores, 2), scores[0, 2], rtol=1e-6)
+
+
+def test_rope_refuses_an_odd_head_size():
+    with pytest.raises(Exception, match="even"):
+        OpTestHarness("rope", {"X": _r(1, 1, 4, 7)}).fetch()
+
+
+def _attention_reference(x, params, n_heads, eps, theta):
+    import jax
+    import jax.numpy as jnp
+
+    wq, wk, wv, gq, gk, wo = params
+    B, T, D = x.shape
+
+    def rms(a, g):
+        return a / jnp.sqrt(jnp.mean(a * a, -1, keepdims=True) + eps) * g
+
+    def rope(a):                          # [B, H, T, dh]
+        dh = a.shape[-1]
+        inv = 1.0 / theta ** (jnp.arange(0, dh, 2) / dh)
+        ang = jnp.arange(T)[:, None] * inv[None, :]
+        ang = jnp.concatenate([ang, ang], -1)
+        rot = jnp.concatenate([-a[..., dh // 2:], a[..., :dh // 2]], -1)
+        return a * jnp.cos(ang) + rot * jnp.sin(ang)
+
+    def heads(a):
+        return a.reshape(B, T, n_heads, D // n_heads).transpose(0, 2, 1, 3)
+
+    q, k, v = rms(x @ wq, gq), rms(x @ wk, gk), x @ wv
+    q, k, v = rope(heads(q)), rope(heads(k)), heads(v)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (D // n_heads) ** 0.5
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+    a = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+    return a.transpose(0, 2, 1, 3).reshape(B, T, D) @ wo
+
+
+def test_qk_norm_and_rope_inside_multi_head_attention():
+    """layers.multi_head_attention with QK-norm and RoPE against jax.numpy,
+    the output and every parameter's gradient by generic_grad."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, D, H = 2, 8, 16, 4
+    fluid.reset()
+    x = fluid.layers.data("x", shape=[T, D], dtype="float32")
+    out = fluid.layers.multi_head_attention(
+        x, x, x, num_heads=H, causal=True, qk_norm_epsilon=1e-5,
+        rope_theta=10000.0)
+    loss = fluid.layers.mean(fluid.layers.square(out))
+    pg = fluid.append_backward(loss)
+    main = fluid.default_main_program()
+    params = main.global_block().all_parameters()
+    assert [tuple(p.shape) for p in params] == [
+        (D, D), (D, D), (D, D), (D,), (D,), (D, D)]
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    rng = np.random.RandomState(5)
+    for p in params[3:5]:                 # gains away from one
+        scope.set(p.name, jnp.asarray(rng.uniform(0.5, 1.5, p.shape)))
+    xv = rng.normal(size=(B, T, D)).astype(np.float32)
+    grads = {p.name: g.name for p, g in pg}
+    got = exe.run(feed={"x": xv},
+                  fetch_list=[out] + [grads[p.name] for p in params])
+
+    vals = [jnp.asarray(np.asarray(scope.find(p.name), np.float64))
+            for p in params]
+    ref = lambda ps: _attention_reference(jnp.asarray(xv, jnp.float64), ps,
+                                          H, 1e-5, 10000.0)
+    np.testing.assert_allclose(got[0], ref(vals), atol=2e-5)
+    want = jax.grad(lambda ps: jnp.mean(jnp.square(ref(ps))))(vals)
+    for g, w, p in zip(got[1:], want, params):
+        np.testing.assert_allclose(g, w, atol=2e-6, err_msg=p.name)
+
+
+# ---------------------------------------------------------------------------
+# the dropless form of `moe`, and `moe_router_loss`
+
+
+def _moe_inputs(T=24, D=8, E=6, H=5, seed=0):
+    rng = np.random.RandomState(seed)
+    return {"X": rng.normal(size=(T, D)),
+            "Gate": rng.normal(size=(D, E)),
+            "WI": rng.normal(size=(E, D, H)) * 0.5,
+            "WU": rng.normal(size=(E, D, H)) * 0.5,
+            "WO": rng.normal(size=(E, H, D)) * 0.5}
+
+
+def _moe_dense(x, gate, wi, wu, wo, top_k, act="silu"):
+    """Every token through every expert, one-hot weights: no sort."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = x @ gate
+    p = jax.nn.softmax(logits, -1)
+    kth = jnp.sort(p, -1)[:, -top_k][:, None]
+    w = jnp.where(p >= kth, p, 0.0)
+    actf = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    h = actf(jnp.einsum("td,edh->eth", x, wi))
+    if wu is not None:
+        h = h * jnp.einsum("td,edh->eth", x, wu)
+    y = jnp.einsum("eth,ehd->etd", h, wo)
+    return jnp.einsum("te,etd->td", w, y), logits, jnp.sum(p >= kth, 0)
+
+
+@pytest.mark.parametrize("top_k,gated,act", [(2, True, "silu"),
+                                             (1, False, "relu"),
+                                             (3, True, "silu"),
+                                             (6, False, "silu")])
+def test_moe_dropless_sorted_path_against_dense(top_k, gated, act):
+    import jax
+    import jax.numpy as jnp
+
+    ins = _moe_inputs()
+    if not gated:
+        ins.pop("WU")
+    attrs = {"dropless": True, "top_k": top_k, "gated": gated, "act": act}
+    got = OpTestHarness("moe", ins, attrs,
+                        ["Out", "RouterLogits", "Counts"]).fetch()
+    dense = lambda a: _moe_dense(a["X"], a["Gate"], a["WI"], a.get("WU"),
+                                 a["WO"], top_k, act)
+    want = dense({k: jnp.asarray(v) for k, v in ins.items()})
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    assert float(np.sum(got[2])) == ins["X"].shape[0] * top_k  # dropless
+
+    # gradients of a loss on Out and on the router's logits, by generic_grad
+    from paddle_tpu.ops.registry import EmitContext, get_op_info
+
+    def through_op(a):
+        outs = get_op_info("moe").emit(
+            EmitContext(None, is_test=False),
+            {k: [v] for k, v in a.items()}, attrs)
+        return outs["Out"][0], outs["RouterLogits"][0]
+
+    def loss(fn, a):
+        out, logits = fn(a)[:2]
+        return jnp.sum(out * out) + jnp.sum(jnp.sin(logits))
+
+    a = {k: jnp.asarray(v) for k, v in ins.items()}
+    g_op = jax.grad(lambda a: loss(through_op, a))(a)
+    g_dense = jax.grad(lambda a: loss(dense, a))(a)
+    for k in a:
+        np.testing.assert_allclose(g_op[k], g_dense[k], rtol=1e-5,
+                                   atol=1e-5, err_msg=k)
+
+
+def test_moe_dropless_numeric_grad():
+    ins = _moe_inputs(T=6, D=4, E=3, H=3)
+    OpTestHarness("moe", ins, {"dropless": True, "top_k": 2, "gated": True,
+                               "act": "silu"},
+                  ["Out", "RouterLogits", "Counts"]).check_grad(
+        ["X", "Gate", "WI", "WU", "WO"], max_relative_error=1e-2)
+
+
+def test_moe_router_loss_output_and_grad():
+    rng = np.random.RandomState(2)
+    logits = rng.normal(size=(10, 4))
+    counts = np.array([7.0, 3.0, 6.0, 4.0])
+    p = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+    balance = 4 * np.sum(counts / 10 * p.mean(0))
+    z = np.mean(np.log(np.exp(logits).sum(-1)) ** 2)
+    h = OpTestHarness("moe_router_loss",
+                      {"RouterLogits": logits, "Counts": counts}, {},
+                      ["Balance", "ZLoss"])
+    h.check_output({"Balance": [balance], "ZLoss": [z]}, atol=1e-6)
+    h.check_grad(["RouterLogits"], output_slot="Balance",
+                 max_relative_error=1e-2)
+    h.check_grad(["RouterLogits"], output_slot="ZLoss",
+                 max_relative_error=1e-2)
+
+
+def test_capacity_form_refuses_the_new_attributes():
+    ins = _moe_inputs()
+    with pytest.raises(Exception, match="dropless"):
+        OpTestHarness("moe", ins, {"top_k": 2}).fetch()
+    with pytest.raises(ValueError, match="dropless"):
+        fluid.reset()
+        x = fluid.layers.data("x", shape=[8], dtype="float32")
+        fluid.layers.moe(x, 4, 8, gated=True)
+
+
+def test_moe_cost_follows_the_attributes():
+    """k token-slots a token through three matmuls when gated, two when
+    not; no capacity factor in the dropless form."""
+    from paddle_tpu.ops.registry import ShapeDtype, get_op_info
+
+    cost = get_op_info("moe").cost
+    T, D, E, H = 4096, 2048, 64, 1024
+    ins = {"X": [ShapeDtype((T, D), "bfloat16")],
+           "Gate": [ShapeDtype((D, E), "bfloat16")],
+           "WI": [ShapeDtype((E, D, H), "bfloat16")]}
+    router = 2 * T * D * E
+    got = cost(ins, {}, {"dropless": True, "top_k": 8, "gated": True,
+                         "capacity_factor": 4.0})
+    assert got == {"flops": router + 8 * T * 3 * 2 * D * H}
+    got = cost(ins, {}, {"dropless": True, "top_k": 2, "gated": False})
+    assert got == {"flops": router + 2 * T * 2 * 2 * D * H}
+    old = cost(ins, {}, {"capacity_factor": 2.0})
+    assert old["flops"] == router + 4 * (2 * T) * D * H
+    assert old["collective_bytes"] == 4 * T * D * 2
+
+
+def test_moe_dropless_refuses_an_ep_mesh():
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from paddle_tpu.ops.registry import EmitContext, get_op_info
+
+    ctx = EmitContext(None, is_test=False)
+    ctx.mesh = Mesh(np.array(jax.devices()[:2]), ("ep",))
+    ins = {k: [jnp.asarray(v)] for k, v in _moe_inputs().items()}
+    with pytest.raises(NotImplementedError, match="R2"):
+        get_op_info("moe").emit(ctx, ins, {"dropless": True, "top_k": 2,
+                                           "gated": True, "act": "silu"})
