@@ -162,6 +162,7 @@ class ProgramWorkload:
             return 0.0
         p = self._flash
         T, D = p["T"], p["head_dim"]
+        bq, bk = self._flash_blocks_run(bq, bk)
         rows = p["layers"] * p["batch"] * p["heads"]
         walk = 2.0 * T * D * p["dtype_bytes"]  # one full K+V (or Q+dO)
         extra = rows * walk * (2.0 * max(T // int(bq) - 1, 0)
@@ -171,6 +172,19 @@ class ProgramWorkload:
         if candidate.get("remat"):
             extra *= 1.5  # the recomputed forward repeats the walk
         return extra
+
+    def _flash_blocks_run(self, bq, bk) -> Tuple[int, int]:
+        """The blocks the kernels run for a candidate's (bq, bk): a causal
+        call whose K block holds the whole sequence runs one block a head
+        (flash_attention.one_block_a_head), whatever q block the
+        candidate names."""
+        from ..ops.pallas_kernels.flash_attention import one_block_a_head
+
+        p = self._flash
+        if p.get("causal") and one_block_a_head(
+                int(bq), int(bk), p["T"], p["head_dim"]):
+            bq = p["T"]
+        return int(bq), int(bk)
 
     def feasible(self, candidate, spec) -> Tuple[bool, str]:
         """Pre-compile legality beyond the HBM estimator: flash block
@@ -187,9 +201,11 @@ class ProgramWorkload:
             return True, ""
         D = self._flash["head_dim"]
         b = self._flash["dtype_bytes"]
+        bq, bk = self._flash_blocks_run(bq, bk)
         fwd = (int(bq) * D * (b + 4)       # q block + f32 acc scratch
                + 2 * int(bk) * D * b       # k + v blocks
-               + 3 * int(bq) * 4)          # m/l scratch + lse row slice
+               + 2 * int(bq) * 128 * 4     # m/l scratch, lane-padded columns
+               + int(bq) * 4)              # lse row slice
         bwd = (2 * int(bq) * D * b         # q + dO blocks
                + 2 * int(bk) * D * b       # k + v blocks
                + 2 * int(bk) * D * 4       # dk/dv f32 accumulators

@@ -3,14 +3,38 @@
 Single-chip fused attention: never materializes the [T,T] score matrix in
 HBM.  Grid over (batch*heads, Tq/BQ, Tk/BK) with the K/V walk as the
 INNERMOST grid dimension so the Pallas pipeline double-buffers the K/V
-block DMAs against the MXU GEMMs (the r4 first-contact lesson: a
-fori_loop over one VMEM-resident [T,D] K/V block compiles but runs at
-0.7x of dense XLA attention — no DMA/compute overlap).  The online
-softmax (running max m, normalizer l, unnormalized accumulator) lives in
-VMEM scratch, initialized at the first K block and finalized into the
-output block at the last.  Under causal masking the K/V index maps CLAMP
-to the diagonal block so fully-masked future blocks are never fetched,
-and `pl.when` skips their compute.
+block DMAs against the MXU GEMMs.  The online softmax (running max m,
+normalizer l, unnormalized accumulator) lives in VMEM scratch, initialized
+at the first K block and finalized into the output block at the last.
+Under causal masking the K/V index maps CLAMP to the diagonal block so
+fully-masked future blocks are never fetched, and `pl.when` skips their
+compute.
+
+Inside a block the diagonal crosses, the causal kernels walk strips of q
+rows, each against only the K columns its last row may see: static
+slices, one straight-line walk for each offset d = q0 - k0 such a block
+can have (`_row_strips`, `_run_block`).  Two readings of the v5e say
+when work inside one VMEM-resident block pays (each kernel alone, B 8,
+H 16, T 1024, D 64, bf16; PERF.md, PR 27):
+
+- A LOOP over sub-tiles does not.  r4's first contact had a fori_loop
+  over one resident [T, D] K/V block at 0.7x of dense XLA attention, and
+  PR 27's first walk, nested fori_loops with traced trip counts over
+  (256, 256) score sub-tiles, took 1.74 / 0.96 / 1.79 ms (forward / dq /
+  dkv) for 62.5% of the square where the whole square in one shot took
+  0.82 / 0.73 / 0.90: every iteration is a matmul, a softmax pass and a
+  matmul in a chain, 0.3-0.5 us of latency that nothing overlaps, because
+  a traced trip count cannot be unrolled.  That, not lost DMA overlap, is
+  what a loop costs here: K and V of a head are 128 KB each and the next
+  head's blocks prefetch behind this one's compute either way.
+- STATIC strips do: straight-line code the scheduler interleaves.  dq
+  0.648 -> 0.446 ms and dkv 0.894 -> 0.716 for 56% and 75% of the square.
+  The forward does not follow the scores at all (0.597 unsplit, 0.600 in
+  strips of 128 rows, 0.634 of 256): its time is the per-row softmax
+  bookkeeping and the logsumexp row's relayout (0.471 without it).  m and
+  l as [rows, 1] columns instead of 1-D rows took 0.942 -> 0.817 off it
+  before any skipping, one block a head (`one_block_a_head`) 0.817 ->
+  0.597.
 
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
@@ -24,6 +48,16 @@ Replaces what the reference would have hand-written in paddle/cuda
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
+
+from ...observability.metrics import REGISTRY as _MET
+
+_MET_SCORES = _MET.counter(
+    "flash_score_elements_total",
+    "score elements of the causal flash kernel calls traced (once a "
+    "compile, not once a step), by kernel: part=square the B*H*T*T of "
+    "the call, part=computed those its schedule computes (blocks of the "
+    "future and the part of a strip beyond the diagonal's reach left out)")
 
 
 def _snap_block(block: int, T: int, tile: int = 128) -> int:
@@ -53,8 +87,19 @@ def _snap_block(block: int, T: int, tile: int = 128) -> int:
     return T if T <= block else 0
 
 
+def one_block_a_head(bq: int, bk: int, T: int, D: int) -> bool:
+    """Whether a causal call under snapped blocks (bq, bk) runs one block
+    a head instead: where the K block already holds the whole sequence, a
+    second q block adds a grid step (1.7 us of the forward's 4.6 us a
+    head at T 1024, D 64 on the v5e) and a second, smaller staircase, and
+    saves nothing.  Measured at T 1024 and head sizes 64 and 128; the
+    whole-sequence blocks of a longer T or a wider head are not known to
+    fit VMEM, so they keep the blocks asked for."""
+    return bk == T and bq < T <= 1024 and D <= 128
+
+
 def _snap_blocks(block_q: int, block_k: int, T: int,
-                 interpret: bool = False):
+                 interpret: bool = False, causal_head: int = 0):
     """Aligned (bq, bk) for the public kernel entry points, failing with a
     clear Python error at trace time instead of a Mosaic one at run time.
     Interpret mode has no Mosaic tile contract (tests run tiny T/blocks
@@ -68,7 +113,11 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
     divisors below), then the persisted winner for this sequence
     length, then the argument defaults.  Winner pickup means a
     `paddle tune` result configures every later trace with no env
-    plumbing; the env vars remain the explicit operator override."""
+    plumbing; the env vars remain the explicit operator override.
+
+    `causal_head` is the head size of a causal call (0 for any other): on
+    the chip such a call runs one block a head where one_block_a_head
+    says so, whatever q block was asked for."""
     from ...autotune import knobs
 
     block_q, block_k = knobs.flash_blocks(block_q, block_k, T)
@@ -80,6 +129,9 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
             f"flash attention needs a 128-aligned divisor of T={T} at or "
             f"under block_q={block_q}/block_k={block_k}; use the dense "
             f"path for this shape")
+    if (causal_head and not interpret
+            and one_block_a_head(bq, bk, T, causal_head)):
+        bq = T
     return bq, bk
 
 
@@ -97,8 +149,134 @@ def _causal_kv_idx(bq: int, bk: int):
     return idx
 
 
+# ---------------------------------------------------------------------------
+# The causal walk: a grid block the diagonal crosses is computed strip by
+# strip, each strip only as far as the diagonal reaches.  Every shape in it
+# is static: a crossed block's offset from the diagonal, d = q0 - k0, takes
+# a few values for given blocks, and the body holds one walk for each.
+
+
+# q rows in a strip, as the share of the block's longer side, by kernel: what
+# the v5e read for each kernel alone (module docstring).  The forward is
+# bound by its per-row softmax bookkeeping and gains nothing from skipping,
+# so its strips are thin where thin costs nothing (0.600 ms a call at 128
+# rows, 0.634 at 256, 0.597 unsplit: T 1024, D 64); dq follows the scores
+# (0.446 / 0.453 / 0.519 / 0.648 at 128 / 256 / 512 / unsplit); dkv's
+# transposed products want tall strips (0.716 at 512, 0.749 at 128, 0.894
+# unsplit).
+_STRIPS_A_SIDE = {"flash_fwd": 8, "flash_bwd_dq": 8, "flash_bwd_dkv": 2}
+
+
+def _strip_rows(kernel: str, bq: int, bk: int) -> int:
+    """The q rows a strip of `kernel`'s walk holds: the largest divisor of
+    bq at or under the kernel's share of the block's longer side,
+    128-aligned where the block is (a strip's edge is a sublane offset
+    into the q block and a lane offset into the logsumexp row).  A
+    (1024, 1024) block is walked in strips of 128, 128 and 512 rows, a
+    test's (32, 32) block in strips of 4, 4 and 16: the same staircase at
+    every scale."""
+    target = max(bq, bk) // _STRIPS_A_SIDE[kernel]
+    step = 128 if bq % 128 == 0 else 1
+    sq = max(min(target, bq) // step, 1) * step
+    while bq % sq:
+        sq -= step
+    return sq
+
+
+def _row_strips(d: int, bq: int, bk: int, sq: int) -> tuple:
+    """The walk of a block whose first q row lies d positions after its
+    first K column: [(r0, width, masked)], a strip of q rows [r0, r0 + sq)
+    against the block's K columns [0, width), all that its last row may
+    see; `masked` where its first row may not see them all.  A strip that
+    sees nothing is left out."""
+    out = []
+    for r0 in range(0, bq, sq):
+        width = min(max(d + r0 + sq, 0), bk)
+        if width:
+            out.append((r0, width, width - 1 > d + r0))
+    return tuple(out)
+
+
+class _Plan(NamedTuple):
+    """What a causal kernel does with a [T, T] square of scores under
+    blocks (bq, bk) and strips of sq rows; hashable, it is part of what a
+    kernel call is memoized by."""
+
+    sq: int          # q rows a strip
+    walks: tuple     # ((d, _row_strips(d, ...)), ...): one for each offset
+    #                  d = q0 - k0 a block the diagonal crosses can have
+    full: bool       # some block lies wholly at or below the diagonal: the
+    #                  single-shot body is emitted only then
+    computed: int    # score elements computed: blocks of the future and the
+    #                  part of a strip beyond the diagonal's reach left out
+
+
+def _schedule(T: int, bq: int, bk: int, sq: int) -> _Plan:
+    """The _Plan of a [T, T] square under blocks (bq, bk), strips of sq."""
+    walks, full, computed = {}, False, 0
+    for q0 in range(0, T, bq):
+        for k0 in range(0, T, bk):
+            d = q0 - k0
+            if d <= -bq:
+                continue  # a block of the future
+            if d >= bk - 1:
+                full = True
+                computed += bq * bk
+                continue
+            strips = walks.setdefault(d, _row_strips(d, bq, bk, sq))
+            computed += sum(sq * width for _, width, _ in strips)
+    return _Plan(sq, tuple(sorted(walks.items())), full, computed)
+
+
+def _causal_plan(kernel: str, bh: int, T: int, bq: int, bk: int) -> _Plan:
+    """The schedule of one causal call of `kernel`, counted
+    (flash_score_elements_total) when the call is traced."""
+    plan = _schedule(T, bq, bk, _strip_rows(kernel, bq, bk))
+    _MET_SCORES.inc(bh * T * T, kernel=kernel, part="square")
+    _MET_SCORES.inc(bh * plan.computed, kernel=kernel, part="computed")
+    return plan
+
+
+def _below_diagonal(s, ahead: int):
+    """Scores whose first row lies `ahead` positions after their first
+    column, the future set to -1e30."""
+    import jax
+    import jax.numpy as jnp
+
+    lead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    return jnp.where(lead <= ahead, s, -1e30)
+
+
+def _run_block(d, bq: int, bk: int, plan, strip):
+    """Run the body of the grid block that lies d = q0 - k0 after the
+    diagonal, as calls of `strip(r0, rows, cols, ahead)`: the block's q
+    rows [r0, r0 + rows) against its K columns `cols`, masked where
+    `ahead`, how far row r0 lies after the first of `cols`, is not None.
+    A non-causal call (`plan` None) runs the single-shot body: the whole
+    block as one strip, unmasked.  A causal call runs nothing for a block
+    of the future; the single-shot body for a block wholly at or below
+    the diagonal (it has nothing to skip); and for a block the diagonal
+    crosses the strips of its walk, d static."""
+    from jax.experimental import pallas as pl
+
+    single = functools.partial(strip, 0, bq, slice(None))
+    if plan is None:
+        return single()
+
+    def walk(off, strips):
+        for r0, width, masked in strips:
+            strip(r0, plan.sq, pl.ds(0, width), off + r0 if masked else None)
+
+    if plan.full:
+        pl.when(d >= bk - 1)(single)
+    for off, strips in plan.walks:
+        pl.when(d == off)(functools.partial(walk, off, strips))
+
+
 def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-              scale: float, causal: bool, bq: int, bk: int):
+              scale: float, bq: int, bk: int, plan):
+    """`plan` is None for a non-causal call, else the call's _Plan."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -106,6 +284,7 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
+    q0, k0 = qi * bq, kj * bk
 
     @pl.when(kj == 0)
     def _init():
@@ -113,61 +292,63 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         l_sc[...] = jnp.zeros(l_sc.shape, dtype=jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
-    def _compute():
-        q = q_ref[0]  # [BQ, D] input dtype — keep bf16 for full-rate MXU
-        k = k_ref[0]  # [BK, D]
-        v = v_ref[0]
+    def tile(q, cols, carry, ahead=None):
+        """Online-softmax update of q's rows (running max, normalizer,
+        accumulator) by the K/V rows `cols` of this block; masked where
+        `ahead` says how far q's first row lies after the first of them."""
+        m_prev, l_prev, acc = carry
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
         # bf16 GEMM, f32 accumulate (full-rate MXU), then scale in f32
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        if causal:
-            # position mask is a no-op on fully-past blocks, so apply it
-            # unconditionally under causal (straddle-detection is traced)
-            q_pos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = kj * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+            preferred_element_type=jnp.float32) * scale
+        if ahead is not None:
+            s = _below_diagonal(s, ahead)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * corr + p.sum(axis=-1)
-        m_sc[...] = m_new
+        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_sc[...] = acc_sc[...] * corr[:, None] + pv
+        return m_new, l_new, acc * corr + pv
 
-    if causal:
-        pl.when(kj * bk < (qi + 1) * bq)(_compute)
-    else:
-        _compute()
+    def update(r0, rows, cols, ahead=None):
+        at = pl.ds(r0, rows)
+        # q stays in its input dtype: bf16 keeps the MXU at full rate
+        m, l, acc = tile(q_ref[0, at, :], cols,
+                         (m_sc[at, :], l_sc[at, :], acc_sc[at, :]), ahead)
+        m_sc[at, :] = m
+        l_sc[at, :] = l
+        acc_sc[at, :] = acc
+
+    _run_block(q0 - k0, bq, bk, plan, update)
 
     @pl.when(kj == nk - 1)
     def _finish():
-        o_ref[0] = (acc_sc[...] / l_sc[...][:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
         if lse_ref is not None:
-            lse_ref[0, 0, pl.ds(qi * bq, bq)] = (
-                m_sc[...] + jnp.log(l_sc[...]))
+            lse_ref[0, 0, pl.ds(q0, bq)] = (
+                m_sc[...] + jnp.log(l_sc[...]))[:, 0]
 
 
 def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, **kw):
     _fwd_body(q_ref, k_ref, v_ref, o_ref, None, m_sc, l_sc, acc_sc, **kw)
 
 
-def _fwd_grid(B, H, T, D, bq, bk, causal, with_lse, dtype, interpret,
-              scale):
-    """Shared pallas_call plumbing for the two forward entry points."""
+@functools.lru_cache(maxsize=None)
+def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale):
+    """The forward kernel's call on [BH, T, D] operands, for both forward
+    entry points.  Memoized and jitted: every layer of a model makes the
+    same call, and one callable lets jit trace the kernel body and lower
+    it to Mosaic once a step program instead of once a layer."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nk = T // bk
-
-    if causal:
+    if plan is not None:
         kv_idx = _causal_kv_idx(bq, bk)
     else:
         def kv_idx(b, i, j):
@@ -179,20 +360,22 @@ def _fwd_grid(B, H, T, D, bq, bk, causal, with_lse, dtype, interpret,
         pl.BlockSpec((1, bk, D), kv_idx),
     ]
     out_specs = [pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((B * H, T, D), dtype)]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, D), dtype)]
     kern = _fwd_body if with_lse else _fwd_nolse
     if with_lse:
         out_specs.append(pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32))
-    return pl.pallas_call(
-        functools.partial(kern, scale=scale, causal=causal, bq=bq, bk=bk),
-        grid=(B * H, T // bq, nk),
+        out_shape.append(jax.ShapeDtypeStruct((BH, 1, T), jnp.float32))
+    return jax.jit(pl.pallas_call(
+        functools.partial(kern, scale=scale, bq=bq, bk=bk, plan=plan),
+        grid=(BH, T // bq, T // bk),
         in_specs=in_specs,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            # the running max and normalizer as columns: they meet the
+            # score rows as [rows, 1] with no relayout
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         # with_lse revisits the SHARED (b,0,0) lse row block across the i
@@ -205,7 +388,19 @@ def _fwd_grid(B, H, T, D, bq, bk, causal, with_lse, dtype, interpret,
                 "arbitrary")),
         name="flash_fwd",
         interpret=interpret,
-    )
+    ))
+
+
+def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
+    """flash_attention's and flash_attention_fwd's shared way to _fwd_call:
+    the output(s) on [B*H, T, D]."""
+    B, H, T, D = q.shape
+    bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
+    s = scale if scale is not None else 1.0 / (D ** 0.5)
+    plan = _causal_plan("flash_fwd", B * H, T, bq, bk) if causal else None
+    return _fwd_call(B * H, T, D, bq, bk, plan, with_lse, q.dtype,
+                     interpret, s)(*(a.reshape(B * H, T, D)
+                                     for a in (q, k, v)))
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
@@ -213,16 +408,9 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
                     interpret: bool = False):
     """q,k,v [B,H,T,D] → [B,H,T,D]. block_q/block_k are performance hints,
     snapped down to divisors of T; D ≤ 128 recommended (one lane tile)."""
-    B, H, T, D = q.shape
-    bq, bk = _snap_blocks(block_q, block_k, T, interpret)
-    s = scale if scale is not None else 1.0 / (D ** 0.5)
-
-    qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, T, D)
-    vf = v.reshape(B * H, T, D)
-    out = _fwd_grid(B, H, T, D, bq, bk, causal, False, q.dtype,
-                    interpret, s)(qf, kf, vf)
-    return out.reshape(B, H, T, D)
+    out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   False)
+    return out.reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +418,20 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 # style recompute — P is never materialized in HBM in either direction).
 
 
+def _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows: int):
+    """(q, dO, logsumexp, delta) of this q block's rows [r0, r0 + rows).
+    lse/delta arrive as (1, 1, T) full-row blocks (Mosaic tile contract,
+    see module docstring) and leave as [rows, 1] columns."""
+    from jax.experimental import pallas as pl
+
+    at = pl.ds(r0, rows)
+    row = pl.ds(q0 + r0, rows)
+    return (q_ref[0, at, :], do_ref[0, at, :],
+            lse_ref[0, 0, row][:, None], delta_ref[0, 0, row][:, None])
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_sc, *, scale: float, causal: bool, bq: int, bk: int):
+               acc_sc, *, scale: float, bq: int, bk: int, plan):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -239,42 +439,40 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
+    q0, k0 = qi * bq, kj * bk
 
     @pl.when(kj == 0)
     def _init():
         acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
-    def _compute():
-        q = q_ref[0]
-        do = do_ref[0]  # consumed at v.dtype by the dp GEMM
-        # lse/delta arrive as (1, 1, T) full-row blocks (Mosaic tile
-        # contract, see module docstring); slice this program's bq rows
-        lse = lse_ref[0, 0, pl.ds(qi * bq, bq)]
-        delta = delta_ref[0, 0, pl.ds(qi * bq, bq)]
-        k = k_ref[0]
-        v = v_ref[0]
+    def tile(rows, cols, acc, ahead=None):
+        """dq of `rows` (from _q_rows) gathered over the K/V rows `cols`
+        of this block, added to acc; masked where `ahead` says how far
+        the first of `rows` lies after the first of `cols`."""
+        q, do, lse, delta = rows  # dO is consumed at v.dtype by the dp GEMM
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = kj * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse[:, None])  # true softmax probs via saved lse
+        if ahead is not None:
+            s = _below_diagonal(s, ahead)
+        p = jnp.exp(s - lse)  # true softmax probs via saved lse
         dp = jax.lax.dot_general(
             do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        acc_sc[...] = acc_sc[...] + jax.lax.dot_general(
+        ds = p * (dp - delta) * scale
+        return acc + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kj * bk < (qi + 1) * bq)(_compute)
-    else:
-        _compute()
+    def update(r0, rows, cols, ahead=None):
+        at = pl.ds(r0, rows)
+        acc_sc[at, :] = tile(
+            _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows), cols,
+            acc_sc[at, :], ahead)
+
+    _run_block(q0 - k0, bq, bk, plan, update)
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -283,7 +481,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                causal: bool, bq: int, bk: int):
+                bq: int, bk: int, plan):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -291,45 +489,46 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    q0, k0 = qi * bq, kj * bk
 
     @pl.when(qi == 0)
     def _init():
         dk_sc[...] = jnp.zeros(dk_sc.shape, dtype=jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, dtype=jnp.float32)
 
-    def _compute():
-        k = k_ref[0]  # [BK, D]
-        v = v_ref[0]
-        q = q_ref[0]  # [BQ, D]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * bq, bq)]
-        delta = delta_ref[0, 0, pl.ds(qi * bq, bq)]
+    def tile(cols, rows, carry, ahead=None):
+        """(dk, dv) of the K/V rows `cols` of this block gathered over the
+        q rows `rows` (from _q_rows), added to carry; masked where `ahead`
+        says how far the first of `rows` lies after the first of `cols`."""
+        dk, dv = carry
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        q, do, lse, delta = rows
+        do = do.astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = kj * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse[:, None])
-        dv_sc[...] = dv_sc[...] + jax.lax.dot_general(
+            preferred_element_type=jnp.float32) * scale
+        if ahead is not None:
+            s = _below_diagonal(s, ahead)
+        p = jnp.exp(s - lse)
+        dv = dv + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk_sc[...] = dk_sc[...] + jax.lax.dot_general(
+        ds = p * (dp - delta) * scale
+        dk = dk + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        return dk, dv
 
-    if causal:
-        # a q block contributes iff its last row reaches this k block
-        pl.when((qi + 1) * bq > kj * bk)(_compute)
-    else:
-        _compute()
+    def update(r0, rows, cols, ahead=None):
+        dk_sc[cols, :], dv_sc[cols, :] = tile(
+            cols, _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows),
+            (dk_sc[cols, :], dv_sc[cols, :]), ahead)
+
+    _run_block(q0 - k0, bq, bk, plan, update)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -340,35 +539,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
                         block_k=1024, interpret=False):
     """Forward that also returns the per-row logsumexp (backward residual)."""
-    B, H, T, D = q.shape
-    bq, bk = _snap_blocks(block_q, block_k, T, interpret)
-    s = scale if scale is not None else 1.0 / (D ** 0.5)
-    qf, kf, vf = (a.reshape(B * H, T, D) for a in (q, k, v))
-    out, lse = _fwd_grid(B, H, T, D, bq, bk, causal, True, q.dtype,
-                         interpret, s)(qf, kf, vf)
-    return out.reshape(B, H, T, D), lse.reshape(B * H, T)
+    B, H, T, _D = q.shape
+    out, lse = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                        True)
+    return out.reshape(q.shape), lse.reshape(B * H, T)
 
 
-def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
-                        block_q=512, block_k=1024, interpret=False):
+@functools.lru_cache(maxsize=None)
+def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale):
+    """(dq call, dkv call) on [BH, T, D] operands and (BH, 1, T) lse and
+    delta rows; memoized and jitted like _fwd_call."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, T, D = q.shape
-    bq, bk = _snap_blocks(block_q, block_k, T, interpret)
-    s = scale if scale is not None else 1.0 / (D ** 0.5)
-    qf, kf, vf, of, dof = (a.reshape(B * H, T, D)
-                           for a in (q, k, v, o, do))
-    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
-                    axis=-1)  # [BH, T]
-    # (BH, 1, T) full-row layout for lse/delta: see module docstring
-    lse3 = lse.reshape(B * H, 1, T).astype(jnp.float32)
-    delta3 = delta.reshape(B * H, 1, T)
     row_spec = pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0))
 
-    if causal:
+    if dq_plan is not None:
         kv_idx = _causal_kv_idx(bq, bk)
 
         def q_idx(b, j, i):
@@ -382,9 +570,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
             return (b, i, 0)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=s, causal=causal, bq=bq,
-                          bk=bk),
-        grid=(B * H, T // bq, T // bk),
+        functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
+                          plan=dq_plan),
+        grid=(BH, T // bq, T // bk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), kv_idx),
@@ -394,18 +582,17 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
             row_spec,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, T, D), dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_bwd_dq",
         interpret=interpret,
-    )(qf, kf, vf, dof, lse3, delta3)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=s, causal=causal, bq=bq,
-                          bk=bk),
-        grid=(B * H, T // bk, T // bq),
+    )
+    dkv = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
+                          plan=dkv_plan),
+        grid=(BH, T // bk, T // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), q_idx),
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
@@ -419,8 +606,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, T, D), dtype),
+            jax.ShapeDtypeStruct((BH, T, D), dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
@@ -428,9 +615,33 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_bwd_dkv",
         interpret=interpret,
-    )(qf, kf, vf, dof, lse3, delta3)
-    rs = lambda a: a.reshape(B, H, T, D)
-    return rs(dq), rs(dk), rs(dv)
+    )
+    return jax.jit(dq), jax.jit(dkv)
+
+
+def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
+                        block_q=512, block_k=1024, interpret=False):
+    import jax.numpy as jnp
+
+    B, H, T, D = q.shape
+    bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
+    s = scale if scale is not None else 1.0 / (D ** 0.5)
+    qf, kf, vf, of, dof = (a.reshape(B * H, T, D)
+                           for a in (q, k, v, o, do))
+    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
+                    axis=-1)  # [BH, T]
+    # (BH, 1, T) full-row layout for lse/delta: see module docstring
+    lse3 = lse.reshape(B * H, 1, T).astype(jnp.float32)
+    delta3 = delta.reshape(B * H, 1, T)
+    dq_plan = dkv_plan = None
+    if causal:
+        dq_plan = _causal_plan("flash_bwd_dq", B * H, T, bq, bk)
+        dkv_plan = _causal_plan("flash_bwd_dkv", B * H, T, bq, bk)
+    dq_call, dkv_call = _bwd_calls(B * H, T, D, bq, bk, dq_plan, dkv_plan,
+                                   q.dtype, interpret, s)
+    dq = dq_call(qf, kf, vf, dof, lse3, delta3)
+    dk, dv = dkv_call(qf, kf, vf, dof, lse3, delta3)
+    return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(q.shape)
 
 
 _TRAIN_CACHE = {}

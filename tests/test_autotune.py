@@ -183,13 +183,13 @@ def test_flash_kernel_uses_store_winner(monkeypatch):
     store.default_store().record("flash_attention", {"T": 32}, dk, be,
                                  {"block_q": 16, "block_k": 16})
     seen = {}
-    real = fa._fwd_grid
+    real = fa._fwd_call
 
-    def spy(B, H, T, D, bq, bk, *a, **kw):
+    def spy(BH, T, D, bq, bk, *a, **kw):
         seen["blocks"] = (bq, bk)
-        return real(B, H, T, D, bq, bk, *a, **kw)
+        return real(BH, T, D, bq, bk, *a, **kw)
 
-    monkeypatch.setattr(fa, "_fwd_grid", spy)
+    monkeypatch.setattr(fa, "_fwd_call", spy)
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 1, 32, 8).astype(np.float32))
     out = fa.flash_attention(q, q, q, causal=True, interpret=True)

@@ -178,6 +178,78 @@ def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):
 
 
 # ---------------------------------------------------------------------------
+# flash_score_elements_total: how much of the square the causal kernels do
+
+SCORES = "flash_score_elements_total"
+
+
+def _scores() -> dict:
+    """{(kernel, part): elements} of the counter's series."""
+    fam = obs.REGISTRY.snapshot()["families"].get(SCORES)
+    return {(s["labels"]["kernel"], s["labels"]["part"]): s["value"]
+            for s in (fam["series"] if fam else [])}
+
+
+def test_score_counter_is_the_schedules_geometry():
+    """Tracing the three kernels at GPT-2-medium's shape (T 1024 under the
+    default blocks) counts the square and what the strips of the walk
+    compute of it: at most 75% a kernel, and what `_schedule` says."""
+    import jax
+    import jax.numpy as jnp
+
+    fluid.reset()
+    B, H, T, D = 2, 4, 1024, 64
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32)
+    jax.eval_shape(lambda q, k, v: fa.flash_attention_fwd(
+        q, k, v, causal=True), x, x, x)
+    jax.eval_shape(lambda q, k, v, o, l, do: fa.flash_attention_bwd(
+        q, k, v, o, l, do, causal=True), x, x, x, x, lse, x)
+    got = _scores()
+    # on the chip T 1024 runs one block a head; the tracing here is the
+    # CPU's and keeps the (512, 1024) asked for only in interpret mode
+    bq, bk = fa._snap_blocks(512, 1024, T, causal_head=D)
+    assert (bq, bk) == (1024, 1024)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        plan = fa._schedule(T, bq, bk, fa._strip_rows(kernel, bq, bk))
+        assert T * T / 2 < plan.computed <= 0.75 * T * T
+        assert got[(kernel, "square")] == B * H * T * T
+        assert got[(kernel, "computed")] == B * H * plan.computed
+    assert len(got) == 6
+    assert (sum(v for (_, part), v in got.items() if part == "computed")
+            / sum(v for (_, part), v in got.items() if part == "square")
+            ) == 0.625
+
+    # a non-causal call has no half to skip: the family gets nothing
+    fluid.reset()
+    jax.eval_shape(lambda q, k, v: fa.flash_attention_fwd(q, k, v), x, x, x)
+    jax.eval_shape(lambda q, k, v: fa.flash_attention(q, k, v), x, x, x)
+    jax.eval_shape(lambda q, k, v, o, l, do: fa.flash_attention_bwd(
+        q, k, v, o, l, do), x, x, x, x, lse, x)
+    assert _scores() == {}
+
+
+def test_score_counter_counts_at_trace_time_only(pallas_on_cpu):
+    """Counted when the step is traced, once a compile: a second run of
+    the compiled step adds nothing."""
+    loss, grads, feed = _attention_block()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    exe.run(feed=feed, fetch_list=[loss])
+    first = _scores()
+    assert {k for k, _part in first} == {"flash_fwd", "flash_bwd_dq",
+                                         "flash_bwd_dkv"}
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        # T 128 under (64, 64) blocks: three of four blocks visited, the
+        # two crossed ones walked in strips of an eighth (a half for dkv)
+        share = 0.75 if kernel == "flash_bwd_dkv" else 0.5625
+        assert (first[(kernel, "computed")] / first[(kernel, "square")]
+                == (1 + 2 * share) / 4)
+    exe.run(feed=feed, fetch_list=[loss])
+    assert _scores() == first
+
+
+# ---------------------------------------------------------------------------
 # AOT: the real kernels, compiled for a described v5e
 
 
@@ -255,3 +327,41 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     assert kinds == {"flash_fwd": layers, "flash_bwd_dq": layers,
                      "flash_bwd_dkv": layers}, calls
     assert _counter() == {(SDPA, "1"): float(layers)}
+
+
+# B, H, T, D of the cells' attention: GPT-2-medium at batch 8, OLMoE at 1
+@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (1, 16, 4096, 128)],
+                         ids=["gpt2m_train_bs8", "olmoe_train_t4096"])
+def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
+    """The three kernels with their walks, bf16 under the default blocks
+    and x64 off as the chip runs them, through Mosaic for the described
+    v5e: a strip's edge it cannot align (the lane offset into the
+    (1, 1, T) logsumexp row, a sublane offset into a K block) fails here
+    and not first on the chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    B, H, T, D = shape
+    one = SingleDeviceSharding(v5e)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32, sharding=one)
+    bq, bk = fa._snap_blocks(512, 1024, T, causal_head=D)
+    assert (bq, bk) == ((1024, 1024) if T == 1024 else (512, 1024))
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        plan = fa._schedule(T, bq, bk, fa._strip_rows(kernel, bq, bk))
+        assert plan.computed <= T * T * 0.75
+    with jax.enable_x64(False):
+        calls = {
+            "flash_fwd": jax.jit(lambda q, k, v: fa.flash_attention_fwd(
+                q, k, v, causal=True)).lower(x, x, x),
+            "flash_fwd_nolse": jax.jit(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True)).lower(x, x, x),
+            "flash_bwd": jax.jit(
+                lambda q, k, v, o, l, do: fa.flash_attention_bwd(
+                    q, k, v, o, l, do, causal=True)).lower(
+                        x, x, x, x, lse, x)}
+        for name, lowered in calls.items():
+            text = lowered.compile().as_text()
+            assert text.count('custom_call_target="tpu_custom_call"') == (
+                2 if name == "flash_bwd" else 1), name

@@ -642,3 +642,185 @@ def test_program_errors_propagate():
                     fetch_list=[y])
     finally:
         fluid.reset()
+
+
+# ---------------------------------------------------------------------------
+# The causal walk: a grid block the diagonal crosses is computed in strips
+# of q rows, each only as far as the diagonal reaches.
+
+
+def _dense_f32(q, k, v, causal):
+    T, D = q.shape[2], q.shape[3]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.float32(D ** 0.5)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+    return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
+def _check_walk(T, bq, bk, causal, seed=0):
+    """out, lse, dq, dk, dv of the three kernels against dense float32
+    attention and its gradients."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    B, H, D = 1, 2, 16
+    rng = np.random.RandomState(seed)
+    q, k, v, do = (jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
+                   for _ in range(4))
+    kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
+    out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+    dq, dk, dv = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
+    want_out, want_lse = _dense_f32(q, k, v, causal)
+    grads = jax.vjp(lambda *a: _dense_f32(*a, causal)[0], q, k, v)[1](do)
+    got = dict(out=out, lse=lse.reshape(B, H, T), dq=dq, dk=dk, dv=dv,
+               nolse=fa.flash_attention(q, k, v, **kw))
+    want = dict(out=want_out, lse=want_lse, dq=grads[0], dk=grads[1],
+                dv=grads[2], nolse=want_out)
+    for name in got:
+        np.testing.assert_allclose(
+            np.asarray(got[name]), np.asarray(want[name]), atol=2e-5,
+            rtol=2e-5, err_msg=name)
+
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@pytest.fixture
+def walk_spy(monkeypatch):
+    """The plan each kernel body is traced with, None for a non-causal
+    one (the memoized calls forgotten first, so every body is traced
+    here)."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    seen, real = [], fa._run_block
+
+    def run_block(d, bq, bk, plan, strip):
+        seen.append(plan)
+        return real(d, bq, bk, plan, strip)
+
+    fa._fwd_call.cache_clear()
+    fa._bwd_calls.cache_clear()
+    monkeypatch.setattr(fa, "_run_block", run_block)
+    return seen
+
+
+# (T, bq, bk, causal, rows a strip or None for each kernel's own): the
+# block geometries the cells and the callers produce, a thirty-second
+# their size
+WALK_CASES = {
+    "gpt2m_one_block_a_head": (32, 32, 32, True, None),
+    "two_q_blocks_over_one_k_block": (32, 16, 32, True, None),
+    "olmoe_8x4_full_crossed_and_skipped": (128, 16, 32, True, None),
+    "bq_above_bk": (64, 32, 16, True, None),
+    "square_blocks_several": (64, 16, 16, True, None),
+    "strips_of_one_row": (32, 16, 32, True, 1),
+    "block_is_one_strip": (64, 16, 16, True, 16),
+    "non_causal_single_shot": (32, 16, 32, False, None),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_flash_causal_walk_matches_dense(case, walk_spy, monkeypatch):
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    T, bq, bk, causal, rows = WALK_CASES[case]
+    if rows is not None:
+        monkeypatch.setattr(fa, "_strip_rows", lambda *a: rows)
+    _check_walk(T, bq, bk, causal)
+    if not causal:
+        # no plan in any of the four bodies: the single-shot body ran
+        assert walk_spy == [None] * 4
+        return
+    # flash_attention_fwd, dq, dkv, then flash_attention (no logsumexp)
+    plans = [fa._schedule(T, bq, bk, fa._strip_rows(kernel, bq, bk))
+             for kernel in KERNELS + ("flash_fwd",)]
+    assert walk_spy == plans
+    for plan in plans:
+        assert bq % plan.sq == 0 and plan.walks
+        # the single-shot body is emitted only where some block lies wholly
+        # below the diagonal: the last q block against the first K block
+        assert plan.full == (T - bq >= bk - 1)
+    if case == "gpt2m_one_block_a_head":
+        # (1024, 1024) at T 1024, a thirty-second: the forward and dq in
+        # strips of 4 rows that see 4, 8, ... 32 columns, dkv in two of 16
+        assert plans[0].walks == (
+            (0, tuple((4 * i, 4 * i + 4, True) for i in range(8))),)
+        assert plans[2].walks == ((0, ((0, 16, True), (16, 32, True))),)
+    if case == "olmoe_8x4_full_crossed_and_skipped":
+        # (512, 1024) at T 4096: crossed blocks at d = 0 and d = 512, the
+        # last strip of the second reaching the block's whole width
+        assert [d for d, _ in plans[0].walks] == [0, 16]
+        assert plans[0].walks[1][1][-1] == (12, 32, True)
+        assert plans[2].walks == ((0, ((0, 16, True),)),
+                                  (16, ((0, 32, True),)))
+
+
+@pytest.mark.parametrize("mutant", ["stops_a_strip_short", "unmasked",
+                                    "skips_the_last_strip"])
+@pytest.mark.parametrize("case", ["gpt2m_one_block_a_head",
+                                  "olmoe_8x4_full_crossed_and_skipped"])
+def test_flash_causal_walk_mutants_fail(case, mutant, monkeypatch):
+    """A strip that stops short is the walk's likeliest bug: a thickness
+    of scores dropped, or a crossed strip taken for a clear one, moves the
+    outputs by far more than rounding."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    real = fa._row_strips
+
+    def strips(d, bq, bk, sq):
+        out = real(d, bq, bk, sq)
+        if mutant == "stops_a_strip_short":
+            return tuple((r0, w - min(sq, w - 1), m) for r0, w, m in out)
+        if mutant == "unmasked":
+            return tuple((r0, w, False) for r0, w, _ in out)
+        return out[:-1] if len(out) > 1 else out
+
+    T, bq, bk, causal, _rows = WALK_CASES[case]
+    _check_walk(T, bq, bk, causal)  # the walk as it is passes
+    monkeypatch.setattr(fa, "_row_strips", strips)
+    with pytest.raises(AssertionError):
+        _check_walk(T, bq, bk, causal)
+
+
+@pytest.mark.parametrize("geometry", [
+    (1024, 1024, 1024), (1024, 512, 1024), (4096, 512, 1024),
+    (4096, 1024, 1024), (2048, 256, 512), (1536, 512, 768), (64, 32, 16),
+    (128, 16, 32)])
+def test_flash_schedule_counts_what_the_strips_compute(geometry):
+    """`_schedule` (what flash_score_elements_total counts) against a brute
+    count position by position: every score at or below the diagonal lies
+    in exactly one strip's reach; what is computed beyond the causal half
+    is the staircase above the diagonal, never wider than a strip is
+    tall."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    T, bq, bk = geometry
+    half = np.tril(np.ones((T, T), bool))
+    shares = {}
+    for kernel in KERNELS:
+        sq = fa._strip_rows(kernel, bq, bk)
+        plan = fa._schedule(T, bq, bk, sq)
+        walked = np.zeros((T, T), np.int32)
+        for q0 in range(0, T, bq):
+            for k0 in range(0, T, bk):
+                d = q0 - k0
+                if d <= -bq:
+                    continue
+                if d >= bk - 1:
+                    assert plan.full
+                    walked[q0:q0 + bq, k0:k0 + bk] += 1
+                    continue
+                assert dict(plan.walks)[d] == fa._row_strips(d, bq, bk, sq)
+                for r0, width, masked in fa._row_strips(d, bq, bk, sq):
+                    walked[q0 + r0:q0 + r0 + sq, k0:k0 + width] += 1
+                    assert masked == (k0 + width - 1 > q0 + r0)
+        assert walked.max() == 1 and (walked[half] == 1).all()
+        assert plan.computed == walked.sum()
+        beyond = np.argwhere(walked.astype(bool) & ~half)
+        assert (beyond[:, 1] - beyond[:, 0] < sq).all()
+        shares[kernel] = plan.computed / (T * T)
+    if geometry == (1024, 1024, 1024):  # gpt2m_train_bs8
+        assert shares == {"flash_fwd": 0.5625, "flash_bwd_dq": 0.5625,
+                          "flash_bwd_dkv": 0.75}
+    if geometry == (4096, 512, 1024):  # olmoe_train_t4096
+        assert all(v <= 0.5625 for v in shares.values())
