@@ -227,8 +227,8 @@ def phase_recurrent_train(place, log: _CompileLog, cell: str = "lstm",
                           batch_size: int = 64, hidden: int = 512,
                           seq_len: int = 96, vocab: int = 30000,
                           steps: int = 3) -> dict:
-    """The stacked recurrent text classifier as bench.py's
-    bench_lstm_train builds it (2 x LSTM, the second reversed; bf16), or
+    """The stacked recurrent text classifier (2 x LSTM, the second
+    reversed; bf16; batch 64, 96 steps, hidden 512, vocabulary 30000), or
     the same tower with GRU cells.  The compiled step holds the fused
     forward and BPTT kernels."""
     import numpy as np
@@ -281,9 +281,9 @@ def phase_lm_train(place, log: _CompileLog, batch_size: int = 8,
                    seq_len: int = 1024, dim: int = 512, n_layers: int = 8,
                    n_heads: int = 8, vocab: int = 32000,
                    steps: int = 3) -> dict:
-    """Decoder-only LM train as bench.py's bench_gpt_train builds it
-    (bf16); the compiled step holds the flash-attention forward and
-    backward kernels."""
+    """Decoder-only LM train (bf16; 8 x 1024 tokens, 8 layers of width
+    512, vocabulary 32000); the compiled step holds the flash-attention
+    forward and backward kernels."""
     import numpy as np
 
     import paddle_tpu as fluid

@@ -7,9 +7,8 @@ signature, device kind, backend) — i.e. a previous ``paddle tune`` of
 this program on this hardware — the winner's program-level decisions
 are re-applied: today that is the desc-level blanket remat marking
 (attrs-only, the same ``memory_optimize(level=1)`` the trial that won
-was measured with).  Kernel-level winners (flash blocks, bn-conv
-variant, page size) need nothing here: the knobs resolve them from the
-store at trace time.
+was measured with).  Kernel-level winners (flash blocks, page size)
+need nothing here: the knobs resolve them from the store at trace time.
 
 Cost discipline (this sits on Executor.run):
 

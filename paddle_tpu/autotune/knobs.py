@@ -9,7 +9,7 @@ Resolution order, strongest first:
      candidate's parameters for the duration of one trial
      (:func:`trial_overrides`); nothing may shadow the A/B being run;
   2. **environment** — the explicit operator override layer
-     (PADDLE_TPU_FLASH_BQ/BK, PADDLE_TPU_BNCONV_VARIANT, ...).  Values
+     (PADDLE_TPU_FLASH_BQ/BK, PADDLE_TPU_PAGE_SIZE, ...).  Values
      are VALIDATED here: garbage raises a clear error naming the
      variable instead of feeding ``int('x')`` tracebacks (or silent
      defaults) into a trace;
@@ -18,8 +18,7 @@ Resolution order, strongest first:
   4. the caller's **default**.
 
 Knob names are dotted ``<namespace>.<field>`` strings; the namespace is
-also the store's kernel-site kind (``flash_attention``, ``bn_conv``,
-``paged_attention``).
+also the store's kernel-site kind (``flash_attention``, ``paged_attention``).
 """
 
 from __future__ import annotations
@@ -142,50 +141,6 @@ def flash_blocks(block_q: int, block_k: int, T: int) -> Tuple[int, int]:
             bk = w.get("block_k")
     return (int(bq) if bq else int(block_q),
             int(bk) if bk else int(block_k))
-
-
-_BNCONV_VARIANTS = ("v1", "v2", "reference")
-
-
-def bnconv_variant() -> str:
-    """bn-conv 3x3 forward implementation: "v1" (whole-image nine-tap),
-    "v2" (O-blocked pipelined grid — the r5 attempt, now a first-class
-    tunable variant per the >=1.0x-or-delete contract), or "reference"
-    (unfused jnp path).  Trial override > PADDLE_TPU_BNCONV_VARIANT >
-    legacy PADDLE_TPU_BNCONV_V2=1 > stored winner > "v1"."""
-    v = _trial_value("bn_conv.variant")
-    if v is None:
-        raw = os.environ.get("PADDLE_TPU_BNCONV_VARIANT")
-        if raw not in (None, ""):
-            if raw not in _BNCONV_VARIANTS:
-                raise ValueError(
-                    f"PADDLE_TPU_BNCONV_VARIANT={raw!r}: use one of "
-                    f"{_BNCONV_VARIANTS}")
-            v = raw
-        elif os.environ.get("PADDLE_TPU_BNCONV_V2") == "1":
-            v = "v2"  # the r5 A/B env knob, kept as an explicit override
-    if v is None:
-        v = _site_winner("bn_conv", {}).get("variant")
-    v = v or "v1"
-    if v not in _BNCONV_VARIANTS:
-        raise ValueError(f"bn_conv.variant {v!r}: use one of "
-                         f"{_BNCONV_VARIANTS}")
-    return v
-
-
-def bnconv_block_o() -> int:
-    """Explicit v2 weight O-block override (0 = let the kernel pick).
-    Trial override > PADDLE_TPU_BNCONV_BO (validated; "0" is the
-    documented no-override sentinel, not an error) > stored winner >
-    0."""
-    v = _trial_value("bn_conv.block_o")
-    if v is None:
-        if os.environ.get("PADDLE_TPU_BNCONV_BO") == "0":
-            return 0  # pre-knob sentinel: defer to the kernel heuristic
-        v = _env_int("PADDLE_TPU_BNCONV_BO", "bn-conv v2 weight O-block")
-    if v is None:
-        v = _site_winner("bn_conv", {}).get("block_o")
-    return int(v or 0)
 
 
 def paged_page_size(default: int = 16) -> int:

@@ -1,12 +1,12 @@
 """Measurement harness: compile + time the predicted-top-k candidates.
 
-Reuses bench.py's ``_timed_loop`` discipline — warmup runs first (the
-compile is never timed), then ``repeats`` passes of ``iters`` steps
-each, completion by VALUE fetch, best-of-N as the capability number with every pass
-recorded (median is the honest steady-state headline; the spread
-between them is exactly the 6.97-vs-9.89 ms LSTM ambiguity, so both are
-first-class fields).  Donation is the executor's: program runners step
-through ``Executor.run`` with state donated as in production.
+The discipline: warmup runs first (the compile is never timed), then
+``repeats`` passes of ``iters`` steps each, completion by VALUE fetch,
+best-of-N as the capability number with every pass recorded (median is
+the honest steady-state headline; the spread between them is exactly
+the 6.97-vs-9.89 ms LSTM ambiguity, so both are first-class fields).
+Donation is the executor's: program runners step through
+``Executor.run`` with state donated as in production.
 
 Every trial runs inside ``knobs.trial_overrides`` pinning the
 candidate's kernel parameters (resolution order's top layer) and a

@@ -152,18 +152,6 @@ def flash_space(T: int, remat: bool = True,
     return SearchSpace(axes)
 
 
-def bn_conv_space(O: int = 256) -> SearchSpace:
-    """bn-conv 3x3 kernel space: implementation variant (the v2
-    >=1.0x-or-delete contract made explicit: v2 competes as a
-    first-class search-space member) x v2 weight O-block."""
-    blocks = [0]  # 0 = kernel's own heuristic
-    blocks += [b for b in (128, 256) if O % b == 0]
-    return SearchSpace([
-        Choice("bn_conv.variant", ("v1", "v2", "reference")),
-        Choice("bn_conv.block_o", tuple(dict.fromkeys(blocks))),
-    ])
-
-
 def paged_space(max_ctx: int = 1024) -> SearchSpace:
     """Paged-attention tile space: tokens per KV page (the decode
     kernel's K/V tile and the allocator's granularity)."""

@@ -11,7 +11,7 @@ One call to :func:`tune` is the whole ISSUE 14 pipeline:
      always — the winner is only a winner against the measured
      baseline), each under trial overrides + tracer spans;
   4. pick the measured best, persist it (program entry + desc-only
-     entry + per-kernel-site entries so the flash/bn-conv knobs and
+     entry + per-kernel-site entries so the flash/page-size knobs and
      ``build_callable`` pick it up transparently), and report the
      prior's rank error — the number that calibrates the cost model.
 """
@@ -116,7 +116,7 @@ def tune(workload, measurer=None, top_k: int = 5,
     if desc_site != site:
         st.record("program_desc", desc_site, device_kind, backend,
                   winner=winner_row["params"], **meta)
-    # kernel-site entries: the transparent pickup the flash/bn-conv
+    # kernel-site entries: the transparent pickup the flash/page-size
     # knob resolution reads on the next trace
     for ns, ksite, fields in workload.kernel_sites():
         kwin = {field: winner_row["params"][knob]

@@ -9,9 +9,8 @@ Two shapes:
     touched).  The ``remat`` axis applies the desc-level blanket
     rematerialization pass to the built program, which is exactly what
     the executor's winner pickup (integration.py) re-applies later.
-  * :class:`BnConvWorkload` — a kernel microbench (the bn-conv 3x3
-    variant A/B of the >=1.0x-or-delete contract): candidates select the
-    implementation variant, the runner asserts parity against the jnp
+  * :class:`PagedDecodeWorkload` — a kernel microbench: candidates
+    select the kernel's tile, the runner asserts parity against the jnp
     reference BEFORE timing (a fast wrong kernel must never win).
 
 Named registry at the bottom (``WORKLOADS``) — the `paddle tune MODEL`
@@ -42,9 +41,9 @@ class Built:
 
 
 class _ProgramRunner:
-    """Measurement runner with the bench.py `_timed_loop` staging
-    discipline: feed staged to the device ONCE (the compute-path
-    number), state donated by the executor, completion by value fetch."""
+    """Measurement runner: feed staged to the device ONCE (the
+    compute-path number), state donated by the executor, completion by
+    value fetch."""
 
     def __init__(self, built: Built):
         import jax
@@ -344,7 +343,7 @@ class MlpDepthWorkload(ProgramWorkload):
 
 
 # ---------------------------------------------------------------------------
-# bn-conv kernel workload (the v2 >=1.0x-or-delete contract, executed)
+# kernel workloads
 
 
 class _KernelRunner:
@@ -364,96 +363,6 @@ class _KernelRunner:
 
     def close(self):
         pass
-
-
-class BnConvWorkload:
-    """bn(+act)+conv3x3 forward variants (v1 whole-image / v2 O-blocked
-    / unfused reference) on one fixed training-shape tile.  On CPU the
-    Pallas variants run in interpret mode — parity there is the
-    correctness half of the r5 contract; the timing half that DECIDES
-    v1-vs-v2 is the on-chip `autotune_sweep`/`kernels_bnconv_v2`
-    capture (interpret-mode timing measures the interpreter)."""
-
-    kind = "kernel"
-    name = "bn_conv"
-
-    def __init__(self, N=2, H=8, W=8, K=128, O=256):
-        self.shape = (N, H, W, K, O)
-
-    def space(self) -> _space.SearchSpace:
-        return _space.bn_conv_space(O=self.shape[4])
-
-    def site(self) -> dict:
-        N, H, W, K, O = self.shape
-        return {"workload": self.name,
-                "x": [N, H, W, K], "w": [3, 3, K, O],
-                "dtype": "float32"}
-
-    def kernel_sites(self) -> Tuple:
-        return (("bn_conv", {}, {"variant": "bn_conv.variant",
-                                 "block_o": "bn_conv.block_o"}),)
-
-    def program_for(self, candidate):
-        return None  # kernel workload: priced analytically
-
-    def analytic_cost(self, candidate, spec) -> dict:
-        """Static FLOPs/bytes per variant.  The byte model gives v1 its
-        per-image weight re-fetch, v2 one weight pass, and the reference
-        the materialized normalized activation (write + read back) — the
-        fusion the kernels exist to delete.  Pallas pipelining quality
-        (the thing v2 actually changes) is NOT static-priceable; equal-
-        byte candidates tie in the prior and the measurement decides."""
-        N, H, W, K, O = self.shape
-        b = 4  # float32
-        x_bytes = N * H * W * K * b
-        w_bytes = 9 * K * O * b
-        o_bytes = N * H * W * O * b
-        flops = 2 * N * H * W * O * K * 9 + 6 * N * H * W * K
-        variant = candidate.get("bn_conv.variant", "v1")
-        if variant == "v1":
-            bytes_ = x_bytes + N * w_bytes + o_bytes
-        elif variant == "v2":
-            bytes_ = x_bytes + w_bytes + o_bytes
-        else:  # reference: normalized map hits HBM both ways
-            bytes_ = 3 * x_bytes + w_bytes + o_bytes
-        return {"flops": flops, "bytes": bytes_}
-
-    def feasible(self, candidate, spec):
-        return True, ""
-
-    def _args(self):
-        import jax.numpy as jnp
-
-        N, H, W, K, O = self.shape
-        rng = np.random.RandomState(3)
-        x = jnp.asarray(rng.randn(N, H, W, K).astype(np.float32))
-        w = jnp.asarray(rng.randn(O, K, 3, 3).astype(np.float32) * 0.05)
-        g = jnp.asarray(rng.rand(K).astype(np.float32) + 0.5)
-        be = jnp.asarray(rng.randn(K).astype(np.float32))
-        mu = jnp.asarray(rng.randn(K).astype(np.float32) * 0.1)
-        var = jnp.asarray(rng.rand(K).astype(np.float32) + 0.5)
-        return x, g, be, mu, var, w
-
-    def build_runner(self, candidate) -> _KernelRunner:
-        import jax
-
-        from ..ops.pallas_kernels import bn_conv as bc
-
-        x, g, be, mu, var, w = self._args()
-        interpret = jax.default_backend() != "tpu"
-        # the variant under test comes from the ACTIVE TRIAL OVERRIDE —
-        # the same resolution path production traces use, so this A/B
-        # proves the routing, not just the kernels
-        fn = bc.make_bn_conv3x3_train(act="relu", has_residual=False,
-                                      stride=1, interpret=interpret)
-        args = (x, g, be, mu, var, bc._w_hwio(w))
-        # parity gate before any timing: CPU interpret parity is the
-        # correctness half of the v2 contract
-        ref = bc.bn_conv3x3_reference(x, g, be, mu, var, w)
-        got = fn(*args)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=2e-3, rtol=2e-3)
-        return _KernelRunner(fn, args)
 
 
 class PagedDecodeWorkload:
@@ -1053,7 +962,6 @@ WORKLOADS: Dict[str, Callable[[], object]] = {
                         "block_k": "flash_attention.block_k"}),),
         flash_profile={"T": 256, "head_dim": 32, "heads": 2, "batch": 2,
                        "layers": 2, "causal": True, "dtype_bytes": 4}),
-    "bn_conv": BnConvWorkload,
     "paged_decode": PagedDecodeWorkload,
     "spec_decode": SpecDecodeWorkload,
     "lstm": lambda: ProgramWorkload("lstm", _build_lstm, _lstm_space),

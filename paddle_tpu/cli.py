@@ -39,8 +39,8 @@ Usage: python -m paddle_tpu <subcommand> [args]
   trace DIR|FILE        — same run, writing the Chrome/Perfetto
                           trace-event JSON (open in ui.perfetto.dev)
   tune WORKLOAD|DIR     — analyzer-guided autotuner (autotune/): rank a
-                          typed search space (kernel blocks, bn-conv
-                          variant, remat, XLA flags) with the static
+                          typed search space (kernel blocks, remat,
+                          XLA flags) with the static
                           cost+HBM analyzers, compile/measure only the
                           predicted-top-k, persist the winner keyed
                           like the compile cache so kernels and the
@@ -668,7 +668,7 @@ def cmd_attribute(args) -> int:
 
 def cmd_tune(args) -> int:
     """`paddle tune WORKLOAD` — the ISSUE 14 search loop.  WORKLOAD is
-    a registered name (gpt_small, bn_conv, paged_decode, lstm) or a
+    a registered name (gpt_small, paged_decode, lstm) or a
     saved-model dir.
     Winners persist in the autotune store; a second run is a cache hit
     (no re-measurement) unless --force."""
@@ -1000,8 +1000,8 @@ def main(argv=None) -> int:
 
     p = sub.add_parser("tune")
     p.add_argument("workload",
-                   help="registered workload (gpt_small|bn_conv|"
-                        "paged_decode|lstm) or a saved-model dir")
+                   help="registered workload (gpt_small|paged_decode|"
+                        "lstm) or a saved-model dir")
     p.add_argument("--top-k", type=int, default=5,
                    help="how many predicted-best candidates to "
                         "compile+measure (the prior gate)")

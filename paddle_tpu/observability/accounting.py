@@ -12,7 +12,7 @@ materializes the error ratios
     pred_vs_measured_step_time_ratio{program=...}  = predicted/measured
     pred_vs_measured_peak_ratio{program=...}       = predicted/measured
 
-which :func:`artifact_rows` emits in the bench.py artifact schema so
+which :func:`artifact_rows` emits as ``metrics.artifact_metric`` rows so
 the cost model's error can be read per round without bespoke plumbing.
 
 Ratio convention: predicted/measured, matching the ISSUE text — 1.0 is a
@@ -190,7 +190,7 @@ def report() -> List[dict]:
 
 
 def artifact_rows() -> List[dict]:
-    """report() in the bench.py artifact schema — the rows the
+    """report() as ``metrics.artifact_metric`` rows — the rows the
     book-model/small-LM acceptance artifact consumes.  Skips programs
     with no measurement yet."""
     from .metrics import artifact_metric

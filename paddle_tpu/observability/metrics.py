@@ -3,7 +3,7 @@ metric substrate for the whole framework (ISSUE 13).
 
 Before this module every tier kept a private dict — ``profiler.py``'s
 global event map, ``ServingEngine.counters``, the master's requeue log,
-serve_bench/bench.py's ad-hoc artifact rows — so ROADMAP #3's
+serve_bench's ad-hoc artifact rows — so ROADMAP #3's
 "publish predicted-vs-measured error" had nowhere to read from.  The
 TensorFlow systems paper treats runtime metrics as a first-class
 subsystem for exactly this reason: a dataflow runtime is undebuggable
@@ -21,7 +21,7 @@ Design points:
     and a JSON snapshot (``snapshot``), both pure functions of registry
     state;
   * **namespace ownership** — ``artifact_metric`` is the single
-    constructor for bench-artifact rows (the names serve_bench/bench.py
+    constructor for bench-artifact rows (the names serve_bench
     used to mint ad hoc); it enforces the naming grammar and the PR 11
     ``serve_v2``/``_solo`` ownership rules documented in
     docs/observability.md.
@@ -453,7 +453,7 @@ class MirroredCounters(dict):
 
 
 # ---------------------------------------------------------------------------
-# artifact-metric namespace ownership (the names serve_bench/bench.py mint)
+# artifact-metric namespace ownership (the names serve_bench mints)
 
 # grammar: snake_case with optional config probes (_bs64, _seq1024 ...)
 _ARTIFACT_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*[a-z0-9]$")
@@ -468,7 +468,7 @@ def artifact_metric(metric: str, value, unit: str,
                     ab_artifact: bool = False, **fields) -> dict:
     """Construct one bench-schema artifact row, validating the metric
     name against the owned namespace (docs/observability.md).  The
-    single place such names are minted — serve_bench/bench.py route
+    single place such names are minted — serve_bench routes
     through here instead of hand-building dicts."""
     if not _ARTIFACT_NAME_RE.match(metric):
         raise ValueError(f"artifact metric {metric!r} violates the "

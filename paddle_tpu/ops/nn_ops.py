@@ -191,9 +191,8 @@ def pool2d(ctx, ins, attrs):
 @register_op("batch_norm", non_diff_outputs=("MeanOut", "VarianceOut"))
 def batch_norm(ctx, ins, attrs):
     # SavedMean/SavedVariance are DIFFABLE (they're pure functions of X in
-    # train mode): training_fusion routes the fused 1x1-conv's dmean/dvar
-    # cotangents through them back into dX.  Ordinary programs leave the
-    # saved vars stop_gradient, so nothing changes for them.
+    # train mode): an op that read them would send its cotangents through
+    # them back into dX.  The layers leave the saved vars stop_gradient.
     """Reference batch_norm_op.cc. Train mode: batch stats + running-stat
     update (MeanOut/VarianceOut alias the Mean/Variance state vars, persisted
     by the executor's written-state logic). Test mode: running stats."""
@@ -237,109 +236,6 @@ def batch_norm(ctx, ins, attrs):
         "SavedMean": [saved_mean],
         "SavedVariance": [saved_var],
     }
-
-
-@register_op("bn_act_conv1x1")
-def bn_act_conv1x1(ctx, ins, attrs):
-    """Fused BatchNorm(+residual)+act -> 1x1 convolution (NHWC): the
-    normalized activation never materializes in HBM — on TPU via the
-    Pallas bn_matmul kernel pair (custom_vjp: single-sweep fused backward
-    with VMEM-resident dW/dgamma/dbeta accumulators), elsewhere via the
-    jnp reference that XLA fuses as well as it can.  Created only by
-    training_fusion.fuse_bn_matmul, which reads the stats from the kept
-    batch_norm op's SavedMean/SavedVariance outputs; replaces what the
-    reference would hand-fuse in paddle/cuda conv epilogues
-    (SURVEY.md §2.10)."""
-    import jax.numpy as jnp
-
-    x = ins["X"][0]           # [N,H,W,K] raw conv output (pre-BN)
-    scale, bias = ins["Scale"][0], ins["Bias"][0]
-    mean, var = ins["SavedMean"][0], ins["SavedVariance"][0]
-    w = ins["Filter"][0]      # OIHW [O, K, 1, 1]
-    res = ins["Residual"][0] if ins.get("Residual") else None
-    eps = float(attrs.get("epsilon", 1e-5))
-    act = attrs.get("act") or None
-    strides = _pair(attrs.get("strides", [1, 1]))
-
-    if strides != [1, 1]:
-        x = x[:, ::strides[0], ::strides[1], :]
-        if res is not None:
-            res = res[:, ::strides[0], ::strides[1], :]
-    n, h, ww, k = x.shape
-    o = w.shape[0]
-    x2 = x.reshape(n * h * ww, k)
-    r2 = res.reshape(n * h * ww, k) if res is not None else None
-    w2 = w.reshape(o, k).T  # [K, O]
-
-    from .pallas_kernels import bn_matmul as bmm
-    from .pallas_kernels._common import pallas_dispatch_ok
-
-    out2 = None
-    if (pallas_dispatch_ok(ctx)
-            and bmm.eligible(x2.shape[0], k, o, x2.dtype.itemsize,
-                             train=not ctx.is_test)):
-        f = bmm.make_bn_matmul_train(act=act, eps=eps,
-                                     has_residual=r2 is not None)
-        args = (x2, scale.astype(jnp.float32), bias.astype(jnp.float32),
-                mean.astype(jnp.float32), var.astype(jnp.float32), w2)
-        out2 = f(*args, r2) if r2 is not None else f(*args)
-        ctx.kernel_forward(reused=False)
-    if out2 is None:
-        sdt = jnp.float64 if x2.dtype == jnp.float64 else jnp.float32
-        out2 = bmm.bn_matmul_reference(
-            x2, scale.astype(sdt), bias.astype(sdt),
-            mean.astype(sdt), var.astype(sdt), w2,
-            r=r2, act=act, eps=eps)
-    return {"Output": [out2.reshape(n, h, ww, o)]}
-
-
-@register_op("bn_act_conv3x3")
-def bn_act_conv3x3(ctx, ins, attrs):
-    """Fused BatchNorm(+residual)+act -> 3x3 convolution (NHWC, stride
-    1 or 2, pad 1):
-    bn_act_conv1x1's companion for the bottleneck's middle conv, backed
-    by ops/pallas_kernels/bn_conv.py (whole-image VMEM tiles, nine-tap
-    matmuls, single-N-sweep fused backward).  Created only by
-    training_fusion.fuse_bn_matmul; ineligible shapes fall back to
-    normalize + XLA conv — exactly the unfused semantics."""
-    import jax.numpy as jnp
-
-    x = ins["X"][0]           # [N,H,W,K] raw conv output (pre-BN)
-    scale, bias = ins["Scale"][0], ins["Bias"][0]
-    mean, var = ins["SavedMean"][0], ins["SavedVariance"][0]
-    w = ins["Filter"][0]      # OIHW [O, K, 3, 3]
-    res = ins["Residual"][0] if ins.get("Residual") else None
-    eps = float(attrs.get("epsilon", 1e-5))
-    act = attrs.get("act") or None
-    strides = _pair(attrs.get("strides", [1, 1]))
-    # the kernel is square-stride only; a non-square stride (never
-    # produced by training_fusion) takes the reference path
-    stride = strides[0] if strides[0] == strides[1] else tuple(strides)
-
-    from .pallas_kernels import bn_conv as bcv
-    from .pallas_kernels._common import pallas_dispatch_ok
-
-    n, h, ww, k = x.shape
-    o = w.shape[0]
-    if (pallas_dispatch_ok(ctx) and isinstance(stride, int)
-            and bcv.eligible(n, h, ww, k, o, x.dtype.itemsize,
-                             train=not ctx.is_test,
-                             has_residual=res is not None,
-                             stride=stride)):
-        f = bcv.make_bn_conv3x3_train(act=act, eps=eps,
-                                      has_residual=res is not None,
-                                      stride=stride)
-        args = (x, scale.astype(jnp.float32), bias.astype(jnp.float32),
-                mean.astype(jnp.float32), var.astype(jnp.float32),
-                bcv._w_hwio(w))
-        out = f(*args, res) if res is not None else f(*args)
-        ctx.kernel_forward(reused=False)
-    else:
-        # the reference derives its stats dtype from x and casts params
-        out = bcv.bn_conv3x3_reference(x, scale, bias, mean, var, w,
-                                       r=res, act=act, eps=eps,
-                                       stride=stride)
-    return {"Output": [out]}
 
 
 @register_op("layer_norm")

@@ -10,8 +10,7 @@ requests finish).  This module is the attention core over that layout:
 one query token per sequence against its own paged, ragged-length
 context.
 
-Two implementations behind one contract, mirroring flash_attention.py /
-bn_conv.py:
+Two implementations behind one contract, mirroring flash_attention.py:
 
   * ``paged_attention_ref`` — pure JAX.  Gathers the page table into a
     dense ``[N, maxp*page_size, ...]`` view and runs masked softmax

@@ -145,17 +145,6 @@ def test_flash_env_garbage_raises(monkeypatch):
         knobs.flash_blocks(512, 1024, 512)
 
 
-def test_bnconv_variant_resolution(monkeypatch):
-    assert knobs.bnconv_variant() == "v1"
-    monkeypatch.setenv("PADDLE_TPU_BNCONV_V2", "1")  # legacy knob
-    assert knobs.bnconv_variant() == "v2"
-    monkeypatch.setenv("PADDLE_TPU_BNCONV_VARIANT", "reference")
-    assert knobs.bnconv_variant() == "reference"  # explicit wins
-    monkeypatch.setenv("PADDLE_TPU_BNCONV_VARIANT", "v3")
-    with pytest.raises(ValueError, match="BNCONV_VARIANT"):
-        knobs.bnconv_variant()
-
-
 def test_page_size_validation(monkeypatch):
     from paddle_tpu.serving.kv_cache import page_size_from_env
 
@@ -200,15 +189,6 @@ def test_flash_kernel_uses_store_winner(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ra.attention(q, q, q, causal=True)),
         atol=1e-5, rtol=1e-5)
-
-
-def test_bnconv_trial_override_reaches_kernel():
-    from paddle_tpu.ops.pallas_kernels import bn_conv as bc
-
-    with knobs.trial_overrides({"bn_conv.variant": "reference"}):
-        f = bc.make_bn_conv3x3_train(interpret=True)
-    # the reference variant is a plain function, not a custom_vjp
-    assert not hasattr(f, "defvjp")
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +289,7 @@ def test_prior_prices_remat_peak_reduction():
 
 def test_tune_winner_persists_and_cache_hits():
     m = MockMeasurer()
-    rep = tuner.tune(workloads.get_workload("bn_conv"), measurer=m,
+    rep = tuner.tune(workloads.get_workload("gpt_small"), measurer=m,
                      top_k=3)
     assert not rep["cache_hit"]
     assert rep["winner_row"]["best_s"] <= rep["default_row"]["best_s"]
@@ -317,24 +297,26 @@ def test_tune_winner_persists_and_cache_hits():
     assert n_measured >= 2  # top-k + (maybe) appended baseline
     # second tune: pure store hit, no measurement
     m2 = MockMeasurer()
-    rep2 = tuner.tune(workloads.get_workload("bn_conv"), measurer=m2)
+    rep2 = tuner.tune(workloads.get_workload("gpt_small"), measurer=m2)
     assert rep2["cache_hit"] and rep2["winner"] == rep["winner"]
     assert not m2.measured
     # --force re-measures
     m3 = MockMeasurer()
-    rep3 = tuner.tune(workloads.get_workload("bn_conv"), measurer=m3,
+    rep3 = tuner.tune(workloads.get_workload("gpt_small"), measurer=m3,
                       force=True, top_k=3)
     assert not rep3["cache_hit"] and m3.measured
 
 
 def test_tune_records_kernel_site_winner():
-    m = MockMeasurer(time_fn=lambda wl, c: 1e-3 if c.get(
-        "bn_conv.variant") == "v2" else 2e-3)
-    rep = tuner.tune(workloads.get_workload("bn_conv"), measurer=m,
+    m = MockMeasurer(time_fn=lambda wl, c: 1e-3 if (
+        c.get("flash_attention.block_q"),
+        c.get("flash_attention.block_k")) == (128, 256) else 2e-3)
+    rep = tuner.tune(workloads.get_workload("gpt_small"), measurer=m,
                      measure_all=True)
-    assert rep["winner"]["bn_conv.variant"] == "v2"
-    # the kernel knob now resolves the tuned variant with NO env set
-    assert knobs.bnconv_variant() == "v2"
+    assert rep["winner"]["flash_attention.block_q"] == 128
+    assert rep["winner"]["flash_attention.block_k"] == 256
+    # the kernel knob now resolves the tuned blocks with NO env set
+    assert knobs.flash_blocks(512, 1024, 256) == (128, 256)
 
 
 def test_paged_decode_winner_reaches_engine_default():
@@ -469,22 +451,22 @@ def test_build_callable_desc_only_pickup():
 # CLI + sweep smoke
 
 
-def test_cli_tune_smoke_bn_conv():
+def test_cli_tune_smoke_gpt_small():
     from paddle_tpu.cli import main as cli_main
 
-    assert cli_main(["tune", "bn_conv", "--smoke"]) == 0
+    assert cli_main(["tune", "gpt_small", "--smoke"]) == 0
 
 
 def test_cli_tune_mock_json(tmp_path, capsys):
     from paddle_tpu.cli import main as cli_main
 
-    rc = cli_main(["tune", "bn_conv", "--mock", "--json",
+    rc = cli_main(["tune", "gpt_small", "--mock", "--json",
                    "--store", str(tmp_path / "s")])
     assert rc == 0
     rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rep["winner"] and not rep["cache_hit"]
     # second CLI invocation over the same store: cache hit
-    rc = cli_main(["tune", "bn_conv", "--mock", "--json",
+    rc = cli_main(["tune", "gpt_small", "--mock", "--json",
                    "--store", str(tmp_path / "s")])
     rep2 = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and rep2["cache_hit"]
@@ -500,5 +482,5 @@ def test_sweep_smoke_emits_rank_artifact(capsys):
     head = json.loads(line)
     assert head["metric"] == "autotune_sweep_workloads"
     rows = {r["metric"]: r for r in head["extra_metrics"]}
-    assert "autotune_rank_error_bn_conv" in rows
-    assert rows["autotune_rank_error_bn_conv"]["candidates"]
+    assert "autotune_rank_error_gpt_small" in rows
+    assert rows["autotune_rank_error_gpt_small"]["candidates"]

@@ -94,32 +94,15 @@ def test_chip_smoke_refuses_the_cpu():
     assert out.stdout.strip() == ""
 
 
-def test_bench_all_exits_nonzero_when_a_mode_fails():
-    """A failed child mode is an error row AND a non-zero exit; nothing is
-    retried on another path or printed in its place."""
-    out = _run([sys.executable, os.path.join(REPO, "bench.py")],
-               env={"BENCH_MODEL": "all", "BENCH_LAYOUT": "NHWC",
-                    "BENCH_DTYPE": "no_such_dtype", "BENCH_BUDGET": "120",
-                    "BENCH_ITERS": "1", "BENCH_WARMUP": "0",
-                    "BENCH_REPEATS": "1"})
-    assert out.returncode != 0, out.stdout
-    rows = [json.loads(l) for l in out.stdout.splitlines()
-            if l.startswith("{")]
-    assert rows and rows[-1]["unit"] == "error"
-    assert "no_such_dtype" in rows[-1]["error"]
-    assert "provenance" not in rows[-1] and "note" not in rows[-1]
-    assert "mode(s) failed" in out.stderr
-
-
 # ---------------------------------------------------------------------------
 # one process for each chip
 
 
 def test_imports_initialise_no_backend():
-    """bench.py's parent and any launcher import these and must leave the
-    chip free for the child that needs it."""
+    """A launcher imports these and must leave the chip free for the
+    child that needs it."""
     out = _run(
-        "import paddle_tpu, bench, chip_smoke\n"
+        "import paddle_tpu, chip_smoke\n"
         "from paddle_tpu.framework.place import backend_initialized, "
         "holds_accelerator\n"
         "assert not backend_initialized()\n"

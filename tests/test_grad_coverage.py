@@ -106,14 +106,13 @@ def test_every_differentiable_op_is_checked_or_excluded():
 
     # pinned counts (VERDICT r2 #6): a change to either side must be a
     # conscious edit of this file, not a silent drift
-    # r4: +2 training-fusion ops (bn_act_conv1x1, bn_act_conv3x3), each
-    # numerically checked in test_training_fusion.py
+    # PR 28: -2 (the BN-fusion tier's two ops went with the tier)
     # r5: +2 trig ops (sin, cos — the layers/ops.py activation surface),
     # numerically checked in test_ops_grad_sweep.py
     # PR 26: +3 (rms_norm, rope, moe_router_loss — the OLMoE block), each
     # numerically checked in test_llm_ops.py
-    assert len(diffable) == 151, (
+    assert len(diffable) == 149, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 151 - 11
+    assert len(checked) == 149 - 11

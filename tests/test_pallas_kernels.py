@@ -569,6 +569,28 @@ def test_fused_rnn_reverse_training_and_gru(monkeypatch):
                                atol=2e-5)
 
 
+@pytest.mark.parametrize("platform,mesh,switched_off,want", [
+    ("tpu", None, False, True),
+    ("tpu", object(), False, False),   # GSPMD cannot partition a Mosaic call
+    ("cpu", None, False, False),
+    ("tpu", None, True, False),        # PADDLE_TPU_NO_FUSED_KERNELS=1
+])
+def test_pallas_dispatch_gate(monkeypatch, platform, mesh, switched_off,
+                              want):
+    """The one gate every fused-kernel emitter asks: a TPU target, no
+    mesh, kernels not switched off."""
+    from paddle_tpu.ops import registry as reg
+    from paddle_tpu.ops.pallas_kernels import _common
+
+    monkeypatch.delenv("PADDLE_TPU_NO_FUSED_KERNELS", raising=False)
+    if switched_off:
+        monkeypatch.setenv("PADDLE_TPU_NO_FUSED_KERNELS", "1")
+    ctx = reg.EmitContext(jax.random.PRNGKey(0), is_test=False)
+    monkeypatch.setattr(ctx, "target_platform", lambda: platform)
+    ctx.mesh = mesh
+    assert _common.pallas_dispatch_ok(ctx) is want
+
+
 def test_mosaic_failure_propagates_and_disables_nothing(monkeypatch):
     """A Mosaic compilation failure in a fused kernel is the caller's
     error, carrying the op's name and the compiler's words: the executor

@@ -19,7 +19,7 @@ artifact, not a human, says which number is which.  The on-chip
 times.
 
 Flags:
-  --workloads a,b,c  (default gpt_small,bn_conv,lstm)
+  --workloads a,b,c  (default gpt_small,lstm,mlp_depth)
   --smoke            mock measurer + schema asserts (the CI gate)
   --top-k N          the rank-error gate being judged (default 5)
   --iters/--repeats/--warmup   trial sizing
@@ -38,7 +38,7 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-DEFAULT_WORKLOADS = "gpt_small,bn_conv,lstm,mlp_depth"
+DEFAULT_WORKLOADS = "gpt_small,lstm,mlp_depth"
 
 
 def populate_calibration(models=("fit_a_line", "small_lm", "lstm")):
@@ -211,7 +211,7 @@ def main(argv=None) -> int:
         populate_calibration()
     if args.smoke:
         measurer = MockMeasurer()
-        args.workloads = "bn_conv"
+        args.workloads = "gpt_small"
     else:
         measurer = TimedMeasurer(warmup=args.warmup, iters=args.iters,
                                  repeats=args.repeats)
@@ -255,7 +255,7 @@ def main(argv=None) -> int:
         names_seen = {e["name"] for e in obs.TRACER.events()}
         assert "autotune.rank" in names_seen, sorted(names_seen)
         by_name = {r["metric"]: r for r in all_rows}
-        r = by_name["autotune_rank_error_bn_conv"]
+        r = by_name["autotune_rank_error_gpt_small"]
         assert r["value"] >= 1 and r["candidates"], r
         print("# autotune sweep smoke OK", file=sys.stderr)
 

@@ -5,10 +5,8 @@ traffic of compiled training steps (VERDICT r3 Next #2/#8).
 Two jobs, one methodology (parse XLA's post-optimization HLO dump):
 
   bytes        per-op-kind materialized output bytes of the ResNet-50
-               train step — the evidence artifact for the BN->conv
-               fusion work (docs/perf_resnet50_roofline.md counted
-               12.9 GB/step of elementwise fusion writes; this tool
-               measures how the training_fusion pass moves that number)
+               train step (docs/perf_resnet50_roofline.md counted
+               12.9 GB/step of elementwise fusion writes)
   collectives  per-mode collective op counts + buffer bytes for the
                multi-chip programs (dp / sp-ring / sp-ulysses / ep) on
                the 8-virtual-device CPU mesh — the honest substitute for
@@ -18,7 +16,7 @@ Two jobs, one methodology (parse XLA's post-optimization HLO dump):
                all-gather (S-1)/S x bytes...) is noted per row.
 
 Usage:
-  python tools/hlo_analysis.py bytes [--fuse-bn] [--no-remat] [--bs N]
+  python tools/hlo_analysis.py bytes [--no-remat] [--bs N]
   python tools/hlo_analysis.py collectives [--mode dp|sp_ring|sp_ulysses|ep]
   python tools/hlo_analysis.py peak      # static-vs-measured HBM peak on
                                          # the 3 validation programs
@@ -120,8 +118,7 @@ def parse_module(path: str):
     calls=/to_apply=/select=/scatter= is an inlined body (code review
     r5: reduce regions named %region_N would slip a name-based filter).
     Only top_kinds supports an honest HBM-traffic roofline; the all-
-    instruction table remains useful for fusion-content comparisons
-    (r4's fused-vs-unfused ledgers)."""
+    instruction table remains useful for fusion-content comparisons."""
     with open(path) as f:
         text = f.read()
     inlined = set()
@@ -212,8 +209,6 @@ def run_child(mode: str, dump_dir: str, args) -> None:
         env["JAX_PLATFORMS"] = "cpu"
     argv = [sys.executable, os.path.abspath(__file__), "--child", mode,
             "--bs", str(args.bs), "--image", str(args.image)]
-    if args.fuse_bn:
-        argv.append("--fuse-bn")
     if args.no_remat:
         argv.append("--no-remat")
     if args.submode:
@@ -369,8 +364,7 @@ def child_roofline(args) -> None:
     hw = args.image
     avg_cost, _ = resnet.build_train_program(
         batch_size=args.bs, depth=50, dtype="bfloat16", layout="NHWC",
-        image_shape=(3, hw, hw), remat=not args.no_remat,
-        fuse_bn=args.fuse_bn)
+        image_shape=(3, hw, hw), remat=not args.no_remat)
     program = fluid.default_main_program()
     chip = acost.detect_chip()
     static = acost.program_cost(program, batch_size=args.bs, chip=chip)
@@ -427,8 +421,7 @@ def child_bytes(args) -> None:
     hw = args.image
     avg_cost, _ = resnet.build_train_program(
         batch_size=args.bs, depth=50, dtype="bfloat16", layout="NHWC",
-        image_shape=(3, hw, hw), remat=not args.no_remat,
-        fuse_bn=args.fuse_bn)
+        image_shape=(3, hw, hw), remat=not args.no_remat)
     exe = fluid.Executor(fluid.default_place())
     exe.run(fluid.default_startup_program())
     rng = np.random.RandomState(0)
@@ -674,8 +667,7 @@ def analyze(mode: str, args) -> dict:
             or k in COLLECTIVES},
     }
     if mode == "bytes":
-        rec["config"] = {"bs": args.bs, "fuse_bn": args.fuse_bn,
-                         "remat": not args.no_remat}
+        rec["config"] = {"bs": args.bs, "remat": not args.no_remat}
         rec["fusion_bytes"] = kinds.get("fusion", {}).get("out_bytes", 0)
         rec["conv_bytes"] = (
             kinds.get("convolution", {}).get("out_bytes", 0)
@@ -788,7 +780,6 @@ def main():
                     help="input height/width (a CPU evidence run wants a "
                          "small proxy; the chip capture keeps 224)")
     ap.add_argument("--timeout", type=float, default=1800)
-    ap.add_argument("--fuse-bn", action="store_true")
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--tpu", action="store_true",
                     help="bytes mode: use the environment's accelerator "
@@ -833,10 +824,7 @@ def main():
         run_loop(args)
         return
     if args.what in ("bytes", "all"):
-        for fuse in ((False, True) if args.what == "all"
-                     else (args.fuse_bn,)):
-            args.fuse_bn = fuse
-            print(json.dumps(analyze("bytes", args)), flush=True)
+        print(json.dumps(analyze("bytes", args)), flush=True)
     if args.what in ("collectives", "all"):
         modes = ([args.submode] if args.submode
                  else ["dp", "sp_ring", "sp_ulysses", "ep"])

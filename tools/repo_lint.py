@@ -31,13 +31,11 @@ Classes of rot this repo has actually accumulated:
      ``paddle_tpu/observability/`` — ISSUE 13 unified the telemetry
      substrate precisely because every tier had grown its own
      ``time.perf_counter()`` bookkeeping (profiler.py's global event
-     map, serve_bench/bench.py private dicts); new timing goes through
+     map, serve_bench's private dicts); new timing goes through
      ``observability.metrics.monotime`` / ``REGISTRY.timed()`` /
-     tracer spans so it lands in the shared registry.  Shim-listed
-     exemptions: the kernel/step microbench oracles whose timing IS
-     the product (tools/bench_kernels.py, tools/profile_resnet.py);
-     ``tests/`` are exempt as always.  Line-anchored tripwire like the
-     others, not an AST proof.
+     tracer spans so it lands in the shared registry.  ``tests/`` are
+     exempt as always.  Line-anchored tripwire like the others, not an
+     AST proof.
   8. checkpoint-directory writes outside ``distributed/checkpoint.py``
      — the chaos suite's crash-recovery proof rests on every byte in a
      ``ckpt_<n>`` dir (and the LATEST pointer) being published by one
@@ -59,14 +57,14 @@ Classes of rot this repo has actually accumulated:
 
   10. raw tuning-knob env reads outside ``paddle_tpu/autotune/`` — the
      autotuner (ISSUE 14) made PADDLE_TPU_FLASH_BQ/BK,
-     PADDLE_TPU_BNCONV_*, PADDLE_TPU_PAGE_SIZE and friends an explicit
-     OVERRIDE LAYER resolved (and validated) in
-     ``paddle_tpu/autotune/knobs.py``: trial override > env > winner
-     store > default.  A raw ``os.environ`` read of a knob-class name
-     anywhere else re-creates the pre-ISSUE-14 world where the env var
-     is the only mechanism, the store is silently bypassed, and
-     garbage values int()-crash at trace time.  Line-anchored
-     tripwire; ``tests/`` exempt (they monkeypatch knobs on purpose).
+     PADDLE_TPU_PAGE_SIZE and friends an explicit OVERRIDE LAYER
+     resolved (and validated) in ``paddle_tpu/autotune/knobs.py``:
+     trial override > env > winner store > default.  A raw
+     ``os.environ`` read of a knob-class name anywhere else re-creates
+     the pre-ISSUE-14 world where the env var is the only mechanism,
+     the store is silently bypassed, and garbage values int()-crash at
+     trace time.  Line-anchored tripwire; ``tests/`` exempt (they
+     monkeypatch knobs on purpose).
 
 Usage: ``python tools/repo_lint.py [root]`` — prints findings, exits 1 if
 any.  `tests/` is exempt from the __init__ rule (pytest rootdir-style
@@ -80,7 +78,7 @@ import re
 import sys
 
 # the root-level scripts the line rules police
-_ROOT_SCRIPTS = ("bench.py", "chip_smoke.py", "__graft_entry__.py")
+_ROOT_SCRIPTS = ("chip_smoke.py", "__graft_entry__.py")
 # directory names whose contents are never package code
 _SKIP_DIRS = {".git", "__pycache__", "node_modules", ".venv"}
 # top-level trees exempt from the missing-__init__ rule
@@ -208,12 +206,6 @@ def _check_page_table(root, dirpath, filenames, findings):
 _PERF_COUNTER_RE = re.compile(r"\bperf_" + r"counter\s*\(")
 _PERF_COUNTER_DIRS = ("paddle_tpu", "tools")
 _PERF_COUNTER_OK_DIR = os.path.join("paddle_tpu", "observability")
-# measurement oracles whose timing loop IS the deliverable: their
-# numbers feed artifacts directly and never mint registry metrics
-_PERF_COUNTER_OK = {
-    os.path.join("tools", "bench_kernels.py"),
-    os.path.join("tools", "profile_resnet.py"),
-}
 
 
 def _check_perf_counter(root, dirpath, filenames, findings):
@@ -229,8 +221,7 @@ def _check_perf_counter(root, dirpath, filenames, findings):
             continue
         path = os.path.join(dirpath, fname)
         rel = os.path.relpath(path, root)
-        if rel in _PERF_COUNTER_OK or rel == os.path.join(
-                "tools", "repo_lint.py"):
+        if rel == os.path.join("tools", "repo_lint.py"):
             continue
         # the top-level scan covers _ROOT_SCRIPTS only
         if top == "" and fname not in _ROOT_SCRIPTS:
@@ -244,8 +235,7 @@ def _check_perf_counter(root, dirpath, filenames, findings):
                             f"(use observability.metrics.monotime / "
                             f"REGISTRY.timed() / tracer spans so the "
                             f"measurement lands in the shared "
-                            f"registry; oracles may be shim-listed in "
-                            f"repo_lint._PERF_COUNTER_OK)")
+                            f"registry)")
         except OSError:
             pass
 
@@ -303,7 +293,7 @@ def _check_ckpt_writes(root, dirpath, filenames, findings):
 # definition — extend it when a new tunable parameter gains an env
 # override (and route the read through autotune/knobs.py).
 _KNOB_ENV_RE = re.compile(
-    r"os\.environ\b[^\n]*PADDLE_TPU_(?:FLASH_|BNCONV_|PAGE_SIZE"
+    r"os\.environ\b[^\n]*PADDLE_TPU_(?:FLASH_|PAGE_SIZE"
     r"|AUTOTUNE\b|SPEC_K\b|SPEC_DRAFT_LAYERS|STEPS_PER_DISPATCH)")
 # plain assignments (and the matching teardown pop) are the EXPORT side
 # of the knob layer (a bench pinning its config so knobs.py resolves it

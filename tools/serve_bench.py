@@ -3,7 +3,7 @@
 
 Drives paddle_tpu.serving.ServingEngine over a DecoderLM with synthetic
 Poisson traffic — mixed prompt lengths, open-loop arrivals — and prints
-ONE JSON line in the bench.py artifact schema.
+ONE JSON line of ``observability.metrics.artifact_metric`` rows.
 
 Five modes (`--scheduler`):
 
@@ -47,7 +47,7 @@ v2 engine packs more concurrent requests into the same pool.  Standalone
 worst-case default pool — the PR 7 capture config, so the longitudinal
 `serve_decode_tok_per_s_*` series stays comparable.
 
-Env knobs (bench.py idiom):
+Env knobs:
   SERVE_SLOTS=64        decode slots (max batch)
   SERVE_REQUESTS=96     total synthetic requests (>= 64 for acceptance)
   SERVE_RATE=32         mean Poisson arrival rate, requests/sec
