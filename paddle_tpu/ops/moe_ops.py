@@ -15,12 +15,16 @@ no MoE).  Two forms of one op:
   experts): the `top_k` largest router probabilities a token, every chosen
   (token, expert) pair computed, many experts on one chip.  The token-slots
   are sorted by expert ONCE; the sort serves the two or three grouped
-  matmuls (`lax.ragged_dot` over the sorted rows, one group an expert) and
-  the combine.  `gated=True` makes an expert `WO(act(WI x) * (WU x))`.  The
-  router's logits and the per-expert token counts leave the op for the
-  auxiliary losses (`moe_router_loss`).  Not under an 'ep' mesh yet."""
+  matmuls (`lax.ragged_dot` over the sorted rows, one group an expert; on
+  a TPU their backward products are the two Pallas kernels of
+  pallas_kernels/grouped_matmul.py, `_grouped_matmul`) and the combine.
+  `gated=True` makes an expert `WO(act(WI x) * (WU x))`.  The router's
+  logits and the per-expert token counts leave the op for the auxiliary
+  losses (`moe_router_loss`).  Not under an 'ep' mesh yet."""
 
 from __future__ import annotations
+
+import functools
 
 from ..observability.attribution import part_scope
 from ..observability.metrics import REGISTRY as _MET
@@ -32,6 +36,13 @@ _MET_MOE_LAYERS = _MET.counter(
     "dropless expert layers traced (forward emission; once a compile, not "
     "once a step), by top_k, number of experts and the grouped matmul's "
     "implementation")
+_MET_GROUPED_BWD = _MET.counter(
+    "moe_grouped_backward_total",
+    "grouped expert matmuls whose backward was traced (once a compile, not "
+    "once a step), by what runs the two backward products: impl=pallas "
+    "(the kernels ragged-dot-dlhs and ragged-dot-drhs, counted in the "
+    "backward rule) or ragged_dot (autodiff's transposes, counted at "
+    "generic_grad's re-emission)")
 
 
 def _dispatch(x, gate_w, n_exp, capacity):
@@ -99,7 +110,53 @@ def _route_top_k(x, gate_w, top_k):
     return logits, weights, experts
 
 
-def _moe_dropless(x, gate_w, wi, wu, wo, top_k, act):
+@functools.lru_cache(maxsize=None)
+def _ragged_dot_kernel_backward():
+    """`lax.ragged_dot` whose backward rule is the two Pallas kernels.  One
+    function for every call (jit caches by identity).  The forward stays
+    plain HLO, so generic_grad's re-emission of it merges with the first
+    under CSE and nothing has to be kept for the grad op."""
+    import jax
+    from jax import lax
+
+    from .pallas_kernels import grouped_matmul
+
+    @jax.custom_vjp
+    def product(xs, w, counts):
+        return lax.ragged_dot(xs, w, counts)
+
+    def backward(res, dy):
+        xs, w, counts = res
+        _MET_GROUPED_BWD.inc(impl="pallas")
+        return grouped_matmul.grouped_matmul_bwd(xs, w, counts, dy) + (None,)
+
+    product.defvjp(lambda xs, w, counts: (lax.ragged_dot(xs, w, counts),
+                                          (xs, w, counts)), backward)
+    return product
+
+
+def _grouped_matmul(ctx, xs, w, counts):
+    """xs [R, K] rows sorted by group, w [G, K, N], counts [G] int32 that
+    sum to R -> [R, N]: the rows of group g times w[g].  Forward
+    `lax.ragged_dot` always.  Where the trace targets one TPU and the
+    shapes are whole tiles, the backward products dX and dW are the
+    kernels of pallas_kernels/grouped_matmul.py, which read and write w in
+    its stored layout; elsewhere (the CPU, a mesh, kernels switched off,
+    an unaligned width) autodiff's transposes, as before."""
+    from jax import lax
+
+    from .pallas_kernels import grouped_matmul
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    if pallas_dispatch_ok(ctx) and grouped_matmul.usable(
+            xs.shape[0], w.shape[1], w.shape[2], xs.dtype.itemsize):
+        return _ragged_dot_kernel_backward()(xs, w, counts)
+    if ctx.in_grad_replay():
+        _MET_GROUPED_BWD.inc(impl="ragged_dot")
+    return lax.ragged_dot(xs, w, counts)
+
+
+def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act):
     """-> (out [T, D], router logits [T, E] f32, counts [E] f32).
 
     Slot s = t * k + j is token t's j-th choice.  `order` lists the slots
@@ -107,7 +164,6 @@ def _moe_dropless(x, gate_w, wi, wu, wo, top_k, act):
     inverse; rows move only by gathers, forward and backward."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     T, D = x.shape
     n_exp = wi.shape[0]
@@ -122,11 +178,11 @@ def _moe_dropless(x, gate_w, wi, wu, wo, top_k, act):
         xs = _gather_slots(x, order, inv, top_k)            # [T*k, D]
     with part_scope("moe.experts"):
         wide = logits.dtype
-        h = lax.ragged_dot(xs, wi, counts).astype(wide)
+        h = _grouped_matmul(ctx, xs, wi, counts).astype(wide)
         h = _act_fn(act)(h)
         if wu is not None:
-            h = h * lax.ragged_dot(xs, wu, counts).astype(wide)
-        ys = lax.ragged_dot(h.astype(x.dtype), wo, counts)  # [T*k, D]
+            h = h * _grouped_matmul(ctx, xs, wu, counts).astype(wide)
+        ys = _grouped_matmul(ctx, h.astype(x.dtype), wo, counts)  # [T*k, D]
     with part_scope("moe.combine"):
         y = _permute_rows(ys, inv, order).reshape(T, top_k, D)
         out = jnp.sum(y.astype(wide) * weights[..., None], axis=1)
@@ -216,8 +272,8 @@ def moe(ctx, ins, attrs):
         if not ctx.in_grad_replay():
             _MET_MOE_LAYERS.inc(top_k=str(top_k), experts=str(n_exp),
                                 impl="ragged_dot")
-        out, logits, counts = _moe_dropless(x, gate_w, wi, wu, wo, top_k,
-                                            act)
+        out, logits, counts = _moe_dropless(ctx, x, gate_w, wi, wu, wo,
+                                            top_k, act)
         return {"Out": [out], "RouterLogits": [logits], "Counts": [counts]}
     if top_k != 1 or gated:
         raise ValueError(

@@ -266,9 +266,9 @@ def v5e():
     return topo.devices[0]
 
 
-def _compiled_step_text(loss, device, batch, seq_len) -> str:
-    """The optimized HLO of the executor's step for `device`, from shapes
-    alone (as tests/benchmarks/test_benchmark.py `_aot` compiles it)."""
+def _compiled_step(loss, device, batch, seq_len):
+    """The executor's step compiled for `device`, from shapes alone (as
+    tests/benchmarks/test_benchmark.py `_aot` compiles it)."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
@@ -301,7 +301,7 @@ def _compiled_step_text(loss, device, batch, seq_len) -> str:
             {n: of_var(n) for n in compiled.rw_state},
             {n: of_var(n) for n in compiled.external_reads},
             {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
-            sds((2,), np.uint32)).compile().as_text()
+            sds((2,), np.uint32)).compile()
 
 
 def test_aot_one_forward_kernel_a_layer(v5e):
@@ -313,7 +313,7 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     layers = 2
     loss = build_lm_train_program(1024, vocab_size=512, dim=128,
                                   n_layers=layers, n_heads=2)
-    text = _compiled_step_text(loss, v5e, batch=2, seq_len=1024)
+    text = _compiled_step(loss, v5e, batch=2, seq_len=1024).as_text()
     calls = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     kinds = {}
@@ -365,3 +365,64 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
             text = lowered.compile().as_text()
             assert text.count('custom_call_target="tpu_custom_call"') == (
                 2 if name == "flash_bwd" else 1), name
+
+
+# ---------------------------------------------------------------------------
+# AOT: the expert layer's backward kernels in OLMoE's real step
+
+
+def _peak_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
+        v5e, monkeypatch):
+    """`olmoe_train_t4096`'s step at the published widths (2 layers, 64
+    experts of 1024, 4096 tokens with 8 experts each, bf16): the compiled
+    HLO holds the 6 forward `ragged-dot` products XLA lowers itself and 12
+    calls of the two Pallas kernels, no backward `ragged-dot`, and NO copy
+    or transpose of the stacked expert weights; it needs no more memory
+    than the same step with autodiff's transposes (the parent's)."""
+    from paddle_tpu.models.transformer import build_moe_lm_train_program
+    from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
+
+    layers = 2
+    args = dict(seq_len=4096, vocab_size=50304, dim=2048, n_layers=layers,
+                n_heads=16, num_experts=64, expert_dim=1024, top_k=8,
+                dtype="bfloat16")
+
+    def compiled():
+        fluid.reset()
+        loss = build_moe_lm_train_program(**args)
+        return _compiled_step(loss, v5e, batch=1, seq_len=4096)
+
+    def instructions(text, pattern):
+        return re.findall(r"^\s*(?:ROOT )?%(" + pattern + r")[.\d]* = ",
+                          text, re.M)
+
+    stacked = r"bf16\[64,(?:2048,1024|1024,2048)\]"
+    relayout = (r"^\s*%(?:copy|transpose)[.\d]* = " + stacked
+                + r"[^=\n]* (?:copy|transpose)\(")
+
+    change = compiled()
+    text = change.as_text()
+    assert len(instructions(text, gm.DLHS)) == 3 * layers, "dlhs"
+    assert len(instructions(text, gm.DRHS)) == 3 * layers, "drhs"
+    assert len(instructions(text, "ragged-dot-none")) == 3 * layers
+    assert not re.findall(relayout, text, re.M)
+    fam = obs.REGISTRY.snapshot()["families"]
+    assert {s["labels"]["impl"]: s["value"] for s in fam[
+        "moe_grouped_backward_total"]["series"]} == {"pallas": 3.0 * layers}
+    assert _counter() == {(SDPA, "1"): float(layers)}  # flash's, no moe
+
+    # the parent's step: the gate closed for these kernels alone
+    monkeypatch.setattr(gm, "usable", lambda *shape: False)
+    parent = compiled()
+    text = parent.as_text()
+    assert len(instructions(text, "ragged-dot-none")) == 9 * layers
+    assert len(re.findall(relayout, text, re.M)) >= 2 * layers
+    print("AOT olmoe step peak_bytes: parent", _peak_bytes(parent),
+          "change", _peak_bytes(change))
+    assert _peak_bytes(change) <= _peak_bytes(parent)
