@@ -7,7 +7,7 @@ lowers to XLA in one piece."""
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Union
+from typing import List, NamedTuple, Optional, Sequence, Union
 
 from ..framework.core import Variable
 from ..framework.initializer import ConstantInitializer, NormalInitializer
@@ -387,6 +387,46 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     return propagate_length(queries, out)
 
 
+def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
+                     v_dim, rope_theta=10000.0, epsilon=1e-5,
+                     param_attr=None, name=None):
+    """Causal multi-head latent attention over [B, T, D] (DeepSeek-V2's
+    MLA, the form without a query latent; ops/llm_ops.py latent_attention
+    has the equations): keys and values come from a latent of `kv_rank`
+    columns with an RMSNorm of its own, one rotary key of `qk_rope_dim`
+    columns is shared by all heads, queries and keys are `qk_nope_dim` +
+    `qk_rope_dim` wide and values `v_dim`.  Five parameters, in creation
+    order: WQ, WKVA, the latent norm's gain, WKVB, WO; no bias."""
+    helper = LayerHelper("latent_attention", name=name)
+    D = input.shape[-1]
+    attr = param_attr if isinstance(param_attr, dict) else {}
+
+    def weight(shape):
+        return helper.create_parameter(attr=attr, shape=shape,
+                                       dtype=input.dtype)
+
+    wq = weight([D, num_heads * (qk_nope_dim + qk_rope_dim)])
+    wkva = weight([D, kv_rank + qk_rope_dim])
+    gain = helper.create_parameter(
+        attr={}, shape=[kv_rank], dtype=input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    wkvb = weight([kv_rank, num_heads * (qk_nope_dim + v_dim)])
+    wo = weight([num_heads * v_dim, D])
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(
+        "latent_attention",
+        inputs={"X": [input.name], "WQ": [wq.name], "WKVA": [wkva.name],
+                "KVNorm": [gain.name], "WKVB": [wkvb.name],
+                "WO": [wo.name]},
+        outputs={"Out": [out.name]},
+        attrs={"num_heads": int(num_heads), "qk_nope_dim": int(qk_nope_dim),
+               "qk_rope_dim": int(qk_rope_dim), "v_dim": int(v_dim),
+               "theta": float(rope_theta), "epsilon": float(epsilon)})
+    from .sequence import propagate_length
+
+    return propagate_length(input, out)
+
+
 def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
     helper = LayerHelper("matmul", name=name)
     out = helper.create_tmp_variable(x.dtype)
@@ -548,9 +588,23 @@ def auc(input, label):
     return out
 
 
+class MoeShare(NamedTuple):
+    """What `moe` returns for a share of an expert layer (`held`)."""
+
+    out: Variable             # [N, D]: the held experts' part + the shared one
+    scores: Variable          # [N, E] float32 router scores
+    weights: Variable         # [N, top_k] float32: each token's top-k weights
+    counts: Variable          # [E]: pairs each of ALL E experts was chosen for
+    held_pairs: Variable      # [1]: the pairs on held experts
+    dropped_pairs: Variable   # [1]: those of them the buffer had no row for
+    bias: Optional[Variable]  # [E] selection bias (no gradient), or None
+
+
 def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         param_attr=None, name=None, top_k=1, gated=False, dropless=False,
-        initializer=None):
+        initializer=None, held=None, scoring="softmax", select_bias=None,
+        renormalise=False, routed_scale=1.0, buffer_rows=None,
+        shared_hidden=0):
     """Mixture-of-experts FFN layer (beyond-reference — SURVEY.md §2.16 last
     row).  `input` [N, D] tokens -> [N, D].  Expert weights are stacked
     [E, D, H]/[E, H, D]; under a ParallelExecutor whose mesh has an 'ep'
@@ -560,7 +614,19 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     `dropless=True` is the fine-grained form: the `top_k` largest router
     probabilities a token, nothing dropped, `gated` experts `WO(act(WI x)
     * (WU x))`; it returns (out, router_logits [N, E] float32, counts
-    [E]), the last two for `moe_router_loss`."""
+    [E]), the last two for `moe_router_loss`.
+
+    `held=(first, n)` makes the layer ONE CHIP'S SHARE of a dropless layer
+    whose experts are spread over chips: the router keeps `num_experts`
+    outputs and `top_k` a token, the stacked weights hold the experts
+    [first, first + n) only, and the result is their part of the layer's
+    sum (-> `MoeShare`).  With it come DeepSeek-V3's router (`scoring`
+    'sigmoid', `select_bias`: an initializer for a bias [E] that is added
+    for the choice only and takes no gradient, `renormalise` the chosen
+    weights to sum to one, times `routed_scale`), `buffer_rows` (the
+    static rows the held pairs are computed in; N * top_k, which nothing
+    can overflow, by default) and `shared_hidden` (> 0: one more gated
+    expert of that width which every token passes, inside the same op)."""
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     d_model = input.shape[-1]
 
@@ -572,13 +638,23 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
             attr=attr or {}, shape=shape, dtype=input.dtype,
             default_initializer=init(fan_in))
 
+    # a "share" that is every expert under OLMoE's router is the whole
+    # dropless layer: the op it always was
+    share = held is not None and not (
+        tuple(held) == (0, num_experts) and scoring == "softmax"
+        and select_bias is None and not renormalise and routed_scale == 1.0
+        and not buffer_rows and not shared_hidden)
+    if share and not dropless:
+        raise ValueError("layers.moe: a share of the experts (held) needs "
+                         "dropless=True (ops/moe_ops.py)")
+    stacked = int(held[1]) if share else num_experts
     gate = weight([d_model, num_experts], d_model,
                   param_attr if isinstance(param_attr, dict) else None)
-    wi = weight([num_experts, d_model, d_hidden], d_model)
+    wi = weight([stacked, d_model, d_hidden], d_model)
     ins = {"X": [input.name], "Gate": [gate.name], "WI": [wi.name]}
     if gated:
-        ins["WU"] = [weight([num_experts, d_model, d_hidden], d_model).name]
-    ins["WO"] = [weight([num_experts, d_hidden, d_model], d_hidden).name]
+        ins["WU"] = [weight([stacked, d_model, d_hidden], d_model).name]
+    ins["WO"] = [weight([stacked, d_hidden, d_model], d_hidden).name]
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     outs = {"Out": [out.name]}
     attrs = {"capacity_factor": capacity_factor, "act": act}
@@ -592,11 +668,37 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         "float32", shape=(input.shape[0], num_experts))
     counts = helper.create_tmp_variable("float32", shape=(num_experts,),
                                         stop_gradient=True)
-    outs.update({"RouterLogits": [logits.name], "Counts": [counts.name]})
     attrs.update({"top_k": int(top_k), "gated": bool(gated),
                   "dropless": True})
+    if not share:
+        outs.update({"RouterLogits": [logits.name], "Counts": [counts.name]})
+        helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)
+        return out, logits, counts
+    bias = None
+    if select_bias is not None:
+        bias = helper.create_parameter(
+            attr={"trainable": False}, shape=[num_experts], dtype="float32",
+            default_initializer=select_bias)
+        ins["Bias"] = [bias.name]
+    if shared_hidden:
+        ins["SI"] = [weight([d_model, shared_hidden], d_model).name]
+        if gated:
+            ins["SU"] = [weight([d_model, shared_hidden], d_model).name]
+        ins["SO"] = [weight([shared_hidden, d_model], shared_hidden).name]
+    pairs, dropped = (helper.create_tmp_variable(
+        "float32", shape=(1,), stop_gradient=True) for _ in range(2))
+    weights = helper.create_tmp_variable(
+        "float32", shape=(input.shape[0], int(top_k)), stop_gradient=True)
+    outs.update({"RouterScores": [logits.name], "Counts": [counts.name],
+                 "RouterWeights": [weights.name],
+                 "HeldPairs": [pairs.name], "DroppedPairs": [dropped.name]})
+    attrs.update({"first_expert": int(held[0]), "scoring": scoring,
+                  "renormalise": bool(renormalise),
+                  "routed_scale": float(routed_scale)})
+    if buffer_rows:
+        attrs["buffer_rows"] = int(buffer_rows)
     helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)
-    return out, logits, counts
+    return MoeShare(out, logits, weights, counts, pairs, dropped, bias)
 
 
 def moe_router_loss(router_logits, counts):
@@ -612,6 +714,31 @@ def moe_router_loss(router_logits, counts):
                 "Counts": [counts.name]},
         outputs={"Balance": [balance.name], "ZLoss": [z.name]})
     return balance, z
+
+
+def moe_sequence_balance_loss(scores, counts, top_k):
+    """DeepSeek-V3's sequence-wise balance loss [1] of one expert layer,
+    from a `MoeShare`'s scores and counts (ops/moe_ops.py
+    moe_sequence_balance_loss has the formula)."""
+    helper = LayerHelper("moe_sequence_balance_loss")
+    balance = helper.create_tmp_variable("float32", shape=(1,))
+    helper.append_op(
+        "moe_sequence_balance_loss",
+        inputs={"RouterScores": [scores.name], "Counts": [counts.name]},
+        outputs={"Balance": [balance.name]}, attrs={"top_k": int(top_k)})
+    return balance
+
+
+def moe_bias_update(bias, counts, rate):
+    """Append the auxiliary-loss-free balancing step on a `MoeShare`'s
+    selection bias: bias += rate * sign(mean(counts) - counts), in place.
+    Call it after `minimize`, so that it follows the backward pass."""
+    helper = LayerHelper("moe_bias_update")
+    helper.append_op(
+        "moe_bias_update",
+        inputs={"Bias": [bias.name], "Counts": [counts.name]},
+        outputs={"BiasOut": [bias.name]}, attrs={"rate": float(rate)})
+    return bias
 
 
 def pipeline_stage(name=None):

@@ -49,7 +49,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                sp_schedule="zigzag", norm="layer_norm", norm_epsilon=1e-5,
                positions="learned", rope_theta=10000.0, qk_norm=False,
                ffn="mlp", moe=None, router_outputs=None, init_scale=None,
-               emb_init_scale=None):
+               emb_init_scale=None, attention="multi_head", mla=None,
+               dense_layers=0, dense_dim=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -65,7 +66,16 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     {"num_experts", "d_hidden", "top_k"} and optionally "act" ('silu') and
     "gated" (True).  An 'moe' block appends its layer's (router logits,
     per-expert counts) to the list `router_outputs`, for the auxiliary
-    losses (`moe_lm_loss`).  `init_scale` draws every matrix (embedding,
+    losses (`moe_lm_loss`).  Further keys of `moe` ("held", "scoring",
+    "select_bias", "renormalise", "routed_scale", "buffer_rows",
+    "shared_hidden": `layers.moe`) make the block one chip's share of its
+    experts; it then appends the layer's `layers.MoeShare`.  The first
+    `dense_layers` blocks of an 'moe' tower have a SiLU-gated MLP of width
+    `dense_dim` without bias instead (DeepSeek's `first_k_dense_replace`).
+    `attention` 'multi_head' or 'latent' with `mla` = {"kv_rank",
+    "qk_nope_dim", "qk_rope_dim", "v_dim"} (`layers.latent_attention`;
+    rotary by construction: `rope_theta`, no position table).
+    `init_scale` draws every matrix (embedding,
     projections, experts, head) from normal(0, init_scale) instead of each
     layer's default; `emb_init_scale` gives the token embedding a scale of
     its own."""
@@ -75,6 +85,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         raise ValueError(f"positions {positions!r}: use 'learned' or 'rope'")
     if ffn not in ("mlp", "moe"):
         raise ValueError(f"ffn {ffn!r}: use 'mlp' or 'moe'")
+    if attention not in ("multi_head", "latent"):
+        raise ValueError(f"attention {attention!r}: use 'multi_head' or "
+                         f"'latent'")
     init = (NormalInitializer(scale=init_scale) if init_scale is not None
             else None)
     attr = {"initializer": init} if init is not None else None
@@ -85,20 +98,43 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                                    epsilon=norm_epsilon)
         return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_epsilon)
 
-    def feed_forward(h):
+    def attend(h):
+        if attention == "latent":
+            return layers.latent_attention(
+                h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
+                param_attr=attr, **mla)
+        return layers.multi_head_attention(
+            h, h, h, num_heads=n_heads, causal=True,
+            param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
+            sp_schedule=sp_schedule,
+            qk_norm_epsilon=norm_epsilon if qk_norm else None,
+            rope_theta=rope_theta if positions == "rope" else None)
+
+    def feed_forward(h, layer):
         if ffn == "mlp":
             m = layers.fc(h, dim * mlp_ratio, num_flatten_dims=2,
                           param_attr=attr, act="gelu")
             return layers.fc(m, dim, num_flatten_dims=2, param_attr=attr)
+        if layer < dense_layers:
+            gate, up = (layers.fc(h, dense_dim, num_flatten_dims=2,
+                                  param_attr=attr, bias_attr=False, act=a)
+                        for a in ("silu", None))
+            return layers.fc(layers.elementwise_mul(gate, up), dim,
+                             num_flatten_dims=2, param_attr=attr,
+                             bias_attr=False)
         T = h.shape[1]
-        m, logits, counts = layers.moe(
+        kinds = {k: v for k, v in moe.items()
+                 if k not in ("num_experts", "d_hidden", "top_k", "act",
+                              "gated")}
+        got = layers.moe(
             layers.reshape(h, [-1, dim]), moe["num_experts"],
             moe["d_hidden"], act=moe.get("act", "silu"),
             top_k=moe["top_k"], gated=moe.get("gated", True),
-            dropless=True, initializer=init)
+            dropless=True, initializer=init, **kinds)
         if router_outputs is not None:
-            router_outputs.append((logits, counts))
-        return layers.reshape(m, [-1, T, dim])
+            router_outputs.append(
+                got if isinstance(got, layers.MoeShare) else got[1:])
+        return layers.reshape(got[0], [-1, T, dim])
 
     emb_attr = attr if emb_init_scale is None else {
         "initializer": NormalInitializer(scale=emb_init_scale)}
@@ -111,19 +147,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         x = layers.dropout(x, dropout_prob, is_test=is_test)
 
     blk = (layers.recompute if remat else contextlib.nullcontext)
-    for _ in range(n_layers):
+    for layer in range(n_layers):
         with blk():
-            h = normed(x)
-            a = layers.multi_head_attention(
-                h, h, h, num_heads=n_heads, causal=True,
-                param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
-                sp_schedule=sp_schedule,
-                qk_norm_epsilon=norm_epsilon if qk_norm else None,
-                rope_theta=rope_theta if positions == "rope" else None)
+            a = attend(normed(x))
             if dropout_prob:
                 a = layers.dropout(a, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, a)
-            m = feed_forward(normed(x))
+            m = feed_forward(normed(x), layer)
             if dropout_prob:
                 m = layers.dropout(m, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, m)
@@ -136,7 +166,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
 # _lm_fns) know
 _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
-               "qk_norm": False, "ffn": "mlp"}
+               "qk_norm": False, "ffn": "mlp", "attention": "multi_head"}
 
 
 def lm_loss(logits, targets, dtype="float32"):
@@ -550,4 +580,65 @@ def build_moe_lm_train_program(seq_len, vocab_size, dim, n_layers, n_heads,
     # "nothing dropped" exactly: seq_len * top_k a sequence
     layers.reduce_sum(routers[-1][1])
     opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def build_mla_moe_lm_train_program(
+        seq_len, vocab_size, dim, n_layers, n_heads, kv_rank, qk_nope_dim,
+        qk_rope_dim, v_dim, dense_dim, num_experts, expert_dim, top_k,
+        shared_experts, held_experts, first_expert=0, buffer_rows=None,
+        routed_scale=1.0, dense_layers=1, norm_epsilon=1e-5,
+        rope_theta=10000.0, balance_weight=1e-4, bias_update_rate=1e-3,
+        bias_init_scale=0.0, dtype="bfloat16", learning_rate=3e-5,
+        init_scale=0.02, emb_init_scale=None):
+    """DeepSeek-V3-shaped decoder (`model_type` deepseek_v3 without a query
+    latent: Moonlight-16B-A3B) as ONE CHIP'S SHARE of an expert-parallel
+    deployment: RMSNorm pre-norm blocks, latent attention, a SiLU-gated MLP
+    of `dense_dim` in the first `dense_layers` blocks and in the others an
+    expert layer whose router scores all `num_experts` by sigmoid, chooses
+    `top_k` by score + bias, renormalises their weights and scales them by
+    `routed_scale`; of those experts this chip holds `held_experts` from
+    `first_expert` on and computes their part in a buffer of `buffer_rows`
+    rows, beside one shared expert of `shared_experts` x `expert_dim`;
+    `vocab_size` is the slice of the vocabulary this chip embeds and
+    scores; no bias, untied head.  Loss: next-token cross entropy +
+    `balance_weight` x the sequence-wise balance loss averaged over the
+    expert layers; Adam; then every expert layer's selection bias moves by
+    `bias_update_rate` against its counts (it takes no gradient).
+    `bias_init_scale` draws the bias from normal(0, that) instead of zeros:
+    a fresh DeepSeek model starts at zero, a checked one must not (a
+    program that forgot the bias would pass).  Returns the loss.  Feeds as
+    `build_lm_train_program`."""
+    from .. import optimizer as opt
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, n_layers, n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm",
+        norm_epsilon=norm_epsilon, positions="rope", rope_theta=rope_theta,
+        attention="latent",
+        mla={"kv_rank": kv_rank, "qk_nope_dim": qk_nope_dim,
+             "qk_rope_dim": qk_rope_dim, "v_dim": v_dim},
+        ffn="moe", dense_layers=dense_layers, dense_dim=dense_dim,
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "sigmoid", "renormalise": True,
+             "routed_scale": routed_scale, "buffer_rows": buffer_rows,
+             "select_bias": NormalInitializer(scale=bias_init_scale),
+             "shared_hidden": shared_experts * expert_dim},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    balance = layers.sums([layers.moe_sequence_balance_loss(
+        s.scores, s.counts, top_k) for s in shares])
+    loss = layers.elementwise_add(
+        loss, layers.scale(balance, scale=balance_weight / len(shares)))
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    for s in shares:
+        layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
     return loss

@@ -91,9 +91,50 @@ def attention_gru_decoder(ctx, ins, attrs):
             "Context": [jnp.moveaxis(ctxs, 0, 1)]}
 
 
+def flash_single_chip(ctx, q, k, v, causal: bool):
+    """The single-chip fast path of an attention emitter: the Pallas flash
+    kernel (VMEM-tiled online softmax) on Q, K [B,H,T,D] and V [B,H,T,Dv],
+    where the trace targets one TPU and the shapes fit the kernel's
+    contract: self-attention lengths, T tiles of 128, a lane-width head
+    (of the values; latent attention's queries and keys carry rotary
+    columns beside it, two lane tiles at most).  Sharded mesh execution
+    keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
+    call).  -> None where it does not apply, else (out, saved).
+
+    Training goes through the custom_vjp pair (FlashAttention-2-style
+    blockwise backward), which generic_grad's jax.vjp honors, and the
+    forward kernel runs once a layer: `saved` is the (out, lse) pair the
+    calling emitter keeps beside ITS outputs (`ctx.keep_for_grad`), and
+    its grad op's re-emission differentiates through them with no second
+    launch (`saved` is None there, and in inference)."""
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    if not pallas_dispatch_ok(ctx):
+        return None
+    T, D, Dv = q.shape[2], q.shape[3], v.shape[3]
+    if not (T % 128 == 0 and Dv <= 128 and (D == Dv or D <= 256)
+            and k.shape[2] == T and v.shape[2] == T):
+        return None
+    from .pallas_kernels import flash_attention as fa
+
+    if ctx.is_test:
+        return fa.flash_attention(q, k, v, causal=causal), None
+    train = fa.make_flash_train(causal=causal)
+    kept = ctx.kept_for_grad()
+    saved = None
+    if kept is not None:
+        out = train.from_saved(q, k, v, *kept)
+    else:
+        out, lse = train.with_lse(q, k, v)
+        saved = (out, lse)
+    ctx.kernel_forward(reused=kept is not None)
+    return out, saved
+
+
 @register_op("scaled_dot_product_attention")
 def scaled_dot_product_attention(ctx, ins, attrs):
-    """Multi-head attention core: Q,K,V [B,H,T,D] → [B,H,T,D].
+    """Multi-head attention core: Q,K [B,H,T,D], V [B,H,T,Dv] → [B,H,T,Dv]
+    (Dv = D everywhere but in latent attention), scores over sqrt(D).
 
     Under a ParallelExecutor whose mesh has an 'sp' axis > 1, dispatches by
     the `sp_mode` attr: 'ring' (default — K/V chunks rotate over ICI,
@@ -139,35 +180,11 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                 f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
     else:
         out = None
-        from .pallas_kernels._common import pallas_dispatch_ok
-
-        if pallas_dispatch_ok(ctx):
-            # single-chip fast path: the Pallas flash kernel (VMEM-tiled
-            # online softmax); training goes through the custom_vjp pair
-            # (FlashAttention-2-style blockwise backward), which
-            # generic_grad's jax.vjp honors.  Sharded mesh execution keeps
-            # the XLA-fused dense path (GSPMD cannot partition the Mosaic
-            # call).  Shape gates per the kernel's contract:
-            # self-attention lengths, T tiles of 128, lane-width head dim.
-            T, D = q.shape[2], q.shape[3]
-            if (T % 128 == 0 and D <= 128 and k.shape[2] == T
-                    and v.shape[2] == T):
-                from .pallas_kernels import flash_attention as fa
-
-                if ctx.is_test:
-                    out = fa.flash_attention(q, k, v, causal=causal)
-                else:
-                    # the forward kernel once a layer: the forward op
-                    # keeps (out, lse), and its grad op's re-emission
-                    # differentiates through them with no second launch
-                    train = fa.make_flash_train(causal=causal)
-                    kept = ctx.kept_for_grad()
-                    if kept is not None:
-                        out = train.from_saved(q, k, v, *kept)
-                    else:
-                        out, lse = train.with_lse(q, k, v)
-                        ctx.keep_for_grad(attrs, [out], (out, lse))
-                    ctx.kernel_forward(reused=kept is not None)
+        got = flash_single_chip(ctx, q, k, v, causal)
+        if got is not None:
+            out, saved = got
+            if saved is not None:
+                ctx.keep_for_grad(attrs, [out], saved)
         if out is None:
             out = ra.attention(q, k, v, causal=causal)
     return {"Out": [out]}

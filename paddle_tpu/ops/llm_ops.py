@@ -1,15 +1,24 @@
 """Ops of the post-2020 decoder block that the GPT-2-shaped tower lacks:
-RMSNorm and rotary position embedding (beyond-reference, like the rest of
-the transformer tier; first user: OLMoE, models/transformer.py).
+RMSNorm, rotary position embedding (beyond-reference, like the rest of
+the transformer tier; first user: OLMoE, models/transformer.py) and latent
+attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B).
 
-Both are plain jax.numpy, so `generic_grad` differentiates them by
+The first two are plain jax.numpy, so `generic_grad` differentiates them by
 re-emission and XLA's CSE merges the re-emitted forward with the first.
 Statistics and rotations are at least float32 whatever the compute dtype
 (`wide_dtype`); the result goes back to the input's dtype."""
 
 from __future__ import annotations
 
+from ..observability.attribution import part_scope
+from ..observability.metrics import REGISTRY as _MET
 from .registry import register_op
+
+_MET_MLA_LAYERS = _MET.counter(
+    "mla_layers_traced_total",
+    "latent attention layers traced (forward emission; once a compile, not "
+    "once a step), by the width of a head's queries and keys (qk_dim), of "
+    "its values (v_dim) and the rank of the K/V latent (kv_rank)")
 
 
 def wide_dtype(dtype):
@@ -19,35 +28,38 @@ def wide_dtype(dtype):
     return jnp.promote_types(dtype, jnp.float32)
 
 
+def rms(x, eps: float, axes, gain=None):
+    """x over sqrt(mean of its squares over `axes` + eps), times `gain`
+    (shaped like those axes) where given; at least float32 inside, x's
+    dtype out."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(wide_dtype(x.dtype))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=axes, keepdims=True) + eps)
+    if gain is not None:
+        y = y * gain.astype(xf.dtype).reshape(tuple(x.shape[a] for a in axes))
+    return y.astype(x.dtype)
+
+
 @register_op("rms_norm")
 def rms_norm(ctx, ins, attrs):
     """X [..., D...] -> Y = X / sqrt(mean(X^2 over the axes from
     `begin_norm_axis`) + epsilon) * Scale (Zhang & Sennrich 2019,
     arXiv:1910.07467).  No mean is subtracted and there is no bias."""
-    import jax
-    import jax.numpy as jnp
-
     x = ins["X"][0]
-    eps = float(attrs.get("epsilon", 1e-5))
     begin = int(attrs.get("begin_norm_axis", 1))
-    axes = tuple(range(begin, x.ndim))
-    xf = x.astype(wide_dtype(x.dtype))
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=axes, keepdims=True) + eps)
-    if ins.get("Scale") and ins["Scale"][0] is not None:
-        y = y * ins["Scale"][0].astype(xf.dtype).reshape(x.shape[begin:])
-    return {"Y": [y.astype(x.dtype)]}
+    gain = ins["Scale"][0] if ins.get("Scale") else None
+    return {"Y": [rms(x, float(attrs.get("epsilon", 1e-5)),
+                      tuple(range(begin, x.ndim)), gain)]}
 
 
-@register_op("rope")
-def rope(ctx, ins, attrs):
-    """Rotary position embedding in its rotate-half form (Su et al. 2021,
-    arXiv:2104.09864, as GPT-NeoX and transformers apply it): X [B, H, T,
-    D] with D even; position t of every head turns the pair (x[i], x[i +
-    D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1."""
+def rotate_half(x, theta: float):
+    """X [..., T, D] with D even turned by position: the pair (x[i], x[i +
+    D/2]) of position t by the angle t * theta ** (-2i / D), positions
+    0..T-1; at least float32 inside, X's dtype out."""
     import jax.numpy as jnp
 
-    x = ins["X"][0]
-    theta = float(attrs.get("theta", 10000.0))
     T, D = x.shape[-2], x.shape[-1]
     if D % 2:
         raise ValueError(f"rope op: head size {D} must be even")
@@ -58,7 +70,74 @@ def rope(ctx, ins, attrs):
     cos, sin = jnp.cos(ang), jnp.sin(ang)          # [T, D/2]
     a, b = xf[..., :half], xf[..., half:]
     y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return {"Out": [y.astype(x.dtype)]}
+    return y.astype(x.dtype)
+
+
+@register_op("rope")
+def rope(ctx, ins, attrs):
+    """Rotary position embedding in its rotate-half form (Su et al. 2021,
+    arXiv:2104.09864, as GPT-NeoX and transformers apply it): X [B, H, T,
+    D] with D even; position t of every head turns the pair (x[i], x[i +
+    D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1."""
+    return {"Out": [rotate_half(ins["X"][0],
+                                float(attrs.get("theta", 10000.0)))]}
+
+
+@register_op("latent_attention")
+def latent_attention(ctx, ins, attrs):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
+    2.1; the `q_lora_rank` null form of DeepSeek-V3's modelling code) over
+    X [B, T, D], causal, H heads:
+
+      q = X WQ -> [B, T, H, dn + dr] = (q_nope, q_pe)
+      c = X WKVA -> [B, T, r + dr] = (c_kv, k_pe): the latent a serving
+          cache would hold, and ONE rotary key all heads share
+      kv = RMSNorm(c_kv; KVNorm) WKVB -> [B, T, H, dn + dv] = (k_nope, v)
+      q = [q_nope; rope(q_pe)], k = [k_nope; rope(k_pe)] in every head,
+      softmax(q k^T / sqrt(dn + dr)) v -> [B, T, H dv], times WO.
+
+    attrs: num_heads, qk_nope_dim (dn), qk_rope_dim (dr), v_dim (dv), theta,
+    epsilon.  RoPE is the rotate-half form (`rotate_half`).  The keys are
+    dn + dr wide and the values dv: on one TPU the two-width flash kernels
+    (attention_ops.flash_single_chip), elsewhere dense attention."""
+    import jax.numpy as jnp
+
+    from ..parallel.ring_attention import attention as dense_attention
+    from .attention_ops import flash_single_chip
+
+    x = ins["X"][0]
+    wq, wkva, wkvb, wo = (ins[k][0] for k in ("WQ", "WKVA", "WKVB", "WO"))
+    H = int(attrs["num_heads"])
+    dn, dr, dv = (int(attrs[k]) for k in ("qk_nope_dim", "qk_rope_dim",
+                                          "v_dim"))
+    theta = float(attrs.get("theta", 10000.0))
+    B, T, _ = x.shape
+    rank = wkva.shape[1] - dr
+    if not ctx.in_grad_replay():
+        _MET_MLA_LAYERS.inc(qk_dim=str(dn + dr), v_dim=str(dv),
+                            kv_rank=str(rank))
+    heads = lambda a: jnp.swapaxes(a, 1, 2)          # [B,T,H,d] <-> [B,H,T,d]
+    with part_scope("mla.project"):
+        q = heads((x @ wq).reshape(B, T, H, dn + dr))
+        c = x @ wkva
+        c_kv = rms(c[..., :rank], float(attrs.get("epsilon", 1e-5)), (2,),
+                   ins["KVNorm"][0])
+        kv = heads((c_kv @ wkvb).reshape(B, T, H, dn + dv))
+    with part_scope("mla.rope"):
+        k_pe = rotate_half(c[:, None, :, rank:], theta)        # [B,1,T,dr]
+        q = jnp.concatenate([q[..., :dn], rotate_half(q[..., dn:], theta)],
+                            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (B, H, T, dr))], axis=-1)
+        v = kv[..., dn:]
+    with part_scope("mla.attend"):
+        got = flash_single_chip(ctx, q, k, v, True)
+        attn, saved = got if got is not None else (
+            dense_attention(q, k, v, causal=True), None)
+    out = heads(attn).reshape(B, T, H * dv) @ wo
+    if saved is not None:
+        ctx.keep_for_grad(attrs, [out], saved)
+    return {"Out": [out]}
 
 
 # ---------------------------------------------------------------------------
@@ -83,5 +162,20 @@ def _rope_cost(ins, outs, attrs):
     return {"flops": 3 * x.size + x.shape[-2] * x.shape[-1]}
 
 
+def _latent_attention_cost(ins, outs, attrs):
+    """The four projections and the causal half of the two score products
+    (q k^T over dn + dr, p v over dv)."""
+    x = ins.get("X", [None])[0]
+    if x is None or len(x.shape) != 3:
+        return {}
+    b, t, _ = x.shape
+    heads = int(attrs["num_heads"])
+    widths = sum(int(attrs[k]) for k in ("qk_nope_dim", "qk_rope_dim",
+                                         "v_dim"))
+    weights = sum(ins[k][0].size for k in ("WQ", "WKVA", "WKVB", "WO"))
+    return {"flops": 2 * b * t * weights + b * heads * t * t * widths}
+
+
+register_cost("latent_attention", _latent_attention_cost)
 register_cost("rms_norm", _rms_norm_cost)
 register_cost("rope", _rope_cost)

@@ -20,7 +20,17 @@ no MoE).  Two forms of one op:
   pallas_kernels/grouped_matmul.py, `_grouped_matmul`) and the combine.
   `gated=True` makes an expert `WO(act(WI x) * (WU x))`.  The router's
   logits and the per-expert token counts leave the op for the auxiliary
-  losses (`moe_router_loss`).  Not under an 'ep' mesh yet."""
+  losses (`moe_router_loss`).  Not under an 'ep' mesh yet.
+* a share of the dropless form (attr `first_expert`; DeepSeek-V3-style
+  routing): this chip holds the contiguous experts [first, first + held)
+  of a layer whose router still has an output for every expert of the
+  deployment.  The router scores all of them (softmax or sigmoid, an
+  untrained selection bias, renormalised and scaled top-k weights),
+  `Counts` is over all of them, and the op computes the chosen pairs that
+  land on held experts, in a static buffer of `buffer_rows` rows whose
+  filled part is data-dependent (`_moe_share`); a shared expert every
+  token passes may ride beside them.  The partial sum is the op's output:
+  nothing stands in for the chips that hold the other experts."""
 
 from __future__ import annotations
 
@@ -36,6 +46,12 @@ _MET_MOE_LAYERS = _MET.counter(
     "dropless expert layers traced (forward emission; once a compile, not "
     "once a step), by top_k, number of experts and the grouped matmul's "
     "implementation")
+_MET_SHARE_LAYERS = _MET.counter(
+    "moe_share_layers_traced_total",
+    "expert layers traced that hold a share of their experts (forward "
+    "emission; once a compile, not once a step), by experts held, experts "
+    "routed over, top_k and the rows of the static buffer the held pairs "
+    "are computed in")
 _MET_GROUPED_BWD = _MET.counter(
     "moe_grouped_backward_total",
     "grouped expert matmuls whose backward was traced (once a compile, not "
@@ -189,6 +205,145 @@ def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act):
     return out.astype(x.dtype), logits, counts.astype(jnp.float32)
 
 
+def _route_scored(x, gate_w, bias, top_k, scoring, renormalise, scale):
+    """Router of the share form, float32 like `_route_top_k`: -> (scores
+    [T, E], weights [T, k], experts [T, k]).  The `top_k` experts are the
+    largest of scores + bias (DeepSeek-V3's `e_score_correction_bias`,
+    which steers the choice and takes no gradient); their weights are the
+    scores WITHOUT it, divided by their sum + 1e-20 where `renormalise`,
+    times `scale`."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    wide = wide_dtype(x.dtype)
+    logits = jnp.dot(x.astype(wide), gate_w.astype(wide),
+                     precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    chosen_by = scores if bias is None else scores + lax.stop_gradient(
+        bias.astype(wide))
+    _, experts = lax.top_k(chosen_by, top_k)
+    # the scores at the chosen indices as a masked sum over the experts,
+    # forward and backward elementwise: a gather of T * k scalars costs the
+    # chip as much as one of T * k rows (0.45 ms at 8192 x 6; PERF.md, PR 30)
+    chosen = experts[..., None] == jnp.arange(scores.shape[-1])
+    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return scores, weights * scale, experts
+
+
+def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
+               rows, route):
+    """-> (out [T, D], scores [T, E] f32, top-k weights [T, k] f32, counts
+    [E] f32, held pairs [1] f32, dropped pairs [1] f32) for the experts
+    [first, first + held) of E, held = wi.shape[0], E = gate_w.shape[1].
+
+    The (token, expert) pairs on held experts are sorted to the front, by
+    expert (stable, so by token within one), and the first `rows` of them
+    fill the buffer the grouped matmuls see; its groups are the held
+    experts' counts, cut where the buffer ends (`_sort_carrying`: the
+    pairs' weights ride the same sort).  Rows past the filled part
+    are in no group: never multiplied, and whatever a grouped product
+    leaves in them is replaced by zero as it comes out, forward and (the
+    select's transpose) backward: on the chip `lax.ragged_dot` and the
+    backward kernels leave rows no group has unwritten, NaN included, and
+    a product with zero would keep the NaN (PERF.md, PR 30: a buffer of T x
+    top_k rows, three quarters of its tiles unvisited, read NaN gradients
+    until every product's output went through a select).  Tokens reach the buffer by a gather and leave it by a
+    scatter-add, both `rows` wide, and autodiff's transposes are the other
+    of the two.  `shared` = (WI, WU or None, WO) of one expert every token
+    passes, or None."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    T, D = x.shape
+    held, n_exp = wi.shape[0], gate_w.shape[1]
+    with part_scope("moe.route"):
+        scores, weights, experts = _route_scored(x, gate_w, bias, top_k,
+                                                 **route)
+    wide = scores.dtype
+    with part_scope("moe.permute"):
+        flat = experts.reshape(-1).astype(jnp.int32)           # [T * k]
+        counts = jnp.sum(jax.nn.one_hot(flat, n_exp, dtype=jnp.int32),
+                         axis=0)
+        local = flat - first
+        here = (local >= 0) & (local < held)
+        order, w = _sort_carrying(jnp.where(here, local, held),
+                                  weights.reshape(-1), rows)
+        ends = jnp.minimum(jnp.cumsum(counts[first:first + held]), rows)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        pairs = jnp.sum(counts[first:first + held])
+        filled = (jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
+        token = order // top_k
+        xs = jnp.where(filled, x[token], jnp.zeros((), x.dtype))
+
+    def grouped(rows_in, w):
+        return jnp.where(filled, _grouped_matmul(ctx, rows_in, w, sizes),
+                         jnp.zeros((), rows_in.dtype))
+
+    with part_scope("moe.experts"):
+        h = _act_fn(act)(grouped(xs, wi).astype(wide))
+        if wu is not None:
+            h = h * grouped(xs, wu).astype(wide)
+        ys = grouped(h.astype(x.dtype), wo)                    # [rows, D]
+    with part_scope("moe.combine"):
+        out = jnp.zeros((T, D), wide).at[token].add(
+            ys.astype(wide) * w[:, None])
+    if shared is not None:
+        with part_scope("moe.shared"):
+            si, su, so = shared
+            m = _act_fn(act)((x @ si).astype(wide))
+            if su is not None:
+                m = m * (x @ su).astype(wide)
+            out = out + (m.astype(x.dtype) @ so).astype(wide)
+    as_f32 = lambda n: n.astype(jnp.float32).reshape(1)
+    # a token's weights largest first: where rounding swaps two of its
+    # experts (or its last with the next) the sorted weights hardly move
+    return (out.astype(x.dtype), scores, lax.top_k(weights, top_k)[0],
+            counts.astype(jnp.float32), as_f32(pairs),
+            as_f32(pairs - ends[-1]))
+
+
+def _sort_carrying(key, values, rows: int):
+    """-> (order [rows] int32, values[order] [rows]): the first `rows`
+    entries of the stable sort of `key` [N], and `values` [N] carried
+    through the same sort.  The values ride the sort forward, and their
+    gradient rides a second sort back (by `order`, the inverse
+    permutation): a sort of 49152 entries is 0.05 ms on the v5e where a
+    gather of 12288 scalars is 0.28 (PERF.md, PR 30)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = key.shape[0]
+
+    def run(key, values):
+        _, order, carried = lax.sort(
+            (key, lax.iota(jnp.int32, n), values), num_keys=1,
+            is_stable=True)
+        return order, carried
+
+    @jax.custom_vjp
+    def take(key, values):
+        order, carried = run(key, values)
+        return order[:rows], carried[:rows]
+
+    def fwd(key, values):
+        order, carried = run(key, values)
+        return (order[:rows], carried[:rows]), order
+
+    def bwd(order, cts):
+        g = jnp.concatenate([cts[1], jnp.zeros(n - rows, cts[1].dtype)])
+        return None, lax.sort((order, g), num_keys=1)[1]
+
+    take.defvjp(fwd, bwd)
+    return take(key, values)
+
+
 def _permute_rows(x, perm, inv):
     """x[perm] for a permutation `perm` with inverse `inv`.  The transpose
     of a gather is a scatter-add; of a permutation it is the gather by the
@@ -226,7 +381,9 @@ def _gather_slots(x, order, inv, k):
     return take(x, order, inv)
 
 
-@register_op("moe", non_diff_outputs=("Counts",))
+@register_op("moe", non_diff_inputs=("Bias",),
+             non_diff_outputs=("Counts", "RouterWeights", "HeldPairs",
+                               "DroppedPairs"))
 def moe(ctx, ins, attrs):
     """X [T, D] tokens; Gate [D, E]; WI [E, D, H]; WO [E, H, D] -> Out [T, D].
 
@@ -236,7 +393,20 @@ def moe(ctx, ins, attrs):
     With `dropless` (see the module's docstring): attrs top_k (1) and gated
     (False; then WU [E, D, H] is an input too), capacity_factor unused;
     outputs Out, RouterLogits [T, E] float32 and Counts [E] float32 (the
-    (token, expert) pairs each expert computed; they sum to T * top_k)."""
+    (token, expert) pairs each expert computed; they sum to T * top_k).
+
+    With `dropless` and `first_expert` (a share; the module's docstring):
+    Gate is [D, E] and WI / WU / WO stack the HELD experts [first_expert,
+    first_expert + held); attrs scoring ('softmax' | 'sigmoid'),
+    renormalise, routed_scale, buffer_rows (T * top_k by default: then no
+    pair can be dropped); inputs Bias [E] (optional: the selection bias)
+    and SI / SU / SO (optional: the shared expert's [D, Hs], [D, Hs], [Hs,
+    D]).  Outputs Out (the held experts' part of the layer plus the shared
+    expert), RouterScores [T, E] float32, RouterWeights [T, top_k] float32
+    (each token's weights, largest first; for a check, it passes no
+    gradient on), Counts [E] (over ALL E: they sum to T * top_k),
+    HeldPairs [1] (the pairs on held experts) and DroppedPairs [1] (those
+    of them the buffer had no row for)."""
     import math
 
     x = ins["X"][0]
@@ -266,9 +436,13 @@ def moe(ctx, ins, attrs):
                 "(ROADMAP.md R2: 16 experts a chip over ep=4); use the "
                 "single-chip Executor, or the capacity form with experts "
                 "= ep")
-        if not 1 <= top_k <= n_exp:
-            raise ValueError(f"moe op: top_k {top_k} not in [1, {n_exp}]")
+        if not 1 <= top_k <= gate_w.shape[1]:
+            raise ValueError(f"moe op: top_k {top_k} not in "
+                             f"[1, {gate_w.shape[1]}]")
         wu = ins["WU"][0] if gated else None
+        if "first_expert" in attrs:
+            return _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo,
+                               top_k, act)
         if not ctx.in_grad_replay():
             _MET_MOE_LAYERS.inc(top_k=str(top_k), experts=str(n_exp),
                                 impl="ragged_dot")
@@ -299,6 +473,39 @@ def moe(ctx, ins, attrs):
     return {"Out": [out]}
 
 
+def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act):
+    """The `moe` op's share form: attrs and optional inputs to
+    `_moe_share`, and its six outputs to their slots."""
+    held, n_exp = wi.shape[0], gate_w.shape[1]
+    first = int(attrs["first_expert"])
+    rows = int(attrs.get("buffer_rows") or x.shape[0] * top_k)
+    if not 0 <= first <= n_exp - held:
+        raise ValueError(f"moe op: held experts [{first}, {first + held}) "
+                         f"are not among the router's {n_exp}")
+    scoring = str(attrs.get("scoring", "softmax"))
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe op: scoring {scoring!r}: use 'softmax' or "
+                         f"'sigmoid'")
+    if not 0 < rows <= x.shape[0] * top_k:
+        raise ValueError(f"moe op: buffer_rows {rows} not in (0, "
+                         f"{x.shape[0] * top_k}]")
+    one = lambda slot: ins[slot][0] if ins.get(slot) else None
+    shared = None
+    if one("SI") is not None:
+        shared = (one("SI"), one("SU"), one("SO"))
+    if not ctx.in_grad_replay():
+        _MET_SHARE_LAYERS.inc(held=str(held), experts=str(n_exp),
+                              top_k=str(top_k), buffer_rows=str(rows))
+    out, scores, weights, counts, pairs, dropped = _moe_share(
+        ctx, x, gate_w, one("Bias"), wi, wu, wo, shared, top_k, act, first,
+        rows, {"scoring": scoring,
+               "renormalise": bool(attrs.get("renormalise", False)),
+               "scale": float(attrs.get("routed_scale", 1.0))})
+    return {"Out": [out], "RouterScores": [scores],
+            "RouterWeights": [weights], "Counts": [counts],
+            "HeldPairs": [pairs], "DroppedPairs": [dropped]}
+
+
 @register_op("moe_router_loss", non_diff_inputs=("Counts",))
 def moe_router_loss(ctx, ins, attrs):
     """The two auxiliary losses of one expert layer, from what the `moe` op
@@ -325,6 +532,41 @@ def moe_router_loss(ctx, ins, attrs):
     balance = n_exp * jnp.sum(counts / T * mean_prob)
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return {"Balance": [balance.reshape(1)], "ZLoss": [z.reshape(1)]}
+
+
+@register_op("moe_sequence_balance_loss", non_diff_inputs=("Counts",))
+def moe_sequence_balance_loss(ctx, ins, attrs):
+    """DeepSeek-V3's sequence-wise balance loss (arXiv:2412.19437, eq. 17
+    to 20) of one expert layer, for ONE sequence: RouterScores [T, E]
+    float32, Counts [E] over all E experts, attr top_k ->
+
+      Balance [1]  sum_e f_e P_e, f_e = Counts_e * E / (top_k * T), P_e the
+                   mean over the tokens of scores_e / sum_e' scores_e'.
+
+    Counts carries no gradient; the loss reaches the router through the
+    scores."""
+    import jax.numpy as jnp
+
+    scores = ins["RouterScores"][0]
+    scores = scores.astype(wide_dtype(scores.dtype))
+    T, n_exp = scores.shape
+    f = ins["Counts"][0].astype(scores.dtype) * (
+        n_exp / (float(attrs["top_k"]) * T))
+    p = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=0)
+    return {"Balance": [jnp.sum(f * p).reshape(1)]}
+
+
+@register_op("moe_bias_update", grad=None)
+def moe_bias_update(ctx, ins, attrs):
+    """The auxiliary-loss-free balancing step (DeepSeek-V3, section 2.1.2):
+    Bias [E] += rate * sign(mean(Counts) - Counts), an expert that got
+    fewer pairs than the mean is preferred a little more next step.  Runs
+    after the backward pass: the step's own routing used the old bias."""
+    import jax.numpy as jnp
+
+    bias, counts = ins["Bias"][0], ins["Counts"][0]
+    step = float(attrs["rate"]) * jnp.sign(jnp.mean(counts) - counts)
+    return {"BiasOut": [bias + step.astype(bias.dtype)]}
 
 
 def _moe_sharded(ctx, x, gate_w, wi, wo, mesh, token_axes, factor, act):
@@ -400,7 +642,16 @@ def _moe_cost(ins, outs, attrs):
     if bool(attrs.get("dropless", False)):
         matmuls = 3 if bool(attrs.get("gated", False)) else 2
         slots = t * int(attrs.get("top_k", 1))
-        return {"flops": 2 * t * d * e + matmuls * 2 * slots * d * h}
+        flops = 0
+        if "first_expert" in attrs:
+            # a share: the expected pairs on held experts under balanced
+            # routing, and the shared expert every token passes
+            slots = slots * wi.shape[0] // e
+            si = ins.get("SI", [None])[0]
+            if si is not None:
+                flops = matmuls * 2 * t * d * si.shape[1]
+        return {"flops": flops + 2 * t * d * e
+                + matmuls * 2 * slots * d * h}
     factor = float(attrs.get("capacity_factor", 1.0))
     routed = int(t * max(factor, 1.0))
     flops = 2 * t * d * e + 4 * routed * d * h
@@ -426,8 +677,9 @@ def _moe_sharding(ctx, ins, outs, attrs):
         return {}
     if bool(attrs.get("dropless", False)):
         lead = tuple(x.spec)[:1]
+        scores = "RouterScores" if "first_expert" in attrs else "RouterLogits"
         return {"Out": [tuple(x.spec)],
-                "RouterLogits": [lead + (None,) if lead else None],
+                scores: [lead + (None,) if lead else None],
                 "Counts": [(None,)]}
     ep = ctx.axis_size("ep")
     if ep > 1:

@@ -338,11 +338,13 @@ def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, **kw):
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale):
-    """The forward kernel's call on [BH, T, D] operands, for both forward
-    entry points.  Memoized and jitted: every layer of a model makes the
-    same call, and one callable lets jit trace the kernel body and lower
-    it to Mosaic once a step program instead of once a layer."""
+def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
+              Dv):
+    """The forward kernel's call on q, k [BH, T, D] and v [BH, T, Dv]
+    operands (the output is v's width), for both forward entry points.
+    Memoized and jitted: every layer of a model makes the same call, and
+    one callable lets jit trace the kernel body and lower it to Mosaic
+    once a step program instead of once a layer."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -357,10 +359,10 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale):
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), kv_idx),
-        pl.BlockSpec((1, bk, D), kv_idx),
+        pl.BlockSpec((1, bk, Dv), kv_idx),
     ]
-    out_specs = [pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, T, D), dtype)]
+    out_specs = [pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, Dv), dtype)]
     kern = _fwd_body if with_lse else _fwd_nolse
     if with_lse:
         out_specs.append(pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0)))
@@ -376,7 +378,7 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale):
             # score rows as [rows, 1] with no relayout
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         # with_lse revisits the SHARED (b,0,0) lse row block across the i
         # dimension — on a Megacore part a "parallel" i could split that
@@ -393,24 +395,28 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale):
 
 def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
     """flash_attention's and flash_attention_fwd's shared way to _fwd_call:
-    the output(s) on [B*H, T, D]."""
+    the output(s) on [B*H, T, Dv]."""
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
     plan = _causal_plan("flash_fwd", B * H, T, bq, bk) if causal else None
     return _fwd_call(B * H, T, D, bq, bk, plan, with_lse, q.dtype,
-                     interpret, s)(*(a.reshape(B * H, T, D)
-                                     for a in (q, k, v)))
+                     interpret, s, Dv)(*(a.reshape(B * H, T, a.shape[-1])
+                                         for a in (q, k, v)))
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = 512, block_k: int = 1024,
                     interpret: bool = False):
-    """q,k,v [B,H,T,D] → [B,H,T,D]. block_q/block_k are performance hints,
-    snapped down to divisors of T; D ≤ 128 recommended (one lane tile)."""
+    """q, k [B,H,T,D], v [B,H,T,Dv] → [B,H,T,Dv] (Dv = D but in latent
+    attention, whose keys carry rotary columns its values lack; the
+    default scale is 1/sqrt(D), the width the scores contract over).
+    block_q/block_k are performance hints, snapped down to divisors of T;
+    D ≤ 128 recommended (one lane tile)."""
     out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    False)
-    return out.reshape(q.shape)
+    return out.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -542,13 +548,15 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
     B, H, T, _D = q.shape
     out, lse = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
                         True)
-    return out.reshape(q.shape), lse.reshape(B * H, T)
+    return out.reshape(v.shape), lse.reshape(B * H, T)
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale):
-    """(dq call, dkv call) on [BH, T, D] operands and (BH, 1, T) lse and
-    delta rows; memoized and jitted like _fwd_call."""
+def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
+               Dv):
+    """(dq call, dkv call) on q, k [BH, T, D], v, dO [BH, T, Dv] operands
+    and (BH, 1, T) lse and delta rows (dq and dk leave in D, dv in Dv);
+    memoized and jitted like _fwd_call."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -576,8 +584,8 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), kv_idx),
-            pl.BlockSpec((1, bk, D), kv_idx),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, Dv), kv_idx),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             row_spec,
             row_spec,
         ],
@@ -596,21 +604,21 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale):
         in_specs=[
             pl.BlockSpec((1, bq, D), q_idx),
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), q_idx),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bq, Dv), q_idx),
             row_spec,
             row_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), dtype),
-            jax.ShapeDtypeStruct((BH, T, D), dtype),
+            jax.ShapeDtypeStruct((BH, T, Dv), dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_bwd_dkv",
@@ -624,9 +632,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
     import jax.numpy as jnp
 
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
-    qf, kf, vf, of, dof = (a.reshape(B * H, T, D)
+    qf, kf, vf, of, dof = (a.reshape(B * H, T, a.shape[-1])
                            for a in (q, k, v, o, do))
     delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
                     axis=-1)  # [BH, T]
@@ -638,10 +647,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
         dq_plan = _causal_plan("flash_bwd_dq", B * H, T, bq, bk)
         dkv_plan = _causal_plan("flash_bwd_dkv", B * H, T, bq, bk)
     dq_call, dkv_call = _bwd_calls(B * H, T, D, bq, bk, dq_plan, dkv_plan,
-                                   q.dtype, interpret, s)
+                                   q.dtype, interpret, s, Dv)
     dq = dq_call(qf, kf, vf, dof, lse3, delta3)
     dk, dv = dkv_call(qf, kf, vf, dof, lse3, delta3)
-    return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(q.shape)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _TRAIN_CACHE = {}

@@ -111,8 +111,10 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # numerically checked in test_ops_grad_sweep.py
     # PR 26: +3 (rms_norm, rope, moe_router_loss — the OLMoE block), each
     # numerically checked in test_llm_ops.py
-    assert len(diffable) == 149, (
+    # PR 30: +2 (latent_attention, moe_sequence_balance_loss — Moonlight's
+    # block), each numerically checked in test_llm_ops.py
+    assert len(diffable) == 151, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 149 - 11
+    assert len(checked) == 151 - 11

@@ -289,3 +289,95 @@ def test_moe_dropless_refuses_an_ep_mesh():
     with pytest.raises(NotImplementedError, match="R2"):
         get_op_info("moe").emit(ctx, ins, {"dropless": True, "top_k": 2,
                                            "gated": True, "act": "silu"})
+
+
+# ---------------------------------------------------------------------------
+# latent attention and the share's balance loss (PR 30)
+
+
+def test_latent_attention_output_and_grad():
+    """Against plain numpy: one rotary key for all heads, RoPE on the
+    rotary columns only, scores over sqrt(dn + dr), values dv wide."""
+    B, T, D, H, dn, dr, dv, r = 2, 6, 8, 2, 4, 2, 3, 5
+    rng = np.random.RandomState(4)
+    ins = {"X": rng.normal(size=(B, T, D)),
+           "WQ": rng.normal(size=(D, H * (dn + dr))) * 0.5,
+           "WKVA": rng.normal(size=(D, r + dr)) * 0.5,
+           "KVNorm": rng.uniform(0.5, 1.5, size=(r,)),
+           "WKVB": rng.normal(size=(r, H * (dn + dv))) * 0.5,
+           "WO": rng.normal(size=(H * dv, D)) * 0.5}
+
+    def rot(x):                                    # [..., T, dr]
+        half = dr // 2
+        ang = np.arange(T)[:, None] * 50000.0 ** (
+            -np.arange(half) / half)[None, :]
+        a, b = x[..., :half], x[..., half:]
+        return np.concatenate([a * np.cos(ang) - b * np.sin(ang),
+                               b * np.cos(ang) + a * np.sin(ang)], -1)
+
+    x = ins["X"]
+    q = (x @ ins["WQ"]).reshape(B, T, H, dn + dr).transpose(0, 2, 1, 3)
+    c = x @ ins["WKVA"]
+    ckv = c[..., :r]
+    ckv = ckv / np.sqrt((ckv * ckv).mean(-1, keepdims=True) + 1e-5) * ins[
+        "KVNorm"]
+    kv = (ckv @ ins["WKVB"]).reshape(B, T, H, dn + dv).transpose(0, 2, 1, 3)
+    qf = np.concatenate([q[..., :dn], rot(q[..., dn:])], -1)
+    kf = np.concatenate([kv[..., :dn], np.broadcast_to(
+        rot(c[:, None, :, r:]), (B, H, T, dr))], -1)
+    s = np.einsum("bhqd,bhkd->bhqk", qf, kf) / np.sqrt(dn + dr)
+    s = np.where(np.tril(np.ones((T, T), bool)), s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    o = np.einsum("bhqk,bhkd->bhqd", p, kv[..., dn:])
+    want = o.transpose(0, 2, 1, 3).reshape(B, T, H * dv) @ ins["WO"]
+    h = OpTestHarness("latent_attention", ins,
+                      {"num_heads": H, "qk_nope_dim": dn, "qk_rope_dim": dr,
+                       "v_dim": dv, "theta": 50000.0, "epsilon": 1e-5},
+                      ["Out"])
+    h.check_output({"Out": want}, atol=1e-5)
+    h.check_grad(["X", "WQ", "WKVA", "KVNorm", "WKVB", "WO"],
+                 output_slot="Out", max_relative_error=1e-2)
+
+
+def test_moe_sequence_balance_loss_output_and_grad():
+    rng = np.random.RandomState(5)
+    scores = 1 / (1 + np.exp(-rng.normal(size=(10, 4))))
+    counts = np.array([7.0, 3.0, 6.0, 4.0])
+    want = np.sum(counts * 4 / (2 * 10)
+                  * (scores / scores.sum(-1, keepdims=True)).mean(0))
+    h = OpTestHarness("moe_sequence_balance_loss",
+                      {"RouterScores": scores, "Counts": counts},
+                      {"top_k": 2}, ["Balance"])
+    h.check_output({"Balance": [want]}, atol=1e-6)
+    h.check_grad(["RouterScores"], output_slot="Balance",
+                 max_relative_error=1e-2)
+
+
+def test_moe_share_op_grad_against_numeric():
+    """The share form through `generic_grad`: X, the router, the held
+    experts and the shared expert, at a buffer twice the held pairs."""
+    rng = np.random.RandomState(6)
+    T, D, E, held, H, S = 12, 6, 8, 3, 4, 5
+    ins = {"X": rng.normal(size=(T, D)),
+           "Gate": rng.normal(size=(D, E)),
+           "Bias": rng.normal(size=(E,)) * 0.1,
+           "WI": rng.normal(size=(held, D, H)) * 0.5,
+           "WU": rng.normal(size=(held, D, H)) * 0.5,
+           "WO": rng.normal(size=(held, H, D)) * 0.5,
+           "SI": rng.normal(size=(D, S)) * 0.5,
+           "SU": rng.normal(size=(D, S)) * 0.5,
+           "SO": rng.normal(size=(S, D)) * 0.5}
+    h = OpTestHarness(
+        "moe", ins,
+        {"dropless": True, "gated": True, "top_k": 3, "act": "silu",
+         "first_expert": 2, "scoring": "sigmoid", "renormalise": True,
+         "routed_scale": 2.446, "buffer_rows": 24},
+        ["Out", "RouterScores", "RouterWeights", "Counts", "HeldPairs",
+         "DroppedPairs"])
+    out = dict(zip(["Out", "RouterScores", "RouterWeights", "Counts",
+                    "HeldPairs", "DroppedPairs"], h.fetch()))
+    assert float(np.asarray(out["DroppedPairs"]).reshape(())) == 0.0
+    assert np.asarray(out["Counts"]).sum() == T * 3
+    h.check_grad(["X", "Gate", "WI", "WU", "WO", "SI", "SU", "SO"],
+                 output_slot="Out", max_relative_error=2e-2)
