@@ -28,13 +28,28 @@ H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   what a loop costs here: K and V of a head are 128 KB each and the next
   head's blocks prefetch behind this one's compute either way.
 - STATIC strips do: straight-line code the scheduler interleaves.  dq
-  0.648 -> 0.446 ms and dkv 0.894 -> 0.716 for 56% and 75% of the square.
+  0.648 -> 0.446 ms and dkv 0.894 -> 0.716 for 56% and 75% of the square
+  (dkv then still on the forward's tile, in two strips a side).
   The forward does not follow the scores at all (0.597 unsplit, 0.600 in
   strips of 128 rows, 0.634 of 256): its time is the per-row softmax
   bookkeeping and the logsumexp row's relayout (0.471 without it).  m and
   l as [rows, 1] columns instead of 1-D rows took 0.942 -> 0.817 off it
   before any skipping, one block a head (`one_block_a_head`) 0.817 ->
   0.597.
+- dkv holds its score tile TRANSPOSED, [K rows, q rows] (`k q^T`, the
+  orientation of splash attention's dK / dV kernel; PR 31, device ms a
+  call from a trace): p^T dO and ds^T q are plain products of operands in
+  the input dtype, and the logsumexp and delta meet the tile as the lane
+  rows they are stored as.  At the two strips a side it had, that alone
+  bought 3% (0.649 -> 0.629; 0.956 -> 0.933 at T 4096, D 128; 4.990 ->
+  4.951 at T 8192, 192 / 128): Mosaic's two tile transposes were cheap.
+  What it bought is THIN strips: the old tile lost by them (0.684 at
+  eight a side), this one gains (0.485 / 0.895 / 4.806, for 56% of the
+  square instead of 75% at T 1024).  The operands' type bought nothing:
+  Mosaic's float32 product at default precision is ONE bf16 pass (dv
+  against dense float32 attention reads the same to four digits either
+  way), and rounding p to bf16 first is one more pass over the tile
+  (0.480 -> 0.485); bf16 stands because it is the precision stated.
 
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
@@ -161,10 +176,13 @@ def _causal_kv_idx(bq: int, bk: int):
 # bound by its per-row softmax bookkeeping and gains nothing from skipping,
 # so its strips are thin where thin costs nothing (0.600 ms a call at 128
 # rows, 0.634 at 256, 0.597 unsplit: T 1024, D 64); dq follows the scores
-# (0.446 / 0.453 / 0.519 / 0.648 at 128 / 256 / 512 / unsplit); dkv's
-# transposed products want tall strips (0.716 at 512, 0.749 at 128, 0.894
-# unsplit).
-_STRIPS_A_SIDE = {"flash_fwd": 8, "flash_bwd_dq": 8, "flash_bwd_dkv": 2}
+# (0.446 / 0.453 / 0.519 / 0.648 at 128 / 256 / 512 / unsplit).  dkv, on its
+# transposed tile, follows them too (device ms a call at 2 / 4 / 8 strips a
+# side, PR 31: 0.629 / 0.563 / 0.485 at T 1024, D 64; 0.933 / 0.914 / 0.895
+# at T 4096, D 128; 4.951 / 4.821 / 4.806 at T 8192, 192 / 128); while its
+# tile lay as the forward's, its two transposes wanted tall strips (0.649 /
+# 0.653 / 0.684; 0.956 / 0.989 / 1.000; 4.990 / 4.866 / 5.006) and it had 2.
+_STRIPS_A_SIDE = {"flash_fwd": 8, "flash_bwd_dq": 8, "flash_bwd_dkv": 8}
 
 
 def _strip_rows(kernel: str, bq: int, bk: int) -> int:
@@ -172,9 +190,8 @@ def _strip_rows(kernel: str, bq: int, bk: int) -> int:
     bq at or under the kernel's share of the block's longer side,
     128-aligned where the block is (a strip's edge is a sublane offset
     into the q block and a lane offset into the logsumexp row).  A
-    (1024, 1024) block is walked in strips of 128, 128 and 512 rows, a
-    test's (32, 32) block in strips of 4, 4 and 16: the same staircase at
-    every scale."""
+    (1024, 1024) block is walked in strips of 128 rows, a test's (32, 32)
+    block in strips of 4: the same staircase at every scale."""
     target = max(bq, bk) // _STRIPS_A_SIDE[kernel]
     step = 128 if bq % 128 == 0 else 1
     sq = max(min(target, bq) // step, 1) * step
@@ -237,14 +254,15 @@ def _causal_plan(kernel: str, bh: int, T: int, bq: int, bk: int) -> _Plan:
     return plan
 
 
-def _below_diagonal(s, ahead: int):
-    """Scores whose first row lies `ahead` positions after their first
-    column, the future set to -1e30."""
+def _below_diagonal(s, ahead: int, q_axis: int = 0):
+    """Scores whose first q row lies `ahead` positions after their first
+    K column, the future set to -1e30; the q rows run along `q_axis` of
+    `s` (1 in dkv's transposed tile)."""
     import jax
     import jax.numpy as jnp
 
-    lead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    lead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
     return jnp.where(lead <= ahead, s, -1e30)
 
 
@@ -502,37 +520,36 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = jnp.zeros(dk_sc.shape, dtype=jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, dtype=jnp.float32)
 
-    def tile(cols, rows, carry, ahead=None):
-        """(dk, dv) of the K/V rows `cols` of this block gathered over the
-        q rows `rows` (from _q_rows), added to carry; masked where `ahead`
-        says how far the first of `rows` lies after the first of `cols`."""
-        dk, dv = carry
+    def update(r0, rows, cols, ahead=None):
+        """Add to (dk, dv) of the K/V rows `cols` of this block what its q
+        rows [r0, r0 + rows) give them; masked where `ahead` says how far
+        q row r0 lies after the first of `cols`.  The score tile is held
+        TRANSPOSED, [cols, rows]: p^T dO and ds^T q are then plain
+        products of operands in the input dtype, and the logsumexp and
+        delta meet the tile as the [1, rows] lane rows they are stored as
+        (the orientation of splash attention's dK / dV kernel)."""
         k = k_ref[0, cols, :]
         v = v_ref[0, cols, :]
-        q, do, lse, delta = rows
-        do = do.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        at = pl.ds(r0, rows)
+        row = pl.ds(q0 + r0, rows)
+        q = q_ref[0, at, :]
+        do = do_ref[0, at, :]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if ahead is not None:
-            s = _below_diagonal(s, ahead)
-        p = jnp.exp(s - lse)
-        dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            st = _below_diagonal(st, ahead, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, :, row])
+        dv_sc[cols, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+        dpt = jax.lax.dot_general(
+            v, do.astype(v.dtype), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        dst = pt * (dpt - delta_ref[0, :, row]) * scale
+        dk_sc[cols, :] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dk, dv
-
-    def update(r0, rows, cols, ahead=None):
-        dk_sc[cols, :], dv_sc[cols, :] = tile(
-            cols, _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows),
-            (dk_sc[cols, :], dv_sc[cols, :]), ahead)
 
     _run_block(q0 - k0, bq, bk, plan, update)
 

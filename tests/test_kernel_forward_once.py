@@ -218,7 +218,7 @@ def test_score_counter_is_the_schedules_geometry():
     assert len(got) == 6
     assert (sum(v for (_, part), v in got.items() if part == "computed")
             / sum(v for (_, part), v in got.items() if part == "square")
-            ) == 0.625
+            ) == 0.5625
 
     # a non-causal call has no half to skip: the family gets nothing
     fluid.reset()
@@ -241,10 +241,9 @@ def test_score_counter_counts_at_trace_time_only(pallas_on_cpu):
                                          "flash_bwd_dkv"}
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         # T 128 under (64, 64) blocks: three of four blocks visited, the
-        # two crossed ones walked in strips of an eighth (a half for dkv)
-        share = 0.75 if kernel == "flash_bwd_dkv" else 0.5625
+        # two crossed ones walked in strips of an eighth
         assert (first[(kernel, "computed")] / first[(kernel, "square")]
-                == (1 + 2 * share) / 4)
+                == (1 + 2 * 0.5625) / 4)
     exe.run(feed=feed, fetch_list=[loss])
     assert _scores() == first
 
@@ -329,42 +328,55 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     assert _counter() == {(SDPA, "1"): float(layers)}
 
 
-# B, H, T, D of the cells' attention: GPT-2-medium at batch 8, OLMoE at 1
-@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (1, 16, 4096, 128)],
-                         ids=["gpt2m_train_bs8", "olmoe_train_t4096"])
+# B, H, T, D, Dv of the cells' attention: GPT-2-medium at batch 8, OLMoE and
+# Moonlight (keys 192 wide, values 128) at 1; and a short chunk of ring
+# attention's, whose blocks are the whole dimension (one UNDER 128 long is
+# refused by Mosaic in all three kernels: a lane offset into the logsumexp
+# row it cannot prove aligned, at PR 31's parent as after it: PERF.md 7)
+@pytest.mark.parametrize("shape", [
+    (8, 16, 1024, 64, 64), (1, 16, 4096, 128, 128), (1, 16, 8192, 192, 128),
+    (2, 4, 256, 64, 64)],
+    ids=["gpt2m_train_bs8", "olmoe_train_t4096", "moonlight_train_t8192",
+         "whole_dimension_blocks"])
 def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
     """The three kernels with their walks, bf16 under the default blocks
     and x64 off as the chip runs them, through Mosaic for the described
     v5e: a strip's edge it cannot align (the lane offset into the
-    (1, 1, T) logsumexp row, a sublane offset into a K block) fails here
-    and not first on the chip."""
+    (1, 1, T) logsumexp row, a sublane offset into a K block, dkv's
+    [K rows, q rows] tile) fails here and not first on the chip."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    B, H, T, D = shape
+    B, H, T, D, Dv = shape
     one = SingleDeviceSharding(v5e)
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one)
+    y = jax.ShapeDtypeStruct((B, H, T, Dv), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32, sharding=one)
     bq, bk = fa._snap_blocks(512, 1024, T, causal_head=D)
-    assert (bq, bk) == ((1024, 1024) if T == 1024 else (512, 1024))
+    assert (bq, bk) == ((T, T) if T <= 1024 else (512, 1024))
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         plan = fa._schedule(T, bq, bk, fa._strip_rows(kernel, bq, bk))
         assert plan.computed <= T * T * 0.75
     with jax.enable_x64(False):
         calls = {
             "flash_fwd": jax.jit(lambda q, k, v: fa.flash_attention_fwd(
-                q, k, v, causal=True)).lower(x, x, x),
+                q, k, v, causal=True)).lower(x, x, y),
             "flash_fwd_nolse": jax.jit(lambda q, k, v: fa.flash_attention(
-                q, k, v, causal=True)).lower(x, x, x),
+                q, k, v, causal=True)).lower(x, x, y),
             "flash_bwd": jax.jit(
                 lambda q, k, v, o, l, do: fa.flash_attention_bwd(
                     q, k, v, o, l, do, causal=True)).lower(
-                        x, x, x, x, lse, x)}
+                        x, x, y, y, lse, y),
+            # the single-shot body, as a ring step off the diagonal runs it
+            "flash_bwd_whole": jax.jit(
+                lambda q, k, v, o, l, do: fa.flash_attention_bwd(
+                    q, k, v, o, l, do, causal=False)).lower(
+                        x, x, y, y, lse, y)}
         for name, lowered in calls.items():
             text = lowered.compile().as_text()
             assert text.count('custom_call_target="tpu_custom_call"') == (
-                2 if name == "flash_bwd" else 1), name
+                2 if name.startswith("flash_bwd") else 1), name
 
 
 # ---------------------------------------------------------------------------

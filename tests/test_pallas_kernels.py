@@ -763,18 +763,114 @@ def test_flash_causal_walk_matches_dense(case, walk_spy, monkeypatch):
         # below the diagonal: the last q block against the first K block
         assert plan.full == (T - bq >= bk - 1)
     if case == "gpt2m_one_block_a_head":
-        # (1024, 1024) at T 1024, a thirty-second: the forward and dq in
-        # strips of 4 rows that see 4, 8, ... 32 columns, dkv in two of 16
-        assert plans[0].walks == (
+        # (1024, 1024) at T 1024, a thirty-second: all three kernels in
+        # strips of 4 rows that see 4, 8, ... 32 columns
+        assert plans[0].walks == plans[2].walks == (
             (0, tuple((4 * i, 4 * i + 4, True) for i in range(8))),)
-        assert plans[2].walks == ((0, ((0, 16, True), (16, 32, True))),)
     if case == "olmoe_8x4_full_crossed_and_skipped":
         # (512, 1024) at T 4096: crossed blocks at d = 0 and d = 512, the
         # last strip of the second reaching the block's whole width
         assert [d for d, _ in plans[0].walks] == [0, 16]
         assert plans[0].walks[1][1][-1] == (12, 32, True)
-        assert plans[2].walks == ((0, ((0, 16, True),)),
-                                  (16, ((0, 32, True),)))
+        assert plans[2].walks == plans[0].walks
+
+
+@pytest.mark.parametrize("strips", [2, 4, 8])
+@pytest.mark.parametrize("geometry", [(32, 32, 32), (64, 16, 32)],
+                         ids=["one_block_a_head", "several_blocks"])
+@pytest.mark.parametrize("widths", [(16, 16), (24, 16)],
+                         ids=["Dv_is_D", "192_128_shaped"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "whole"])
+def test_flash_dkv_transposed_tile_matches_dense(causal, widths, geometry,
+                                                 strips):
+    """dk and dv of the dkv kernel, which holds its score tile transposed
+    ([K rows, q rows]: `k q^T`), against dense float32 attention: masked
+    and not, keys wider than values as latent attention's are, one block
+    a head and several, the block's longer side walked in 2, 4 and 8
+    strips."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    T, bq, bk = geometry
+    D, Dv = widths
+    rng = np.random.RandomState(strips)
+    q, k = (jnp.asarray(rng.randn(1, 2, T, D).astype(np.float32))
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.randn(1, 2, T, Dv).astype(np.float32))
+             for _ in range(2))
+    (out, lse), vjp = jax.vjp(lambda *a: _dense_f32(*a, causal), q, k, v)
+    want = vjp((do, jnp.zeros_like(lse)))
+    plan = None
+    if causal:
+        plan = fa._schedule(T, bq, bk, min(max(bq, bk) // strips, bq))
+    # the kernel alone, on the dense forward's output and logsumexp
+    _dq, dkv = fa._bwd_calls(2, T, D, bq, bk, plan, plan, q.dtype, True,
+                             1.0 / D ** 0.5, Dv)
+    flat = [a.reshape(2, T, a.shape[-1]) for a in (q, k, v, do)]
+    dk, dv = dkv(*flat, lse.reshape(2, 1, T),
+                 (out * do).sum(-1).reshape(2, 1, T))
+    for name, got, ref in (("dk", dk, want[1]), ("dv", dv, want[2])):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref[0]),
+                                   atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations
+    (a pallas_call's body, the branches of a `pl.when`)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "whole"])
+def test_flash_dkv_body_multiplies_plain_bf16_operands(causal):
+    """The traced dkv body of a bf16 call: four products a strip (k q^T,
+    p^T dO, v dO^T, ds^T q), none contracting dimension 0 of its left
+    operand (a transposed left operand is a transpose of the whole score
+    tile in Mosaic), none with a float32 operand, the scores accumulated
+    in float32; no transpose anywhere, and no [rows, 1] column made of the
+    logsumexp or delta rows."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    BH, T, D, Dv, bq, bk = 2, 64, 24, 16, 32, 64
+    plan = None
+    if causal:
+        plan = fa._schedule(T, bq, bk, fa._strip_rows("flash_bwd_dkv",
+                                                      bq, bk))
+    _dq, dkv = fa._bwd_calls(BH, T, D, bq, bk, plan, plan, jnp.bfloat16,
+                             True, 0.25, Dv)
+    wide = jax.ShapeDtypeStruct((BH, T, D), jnp.bfloat16)
+    thin = jax.ShapeDtypeStruct((BH, T, Dv), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)
+    (call,) = [e for e in _eqns(jax.make_jaxpr(dkv)(
+        wide, wide, thin, thin, row, row).jaxpr)
+        if e.primitive.name == "pallas_call"]
+    body = list(_eqns(call.params["jaxpr"]))
+    dots = [e for e in body if e.primitive.name == "dot_general"]
+    strips = sum(len(w) for _, w in plan.walks) if causal else 1
+    assert len(dots) == 4 * strips
+    for e in dots:
+        (lhs_contract, rhs_contract), _batch = e.params["dimension_numbers"]
+        lhs, rhs = (x.aval for x in e.invars)
+        assert lhs_contract == (1,), e
+        assert rhs_contract in ((0,), (1,)), e
+        assert lhs.dtype == rhs.dtype == jnp.bfloat16, e
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+    # the score-shaped results: [K rows, q rows], the q rows on the lanes
+    sq = plan.sq if causal else bq
+    scores = [e.outvars[0].aval.shape for e in dots
+              if e.params["dimension_numbers"][0][1] == (1,)]
+    assert scores and all(shape[1] == sq for shape in scores), scores
+    names = {e.primitive.name for e in body}
+    assert "transpose" not in names, names
+    for e in body:
+        for out in e.outvars:
+            shape = getattr(out.aval, "shape", ())
+            assert not (len(shape) == 2 and shape[1] == 1), e
+    assert {"exp", "dot_general"} <= names
 
 
 @pytest.mark.parametrize("mutant", ["stops_a_strip_short", "unmasked",
@@ -842,7 +938,6 @@ def test_flash_schedule_counts_what_the_strips_compute(geometry):
         assert (beyond[:, 1] - beyond[:, 0] < sq).all()
         shares[kernel] = plan.computed / (T * T)
     if geometry == (1024, 1024, 1024):  # gpt2m_train_bs8
-        assert shares == {"flash_fwd": 0.5625, "flash_bwd_dq": 0.5625,
-                          "flash_bwd_dkv": 0.75}
+        assert shares == dict.fromkeys(KERNELS, 0.5625)
     if geometry == (4096, 512, 1024):  # olmoe_train_t4096
         assert all(v <= 0.5625 for v in shares.values())
