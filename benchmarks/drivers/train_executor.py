@@ -13,6 +13,8 @@ that the per-layer readers read:
   traced                          the same for the traced slice, or None
   trace_path                      the .xplane.pb of a traced run, or None
   checks                          reference and loss checks, with numbers
+  compared                        {name: [number, limit]}: every number
+                                  `correct` rests on beside its limit
 
 `train_parallel` reuses everything here and swaps the executor.
 """
@@ -188,8 +190,12 @@ def run(ctx, make_executor=make_executor) -> dict:
 
     finite = all(math.isfinite(x) for x in [first_loss, *losses.values()])
     fell = finite and losses[fell_step] < first_loss
-    no_compile = window["compile_events"] == 0 and (
-        traced is None or traced["compile_events"] == 0)
+    compile_events = window["compile_events"] + (
+        traced["compile_events"] if traced else 0)
+    no_compile = compile_events == 0
+    compared = {k: [errors[k], ref.TOL[k]] for k in errors}
+    compared["loss_at_fell_step"] = [losses[fell_step], first_loss]
+    compared["compile_events_in_window"] = [compile_events, 0]
     window["samples"] = window["steps"] * batch
     if traced is not None:
         traced["samples"] = traced["steps"] * batch
@@ -202,6 +208,7 @@ def run(ctx, make_executor=make_executor) -> dict:
             "setup_s": setup_s},
         "devices": devices, "setup": setup, "window": window,
         "traced": traced, "trace_path": trace_path, "batch": batch,
+        "compared": compared,
         "checks": {"reference_ok": ref_ok, "reference_errors": errors,
                    "tolerances": {k: ref.TOL[k] for k in errors},
                    "first_loss": first_loss, "loss_fell_step": fell_step,

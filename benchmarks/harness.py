@@ -80,6 +80,17 @@ def resolve(dotted: str):
     return getattr(importlib.import_module(mod), attr)
 
 
+def flops_per_sample(config: dict) -> float:
+    """The operations one sample needs, by a configuration's `flops` entry:
+    a `function`, its `args`, and optionally the `module` it lives in, a
+    file of benchmarks/ (default `flops`), so that a configuration whose
+    count flops.py cannot make names its own file and no stand-in."""
+    spec = config["flops"]
+    fn = getattr(load_module(".", spec.get("module", "flops")),
+                 spec["function"])
+    return fn(**spec["args"])
+
+
 def metrics_of(manifest: dict, group: str, cell_name: str) -> list:
     """The metrics of `group` ('end_to_end' or 'per_layer') that `cell_name`
     reports: those with no `workloads` key, and those that list it."""
@@ -327,10 +338,21 @@ def device_block(devices, trace_summary=None) -> dict:
 
 
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, breakdown=None) -> str:
-    """The one JSON object the driver reads, with exactly its keys."""
+                device: dict, breakdown=None, compared=None) -> str:
+    """The one JSON object the driver reads, with exactly its keys, and
+    last `compared`: {name: [number, limit]}, every number `correct` rests
+    on beside its limit (the driver keeps the line's end of a run that was
+    not correct, so this is what the next session sees of it)."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    if compared is not None:
+        out["compared"] = compared
     return json.dumps(out)
+
+
+def compared_lines(compared: dict) -> list:
+    """`compared` as the last lines of standard error, one a number."""
+    return [f"compared {name}: {value!r} limit {limit!r}"
+            for name, (value, limit) in compared.items()]

@@ -53,8 +53,8 @@ CENTERED = ("sample_loss",)
 
 # Tolerances: program (bf16 weights and activations, f32 batch-norm
 # statistics and loss) against this float32 reference; arrays by
-# |got - want| / |want| in the 2-norm, the loss relative.  Each is 1.5
-# times the worst reading on the v5e (PERF.md, PR 23, "reference
+# |got - want| / |want| in the 2-norm, the loss relative.  Three of them
+# are 1.5 times the worst reading on the v5e (PERF.md, PR 23, "reference
 # readings": sample_loss 0.087 to 0.121, grad_-2 0.067 to 0.105, grad_-4
 # 0.178 to 0.274 over both cells' seeds; the four-chip cell's batch of 512
 # reads 0.7 of the one-chip cell's in the two gradients and the same in
@@ -62,11 +62,33 @@ CENTERED = ("sample_loss",)
 # program (sample_loss 0.095 to 0.108, grad_-2 0.086 to 0.097, grad_-4
 # 0.23 to 0.26), so the bound is bf16's own error with half as much again:
 # a rounding 1.5 times coarser than bf16 fails.  `loss` and `grad_-1` are
-# means whose roundings mostly cancel, so their readings scatter (0.00002
-# to 0.0021; 0.0028 to 0.0055) and their bounds are 1.5 to 2 times the
-# worst.
-TOL = {"loss": 0.004, "sample_loss": 0.18, "grad_-2": 0.16,
-       "grad_-1": 0.008, "grad_-4": 0.4}
+# means whose roundings mostly cancel, so their readings scatter and their
+# bounds are 1.5 to 2 times the worst of the widest sweep made: 75 seeds of
+# the one-chip cell on the v5e (PERF.md, PR 32; reference_sweep.py; the
+# other three keys read as PR 23 found them, 0.135, 0.103 and 0.277 at the
+# worst).
+#   loss     0.0000 to 0.00385, half-normal with an rms of 0.00147: the old
+#            bound of 0.004 (from 0.0021, a dozen seeds) stood 2.7 rms out
+#            and would have failed one honest run in 150.  0.007 is 1.8
+#            times the worst and 4.8 rms out.
+#   grad_-1  73 seeds read 0.0030 to 0.0059, two read 0.0096 and 0.0144
+#            (seed 2800113, which failed the old 0.008 at every commit:
+#            PERF.md, PR 28).  No fault: the images are all alike, so every
+#            image gives a class the same probability, and in those two
+#            seeds ONE class holds 0.04 and 0.09 of it (0.013 to 0.055
+#            elsewhere); its logit, about 4.5, is kept in bf16 to 1/64, the
+#            same rounding in all 128 images, which moves that one
+#            coordinate of the mean by 2^-10 and 2^-9: 91% and 96% of the
+#            whole error.  The mechanism is bounded by the rounding of one
+#            logit under 8 over that class's share of the gradient's norm,
+#            0.021 x 0.67 here, so 0.028, twice the worst, has room.  The
+#            fp8 control reads 0.005 here (a mean again): this key holds
+#            the bias gradient's arithmetic (a sum for a mean, a gradient
+#            left out: errors of 1 and more), not the precision, which
+#            `sample_loss`, `grad_-2` and `grad_-4` hold (control 0.32 to
+#            0.46, 0.18 to 0.21, 0.44 to 0.48 on three seeds at batch 128).
+TOL = {"loss": 0.007, "sample_loss": 0.18, "grad_-2": 0.16,
+       "grad_-1": 0.028, "grad_-4": 0.4}
 
 
 def _conv(x, w, stride, pad):
@@ -180,11 +202,20 @@ def check_fn(params, image, label, depth: int = 50,
     return out
 
 
-def train_check(params, feed: dict, config: dict) -> dict:
+def train_check(params, feed: dict, config: dict,
+                act: str = "float32") -> dict:
     """On the device, from the same weights and batch as the program's
     step."""
     import jax
 
     depth = int(config["depth"])
-    return jax.jit(lambda ps, image, label: check_fn(ps, image, label, depth))(
+    return jax.jit(
+        lambda ps, image, label: check_fn(ps, image, label, depth, act))(
         list(params), feed["image"], feed["label"].reshape(-1))
+
+
+def control_check(params, feed: dict, config: dict) -> dict:
+    """The control: this reference with its stored activations in the
+    nearest precision below the configuration's bfloat16.  Put in the
+    program's place it has to fail `TOL` (reference_sweep.py --control)."""
+    return train_check(params, feed, config, act="float8_e4m3fn")

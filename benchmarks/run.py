@@ -7,9 +7,11 @@ One process, on the machine it is started on.  It needs a TPU with at least
 the chips the cell asks for and otherwise exits non-zero, naming what it
 found, before building any program: there is no CPU mode.  The last line of
 its standard output is the one JSON object the driver reads (`correct`,
-`attempted`, `failed`, `metrics`, `device`, and `breakdown` in a traced
-run); the line before it carries the checks and every number in full, and
-the same goes to chiprun_out/ (git-ignored).
+`attempted`, `failed`, `metrics`, `device`, `breakdown` in a traced run, and
+last `compared`: every number `correct` rests on beside its limit, which are
+also the last lines of standard error); the line before it carries the
+checks and every number in full, and the same goes to chiprun_out/
+(git-ignored).
 
 `--trace 0` reports the cell's end-to-end metrics, measured with the
 profiler off.  `--trace 1` reports its per-layer metrics: host-clock ones
@@ -137,12 +139,14 @@ def main(argv=None) -> int:
             "trace_summary": summary, "detail": detail}
     line = harness.result_line(
         record["correct"], record["attempted"], record["failed"], metrics,
-        harness.device_block(found, summary), breakdown)
+        harness.device_block(found, summary), breakdown, record["compared"])
     with open(os.path.join(out_dir, tag + ".json"), "w",
               encoding="utf-8") as f:
         json.dump({"info": info, "result": json.loads(line)}, f, indent=1)
     print(json.dumps({"info": info}), flush=True)
     print(line, flush=True)
+    print("\n".join(harness.compared_lines(record["compared"])),
+          file=sys.stderr, flush=True)
     return 0
 
 

@@ -165,14 +165,108 @@ def test_every_file_a_cell_names_resolves(cell):
                                         traffic["generator"]).generate)
     ref = harness.load_module("reference", config["name"])
     assert ref.TOL and callable(ref.train_check)
-    flops = harness.load_module(".", "flops")
-    assert getattr(flops, config["flops"]["function"])(
-        **config["flops"]["args"]) > 0
+    assert harness.flops_per_sample(config) > 0
     assert callable(harness.resolve(config["train"]["builder"]))
     assert traffic["loss_fell_step"] % traffic["loss_read_every"] == 0
     for m in harness.metrics_of(MANIFEST, "per_layer", cell):
         assert callable(harness.load_module("layer_metrics",
                                             m["name"]).read)
+
+
+def test_a_configurations_flops_entry_may_name_its_module():
+    """`flops` names a function of benchmarks/flops.py, or of the file its
+    optional `module` names: by hand, the default and a named one."""
+    dense = harness.load_json("configs", "gpt2-medium")
+    assert "module" not in dense["flops"]
+    F = harness.load_module(".", "flops")
+    assert harness.flops_per_sample(dense) == getattr(
+        F, dense["flops"]["function"])(**dense["flops"]["args"])
+    moe = harness.load_json("configs", "olmoe-1b-7b")
+    named = {"flops": dict(moe["flops_moe"], module="flops_moe")}
+    assert harness.flops_per_sample(named) == harness.load_module(
+        ".", "flops_moe").olmoe_train_flops_per_sample(
+        **moe["flops_moe"]["args"])
+    assert harness.flops_per_sample(named) != harness.flops_per_sample(moe)
+    with pytest.raises(AttributeError):  # no `module`: flops.py is asked
+        harness.flops_per_sample({"flops": moe["flops_moe"]})
+    with pytest.raises(FileNotFoundError):
+        harness.flops_per_sample({"flops": dict(moe["flops"], module="no")})
+
+
+def _with_entries_appended(manifest: dict) -> dict:
+    """The manifest as a later PR leaves it: a configuration, a cell on it
+    and a per-layer metric APPENDED, the cell's name appended to every
+    list that holds all the cells there are (what a training cell joins)."""
+    m = copy.deepcopy(manifest)
+    cells = {c["name"] for c in m["workloads"]}
+    m["configs"].append({
+        "name": "appended-config", "reduced": [],
+        "source": "https://example.org/appended-config",
+        "file": "tests/benchmarks/appended_config.json",
+        "why": "no model: what a later PR's configuration looks like"})
+    m["workloads"].append({
+        "name": "appended_cell", "config": "appended-config",
+        "traffic": m["workloads"][0]["traffic"], "chips": 1,
+        "why": "no cell: what a later PR's cell looks like"})
+    for x in m["end_to_end"] + m["per_layer"]:
+        if set(x.get("workloads", ())) == cells:
+            x["workloads"].append("appended_cell")
+    m["per_layer"].append({
+        "name": "appended_metric", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": m["per_layer"][0]["layer"],
+        "moves": "train_samples_per_s", "workloads": ["appended_cell"]})
+    return m
+
+
+def _manifest_tests() -> dict:
+    """Every test of tests/benchmarks/ that reads the manifest as a whole:
+    `test_manifest*` by name (benchmarks/README.md asks a cell's own test
+    for that name), and the Moonlight readers' test."""
+    import glob
+    import importlib
+
+    found = {}
+    for path in sorted(glob.glob(os.path.join(HERE, "test_*.py"))):
+        mod = importlib.import_module(os.path.basename(path)[:-3])
+        for name, fn in vars(mod).items():
+            if callable(fn) and (name.startswith("test_manifest") or name in (
+                    "test_run_seconds_fits_a_full_check_of_24_cells",
+                    "test_the_seven_readers_say_what_their_entries_will")):
+                found[f"{mod.__name__}::{name}"] = (mod, fn)
+    found.pop(f"{__name__}::test_manifest_tests_hold_with_a_cell_appended")
+    return found
+
+
+def test_manifest_tests_hold_with_a_cell_appended(monkeypatch):
+    """A later PR appends a configuration, a cell and a per-layer metric and
+    may edit no file that is there, so no test may hold an entry to its
+    PLACE in a list (PR 30's cell test held its entries to the lists' ends,
+    PR 27's its metric to `per_layer`'s: nothing could be added, PERF.md
+    section 6, PR 32).  Every manifest-reading test runs again here on the
+    manifest with entries appended, and all of them have to pass."""
+    import traceback
+
+    tests = _manifest_tests()
+    assert len(tests) >= 9 and len({m for m, _ in tests.values()}) >= 4
+    later = _with_entries_appended(harness.load_manifest())
+    monkeypatch.setattr(harness, "load_manifest",
+                        lambda root=ROOT: copy.deepcopy(later))
+    for mod, _ in tests.values():
+        for name, value in (
+                ("MANIFEST", later),
+                ("CELLS", [c["name"] for c in later["workloads"]]),
+                ("PER_LAYER", [x["name"] for x in later["per_layer"]])):
+            if hasattr(mod, name):
+                monkeypatch.setattr(mod, name, value)
+    failed = []
+    for ident, (_, fn) in tests.items():
+        try:
+            fn()
+        except Exception as e:  # every failure is named, not the first
+            at = traceback.extract_tb(e.__traceback__)[-1]
+            failed.append(f"{ident} at {os.path.basename(at.filename)}:"
+                          f"{at.lineno}: {at.line}")
+    assert not failed, "\n".join(failed)
 
 
 @pytest.mark.parametrize("name", PER_LAYER)
@@ -530,6 +624,75 @@ def test_loss_fell_is_read_at_a_fixed_step_whatever_the_window(tmp_path):
                                   loss_fell_step=5), tmp_path))
 
 
+def test_reference_sweep_reads_every_key_and_its_control_fails(tmp_path):
+    """reference_sweep.py at toy size on the CPU: one row a seed from the
+    cell's own driver, every compared key beside its tolerance; the float32
+    program agrees with the reference, and the reference in fp8, put in the
+    program's place, fails `sample_loss` and one key more."""
+    import paddle_tpu as fluid
+
+    S = harness.load_module(".", "reference_sweep")
+    ref = harness.load_module("reference", "resnet50")
+    rows = list(S.sweep(
+        {"name": "toy"}, _toy_resnet("float32"),
+        _toy_traffic("train_staged_bs128", batch=8, loss_fell_step=4),
+        [3, 2 ** 31 + 5],
+        lambda i: fluid.CPUPlace(), str(tmp_path / "trace"), control=1))
+    assert [r["seed"] for r in rows] == [3, 2 ** 31 + 5]
+    for r in rows:
+        assert r["correct"] and set(ref.TOL) < set(r["compared"])
+        for k, tol in ref.TOL.items():
+            assert r["compared"][k][1] == tol and r["compared"][k][0] < 1e-3
+    assert "control" in rows[0] and "control" not in rows[1]
+    failed = {k for k, e in rows[0]["control"].items()
+              if not e <= ref.TOL[k]}
+    assert "sample_loss" in failed and len(failed) >= 2, rows[0]
+
+
+def test_a_step_that_leaves_its_state_unchanged_is_not_correct(tmp_path):
+    """The rest of a run driven with the timed path broken underneath: an
+    executor whose step computes and fetches as it should and then puts the
+    parameters back where they were.  The loss cannot fall, `correct` comes
+    out false, and `compared` names the number that failed beside its
+    limit (run.py prints it last on both streams)."""
+    import paddle_tpu as fluid
+
+    drv = harness.load_module("drivers", "train_executor")
+
+    def broken(ctx, fluid_):
+        exe, devices, place = drv.make_executor(ctx, fluid_)
+        run, kept = exe.run, {}
+
+        def run_and_undo(program=None, **kw):
+            out = run(program, **kw) if program is not None else run(**kw)
+            scope = fluid.global_scope()
+            names = [p.name for p in fluid.default_main_program()
+                     .global_block().all_parameters()]
+            if program is not None:  # the startup program: keep its weights
+                kept.update({n: np.array(scope.find_np(n)) for n in names})
+            else:
+                for n in names:
+                    scope.set(n, place(n, kept[n].astype(
+                        scope.find_np(n).dtype)))
+            return out
+
+        exe.run = run_and_undo
+        return exe, devices, place
+
+    rec = drv.run(_ctx(_toy_lm("float32"),
+                       _toy_traffic("train_staged_bs8", batch=2,
+                                    loss_fell_step=4, loss_read_every=2,
+                                    staged_batches=1), tmp_path,
+                       seconds=0.01), make_executor=broken)
+    assert rec["correct"] is False and rec["checks"]["reference_ok"]
+    value, limit = rec["compared"]["loss_at_fell_step"]
+    assert not value < limit and limit == rec["checks"]["first_loss"]
+    for k, tol in rec["checks"]["tolerances"].items():
+        assert rec["compared"][k] == [rec["checks"]["reference_errors"][k],
+                                      tol]
+    assert rec["compared"]["compile_events_in_window"] == [0, 0]
+
+
 # ---------------------------------------------------------------------------
 # the comparison that decides `correct`: it has to be able to fail
 
@@ -745,7 +908,17 @@ def test_result_line_has_exactly_the_contracts_keys():
                                   "memory_peak_bytes"}
     traced = json.loads(harness.result_line(
         True, 1, 0, {}, device, {"device_ops": [], "idle_gaps": []}))
-    assert list(traced)[-1] == "breakdown" and "\n" not in line
+    assert set(traced) - set(got) == {"breakdown"} and "\n" not in line
+    # every number compared beside its limit: a key of its own, the last
+    compared = {"grad_0": [0.01, 0.04], "compile_events_in_window": [0, 0]}
+    both = json.loads(harness.result_line(
+        False, 1, 0, {}, device, {"device_ops": [], "idle_gaps": []},
+        compared))
+    assert list(both)[-1] == "compared" and both["compared"] == compared
+    assert set(both) - set(traced) == {"compared"}
+    assert harness.compared_lines(compared) == [
+        "compared grad_0: 0.01 limit 0.04",
+        "compared compile_events_in_window: 0 limit 0"]
 
 
 # ---------------------------------------------------------------------------

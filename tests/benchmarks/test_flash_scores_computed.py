@@ -72,14 +72,18 @@ def test_reader_finds_nothing_in_a_program_without_the_counter(monkeypatch):
 
 
 def test_manifest_entry_names_the_two_lm_cells():
+    """The two cells PR 27 named are in the list, and whichever later cell
+    runs a causal flash kernel may join them; where the entry stands among
+    the others is the driver's business, never a test's."""
     m = harness.load_manifest()
     (entry,) = [x for x in m["per_layer"] if x["name"] == NAME]
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "Pallas kernels",
-        "moves": "train_samples_per_s",
-        "workloads": ["gpt2m_train_bs8", "olmoe_train_t4096"]}
-    assert m["per_layer"][-1] is entry  # appended, nothing moved
+        "moves": "train_samples_per_s"}
+    assert {"gpt2m_train_bs8", "olmoe_train_t4096"} <= set(
+        entry["workloads"])
+    assert len(set(entry["workloads"])) == len(entry["workloads"])
     for cell in entry["workloads"]:
         assert NAME in {x["name"] for x in
                         harness.metrics_of(m, "per_layer", cell)}

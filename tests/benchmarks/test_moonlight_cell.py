@@ -4,8 +4,8 @@ program (one chip's share) against its plain reference at a toy size
 of the reference, the counts of benchmarks/flops_mla.py by hand, the share's
 reduction and the seven readers on recorded instructions, and the AOT
 compile of the cell's real step for a described v5e.
-tests/benchmarks/test_benchmark.py (not edited) holds the manifest-wide
-rules over the same files.
+tests/benchmarks/test_benchmark.py holds the manifest-wide rules over the
+same files.
 """
 
 from __future__ import annotations
@@ -101,16 +101,19 @@ def test_manifest_entries_of_the_cell():
             "dispatch_execute_ms.train", "dispatch_writeback_ms.train",
             "idle_in_dispatch_pct.train", "kernel_forward_reruns",
             "compile_s", "cache_misses"} <= per
+    # the seven readers this cell brought, and the counter of the flash
+    # kernels' score elements, which counts this cell's calls as any other's
+    assert set(READERS) | {"flash_scores_computed_pct"} <= per
     # one head size, OLMoE's key names, flops_moe.py: not this cell's
     assert not per & {"mfu_pct", "mfu_active_pct", "flash_fwd_roofline",
-                      "moe_device_share_pct", "flash_scores_computed_pct",
-                      "collective_exposed_ms"}
-    # the new cell and configuration are the LAST of their lists
-    assert m["workloads"][-1]["name"] == CELL
-    assert m["configs"][-1]["name"] == CONFIG
+                      "moe_device_share_pct", "collective_exposed_ms"}
+    # the cell, its configuration and its name in each list are there
+    # exactly once; WHERE in a list is the driver's business, never a
+    # test's (benchmarks/README.md): a later cell is appended after them
+    assert [c["name"] for c in m["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in m["configs"]].count(CONFIG) == 1
     for x in m["end_to_end"] + m["per_layer"]:
-        if CELL in x.get("workloads", ()):
-            assert x["workloads"][-1] == CELL
+        assert x.get("workloads", [CELL]).count(CELL) <= 1, x["name"]
     traffic = harness.load_json("traffic", TRAFFIC)
     base = harness.load_json("traffic", "train_staged_bs1")
     differs = {k for k in base if base[k] != traffic[k]}
@@ -118,6 +121,18 @@ def test_manifest_entries_of_the_cell():
                        "trace_seconds"}
     assert (traffic["loss_read_every"], traffic["loss_fell_step"],
             traffic["trace_seconds"]) == (8, 32, 3)
+
+
+@pytest.mark.parametrize("name", READERS + ("flash_scores_computed_pct",))
+def test_each_reader_of_the_cell_is_listed_for_it(name):
+    """PR 32: the seven readers PR 30 brought have their entries, and the
+    flash kernels' counter lists the cell; so the cell's traced line, and
+    the ledger's, carry the numbers that say where its step goes."""
+    m = harness.load_manifest()
+    (entry,) = [x for x in m["per_layer"] if x["name"] == name]
+    assert CELL in entry["workloads"]
+    assert entry["moves"] == "train_samples_per_s"
+    assert callable(harness.load_module("layer_metrics", name).read)
 
 
 PUBLISHED = {
@@ -583,11 +598,10 @@ def test_sums_by_hand_and_the_seven_readers():
 
 
 def test_the_seven_readers_say_what_their_entries_will():
-    """Each file carries its entry's unit, direction, source and layer.
-    The driver refused the entries put before `flash_scores_computed_pct`,
-    and that entry's own test refuses them after it (PERF.md section 7):
-    until a `benchmark` PR lifts that pin the manifest lists none, and one
-    it does list has to agree with its file and name this cell."""
+    """Each file carries its entry's unit, direction, source and layer,
+    and since PR 32 (which lifted the pin that kept them out, PERF.md
+    section 6) the manifest lists all seven: each entry agrees with its
+    file and names this cell."""
     m = harness.load_manifest()
     listed = {x["name"]: x for x in m["per_layer"]}
     for name in READERS:
@@ -597,10 +611,9 @@ def test_the_seven_readers_say_what_their_entries_will():
         assert mod.BETTER in ("lower", "higher")
         assert mod.SOURCE in ("device_trace", "host_clock")
         assert mod.LAYER in {x["layer"] for x in m["per_layer"]}
-        entry = listed.get(name)
-        if entry is not None:
-            assert CELL in entry["workloads"]
-            assert (entry["unit"], entry["better"], entry["source"],
-                    entry["layer"], entry["moves"]) == (
-                mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER,
-                "train_samples_per_s")
+        entry = listed[name]
+        assert CELL in entry["workloads"]
+        assert (entry["unit"], entry["better"], entry["source"],
+                entry["layer"], entry["moves"]) == (
+            mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER,
+            "train_samples_per_s")
