@@ -7,6 +7,7 @@ lowers to XLA in one piece."""
 
 from __future__ import annotations
 
+import functools
 from typing import List, NamedTuple, Optional, Sequence, Union
 
 from ..framework.core import Variable
@@ -287,10 +288,11 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
 
 
 def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
-             name=None):
+             name=None, part=None):
     """RMSNorm (ops/llm_ops.py): input over sqrt(mean of its squares over
     the axes from `begin_norm_axis` + epsilon), times a learned gain that
-    starts at one.  No mean subtracted, no bias."""
+    starts at one.  No mean subtracted, no bias.  `part` names the scope
+    the op's instructions carry in a trace (`pdtpu.<part>`)."""
     helper = LayerHelper("rms_norm", name=name)
     gain = helper.create_parameter(
         attr=param_attr if isinstance(param_attr, dict) else {},
@@ -300,7 +302,8 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
     helper.append_op(
         "rms_norm", inputs={"X": [input.name], "Scale": [gain.name]},
         outputs={"Y": [out.name]},
-        attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
+        attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis,
+               **({"part": part} if part else {})})
     return out
 
 
@@ -310,7 +313,8 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
 def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          param_attr=None, name=None, sp_mode="ring",
                          sp_schedule="plain", qk_norm_epsilon=None,
-                         rope_theta=None, out_param_attr=None):
+                         rope_theta=None, out_param_attr=None,
+                         num_kv_heads=None, qk_norm_per_head=False):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -320,10 +324,16 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
 
     `qk_norm_epsilon` puts an RMSNorm with that epsilon on the whole Q and
     the whole K projection, before the split into heads (OLMoE's QK-norm);
-    `rope_theta` rotates Q and K per head by their position (`rope` op,
-    rotate-half form) instead of relying on positions added to the
-    input.  `param_attr` is the Q, K and V projections', `out_param_attr`
-    the output projection's."""
+    with `qk_norm_per_head` on each head's D / num_heads columns instead,
+    after the split and before any rotation, with ONE gain of that width
+    for all query heads and one for all key heads (LFM2's `q_layernorm`,
+    `k_layernorm`).  `rope_theta` rotates Q and K per head by their
+    position (`rope` op, rotate-half form) instead of relying on positions
+    added to the input.  `num_kv_heads` (default `num_heads`, and a divisor
+    of it) is how many heads the K and V projections have: query head h
+    attends to key/value head h // (num_heads / num_kv_heads)
+    (grouped-query attention).  `param_attr` is the Q, K and V
+    projections', `out_param_attr` the output projection's."""
     helper = LayerHelper("multi_head_attention", name=name)
     if sp_mode not in ("ring", "alltoall"):
         raise ValueError(f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
@@ -333,22 +343,32 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
             "(zigzag = load-balanced causal flash ring, fwd and bwd)")
     D = queries.shape[-1]
     assert D % num_heads == 0, "hidden size must divide num_heads"
+    head_dim = D // num_heads
+    kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
+    if not 0 < kv_heads <= num_heads or num_heads % kv_heads:
+        raise ValueError(f"multi_head_attention: num_kv_heads {kv_heads} "
+                         f"does not divide num_heads {num_heads}")
+    if qk_norm_per_head and qk_norm_epsilon is None:
+        raise ValueError("multi_head_attention: qk_norm_per_head is a form "
+                         "of the QK-norm: give qk_norm_epsilon")
     q = fc(queries, D, num_flatten_dims=2, param_attr=param_attr,
            bias_attr=False)
-    k = fc(keys, D, num_flatten_dims=2, param_attr=param_attr,
-           bias_attr=False)
-    v = fc(values, D, num_flatten_dims=2, param_attr=param_attr,
-           bias_attr=False)
-    if qk_norm_epsilon is not None:
-        q = rms_norm(q, begin_norm_axis=2, epsilon=qk_norm_epsilon)
-        k = rms_norm(k, begin_norm_axis=2, epsilon=qk_norm_epsilon)
+    k = fc(keys, kv_heads * head_dim, num_flatten_dims=2,
+           param_attr=param_attr, bias_attr=False)
+    v = fc(values, kv_heads * head_dim, num_flatten_dims=2,
+           param_attr=param_attr, bias_attr=False)
+    qk_norm = functools.partial(rms_norm, epsilon=qk_norm_epsilon,
+                                part="attn.qk_norm")
+    if qk_norm_epsilon is not None and not qk_norm_per_head:
+        q, k = qk_norm(q, begin_norm_axis=2), qk_norm(k, begin_norm_axis=2)
 
-    def split_heads(x):
+    def split_heads(x, heads):
         r = helper.create_tmp_variable(x.dtype)
         helper.append_op("reshape", inputs={"X": [x.name]},
                          outputs={"Out": [r.name]},
-                         attrs={"shape": [0, 0, num_heads, D // num_heads]})
-        t = helper.create_tmp_variable(x.dtype)
+                         attrs={"shape": [0, 0, heads, head_dim]})
+        t = helper.create_tmp_variable(
+            x.dtype, shape=(x.shape[0], heads, x.shape[1], head_dim))
         helper.append_op("transpose", inputs={"X": [r.name]},
                          outputs={"Out": [t.name]},
                          attrs={"axis": [0, 2, 1, 3]})
@@ -358,10 +378,15 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         r = helper.create_tmp_variable(x.dtype)
         helper.append_op("rope", inputs={"X": [x.name]},
                          outputs={"Out": [r.name]},
-                         attrs={"theta": float(rope_theta)})
+                         attrs={"theta": float(rope_theta),
+                                "part": "attn.rope"})
         return r
 
-    qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
+    qh, kh, vh = (split_heads(q, num_heads), split_heads(k, kv_heads),
+                  split_heads(v, kv_heads))
+    if qk_norm_per_head:
+        qh, kh = (qk_norm(qh, begin_norm_axis=3),
+                  qk_norm(kh, begin_norm_axis=3))
     if rope_theta is not None:
         qh, kh = rotate(qh), rotate(kh)
     attn = helper.create_tmp_variable(queries.dtype)
@@ -385,6 +410,32 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     from .sequence import propagate_length
 
     return propagate_length(queries, out)
+
+
+def gated_short_conv(input, kernel_size=3, param_attr=None, name=None):
+    """LFM2's gated short convolution over [B, T, D] (ops/llm_ops.py
+    `gated_short_conv` has the equations): an input projection to three
+    thirds B, C, u, a causal depthwise convolution of `kernel_size` taps
+    over B * u, gated by C, and an output projection.  The two
+    projections are `fc` ops (MXU GEMMs).  Three parameters, in creation
+    order: W_in [D, 3D], the taps [D, kernel_size], W_out [D, D]; no
+    bias."""
+    helper = LayerHelper("gated_short_conv", name=name)
+    D = input.shape[-1]
+    bcu = fc(input, 3 * D, num_flatten_dims=2, param_attr=param_attr,
+             bias_attr=False)
+    taps = helper.create_parameter(
+        attr=param_attr if isinstance(param_attr, dict) else {},
+        shape=[D, int(kernel_size)], dtype=input.dtype)
+    gated = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(
+        "gated_short_conv", inputs={"X": [bcu.name], "Filter": [taps.name]},
+        outputs={"Out": [gated.name]}, attrs={})
+    out = fc(gated, D, num_flatten_dims=2, param_attr=param_attr,
+             bias_attr=False)
+    from .sequence import propagate_length
+
+    return propagate_length(input, out)
 
 
 def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
@@ -604,7 +655,7 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         param_attr=None, name=None, top_k=1, gated=False, dropless=False,
         initializer=None, held=None, scoring="softmax", select_bias=None,
         renormalise=False, routed_scale=1.0, buffer_rows=None,
-        shared_hidden=0):
+        shared_hidden=0, renorm_epsilon=None):
     """Mixture-of-experts FFN layer (beyond-reference — SURVEY.md §2.16 last
     row).  `input` [N, D] tokens -> [N, D].  Expert weights are stacked
     [E, D, H]/[E, H, D]; under a ParallelExecutor whose mesh has an 'ep'
@@ -623,7 +674,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     sum (-> `MoeShare`).  With it come DeepSeek-V3's router (`scoring`
     'sigmoid', `select_bias`: an initializer for a bias [E] that is added
     for the choice only and takes no gradient, `renormalise` the chosen
-    weights to sum to one, times `routed_scale`), `buffer_rows` (the
+    weights to sum to one (over their sum + `renorm_epsilon`: DeepSeek's
+    1e-20 where None), times `routed_scale`), `buffer_rows` (the
     static rows the held pairs are computed in; N * top_k, which nothing
     can overflow, by default) and `shared_hidden` (> 0: one more gated
     expert of that width which every token passes, inside the same op)."""
@@ -643,7 +695,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     share = held is not None and not (
         tuple(held) == (0, num_experts) and scoring == "softmax"
         and select_bias is None and not renormalise and routed_scale == 1.0
-        and not buffer_rows and not shared_hidden)
+        and not buffer_rows and not shared_hidden
+        and renorm_epsilon is None)
     if share and not dropless:
         raise ValueError("layers.moe: a share of the experts (held) needs "
                          "dropless=True (ops/moe_ops.py)")
@@ -697,6 +750,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
                   "routed_scale": float(routed_scale)})
     if buffer_rows:
         attrs["buffer_rows"] = int(buffer_rows)
+    if renorm_epsilon is not None:
+        attrs["renorm_epsilon"] = float(renorm_epsilon)
     helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)
     return MoeShare(out, logits, weights, counts, pairs, dropped, bias)
 

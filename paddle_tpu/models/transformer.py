@@ -50,7 +50,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                positions="learned", rope_theta=10000.0, qk_norm=False,
                ffn="mlp", moe=None, router_outputs=None, init_scale=None,
                emb_init_scale=None, attention="multi_head", mla=None,
-               dense_layers=0, dense_dim=None):
+               dense_layers=0, dense_dim=None, layer_types=None, conv=None,
+               n_kv_heads=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -60,8 +61,12 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
     The block's kinds, GPT-2's by default: `norm` 'layer_norm' or
     'rms_norm' (with `norm_epsilon`); `positions` 'learned' (a table added
-    to the embedding) or 'rope' (Q and K rotated per head, `rope_theta`);
-    `qk_norm` (an RMSNorm on the whole Q and K projections); `ffn` 'mlp'
+    to the embedding) or 'rope' (Q and K rotated per head, `rope_theta`,
+    in the layers that attend; no other layer sees a position); `qk_norm`
+    True (an RMSNorm on the whole Q and K projections) or 'head' (on each
+    head, one gain for all query heads and one for all key heads);
+    `n_kv_heads` (fewer key/value heads than `n_heads`, a divisor of it:
+    grouped-query attention); `ffn` 'mlp'
     (GELU, `mlp_ratio`) or 'moe': dropless top-k experts, `moe` =
     {"num_experts", "d_hidden", "top_k"} and optionally "act" ('silu') and
     "gated" (True).  An 'moe' block appends its layer's (router logits,
@@ -75,6 +80,10 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     `attention` 'multi_head' or 'latent' with `mla` = {"kv_rank",
     "qk_nope_dim", "qk_rope_dim", "v_dim"} (`layers.latent_attention`;
     rotary by construction: `rope_theta`, no position table).
+    `layer_types` gives the token mixer layer by layer, `n_layers` of
+    'attention' (the kind `attention` names; everywhere by default) or
+    'conv': a gated short convolution, `conv` = {"kernel_size"}
+    (`layers.gated_short_conv`).
     `init_scale` draws every matrix (embedding,
     projections, experts, head) from normal(0, init_scale) instead of each
     layer's default; `emb_init_scale` gives the token embedding a scale of
@@ -88,6 +97,15 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     if attention not in ("multi_head", "latent"):
         raise ValueError(f"attention {attention!r}: use 'multi_head' or "
                          f"'latent'")
+    if qk_norm not in (False, True, "head"):
+        raise ValueError(f"qk_norm {qk_norm!r}: use True (the whole "
+                         f"projection) or 'head'")
+    if layer_types is None:
+        layer_types = ["attention"] * n_layers
+    if len(layer_types) != n_layers or set(layer_types) - {"attention",
+                                                           "conv"}:
+        raise ValueError(f"layer_types {layer_types!r}: use {n_layers} of "
+                         f"'attention' or 'conv'")
     init = (NormalInitializer(scale=init_scale) if init_scale is not None
             else None)
     attr = {"initializer": init} if init is not None else None
@@ -98,7 +116,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                                    epsilon=norm_epsilon)
         return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_epsilon)
 
-    def attend(h):
+    def mix(h, layer):
+        if layer_types[layer] == "conv":
+            return layers.gated_short_conv(h, param_attr=attr, **conv)
         if attention == "latent":
             return layers.latent_attention(
                 h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
@@ -108,6 +128,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
             param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
             sp_schedule=sp_schedule,
             qk_norm_epsilon=norm_epsilon if qk_norm else None,
+            qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
             rope_theta=rope_theta if positions == "rope" else None)
 
     def feed_forward(h, layer):
@@ -149,7 +170,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     blk = (layers.recompute if remat else contextlib.nullcontext)
     for layer in range(n_layers):
         with blk():
-            a = attend(normed(x))
+            a = mix(normed(x), layer)
             if dropout_prob:
                 a = layers.dropout(a, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, a)
@@ -166,7 +187,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
 # _lm_fns) know
 _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
-               "qk_norm": False, "ffn": "mlp", "attention": "multi_head"}
+               "qk_norm": False, "ffn": "mlp", "attention": "multi_head",
+               "layer_types": None, "n_kv_heads": None}
 
 
 def lm_loss(logits, targets, dtype="float32"):
@@ -499,7 +521,8 @@ class DecoderLM:
             raise NotImplementedError(
                 f"DecoderLM: the generation and paged-serving ops run "
                 f"GPT-2's block only (LayerNorm, learned positions, GELU "
-                f"MLP, 12 parameters a layer); this tower was built with "
+                f"MLP, attention in every layer with one head count, 12 "
+                f"parameters a layer); this tower was built with "
                 f"{self._block} and can be trained, not served, until the "
                 f"serving twin takes the block's kinds (ROADMAP.md S1/D4)")
         p = self._params
@@ -635,6 +658,65 @@ def build_mla_moe_lm_train_program(
         s.scores, s.counts, top_k) for s in shares])
     loss = layers.elementwise_add(
         loss, layers.scale(balance, scale=balance_weight / len(shares)))
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    for s in shares:
+        layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
+    return loss
+
+
+def build_lfm2_moe_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, n_heads, n_kv_heads,
+        conv_kernel, dense_dim, dense_layers, num_experts, expert_dim, top_k,
+        held_experts, first_expert=0, buffer_rows=None, routed_scale=1.0,
+        renorm_epsilon=1e-6, norm_epsilon=1e-5, rope_theta=1000000.0,
+        bias_update_rate=1e-3, bias_init_scale=0.0, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02, emb_init_scale=None):
+    """LFM2-MoE-shaped decoder (`model_type` lfm2_moe, transformers'
+    `Lfm2Moe*`: LFM2-24B-A2B) as ONE CHIP'S SHARE of an expert-parallel
+    deployment: RMSNorm pre-norm blocks whose token mixer is, by
+    `layer_types`, a gated short convolution of `conv_kernel` taps
+    ('conv') or grouped-query attention ('attention': `n_heads` query
+    heads on `n_kv_heads` key/value heads, an RMSNorm on each head of Q
+    and K, then rotate-half RoPE); a SiLU-gated MLP of `dense_dim` in the
+    first `dense_layers` blocks and in the others an expert layer whose
+    router scores all `num_experts` by sigmoid, chooses `top_k` by score +
+    bias and renormalises their weights over their sum + `renorm_epsilon`,
+    times `routed_scale`; of those experts this chip holds `held_experts`
+    from `first_expert` on and computes their part in a buffer of
+    `buffer_rows` rows; no shared expert; `vocab_size` is the slice of the
+    vocabulary this chip embeds and scores; no bias, untied head.  Loss:
+    next-token cross entropy and no auxiliary term (LFM2 publishes none);
+    Adam; then every expert layer's selection bias moves by
+    `bias_update_rate` against its counts.  `bias_init_scale` as
+    `build_mla_moe_lm_train_program`'s.  Returns the loss.  Feeds as
+    `build_lm_train_program`."""
+    from .. import optimizer as opt
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types), n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions="rope", rope_theta=rope_theta, qk_norm="head",
+        n_kv_heads=n_kv_heads,
+        # the published config's name for a layer that attends
+        layer_types=[{"full_attention": "attention"}.get(t, t)
+                     for t in layer_types],
+        conv={"kernel_size": conv_kernel},
+        ffn="moe", dense_layers=dense_layers, dense_dim=dense_dim,
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "sigmoid", "renormalise": True,
+             "renorm_epsilon": renorm_epsilon,
+             "routed_scale": routed_scale, "buffer_rows": buffer_rows,
+             "select_bias": NormalInitializer(scale=bias_init_scale)},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
     # the last layer's routed (token, expert) pairs over ALL experts, for a
     # fetch to hold exactly: seq_len * top_k a sequence
     layers.reduce_sum(shares[-1].counts)

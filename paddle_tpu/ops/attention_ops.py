@@ -13,7 +13,16 @@ simple_attention): score = v·tanh(W_q h + W_m enc)."""
 
 from __future__ import annotations
 
+from ..observability.attribution import part_scope
+from ..observability.metrics import REGISTRY as _MET
 from .registry import register_op
+
+_MET_GQA_LAYERS = _MET.counter(
+    "gqa_attention_layers_traced_total",
+    "scaled_dot_product_attention ops traced whose keys and values have "
+    "fewer heads than their queries (forward emission; once a compile, not "
+    "once a step), by the two head counts (q_heads, kv_heads) and the head "
+    "size (head_dim)")
 
 
 def _attend(h, enc_proj, enc_out, enc_mask, w_q, v):
@@ -93,11 +102,14 @@ def attention_gru_decoder(ctx, ins, attrs):
 
 def flash_single_chip(ctx, q, k, v, causal: bool):
     """The single-chip fast path of an attention emitter: the Pallas flash
-    kernel (VMEM-tiled online softmax) on Q, K [B,H,T,D] and V [B,H,T,Dv],
-    where the trace targets one TPU and the shapes fit the kernel's
-    contract: self-attention lengths, T tiles of 128, a lane-width head
-    (of the values; latent attention's queries and keys carry rotary
-    columns beside it, two lane tiles at most).  Sharded mesh execution
+    kernel (VMEM-tiled online softmax) on Q [B,H,T,D], K [B,Hkv,T,D] and V
+    [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
+    kernel's contract: self-attention lengths, T tiles of 128, a
+    lane-width head (of the values; latent attention's queries and keys
+    carry rotary columns beside it, two lane tiles at most).  Two head
+    counts: H query heads on Hkv key/value heads, H / Hkv on each
+    (grouped-query attention; Hkv = H is the usual case), which the
+    kernels read where they lie, never repeated.  Sharded mesh execution
     keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
     call).  -> None where it does not apply, else (out, saved).
 
@@ -141,16 +153,35 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     memory O(T/S), parallel/ring_attention.py) or 'alltoall'
     (Ulysses-style — one all_to_all pair re-shards seq→heads, dense local
     attention; the better trade when heads >= sp and chunks are small).
-    Otherwise dense flash-style softmax (XLA fuses it)."""
+    Otherwise dense flash-style softmax (XLA fuses it).
+
+    K and V may have fewer heads than Q, a divisor Hkv of H: query head h
+    attends to key/value head h // (H / Hkv).  The flash kernels take the
+    two head counts as they are; every other path sees K and V repeated."""
+    import jax.numpy as jnp
+
     from ..parallel import ring_attention as ra
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    group = q.shape[1] // k.shape[1]
+    if q.shape[1] != group * k.shape[1] or v.shape[1] != k.shape[1]:
+        raise ValueError(
+            f"scaled_dot_product_attention: {q.shape[1]} query heads on "
+            f"{k.shape[1]} key and {v.shape[1]} value heads")
+    if group > 1 and not ctx.in_grad_replay():
+        _MET_GQA_LAYERS.inc(q_heads=str(q.shape[1]),
+                            kv_heads=str(k.shape[1]),
+                            head_dim=str(q.shape[3]))
+
+    def repeated(a):
+        return a if group == 1 else jnp.repeat(a, group, axis=1)
     causal = bool(attrs.get("causal", False))
     sp_mode = str(attrs.get("sp_mode", "ring"))
     from ..parallel.mesh import axis_size
 
     mesh = getattr(ctx, "mesh", None)
     if mesh is not None and axis_size(mesh, "sp") > 1:
+        k, v = repeated(k), repeated(v)
         # on TPU the per-shard attention itself runs the Pallas flash
         # kernel when shapes fit its contract (GSPMD can't partition a
         # Mosaic call, but inside shard_map each device launches its own)
@@ -179,14 +210,15 @@ def scaled_dot_product_attention(ctx, ins, attrs):
             raise ValueError(
                 f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
     else:
-        out = None
-        got = flash_single_chip(ctx, q, k, v, causal)
-        if got is not None:
-            out, saved = got
-            if saved is not None:
-                ctx.keep_for_grad(attrs, [out], saved)
-        if out is None:
-            out = ra.attention(q, k, v, causal=causal)
+        with part_scope("attn.attend"):
+            got = flash_single_chip(ctx, q, k, v, causal)
+            if got is None:
+                out = ra.attention(q, repeated(k), repeated(v),
+                                   causal=causal)
+            else:
+                out, saved = got
+                if saved is not None:
+                    ctx.keep_for_grad(attrs, [out], saved)
     return {"Out": [out]}
 
 

@@ -1,9 +1,10 @@
 """Ops of the post-2020 decoder block that the GPT-2-shaped tower lacks:
 RMSNorm, rotary position embedding (beyond-reference, like the rest of
-the transformer tier; first user: OLMoE, models/transformer.py) and latent
-attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B).
+the transformer tier; first user: OLMoE, models/transformer.py), latent
+attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B) and the gated
+short convolution (LFM2's token mixer in the layers that do not attend).
 
-The first two are plain jax.numpy, so `generic_grad` differentiates them by
+All but latent attention are plain jax.numpy, so `generic_grad` differentiates them by
 re-emission and XLA's CSE merges the re-emitted forward with the first.
 Statistics and rotations are at least float32 whatever the compute dtype
 (`wide_dtype`); the result goes back to the input's dtype."""
@@ -19,6 +20,22 @@ _MET_MLA_LAYERS = _MET.counter(
     "latent attention layers traced (forward emission; once a compile, not "
     "once a step), by the width of a head's queries and keys (qk_dim), of "
     "its values (v_dim) and the rank of the K/V latent (kv_rank)")
+
+
+_MET_CONV_LAYERS = _MET.counter(
+    "short_conv_layers_traced_total",
+    "gated short convolution ops traced (forward emission; once a compile, "
+    "not once a step), by the channels convolved (dim) and the taps a "
+    "channel (kernel)")
+
+
+def _part(attrs):
+    """The scope of an op that a layer names as one part of a larger
+    block (attr `part`: `pdtpu.attn.qk_norm`, `pdtpu.attn.rope`)."""
+    import contextlib
+
+    return (part_scope(str(attrs["part"])) if attrs.get("part")
+            else contextlib.nullcontext())
 
 
 def wide_dtype(dtype):
@@ -50,8 +67,9 @@ def rms_norm(ctx, ins, attrs):
     x = ins["X"][0]
     begin = int(attrs.get("begin_norm_axis", 1))
     gain = ins["Scale"][0] if ins.get("Scale") else None
-    return {"Y": [rms(x, float(attrs.get("epsilon", 1e-5)),
-                      tuple(range(begin, x.ndim)), gain)]}
+    with _part(attrs):
+        return {"Y": [rms(x, float(attrs.get("epsilon", 1e-5)),
+                          tuple(range(begin, x.ndim)), gain)]}
 
 
 def rotate_half(x, theta: float):
@@ -79,8 +97,9 @@ def rope(ctx, ins, attrs):
     arXiv:2104.09864, as GPT-NeoX and transformers apply it): X [B, H, T,
     D] with D even; position t of every head turns the pair (x[i], x[i +
     D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1."""
-    return {"Out": [rotate_half(ins["X"][0],
-                                float(attrs.get("theta", 10000.0)))]}
+    with _part(attrs):
+        return {"Out": [rotate_half(ins["X"][0],
+                                    float(attrs.get("theta", 10000.0)))]}
 
 
 @register_op("latent_attention")
@@ -140,6 +159,47 @@ def latent_attention(ctx, ins, attrs):
     return {"Out": [out]}
 
 
+@register_op("gated_short_conv")
+def gated_short_conv(ctx, ins, attrs):
+    """The gated short convolution of LFM2 (transformers' `Lfm2ShortConv`),
+    without its two projections: X [B, T, 3D] is the input projection's
+    result, three thirds B, C, u in that order; Filter [D, L] holds L taps
+    a channel.
+
+      g = B * u                                  (the input gate)
+      c_t = sum_{j < L} Filter[:, j] * g_{t - (L - 1) + j}, g zero before
+            the sequence starts: depthwise and causal, the LAST tap on the
+            current token
+      Out = C * c                                (the output gate)  [B, T, D]
+
+    No position enters.  L shifted multiply-adds that XLA fuses into one
+    pass over X (bound by HBM: PERF.md, PR 33, has it against
+    `lax.conv_general_dilated`); at least float32 inside, X's dtype out."""
+    import jax.numpy as jnp
+
+    x, w = ins["X"][0], ins["Filter"][0]
+    dim, taps = w.shape
+    if x.ndim != 3 or x.shape[-1] != 3 * dim:
+        raise ValueError(f"gated_short_conv: X {x.shape} is not [B, T, 3 x "
+                         f"{dim}] for a Filter {w.shape}")
+    if not ctx.in_grad_replay():
+        _MET_CONV_LAYERS.inc(dim=str(dim), kernel=str(taps))
+    T = x.shape[1]
+    wide = wide_dtype(x.dtype)
+    gate_in, gate_out, u = jnp.split(x.astype(wide), 3, axis=-1)
+    with part_scope("conv.gate"):
+        g = gate_in * u
+    with part_scope("conv.taps"):
+        wf = w.astype(wide)
+        c = wf[:, taps - 1] * g
+        for back in range(1, min(taps, T)):   # the tap `back` tokens ago
+            c = c + wf[:, taps - 1 - back] * jnp.pad(
+                g, ((0, 0), (back, 0), (0, 0)))[:, :T]
+    with part_scope("conv.gate"):
+        out = gate_out * c
+    return {"Out": [out.astype(x.dtype)]}
+
+
 # ---------------------------------------------------------------------------
 # analytic cost formulas (analysis/cost.py; mechanism in registry.py)
 
@@ -176,6 +236,16 @@ def _latent_attention_cost(ins, outs, attrs):
     return {"flops": 2 * b * t * weights + b * heads * t * t * widths}
 
 
+def _gated_short_conv_cost(ins, outs, attrs):
+    """A multiply-add a tap and the two gates, per output element."""
+    x = ins.get("X", [None])[0]
+    w = ins.get("Filter", [None])[0]
+    if x is None or w is None:
+        return {}
+    return {"flops": (2 * w.shape[1] + 2) * x.size // 3}
+
+
+register_cost("gated_short_conv", _gated_short_conv_cost)
 register_cost("latent_attention", _latent_attention_cost)
 register_cost("rms_norm", _rms_norm_cost)
 register_cost("rope", _rope_cost)

@@ -205,13 +205,16 @@ def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act):
     return out.astype(x.dtype), logits, counts.astype(jnp.float32)
 
 
-def _route_scored(x, gate_w, bias, top_k, scoring, renormalise, scale):
+def _route_scored(x, gate_w, bias, top_k, scoring, renormalise, scale,
+                  epsilon=1e-20):
     """Router of the share form, float32 like `_route_top_k`: -> (scores
     [T, E], weights [T, k], experts [T, k]).  The `top_k` experts are the
     largest of scores + bias (DeepSeek-V3's `e_score_correction_bias`,
     which steers the choice and takes no gradient); their weights are the
-    scores WITHOUT it, divided by their sum + 1e-20 where `renormalise`,
-    times `scale`."""
+    scores WITHOUT it, divided by their sum + `epsilon` where
+    `renormalise`, times `scale`.  The epsilon is the model's own:
+    DeepSeek-V3's and Moonlight's code adds 1e-20 (the default), LFM2's
+    1e-6 (attr `renorm_epsilon`)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -231,7 +234,7 @@ def _route_scored(x, gate_w, bias, top_k, scoring, renormalise, scale):
     weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
     if renormalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
+                             + epsilon)
     return scores, weights * scale, experts
 
 
@@ -398,10 +401,10 @@ def moe(ctx, ins, attrs):
     With `dropless` and `first_expert` (a share; the module's docstring):
     Gate is [D, E] and WI / WU / WO stack the HELD experts [first_expert,
     first_expert + held); attrs scoring ('softmax' | 'sigmoid'),
-    renormalise, routed_scale, buffer_rows (T * top_k by default: then no
-    pair can be dropped); inputs Bias [E] (optional: the selection bias)
-    and SI / SU / SO (optional: the shared expert's [D, Hs], [D, Hs], [Hs,
-    D]).  Outputs Out (the held experts' part of the layer plus the shared
+    renormalise, renorm_epsilon (1e-20), routed_scale, buffer_rows (T *
+    top_k by default: then no pair can be dropped); inputs Bias [E]
+    (optional: the selection bias) and SI / SU / SO (optional: the shared
+    expert's [D, Hs], [D, Hs], [Hs, D]).  Outputs Out (the held experts' part of the layer plus the shared
     expert), RouterScores [T, E] float32, RouterWeights [T, top_k] float32
     (each token's weights, largest first; for a check, it passes no
     gradient on), Counts [E] (over ALL E: they sum to T * top_k),
@@ -500,7 +503,8 @@ def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act):
         ctx, x, gate_w, one("Bias"), wi, wu, wo, shared, top_k, act, first,
         rows, {"scoring": scoring,
                "renormalise": bool(attrs.get("renormalise", False)),
-               "scale": float(attrs.get("routed_scale", 1.0))})
+               "scale": float(attrs.get("routed_scale", 1.0)),
+               "epsilon": float(attrs.get("renorm_epsilon", 1e-20))})
     return {"Out": [out], "RouterScores": [scores],
             "RouterWeights": [weights], "Counts": [counts],
             "HeldPairs": [pairs], "DroppedPairs": [dropped]}

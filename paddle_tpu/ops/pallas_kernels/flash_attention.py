@@ -150,16 +150,31 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
     return bq, bk
 
 
-def _causal_kv_idx(bq: int, bk: int):
-    """K/V index map that CLAMPS fully-future fetches to the diagonal
-    block: the DMA for a skipped block is a re-fetch of an already-
-    buffered index (i.e. free), halving HBM traffic under causal.
-    Shared by forward and _dq_kernel so the diagonal arithmetic cannot
-    drift between them."""
+def _kv_head(group: int):
+    """Flattened query head `b` of [B * Hq] -> its key/value head of [B *
+    Hkv], `group` = Hq / Hkv query heads on each: heads lie contiguous in
+    the flattened axis, so (batch * Hq + h) // group = batch * Hkv + h //
+    group.  The identity where every query head has its own (no op is
+    added to an index map then)."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _kv_idx(bq: int, bk: int, causal: bool, group: int):
+    """K/V index map of the forward and _dq_kernel (one map, so the
+    diagonal arithmetic cannot drift between them): query head b reads
+    its group's K/V head, and under causal masking fully-future fetches
+    CLAMP to the diagonal block: the DMA for a skipped block is a
+    re-fetch of an already-buffered index (i.e. free), halving HBM
+    traffic."""
     import jax.numpy as jnp
 
-    def idx(b, i, j):
-        return (b, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+    head = _kv_head(group)
+    if causal:
+        def idx(b, i, j):
+            return (head(b), jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+    else:
+        def idx(b, i, j):
+            return (head(b), j, 0)
 
     return idx
 
@@ -357,9 +372,10 @@ def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, **kw):
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
-              Dv):
-    """The forward kernel's call on q, k [BH, T, D] and v [BH, T, Dv]
-    operands (the output is v's width), for both forward entry points.
+              Dv, group=1):
+    """The forward kernel's call on q [BH, T, D], k [BH / group, T, D] and
+    v [BH / group, T, Dv] operands (the output is v's width and q's
+    heads), for both forward entry points.
     Memoized and jitted: every layer of a model makes the same call, and
     one callable lets jit trace the kernel body and lower it to Mosaic
     once a step program instead of once a layer."""
@@ -368,12 +384,7 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if plan is not None:
-        kv_idx = _causal_kv_idx(bq, bk)
-    else:
-        def kv_idx(b, i, j):
-            return (b, j, 0)
-
+    kv_idx = _kv_idx(bq, bk, plan is not None, group)
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), kv_idx),
@@ -416,25 +427,41 @@ def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
     the output(s) on [B*H, T, Dv]."""
     B, H, T, D = q.shape
     Dv = v.shape[-1]
+    group = _group(q, k, v)
     bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
     plan = _causal_plan("flash_fwd", B * H, T, bq, bk) if causal else None
     return _fwd_call(B * H, T, D, bq, bk, plan, with_lse, q.dtype,
-                     interpret, s, Dv)(*(a.reshape(B * H, T, a.shape[-1])
-                                         for a in (q, k, v)))
+                     interpret, s, Dv, group)(
+        *(a.reshape(-1, T, a.shape[-1]) for a in (q, k, v)))
+
+
+def _group(q, k, v) -> int:
+    """Query heads on each key/value head (grouped-query attention; 1
+    where every query head has its own)."""
+    heads, kv_heads = q.shape[1], k.shape[1]
+    if v.shape[1] != kv_heads or heads % kv_heads:
+        raise ValueError(
+            f"flash attention: {heads} query heads on {kv_heads} key and "
+            f"{v.shape[1]} value heads; K and V need one head count, and "
+            f"it has to divide the queries'")
+    return heads // kv_heads
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = 512, block_k: int = 1024,
                     interpret: bool = False):
-    """q, k [B,H,T,D], v [B,H,T,Dv] → [B,H,T,Dv] (Dv = D but in latent
-    attention, whose keys carry rotary columns its values lack; the
-    default scale is 1/sqrt(D), the width the scores contract over).
+    """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv] → [B,H,T,Dv] (Dv = D
+    but in latent attention, whose keys carry rotary columns its values
+    lack; the default scale is 1/sqrt(D), the width the scores contract
+    over).  Hkv = H, or a divisor of it (grouped-query attention): query
+    head h then attends to key/value head h // (H / Hkv), read where it
+    lies; K and V are never repeated.
     block_q/block_k are performance hints, snapped down to divisors of T;
     D ≤ 128 recommended (one lane tile)."""
     out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    False)
-    return out.reshape(v.shape)
+    return out.reshape(q.shape[:3] + v.shape[3:])
 
 
 # ---------------------------------------------------------------------------
@@ -505,17 +532,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                bq: int, bk: int, plan):
+                bq: int, bk: int, plan, group: int):
+    """The last grid axis walks the q blocks of the `group` query heads
+    that share this K/V head, a head after the other (_dkv_q_maps): dk and
+    dv add up over all of it in the scratch and are written once."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    # lse rides whole (1, 1, T) rows: T / bq q blocks a head, static
+    qi = step if group == 1 else step % (lse_ref.shape[2] // bq)
     q0, k0 = qi * bq, kj * bk
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros(dk_sc.shape, dtype=jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, dtype=jnp.float32)
@@ -553,7 +585,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _run_block(q0 - k0, bq, bk, plan, update)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -565,34 +597,54 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
     B, H, T, _D = q.shape
     out, lse = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
                         True)
-    return out.reshape(v.shape), lse.reshape(B * H, T)
+    return out.reshape(q.shape[:3] + v.shape[3:]), lse.reshape(B * H, T)
+
+
+@functools.lru_cache(maxsize=None)
+def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int):
+    """(block map of q and dO, row map of lse and delta) of the dkv
+    kernel's grid (K/V head b, K block j, step i).  One query head on a
+    K/V head: step i is q block i.  `group` of them: the steps walk head b
+    * group's q blocks, then the next head's, so head b * group + i // nq
+    and q block i % nq.  Under causal masking the q block clamps to the
+    first that attends K block j (skip-early: a skipped step re-fetches a
+    block already buffered)."""
+    import jax.numpy as jnp
+
+    nq = T // bq
+    if group == 1:
+        head = lambda b, i: b
+        block = lambda i: i
+    else:
+        head = lambda b, i: b * group + i // nq
+        block = lambda i: i % nq
+    if causal:
+        def q_idx(b, j, i):
+            return (head(b, i), jnp.maximum(block(i), (j * bk) // bq), 0)
+    else:
+        def q_idx(b, j, i):
+            return (head(b, i), block(i), 0)
+
+    return q_idx, lambda b, j, i: (head(b, i), 0, 0)
 
 
 @functools.lru_cache(maxsize=None)
 def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
-               Dv):
-    """(dq call, dkv call) on q, k [BH, T, D], v, dO [BH, T, Dv] operands
-    and (BH, 1, T) lse and delta rows (dq and dk leave in D, dv in Dv);
-    memoized and jitted like _fwd_call."""
+               Dv, group=1):
+    """(dq call, dkv call) on q [BH, T, D], dO [BH, T, Dv], k [BH / group,
+    T, D], v [BH / group, T, Dv] operands and (BH, 1, T) lse and delta
+    rows (dq leaves as q, dk as k, dv as v); memoized and jitted like
+    _fwd_call."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     row_spec = pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0))
-
-    if dq_plan is not None:
-        kv_idx = _causal_kv_idx(bq, bk)
-
-        def q_idx(b, j, i):
-            # skip-early clamp: the first q block attending k block j
-            return (b, jnp.maximum(i, (j * bk) // bq), 0)
-    else:
-        def kv_idx(b, i, j):
-            return (b, j, 0)
-
-        def q_idx(b, j, i):
-            return (b, i, 0)
+    kv_idx = _kv_idx(bq, bk, dq_plan is not None, group)
+    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, dq_plan is not None, group)
+    q_row_spec = pl.BlockSpec((1, 1, T), q_row_idx)
+    BHkv = BH // group
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
@@ -616,23 +668,23 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
     )
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dkv_plan),
-        grid=(BH, T // bk, T // bq),
+                          plan=dkv_plan, group=group),
+        grid=(BHkv, T // bk, group * (T // bq)),
         in_specs=[
             pl.BlockSpec((1, bq, D), q_idx),
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bq, Dv), q_idx),
-            row_spec,
-            row_spec,
+            q_row_spec,
+            q_row_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), dtype),
-            jax.ShapeDtypeStruct((BH, T, Dv), dtype),
+            jax.ShapeDtypeStruct((BHkv, T, D), dtype),
+            jax.ShapeDtypeStruct((BHkv, T, Dv), dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, Dv), jnp.float32)],
@@ -650,9 +702,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
 
     B, H, T, D = q.shape
     Dv = v.shape[-1]
+    group = _group(q, k, v)
     bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
-    qf, kf, vf, of, dof = (a.reshape(B * H, T, a.shape[-1])
+    qf, kf, vf, of, dof = (a.reshape(-1, T, a.shape[-1])
                            for a in (q, k, v, o, do))
     delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
                     axis=-1)  # [BH, T]
@@ -664,7 +717,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
         dq_plan = _causal_plan("flash_bwd_dq", B * H, T, bq, bk)
         dkv_plan = _causal_plan("flash_bwd_dkv", B * H, T, bq, bk)
     dq_call, dkv_call = _bwd_calls(B * H, T, D, bq, bk, dq_plan, dkv_plan,
-                                   q.dtype, interpret, s, Dv)
+                                   q.dtype, interpret, s, Dv, group)
     dq = dq_call(qf, kf, vf, dof, lse3, delta3)
     dk, dv = dkv_call(qf, kf, vf, dof, lse3, delta3)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)

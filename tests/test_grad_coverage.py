@@ -113,8 +113,10 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # numerically checked in test_llm_ops.py
     # PR 30: +2 (latent_attention, moe_sequence_balance_loss — Moonlight's
     # block), each numerically checked in test_llm_ops.py
-    assert len(diffable) == 151, (
+    # PR 33: +1 (gated_short_conv — LFM2's token mixer), numerically checked
+    # in test_lfm2.py
+    assert len(diffable) == 152, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 151 - 11
+    assert len(checked) == 152 - 11

@@ -328,16 +328,18 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     assert _counter() == {(SDPA, "1"): float(layers)}
 
 
-# B, H, T, D, Dv of the cells' attention: GPT-2-medium at batch 8, OLMoE and
-# Moonlight (keys 192 wide, values 128) at 1; and a short chunk of ring
+# B, H, T, D, Dv (and, where K and V have fewer heads, Hkv) of the cells'
+# attention: GPT-2-medium at batch 8, OLMoE and Moonlight (keys 192 wide,
+# values 128) at 1, LFM2 (32 query heads on 8 key/value heads of 64) at 1;
+# and a short chunk of ring
 # attention's, whose blocks are the whole dimension (one UNDER 128 long is
 # refused by Mosaic in all three kernels: a lane offset into the logsumexp
 # row it cannot prove aligned, at PR 31's parent as after it: PERF.md 7)
 @pytest.mark.parametrize("shape", [
     (8, 16, 1024, 64, 64), (1, 16, 4096, 128, 128), (1, 16, 8192, 192, 128),
-    (2, 4, 256, 64, 64)],
+    (2, 4, 256, 64, 64), (1, 32, 8192, 64, 64, 8)],
     ids=["gpt2m_train_bs8", "olmoe_train_t4096", "moonlight_train_t8192",
-         "whole_dimension_blocks"])
+         "whole_dimension_blocks", "lfm2_train_t8192"])
 def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
     """The three kernels with their walks, bf16 under the default blocks
     and x64 off as the chip runs them, through Mosaic for the described
@@ -348,10 +350,14 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    B, H, T, D, Dv = shape
+    B, H, T, D, Dv = shape[:5]
+    kv_heads = shape[5] if len(shape) > 5 else H
     one = SingleDeviceSharding(v5e)
     x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one)
     y = jax.ShapeDtypeStruct((B, H, T, Dv), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((B, kv_heads, T, D), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((B, kv_heads, T, Dv), jnp.bfloat16,
+                             sharding=one)
     lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32, sharding=one)
     bq, bk = fa._snap_blocks(512, 1024, T, causal_head=D)
     assert (bq, bk) == ((T, T) if T <= 1024 else (512, 1024))
@@ -361,18 +367,18 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
     with jax.enable_x64(False):
         calls = {
             "flash_fwd": jax.jit(lambda q, k, v: fa.flash_attention_fwd(
-                q, k, v, causal=True)).lower(x, x, y),
+                q, k, v, causal=True)).lower(x, k, v),
             "flash_fwd_nolse": jax.jit(lambda q, k, v: fa.flash_attention(
-                q, k, v, causal=True)).lower(x, x, y),
+                q, k, v, causal=True)).lower(x, k, v),
             "flash_bwd": jax.jit(
                 lambda q, k, v, o, l, do: fa.flash_attention_bwd(
                     q, k, v, o, l, do, causal=True)).lower(
-                        x, x, y, y, lse, y),
+                        x, k, v, y, lse, y),
             # the single-shot body, as a ring step off the diagonal runs it
             "flash_bwd_whole": jax.jit(
                 lambda q, k, v, o, l, do: fa.flash_attention_bwd(
                     q, k, v, o, l, do, causal=False)).lower(
-                        x, x, y, y, lse, y)}
+                        x, k, v, y, lse, y)}
         for name, lowered in calls.items():
             text = lowered.compile().as_text()
             assert text.count('custom_call_target="tpu_custom_call"') == (

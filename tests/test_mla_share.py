@@ -153,7 +153,7 @@ def test_sdpa_gate_takes_unequal_widths_and_keeps_the_old_limit():
 # the expert layer as a share
 
 
-def _whole_layer(x, gate, bias, wi, wu, wo, top_k, scale):
+def _whole_layer(x, gate, bias, wi, wu, wo, top_k, scale, epsilon=1e-20):
     """The uncut layer in plain numpy/jnp: sigmoid scores, top-k of score +
     bias, renormalised weights times scale, every chosen pair computed."""
     import jax
@@ -162,7 +162,7 @@ def _whole_layer(x, gate, bias, wi, wu, wo, top_k, scale):
     s = jax.nn.sigmoid(x @ gate)
     _, idx = jax.lax.top_k(s + bias, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    w = w / (w.sum(-1, keepdims=True) + epsilon) * scale
     out = jnp.zeros_like(x)
     for e in range(wi.shape[0]):
         y = (jax.nn.silu(x @ wi[e]) * (x @ wu[e])) @ wo[e]
@@ -170,28 +170,39 @@ def _whole_layer(x, gate, bias, wi, wu, wo, top_k, scale):
     return out, idx
 
 
-def test_eight_shares_add_up_to_the_uncut_layer():
-    """held 8 of 64, top-6, sigmoid, bias, scale 2.446: the layer run 8
-    times with first = 0, 8, ..., 56 and the shared expert counted ONCE
-    adds up to the whole layer; every share's counts are the whole
-    layer's, its held pairs are its slice of them, nothing is dropped."""
+@pytest.mark.parametrize("k,scale,epsilon,shared_hidden", [
+    (6, 2.446, 1e-20, 24), (4, 1.0, 1e-6, 0)],
+    ids=["moonlight_with_a_shared_expert", "lfm2_without"])
+def test_eight_shares_add_up_to_the_uncut_layer(k, scale, epsilon,
+                                                shared_hidden):
+    """held 8 of 64, sigmoid, bias; Moonlight's top-6, scale 2.446 and a
+    shared expert, LFM2's top-4, scale 1, epsilon 1e-6 and NO shared
+    expert: the layer run 8 times with first = 0, 8, ..., 56 (and the
+    shared expert, where there is one, counted ONCE) adds up to the whole
+    layer; every share's counts are the whole layer's, its held pairs are
+    its slice of them, nothing is dropped."""
     import jax
     import jax.numpy as jnp
 
-    T, D, E, H, k, held = 64, 32, 64, 16, 6, 8
+    T, D, E, H, held = 64, 32, 64, 16, 8
     with jax.enable_x64(False):
         x = jnp.asarray(_rand((T, D), 1))
         gate = jnp.asarray(_rand((D, E), 2, 0.5))
         bias = jnp.asarray(_rand((E,), 3, 0.05))
         wi, wu = (jnp.asarray(_rand((E, D, H), i, 0.3)) for i in (4, 5))
         wo = jnp.asarray(_rand((E, H, D), 6, 0.3))
-        shared = tuple(jnp.asarray(_rand(s, i, 0.3)) for i, s in (
-            (7, (D, 24)), (8, (D, 24)), (9, (24, D))))
-        want, idx = _whole_layer(x, gate, bias, wi, wu, wo, k, 2.446)
-        want = want + (jax.nn.silu(x @ shared[0]) * (x @ shared[1])
-                       ) @ shared[2]
+        want, idx = _whole_layer(x, gate, bias, wi, wu, wo, k, scale,
+                                 epsilon)
+        shared = None
+        if shared_hidden:
+            shared = tuple(jnp.asarray(_rand(s, i, 0.3)) for i, s in (
+                (7, (D, shared_hidden)), (8, (D, shared_hidden)),
+                (9, (shared_hidden, D))))
+            want = want + (jax.nn.silu(x @ shared[0]) * (x @ shared[1])
+                           ) @ shared[2]
         ctx = reg.EmitContext(None, is_test=False)
-        route = {"scoring": "sigmoid", "renormalise": True, "scale": 2.446}
+        route = {"scoring": "sigmoid", "renormalise": True, "scale": scale,
+                 "epsilon": epsilon}
         total = jnp.zeros_like(x)
         whole_counts = np.bincount(np.asarray(idx).ravel(), minlength=E)
         for first in range(0, E, held):
@@ -206,7 +217,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
             assert float(pairs[0]) == whole_counts[at].sum()
             assert float(dropped[0]) == 0.0
             assert scores.shape == (T, E) and weights.shape == (T, k)
-            np.testing.assert_allclose(weights.sum(-1), 2.446, rtol=1e-5)
+            np.testing.assert_allclose(weights.sum(-1), scale, rtol=1e-5)
         assert whole_counts.sum() == T * k
         np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
