@@ -32,7 +32,8 @@ H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   (dkv then still on the forward's tile, in two strips a side).
   The forward does not follow the scores at all (0.597 unsplit, 0.600 in
   strips of 128 rows, 0.634 of 256): its time is the per-row softmax
-  bookkeeping and the logsumexp row's relayout (0.471 without it).  m and
+  bookkeeping and the logsumexp row's relayout (0.471 without it; PR 34
+  took the relayout and, at one K block a head, the bookkeeping out).  m and
   l as [rows, 1] columns instead of 1-D rows took 0.942 -> 0.817 off it
   before any skipping, one block a head (`one_block_a_head`) 0.817 ->
   0.597.
@@ -50,6 +51,52 @@ H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   against dense float32 attention reads the same to four digits either
   way), and rounding p to bf16 first is one more pass over the tile
   (0.480 -> 0.485); bf16 stands because it is the precision stated.
+
+- What a pass over the score tile costs: NOTHING that shows (PR 34,
+  device ms a call from a trace, forward / dq / dkv, each step alone
+  on PR 33's kernels; shapes: B 8 H 16 T 1024 D 64 | T 4096 D 128 |
+  T 8192 192 / 128 | T 8192 D 64, 32 query heads on 8):
+    as they were      0.5631 0.3815 0.4856 | 0.8232 0.7483 0.8683 |
+                      3.4962 4.3488 4.7483 | 5.2009 5.3677 6.7770
+    `ds` without the scale, the factor on dq's and dk's accumulators
+                      0.5630 0.3806 0.4844 | 0.8234 0.7479 0.8685 |
+                      3.4958 4.3441 4.7502 | 5.2009 5.3673 6.7778
+    the mask on the sub-tile the diagonal crosses only (8 of 36 units
+    of 128 x 128 at T 1024 instead of 36)
+                      0.5617 0.3821 0.4845 | 0.8216 0.7500 0.8686 |
+                      3.4965 4.3490 4.7480 | 5.1958 5.3654 6.7816
+    the running max on raw scores, the scale in the exponent
+                      0.5629 0.3819 0.4854 | 0.8243 0.7475 0.8683 |
+                      3.4961 4.3486 4.7484 | 5.1561 5.3664 6.7766
+    and that exponent a power of two (`exp2`, log2(e) in the constant)
+                      0.5580 0.3807 0.4842 | 0.8118 0.7487 0.8677 |
+                      3.4653 4.3458 4.7475 | 5.0372 5.3660 6.7755
+  Two multiplies and five operations of a mask an element, taken off
+  78% of the elements, move no kernel by 0.3%: the VPU has slack under
+  all three.  dq and dkv run at 77% and 81% of what the MXU can do with
+  a 64-wide head (a 64-deep contraction and a 64-wide result each fill
+  half a pass: 3 and 4 products of 128 x W x 128 a strip), and their
+  logsumexp and delta columns are free (dq with constants in their
+  place: 0.3760).  Only the forward was far from that, and not by its
+  tile: by what it does with COLUMNS.  (1) Its logsumexp leaves as a
+  lane row, and `column[:, 0]` is a relayout of sublanes into lanes:
+  the same row from selects on a [128, 128] identity and adds down the
+  sublanes (`_column_as_row`) took 0.5580 -> 0.4656 | 0.8118 -> 0.7989
+  | 3.4651 -> 3.4622 | 5.0371 -> 4.8228.  (2) Where one K block holds
+  the sequence nothing needs carrying: no scratch, no correction, a
+  strip's result leaves as it is made: 0.4656 -> 0.3908 at T 1024
+  (0.4386 from 0.7132 without a mask); the longer shapes have K blocks
+  to carry across and are untouched.  As it stands: 0.3905 | 0.7964 |
+  3.4536 | 4.8175, dq and dkv the parent's.  Tried on top and NOT kept:
+  the normalizer from the MXU (a column of ones beside V, free where
+  Dv is 64) 0.3908 -> 0.3707 at T 1024 but 4.8234 -> 4.9925 at T 8192,
+  and it sums the ROUNDED probabilities; the normalizer as lane-wise
+  partial sums carried across K blocks, reduced once a q block (0.7964
+  -> 0.8113 | 3.4536 -> 3.4652 | 4.8175 -> 4.8682: the sum's lane
+  reduction is not what the long shapes wait for); the forward on dkv's
+  transposed tile (max and sum down the sublanes, no column anywhere)
+  0.6092 | 0.8772 | 3.7613 | 4.7913: `v^T p^T` streams 64 rows a
+  weight tile through the MXU and transposes V.
 
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
@@ -307,9 +354,39 @@ def _run_block(d, bq: int, bk: int, plan, strip):
         pl.when(d == off)(functools.partial(walk, off, strips))
 
 
-def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
+_LOG2E = 1.4426950408889634  # the forward's exponent is a power of two
+
+
+def _column_as_row(col):
+    """A [n, 1] column as the [1, n] lane row the logsumexp is stored as,
+    n in whole lane tiles: each 128 rows are set on the diagonal of a
+    [128, 128] tile and summed down the sublanes (exact: one term a
+    sum), which Mosaic runs as selects and adds; `col[:, 0]`, its relayout
+    of sublanes into lanes, cost the forward a fifth of its time at T 1024
+    (module docstring, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+    parts = [jnp.sum(jnp.where(eye, col[r:r + 128, :], 0.0), axis=0,
+                     keepdims=True) for r in range(0, col.shape[0], 128)]
+    return jnp.concatenate(parts, axis=1)
+
+
+def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
               scale: float, bq: int, bk: int, plan):
-    """`plan` is None for a non-causal call, else the call's _Plan."""
+    """`plan` is None for a non-causal call, else the call's _Plan.
+    `scratch` is the running max, normalizer and accumulator carried
+    across K blocks, or nothing where the K block holds the whole
+    sequence: a strip of q rows then meets all its columns in one visit,
+    its softmax is final as it is computed and leaves at once.
+
+    The running max is kept on the RAW scores (a maximum commutes with a
+    positive factor) and the scale meets the tile once, inside the
+    exponent, with log2(e): `exp2((s - m) * c)`.  The logsumexp that
+    leaves is that of the SCALED scores, `m * scale + log(l)`: the
+    backward and ring attention's merge take it as such."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -318,56 +395,76 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
     q0, k0 = qi * bq, kj * bk
-
-    @pl.when(kj == 0)
-    def _init():
-        m_sc[...] = jnp.full(m_sc.shape, -1e30, dtype=jnp.float32)
-        l_sc[...] = jnp.zeros(l_sc.shape, dtype=jnp.float32)
-        acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
+    c = scale * _LOG2E
+    whole = not scratch
 
     def tile(q, cols, carry, ahead=None):
-        """Online-softmax update of q's rows (running max, normalizer,
-        accumulator) by the K/V rows `cols` of this block; masked where
-        `ahead` says how far q's first row lies after the first of them."""
-        m_prev, l_prev, acc = carry
+        """(max, normalizer, accumulator) of q's rows over the K/V rows
+        `cols` of this block, folded into `carry` (the three from the K
+        blocks before; None where there are none); masked where `ahead`
+        says how far q's first row lies after the first of `cols`."""
         k = k_ref[0, cols, :]
         v = v_ref[0, cols, :]
-        # bf16 GEMM, f32 accumulate (full-rate MXU), then scale in f32
+        # bf16 GEMM, f32 accumulate (full-rate MXU)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32)
         if ahead is not None:
             s = _below_diagonal(s, ahead)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        m = s.max(axis=-1, keepdims=True)
+        if carry is not None:
+            m_prev, l_prev, acc = carry
+            m = jnp.maximum(m_prev, m)
+        p = jnp.exp2((s - m) * c)
+        l = p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr + pv
+        if carry is None:
+            return m, l, pv
+        corr = jnp.exp2((m_prev - m) * c)
+        return m, l_prev * corr + l, acc * corr + pv
+
+    def leave(at, row, m, l, acc):
+        """Write out the block's rows `at`, rows `row` of the sequence."""
+        o_ref[0, at, :] = (acc / l).astype(o_ref.dtype)
+        if lse_ref is None:
+            return
+        lse = m * scale + jnp.log(l)
+        if lse.shape[0] % 128:  # off the lane grid (interpret mode's tiny
+            lse_ref[0, 0, row] = lse[:, 0]  # blocks, a short ring chunk)
+        else:
+            lse_ref[0, :, row] = _column_as_row(lse)
 
     def update(r0, rows, cols, ahead=None):
         at = pl.ds(r0, rows)
-        # q stays in its input dtype: bf16 keeps the MXU at full rate
-        m, l, acc = tile(q_ref[0, at, :], cols,
-                         (m_sc[at, :], l_sc[at, :], acc_sc[at, :]), ahead)
-        m_sc[at, :] = m
-        l_sc[at, :] = l
-        acc_sc[at, :] = acc
+        q = q_ref[0, at, :]  # in its input dtype: bf16 keeps the MXU's rate
+        if whole:
+            leave(at, pl.ds(q0 + r0, rows), *tile(q, cols, None, ahead))
+            return
+        m_sc[at, :], l_sc[at, :], acc_sc[at, :] = tile(
+            q, cols, (m_sc[at, :], l_sc[at, :], acc_sc[at, :]), ahead)
+
+    if not whole:
+        m_sc, l_sc, acc_sc = scratch
+
+        @pl.when(kj == 0)
+        def _init():
+            m_sc[...] = jnp.full(m_sc.shape, -1e30, dtype=jnp.float32)
+            l_sc[...] = jnp.zeros(l_sc.shape, dtype=jnp.float32)
+            acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
     _run_block(q0 - k0, bq, bk, plan, update)
 
-    @pl.when(kj == nk - 1)
-    def _finish():
-        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse_ref[0, 0, pl.ds(q0, bq)] = (
-                m_sc[...] + jnp.log(l_sc[...]))[:, 0]
+    if not whole:
+        @pl.when(kj == nk - 1)
+        def _finish():
+            leave(slice(None), pl.ds(q0, bq), m_sc[...], l_sc[...],
+                  acc_sc[...])
 
 
-def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, **kw):
-    _fwd_body(q_ref, k_ref, v_ref, o_ref, None, m_sc, l_sc, acc_sc, **kw)
+def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
+    _fwd_body(q_ref, k_ref, v_ref, o_ref, None, *scratch, **kw)
 
 
 @functools.lru_cache(maxsize=None)
@@ -402,7 +499,8 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
         in_specs=in_specs,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
-        scratch_shapes=[
+        # nothing is carried where one K block holds the sequence
+        scratch_shapes=[] if bk == T else [
             # the running max and normalizer as columns: they meet the
             # score rows as [rows, 1] with no relayout
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -430,6 +528,10 @@ def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
     group = _group(q, k, v)
     bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
     s = scale if scale is not None else 1.0 / (D ** 0.5)
+    if not s > 0:
+        raise ValueError(
+            f"flash attention: scale {s!r}; the forward keeps its running "
+            f"max on raw scores, which takes a positive scale")
     plan = _causal_plan("flash_fwd", B * H, T, bq, bk) if causal else None
     return _fwd_call(B * H, T, D, bq, bk, plan, with_lse, q.dtype,
                      interpret, s, Dv, group)(
