@@ -539,11 +539,10 @@ def cmd_attribute(args) -> int:
     Runs the deterministic CPU segment oracle
     (observability/attribution.py), joins measured per-op time against
     the static cost model, publishes the op_pred_vs_measured gauges,
-    and emits ONE bench-schema artifact line.  --profile additionally
-    captures a jax.profiler trace of jitted steps with the op identity
-    scopes threaded (the on-chip path);
-    --update-calibration feeds the table into the calibration store the
-    autotune prior consumes."""
+    and emits ONE bench-schema artifact line.  --update-calibration
+    feeds the table into the calibration store the autotune prior
+    consumes.  (On a chip the table comes from a traced benchmark run:
+    docs/observability.md, "Per-op attribution".)"""
     import json as _json
 
     from . import observability as obs
@@ -561,22 +560,18 @@ def cmd_attribute(args) -> int:
     if builder is not None:
         label = args.model
         fluid.reset()
-        feed, fetch, bs = builder()
+        feed, _fetch, bs = builder()
         program = fluid.default_main_program()
-        exe = fluid.Executor(fluid.default_place())
-        exe.run(fluid.default_startup_program())
+        fluid.Executor(fluid.default_place()).run(
+            fluid.default_startup_program())
         scope = None  # the startup run populated the global scope
     else:
         from .analysis import equivalence as eqv
         from .analysis.dataflow import state_classes
-        from .framework.executor import Executor
-        from .framework.place import CPUPlace
         from .framework.scope import Scope
 
-        program, feed_names, fetch = _load_program_any(args.model)
+        program, feed_names, _fetch = _load_program_any(args.model)
         block = program.global_block()
-        if fetch is None:
-            fetch = eqv.sink_outputs(block)
         if feed_names is None:
             feed_names = [v.name for v in block.vars.values()
                           if v.is_data]
@@ -596,34 +591,12 @@ def cmd_attribute(args) -> int:
                 scope.set(name, eqv._seed_array(
                     name, eqv._bind(dv.shape, 1), dv.dtype or "float32",
                     0))
-        exe = Executor(CPUPlace())
 
     table = obs.attribution.attribute_cpu(
         program, feed, scope=scope, batch_size=bs,
         repeats=args.repeats, chip=chip)
     obs.attribution.publish(table, label)
     row = obs.attribution.artifact_row(table, label)
-
-    if args.profile:
-        # jitted steps under jax.profiler with the identity scopes
-        # forced on; a FRESH executor so the step compiles scoped
-        # instead of reusing an unscoped cached executable
-        pexe = fluid.Executor(fluid.default_place()) \
-            if builder is not None else type(exe)(exe.place)
-
-        def step(i):
-            pexe.run(program, feed=dict(feed), fetch_list=list(fetch),
-                     scope=scope, rng_step=i)
-
-        cap = obs.attribution.capture_profile(step, args.profile,
-                                              steps=args.steps)
-        row["profile_trace"] = cap["trace_file"] or cap["trace_dir"]
-        if cap["by_scope"]:
-            ptab = obs.attribution.table_from_scopes(
-                program.global_block(), cap["by_scope"],
-                batch_size=bs, chip=chip)
-            row["profile_table"] = obs.attribution.artifact_row(
-                ptab, label)["by_type"]
 
     if args.update_calibration:
         entry = obs.calibration.default_store().record_attribution(table)
@@ -970,8 +943,6 @@ def main(argv=None) -> int:
                         "small_lm|lstm) or a saved-model dir/file")
     p.add_argument("--repeats", type=int, default=3,
                    help="oracle walks per op (median is reported)")
-    p.add_argument("--steps", type=int, default=3,
-                   help="jitted steps under --profile")
     p.add_argument("--batch-size", type=int, default=2,
                    help="binds -1 feed dims of saved models")
     p.add_argument("--chip", default=None,
@@ -979,10 +950,6 @@ def main(argv=None) -> int:
                         "detected backend)")
     p.add_argument("--top", type=int, default=8,
                    help="op types shown in the human table")
-    p.add_argument("--profile", default=None,
-                   help="also capture a jax.profiler trace (Perfetto) "
-                        "of jitted steps into this dir — the on-chip "
-                        "op_attribution evidence path")
     p.add_argument("--update-calibration", action="store_true",
                    help="feed the table into the calibration store "
                         "(observability/calibration.py)")

@@ -15,6 +15,7 @@ save/load/prune/transpile contract of framework.proto without carrying proto2.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import itertools
 import json
@@ -313,20 +314,27 @@ class Block:
         return [v for v in self.vars.values() if isinstance(v, Parameter)]
 
     # -- ops ----------------------------------------------------------------
-    def append_op(self, type, inputs=None, outputs=None, attrs=None) -> Operator:
+    def _new_op(self, type, inputs, outputs, attrs) -> Operator:
+        """The one place an op enters a block."""
         op = Operator(self, type, inputs, outputs, attrs)
         # stable per-op uid: the PRNG salt for stochastic ops (ops/registry.py
         # EmitContext.rng) — survives serialization so replays are exact
         op.attrs.setdefault("__uid__", self.program._take_uid())
-        self.ops.append(op)
+        # the model part being built (Program.part_guard), unless the op
+        # names its own
+        if self.program._part and not op.attrs.get("part"):
+            op.attrs["part"] = self.program._part
         self.program._bump()
         return op
 
+    def append_op(self, type, inputs=None, outputs=None, attrs=None) -> Operator:
+        op = self._new_op(type, inputs, outputs, attrs)
+        self.ops.append(op)
+        return op
+
     def prepend_op(self, type, inputs=None, outputs=None, attrs=None) -> Operator:
-        op = Operator(self, type, inputs, outputs, attrs)
-        op.attrs.setdefault("__uid__", self.program._take_uid())
+        op = self._new_op(type, inputs, outputs, attrs)
         self.ops.insert(0, op)
-        self.program._bump()
         return op
 
     def to_dict(self):
@@ -367,10 +375,24 @@ class Program:
         self._cache_token = next(Program._token_counter)
         self._next_uid = 0
         self.random_seed = 0
+        self._part = None  # the part_guard in force
 
     def _take_uid(self) -> int:
         self._next_uid += 1
         return self._next_uid - 1
+
+    @contextlib.contextmanager
+    def part_guard(self, name: str):
+        """Every op appended to this program inside the guard that names no
+        `part` of its own gets `part` = `name` (`lm.head`): the executor
+        lowers it, and its grad op, inside the scope `pdtpu.<name>`
+        (observability/attribution.py), so a trace says which part of the
+        model an instruction belongs to."""
+        outer, self._part = self._part, name
+        try:
+            yield
+        finally:
+            self._part = outer
 
     # -- structure ----------------------------------------------------------
     def global_block(self) -> Block:

@@ -53,6 +53,12 @@ _MET_JAX_COMPILES = _MET.counter(
     "executor_jax_compiles_total",
     "XLA compiles inside Executor.run; cached=1 came from the persistent "
     "cache")
+_MET_OP_EMIT_S = _MET.counter(
+    "executor_op_emit_seconds_total",
+    "host seconds inside each op's emitter while a program is traced "
+    "(once a compile, never a step), by op type (`<fwd>_grad` for a "
+    "generic_grad); self time: an op that lowers a sub-block does not "
+    "count the ops of that block")
 
 # jax.monitoring's duration events of one compile -> the counter's phase
 _COMPILE_PHASES = {
@@ -934,13 +940,21 @@ def _lower_op(op, env, ctx):
 
 def _lower_ops(ops, env, ctx):
     """Trace every op's emitter into the surrounding JAX trace, threading the
-    SSA environment (name → traced array).  With op attribution enabled each
-    op is wrapped in its identity named-scope so every HLO instruction maps
-    back to its desc op; disabled, the scope is a shared no-op (one attribute
-    check per op per TRACE, never per step)."""
+    SSA environment (name → traced array).  Each op is lowered inside its
+    identity scope (`pdop__<type>__u<uid>`) and, where its desc names one,
+    its model part's (`pdtpu.<part>`), so every HLO instruction maps back to
+    the desc op that emitted it; the emitter's host time goes to
+    `executor_op_emit_seconds_total`.  All of it once an op a TRACE: a step
+    of a compiled program never comes here."""
     for op in ops:
         if op.type in _NOOP_TYPES:
             continue
+        outer, ctx.emit_nested_s = ctx.emit_nested_s, 0.0
+        t0 = _monotime()
         with _attr.op_scope(op):
             _lower_op(op, env, ctx)
+        spent = _monotime() - t0
+        _MET_OP_EMIT_S.inc(max(spent - ctx.emit_nested_s, 0.0),
+                           op=_attr.op_type(op))
+        ctx.emit_nested_s = outer + spent
     return env

@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 
 from .. import layers
+from ..framework.core import default_main_program
 from ..framework.initializer import NormalInitializer
 from ..framework.layer_helper import LayerHelper
 from ..layers import fluid_compat
@@ -179,8 +180,10 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 m = layers.dropout(m, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, m)
 
-    return layers.fc(normed(x), vocab_size, num_flatten_dims=2,
-                     param_attr=attr, bias_attr=False)
+    h = normed(x)
+    with default_main_program().part_guard("lm.head"):
+        return layers.fc(h, vocab_size, num_flatten_dims=2,
+                         param_attr=attr, bias_attr=False)
 
 
 # decoder_lm's arguments that change the block's parameters or equations,
@@ -196,11 +199,12 @@ def lm_loss(logits, targets, dtype="float32"):
     shifted by the data pipeline).  Softmax runs in f32 regardless of the
     model compute dtype."""
     V = logits.shape[-1]
-    flat = layers.reshape(logits, [-1, V])
-    if dtype != "float32":
-        flat = layers.cast(flat, "float32")
-    tgt = layers.reshape(targets, [-1, 1])
-    return layers.mean(layers.softmax_with_cross_entropy(flat, tgt))
+    with default_main_program().part_guard("lm.loss"):
+        flat = layers.reshape(logits, [-1, V])
+        if dtype != "float32":
+            flat = layers.cast(flat, "float32")
+        tgt = layers.reshape(targets, [-1, 1])
+        return layers.mean(layers.softmax_with_cross_entropy(flat, tgt))
 
 
 def moe_lm_loss(logits, targets, router_outputs, dtype="float32",
@@ -245,8 +249,6 @@ class DecoderLM:
         self._block = {}
 
     def logits(self, tokens, **kw):
-        from ..framework.core import default_main_program
-
         if self._params is not None:
             raise RuntimeError(
                 "DecoderLM.logits() already built this model's tower — "
@@ -345,8 +347,6 @@ class DecoderLM:
         programs (each declaring the same names) share one physical
         cache, exactly like parameters are shared between the tower and
         generation programs."""
-        from ..framework.core import default_main_program
-
         dh = self.dim // self.n_heads
         shape = (self.n_layers, int(num_pages), self.n_heads,
                  int(page_size), dh)
@@ -515,8 +515,6 @@ class DecoderLM:
     def _decode_inputs(self, prompt):
         """Wire the recorded tower parameters into a decode op's slots,
         declaring them in the current program (see generate())."""
-        from ..framework.core import default_main_program
-
         if self._block:
             raise NotImplementedError(
                 f"DecoderLM: the generation and paged-serving ops run "

@@ -12,8 +12,8 @@ Three layers, one namespace:
     predictions attached per program, measured step times and XLA peaks
     recorded against them, error ratios materialized as metrics;
   * :mod:`.attribution` — per-op device-time attribution (ISSUE 16):
-    named-scope identity threading, the profile capture + CPU segment
-    oracle, and the per-op predicted-vs-measured table;
+    named-scope identity threading (always on), the CPU segment oracle,
+    and the per-op predicted-vs-measured table;
   * :mod:`.calibration` — the sealed per-(op type, chip, dtype)
     correction-factor store the attribution tables feed and the cost
     model/autotune prior consume.
@@ -111,4 +111,3 @@ def reset():
     REGISTRY.reset()
     TRACER.reset()
     accounting.reset()
-    attribution.reset()

@@ -1,27 +1,28 @@
-"""Per-op device-time attribution (ISSUE 16): profile -> ProgramDesc.
+"""Per-op device-time attribution (ISSUE 16, ISSUE 35): profile -> ProgramDesc.
 
-Three pieces, one table:
+Two pieces, one identity:
 
-  * **identity threading** — :func:`op_scope` is the repo's ONE
-    ``jax.named_scope`` mint (repo_lint rule 10).  The executor/compiler
-    wrap every lowered op in it, so each HLO instruction's metadata
-    carries ``pdop__<type>__u<uid>`` and traces back to the desc op that
-    produced it.  Off by default: when disabled the scope is a shared
-    no-op context and the lowering hot path pays one attribute check
-    per op per TRACE (never per step).
-  * **capture** — :func:`capture_profile` runs steps under
-    ``jax.profiler.trace`` (Perfetto output — the on-chip path) and
-    best-effort parses the
-    scope-named events back into per-op durations;
-    :func:`attribute_cpu` is the deterministic CPU fallback oracle:
-    segment-timed eager execution over the hazard-respecting
-    topological order derived from ``analysis/dataflow.py`` (RAW edges
-    from ``dependency_graph`` plus every textual read/write-before-write
-    ordering, so the schedule preserves exactly the semantics the linear
-    executor's textual order guarantees).
-  * **join** — both paths produce the SAME per-op table: measured time
-    share joined against ``analysis/cost.py``'s per-op FLOPs/bytes
-    prediction, published as ``op_pred_vs_measured{op_type=...}`` /
+  * **identity threading** — :func:`op_scope` and :func:`part_scope` are
+    the repo's ONE ``jax.named_scope`` mint (repo_lint rule 10).  The
+    executor wraps every lowered op in ``pdop__<type>__u<uid>`` (a grad
+    op says whose gradient it is: ``pdop__mul_grad__u<uid>``) and, inside
+    it, in the ``pdtpu.<part>`` its desc names (attr ``part``; a grad op
+    takes the forward op's), so each HLO instruction's metadata traces
+    back to the desc op and the model part that produced it.  Always on:
+    a scope is metadata of the traced program, costs one context manager
+    an op a TRACE and a step nothing.  On a chip the compiled program's
+    own metadata is read back from a profiler trace by the benchmark
+    (``benchmarks/reduce/op_scopes.py``: the xplane's HloProto joins an
+    event's instruction to these names).
+  * **the CPU oracle and the join** — :func:`attribute_cpu` is the
+    deterministic CPU oracle: segment-timed eager execution over the
+    hazard-respecting topological order derived from
+    ``analysis/dataflow.py`` (RAW edges from ``dependency_graph`` plus
+    every textual read/write-before-write ordering, so the schedule
+    preserves exactly the semantics the linear executor's textual order
+    guarantees).  :func:`build_table` joins its measured time share
+    against ``analysis/cost.py``'s per-op FLOPs/bytes prediction,
+    published as ``op_pred_vs_measured{op_type=...}`` /
     ``op_measured_time_share`` gauges and a bench-schema artifact row.
     The table is also what feeds the calibration store
     (observability/calibration.py) — measured/predicted per
@@ -32,28 +33,17 @@ Three pieces, one table:
 from __future__ import annotations
 
 import contextlib
-import glob
-import gzip
-import json
-import os
 import re
 from statistics import median
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 from .metrics import REGISTRY, artifact_metric, monotime
 
 # ---------------------------------------------------------------------------
 # identity threading: the one named-scope mint
 
-_ENV_FLAG = "PADDLE_TPU_OP_ATTRIBUTION"
 _SCOPE_FMT = "pdop__{type}__u{uid}"
 _SCOPE_RE = re.compile(r"pdop__([A-Za-z0-9_]+)__u(\d+)")
-
-# None -> defer to the env gate; True/False -> explicit enable()/disable()
-_override: Optional[bool] = None
-
-# one shared no-op context for the disabled path (reentrant + reusable)
-_NOOP_SCOPE = contextlib.nullcontext()
 
 # gauge handles resolved once (families survive REGISTRY.reset(), the
 # accounting.py idiom)
@@ -69,58 +59,57 @@ _G_COVERAGE = REGISTRY.gauge(
     "fraction of measured step time attributed to named desc ops")
 
 
-def enabled() -> bool:
-    """Is op-identity threading on?  Explicit enable()/disable() wins;
-    otherwise the $PADDLE_TPU_OP_ATTRIBUTION gate (default off)."""
-    if _override is not None:
-        return _override
-    return os.environ.get(_ENV_FLAG, "0") not in ("", "0", "false")
+def op_type(op) -> str:
+    """The type an op is known by in scopes and counters: its own, and for
+    a `generic_grad` the forward op's with `_grad` (`mul_grad`)."""
+    if op.type == "generic_grad":
+        return f"{op.attrs.get('__fwd_type__', 'generic')}_grad"
+    return op.type
 
 
-def enable():
-    global _override
-    _override = True
-
-
-def disable():
-    global _override
-    _override = False
-
-
-def reset():
-    """Back to the env-gated default (fluid.reset() hook)."""
-    global _override
-    _override = None
+def op_part(op):
+    """The model part an op's desc names (attr `part`; a `generic_grad`
+    carries the forward op's), or None."""
+    attrs = op.attrs
+    if op.type == "generic_grad":
+        attrs = attrs.get("__fwd_attrs__") or {}
+    return attrs.get("part") or None
 
 
 def scope_name(op) -> str:
-    """The per-op scope string: type + desc uid (core.py's per-program
-    monotonic ``__uid__``), the same identity ctx.rng folds in."""
-    return _SCOPE_FMT.format(type=op.type,
+    """The per-op scope string: type (:func:`op_type`) + desc uid
+    (core.py's per-program monotonic ``__uid__``), the same identity
+    ctx.rng folds in; a grad op shares its forward op's uid."""
+    return _SCOPE_FMT.format(type=op_type(op),
                              uid=int(op.attrs.get("__uid__", 0)))
 
 
+@contextlib.contextmanager
 def op_scope(op):
-    """Context manager wrapping one op's lowering in a ``jax.named_scope``
-    carrying its desc identity — THE one place the repo opens a named
-    scope (repo_lint rule 10).  A shared no-op when attribution is off,
-    so the executor/compiler call it unconditionally."""
-    if not enabled():
-        return _NOOP_SCOPE
+    """Wraps one op's lowering in the ``jax.named_scope`` that carries its
+    desc identity and, inside it, in its model part's where its desc names
+    one (:func:`op_part`), so forward and backward instructions both carry
+    `pdtpu.<part>`.  Reached once an op a TRACE, never by a step of a
+    compiled program."""
     import jax
 
-    return jax.named_scope(scope_name(op))
+    part = op_part(op)
+    with jax.named_scope(scope_name(op)):
+        if part is None:
+            yield
+        else:
+            with part_scope(part):
+                yield
 
 
 def part_scope(name: str):
-    """A named part INSIDE one op's lowering (`pdtpu.<name>`, as the
-    tracer's spans are named): an emitter with several stages worth telling
-    apart in an HLO dump (the dropless `moe` op's route / permute / experts
-    / combine) wraps each in one.  It nests under the op's own scope when
-    attribution is on and never looks like one (`parse_scope` matches
-    `pdop__...` only); always on, since a scope is metadata of the traced
-    program and costs a step nothing.  Beside `op_scope`, so that named
-    scopes still have one home."""
+    """A named part of the model (`pdtpu.<name>`, as the tracer's spans
+    are named), nested under the op's own scope and never looking like
+    one (`parse_scope` matches `pdop__...` only).  The executor opens the
+    one an op's desc names (attr `part`: `lm.head`, `attn.rope`); an
+    emitter with several stages worth telling apart in a trace (the
+    dropless `moe` op's route / permute / experts / combine) wraps each
+    in one.  Beside `op_scope`, so that named scopes have one home."""
     import jax
 
     return jax.named_scope("pdtpu." + name)
@@ -282,84 +271,6 @@ def attribute_cpu(program, feed, *, scope=None, state=None, block_id=0,
     return build_table(block, measured, median(walls),
                        batch_size=batch_size, chip=chip,
                        mode="cpu-oracle", repeats=int(repeats))
-
-
-# ---------------------------------------------------------------------------
-# profiler capture path (the chip window's op_attribution evidence)
-
-
-def capture_profile(step_fn, out_dir, steps=3) -> dict:
-    """Run ``step_fn(i)`` for `steps` iterations under a
-    ``jax.profiler`` trace with op-identity threading forced on, then
-    best-effort parse the Perfetto/Chrome events back into per-scope
-    durations.  Returns ``{"trace_dir", "trace_file", "by_scope"}``;
-    ``by_scope`` is None when the backend's trace carries no parsable
-    scope-named events (the CPU case) — callers fall back to
-    :func:`attribute_cpu`, which produces the same table shape."""
-    import jax
-
-    os.makedirs(out_dir, exist_ok=True)
-    prev = _override
-    enable()
-    try:
-        with jax.profiler.trace(out_dir):
-            for i in range(max(1, int(steps))):
-                step_fn(i)
-    finally:
-        globals()["_override"] = prev
-    files = sorted(
-        glob.glob(os.path.join(out_dir, "**", "*.trace.json.gz"),
-                  recursive=True)
-        + glob.glob(os.path.join(out_dir, "**", "*.trace.json"),
-                    recursive=True))
-    trace_file = files[-1] if files else None
-    by_scope = _parse_trace_events(trace_file) if trace_file else None
-    return {"trace_dir": out_dir, "trace_file": trace_file,
-            "by_scope": by_scope or None}
-
-
-def _parse_trace_events(path) -> Optional[Dict[tuple, float]]:
-    """{(op_type, uid): seconds} accumulated over complete ('X') events
-    whose name/args carry a pdop scope; None on unreadable/empty."""
-    try:
-        opener = gzip.open if str(path).endswith(".gz") else open
-        with opener(path, "rt") as f:
-            obj = json.load(f)
-    except Exception:
-        return None
-    events = obj.get("traceEvents") if isinstance(obj, dict) else None
-    if not isinstance(events, list):
-        return None
-    acc: Dict[tuple, float] = {}
-    for e in events:
-        if not isinstance(e, dict) or e.get("ph") != "X":
-            continue
-        blob = str(e.get("name", ""))
-        args = e.get("args")
-        if isinstance(args, dict):
-            blob += " " + " ".join(str(v) for v in args.values())
-        hit = parse_scope(blob)
-        if hit is None:
-            continue
-        acc[hit] = acc.get(hit, 0.0) + float(e.get("dur", 0.0)) * 1e-6
-    return acc or None
-
-
-def table_from_scopes(block, by_scope, *, batch_size=64,
-                      chip=None) -> dict:
-    """The profile path's half of "both produce the same table": map
-    parsed per-scope durations back onto desc op indices via uid and
-    join predictions exactly like the CPU oracle."""
-    by_uid = {int(op.attrs.get("__uid__", -1)): i
-              for i, op in enumerate(block.ops)}
-    measured: List[Optional[float]] = [None] * len(block.ops)
-    for (_type, uid), secs in (by_scope or {}).items():
-        i = by_uid.get(uid)
-        if i is not None:
-            measured[i] = (measured[i] or 0.0) + secs
-    total = sum(m for m in measured if m) or 0.0
-    return build_table(block, measured, total, batch_size=batch_size,
-                       chip=chip, mode="profile")
 
 
 # ---------------------------------------------------------------------------

@@ -29,15 +29,6 @@ _MET_CONV_LAYERS = _MET.counter(
     "channel (kernel)")
 
 
-def _part(attrs):
-    """The scope of an op that a layer names as one part of a larger
-    block (attr `part`: `pdtpu.attn.qk_norm`, `pdtpu.attn.rope`)."""
-    import contextlib
-
-    return (part_scope(str(attrs["part"])) if attrs.get("part")
-            else contextlib.nullcontext())
-
-
 def wide_dtype(dtype):
     """float32 for bf16 / f16 / f32 inputs, float64 for float64 ones."""
     import jax.numpy as jnp
@@ -67,9 +58,8 @@ def rms_norm(ctx, ins, attrs):
     x = ins["X"][0]
     begin = int(attrs.get("begin_norm_axis", 1))
     gain = ins["Scale"][0] if ins.get("Scale") else None
-    with _part(attrs):
-        return {"Y": [rms(x, float(attrs.get("epsilon", 1e-5)),
-                          tuple(range(begin, x.ndim)), gain)]}
+    return {"Y": [rms(x, float(attrs.get("epsilon", 1e-5)),
+                      tuple(range(begin, x.ndim)), gain)]}
 
 
 def rotate_half(x, theta: float):
@@ -97,9 +87,8 @@ def rope(ctx, ins, attrs):
     arXiv:2104.09864, as GPT-NeoX and transformers apply it): X [B, H, T,
     D] with D even; position t of every head turns the pair (x[i], x[i +
     D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1."""
-    with _part(attrs):
-        return {"Out": [rotate_half(ins["X"][0],
-                                    float(attrs.get("theta", 10000.0)))]}
+    return {"Out": [rotate_half(ins["X"][0],
+                                float(attrs.get("theta", 10000.0)))]}
 
 
 @register_op("latent_attention")

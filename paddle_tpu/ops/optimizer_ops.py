@@ -9,7 +9,18 @@ donates the buffers, making updates genuinely in-place in HBM."""
 
 from __future__ import annotations
 
+import functools
+
+from ..observability.metrics import REGISTRY as _MET
 from .registry import register_op
+
+_MET_UPDATE_BYTES = _MET.counter(
+    "optimizer_update_bytes_total",
+    "bytes one step must move for the optimizer ops traced (once a "
+    "compile, not once a step), by op type and tensor: param (read + "
+    "written), state (moments, velocity, accumulators: read + written), "
+    "grad (read; apart, since a gradient fused into its consumer never "
+    "touches HBM)")
 
 
 def _jnp():
@@ -18,13 +29,45 @@ def _jnp():
     return jnp
 
 
-@register_op("sgd", grad=None)
+def _nbytes(values) -> int:
+    return sum(v.size * v.dtype.itemsize for v in values if v is not None)
+
+
+def _update_op(type: str):
+    """Register an optimizer op (no grad op of its own) whose emission
+    counts the bytes the update moves a step.  Every output but `ParamOut`
+    is state that replaces an input of its shape and dtype, so state is
+    read and written once each; `LearningRate` and the like are scalars
+    and not counted."""
+
+    def _do(fn):
+        @functools.wraps(fn)
+        def emit(ctx, ins, attrs):
+            outs = fn(ctx, ins, attrs)
+            moved = {
+                "param": _nbytes(ins.get("Param", ()))
+                + _nbytes(outs.get("ParamOut", ())),
+                "state": 2 * sum(_nbytes(vs) for slot, vs in outs.items()
+                                 if slot != "ParamOut"),
+                "grad": _nbytes(ins.get("Grad", ())),
+            }
+            for tensor, n in moved.items():
+                if n:
+                    _MET_UPDATE_BYTES.inc(n, op=type, tensor=tensor)
+            return outs
+
+        return register_op(type, emit, grad=None)
+
+    return _do
+
+
+@_update_op("sgd")
 def sgd(ctx, ins, attrs):
     p, g, lr = ins["Param"][0], ins["Grad"][0], ins["LearningRate"][0]
     return {"ParamOut": [p - lr.reshape(()) * g.astype(p.dtype)]}
 
 
-@register_op("momentum", grad=None)
+@_update_op("momentum")
 def momentum(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -41,7 +84,7 @@ def momentum(ctx, ins, attrs):
     return {"ParamOut": [p_out], "VelocityOut": [v_out]}
 
 
-@register_op("adam", grad=None)
+@_update_op("adam")
 def adam(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -61,7 +104,7 @@ def adam(ctx, ins, attrs):
     return {"ParamOut": [p_out], "Moment1Out": [m_out], "Moment2Out": [v_out]}
 
 
-@register_op("adam_beta_pow_update", grad=None)
+@_update_op("adam_beta_pow_update")
 def adam_beta_pow_update(ctx, ins, attrs):
     """Advance Beta1Pow/Beta2Pow accumulators (the reference does this inside
     python optimizer.py's _finish_update via scale ops)."""
@@ -72,7 +115,7 @@ def adam_beta_pow_update(ctx, ins, attrs):
     }
 
 
-@register_op("adamax", grad=None)
+@_update_op("adamax")
 def adamax(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -88,7 +131,7 @@ def adamax(ctx, ins, attrs):
     return {"ParamOut": [p_out], "MomentOut": [m_out], "InfNormOut": [inf_out]}
 
 
-@register_op("adagrad", grad=None)
+@_update_op("adagrad")
 def adagrad(ctx, ins, attrs):
     jnp = _jnp()
     p, g, m = ins["Param"][0], ins["Grad"][0], ins["Moment"][0]
@@ -99,7 +142,7 @@ def adagrad(ctx, ins, attrs):
     return {"ParamOut": [p_out], "MomentOut": [m_out]}
 
 
-@register_op("decayed_adagrad", grad=None)
+@_update_op("decayed_adagrad")
 def decayed_adagrad(ctx, ins, attrs):
     jnp = _jnp()
     p, g, m = ins["Param"][0], ins["Grad"][0], ins["Moment"][0]
@@ -111,7 +154,7 @@ def decayed_adagrad(ctx, ins, attrs):
     return {"ParamOut": [p_out], "MomentOut": [m_out]}
 
 
-@register_op("adadelta", grad=None)
+@_update_op("adadelta")
 def adadelta(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -128,7 +171,7 @@ def adadelta(ctx, ins, attrs):
     }
 
 
-@register_op("rmsprop", grad=None)
+@_update_op("rmsprop")
 def rmsprop(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -143,7 +186,7 @@ def rmsprop(ctx, ins, attrs):
             "MomentOut": [mom_out]}
 
 
-@register_op("ftrl", grad=None)
+@_update_op("ftrl")
 def ftrl(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -165,7 +208,7 @@ def ftrl(ctx, ins, attrs):
             "LinearAccumOut": [lin_out]}
 
 
-@register_op("proximal_gd", grad=None)
+@_update_op("proximal_gd")
 def proximal_gd(ctx, ins, attrs):
     jnp = _jnp()
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -180,7 +223,7 @@ def proximal_gd(ctx, ins, attrs):
     return {"ParamOut": [p_out]}
 
 
-@register_op("proximal_adagrad", grad=None)
+@_update_op("proximal_adagrad")
 def proximal_adagrad(ctx, ins, attrs):
     jnp = _jnp()
     p, g, m = ins["Param"][0], ins["Grad"][0], ins["Moment"][0]
@@ -197,7 +240,7 @@ def proximal_adagrad(ctx, ins, attrs):
     return {"ParamOut": [p_out], "MomentOut": [m_out]}
 
 
-@register_op("average_accumulates", grad=None)
+@_update_op("average_accumulates")
 def average_accumulates(ctx, ins, attrs):
     """Sliding-window parameter-sum accumulation (reference
     paddle/parameter/AverageOptimizer.cpp — PARAMETER_SUM rotation; same

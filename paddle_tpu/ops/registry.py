@@ -191,6 +191,9 @@ class EmitContext:
         self._kept = {}
         # the _Replay of the forward op generic_grad is re-emitting now
         self._replay = None
+        # host seconds the ops lowered so far inside the op being lowered
+        # took (a sub-block's): _lower_ops takes them off that op's own
+        self.emit_nested_s = 0.0
 
     def rng(self, attrs) -> "object":
         """Deterministic per-op PRNG key: base key folded with the op's uid.

@@ -17,12 +17,13 @@ from paddle_tpu.models import transformer as tr
 
 
 def _desc(main):
-    """What a program IS, without the `__uid__`s: ops with their slots and
-    attributes in order, and the parameters."""
+    """What a program IS, without the `__uid__`s and the `part`s (which
+    name an op's instructions in a trace and compute nothing): ops with
+    their slots and attributes in order, and the parameters."""
     ops = [(op.type, sorted((k, len(v)) for k, v in op.inputs.items()),
             sorted((k, len(v)) for k, v in op.outputs.items()),
             sorted((k, repr(v)) for k, v in op.attrs.items()
-                   if not k.startswith("__")))
+                   if not k.startswith("__") and k != "part"))
            for op in main.global_block().ops]
     params = [(p.name, tuple(p.shape), str(p.dtype))
               for p in main.global_block().all_parameters()]
@@ -74,6 +75,19 @@ def test_gpt2_tower_is_the_parents():
                    "4995c3ae98")
     assert params == ("2daf6a02f2fb0b758333429f4a6253e6a0986f627dbe8f81e538"
                       "566886f6ee6d")
+    # what PR 35 added to the desc: the head's projection and the loss's
+    # ops say which part of the model they are, and their grad ops with them
+    named = [(op.type, op.attrs.get("__fwd_type__"),
+              (op.attrs.get("__fwd_attrs__") or op.attrs).get("part"))
+             for op in fluid.default_main_program().global_block().ops]
+    assert [(t, p) for t, _, p in named if p and t != "generic_grad"] == [
+        ("mul", "lm.head"), ("reshape", "lm.loss"), ("cast", "lm.loss"),
+        ("reshape", "lm.loss"), ("softmax_with_cross_entropy", "lm.loss"),
+        ("mean", "lm.loss")]
+    assert sorted((f, p) for t, f, p in named
+                  if p and t == "generic_grad") == [
+        ("cast", "lm.loss"), ("mean", "lm.loss"), ("mul", "lm.head"),
+        ("reshape", "lm.loss"), ("softmax_with_cross_entropy", "lm.loss")]
 
 
 def test_gpt2_kinds_spelled_out_lower_to_the_same_step():
