@@ -237,10 +237,13 @@ def _ws_sdpa(ins, outs, attrs):
     cotangents — 4 x B*H*T*S."""
     q = _operand(ins, "Q")
     k = _operand(ins, "K")
-    if q is None or k is None or len(q[0]) != 4:
+    bthd = str(attrs.get("layout", "bhtd")) == "bthd"  # Q [B, T, H * D]
+    if q is None or k is None or len(q[0]) != (3 if bthd else 4):
         return 0
-    b, h, t, _ = q[0]
-    s = k[0][2]
+    if bthd:
+        (b, t, _), h, s = q[0], int(attrs.get("num_heads", 1)), k[0][1]
+    else:
+        (b, h, t, _), s = q[0], k[0][2]
     return 4 * int(b) * int(h) * int(t) * int(s) * q[1]
 
 

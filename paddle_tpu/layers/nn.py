@@ -333,7 +333,13 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     of it) is how many heads the K and V projections have: query head h
     attends to key/value head h // (num_heads / num_kv_heads)
     (grouped-query attention).  `param_attr` is the Q, K and V
-    projections', `out_param_attr` the output projection's."""
+    projections', `out_param_attr` the output projection's.
+
+    Where nothing per head stands between the projections and attention
+    (no `rope_theta`, no `qk_norm_per_head`) the attention op takes Q, K
+    and V as [B, T, heads * head_dim] (`layout` "bthd") and no `reshape`
+    or `transpose` op is emitted on either side of it; else the heads are
+    split to [B, heads, T, head_dim] first and merged after."""
     helper = LayerHelper("multi_head_attention", name=name)
     if sp_mode not in ("ring", "alltoall"):
         raise ValueError(f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
@@ -382,29 +388,42 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                                 "part": "attn.rope"})
         return r
 
-    qh, kh, vh = (split_heads(q, num_heads), split_heads(k, kv_heads),
-                  split_heads(v, kv_heads))
-    if qk_norm_per_head:
-        qh, kh = (qk_norm(qh, begin_norm_axis=3),
-                  qk_norm(kh, begin_norm_axis=3))
-    if rope_theta is not None:
-        qh, kh = rotate(qh), rotate(kh)
-    attn = helper.create_tmp_variable(queries.dtype)
-    helper.append_op(
-        "scaled_dot_product_attention",
-        inputs={"Q": [qh.name], "K": [kh.name], "V": [vh.name]},
-        outputs={"Out": [attn.name]},
-        attrs={"causal": causal, "sp_mode": sp_mode,
-               "sp_schedule": sp_schedule},
-    )
-    back = helper.create_tmp_variable(queries.dtype)
-    helper.append_op("transpose", inputs={"X": [attn.name]},
-                     outputs={"Out": [back.name]},
-                     attrs={"axis": [0, 2, 1, 3]})
-    merged = helper.create_tmp_variable(queries.dtype, shape=queries.shape)
-    helper.append_op("reshape", inputs={"X": [back.name]},
-                     outputs={"Out": [merged.name]},
-                     attrs={"shape": [0, 0, D]})
+    sdpa_attrs = {"causal": causal, "sp_mode": sp_mode,
+                  "sp_schedule": sp_schedule}
+    if rope_theta is None and not qk_norm_per_head:
+        # nothing per head stands between the projections and attention:
+        # the op takes Q, K, V as they lie and leaves its output as the
+        # output projection reads it; no reshape, no transpose
+        merged = helper.create_tmp_variable(queries.dtype,
+                                            shape=queries.shape)
+        helper.append_op(
+            "scaled_dot_product_attention",
+            inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
+            outputs={"Out": [merged.name]},
+            attrs={**sdpa_attrs, "layout": "bthd", "num_heads": num_heads,
+                   "num_kv_heads": kv_heads})
+    else:
+        qh, kh, vh = (split_heads(q, num_heads), split_heads(k, kv_heads),
+                      split_heads(v, kv_heads))
+        if qk_norm_per_head:
+            qh, kh = (qk_norm(qh, begin_norm_axis=3),
+                      qk_norm(kh, begin_norm_axis=3))
+        if rope_theta is not None:
+            qh, kh = rotate(qh), rotate(kh)
+        attn = helper.create_tmp_variable(queries.dtype)
+        helper.append_op(
+            "scaled_dot_product_attention",
+            inputs={"Q": [qh.name], "K": [kh.name], "V": [vh.name]},
+            outputs={"Out": [attn.name]}, attrs=sdpa_attrs)
+        back = helper.create_tmp_variable(queries.dtype)
+        helper.append_op("transpose", inputs={"X": [attn.name]},
+                         outputs={"Out": [back.name]},
+                         attrs={"axis": [0, 2, 1, 3]})
+        merged = helper.create_tmp_variable(queries.dtype,
+                                            shape=queries.shape)
+        helper.append_op("reshape", inputs={"X": [back.name]},
+                         outputs={"Out": [merged.name]},
+                         attrs={"shape": [0, 0, D]})
     out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
              bias_attr=False)
     from .sequence import propagate_length

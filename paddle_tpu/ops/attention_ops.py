@@ -23,6 +23,14 @@ _MET_GQA_LAYERS = _MET.counter(
     "fewer heads than their queries (forward emission; once a compile, not "
     "once a step), by the two head counts (q_heads, kv_heads) and the head "
     "size (head_dim)")
+_MET_ATTN_LAYERS = _MET.counter(
+    "attention_layers_traced_total",
+    "scaled_dot_product_attention ops traced (forward emission; once a "
+    "compile, not once a step), by the `layout` attr of the desc op (bhtd: "
+    "Q [B,H,T,D]; bthd: Q [B,T,H*D], as the projections leave it) and the "
+    "path the emitter took (flash_packed: the Pallas kernels on [B,T,H*D] "
+    "as it lies; flash: the kernels on [B,H,T,D]; dense: XLA's fused "
+    "softmax; ring, alltoall: sequence parallel)")
 
 
 def _attend(h, enc_proj, enc_out, enc_mask, w_q, v):
@@ -100,7 +108,7 @@ def attention_gru_decoder(ctx, ins, attrs):
             "Context": [jnp.moveaxis(ctxs, 0, 1)]}
 
 
-def flash_single_chip(ctx, q, k, v, causal: bool):
+def flash_single_chip(ctx, q, k, v, causal: bool, heads=None):
     """The single-chip fast path of an attention emitter: the Pallas flash
     kernel (VMEM-tiled online softmax) on Q [B,H,T,D], K [B,Hkv,T,D] and V
     [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
@@ -109,7 +117,11 @@ def flash_single_chip(ctx, q, k, v, causal: bool):
     carry rotary columns beside it, two lane tiles at most).  Two head
     counts: H query heads on Hkv key/value heads, H / Hkv on each
     (grouped-query attention; Hkv = H is the usual case), which the
-    kernels read where they lie, never repeated.  Sharded mesh execution
+    kernels read where they lie, never repeated.  With `heads`: on Q, K
+    and V [B,T,heads*D] as the projections leave them, heads of 64 or 128
+    lanes, each query head on a key/value head of its own (the kernels
+    address a head as a column block, two of 64 to a block; the output
+    leaves in that layout too).  Sharded mesh execution
     keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
     call).  -> None where it does not apply, else (out, saved).
 
@@ -123,15 +135,23 @@ def flash_single_chip(ctx, q, k, v, causal: bool):
 
     if not pallas_dispatch_ok(ctx):
         return None
-    T, D, Dv = q.shape[2], q.shape[3], v.shape[3]
-    if not (T % 128 == 0 and Dv <= 128 and (D == Dv or D <= 256)
-            and k.shape[2] == T and v.shape[2] == T):
+    if heads is None:
+        T, D, Dv = q.shape[2], q.shape[3], v.shape[3]
+        fits = (T % 128 == 0 and Dv <= 128 and (D == Dv or D <= 256)
+                and k.shape[2] == T and v.shape[2] == T)
+    else:
+        T = q.shape[1]
+        fits = (T % 128 == 0 and k.shape == q.shape and v.shape == q.shape
+                and q.shape[2] in (64 * heads, 128 * heads)
+                and q.shape[2] % 128 == 0)
+    if not fits:
         return None
     from .pallas_kernels import flash_attention as fa
 
+    layout = {} if heads is None else {"heads": heads}
     if ctx.is_test:
-        return fa.flash_attention(q, k, v, causal=causal), None
-    train = fa.make_flash_train(causal=causal)
+        return fa.flash_attention(q, k, v, causal=causal, **layout), None
+    train = fa.make_flash_train(causal=causal, **layout)
     kept = ctx.kept_for_grad()
     saved = None
     if kept is not None:
@@ -147,6 +167,13 @@ def flash_single_chip(ctx, q, k, v, causal: bool):
 def scaled_dot_product_attention(ctx, ins, attrs):
     """Multi-head attention core: Q,K [B,H,T,D], V [B,H,T,Dv] → [B,H,T,Dv]
     (Dv = D everywhere but in latent attention), scores over sqrt(D).
+    With the attr `layout` = "bthd" (default "bhtd", the above): Q
+    [B,T,H*D], K [B,T,Hkv*D], V [B,T,Hkv*Dv] → [B,T,H*Dv], the layout a
+    projection leaves and the next one reads, heads from the attrs
+    `num_heads` and `num_kv_heads`.  On one TPU, where the shapes allow
+    (flash_single_chip), the flash kernels read that layout as it lies;
+    every other path splits the heads here, inside the emitter, and runs
+    as for "bhtd".
 
     Under a ParallelExecutor whose mesh has an 'sp' axis > 1, dispatches by
     the `sp_mode` attr: 'ring' (default — K/V chunks rotate over ICI,
@@ -161,8 +188,39 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     import jax.numpy as jnp
 
     from ..parallel import ring_attention as ra
+    from ..parallel.mesh import axis_size
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    layout = str(attrs.get("layout", "bhtd"))
+    if layout not in ("bhtd", "bthd"):
+        raise ValueError(f"layout {layout!r}: use 'bhtd' or 'bthd'")
+    causal = bool(attrs.get("causal", False))
+    sp_mode = str(attrs.get("sp_mode", "ring"))
+    mesh = getattr(ctx, "mesh", None)
+    sp = mesh is not None and axis_size(mesh, "sp") > 1
+
+    def traced(path):
+        if not ctx.in_grad_replay():
+            _MET_ATTN_LAYERS.inc(layout=layout, path=path)
+
+    if layout == "bthd":
+        heads = int(attrs["num_heads"])
+        kv_heads = int(attrs.get("num_kv_heads", heads))
+        got = None
+        if not sp and kv_heads == heads:
+            with part_scope("attn.attend"):
+                got = flash_single_chip(ctx, q, k, v, causal, heads=heads)
+        if got is not None:
+            out, saved = got
+            if saved is not None:
+                ctx.keep_for_grad(attrs, [out], saved)
+            traced("flash_packed")
+            return {"Out": [out]}
+
+        def split(a, n):  # [B, T, n * d] -> [B, n, T, d]
+            return a.reshape(a.shape[:2] + (n, -1)).transpose(0, 2, 1, 3)
+        q, k, v = split(q, heads), split(k, kv_heads), split(v, kv_heads)
+
     group = q.shape[1] // k.shape[1]
     if q.shape[1] != group * k.shape[1] or v.shape[1] != k.shape[1]:
         raise ValueError(
@@ -175,12 +233,9 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 
     def repeated(a):
         return a if group == 1 else jnp.repeat(a, group, axis=1)
-    causal = bool(attrs.get("causal", False))
-    sp_mode = str(attrs.get("sp_mode", "ring"))
-    from ..parallel.mesh import axis_size
 
-    mesh = getattr(ctx, "mesh", None)
-    if mesh is not None and axis_size(mesh, "sp") > 1:
+    saved = None
+    if sp:
         k, v = repeated(k), repeated(v)
         # on TPU the per-shard attention itself runs the Pallas flash
         # kernel when shapes fit its contract (GSPMD can't partition a
@@ -209,6 +264,7 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         else:
             raise ValueError(
                 f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
+        traced(sp_mode)
     else:
         with part_scope("attn.attend"):
             got = flash_single_chip(ctx, q, k, v, causal)
@@ -217,8 +273,14 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                                    causal=causal)
             else:
                 out, saved = got
-                if saved is not None:
-                    ctx.keep_for_grad(attrs, [out], saved)
+        traced("dense" if got is None else "flash")
+    if layout == "bthd":  # [B, H, T, Dv] -> [B, T, H * Dv]
+        out = out.transpose(0, 2, 1, 3).reshape(
+            out.shape[0], out.shape[2], -1)
+    if saved is not None:
+        # beside the op's OWN output: the grad op must receive that very
+        # value for the pair to be its (the kernels' out is saved[0])
+        ctx.keep_for_grad(attrs, [out], saved)
     return {"Out": [out]}
 
 
@@ -759,11 +821,15 @@ def _sdpa_cost(ins, outs, attrs):
     is the Q/K/V reads plus the output write only."""
     q = ins.get("Q", [None])[0]
     k = ins.get("K", [None])[0]
-    if q is None or k is None or len(q.shape) != 4:
+    bthd = str(attrs.get("layout", "bhtd")) == "bthd"
+    if q is None or k is None or len(q.shape) != (3 if bthd else 4):
         return {}
-    b, h, t, d = q.shape
-    s = k.shape[2]
-    flops = 4 * b * h * t * s * d
+    if bthd:  # [B, T, H * D]: the heads' columns add up to the width
+        (b, t, hd), s = q.shape, k.shape[1]
+    else:
+        b, h, t, d = q.shape
+        hd, s = h * d, k.shape[2]
+    flops = 4 * b * t * s * hd
     if bool(attrs.get("causal", False)):
         flops //= 2  # masked half of the score matrix is never computed
     return {"flops": flops}

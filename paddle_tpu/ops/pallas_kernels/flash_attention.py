@@ -98,6 +98,43 @@ H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   0.6092 | 0.8772 | 3.7613 | 4.7913: `v^T p^T` streams 64 rows a
   weight tile through the MXU and transposes V.
 
+- Two heads of 64 in one 128-lane block of [B, T, H * D], the layout
+  the projections leave (PR 36; `heads=`, `_tile_at`, `_pack`).  XLA
+  tiles a bf16 operand in (8, 128)(2, 1): the [B * H, T, 64] operands
+  of the other entry are PADDED to 128 lanes in HBM (`bf16[128,1024,64]
+  {2,1,0:T(8,128)(2,1)}` in the compiled step), twice their bytes, and
+  the transposes that make them cost GPT-2-medium's step 13.4 ms of
+  160.9 with 5.6 more of copies and `delta` over padded tensors beside
+  the kernels.  On [B, T, H * D] a head is a column block: block g % nb
+  of batch g // nb in every index map, and at D 64 two heads ride one
+  block, each a walk of its own in straight-line code through the SAME
+  K/V tile.  Device ms a call from a trace, forward / dq / dkv, B 8
+  H 16 T 1024 D 64 | B 1 H 16 T 4096 D 128, each variant in the same
+  call as the parent's [B, H, T, D] kernels:
+    parent, [B, H, T, D]   0.3905 0.3815 0.4856 | 0.7985 0.7500 0.8685
+    head a's scores from `where(lane in a, q2, 0)` against all 128
+    lanes of k2, `p_a @ v2` full width with the head's half kept by a
+    select (dq: dO masked like q, `ds_a @ k2` kept by a select; dkv: q
+    and dO masked, so `p_a^T @ dO_a` and `ds_a^T @ q_a` are zero beside
+    the head's lanes and dk, dv just add up): KEPT
+                           0.3590 0.3536 0.4376 | 0.8208 0.7307 0.9206
+    the same, dkv's two heads summed before ONE add into the scratch
+                           0.3590 0.3536 0.4365
+    static lane slices `[:, :64]` / `[:, 64:]` of q, k, v, dO, today's
+    64-wide products, halves joined by a concatenate
+                           0.3945 0.3762 0.4503
+  A 64-deep contraction and a 64-wide result each cost the MXU a whole
+  pass, so the masked products are no pass more; the selects ride the
+  VPU's slack; half the grid steps and lane-dense DMAs are the gain
+  (8% / 7% / 10%).  A slice of the upper half is a lane shift a strip
+  and loses to the select.  One head a block at D 128 is the other
+  entry's body on strided blocks: dq gains, dkv's q blocks (256-byte
+  rows 4 KB apart) lose; no cell runs it.  `pltpu.roll` was not tried:
+  the slices it would feed already lost.  The other entry traces to
+  the parent's jaxprs byte for byte (tests/test_pallas_kernels.py) and
+  read the parent's times: 3.459 4.349 4.748 at T 8192, 192 / 128,
+  4.818 5.366 6.777 at T 8192, D 64, 32 on 8.
+
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
 array's — a (1, bq) block over a (BH, T) array satisfies neither (first
@@ -206,7 +243,81 @@ def _kv_head(group: int):
     return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
-def _kv_idx(bq: int, bk: int, causal: bool, group: int):
+def _tile_at(nb: int):
+    """The two addressings of one body: the block index of grid head-step
+    `g`'s rows `r`.  On [B * H, T, D] operands (`nb` 0) the step is a head
+    and its tile the head's own array.  On [B, T, H * D] operands, the
+    layout the projections leave, the step is lane block g % nb of batch
+    g // nb, `nb` blocks of 128 lanes across: one head of 128, or two of
+    64 side by side (`_pack`)."""
+    if not nb:
+        return lambda g, r: (g, r, 0)
+    return lambda g, r: (g // nb, r, g % nb)
+
+
+def _pack(nb: int, D: int) -> int:
+    """Heads in one block of the addressing `nb` (_tile_at) at head size
+    D: as many as fill the 128 lanes of a [B, T, H * D] block, else one."""
+    return 128 // D if nb else 1
+
+
+def _first_lane(a: int, pack: int):
+    """Where head `a` of a block of `pack` heads begins, as the traced
+    scalar the heads' shared walk (_shared) takes it; None where the block
+    is one head's."""
+    import jax.numpy as jnp
+
+    return None if pack == 1 else jnp.int32(a * (128 // pack))
+
+
+def _head_lanes(lo, *tiles):
+    """Of [rows, 128] tiles that hold two heads side by side, the 64 lanes
+    from `lo` with the other head's set to zero: a product that contracts
+    over all 128 lanes then sums this head's alone, exact zeros beside
+    them (a 64-deep contraction costs the MXU a whole pass as well).  The
+    tiles themselves where they hold one head (`lo` None)."""
+    import jax
+    import jax.numpy as jnp
+
+    if lo is None:
+        return tiles
+    lane = jax.lax.broadcasted_iota(jnp.int32, tiles[0].shape, 1)
+    keep = (lane >= lo) & (lane < lo + 64)
+    return tuple(jnp.where(keep, x, 0) for x in tiles)
+
+
+@functools.lru_cache(maxsize=None)
+def _shared(fn, *static):
+    """`fn`, a strip's work for ONE head, traced once for all the calls
+    of one shape and inlined at each: the two heads of a block walk the
+    same strips, and every strip's logsumexp row is one shape.  Tracing a
+    kernel body is host time in every process's set-up, and the chip
+    machine's host is slow at it: with the two-head bodies traced twice
+    over the attention op's emitters took 4.11 + 2.10 s in
+    gpt2m_train_bs8's warm set-up against the parent's 2.60 + 1.06, so
+    2.56 + 2.38 (PERF.md, PR 36).  What is traced is what straight-line
+    code would be: the jaxpr of a one-head call is the parent's byte for
+    byte (tests/test_pallas_kernels.py)."""
+    import jax
+
+    return jax.jit(fn, static_argnames=static, inline=True)
+
+
+def _join_heads(parts):
+    """One [rows, 128] tile from one [rows, 128] (or [rows, 1]) value a
+    head of the block: head a's lanes from parts[a]."""
+    import jax
+    import jax.numpy as jnp
+
+    if len(parts) == 1:
+        return parts[0]
+    first, second = parts  # 64 lanes each
+    shape = (max(first.shape[0], second.shape[0]), 128)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jnp.where(lane < 64, first, second)
+
+
+def _kv_idx(bq: int, bk: int, causal: bool, group: int, nb: int = 0):
     """K/V index map of the forward and _dq_kernel (one map, so the
     diagonal arithmetic cannot drift between them): query head b reads
     its group's K/V head, and under causal masking fully-future fetches
@@ -215,13 +326,13 @@ def _kv_idx(bq: int, bk: int, causal: bool, group: int):
     traffic."""
     import jax.numpy as jnp
 
-    head = _kv_head(group)
+    head, at = _kv_head(group), _tile_at(nb)
     if causal:
         def idx(b, i, j):
-            return (head(b), jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+            return at(head(b), jnp.minimum(j, ((i + 1) * bq - 1) // bk))
     else:
         def idx(b, i, j):
-            return (head(b), j, 0)
+            return at(head(b), j)
 
     return idx
 
@@ -340,7 +451,7 @@ def _run_block(d, bq: int, bk: int, plan, strip):
     crosses the strips of its walk, d static."""
     from jax.experimental import pallas as pl
 
-    single = functools.partial(strip, 0, bq, slice(None))
+    single = functools.partial(strip, 0, bq, pl.ds(0, bk))
     if plan is None:
         return single()
 
@@ -374,20 +485,65 @@ def _column_as_row(col):
     return jnp.concatenate(parts, axis=1)
 
 
+def _fwd_tile(q, k, v, lo, carry, *, c: float, ahead):
+    """(max, normalizer, p v, correction) of one head's q rows (the lanes
+    from `lo`, _head_lanes) over the K/V rows k, v of a block; the max and
+    normalizer folded into `carry` (the two from the K blocks before), the
+    correction what those blocks' accumulator takes: all None where there
+    are none.  Masked where `ahead` says how far q's first row lies after
+    k's first."""
+    import jax
+    import jax.numpy as jnp
+
+    # bf16 GEMM, f32 accumulate (full-rate MXU)
+    (q,) = _head_lanes(lo, q)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if ahead is not None:
+        s = _below_diagonal(s, ahead)
+    m = s.max(axis=-1, keepdims=True)
+    if carry is not None:
+        m_prev, l_prev = carry
+        m = jnp.maximum(m_prev, m)
+    p = jnp.exp2((s - m) * c)
+    l = p.sum(axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if carry is None:
+        return m, l, pv, None
+    corr = jnp.exp2((m_prev - m) * c)
+    return m, l_prev * corr + l, pv, corr
+
+
+def _lse_row(m, l, *, scale: float):
+    """The logsumexp of the SCALED scores from a head's raw max and
+    normalizer columns, as it is stored: a lane row, or the column where
+    the rows are off the lane grid (interpret mode's tiny blocks, a short
+    ring chunk)."""
+    import jax.numpy as jnp
+
+    lse = m * scale + jnp.log(l)
+    return lse if lse.shape[0] % 128 else _column_as_row(lse)
+
+
 def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-              scale: float, bq: int, bk: int, plan):
-    """`plan` is None for a non-causal call, else the call's _Plan.
-    `scratch` is the running max, normalizer and accumulator carried
-    across K blocks, or nothing where the K block holds the whole
-    sequence: a strip of q rows then meets all its columns in one visit,
-    its softmax is final as it is computed and leaves at once.
+              scale: float, bq: int, bk: int, plan, pack: int = 1):
+    """`plan` is None for a non-causal call, else the call's _Plan; `pack`
+    the heads side by side in the blocks' lanes (_pack), each a walk of
+    its own through the same K/V tile in straight-line code.
+    `scratch` is the running max and normalizer of each head and the
+    block's accumulator carried across K blocks, or nothing where the K
+    block holds the whole sequence: a strip of q rows then meets all its
+    columns in one visit, its softmax is final as it is computed and
+    leaves at once.
 
     The running max is kept on the RAW scores (a maximum commutes with a
     positive factor) and the scale meets the tile once, inside the
     exponent, with log2(e): `exp2((s - m) * c)`.  The logsumexp that
     leaves is that of the SCALED scores, `m * scale + log(l)`: the
     backward and ring attention's merge take it as such."""
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -397,61 +553,53 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     q0, k0 = qi * bq, kj * bk
     c = scale * _LOG2E
     whole = not scratch
+    heads = range(pack)
 
-    def tile(q, cols, carry, ahead=None):
-        """(max, normalizer, accumulator) of q's rows over the K/V rows
-        `cols` of this block, folded into `carry` (the three from the K
-        blocks before; None where there are none); masked where `ahead`
-        says how far q's first row lies after the first of `cols`."""
-        k = k_ref[0, cols, :]
-        v = v_ref[0, cols, :]
-        # bf16 GEMM, f32 accumulate (full-rate MXU)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if ahead is not None:
-            s = _below_diagonal(s, ahead)
-        m = s.max(axis=-1, keepdims=True)
-        if carry is not None:
-            m_prev, l_prev, acc = carry
-            m = jnp.maximum(m_prev, m)
-        p = jnp.exp2((s - m) * c)
-        l = p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if carry is None:
-            return m, l, pv
-        corr = jnp.exp2((m_prev - m) * c)
-        return m, l_prev * corr + l, acc * corr + pv
+    tile = _shared(_fwd_tile, "c", "ahead")
+    lse_row = _shared(_lse_row, "scale")
 
-    def leave(at, row, m, l, acc):
-        """Write out the block's rows `at`, rows `row` of the sequence."""
-        o_ref[0, at, :] = (acc / l).astype(o_ref.dtype)
+    def leave(at, row, ms, ls, acc):
+        """Write out the block's rows `at`, rows `row` of the sequence:
+        each head's max and normalizer, the block's accumulator."""
+        o_ref[0, at, :] = (acc / _join_heads(ls)).astype(o_ref.dtype)
         if lse_ref is None:
             return
-        lse = m * scale + jnp.log(l)
-        if lse.shape[0] % 128:  # off the lane grid (interpret mode's tiny
-            lse_ref[0, 0, row] = lse[:, 0]  # blocks, a short ring chunk)
-        else:
-            lse_ref[0, :, row] = _column_as_row(lse)
+        for a in heads:
+            lse = lse_row(ms[a], ls[a], scale=scale)
+            if lse.shape[0] == 1:
+                lse_ref[a, :, row] = lse
+            else:  # off the lane grid: the column, squeezed
+                lse_ref[a, 0, row] = lse[:, 0]
 
     def update(r0, rows, cols, ahead=None):
         at = pl.ds(r0, rows)
         q = q_ref[0, at, :]  # in its input dtype: bf16 keeps the MXU's rate
         if whole:
-            leave(at, pl.ds(q0 + r0, rows), *tile(q, cols, None, ahead))
+            carry, row = [None] * pack, pl.ds(q0 + r0, rows)
+        else:
+            carry = [(m_sc[a][at, :], l_sc[a][at, :]) for a in heads]
+            acc = acc_sc[at, :]
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        ms, ls, pvs, corrs = zip(*(
+            tile(q, k, v, _first_lane(a, pack), carry[a], c=c, ahead=ahead)
+            for a in heads))
+        if whole:
+            leave(at, row, ms, ls, _join_heads(pvs))
             return
-        m_sc[at, :], l_sc[at, :], acc_sc[at, :] = tile(
-            q, cols, (m_sc[at, :], l_sc[at, :], acc_sc[at, :]), ahead)
+        acc = acc * _join_heads(corrs) + _join_heads(pvs)
+        for a in heads:
+            m_sc[a][at, :], l_sc[a][at, :] = ms[a], ls[a]
+        acc_sc[at, :] = acc
 
     if not whole:
-        m_sc, l_sc, acc_sc = scratch
+        m_sc, l_sc, acc_sc = scratch[:pack], scratch[pack:-1], scratch[-1]
 
         @pl.when(kj == 0)
         def _init():
-            m_sc[...] = jnp.full(m_sc.shape, -1e30, dtype=jnp.float32)
-            l_sc[...] = jnp.zeros(l_sc.shape, dtype=jnp.float32)
+            for a in heads:
+                m_sc[a][...] = jnp.full(m_sc[a].shape, -1e30, jnp.float32)
+                l_sc[a][...] = jnp.zeros(l_sc[a].shape, jnp.float32)
             acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
     _run_block(q0 - k0, bq, bk, plan, update)
@@ -459,8 +607,8 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     if not whole:
         @pl.when(kj == nk - 1)
         def _finish():
-            leave(slice(None), pl.ds(q0, bq), m_sc[...], l_sc[...],
-                  acc_sc[...])
+            leave(slice(None), pl.ds(q0, bq), [m[...] for m in m_sc],
+                  [l[...] for l in l_sc], acc_sc[...])
 
 
 def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
@@ -469,10 +617,11 @@ def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
-              Dv, group=1):
+              Dv, group=1, nb=0):
     """The forward kernel's call on q [BH, T, D], k [BH / group, T, D] and
     v [BH / group, T, Dv] operands (the output is v's width and q's
-    heads), for both forward entry points.
+    heads), or, `nb` lane blocks across (_tile_at), on [B, T, H * D]
+    operands; for both forward entry points.
     Memoized and jitted: every layer of a model makes the same call, and
     one callable lets jit trace the kernel body and lower it to Mosaic
     once a step program instead of once a layer."""
@@ -481,31 +630,38 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    kv_idx = _kv_idx(bq, bk, plan is not None, group)
+    pack, at = _pack(nb, D), _tile_at(nb)
+    W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
+    kv_idx = _kv_idx(bq, bk, plan is not None, group, nb)
+    q_idx = lambda g, i, j: at(g, i)
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), kv_idx),
-        pl.BlockSpec((1, bk, Dv), kv_idx),
+        pl.BlockSpec((1, bq, W), q_idx),
+        pl.BlockSpec((1, bk, W), kv_idx),
+        pl.BlockSpec((1, bk, Wv), kv_idx),
     ]
-    out_specs = [pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, T, Dv), dtype)]
+    out_specs = [pl.BlockSpec((1, bq, Wv), q_idx)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (BH // (pack * nb), T, nb * Wv) if nb else (BH, T, Dv), dtype)]
     kern = _fwd_body if with_lse else _fwd_nolse
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0)))
+        out_specs.append(
+            pl.BlockSpec((pack, 1, T), lambda g, i, j: (g, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((BH, 1, T), jnp.float32))
+    column = pltpu.VMEM((bq, 1), jnp.float32)
     return jax.jit(pl.pallas_call(
-        functools.partial(kern, scale=scale, bq=bq, bk=bk, plan=plan),
-        grid=(BH, T // bq, T // bk),
+        functools.partial(kern, scale=scale, bq=bq, bk=bk, plan=plan,
+                          pack=pack),
+        grid=(BH // pack, T // bq, T // bk),
         in_specs=in_specs,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
         # nothing is carried where one K block holds the sequence
         scratch_shapes=[] if bk == T else [
-            # the running max and normalizer as columns: they meet the
-            # score rows as [rows, 1] with no relayout
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
+            # each head's running max, then each head's normalizer, as
+            # columns: they meet the score rows as [rows, 1] with no
+            # relayout
+            *[column] * (2 * pack),
+            pltpu.VMEM((bq, Wv), jnp.float32),
         ],
         # with_lse revisits the SHARED (b,0,0) lse row block across the i
         # dimension — on a Megacore part a "parallel" i could split that
@@ -520,22 +676,58 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
     ))
 
 
-def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse):
+class _Call(NamedTuple):
+    """What an entry point's operands say of the call they ask for."""
+
+    BH: int      # batch x query heads
+    T: int
+    D: int       # head size of q and k
+    Dv: int      # and of v
+    group: int   # query heads on each key/value head
+    nb: int      # lane blocks across [B, T, H * D] operands; 0: [B, H, T, D]
+
+
+def _call_of(q, k, v, heads) -> _Call:
+    """`heads` None: q [B, H, T, D], k [B, Hkv, T, D], v [B, Hkv, T, Dv].
+    Else the projections' layout, q, k and v all [B, T, heads * D], which
+    the kernels address by lane blocks of 128: heads of 128, or of 64 two
+    to a block, each query head on a key/value head of its own."""
+    if heads is None:
+        B, H, T, D = q.shape
+        return _Call(B * H, T, D, v.shape[-1], _group(q, k, v), 0)
+    B, T, W = q.shape
+    D = W // heads
+    if (k.shape != q.shape or v.shape != q.shape or D * heads != W
+            or D not in (64, 128) or W % 128):
+        raise ValueError(
+            f"flash attention on [B, T, H * D] operands takes q, k and v "
+            f"of one shape and heads of 64 (an even number) or 128; got "
+            f"{q.shape}, {k.shape}, {v.shape} at {heads} heads")
+    return _Call(B * heads, T, D, D, 1, W // 128)
+
+
+def _heads_first(a, call: _Call):
+    """An operand as its kernel call takes it: [B, H, T, D] flattened to
+    [B * H, T, D]; [B, T, H * D] as it is."""
+    return a if call.nb else a.reshape(-1, call.T, a.shape[-1])
+
+
+def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse,
+             heads=None):
     """flash_attention's and flash_attention_fwd's shared way to _fwd_call:
-    the output(s) on [B*H, T, Dv]."""
-    B, H, T, D = q.shape
-    Dv = v.shape[-1]
-    group = _group(q, k, v)
-    bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
-    s = scale if scale is not None else 1.0 / (D ** 0.5)
+    the output(s) on [B*H, T, Dv], or on [B, T, H * D] as q (`heads`)."""
+    c = _call_of(q, k, v, heads)
+    bq, bk = _snap_blocks(block_q, block_k, c.T, interpret,
+                          c.D if causal else 0)
+    s = scale if scale is not None else 1.0 / (c.D ** 0.5)
     if not s > 0:
         raise ValueError(
             f"flash attention: scale {s!r}; the forward keeps its running "
             f"max on raw scores, which takes a positive scale")
-    plan = _causal_plan("flash_fwd", B * H, T, bq, bk) if causal else None
-    return _fwd_call(B * H, T, D, bq, bk, plan, with_lse, q.dtype,
-                     interpret, s, Dv, group)(
-        *(a.reshape(-1, T, a.shape[-1]) for a in (q, k, v)))
+    plan = _causal_plan("flash_fwd", c.BH, c.T, bq, bk) if causal else None
+    return _fwd_call(c.BH, c.T, c.D, bq, bk, plan, with_lse, q.dtype,
+                     interpret, s, c.Dv, c.group, c.nb)(
+        *(_heads_first(a, c) for a in (q, k, v)))
 
 
 def _group(q, k, v) -> int:
@@ -552,17 +744,19 @@ def _group(q, k, v) -> int:
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = 512, block_k: int = 1024,
-                    interpret: bool = False):
+                    interpret: bool = False, heads=None):
     """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv] → [B,H,T,Dv] (Dv = D
     but in latent attention, whose keys carry rotary columns its values
     lack; the default scale is 1/sqrt(D), the width the scores contract
     over).  Hkv = H, or a divisor of it (grouped-query attention): query
     head h then attends to key/value head h // (H / Hkv), read where it
     lies; K and V are never repeated.
+    With `heads`: q, k, v [B,T,heads*D] → [B,T,heads*D], the layout the
+    projections leave and the next one reads (_call_of has the contract).
     block_q/block_k are performance hints, snapped down to divisors of T;
     D ≤ 128 recommended (one lane tile)."""
     out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   False)
+                   False, heads)
     return out.reshape(q.shape[:3] + v.shape[3:])
 
 
@@ -571,21 +765,34 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 # style recompute — P is never materialized in HBM in either direction).
 
 
-def _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows: int):
-    """(q, dO, logsumexp, delta) of this q block's rows [r0, r0 + rows).
-    lse/delta arrive as (1, 1, T) full-row blocks (Mosaic tile contract,
-    see module docstring) and leave as [rows, 1] columns."""
-    from jax.experimental import pallas as pl
+def _dq_tile(q, do, lse, delta, k, v, lo, *, scale: float, ahead):
+    """dq of one head's rows (q and dO: the lanes from `lo`, _head_lanes;
+    its logsumexp and delta as [rows, 1] columns) gathered over the K/V
+    rows k, v of a block, over all the block's lanes (the head's own hold
+    its dq); masked where `ahead` says how far the first row lies after
+    k's first."""
+    import jax
+    import jax.numpy as jnp
 
-    at = pl.ds(r0, rows)
-    row = pl.ds(q0 + r0, rows)
-    return (q_ref[0, at, :], do_ref[0, at, :],
-            lse_ref[0, 0, row][:, None], delta_ref[0, 0, row][:, None])
+    q, do = _head_lanes(lo, q, do)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if ahead is not None:
+        s = _below_diagonal(s, ahead)
+    p = jnp.exp(s - lse)  # true softmax probs via saved lse
+    dp = jax.lax.dot_general(  # dO is consumed at v.dtype by the dp GEMM
+        do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta) * scale
+    return jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_sc, *, scale: float, bq: int, bk: int, plan):
-    import jax
+               acc_sc, *, scale: float, bq: int, bk: int, plan,
+               pack: int = 1):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -593,37 +800,27 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
     q0, k0 = qi * bq, kj * bk
+    tile = _shared(_dq_tile, "scale", "ahead")
 
     @pl.when(kj == 0)
     def _init():
         acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
-    def tile(rows, cols, acc, ahead=None):
-        """dq of `rows` (from _q_rows) gathered over the K/V rows `cols`
-        of this block, added to acc; masked where `ahead` says how far
-        the first of `rows` lies after the first of `cols`."""
-        q, do, lse, delta = rows  # dO is consumed at v.dtype by the dp GEMM
-        k = k_ref[0, cols, :]
-        v = v_ref[0, cols, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if ahead is not None:
-            s = _below_diagonal(s, ahead)
-        p = jnp.exp(s - lse)  # true softmax probs via saved lse
-        dp = jax.lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return acc + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
     def update(r0, rows, cols, ahead=None):
         at = pl.ds(r0, rows)
-        acc_sc[at, :] = tile(
-            _q_rows(q_ref, do_ref, lse_ref, delta_ref, q0, r0, rows), cols,
-            acc_sc[at, :], ahead)
+        row = pl.ds(q0 + r0, rows)
+        q, do = q_ref[0, at, :], do_ref[0, at, :]
+        # lse/delta arrive as (pack, 1, T) full-row blocks (Mosaic tile
+        # contract, see module docstring) and leave as [rows, 1] columns
+        columns = [(lse_ref[a, 0, row][:, None], delta_ref[a, 0, row][:, None])
+                   for a in range(pack)]
+        acc = acc_sc[at, :]
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        acc_sc[at, :] = acc + _join_heads(
+            [tile(q, do, lse, delta, k, v, _first_lane(a, pack),
+                  scale=scale, ahead=ahead)
+             for a, (lse, delta) in enumerate(columns)])
 
     _run_block(q0 - k0, bq, bk, plan, update)
 
@@ -632,19 +829,55 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = acc_sc[...].astype(dq_ref.dtype)
 
 
+def _dkv_tile(k, v, q, do, lse_ref, dv_sc, delta_ref, dk_sc, lo, cols, row,
+              *, scale: float, ahead):
+    """Add to (dk, dv) of a block's K/V rows `cols` what one head's q rows
+    give them (q and dO: the lanes from `lo`, _head_lanes; the head's
+    logsumexp and delta the lanes `row` of its row of their blocks);
+    masked where `ahead` says how far the first q row lies after the first
+    of `cols`.  The score tile is held TRANSPOSED, [cols, rows]: p^T dO
+    and ds^T q are then plain products of operands in the input dtype,
+    and the logsumexp and delta meet the tile as the [1, rows] lane rows
+    they are stored as (the orientation of splash attention's dK / dV
+    kernel).  Of heads side by side, each one's q and dO are zero outside
+    its lanes, so its products fall into its own lanes of dk and dv and
+    add nothing beside them."""
+    import jax
+    import jax.numpy as jnp
+
+    head = 0 if lo is None else lo // 64
+    q, do = _head_lanes(lo, q, do)
+    st = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if ahead is not None:
+        st = _below_diagonal(st, ahead, q_axis=1)
+    pt = jnp.exp(st - lse_ref[head, :, row])
+    dv_sc[cols, :] += jax.lax.dot_general(
+        pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dpt = jax.lax.dot_general(
+        v, do.astype(v.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dst = pt * (dpt - delta_ref[head, :, row]) * scale
+    dk_sc[cols, :] += jax.lax.dot_general(
+        dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                bq: int, bk: int, plan, group: int):
+                bq: int, bk: int, plan, group: int, pack: int = 1):
     """The last grid axis walks the q blocks of the `group` query heads
     that share this K/V head, a head after the other (_dkv_q_maps): dk and
     dv add up over all of it in the scratch and are written once."""
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     kj = pl.program_id(1)
     step = pl.program_id(2)
     steps = pl.num_programs(2)
+    tile = _shared(_dkv_tile, "scale", "ahead")
     # lse rides whole (1, 1, T) rows: T / bq q blocks a head, static
     qi = step if group == 1 else step % (lse_ref.shape[2] // bq)
     q0, k0 = qi * bq, kj * bk
@@ -655,35 +888,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_sc[...] = jnp.zeros(dv_sc.shape, dtype=jnp.float32)
 
     def update(r0, rows, cols, ahead=None):
-        """Add to (dk, dv) of the K/V rows `cols` of this block what its q
-        rows [r0, r0 + rows) give them; masked where `ahead` says how far
-        q row r0 lies after the first of `cols`.  The score tile is held
-        TRANSPOSED, [cols, rows]: p^T dO and ds^T q are then plain
-        products of operands in the input dtype, and the logsumexp and
-        delta meet the tile as the [1, rows] lane rows they are stored as
-        (the orientation of splash attention's dK / dV kernel)."""
         k = k_ref[0, cols, :]
         v = v_ref[0, cols, :]
         at = pl.ds(r0, rows)
         row = pl.ds(q0 + r0, rows)
         q = q_ref[0, at, :]
         do = do_ref[0, at, :]
-        st = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if ahead is not None:
-            st = _below_diagonal(st, ahead, q_axis=1)
-        pt = jnp.exp(st - lse_ref[0, :, row])
-        dv_sc[cols, :] += jax.lax.dot_general(
-            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(
-            v, do.astype(v.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta_ref[0, :, row]) * scale
-        dk_sc[cols, :] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for a in range(pack):
+            tile(k, v, q, do, lse_ref, dv_sc, delta_ref, dk_sc,
+                 _first_lane(a, pack), cols, row, scale=scale, ahead=ahead)
 
     _run_block(q0 - k0, bq, bk, plan, update)
 
@@ -694,16 +907,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
-                        block_k=1024, interpret=False):
-    """Forward that also returns the per-row logsumexp (backward residual)."""
-    B, H, T, _D = q.shape
+                        block_k=1024, interpret=False, heads=None):
+    """Forward that also returns the per-row logsumexp (backward
+    residual), [B * H, T] in either layout."""
     out, lse = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                        True)
-    return out.reshape(q.shape[:3] + v.shape[3:]), lse.reshape(B * H, T)
+                        True, heads)
+    return out.reshape(q.shape[:3] + v.shape[3:]), lse.reshape(lse.shape[0],
+                                                               -1)
 
 
 @functools.lru_cache(maxsize=None)
-def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int):
+def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int,
+                nb: int = 0):
     """(block map of q and dO, row map of lse and delta) of the dkv
     kernel's grid (K/V head b, K block j, step i).  One query head on a
     K/V head: step i is q block i.  `group` of them: the steps walk head b
@@ -714,6 +929,7 @@ def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int):
     import jax.numpy as jnp
 
     nq = T // bq
+    at = _tile_at(nb)
     if group == 1:
         head = lambda b, i: b
         block = lambda i: i
@@ -722,74 +938,82 @@ def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int):
         block = lambda i: i % nq
     if causal:
         def q_idx(b, j, i):
-            return (head(b, i), jnp.maximum(block(i), (j * bk) // bq), 0)
+            return at(head(b, i), jnp.maximum(block(i), (j * bk) // bq))
     else:
         def q_idx(b, j, i):
-            return (head(b, i), block(i), 0)
+            return at(head(b, i), block(i))
 
     return q_idx, lambda b, j, i: (head(b, i), 0, 0)
 
 
 @functools.lru_cache(maxsize=None)
 def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
-               Dv, group=1):
+               Dv, group=1, nb=0):
     """(dq call, dkv call) on q [BH, T, D], dO [BH, T, Dv], k [BH / group,
-    T, D], v [BH / group, T, Dv] operands and (BH, 1, T) lse and delta
-    rows (dq leaves as q, dk as k, dv as v); memoized and jitted like
+    T, D], v [BH / group, T, Dv] operands, or, `nb` lane blocks across
+    (_tile_at), all five [B, T, H * D]; and (BH, 1, T) lse and delta rows
+    (dq leaves as q, dk as k, dv as v); memoized and jitted like
     _fwd_call."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    row_spec = pl.BlockSpec((1, 1, T), lambda b, i, j: (b, 0, 0))
-    kv_idx = _kv_idx(bq, bk, dq_plan is not None, group)
-    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, dq_plan is not None, group)
-    q_row_spec = pl.BlockSpec((1, 1, T), q_row_idx)
+    pack, at = _pack(nb, D), _tile_at(nb)
+    W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
+    row_spec = pl.BlockSpec((pack, 1, T), lambda b, i, j: (b, 0, 0))
+    kv_idx = _kv_idx(bq, bk, dq_plan is not None, group, nb)
+    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, dq_plan is not None, group,
+                                   nb)
+    q_row_spec = pl.BlockSpec((pack, 1, T), q_row_idx)
     BHkv = BH // group
 
+    def shape(heads, lanes):
+        return jax.ShapeDtypeStruct(
+            (heads // (pack * nb), T, nb * lanes) if nb
+            else (heads, T, lanes), dtype)
+
+    rows = lambda b, i, j: at(b, i)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dq_plan),
-        grid=(BH, T // bq, T // bk),
+                          plan=dq_plan, pack=pack),
+        grid=(BH // pack, T // bq, T // bk),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), kv_idx),
-            pl.BlockSpec((1, bk, Dv), kv_idx),
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, W), rows),
+            pl.BlockSpec((1, bk, W), kv_idx),
+            pl.BlockSpec((1, bk, Wv), kv_idx),
+            pl.BlockSpec((1, bq, Wv), rows),
             row_spec,
             row_spec,
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bq, W), rows),
+        out_shape=shape(BH, W),
+        scratch_shapes=[pltpu.VMEM((bq, W), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_bwd_dq",
         interpret=interpret,
     )
+    cols = lambda b, j, i: at(b, j)
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dkv_plan, group=group),
-        grid=(BHkv, T // bk, group * (T // bq)),
+                          plan=dkv_plan, group=group, pack=pack),
+        grid=(BHkv // pack, T // bk, group * (T // bq)),
         in_specs=[
-            pl.BlockSpec((1, bq, D), q_idx),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, Dv), q_idx),
+            pl.BlockSpec((1, bq, W), q_idx),
+            pl.BlockSpec((1, bk, W), cols),
+            pl.BlockSpec((1, bk, Wv), cols),
+            pl.BlockSpec((1, bq, Wv), q_idx),
             q_row_spec,
             q_row_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, W), cols),
+            pl.BlockSpec((1, bk, Wv), cols),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BHkv, T, D), dtype),
-            jax.ShapeDtypeStruct((BHkv, T, Dv), dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, Dv), jnp.float32)],
+        out_shape=[shape(BHkv, W), shape(BHkv, Wv)],
+        scratch_shapes=[pltpu.VMEM((bk, W), jnp.float32),
+                        pltpu.VMEM((bk, Wv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_bwd_dkv",
@@ -799,27 +1023,32 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
 
 
 def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
-                        block_q=512, block_k=1024, interpret=False):
+                        block_q=512, block_k=1024, interpret=False,
+                        heads=None):
     import jax.numpy as jnp
 
-    B, H, T, D = q.shape
-    Dv = v.shape[-1]
-    group = _group(q, k, v)
-    bq, bk = _snap_blocks(block_q, block_k, T, interpret, D if causal else 0)
-    s = scale if scale is not None else 1.0 / (D ** 0.5)
-    qf, kf, vf, of, dof = (a.reshape(-1, T, a.shape[-1])
-                           for a in (q, k, v, o, do))
-    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
-                    axis=-1)  # [BH, T]
+    c = _call_of(q, k, v, heads)
+    bq, bk = _snap_blocks(block_q, block_k, c.T, interpret,
+                          c.D if causal else 0)
+    s = scale if scale is not None else 1.0 / (c.D ** 0.5)
+    qf, kf, vf, of, dof = (_heads_first(a, c) for a in (q, k, v, o, do))
+    # a head's rows of dO * O summed over its own columns, [B * H, T]
+    delta = of.astype(jnp.float32) * dof.astype(jnp.float32)
+    if c.nb:  # [B, T, H * D] -> [B, H, T]
+        delta = jnp.moveaxis(
+            delta.reshape(delta.shape[:2] + (heads, c.D)).sum(-1), 2, 1)
+    else:
+        delta = delta.sum(-1)
     # (BH, 1, T) full-row layout for lse/delta: see module docstring
-    lse3 = lse.reshape(B * H, 1, T).astype(jnp.float32)
-    delta3 = delta.reshape(B * H, 1, T)
+    lse3 = lse.reshape(c.BH, 1, c.T).astype(jnp.float32)
+    delta3 = delta.reshape(c.BH, 1, c.T)
     dq_plan = dkv_plan = None
     if causal:
-        dq_plan = _causal_plan("flash_bwd_dq", B * H, T, bq, bk)
-        dkv_plan = _causal_plan("flash_bwd_dkv", B * H, T, bq, bk)
-    dq_call, dkv_call = _bwd_calls(B * H, T, D, bq, bk, dq_plan, dkv_plan,
-                                   q.dtype, interpret, s, Dv, group)
+        dq_plan = _causal_plan("flash_bwd_dq", c.BH, c.T, bq, bk)
+        dkv_plan = _causal_plan("flash_bwd_dkv", c.BH, c.T, bq, bk)
+    dq_call, dkv_call = _bwd_calls(c.BH, c.T, c.D, bq, bk, dq_plan,
+                                   dkv_plan, q.dtype, interpret, s, c.Dv,
+                                   c.group, c.nb)
     dq = dq_call(qf, kf, vf, dof, lse3, delta3)
     dk, dv = dkv_call(qf, kf, vf, dof, lse3, delta3)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
@@ -829,12 +1058,13 @@ _TRAIN_CACHE = {}
 
 
 def make_flash_train(causal: bool = False, scale=None, interpret=False,
-                     block_q: int = 512, block_k: int = 1024):
+                     block_q: int = 512, block_k: int = 1024, heads=None):
     """custom_vjp fused attention for TRAINING (honored by generic_grad's
     jax.vjp like the recurrence kernels).  Memoized per
-    (causal, scale, interpret, blocks): emitters call this on every trace,
-    and a fresh wrapper each time would defeat jit's function-identity
-    caching (ADVICE r2).
+    (causal, scale, interpret, blocks, heads): emitters call this on every
+    trace, and a fresh wrapper each time would defeat jit's
+    function-identity caching (ADVICE r2).  `heads`: the operands are
+    [B, T, heads * D] (_call_of).
 
     The returned function carries the pair a forward op and its grad op
     split between them, so the forward kernel runs once a layer and not
@@ -844,14 +1074,14 @@ def make_flash_train(causal: bool = False, scale=None, interpret=False,
     out its logsumexp, and `.from_saved(q, k, v, out, lse) -> out`
     launches nothing forward and differentiates as the flash backward on
     the saved pair.  scaled_dot_product_attention uses both."""
-    key = (causal, scale, interpret, block_q, block_k)
+    key = (causal, scale, interpret, block_q, block_k, heads)
     cached = _TRAIN_CACHE.get(key)
     if cached is not None:
         return cached
     import jax
 
     kw = dict(causal=causal, scale=scale, interpret=interpret,
-              block_q=block_q, block_k=block_k)
+              block_q=block_q, block_k=block_k, heads=heads)
 
     @jax.custom_vjp
     def attn(q, k, v):

@@ -20,7 +20,11 @@ from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
 COUNTER = "executor_grad_kernel_forward_total"
 SDPA = "scaled_dot_product_attention"
-T, DIM, HEADS = 128, 32, 2
+T, HEADS = 128, 2
+# the model width by the path the emitter takes there: heads of 16 go the
+# [B, H, T, D] way, heads of 64 ride two to a lane block of [B, T, H * D]
+WIDTHS = {"flash": 32, "flash_packed": 128}
+LAYERS = "attention_layers_traced_total"
 
 
 def _counter() -> dict:
@@ -30,11 +34,24 @@ def _counter() -> dict:
             for s in (fam["series"] if fam else [])}
 
 
-@pytest.fixture
-def pallas_on_cpu(monkeypatch):
+def _paths() -> dict:
+    """{(layout, path): layers} of attention_layers_traced_total."""
+    fam = obs.REGISTRY.snapshot()["families"].get(LAYERS)
+    return {(s["labels"]["layout"], s["labels"]["path"]): s["value"]
+            for s in (fam["series"] if fam else [])}
+
+
+@pytest.fixture(params=sorted(WIDTHS))
+def pallas_on_cpu(monkeypatch, request):
     """Every trace claims a TPU target and the flash kernels interpret;
-    returns the list of forward-kernel launches traced."""
-    launches = []
+    returns the list of forward-kernel launches traced, `.path` the way
+    through the emitter the parameter's width takes."""
+
+    class Launches(list):
+        path = request.param
+
+    launches = Launches()
+    monkeypatch.setitem(globals(), "DIM", WIDTHS[request.param])
     real_train, real_fwd = fa.make_flash_train, fa.flash_attention_fwd
 
     def spy_fwd(q, k, v, **kw):
@@ -46,8 +63,9 @@ def pallas_on_cpu(monkeypatch):
     monkeypatch.setattr(fa, "flash_attention_fwd", spy_fwd)
     monkeypatch.setattr(
         fa, "make_flash_train",
-        lambda causal=False, scale=None, interpret=False:
-        real_train(causal=causal, interpret=True, block_q=64, block_k=64))
+        lambda causal=False, scale=None, interpret=False, heads=None:
+        real_train(causal=causal, interpret=True, block_q=64, block_k=64,
+                   heads=heads))
     # the memo holds closures over the real forward: start from none, and
     # leave none behind that holds the spy
     monkeypatch.setattr(fa, "_TRAIN_CACHE", {})
@@ -89,6 +107,12 @@ def test_saved_pair_gives_the_fallbacks_bits(pallas_on_cpu, monkeypatch):
     with_pair = _step()
     assert len(pallas_on_cpu) == 1, pallas_on_cpu
     assert _counter() == {(SDPA, "1"): 1.0}
+    # the layer's layout, the emitter's path, and the forward emission
+    # alone counted (the grad op's re-emission adds nothing)
+    assert _paths() == {("bthd", pallas_on_cpu.path): 1.0}
+    packed = pallas_on_cpu.path == "flash_packed"
+    assert pallas_on_cpu[0] == ((2, T, DIM) if packed
+                                else (2, HEADS, T, DIM // HEADS))
 
     del pallas_on_cpu[:]
     # the table emptied before the grad op: nothing is ever kept
@@ -265,8 +289,8 @@ def v5e():
     return topo.devices[0]
 
 
-def _compiled_step(loss, device, batch, seq_len):
-    """The executor's step compiled for `device`, from shapes alone (as
+def _lowered_step(loss, device, batch, seq_len):
+    """The executor's step lowered for `device`, from shapes alone (as
     tests/benchmarks/test_benchmark.py `_aot` compiles it)."""
     import jax
     from jax.sharding import SingleDeviceSharding
@@ -300,7 +324,7 @@ def _compiled_step(loss, device, batch, seq_len):
             {n: of_var(n) for n in compiled.rw_state},
             {n: of_var(n) for n in compiled.external_reads},
             {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
-            sds((2,), np.uint32)).compile()
+            sds((2,), np.uint32))
 
 
 def test_aot_one_forward_kernel_a_layer(v5e):
@@ -312,7 +336,15 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     layers = 2
     loss = build_lm_train_program(1024, vocab_size=512, dim=128,
                                   n_layers=layers, n_heads=2)
-    text = _compiled_step(loss, v5e, batch=2, seq_len=1024).as_text()
+    lowered = _lowered_step(loss, v5e, batch=2, seq_len=1024)
+    # the kernels read Q, K, V where the projections left them: the step
+    # as JAX hands it to XLA moves no [B, T, H, D] tensor to [B, H, T, D]
+    # or back (four a layer forward, four backward before the layout)
+    relayouts = re.findall(
+        r"stablehlo\.transpose[^\n]*tensor<2x(?:1024x2|2x1024)x64xbf16>",
+        lowered.as_text())
+    assert not relayouts, relayouts
+    text = lowered.compile().as_text()
     calls = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     kinds = {}
@@ -326,6 +358,7 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     assert kinds == {"flash_fwd": layers, "flash_bwd_dq": layers,
                      "flash_bwd_dkv": layers}, calls
     assert _counter() == {(SDPA, "1"): float(layers)}
+    assert _paths() == {("bthd", "flash_packed"): float(layers)}
 
 
 # B, H, T, D, Dv (and, where K and V have fewer heads, Hkv) of the cells'
@@ -414,7 +447,7 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     def compiled():
         fluid.reset()
         loss = build_moe_lm_train_program(**args)
-        return _compiled_step(loss, v5e, batch=1, seq_len=4096)
+        return _lowered_step(loss, v5e, batch=1, seq_len=4096).compile()
 
     def instructions(text, pattern):
         return re.findall(r"^\s*(?:ROOT )?%(" + pattern + r")[.\d]* = ",
@@ -434,6 +467,9 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     assert {s["labels"]["impl"]: s["value"] for s in fam[
         "moe_grouped_backward_total"]["series"]} == {"pallas": 3.0 * layers}
     assert _counter() == {(SDPA, "1"): float(layers)}  # flash's, no moe
+    # RoPE stands between projection and attention: the old desc, the
+    # kernels' [B, H, T, D] entry
+    assert _paths() == {("bhtd", "flash"): float(layers)}
 
     # the parent's step: the gate closed for these kernels alone
     monkeypatch.setattr(gm, "usable", lambda *shape: False)
