@@ -314,7 +314,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          param_attr=None, name=None, sp_mode="ring",
                          sp_schedule="plain", qk_norm_epsilon=None,
                          rope_theta=None, out_param_attr=None,
-                         num_kv_heads=None, qk_norm_per_head=False):
+                         num_kv_heads=None, qk_norm_per_head=False,
+                         head_dim=None, block_diffusion=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -332,8 +333,14 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     added to the input.  `num_kv_heads` (default `num_heads`, and a divisor
     of it) is how many heads the K and V projections have: query head h
     attends to key/value head h // (num_heads / num_kv_heads)
-    (grouped-query attention).  `param_attr` is the Q, K and V
-    projections', `out_param_attr` the output projection's.
+    (grouped-query attention).  `head_dim` is a head's width where it is
+    not D / num_heads: Q is then D -> num_heads * head_dim and the output
+    projection num_heads * head_dim -> D.  `block_diffusion` = (seq_len L,
+    block_length): the T = 2L rows are the noised and the clean copy of L
+    tokens; they attend under the block-diffusion mask (`mask` attrs of
+    scaled_dot_product_attention) and row r is rotated as position r mod L.
+    `param_attr` is the Q, K and V projections', `out_param_attr` the
+    output projection's.
 
     Where nothing per head stands between the projections and attention
     (no `rope_theta`, no `qk_norm_per_head`) the attention op takes Q, K
@@ -348,8 +355,13 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
             f"sp_schedule {sp_schedule!r}: use 'plain' or 'zigzag' "
             "(zigzag = load-balanced causal flash ring, fwd and bwd)")
     D = queries.shape[-1]
-    assert D % num_heads == 0, "hidden size must divide num_heads"
-    head_dim = D // num_heads
+    if head_dim is None:
+        if D % num_heads:
+            raise ValueError(
+                f"multi_head_attention: num_heads {num_heads} does not "
+                f"divide the hidden size {D}; give head_dim")
+        head_dim = D // num_heads
+    head_dim = int(head_dim)
     kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
     if not 0 < kv_heads <= num_heads or num_heads % kv_heads:
         raise ValueError(f"multi_head_attention: num_kv_heads {kv_heads} "
@@ -357,8 +369,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     if qk_norm_per_head and qk_norm_epsilon is None:
         raise ValueError("multi_head_attention: qk_norm_per_head is a form "
                          "of the QK-norm: give qk_norm_epsilon")
-    q = fc(queries, D, num_flatten_dims=2, param_attr=param_attr,
-           bias_attr=False)
+    q = fc(queries, num_heads * head_dim, num_flatten_dims=2,
+           param_attr=param_attr, bias_attr=False)
     k = fc(keys, kv_heads * head_dim, num_flatten_dims=2,
            param_attr=param_attr, bias_attr=False)
     v = fc(values, kv_heads * head_dim, num_flatten_dims=2,
@@ -385,17 +397,23 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         helper.append_op("rope", inputs={"X": [x.name]},
                          outputs={"Out": [r.name]},
                          attrs={"theta": float(rope_theta),
-                                "part": "attn.rope"})
+                                "part": "attn.rope",
+                                **({"period": int(block_diffusion[0])}
+                                   if block_diffusion else {})})
         return r
 
+    wide = tuple(queries.shape[:-1]) + (num_heads * head_dim,)
     sdpa_attrs = {"causal": causal, "sp_mode": sp_mode,
                   "sp_schedule": sp_schedule}
+    if block_diffusion:
+        sdpa_attrs.update({"mask": "block_diffusion",
+                           "seq_len": int(block_diffusion[0]),
+                           "block_length": int(block_diffusion[1])})
     if rope_theta is None and not qk_norm_per_head:
         # nothing per head stands between the projections and attention:
         # the op takes Q, K, V as they lie and leaves its output as the
         # output projection reads it; no reshape, no transpose
-        merged = helper.create_tmp_variable(queries.dtype,
-                                            shape=queries.shape)
+        merged = helper.create_tmp_variable(queries.dtype, shape=wide)
         helper.append_op(
             "scaled_dot_product_attention",
             inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
@@ -419,16 +437,39 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         helper.append_op("transpose", inputs={"X": [attn.name]},
                          outputs={"Out": [back.name]},
                          attrs={"axis": [0, 2, 1, 3]})
-        merged = helper.create_tmp_variable(queries.dtype,
-                                            shape=queries.shape)
+        merged = helper.create_tmp_variable(queries.dtype, shape=wide)
         helper.append_op("reshape", inputs={"X": [back.name]},
                          outputs={"Out": [merged.name]},
-                         attrs={"shape": [0, 0, D]})
+                         attrs={"shape": [0, 0, num_heads * head_dim]})
     out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
              bias_attr=False)
     from .sequence import propagate_length
 
     return propagate_length(queries, out)
+
+
+def block_diffusion_noise(tokens, token_noise, block_noise, block_length,
+                          mask_id, t_min=0.0, name=None):
+    """The input of a block-diffusion training step (ops/llm_ops.py
+    `block_diffusion_noise` has the equations): `tokens` [B, L, 1] clean,
+    `token_noise` [B, L, 1] and `block_noise` [B, L / block_length, 1]
+    uniform draws that are FED -> ([noisy ; clean] [B, 2L, 1], the mask m
+    [B, L, 1] float32, the loss weights m / t [B, L, 1] float32)."""
+    helper = LayerHelper("block_diffusion_noise", name=name)
+    B, L = tokens.shape[0], tokens.shape[1]
+    out = helper.create_tmp_variable(tokens.dtype, shape=(B, 2 * L, 1),
+                                     stop_gradient=True)
+    mask, weight = (helper.create_tmp_variable(
+        "float32", shape=(B, L, 1), stop_gradient=True) for _ in range(2))
+    helper.append_op(
+        "block_diffusion_noise",
+        inputs={"Tokens": [tokens.name], "TokenNoise": [token_noise.name],
+                "BlockNoise": [block_noise.name]},
+        outputs={"Out": [out.name], "Mask": [mask.name],
+                 "Weight": [weight.name]},
+        attrs={"block_length": int(block_length), "mask_id": int(mask_id),
+               "t_min": float(t_min)})
+    return out, mask, weight
 
 
 def gated_short_conv(input, kernel_size=3, param_attr=None, name=None):

@@ -52,7 +52,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                ffn="mlp", moe=None, router_outputs=None, init_scale=None,
                emb_init_scale=None, attention="multi_head", mla=None,
                dense_layers=0, dense_dim=None, layer_types=None, conv=None,
-               n_kv_heads=None):
+               n_kv_heads=None, head_dim=None, block_diffusion=None,
+               emb_init_seed=0):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -85,10 +86,21 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     'attention' (the kind `attention` names; everywhere by default) or
     'conv': a gated short convolution, `conv` = {"kernel_size"}
     (`layers.gated_short_conv`).
+    `head_dim` is an attention head's width where it is not dim /
+    n_heads.  `block_diffusion` = {"block_length", "mask_id", "t_min",
+    "token_noise", "block_noise"} makes the step a block-diffusion
+    training step (BD3-LM's objective): the T tokens are noised block by
+    block from the two fed draws (`layers.block_diffusion_noise`), the
+    tower runs the 2T rows [noisy ; clean] under the block-diffusion
+    attention mask with row r at position r mod T, and the head reads the
+    noisy half alone: logits [B, T, vocab_size] of the token AT each
+    position.  The dict gains "mask" and "weight" [B, T, 1], what
+    `block_diffusion_loss` weighs the tokens by.
     `init_scale` draws every matrix (embedding,
     projections, experts, head) from normal(0, init_scale) instead of each
     layer's default; `emb_init_scale` gives the token embedding a scale of
-    its own."""
+    its own, and `emb_init_seed` a seed of its own (the same table whatever
+    the program's `random_seed`; 0: the program's)."""
     if norm not in ("layer_norm", "rms_norm"):
         raise ValueError(f"norm {norm!r}: use 'layer_norm' or 'rms_norm'")
     if positions not in ("learned", "rope"):
@@ -110,6 +122,22 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     init = (NormalInitializer(scale=init_scale) if init_scale is not None
             else None)
     attr = {"initializer": init} if init is not None else None
+    clean_len = tokens.shape[1]
+    bd = None
+    if block_diffusion is not None:
+        if attention != "multi_head" or positions != "rope" or set(
+                layer_types) != {"attention"}:
+            raise ValueError(
+                "decoder_lm: block diffusion runs multi-head attention with "
+                "rotary positions in every layer (a position table, latent "
+                "attention and the convolution know one copy of a sequence)")
+        bd = (clean_len, int(block_diffusion["block_length"]))
+        tokens, block_diffusion["mask"], block_diffusion["weight"] = (
+            layers.block_diffusion_noise(
+                tokens, block_diffusion["token_noise"],
+                block_diffusion["block_noise"], bd[1],
+                block_diffusion["mask_id"],
+                t_min=block_diffusion.get("t_min", 0.0)))
 
     def normed(x):
         if norm == "rms_norm":
@@ -125,12 +153,14 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
                 param_attr=attr, **mla)
         return layers.multi_head_attention(
-            h, h, h, num_heads=n_heads, causal=True,
+            h, h, h, num_heads=n_heads, causal=bd is None,
             param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
             sp_schedule=sp_schedule,
             qk_norm_epsilon=norm_epsilon if qk_norm else None,
             qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
-            rope_theta=rope_theta if positions == "rope" else None)
+            rope_theta=rope_theta if positions == "rope" else None,
+            **({"head_dim": head_dim} if head_dim else {}),
+            **({"block_diffusion": bd} if bd else {}))
 
     def feed_forward(h, layer):
         if ffn == "mlp":
@@ -159,7 +189,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         return layers.reshape(got[0], [-1, T, dim])
 
     emb_attr = attr if emb_init_scale is None else {
-        "initializer": NormalInitializer(scale=emb_init_scale)}
+        "initializer": NormalInitializer(scale=emb_init_scale,
+                                         seed=emb_init_seed)}
     x = layers.embedding(tokens, size=[vocab_size, dim], param_attr=emb_attr,
                          dtype=dtype)
     if positions == "learned":
@@ -180,6 +211,15 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 m = layers.dropout(m, dropout_prob, is_test=is_test)
             x = layers.elementwise_add(x, m)
 
+    if bd:  # the noisy half: the rows the loss reads
+        helper = LayerHelper("noisy_rows")
+        noisy = helper.create_tmp_variable(
+            dtype, shape=(x.shape[0], int(clean_len), dim))
+        helper.append_op("slice", inputs={"Input": [x.name]},
+                         outputs={"Out": [noisy.name]},
+                         attrs={"axes": [1], "starts": [0],
+                                "ends": [int(clean_len)]})
+        x = noisy
     h = normed(x)
     with default_main_program().part_guard("lm.head"):
         return layers.fc(h, vocab_size, num_flatten_dims=2,
@@ -191,7 +231,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 # _lm_fns) know
 _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "qk_norm": False, "ffn": "mlp", "attention": "multi_head",
-               "layer_types": None, "n_kv_heads": None}
+               "layer_types": None, "n_kv_heads": None, "head_dim": None,
+               "block_diffusion": None}
 
 
 def lm_loss(logits, targets, dtype="float32"):
@@ -205,6 +246,36 @@ def lm_loss(logits, targets, dtype="float32"):
             flat = layers.cast(flat, "float32")
         tgt = layers.reshape(targets, [-1, 1])
         return layers.mean(layers.softmax_with_cross_entropy(flat, tgt))
+
+
+def block_diffusion_loss(logits, tokens, weight, dtype="float32"):
+    """The block-diffusion objective (BD3-LM, arXiv:2503.09573): logits [B,
+    L, V] of the noisy rows against the clean `tokens` [B, L, 1] AT their
+    own positions (no shift), each token's cross-entropy times `weight`
+    [B, L, 1] = m / t (one over its block's noise level where the token
+    was masked, zero where it was not; `decoder_lm` leaves it in its
+    `block_diffusion` dict), summed and divided by B L.  Softmax in f32
+    regardless of the model compute dtype.
+
+    -> (objective, reported): the objective is what a step minimises;
+    `reported` = objective / mean(weight), the weighted MEAN of the masked
+    tokens' cross-entropies, is what a step reads back as its loss.  The
+    objective's scale is the draw's: at L 4096 in blocks of 4 with t
+    uniform on [1e-3, 1], mean(m / t) has a standard deviation of 3.8%
+    (each block adds 4 (1 - t) / t to its variance), as much as 32 steps
+    of training move the loss, so two steps' objectives say nothing of
+    whether the model learnt; the weighted mean moves 0.4% by the draw."""
+    V = logits.shape[-1]
+    with default_main_program().part_guard("lm.loss"):
+        flat = layers.reshape(logits, [-1, V])
+        if dtype != "float32":
+            flat = layers.cast(flat, "float32")
+        tgt = layers.reshape(tokens, [-1, 1])
+        per_token = layers.softmax_with_cross_entropy(flat, tgt)
+        weight = layers.reshape(weight, [-1, 1])
+        scale = layers.mean(weight)
+        objective = layers.mean(layers.elementwise_mul(per_token, weight))
+        return objective, layers.elementwise_div(objective, scale)
 
 
 def moe_lm_loss(logits, targets, router_outputs, dtype="float32",
@@ -721,4 +792,75 @@ def build_lfm2_moe_lm_train_program(
     opt.Adam(learning_rate=learning_rate).minimize(loss)
     for s in shares:
         layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
+    return loss
+
+
+def build_sdar_moe_lm_train_program(
+        seq_len, block_length, vocab_size, mask_id, dim, n_layers, n_heads,
+        n_kv_heads, head_dim, num_experts, expert_dim, top_k, held_experts,
+        first_expert=0, buffer_rows=None, t_min=1e-3, norm_epsilon=1e-6,
+        rope_theta=1000000.0, dtype="bfloat16", learning_rate=3e-5,
+        init_scale=0.02, emb_init_scale=None, routing_seed=0):
+    """SDAR-MoE-shaped decoder (`model_type` sdar_moe: SDAR-30B-A3B-Chat,
+    arXiv:2510.06303, a Qwen3-MoE block trained by block diffusion) as ONE
+    CHIP'S SHARE of an expert-parallel deployment, in a BLOCK-DIFFUSION
+    training step: the `seq_len` fed tokens are noised in blocks of
+    `block_length` (a level t uniform on [`t_min`, 1] a block from the fed
+    `block_noise`, a token masked to `mask_id` where its fed `token_noise`
+    lies under t) and run as 2 x seq_len rows [noisy ; clean] under the
+    block-diffusion attention mask.  RMSNorm pre-norm blocks;
+    grouped-query attention, `n_heads` query heads on `n_kv_heads`
+    key/value heads of `head_dim` (its own width, not dim / n_heads), an
+    RMSNorm on each head of Q and K, then rotate-half RoPE at the
+    position in the clean sequence; in every block an expert layer whose
+    router scores all `num_experts` by softmax, chooses `top_k` and
+    renormalises their weights to sum to one; of those experts this chip
+    holds `held_experts` from `first_expert` on and computes their part in
+    a buffer of `buffer_rows` rows; no shared expert, no dense layer;
+    `vocab_size` is the slice of the vocabulary this chip embeds and
+    scores, `mask_id` one of its rows; no bias, untied head.  Loss: the
+    masked tokens' cross-entropy at their own positions, each over its
+    block's t, summed over the sequence's length (`block_diffusion_loss`'s
+    objective); no auxiliary term; Adam.  `routing_seed` (0: the program's
+    `random_seed`, like every other weight) draws the token embedding and
+    the routers from a seed of their own: what decides which experts a
+    token goes to, the MASK token above all (a quarter of a step's rows,
+    all to the same `top_k` experts of a layer), is then the same in every
+    run, as a checkpoint's is.  Returns the loss a step reports
+    (`block_diffusion_loss`'s second).  Feeds: 'tokens' [B,
+    seq_len, 1] int64, 'token_noise' [B, seq_len, 1] and 'block_noise' [B,
+    seq_len / block_length, 1] float32 uniform in [0, 1)."""
+    from .. import optimizer as opt
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    noise = {"block_length": block_length, "mask_id": mask_id,
+             "t_min": t_min,
+             "token_noise": layers.data(
+                 "token_noise", shape=[seq_len, 1], dtype="float32"),
+             "block_noise": layers.data(
+                 "block_noise", shape=[seq_len // block_length, 1],
+                 dtype="float32")}
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, n_layers, n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions="rope", rope_theta=rope_theta, qk_norm="head",
+        n_kv_heads=n_kv_heads, head_dim=head_dim, block_diffusion=noise,
+        ffn="moe",
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "softmax", "renormalise": True,
+             "buffer_rows": buffer_rows,
+             "param_attr": {"initializer": NormalInitializer(
+                 scale=init_scale, seed=routing_seed)}},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale, emb_init_seed=routing_seed)
+    objective, loss = block_diffusion_loss(logits, tokens, noise["weight"],
+                                           dtype=dtype)
+    # the share of the tokens the noise masked, and the last layer's routed
+    # (row, expert) pairs over ALL experts, for fetches to hold exactly:
+    # 2 x seq_len x top_k a sequence
+    layers.reduce_mean(noise["mask"])
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(objective)
     return loss

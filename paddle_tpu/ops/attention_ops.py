@@ -29,8 +29,16 @@ _MET_ATTN_LAYERS = _MET.counter(
     "compile, not once a step), by the `layout` attr of the desc op (bhtd: "
     "Q [B,H,T,D]; bthd: Q [B,T,H*D], as the projections leave it) and the "
     "path the emitter took (flash_packed: the Pallas kernels on [B,T,H*D] "
-    "as it lies; flash: the kernels on [B,H,T,D]; dense: XLA's fused "
-    "softmax; ring, alltoall: sequence parallel)")
+    "as it lies; flash: the kernels on [B,H,T,D]; flash_block_diffusion: "
+    "those under the block-diffusion mask; dense: XLA's fused softmax; "
+    "ring, alltoall: sequence parallel)")
+_MET_BD_LAYERS = _MET.counter(
+    "block_diffusion_layers_traced_total",
+    "scaled_dot_product_attention ops traced under the block-diffusion mask "
+    "(forward emission; once a compile, not once a step), by the tokens a "
+    "sample (seq_len; the op sees twice as many rows), the tokens a block "
+    "(block_length), the two head counts (q_heads, kv_heads) and the head "
+    "size (head_dim)")
 
 
 def _attend(h, enc_proj, enc_out, enc_mask, w_q, v):
@@ -108,7 +116,46 @@ def attention_gru_decoder(ctx, ins, attrs):
             "Context": [jnp.moveaxis(ctxs, 0, 1)]}
 
 
-def flash_single_chip(ctx, q, k, v, causal: bool, heads=None):
+def block_diffusion_allowed(seq_len: int, block_length: int):
+    """Allowed(r, c) [2L, 2L] of block-diffusion training over [noisy ;
+    clean] rows, from its definition: row r stands at position r mod L in
+    block (r mod L) // b; a noisy row sees the noisy rows of its own block
+    and the clean rows of the blocks before it, a clean row the clean rows
+    of its own block and of those before it, and no clean row a noisy one.
+    What the dense path applies, and what the flash kernels' regions
+    (flash_attention.block_diffusion_mask) are tested against."""
+    import jax.numpy as jnp
+
+    L, b = int(seq_len), int(block_length)
+    at = jnp.arange(2 * L)
+    noisy, block = at < L, (at % L) // b
+    r_noisy, c_noisy = noisy[:, None], noisy[None, :]
+    r_block, c_block = block[:, None], block[None, :]
+    return ((r_noisy & c_noisy & (r_block == c_block))
+            | (r_noisy & ~c_noisy & (c_block < r_block))
+            | (~r_noisy & ~c_noisy & (c_block <= r_block)))
+
+
+def _mask_attrs(attrs, T: int):
+    """(seq_len, block_length) of an op whose `mask` attr is
+    'block_diffusion', else None ('' and 'none': no mask of its own)."""
+    kind = str(attrs.get("mask", "") or "none")
+    if kind == "none":
+        return None
+    if kind != "block_diffusion":
+        raise ValueError(f"scaled_dot_product_attention: mask {kind!r}: "
+                         f"use 'block_diffusion'")
+    L, b = int(attrs["seq_len"]), int(attrs["block_length"])
+    if bool(attrs.get("causal", False)) or T != 2 * L or b < 1 or L % b:
+        raise ValueError(
+            f"scaled_dot_product_attention: the block-diffusion mask runs "
+            f"2 x seq_len rows in whole blocks and is not causal; got "
+            f"{T} rows, seq_len {L}, block_length {b}, causal "
+            f"{attrs.get('causal', False)}")
+    return L, b
+
+
+def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None):
     """The single-chip fast path of an attention emitter: the Pallas flash
     kernel (VMEM-tiled online softmax) on Q [B,H,T,D], K [B,Hkv,T,D] and V
     [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
@@ -123,7 +170,9 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None):
     address a head as a column block, two of 64 to a block; the output
     leaves in that layout too).  Sharded mesh execution
     keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
-    call).  -> None where it does not apply, else (out, saved).
+    call).  `mask`: (seq_len, block_length) of the block-diffusion mask,
+    inside the same kernels, where blocks of 128 divide seq_len in whole
+    treads.  -> None where it does not apply, else (out, saved).
 
     Training goes through the custom_vjp pair (FlashAttention-2-style
     blockwise backward), which generic_grad's jax.vjp honors, and the
@@ -144,11 +193,16 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None):
         fits = (T % 128 == 0 and k.shape == q.shape and v.shape == q.shape
                 and q.shape[2] in (64 * heads, 128 * heads)
                 and q.shape[2] % 128 == 0)
+    if mask is not None:
+        fits = fits and mask[0] % 128 == 0 and 128 % mask[1] == 0
     if not fits:
         return None
     from .pallas_kernels import flash_attention as fa
 
     layout = {} if heads is None else {"heads": heads}
+    if mask is not None:
+        layout.update(mask=fa.block_diffusion_mask(*mask),
+                      block_q=fa.MASK_BLOCKS[0], block_k=fa.MASK_BLOCKS[1])
     if ctx.is_test:
         return fa.flash_attention(q, k, v, causal=causal, **layout), None
     train = fa.make_flash_train(causal=causal, **layout)
@@ -184,7 +238,13 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 
     K and V may have fewer heads than Q, a divisor Hkv of H: query head h
     attends to key/value head h // (H / Hkv).  The flash kernels take the
-    two head counts as they are; every other path sees K and V repeated."""
+    two head counts as they are; every other path sees K and V repeated.
+
+    The attr `mask` = "block_diffusion" with `seq_len` L and `block_length`
+    b: the T = 2L rows are the noised and the clean copy of L tokens and
+    attend under `block_diffusion_allowed` (`causal` stays false): inside
+    the flash kernels on one TPU, dense under the same Allowed everywhere
+    else, a mesh included."""
     import jax.numpy as jnp
 
     from ..parallel import ring_attention as ra
@@ -195,9 +255,10 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     if layout not in ("bhtd", "bthd"):
         raise ValueError(f"layout {layout!r}: use 'bhtd' or 'bthd'")
     causal = bool(attrs.get("causal", False))
+    bd = _mask_attrs(attrs, q.shape[1 if layout == "bthd" else 2])
     sp_mode = str(attrs.get("sp_mode", "ring"))
     mesh = getattr(ctx, "mesh", None)
-    sp = mesh is not None and axis_size(mesh, "sp") > 1
+    sp = bd is None and mesh is not None and axis_size(mesh, "sp") > 1
 
     def traced(path):
         if not ctx.in_grad_replay():
@@ -207,7 +268,7 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         heads = int(attrs["num_heads"])
         kv_heads = int(attrs.get("num_kv_heads", heads))
         got = None
-        if not sp and kv_heads == heads:
+        if not sp and kv_heads == heads and bd is None:
             with part_scope("attn.attend"):
                 got = flash_single_chip(ctx, q, k, v, causal, heads=heads)
         if got is not None:
@@ -230,6 +291,10 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         _MET_GQA_LAYERS.inc(q_heads=str(q.shape[1]),
                             kv_heads=str(k.shape[1]),
                             head_dim=str(q.shape[3]))
+    if bd is not None and not ctx.in_grad_replay():
+        _MET_BD_LAYERS.inc(seq_len=str(bd[0]), block_length=str(bd[1]),
+                           q_heads=str(q.shape[1]), kv_heads=str(k.shape[1]),
+                           head_dim=str(q.shape[3]))
 
     def repeated(a):
         return a if group == 1 else jnp.repeat(a, group, axis=1)
@@ -267,13 +332,15 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         traced(sp_mode)
     else:
         with part_scope("attn.attend"):
-            got = flash_single_chip(ctx, q, k, v, causal)
-            if got is None:
-                out = ra.attention(q, repeated(k), repeated(v),
-                                   causal=causal)
-            else:
+            got = flash_single_chip(ctx, q, k, v, causal, mask=bd)
+            if got is not None:
                 out, saved = got
-        traced("dense" if got is None else "flash")
+            else:
+                out = ra.attention(
+                    q, repeated(k), repeated(v), causal=causal,
+                    allowed=bd and block_diffusion_allowed(*bd))
+        traced("dense" if got is None else
+               "flash" if bd is None else "flash_block_diffusion")
     if layout == "bthd":  # [B, H, T, Dv] -> [B, T, H * Dv]
         out = out.transpose(0, 2, 1, 3).reshape(
             out.shape[0], out.shape[2], -1)
@@ -832,6 +899,10 @@ def _sdpa_cost(ins, outs, attrs):
     flops = 4 * b * t * s * hd
     if bool(attrs.get("causal", False)):
         flops //= 2  # masked half of the score matrix is never computed
+    if str(attrs.get("mask", "") or "none") == "block_diffusion":
+        # L^2 + L b live scores of the (2L)^2
+        L, blk = int(attrs["seq_len"]), int(attrs["block_length"])
+        flops = 4 * b * hd * (L * L + L * blk)
     return {"flops": flops}
 
 

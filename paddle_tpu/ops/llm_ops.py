@@ -1,8 +1,10 @@
 """Ops of the post-2020 decoder block that the GPT-2-shaped tower lacks:
 RMSNorm, rotary position embedding (beyond-reference, like the rest of
 the transformer tier; first user: OLMoE, models/transformer.py), latent
-attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B) and the gated
-short convolution (LFM2's token mixer in the layers that do not attend).
+attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B), the gated
+short convolution (LFM2's token mixer in the layers that do not attend) and
+the noising of block-diffusion training (BD3-LM's objective; first user:
+SDAR-30B-A3B).
 
 All but latent attention are plain jax.numpy, so `generic_grad` differentiates them by
 re-emission and XLA's CSE merges the re-emitted forward with the first.
@@ -62,10 +64,11 @@ def rms_norm(ctx, ins, attrs):
                       tuple(range(begin, x.ndim)), gain)]}
 
 
-def rotate_half(x, theta: float):
+def rotate_half(x, theta: float, period: int = 0):
     """X [..., T, D] with D even turned by position: the pair (x[i], x[i +
     D/2]) of position t by the angle t * theta ** (-2i / D), positions
-    0..T-1; at least float32 inside, X's dtype out."""
+    0..T-1, or, with a `period`, row r at position r mod period (copies of
+    one sequence side by side); at least float32 inside, X's dtype out."""
     import jax.numpy as jnp
 
     T, D = x.shape[-2], x.shape[-1]
@@ -74,7 +77,10 @@ def rotate_half(x, theta: float):
     half = D // 2
     xf = x.astype(wide_dtype(x.dtype))
     inv_freq = theta ** (-jnp.arange(half, dtype=xf.dtype) / half)
-    ang = jnp.arange(T, dtype=xf.dtype)[:, None] * inv_freq[None, :]
+    pos = jnp.arange(T, dtype=xf.dtype)
+    if period:
+        pos = (jnp.arange(T) % period).astype(xf.dtype)
+    ang = pos[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)          # [T, D/2]
     a, b = xf[..., :half], xf[..., half:]
     y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -86,9 +92,49 @@ def rope(ctx, ins, attrs):
     """Rotary position embedding in its rotate-half form (Su et al. 2021,
     arXiv:2104.09864, as GPT-NeoX and transformers apply it): X [B, H, T,
     D] with D even; position t of every head turns the pair (x[i], x[i +
-    D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1."""
+    D/2]) by the angle t * theta ** (-2i / D).  Positions are 0..T-1; with
+    the attr `period`, row r stands at position r mod period."""
     return {"Out": [rotate_half(ins["X"][0],
-                                float(attrs.get("theta", 10000.0)))]}
+                                float(attrs.get("theta", 10000.0)),
+                                int(attrs.get("period", 0)))]}
+
+
+@register_op("block_diffusion_noise", grad=None)
+def block_diffusion_noise(ctx, ins, attrs):
+    """The input of a block-diffusion training step (BD3-LM,
+    arXiv:2503.09573, as SDAR, arXiv:2510.06303, trains): Tokens [B, L, 1]
+    clean tokens x0, in blocks of `block_length`; BlockNoise [B, L /
+    block_length, 1] uniform in [0, 1), one draw a block, gives the
+    block's noise level t = t_min + (1 - t_min) * draw; TokenNoise [B, L,
+    1] uniform in [0, 1), one draw a token, masks the token where it lies
+    under its block's level.
+
+      Mask   [B, L, 1] float32: m_i = [u_i < t_blk(i)]
+      Weight [B, L, 1] float32: m_i / t_blk(i), the loss's weight
+      Out    [B, 2L, 1]: [xt ; x0], xt_i = `mask_id` where m_i else x0_i
+
+    The noise is FED, not drawn here: whoever feeds it knows the mask.
+    Nothing here takes a gradient (`grad=None`): the tokens are integers
+    and the draws are data."""
+    import jax.numpy as jnp
+
+    tokens, u, draw = (ins[k][0] for k in ("Tokens", "TokenNoise",
+                                           "BlockNoise"))
+    b = int(attrs["block_length"])
+    t_min = float(attrs.get("t_min", 0.0))
+    if tokens.shape[1] != draw.shape[1] * b or u.shape != tokens.shape:
+        raise ValueError(
+            f"block_diffusion_noise: {tokens.shape} tokens, {u.shape} token "
+            f"draws and {draw.shape} block draws at {b} tokens a block")
+    with part_scope("bd.noise"):
+        t = t_min + (1.0 - t_min) * draw.astype(jnp.float32)
+        t = jnp.repeat(t, b, axis=1)                        # [B, L, 1]
+        masked = u.astype(jnp.float32) < t
+        noisy = jnp.where(masked, jnp.asarray(int(attrs["mask_id"]),
+                                              tokens.dtype), tokens)
+        m = masked.astype(jnp.float32)
+        return {"Out": [jnp.concatenate([noisy, tokens], axis=1)],
+                "Mask": [m], "Weight": [m / t]}
 
 
 @register_op("latent_attention")

@@ -6,16 +6,27 @@ INNERMOST grid dimension so the Pallas pipeline double-buffers the K/V
 block DMAs against the MXU GEMMs.  The online softmax (running max m,
 normalizer l, unnormalized accumulator) lives in VMEM scratch, initialized
 at the first K block and finalized into the output block at the last.
-Under causal masking the K/V index maps CLAMP to the diagonal block so
-fully-masked future blocks are never fetched, and `pl.when` skips their
+Under a mask the K/V index maps CLAMP a dead block's fetch to a live one,
+so fully-masked blocks are never fetched, and `pl.when` skips their
 compute.
 
-Inside a block the diagonal crosses, the causal kernels walk strips of q
-rows, each against only the K columns its last row may see: static
+A mask is a static description of a call's LIVE REGIONS (`_Stairs`: a
+rectangle of whole blocks and a staircase of `step` rows a tread inside
+it), and the schedule (`_schedule`), the walk of a block (`_run_block`),
+the index maps' clamps (`_live_k_block`, `_live_q_block`) and the mask
+inside a strip (`_below_diagonal`) all derive from it.  The causal
+diagonal is one region, the whole square, one row a tread
+(`causal_mask`); block-diffusion training over [noisy ; clean] rows
+(`block_diffusion_mask`) is three: the noisy rows' block diagonal, their
+clean context strictly before the block, the clean rows' block-causal
+half; the quadrant clean-on-noisy is in no region and is dead.
+
+Inside a block a staircase crosses, the kernels walk strips of q
+rows, each against only the K columns its rows may see: static
 slices, one straight-line walk for each offset d = q0 - k0 such a block
-can have (`_row_strips`, `_run_block`).  Two readings of the v5e say
-when work inside one VMEM-resident block pays (each kernel alone, B 8,
-H 16, T 1024, D 64, bf16; PERF.md, PR 27):
+can have (`_row_strips`, `_stair_strips`, `_run_block`).  Two readings of
+the v5e say when work inside one VMEM-resident block pays (each kernel
+alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
 
 - A LOOP over sub-tiles does not.  r4's first contact had a fori_loop
   over one resident [T, D] K/V block at 0.7x of dense XLA attention, and
@@ -135,6 +146,48 @@ H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   read the parent's times: 3.459 4.349 4.748 at T 8192, 192 / 128,
   4.818 5.366 6.777 at T 8192, D 64, 32 on 8.
 
+- The block-diffusion mask as ONE call over the [2L, 2L] square (PR 37;
+  `block_diffusion_mask`, `_stair_strips`, `_live_k_block`).  Its three
+  live regions hold L^2 + L b of the 4 L^2 scores (25.02% at L 4096, b 4).
+  The grid is the causal calls' own, (head, q block, K block) over all 2L
+  rows and columns: a dead step (the whole quadrant clean-on-noisy, the
+  noisy columns beyond a row's own block, the clean columns beyond a
+  staircase) fetches nothing new, because the index maps clamp it to a
+  block the row already holds, and computes nothing; a live step runs
+  whole (a block wholly under a staircase) or in the static strips of its
+  offset d inside its region, at most two offsets a region for the
+  blocks used.  A strip of a staircase whose reach ends off the lane grid
+  (rows see up to 4 columns FEWER than a multiple of 128) moves out to
+  the grid and its _Tread masks the rest; a strip of the block diagonal
+  is one [128, 128] tile of which 4 x 4 squares are live, 0.2% of what is
+  computed.  Device ms a call from a trace, forward / dq / dkv, each
+  kernel alone, B 1, 32 query heads on 4 key/value heads of 128, 2L =
+  8192, b = 4, bf16, against the CAUSAL call of the same shape (which
+  computes 50.8% of the square where the mask's schedule computes 26.6%):
+    causal, blocks (512, 1024)           5.461 5.349 6.672
+    the mask, blocks (512, 1024)         4.183 3.357 4.246
+    the mask, blocks (1024, 1024)        3.426 2.952 3.615   KEPT
+    (512, 512)                           5.867 4.018 5.144
+    (1024, 512)                          5.377 3.443 4.306
+    (256, 1024)                          6.240 4.492 6.060
+    (2048, 1024)                         3.105 2.742 28.652
+    (1024, 2048)                         3.113 2.766 28.461
+    (2048, 2048), (4096, 1024)           out of VMEM (24.9 MB of 16)
+  Half the scores for 63 / 55 / 54% of the causal call's time: the
+  backward kernels follow the scores, the forward again less (its time is
+  per-row bookkeeping, and every q block pays a block-diagonal step of
+  1024 x 128 columns for 4 live ones a row).  Of the least time of the
+  LIVE scores (1.397 / 2.095 / 2.793 ms at the bf16 peak) that is 41 / 71
+  / 77%.  Larger q blocks gain because a dead grid step is not free (0.35
+  us; 64 steps a head here, 24 of them live, against 128 and 48 at (512,
+  1024)) and K blocks stay longer; beyond 1024 rows dkv's transposed tile
+  no longer fits the registers (8 times slower) or the kernel the VMEM.
+  NOT tried: clean keys and noisy keys as two K ranges of one walk (a
+  grid of L / bk + 1 steps a q block and no dead quadrant: it would take
+  the 40 dead steps a head, ~14 us of 107, out of the forward and dq, and
+  wants index maps that are no clamp); blocks chosen per kernel ((2048,
+  1024) for the forward and dq alone: 0.5 ms a layer).
+
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
 array's — a (1, bq) block over a (BH, T) array satisfies neither (first
@@ -147,16 +200,18 @@ Replaces what the reference would have hand-written in paddle/cuda
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 from ...observability.metrics import REGISTRY as _MET
 
 _MET_SCORES = _MET.counter(
     "flash_score_elements_total",
-    "score elements of the causal flash kernel calls traced (once a "
-    "compile, not once a step), by kernel: part=square the B*H*T*T of "
-    "the call, part=computed those its schedule computes (blocks of the "
-    "future and the part of a strip beyond the diagonal's reach left out)")
+    "score elements of the masked flash kernel calls traced, causal or "
+    "under a mask of their own (once a compile, not once a step), by "
+    "kernel: part=square the B*H*T*T of the call, part=computed those its "
+    "schedule computes (dead blocks and the part of a strip beyond a "
+    "staircase's reach left out)")
 
 
 def _snap_block(block: int, T: int, tile: int = 128) -> int:
@@ -198,7 +253,8 @@ def one_block_a_head(bq: int, bk: int, T: int, D: int) -> bool:
 
 
 def _snap_blocks(block_q: int, block_k: int, T: int,
-                 interpret: bool = False, causal_head: int = 0):
+                 interpret: bool = False, causal_head: int = 0,
+                 unit: int = 0):
     """Aligned (bq, bk) for the public kernel entry points, failing with a
     clear Python error at trace time instead of a Mosaic one at run time.
     Interpret mode has no Mosaic tile contract (tests run tiny T/blocks
@@ -216,13 +272,15 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
 
     `causal_head` is the head size of a causal call (0 for any other): on
     the chip such a call runs one block a head where one_block_a_head
-    says so, whatever q block was asked for."""
+    says so, whatever q block was asked for.  `unit` is the length the
+    blocks of a call under a mask of several regions have to divide
+    (_mask_unit), where T is not it."""
     from ...autotune import knobs
 
     block_q, block_k = knobs.flash_blocks(block_q, block_k, T)
     tile = 1 if interpret else 128
-    bq = _snap_block(block_q, T, tile)
-    bk = _snap_block(block_k, T, tile)
+    bq = _snap_block(block_q, unit or T, tile)
+    bk = _snap_block(block_k, unit or T, tile)
     if not bq or not bk:
         raise ValueError(
             f"flash attention needs a 128-aligned divisor of T={T} at or "
@@ -317,19 +375,187 @@ def _join_heads(parts):
     return jnp.where(lane < 64, first, second)
 
 
-def _kv_idx(bq: int, bk: int, causal: bool, group: int, nb: int = 0):
-    """K/V index map of the forward and _dq_kernel (one map, so the
-    diagonal arithmetic cannot drift between them): query head b reads
-    its group's K/V head, and under causal masking fully-future fetches
-    CLAMP to the diagonal block: the DMA for a skipped block is a
-    re-fetch of an already-buffered index (i.e. free), halving HBM
-    traffic."""
+class _Stairs(NamedTuple):
+    """One live region of a call's [T, T] square of scores: the q rows
+    `rows` = [lo, hi) against the K columns `cols`, both in whole blocks.
+    Row r, counted from rows[0], sees the columns c, counted from cols[0],
+    up to (r // step) * step + reach: a staircase of `step` rows a tread;
+    from column 0 on, or, `band`, from (r // step) * step on.  What no
+    region of a mask (a tuple of these) holds is dead: neither fetched nor
+    computed.  The schedule (_schedule), the walk of a block (_run_block),
+    the clamps of the index maps (_kv_idx, _dkv_q_maps) and the mask
+    inside a strip (_below_diagonal) all derive from it."""
+
+    rows: tuple
+    cols: tuple
+    step: int = 1
+    reach: int = 0
+    band: bool = False
+
+
+def causal_mask(T: int) -> tuple:
+    """Position r sees the positions up to r: one region, the whole
+    square, a staircase of one row a tread."""
+    return (_Stairs((0, T), (0, T)),)
+
+
+def block_diffusion_mask(seq_len: int, block_length: int) -> tuple:
+    """The mask of block-diffusion training (BD3-LM, arXiv:2503.09573) over
+    2L rows, the noised copy of L tokens then the clean copy, in blocks of
+    b tokens: a noisy row sees the noisy rows of its own block (a block
+    diagonal) and the clean rows of the blocks BEFORE its own; a clean row
+    sees the clean rows of its own block and of those before it; no clean
+    row sees a noisy one.  L^2 + L b live scores of 4 L^2."""
+    L, b = int(seq_len), int(block_length)
+    if b < 1 or L % b:
+        raise ValueError(f"block diffusion: blocks of {b} do not divide "
+                         f"{L} tokens")
+    return (_Stairs((0, L), (0, L), b, b - 1, True),
+            _Stairs((0, L), (L, 2 * L), b, -1),
+            _Stairs((L, 2 * L), (L, 2 * L), b, b - 1))
+
+
+# The blocks a call under a mask of several regions asks for where its
+# caller has no wish of its own (attention_ops.flash_single_chip): under
+# the block-diffusion mask at 2L = 8192, 32 query heads on 4 of 128, each
+# kernel alone read (device ms a call, forward / dq / dkv; module docstring,
+# PR 37) 4.18 / 3.36 / 4.25 at the causal calls' (512, 1024), 3.43 / 2.95 /
+# 3.62 here; (2048, 1024) and (1024, 2048) give the forward and dq 8% more
+# and cost dkv a factor of EIGHT (28.6 ms), (2048, 2048) runs out of VMEM.
+MASK_BLOCKS = (1024, 1024)
+
+
+def _mask_unit(mask, T: int) -> int:
+    """The length a mask's blocks have to divide: every region begins and
+    ends on a block's edge."""
+    return math.gcd(T, *(e for s in mask for e in s.rows + s.cols))
+
+
+def _check_mask(mask, T: int, bq: int, bk: int):
+    for s in mask:
+        if s.rows[1] > T or s.cols[1] > T:
+            raise ValueError(f"flash attention: a mask over {s.rows} x "
+                             f"{s.cols} on {T} positions")
+        if s.step > 1 and (bq % s.step or bk % s.step):
+            raise ValueError(
+                f"flash attention: blocks ({bq}, {bk}) are not whole treads "
+                f"of a mask's {s.step} rows; use the dense path")
+
+
+def _pick(x, pieces):
+    """`pieces` [(bound, value)] in rising order of bound: the value of the
+    first piece whose bound lies above the traced scalar `x`, else the
+    last's.  One piece is its value, with no select."""
     import jax.numpy as jnp
 
+    out = pieces[-1][1]
+    for bound, value in reversed(pieces[:-1]):
+        out = jnp.where(x < bound, value, out)
+    return out
+
+
+def _bands(mask, axis: int, block: int):
+    """A mask's regions grouped by their extent along `axis` (0: rows, 1:
+    columns): [(end of the band in blocks of `block`, its regions in rising
+    order along the other axis)].  The bands must not overlap (a block
+    lies in one)."""
+    groups = {}
+    for s in mask:
+        groups.setdefault(s[axis], []).append(s)
+    out, at = [], 0
+    for (lo, hi), regions in sorted(groups.items()):
+        if lo < at:
+            raise ValueError(f"flash attention: a mask's regions overlap "
+                             f"at {lo} along axis {axis}")
+        at = hi
+        out.append((hi // block, sorted(regions, key=lambda s: s[1 - axis])))
+    return out
+
+
+def _moved(x, by: int):
+    """x + by; no operation where `by` is 0, so that the index maps of a
+    region that begins at the square's corner (the causal diagonal) stay
+    the expressions they were."""
+    return x + by if by else x
+
+
+def _clamp(x, lo, hi, first: int, last: int):
+    """`x` held inside [lo, hi]; a bound that cannot bind (`lo` the static
+    `first`, `hi` the static `last`) adds no operation."""
+    import jax.numpy as jnp
+
+    if not (isinstance(lo, int) and lo <= first):
+        x = jnp.maximum(x, lo)
+    if not (isinstance(hi, int) and hi >= last):
+        x = jnp.minimum(x, hi)
+    return x
+
+
+def _live_k_block(mask, bq: int, bk: int, nk: int):
+    """(i, j) -> the K block that q block i's step j fetches: j itself
+    where the block (i, j) is live, else the nearest live block of the
+    region that holds or follows it, so that a dead step re-fetches a
+    block already buffered (i.e. free).  Under causal masking that is
+    min(j, the diagonal's block).  (Where a block is no taller than a
+    tread, the first block of a staircase that begins a tread late has
+    nothing live in that region and fetches the block before it: one DMA
+    too many, and nothing wrong, for _run_block skips by the step's own
+    place.)"""
+    def of_region(s, i, j):
+        # the last row of q block i sees up to column `hi` of the square,
+        # the first one, in a band, from `lo` on
+        off = s.cols[0] - s.rows[0]
+        hi = _moved((i + 1) * bq - (s.step - s.reach), off) // bk
+        lo = _moved(i * bq, off) // bk if s.band else s.cols[0] // bk
+        return _clamp(j, lo, hi, 0, nk - 1)
+
+    def idx(i, j):
+        return _pick(i, [
+            (end, _pick(j, [(s.cols[1] // bk, of_region(s, i, j))
+                            for s in regions]))
+            for end, regions in _bands(mask, 0, bq)])
+
+    return idx
+
+
+def _live_q_block(mask, bq: int, bk: int, nq: int):
+    """(j, i) -> the q block that K block j's step i fetches in the dkv
+    kernel: _live_k_block's twin along the other axis.  Under causal
+    masking max(i, the first q block that attends K block j) (skip-early:
+    a skipped step re-fetches a block already buffered)."""
+    def of_region(s, j, i):
+        # the first row of the square that sees K block j's first column
+        # (a tread later where a row's own tread is hidden from it), and
+        # in a band the last that sees its last
+        off = s.rows[0] - s.cols[0]
+        lo = _moved(j * bk, off + (s.step if s.reach < 0 else 0)) // bq
+        hi = (_moved((j + 1) * bk - 1, off) // bq if s.band
+              else s.rows[1] // bq - 1)
+        return _clamp(i, lo, hi, 0, nq - 1)
+
+    def idx(j, i):
+        return _pick(j, [
+            (end, _pick(i, [(s.rows[1] // bq, of_region(s, j, i))
+                            for s in regions]))
+            for end, regions in _bands(mask, 1, bk)])
+
+    return idx
+
+
+def _kv_idx(bq: int, bk: int, mask, group: int, nb: int = 0, T: int = 0):
+    """K/V index map of the forward and _dq_kernel (one map, so the
+    masks' arithmetic cannot drift between them): query head b reads
+    its group's K/V head, and under a mask (a tuple of _Stairs; None: every
+    block is live) the fetch of a dead block CLAMPS to a live one
+    (_live_k_block): the DMA for a skipped block is a re-fetch of an
+    already-buffered index (i.e. free), halving HBM traffic under causal
+    masking."""
     head, at = _kv_head(group), _tile_at(nb)
-    if causal:
+    if mask is not None:
+        live = _live_k_block(mask, bq, bk, T // bk)
+
         def idx(b, i, j):
-            return at(head(b), jnp.minimum(j, ((i + 1) * bq - 1) // bk))
+            return at(head(b), live(i, j))
     else:
         def idx(b, i, j):
             return at(head(b), j)
@@ -338,10 +564,11 @@ def _kv_idx(bq: int, bk: int, causal: bool, group: int, nb: int = 0):
 
 
 # ---------------------------------------------------------------------------
-# The causal walk: a grid block the diagonal crosses is computed strip by
-# strip, each strip only as far as the diagonal reaches.  Every shape in it
-# is static: a crossed block's offset from the diagonal, d = q0 - k0, takes
-# a few values for given blocks, and the body holds one walk for each.
+# The masked walk: a grid block a staircase crosses is computed strip by
+# strip, each strip only as far as the staircase reaches.  Every shape in it
+# is static: a crossed block's offset from its region's staircase, d = q0 -
+# k0 counted inside the region, takes a few values for given blocks, and the
+# body holds one walk for each.
 
 
 # q rows in a strip, as the share of the block's longer side, by kernel: what
@@ -375,10 +602,10 @@ def _strip_rows(kernel: str, bq: int, bk: int) -> int:
 
 def _row_strips(d: int, bq: int, bk: int, sq: int) -> tuple:
     """The walk of a block whose first q row lies d positions after its
-    first K column: [(r0, width, masked)], a strip of q rows [r0, r0 + sq)
-    against the block's K columns [0, width), all that its last row may
-    see; `masked` where its first row may not see them all.  A strip that
-    sees nothing is left out."""
+    first K column, under the causal diagonal: [(r0, width, masked)], a
+    strip of q rows [r0, r0 + sq) against the block's K columns [0,
+    width), all that its last row may see; `masked` where its first row
+    may not see them all.  A strip that sees nothing is left out."""
     out = []
     for r0 in range(0, bq, sq):
         width = min(max(d + r0 + sq, 0), bk)
@@ -387,8 +614,65 @@ def _row_strips(d: int, bq: int, bk: int, sq: int) -> tuple:
     return tuple(out)
 
 
+class _Tread(NamedTuple):
+    """The mask inside a strip of a staircase of `step` rows a tread: row
+    r and column c of the strip are live where c - (r // step) * step lies
+    in [ahead - span, ahead] (`span` None: at or under `ahead`).  Static
+    and hashable: it takes `ahead`'s place in a tile's arguments, where an
+    int is the causal diagonal's (step 1)."""
+
+    ahead: int
+    step: int
+    span: object = None
+
+
+def _stair_strips(d: int, bq: int, bk: int, sq: int, stairs) -> tuple:
+    """_row_strips for a staircase of several rows a tread (_Stairs; d
+    counted inside its region): [(r0, c0, width, tread)], a strip of q
+    rows [r0, r0 + sq) against the block's K columns [c0, c0 + width),
+    from the first column its first row sees to the last its last row
+    sees, both moved out to the lane grid where the blocks lie on it (a
+    reach of -1 would end a strip at 124 columns); `tread` the _Tread
+    that masks it, None where every row sees all of it."""
+    step, reach = stairs.step, stairs.reach
+    if sq % step and step % sq:
+        raise ValueError(f"flash attention: strips of {sq} rows and treads "
+                         f"of {step} do not nest; use the dense path")
+    grid = 128 if not (bk % 128 or sq % 128 or d % 128) else 1
+    out = []
+    for r0 in range(0, bq, sq):
+        first = d + r0 // step * step          # the first row's tread
+        last = d + (r0 + sq - 1) // step * step
+        lo = max(first, 0) if stairs.band else 0
+        hi = min(last + reach, bk - 1)
+        if hi < 0 or lo > bk - 1:
+            continue
+        c0 = lo // grid * grid
+        end = min(-(-(hi + 1) // grid) * grid, bk)
+        clear = first + reach >= end - 1 and not (stairs.band and last > c0)
+        tread = None
+        if not clear:
+            if r0 % step:  # a strip inside one tread is never crossed
+                raise ValueError(
+                    f"flash attention: a strip of {sq} rows at {r0} off "
+                    f"the lane grid inside a tread of {step}")
+            tread = _Tread(d + r0 + reach - c0, step,
+                           reach if stairs.band else None)
+        out.append((r0, c0, end - c0, tread))
+    return tuple(out)
+
+
+class _Part(NamedTuple):
+    """A plan's walk of ONE region of a mask."""
+
+    stairs: _Stairs
+    walks: tuple     # ((d, _stair_strips(d, ...)), ...), d inside the region
+    full: object     # the least d at which a block is wholly live, or None
+    #                  where no block of the region is
+
+
 class _Plan(NamedTuple):
-    """What a causal kernel does with a [T, T] square of scores under
+    """What a masked kernel does with a [T, T] square of scores under
     blocks (bq, bk) and strips of sq rows; hashable, it is part of what a
     kernel call is memoized by."""
 
@@ -397,58 +681,111 @@ class _Plan(NamedTuple):
     #                  d = q0 - k0 a block the diagonal crosses can have
     full: bool       # some block lies wholly at or below the diagonal: the
     #                  single-shot body is emitted only then
-    computed: int    # score elements computed: blocks of the future and the
-    #                  part of a strip beyond the diagonal's reach left out
+    computed: int    # score elements computed: dead blocks and the part of
+    #                  a strip beyond the staircase's reach left out
+    parts: tuple = ()  # a mask of several regions or treads: one _Part a
+    #                  region, and `walks` empty; (): the causal diagonal
 
 
-def _schedule(T: int, bq: int, bk: int, sq: int) -> _Plan:
-    """The _Plan of a [T, T] square under blocks (bq, bk), strips of sq."""
-    walks, full, computed = {}, False, 0
-    for q0 in range(0, T, bq):
-        for k0 in range(0, T, bk):
-            d = q0 - k0
-            if d <= -bq:
-                continue  # a block of the future
-            if d >= bk - 1:
-                full = True
-                computed += bq * bk
-                continue
-            strips = walks.setdefault(d, _row_strips(d, bq, bk, sq))
-            computed += sum(sq * width for _, width, _ in strips)
-    return _Plan(sq, tuple(sorted(walks.items())), full, computed)
+def _mask_of(plan, T: int):
+    """The mask a plan was made for (None: none)."""
+    if plan is None:
+        return None
+    return tuple(p.stairs for p in plan.parts) or causal_mask(T)
 
 
-def _causal_plan(kernel: str, bh: int, T: int, bq: int, bk: int) -> _Plan:
-    """The schedule of one causal call of `kernel`, counted
-    (flash_score_elements_total) when the call is traced."""
-    plan = _schedule(T, bq, bk, _strip_rows(kernel, bq, bk))
+def _schedule(T: int, bq: int, bk: int, sq: int, mask=None) -> _Plan:
+    """The _Plan of a [T, T] square under blocks (bq, bk), strips of sq;
+    under the causal diagonal, or under `mask`."""
+    if mask is None or mask == causal_mask(T):
+        walks, full, computed = {}, False, 0
+        for q0 in range(0, T, bq):
+            for k0 in range(0, T, bk):
+                d = q0 - k0
+                if d <= -bq:
+                    continue  # a block of the future
+                if d >= bk - 1:
+                    full = True
+                    computed += bq * bk
+                    continue
+                strips = walks.setdefault(d, _row_strips(d, bq, bk, sq))
+                computed += sum(sq * width for _, width, _ in strips)
+        return _Plan(sq, tuple(sorted(walks.items())), full, computed)
+    _check_mask(mask, T, bq, bk)
+    parts, computed = [], 0
+    for s in mask:
+        walks, full = {}, None
+        for q0 in range(s.rows[0], s.rows[1], bq):
+            for k0 in range(s.cols[0], s.cols[1], bk):
+                d = (q0 - s.rows[0]) - (k0 - s.cols[0])
+                if not s.band and d + s.reach >= bk - 1:
+                    full = d if full is None else min(full, d)
+                    computed += bq * bk
+                    continue
+                strips = walks.setdefault(
+                    d, _stair_strips(d, bq, bk, sq, s))
+                computed += sum(sq * width for _, _, width, _ in strips)
+        parts.append(_Part(s, tuple(sorted(
+            (d, w) for d, w in walks.items() if w)), full))
+    return _Plan(sq, (), any(p.full is not None for p in parts), computed,
+                 tuple(parts))
+
+
+def _masked_plan(kernel: str, bh: int, T: int, bq: int, bk: int,
+                 mask=None) -> _Plan:
+    """The schedule of one masked call of `kernel` (the causal diagonal,
+    or `mask`), counted (flash_score_elements_total) when the call is
+    traced."""
+    plan = _schedule(T, bq, bk, _strip_rows(kernel, bq, bk), mask)
     _MET_SCORES.inc(bh * T * T, kernel=kernel, part="square")
     _MET_SCORES.inc(bh * plan.computed, kernel=kernel, part="computed")
     return plan
 
 
-def _below_diagonal(s, ahead: int, q_axis: int = 0):
+def _below_diagonal(s, ahead, q_axis: int = 0):
     """Scores whose first q row lies `ahead` positions after their first
     K column, the future set to -1e30; the q rows run along `q_axis` of
-    `s` (1 in dkv's transposed tile)."""
+    `s` (1 in dkv's transposed tile).  `ahead` a _Tread: the staircase of
+    its `step` rows a tread instead, and in a band what lies before a
+    row's tread as well."""
     import jax
     import jax.numpy as jnp
 
-    lead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-            - jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
-    return jnp.where(lead <= ahead, s, -1e30)
+    if not isinstance(ahead, _Tread):
+        lead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+                - jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
+        return jnp.where(lead <= ahead, s, -1e30)
+    ahead, step, span = ahead
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    if step & (step - 1):
+        row = row - jax.lax.rem(row, step)
+    else:  # a power of two: the tread's first row by one `and`
+        row = row & -step
+    lead = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) - row
+    live = lead <= ahead
+    if span is not None:
+        live = live & (lead >= ahead - span)
+    return jnp.where(live, s, -1e30)
+
+
+def _where(plan, q0, k0):
+    """What _run_block needs to know of where a grid block lies: how far
+    its first q row lies after its first K column, and under a mask of
+    several regions both."""
+    return (q0, k0) if plan is not None and plan.parts else q0 - k0
 
 
 def _run_block(d, bq: int, bk: int, plan, strip):
     """Run the body of the grid block that lies d = q0 - k0 after the
-    diagonal, as calls of `strip(r0, rows, cols, ahead)`: the block's q
-    rows [r0, r0 + rows) against its K columns `cols`, masked where
-    `ahead`, how far row r0 lies after the first of `cols`, is not None.
-    A non-causal call (`plan` None) runs the single-shot body: the whole
-    block as one strip, unmasked.  A causal call runs nothing for a block
-    of the future; the single-shot body for a block wholly at or below
-    the diagonal (it has nothing to skip); and for a block the diagonal
-    crosses the strips of its walk, d static."""
+    diagonal (_where), as calls of `strip(r0, rows, cols, ahead)`: the
+    block's q rows [r0, r0 + rows) against its K columns `cols`, masked
+    where `ahead` (how far row r0 lies after the first of `cols`, or a
+    _Tread) is not None.
+    A call without a mask (`plan` None) runs the single-shot body: the
+    whole block as one strip, unmasked.  A masked call runs nothing for a
+    dead block; the single-shot body for a block wholly live (it has
+    nothing to skip); and for a block a staircase crosses the strips of
+    its walk, d static."""
     from jax.experimental import pallas as pl
 
     single = functools.partial(strip, 0, bq, pl.ds(0, bk))
@@ -459,10 +796,32 @@ def _run_block(d, bq: int, bk: int, plan, strip):
         for r0, width, masked in strips:
             strip(r0, plan.sq, pl.ds(0, width), off + r0 if masked else None)
 
-    if plan.full:
-        pl.when(d >= bk - 1)(single)
-    for off, strips in plan.walks:
-        pl.when(d == off)(functools.partial(walk, off, strips))
+    def stair_walk(strips):
+        for r0, c0, width, tread in strips:
+            strip(r0, plan.sq, pl.ds(c0, width), tread)
+
+    if not plan.parts:
+        if plan.full:
+            pl.when(d >= bk - 1)(single)
+        for off, strips in plan.walks:
+            pl.when(d == off)(functools.partial(walk, off, strips))
+        return
+    q0, k0 = d
+    for part in plan.parts:
+        (r_lo, r_hi), (c_lo, c_hi) = part.stairs.rows, part.stairs.cols
+        # a block lies in one region: its corner says which
+        inside = [q0 >= r_lo] * bool(r_lo) + [k0 >= c_lo] * bool(c_lo)
+        inside += [q0 < r_hi] * any(p.stairs.rows[0] >= r_hi
+                                    for p in plan.parts)
+        inside += [k0 < c_hi] * any(p.stairs.cols[0] >= c_hi
+                                    for p in plan.parts)
+        rel = _moved(q0 - k0, c_lo - r_lo)
+        when = lambda cond: pl.when(functools.reduce(
+            lambda a, b: a & b, inside + [cond]))
+        if part.full is not None:
+            when(rel >= part.full)(single)
+        for off, strips in part.walks:
+            when(rel == off)(functools.partial(stair_walk, strips))
 
 
 _LOG2E = 1.4426950408889634  # the forward's exponent is a power of two
@@ -602,7 +961,7 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 l_sc[a][...] = jnp.zeros(l_sc[a].shape, jnp.float32)
             acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
 
-    _run_block(q0 - k0, bq, bk, plan, update)
+    _run_block(_where(plan, q0, k0), bq, bk, plan, update)
 
     if not whole:
         @pl.when(kj == nk - 1)
@@ -632,7 +991,7 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
 
     pack, at = _pack(nb, D), _tile_at(nb)
     W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
-    kv_idx = _kv_idx(bq, bk, plan is not None, group, nb)
+    kv_idx = _kv_idx(bq, bk, _mask_of(plan, T), group, nb, T)
     q_idx = lambda g, i, j: at(g, i)
     in_specs = [
         pl.BlockSpec((1, bq, W), q_idx),
@@ -712,19 +1071,32 @@ def _heads_first(a, call: _Call):
     return a if call.nb else a.reshape(-1, call.T, a.shape[-1])
 
 
+def _blocks(c, causal, mask, block_q, block_k, interpret):
+    """(bq, bk) of a call, snapped; under a `mask` of its own (a tuple of
+    _Stairs, which excludes `causal`) to what its regions' edges allow."""
+    if mask is None:
+        return _snap_blocks(block_q, block_k, c.T, interpret,
+                            c.D if causal else 0)
+    if causal:
+        raise ValueError("flash attention: `causal` and a `mask` of its "
+                         "own exclude each other")
+    return _snap_blocks(block_q, block_k, c.T, interpret,
+                        unit=_mask_unit(mask, c.T))
+
+
 def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse,
-             heads=None):
+             heads=None, mask=None):
     """flash_attention's and flash_attention_fwd's shared way to _fwd_call:
     the output(s) on [B*H, T, Dv], or on [B, T, H * D] as q (`heads`)."""
     c = _call_of(q, k, v, heads)
-    bq, bk = _snap_blocks(block_q, block_k, c.T, interpret,
-                          c.D if causal else 0)
+    bq, bk = _blocks(c, causal, mask, block_q, block_k, interpret)
     s = scale if scale is not None else 1.0 / (c.D ** 0.5)
     if not s > 0:
         raise ValueError(
             f"flash attention: scale {s!r}; the forward keeps its running "
             f"max on raw scores, which takes a positive scale")
-    plan = _causal_plan("flash_fwd", c.BH, c.T, bq, bk) if causal else None
+    plan = (_masked_plan("flash_fwd", c.BH, c.T, bq, bk, mask)
+            if causal or mask else None)
     return _fwd_call(c.BH, c.T, c.D, bq, bk, plan, with_lse, q.dtype,
                      interpret, s, c.Dv, c.group, c.nb)(
         *(_heads_first(a, c) for a in (q, k, v)))
@@ -744,7 +1116,7 @@ def _group(q, k, v) -> int:
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = 512, block_k: int = 1024,
-                    interpret: bool = False, heads=None):
+                    interpret: bool = False, heads=None, mask=None):
     """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv] → [B,H,T,Dv] (Dv = D
     but in latent attention, whose keys carry rotary columns its values
     lack; the default scale is 1/sqrt(D), the width the scores contract
@@ -753,10 +1125,13 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     lies; K and V are never repeated.
     With `heads`: q, k, v [B,T,heads*D] → [B,T,heads*D], the layout the
     projections leave and the next one reads (_call_of has the contract).
+    `mask`: the live regions of the [T, T] scores where they are not the
+    causal half (`block_diffusion_mask`); what no region holds is neither
+    fetched nor computed.
     block_q/block_k are performance hints, snapped down to divisors of T;
     D ≤ 128 recommended (one lane tile)."""
     out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   False, heads)
+                   False, heads, mask)
     return out.reshape(q.shape[:3] + v.shape[3:])
 
 
@@ -822,7 +1197,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                   scale=scale, ahead=ahead)
              for a, (lse, delta) in enumerate(columns)])
 
-    _run_block(q0 - k0, bq, bk, plan, update)
+    _run_block(_where(plan, q0, k0), bq, bk, plan, update)
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -898,7 +1273,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             tile(k, v, q, do, lse_ref, dv_sc, delta_ref, dk_sc,
                  _first_lane(a, pack), cols, row, scale=scale, ahead=ahead)
 
-    _run_block(q0 - k0, bq, bk, plan, update)
+    _run_block(_where(plan, q0, k0), bq, bk, plan, update)
 
     @pl.when(step == steps - 1)
     def _finish():
@@ -907,27 +1282,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
-                        block_k=1024, interpret=False, heads=None):
+                        block_k=1024, interpret=False, heads=None,
+                        mask=None):
     """Forward that also returns the per-row logsumexp (backward
     residual), [B * H, T] in either layout."""
     out, lse = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                        True, heads)
+                        True, heads, mask)
     return out.reshape(q.shape[:3] + v.shape[3:]), lse.reshape(lse.shape[0],
                                                                -1)
 
 
 @functools.lru_cache(maxsize=None)
-def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int,
-                nb: int = 0):
+def _dkv_q_maps(T: int, bq: int, bk: int, mask, group: int, nb: int = 0):
     """(block map of q and dO, row map of lse and delta) of the dkv
     kernel's grid (K/V head b, K block j, step i).  One query head on a
     K/V head: step i is q block i.  `group` of them: the steps walk head b
     * group's q blocks, then the next head's, so head b * group + i // nq
-    and q block i % nq.  Under causal masking the q block clamps to the
-    first that attends K block j (skip-early: a skipped step re-fetches a
-    block already buffered)."""
-    import jax.numpy as jnp
-
+    and q block i % nq.  Under a mask (a tuple of _Stairs) the q block
+    clamps to a live one of K block j (_live_q_block; under causal masking
+    the first that attends it)."""
     nq = T // bq
     at = _tile_at(nb)
     if group == 1:
@@ -936,9 +1309,11 @@ def _dkv_q_maps(T: int, bq: int, bk: int, causal: bool, group: int,
     else:
         head = lambda b, i: b * group + i // nq
         block = lambda i: i % nq
-    if causal:
+    if mask is not None:
+        live = _live_q_block(mask, bq, bk, nq)
+
         def q_idx(b, j, i):
-            return at(head(b, i), jnp.maximum(block(i), (j * bk) // bq))
+            return at(head(b, i), live(j, block(i)))
     else:
         def q_idx(b, j, i):
             return at(head(b, i), block(i))
@@ -962,9 +1337,9 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
     pack, at = _pack(nb, D), _tile_at(nb)
     W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
     row_spec = pl.BlockSpec((pack, 1, T), lambda b, i, j: (b, 0, 0))
-    kv_idx = _kv_idx(bq, bk, dq_plan is not None, group, nb)
-    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, dq_plan is not None, group,
-                                   nb)
+    mask = _mask_of(dq_plan, T)
+    kv_idx = _kv_idx(bq, bk, mask, group, nb, T)
+    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, mask, group, nb)
     q_row_spec = pl.BlockSpec((pack, 1, T), q_row_idx)
     BHkv = BH // group
 
@@ -1024,12 +1399,11 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
 
 def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
                         block_q=512, block_k=1024, interpret=False,
-                        heads=None):
+                        heads=None, mask=None):
     import jax.numpy as jnp
 
     c = _call_of(q, k, v, heads)
-    bq, bk = _snap_blocks(block_q, block_k, c.T, interpret,
-                          c.D if causal else 0)
+    bq, bk = _blocks(c, causal, mask, block_q, block_k, interpret)
     s = scale if scale is not None else 1.0 / (c.D ** 0.5)
     qf, kf, vf, of, dof = (_heads_first(a, c) for a in (q, k, v, o, do))
     # a head's rows of dO * O summed over its own columns, [B * H, T]
@@ -1043,9 +1417,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
     lse3 = lse.reshape(c.BH, 1, c.T).astype(jnp.float32)
     delta3 = delta.reshape(c.BH, 1, c.T)
     dq_plan = dkv_plan = None
-    if causal:
-        dq_plan = _causal_plan("flash_bwd_dq", c.BH, c.T, bq, bk)
-        dkv_plan = _causal_plan("flash_bwd_dkv", c.BH, c.T, bq, bk)
+    if causal or mask:
+        dq_plan = _masked_plan("flash_bwd_dq", c.BH, c.T, bq, bk, mask)
+        dkv_plan = _masked_plan("flash_bwd_dkv", c.BH, c.T, bq, bk, mask)
     dq_call, dkv_call = _bwd_calls(c.BH, c.T, c.D, bq, bk, dq_plan,
                                    dkv_plan, q.dtype, interpret, s, c.Dv,
                                    c.group, c.nb)
@@ -1058,10 +1432,11 @@ _TRAIN_CACHE = {}
 
 
 def make_flash_train(causal: bool = False, scale=None, interpret=False,
-                     block_q: int = 512, block_k: int = 1024, heads=None):
+                     block_q: int = 512, block_k: int = 1024, heads=None,
+                     mask=None):
     """custom_vjp fused attention for TRAINING (honored by generic_grad's
-    jax.vjp like the recurrence kernels).  Memoized per
-    (causal, scale, interpret, blocks, heads): emitters call this on every
+    jax.vjp like the recurrence kernels).  Memoized per (causal, scale,
+    interpret, blocks, heads, mask): emitters call this on every
     trace, and a fresh wrapper each time would defeat jit's
     function-identity caching (ADVICE r2).  `heads`: the operands are
     [B, T, heads * D] (_call_of).
@@ -1074,14 +1449,14 @@ def make_flash_train(causal: bool = False, scale=None, interpret=False,
     out its logsumexp, and `.from_saved(q, k, v, out, lse) -> out`
     launches nothing forward and differentiates as the flash backward on
     the saved pair.  scaled_dot_product_attention uses both."""
-    key = (causal, scale, interpret, block_q, block_k, heads)
+    key = (causal, scale, interpret, block_q, block_k, heads, mask)
     cached = _TRAIN_CACHE.get(key)
     if cached is not None:
         return cached
     import jax
 
     kw = dict(causal=causal, scale=scale, interpret=interpret,
-              block_q=block_q, block_k=block_k, heads=heads)
+              block_q=block_q, block_k=block_k, heads=heads, mask=mask)
 
     @jax.custom_vjp
     def attn(q, k, v):

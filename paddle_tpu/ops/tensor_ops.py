@@ -67,6 +67,11 @@ def gaussian_random(ctx, ins, attrs):
     mean = float(attrs.get("mean", 0.0))
     std = float(attrs.get("std", 1.0))
     key = ctx.rng(attrs)
+    if int(attrs.get("seed", 0)):
+        # the reference's attr: a seed of its own draws the same values
+        # whatever the program's random_seed (0: the program's)
+        key = jax.random.fold_in(jax.random.PRNGKey(int(attrs["seed"])),
+                                 int(attrs.get("__uid__", 0)))
     return {"Out": [(mean + std * jax.random.normal(key, shape, dtype=jnp.float32)
                      ).astype(dt)]}
 

@@ -20,8 +20,10 @@ import functools
 from typing import Optional
 
 
-def attention(q, k, v, causal: bool = False, scale: Optional[float] = None):
-    """Dense reference: q,k,v [B, H, T, D] → [B, H, T, D]."""
+def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
+              allowed=None):
+    """Dense reference: q,k,v [B, H, T, D] → [B, H, T, D]; under the causal
+    mask, or under `allowed`, a boolean [T, T]."""
     import jax
     import jax.numpy as jnp
 
@@ -30,8 +32,9 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None):
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * s
     if causal:
         T = q.shape[2]
-        mask = jnp.tril(jnp.ones((T, T), dtype=bool))
-        logits = jnp.where(mask[None, None], logits, -1e30)
+        allowed = jnp.tril(jnp.ones((T, T), dtype=bool))
+    if allowed is not None:
+        logits = jnp.where(allowed[None, None], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", w, v)
 
