@@ -287,6 +287,14 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out)
 
 
+def _rms_gain(helper, width, dtype, param_attr=None):
+    """An RMSNorm's learned gain [width], starting at one."""
+    return helper.create_parameter(
+        attr=param_attr if isinstance(param_attr, dict) else {},
+        shape=[width], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+
+
 def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
              name=None, part=None):
     """RMSNorm (ops/llm_ops.py): input over sqrt(mean of its squares over
@@ -294,10 +302,8 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
     starts at one.  No mean subtracted, no bias.  `part` names the scope
     the op's instructions carry in a trace (`pdtpu.<part>`)."""
     helper = LayerHelper("rms_norm", name=name)
-    gain = helper.create_parameter(
-        attr=param_attr if isinstance(param_attr, dict) else {},
-        shape=[_shape_prod(input.shape[begin_norm_axis:])],
-        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    gain = _rms_gain(helper, _shape_prod(input.shape[begin_norm_axis:]),
+                     input.dtype, param_attr)
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     helper.append_op(
         "rms_norm", inputs={"X": [input.name], "Scale": [gain.name]},
@@ -329,8 +335,11 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     after the split and before any rotation, with ONE gain of that width
     for all query heads and one for all key heads (LFM2's `q_layernorm`,
     `k_layernorm`).  `rope_theta` rotates Q and K per head by their
-    position (`rope` op, rotate-half form) instead of relying on positions
-    added to the input.  `num_kv_heads` (default `num_heads`, and a divisor
+    position (rotate-half form) instead of relying on positions added to
+    the input: ONE `head_norm_rope` op each takes Q and K from the
+    projection's [B, T, heads * head_dim] to attention's [B, heads, T,
+    head_dim], the per-head norm (where asked for) and the turn inside
+    it.  `num_kv_heads` (default `num_heads`, and a divisor
     of it) is how many heads the K and V projections have: query head h
     attends to key/value head h // (num_heads / num_kv_heads)
     (grouped-query attention).  `head_dim` is a head's width where it is
@@ -345,8 +354,9 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     Where nothing per head stands between the projections and attention
     (no `rope_theta`, no `qk_norm_per_head`) the attention op takes Q, K
     and V as [B, T, heads * head_dim] (`layout` "bthd") and no `reshape`
-    or `transpose` op is emitted on either side of it; else the heads are
-    split to [B, heads, T, head_dim] first and merged after."""
+    or `transpose` op is emitted on either side of it; else V's heads are
+    split to [B, heads, T, head_dim] by a `reshape` and a `transpose` (Q's
+    and K's too where they are not rotated) and the output merged after."""
     helper = LayerHelper("multi_head_attention", name=name)
     if sp_mode not in ("ring", "alltoall"):
         raise ValueError(f"sp_mode {sp_mode!r}: use 'ring' or 'alltoall'")
@@ -392,14 +402,24 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          attrs={"axis": [0, 2, 1, 3]})
         return t
 
-    def rotate(x):
-        r = helper.create_tmp_variable(x.dtype)
-        helper.append_op("rope", inputs={"X": [x.name]},
-                         outputs={"Out": [r.name]},
-                         attrs={"theta": float(rope_theta),
-                                "part": "attn.rope",
-                                **({"period": int(block_diffusion[0])}
-                                   if block_diffusion else {})})
+    def prepared(x, heads):
+        """Q or K [B, T, heads * head_dim] -> [B, heads, T, head_dim],
+        normed per head (where asked for) and rotated, by one op."""
+        ins, attrs = {"X": [x.name]}, {
+            "num_heads": heads, "theta": float(rope_theta),
+            "part": "attn.qk_prep"}
+        if qk_norm_per_head:
+            # named as the `rms_norm` layer's gain it was: saved models and
+            # every later `rms_norm` keep their parameters' names
+            gain = _rms_gain(LayerHelper("rms_norm"), head_dim, x.dtype)
+            ins["Scale"] = [gain.name]
+            attrs["epsilon"] = qk_norm_epsilon
+        if block_diffusion:
+            attrs["period"] = int(block_diffusion[0])
+        r = helper.create_tmp_variable(
+            x.dtype, shape=(x.shape[0], heads, x.shape[1], head_dim))
+        helper.append_op("head_norm_rope", inputs=ins,
+                         outputs={"Out": [r.name]}, attrs=attrs)
         return r
 
     wide = tuple(queries.shape[:-1]) + (num_heads * head_dim,)
@@ -421,13 +441,14 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
             attrs={**sdpa_attrs, "layout": "bthd", "num_heads": num_heads,
                    "num_kv_heads": kv_heads})
     else:
-        qh, kh, vh = (split_heads(q, num_heads), split_heads(k, kv_heads),
-                      split_heads(v, kv_heads))
-        if qk_norm_per_head:
+        if rope_theta is not None:
+            qh, kh, vh = (prepared(q, num_heads), prepared(k, kv_heads),
+                          split_heads(v, kv_heads))
+        else:  # a per-head norm and no turn
+            qh, kh, vh = (split_heads(q, num_heads),
+                          split_heads(k, kv_heads), split_heads(v, kv_heads))
             qh, kh = (qk_norm(qh, begin_norm_axis=3),
                       qk_norm(kh, begin_norm_axis=3))
-        if rope_theta is not None:
-            qh, kh = rotate(qh), rotate(kh)
         attn = helper.create_tmp_variable(queries.dtype)
         helper.append_op(
             "scaled_dot_product_attention",

@@ -6,17 +6,29 @@ short convolution (LFM2's token mixer in the layers that do not attend) and
 the noising of block-diffusion training (BD3-LM's objective; first user:
 SDAR-30B-A3B).
 
-All but latent attention are plain jax.numpy, so `generic_grad` differentiates them by
-re-emission and XLA's CSE merges the re-emitted forward with the first.
-Statistics and rotations are at least float32 whatever the compute dtype
-(`wide_dtype`); the result goes back to the input's dtype."""
+All but latent attention and `head_norm_rope` are plain jax.numpy, so
+`generic_grad` differentiates them by re-emission and XLA's CSE merges the
+re-emitted forward with the first.  `head_norm_rope` (Q or K from the
+projection's layout to attention's: the per-head norm, the rotary turn and
+the head split in one pass; first users: OLMoE, LFM2, SDAR) brings its own
+grad op, whose emitter needs the forward's inputs alone and never emits the
+forward.  Statistics and rotations are at least float32 whatever the
+compute dtype (`wide_dtype`); the result goes back to the input's dtype."""
 
 from __future__ import annotations
 
 from ..observability.attribution import part_scope
 from ..observability.metrics import REGISTRY as _MET
-from .registry import register_op
+from .registry import GRAD_SUFFIX, register_op
 
+_MET_QK_PREP = _MET.counter(
+    "qk_prep_layers_traced_total",
+    "head_norm_rope ops traced (forward emission; once a compile, not once "
+    "a step; one for Q and one for K a layer), by the path the emitter took "
+    "(pallas: the kernel on [B,T,H*D] one head of 128 lanes a block; "
+    "pallas_packed: two heads of 64 a block; xla: plain jax.numpy), the "
+    "head size (head_dim), the head count (heads) and the norm in front of "
+    "the turn (norm: head, a per-head RMSNorm, or none)")
 _MET_MLA_LAYERS = _MET.counter(
     "mla_layers_traced_total",
     "latent attention layers traced (forward emission; once a compile, not "
@@ -97,6 +109,125 @@ def rope(ctx, ins, attrs):
     return {"Out": [rotate_half(ins["X"][0],
                                 float(attrs.get("theta", 10000.0)),
                                 int(attrs.get("period", 0)))]}
+
+
+def head_norm_rope_plain(x, gain, heads: int, eps, theta: float,
+                         period: int = 0):
+    """X [B, T, heads * D] -> [B, heads, T, D]: per head and row an
+    RMSNorm over the head's D columns where `eps` is given (times `gain`
+    [D], one for all heads, where given), then the rotate-half turn at the
+    row's position (`rotate_half`'s angles), at least float32 from end to
+    end with ONE rounding to X's dtype.  What the kernels of
+    ops/pallas_kernels/head_norm_rope.py compute, in plain jax.numpy."""
+    import jax
+    import jax.numpy as jnp
+
+    from .pallas_kernels.head_norm_rope import tables
+
+    B, T, width = x.shape
+    D = width // heads
+    wide = wide_dtype(x.dtype)
+    y = x.astype(wide).reshape(B, T, heads, D)
+    if eps is not None:
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    if gain is not None:
+        y = y * gain.astype(wide)
+    cos, sin = (t[None, :, None, :] for t in tables(T, D, theta, period,
+                                                    dtype=wide))
+    out = y * cos + jnp.roll(y, D // 2, axis=-1) * sin
+    return out.transpose(0, 2, 1, 3).astype(x.dtype)
+
+
+def _qk_prep(ctx, ins, attrs):
+    """(x, gain, the arguments both emissions share, the kernels' heads a
+    block or 0 where the plain emission runs) of a `head_norm_rope` op or
+    its grad op."""
+    from .pallas_kernels import head_norm_rope as kernels
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    x = ins["X"][0]
+    gain = ins["Scale"][0] if ins.get("Scale") else None
+    heads = int(attrs["num_heads"])
+    eps = attrs.get("epsilon")
+    if x.ndim != 3 or x.shape[2] % (2 * heads):
+        raise ValueError(f"head_norm_rope: X {x.shape} is not [B, T, "
+                         f"{heads} heads of an even size]")
+    if gain is not None and eps is None:
+        raise ValueError("head_norm_rope: Scale is the norm's gain: give "
+                         "epsilon")
+    kw = dict(heads=heads, eps=None if eps is None else float(eps),
+              theta=float(attrs.get("theta", 10000.0)),
+              period=int(attrs.get("period", 0)))
+    pack = kernels.pack_of(x.shape[1], x.shape[2] // heads, heads,
+                           x.dtype) if pallas_dispatch_ok(ctx) else 0
+    return x, gain, kw, pack
+
+
+def _head_norm_rope_grad_maker(op, wanted):
+    """One `head_norm_rope_grad` desc: the forward op's inputs and Out's
+    cotangent in, the wanted inputs' cotangents out, the forward's attrs
+    (its `part` and `__uid__` with them).  Not a `generic_grad`: the
+    backward needs no forward emitted again."""
+    outs = {slot + GRAD_SUFFIX: [n + GRAD_SUFFIX if n in wanted else ""
+                                 for n in names]
+            for slot, names in op.inputs.items()}
+    if not any(n for names in outs.values() for n in names):
+        return []
+    ins = {slot: list(names) for slot, names in op.inputs.items()}
+    ins["Out" + GRAD_SUFFIX] = [n + GRAD_SUFFIX for n in op.outputs["Out"]]
+    return [("head_norm_rope_grad", ins, outs, dict(op.attrs))]
+
+
+@register_op("head_norm_rope", grad=_head_norm_rope_grad_maker)
+def head_norm_rope(ctx, ins, attrs):
+    """Q (or K) from the projection's layout to attention's in one pass: X
+    [B, T, H * D] -> Out [B, H, T, D] with, per head and row, an RMSNorm
+    over the head's D columns (attr `epsilon`; absent: no norm) times
+    Scale [D] (optional; ONE gain for all heads), then the rotate-half
+    rotary turn (`rope`'s: attrs `theta`, `period`).  At least float32
+    inside, one rounding at the end.  attrs: `num_heads`.
+
+    On one TPU with heads of 128 or 64 lanes and T in 128s a Pallas kernel
+    reads each head's column block where it lies and writes it where the
+    flash kernels read it (ops/pallas_kernels/head_norm_rope.py);
+    everywhere else (the CPU, a mesh, other head sizes) plain jax.numpy
+    (`head_norm_rope_plain`)."""
+    from .pallas_kernels import head_norm_rope as kernels
+
+    x, gain, kw, pack = _qk_prep(ctx, ins, attrs)
+    if not ctx.in_grad_replay():
+        _MET_QK_PREP.inc(
+            path=("xla", "pallas", "pallas_packed")[pack],
+            head_dim=str(x.shape[2] // kw["heads"]), heads=str(kw["heads"]),
+            norm="none" if kw["eps"] is None else "head")
+    emit = kernels.head_norm_rope if pack else head_norm_rope_plain
+    return {"Out": [emit(x, gain, **kw)]}
+
+
+@register_op("head_norm_rope_grad", grad=None)
+def head_norm_rope_grad(ctx, ins, attrs):
+    """`head_norm_rope`'s backward from X, Scale and Out@GRAD alone: the
+    turn by the negative angle, the norm's backward on a recomputed row
+    statistic -> X@GRAD, Scale@GRAD.  The backward kernel where the
+    forward took its kernel, else `jax.vjp` of the plain emission (plain
+    HLO, which XLA merges with the forward's)."""
+    import jax
+
+    from .pallas_kernels import head_norm_rope as kernels
+
+    x, gain, kw, pack = _qk_prep(ctx, ins, attrs)
+    dout = ins["Out" + GRAD_SUFFIX][0].astype(x.dtype)
+    if pack:
+        dx, dgain = kernels.head_norm_rope_bwd(dout, x, gain, **kw)
+    else:
+        grads = jax.vjp(
+            lambda a, g=None: head_norm_rope_plain(a, g, **kw),
+            *((x,) if gain is None else (x, gain)))[1](dout)
+        dx, dgain = grads[0], grads[-1]   # dgain unread without a gain
+    out = {"X" + GRAD_SUFFIX: [dx]}
+    if gain is not None:
+        out["Scale" + GRAD_SUFFIX] = [dgain.astype(gain.dtype)]
+    return out
 
 
 @register_op("block_diffusion_noise", grad=None)
@@ -280,7 +411,25 @@ def _gated_short_conv_cost(ins, outs, attrs):
     return {"flops": (2 * w.shape[1] + 2) * x.size // 3}
 
 
+def _head_norm_rope_cost(ins, outs, attrs):
+    """`rope`'s count, and `rms_norm`'s where the op norms."""
+    x = ins.get("X", [None])[0]
+    if x is None or len(x.shape) != 3:
+        return {}
+    norm = 4 * x.size if attrs.get("epsilon") is not None else 0
+    return {"flops": norm + 3 * x.size
+            + x.shape[1] * x.shape[2] // int(attrs["num_heads"])}
+
+
+def _head_norm_rope_grad_cost(ins, outs, attrs):
+    """Twice the forward's, as `generic_grad` counts a backward."""
+    return {k: 2 * v for k, v in _head_norm_rope_cost(ins, outs,
+                                                      attrs).items()}
+
+
 register_cost("gated_short_conv", _gated_short_conv_cost)
+register_cost("head_norm_rope", _head_norm_rope_cost)
+register_cost("head_norm_rope_grad", _head_norm_rope_grad_cost)
 register_cost("latent_attention", _latent_attention_cost)
 register_cost("rms_norm", _rms_norm_cost)
 register_cost("rope", _rope_cost)

@@ -87,37 +87,67 @@ def _lfm2_toy():
         bias_init_scale=0.05)
 
 
-SPLIT = ["reshape", "transpose"] * 3
-# the ops of one attention layer, projections to output projection, as
-# they were before the layout existed: whole-width QK-norm then RoPE
-# (OLMoE), per-head QK-norm then RoPE (LFM2)
-HAD = {
-    "olmoe": (_olmoe_toy, 2, ["mul", "mul", "mul", "rms_norm", "rms_norm"]
-              + SPLIT + ["rope", "rope", SDPA, "transpose", "reshape",
-                         "mul"]),
-    "lfm2": (_lfm2_toy, 1, ["mul", "mul", "mul"] + SPLIT
-             + ["rms_norm", "rms_norm", "rope", "rope", SDPA, "transpose",
-                "reshape", "mul"]),
+PREP = "head_norm_rope"
+# the ops of one attention layer, projections to output projection: ONE op
+# takes Q, and one K, from the projection's layout to attention's (PR 38:
+# in place of a reshape, a transpose, a per-head `rms_norm` and a `rope`
+# each); V's split and the output's merge are the ops they were.
+# Whole-width QK-norm then the turn (OLMoE), per-head QK-norm inside the op
+# (LFM2)
+HAS = {
+    "olmoe": (_olmoe_toy, 2, ["mul", "mul", "mul", "rms_norm", "rms_norm",
+                              PREP, PREP, "reshape", "transpose", SDPA,
+                              "transpose", "reshape", "mul"]),
+    "lfm2": (_lfm2_toy, 1, ["mul", "mul", "mul", PREP, PREP, "reshape",
+                            "transpose", SDPA, "transpose", "reshape",
+                            "mul"]),
 }
 
 
-@pytest.mark.parametrize("model", list(HAD))
-def test_rope_and_per_head_norm_keep_the_ops_they_had(model):
-    """Something per head between projection and attention: the layer
-    emits exactly the ops it did, and the attention op's desc carries no
+@pytest.mark.parametrize("model", list(HAS))
+def test_rope_and_per_head_norm_are_one_op_for_q_and_one_for_k(model):
+    """Something per head between projection and attention: no `rope`, no
+    per-head `rms_norm`, no reshape or transpose for Q and K; V's split and
+    the output's merge unchanged, and the attention op's desc carries no
     new attr (its default layout is what every old desc means)."""
-    build, layers, had = HAD[model]
+    build, layers, has = HAS[model]
     build()
     ops = _forward_ops()
+    made_by = {n: op for op in ops for n in op.output_names()}
     attend = [i for i, op in enumerate(ops) if op.type == SDPA]
     assert len(attend) == layers
-    at = had.index(SDPA)
+    assert not [op for op in ops if op.type == "rope"]
+    at = has.index(SDPA)
     for i in attend:
-        assert _around(ops, i, at, len(had) - at - 1) == had
+        assert _around(ops, i, at, len(has) - at - 1) == has
         assert sorted(k for k in ops[i].attrs if not k.startswith("__")) \
             == ["causal", "sp_mode", "sp_schedule"]
-        assert ops[i - 1].type == "rope"
+        for slot in ("Q", "K"):
+            prep = made_by[ops[i].inputs[slot][0]]
+            assert prep.type == PREP and prep.attrs["part"] == "attn.qk_prep"
+            # straight from the projection (OLMoE: from its whole-width norm)
+            assert made_by[prep.inputs["X"][0]].type in ("mul", "rms_norm")
+            assert ("Scale" in prep.inputs) == (model == "lfm2") == (
+                "epsilon" in prep.attrs)
+        split = made_by[ops[i].inputs["V"][0]]
+        assert (split.type, split.attrs["axis"]) == ("transpose",
+                                                     [0, 2, 1, 3])
+        assert made_by[made_by[split.inputs["X"][0]].inputs["X"][0]].type \
+            == "mul"
         assert ops[i + 1].attrs["axis"] == [0, 2, 1, 3]
+
+
+def test_a_per_head_norm_without_rope_keeps_the_ops_it_had():
+    """No `rope_theta`: the program is the parent's op for op (the split
+    of Q, K and V, then a `rms_norm` on Q's heads and one on K's)."""
+    fluid.reset()
+    x = fluid.layers.data("x", shape=[16, 32], dtype="float32")
+    fluid.layers.multi_head_attention(x, x, x, 4, causal=True,
+                                      qk_norm_epsilon=1e-5,
+                                      qk_norm_per_head=True, num_kv_heads=2)
+    assert [op.type for op in _forward_ops()] == (
+        ["mul"] * 3 + ["reshape", "transpose"] * 3 + ["rms_norm"] * 2
+        + [SDPA, "transpose", "reshape", "mul"])
 
 
 # ---------------------------------------------------------------------------

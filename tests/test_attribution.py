@@ -141,8 +141,10 @@ def test_toy_lm_names_grads_parts_head_and_loss():
     assert "pdop__mul_grad__u" in joined
     assert "pdop__generic_grad__u" not in joined
     # an op with `part` carries it forward AND backward (the layer's own:
-    # rope and the QK-norm; the guard's: the head and the loss)
-    for part, fwd, bwd in (("attn.rope", "rope", "rope_grad"),
+    # Q's and K's turn, whose grad op is a desc op of its own type, and the
+    # QK-norm; the guard's: the head and the loss)
+    for part, fwd, bwd in (("attn.qk_prep", "head_norm_rope",
+                            "head_norm_rope_grad"),
                            ("attn.qk_norm", "rms_norm", "rms_norm_grad"),
                            ("lm.head", "mul", "mul_grad"),
                            ("lm.loss", "softmax_with_cross_entropy",

@@ -327,6 +327,23 @@ def _lowered_step(loss, device, batch, seq_len):
             sds((2,), np.uint32))
 
 
+def _kernel_calls(text):
+    """{kernel: Mosaic calls} of a compiled step's text, by the kernels'
+    names (the longest first: a name is another's head)."""
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    kinds = {}
+    for name in calls:
+        for kernel in ("head_norm_rope_bwd", "head_norm_rope",
+                       "flash_bwd_dq", "flash_bwd_dkv", "flash_fwd"):
+            if kernel in name:
+                kinds[kernel] = kinds.get(kernel, 0) + 1
+                break
+        else:
+            kinds[name] = 1
+    return kinds
+
+
 def test_aot_one_forward_kernel_a_layer(v5e):
     """A 2-layer LM at T 1024 and head size 64: the compiled step holds one
     forward, one dq and one dkv Mosaic call a layer (it held two forwards),
@@ -344,21 +361,56 @@ def test_aot_one_forward_kernel_a_layer(v5e):
         r"stablehlo\.transpose[^\n]*tensor<2x(?:1024x2|2x1024)x64xbf16>",
         lowered.as_text())
     assert not relayouts, relayouts
-    text = lowered.compile().as_text()
-    calls = re.findall(
-        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    kinds = {}
-    for name in calls:
-        for kernel in ("flash_bwd_dq", "flash_bwd_dkv", "flash_fwd"):
-            if kernel in name:
-                kinds[kernel] = kinds.get(kernel, 0) + 1
-                break
-        else:
-            kinds[name] = 1
+    kinds = _kernel_calls(lowered.compile().as_text())
     assert kinds == {"flash_fwd": layers, "flash_bwd_dq": layers,
-                     "flash_bwd_dkv": layers}, calls
+                     "flash_bwd_dkv": layers}, kinds
     assert _counter() == {(SDPA, "1"): float(layers)}
     assert _paths() == {("bthd", "flash_packed"): float(layers)}
+
+
+@pytest.mark.parametrize("head_dim,kv_heads,path", [
+    (128, 1, "pallas"), (64, 2, "pallas_packed")],
+    ids=["heads_of_128", "pairs_of_64"])
+def test_aot_one_qk_prep_kernel_each_way_for_q_and_for_k(v5e, head_dim,
+                                                         kv_heads, path):
+    """A 2-layer decoder with RoPE and a per-head QK-norm at T 1024, four
+    query heads on `kv_heads`: the compiled step holds ONE `head_norm_rope`
+    call for Q and one for K a layer and as many backward calls (the grad
+    op launches no forward), beside the three flash kernels;
+    `executor_grad_kernel_forward_total` keeps the attention op's series
+    alone; of the transposes between [B, T, H, D] and [B, H, T, D] in the
+    step JAX hands to XLA, V's and the output's are left."""
+    from paddle_tpu.models import transformer as tr
+
+    layers, heads, T = 2, 4, 1024
+    fluid.reset()
+    tokens = fluid.layers.data("tokens", shape=[T, 1], dtype="int64")
+    targets = fluid.layers.data("targets", shape=[T, 1], dtype="int64")
+    logits = tr.decoder_lm(
+        tokens, 512, heads * head_dim, layers, heads, max_len=T,
+        dtype="bfloat16", norm="rms_norm", positions="rope",
+        rope_theta=1e6, qk_norm="head", n_kv_heads=kv_heads)
+    loss = tr.lm_loss(logits, targets)
+    fluid.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+    lowered = _lowered_step(loss, v5e, batch=2, seq_len=T)
+    def moved(n):  # transposes to or from [B, n, T, D] in the step
+        return len(re.findall(
+            r"stablehlo\.transpose[^\n]*-> tensor<2x(?:1024x%dx%d|%dx1024x%d)"
+            r"xbf16>" % (n, head_dim, n, head_dim), lowered.as_text()))
+
+    # the output's merge and its gradient; V's split and its gradient:
+    # nothing of Q's or K's (it was three times as many)
+    assert (moved(heads), moved(kv_heads)) == (2 * layers, 2 * layers)
+    kinds = _kernel_calls(lowered.compile().as_text())
+    assert kinds == {"head_norm_rope": 2 * layers,
+                     "head_norm_rope_bwd": 2 * layers, "flash_fwd": layers,
+                     "flash_bwd_dq": layers, "flash_bwd_dkv": layers}, kinds
+    assert _counter() == {(SDPA, "1"): float(layers)}
+    fam = obs.REGISTRY.snapshot()["families"]["qk_prep_layers_traced_total"]
+    assert {(s["labels"]["path"], s["labels"]["heads"]): s["value"]
+            for s in fam["series"]} == {
+        (path, str(heads)): float(layers),
+        (path, str(kv_heads)): float(layers)}
 
 
 # B, H, T, D, Dv (and, where K and V have fewer heads, Hkv) of the cells'

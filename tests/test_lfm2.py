@@ -226,7 +226,8 @@ def test_gated_short_conv_layer_and_counter():
 def test_multi_head_attention_per_head_qk_norm_and_kv_heads():
     """num_kv_heads narrows the K and V projections; qk_norm_per_head puts
     ONE gain of the head's width on Q and one on K, after the split and
-    before RoPE; the result is attention computed by hand."""
+    before RoPE, all inside Q's and K's `head_norm_rope` op; the result is
+    attention computed by hand."""
     import jax
     import jax.numpy as jnp
 
@@ -242,11 +243,13 @@ def test_multi_head_attention_per_head_qk_norm_and_kv_heads():
     assert [tuple(p.shape) for p in params] == [
         (D, D), (D, KV * d), (D, KV * d), (d,), (d,), (D, D)]
     ops = [op.type for op in main.global_block().ops]
-    assert ops.index("rope") > max(i for i, o in enumerate(ops)
-                                   if o == "rms_norm")
-    parts = [op.attrs.get("part") for op in main.global_block().ops
-             if op.type in ("rms_norm", "rope")]
-    assert parts == ["attn.qk_norm"] * 2 + ["attn.rope"] * 2
+    assert "rope" not in ops and "rms_norm" not in ops
+    prep = [op for op in main.global_block().ops
+            if op.type == "head_norm_rope"]
+    assert [(op.attrs["num_heads"], op.attrs["epsilon"], op.attrs["theta"],
+             op.attrs["part"], op.inputs["Scale"]) for op in prep] == [
+        (H, eps, theta, "attn.qk_prep", [params[3].name]),
+        (KV, eps, theta, "attn.qk_prep", [params[4].name])]
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     scope = fluid.global_scope()
@@ -348,11 +351,13 @@ def test_lfm2_program_is_built_from_the_new_layers():
     fwd = ops[:ops.index("generic_grad")]
     assert fwd.count("gated_short_conv") == 4
     assert fwd.count("scaled_dot_product_attention") == 1
-    assert fwd.count("rope") == 2                  # the attention layer only
+    assert fwd.count("head_norm_rope") == 2        # the attention layer only
+    assert "rope" not in fwd
     assert fwd.count("moe") == 4
     assert "moe_sequence_balance_loss" not in fwd  # no auxiliary loss
     assert "layer_norm" not in fwd and "slice" not in fwd
-    assert fwd.count("rms_norm") == 2 * 5 + 2 + 1
+    # two a block and the final one; Q's and K's are inside head_norm_rope
+    assert fwd.count("rms_norm") == 2 * 5 + 1
     (moe,) = {repr(sorted((k, v) for k, v in op.attrs.items()
                           if not k.startswith("__")))
               for op in main.global_block().ops if op.type == "moe"}
@@ -479,10 +484,12 @@ def test_decoder_lm_serving_still_refuses_every_block_but_gpt2s():
 # aaa7b10`, the parent of PR 33 (CHANGES.md, PR 33, has them with x64 off
 # too, where the first two are PR 31's).  GPT-2's is PR 36's, which moved
 # that tower's head split from desc ops into the attention op's emitter on
-# purpose (7723a900...04dc95 until then); OLMoE's and Moonlight's stand.
+# purpose (7723a900...04dc95 until then); OLMoE's is PR 38's, which put
+# Q's and K's head split and turn into one op (`head_norm_rope`) on purpose
+# (826a329c...70c32ba until then); Moonlight's stands.
 PARENTS = {
     "gpt2": "ed922119d4ec49dd0c3f94be6df75340099a667662de0d6270d2933b074992eb",
-    "olmoe": "826a329cfa2262bd49464010cda7b3140bc8268480dfe2ded5ac1a7e170c32ba",
+    "olmoe": "5f5f6b496f58afc4eada129e5b397446423c1b89cd930b7b61c87f76210d14f0",
     "moonlight":
         "6dfbeb70dbad6033c1550246370189d715c90c8ee4197d48ce272d74f6f86969"}
 
@@ -491,7 +498,7 @@ PARENTS = {
 def test_lowered_steps_of_the_old_towers_are_the_parents(tower):
     """With one head count, no `layer_types` and `renorm_epsilon` unset the
     GPT-2, OLMoE and Moonlight toy towers lower byte for byte to the
-    parent's steps."""
+    steps recorded above."""
     fluid.reset()
     if tower == "gpt2":
         loss = tr.build_lm_train_program(64, vocab_size=64, dim=32,

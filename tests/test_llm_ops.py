@@ -1,6 +1,7 @@
-"""rms_norm, rope, QK-norm and the dropless form of the `moe` op, each against
-plain jax.numpy: outputs directly, gradients through `generic_grad` (the
-numeric sweep of tests/op_test.py, and a dense evaluation under jax.grad).
+"""rms_norm, rope, head_norm_rope, QK-norm and the dropless form of the `moe`
+op, each against plain jax.numpy: outputs directly, gradients through
+`generic_grad` or the op's own grad op (the numeric sweep of
+tests/op_test.py, and a dense evaluation under jax.grad).
 ops/llm_ops.py, ops/moe_ops.py, layers/nn.py."""
 
 import numpy as np
@@ -146,6 +147,174 @@ def test_qk_norm_and_rope_inside_multi_head_attention():
     want = jax.grad(lambda ps: jnp.mean(jnp.square(ref(ps))))(vals)
     for g, w, p in zip(got[1:], want, params):
         np.testing.assert_allclose(g, w, atol=2e-6, err_msg=p.name)
+
+
+# ---------------------------------------------------------------------------
+# head_norm_rope: Q or K from the projection's layout to attention's, the
+# per-head norm and the turn inside (PR 38)
+
+
+def _chain_numpy(x, gain, heads, eps, theta, period=0):
+    """What the layer emitted before the op: split the heads, `rms_norm`
+    over a head's columns, `rope`.  float64."""
+    B, T, W = x.shape
+    y = x.astype(np.float64).reshape(B, T, heads, W // heads)
+    y = y.transpose(0, 2, 1, 3)
+    if eps is not None:
+        y = y / np.sqrt((y * y).mean(-1, keepdims=True) + eps)
+    if gain is not None:
+        y = y * gain
+    if period:
+        return np.concatenate(
+            [_rope_numpy(y[:, :, i:i + period], theta)
+             for i in range(0, T, period)], axis=2)
+    return _rope_numpy(y, theta)
+
+
+PREP_CASES = {
+    # heads, head size, epsilon, a gain, period
+    "norm_and_gain": (3, 8, 1e-5, True, 0),
+    "norm_without_gain": (2, 8, 1e-5, False, 0),
+    "turn_alone": (4, 4, None, False, 0),
+    "norm_gain_period": (2, 8, 1e-6, True, 3),
+    "one_head": (1, 16, 1e-5, True, 0),
+}
+
+
+def _prep_case(case, T=6):
+    heads, d, eps, gained, period = PREP_CASES[case]
+    ins = {"X": _r(2, T, heads * d, seed=len(case))}
+    if gained:
+        ins["Scale"] = _r(d, lo=0.5, hi=1.5, seed=1)
+    attrs = {"num_heads": heads, "theta": 100.0}
+    if eps is not None:
+        attrs["epsilon"] = eps
+    if period:
+        attrs["period"] = period
+    want = _chain_numpy(ins["X"], ins.get("Scale"), heads, eps, 100.0,
+                        period)
+    return ins, attrs, want
+
+
+@pytest.mark.parametrize("case", list(PREP_CASES))
+def test_head_norm_rope_output_and_grad(case):
+    """The op against the chain it stands for, and its own grad op (X and
+    Scale) against central differences."""
+    ins, attrs, want = _prep_case(case)
+    h = OpTestHarness("head_norm_rope", ins, attrs)
+    h.check_output({"Out": want}, atol=1e-5)
+    h.check_grad(sorted(ins), max_relative_error=1e-2)
+
+
+@pytest.mark.parametrize("case", list(PREP_CASES))
+def test_head_norm_rope_plain_is_the_old_chain_in_float32(case):
+    """`head_norm_rope_plain` against `rms` then `rotate_half` on the split
+    heads, as the layer's three ops ran them, in float32: the result and,
+    under jax.vjp, dX and dScale."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import llm_ops
+
+    heads, d, eps, gained, period = PREP_CASES[case]
+    ins, _, _ = _prep_case(case)
+    x = jnp.asarray(ins["X"], jnp.float32)
+    g = jnp.asarray(ins["Scale"], jnp.float32) if gained else None
+    B, T, _ = x.shape
+
+    def chain(x, g):
+        y = x.reshape(B, T, heads, d).transpose(0, 2, 1, 3)
+        if eps is not None:
+            y = llm_ops.rms(y, eps, (3,), g)
+        return llm_ops.rotate_half(y, 100.0, period)
+
+    def plain(x, g):
+        return llm_ops.head_norm_rope_plain(x, g, heads, eps, 100.0, period)
+
+    with jax.enable_x64(False):
+        want, back = jax.vjp(chain, x, g)
+        got, back_plain = jax.vjp(plain, x, g)
+        assert got.dtype == jnp.float32 and got.shape == (B, heads, T, d)
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+        dout = jnp.asarray(_r(B, heads, T, d, seed=9), jnp.float32)
+        for a, b in zip(back_plain(dout), back(dout)):
+            if b is not None and a is not None:
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
+
+
+def test_head_norm_rope_rounds_bf16_once():
+    """A bf16 input is normed and turned in float32 and rounded ONCE: the
+    result is the float32 result's nearest bf16, where the chain of two
+    ops (each rounding its own) is not always."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import llm_ops
+
+    heads, d, T = 2, 64, 8
+    x = jnp.asarray(_r(1, T, heads * d, seed=4), jnp.bfloat16)
+    g = jnp.asarray(_r(d, lo=0.5, hi=1.5, seed=5), jnp.bfloat16)
+    got = llm_ops.head_norm_rope_plain(x, g, heads, 1e-6, 1e4)
+    assert got.dtype == jnp.bfloat16
+    wide = llm_ops.head_norm_rope_plain(
+        x.astype(jnp.float32), g.astype(jnp.float32), heads, 1e-6, 1e4)
+    assert got.tobytes() == wide.astype(jnp.bfloat16).tobytes()
+    chain = llm_ops.rotate_half(llm_ops.rms(
+        x.reshape(1, T, heads, d).transpose(0, 2, 1, 3), 1e-6, (3,), g), 1e4)
+    twice = np.asarray(chain, np.float32)
+    once = np.asarray(got, np.float32)
+    exact = np.asarray(wide, np.float32)
+    assert np.abs(once - exact).max() <= np.abs(twice - exact).max()
+    assert np.abs(once - exact).max() <= 2 ** -8 * np.abs(exact).max()
+
+
+def test_head_norm_rope_grad_is_a_desc_op_of_its_own():
+    """append_backward gives the op ONE `head_norm_rope_grad` desc (X,
+    Scale, Out@GRAD in; X@GRAD, Scale@GRAD out; the forward's attrs, uid
+    and part), not a `generic_grad`: nothing re-emits the forward."""
+    fluid.reset()
+    x = fluid.layers.data("x", shape=[8, 16], dtype="float32")
+    out = fluid.layers.multi_head_attention(
+        x, x, x, 4, causal=True, qk_norm_epsilon=1e-5, rope_theta=100.0,
+        qk_norm_per_head=True, num_kv_heads=2)
+    fluid.append_backward(fluid.layers.mean(out))
+    ops = fluid.default_main_program().global_block().ops
+    fwd = [op for op in ops if op.type == "head_norm_rope"]
+    bwd = [op for op in ops if op.type == "head_norm_rope_grad"]
+    assert len(fwd) == len(bwd) == 2
+    assert not [op for op in ops if op.type == "generic_grad"
+                and op.attrs["__fwd_type__"] == "head_norm_rope"]
+    for f, b in zip(fwd, reversed(bwd)):
+        assert b.attrs == f.attrs and b.attrs["part"] == "attn.qk_prep"
+        assert b.inputs == {**f.inputs,
+                            "Out@GRAD": [f.outputs["Out"][0] + "@GRAD"]}
+        assert sorted(b.outputs) == ["Scale@GRAD", "X@GRAD"]
+        assert all(n.startswith(f.inputs[slot[:-5]][0])
+                   for slot, (n,) in b.outputs.items())
+
+
+def test_head_norm_rope_refuses_what_it_cannot_split():
+    with pytest.raises(Exception, match="heads of an even size"):
+        OpTestHarness("head_norm_rope", {"X": _r(1, 4, 10)},
+                      {"num_heads": 3}).fetch()
+    with pytest.raises(Exception, match="epsilon"):
+        OpTestHarness("head_norm_rope",
+                      {"X": _r(1, 4, 8), "Scale": _r(4)},
+                      {"num_heads": 2}).fetch()
+
+
+def test_head_norm_rope_cost_is_the_two_ops_it_replaces():
+    from paddle_tpu.ops.registry import ShapeDtype, get_op_info
+
+    x = ShapeDtype((2, 16, 4 * 8))
+    rope = get_op_info("rope").cost({"X": [ShapeDtype((2, 4, 16, 8))]}, {},
+                                    {})["flops"]
+    norm = get_op_info("rms_norm").cost({"X": [x]}, {}, {})["flops"]
+    cost = get_op_info("head_norm_rope").cost
+    assert cost({"X": [x]}, {}, {"num_heads": 4})["flops"] == rope
+    assert cost({"X": [x]}, {}, {"num_heads": 4,
+                                 "epsilon": 1e-5})["flops"] == rope + norm
+    assert get_op_info("head_norm_rope_grad").cost(
+        {"X": [x]}, {}, {"num_heads": 4})["flops"] == 2 * rope
 
 
 # ---------------------------------------------------------------------------

@@ -136,7 +136,11 @@ def test_olmoe_program_is_built_from_the_new_layers():
     fwd = fwd[:fwd.index("generic_grad")] if "generic_grad" in fwd else fwd
     assert "layer_norm" not in fwd and "gelu" not in fwd
     assert fwd.count("rms_norm") == 2 * 4 + 1      # 2 a block + q, k; final
-    assert fwd.count("rope") == 2 * 2              # q and k a layer
+    assert fwd.count("head_norm_rope") == 2 * 2    # q and k a layer
+    assert "rope" not in fwd
+    assert ["epsilon" in op.attrs or "Scale" in op.inputs
+            for op in main.global_block().ops
+            if op.type == "head_norm_rope"] == [False] * 4
     assert fwd.count("moe") == 2 and fwd.count("moe_router_loss") == 2
     assert fwd.count("scaled_dot_product_attention") == 2
     for op in main.global_block().ops:

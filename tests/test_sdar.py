@@ -388,7 +388,8 @@ def test_rope_with_a_period_is_rotate_half_on_positions_mod_the_period():
 def test_multi_head_attention_with_a_head_dim_of_its_own_and_the_mask():
     """head_dim 16 on a model whose hidden / heads is 8: Q is D -> H *
     head_dim, the output projection H * head_dim -> D; under
-    `block_diffusion` the op carries the mask attrs and RoPE the period;
+    `block_diffusion` the op carries the mask attrs and Q's and K's
+    `head_norm_rope` the period;
     the result is attention computed by hand."""
     B, L, b, D, H, KV, d, eps, theta = 2, 6, 2, 16, 2, 1, 16, 1e-6, 100.0
     T = 2 * L
@@ -407,8 +408,10 @@ def test_multi_head_attention_with_a_head_dim_of_its_own_and_the_mask():
     assert (sdpa.attrs["mask"], sdpa.attrs["seq_len"],
             sdpa.attrs["block_length"], sdpa.attrs["causal"]) == (
         "block_diffusion", L, b, False)
-    assert [op.attrs["period"] for op in main.global_block().ops
-            if op.type == "rope"] == [L, L]
+    assert [(op.attrs["period"], op.attrs["num_heads"], op.attrs["epsilon"])
+            for op in main.global_block().ops
+            if op.type == "head_norm_rope"] == [(L, H, eps), (L, KV, eps)]
+    assert not [op for op in main.global_block().ops if op.type == "rope"]
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     scope = fluid.global_scope()
