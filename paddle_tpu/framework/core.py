@@ -387,8 +387,12 @@ class Program:
         `part` of its own gets `part` = `name` (`lm.head`): the executor
         lowers it, and its grad op, inside the scope `pdtpu.<name>`
         (observability/attribution.py), so a trace says which part of the
-        model an instruction belongs to."""
-        outer, self._part = self._part, name
+        model an instruction belongs to.  Guards nest: inside an outer
+        guard the part is `<outer>/<name>`, lowered as the scope
+        `pdtpu.<name>` inside `pdtpu.<outer>` (a module's own head: `lm.head`
+        inside `mtp.head`)."""
+        outer = self._part
+        self._part = f"{outer}/{name}" if outer else name
         try:
             yield
         finally:

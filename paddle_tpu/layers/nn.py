@@ -521,14 +521,20 @@ def gated_short_conv(input, kernel_size=3, param_attr=None, name=None):
 
 def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
                      v_dim, rope_theta=10000.0, epsilon=1e-5,
-                     param_attr=None, name=None):
+                     param_attr=None, name=None, q_rank=None, yarn=None):
     """Causal multi-head latent attention over [B, T, D] (DeepSeek-V2's
-    MLA, the form without a query latent; ops/llm_ops.py latent_attention
-    has the equations): keys and values come from a latent of `kv_rank`
-    columns with an RMSNorm of its own, one rotary key of `qk_rope_dim`
-    columns is shared by all heads, queries and keys are `qk_nope_dim` +
-    `qk_rope_dim` wide and values `v_dim`.  Five parameters, in creation
-    order: WQ, WKVA, the latent norm's gain, WKVB, WO; no bias."""
+    MLA; ops/llm_ops.py latent_attention has the equations): keys and
+    values come from a latent of `kv_rank` columns with an RMSNorm of its
+    own, one rotary key of `qk_rope_dim` columns is shared by all heads,
+    queries and keys are `qk_nope_dim` + `qk_rope_dim` wide and values
+    `v_dim`.  Five parameters, in creation order: WQ, WKVA, the latent
+    norm's gain, WKVB, WO; no bias.  With `q_rank` the queries come from a
+    latent of that many columns with an RMSNorm of its own (DeepSeek-V3's
+    `q_lora_rank`): WQA, its gain and WQB stand where WQ stood, seven
+    parameters.  `yarn` = {"factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} (a published
+    `rope_scaling` of type yarn) blends the rotary frequencies and sets
+    the softmax scale."""
     helper = LayerHelper("latent_attention", name=name)
     D = input.shape[-1]
     attr = param_attr if isinstance(param_attr, dict) else {}
@@ -537,26 +543,145 @@ def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
         return helper.create_parameter(attr=attr, shape=shape,
                                        dtype=input.dtype)
 
-    wq = weight([D, num_heads * (qk_nope_dim + qk_rope_dim)])
-    wkva = weight([D, kv_rank + qk_rope_dim])
-    gain = helper.create_parameter(
-        attr={}, shape=[kv_rank], dtype=input.dtype,
-        default_initializer=ConstantInitializer(1.0))
-    wkvb = weight([kv_rank, num_heads * (qk_nope_dim + v_dim)])
-    wo = weight([num_heads * v_dim, D])
+    def gain(width):
+        return helper.create_parameter(
+            attr={}, shape=[width], dtype=input.dtype,
+            default_initializer=ConstantInitializer(1.0))
+
+    q_width = num_heads * (qk_nope_dim + qk_rope_dim)
+    ins = {"X": [input.name]}
+    if q_rank:
+        ins["WQA"] = [weight([D, q_rank]).name]
+        ins["QNorm"] = [gain(q_rank).name]
+        ins["WQB"] = [weight([q_rank, q_width]).name]
+    else:
+        ins["WQ"] = [weight([D, q_width]).name]
+    ins["WKVA"] = [weight([D, kv_rank + qk_rope_dim]).name]
+    ins["KVNorm"] = [gain(kv_rank).name]
+    ins["WKVB"] = [weight([kv_rank, num_heads * (qk_nope_dim + v_dim)]).name]
+    ins["WO"] = [weight([num_heads * v_dim, D]).name]
+    attrs = {"num_heads": int(num_heads), "qk_nope_dim": int(qk_nope_dim),
+             "qk_rope_dim": int(qk_rope_dim), "v_dim": int(v_dim),
+             "theta": float(rope_theta), "epsilon": float(epsilon)}
+    if yarn:
+        attrs.update(
+            yarn_factor=float(yarn["factor"]),
+            yarn_original_max=int(yarn["original_max_position_embeddings"]),
+            yarn_beta_fast=float(yarn.get("beta_fast", 32)),
+            yarn_beta_slow=float(yarn.get("beta_slow", 1)),
+            yarn_mscale=float(yarn.get("mscale", 1)),
+            yarn_mscale_all_dim=float(yarn.get("mscale_all_dim", 0)))
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
-    helper.append_op(
-        "latent_attention",
-        inputs={"X": [input.name], "WQ": [wq.name], "WKVA": [wkva.name],
-                "KVNorm": [gain.name], "WKVB": [wkvb.name],
-                "WO": [wo.name]},
-        outputs={"Out": [out.name]},
-        attrs={"num_heads": int(num_heads), "qk_nope_dim": int(qk_nope_dim),
-               "qk_rope_dim": int(qk_rope_dim), "v_dim": int(v_dim),
-               "theta": float(rope_theta), "epsilon": float(epsilon)})
+    helper.append_op("latent_attention", inputs=ins,
+                     outputs={"Out": [out.name]}, attrs=attrs)
     from .sequence import propagate_length
 
     return propagate_length(input, out)
+
+
+def mtp_project(hidden, next_embedding, epsilon=1e-5, param_attr=None,
+                name=None):
+    """A multi-token-prediction module's way in (DeepSeek-V3,
+    arXiv:2412.19437, section 2.2; ops/llm_ops.py mtp_project): W
+    [RMSNorm(hidden) ; RMSNorm(next_embedding)] over [B, T, D] each -> [B,
+    T, D].  Three parameters, in creation order: the two norms' gains and
+    W [2 D, D] (`param_attr`); no bias."""
+    helper = LayerHelper("mtp_project", name=name)
+    D = hidden.shape[-1]
+    gains = [helper.create_parameter(
+        attr={}, shape=[D], dtype=hidden.dtype,
+        default_initializer=ConstantInitializer(1.0)) for _ in range(2)]
+    w = helper.create_parameter(
+        attr=param_attr if isinstance(param_attr, dict) else {},
+        shape=[2 * D, D], dtype=hidden.dtype)
+    out = helper.create_tmp_variable(hidden.dtype, shape=hidden.shape)
+    helper.append_op(
+        "mtp_project",
+        inputs={"H": [hidden.name], "E": [next_embedding.name],
+                "HNorm": [gains[0].name], "ENorm": [gains[1].name],
+                "W": [w.name]},
+        outputs={"Out": [out.name]},
+        attrs={"epsilon": float(epsilon), "depth": 1})
+    return out
+
+
+def hyper_connection_pre(streams, sinkhorn_iters=20, epsilon=1e-6,
+                         norm_epsilon=1e-6, clamp=(-30.0, 30.0),
+                         param_attr=None, alpha_attr=None, beta_attr=None,
+                         name=None):
+    """What one sub-layer reads of the n residual streams [B, n, T, C]
+    (manifold-constrained hyper-connections; ops/llm_ops.py
+    hyper_connection_pre has the equations) -> (u [B, T, C], the
+    sub-layer's input before its own norm; h_post, h_res: what
+    `hyper_connection_post` writes its result back through).  Five
+    parameters of the sub-layer's own, in creation order: PhiPre, PhiPost
+    [n C, n], PhiRes [n C, n n] (`param_attr`), Alpha [3] (`alpha_attr`;
+    mHC's 0.01 by default) and Beta [n + n + n n] (`beta_attr`; zeros)."""
+    helper = LayerHelper("hyper_connection", name=name)
+    B, n, T, C = streams.shape
+
+    def param(attr, shape, default=None):
+        return helper.create_parameter(
+            attr=attr if isinstance(attr, dict) else {}, shape=shape,
+            dtype=streams.dtype, default_initializer=default)
+
+    ins = {"X": [streams.name]}
+    for slot, cols in (("PhiPre", n), ("PhiPost", n), ("PhiRes", n * n)):
+        ins[slot] = [param(param_attr, [n * C, cols]).name]
+    ins["Alpha"] = [param(alpha_attr, [3], ConstantInitializer(0.01)).name]
+    ins["Beta"] = [param(beta_attr, [(2 + n) * n],
+                         ConstantInitializer(0.0)).name]
+    u = helper.create_tmp_variable(streams.dtype, shape=(B, T, C))
+    h_post = helper.create_tmp_variable("float32", shape=(B, T, n))
+    h_res = helper.create_tmp_variable("float32", shape=(B, T, n, n))
+    helper.append_op(
+        "hyper_connection_pre", inputs=ins,
+        outputs={"U": [u.name], "HPost": [h_post.name],
+                 "HRes": [h_res.name]},
+        attrs={"streams": int(n), "sinkhorn_iters": int(sinkhorn_iters),
+               "epsilon": float(epsilon),
+               "norm_epsilon": float(norm_epsilon),
+               "clamp_min": float(clamp[0]), "clamp_max": float(clamp[1])})
+    return u, h_post, h_res
+
+
+def hyper_connection_post(streams, y, h_post, h_res, name=None):
+    """The sub-layer's result `y` [B, T, C] written back into the streams
+    [B, n, T, C]: stream i = sum_j h_res[i, j] stream j + h_post[i] y
+    (ops/llm_ops.py hyper_connection_post)."""
+    helper = LayerHelper("hyper_connection", name=name)
+    out = helper.create_tmp_variable(streams.dtype, shape=streams.shape)
+    helper.append_op(
+        "hyper_connection_post",
+        inputs={"X": [streams.name], "Y": [y.name], "HPost": [h_post.name],
+                "HRes": [h_res.name]},
+        outputs={"Out": [out.name]})
+    return out
+
+
+def hyper_connection_streams(x, n, name=None):
+    """Where the streams start: `n` copies of x [B, T, C], stream by
+    stream [B, n, T, C] (hyper-connections' own, arXiv:2409.19606)."""
+    helper = LayerHelper("hyper_connection", name=name)
+    B, T, C = x.shape
+    one = helper.create_tmp_variable(x.dtype, shape=(B, 1, T, C))
+    helper.append_op("unsqueeze", inputs={"X": [x.name]},
+                     outputs={"Out": [one.name]}, attrs={"axes": [1]})
+    out = helper.create_tmp_variable(x.dtype, shape=(B, n, T, C))
+    helper.append_op("expand", inputs={"X": [one.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"expand_times": [1, n, 1, 1]})
+    return out
+
+
+def hyper_connection_sum(streams, name=None):
+    """Where the streams end: their sum [B, T, C]."""
+    helper = LayerHelper("hyper_connection", name=name)
+    B, _, T, C = streams.shape
+    out = helper.create_tmp_variable(streams.dtype, shape=(B, T, C))
+    helper.append_op("hyper_connection_sum", inputs={"X": [streams.name]},
+                     outputs={"Out": [out.name]})
+    return out
 
 
 def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):

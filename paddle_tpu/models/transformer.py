@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 
 from .. import layers
+from ..framework import unique_name
 from ..framework.core import default_main_program
 from ..framework.initializer import NormalInitializer
 from ..framework.layer_helper import LayerHelper
@@ -53,7 +54,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                emb_init_scale=None, attention="multi_head", mla=None,
                dense_layers=0, dense_dim=None, layer_types=None, conv=None,
                n_kv_heads=None, head_dim=None, block_diffusion=None,
-               emb_init_seed=0):
+               emb_init_seed=0, hyper=None, mtp=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -80,8 +81,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     `dense_layers` blocks of an 'moe' tower have a SiLU-gated MLP of width
     `dense_dim` without bias instead (DeepSeek's `first_k_dense_replace`).
     `attention` 'multi_head' or 'latent' with `mla` = {"kv_rank",
-    "qk_nope_dim", "qk_rope_dim", "v_dim"} (`layers.latent_attention`;
-    rotary by construction: `rope_theta`, no position table).
+    "qk_nope_dim", "qk_rope_dim", "v_dim"} and optionally "q_rank" (a
+    query latent) and "yarn" (`layers.latent_attention`; rotary by
+    construction: `rope_theta`, no position table).
     `layer_types` gives the token mixer layer by layer, `n_layers` of
     'attention' (the kind `attention` names; everywhere by default) or
     'conv': a gated short convolution, `conv` = {"kernel_size"}
@@ -96,6 +98,24 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     noisy half alone: logits [B, T, vocab_size] of the token AT each
     position.  The dict gains "mask" and "weight" [B, T, 1], what
     `block_diffusion_loss` weighs the tokens by.
+    `hyper` = {"streams", "sinkhorn_iters", "epsilon", "clamp"} and
+    optionally "alpha_init", "beta_init" (initializers) changes the
+    residual path: instead of one tensor that every sub-layer's result is
+    added to, the loop carries "streams" copies of the embedding stream by
+    stream [B, n, T, dim]; every sub-layer reads a learned per-token mix of
+    them and writes back through a doubly stochastic matrix
+    (`layers.hyper_connection_pre` / `_post`, each sub-layer with
+    parameters of its own), and the streams' sum goes to the final norm.
+    The dict gains "mixing": every sub-layer's stream-mixing matrices [B,
+    T, n, n] in order, the first sub-layer's first.
+    `mtp` = {"tokens": [B, T, 1] the token AFTER each position} adds a
+    multi-token-prediction module of depth 1 (DeepSeek-V3,
+    arXiv:2412.19437, section 2.2): a projection [2 dim, dim] of
+    [norm(h) ; norm(embedding(next token))], h the main tower's output
+    before its final norm, the embedding the tower's own; one more block of
+    the tower's last kind; a final norm of its own and the tower's head.
+    The dict gains "logits" [B, T, vocab_size]: of the token two positions
+    on.  An expert block appends to `router_outputs` like the others.
     `init_scale` draws every matrix (embedding,
     projections, experts, head) from normal(0, init_scale) instead of each
     layer's default; `emb_init_scale` gives the token embedding a scale of
@@ -119,6 +139,12 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                                                            "conv"}:
         raise ValueError(f"layer_types {layer_types!r}: use {n_layers} of "
                          f"'attention' or 'conv'")
+    if mtp is not None:   # the module's block is of the last layer's kind
+        if block_diffusion is not None or positions == "learned":
+            raise ValueError("decoder_lm: the multi-token-prediction module "
+                             "runs on a next-token tower with rotary "
+                             "positions")
+        layer_types = list(layer_types) + [layer_types[-1]]
     init = (NormalInitializer(scale=init_scale) if init_scale is not None
             else None)
     attr = {"initializer": init} if init is not None else None
@@ -191,6 +217,11 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     emb_attr = attr if emb_init_scale is None else {
         "initializer": NormalInitializer(scale=emb_init_scale,
                                          seed=emb_init_seed)}
+    head_attr = attr
+    if mtp is not None:   # the module looks up and scores by the same two
+        emb_attr, head_attr = (
+            dict(a or {}, name=unique_name.generate("decoder_lm." + what))
+            for a, what in ((emb_attr, "embedding"), (head_attr, "head")))
     x = layers.embedding(tokens, size=[vocab_size, dim], param_attr=emb_attr,
                          dtype=dtype)
     if positions == "learned":
@@ -199,17 +230,39 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     if dropout_prob:
         x = layers.dropout(x, dropout_prob, is_test=is_test)
 
+    streams = int(hyper["streams"]) if hyper else 0
+
+    def sublayer(x, f):
+        """x with one sub-layer's result: x + f(norm(x)), or under `hyper`
+        the hyper-connection around f."""
+        read = x
+        if streams:
+            read, h_post, h_res = layers.hyper_connection_pre(
+                x, sinkhorn_iters=hyper["sinkhorn_iters"],
+                epsilon=hyper["epsilon"], norm_epsilon=norm_epsilon,
+                clamp=hyper["clamp"], param_attr=attr,
+                alpha_attr={"initializer": hyper.get("alpha_init")},
+                beta_attr={"initializer": hyper.get("beta_init")})
+            hyper.setdefault("mixing", []).append(h_res)
+        out = f(normed(read))
+        if dropout_prob:
+            out = layers.dropout(out, dropout_prob, is_test=is_test)
+        if streams:
+            return layers.hyper_connection_post(x, out, h_post, h_res)
+        return layers.elementwise_add(x, out)
+
+    def block(x, layer):
+        x = sublayer(x, lambda h: mix(h, layer))
+        return sublayer(x, lambda h: feed_forward(h, layer))
+
     blk = (layers.recompute if remat else contextlib.nullcontext)
+    if streams:
+        x = layers.hyper_connection_streams(x, streams)
     for layer in range(n_layers):
         with blk():
-            a = mix(normed(x), layer)
-            if dropout_prob:
-                a = layers.dropout(a, dropout_prob, is_test=is_test)
-            x = layers.elementwise_add(x, a)
-            m = feed_forward(normed(x), layer)
-            if dropout_prob:
-                m = layers.dropout(m, dropout_prob, is_test=is_test)
-            x = layers.elementwise_add(x, m)
+            x = block(x, layer)
+    if streams:
+        x = layers.hyper_connection_sum(x)
 
     if bd:  # the noisy half: the rows the loss reads
         helper = LayerHelper("noisy_rows")
@@ -221,9 +274,28 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                                 "ends": [int(clean_len)]})
         x = noisy
     h = normed(x)
-    with default_main_program().part_guard("lm.head"):
-        return layers.fc(h, vocab_size, num_flatten_dims=2,
-                         param_attr=attr, bias_attr=False)
+    prog = default_main_program()
+    with prog.part_guard("lm.head"):
+        logits = layers.fc(h, vocab_size, num_flatten_dims=2,
+                           param_attr=head_attr, bias_attr=False)
+    if mtp is None:
+        return logits
+    with prog.part_guard("mtp.project"):
+        nxt = layers.embedding(mtp["tokens"], size=[vocab_size, dim],
+                               param_attr=emb_attr, dtype=dtype)
+    h1 = layers.mtp_project(x, nxt, epsilon=norm_epsilon, param_attr=attr)
+    with prog.part_guard("mtp.block"), blk():
+        if streams:
+            h1 = layers.hyper_connection_streams(h1, streams)
+        h1 = block(h1, n_layers)
+        if streams:
+            h1 = layers.hyper_connection_sum(h1)
+    with prog.part_guard("mtp.head"):
+        h1 = normed(h1)
+        with prog.part_guard("lm.head"):
+            mtp["logits"] = layers.fc(h1, vocab_size, num_flatten_dims=2,
+                                      param_attr=head_attr, bias_attr=False)
+    return logits
 
 
 # decoder_lm's arguments that change the block's parameters or equations,
@@ -232,20 +304,36 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "qk_norm": False, "ffn": "mlp", "attention": "multi_head",
                "layer_types": None, "n_kv_heads": None, "head_dim": None,
-               "block_diffusion": None}
+               "block_diffusion": None, "hyper": None, "mtp": None}
 
 
-def lm_loss(logits, targets, dtype="float32"):
+def lm_loss(logits, targets, dtype="float32", drop_last=0):
     """Next-token loss: logits [B, T, V] vs targets [B, T, 1] (already
     shifted by the data pipeline).  Softmax runs in f32 regardless of the
-    model compute dtype."""
+    model compute dtype.  `drop_last` leaves the last so many positions of
+    every sequence out of the mean (targets shifted further than the
+    sequence reaches: a multi-token-prediction module's)."""
     V = logits.shape[-1]
     with default_main_program().part_guard("lm.loss"):
         flat = layers.reshape(logits, [-1, V])
         if dtype != "float32":
             flat = layers.cast(flat, "float32")
         tgt = layers.reshape(targets, [-1, 1])
-        return layers.mean(layers.softmax_with_cross_entropy(flat, tgt))
+        per_token = layers.softmax_with_cross_entropy(flat, tgt)
+        if drop_last:
+            T = int(logits.shape[1])
+            helper = LayerHelper("kept_positions")
+            kept = helper.create_tmp_variable(
+                "float32", shape=(logits.shape[0], T - drop_last, 1))
+            helper.append_op(
+                "slice",
+                inputs={"Input": [layers.reshape(per_token,
+                                                 [-1, T, 1]).name]},
+                outputs={"Out": [kept.name]},
+                attrs={"axes": [1], "starts": [0],
+                       "ends": [T - drop_last]})
+            per_token = kept
+        return layers.mean(per_token)
 
 
 def block_diffusion_loss(logits, tokens, weight, dtype="float32"):
@@ -733,6 +821,103 @@ def build_mla_moe_lm_train_program(
     opt.Adam(learning_rate=learning_rate).minimize(loss)
     for s in shares:
         layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
+    return loss
+
+
+def build_hc_mla_moe_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, n_heads, q_rank, kv_rank,
+        qk_nope_dim, qk_rope_dim, v_dim, yarn, hc_streams, hc_sinkhorn_iters,
+        dense_dim, num_experts, expert_dim, top_k, shared_experts,
+        held_experts, first_expert=0, buffer_rows=None, routed_scale=1.0,
+        dense_layers=1, hc_eps=1e-6, hc_clamp=(-30.0, 30.0),
+        hc_alpha_range=(0.01, 0.01), hc_beta_scale=0.0, mtp_weight=0.1,
+        norm_epsilon=1e-6, rope_theta=10000.0, balance_weight=1e-4,
+        bias_update_rate=1e-3, bias_init_scale=0.0, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02, emb_init_scale=None):
+    """DeepSeek-V3-shaped decoder with a query latent, YaRN, a
+    hyper-connected residual path and a multi-token-prediction module
+    (`model_type` xing4_0: Xing4.0-29B-A4B) as ONE CHIP'S SHARE of an
+    expert-parallel deployment.  `build_mla_moe_lm_train_program`'s tower
+    (its arguments of the same names mean the same; `layer_types` names
+    every block this chip runs, the module's LAST, each 'full_attention':
+    the tower has one block fewer) with: queries from a
+    latent of `q_rank` columns with its own RMSNorm; `yarn`, the published
+    `rope_scaling` (frequencies and softmax scale); the residual path as
+    `hc_streams` streams that every sub-layer reads and writes through
+    per-token gates and a stream-mixing matrix made doubly stochastic by
+    `hc_sinkhorn_iters` Sinkhorn iterations (`hc_eps` in their
+    denominators, `hc_clamp` on the matrix's logits; `decoder_lm`'s
+    `hyper`), its static terms drawn at random (a_* uniform on
+    `hc_alpha_range`, b_* normal(0, `hc_beta_scale`); mHC's own start is a
+    = 0.01, b = 0: the defaults); and after the tower's blocks ONE more
+    expert block as a multi-token-prediction module of depth 1
+    (`decoder_lm`'s `mtp`) on the fed 'targets' as the next tokens, scored
+    by the tower's own head against the fed 'next_targets'.  Loss:
+    next-token cross entropy + `mtp_weight` x the module's (the token two
+    places on; a sequence's last position left out) + `balance_weight` x
+    the sequence-wise balance loss averaged over the expert layers, the
+    module's included; Adam; then every expert layer's selection bias
+    moves.  Returns the loss.  Feeds: 'tokens', 'targets' (tokens one
+    place left) and 'next_targets' (two places left), [B, T, 1] int64."""
+    from .. import optimizer as opt
+    from ..framework.initializer import UniformInitializer
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    next_targets = layers.data("next_targets", shape=[seq_len, 1],
+                               dtype="int64")
+    if set(layer_types) != {"full_attention"} or len(layer_types) < 2:
+        raise ValueError(f"layer_types {layer_types!r}: the tower's blocks "
+                         f"and the module's, each 'full_attention'")
+    shares = []
+    module = {"tokens": targets}
+    streams = {"streams": hc_streams, "sinkhorn_iters": hc_sinkhorn_iters,
+               "epsilon": hc_eps, "clamp": tuple(hc_clamp),
+               "alpha_init": UniformInitializer(*hc_alpha_range),
+               "beta_init": NormalInitializer(scale=hc_beta_scale)}
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types) - 1, n_heads,
+        max_len=seq_len,
+        dtype=dtype, norm="rms_norm",
+        norm_epsilon=norm_epsilon, positions="rope", rope_theta=rope_theta,
+        attention="latent",
+        mla={"kv_rank": kv_rank, "qk_nope_dim": qk_nope_dim,
+             "qk_rope_dim": qk_rope_dim, "v_dim": v_dim, "q_rank": q_rank,
+             "yarn": yarn},
+        hyper=streams, mtp=module,
+        ffn="moe", dense_layers=dense_layers, dense_dim=dense_dim,
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "sigmoid", "renormalise": True,
+             "routed_scale": routed_scale, "buffer_rows": buffer_rows,
+             "select_bias": NormalInitializer(scale=bias_init_scale),
+             "shared_hidden": shared_experts * expert_dim},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    # the module's loss first: the program's last cross-entropy op is then
+    # the main loss's and its last `slice` the module's kept positions, for
+    # a fetch of either by op type
+    with default_main_program().part_guard("mtp.loss"):
+        ahead = lm_loss(module["logits"], next_targets, dtype=dtype,
+                        drop_last=1)
+    loss = layers.elementwise_add(lm_loss(logits, targets, dtype=dtype),
+                                  layers.scale(ahead, scale=mtp_weight))
+    balance = layers.sums([layers.moe_sequence_balance_loss(
+        s.scores, s.counts, top_k) for s in shares])
+    loss = layers.elementwise_add(
+        loss, layers.scale(balance, scale=balance_weight / len(shares)))
+    # the module's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    for s in shares:
+        layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
+    # the FIRST sub-layer's stream-mixing matrices, as the program's last
+    # `assign`, for a fetch to hold: there the streams are four copies of
+    # the embedding, the same bf16 values for whoever checks, so the
+    # matrix says how the gates and the Sinkhorn iterations were computed
+    # and not what six blocks of bf16 left of the streams
+    layers.assign(streams["mixing"][0])
     return loss
 
 

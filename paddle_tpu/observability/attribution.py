@@ -69,7 +69,8 @@ def op_type(op) -> str:
 
 def op_part(op):
     """The model part an op's desc names (attr `part`; a `generic_grad`
-    carries the forward op's), or None."""
+    carries the forward op's; `<outer>/<inner>` under nested
+    `Program.part_guard`s), or None."""
     attrs = op.attrs
     if op.type == "generic_grad":
         attrs = attrs.get("__fwd_attrs__") or {}
@@ -94,12 +95,12 @@ def op_scope(op):
     import jax
 
     part = op_part(op)
-    with jax.named_scope(scope_name(op)):
-        if part is None:
-            yield
-        else:
-            with part_scope(part):
-                yield
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(jax.named_scope(scope_name(op)))
+        for name in (part or "").split("/"):   # nested guards, outer first
+            if name:
+                stack.enter_context(part_scope(name))
+        yield
 
 
 def part_scope(name: str):

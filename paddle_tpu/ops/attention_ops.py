@@ -155,7 +155,8 @@ def _mask_attrs(attrs, T: int):
     return L, b
 
 
-def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None):
+def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
+                      scale=None):
     """The single-chip fast path of an attention emitter: the Pallas flash
     kernel (VMEM-tiled online softmax) on Q [B,H,T,D], K [B,Hkv,T,D] and V
     [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
@@ -172,7 +173,9 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None):
     keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
     call).  `mask`: (seq_len, block_length) of the block-diffusion mask,
     inside the same kernels, where blocks of 128 divide seq_len in whole
-    treads.  -> None where it does not apply, else (out, saved).
+    treads.  `scale`: the softmax scale where it is not 1 / sqrt(D) (YaRN's
+    temperature in latent attention), a positive float the three kernels
+    take as theirs.  -> None where it does not apply, else (out, saved).
 
     Training goes through the custom_vjp pair (FlashAttention-2-style
     blockwise backward), which generic_grad's jax.vjp honors, and the
@@ -200,6 +203,8 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None):
     from .pallas_kernels import flash_attention as fa
 
     layout = {} if heads is None else {"heads": heads}
+    if scale is not None:
+        layout["scale"] = float(scale)
     if mask is not None:
         layout.update(mask=fa.block_diffusion_mask(*mask),
                       block_q=fa.MASK_BLOCKS[0], block_k=fa.MASK_BLOCKS[1])

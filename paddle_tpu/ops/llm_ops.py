@@ -4,7 +4,8 @@ the transformer tier; first user: OLMoE, models/transformer.py), latent
 attention (DeepSeek-V2's MLA; first user: Moonlight-16B-A3B), the gated
 short convolution (LFM2's token mixer in the layers that do not attend) and
 the noising of block-diffusion training (BD3-LM's objective; first user:
-SDAR-30B-A3B).
+SDAR-30B-A3B) and the hyper-connection, a residual path of several streams
+mixed per token (mHC, arXiv:2512.24880; first user: Xing4.0-29B-A4B).
 
 All but latent attention and `head_norm_rope` are plain jax.numpy, so
 `generic_grad` differentiates them by re-emission and XLA's CSE merges the
@@ -36,6 +37,23 @@ _MET_MLA_LAYERS = _MET.counter(
     "its values (v_dim) and the rank of the K/V latent (kv_rank)")
 
 
+_MET_MLA_QUERY_LATENTS = _MET.counter(
+    "mla_query_latents_traced_total",
+    "latent attention layers traced whose queries come from a latent of "
+    "their own (WQA, QNorm, WQB; forward emission, once a compile), by the "
+    "latent's rank (q_rank) and the YaRN factor on the rotary frequencies "
+    "(yarn_factor; 1: none)")
+_MET_HC_LAYERS = _MET.counter(
+    "hyper_connection_layers_traced_total",
+    "hyper_connection_pre ops traced (forward emission; once a compile, not "
+    "once a step; one a sub-layer), by the residual streams (streams), a "
+    "stream's width (dim) and the Sinkhorn iterations on the stream-mixing "
+    "matrix (sinkhorn_iters)")
+_MET_MTP_MODULES = _MET.counter(
+    "mtp_modules_traced_total",
+    "multi-token-prediction modules traced (mtp_project's forward emission; "
+    "once a compile, not once a step), by how many tokens past the next one "
+    "the module predicts (depth)")
 _MET_CONV_LAYERS = _MET.counter(
     "short_conv_layers_traced_total",
     "gated short convolution ops traced (forward emission; once a compile, "
@@ -76,11 +94,45 @@ def rms_norm(ctx, ins, attrs):
                       tuple(range(begin, x.ndim)), gain)]}
 
 
-def rotate_half(x, theta: float, period: int = 0):
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """The dim / 2 rotary frequencies under YaRN (Peng et al. 2023,
+    arXiv:2309.00071, as DeepSeek-V3's `DeepseekV3YarnRotaryEmbedding`
+    blends them): frequency i is theta ** (-2i / dim) where it turns more
+    than `beta_fast` times over `original_max` positions, that over
+    `factor` where it turns fewer than `beta_slow` times, and a linear
+    blend by i between the two indices.  A numpy array: a trace-time
+    constant."""
+    import math
+
+    import numpy as np
+
+    def turns_at(turns):    # the index whose frequency makes `turns` turns
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature, 0.1 * mscale * ln(factor) + 1."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotate_half(x, theta: float, period: int = 0, inv_freq=None):
     """X [..., T, D] with D even turned by position: the pair (x[i], x[i +
     D/2]) of position t by the angle t * theta ** (-2i / D), positions
     0..T-1, or, with a `period`, row r at position r mod period (copies of
-    one sequence side by side); at least float32 inside, X's dtype out."""
+    one sequence side by side); with `inv_freq` [D/2], by the angle t *
+    inv_freq[i] instead (`yarn_inv_freq`); at least float32 inside, X's
+    dtype out."""
     import jax.numpy as jnp
 
     T, D = x.shape[-2], x.shape[-1]
@@ -88,7 +140,10 @@ def rotate_half(x, theta: float, period: int = 0):
         raise ValueError(f"rope op: head size {D} must be even")
     half = D // 2
     xf = x.astype(wide_dtype(x.dtype))
-    inv_freq = theta ** (-jnp.arange(half, dtype=xf.dtype) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=xf.dtype) / half)
+    else:
+        inv_freq = jnp.asarray(inv_freq, xf.dtype)
     pos = jnp.arange(T, dtype=xf.dtype)
     if period:
         pos = (jnp.arange(T) % period).astype(xf.dtype)
@@ -271,10 +326,12 @@ def block_diffusion_noise(ctx, ins, attrs):
 @register_op("latent_attention")
 def latent_attention(ctx, ins, attrs):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
-    2.1; the `q_lora_rank` null form of DeepSeek-V3's modelling code) over
-    X [B, T, D], causal, H heads:
+    2.1, as DeepSeek-V3's modelling code computes it) over X [B, T, D],
+    causal, H heads:
 
-      q = X WQ -> [B, T, H, dn + dr] = (q_nope, q_pe)
+      q = X WQ -> [B, T, H, dn + dr] = (q_nope, q_pe): the `q_lora_rank`
+          null form; or, with a query latent (inputs WQA, QNorm, WQB in
+          WQ's place), q = RMSNorm(X WQA; QNorm) WQB
       c = X WKVA -> [B, T, r + dr] = (c_kv, k_pe): the latent a serving
           cache would hold, and ONE rotary key all heads share
       kv = RMSNorm(c_kv; KVNorm) WKVB -> [B, T, H, dn + dv] = (k_nope, v)
@@ -282,47 +339,360 @@ def latent_attention(ctx, ins, attrs):
       softmax(q k^T / sqrt(dn + dr)) v -> [B, T, H dv], times WO.
 
     attrs: num_heads, qk_nope_dim (dn), qk_rope_dim (dr), v_dim (dv), theta,
-    epsilon.  RoPE is the rotate-half form (`rotate_half`).  The keys are
-    dn + dr wide and the values dv: on one TPU the two-width flash kernels
-    (attention_ops.flash_single_chip), elsewhere dense attention."""
+    epsilon.  RoPE is the rotate-half form (`rotate_half`).  With
+    `yarn_factor` (and `yarn_original_max`, `yarn_beta_fast`,
+    `yarn_beta_slow`, `yarn_mscale`, `yarn_mscale_all_dim`) the rotary
+    frequencies are YaRN's (`yarn_inv_freq`), cos and sin times
+    mscale(yarn_mscale) / mscale(yarn_mscale_all_dim), and the softmax
+    scale mscale(yarn_mscale_all_dim)^2 / sqrt(dn + dr) (`yarn_mscale`).
+    The keys are dn + dr wide and the values dv: on one TPU the two-width
+    flash kernels (attention_ops.flash_single_chip), elsewhere dense
+    attention."""
     import jax.numpy as jnp
 
     from ..parallel.ring_attention import attention as dense_attention
     from .attention_ops import flash_single_chip
 
     x = ins["X"][0]
-    wq, wkva, wkvb, wo = (ins[k][0] for k in ("WQ", "WKVA", "WKVB", "WO"))
+    wkva, wkvb, wo = (ins[k][0] for k in ("WKVA", "WKVB", "WO"))
     H = int(attrs["num_heads"])
     dn, dr, dv = (int(attrs[k]) for k in ("qk_nope_dim", "qk_rope_dim",
                                           "v_dim"))
     theta = float(attrs.get("theta", 10000.0))
+    eps = float(attrs.get("epsilon", 1e-5))
     B, T, _ = x.shape
     rank = wkva.shape[1] - dr
+    latent = bool(ins.get("WQA"))
+    factor = float(attrs.get("yarn_factor", 1.0))
+    inv_freq = scale = None
+    turn = 1.0
+    if factor != 1.0:
+        inv_freq = yarn_inv_freq(
+            dr, theta, factor, int(attrs["yarn_original_max"]),
+            float(attrs["yarn_beta_fast"]), float(attrs["yarn_beta_slow"]))
+        all_dim = yarn_mscale(factor, float(attrs["yarn_mscale_all_dim"]))
+        turn = yarn_mscale(factor, float(attrs["yarn_mscale"])) / all_dim
+        scale = all_dim * all_dim / (dn + dr) ** 0.5
     if not ctx.in_grad_replay():
         _MET_MLA_LAYERS.inc(qk_dim=str(dn + dr), v_dim=str(dv),
                             kv_rank=str(rank))
+        if latent:
+            _MET_MLA_QUERY_LATENTS.inc(q_rank=str(ins["WQA"][0].shape[1]),
+                                       yarn_factor=f"{factor:g}")
     heads = lambda a: jnp.swapaxes(a, 1, 2)          # [B,T,H,d] <-> [B,H,T,d]
+
+    def rope(a):
+        out = rotate_half(a, theta, inv_freq=inv_freq)
+        return out if turn == 1.0 else out * jnp.asarray(turn, a.dtype)
+
     with part_scope("mla.project"):
-        q = heads((x @ wq).reshape(B, T, H, dn + dr))
+        if latent:
+            q = rms(x @ ins["WQA"][0], eps, (2,), ins["QNorm"][0]) @ ins[
+                "WQB"][0]
+        else:
+            q = x @ ins["WQ"][0]
+        q = heads(q.reshape(B, T, H, dn + dr))
         c = x @ wkva
-        c_kv = rms(c[..., :rank], float(attrs.get("epsilon", 1e-5)), (2,),
-                   ins["KVNorm"][0])
+        c_kv = rms(c[..., :rank], eps, (2,), ins["KVNorm"][0])
         kv = heads((c_kv @ wkvb).reshape(B, T, H, dn + dv))
     with part_scope("mla.rope"):
-        k_pe = rotate_half(c[:, None, :, rank:], theta)        # [B,1,T,dr]
-        q = jnp.concatenate([q[..., :dn], rotate_half(q[..., dn:], theta)],
-                            axis=-1)
+        k_pe = rope(c[:, None, :, rank:])                      # [B,1,T,dr]
+        q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_pe, (B, H, T, dr))], axis=-1)
         v = kv[..., dn:]
     with part_scope("mla.attend"):
-        got = flash_single_chip(ctx, q, k, v, True)
+        got = flash_single_chip(ctx, q, k, v, True, scale=scale)
         attn, saved = got if got is not None else (
-            dense_attention(q, k, v, causal=True), None)
+            dense_attention(q, k, v, causal=True, scale=scale), None)
     out = heads(attn).reshape(B, T, H * dv) @ wo
     if saved is not None:
         ctx.keep_for_grad(attrs, [out], saved)
     return {"Out": [out]}
+
+
+def _sinkhorn(m, iters: int, eps: float):
+    """`iters` times: every row of M [n, n, ...] (row, column, then a
+    token's axes) over its sum + eps, then every column over its sum +
+    eps.  One `lax.scan` step an iteration, so the step's graph holds the
+    iteration once; the sums are adds of slices, elementwise on whole
+    token vectors (the token axis on the lanes)."""
+    from jax import lax
+
+    n = m.shape[0]
+
+    def step(m, _):
+        m = m / (sum(m[:, j] for j in range(n)) + eps)[:, None]
+        m = m / (sum(m[i] for i in range(n)) + eps)[None]
+        return m, None
+
+    return lax.scan(step, m, None, length=iters)[0]
+
+
+def _hc_gates(proj, inv, alpha, beta, n: int, iters: int, eps: float,
+              clamp):
+    """(H_pre [n, B, T], H_post [n, B, T], M [n, n, B, T]) of the raw
+    projection vec(X) Phi [K, B, T], the norm's factor [B, T], Alpha [3]
+    and Beta [K]; K = n + n + n n.  Small tensors, the token axis last."""
+    import jax
+    import jax.numpy as jnp
+
+    a = jnp.concatenate([jnp.broadcast_to(v, (k,))
+                         for v, k in zip(alpha, (n, n, n * n))])
+    ht = proj * inv * a[:, None, None] + beta[:, None, None]
+    raw = jnp.exp(jnp.clip(ht[2 * n:], clamp[0], clamp[1]))
+    return (jax.nn.sigmoid(ht[:n]), 2.0 * jax.nn.sigmoid(ht[n:2 * n]),
+            _sinkhorn(raw.reshape((n, n) + raw.shape[1:]), iters, eps))
+
+
+def _streams_of(x):
+    """X [B, n, T, C] -> its n streams [B, T, C], at least float32."""
+    xw = x.astype(wide_dtype(x.dtype))
+    return [xw[:, i] for i in range(x.shape[1])]
+
+
+def _over_columns(a, b):
+    """sum over the last axis of a * b: one number a token."""
+    import jax.numpy as jnp
+
+    return jnp.sum(a * b, axis=-1)
+
+
+def _hc_pre(n: int, iters: int, eps: float, norm_eps: float, clamp,
+            exact: bool):
+    """`hyper_connection_pre` on (X [B, n, T, C], Phi [n, C, K], Alpha,
+    Beta) -> (U, H_post [B, T, n], M [B, T, n, n]) with a backward written
+    out: it keeps X, Phi, the raw projection and the norm's factor, makes
+    the gates and the Sinkhorn iterations again (mHC's own recipe,
+    arXiv:2512.24880 section 4.3), and writes X's gradient stream by
+    stream, stacked once.  (Autodiff of a stream's slice is a `pad` to all
+    the streams: n float32 tensors of the streams' whole size a sub-layer,
+    and the op read 136 ms a step in `xing4_train_t4096` against 70 so;
+    PERF.md, PR 39.)  `exact`: the products take X and Phi as stored (a
+    TPU)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    gates = lambda *a: _hc_gates(*a, n, iters, eps, clamp)  # noqa: E731
+
+    def stored(a, wide):
+        return a if exact else a.astype(wide)
+
+    def forward(x, phi, alpha, beta):
+        wide = wide_dtype(x.dtype)
+        with part_scope("hc.gates"):
+            # vec(X) Phi stream by stream, X as it lies; the SMALL result
+            # turned so that the token axis is last
+            proj = jnp.moveaxis(sum(
+                lax.dot_general(stored(x[:, i], wide), stored(phi[i], wide),
+                                (((2,), (0,)), ((), ())),
+                                preferred_element_type=wide)
+                for i in range(n)), 2, 0)                       # [K, B, T]
+            xw = x.astype(wide)
+            inv = lax.rsqrt(jnp.mean(xw * xw, axis=(1, 3)) + norm_eps)
+            h_pre, h_post, m = gates(proj, inv, alpha.astype(wide),
+                                     beta.astype(wide))
+            # what meets the streams leaves as one column a token ([B, T,
+            # ..]: the token axis where the streams have it), so that XLA
+            # turns these small tensors and not the streams
+            read = jnp.moveaxis(h_pre, 0, 2)                    # [B, T, n]
+        with part_scope("hc.read"):
+            u = sum(read[..., i:i + 1] * xi
+                    for i, xi in enumerate(_streams_of(x)))
+        return ((u.astype(x.dtype), jnp.moveaxis(h_post, 0, 2),
+                 jnp.transpose(m, (2, 3, 0, 1))),
+                (x, phi, alpha, beta, proj, inv))
+
+    @jax.custom_vjp
+    def pre(x, phi, alpha, beta):
+        return forward(x, phi, alpha, beta)[0]
+
+    def backward(kept, cts):
+        x, phi, alpha, beta, proj, inv = kept
+        du, dh_post, dm = cts
+        wide = wide_dtype(x.dtype)
+        du = du.astype(wide)
+        xs = _streams_of(x)
+        with part_scope("hc.gates"):
+            (h_pre, _, _), back = jax.vjp(gates, proj, inv,
+                                          alpha.astype(wide),
+                                          beta.astype(wide))
+        with part_scope("hc.read"):
+            dh_pre = jnp.stack([_over_columns(du, xi) for xi in xs])
+        with part_scope("hc.gates"):
+            dproj, dinv, dalpha, dbeta = back(
+                (dh_pre, jnp.moveaxis(dh_post.astype(wide), 2, 0),
+                 jnp.transpose(dm.astype(wide), (2, 3, 0, 1))))
+            dproj = jnp.moveaxis(dproj, 0, 2)                   # [B, T, K]
+            dphi = jnp.stack([
+                lax.dot_general(stored(x[:, i], wide), dproj,
+                                (((0, 1), (0, 1)), ((), ())),
+                                preferred_element_type=wide)
+                for i in range(n)])                             # [n, C, K]
+            through = [lax.dot_general(dproj, phi[i].astype(wide),
+                                       (((2,), (1,)), ((), ())))
+                       for i in range(n)]                       # [B, T, C]
+            # d inv / d x = -inv^3 x / (n C); a column a token, as `read`
+            cols = jnp.stack(
+                [*h_pre, -dinv * inv * inv * inv / (n * x.shape[3])], axis=-1)
+        with part_scope("hc.read"):
+            dx = jnp.stack([
+                (cols[..., i:i + 1] * du + cols[..., n:] * xi + t).astype(
+                    x.dtype)
+                for i, (xi, t) in enumerate(zip(xs, through))], axis=1)
+        return (dx, dphi.astype(phi.dtype),
+                dalpha.astype(alpha.dtype), dbeta.astype(beta.dtype))
+
+    pre.defvjp(forward, backward)
+    return pre
+
+
+def _hc_post():
+    """`hyper_connection_post` on (X [B, n, T, C], Y, H_post [B, T, n], M
+    [B, T, n, n]) with a backward written out: the new streams, and the
+    streams' gradient, stream by stream and stacked once; the gates'
+    gradients sums over a token's columns."""
+    import jax
+    import jax.numpy as jnp
+
+    def write(x, y, h_post, m):
+        yw = y.astype(wide_dtype(x.dtype))
+        with part_scope("hc.write"):
+            # Out[:, i] = sum_j M[i, j] X[:, j] + H_post[i] Y
+            xs = _streams_of(x)
+            return jnp.stack([
+                sum((m[:, :, i, j, None] * xs[j] for j in range(len(xs))),
+                    h_post[:, :, i, None] * yw).astype(x.dtype)
+                for i in range(len(xs))], axis=1)
+
+    @jax.custom_vjp
+    def post(x, y, h_post, m):
+        return write(x, y, h_post, m)
+
+    def backward(kept, dout):
+        x, y, h_post, m = kept
+        xs, yw = _streams_of(x), y.astype(wide_dtype(x.dtype))
+        with part_scope("hc.write"):
+            ds = _streams_of(dout)
+            # dX[:, j] = sum_i M[i, j] dOut[:, i]
+            dx = jnp.stack([
+                sum(m[:, :, i, j, None] * ds[i]
+                    for i in range(len(ds))).astype(x.dtype)
+                for j in range(len(ds))], axis=1)
+            dy = sum(h_post[:, :, i, None] * di for i, di in enumerate(ds))
+            dh_post = jnp.stack([_over_columns(d, yw) for d in ds], axis=-1)
+            dm = jnp.stack([jnp.stack([_over_columns(d, xj) for xj in xs],
+                                      axis=-1) for d in ds], axis=-2)
+        return (dx.astype(x.dtype), dy.astype(y.dtype),
+                dh_post.astype(h_post.dtype), dm.astype(m.dtype))
+
+    post.defvjp(lambda *a: (write(*a), a), backward)
+    return post
+
+
+@register_op("hyper_connection_pre")
+def hyper_connection_pre(ctx, ins, attrs):
+    """What a sub-layer reads of n residual streams, and how its result
+    goes back (manifold-constrained hyper-connections, mHC,
+    arXiv:2512.24880, over hyper-connections, arXiv:2409.19606).  X [B, n,
+    T, C]: n streams of C columns a token, stream by stream (a stream is
+    one slab; side by side in a token's row, [B, T, n C], every
+    sub-layer's write would be a concatenation along the lanes, which XLA
+    makes n pads of the whole width); vec(X) of a token is its n rows one
+    after the other.  PhiPre, PhiPost [n C, n], PhiRes [n C, n n]; Alpha
+    [3] = (a_pre, a_post, a_res); Beta [n + n + n n] = (b_pre, b_post,
+    b_res row by row).
+
+      xbar   = vec(X) / sqrt(mean(vec(X)^2) + norm_epsilon)   (no gain)
+      Ht_pre = a_pre xbar PhiPre + b_pre,  Ht_post, Ht_res alike
+      H_pre  = sigmoid(Ht_pre);  H_post = 2 sigmoid(Ht_post)
+      M      = exp(clip(Ht_res, clamp_min, clamp_max)) as n x n, then
+               `sinkhorn_iters` times: rows over their sum + epsilon,
+               columns over their sum + epsilon
+      U      = sum_i H_pre[i] X[i]                     [B, T, C]
+
+    -> U (X's dtype), HPost [B, T, n] and HRes [B, T, n, n] (HRes[b, t, i,
+    j] = M_t[i, j]; both at least float32; inside, the gates and the
+    iterations run with the token axis last, on the lanes, and leave as one
+    column a token, the orientation the streams meet them in).  Everything
+    but the product X Phi is at least float32 whatever X's dtype; on a TPU
+    the product takes X and Phi as they are stored and accumulates in
+    float32, so bf16 operands enter it exactly and X is read at its own
+    width (elsewhere both are widened first: XLA's CPU runtime has no bf16
+    x bf16 -> f32 product); the norm's factor is a token's scalar and is
+    applied to the product.  The backward is written out (`_hc_pre`).  attrs: streams (n), sinkhorn_iters, epsilon,
+    norm_epsilon, clamp_min, clamp_max."""
+    import jax.numpy as jnp
+
+    x = ins["X"][0]
+    n = int(attrs["streams"])
+    iters = int(attrs["sinkhorn_iters"])
+    phis = [ins[k][0] for k in ("PhiPre", "PhiPost", "PhiRes")]
+    if x.ndim != 4 or x.shape[1] != n or [p.shape for p in phis] != [
+            (n * x.shape[3], k) for k in (n, n, n * n)]:
+        raise ValueError(f"hyper_connection_pre: X {x.shape} is not [B, "
+                         f"{n} streams, T, C] for Phi "
+                         f"{[p.shape for p in phis]}")
+    if not ctx.in_grad_replay():
+        _MET_HC_LAYERS.inc(streams=str(n), dim=str(x.shape[3]),
+                           sinkhorn_iters=str(iters))
+    phi = jnp.concatenate(phis, axis=1).astype(x.dtype).reshape(
+        n, x.shape[3], (2 + n) * n)
+    u, h_post, h_res = _hc_pre(
+        n, iters, float(attrs["epsilon"]), float(attrs["norm_epsilon"]),
+        (float(attrs["clamp_min"]), float(attrs["clamp_max"])),
+        ctx.target_platform() == "tpu")(
+            x, phi, ins["Alpha"][0], ins["Beta"][0])
+    return {"U": [u], "HPost": [h_post], "HRes": [h_res]}
+
+
+@register_op("hyper_connection_post")
+def hyper_connection_post(ctx, ins, attrs):
+    """A sub-layer's result Y [B, T, C] written back into the n streams X
+    [B, n, T, C] through `hyper_connection_pre`'s HPost [B, T, n] and HRes
+    [B, T, n, n]:  Out[i] = sum_j HRes[i, j] X[j] + HPost[i] Y, at least
+    float32 inside and ONE rounding to X's dtype; the backward is written
+    out (`_hc_post`)."""
+    x, y, h_post, h_res = (ins[k][0] for k in ("X", "Y", "HPost", "HRes"))
+    n = x.shape[1]
+    if (x.ndim != 4 or x.shape[:1] + x.shape[2:] != y.shape
+            or h_res.shape[2:] != (n, n)):
+        raise ValueError(f"hyper_connection_post: X {x.shape}, Y {y.shape},"
+                         f" HRes {h_res.shape}")
+    return {"Out": [_hc_post()(x, y, h_post, h_res)]}
+
+
+@register_op("hyper_connection_sum")
+def hyper_connection_sum(ctx, ins, attrs):
+    """Where the streams end: X [B, n, T, C] -> the sum of its n streams
+    [B, T, C] (hyper-connections' own, arXiv:2409.19606), added in at least
+    float32 with one rounding."""
+    import jax.numpy as jnp
+
+    x = ins["X"][0]
+    with part_scope("hc.read"):
+        return {"Out": [jnp.sum(x.astype(wide_dtype(x.dtype)),
+                                axis=1).astype(x.dtype)]}
+
+
+@register_op("mtp_project")
+def mtp_project(ctx, ins, attrs):
+    """The way into a multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437, section 2.2, equation 21): H [B, T, D] the hidden
+    state a position has at the depth before (the main tower's, before its
+    final norm), E [B, T, D] the embedding of the token `depth` places on;
+    Out = [RMSNorm(H; HNorm) ; RMSNorm(E; ENorm)] W, W [2 D, D], computed
+    as two products on W's halves (no [B, T, 2 D] is made).  attrs:
+    epsilon, depth."""
+    h, e, w = ins["H"][0], ins["E"][0], ins["W"][0]
+    D = h.shape[-1]
+    eps = float(attrs.get("epsilon", 1e-5))
+    if not ctx.in_grad_replay():
+        _MET_MTP_MODULES.inc(depth=str(int(attrs.get("depth", 1))))
+    with part_scope("mtp.project"):
+        return {"Out": [rms(h, eps, (2,), ins["HNorm"][0]) @ w[:D]
+                        + rms(e, eps, (2,), ins["ENorm"][0]) @ w[D:]]}
 
 
 @register_op("gated_short_conv")
@@ -398,7 +768,8 @@ def _latent_attention_cost(ins, outs, attrs):
     heads = int(attrs["num_heads"])
     widths = sum(int(attrs[k]) for k in ("qk_nope_dim", "qk_rope_dim",
                                          "v_dim"))
-    weights = sum(ins[k][0].size for k in ("WQ", "WKVA", "WKVB", "WO"))
+    weights = sum(ins[k][0].size for k in ("WQ", "WQA", "WQB", "WKVA",
+                                           "WKVB", "WO") if ins.get(k))
     return {"flops": 2 * b * t * weights + b * heads * t * t * widths}
 
 

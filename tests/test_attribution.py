@@ -178,7 +178,9 @@ def test_part_guard_stamps_ops_appended_inside_and_keeps_an_ops_own():
                                                         nested, again, after)
             if v.name in op.output_names()}
     assert part == {before.name: None, inside.name: "blk.outer",
-                    own.name: "attn.qk_norm", nested.name: "blk.inner",
+                    own.name: "attn.qk_norm",
+                    # guards nest since PR 39: outer first, `/` between
+                    nested.name: "blk.outer/blk.inner",
                     again.name: "blk.outer", after.name: None}
     # the startup program's ops (the gain's initializer) are not the guard's
     assert not any(op.attrs.get("part")

@@ -118,8 +118,12 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # PR 38: +1 (head_norm_rope — Q and K from projection to attention;
     # its grad op `head_norm_rope_grad` is not differentiable), numerically
     # checked in test_llm_ops.py
-    assert len(diffable) == 153, (
+    # PR 39: +4 (hyper_connection_pre, hyper_connection_post,
+    # hyper_connection_sum, mtp_project: Xing4.0's residual path and its
+    # multi-token-prediction module), each numerically checked in
+    # test_xing.py
+    assert len(diffable) == 157, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 153 - 11
+    assert len(checked) == 157 - 11

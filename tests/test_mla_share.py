@@ -171,13 +171,15 @@ def _whole_layer(x, gate, bias, wi, wu, wo, top_k, scale, epsilon=1e-20):
 
 
 @pytest.mark.parametrize("k,scale,epsilon,shared_hidden", [
-    (6, 2.446, 1e-20, 24), (4, 1.0, 1e-6, 0)],
-    ids=["moonlight_with_a_shared_expert", "lfm2_without"])
+    (6, 2.446, 1e-20, 24), (4, 1.0, 1e-6, 0), (4, 2.0, 1e-20, 16)],
+    ids=["moonlight_with_a_shared_expert", "lfm2_without",
+         "xing4_top4_scale2_one_shared_expert"])
 def test_eight_shares_add_up_to_the_uncut_layer(k, scale, epsilon,
                                                 shared_hidden):
     """held 8 of 64, sigmoid, bias; Moonlight's top-6, scale 2.446 and a
     shared expert, LFM2's top-4, scale 1, epsilon 1e-6 and NO shared
-    expert: the layer run 8 times with first = 0, 8, ..., 56 (and the
+    expert, Xing4.0's top-4, scale 2 and ONE shared expert of the routed
+    experts' width: the layer run 8 times with first = 0, 8, ..., 56 (and the
     shared expert, where there is one, counted ONCE) adds up to the whole
     layer; every share's counts are the whole layer's, its held pairs are
     its slice of them, nothing is dropped."""
