@@ -616,7 +616,12 @@ def hyper_connection_pre(streams, sinkhorn_iters=20, epsilon=1e-6,
     `hyper_connection_post` writes its result back through).  Five
     parameters of the sub-layer's own, in creation order: PhiPre, PhiPost
     [n C, n], PhiRes [n C, n n] (`param_attr`), Alpha [3] (`alpha_attr`;
-    mHC's 0.01 by default) and Beta [n + n + n n] (`beta_attr`; zeros)."""
+    mHC's 0.01 by default) and Beta [n + n + n n] (`beta_attr`; zeros).
+    The op also leaves the raw projection and the norm's factor (Proj,
+    Inv: small float32 tensors) for its grad op.  On one TPU the passes
+    over the streams of this op, of `hyper_connection_post` and of their
+    grad ops are Pallas kernels (C in 128s, T in whole token tiles);
+    everywhere else plain jax.numpy."""
     helper = LayerHelper("hyper_connection", name=name)
     B, n, T, C = streams.shape
 
@@ -634,10 +639,14 @@ def hyper_connection_pre(streams, sinkhorn_iters=20, epsilon=1e-6,
     u = helper.create_tmp_variable(streams.dtype, shape=(B, T, C))
     h_post = helper.create_tmp_variable("float32", shape=(B, T, n))
     h_res = helper.create_tmp_variable("float32", shape=(B, T, n, n))
+    kept = [helper.create_tmp_variable("float32", shape=shape,
+                                       stop_gradient=True)
+            for shape in (((2 + n) * n, B, T), (B, T))]
     helper.append_op(
         "hyper_connection_pre", inputs=ins,
         outputs={"U": [u.name], "HPost": [h_post.name],
-                 "HRes": [h_res.name]},
+                 "HRes": [h_res.name], "Proj": [kept[0].name],
+                 "Inv": [kept[1].name]},
         attrs={"streams": int(n), "sinkhorn_iters": int(sinkhorn_iters),
                "epsilon": float(epsilon),
                "norm_epsilon": float(norm_epsilon),
@@ -648,7 +657,8 @@ def hyper_connection_pre(streams, sinkhorn_iters=20, epsilon=1e-6,
 def hyper_connection_post(streams, y, h_post, h_res, name=None):
     """The sub-layer's result `y` [B, T, C] written back into the streams
     [B, n, T, C]: stream i = sum_j h_res[i, j] stream j + h_post[i] y
-    (ops/llm_ops.py hyper_connection_post)."""
+    (ops/llm_ops.py hyper_connection_post; one Pallas kernel where
+    `hyper_connection_pre` takes its own)."""
     helper = LayerHelper("hyper_connection", name=name)
     out = helper.create_tmp_variable(streams.dtype, shape=streams.shape)
     helper.append_op(

@@ -7,14 +7,18 @@ the noising of block-diffusion training (BD3-LM's objective; first user:
 SDAR-30B-A3B) and the hyper-connection, a residual path of several streams
 mixed per token (mHC, arXiv:2512.24880; first user: Xing4.0-29B-A4B).
 
-All but latent attention and `head_norm_rope` are plain jax.numpy, so
-`generic_grad` differentiates them by re-emission and XLA's CSE merges the
-re-emitted forward with the first.  `head_norm_rope` (Q or K from the
-projection's layout to attention's: the per-head norm, the rotary turn and
-the head split in one pass; first users: OLMoE, LFM2, SDAR) brings its own
-grad op, whose emitter needs the forward's inputs alone and never emits the
-forward.  Statistics and rotations are at least float32 whatever the
-compute dtype (`wide_dtype`); the result goes back to the input's dtype."""
+All but latent attention, `head_norm_rope` and the two hyper-connection
+ops are plain jax.numpy, so `generic_grad` differentiates them by
+re-emission and XLA's CSE merges the re-emitted forward with the first.
+`head_norm_rope` (Q or K from the projection's layout to attention's: the
+per-head norm, the rotary turn and the head split in one pass; first users:
+OLMoE, LFM2, SDAR) and `hyper_connection_pre` / `_post` take Pallas kernels
+on one TPU and plain jax.numpy everywhere else, and bring grad ops of their
+own, whose emitters need the forward's inputs (and the small outputs it
+kept) alone and never emit the forward: a Mosaic call is opaque to CSE, so
+a re-emitted forward would run twice.  Statistics and rotations are at
+least float32 whatever the compute dtype (`wide_dtype`); the result goes
+back to the input's dtype."""
 
 from __future__ import annotations
 
@@ -49,6 +53,13 @@ _MET_HC_LAYERS = _MET.counter(
     "once a step; one a sub-layer), by the residual streams (streams), a "
     "stream's width (dim) and the Sinkhorn iterations on the stream-mixing "
     "matrix (sinkhorn_iters)")
+_MET_HC_KERNELS = _MET.counter(
+    "hyper_connection_kernels_traced_total",
+    "hyper-connection ops traced (once a compile, not once a step; one of "
+    "each a sub-layer), by the op (op: pre, post, pre_grad, post_grad) and "
+    "the path its emitter took (pallas: the kernels of "
+    "ops/pallas_kernels/hyper_connection.py, a token tile of all streams "
+    "in VMEM; xla: plain jax.numpy)")
 _MET_MTP_MODULES = _MET.counter(
     "mtp_modules_traced_total",
     "multi-token-prediction modules traced (mtp_project's forward emission; "
@@ -218,22 +229,32 @@ def _qk_prep(ctx, ins, attrs):
     return x, gain, kw, pack
 
 
-def _head_norm_rope_grad_maker(op, wanted):
-    """One `head_norm_rope_grad` desc: the forward op's inputs and Out's
-    cotangent in, the wanted inputs' cotangents out, the forward's attrs
-    (its `part` and `__uid__` with them).  Not a `generic_grad`: the
-    backward needs no forward emitted again."""
-    outs = {slot + GRAD_SUFFIX: [n + GRAD_SUFFIX if n in wanted else ""
-                                 for n in names]
-            for slot, names in op.inputs.items()}
-    if not any(n for names in outs.values() for n in names):
-        return []
-    ins = {slot: list(names) for slot, names in op.inputs.items()}
-    ins["Out" + GRAD_SUFFIX] = [n + GRAD_SUFFIX for n in op.outputs["Out"]]
-    return [("head_norm_rope_grad", ins, outs, dict(op.attrs))]
+def _own_grad_maker(grad_type: str, kept=()):
+    """A grad maker for an op whose backward is an op of its own: ONE
+    `grad_type` desc with the forward op's inputs, its `kept` outputs and
+    the other outputs' cotangents in, the wanted inputs' cotangents out,
+    the forward's attrs (its `part` and `__uid__` with them).  Not a
+    `generic_grad`: the backward needs no forward emitted again."""
+
+    def maker(op, wanted):
+        outs = {slot + GRAD_SUFFIX: [n + GRAD_SUFFIX if n in wanted else ""
+                                     for n in names]
+                for slot, names in op.inputs.items()}
+        if not any(n for names in outs.values() for n in names):
+            return []
+        ins = {slot: list(names) for slot, names in op.inputs.items()}
+        for slot, names in op.outputs.items():
+            if slot in kept:
+                ins[slot] = list(names)
+            else:
+                ins[slot + GRAD_SUFFIX] = [n + GRAD_SUFFIX for n in names]
+        return [(grad_type, ins, outs, dict(op.attrs))]
+
+    return maker
 
 
-@register_op("head_norm_rope", grad=_head_norm_rope_grad_maker)
+@register_op("head_norm_rope",
+             grad=_own_grad_maker("head_norm_rope_grad"))
 def head_norm_rope(ctx, ins, attrs):
     """Q (or K) from the projection's layout to attention's in one pass: X
     [B, T, H * D] -> Out [B, H, T, D] with, per head and row, an RMSNorm
@@ -459,20 +480,29 @@ def _over_columns(a, b):
 
 
 def _hc_pre(n: int, iters: int, eps: float, norm_eps: float, clamp,
-            exact: bool):
-    """`hyper_connection_pre` on (X [B, n, T, C], Phi [n, C, K], Alpha,
-    Beta) -> (U, H_post [B, T, n], M [B, T, n, n]) with a backward written
-    out: it keeps X, Phi, the raw projection and the norm's factor, makes
-    the gates and the Sinkhorn iterations again (mHC's own recipe,
-    arXiv:2512.24880 section 4.3), and writes X's gradient stream by
-    stream, stacked once.  (Autodiff of a stream's slice is a `pad` to all
-    the streams: n float32 tensors of the streams' whole size a sub-layer,
-    and the op read 136 ms a step in `xing4_train_t4096` against 70 so;
-    PERF.md, PR 39.)  `exact`: the products take X and Phi as stored (a
-    TPU)."""
+            exact: bool, kernels: bool):
+    """(forward, backward) of `hyper_connection_pre`, both written out.
+
+      forward(X [B, n, T, C], Phi [n, C, K], Alpha, Beta) -> (U, H_post
+        [B, T, n], M [B, T, n, n], the raw projection [K, B, T], the
+        norm's factor [B, T])
+      backward(X, Phi, Alpha, Beta, projection, factor, dU, dH_post, dM)
+        -> (dX, dPhi, dAlpha, dBeta)
+
+    The backward makes the gates and the Sinkhorn iterations again from
+    the kept projection and factor (mHC's own recipe, arXiv:2512.24880
+    section 4.3) and writes X's gradient stream by stream.  (Autodiff of a
+    stream's slice is a `pad` to all the streams: n float32 tensors of the
+    streams' whole size a sub-layer, and the op read 136 ms a step in
+    `xing4_train_t4096` against 70 so; PERF.md, PR 39.)  `exact`: the
+    products take X and Phi as stored (a TPU).  `kernels`: every pass over
+    the streams is one kernel of ops/pallas_kernels/hyper_connection.py
+    (the gates stay here: small tensors, the token axis last)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
+
+    from .pallas_kernels import hyper_connection as K
 
     gates = lambda *a: _hc_gates(*a, n, iters, eps, clamp)  # noqa: E731
 
@@ -481,84 +511,100 @@ def _hc_pre(n: int, iters: int, eps: float, norm_eps: float, clamp,
 
     def forward(x, phi, alpha, beta):
         wide = wide_dtype(x.dtype)
-        with part_scope("hc.gates"):
-            # vec(X) Phi stream by stream, X as it lies; the SMALL result
-            # turned so that the token axis is last
-            proj = jnp.moveaxis(sum(
-                lax.dot_general(stored(x[:, i], wide), stored(phi[i], wide),
-                                (((2,), (0,)), ((), ())),
-                                preferred_element_type=wide)
-                for i in range(n)), 2, 0)                       # [K, B, T]
-            xw = x.astype(wide)
-            inv = lax.rsqrt(jnp.mean(xw * xw, axis=(1, 3)) + norm_eps)
-            h_pre, h_post, m = gates(proj, inv, alpha.astype(wide),
+        if kernels:
+            with part_scope("hc.read"):
+                u, proj, inv = K.pre_fwd(
+                    x, K.phi_transposed(phi), alpha.astype(wide),
+                    beta.astype(wide), norm_eps=norm_eps)
+                proj = jnp.moveaxis(proj, 2, 0)                 # [K, B, T]
+            with part_scope("hc.gates"):
+                _, h_post, m = gates(proj, inv, alpha.astype(wide),
                                      beta.astype(wide))
-            # what meets the streams leaves as one column a token ([B, T,
-            # ..]: the token axis where the streams have it), so that XLA
-            # turns these small tensors and not the streams
-            read = jnp.moveaxis(h_pre, 0, 2)                    # [B, T, n]
-        with part_scope("hc.read"):
-            u = sum(read[..., i:i + 1] * xi
-                    for i, xi in enumerate(_streams_of(x)))
-        return ((u.astype(x.dtype), jnp.moveaxis(h_post, 0, 2),
-                 jnp.transpose(m, (2, 3, 0, 1))),
-                (x, phi, alpha, beta, proj, inv))
+        else:
+            with part_scope("hc.gates"):
+                # vec(X) Phi stream by stream, X as it lies; the SMALL
+                # result turned so that the token axis is last
+                proj = jnp.moveaxis(sum(
+                    lax.dot_general(stored(x[:, i], wide),
+                                    stored(phi[i], wide),
+                                    (((2,), (0,)), ((), ())),
+                                    preferred_element_type=wide)
+                    for i in range(n)), 2, 0)                   # [K, B, T]
+                xw = x.astype(wide)
+                inv = lax.rsqrt(jnp.mean(xw * xw, axis=(1, 3)) + norm_eps)
+                h_pre, h_post, m = gates(proj, inv, alpha.astype(wide),
+                                         beta.astype(wide))
+                # what meets the streams leaves as one column a token ([B,
+                # T, ..]: the token axis where the streams have it), so
+                # that XLA turns these small tensors and not the streams
+                read = jnp.moveaxis(h_pre, 0, 2)                # [B, T, n]
+            with part_scope("hc.read"):
+                u = sum(read[..., i:i + 1] * xi
+                        for i, xi in enumerate(_streams_of(x))).astype(
+                            x.dtype)
+        return (u, jnp.moveaxis(h_post, 0, 2),
+                jnp.transpose(m, (2, 3, 0, 1)), proj, inv)
 
-    @jax.custom_vjp
-    def pre(x, phi, alpha, beta):
-        return forward(x, phi, alpha, beta)[0]
-
-    def backward(kept, cts):
-        x, phi, alpha, beta, proj, inv = kept
-        du, dh_post, dm = cts
+    def backward(x, phi, alpha, beta, proj, inv, du, dh_post, dm):
         wide = wide_dtype(x.dtype)
-        du = du.astype(wide)
-        xs = _streams_of(x)
         with part_scope("hc.gates"):
             (h_pre, _, _), back = jax.vjp(gates, proj, inv,
                                           alpha.astype(wide),
                                           beta.astype(wide))
         with part_scope("hc.read"):
-            dh_pre = jnp.stack([_over_columns(du, xi) for xi in xs])
+            if kernels:
+                du = du.astype(x.dtype)
+                dh_pre = jnp.moveaxis(K.pre_bwd_a(x, du), 2, 0)
+            else:
+                du, xs = du.astype(wide), _streams_of(x)
+                dh_pre = jnp.stack([_over_columns(du, xi) for xi in xs])
         with part_scope("hc.gates"):
             dproj, dinv, dalpha, dbeta = back(
                 (dh_pre, jnp.moveaxis(dh_post.astype(wide), 2, 0),
                  jnp.transpose(dm.astype(wide), (2, 3, 0, 1))))
             dproj = jnp.moveaxis(dproj, 0, 2)                   # [B, T, K]
-            dphi = jnp.stack([
-                lax.dot_general(stored(x[:, i], wide), dproj,
-                                (((0, 1), (0, 1)), ((), ())),
-                                preferred_element_type=wide)
-                for i in range(n)])                             # [n, C, K]
-            through = [lax.dot_general(dproj, phi[i].astype(wide),
-                                       (((2,), (1,)), ((), ())))
-                       for i in range(n)]                       # [B, T, C]
             # d inv / d x = -inv^3 x / (n C); a column a token, as `read`
             cols = jnp.stack(
                 [*h_pre, -dinv * inv * inv * inv / (n * x.shape[3])], axis=-1)
+            if not kernels:
+                dphi = jnp.stack([
+                    lax.dot_general(stored(x[:, i], wide), dproj,
+                                    (((0, 1), (0, 1)), ((), ())),
+                                    preferred_element_type=wide)
+                    for i in range(n)])                         # [n, C, K]
+                through = [lax.dot_general(dproj, phi[i].astype(wide),
+                                           (((2,), (1,)), ((), ())))
+                           for i in range(n)]                   # [B, T, C]
         with part_scope("hc.read"):
-            dx = jnp.stack([
-                (cols[..., i:i + 1] * du + cols[..., n:] * xi + t).astype(
-                    x.dtype)
-                for i, (xi, t) in enumerate(zip(xs, through))], axis=1)
+            if kernels:
+                dx, dphi = K.pre_bwd_b(x, du, cols, dproj,
+                                       K.phi_transposed(phi))
+            else:
+                dx = jnp.stack([
+                    (cols[..., i:i + 1] * du + cols[..., n:] * xi
+                     + t).astype(x.dtype)
+                    for i, (xi, t) in enumerate(zip(xs, through))], axis=1)
         return (dx, dphi.astype(phi.dtype),
                 dalpha.astype(alpha.dtype), dbeta.astype(beta.dtype))
 
-    pre.defvjp(forward, backward)
-    return pre
+    return forward, backward
 
 
-def _hc_post():
-    """`hyper_connection_post` on (X [B, n, T, C], Y, H_post [B, T, n], M
-    [B, T, n, n]) with a backward written out: the new streams, and the
-    streams' gradient, stream by stream and stacked once; the gates'
-    gradients sums over a token's columns."""
-    import jax
+def _hc_post(kernels: bool):
+    """(forward, backward) of `hyper_connection_post` on (X [B, n, T, C],
+    Y, H_post [B, T, n], M [B, T, n, n]), both written out: the new
+    streams, and the streams' gradient, stream by stream and stacked once;
+    the gates' gradients sums over a token's columns.  `kernels`: one
+    kernel of ops/pallas_kernels/hyper_connection.py each."""
     import jax.numpy as jnp
 
-    def write(x, y, h_post, m):
-        yw = y.astype(wide_dtype(x.dtype))
+    from .pallas_kernels import hyper_connection as K
+
+    def forward(x, y, h_post, m):
         with part_scope("hc.write"):
+            if kernels:
+                return K.post_fwd(x, y, h_post, m)
+            yw = y.astype(wide_dtype(x.dtype))
             # Out[:, i] = sum_j M[i, j] X[:, j] + H_post[i] Y
             xs = _streams_of(x)
             return jnp.stack([
@@ -566,32 +612,73 @@ def _hc_post():
                     h_post[:, :, i, None] * yw).astype(x.dtype)
                 for i in range(len(xs))], axis=1)
 
-    @jax.custom_vjp
-    def post(x, y, h_post, m):
-        return write(x, y, h_post, m)
-
-    def backward(kept, dout):
-        x, y, h_post, m = kept
-        xs, yw = _streams_of(x), y.astype(wide_dtype(x.dtype))
+    def backward(x, y, h_post, m, dout):
         with part_scope("hc.write"):
-            ds = _streams_of(dout)
-            # dX[:, j] = sum_i M[i, j] dOut[:, i]
-            dx = jnp.stack([
-                sum(m[:, :, i, j, None] * ds[i]
-                    for i in range(len(ds))).astype(x.dtype)
-                for j in range(len(ds))], axis=1)
-            dy = sum(h_post[:, :, i, None] * di for i, di in enumerate(ds))
-            dh_post = jnp.stack([_over_columns(d, yw) for d in ds], axis=-1)
-            dm = jnp.stack([jnp.stack([_over_columns(d, xj) for xj in xs],
-                                      axis=-1) for d in ds], axis=-2)
+            if kernels:
+                dx, dy, dh_post, dm = K.post_bwd(x, y, dout.astype(x.dtype),
+                                                 h_post, m)
+            else:
+                xs, yw = _streams_of(x), y.astype(wide_dtype(x.dtype))
+                ds = _streams_of(dout)
+                # dX[:, j] = sum_i M[i, j] dOut[:, i]
+                dx = jnp.stack([
+                    sum(m[:, :, i, j, None] * ds[i]
+                        for i in range(len(ds))).astype(x.dtype)
+                    for j in range(len(ds))], axis=1)
+                dy = sum(h_post[:, :, i, None] * di
+                         for i, di in enumerate(ds))
+                dh_post = jnp.stack([_over_columns(d, yw) for d in ds],
+                                    axis=-1)
+                dm = jnp.stack([jnp.stack([_over_columns(d, xj)
+                                           for xj in xs], axis=-1)
+                                for d in ds], axis=-2)
         return (dx.astype(x.dtype), dy.astype(y.dtype),
                 dh_post.astype(h_post.dtype), dm.astype(m.dtype))
 
-    post.defvjp(lambda *a: (write(*a), a), backward)
-    return post
+    return forward, backward
 
 
-@register_op("hyper_connection_pre")
+def _hc_kernels(ctx, x, op: str) -> bool:
+    """Whether the hyper-connection op `op` on X [B, n, T, C] takes the
+    kernels: one TPU, no mesh, kernels not disabled, and a shape they take
+    (ops/pallas_kernels/hyper_connection.py `usable`).  Counts the
+    emission by the path taken."""
+    from .pallas_kernels import hyper_connection as K
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    _, n, T, C = x.shape
+    kernels = pallas_dispatch_ok(ctx) and K.usable(n, T, C, x.dtype)
+    if not ctx.in_grad_replay():
+        _MET_HC_KERNELS.inc(op=op, path="pallas" if kernels else "xla")
+    return kernels
+
+
+def _hc_pre_parts(ctx, ins, attrs, op: str):
+    """(X, Phi [n, C, K], Alpha, Beta, the three Phi slots' tensors,
+    `_hc_pre`'s pair) of a `hyper_connection_pre` op or its grad op."""
+    import jax.numpy as jnp
+
+    x = ins["X"][0]
+    n = int(attrs["streams"])
+    phis = [ins[k][0] for k in ("PhiPre", "PhiPost", "PhiRes")]
+    if x.ndim != 4 or x.shape[1] != n or [p.shape for p in phis] != [
+            (n * x.shape[3], k) for k in (n, n, n * n)]:
+        raise ValueError(f"hyper_connection_pre: X {x.shape} is not [B, "
+                         f"{n} streams, T, C] for Phi "
+                         f"{[p.shape for p in phis]}")
+    phi = jnp.concatenate(phis, axis=1).astype(x.dtype).reshape(
+        n, x.shape[3], (2 + n) * n)
+    fns = _hc_pre(
+        n, int(attrs["sinkhorn_iters"]), float(attrs["epsilon"]),
+        float(attrs["norm_epsilon"]),
+        (float(attrs["clamp_min"]), float(attrs["clamp_max"])),
+        ctx.target_platform() == "tpu", _hc_kernels(ctx, x, op))
+    return x, phi, ins["Alpha"][0], ins["Beta"][0], phis, fns
+
+
+@register_op("hyper_connection_pre",
+             grad=_own_grad_maker("hyper_connection_pre_grad",
+                                  kept=("Proj", "Inv")))
 def hyper_connection_pre(ctx, ins, attrs):
     """What a sub-layer reads of n residual streams, and how its result
     goes back (manifold-constrained hyper-connections, mHC,
@@ -615,52 +702,112 @@ def hyper_connection_pre(ctx, ins, attrs):
     -> U (X's dtype), HPost [B, T, n] and HRes [B, T, n, n] (HRes[b, t, i,
     j] = M_t[i, j]; both at least float32; inside, the gates and the
     iterations run with the token axis last, on the lanes, and leave as one
-    column a token, the orientation the streams meet them in).  Everything
-    but the product X Phi is at least float32 whatever X's dtype; on a TPU
-    the product takes X and Phi as they are stored and accumulates in
-    float32, so bf16 operands enter it exactly and X is read at its own
-    width (elsewhere both are widened first: XLA's CPU runtime has no bf16
-    x bf16 -> f32 product); the norm's factor is a token's scalar and is
-    applied to the product.  The backward is written out (`_hc_pre`).  attrs: streams (n), sinkhorn_iters, epsilon,
-    norm_epsilon, clamp_min, clamp_max."""
+    column a token, the orientation the streams meet them in), and what
+    the grad op `hyper_connection_pre_grad` needs besides the inputs: Proj
+    [n + n + n n, B, T], the raw product vec(X) Phi, and Inv [B, T], the
+    norm's factor (both at least float32; no gradient flows into them).
+    Everything but the product X Phi is at least float32 whatever X's
+    dtype; on a TPU the product takes X and Phi as they are stored and
+    accumulates in float32, so bf16 operands enter it exactly and X is read
+    at its own width (elsewhere both are widened first: XLA's CPU runtime
+    has no bf16 x bf16 -> f32 product); the norm's factor is a token's
+    scalar and is applied to the product.
+
+    On one TPU, with C in 128s, T in whole token tiles and bf16 or float32
+    streams, one Pallas kernel reads the streams once for the product, the
+    statistic and the weighted read (ops/pallas_kernels/
+    hyper_connection.py; the gates and the Sinkhorn iterations stay XLA's);
+    everywhere else (the CPU, a mesh, other shapes) plain jax.numpy
+    (`_hc_pre`); `hyper_connection_kernels_traced_total` says which.
+    attrs: streams (n), sinkhorn_iters, epsilon, norm_epsilon, clamp_min,
+    clamp_max."""
+    x, phi, alpha, beta, _, (forward, _) = _hc_pre_parts(ctx, ins, attrs,
+                                                         "pre")
+    if not ctx.in_grad_replay():
+        _MET_HC_LAYERS.inc(streams=str(x.shape[1]), dim=str(x.shape[3]),
+                           sinkhorn_iters=str(int(attrs["sinkhorn_iters"])))
+    u, h_post, h_res, proj, inv = forward(x, phi, alpha, beta)
+    return {"U": [u], "HPost": [h_post], "HRes": [h_res], "Proj": [proj],
+            "Inv": [inv]}
+
+
+@register_op("hyper_connection_pre_grad", grad=None)
+def hyper_connection_pre_grad(ctx, ins, attrs):
+    """`hyper_connection_pre`'s backward from the forward op's inputs, its
+    outputs Proj and Inv and the cotangents of U, HPost and HRes (absent:
+    zeros): the gates and the Sinkhorn iterations made again from Proj and
+    Inv and differentiated in XLA, the passes over the streams (dH_pre =
+    <dU, X[i]> a token; X@GRAD and Phi's gradient) as the two backward
+    kernels where the forward took its kernel, else plain jax.numpy ->
+    X@GRAD, PhiPre@GRAD, PhiPost@GRAD, PhiRes@GRAD, Alpha@GRAD,
+    Beta@GRAD."""
     import jax.numpy as jnp
 
-    x = ins["X"][0]
-    n = int(attrs["streams"])
-    iters = int(attrs["sinkhorn_iters"])
-    phis = [ins[k][0] for k in ("PhiPre", "PhiPost", "PhiRes")]
-    if x.ndim != 4 or x.shape[1] != n or [p.shape for p in phis] != [
-            (n * x.shape[3], k) for k in (n, n, n * n)]:
-        raise ValueError(f"hyper_connection_pre: X {x.shape} is not [B, "
-                         f"{n} streams, T, C] for Phi "
-                         f"{[p.shape for p in phis]}")
-    if not ctx.in_grad_replay():
-        _MET_HC_LAYERS.inc(streams=str(n), dim=str(x.shape[3]),
-                           sinkhorn_iters=str(iters))
-    phi = jnp.concatenate(phis, axis=1).astype(x.dtype).reshape(
-        n, x.shape[3], (2 + n) * n)
-    u, h_post, h_res = _hc_pre(
-        n, iters, float(attrs["epsilon"]), float(attrs["norm_epsilon"]),
-        (float(attrs["clamp_min"]), float(attrs["clamp_max"])),
-        ctx.target_platform() == "tpu")(
-            x, phi, ins["Alpha"][0], ins["Beta"][0])
-    return {"U": [u], "HPost": [h_post], "HRes": [h_res]}
+    x, phi, alpha, beta, phis, (_, backward) = _hc_pre_parts(
+        ctx, ins, attrs, "pre_grad")
+    if not (ins.get("Proj") and ins.get("Inv")):
+        raise ValueError("hyper_connection_pre_grad: the forward op names "
+                         "no Proj and Inv outputs to keep")
+    B, n, T, _ = x.shape
+    wide = wide_dtype(x.dtype)
+
+    def ct(slot, shape, dtype):   # zeros where nothing reads the output
+        got = (ins.get(slot + GRAD_SUFFIX) or [None])[0]
+        return jnp.zeros(shape, dtype) if got is None else got
+
+    dx, dphi, dalpha, dbeta = backward(
+        x, phi, alpha, beta, ins["Proj"][0], ins["Inv"][0],
+        ct("U", (B, T, x.shape[3]), x.dtype), ct("HPost", (B, T, n), wide),
+        ct("HRes", (B, T, n, n), wide))
+    dphis = jnp.split(dphi.reshape(n * x.shape[3], -1), (n, 2 * n), axis=1)
+    out = {"X" + GRAD_SUFFIX: [dx], "Alpha" + GRAD_SUFFIX: [dalpha],
+           "Beta" + GRAD_SUFFIX: [dbeta]}
+    for slot, p, d in zip(("PhiPre", "PhiPost", "PhiRes"), phis, dphis):
+        out[slot + GRAD_SUFFIX] = [d.astype(p.dtype)]
+    return out
 
 
-@register_op("hyper_connection_post")
-def hyper_connection_post(ctx, ins, attrs):
-    """A sub-layer's result Y [B, T, C] written back into the n streams X
-    [B, n, T, C] through `hyper_connection_pre`'s HPost [B, T, n] and HRes
-    [B, T, n, n]:  Out[i] = sum_j HRes[i, j] X[j] + HPost[i] Y, at least
-    float32 inside and ONE rounding to X's dtype; the backward is written
-    out (`_hc_post`)."""
+def _hc_post_parts(ctx, ins, op: str):
+    """(X, Y, HPost, HRes, `_hc_post`'s pair) of a `hyper_connection_post`
+    op or its grad op."""
     x, y, h_post, h_res = (ins[k][0] for k in ("X", "Y", "HPost", "HRes"))
     n = x.shape[1]
     if (x.ndim != 4 or x.shape[:1] + x.shape[2:] != y.shape
             or h_res.shape[2:] != (n, n)):
         raise ValueError(f"hyper_connection_post: X {x.shape}, Y {y.shape},"
                          f" HRes {h_res.shape}")
-    return {"Out": [_hc_post()(x, y, h_post, h_res)]}
+    return x, y, h_post, h_res, _hc_post(_hc_kernels(ctx, x, op))
+
+
+@register_op("hyper_connection_post",
+             grad=_own_grad_maker("hyper_connection_post_grad"))
+def hyper_connection_post(ctx, ins, attrs):
+    """A sub-layer's result Y [B, T, C] written back into the n streams X
+    [B, n, T, C] through `hyper_connection_pre`'s HPost [B, T, n] and HRes
+    [B, T, n, n]:  Out[i] = sum_j HRes[i, j] X[j] + HPost[i] Y, at least
+    float32 inside and ONE rounding to X's dtype.  One Pallas kernel where
+    `hyper_connection_pre` takes its kernel (one TPU, C in 128s, T in
+    whole token tiles), plain jax.numpy everywhere else (`_hc_post`); the
+    backward is the op `hyper_connection_post_grad`."""
+    x, y, h_post, h_res, (forward, _) = _hc_post_parts(ctx, ins, "post")
+    return {"Out": [forward(x, y, h_post, h_res)]}
+
+
+@register_op("hyper_connection_post_grad", grad=None)
+def hyper_connection_post_grad(ctx, ins, attrs):
+    """`hyper_connection_post`'s backward from its inputs and Out@GRAD:
+    X@GRAD[j] = sum_i HRes[i, j] Out@GRAD[i], Y@GRAD = sum_i HPost[i]
+    Out@GRAD[i], and the gates' gradients, sums over a token's columns
+    (HPost@GRAD[i] = <Out@GRAD[i], Y>, HRes@GRAD[i, j] = <Out@GRAD[i],
+    X[j]>); one kernel where the forward took its kernel, else plain
+    jax.numpy."""
+    x, y, h_post, h_res, (_, backward) = _hc_post_parts(ctx, ins,
+                                                        "post_grad")
+    dx, dy, dh_post, dh_res = backward(
+        x, y, h_post, h_res, ins["Out" + GRAD_SUFFIX][0])
+    return {"X" + GRAD_SUFFIX: [dx], "Y" + GRAD_SUFFIX: [dy],
+            "HPost" + GRAD_SUFFIX: [dh_post],
+            "HRes" + GRAD_SUFFIX: [dh_res]}
 
 
 @register_op("hyper_connection_sum")

@@ -122,8 +122,45 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # hyper_connection_sum, mtp_project: Xing4.0's residual path and its
     # multi-token-prediction module), each numerically checked in
     # test_xing.py
+    # PR 40: +0 (hyper_connection_pre and _post stay checked in
+    # test_xing.py, now through grad ops of their OWN,
+    # `hyper_connection_pre_grad` / `hyper_connection_post_grad`, which
+    # are not differentiable themselves: the test below)
     assert len(diffable) == 157, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
     assert len(checked) == 157 - 11
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("fwd,grad,kept", [
+    ("head_norm_rope", "head_norm_rope_grad", ()),
+    ("hyper_connection_pre", "hyper_connection_pre_grad", ("Proj", "Inv")),
+    ("hyper_connection_post", "hyper_connection_post_grad", ())])
+def test_ops_with_grad_ops_of_their_own(fwd, grad, kept):
+    """An op whose emitter may launch a Pallas kernel brings a grad op of
+    its own type (a re-emitted forward would launch the kernel twice: a
+    Mosaic call is opaque to CSE): the forward is differentiable and
+    numerically checked, its maker yields ONE desc of the grad type that
+    reads the forward's inputs, its kept outputs and the other outputs'
+    cotangents, and the grad op itself is not differentiable."""
+    assert reg.get_op_info(grad).grad is None
+    maker = reg.get_op_info(fwd).grad
+    assert callable(maker)
+    assert fwd in _numerically_checked_ops()
+
+    class Op:
+        type = fwd
+        inputs = {"X": ["x"], "W": ["w"]}
+        outputs = {"Out": ["out"], **{k: [k.lower()] for k in kept}}
+        attrs = {"__uid__": 7, "part": "blk"}
+
+    ((gtype, gins, gouts, gattrs),) = maker(Op, {"x"})
+    assert gtype == grad and gattrs == Op.attrs
+    assert gins == {"X": ["x"], "W": ["w"], "Out@GRAD": ["out@GRAD"],
+                    **{k: [k.lower()] for k in kept}}
+    assert gouts == {"X@GRAD": ["x@GRAD"], "W@GRAD": [""]}
+    assert maker(Op, set()) == []

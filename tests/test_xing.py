@@ -266,7 +266,7 @@ def test_hyper_connection_pre_grad_is_the_numeric_one(slot):
     reference (grad_38, grad_39 above)."""
     ins, attrs = _hc_inputs()
     h = OpTestHarness("hyper_connection_pre", ins, attrs,
-                      out_slots=["U", "HPost", "HRes"])
+                      out_slots=["U", "HPost", "HRes", "Proj", "Inv"])
     h.check_grad(["X", "PhiPre", "PhiPost", "Alpha", "Beta"],
                  output_slot=slot, max_relative_error=1e-2)
 
@@ -281,6 +281,187 @@ def test_hyper_connection_post_and_sum_grads_are_the_numeric_ones():
         ["X", "Y", "HPost", "HRes"], max_relative_error=1e-2)
     OpTestHarness("hyper_connection_sum", {"X": ins["X"]}, {}).check_grad(
         ["X"], max_relative_error=1e-2)
+
+
+def _series(family):
+    from paddle_tpu import observability as obs
+
+    fam = obs.REGISTRY.snapshot()["families"].get(family)
+    return {tuple(sorted(s["labels"].items())): s["value"]
+            for s in (fam["series"] if fam else [])}
+
+
+def _hc_sublayer_step(x, y_gain, params, attrs):
+    """A program of one sub-layer around the two ops: U = pre(X), Y = U *
+    gain, Out = post(X, Y), loss = mean(Out^2)-like; X and the five
+    parameters trainable -> (op types, Out, the six gradients)."""
+    fluid.reset()
+    block = fluid.default_main_program().global_block()
+    names = {"X": "x", **{k: k.lower() for k in params}}
+    for slot, arr in dict(params, X=x).items():
+        block.create_parameter(name=names[slot], shape=arr.shape,
+                               dtype="float32")
+    xv = block.var("x")
+    gain = block.create_var(name="gain", shape=y_gain.shape,
+                            dtype="float32", stop_gradient=True)
+    B, n, Tn, C = x.shape
+    outs = {"U": (B, Tn, C), "HPost": (B, Tn, n), "HRes": (B, Tn, n, n),
+            "Proj": ((2 + n) * n, B, Tn), "Inv": (B, Tn)}
+    made = {k: block.create_var(name=k.lower() + "_out", shape=sh,
+                                dtype="float32",
+                                stop_gradient=k in ("Proj", "Inv"))
+            for k, sh in outs.items()}
+    block.append_op("hyper_connection_pre",
+                    inputs={k: [v] for k, v in names.items()},
+                    outputs={k: [v.name] for k, v in made.items()},
+                    attrs=dict(attrs, part="blk.one"))
+    y = fluid.layers.elementwise_mul(made["U"], gain)
+    new = fluid.layers.hyper_connection_post(xv, y, made["HPost"],
+                                             made["HRes"])
+    loss = fluid.layers.mean(fluid.layers.elementwise_mul(new, new))
+    grads = dict((p.name, g.name) for p, g in fluid.append_backward(loss))
+    scope = fluid.global_scope()
+    for slot, arr in dict(params, X=x).items():
+        scope.set(names[slot], arr)
+    scope.set("gain", y_gain)
+    exe = fluid.Executor(fluid.CPUPlace())
+    got = exe.run(feed={}, fetch_list=[new] + [grads[v]
+                                              for v in names.values()])
+    return ([op.type for op in block.ops], block.ops,
+            [np.asarray(a) for a in got])
+
+
+def _hc_step_operands(n=4, C=128, B=2, Tn=32):
+    x = _rand((B, n, Tn, C), 41)
+    params = {"PhiPre": _rand((n * C, n), 42, 0.05),
+              "PhiPost": _rand((n * C, n), 43, 0.05),
+              "PhiRes": _rand((n * C, n * n), 44, 0.05),
+              "Alpha": np.asarray([0.3, 0.4, 0.5], np.float32),
+              "Beta": _rand(((2 + n) * n,), 45)}
+    attrs = {"streams": n, "sinkhorn_iters": 20, "epsilon": 1e-6,
+             "norm_epsilon": 1e-6, "clamp_min": -30.0, "clamp_max": 30.0}
+    return x, 1.0 + 0.3 * _rand((B, Tn, C), 46), params, attrs
+
+
+def _kernels_on(monkeypatch, launched):
+    """The trace believes in one TPU; the kernels run in interpret mode."""
+    from paddle_tpu.ops.pallas_kernels import hyper_connection as K
+
+    def call(kernel, x, norm_eps=0.0, **_):
+        launched.append(kernel)
+        return K._calls(*x.shape, str(x.dtype), norm_eps, True,
+                        K.TOKEN_TILE)[kernel]
+
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(K, "_call", call)
+
+
+def test_hyper_connection_grads_are_desc_ops_of_their_own():
+    """append_backward gives each of the two ops ONE grad desc of its own
+    type (the forward's inputs, the kept Proj and Inv, the cotangents; the
+    forward's attrs, its part with them) and no `generic_grad`: nothing
+    emits the forward a second time."""
+    x, gain, params, attrs = _hc_step_operands()
+    types, ops, _ = _hc_sublayer_step(x, gain, params, attrs)
+    assert types.count("hyper_connection_pre_grad") == 1
+    assert types.count("hyper_connection_post_grad") == 1
+    assert not [op for op in ops if op.type == "generic_grad"
+                and op.attrs["__fwd_type__"].startswith("hyper_connection")]
+    (pre,) = [op for op in ops if op.type == "hyper_connection_pre_grad"]
+    (post,) = [op for op in ops if op.type == "hyper_connection_post_grad"]
+    assert sorted(pre.inputs) == sorted(
+        ["X", "PhiPre", "PhiPost", "PhiRes", "Alpha", "Beta", "Proj", "Inv",
+         "U@GRAD", "HPost@GRAD", "HRes@GRAD"])
+    assert sorted(pre.outputs) == sorted(
+        k + "@GRAD" for k in ("X", "PhiPre", "PhiPost", "PhiRes", "Alpha",
+                              "Beta"))
+    assert pre.attrs["part"] == "blk.one" and pre.attrs["streams"] == 4
+    assert sorted(post.inputs) == ["HPost", "HRes", "Out@GRAD", "X", "Y"]
+    assert sorted(post.outputs) == ["HPost@GRAD", "HRes@GRAD", "X@GRAD",
+                                    "Y@GRAD"]
+    for name in ("hyper_connection_pre_grad", "hyper_connection_post_grad"):
+        assert reg.get_op_info(name).grad is None
+
+
+def test_hyper_connection_ops_take_the_kernels_on_a_tpu(monkeypatch):
+    """Where the trace targets one TPU each of the four emitters launches
+    its kernels ONCE (the forward's never again in the backward), the
+    numbers are the plain emission's, the counter names the path and
+    `executor_grad_kernel_forward_total` gets no series; the switch sends
+    all four the plain way, to the bit."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops.pallas_kernels import hyper_connection as K
+
+    x, gain, params, attrs = _hc_step_operands()
+    obs.REGISTRY.reset()
+    _, _, want = _hc_sublayer_step(x, gain, params, attrs)
+    four = ("pre", "post", "pre_grad", "post_grad")
+    path = lambda p: {tuple(sorted({"op": o, "path": p}.items())): 1.0  # noqa
+                      for o in four}
+    assert _series("hyper_connection_kernels_traced_total") == path("xla")
+    launched = []
+    _kernels_on(monkeypatch, launched)
+    obs.REGISTRY.reset()
+    _, _, got = _hc_sublayer_step(x, gain, params, attrs)
+    assert launched == [K.PRE_FWD, K.POST_FWD, K.POST_BWD, K.PRE_BWD_A,
+                        K.PRE_BWD_B]
+    assert _series("hyper_connection_kernels_traced_total") == path(
+        "pallas")
+    assert _series("executor_grad_kernel_forward_total") == {}
+    assert _series("hyper_connection_layers_traced_total") == {
+        (("dim", "128"), ("sinkhorn_iters", "20"), ("streams", "4")): 1.0}
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * np.abs(b).max())
+    del launched[:]
+    monkeypatch.setenv("PADDLE_TPU_NO_FUSED_KERNELS", "1")
+    _, _, again = _hc_sublayer_step(x, gain, params, attrs)
+    assert launched == []
+    for a, b in zip(again, want):
+        assert a.tobytes() == b.tobytes()
+
+
+def test_toy_step_is_the_same_under_both_paths(monkeypatch):
+    """`build_hc_mla_moe_lm_train_program` at a width the kernels take:
+    the first step's loss and the gradients of every hyper-connection
+    parameter with the kernels (interpret mode) against the plain
+    emission's, and the two sub-layers of each block (the module's is the
+    last of `layer_types`) launch the five kernels once each."""
+    from paddle_tpu.ops.pallas_kernels import hyper_connection as K
+
+    toy = dict(TOY, dim=128, layer_types=["full_attention"] * 2)
+    tok = np.random.RandomState(3).randint(0, 97, (1, T, 1)).astype(np.int64)
+    feed = {"tokens": tok, "targets": np.roll(tok, -1, 1),
+            "next_targets": np.roll(tok, -2, 1)}
+
+    def step():
+        fluid.reset()
+        loss = tr.build_hc_mla_moe_lm_train_program(**toy)
+        main, startup = (fluid.default_main_program(),
+                         fluid.default_startup_program())
+        main.random_seed = startup.random_seed = 11
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        hc = [p.name + "@GRAD" for p in main.global_block().all_parameters()
+              if p.name.startswith("hyper_connection")]
+        return [np.asarray(a) for a in exe.run(feed=feed,
+                                               fetch_list=[loss] + hc)]
+
+    want = step()
+    launched = []
+    _kernels_on(monkeypatch, launched)
+    got = step()
+    sublayers = 2 * len(toy["layer_types"])
+    assert len(want) == 1 + 5 * sublayers
+    assert sorted(launched) == sorted(list(K.BLOCKS) * sublayers)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    # against the largest gradient of its kind: the first sub-layer reads
+    # four equal streams, and some of its gradients are rounding alone
+    for kind in range(5):
+        scale = max(np.abs(b).max() for b in want[1 + kind::5])
+        for a, b in zip(got[1 + kind::5], want[1 + kind::5]):
+            np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4 * scale)
 
 
 def test_mtp_project_output_and_grad():
