@@ -29,8 +29,11 @@ no MoE).  Two forms of one op:
   `Counts` is over all of them, and the op computes the chosen pairs that
   land on held experts, in a static buffer of `buffer_rows` rows whose
   filled part is data-dependent (`_moe_share`); a shared expert every
-  token passes may ride beside them.  The partial sum is the op's output:
-  nothing stands in for the chips that hold the other experts."""
+  token passes may ride beside them.  The rows leave the buffer for their
+  tokens, forward and backward, by a sum over rows sorted by token (on a
+  TPU the kernel of pallas_kernels/segment_sum.py, `_rows_to_tokens`).
+  The partial sum is the op's output: nothing stands in for the chips
+  that hold the other experts."""
 
 from __future__ import annotations
 
@@ -52,6 +55,15 @@ _MET_SHARE_LAYERS = _MET.counter(
     "emission; once a compile, not once a step), by experts held, experts "
     "routed over, top_k and the rows of the static buffer the held pairs "
     "are computed in")
+_MET_ROWS_TO_TOKENS = _MET.counter(
+    "moe_share_rows_to_tokens_traced_total",
+    "sums of a share's buffer rows into their tokens traced (once a "
+    "compile, not once a step), by op (combine: the forward's weighted "
+    "expert outputs, counted at the forward emission; permute_grad: the "
+    "backward of the row gather, counted in the backward rule or, for "
+    "autodiff's transpose, at generic_grad's re-emission) and by path "
+    "(segment_sum: the kernel segment-sum-rows over token-sorted rows; "
+    "scatter_add: XLA's)")
 _MET_GROUPED_BWD = _MET.counter(
     "moe_grouped_backward_total",
     "grouped expert matmuls whose backward was traced (once a compile, not "
@@ -255,10 +267,16 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
     backward kernels leave rows no group has unwritten, NaN included, and
     a product with zero would keep the NaN (PERF.md, PR 30: a buffer of T x
     top_k rows, three quarters of its tiles unvisited, read NaN gradients
-    until every product's output went through a select).  Tokens reach the buffer by a gather and leave it by a
-    scatter-add, both `rows` wide, and autodiff's transposes are the other
-    of the two.  `shared` = (WI, WU or None, WO) of one expert every token
-    passes, or None."""
+    until every product's output went through a select).  Tokens reach the
+    buffer by a gather `rows` wide (`_tokens_to_rows`), and rows leave it
+    for their tokens, the forward's weighted outputs and the backward's
+    row gradients alike, by a sum over the rows sorted by token
+    (`_rows_to_tokens`: one more sort a layer, of the rows' tokens, serves
+    both; on one TPU at whole tiles the kernel `segment-sum-rows` of
+    pallas_kernels/segment_sum.py, whose visit list is the grouped
+    matmuls' `_visits` with token tiles for groups; elsewhere XLA's
+    scatter-add, as before).  `shared` = (WI, WU or None, WO) of one
+    expert every token passes, or None."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -282,7 +300,8 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
         pairs = jnp.sum(counts[first:first + held])
         filled = (jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
         token = order // top_k
-        xs = jnp.where(filled, x[token], jnp.zeros((), x.dtype))
+        by_token = _token_order(ctx, x, token, filled, w, flat.shape[0])
+        xs = _tokens_to_rows(ctx, x, token, filled, by_token)
 
     def grouped(rows_in, w):
         return jnp.where(filled, _grouped_matmul(ctx, rows_in, w, sizes),
@@ -294,8 +313,11 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
             h = h * grouped(xs, wu).astype(wide)
         ys = grouped(h.astype(x.dtype), wo)                    # [rows, D]
     with part_scope("moe.combine"):
-        out = jnp.zeros((T, D), wide).at[token].add(
-            ys.astype(wide) * w[:, None])
+        if not ctx.in_grad_replay():
+            _MET_ROWS_TO_TOKENS.inc(
+                op="combine",
+                path="scatter_add" if by_token is None else "segment_sum")
+        out = _rows_to_tokens(ys, token, w, filled, T, by_token)
     if shared is not None:
         with part_scope("moe.shared"):
             si, su, so = shared
@@ -309,6 +331,106 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
     return (out.astype(x.dtype), scores, lax.top_k(weights, top_k)[0],
             counts.astype(jnp.float32), as_f32(pairs),
             as_f32(pairs - ends[-1]))
+
+
+def _token_order(ctx, x, token, filled, w, pairs: int):
+    """What `_rows_to_tokens` needs to take the kernel, made once a layer
+    for its two calls: pallas_kernels/segment_sum.py `token_order` of the
+    buffer's rows (their tokens ascending, the permutation, the weights in
+    that order, the rows of each token tile), its sort as long as
+    `_sort_carrying`'s of the `pairs`.  None where the trace does not
+    target one TPU or the shapes are not whole tiles: then the rows leave
+    by XLA's scatter-add."""
+    from .pallas_kernels import segment_sum
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    if not (pallas_dispatch_ok(ctx) and segment_sum.usable(
+            token.shape[0], x.shape[0], x.shape[1], x.dtype.itemsize)):
+        return None
+    return segment_sum.token_order(token, filled[:, 0], w, x.shape[0],
+                                   sort_length=pairs)
+
+
+def _tokens_to_rows(ctx, x, token, filled, by_token):
+    """x [T, D] -> [R, D]: row r is x[token[r]], zero where not `filled`
+    [R, 1].  Backward: the rows' gradients summed into their tokens,
+    `_rows_to_tokens` without weights in x's dtype (by_token given), or
+    autodiff's transpose of the gather, a scatter-add (None)."""
+    import jax
+    import jax.numpy as jnp
+
+    def take(x, token, filled):
+        return jnp.where(filled, x[token], jnp.zeros((), x.dtype))
+
+    if by_token is None:
+        if ctx.in_grad_replay():
+            _MET_ROWS_TO_TOKENS.inc(op="permute_grad", path="scatter_add")
+        return take(x, token, filled)
+
+    @jax.custom_vjp
+    def rows_of(x, token, filled, by_token):
+        return take(x, token, filled)
+
+    def bwd(res, g):
+        token, filled, by_token = res
+        _MET_ROWS_TO_TOKENS.inc(op="permute_grad", path="segment_sum")
+        return (_rows_to_tokens(g, token, None, filled, x.shape[0],
+                                by_token), None, None, None)
+
+    rows_of.defvjp(lambda x, token, filled, by_token: (
+        take(x, token, filled), (token, filled, by_token)), bwd)
+    return rows_of(x, token, filled, by_token)
+
+
+def _rows_to_tokens(rows_in, token, weight, filled, tokens: int, by_token):
+    """rows_in [R, D], token [R], weight [R] float32 or None, filled [R, 1]
+    -> [tokens, D]: out[t] = the sum of rows_in[r] (times weight[r]) over
+    the filled rows of token t; weighted, the products and the sum are
+    float32 and so is the result, else the sum is float32 and the result
+    rows_in's dtype after one rounding.
+
+    by_token None (the weighted sum only): `zeros.at[token].add(...)`,
+    XLA's scatter-add (a row that is not filled must hold zeros), under
+    plain autodiff.  Else (`_token_order`): the rows gathered into token
+    order and summed by the kernel `segment-sum-rows`, whose backward is
+    what autodiff gives the scatter-add: d rows_in = g[token] * weight,
+    d weight[r] = <g[token[r]], rows_in[r]> in float32, both zero where
+    not filled."""
+    import jax
+    import jax.numpy as jnp
+
+    from .pallas_kernels import segment_sum
+
+    if by_token is None:
+        return jnp.zeros((tokens, rows_in.shape[1]), weight.dtype).at[
+            token].add(rows_in.astype(weight.dtype) * weight[:, None])
+
+    def run(rows_in, by_token, weighted=True):
+        seg, perm, weight_sorted, counts = by_token
+        return segment_sum.segment_sum(
+            rows_in[perm], seg, counts, tokens,
+            weight_sorted if weighted else None)
+
+    if weight is None:
+        return run(rows_in, by_token, weighted=False)
+
+    @jax.custom_vjp
+    def summed(rows_in, weight, token, filled, by_token):
+        return run(rows_in, by_token)
+
+    def bwd(res, g):
+        rows_in, weight, token, filled = res
+        g = g[token]
+        return (jnp.where(filled, g * weight[:, None], 0.0).astype(
+                    rows_in.dtype),
+                jnp.where(filled[:, 0], jnp.sum(
+                    g * rows_in.astype(g.dtype), axis=-1), 0.0).astype(
+                        weight.dtype),
+                None, None, None)
+
+    summed.defvjp(lambda rows_in, weight, token, filled, by_token: (
+        run(rows_in, by_token), (rows_in, weight, token, filled)), bwd)
+    return summed(rows_in, weight, token, filled, by_token)
 
 
 def _sort_carrying(key, values, rows: int):

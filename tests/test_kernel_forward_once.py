@@ -532,3 +532,65 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     print("AOT olmoe step peak_bytes: parent", _peak_bytes(parent),
           "change", _peak_bytes(change))
     assert _peak_bytes(change) <= _peak_bytes(parent)
+
+
+SHARE_ROWS = "moe_share_rows_to_tokens_traced_total"
+
+
+def test_aot_share_rows_leave_the_buffer_by_two_kernel_calls_a_layer(
+        v5e, monkeypatch):
+    """Moonlight's share at its published widths, cut to the dense layer
+    and ONE expert layer and to T 2048 (XLA:TPU compiles the cell's sorts
+    of 49152 pairs for 25 s; 8 of 64 experts held, a 3072-row buffer,
+    bf16): the compiled step holds TWO `segment-sum-rows` calls, the
+    forward combine's and the row gather's backward (not three: the
+    forward that generic_grad re-emits feeds nothing and goes), no scatter
+    whose result is [T, D] is left in the expert layer, the counter reads
+    the kernel for both, and the `moe` op has no series among the grad
+    ops that launched a kernel's forward again.  With the gate closed the
+    same step holds the parent's two scatter-adds."""
+    from paddle_tpu.models.transformer import build_mla_moe_lm_train_program
+    from paddle_tpu.ops.pallas_kernels import segment_sum as ss
+
+    t, dim, rows = 2048, 2048, 3072
+    args = dict(seq_len=t, vocab_size=1024, dim=dim, n_layers=2, n_heads=16,
+                kv_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_dim=128,
+                dense_dim=1024, dense_layers=1, num_experts=64,
+                expert_dim=1408, top_k=6, shared_experts=2, held_experts=8,
+                buffer_rows=rows, routed_scale=2.446, dtype="bfloat16")
+
+    def lowered():
+        fluid.reset()
+        loss = build_mla_moe_lm_train_program(**args)
+        return _lowered_step(loss, v5e, batch=1, seq_len=t)
+
+    def series():
+        fam = obs.REGISTRY.snapshot()["families"].get(SHARE_ROWS)
+        return {(s["labels"]["op"], s["labels"]["path"]): s["value"]
+                for s in (fam["series"] if fam else [])}
+
+    on_tokens = rf"tensor<{t}x{dim}x(?:f32|bf16)>"
+    text = lowered().compile().as_text()
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sum(ss.NAME in name for name in calls) == 2, calls
+    # no scatter onto [T, D] that the combine or the permute part made
+    assert not [line for line in text.splitlines() if re.search(
+        rf" = (?:f32|bf16)\[{t},{dim}\][^=]* scatter\(", line)
+        and re.search(r"pdtpu\.moe\.(?:combine|permute)", line)]
+    assert series() == {("combine", "segment_sum"): 1.0,
+                        ("permute_grad", "segment_sum"): 1.0}
+    # the attention op's kept pair, and nothing of the expert layer's
+    assert _counter() == {("latent_attention", "1"): 2.0}
+
+    # the gate closed: the step as JAX hands it to XLA (not compiled: 40 s)
+    monkeypatch.setattr(ss, "usable", lambda *shape: False)
+    text = lowered().as_text()
+    assert ss.NAME not in text
+    # a scatter's last line: its region closes, then (operand, indices,
+    # updates) -> result
+    assert len(re.findall(
+        rf"\}}\) : \({on_tokens}, tensor<{rows}x1xi32>, "
+        rf"tensor<{rows}x{dim}x(?:f32|bf16)>\) -> {on_tokens}", text)) == 2
+    assert series() == {("combine", "scatter_add"): 1.0,
+                        ("permute_grad", "scatter_add"): 1.0}

@@ -458,3 +458,108 @@ def test_rows_no_group_has_never_reach_a_result(monkeypatch):
                     jax.tree_util.tree_leaves(clean)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# rows to tokens: the kernel `segment-sum-rows` against XLA's scatter-add
+
+
+SHARE_ROWS = "moe_share_rows_to_tokens_traced_total"
+
+
+def _share_step(dtype, buffer_rows, layers=2):
+    """`layers` gated share layers (4 of 8 experts held from 2 on, sigmoid
+    scores, a selection bias, a shared expert) under SGD, one step: the
+    last layer's Out and RouterWeights, then the gradients of its input
+    and of its trainable parameters (router, held Wgate, Wup, Wdown, the
+    shared expert's three)."""
+    from paddle_tpu.framework.initializer import NormalInitializer
+
+    tokens, dim = 128, 128
+    fluid.reset()
+    x = fluid.layers.data("x", shape=[dim], dtype=dtype)
+    # a projection first: every layer's input then wants its gradient
+    h = fluid.layers.fc(x, dim, bias_attr=False)
+    for _ in range(layers):
+        before = len(fluid.default_main_program().global_block()
+                     .all_parameters())
+        inp = h
+        share = fluid.layers.moe(
+            inp, 8, 64, act="silu", top_k=2, gated=True, dropless=True,
+            held=(2, 4), scoring="sigmoid", renormalise=True,
+            routed_scale=2.0, select_bias=NormalInitializer(scale=0.05),
+            buffer_rows=buffer_rows, shared_hidden=32)
+        h = inp + share.out
+    wide = fluid.layers.cast(h, "float32")
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(
+        fluid.layers.mean(wide * wide))
+    main = fluid.default_main_program()
+    params = [p for p in main.global_block().all_parameters()[before:]
+              if p.trainable]
+    assert [len(p.shape) for p in params] == [2, 3, 3, 3, 2, 2, 2]
+    fetch = [share.out, share.weights, share.dropped_pairs, inp.name + "@GRAD"] + [
+        p.name + "@GRAD" for p in params]
+    exe = fluid.Executor(fluid.CPUPlace())
+    main.random_seed = fluid.default_startup_program().random_seed = 41
+    exe.run(fluid.default_startup_program())
+    feed = {"x": _rand((tokens, dim), 5).astype(dtype)}
+    return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
+
+
+@pytest.mark.parametrize("buffer_rows", [None, 96],
+                         ids=["a_roomy_buffer", "a_buffer_that_drops_pairs"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_share_with_the_segment_sum_kernel_equals_the_scatter_add(
+        monkeypatch, dtype, buffer_rows):
+    """The `moe` op's share form through the executor, forward and
+    backward, with the kernel path forced through interpret mode (the
+    trace claims a TPU target; the grouped matmuls keep autodiff's
+    transposes, so the two scatter-adds a layer are ALL that differs):
+    Out, RouterWeights and every gradient agree with the fallback's, the
+    counter names the path for both sums of both layers, and the `moe` op
+    launches no kernel's forward again."""
+    import functools
+
+    from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
+    from paddle_tpu.ops.pallas_kernels import segment_sum as ss
+
+    def paths():
+        return {(s[0]["op"], s[0]["path"]): s[1]
+                for s in _series(SHARE_ROWS)}
+
+    layers = 2
+    fallback = _share_step(dtype, buffer_rows, layers)
+    assert paths() == {("combine", "scatter_add"): float(layers),
+                       ("permute_grad", "scatter_add"): float(layers)}
+
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(gm, "usable", lambda *shape: False)
+    monkeypatch.setattr(ss, "ROW_TILE", 32)
+    monkeypatch.setattr(ss, "segment_sum",
+                        functools.partial(ss.segment_sum, interpret=True))
+    kernel = _share_step(dtype, buffer_rows, layers)
+    assert paths() == {("combine", "segment_sum"): float(layers),
+                       ("permute_grad", "segment_sum"): float(layers)}
+    assert _series("executor_grad_kernel_forward_total") == []
+
+    assert (float(kernel[2][0]) > 0) == (buffer_rows is not None)
+    np.testing.assert_array_equal(kernel[2], fallback[2])   # DroppedPairs
+    names = ("Out", "RouterWeights", "DroppedPairs", "X", "Gate", "WI", "WU",
+             "WO", "SI", "SU", "SO")
+    for name, a, b in zip(names, kernel, fallback):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0 or name == "DroppedPairs", name
+        if dtype == "float32":
+            # float32 sums in another order
+            np.testing.assert_allclose(a, b, rtol=1e-5,
+                                       atol=1e-6 * np.abs(b).max(),
+                                       err_msg=name)
+        else:
+            # x's gradient is rounded to bf16 once where the scatter-add
+            # rounds at every add, and the first layer's gradients carry
+            # that on
+            np.testing.assert_allclose(a, b, rtol=2.0 ** -6,
+                                       atol=2.0 ** -7 * np.abs(b).max(),
+                                       err_msg=name)
