@@ -37,7 +37,6 @@ from .lod import LoDTensor  # noqa: F401
 Tensor = LoDTensor  # reference fluid alias (__init__.py Tensor)
 from . import analysis  # noqa: F401  (program verifier: fluid.analysis.verify_program)
 from . import observability  # noqa: F401  (metrics registry + step tracing)
-from . import autotune  # noqa: F401  (analyzer-guided tuner; import-light)
 from .memory_optimization_transpiler import memory_optimize, release_memory  # noqa: F401
 from .inference_transpiler import InferenceTranspiler, fuse_batch_norm  # noqa: F401
 from .framework import initializer  # noqa: F401
@@ -80,6 +79,6 @@ def reset():
     from .v1 import reset_v1_config
 
     reset_v1_config()
-    # telemetry: fresh metric series / trace ring / tracked programs so
+    # telemetry: fresh metric series / trace ring so
     # tests and benches never read a previous run's counters
     observability.reset()

@@ -58,10 +58,7 @@ _DTYPE_RATE = {"bfloat16": 1.0, "float16": 1.0,
 
 # Per-DISPATCH host overhead floor (seconds): tracing-free jit call +
 # transfer setup + fetch sync — what one Executor.run pays beyond the
-# device step itself.  Defaults are deliberately coarse priors; when
-# the PR 16 calibration store holds measured per-op affine intercepts
-# for the chip, `step_loop_cost` prices with their SUM instead (that
-# sum is exactly what `calibrated_step_time_s` adds once per dispatch).
+# device step itself.  Deliberately coarse priors (`step_loop_cost`).
 DEFAULT_DISPATCH_OVERHEAD_S: Dict[str, float] = {
     "v4": 8e-5, "v5e": 8e-5, "v5p": 8e-5, "v6e": 8e-5,
     "cpu-host": 1.5e-4,
@@ -186,43 +183,19 @@ def op_cost(block, op, batch_size: int = 64) -> dict:
 # program roll-up
 
 
-def _calibration_factors(chip: str, calibration: Optional[bool]) -> dict:
-    """The chip's stored correction factors, or {} when the calibrated
-    layer is off (arg False, or arg None + $PADDLE_TPU_CALIBRATION=0)
-    or nothing has been learned yet."""
-    if calibration is False:
-        return {}
-    from ..observability import calibration as _calib
-
-    if calibration is None and not _calib.calibration_enabled():
-        return {}
-    return _calib.default_store().factors(chip)
-
-
 def program_cost(program, batch_size: int = 64, block_id: int = 0,
-                 chip: Optional[str] = None,
-                 calibration: Optional[bool] = None) -> dict:
+                 chip: Optional[str] = None) -> dict:
     """Roofline report for one block: totals, a per-op-type table (by
     FLOPs, descending), arithmetic intensity, and the predicted step
-    time/MFU ceiling for `chip` (see module docstring for the model).
-
-    `calibration`: None defers to $PADDLE_TPU_CALIBRATION (default on);
-    when factors exist for this chip the report ADDS
-    ``calibrated_step_time_s`` (per-op roofline times priced through
-    the measured per-(op type, dtype) affine corrections — ``factor *
-    t_op + overhead_s`` — from observability/calibration.py, summed)
-    beside the raw model — the
-    raw keys never change, so uncalibrated consumers are unaffected."""
+    time/MFU ceiling for `chip` (see module docstring for the model)."""
     block = program.blocks[block_id]
     spec = chip_spec(chip)
     peak = spec["flops_bf16"]
     bw = spec["hbm_gbps"] * 1e9
-    factors = _calibration_factors(spec["chip"], calibration)
     by_type: Dict[str, dict] = {}
     flops_by_dtype: Dict[str, int] = {}
     tot_flops = tot_bytes = tot_coll = 0
-    per_op_time = cal_time = overhead_total = 0.0
-    applied = 0
+    per_op_time = 0.0
     unmodeled = 0
     for op in block.ops:
         c = op_cost(block, op, batch_size)
@@ -239,32 +212,18 @@ def program_cost(program, batch_size: int = 64, block_id: int = 0,
         dt = c["dtype"] or "float32"
         flops_by_dtype[dt] = flops_by_dtype.get(dt, 0) + c["flops"]
         # per-op roofline time (max of the op's own compute/memory
-        # legs): Σ over ops is the no-overlap-across-ops variant the
-        # calibration factors scale; the raw headline below keeps the
-        # perfect-overlap max-of-sums model
+        # legs): Σ over ops is the no-overlap-across-ops variant; the
+        # headline below keeps the perfect-overlap max-of-sums model
         rate = peak * _DTYPE_RATE.get(dt, 0.5)
         t_op = max(c["flops"] / rate if rate else 0.0,
                    c["bytes"] / bw if bw else 0.0)
         per_op_time += t_op
-        if factors:
-            from ..observability import calibration as _calib
-
-            ent = factors.get(_calib.factor_key(op.type, dt))
-            if ent:
-                # affine: the fitted overhead_s charges the per-op
-                # dispatch floor a ratio cannot see (calibration.py)
-                overhead_total += float(ent.get("overhead_s") or 0.0)
-                cal_time += (float(ent["factor"]) * t_op
-                             + float(ent.get("overhead_s") or 0.0))
-                applied += 1
-            else:
-                cal_time += t_op
 
     t_compute = sum(f / (peak * _DTYPE_RATE.get(dt, 0.5))
                     for dt, f in flops_by_dtype.items() if f)
     t_memory = tot_bytes / bw if bw else 0.0
     step = max(t_compute, t_memory)
-    report = {
+    return {
         "batch_size": int(batch_size),
         "block_id": int(block_id),
         "chip": spec["chip"],
@@ -287,16 +246,6 @@ def program_cost(program, batch_size: int = 64, block_id: int = 0,
         "by_type": dict(sorted(by_type.items(),
                                key=lambda kv: -kv[1]["flops"])),
     }
-    if factors and applied:
-        report["calibrated_step_time_s"] = cal_time
-        report["calibration"] = {"chip": spec["chip"],
-                                 "factors_applied": int(applied),
-                                 "factors_known": len(factors),
-                                 # the per-dispatch share of the affine
-                                 # fits: what one fused K-step loop pays
-                                 # ONCE instead of K times (step_loop_cost)
-                                 "overhead_s_total": overhead_total}
-    return report
 
 
 def roofline_with_comm(report: dict, comm: dict,
@@ -334,7 +283,6 @@ def roofline_with_comm(report: dict, comm: dict,
 
 def step_loop_cost(program, k: int, batch_size: int = 64,
                    block_id: int = 0, chip: Optional[str] = None,
-                   calibration: Optional[bool] = None,
                    overhead_s: Optional[float] = None) -> dict:
     """Price a fused K-step dispatch (framework/step_loop.py) against K
     sequential dispatches of the same program:
@@ -342,31 +290,18 @@ def step_loop_cost(program, k: int, batch_size: int = 64,
         fused      = K * step + 1 * overhead_s
         sequential = K * (step + overhead_s)
 
-    `step` is the pure device step (calibrated when the store has
-    factors for this chip — with the affine intercepts REMOVED, since
-    they are the per-dispatch share being amortized); `overhead_s` is
-    the per-dispatch host floor (explicit arg > calibration intercept
-    sum > DEFAULT_DISPATCH_OVERHEAD_S for the chip).  The predicted
-    speedup `sequential / fused` is the rankable quantity `paddle tune
-    step_loop` prices K candidates with, and the bench `step_loop`
-    sweep publishes predicted-vs-measured error against."""
+    `step` is the roofline's pure device step; `overhead_s` is the
+    per-dispatch host floor (the explicit arg, else
+    DEFAULT_DISPATCH_OVERHEAD_S for the chip).  The predicted speedup
+    `sequential / fused` ranks K candidates."""
     if int(k) < 1:
         raise ValueError(f"steps_per_dispatch k={k} must be >= 1")
     k = int(k)
-    rep = program_cost(program, batch_size, block_id, chip, calibration)
-    cal = rep.get("calibration") or {}
-    if overhead_s is None:
-        overhead_s = cal.get("overhead_s_total")
+    rep = program_cost(program, batch_size, block_id, chip)
     if not overhead_s:
         overhead_s = DEFAULT_DISPATCH_OVERHEAD_S.get(rep["chip"], 8e-5)
     overhead_s = float(overhead_s)
-    if "calibrated_step_time_s" in rep:
-        step = max(rep["calibrated_step_time_s"]
-                   - float(cal.get("overhead_s_total") or 0.0), 0.0)
-        step_source = "calibrated"
-    else:
-        step = rep["predicted_step_time_s"]
-        step_source = "roofline"
+    step = rep["predicted_step_time_s"]
     fused = k * step + overhead_s
     sequential = k * (step + overhead_s)
     return {
@@ -375,7 +310,6 @@ def step_loop_cost(program, k: int, batch_size: int = 64,
         "batch_size": int(batch_size),
         "k": k,
         "step_time_s": step,
-        "step_source": step_source,
         "overhead_s": overhead_s,
         "fused_time_s": fused,
         "sequential_time_s": sequential,

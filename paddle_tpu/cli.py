@@ -35,16 +35,9 @@ Usage: python -m paddle_tpu <subcommand> [args]
   metrics DIR|FILE      — run N traced steps of a saved model under the
                           telemetry layer (observability/) and print the
                           metrics registry: Prometheus text, or --json
-                          for the snapshot + predicted-vs-measured report
+                          for the snapshot
   trace DIR|FILE        — same run, writing the Chrome/Perfetto
                           trace-event JSON (open in ui.perfetto.dev)
-  tune WORKLOAD|DIR     — analyzer-guided autotuner (autotune/): rank a
-                          typed search space (kernel blocks, remat,
-                          XLA flags) with the static
-                          cost+HBM analyzers, compile/measure only the
-                          predicted-top-k, persist the winner keyed
-                          like the compile cache so kernels and the
-                          executor pick it up on the next run
   show_pb DIR|FILE      — human-readable dump of blocks/ops/vars
   pserver ...           — host parameter service (distributed/pserver)
   master ...            — fault-tolerant task-dispatch service
@@ -453,11 +446,10 @@ def cmd_diff(args) -> int:
 
 def _telemetry_run(args):
     """Shared runner for the `metrics` and `trace` subcommands: load a
-    saved model, attach predicted-vs-measured accounting, drive N
-    executor steps on deterministic synthetic feeds (the equivalence
-    oracle's feed/state seeding) with the tracer enabled, and record
-    the measured peak.  Returns the observability module, whose
-    registry/tracer/accounting now hold the run."""
+    saved model and drive N executor steps on deterministic synthetic
+    feeds (the equivalence oracle's feed/state seeding) with the tracer
+    enabled.  Returns the observability module, whose registry/tracer
+    now hold the run."""
     from . import observability as obs
     from .analysis import equivalence as eqv
     from .analysis.dataflow import state_classes
@@ -472,8 +464,6 @@ def _telemetry_run(args):
     if feed is None:
         feed = [v.name for v in block.vars.values() if v.is_data]
     obs.enable_tracing()
-    label = os.path.basename(os.path.normpath(args.model)) or "model"
-    obs.accounting.track(program, label, batch_size=args.batch_size)
     feeds = eqv.build_feeds(program, feed, batch_size=args.batch_size)
     scope = _load_scope_for(args.model) or Scope()
     # saved dirs carry persistables; anything else the block reads is
@@ -491,16 +481,13 @@ def _telemetry_run(args):
         with obs.span("telemetry.step", step=i):
             exe.run(program, feed=dict(feeds), fetch_list=list(fetch),
                     scope=scope, rng_step=i)
-    obs.accounting.record_measured_peak(program, exe, feed=dict(feeds),
-                                        fetch_list=list(fetch),
-                                        scope=scope)
     return obs
 
 
 def cmd_metrics(args) -> int:
     """Run a saved model under the telemetry layer and print the
-    registry state: Prometheus text by default, --json for the snapshot
-    (with the predicted-vs-measured report attached)."""
+    registry state: Prometheus text by default, --json for the
+    snapshot."""
     import json as _json
 
     obs = _telemetry_run(args)
@@ -508,9 +495,7 @@ def cmd_metrics(args) -> int:
         obs.TRACER.export(args.trace_out)
         print(f"# trace written to {args.trace_out}", file=sys.stderr)
     if args.json:
-        body = obs.REGISTRY.snapshot()
-        body["pred_vs_measured"] = obs.accounting.report()
-        print(_json.dumps(body))
+        print(_json.dumps(obs.REGISTRY.snapshot()))
     else:
         print(obs.REGISTRY.render_prometheus(), end="")
     return 0
@@ -528,250 +513,6 @@ def cmd_trace(args) -> int:
     print(f"{out}: {n} events"
           + (f"; SCHEMA PROBLEMS: {problems}" if problems else ""))
     return 1 if problems else 0
-
-
-def cmd_attribute(args) -> int:
-    """`paddle attribute MODEL` — the ISSUE 16 per-op device-time
-    attribution table.  MODEL is a standing calibration program
-    (fit_a_line|recognize_digits|small_lm|lstm, models/standing.py) or
-    a saved-model dir/file.
-
-    Runs the deterministic CPU segment oracle
-    (observability/attribution.py), joins measured per-op time against
-    the static cost model, publishes the op_pred_vs_measured gauges,
-    and emits ONE bench-schema artifact line.  --update-calibration
-    feeds the table into the calibration store the autotune prior
-    consumes.  (On a chip the table comes from a traced benchmark run:
-    docs/observability.md, "Per-op attribution".)"""
-    import json as _json
-
-    from . import observability as obs
-    from .analysis import cost as acost
-
-    if args.calibration_root:
-        os.environ["PADDLE_TPU_CALIBRATION_CACHE"] = os.path.abspath(
-            args.calibration_root)
-    chip = args.chip or acost.detect_chip()
-
-    import paddle_tpu as fluid
-    from .models.standing import get_builder
-
-    builder = get_builder(args.model)
-    if builder is not None:
-        label = args.model
-        fluid.reset()
-        feed, _fetch, bs = builder()
-        program = fluid.default_main_program()
-        fluid.Executor(fluid.default_place()).run(
-            fluid.default_startup_program())
-        scope = None  # the startup run populated the global scope
-    else:
-        from .analysis import equivalence as eqv
-        from .analysis.dataflow import state_classes
-        from .framework.scope import Scope
-
-        program, feed_names, _fetch = _load_program_any(args.model)
-        block = program.global_block()
-        if feed_names is None:
-            feed_names = [v.name for v in block.vars.values()
-                          if v.is_data]
-        label = (os.path.basename(os.path.normpath(args.model))
-                 or "model").replace("-", "_").replace(".", "_")
-        bs = args.batch_size
-        feed = eqv.build_feeds(program, feed_names, batch_size=bs)
-        scope = _load_scope_for(args.model) or Scope()
-        # saved dirs carry persistables; anything else the block reads
-        # is seeded deterministically by name (the oracle idiom)
-        ext, rw, _ = state_classes(block, list(feed))
-        for name in list(ext) + list(rw):
-            if scope.find(name) is not None:
-                continue
-            dv = block._find_var_recursive(name)
-            if dv is not None and dv.shape is not None:
-                scope.set(name, eqv._seed_array(
-                    name, eqv._bind(dv.shape, 1), dv.dtype or "float32",
-                    0))
-
-    table = obs.attribution.attribute_cpu(
-        program, feed, scope=scope, batch_size=bs,
-        repeats=args.repeats, chip=chip)
-    obs.attribution.publish(table, label)
-    row = obs.attribution.artifact_row(table, label)
-
-    if args.update_calibration:
-        entry = obs.calibration.default_store().record_attribution(table)
-        row["calibration_updated"] = bool(entry)
-
-    if args.smoke:
-        # the run_tests.sh attribution gate (acceptance: >=80% of
-        # measured step time attributed to named desc ops)
-        assert table["coverage"] >= 0.8, \
-            f"attribution coverage {table['coverage']:.3f} < 0.8"
-        assert table["n_ops"] > 0 and table["by_type"], table["n_ops"]
-        assert all(r["uid"] >= 0 for r in table["rows"]), \
-            "desc op without a __uid__ in the attribution table"
-        snapshot = obs.REGISTRY.snapshot()
-        sp = obs.validate_snapshot(snapshot)
-        assert not sp, f"snapshot schema: {sp}"
-        for fam in ("op_pred_vs_measured", "op_measured_time_share",
-                    "op_attribution_coverage"):
-            assert fam in snapshot["families"], f"missing family {fam}"
-        print(f"# attribution smoke OK ({label}: {table['n_ops']} ops, "
-              f"coverage {table['coverage']:.3f}, top "
-              f"{table['top_op']})", file=sys.stderr)
-
-    line = _json.dumps(row)
-    if not args.json:
-        print(f"attribution {label} ({table['mode']}, chip "
-              f"{table['chip']}): {table['n_ops']} ops, "
-              f"{table['total_s'] * 1e3:.3f} ms/walk, coverage "
-              f"{table['coverage']:.3f}", file=sys.stderr)
-        for t, e in list(table["by_type"].items())[:args.top]:
-            print(f"  {t:<28} x{e['count']:<4} "
-                  f"{e['measured_share'] * 100:6.2f}% measured  "
-                  f"{e['pred_share'] * 100:6.2f}% predicted  "
-                  f"pred/meas {e['pred_vs_measured']:.2e}",
-                  file=sys.stderr)
-    print(line, flush=True)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(line + "\n")
-    return 0
-
-
-def cmd_tune(args) -> int:
-    """`paddle tune WORKLOAD` — the ISSUE 14 search loop.  WORKLOAD is
-    a registered name (gpt_small, paged_decode, lstm) or a
-    saved-model dir.
-    Winners persist in the autotune store; a second run is a cache hit
-    (no re-measurement) unless --force."""
-    if args.store:
-        # the store location must bind for the WHOLE process (kernel
-        # knob resolution during trials reads default_store), not just
-        # the tuner's own handle
-        os.environ["PADDLE_TPU_AUTOTUNE_CACHE"] = os.path.abspath(
-            args.store)
-    elif args.mock and not args.smoke \
-            and "PADDLE_TPU_AUTOTUNE_CACHE" not in os.environ:
-        # mock winners are digest-hash noise: persisting them into the
-        # REAL default store would make production traces pick up
-        # meaningless block sizes — route to a throwaway unless the
-        # user named a store explicitly
-        import tempfile
-
-        tmp = tempfile.mkdtemp(prefix="paddle_tune_mock_")
-        os.environ["PADDLE_TPU_AUTOTUNE_CACHE"] = tmp
-        print(f"# --mock: winners land in throwaway store {tmp} "
-              f"(pass --store to keep them)", file=sys.stderr)
-    from . import autotune
-    from .autotune import measure as at_measure
-    from .autotune import workloads as at_workloads
-
-    if args.child_measure:
-        # hidden subprocess half of XLA-flag trials: measure exactly one
-        # candidate in this (freshly-flagged) process, print one JSON line
-        wl = at_workloads.get_workload(args.workload)
-        return at_measure.child_measure(wl, args.child_measure)
-
-    if args.smoke:
-        return _tune_smoke(args)
-
-    wl = at_workloads.get_workload(args.workload)
-    measurer = (at_measure.MockMeasurer() if args.mock
-                else at_measure.TimedMeasurer(warmup=args.warmup,
-                                              iters=args.iters,
-                                              repeats=args.repeats))
-    rep = autotune.tune(wl, measurer=measurer, top_k=args.top_k,
-                        chip=args.chip, force=args.force,
-                        measure_all=args.measure_all)
-    if args.json:
-        print(json.dumps(rep))
-        return 0
-    return _render_tune(rep)
-
-
-def _render_tune(rep) -> int:
-    from .autotune import store as at_store
-
-    if rep.get("cache_hit"):
-        e = rep["entry"]
-        print(f"tune {rep['workload']}: winner loaded from store "
-              f"(cache hit, no re-measurement)")
-        print(f"  params   {rep['winner']}")
-        print(f"  measured {e.get('measured_s', 0) * 1e3:.3f} ms/step "
-              f"(tuned {e.get('created_utc', '?')}; --force re-measures)")
-        return 0
-    print(f"tune {rep['workload']}: space {rep['space_size']}, "
-          f"{rep['n_feasible']} feasible, {rep['n_rejected']} rejected "
-          f"by the analyzers before any compile")
-    for t in rep["trials"]:
-        mark = "*" if t["digest"] == rep["winner_row"]["digest"] else " "
-        print(f" {mark} {t['digest']}  pred "
-              f"{t['predicted_step_s'] * 1e3:9.4f} ms  measured "
-              f"{t['best_s'] * 1e3:9.4f}/{t['median_s'] * 1e3:.4f} ms "
-              f"(best/median)  {t['params']}")
-    base = rep.get("default_row")
-    win = rep["winner_row"]
-    if base:
-        speedup = base["best_s"] / win["best_s"] if win["best_s"] else 0
-        print(f"  winner vs default: {speedup:.3f}x "
-              f"({base['best_s'] * 1e3:.4f} -> "
-              f"{win['best_s'] * 1e3:.4f} ms)")
-    print(f"  prior rank of measured winner: {rep['rank_of_winner']} "
-          f"(in top-k: {rep['in_top_k']})")
-    print(f"  persisted -> {at_store.default_store().root}")
-    return 0
-
-
-def _tune_smoke(args) -> int:
-    """run_tests.sh fast gate: tiny space + mock measurer in a private
-    store — asserts the prior/measure/store/cache-hit loop end to end
-    without compiling anything."""
-    import tempfile
-
-    from . import autotune
-    from .autotune import workloads as at_workloads
-    from .autotune.measure import MockMeasurer
-
-    with tempfile.TemporaryDirectory() as tmp:
-        os.environ["PADDLE_TPU_AUTOTUNE_CACHE"] = tmp
-        from .autotune import integration as at_int
-
-        at_int.reset()
-        wl = at_workloads.get_workload(args.workload)
-        m = MockMeasurer()
-        rep = autotune.tune(wl, measurer=m, top_k=3)
-        assert not rep["cache_hit"] and rep["winner"], rep
-        assert m.measured, "mock measurer never ran"
-        assert rep["default_row"] is not None, \
-            "baseline candidate was not measured"
-        # winner is measured-best by construction: >= the default
-        assert rep["winner_row"]["best_s"] <= \
-            rep["default_row"]["best_s"] + 1e-12
-        # second run: the persisted winner must come back with NO
-        # measurement (the acceptance cache-hit contract)
-        m2 = MockMeasurer()
-        rep2 = autotune.tune(at_workloads.get_workload(args.workload),
-                             measurer=m2)
-        assert rep2["cache_hit"] and not m2.measured, rep2
-        assert rep2["winner"] == rep["winner"]
-        # memory-infeasible candidates must be rejected BEFORE any
-        # compile: under a 1 MiB budget everything is infeasible
-        if getattr(wl, "kind", "") == "program":
-            m3 = MockMeasurer()
-            try:
-                autotune.tune(at_workloads.get_workload(args.workload),
-                              measurer=m3, force=True,
-                              hbm_bytes=1 << 20)
-                raise AssertionError("1MiB-budget tune did not reject")
-            except RuntimeError:
-                pass
-            assert not m3.measured, \
-                "infeasible candidates were measured"
-        print(f"# autotune smoke OK ({args.workload}: "
-              f"{len(m.measured)} mock trials, winner "
-              f"{rep['winner']}, cache-hit verified)", file=sys.stderr)
-    return 0
 
 
 def cmd_show_pb(args) -> int:
@@ -920,8 +661,8 @@ def main(argv=None) -> int:
     p.add_argument("--batch-size", type=int, default=2,
                    help="binds -1 feed dims of the synthetic feeds")
     p.add_argument("--json", action="store_true",
-                   help="registry snapshot JSON (+ pred_vs_measured "
-                        "report) instead of Prometheus text")
+                   help="registry snapshot JSON instead of Prometheus "
+                        "text")
     p.add_argument("--trace-out", default=None,
                    help="also write the step trace JSON here")
     p.set_defaults(fn=cmd_metrics)
@@ -936,67 +677,6 @@ def main(argv=None) -> int:
     p.add_argument("--out", default=None,
                    help="trace path (default MODEL.trace.json)")
     p.set_defaults(fn=cmd_trace)
-
-    p = sub.add_parser("attribute")
-    p.add_argument("model",
-                   help="standing program (fit_a_line|recognize_digits|"
-                        "small_lm|lstm) or a saved-model dir/file")
-    p.add_argument("--repeats", type=int, default=3,
-                   help="oracle walks per op (median is reported)")
-    p.add_argument("--batch-size", type=int, default=2,
-                   help="binds -1 feed dims of saved models")
-    p.add_argument("--chip", default=None,
-                   help="chip spec for the predicted column (default: "
-                        "detected backend)")
-    p.add_argument("--top", type=int, default=8,
-                   help="op types shown in the human table")
-    p.add_argument("--update-calibration", action="store_true",
-                   help="feed the table into the calibration store "
-                        "(observability/calibration.py)")
-    p.add_argument("--calibration-root", default=None,
-                   help="calibration store dir (default "
-                        "$PADDLE_TPU_CALIBRATION_CACHE or "
-                        "~/.cache/paddle_tpu/calibration)")
-    p.add_argument("--json", action="store_true",
-                   help="suppress the human table (artifact line only)")
-    p.add_argument("--out", default=None,
-                   help="also write the artifact line to FILE")
-    p.add_argument("--smoke", action="store_true",
-                   help="CI gate: coverage/schema asserts")
-    p.set_defaults(fn=cmd_attribute)
-
-    p = sub.add_parser("tune")
-    p.add_argument("workload",
-                   help="registered workload (gpt_small|paged_decode|"
-                        "lstm) or a saved-model dir")
-    p.add_argument("--top-k", type=int, default=5,
-                   help="how many predicted-best candidates to "
-                        "compile+measure (the prior gate)")
-    p.add_argument("--chip", default=None,
-                   help="chip spec for the prior (default: detected "
-                        "backend, $PADDLE_TPU_CHIP, v5e)")
-    p.add_argument("--store", default=None,
-                   help="winner-store dir (default "
-                        "$PADDLE_TPU_AUTOTUNE_CACHE or "
-                        "~/.cache/paddle_tpu/autotune)")
-    p.add_argument("--force", action="store_true",
-                   help="re-measure even when the store has a winner")
-    p.add_argument("--measure-all", action="store_true",
-                   help="measure every feasible candidate, not just "
-                        "top-k (the sweep tool's rank-error mode)")
-    p.add_argument("--mock", action="store_true",
-                   help="deterministic mock measurer (no compile)")
-    p.add_argument("--warmup", type=int, default=2)
-    p.add_argument("--iters", type=int, default=8)
-    p.add_argument("--repeats", type=int, default=3)
-    p.add_argument("--json", action="store_true")
-    p.add_argument("--smoke", action="store_true",
-                   help="CI gate: tiny mock tune in a throwaway store, "
-                        "asserting the rank/measure/persist/cache-hit "
-                        "loop")
-    p.add_argument("--child-measure", default=None,
-                   help=argparse.SUPPRESS)
-    p.set_defaults(fn=cmd_tune)
 
     p = sub.add_parser("merge_model")
     p.add_argument("model_dir")

@@ -22,13 +22,6 @@ def build_callable(program, fetch_list, scope=None, feed_names=None,
     import jax
 
     scope = scope or global_scope()
-    # autotune winner pickup: build_callable has no feed signature, so
-    # it reads the desc-only twin entry (`program_desc`) a `paddle
-    # tune` run records beside the full one — tuned remat marks apply
-    # before the analysis pass below sees the block
-    from .autotune.integration import maybe_apply_program_winner
-
-    maybe_apply_program_winner(program, {})
     block = program.global_block()
     fetch_names = [f.name if hasattr(f, "name") else f for f in fetch_list]
     feed_names = feed_names or [

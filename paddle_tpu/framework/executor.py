@@ -26,7 +26,6 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..observability import accounting as _acct
 from ..observability import attribution as _attr
 from ..observability.metrics import REGISTRY as _MET, monotime as _monotime
 from ..observability.tracing import TRACER as _TRC
@@ -358,17 +357,15 @@ class Executor:
         state is the post-K value, the PRNG stream matches K sequential
         runs bit-for-bit, and `rng_step` (when given) pins the FIRST
         step's index.  None defers to PADDLE_TPU_STEPS_PER_DISPATCH
-        (resolved through autotune.knobs; the stored `tune step_loop`
-        winner is deliberately NOT auto-applied here — K changes the
-        run() return shape, so only an explicit opt-in may set it).
+        (paddle_tpu/knobs.py).
         Loop-unsafe programs (save/load ops, nested control flow) fall
         back loudly to K sequential dispatches."""
         from .core import default_main_program
 
         if steps_per_dispatch is None:
-            from ..autotune.knobs import steps_per_dispatch as _k_knob
+            from ..knobs import steps_per_dispatch as _k_knob
 
-            steps_per_dispatch = _k_knob(default=1, store=False)
+            steps_per_dispatch = _k_knob(default=1)
         k = int(steps_per_dispatch)
         if k < 1:
             raise ValueError(f"steps_per_dispatch={k} must be >= 1")
@@ -381,7 +378,6 @@ class Executor:
         feed = feed or {}
         fetch_names = [_fetch_name(f) for f in (fetch_list or [])]
         scope = scope if scope is not None else global_scope()
-        t_run0 = _monotime()
 
         if verify is None:
             from ..analysis.verifier import env_verify_enabled
@@ -392,7 +388,7 @@ class Executor:
                                  fetch_names)
 
         return self._dispatch(program, block_id, feed, fetch_names, scope,
-                              return_numpy, rng_step, 1, None, t_run0)
+                              return_numpy, rng_step, 1, None)
 
     def _dispatch(self, *args):
         """_dispatch_under_spans, marked on the thread for the compile
@@ -404,11 +400,10 @@ class Executor:
             _compiling.dispatches -= 1
 
     def _dispatch_under_spans(self, program, block_id, feed, fetch_names,
-                              scope, return_numpy, rng_step, k, fetch_every,
-                              t_run0):
+                              scope, return_numpy, rng_step, k, fetch_every):
         """One dispatch of `k` steps under its spans, shared by run() (k=1)
         and the fused path of _run_loop(): a root `executor.run` and under
-        it `executor.prepare` (feeds, autotune winner, cache key, load-file
+        it `executor.prepare` (feeds, cache key, load-file
         signature, cache lookup; `executor.build` inside it when the key
         is new), `executor.donate`, `executor.rng`, `executor.execute`
         (the jitted call: JAX traces, lowers and compiles in its first
@@ -427,20 +422,6 @@ class Executor:
                     from . import step_loop
 
                     step_loop.check_stacked(feed_vals, k)
-                # autotune winner pickup (autotune/integration.py): a
-                # persisted `paddle tune` winner for this exact (program
-                # digest, feed signature, device, backend) re-applies its
-                # program-level decisions (attrs-only remat marks) BEFORE
-                # the cache key is computed, so the tuned executable is
-                # what gets cached.  One memoized lookup per program
-                # version; an empty store is a single scandir;
-                # PADDLE_TPU_AUTOTUNE=0 disables.
-                if block_id == 0:
-                    from ..autotune.integration import (
-                        maybe_apply_program_winner)
-
-                    maybe_apply_program_winner(program, feed_vals)
-
                 key = self._cache_key(program, block_id, feed_vals,
                                       fetch_names)
                 if k > 1:
@@ -527,10 +508,6 @@ class Executor:
                             f"non-finite values in {n!r} after step "
                             f"{self._step}")
             _MET_STEPS.inc()
-            # predicted-vs-measured: tracked programs record this step's
-            # wall time (observability/accounting.py; cheap no-op for the
-            # rest)
-            _acct.on_step(program, _monotime() - t_run0, compiled_now)
             if not return_numpy:
                 return [fetches[n] for n in fetch_names]
             with _TRC.span("executor.fetch", step=step,
@@ -611,7 +588,6 @@ class Executor:
         feed = feed or {}
         fetch_names = [_fetch_name(f) for f in (fetch_list or [])]
         scope = scope if scope is not None else global_scope()
-        t_run0 = _monotime()
 
         if verify is None:
             from ..analysis.verifier import env_verify_enabled
@@ -655,7 +631,7 @@ class Executor:
                     for j in range(len(fetch_names))]
 
         return self._dispatch(program, block_id, feed, fetch_names, scope,
-                              return_numpy, rng_step, k, fetch_every, t_run0)
+                              return_numpy, rng_step, k, fetch_every)
 
     # ------------------------------------------------------------------
     def _verify_program(self, program, block_id, feed_names, fetch_names):

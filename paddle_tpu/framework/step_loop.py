@@ -6,10 +6,8 @@ block: the state carry stays resident in HBM (donated, exactly like the
 single-step path), per-step PRNG keys are derived ON DEVICE from the
 same `fold_in(PRNGKey(seed), step)` stream the sequential path uses, and
 fetches come back stacked `(K, ...)` (or last-only).  The per-dispatch
-host overhead — the affine intercept PR 16's calibration store measures
-— is paid once per K steps instead of once per step, which is the whole
-point (`analysis/cost.step_loop_cost` prices it; `paddle tune
-step_loop` measures it).
+host overhead is paid once per K steps instead of once per step, which
+is the whole point (`analysis/cost.step_loop_cost` prices it).
 
 Bitwise contract: the fused loop is provably identical to K sequential
 `run()` calls on every fetch and every written-back state value

@@ -1,11 +1,6 @@
-"""The standing calibration programs (ISSUE 13/16).
-
-fit-a-line, recognize-digits, the small decoder LM, and the autotune
-LSTM — the fixed set of programs every calibration layer measures:
-tools/pred_vs_measured.py (program-level ratios), ``paddle attribute``
-(the per-op attribution table) and the autotune sweep all build from
-HERE, so the ratios, the per-op factors, and the sweep's
-rank errors describe the SAME descs.
+"""Four small fixed training programs: fit-a-line, recognize-digits, a
+2-layer decoder LM and a 2xLSTM classifier, each with its seeded feed.
+`analysis/equivalence.py` proves its rewrites on the LM.
 
 Each builder mutates the default main/startup programs (callers
 ``fluid.reset()`` first) and returns ``(feed, fetch_list, batch_size)``.
@@ -66,23 +61,24 @@ def build_small_lm():
 
 
 def build_lstm():
-    """Shares the autotune workload's builder so `paddle tune lstm`,
-    the sweep artifact, pred_vs_measured's standing row, and the
-    attribution table all describe the SAME program (the 6.97-vs-9.89 ms
-    reconciliation family)."""
-    from ..autotune.workloads import _build_lstm as build
+    """2xLSTM (hidden 128, 32 steps) + fc classification over a
+    1000-word vocabulary, Adam: the bench LSTM's shape at a CPU's size."""
+    import paddle_tpu as fluid
+    from . import image_models
 
-    return build()
-
-
-MODELS = (("fit_a_line", build_fit_a_line),
-          ("recognize_digits", build_recognize_digits),
-          ("small_lm", build_small_lm),
-          ("lstm", build_lstm))
-
-
-def get_builder(name):
-    for n, b in MODELS:
-        if n == name:
-            return b
-    return None
+    bs, hidden, seq = 8, 128, 32
+    words = fluid.layers.sequence_data(name="words", shape=[1],
+                                       dtype="int64", max_len=seq)
+    label = fluid.layers.data(name="label", shape=[1], dtype="int64")
+    emb = fluid.layers.sequence_embedding(words, size=[1000, hidden],
+                                          dtype="float32")
+    logits = image_models.stacked_lstm_net(emb, hidden_dim=hidden,
+                                           stacked_num=2, class_dim=2)
+    loss = fluid.layers.mean(
+        fluid.layers.softmax_with_cross_entropy(logits, label))
+    fluid.optimizer.Adam(learning_rate=0.002).minimize(loss)
+    rng = np.random.RandomState(11)
+    feed = {"words": rng.randint(0, 1000, (bs, seq, 1)).astype(np.int64),
+            "words@LENGTH": np.full((bs,), seq, dtype=np.int32),
+            "label": rng.randint(0, 2, (bs, 1)).astype(np.int64)}
+    return feed, [loss], bs

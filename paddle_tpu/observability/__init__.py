@@ -1,6 +1,6 @@
 """paddle_tpu.observability — the unified telemetry substrate (ISSUE 13).
 
-Three layers, one namespace:
+Three modules, one namespace:
 
   * :mod:`.metrics` — the process-global ``REGISTRY`` of counters /
     gauges / histograms with labels; Prometheus text + JSON snapshot
@@ -8,15 +8,9 @@ Three layers, one namespace:
     (``artifact_metric``);
   * :mod:`.tracing` — the process-global ``TRACER``: nested spans in a
     bounded ring, Chrome/Perfetto trace-event export;
-  * :mod:`.accounting` — predicted-vs-measured: static cost/memory
-    predictions attached per program, measured step times and XLA peaks
-    recorded against them, error ratios materialized as metrics;
-  * :mod:`.attribution` — per-op device-time attribution (ISSUE 16):
-    named-scope identity threading (always on), the CPU segment oracle,
-    and the per-op predicted-vs-measured table;
-  * :mod:`.calibration` — the sealed per-(op type, chip, dtype)
-    correction-factor store the attribution tables feed and the cost
-    model/autotune prior consume.
+  * :mod:`.attribution` — the op identity every compiled step carries
+    (named-scope threading, always on): what a traced run on the chip
+    reads per-op device time by.
 
 Usage:
 
@@ -32,9 +26,7 @@ Everything is near-zero cost when disabled — instrumentation in the
 executor/serving/service hot paths stays compiled in at all times.
 """
 
-from . import accounting  # noqa: F401
 from . import attribution  # noqa: F401
-from . import calibration  # noqa: F401
 from . import metrics  # noqa: F401
 from . import tracing  # noqa: F401
 from .httpd import TelemetryServer, serve_http  # noqa: F401
@@ -76,7 +68,7 @@ def disable_tracing():
 def export_telemetry(trace_obj=None, trace_path=None,
                      metrics_obj=None, metrics_path=None):
     """Write + schema-validate telemetry artifacts in one place (the
-    serve_bench / chaos_run / pred_vs_measured export path — one
+    serve_bench / chaos_run export path — one
     implementation, so their validation semantics cannot drift).
 
     `metrics_obj` is either a bare registry snapshot or the multi-run
@@ -106,8 +98,7 @@ def export_telemetry(trace_obj=None, trace_path=None,
 
 
 def reset():
-    """Fresh registry/tracer/accounting state (fluid.reset() hook —
+    """Fresh registry/tracer state (fluid.reset() hook —
     clears series and the ring in place so held handles stay valid)."""
     REGISTRY.reset()
     TRACER.reset()
-    accounting.reset()

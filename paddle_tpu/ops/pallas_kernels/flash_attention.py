@@ -142,7 +142,7 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   entry's body on strided blocks: dq gains, dkv's q blocks (256-byte
   rows 4 KB apart) lose; no cell runs it.  `pltpu.roll` was not tried:
   the slices it would feed already lost.  The other entry traces to
-  the parent's jaxprs byte for byte (tests/test_pallas_kernels.py) and
+  the parent's jaxprs byte for byte (tests/test_flash_packed.py) and
   read the parent's times: 3.459 4.349 4.748 at T 8192, 192 / 128,
   4.818 5.366 6.777 at T 8192, D 64, 32 on 8.
 
@@ -260,22 +260,17 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
     Interpret mode has no Mosaic tile contract (tests run tiny T/blocks
     there), so it keeps plain largest-divisor snapping.
 
-    The requested blocks resolve through the autotune knob layer
-    (paddle_tpu/autotune/knobs.py) at trace time: an active tuning
-    trial's override first, then the PADDLE_TPU_FLASH_BQ/BK env vars
-    (now VALIDATED — garbage raises a clear error instead of an
-    int() traceback, and the values are still clamped to legal aligned
-    divisors below), then the persisted winner for this sequence
-    length, then the argument defaults.  Winner pickup means a
-    `paddle tune` result configures every later trace with no env
-    plumbing; the env vars remain the explicit operator override.
+    The requested blocks resolve through paddle_tpu/knobs.py at trace
+    time: the PADDLE_TPU_FLASH_BQ/BK env vars (validated — garbage
+    raises a clear error — and still clamped to legal aligned divisors
+    below), else the argument defaults.
 
     `causal_head` is the head size of a causal call (0 for any other): on
     the chip such a call runs one block a head where one_block_a_head
     says so, whatever q block was asked for.  `unit` is the length the
     blocks of a call under a mask of several regions have to divide
     (_mask_unit), where T is not it."""
-    from ...autotune import knobs
+    from ... import knobs
 
     block_q, block_k = knobs.flash_blocks(block_q, block_k, T)
     tile = 1 if interpret else 128
@@ -355,7 +350,7 @@ def _shared(fn, *static):
     gpt2m_train_bs8's warm set-up against the parent's 2.60 + 1.06, so
     2.56 + 2.38 (PERF.md, PR 36).  What is traced is what straight-line
     code would be: the jaxpr of a one-head call is the parent's byte for
-    byte (tests/test_pallas_kernels.py)."""
+    byte (tests/test_flash_packed.py)."""
     import jax
 
     return jax.jit(fn, static_argnames=static, inline=True)

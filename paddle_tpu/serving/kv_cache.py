@@ -37,11 +37,9 @@ def page_size_from_env(default: int = 16) -> int:
     allocator's granularity.  16 fills a whole sublane tile in bf16
     (and two in f32) — the smallest size the Pallas kernel gate
     accepts; raise it to trade page-table length for allocation
-    granularity.  Resolved through the autotune knob layer: an active
-    trial override, then PADDLE_TPU_PAGE_SIZE (VALIDATED now — garbage
-    used to silently fall back to the default), then the persisted
-    `paddle tune` winner for this device, then `default`."""
-    from ..autotune import knobs
+    granularity.  PADDLE_TPU_PAGE_SIZE (validated; paddle_tpu/knobs.py),
+    else `default`."""
+    from .. import knobs
 
     return knobs.paged_page_size(default)
 

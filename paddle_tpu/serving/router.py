@@ -15,13 +15,13 @@ tier instead of inventing heuristics:
   * PLACEMENT — cheapest predicted FINISH: each replica's per-token
     device time comes from the cost analyzer (``analysis.cost
     .program_cost`` over its decode program at its compiled batch
-    shape, calibrated when factors exist; an optional per-replica comm
+    shape; an optional per-replica comm
     report is folded through ``roofline_with_comm`` for sharded
     replicas), multiplied by the decode tokens already committed to
     that replica (queued + running remaining budgets) plus the
     newcomer's own.  Identical replicas degrade to join-shortest-queue
-    in tokens; heterogeneous replicas (different chips / batch shapes /
-    calibration) weight the queue by measured-model speed.
+    in tokens; heterogeneous replicas (different chips / batch shapes)
+    weight the queue by the model's predicted speed.
 
 Draining uses the engines' existing ``pop_finished()`` — the router
 adds no completion path of its own, and per-request results are merged
@@ -75,9 +75,7 @@ class ReplicaRouter:
             comm = comm_reports[i] if comm_reports else None
             if comm:
                 rep = roofline_with_comm(rep, comm)
-            step = float(rep.get("calibrated_step_time_s")
-                         or rep["predicted_step_time_s"])
-            self.step_cost_s.append(step)
+            self.step_cost_s.append(float(rep["predicted_step_time_s"]))
         self.token_cost_s = [s / max(1, e.num_slots)
                              for s, e in zip(self.step_cost_s,
                                              self.engines)]

@@ -32,10 +32,9 @@ One speculative ROUND per engine step, over every decoding slot:
      argument), and their pages stay owned by the request until
      finish/preempt like any other.
 
-The speculation depth K and the draft depth resolve through the
-autotune knob layer (knobs.speculation_k / knobs.spec_draft_layers):
-trial override > validated env > persisted ``paddle tune spec_decode``
-winner > default.
+The speculation depth K and the draft depth resolve through
+paddle_tpu/knobs.py (knobs.speculation_k / knobs.spec_draft_layers):
+the validated environment value, else the default.
 """
 
 from __future__ import annotations
@@ -53,7 +52,7 @@ def build_draft_lm(lm, n_layers: Optional[int] = None):
     truncation outside this module): resolve the draft depth through
     the knob layer and return the truncated parameter-sharing view."""
     if n_layers is None:
-        from ..autotune import knobs
+        from .. import knobs
 
         n_layers = knobs.spec_draft_layers(max(1, lm.n_layers // 2))
     n_layers = max(1, min(int(n_layers), lm.n_layers))
@@ -73,8 +72,7 @@ class SpeculativeDecoder:
 
     def __init__(self, engine, k: Optional[int] = None,
                  draft_layers: Optional[int] = None):
-        from .. import layers
-        from ..autotune import knobs
+        from .. import knobs, layers
         from ..framework.core import Program, program_guard
 
         if k is None:

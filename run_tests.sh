@@ -46,59 +46,6 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 JAX_PLATFORMS=cpu python tools/hlo_analysis.py loop --ks 1,4 > /dev/null \
     || { echo "step-loop bitwise parity gate failed (rc=$?)"; exit 1; }
 
-# telemetry smoke (docs/observability.md ISSUE 13): a traced fit-a-line
-# train step through the unified telemetry layer — asserts the executor
-# phase spans exist, the Perfetto trace and metrics snapshot are
-# schema-valid, and the predicted-vs-measured ratios are sane (the
-# static-model error channel ROADMAP #3 consumes)
-env JAX_PLATFORMS=cpu python tools/pred_vs_measured.py --smoke > /dev/null \
-    || { echo "telemetry smoke failed (rc=$?)"; exit 1; }
-
-# autotune smoke (docs/autotune.md ISSUE 14): the analyzer-guided
-# tuner's rank -> measure -> persist -> cache-hit loop over a tiny
-# space with the deterministic mock measurer in a throwaway store —
-# also proves memory-infeasible candidates never reach a trial
-env JAX_PLATFORMS=cpu python -m paddle_tpu tune gpt_small --smoke \
-    || { echo "autotune smoke failed (rc=$?)"; exit 1; }
-# the ISSUE 18 speculation axes (speculation_k x draft_layers) ride the
-# same loop: rank by the cost model, measure the survivors, persist
-env JAX_PLATFORMS=cpu python -m paddle_tpu tune spec_decode --smoke \
-    || { echo "spec_decode autotune smoke failed (rc=$?)"; exit 1; }
-# the ISSUE 19 mesh_layout axis: slice-count x per-slice topology priced
-# by roofline_with_comm (ICI-heavy vs DCN-heavy layouts ranked by the
-# per-link-class wire model)
-env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    python -m paddle_tpu tune mesh_layout --smoke \
-    || { echo "mesh_layout autotune smoke failed (rc=$?)"; exit 1; }
-# the ISSUE 20 steps_per_dispatch axis: fused-K candidates ranked by the
-# amortized dispatch-overhead model (cost.step_loop_cost), winner lands
-# in the store and resolves through knobs.steps_per_dispatch
-env JAX_PLATFORMS=cpu python -m paddle_tpu tune step_loop --smoke \
-    || { echo "step_loop autotune smoke failed (rc=$?)"; exit 1; }
-
-# attribution smoke + regression sentinel (docs/observability.md ISSUE
-# 16): `paddle attribute` runs the deterministic CPU segment oracle
-# over fit-a-line — asserts >=80% of measured step time lands on named
-# desc ops and the artifact/snapshot schemas hold — then the sentinel
-# (a) proves its own verdict logic on a synthetic pair (identical=PASS,
-# injected slowdown=REGRESSED naming the guilty op) and (b) diffs the
-# fresh artifact against the committed golden baseline.  The golden
-# compare scores the COVERAGE metric (machine-independent, ~1.0
-# everywhere); raw per-op times never gate CI.  Calibration-store
-# writes are opt-in (--update-calibration), so this gate cannot
-# contaminate later `paddle tune` pricing.
-attr_tmp=$(mktemp -d)
-env JAX_PLATFORMS=cpu python -m paddle_tpu attribute fit_a_line --smoke \
-    --json --out "$attr_tmp/attribution.json" > /dev/null \
-    || { echo "attribution smoke failed (rc=$?)"; rm -rf "$attr_tmp"; exit 1; }
-python tools/sentinel.py --self-test \
-    || { echo "sentinel self-test failed (rc=$?)"; rm -rf "$attr_tmp"; exit 1; }
-python tools/sentinel.py --baseline tools/sentinel_golden.json \
-    --candidate "$attr_tmp/attribution.json" --threshold 0.5 \
-    || { echo "sentinel flagged a regression vs the golden baseline (rc=$?)"; \
-         rm -rf "$attr_tmp"; exit 1; }
-rm -rf "$attr_tmp"
-
 # chaos smoke (docs/distributed.md): one seeded worker-kill against the
 # elastic training service, recovery proved equivalent to the
 # uninterrupted reference by the PR 10 differential oracle — <30s, fails

@@ -803,7 +803,7 @@ def test_repo_lint_truncated_mint_guard(tmp_path):
 
 
 def test_repo_lint_spec_knob_env_guard(tmp_path):
-    """Raw reads of the speculation knobs outside autotune/ are
+    """Raw reads of the speculation knobs outside paddle_tpu/knobs.py are
     findings; plain exports (os.environ[...] = ...) are the knob
     layer's input side and stay exempt (ISSUE 18)."""
     rl = _repo_lint_module()

@@ -114,32 +114,6 @@ def test_imports_initialise_no_backend():
     assert out.returncode == 0 and out.stdout.strip() == "OK", out.stderr
 
 
-def test_flag_candidate_subprocess_refused_when_holding_the_chip(
-        monkeypatch):
-    """An XLA-flag candidate is measured in a child; from a process that
-    already holds the accelerator that child would hang, so it is a clear
-    error before anything is started."""
-    from paddle_tpu.autotune import measure
-    from paddle_tpu.framework import place
-
-    class Cand:
-        digest = "c0ffee"
-        params = {"xla_flags": "--xla_foo=1"}
-
-        def get(self, k, d=None):
-            return self.params.get(k, d)
-
-    class Work:
-        name = "lstm"
-
-    monkeypatch.setattr(place, "holds_accelerator", lambda: True)
-    monkeypatch.setattr(
-        measure.subprocess, "run",
-        lambda *a, **k: pytest.fail("started a child that needs the chip"))
-    with pytest.raises(RuntimeError, match="already holds it"):
-        measure.TimedMeasurer().measure(Work(), Cand())
-
-
 # ---------------------------------------------------------------------------
 # one compile per program
 

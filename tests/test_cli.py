@@ -54,6 +54,27 @@ def test_validate(tmp_path, capsys):
     assert cli.main(["validate", d]) == 0
 
 
+@pytest.mark.parametrize("command", ["metrics", "trace"])
+def test_telemetry_commands_run_a_saved_model(command, tmp_path, capsys):
+    """`paddle metrics --json` prints the registry's snapshot of the steps
+    it drove and `paddle trace` writes their spans as a schema-valid Chrome
+    trace (exit 1 where it is not)."""
+    from paddle_tpu import observability as obs
+
+    d, _ = _saved_model(tmp_path)
+    if command == "metrics":
+        assert cli.main(["metrics", d, "--steps", "2", "--json"]) == 0
+        snap = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert not obs.validate_snapshot(snap)
+        assert "executor_steps_total" in snap["families"]
+        return
+    out = str(tmp_path / "t.json")
+    assert cli.main(["trace", d, "--steps", "2", "--out", out]) == 0
+    with open(out) as f:
+        names = {e["name"] for e in json.load(f)["traceEvents"]}
+    assert {"telemetry.step", "executor.run", "executor.execute"} <= names
+
+
 def test_merge_model_roundtrip(tmp_path, capsys):
     d, pred = _saved_model(tmp_path)
     bundle = str(tmp_path / "model.paddle")

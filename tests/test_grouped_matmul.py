@@ -1,7 +1,7 @@
 """The grouped matmul's backward kernels (ops/pallas_kernels/grouped_matmul.py)
 and the one function of ops/moe_ops.py that chooses them.
 
-On the CPU the kernels run in interpret mode, as tests/test_pallas_kernels.py
+On the CPU the kernels run in interpret mode, as tests/test_flash_walks.py
 does it, and the `moe` op reaches them as tests/test_kernel_forward_once.py's
 fixture does it: the emit context claims a TPU target.  The AOT compile of
 the cell's real step for a described v5e is in tests/test_kernel_forward_once.py

@@ -3,7 +3,7 @@ the EmitContext and its generic_grad differentiates through them instead
 of launching `flash_fwd` a second time (ops/registry.py keep_for_grad,
 ops/pallas_kernels/flash_attention.py make_flash_train).
 
-On the CPU the Pallas path is reached as tests/test_pallas_kernels.py does
+On the CPU the Pallas path is reached as tests/test_kernel_dispatch.py does
 it: the emit context claims a TPU target and the kernels run in interpret
 mode.  The AOT test compiles the real kernels for a described v5e."""
 
@@ -151,22 +151,6 @@ def test_remat_grad_op_still_recomputes(pallas_on_cpu):
     assert _counter() == {(SDPA, "0"): 1.0}
     for a, b in zip(plain, remat):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
-
-def test_attribution_oracle_walks_the_pallas_path(pallas_on_cpu):
-    """The eager op-by-op walk shares one context a walk, so its grad op
-    finds the pair too; either way it runs and covers the block."""
-    from paddle_tpu.observability import attribution as attr
-
-    _loss, _grads, feed = _attention_block()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    table = attr.attribute_cpu(fluid.default_main_program(), feed,
-                               batch_size=2, repeats=1)
-    assert table["n_ops"] > 0 and table["coverage"] > 0.5
-    assert SDPA in table["by_type"] and "generic_grad" in table["by_type"]
-    assert len(pallas_on_cpu) == 1
-    assert _counter() == {(SDPA, "1"): 1.0}
 
 
 def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):

@@ -1,0 +1,134 @@
+"""What is left of "tuning" is a handful of constants in the kernels: this is
+the check that they admit the benchmark's cells.  For each LM configuration
+of benchmarks/configs (read, never edited) the train program is built from
+its `train.args`, with no environment set, and every kernel-backed op of it
+is put to its kernel's own gate at the shapes its desc carries: the flash
+blocks snap (the kernels' defaults, which `knobs.flash_blocks` hands back
+where no variable is set), and
+`grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of` and
+`hyper_connection.usable` say yes.  Nothing compiles: milliseconds where the
+AOT tests of the same cells take minutes."""
+
+import glob
+import importlib
+import inspect
+import json
+import os
+
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.ops.pallas_kernels import (flash_attention, grouped_matmul,
+                                           head_norm_rope, hyper_connection,
+                                           segment_sum)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# configuration -> the mechanisms its train program has
+MECHANISMS = {
+    "gpt2-medium": {"flash"},
+    "olmoe-1b-7b": {"flash", "head_norm_rope", "grouped_matmul"},
+    "moonlight-16b-a3b": {"flash", "grouped_matmul", "segment_sum"},
+    "lfm2-24b-a2b": {"flash", "head_norm_rope", "grouped_matmul",
+                     "segment_sum"},
+    "sdar-30b-a3b": {"flash", "head_norm_rope", "grouped_matmul",
+                     "segment_sum"},
+    "xing4-29b-a4b": {"flash", "grouped_matmul", "segment_sum",
+                      "hyper_connection"},
+}
+
+
+def _read(*path):
+    with open(os.path.join(ROOT, *path), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _batch(name):
+    """The batch of the configuration's first cell."""
+    cell = next(w for w in _read("BENCHMARK.json")["workloads"]
+                if w["config"] == name)
+    return _read("benchmarks", "traffic", cell["traffic"] + ".json")["batch"]
+
+
+def _flash_gate(T, D, mask=None):
+    """The blocks a call on T positions runs with on the chip."""
+    fa = flash_attention
+    if mask is None:
+        defaults = inspect.signature(fa.flash_attention).parameters
+        bq, bk = fa._snap_blocks(defaults["block_q"].default,
+                                 defaults["block_k"].default, T,
+                                 causal_head=D)
+    else:
+        bq, bk = fa._snap_blocks(
+            *fa.MASK_BLOCKS, T,
+            unit=fa._mask_unit(fa.block_diffusion_mask(*mask), T))
+    return all(b % 128 == 0 and T % b == 0 for b in (bq, bk))
+
+
+@pytest.mark.parametrize("name", list(MECHANISMS))
+def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
+    for var in [v for v in os.environ if v.startswith("PADDLE_TPU_")]:
+        monkeypatch.delenv(var)
+    config = _read("benchmarks", "configs", name + ".json")
+    module, builder = config["train"]["builder"].split(":")
+    getattr(importlib.import_module(module), builder)(
+        **config["train"]["args"])
+    block = fluid.default_main_program().global_block()
+
+    def shape(op, slot):
+        return block._find_var_recursive(op.inputs[slot][0]).shape
+
+    def dtype(op, slot):
+        return jnp.dtype(block._find_var_recursive(op.inputs[slot][0]).dtype)
+
+    passed, positions = set(), 0
+    for op in block.ops:
+        if op.type == "scaled_dot_product_attention":
+            q = shape(op, "Q")
+            packed = op.attrs.get("layout") == "bthd"
+            T = q[1] if packed else q[2]
+            D = q[2] // op.attrs["num_heads"] if packed else q[3]
+            mask = (op.attrs.get("mask") and
+                    (op.attrs["seq_len"], op.attrs["block_length"]))
+            assert _flash_gate(T, D, mask or None), (op.type, q)
+            passed.add("flash")
+            positions = max(positions, T)
+        elif op.type == "latent_attention":
+            T = shape(op, "X")[1]
+            assert _flash_gate(
+                T, op.attrs["qk_nope_dim"] + op.attrs["qk_rope_dim"])
+            passed.add("flash")
+            positions = max(positions, T)
+        elif op.type == "head_norm_rope":
+            _, T, width = shape(op, "X")
+            heads = op.attrs["num_heads"]
+            assert head_norm_rope.pack_of(T, width // heads, heads,
+                                          dtype(op, "X")), (T, width, heads)
+            passed.add("head_norm_rope")
+        elif op.type == "moe":
+            tokens = _batch(name) * positions
+            size = dtype(op, "X").itemsize
+            rows = op.attrs.get("buffer_rows") or tokens * op.attrs["top_k"]
+            for slot in ("WI", "WU", "WO"):
+                _, k, n = shape(op, slot)
+                assert grouped_matmul.usable(rows, k, n, size), (slot, rows)
+            passed.add("grouped_matmul")
+            if op.attrs.get("buffer_rows"):
+                assert segment_sum.usable(rows, tokens, shape(op, "X")[1],
+                                          size), (rows, tokens)
+                passed.add("segment_sum")
+        elif op.type.startswith("hyper_connection_p"):  # pre, post, grads
+            _, n, T, C = shape(op, "X")
+            assert hyper_connection.usable(n, T, C, dtype(op, "X")), (n, T, C)
+            passed.add("hyper_connection")
+    assert passed == MECHANISMS[name]
+
+
+def test_every_lm_configuration_is_held():
+    names = {os.path.basename(p)[:-5] for p in
+             glob.glob(os.path.join(ROOT, "benchmarks", "configs", "*.json"))}
+    lm = {n for n in names
+          if "seq_len" in _read("benchmarks", "configs",
+                                n + ".json")["train"]["args"]}
+    assert lm == set(MECHANISMS)

@@ -191,7 +191,7 @@ def test_flash_schedule_counts_what_the_mask_keeps(geometry):
 
 def test_the_causal_diagonal_is_one_region_of_the_same_schedule():
     """`causal_mask(T)` IS the causal plan (the hash test of
-    tests/test_pallas_kernels.py holds its jaxprs), and the index maps that
+    tests/test_flash_packed.py holds its jaxprs), and the index maps that
     derive from it are the causal clamps, block by block."""
     T, bq, bk = 128, 16, 32
     assert fa._schedule(T, bq, bk, 4, fa.causal_mask(T)) == fa._schedule(

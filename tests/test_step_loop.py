@@ -296,25 +296,25 @@ class TestDataFeederStacking:
 
 class TestKnob:
     def test_env_override(self, monkeypatch):
-        from paddle_tpu.autotune import knobs
+        from paddle_tpu import knobs
 
         monkeypatch.setenv("PADDLE_TPU_STEPS_PER_DISPATCH", "4")
-        assert knobs.steps_per_dispatch(default=1, store=False) == 4
+        assert knobs.steps_per_dispatch(default=1) == 4
 
     def test_env_garbage_rejected(self, monkeypatch):
-        from paddle_tpu.autotune import knobs
+        from paddle_tpu import knobs
 
         monkeypatch.setenv("PADDLE_TPU_STEPS_PER_DISPATCH", "zero")
         with pytest.raises(ValueError):
-            knobs.steps_per_dispatch(default=1, store=False)
+            knobs.steps_per_dispatch(default=1)
         monkeypatch.setenv("PADDLE_TPU_STEPS_PER_DISPATCH", "-2")
         with pytest.raises(ValueError):
-            knobs.steps_per_dispatch(default=1, store=False)
+            knobs.steps_per_dispatch(default=1)
 
     def test_default_passthrough(self):
-        from paddle_tpu.autotune import knobs
+        from paddle_tpu import knobs
 
-        assert knobs.steps_per_dispatch(default=1, store=False) == 1
+        assert knobs.steps_per_dispatch(default=1) == 1
 
     def test_executor_run_respects_env(self, monkeypatch):
         cost, main, startup = _train_mlp()

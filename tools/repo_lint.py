@@ -55,16 +55,12 @@ Classes of rot this repo has actually accumulated:
      join.  Line-anchored tripwire; ``tests/`` exempt (they assert on
      scope behaviour).
 
-  10. raw tuning-knob env reads outside ``paddle_tpu/autotune/`` — the
-     autotuner (ISSUE 14) made PADDLE_TPU_FLASH_BQ/BK,
-     PADDLE_TPU_PAGE_SIZE and friends an explicit OVERRIDE LAYER
-     resolved (and validated) in ``paddle_tpu/autotune/knobs.py``:
-     trial override > env > winner store > default.  A raw
-     ``os.environ`` read of a knob-class name anywhere else re-creates
-     the pre-ISSUE-14 world where the env var is the only mechanism,
-     the store is silently bypassed, and garbage values int()-crash at
-     trace time.  Line-anchored tripwire; ``tests/`` exempt (they
-     monkeypatch knobs on purpose).
+  10. raw tuning-knob env reads outside ``paddle_tpu/knobs.py`` —
+     PADDLE_TPU_FLASH_BQ/BK, PADDLE_TPU_PAGE_SIZE and friends are read
+     and VALIDATED in that one module.  A raw ``os.environ`` read of a
+     knob-class name anywhere else lets garbage values int()-crash at
+     trace time or fall back to a default in silence.  Line-anchored
+     tripwire; ``tests/`` exempt (they monkeypatch knobs on purpose).
 
 Usage: ``python tools/repo_lint.py [root]`` — prints findings, exits 1 if
 any.  `tests/` is exempt from the __init__ rule (pytest rootdir-style
@@ -289,20 +285,20 @@ def _check_ckpt_writes(root, dirpath, filenames, findings):
 
 
 # the tuning-knob env guard: os.environ reads of knob-class names
-# outside the autotune package.  The name list is the knob-class
+# outside paddle_tpu/knobs.py.  The name list is the knob-class
 # definition — extend it when a new tunable parameter gains an env
-# override (and route the read through autotune/knobs.py).
+# override (and route the read through knobs.py).
 _KNOB_ENV_RE = re.compile(
     r"os\.environ\b[^\n]*PADDLE_TPU_(?:FLASH_|PAGE_SIZE"
-    r"|AUTOTUNE\b|SPEC_K\b|SPEC_DRAFT_LAYERS|STEPS_PER_DISPATCH)")
+    r"|SPEC_K\b|SPEC_DRAFT_LAYERS|STEPS_PER_DISPATCH)")
 # plain assignments (and the matching teardown pop) are the EXPORT side
 # of the knob layer (a bench pinning its config so knobs.py resolves it
-# for the whole process) — only raw reads bypass validation/precedence
-# and get flagged
+# for the whole process) — only raw reads bypass validation and get
+# flagged
 _KNOB_ENV_WRITE_RE = re.compile(
     r"os\.environ\[[^\]]+\]\s*=|os\.environ\.pop\(")
 _KNOB_ENV_DIRS = ("paddle_tpu", "tools")
-_KNOB_ENV_OK_DIR = os.path.join("paddle_tpu", "autotune")
+_KNOB_ENV_OK_FILE = os.path.join("paddle_tpu", "knobs.py")
 
 
 def _check_knob_env(root, dirpath, filenames, findings):
@@ -310,15 +306,13 @@ def _check_knob_env(root, dirpath, filenames, findings):
     top = "" if rel_dir == "." else rel_dir.split(os.sep)[0]
     if top and top not in _KNOB_ENV_DIRS:
         return
-    if rel_dir == _KNOB_ENV_OK_DIR \
-            or rel_dir.startswith(_KNOB_ENV_OK_DIR + os.sep):
-        return
     for fname in filenames:
         if not fname.endswith(".py"):
             continue
         path = os.path.join(dirpath, fname)
         rel = os.path.relpath(path, root)
-        if rel == os.path.join("tools", "repo_lint.py"):
+        if rel in (os.path.join("tools", "repo_lint.py"),
+                   _KNOB_ENV_OK_FILE):
             continue
         if top == "" and fname not in _ROOT_SCRIPTS:
             continue
@@ -329,11 +323,8 @@ def _check_knob_env(root, dirpath, filenames, findings):
                             and not _KNOB_ENV_WRITE_RE.search(line):
                         findings.append(
                             f"raw tuning-knob env read: {rel}:{i} "
-                            f"(resolve through paddle_tpu/autotune/"
-                            f"knobs.py — trial override > validated "
-                            f"env > winner store > default — so the "
-                            f"env var stays an override layer, not "
-                            f"the only mechanism)")
+                            f"(read it through paddle_tpu/knobs.py, "
+                            f"which validates it)")
         except OSError:
             pass
 

@@ -62,8 +62,8 @@ Env knobs:
   SERVE_REPEATS=3       paired repeats per side (spec/router modes);
                         medians are compared, not single runs
   SERVE_SPEC_K=6        speculation depth (spec mode; exported as
-                        PADDLE_TPU_SPEC_K so the knob layer resolves it
-                        above any persisted autotune winner)
+                        PADDLE_TPU_SPEC_K, which paddle_tpu/knobs.py
+                        reads)
   SERVE_SPEC_DRAFT_LAYERS=1      draft tower depth (spec mode)
   SERVE_SPEC_TAIL_SCALE=0.01     damping of the target's post-draft
                         residual branches (spec mode; 0 disables)
@@ -794,9 +794,7 @@ def main(argv=None):
         cfg["spec_draft"] = _env_int("SERVE_SPEC_DRAFT_LAYERS", 1)
         cfg["spec_tail_scale"] = _env_float("SERVE_SPEC_TAIL_SCALE",
                                             0.01)
-        # export through the knob env (validated there) so the bench
-        # config outranks any persisted `paddle tune spec_decode`
-        # winner — the A/B row must be self-describing
+        # export through the knob env (validated in paddle_tpu/knobs.py)
         os.environ["PADDLE_TPU_SPEC_K"] = str(cfg["spec_k"])
         os.environ["PADDLE_TPU_SPEC_DRAFT_LAYERS"] = str(
             cfg["spec_draft"])
