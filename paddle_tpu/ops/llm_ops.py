@@ -7,12 +7,13 @@ the noising of block-diffusion training (BD3-LM's objective; first user:
 SDAR-30B-A3B) and the hyper-connection, a residual path of several streams
 mixed per token (mHC, arXiv:2512.24880; first user: Xing4.0-29B-A4B).
 
-All but latent attention, `head_norm_rope` and the two hyper-connection
-ops are plain jax.numpy, so `generic_grad` differentiates them by
-re-emission and XLA's CSE merges the re-emitted forward with the first.
-`head_norm_rope` (Q or K from the projection's layout to attention's: the
-per-head norm, the rotary turn and the head split in one pass; first users:
-OLMoE, LFM2, SDAR) and `hyper_connection_pre` / `_post` take Pallas kernels
+All but latent attention, `head_norm_rope`, `gated_short_conv` and the two
+hyper-connection ops are plain jax.numpy, so `generic_grad` differentiates
+them by re-emission and XLA's CSE merges the re-emitted forward with the
+first.  `head_norm_rope` (Q or K from the projection's layout to
+attention's: the per-head norm, the rotary turn and the head split in one
+pass; first users: OLMoE, LFM2, SDAR), `gated_short_conv` and
+`hyper_connection_pre` / `_post` take Pallas kernels
 on one TPU and plain jax.numpy everywhere else, and bring grad ops of their
 own, whose emitters need the forward's inputs (and the small outputs it
 kept) alone and never emit the forward: a Mosaic call is opaque to CSE, so
@@ -70,6 +71,13 @@ _MET_CONV_LAYERS = _MET.counter(
     "gated short convolution ops traced (forward emission; once a compile, "
     "not once a step), by the channels convolved (dim) and the taps a "
     "channel (kernel)")
+_MET_CONV_KERNELS = _MET.counter(
+    "short_conv_kernels_traced_total",
+    "gated short convolution ops traced (once a compile, not once a step; "
+    "one of each a convolution layer), by the op (op: fwd, grad) and the "
+    "path its emitter took (pallas: the kernels of "
+    "ops/pallas_kernels/short_conv.py, a tile of whole rows of X in VMEM; "
+    "xla: plain jax.numpy)")
 
 
 def wide_dtype(dtype):
@@ -842,32 +850,14 @@ def mtp_project(ctx, ins, attrs):
                         + rms(e, eps, (2,), ins["ENorm"][0]) @ w[D:]]}
 
 
-@register_op("gated_short_conv")
-def gated_short_conv(ctx, ins, attrs):
-    """The gated short convolution of LFM2 (transformers' `Lfm2ShortConv`),
-    without its two projections: X [B, T, 3D] is the input projection's
-    result, three thirds B, C, u in that order; Filter [D, L] holds L taps
-    a channel.
-
-      g = B * u                                  (the input gate)
-      c_t = sum_{j < L} Filter[:, j] * g_{t - (L - 1) + j}, g zero before
-            the sequence starts: depthwise and causal, the LAST tap on the
-            current token
-      Out = C * c                                (the output gate)  [B, T, D]
-
-    No position enters.  L shifted multiply-adds that XLA fuses into one
-    pass over X (bound by HBM: PERF.md, PR 33, has it against
-    `lax.conv_general_dilated`); at least float32 inside, X's dtype out."""
+def gated_short_conv_plain(x, w):
+    """X [B, T, 3D], Filter [D, L] -> Out [B, T, D] (`gated_short_conv`'s
+    equations): L shifted multiply-adds that XLA fuses into one pass over
+    X, at least float32 inside, X's dtype out.  What the kernels of
+    ops/pallas_kernels/short_conv.py compute, in plain jax.numpy."""
     import jax.numpy as jnp
 
-    x, w = ins["X"][0], ins["Filter"][0]
-    dim, taps = w.shape
-    if x.ndim != 3 or x.shape[-1] != 3 * dim:
-        raise ValueError(f"gated_short_conv: X {x.shape} is not [B, T, 3 x "
-                         f"{dim}] for a Filter {w.shape}")
-    if not ctx.in_grad_replay():
-        _MET_CONV_LAYERS.inc(dim=str(dim), kernel=str(taps))
-    T = x.shape[1]
+    T, taps = x.shape[1], w.shape[1]
     wide = wide_dtype(x.dtype)
     gate_in, gate_out, u = jnp.split(x.astype(wide), 3, axis=-1)
     with part_scope("conv.gate"):
@@ -880,7 +870,83 @@ def gated_short_conv(ctx, ins, attrs):
                 g, ((0, 0), (back, 0), (0, 0)))[:, :T]
     with part_scope("conv.gate"):
         out = gate_out * c
-    return {"Out": [out.astype(x.dtype)]}
+    return out.astype(x.dtype)
+
+
+def _short_conv(ctx, ins, op: str):
+    """(X, Filter, whether the kernels of ops/pallas_kernels/short_conv.py
+    run) of a `gated_short_conv` op or its grad op (`op`: fwd, grad): one
+    TPU, no mesh, kernels not disabled, and a shape they take (`usable`).
+    Counts the emission by the path taken."""
+    from .pallas_kernels import short_conv as kernels
+    from .pallas_kernels._common import pallas_dispatch_ok
+
+    x, w = ins["X"][0], ins["Filter"][0]
+    dim, taps = w.shape
+    if x.ndim != 3 or x.shape[-1] != 3 * dim:
+        raise ValueError(f"gated_short_conv: X {x.shape} is not [B, T, 3 x "
+                         f"{dim}] for a Filter {w.shape}")
+    take = pallas_dispatch_ok(ctx) and kernels.usable(x.shape[1], dim, taps,
+                                                      x.dtype)
+    if not ctx.in_grad_replay():
+        _MET_CONV_KERNELS.inc(op=op, path="pallas" if take else "xla")
+    return x, w, take
+
+
+@register_op("gated_short_conv",
+             grad=_own_grad_maker("gated_short_conv_grad"))
+def gated_short_conv(ctx, ins, attrs):
+    """The gated short convolution of LFM2 (transformers' `Lfm2ShortConv`),
+    without its two projections: X [B, T, 3D] is the input projection's
+    result, three thirds B, C, u in that order; Filter [D, L] holds L taps
+    a channel.
+
+      g = B * u                                  (the input gate)
+      c_t = sum_{j < L} Filter[:, j] * g_{t - (L - 1) + j}, g zero before
+            the sequence starts: depthwise and causal, the LAST tap on the
+            current token
+      Out = C * c                                (the output gate)  [B, T, D]
+
+    No position enters.  At least float32 inside, X's dtype out.  On one
+    TPU, with D in 128-lane blocks, T in whole row tiles and bf16 or
+    float32 X, one Pallas kernel reads the three thirds where the
+    projection wrote them, widens in VMEM and writes Out once
+    (ops/pallas_kernels/short_conv.py); everywhere else (the CPU, a mesh,
+    other shapes) L shifted multiply-adds that XLA fuses into one pass over
+    X (`gated_short_conv_plain`; bound by HBM: PERF.md, PR 33, has it
+    against `lax.conv_general_dilated`); `short_conv_kernels_traced_total`
+    says which.  The backward is the op `gated_short_conv_grad`."""
+    from .pallas_kernels import short_conv as kernels
+
+    x, w, take = _short_conv(ctx, ins, "fwd")
+    if not ctx.in_grad_replay():
+        _MET_CONV_LAYERS.inc(dim=str(w.shape[0]), kernel=str(w.shape[1]))
+    if not take:
+        return {"Out": [gated_short_conv_plain(x, w)]}
+    with part_scope("conv.taps"):
+        return {"Out": [kernels.short_conv_fwd(x, w)]}
+
+
+@register_op("gated_short_conv_grad", grad=None)
+def gated_short_conv_grad(ctx, ins, attrs):
+    """`gated_short_conv`'s backward from X, Filter and Out@GRAD alone (g
+    and c are made again; nothing of the forward is kept) -> X@GRAD in X's
+    dtype, Filter@GRAD summed in float32.  The backward kernel where the
+    forward took its kernel, else `jax.vjp` of the plain emission (plain
+    HLO, which XLA merges with the forward's)."""
+    import jax
+
+    from .pallas_kernels import short_conv as kernels
+
+    x, w, take = _short_conv(ctx, ins, "grad")
+    dout = ins["Out" + GRAD_SUFFIX][0].astype(x.dtype)
+    if take:
+        with part_scope("conv.taps"):
+            dx, dw = kernels.short_conv_bwd(dout, x, w)
+    else:
+        dx, dw = jax.vjp(gated_short_conv_plain, x, w)[1](dout)
+    return {"X" + GRAD_SUFFIX: [dx],
+            "Filter" + GRAD_SUFFIX: [dw.astype(w.dtype)]}
 
 
 # ---------------------------------------------------------------------------

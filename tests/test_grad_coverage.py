@@ -126,6 +126,9 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # test_xing.py, now through grad ops of their OWN,
     # `hyper_connection_pre_grad` / `hyper_connection_post_grad`, which
     # are not differentiable themselves: the test below)
+    # PR 46: +0 (gated_short_conv stays checked in test_lfm2.py, now
+    # through a grad op of its OWN, `gated_short_conv_grad`, which is not
+    # differentiable itself: the test below)
     # PR 45: +2 (lightning_attention, block_sparse_attention: MiniCPM-SALA's
     # two token mixers; `block_topk_select` takes no gradient), each
     # numerically checked in test_sala.py
@@ -141,6 +144,7 @@ import pytest  # noqa: E402
 
 @pytest.mark.parametrize("fwd,grad,kept", [
     ("head_norm_rope", "head_norm_rope_grad", ()),
+    ("gated_short_conv", "gated_short_conv_grad", ()),
     ("hyper_connection_pre", "hyper_connection_pre_grad", ("Proj", "Inv")),
     ("hyper_connection_post", "hyper_connection_post_grad", ())])
 def test_ops_with_grad_ops_of_their_own(fwd, grad, kept):

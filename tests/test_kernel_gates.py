@@ -6,8 +6,9 @@ is put to its kernel's own gate at the shapes its desc carries: the flash
 blocks snap (the kernels' defaults, which `knobs.flash_blocks` hands back
 where no variable is set), and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
-`hyper_connection.usable` and `sparse_flash.usable` say yes.  Nothing compiles: milliseconds where the
-AOT tests of the same cells take minutes."""
+`hyper_connection.usable`, `sparse_flash.usable` and `short_conv.usable`
+say yes.  Nothing compiles: milliseconds where the AOT tests of the same
+cells take minutes."""
 
 import glob
 import importlib
@@ -21,7 +22,8 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.ops.pallas_kernels import (flash_attention, grouped_matmul,
                                            head_norm_rope, hyper_connection,
-                                           segment_sum, sparse_flash)
+                                           segment_sum, short_conv,
+                                           sparse_flash)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -31,7 +33,7 @@ MECHANISMS = {
     "olmoe-1b-7b": {"flash", "head_norm_rope", "grouped_matmul"},
     "moonlight-16b-a3b": {"flash", "grouped_matmul", "segment_sum"},
     "lfm2-24b-a2b": {"flash", "head_norm_rope", "grouped_matmul",
-                     "segment_sum"},
+                     "segment_sum", "short_conv"},
     "sdar-30b-a3b": {"flash", "head_norm_rope", "grouped_matmul",
                      "segment_sum"},
     "xing4-29b-a4b": {"flash", "grouped_matmul", "segment_sum",
@@ -127,6 +129,11 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
             assert sparse_flash.usable(rows // heads, D, heads // kv_heads,
                                        op.attrs["block"]), (rows, D)
             passed.add("sparse_flash")
+        elif op.type == "gated_short_conv":
+            _, T, _ = shape(op, "X")
+            D, L = shape(op, "Filter")
+            assert short_conv.usable(T, D, L, dtype(op, "X")), (T, D, L)
+            passed.add("short_conv")
         elif op.type.startswith("hyper_connection_p"):  # pre, post, grads
             _, n, T, C = shape(op, "X")
             assert hyper_connection.usable(n, T, C, dtype(op, "X")), (n, T, C)
