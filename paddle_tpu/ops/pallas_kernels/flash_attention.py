@@ -188,6 +188,52 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   wants index maps that are no clamp); blocks chosen per kernel ((2048,
   1024) for the forward and dq alone: 0.5 ms a layer).
 
+- Where the backward's delta = rowsum(dO * O) is made (PR 47).  XLA made
+  it everywhere: a float32 product, a sum over a head's columns, and on
+  [B, T, H * D] a transpose to [B, H, T].  There (GPT-2-medium's 24
+  layers) it cost 1.95 ms of a 143.0 ms step by event: copies of O and dO
+  0.78 + 0.82 (the 64-wide split of the lane dimension is a relayout),
+  the reduce 0.17, slices and reshapes 0.12, and 0.06 in the output
+  projection's dX product, whose event carried the multiply in its
+  epilogue (PR 36 read that event's whole 2.22 ms as delta's: 2.17 of it
+  is the product, which stays).  Three forms, device ms a backward from a
+  trace (dq + dkv + what makes delta; each shape alone, operands of the
+  cell's size; P the parent, A delta inside dq from the O tile blocked as
+  dO, the rows a second output, B a kernel of its own, `flash_bwd_delta`,
+  4096 rows a step, before the parent's dq):
+                                          P        A        B
+    packed B 8 T 1024 H 16 D 64 (GPT-2)   0.9029   0.7968   0.8234
+    packed B 4 T 4096 H 16 D 64           6.2189   6.1296   6.1143
+    packed B 2 T 4096 H 16 D 128          3.7896   3.6109   3.6150
+    [8, 16, 1024, 64]                     1.2859   1.3707   1.3303
+    [8, 16, 1024, 128]                    0.9646   0.8312   0.8875
+    [1, 16, 4096, 128] (OLMoE)            1.7070   1.7356   1.7453
+    [1, 16, 8192, 192 / 128] (Moonlight)  9.8231   9.9823   9.9223
+    [1, 32, 4096, 192 / 128] (Xing4's)    5.5206   5.6225   5.5757
+    [1, 32 on 8, 8192, 64] (LFM2)        12.8257  12.8815  12.8413
+    [1, 32 on 4, 8192, 128], the block-diffusion mask (SDAR)
+                                          6.8576   7.1150   7.1473
+  In A dq grows by what the work is (0.3534 -> 0.3713 ms at GPT-2's call,
+  0.348 -> 0.3805 in its step; 4.348 -> 4.526 at Moonlight's): the lane
+  reductions and the column's way to a row (_column_as_row) are not
+  hidden under the MXU, and every further operand costs every grid step
+  its bookkeeping; B moves O and dO once more at HBM's rate (0.058 ms at
+  GPT-2's call, 0.091 at Moonlight's, 0.179 at SDAR's).  On [B, H, T, D]
+  XLA's form is a plain sum over the last axis, at HBM's rate alone
+  (0.046 / 0.090 / 0.179 ms at OLMoE's, Moonlight's, SDAR's call) and
+  folded into whatever makes dO in a step; BOTH kernel forms lose to it
+  alone at every long shape above and won nothing in the cells (samples/s,
+  one run each: Moonlight 4.3868 -> A 4.3702 / B 4.3728, SDAR 5.7213 ->
+  5.7172 / 5.7045; two runs of ONE program differ by 0.2% there), so that
+  entry keeps it and traces to the parent's jaxprs still.  On [B, T, H * D] A is kept (GPT-2-medium
+  55.75 -> 56.17 samples/s; B 55.99).  Inside A: the delta columns kept
+  in a [bq, 1] VMEM scratch a head for the walk read 0.4137 ms at GPT-2's
+  call where reading the written rows back reads 0.3713 (a [rows, 1]
+  scratch is a masked store and a load a sublane tile; the row's way back
+  to a column was free already); made strip by strip inside the walk
+  where one K block holds the sequence, 0.3740; the q axis "parallel" or
+  "arbitrary", the same to four digits (one core).
+
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
 array's — a (1, bq) block over a (BH, T) array satisfies neither (first
@@ -212,6 +258,14 @@ _MET_SCORES = _MET.counter(
     "kernel: part=square the B*H*T*T of the call, part=computed those its "
     "schedule computes (dead blocks and the part of a strip beyond a "
     "staircase's reach left out)")
+
+
+_MET_DELTA = _MET.counter(
+    "flash_backward_delta_traced_total",
+    "flash backward passes traced (once a compile, not once a step), by "
+    "where their delta = rowsum(dO * O) is made: where=dq inside the "
+    "flash_bwd_dq kernel, from the O and dO tiles ([B, T, H * D] "
+    "operands); where=xla by XLA before it ([B, H, T, D] operands)")
 
 
 def _snap_block(block: int, T: int, tile: int = 128) -> int:
@@ -1160,12 +1214,34 @@ def _dq_tile(q, do, lse, delta, k, v, lo, *, scale: float, ahead):
         preferred_element_type=jnp.float32)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_sc, *, scale: float, bq: int, bk: int, plan,
-               pack: int = 1):
+def _delta_column(do, o, lo):
+    """A head's rows of dO * O summed over its own columns (the lanes from
+    `lo`, _head_lanes), as a [rows, 1] float32 column: the float32 product
+    of the operands as they are stored, summed in float32."""
+    import jax.numpy as jnp
+
+    (prod,) = _head_lanes(lo, do.astype(jnp.float32) * o.astype(jnp.float32))
+    return prod.sum(axis=-1, keepdims=True)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, *refs, scale: float, bq: int,
+               bk: int, plan, pack: int = 1, makes_delta: bool = False):
+    """dq of a q block gathered over its K steps.  `refs`: the logsumexp
+    and delta rows, dq, the accumulator; or, `makes_delta`, O, the
+    logsumexp rows, dq, the delta rows as a second OUTPUT, the accumulator:
+    delta = rowsum(dO * O) is then made here, at the q block's first K
+    step, from the dO tile the walk reads anyway and the O tile beside it,
+    a lane tile of rows at a time, each head's into its own row of the
+    (pack, 1, T) block (a whole row a head, resident across the head's q
+    blocks, each writing its own lanes, as the forward's logsumexp
+    leaves), and the walk reads it back from there."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if makes_delta:
+        o_ref, lse_ref, dq_ref, delta_ref, acc_sc = refs
+    else:
+        lse_ref, delta_ref, dq_ref, acc_sc = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -1175,6 +1251,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(kj == 0)
     def _init():
         acc_sc[...] = jnp.zeros(acc_sc.shape, dtype=jnp.float32)
+        if not makes_delta:
+            return
+        column = _shared(_delta_column)
+        tall = 128 if bq % 128 == 0 else bq
+        for r0 in range(0, bq, tall):
+            at, row = pl.ds(r0, tall), pl.ds(q0 + r0, tall)
+            do, o = do_ref[0, at, :], o_ref[0, at, :]
+            for a in range(pack):
+                delta = column(do, o, _first_lane(a, pack))
+                if tall % 128:  # off the lane grid: the column, squeezed
+                    delta_ref[a, 0, row] = delta[:, 0]
+                else:
+                    delta_ref[a, :, row] = _column_as_row(delta)
 
     def update(r0, rows, cols, ahead=None):
         at = pl.ds(r0, rows)
@@ -1319,11 +1408,13 @@ def _dkv_q_maps(T: int, bq: int, bk: int, mask, group: int, nb: int = 0):
 @functools.lru_cache(maxsize=None)
 def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
                Dv, group=1, nb=0):
-    """(dq call, dkv call) on q [BH, T, D], dO [BH, T, Dv], k [BH / group,
-    T, D], v [BH / group, T, Dv] operands, or, `nb` lane blocks across
-    (_tile_at), all five [B, T, H * D]; and (BH, 1, T) lse and delta rows
-    (dq leaves as q, dk as k, dv as v); memoized and jitted like
-    _fwd_call."""
+    """(dq call, dkv call), memoized and jitted like _fwd_call; dq leaves
+    as q, dk as k, dv as v, and lse and delta are (BH, 1, T) float32 rows.
+    On q [BH, T, D], dO [BH, T, Dv], k [BH / group, T, D], v [BH / group,
+    T, Dv] operands both take (q, k, v, dO, lse, delta).  On [B, T, H * D]
+    operands, `nb` lane blocks across (_tile_at), dq(q, k, v, dO, O, lse)
+    returns (dq, delta rows): delta = rowsum(dO * O) is made inside it
+    (_dq_kernel) and dkv takes the rows as they leave."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1344,23 +1435,33 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
             else (heads, T, lanes), dtype)
 
     rows = lambda b, i, j: at(b, i)
+    in_dq = bool(nb)  # delta made inside dq: the module docstring, PR 47
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dq_plan, pack=pack),
+                          plan=dq_plan, pack=pack, makes_delta=in_dq),
         grid=(BH // pack, T // bq, T // bk),
         in_specs=[
             pl.BlockSpec((1, bq, W), rows),
             pl.BlockSpec((1, bk, W), kv_idx),
             pl.BlockSpec((1, bk, Wv), kv_idx),
             pl.BlockSpec((1, bq, Wv), rows),
-            row_spec,
-            row_spec,
+            # O blocked as dO, or the delta rows after the logsumexp's
+            *([pl.BlockSpec((1, bq, Wv), rows), row_spec] if in_dq
+              else [row_spec, row_spec]),
         ],
-        out_specs=pl.BlockSpec((1, bq, W), rows),
-        out_shape=shape(BH, W),
+        out_specs=([pl.BlockSpec((1, bq, W), rows), row_spec] if in_dq
+                   else pl.BlockSpec((1, bq, W), rows)),
+        out_shape=([shape(BH, W),
+                    jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)] if in_dq
+                   else shape(BH, W)),
         scratch_shapes=[pltpu.VMEM((bq, W), jnp.float32)],
+        # the delta rows dq writes stay resident across a head's q blocks,
+        # so the q axis is sequential then, as the forward's is for its
+        # logsumexp (one core on the v5e: the same time either way)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=(
+                "parallel", "arbitrary" if in_dq else "parallel",
+                "arbitrary")),
         name="flash_bwd_dq",
         interpret=interpret,
     )
@@ -1401,24 +1502,26 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
     bq, bk = _blocks(c, causal, mask, block_q, block_k, interpret)
     s = scale if scale is not None else 1.0 / (c.D ** 0.5)
     qf, kf, vf, of, dof = (_heads_first(a, c) for a in (q, k, v, o, do))
-    # a head's rows of dO * O summed over its own columns, [B * H, T]
-    delta = of.astype(jnp.float32) * dof.astype(jnp.float32)
-    if c.nb:  # [B, T, H * D] -> [B, H, T]
-        delta = jnp.moveaxis(
-            delta.reshape(delta.shape[:2] + (heads, c.D)).sum(-1), 2, 1)
-    else:
-        delta = delta.sum(-1)
+    if not c.nb:
+        # a head's rows of dO * O summed over its own columns, [B * H, T]:
+        # XLA folds the sum into whatever makes dO (module docstring, PR
+        # 47); here, before lse3, so this entry's jaxprs stay the pinned ones
+        delta = (of.astype(jnp.float32) * dof.astype(jnp.float32)).sum(-1)
     # (BH, 1, T) full-row layout for lse/delta: see module docstring
     lse3 = lse.reshape(c.BH, 1, c.T).astype(jnp.float32)
-    delta3 = delta.reshape(c.BH, 1, c.T)
     dq_plan = dkv_plan = None
     if causal or mask:
         dq_plan = _masked_plan("flash_bwd_dq", c.BH, c.T, bq, bk, mask)
         dkv_plan = _masked_plan("flash_bwd_dkv", c.BH, c.T, bq, bk, mask)
+    _MET_DELTA.inc(1, where="dq" if c.nb else "xla")
     dq_call, dkv_call = _bwd_calls(c.BH, c.T, c.D, bq, bk, dq_plan,
                                    dkv_plan, q.dtype, interpret, s, c.Dv,
                                    c.group, c.nb)
-    dq = dq_call(qf, kf, vf, dof, lse3, delta3)
+    if c.nb:  # the layout the projections leave: dq makes delta itself
+        dq, delta3 = dq_call(qf, kf, vf, dof, of, lse3)
+    else:
+        delta3 = delta.reshape(c.BH, 1, c.T)
+        dq = dq_call(qf, kf, vf, dof, lse3, delta3)
     dk, dv = dkv_call(qf, kf, vf, dof, lse3, delta3)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 

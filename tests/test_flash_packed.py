@@ -81,21 +81,25 @@ PAIR_MUTANTS = {  # body, the helper it gets wrong: the first result to fail
     "dq_reads_the_neighbours_lanes": ("_dq_kernel", "_head_lanes", "dq"),
     "dq_keeps_the_neighbours_half": ("_dq_kernel", "_join_heads", "dq"),
     "dkv_reads_the_neighbours_lanes": ("_dkv_kernel", "_head_lanes", "dk"),
+    "dq_sums_both_heads_into_one_delta": ("_dq_kernel", "_delta_column",
+                                          "dq"),
 }
 
 
 @pytest.mark.parametrize("mutant", list(PAIR_MUTANTS))
 def test_flash_packed_wrong_half_of_a_pair_fails(mutant, monkeypatch):
     """A body that takes the WRONG head of a pair, reading its neighbour's
-    lanes of q and dO or keeping its neighbour's half of a product, fails
-    the check in the result that body writes."""
+    lanes of q and dO or keeping its neighbour's half of a product, or
+    that sums dO * O over BOTH heads' lanes into one delta (PR 47: dq makes
+    it on this layout), fails the check in the result that body writes."""
     from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
     body, helper, fails = PAIR_MUTANTS[mutant]
     real_body, real_helper = getattr(fa, body), getattr(fa, helper)
     wrong = {"_head_lanes": lambda lo, *tiles: real_helper(
                  None if lo is None else 64 - lo, *tiles),
-             "_join_heads": lambda parts: real_helper(list(parts)[::-1])}
+             "_join_heads": lambda parts: real_helper(list(parts)[::-1]),
+             "_delta_column": lambda do, o, lo: real_helper(do, o, None)}
 
     def mutated(*refs, **kw):
         with monkeypatch.context() as m:
@@ -161,7 +165,9 @@ def test_flash_packed_train_pair_differentiates(monkeypatch):
 # two heads a block: sha256 of the jaxprs it traces to (forward with and
 # without the logsumexp, backward, the train wrapper's vjp; causal, default
 # blocks as the chip snaps them) under this suite's conftest, computed by
-# this function at `git archive 7380891`, the parent of PR 36
+# this function at `git archive 7380891`, the parent of PR 36 (PR 47 gave
+# the OTHER entry's dq a delta of its own making and left this one's
+# backward, XLA's delta included, as it was: the hashes did not move)
 OLD_ENTRY = {
     "lfm2_32_on_8_T8192_D64": ((1, 32, 8, 8192, 64, 64),
         "010a369967297fc4ccd074bf45f6ce6239428214a3b62c583972c15a63c958ea"),

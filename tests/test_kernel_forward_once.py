@@ -350,6 +350,11 @@ def test_aot_one_forward_kernel_a_layer(v5e):
                      "flash_bwd_dkv": layers}, kinds
     assert _counter() == {(SDPA, "1"): float(layers)}
     assert _paths() == {("bthd", "flash_packed"): float(layers)}
+    # and each layer's dq kernel makes the backward's delta itself (PR 47)
+    fam = obs.REGISTRY.snapshot()["families"][
+        "flash_backward_delta_traced_total"]
+    assert {s["labels"]["where"]: s["value"] for s in fam["series"]} == {
+        "dq": float(layers)}
 
 
 @pytest.mark.parametrize("head_dim,kv_heads,path", [
@@ -452,6 +457,34 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
             text = lowered.compile().as_text()
             assert text.count('custom_call_target="tpu_custom_call"') == (
                 2 if name.startswith("flash_bwd") else 1), name
+
+
+@pytest.mark.parametrize("shape,heads", [((8, 1024, 1024), 16),
+                                         ((1, 4096, 2048), 16)],
+                         ids=["gpt2m_train_bs8", "heads_of_128_T4096"])
+def test_aot_packed_backward_makes_its_own_delta(v5e, shape, heads):
+    """The backward on [B, T, H * D] operands, bf16 and x64 off as the chip
+    runs it, through Mosaic for the described v5e: `flash_bwd_dq` takes O
+    beside dO and writes the delta rows `flash_bwd_dkv` reads (two heads
+    of 64 a lane block at GPT-2-medium's call, one of 128), and the
+    compiled program holds the two kernels and NO float32 tensor of O's
+    shape: XLA's product dO * O, which it wrote out at four bytes an
+    element, is gone (PR 47)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    B, T, W = shape
+    one = SingleDeviceSharding(v5e)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((B * heads, T), jnp.float32, sharding=one)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda q, k, v, o, l, do: fa.flash_attention_bwd(
+            q, k, v, o, l, do, causal=True, heads=heads)).lower(
+                x, x, x, x, lse, x).compile().as_text()
+    assert _kernel_calls(text) == {"flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert f"f32[{B},{T},{W}]" not in text
+    assert f"f32[{B},{T},{heads},{W // heads}]" not in text
 
 
 # ---------------------------------------------------------------------------
