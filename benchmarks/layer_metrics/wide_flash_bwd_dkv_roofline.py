@@ -1,0 +1,19 @@
+"""wide_flash_bwd_dkv_roofline — the roofline share of the `flash_bwd_dkv`
+kernel in the traced window at a head width of its own (`train.args`'
+`head_dim`): see wide_flash_fwd_roofline.py, whose `kernel_share` does the
+arithmetic (FLOPs and bytes of kind 'bwd_dkv' from benchmarks/flops_lfm2.py
+over the kernel's device time in the trace)."""
+
+LAYER = "Pallas kernels"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "device_trace"
+MOVES = "train_samples_per_s"
+
+
+def read(run):
+    from harness import load_module
+
+    return load_module("layer_metrics",
+                       "wide_flash_fwd_roofline").kernel_share(
+        run, "flash_bwd_dkv", "bwd_dkv")

@@ -11,7 +11,8 @@ import functools
 from typing import List, NamedTuple, Optional, Sequence, Union
 
 from ..framework.core import Variable
-from ..framework.initializer import ConstantInitializer, NormalInitializer
+from ..framework.initializer import (ConstantInitializer, Initializer,
+                                     NormalInitializer, UniformInitializer)
 from ..framework.layer_helper import LayerHelper
 
 
@@ -321,7 +322,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          sp_schedule="plain", qk_norm_epsilon=None,
                          rope_theta=None, out_param_attr=None,
                          num_kv_heads=None, qk_norm_per_head=False,
-                         head_dim=None, block_diffusion=None):
+                         head_dim=None, block_diffusion=None,
+                         output_gate=False, rotary_dim=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -348,6 +350,13 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     block_length): the T = 2L rows are the noised and the clean copy of L
     tokens; they attend under the block-diffusion mask (`mask` attrs of
     scaled_dot_product_attention) and row r is rotated as position r mod L.
+    `output_gate`: the query projection is twice as wide, [q | gate], and
+    the attention's result is multiplied by sigmoid(gate) before the output
+    projection (Qwen3-Next's gated attention; the op
+    `attention_output_gate`, scope `pdtpu.attn.gate`).  `rotary_dim`: the
+    rotary turn takes the first so many columns of a head alone (a
+    `partial_rotary_factor`: rotate-half inside them, their own
+    frequencies) and the others pass unturned.
     `param_attr` is the Q, K and V projections', `out_param_attr` the
     output projection's.
 
@@ -379,8 +388,25 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     if qk_norm_per_head and qk_norm_epsilon is None:
         raise ValueError("multi_head_attention: qk_norm_per_head is a form "
                          "of the QK-norm: give qk_norm_epsilon")
-    q = fc(queries, num_heads * head_dim, num_flatten_dims=2,
-           param_attr=param_attr, bias_attr=False)
+    if rotary_dim is not None and rope_theta is None:
+        raise ValueError("multi_head_attention: rotary_dim is the width of "
+                         "the rotary turn: give rope_theta")
+    q = fc(queries, (2 if output_gate else 1) * num_heads * head_dim,
+           num_flatten_dims=2, param_attr=param_attr, bias_attr=False)
+    gate = None
+    if output_gate:   # [q | gate], each num_heads * head_dim wide
+        def half(n):
+            out = helper.create_tmp_variable(
+                q.dtype,
+                shape=tuple(q.shape[:-1]) + (num_heads * head_dim,))
+            helper.append_op(
+                "slice", inputs={"Input": [q.name]},
+                outputs={"Out": [out.name]},
+                attrs={"axes": [2], "starts": [n * num_heads * head_dim],
+                       "ends": [(n + 1) * num_heads * head_dim],
+                       "part": "attn.gate"})
+            return out
+        q, gate = half(0), half(1)
     k = fc(keys, kv_heads * head_dim, num_flatten_dims=2,
            param_attr=param_attr, bias_attr=False)
     v = fc(values, kv_heads * head_dim, num_flatten_dims=2,
@@ -416,6 +442,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
             attrs["epsilon"] = qk_norm_epsilon
         if block_diffusion:
             attrs["period"] = int(block_diffusion[0])
+        if rotary_dim is not None:
+            attrs["rotary_dim"] = int(rotary_dim)
         r = helper.create_tmp_variable(
             x.dtype, shape=(x.shape[0], heads, x.shape[1], head_dim))
         helper.append_op("head_norm_rope", inputs=ins,
@@ -462,6 +490,17 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         helper.append_op("reshape", inputs={"X": [back.name]},
                          outputs={"Out": [merged.name]},
                          attrs={"shape": [0, 0, num_heads * head_dim]})
+    if gate is not None:
+        gated = helper.create_tmp_variable(queries.dtype, shape=wide)
+        helper.append_op(
+            "attention_output_gate",
+            inputs={"X": [merged.name], "Gate": [gate.name]},
+            outputs={"Out": [gated.name]},
+            attrs={"num_heads": num_heads, "num_kv_heads": kv_heads,
+                   "head_dim": head_dim,
+                   "rotary_dim": int(rotary_dim or head_dim),
+                   "part": "attn.gate"})
+        merged = gated
     out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
              bias_attr=False)
     from .sequence import propagate_length
@@ -636,6 +675,94 @@ def lightning_attention(input, num_heads, head_dim, layer_index=0,
     return propagate_length(input, fc(
         out, input.shape[-1], num_flatten_dims=2, param_attr=param_attr,
         bias_attr=False))
+
+
+class _UniformThrough(Initializer):
+    """A float32 uniform draw on [low, high) taken through unary ops in
+    order, then cast to the parameter: `gated_delta_net`'s A_log (log) and
+    dt_bias (the x with softplus(x) = exp(u): log(exp(exp(u)) - 1))."""
+
+    def __init__(self, low, high, ops):
+        self.low, self.high, self.ops = low, high, ops
+
+    def __call__(self, var, block):
+        draw = block.create_var(shape=var.shape, dtype="float32")
+        block.append_op(
+            "uniform_random", outputs={"Out": [draw.name]},
+            attrs={"shape": list(var.shape), "min": self.low,
+                   "max": self.high, "seed": 0, "dtype": "float32"})
+        for op_type, attrs in self.ops:
+            block.append_op(op_type, inputs={"X": [draw.name]},
+                            outputs={"Out": [draw.name]}, attrs=attrs)
+        block.append_op("cast", inputs={"X": [draw.name]},
+                        outputs={"Out": [var.name]},
+                        attrs={"out_dtype": var.dtype})
+
+
+def gated_delta_net(input, key_heads, value_heads, key_dim, value_dim,
+                    conv_kernel=4, epsilon=1e-6, param_attr=None, name=None):
+    """A gated-DeltaNet mixer over [B, T, D] (Gated Delta Networks,
+    arXiv:2412.06464, as transformers' `Qwen3NextGatedDeltaNet` computes
+    it; ops/sparse_linear_ops.py `gated_delta_rule` has the equations):
+    one projection to [q | k | v | z] (`key_heads` heads of `key_dim` for q
+    and k, `value_heads` of `value_dim` for v and the output gate z), one
+    to the per-token gates [b | a] of every value head, a causal depthwise
+    convolution of `conv_kernel` taps + SiLU over [q | k | v], the gated
+    delta rule in chunks on l2-normalised q and k, a
+    per-head RMSNorm of the result times SiLU(z), and an output
+    projection.  Eight parameters, in creation order: W_qkvz [D, 2 Hk Dk +
+    2 Hv Dv], W_ba [D, 2 Hv], the taps [2 Hk Dk + Hv Dv, conv_kernel]
+    (uniform on +- conv_kernel^-1/2, torch's Conv1d default, whatever
+    `param_attr` says), A_log [Hv] (log of uniform [1, 16)), dt_bias [Hv]
+    (the inverse softplus of a log-uniform draw on [0.001, 0.1]: Mamba-2's
+    rule, which the published layer follows), the output norm's gain [Dv]
+    (one), W_out [Hv Dv, D]; no bias.  The decay a token is exp(-exp(A_log) *
+    softplus(a + dt_bias))."""
+    import math
+
+    helper = LayerHelper("gated_delta_net", name=name)
+    Hk, Hv, Dk, Dv = (int(n) for n in (key_heads, value_heads, key_dim,
+                                       value_dim))
+    if Hk < 1 or Hv % Hk:
+        raise ValueError(f"gated_delta_net: {Hv} value heads on {Hk} key "
+                         f"heads")
+    prog = helper.main_program
+    with prog.part_guard("gdn.project"):
+        qkvz = fc(input, 2 * Hk * Dk + 2 * Hv * Dv, num_flatten_dims=2,
+                  param_attr=param_attr, bias_attr=False)
+        ba = fc(input, 2 * Hv, num_flatten_dims=2, param_attr=param_attr,
+                bias_attr=False)
+    bound = int(conv_kernel) ** -0.5
+    taps = helper.create_parameter(
+        attr={}, shape=[2 * Hk * Dk + Hv * Dv, int(conv_kernel)],
+        dtype=input.dtype,
+        default_initializer=UniformInitializer(-bound, bound))
+    a_log = helper.create_parameter(
+        attr={}, shape=[Hv], dtype=input.dtype,
+        default_initializer=_UniformThrough(1.0, 16.0, [("log", {})]))
+    dt_bias = helper.create_parameter(
+        attr={}, shape=[Hv], dtype=input.dtype,
+        default_initializer=_UniformThrough(
+            math.log(1e-3), math.log(1e-1),
+            [("exp", {}), ("exp", {}), ("scale", {"bias": -1.0}),
+             ("log", {})]))
+    gain = _rms_gain(helper, Dv, input.dtype)
+    out = helper.create_tmp_variable(
+        input.dtype, shape=tuple(input.shape[:2]) + (Hv * Dv,))
+    helper.append_op(
+        "gated_delta_rule",
+        inputs={"X": [qkvz.name], "BA": [ba.name], "Conv": [taps.name],
+                "ALog": [a_log.name], "DtBias": [dt_bias.name],
+                "Norm": [gain.name]},
+        outputs={"Out": [out.name]},
+        attrs={"key_heads": Hk, "value_heads": Hv, "key_dim": Dk,
+               "epsilon": float(epsilon)})
+    from .sequence import propagate_length
+
+    with prog.part_guard("gdn.project"):
+        return propagate_length(input, fc(
+            out, input.shape[-1], num_flatten_dims=2, param_attr=param_attr,
+            bias_attr=False))
 
 
 SPARSE_DEFAULTS = {"kernel": 32, "stride": 16, "block": 64, "window": 2048,
@@ -1068,7 +1195,7 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         param_attr=None, name=None, top_k=1, gated=False, dropless=False,
         initializer=None, held=None, scoring="softmax", select_bias=None,
         renormalise=False, routed_scale=1.0, buffer_rows=None,
-        shared_hidden=0, renorm_epsilon=None):
+        shared_hidden=0, renorm_epsilon=None, shared_gate=False):
     """Mixture-of-experts FFN layer (beyond-reference — SURVEY.md §2.16 last
     row).  `input` [N, D] tokens -> [N, D].  Expert weights are stacked
     [E, D, H]/[E, H, D]; under a ParallelExecutor whose mesh has an 'ep'
@@ -1090,8 +1217,10 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     weights to sum to one (over their sum + `renorm_epsilon`: DeepSeek's
     1e-20 where None), times `routed_scale`), `buffer_rows` (the
     static rows the held pairs are computed in; N * top_k, which nothing
-    can overflow, by default) and `shared_hidden` (> 0: one more gated
-    expert of that width which every token passes, inside the same op)."""
+    can overflow, by default), `shared_hidden` (> 0: one more gated
+    expert of that width which every token passes, inside the same op) and
+    `shared_gate` (that expert's result times sigmoid(x w), w [D, 1] one
+    more parameter after its three: Qwen's `shared_expert_gate`)."""
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     d_model = input.shape[-1]
 
@@ -1110,6 +1239,9 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         and select_bias is None and not renormalise and routed_scale == 1.0
         and not buffer_rows and not shared_hidden
         and renorm_epsilon is None)
+    if shared_gate and not (share and shared_hidden):
+        raise ValueError("layers.moe: shared_gate gates the shared expert "
+                         "of a share (held, shared_hidden)")
     if share and not dropless:
         raise ValueError("layers.moe: a share of the experts (held) needs "
                          "dropless=True (ops/moe_ops.py)")
@@ -1151,6 +1283,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         if gated:
             ins["SU"] = [weight([d_model, shared_hidden], d_model).name]
         ins["SO"] = [weight([shared_hidden, d_model], shared_hidden).name]
+        if shared_gate:
+            ins["SG"] = [weight([d_model, 1], d_model).name]
     pairs, dropped = (helper.create_tmp_variable(
         "float32", shape=(1,), stop_gradient=True) for _ in range(2))
     weights = helper.create_tmp_variable(

@@ -56,7 +56,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                n_kv_heads=None, head_dim=None, block_diffusion=None,
                emb_init_seed=0, hyper=None, mtp=None, sparse=None,
                linear=None, residual_scale=None, emb_scale=None,
-               logit_scale=None):
+               logit_scale=None, delta=None, attention_gate=False,
+               rotary_dim=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -89,7 +90,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     query latent) and "yarn" (`layers.latent_attention`; rotary by
     construction: `rope_theta`, no position table).
     `layer_types` gives the token mixer layer by layer, `n_layers` of
-    four kinds: 'attention' (the kind `attention` names; everywhere by
+    five kinds: 'attention' (the kind `attention` names; everywhere by
     default); 'conv', a gated short convolution, `conv` = {"kernel_size"}
     (`layers.gated_short_conv`); 'sparse_attention', block-top-k sparse
     attention WITHOUT a position, `sparse` = {"n_heads", "n_kv_heads",
@@ -106,7 +107,14 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     a cut of a deeper one names the published indices)
     (`layers.lightning_attention`).  "heads_held" = (first, count) makes a
     mixer ONE RANK'S SHARE of head parallelism: its result is the held
-    heads' partial sum.
+    heads' partial sum.  'gated_delta_net', the gated delta rule after a
+    short convolution, no position, `delta` = {"key_heads", "value_heads",
+    "key_dim", "value_dim"} and optionally "conv_kernel"
+    (`layers.gated_delta_net`).
+    `attention_gate` gives the layers that attend by 'multi_head' an output
+    gate from the query projection's second half, `rotary_dim` turns the
+    first so many columns of their heads alone
+    (`layers.multi_head_attention`).
     `residual_scale` multiplies every sub-layer's result before it is
     added to the stream, `emb_scale` the embedding, `logit_scale` the
     final norm's result before the head (MiniCPM's `scale_depth` /
@@ -217,6 +225,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 heads_held=linear.get("heads_held"), rope_theta=rope_theta,
                 epsilon=norm_epsilon, chunk=linear.get("chunk", 256),
                 param_attr=attr, gain_attr=linear.get("gain_attr"))
+        if layer_types[layer] == "gated_delta_net":
+            return layers.gated_delta_net(h, epsilon=norm_epsilon,
+                                          param_attr=attr, **delta)
         if attention == "latent":
             return layers.latent_attention(
                 h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
@@ -229,7 +240,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
             qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
             rope_theta=rope_theta if positions == "rope" else None,
             **({"head_dim": head_dim} if head_dim else {}),
-            **({"block_diffusion": bd} if bd else {}))
+            **({"block_diffusion": bd} if bd else {}),
+            **({"output_gate": True} if attention_gate else {}),
+            **({"rotary_dim": rotary_dim} if rotary_dim else {}))
 
     def feed_forward(h, layer):
         if ffn == "mlp":
@@ -348,7 +361,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
 
 # the token mixers `decoder_lm`'s `layer_types` names
-_MIXERS = ("attention", "conv", "sparse_attention", "linear_attention")
+_MIXERS = ("attention", "conv", "sparse_attention", "linear_attention",
+           "gated_delta_net")
 
 # decoder_lm's arguments that change the block's parameters or equations,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
@@ -358,7 +372,8 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "layer_types": None, "n_kv_heads": None, "head_dim": None,
                "block_diffusion": None, "hyper": None, "mtp": None,
                "sparse": None, "linear": None, "residual_scale": None,
-               "emb_scale": None, "logit_scale": None}
+               "emb_scale": None, "logit_scale": None, "delta": None,
+               "attention_gate": False, "rotary_dim": None}
 
 
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
@@ -1172,4 +1187,73 @@ def build_sdar_moe_lm_train_program(
     layers.reduce_mean(noise["mask"])
     layers.reduce_sum(shares[-1].counts)
     opt.Adam(learning_rate=learning_rate).minimize(objective)
+    return loss
+
+
+def build_qwen3_next_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, n_heads, n_kv_heads, head_dim,
+        rotary_dim, linear_key_heads, linear_value_heads, linear_key_dim,
+        linear_value_dim, conv_kernel, num_experts, expert_dim, top_k,
+        shared_experts, held_experts, first_expert=0, buffer_rows=None,
+        dense_layers=0, norm_epsilon=1e-6, rope_theta=10000000.0,
+        dtype="bfloat16", learning_rate=3e-5, init_scale=0.02,
+        emb_init_scale=None):
+    """Qwen3-Next-shaped decoder (`model_type` qwen3_next, transformers'
+    `Qwen3Next*`: Qwen3-Next-80B-A3B) as ONE CHIP'S SHARE of an
+    expert-parallel deployment: RMSNorm pre-norm blocks whose token mixer
+    is, by `layer_types`, a gated DeltaNet ('linear_attention':
+    `linear_key_heads` heads of `linear_key_dim` for q and k,
+    `linear_value_heads` of `linear_value_dim` for v, a causal depthwise
+    convolution of `conv_kernel` taps + SiLU, the gated delta rule, a
+    gated per-head RMSNorm; no position) or
+    gated grouped-query attention ('full_attention': `n_heads` query heads
+    on `n_kv_heads` key/value heads of `head_dim`, an RMSNorm on each head
+    of Q and K, rotate-half RoPE on the first `rotary_dim` columns of a
+    head, the result times sigmoid of a gate taken from the query
+    projection's second half); in every block an expert layer whose
+    router scores all `num_experts` by softmax, chooses `top_k` and
+    renormalises their weights to sum to one; of those experts this chip
+    holds `held_experts` from `first_expert` on and computes their part in
+    a buffer of `buffer_rows` rows, beside one shared expert of
+    `shared_experts` x `expert_dim` times a per-token sigmoid gate;
+    `vocab_size` is the slice of the vocabulary this chip embeds and
+    scores; no bias, untied head.  `dense_layers` is 0 (every block has
+    experts; named for whoever reads `train.args`).  Loss: next-token
+    cross entropy, no auxiliary term; Adam.  Returns the loss.  Feeds as
+    `build_lm_train_program`."""
+    from .. import optimizer as opt
+
+    kinds = {"linear_attention": "gated_delta_net",
+             "full_attention": "attention"}
+    if set(layer_types) - set(kinds) or dense_layers:
+        raise ValueError(f"layer_types {layer_types!r}: 'linear_attention' "
+                         f"or 'full_attention', every block with experts")
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types), n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions="rope", rope_theta=rope_theta, qk_norm="head",
+        n_kv_heads=n_kv_heads, head_dim=head_dim, attention_gate=True,
+        rotary_dim=rotary_dim,
+        layer_types=[kinds[t] for t in layer_types],
+        delta={"key_heads": linear_key_heads,
+               "value_heads": linear_value_heads,
+               "key_dim": linear_key_dim, "value_dim": linear_value_dim,
+               "conv_kernel": conv_kernel},
+        ffn="moe",
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "softmax", "renormalise": True,
+             "buffer_rows": buffer_rows,
+             "shared_hidden": shared_experts * expert_dim,
+             "shared_gate": True},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
     return loss

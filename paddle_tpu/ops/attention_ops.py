@@ -13,6 +13,8 @@ simple_attention): score = v·tanh(W_q h + W_m enc)."""
 
 from __future__ import annotations
 
+import functools
+
 from ..observability.attribution import part_scope
 from ..observability.metrics import REGISTRY as _MET
 from .registry import register_op
@@ -155,14 +157,34 @@ def _mask_attrs(attrs, T: int):
     return L, b
 
 
+WIDE_BLOCKS = (1024, 1024)   # (block_q, block_k) of a call at 256-lane heads
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_dense_once(q_shape, k_shape, v_shape):
+    import logging
+
+    logging.getLogger(__name__).warning(
+        "attention on Q %s, K %s, V %s does not fit the flash kernels' "
+        "contract (flash_single_chip: T in tiles of 128, heads of at most "
+        "256 lanes, values of at most 128 or 256 under keys of 256): the "
+        "dense path holds the [heads, T, T] float32 scores",
+        q_shape, k_shape, v_shape)
+
+
 def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
                       scale=None):
     """The single-chip fast path of an attention emitter: the Pallas flash
     kernel (VMEM-tiled online softmax) on Q [B,H,T,D], K [B,Hkv,T,D] and V
     [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
-    kernel's contract: self-attention lengths, T tiles of 128, a
-    lane-width head (of the values; latent attention's queries and keys
-    carry rotary columns beside it, two lane tiles at most).  Two head
+    kernel's contract: self-attention lengths, T tiles of 128, and heads of
+    at most two lane tiles (256): the values' of one lane tile at most
+    (under queries and keys as wide, or wider: latent attention's carry
+    rotary columns beside them), or of exactly two under queries and keys
+    of two (256 / 256; no other width over 128 has been run through the
+    kernels).  Where the shapes do
+    not fit, the caller's dense path runs, and at T >= 4096 one warning
+    names the shape (a [H, T, T] float32 score tensor follows).  Two head
     counts: H query heads on Hkv key/value heads, H / Hkv on each
     (grouped-query attention; Hkv = H is the usual case), which the
     kernels read where they lie, never repeated.  With `heads`: on Q, K
@@ -189,7 +211,7 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
         return None
     if heads is None:
         T, D, Dv = q.shape[2], q.shape[3], v.shape[3]
-        fits = (T % 128 == 0 and Dv <= 128 and (D == Dv or D <= 256)
+        fits = (T % 128 == 0 and D <= 256 and (Dv <= 128 or Dv == D == 256)
                 and k.shape[2] == T and v.shape[2] == T)
     else:
         T = q.shape[1]
@@ -199,10 +221,22 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     if mask is not None:
         fits = fits and mask[0] % 128 == 0 and 128 % mask[1] == 0
     if not fits:
+        if T >= 4096:
+            # the dense path's [H, T, T] float32 scores: 4.3 GB a layer at
+            # 16 heads of 8192 tokens.  Say so once, by name, before the
+            # allocator does
+            _warn_dense_once(tuple(q.shape), tuple(k.shape), tuple(v.shape))
         return None
     from .pallas_kernels import flash_attention as fa
 
     layout = {} if heads is None else {"heads": heads}
+    if heads is None and v.shape[3] == 256:
+        # two lane tiles in q, k AND v: q blocks of 1024 (the kernels'
+        # default is 512) read 14.67 ms forward + backward where the
+        # default reads 15.57, at 16 query heads on 2 key/value heads of
+        # 256, T 8192 (PERF.md, PR 48, has the eight pairs tried); every
+        # narrower call keeps the default
+        layout.update(block_q=WIDE_BLOCKS[0], block_k=WIDE_BLOCKS[1])
     if scale is not None:
         layout["scale"] = float(scale)
     if mask is not None:
@@ -354,6 +388,35 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         # value for the pair to be its (the kernels' out is saved[0])
         ctx.keep_for_grad(attrs, [out], saved)
     return {"Out": [out]}
+
+
+_MET_GATED_ATTN = _MET.counter(
+    "gated_attention_layers_traced_total",
+    "attention output gates traced (forward emission; once a compile, not "
+    "once a step), by the layer's query heads, key/value heads, head width "
+    "and the columns of a head its rotary turn takes")
+
+
+@register_op("attention_output_gate")
+def attention_output_gate(ctx, ins, attrs):
+    """Out = X * sigmoid(Gate), X and Gate [B, T, H * D]: the output gate
+    of a gated softmax attention (Qwen3-Next), between the heads' merge and
+    the output projection; float32 inside.  attrs `num_heads`,
+    `num_kv_heads`, `head_dim`, `rotary_dim` say which layer it gates (the
+    counter's labels)."""
+    import jax
+
+    from .llm_ops import wide_dtype
+
+    x, gate = ins["X"][0], ins["Gate"][0]
+    if not ctx.in_grad_replay():
+        label = lambda name: str(int(attrs.get(name, 0)))  # noqa: E731
+        _MET_GATED_ATTN.inc(
+            q_heads=label("num_heads"), kv_heads=label("num_kv_heads"),
+            head_dim=label("head_dim"), rotary_dim=label("rotary_dim"))
+    wide = wide_dtype(x.dtype)
+    out = x.astype(wide) * jax.nn.sigmoid(gate.astype(wide))
+    return {"Out": [out.astype(x.dtype)]}
 
 
 # ---------------------------------------------------------------------------

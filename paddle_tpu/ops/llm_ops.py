@@ -186,11 +186,14 @@ def rope(ctx, ins, attrs):
 
 
 def head_norm_rope_plain(x, gain, heads: int, eps, theta: float,
-                         period: int = 0):
+                         period: int = 0, rotary_dim: int = 0):
     """X [B, T, heads * D] -> [B, heads, T, D]: per head and row an
     RMSNorm over the head's D columns where `eps` is given (times `gain`
     [D], one for all heads, where given), then the rotate-half turn at the
-    row's position (`rotate_half`'s angles), at least float32 from end to
+    row's position (`rotate_half`'s angles; with `rotary_dim` on the
+    head's first so many columns alone, rotate-half inside them at their
+    own frequencies theta ** (-2i / rotary_dim), the other columns
+    unturned), at least float32 from end to
     end with ONE rounding to X's dtype.  What the kernels of
     ops/pallas_kernels/head_norm_rope.py compute, in plain jax.numpy."""
     import jax
@@ -206,9 +209,13 @@ def head_norm_rope_plain(x, gain, heads: int, eps, theta: float,
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
     if gain is not None:
         y = y * gain.astype(wide)
-    cos, sin = (t[None, :, None, :] for t in tables(T, D, theta, period,
+    R = int(rotary_dim) or D
+    cos, sin = (t[None, :, None, :] for t in tables(T, R, theta, period,
                                                     dtype=wide))
-    out = y * cos + jnp.roll(y, D // 2, axis=-1) * sin
+    turned = y if R == D else y[..., :R]
+    out = turned * cos + jnp.roll(turned, R // 2, axis=-1) * sin
+    if R < D:
+        out = jnp.concatenate([out, y[..., R:]], axis=-1)
     return out.transpose(0, 2, 1, 3).astype(x.dtype)
 
 
@@ -232,8 +239,15 @@ def _qk_prep(ctx, ins, attrs):
     kw = dict(heads=heads, eps=None if eps is None else float(eps),
               theta=float(attrs.get("theta", 10000.0)),
               period=int(attrs.get("period", 0)))
-    pack = kernels.pack_of(x.shape[1], x.shape[2] // heads, heads,
+    D = x.shape[2] // heads
+    rotary = int(attrs.get("rotary_dim") or D)
+    if not 0 < rotary <= D or rotary % 2:
+        raise ValueError(f"head_norm_rope: rotary_dim {rotary} of a head "
+                         f"of {D}")
+    pack = kernels.pack_of(x.shape[1], D, heads,
                            x.dtype) if pallas_dispatch_ok(ctx) else 0
+    if rotary != D:   # the kernels turn whole heads: the plain emission
+        kw["rotary_dim"], pack = rotary, 0
     return x, gain, kw, pack
 
 
@@ -268,13 +282,15 @@ def head_norm_rope(ctx, ins, attrs):
     [B, T, H * D] -> Out [B, H, T, D] with, per head and row, an RMSNorm
     over the head's D columns (attr `epsilon`; absent: no norm) times
     Scale [D] (optional; ONE gain for all heads), then the rotate-half
-    rotary turn (`rope`'s: attrs `theta`, `period`).  At least float32
+    rotary turn (`rope`'s: attrs `theta`, `period`; with `rotary_dim` on
+    the first so many columns of a head alone).  At least float32
     inside, one rounding at the end.  attrs: `num_heads`.
 
     On one TPU with heads of 128 or 64 lanes and T in 128s a Pallas kernel
     reads each head's column block where it lies and writes it where the
     flash kernels read it (ops/pallas_kernels/head_norm_rope.py);
-    everywhere else (the CPU, a mesh, other head sizes) plain jax.numpy
+    everywhere else (the CPU, a mesh, other head sizes, a partial turn:
+    `qk_prep_layers_traced_total{path="xla"}` says so) plain jax.numpy
     (`head_norm_rope_plain`)."""
     from .pallas_kernels import head_norm_rope as kernels
 

@@ -320,11 +320,18 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
         out = _rows_to_tokens(ys, token, w, filled, T, by_token)
     if shared is not None:
         with part_scope("moe.shared"):
-            si, su, so = shared
+            si, su, so, *gate = shared
+            sg = gate[0] if gate else None
             m = _act_fn(act)((x @ si).astype(wide))
             if su is not None:
                 m = m * (x @ su).astype(wide)
-            out = out + (m.astype(x.dtype) @ so).astype(wide)
+            passed = (m.astype(x.dtype) @ so).astype(wide)
+            if sg is not None:
+                with part_scope("moe.shared_gate"):
+                    passed = passed * jax.nn.sigmoid(jnp.dot(
+                        x.astype(wide), sg.astype(wide),
+                        precision=lax.Precision.HIGHEST))
+            out = out + passed
     as_f32 = lambda n: n.astype(jnp.float32).reshape(1)
     # a token's weights largest first: where rounding swaps two of its
     # experts (or its last with the next) the sorted weights hardly move
@@ -525,8 +532,9 @@ def moe(ctx, ins, attrs):
     first_expert + held); attrs scoring ('softmax' | 'sigmoid'),
     renormalise, renorm_epsilon (1e-20), routed_scale, buffer_rows (T *
     top_k by default: then no pair can be dropped); inputs Bias [E]
-    (optional: the selection bias) and SI / SU / SO (optional: the shared
-    expert's [D, Hs], [D, Hs], [Hs, D]).  Outputs Out (the held experts' part of the layer plus the shared
+    (optional: the selection bias), SI / SU / SO (optional: the shared
+    expert's [D, Hs], [D, Hs], [Hs, D]) and SG (optional: [D, 1], the
+    shared expert's per-token sigmoid gate).  Outputs Out (the held experts' part of the layer plus the shared
     expert), RouterScores [T, E] float32, RouterWeights [T, top_k] float32
     (each token's weights, largest first; for a check, it passes no
     gradient on), Counts [E] (over ALL E: they sum to T * top_k),
@@ -617,7 +625,7 @@ def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act):
     one = lambda slot: ins[slot][0] if ins.get(slot) else None
     shared = None
     if one("SI") is not None:
-        shared = (one("SI"), one("SU"), one("SO"))
+        shared = (one("SI"), one("SU"), one("SO"), one("SG"))
     if not ctx.in_grad_replay():
         _MET_SHARE_LAYERS.inc(held=str(held), experts=str(n_exp),
                               top_k=str(top_k), buffer_rows=str(rows))

@@ -1,10 +1,18 @@
-"""The two token mixers of a sparse + linear-attention hybrid decoder
-(layers/nn.py `lightning_attention`, `block_sparse_attention`,
-`block_topk_select`; models/transformer.py `decoder_lm`).
+"""The token mixers of the sparse and linear-attention hybrid decoders
+(layers/nn.py `lightning_attention`, `gated_delta_net`,
+`block_sparse_attention`, `block_topk_select`; models/transformer.py
+`decoder_lm`).
 
 `lightning_attention`: linear attention with a constant decay a head
 (Lightning Attention-2, arXiv:2401.04658), run in chunks: O(T C), the
 [d, d] state in float32 across chunks, never a T x T matrix.
+
+`gated_delta_rule`: the gated delta rule (Gated DeltaNet,
+arXiv:2412.06464), whose state update is data-dependent: S_t = e^{g_t}
+S_{t-1} + beta_t k_t (v_t - e^{g_t} S_{t-1}^T k_t)^T.  In chunks: a
+unit-lower-triangular inverse inside every chunk and a SEQUENTIAL scan over
+the chunks that carries the float32 [d, d] state; never a T x T matrix,
+never a state a token.
 
 `block_topk_select` and `block_sparse_attention`: InfLLM v2's trainable
 sparse attention (arXiv:2506.07900) in its parameter-free form: every
@@ -30,6 +38,11 @@ _MET_LINATTN = _MET.counter(
     "lightning (linear, decayed) attention ops traced (forward emission; "
     "once a compile, not once a step), by the heads the op holds, their "
     "width and the chunk its scan runs in")
+_MET_GDN = _MET.counter(
+    "gated_delta_layers_traced_total",
+    "gated delta-rule ops traced (forward emission; once a compile, not "
+    "once a step), by their key heads, value heads, head width, the chunk "
+    "the scan runs in and the convolution's taps")
 _MET_SPARSE = _MET.counter(
     "sparse_attention_layers_traced_total",
     "block-sparse attention ops traced (forward emission; once a compile, "
@@ -147,6 +160,208 @@ def lightning_attention(ctx, ins, attrs):
         o = o.transpose(0, 2, 1, 3).reshape(B, T, width)
         out = o * jax.nn.sigmoid(gate.astype(o.dtype))
     return {"Out": [out.astype(q.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule
+
+# Tokens a chunk of the scan.  Not the published kernels' 64: on a v5e one
+# layer's scan at 32 value heads of 128 over 8192 tokens reads, forward +
+# backward, 48.3 ms at 32, 37.0 at 64 and 33.1 at 128 (the [d, d] chunk states
+# and transition matrices, which are what this emission moves through HBM,
+# halve), and the step's peak falls by 1.1 GB (PERF.md, PR 48); the result
+# differs by 8e-7.  A shorter sequence is one chunk.
+DELTA_CHUNK = 128
+
+
+def _product(a, b):
+    """a @ b over the last two axes at HIGHEST precision: the float32
+    products of the delta rule (the state's, the inverse's)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.lru_cache(maxsize=None)
+def _unit_lower_inverse():
+    """a [..., C, C] strictly lower triangular -> (I - a)^-1, as the
+    product (I + a)(I + a^2)(I + a^4)... of the nilpotent a's powers
+    (log2 C squarings, all on the MXU; no substitution loop).  Its vjp
+    keeps the result alone: d a = X^T d X X^T."""
+    import jax
+    import jax.numpy as jnp
+
+    def inverse(a):
+        C = a.shape[-1]
+        x = a + jnp.eye(C, dtype=a.dtype)
+        power, reach = a, 2
+        while reach < C:
+            power = _product(power, power)
+            x = x + _product(x, power)
+            reach *= 2
+        return x
+
+    solve = jax.custom_vjp(inverse)
+
+    def bwd(x, dx):
+        xt = jnp.swapaxes(x, -1, -2)
+        return (_product(_product(xt, dx), xt),)
+
+    solve.defvjp(lambda a: (inverse(a),) * 2, bwd)
+    return solve
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk: int):
+    """The gated delta rule in chunks of `chunk` tokens (the op's is
+    DELTA_CHUNK).  q, k [B, Hk, T,
+    Dk] (k of unit length, q scaled), v [B, Hk, G, T, Dv] (key head j
+    serves its G value heads), g (a token's log-decay, <= 0) and beta [B,
+    Hk, G, T] float32.  Per value head, from S = 0 [Dk, Dv]:
+
+      S_t = e^{g_t} S_{t-1} + beta_t k_t (v_t - e^{g_t} S_{t-1}^T k_t)^T
+      o_t = S_t^T q_t
+
+    Inside a chunk, with gamma_i = sum_{j <= i} g_j:
+      A  = strict-lower(-(beta K) K^T * e^{gamma_i - gamma_j})
+      Tm = (I - A)^-1;  U = Tm (beta V);  W = Tm (beta K e^{gamma})
+    and with K~ = K e^{gamma_C - gamma} a chunk maps its incoming state by
+      S' = (e^{gamma_C} I - K~^T W) S + K~^T U,
+    one [Dk, Dk] x [Dk, Dv] product a step of the `lax.scan` over the
+    chunks, which hands out every chunk's INCOMING state; then, for all
+    chunks at once, V' = U - W S and
+      O = (Q e^{gamma}) S + lower(Q K^T * e^{gamma_i - gamma_j}) V'.
+    The two score products take q and k in their own dtype with float32
+    accumulation; everything after them is float32 at HIGHEST precision
+    (float64 for float64 inputs: the numeric gradient checks).
+    -> [B, Hk, G, T, Dv] float32."""
+    import jax
+    import jax.numpy as jnp
+
+    B, Hk, T, Dk = q.shape
+    G, Dv = v.shape[2], v.shape[-1]
+    C = min(int(chunk), T)
+    if T % C:
+        raise ValueError(f"gated delta rule: chunks of {C} do not divide "
+                         f"{T} tokens")
+    N = T // C
+    f32 = wide_dtype(q.dtype)
+    qc, kc = (a.reshape(B, Hk, 1, N, C, Dk) for a in (q, k))
+    vc = v.reshape(B, Hk, G, N, C, Dv).astype(f32)
+    gamma = jnp.cumsum(g.astype(f32).reshape(B, Hk, G, N, C), axis=-1)
+    bc = beta.astype(f32).reshape(B, Hk, G, N, C)
+    i = jnp.arange(C)
+    lower = i[:, None] >= i[None, :]
+    decay = jnp.exp(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    kk, qk = (jnp.einsum("bhgncd,bhgnjd->bhgncj", a, kc,
+                         preferred_element_type=f32) for a in (kc, qc))
+    a = jnp.where(i[:, None] > i[None, :],
+                  -bc[..., None] * kk * decay, 0.0)
+    tm = _unit_lower_inverse()(a)                       # [B, Hk, G, N, C, C]
+    kf, qf = kc.astype(f32), qc.astype(f32)
+    u = _product(tm, vc * bc[..., None])
+    w = _product(tm, kf * (bc * jnp.exp(gamma))[..., None])
+    last = gamma[..., -1:]                              # [B, Hk, G, N, 1]
+    kt = jnp.swapaxes(kf * jnp.exp(last - gamma)[..., None], -1, -2)
+    carry = (jnp.exp(last)[..., None] * jnp.eye(Dk, dtype=f32)
+             - _product(kt, w))                         # [.., N, Dk, Dk]
+    fresh = _product(kt, u)                             # [.., N, Dk, Dv]
+
+    def step(s, chunk_maps):
+        m, b = chunk_maps
+        return _product(m, s) + b, s
+
+    _, states = jax.lax.scan(
+        step, jnp.zeros((B, Hk, G, Dk, Dv), f32),
+        (jnp.moveaxis(carry, 3, 0), jnp.moveaxis(fresh, 3, 0)))
+    states = jnp.moveaxis(states, 0, 3)                 # incoming, [.., N, ..]
+    inner = u - _product(w, states)
+    out = (_product(qf * jnp.exp(gamma)[..., None], states)
+           + _product(qk * decay, inner))
+    return out.reshape(B, Hk, G, T, Dv)
+
+
+def short_conv_silu(x, w):
+    """x [B, T, C], w [C, L] -> SiLU of the causal depthwise convolution
+    c_t = sum_j w[:, j] x_{t - (L - 1) + j} (x zero before the sequence
+    starts; w[:, L - 1] multiplies the current token), at least float32."""
+    import jax
+    import jax.numpy as jnp
+
+    T, L = x.shape[1], w.shape[1]
+    wide = wide_dtype(x.dtype)
+    padded = jnp.pad(x.astype(wide), ((0, 0), (L - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(w[:, j].astype(wide) * padded[:, j:j + T]
+                           for j in range(L)))
+
+
+@register_op("gated_delta_rule")
+def gated_delta_rule(ctx, ins, attrs):
+    """The core of a gated-DeltaNet mixer between its projections: X [B, T,
+    2 Hk Dk + 2 Hv Dv] = [q | k | v | z] as one `fc` leaves it, BA [B, T, 2
+    Hv] = [b | a], Conv [2 Hk Dk + Hv Dv, L] the depthwise taps over [q | k
+    | v], ALog and DtBias [Hv], Norm [Dv] the output norm's gain; attrs
+    `key_heads` Hk, `value_heads` Hv (a multiple of Hk: key head j serves
+    value heads j Hv / Hk ...), `key_dim` Dk, `epsilon`.
+
+      [q | k | v] = SiLU(causal depthwise conv of [q | k | v]), no bias;
+      q = l2norm(q) / sqrt(Dk), k = l2norm(k) per head  (pdtpu.gdn.conv)
+      beta = sigmoid(b); g = -exp(ALog) softplus(a + DtBias), float32
+                                                         (pdtpu.gdn.gates)
+      o = the gated delta rule (gated_delta_chunked, in chunks of
+          DELTA_CHUNK tokens)                            (pdtpu.gdn.scan)
+      Out = rmsnorm_head(o; Norm) * SiLU(z)              (pdtpu.gdn.norm_gate)
+
+    The scan is recomputed in its backward (jax.checkpoint): the vjp keeps
+    q, k, v, g and beta, and of the scan's own at most the chunks' incoming
+    states while that layer's backward runs."""
+    import jax
+    import jax.numpy as jnp
+
+    x, ba, taps = ins["X"][0], ins["BA"][0], ins["Conv"][0]
+    Hk, Hv = int(attrs["key_heads"]), int(attrs["value_heads"])
+    Dk = int(attrs["key_dim"])
+    eps = float(attrs.get("epsilon", 1e-6))
+    B, T, width = x.shape
+    chunk = min(DELTA_CHUNK, T)
+    G = Hv // max(Hk, 1)
+    Dv = (width - 2 * Hk * Dk) // (2 * Hv)
+    mixed = 2 * Hk * Dk + Hv * Dv
+    if (G * Hk != Hv or 2 * Hk * Dk + 2 * Hv * Dv != width
+            or ba.shape != (B, T, 2 * Hv) or taps.shape[0] != mixed):
+        raise ValueError(
+            f"gated_delta_rule: X {x.shape}, BA {ba.shape}, Conv "
+            f"{taps.shape} at {Hk} key heads of {Dk} and {Hv} value heads")
+    if not ctx.in_grad_replay():
+        _MET_GDN.inc(key_heads=str(Hk), value_heads=str(Hv),
+                     head_dim=str(Dk), chunk=str(chunk),
+                     conv_taps=str(taps.shape[1]))
+    wide = wide_dtype(x.dtype)
+    with part_scope("gdn.conv"):
+        qkv = short_conv_silu(x[..., :mixed], taps)
+        q, k = (qkv[..., n * Hk * Dk:(n + 1) * Hk * Dk].reshape(B, T, Hk, Dk)
+                for n in (0, 1))
+        unit = lambda a: a * jax.lax.rsqrt(                       # noqa: E731
+            jnp.sum(a * a, axis=-1, keepdims=True) + eps)
+        q = (unit(q) * Dk ** -0.5).astype(x.dtype).transpose(0, 2, 1, 3)
+        k = unit(k).astype(x.dtype).transpose(0, 2, 1, 3)
+        v = qkv[..., 2 * Hk * Dk:].astype(x.dtype).reshape(
+            B, T, Hk, G, Dv).transpose(0, 2, 3, 1, 4)
+    with part_scope("gdn.gates"):
+        heads = lambda a: a.astype(wide).reshape(                 # noqa: E731
+            B, T, Hk, G).transpose(0, 2, 3, 1)
+        beta = jax.nn.sigmoid(heads(ba[..., :Hv]))
+        g = heads(-jnp.exp(ins["ALog"][0].astype(wide)) * jax.nn.softplus(
+            ba[..., Hv:].astype(wide) + ins["DtBias"][0].astype(wide)))
+    with part_scope("gdn.scan"):
+        o = jax.checkpoint(functools.partial(
+            gated_delta_chunked, chunk=chunk))(q, k, v, g, beta)
+    with part_scope("gdn.norm_gate"):
+        o = rms(o, eps, (4,), ins["Norm"][0].astype(o.dtype))
+        o = o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hv * Dv)
+        out = o * jax.nn.silu(x[..., mixed:].astype(o.dtype))
+    return {"Out": [out.astype(x.dtype)]}
 
 
 # ---------------------------------------------------------------------------
@@ -390,5 +605,22 @@ def _sparse_attention_cost(ins, outs, attrs):
     return {"flops": int(4 * b * rows * d * keys)}
 
 
+def _gated_delta_cost(ins, outs, attrs):
+    """Per value head and token, the products a chunked delta rule needs
+    (benchmarks/flops_qwen3next.py `gated_delta_cost` has them one by one):
+    4 C Dk + C (Dk + Dv) + 2 C Dv + 6 Dk Dv."""
+    x = ins.get("X", [None])[0]
+    if x is None or len(x.shape) != 3:
+        return {}
+    b, t, width = x.shape
+    hk, hv = int(attrs["key_heads"]), int(attrs["value_heads"])
+    dk = int(attrs["key_dim"])
+    dv = (width - 2 * hk * dk) // (2 * hv)
+    c = min(DELTA_CHUNK, t)
+    return {"flops": b * t * hv * (4 * c * dk + c * (dk + dv) + 2 * c * dv
+                                   + 6 * dk * dv)}
+
+
 register_cost("lightning_attention", _lightning_cost)
+register_cost("gated_delta_rule", _gated_delta_cost)
 register_cost("block_sparse_attention", _sparse_attention_cost)

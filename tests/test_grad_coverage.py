@@ -132,11 +132,14 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # PR 45: +2 (lightning_attention, block_sparse_attention: MiniCPM-SALA's
     # two token mixers; `block_topk_select` takes no gradient), each
     # numerically checked in test_sala.py
-    assert len(diffable) == 159, (
+    # PR 48: +2 (gated_delta_rule: Qwen3-Next's gated-DeltaNet core;
+    # attention_output_gate: its gated attention's sigmoid gate), each
+    # numerically checked in test_qwen3_next.py
+    assert len(diffable) == 161, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 159 - 11
+    assert len(checked) == 161 - 11
 
 
 import pytest  # noqa: E402

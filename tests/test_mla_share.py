@@ -121,7 +121,7 @@ def test_latent_attention_takes_the_flash_kernels_once_a_layer(monkeypatch):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6)
 
 
-def test_sdpa_gate_takes_unequal_widths_and_keeps_the_old_limit():
+def test_sdpa_gate_takes_unequal_widths_and_wide_equal_ones():
     import jax.numpy as jnp
 
     from paddle_tpu.ops.attention_ops import flash_single_chip
@@ -134,19 +134,24 @@ def test_sdpa_gate_takes_unequal_widths_and_keeps_the_old_limit():
 
     took = []
     real = fa.flash_attention
-    fa.flash_attention = lambda q, k, v, causal: took.append(
+    fa.flash_attention = lambda q, k, v, causal, **blocks: took.append(
         (q.shape[-1], v.shape[-1])) or v
     try:
+        # values stop at one lane tile, but for two whole tiles under keys
+        # of two (PR 48: heads of 256 in q, k AND v); no width between
         for dqk, dv, want in ((192, 128, True), (128, 128, True),
                               (256, 128, True), (192, 192, False),
-                              (320, 128, False), (64, 32, True)):
+                              (256, 192, False), (256, 256, True),
+                              (320, 128, False), (320, 320, False),
+                              (64, 32, True)):
             q = jnp.zeros((1, 1, 128, dqk))
             v = jnp.zeros((1, 1, 128, dv))
             assert (flash_single_chip(Ctx(), q, q, v, True)
                     is not None) == want, (dqk, dv)
     finally:
         fa.flash_attention = real
-    assert took == [(192, 128), (128, 128), (256, 128), (64, 32)]
+    assert took == [(192, 128), (128, 128), (256, 128), (256, 256),
+                    (64, 32)]
 
 
 # ---------------------------------------------------------------------------
