@@ -43,6 +43,14 @@ _MET_GDN = _MET.counter(
     "gated delta-rule ops traced (forward emission; once a compile, not "
     "once a step), by their key heads, value heads, head width, the chunk "
     "the scan runs in and the convolution's taps")
+_MET_GDN_KERNELS = _MET.counter(
+    "gated_delta_kernels_traced_total",
+    "emissions of the gated delta rule's scan (once a compile, not once a "
+    "step), by the op that emits it (fwd: gated_delta_rule; grad: its grad "
+    "op's re-emission) and the path taken (pallas: the kernel pair of "
+    "ops/pallas_kernels/gated_delta.py, a chunk's tiles and the state in "
+    "VMEM; xla: gated_delta_chunked as plain jax.numpy under "
+    "jax.checkpoint)")
 _MET_SPARSE = _MET.counter(
     "sparse_attention_layers_traced_total",
     "block-sparse attention ops traced (forward emission; once a compile, "
@@ -234,7 +242,13 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int):
     The two score products take q and k in their own dtype with float32
     accumulation; everything after them is float32 at HIGHEST precision
     (float64 for float64 inputs: the numeric gradient checks).
-    -> [B, Hk, G, T, Dv] float32."""
+    -> [B, Hk, G, T, Dv] float32.
+
+    This is the plain emission: what the op `gated_delta_rule` runs on the
+    CPU, under a mesh, in float64, at widths that are no whole lane tiles
+    and where T is no multiple of 128, and the oracle of the kernel pair
+    that runs the same equations on one TPU
+    (ops/pallas_kernels/gated_delta.py)."""
     import jax
     import jax.numpy as jnp
 
@@ -313,11 +327,24 @@ def gated_delta_rule(ctx, ins, attrs):
           DELTA_CHUNK tokens)                            (pdtpu.gdn.scan)
       Out = rmsnorm_head(o; Norm) * SiLU(z)              (pdtpu.gdn.norm_gate)
 
-    The scan is recomputed in its backward (jax.checkpoint): the vjp keeps
-    q, k, v, g and beta, and of the scan's own at most the chunks' incoming
-    states while that layer's backward runs."""
+    On one TPU, where Dk, Dv and the chunk are whole lane tiles and the
+    chunk divides T, the scan is the kernel pair of
+    ops/pallas_kernels/gated_delta.py under one `jax.custom_vjp` (a chunk's
+    [C, C] and [C, d] tiles and the state in VMEM): the forward op's one
+    launch hands out O, every chunk's incoming state and Tm, kept beside
+    its output (`ctx.keep_for_grad`), and the grad op's re-emission
+    differentiates through them as the reverse pass alone.  Everywhere else
+    (the CPU, a mesh, float64, other widths, T under a chunk,
+    `PADDLE_TPU_NO_FUSED_KERNELS`) `gated_delta_chunked` as plain jax.numpy,
+    recomputed in its backward (jax.checkpoint): the vjp keeps q, k, v, g
+    and beta, and of the scan's own at most the chunks' incoming states
+    while that layer's backward runs.  `gated_delta_kernels_traced_total`
+    says which."""
     import jax
     import jax.numpy as jnp
+
+    from .pallas_kernels import gated_delta as kernels
+    from .pallas_kernels._common import pallas_dispatch_ok
 
     x, ba, taps = ins["X"][0], ins["BA"][0], ins["Conv"][0]
     Hk, Hv = int(attrs["key_heads"]), int(attrs["value_heads"])
@@ -333,10 +360,14 @@ def gated_delta_rule(ctx, ins, attrs):
         raise ValueError(
             f"gated_delta_rule: X {x.shape}, BA {ba.shape}, Conv "
             f"{taps.shape} at {Hk} key heads of {Dk} and {Hv} value heads")
+    take = (pallas_dispatch_ok(ctx)
+            and kernels.usable(T, chunk, Dk, Dv, x.dtype, G))
     if not ctx.in_grad_replay():
         _MET_GDN.inc(key_heads=str(Hk), value_heads=str(Hv),
                      head_dim=str(Dk), chunk=str(chunk),
                      conv_taps=str(taps.shape[1]))
+    _MET_GDN_KERNELS.inc(op="grad" if ctx.in_grad_replay() else "fwd",
+                         path="pallas" if take else "xla")
     wide = wide_dtype(x.dtype)
     with part_scope("gdn.conv"):
         qkv = short_conv_silu(x[..., :mixed], taps)
@@ -354,14 +385,30 @@ def gated_delta_rule(ctx, ins, attrs):
         beta = jax.nn.sigmoid(heads(ba[..., :Hv]))
         g = heads(-jnp.exp(ins["ALog"][0].astype(wide)) * jax.nn.softplus(
             ba[..., Hv:].astype(wide) + ins["DtBias"][0].astype(wide)))
+    saved = None
     with part_scope("gdn.scan"):
-        o = jax.checkpoint(functools.partial(
-            gated_delta_chunked, chunk=chunk))(q, k, v, g, beta)
+        if take:
+            scan = kernels.make_gated_delta(chunk)
+            kept = ctx.kept_for_grad()
+            if kept is not None:
+                o = scan.from_saved(q, k, v, g, beta, *kept)
+            elif ctx.is_test or ctx.in_grad_replay():
+                o = scan(q, k, v, g, beta)
+            else:
+                saved = scan.keeping(q, k, v, g, beta)
+                o = saved[0]
+            ctx.kernel_forward(reused=kept is not None)
+        else:
+            o = jax.checkpoint(functools.partial(
+                gated_delta_chunked, chunk=chunk))(q, k, v, g, beta)
     with part_scope("gdn.norm_gate"):
         o = rms(o, eps, (4,), ins["Norm"][0].astype(o.dtype))
         o = o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hv * Dv)
         out = o * jax.nn.silu(x[..., mixed:].astype(o.dtype))
-    return {"Out": [out.astype(x.dtype)]}
+    out = out.astype(x.dtype)
+    if saved is not None:
+        ctx.keep_for_grad(attrs, [out], saved)
+    return {"Out": [out]}
 
 
 # ---------------------------------------------------------------------------

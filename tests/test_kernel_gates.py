@@ -6,9 +6,9 @@ is put to its kernel's own gate at the shapes its desc carries: the flash
 blocks snap (the kernels' defaults, which `knobs.flash_blocks` hands back
 where no variable is set), and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
-`hyper_connection.usable`, `sparse_flash.usable` and `short_conv.usable`
-say yes.  Nothing compiles: milliseconds where the AOT tests of the same
-cells take minutes."""
+`hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable` and
+`gated_delta.usable` say yes.  Nothing compiles: milliseconds where the AOT
+tests of the same cells take minutes."""
 
 import glob
 import importlib
@@ -20,10 +20,11 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu.ops.pallas_kernels import (flash_attention, grouped_matmul,
-                                           head_norm_rope, hyper_connection,
-                                           segment_sum, short_conv,
-                                           sparse_flash)
+from paddle_tpu.ops import sparse_linear_ops
+from paddle_tpu.ops.pallas_kernels import (flash_attention, gated_delta,
+                                           grouped_matmul, head_norm_rope,
+                                           hyper_connection, segment_sum,
+                                           short_conv, sparse_flash)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -41,7 +42,8 @@ MECHANISMS = {
     "minicpm-sala-9b": {"sparse_flash"},
     # heads of 256 with a partial rotary turn: `head_norm_rope` takes its
     # plain emission there, by design
-    "qwen3-next-80b-a3b": {"flash", "grouped_matmul", "segment_sum"},
+    "qwen3-next-80b-a3b": {"flash", "grouped_matmul", "segment_sum",
+                           "gated_delta"},
 }
 
 
@@ -108,8 +110,16 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
                 T, op.attrs["qk_nope_dim"] + op.attrs["qk_rope_dim"])
             passed.add("flash")
             positions = max(positions, T)
-        elif op.type == "gated_delta_rule":   # no kernel; T for the experts
-            positions = max(positions, shape(op, "X")[1])
+        elif op.type == "gated_delta_rule":
+            _, T, width = shape(op, "X")
+            Hk, Hv = op.attrs["key_heads"], op.attrs["value_heads"]
+            Dk = op.attrs["key_dim"]
+            Dv = (width - 2 * Hk * Dk) // (2 * Hv)
+            assert gated_delta.usable(
+                T, min(sparse_linear_ops.DELTA_CHUNK, T), Dk, Dv,
+                dtype(op, "X"), Hv // Hk), (T, Dk, Dv, Hv // Hk)
+            passed.add("gated_delta")
+            positions = max(positions, T)
         elif op.type == "head_norm_rope" and "rotary_dim" not in op.attrs:
             _, T, width = shape(op, "X")
             heads = op.attrs["num_heads"]

@@ -623,4 +623,69 @@ def test_qwen3_next_program_counts_what_it_traced(monkeypatch):
     assert series("gated_attention_layers_traced_total") == {
         (("head_dim", "16"), ("kv_heads", "2"), ("q_heads", "4"),
          ("rotary_dim", "4")): 1.0}
+    # on the CPU the scan is the plain emission, forward and in the grad
+    # op's re-emission, and no grad op is handed a kernel's kept result
+    assert series("gated_delta_kernels_traced_total") == {
+        (("op", "fwd"), ("path", "xla")): 3.0,
+        (("op", "grad"), ("path", "xla")): 3.0}
+    assert "executor_grad_kernel_forward_total" not in fam or not any(
+        ("op", "gated_delta_rule") in labels
+        for labels in series("executor_grad_kernel_forward_total"))
+    obs.REGISTRY.reset()
+
+
+def test_gated_delta_net_layer_trains_through_the_kernels(monkeypatch):
+    """A DeltaNet block at heads of 128 over two chunks of 128, one SGD
+    step of mean(out^2): where the trace targets one TPU the scan is the
+    kernel pair (interpreted here), the grad op's re-emission reuses the
+    forward op's kept O (`executor_grad_kernel_forward_total` reused=1:
+    what `kernel_forward_reruns` reads as 0) and the step's loss and
+    updated parameters are the plain emission's."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import registry as reg
+    from paddle_tpu.ops.pallas_kernels import gated_delta
+
+    x = _r(1, 256, 32, seed=1).astype(np.float32)
+
+    def step():
+        obs.REGISTRY.reset()
+        fluid.reset()
+        data = fluid.layers.data("x", shape=[256, 32], dtype="float32")
+        out = fluid.layers.gated_delta_net(data, 1, 2, 128, 128,
+                                           conv_kernel=4)
+        loss = fluid.layers.mean(fluid.layers.elementwise_mul(out, out))
+        fluid.optimizer.SGD(learning_rate=0.5).minimize(loss)
+        main, startup = (fluid.default_main_program(),
+                         fluid.default_startup_program())
+        main.random_seed = startup.random_seed = 3
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        (got,) = exe.run(feed={"x": x}, fetch_list=[loss])
+        scope = fluid.global_scope()
+        fam = obs.REGISTRY.snapshot()["families"]
+        series = lambda name: {  # noqa: E731
+            tuple(sorted(s["labels"].items())): s["value"]
+            for s in fam.get(name, {"series": []})["series"]}
+        return (float(got), [np.asarray(scope.find(p.name)) for p in
+                             main.global_block().all_parameters()], series)
+
+    loss, params, series = step()
+    assert series("gated_delta_kernels_traced_total") == {
+        (("op", "fwd"), ("path", "xla")): 1.0,
+        (("op", "grad"), ("path", "xla")): 1.0}
+    assert series("executor_grad_kernel_forward_total") == {}
+    real = gated_delta.make_gated_delta
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(gated_delta, "make_gated_delta",
+                        lambda chunk: real(chunk, True))
+    kernel_loss, kernel_params, series = step()
+    assert series("gated_delta_kernels_traced_total") == {
+        (("op", "fwd"), ("path", "pallas")): 1.0,
+        (("op", "grad"), ("path", "pallas")): 1.0}
+    assert series("executor_grad_kernel_forward_total") == {
+        (("op", "gated_delta_rule"), ("reused", "1")): 1.0}
+    assert abs(kernel_loss - loss) <= 1e-5 * abs(loss)
+    for a, b in zip(kernel_params, params):
+        assert np.abs(a - b).max() <= 1e-4 * np.abs(b).max()
     obs.REGISTRY.reset()
