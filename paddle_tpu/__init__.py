@@ -18,7 +18,15 @@ Fluid-style usage (mirrors python/paddle/v2/fluid/__init__.py):
 Programs are desc graphs (framework/core.py); execution compiles whole blocks
 to XLA (framework/executor.py)."""
 
-from . import layers  # noqa: F401
+import sys as _sys
+import time as _time
+
+# `process.import` of the start-up record (observability/tracing.py): this
+# line to the module's last, on the tracer's clock; with `jax` already in
+# sys.modules the package's own Python, else JAX's import as well
+_import_began, _jax_first = _time.monotonic(), "jax" not in _sys.modules
+
+from . import layers  # noqa: F401,E402
 from . import ops  # noqa: F401  (registers all op emitters)
 from . import optimizer  # noqa: F401
 from . import regularizer  # noqa: F401
@@ -82,3 +90,8 @@ def reset():
     # telemetry: fresh metric series / trace ring so
     # tests and benches never read a previous run's counters
     observability.reset()
+
+
+IMPORT_STAMPS = (_import_began, _time.monotonic())
+observability.TRACER.cold_event("process.import", *IMPORT_STAMPS,
+                                process=True, jax_first=_jax_first)

@@ -503,14 +503,17 @@ def cmd_metrics(args) -> int:
 
 def cmd_trace(args) -> int:
     """Run a saved model under the tracer and write the Chrome/Perfetto
-    trace-event JSON (open it at https://ui.perfetto.dev)."""
+    trace-event JSON (open it at https://ui.perfetto.dev): the process's
+    start-up record (category `cold`), then the ring's spans."""
     obs = _telemetry_run(args)
     out = args.out or (os.path.basename(os.path.normpath(args.model))
                        + ".trace.json")
     obs.TRACER.export(out)
-    problems = obs.validate_chrome_trace(obs.TRACER.to_chrome())
-    n = len(obs.TRACER.events())
-    print(f"{out}: {n} events"
+    exported = obs.TRACER.to_chrome()
+    problems = obs.validate_chrome_trace(exported)
+    n = len(exported["traceEvents"])
+    cold = len(obs.TRACER.startup_events())
+    print(f"{out}: {n} events, {cold} of the start-up record"
           + (f"; SCHEMA PROBLEMS: {problems}" if problems else ""))
     return 1 if problems else 0
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 import threading
 from typing import Dict, List, Optional, Sequence
 
@@ -28,7 +29,7 @@ import numpy as np
 
 from ..observability import attribution as _attr
 from ..observability.metrics import REGISTRY as _MET, monotime as _monotime
-from ..observability.tracing import TRACER as _TRC
+from ..observability.tracing import TRACER as _TRC, now as _trace_now
 from ..ops.registry import EmitContext, get_op_info
 from .core import Program, Variable, canonical_dtype, np_dtype
 from .place import Place, default_place
@@ -66,6 +67,9 @@ _COMPILE_PHASES = {
     "/jax/core/compile/backend_compile_duration": "backend",
 }
 _PERSISTENT_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# sent when an executable has come out of the persistent cache, inside the
+# `backend` interval of the compile it serves
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 # ops the lowerer skips: pure-desc markers with no computation
 _NOOP_TYPES = ("feed", "fetch")
@@ -125,6 +129,11 @@ def _enable_compilation_cache():
     if _cc_enabled or os.environ.get("PADDLE_TPU_NO_COMPILE_CACHE"):
         return
     _cc_enabled = True
+    with _TRC.span("executor.cache_enable", cold=True):
+        _point_jax_at_the_cache()
+
+
+def _point_jax_at_the_cache():
     import jax
 
     # CPU: never enable the persistent cache.  DESERIALIZED XLA:CPU
@@ -142,7 +151,21 @@ def _enable_compilation_cache():
     jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
 
 
-def _on_jax_compile_seconds(event, duration, **kw):
+def _on_jax_phase_start(event, value, **kw):
+    """jax.monitoring scalar listener: JAX announces a phase of a compile
+    as it starts.  Inside a dispatch the tracer's clock is read here, so
+    the interval that `_on_jax_compile_seconds` writes into the start-up
+    record has both its ends on that clock."""
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None or not getattr(_compiling, "dispatches", 0):
+        return
+    began = getattr(_compiling, "open", None)
+    if began is None:
+        began = _compiling.open = []
+    began.append((phase, _trace_now()))
+
+
+def _on_jax_compile_seconds(event, duration, fun_name="", **kw):
     """jax.monitoring duration listener: what JAX compiles while this
     thread is inside Executor._dispatch is the program's, and goes to the
     counters and, where a sink records spans, onto the innermost open one
@@ -150,15 +173,40 @@ def _on_jax_compile_seconds(event, duration, **kw):
     reference, a test's own jit) is not.  JAX calls listeners only when
     something compiles, so a steady step pays nothing.  `trace` counts a
     nested jit's tracing twice, inside its caller's, as every sum of
-    these events does."""
+    these events does.
+
+    Each event is also an INTERVAL of the start-up record, `jax.trace` /
+    `jax.lower` / `jax.backend` with JAX's `fun_name`, under the span open
+    on the thread, from `_on_jax_phase_start`'s stamp (or, where that was
+    not taken, `duration` back) to this call's.  A trace INSIDE another
+    phase is not kept (every `jnp` function is a jit that the step
+    function's trace walks through, and lowering traces more: thousands
+    in a model's step), so the record stays a few events a compile, and
+    the union of what is kept is wall clock with nothing counted twice.
+    `jax.cache_load` (the retrieval from the persistent cache, which
+    carries no name) waits for the `backend` event it lies in and takes
+    that one's."""
+    now = _trace_now()
+    if event == _CACHE_RETRIEVAL:
+        _compiling.load = (now - duration, now)
+        return
     phase = _COMPILE_PHASES.get(event)
     if phase is None:
         return
-    cached = False
+    cached, load = False, None
     if phase == "backend":  # one per XLA compile, and its last event
         cached, _compiling.hit = getattr(_compiling, "hit", False), False
+        load, _compiling.load = getattr(_compiling, "load", None), None
     if not getattr(_compiling, "dispatches", 0):
         return
+    began = getattr(_compiling, "open", None)
+    t0 = now - duration
+    if began and began[-1][0] == phase:
+        t0 = began.pop()[1]
+    if load is not None:
+        _TRC.cold_event("jax.cache_load", *load, fun_name=fun_name)
+    if phase != "trace" or not began:
+        _TRC.cold_event("jax." + phase, t0, now, fun_name=fun_name)
     _MET_COMPILE_S.inc(duration, phase=phase)
     if phase == "backend":
         _MET_JAX_COMPILES.inc(cached="1" if cached else "0")
@@ -177,14 +225,15 @@ def _on_jax_event(event, **kw):
         _compiling.hit = True
 
 
-# per thread: `dispatches`, how many Executor._dispatch calls are open, and
-# `hit`, whether the compile in progress came from the persistent cache
+# per thread: `dispatches`, how many Executor._dispatch calls are open;
+# `hit`, whether the compile in progress came from the persistent cache, and
+# `load`, when it did; `open`, the phases JAX has begun and not ended
 _compiling = threading.local()
 _listening = False
 
 
 def _listen_to_jax_compiles():
-    """Register the two callbacks with jax.monitoring, once a process."""
+    """Register the three callbacks with jax.monitoring, once a process."""
     global _listening
     if _listening:
         return
@@ -193,6 +242,41 @@ def _listen_to_jax_compiles():
 
     mon.register_event_duration_secs_listener(_on_jax_compile_seconds)
     mon.register_event_listener(_on_jax_event)
+    mon.register_scalar_listener(_on_jax_phase_start)
+
+
+def _role(compiled, feed_vals) -> str:
+    """What a reader of the start-up record may know of a program without
+    guessing: `startup` reads nothing (no feed, no state of the scope) and
+    only initialises persistables; every other program is `main`."""
+    reads = feed_vals or compiled.rw_state or compiled.external_reads
+    return "main" if reads else "startup"
+
+
+def _write_saves(save_specs, fetches):
+    """The files of a block's `save` ops, after its step."""
+    for i, (path, overwrite) in enumerate(save_specs):
+        if os.path.exists(path) and not overwrite:
+            raise IOError(
+                f"save op: {path!r} exists and overwrite=False "
+                f"(save_op.cc semantics)")
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        # write through a file object: np.save(path) would append ".npy"
+        # to extension-less reference-style paths
+        with open(path, "wb") as f:
+            np.save(f, np.asarray(fetches[f"{_SAVE_PREFIX}{i}"]),
+                    allow_pickle=False)
+
+
+def _check_finite(fetches, new_state, step):
+    """FLAGS_check_nan_inf analog (reference executor.cc:26, 120-128):
+    scan fetches + updated state for non-finite values."""
+    for n, v in list(fetches.items()) + list(new_state.items()):
+        arr = np.asarray(v)
+        if np.issubdtype(arr.dtype, np.floating) and not np.all(
+                np.isfinite(arr)):
+            raise FloatingPointError(
+                f"non-finite values in {n!r} after step {step}")
 
 
 class Executor:
@@ -409,110 +493,108 @@ class Executor:
         (the jitted call: JAX traces, lowers and compiles in its first
         one), `executor.writeback` and, for `return_numpy`,
         `executor.fetch`.  All carry `step`, the executor's counter at
-        the dispatch's first step."""
+        the dispatch's first step.
+
+        A dispatch that finds no executable (`cold`) is the one a process
+        spends its start-up in: from that moment on its spans are cold
+        (observability/tracing.py: kept in the start-up record whatever
+        is switched on), the root with the program's `role`.  A steady
+        dispatch pays a branch on `cold` for it."""
         import jax
 
         step = self._step
         block = program.blocks[block_id]
         with _TRC.span("executor.run", step=step, k=k,
                        program=program._cache_token) as sp_run:
-            with _TRC.span("executor.prepare", step=step):
-                feed_vals = self._prepare_feeds(block, feed, stacked=k > 1)
-                if k > 1:
-                    from . import step_loop
+            late_root = None  # the cold root, where sp_run is the no-op
+            try:
+                with _TRC.span("executor.prepare", step=step):
+                    feed_vals = self._prepare_feeds(block, feed,
+                                                    stacked=k > 1)
+                    if k > 1:
+                        from . import step_loop
 
-                    step_loop.check_stacked(feed_vals, k)
-                key = self._cache_key(program, block_id, feed_vals,
-                                      fetch_names)
-                if k > 1:
-                    key += ("loop", k, fetch_every)
-                # the load-file signature lives beside the entry, not in
-                # the key: a rewritten load file must *replace* the stale
-                # executable, not leak an unbounded trail of dead entries
-                load_sig = self._load_file_sig(program)
-                entry = self._cache.get(key)
-                compiled_now = entry is None or entry[0] != load_sig
-                if compiled_now:
-                    # the desc analysis and the jax.jit wrapper; nothing
-                    # compiles before the wrapper's first call
-                    with _TRC.span("executor.build", step=step,
-                                   ops=len(block.ops)):
-                        if k == 1:
-                            compiled = self._compile(
-                                program, block_id, feed_vals, fetch_names)
-                        else:
-                            compiled = self._compile_loop(
-                                program, block_id, feed_vals, fetch_names,
-                                k, fetch_every)
-                    self._cache[key] = (load_sig, compiled)
-                else:
-                    compiled = entry[1]
-                _MET_PROG_CACHE.inc(result="miss" if compiled_now else "hit")
-            sp_run.note(cache_hit=not compiled_now)
+                        step_loop.check_stacked(feed_vals, k)
+                    key = self._cache_key(program, block_id, feed_vals,
+                                          fetch_names)
+                    if k > 1:
+                        key += ("loop", k, fetch_every)
+                    # the load-file signature lives beside the entry, not
+                    # in the key: a rewritten load file must *replace* the
+                    # stale executable, not leak an unbounded trail of
+                    # dead entries
+                    load_sig = self._load_file_sig(program)
+                    entry = self._cache.get(key)
+                    cold = entry is None or entry[0] != load_sig
+                    if cold:
+                        sp_run, late_root = _TRC.turn_cold(
+                            sp_run, "executor.run", step=step, k=k,
+                            program=program._cache_token)
+                        # the desc analysis and the jax.jit wrapper;
+                        # nothing compiles before the wrapper's first call
+                        with _TRC.span("executor.build", cold=True,
+                                       step=step, ops=len(block.ops)):
+                            if k == 1:
+                                compiled = self._compile(
+                                    program, block_id, feed_vals,
+                                    fetch_names)
+                            else:
+                                compiled = self._compile_loop(
+                                    program, block_id, feed_vals,
+                                    fetch_names, k, fetch_every)
+                        self._cache[key] = (load_sig, compiled)
+                        sp_run.note(role=_role(compiled, feed_vals))
+                    else:
+                        compiled = entry[1]
+                    _MET_PROG_CACHE.inc(result="miss" if cold else "hit")
+                sp_run.note(cache_hit=not cold)
+                # the DONATION phase: pinning the donated (rw) and
+                # read-only state buffers into device memory before the step
+                with _TRC.span("executor.donate", cold=cold, step=step,
+                               feeds=len(feed)) as sp_don:
+                    state_w, state_r = self._pin_state(compiled, scope,
+                                                       block)
+                    sp_don.note(donated=len(state_w), reads=len(state_r))
 
-            # the DONATION phase: pinning the donated (rw) and read-only
-            # state buffers into device memory before the step
-            with _TRC.span("executor.donate", step=step,
-                           feeds=len(feed)) as sp_don:
-                state_w, state_r = self._pin_state(compiled, scope, block)
-                sp_don.note(donated=len(state_w), reads=len(state_r))
+                with _TRC.span("executor.rng", cold=cold, step=step):
+                    first = step if rng_step is None else int(rng_step)
+                    key0 = self._rng_key(program.random_seed)
+                    if k == 1:
+                        rng = (jax.random.fold_in(key0, first),)
+                    else:
+                        # the loop folds (base key, step index) per step
+                        # ON DEVICE - bitwise the same stream as K
+                        # sequential host-side fold_ins
+                        rng = (key0, np.int32(first))
+                self._step += k
 
-            with _TRC.span("executor.rng", step=step):
-                first = step if rng_step is None else int(rng_step)
-                key0 = self._rng_key(program.random_seed)
-                if k == 1:
-                    rng = (jax.random.fold_in(key0, first),)
-                else:
-                    # the loop folds (base key, step index) per step ON
-                    # DEVICE - bitwise the same stream as K sequential
-                    # host-side fold_ins
-                    rng = (key0, np.int32(first))
-            self._step += k
-
-            with _TRC.span("executor.execute", step=step,
-                           cache_hit=not compiled_now), \
-                    self._device_scope():
-                fetches, new_state = compiled.fn(state_w, state_r,
-                                                 feed_vals, *rng)
-            with _TRC.span("executor.writeback", step=step,
-                           written=len(new_state)):
-                for n, v in new_state.items():
-                    scope.set(n, v)
-                # the donated buffers are dead and the scope has let go of
-                # them: dropping the last references here puts the cost of
-                # freeing a few hundred arrays inside the span, not after
-                # the root at the frame's exit
-                del state_w, state_r
-                for i, (path, overwrite) in enumerate(compiled.save_specs):
-                    if os.path.exists(path) and not overwrite:
-                        raise IOError(
-                            f"save op: {path!r} exists and overwrite=False "
-                            f"(save_op.cc semantics)")
-                    os.makedirs(os.path.dirname(path) or ".",
-                                exist_ok=True)
-                    # write through a file object: np.save(path) would
-                    # append ".npy" to extension-less reference-style paths
-                    with open(path, "wb") as f:
-                        np.save(f,
-                                np.asarray(fetches[f"{_SAVE_PREFIX}{i}"]),
-                                allow_pickle=False)
-            if self.check_nan_inf:
-                # FLAGS_check_nan_inf analog (reference executor.cc:26,
-                # 120-128): scan fetches + updated state for non-finite
-                # values
-                for n, v in list(fetches.items()) + list(new_state.items()):
-                    arr = np.asarray(v)
-                    if np.issubdtype(arr.dtype, np.floating) and not np.all(
-                            np.isfinite(arr)):
-                        raise FloatingPointError(
-                            f"non-finite values in {n!r} after step "
-                            f"{self._step}")
-            _MET_STEPS.inc()
-            if not return_numpy:
-                return [fetches[n] for n in fetch_names]
-            with _TRC.span("executor.fetch", step=step,
-                           fetches=len(fetch_names)):
-                return [as_numpy(fetches[n]) for n in fetch_names]
+                with _TRC.span("executor.execute", cold=cold, step=step,
+                               cache_hit=not cold), \
+                        self._device_scope():
+                    fetches, new_state = compiled.fn(state_w, state_r,
+                                                     feed_vals, *rng)
+                with _TRC.span("executor.writeback", cold=cold, step=step,
+                               written=len(new_state)):
+                    for n, v in new_state.items():
+                        scope.set(n, v)
+                    # the donated buffers are dead and the scope has let go
+                    # of them: dropping the last references here puts the
+                    # cost of freeing a few hundred arrays inside the span,
+                    # not after the root at the frame's exit
+                    del state_w, state_r
+                    if compiled.save_specs:
+                        _write_saves(compiled.save_specs, fetches)
+                if self.check_nan_inf:
+                    _check_finite(fetches, new_state, self._step)
+                _MET_STEPS.inc()
+                if not return_numpy:
+                    return [fetches[n] for n in fetch_names]
+                with _TRC.span("executor.fetch", cold=cold, step=step,
+                               fetches=len(fetch_names)):
+                    return [as_numpy(fetches[n]) for n in fetch_names]
+            finally:
+                if late_root is not None:
+                    late_root.__exit__(*sys.exc_info())
 
     # ------------------------------------------------------------------
     def _device_scope(self):

@@ -22,11 +22,33 @@ class Place:
         return hash((type(self).__name__, tuple(sorted(self.__dict__.items()))))
 
 
+# whether a place of this process has asked JAX for its devices yet
+_resolved = False
+
+
+def _first_devices(platform: str):
+    """`jax.devices(platform)` for a process's first resolution of a place,
+    under the cold span `device.init` of the start-up record
+    (observability/tracing.py): the backend's start, the TPU client's where
+    the program is the first to touch it, and next to nothing where the
+    caller already had (`backend_up`)."""
+    global _resolved
+    import jax
+
+    from ..observability.tracing import TRACER
+
+    _resolved = True
+    with TRACER.span("device.init", cold=True, platform=platform,
+                     backend_up=backend_initialized()):
+        return jax.devices(platform)
+
+
 class CPUPlace(Place):
     def jax_device(self):
         import jax
 
-        return jax.devices("cpu")[0]
+        return (jax.devices("cpu") if _resolved
+                else _first_devices("cpu"))[0]
 
     def __repr__(self):
         return "CPUPlace()"
@@ -45,7 +67,8 @@ class TPUPlace(Place):
         import jax
 
         try:
-            devs = jax.devices("tpu")
+            devs = (jax.devices("tpu") if _resolved
+                    else _first_devices("tpu"))
         except RuntimeError as e:
             raise RuntimeError(
                 f"{self!r}: this process has no TPU backend (default "

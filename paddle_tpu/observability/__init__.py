@@ -7,7 +7,8 @@ Three modules, one namespace:
     exports; the bench-artifact metric-name authority
     (``artifact_metric``);
   * :mod:`.tracing` — the process-global ``TRACER``: nested spans in a
-    bounded ring, Chrome/Perfetto trace-event export;
+    bounded ring, the start-up record of the process's cold path (kept
+    with the ring off), Chrome/Perfetto trace-event export of both;
   * :mod:`.attribution` — the op identity every compiled step carries
     (named-scope threading, always on): what a traced run on the chip
     reads per-op device time by.
@@ -47,10 +48,10 @@ from .tracing import (  # noqa: F401
 )
 
 
-def span(name: str, cat: str = "pdtpu", **args):
+def span(name: str, cat: str = "pdtpu", cold: bool = False, **args):
     """Open a span on the global tracer (the shared no-op while neither
-    the ring nor a profiler session records)."""
-    return TRACER.span(name, cat=cat, **args)
+    the ring nor a profiler session records and the span is not `cold`)."""
+    return TRACER.span(name, cat=cat, cold=cold, **args)
 
 
 def instant(name: str, cat: str = "pdtpu", **args):
@@ -99,6 +100,7 @@ def export_telemetry(trace_obj=None, trace_path=None,
 
 def reset():
     """Fresh registry/tracer state (fluid.reset() hook —
-    clears series and the ring in place so held handles stay valid)."""
+    clears series, the ring and the start-up record but the process's own
+    facts in place, so held handles stay valid)."""
     REGISTRY.reset()
     TRACER.reset()

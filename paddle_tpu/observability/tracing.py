@@ -1,8 +1,9 @@
-"""Structured step tracing: one span, two sinks (ISSUE 13, ISSUE 24).
+"""Structured step tracing: one span, three sinks, one clock (ISSUE 13,
+ISSUE 24, ISSUE 50).
 
 The executor, serving engine, and training service open spans around
 their phases (prepare vs execute vs donation, admission vs prefill-chunk
-vs decode, lease/rollback events).  Every span goes to two places:
+vs decode, lease/rollback events).  A span goes to up to three places:
 
   * **the profiler's trace, whenever a profiler session is active** -
     each span is then also a
@@ -18,12 +19,27 @@ vs decode, lease/rollback events).  Every span goes to two places:
     ``/trace`` endpoint) of a long-lived service, in bounded memory,
     without a profiler.  Off by default (``PADDLE_TPU_TRACE=1`` or
     ``enable()``).
+  * **the start-up record, when the call site marks the span cold** -
+    a span on a path that runs once a process or once a compile (the
+    package's import, the device's start, a dispatch that compiles and
+    JAX's trace / lower / compile intervals inside it) passes
+    ``cold=True`` and is ALSO kept in a small ``deque`` of its own
+    (``COLD_CAPACITY`` events, so a service's steady spans never rotate
+    its start-up out), with absolute stamps of the clock, whether or not
+    anybody switched tracing on: ``startup_events()``, and ahead of the
+    ring's events in ``to_chrome()``.  No switch: what a process did
+    before its first step is always there to read.
 
-With the ring off and no session, nothing would read a span, and
-``Tracer.span`` hands out one shared stateless object: the cost of the
-instrumentation that stays in the hot serving/executor paths at all
-times is that of asking ``TraceAnnotation.is_enabled()``, the flag a
-``TraceMe`` itself checks (PERF.md, PR 24, has the numbers).
+With the ring off and no session, nothing would read a span that is not
+cold, and ``Tracer.span`` hands out one shared stateless object: the cost
+of the instrumentation that stays in the hot serving/executor paths at
+all times is that of asking ``TraceAnnotation.is_enabled()``, the flag a
+``TraceMe`` itself checks (PERF.md, PR 24 and PR 50, have the numbers).
+
+Every stamp (the ring's, the record's, the metrics registry's
+``monotime``) is ``time.monotonic``, the clock the benchmark's harness
+reads too: a reader lays the record beside its own stamps by
+subtraction.
 
 A span that a sink records has an ``id`` (process-wide, from 1) and its
 ``parent``'s id (0 for a root): the innermost recorded span open on the
@@ -44,7 +60,11 @@ from typing import List, Optional
 
 from jax.profiler import TraceAnnotation as _Annotation
 
-_clock = time.perf_counter
+_clock = time.monotonic
+now = _clock  # for a call site that stamps a `cold_event` itself
+# the start-up record's bound: a cold dispatch writes ~10 spans and an
+# event for every function JAX traces, lowers or compiles inside it
+COLD_CAPACITY = 4096
 _ids = itertools.count(1)  # next() on a count is atomic under the GIL
 # whether a profiler session is recording: what a TraceMe asks itself
 # before it records, asked here before one is built
@@ -73,13 +93,15 @@ _NOOP = _NoopSpan()
 
 class _Span:
     __slots__ = ("_tracer", "name", "cat", "args", "id", "parent", "_up",
-                 "_ann", "_noted", "_t0")
+                 "_ann", "_noted", "_t0", "_ring", "_cold")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict,
+                 cold: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._cold = cold
         self._ann = self._noted = None
 
     def note(self, **kw):
@@ -106,7 +128,8 @@ class _Span:
                                     id=self.id, parent=self.parent,
                                     **self.args)
             self._ann.__enter__()
-        self._t0 = _clock() if tr.enabled else None
+        self._ring = tr.enabled
+        self._t0 = _clock() if self._ring or self._cold else None
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -121,18 +144,22 @@ class _Span:
             args = dict(self.args, id=self.id, parent=self.parent)
             if exc_type is not None:
                 args["error"] = exc_type.__name__
-            tr._record({
-                "name": self.name, "cat": self.cat, "ph": "X",
-                "ts": round((self._t0 - tr._epoch) * 1e6, 3),
-                "dur": round((t1 - self._t0) * 1e6, 3),
-                "pid": tr._pid, "tid": threading.get_ident(),
-                "args": args,
-            })
+            if self._ring:
+                tr._record({
+                    "name": self.name, "cat": self.cat, "ph": "X",
+                    "ts": round((self._t0 - tr._epoch) * 1e6, 3),
+                    "dur": round((t1 - self._t0) * 1e6, 3),
+                    "pid": tr._pid, "tid": threading.get_ident(),
+                    "args": args,
+                })
+            if self._cold:
+                tr._keep(self.name, self._t0, t1, args)
         return False
 
 
 class Tracer:
-    """Bounded-ring span recorder with Chrome trace-event export."""
+    """Bounded-ring span recorder with a start-up record beside it and
+    Chrome trace-event export of both."""
 
     def __init__(self, enabled: Optional[bool] = None,
                  capacity: int = 65536):
@@ -141,19 +168,61 @@ class Tracer:
         self.enabled = bool(enabled)
         self.capacity = max(1, int(capacity))
         self._ring = collections.deque(maxlen=self.capacity)
+        # the start-up record: cold events, and apart from them the facts
+        # of the process, which reset() keeps
+        self._cold = collections.deque(maxlen=COLD_CAPACITY)
+        self._process: List[dict] = []
         self._lock = threading.Lock()
         self._local = threading.local()
         self._epoch = _clock()
         self._pid = os.getpid()
 
     # -- recording --------------------------------------------------------
-    def span(self, name: str, cat: str = "pdtpu", **args):
+    def span(self, name: str, cat: str = "pdtpu", cold: bool = False,
+             **args):
         """A span context: a TraceAnnotation while a profiler session is
-        active, a ring event while the tracer is enabled; while neither
-        is, the shared no-op."""
+        active, a ring event while the tracer is enabled, an event of the
+        start-up record when the call site says `cold` (its path runs
+        once a process or once a compile); where none of the three holds,
+        the shared no-op."""
+        if cold:
+            return _Span(self, name, cat, args, True)
         if not self.enabled and not _session_active():
             return _NOOP
         return _Span(self, name, cat, args)
+
+    def turn_cold(self, span, name: str, cat: str = "pdtpu", **args):
+        """For a call site that learns only inside its span that this
+        pass is a cold one (a dispatch finds no executable): `span` as it
+        got it from `span()` -> (the span that now stands for `name`, the
+        span the caller has to `__exit__` itself or None).  A recorded
+        span is marked cold where it stands; in place of the no-op a cold
+        span is opened here, so that it starts when its coldness was
+        found and a steady pass reads no clock for it."""
+        if isinstance(span, _Span):
+            span._cold = True
+            if span._t0 is None:
+                span._t0 = _clock()
+            span.note(**args)
+            return span, None
+        opened = _Span(self, name, cat, args, True).__enter__()
+        return opened, opened
+
+    def cold_event(self, name: str, t0: float, t1: float,
+                   process: bool = False, **args):
+        """An interval somebody else measured (JAX's compile phases, the
+        package's import), written into the start-up record with stamps
+        of this module's clock, under the span open on the calling
+        thread.  `process`: a fact of the process, which reset() keeps.
+        Inside a profiler session also a mark at the interval's END in
+        the profiler's trace, carrying `seconds` and the args."""
+        up = self.current()
+        args.update(id=next(_ids), parent=up.id if up is not None else 0)
+        if _session_active():
+            with _Annotation(ANNOTATION_PREFIX + name, seconds=t1 - t0,
+                             **args):
+                pass
+        self._keep(name, t0, t1, args, process)
 
     def current(self) -> Optional[_Span]:
         """The innermost recorded span open on the calling thread, or
@@ -175,15 +244,49 @@ class Tracer:
         with self._lock:
             self._ring.append(ev)
 
+    def _keep(self, name: str, t0: float, t1: float, args: dict,
+              process: bool = False):
+        ev = {"name": name, "cat": "cold", "ph": "X", "t0": t0, "t1": t1,
+              "pid": self._pid, "tid": threading.get_ident(), "args": args}
+        with self._lock:
+            (self._process if process else self._cold).append(ev)
+
     # -- export -----------------------------------------------------------
     def events(self) -> List[dict]:
         with self._lock:
             return list(self._ring)
 
+    def startup_events(self) -> List[dict]:
+        """The start-up record: the process's facts, then the cold events
+        in the order they ended.  `t0` and `t1` are absolute seconds of
+        `time.monotonic`; `args` carry `id` and `parent` as a ring
+        event's do."""
+        with self._lock:
+            return [dict(e) for e in self._process] + \
+                [dict(e) for e in self._cold]
+
     def to_chrome(self) -> dict:
         """Chrome trace-event JSON object format — loadable by Perfetto
-        (ui.perfetto.dev) and chrome://tracing."""
-        return chrome_envelope(self.events())
+        (ui.perfetto.dev) and chrome://tracing: the start-up record's
+        events (category `cold`) ahead of the ring's, on one time axis
+        that starts at the earlier of the ring's epoch and the record's
+        first stamp; a cold span the ring holds too appears once."""
+        cold, ring = self.startup_events(), self.events()
+        base = min([self._epoch] + [e["t0"] for e in cold])
+        shift = (self._epoch - base) * 1e6
+        out, ids = [], set()
+        for e in cold:
+            t0, t1 = e.pop("t0"), e.pop("t1")
+            e["ts"] = round((t0 - base) * 1e6, 3)
+            e["dur"] = round((t1 - t0) * 1e6, 3)
+            ids.add(e["args"]["id"])
+            out.append(e)
+        for e in ring:
+            if e.get("args", {}).get("id") in ids:
+                continue
+            out.append(dict(e, ts=round(e["ts"] + shift, 3)) if shift
+                       else e)
+        return chrome_envelope(out)
 
     def export(self, path: str) -> str:
         obj = self.to_chrome()
@@ -206,6 +309,7 @@ class Tracer:
     def reset(self):
         with self._lock:
             self._ring.clear()
+            self._cold.clear()
         self._epoch = _clock()
 
 

@@ -46,10 +46,17 @@ class ParallelExecutor(Executor):
         self._pin_device = False
         # the step output pytree must match out_shardings exactly
         self._strict_state = True
-        self.mesh = mesh if mesh is not None else make_mesh(axes, devices)
+        if mesh is None:
+            with _TRC.span("parallel.mesh", cold=True,
+                           axes=str(dict(axes or {}))):
+                mesh = make_mesh(axes, devices)
+        self.mesh = mesh
         self.transpiler = DistributeTranspiler(
             rules, zero_dp_states=zero_dp_states, fsdp_params=fsdp_params)
         self._plans: Dict[int, tuple] = {}
+        # program token -> the desc version whose state this executor has
+        # distributed once: the next `executor.distribute` is a steady one
+        self._distributed: Dict[int, int] = {}
         self.zero_dp_states = self.transpiler.rules.zero_dp_states
         self.fsdp_params = self.transpiler.rules.fsdp_params
 
@@ -59,7 +66,9 @@ class ParallelExecutor(Executor):
         key = (program._cache_token, program._version)
         entry = self._plans.get(key)
         if entry is None:
-            plan = self.transpiler.transpile(program, self.mesh)
+            with _TRC.span("parallel.plan", cold=True,
+                           program=program._cache_token):
+                plan = self.transpiler.transpile(program, self.mesh)
             entry = (plan, dict(self.transpiler.last_provenance))
             self._plans[key] = entry
             # an accumulator-free optimizer (plain SGD) under fsdp_params
@@ -181,8 +190,14 @@ class ParallelExecutor(Executor):
         scope = scope if scope is not None else global_scope()
         block = program.blocks[block_id]
         # pre-shard all scope state the block touches: every call walks
-        # the block's ops, before (and so outside) Executor.run's spans
-        with _TRC.span("executor.distribute", step=self._step,
+        # the block's ops, before (and so outside) Executor.run's spans.
+        # A program version's first pass moves the state to its planned
+        # shardings and is the one that compiles: a cold span
+        cold = self._distributed.get(program._cache_token) != \
+            program._version
+        if cold:
+            self._distributed[program._cache_token] = program._version
+        with _TRC.span("executor.distribute", cold=cold, step=self._step,
                        ops=len(block.ops)):
             names = set()
             for op in block.ops:

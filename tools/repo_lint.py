@@ -32,7 +32,7 @@ Classes of rot this repo has actually accumulated:
      substrate precisely because every tier had grown its own
      ``time.perf_counter()`` bookkeeping (profiler.py's global event
      map, serve_bench's private dicts); new timing goes through
-     ``observability.metrics.monotime`` / ``REGISTRY.timed()`` /
+     ``observability.metrics.monotime`` / a registry histogram /
      tracer spans so it lands in the shared registry.  ``tests/`` are
      exempt as always.  Line-anchored tripwire like the others, not an
      AST proof.
@@ -229,7 +229,7 @@ def _check_perf_counter(root, dirpath, filenames, findings):
                         findings.append(
                             f"ad-hoc perf_counter timing: {rel}:{i} "
                             f"(use observability.metrics.monotime / "
-                            f"REGISTRY.timed() / tracer spans so the "
+                            f"a registry histogram / tracer spans so the "
                             f"measurement lands in the shared "
                             f"registry)")
         except OSError:
