@@ -866,24 +866,36 @@ def mtp_project(ctx, ins, attrs):
                         + rms(e, eps, (2,), ins["ENorm"][0]) @ w[D:]]}
 
 
+def causal_taps(g, w):
+    """g [B, T, C], w [C, L], both wide -> c_t = sum_j w[:, j] g_{t - (L -
+    1) + j}, g zero before the sequence starts: depthwise and causal, the
+    LAST tap on the current token, as L shifted multiply-adds that XLA
+    fuses into one pass over g.  The plain emission of both short
+    convolutions (`gated_short_conv`, `gated_delta_rule`) and the oracle of
+    their kernels."""
+    import jax.numpy as jnp
+
+    T, taps = g.shape[1], w.shape[1]
+    c = w[:, taps - 1] * g
+    for back in range(1, min(taps, T)):       # the tap `back` tokens ago
+        c = c + w[:, taps - 1 - back] * jnp.pad(
+            g, ((0, 0), (back, 0), (0, 0)))[:, :T]
+    return c
+
+
 def gated_short_conv_plain(x, w):
     """X [B, T, 3D], Filter [D, L] -> Out [B, T, D] (`gated_short_conv`'s
-    equations): L shifted multiply-adds that XLA fuses into one pass over
-    X, at least float32 inside, X's dtype out.  What the kernels of
+    equations): `causal_taps` between the two gates, at least float32
+    inside, X's dtype out.  What the kernels of
     ops/pallas_kernels/short_conv.py compute, in plain jax.numpy."""
     import jax.numpy as jnp
 
-    T, taps = x.shape[1], w.shape[1]
     wide = wide_dtype(x.dtype)
     gate_in, gate_out, u = jnp.split(x.astype(wide), 3, axis=-1)
     with part_scope("conv.gate"):
         g = gate_in * u
     with part_scope("conv.taps"):
-        wf = w.astype(wide)
-        c = wf[:, taps - 1] * g
-        for back in range(1, min(taps, T)):   # the tap `back` tokens ago
-            c = c + wf[:, taps - 1 - back] * jnp.pad(
-                g, ((0, 0), (back, 0), (0, 0)))[:, :T]
+        c = causal_taps(g, w.astype(wide))
     with part_scope("conv.gate"):
         out = gate_out * c
     return out.astype(x.dtype)

@@ -30,7 +30,7 @@ import functools
 
 from ..observability.attribution import part_scope
 from ..observability.metrics import REGISTRY as _MET
-from .llm_ops import head_norm_rope_plain, rms, wide_dtype
+from .llm_ops import causal_taps, head_norm_rope_plain, rms, wide_dtype
 from .registry import register_cost, register_op
 
 _MET_LINATTN = _MET.counter(
@@ -51,6 +51,14 @@ _MET_GDN_KERNELS = _MET.counter(
     "ops/pallas_kernels/gated_delta.py, a chunk's tiles and the state in "
     "VMEM; xla: gated_delta_chunked as plain jax.numpy under "
     "jax.checkpoint)")
+_MET_GDN_CONV = _MET.counter(
+    "gated_delta_conv_kernels_traced_total",
+    "emissions of the gated delta rule's convolution part (taps, SiLU, the "
+    "l2 norm of q and k, the heads' split; once a compile, not once a "
+    "step), by the op that emits it (fwd: gated_delta_rule; grad: its grad "
+    "op's re-emission) and the path taken (pallas: the kernel pair of "
+    "ops/pallas_kernels/gdn_conv.py; xla: short_conv_silu and the plain "
+    "norm and split)")
 _MET_SPARSE = _MET.counter(
     "sparse_attention_layers_traced_total",
     "block-sparse attention ops traced (forward emission; once a compile, "
@@ -299,15 +307,37 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int):
 def short_conv_silu(x, w):
     """x [B, T, C], w [C, L] -> SiLU of the causal depthwise convolution
     c_t = sum_j w[:, j] x_{t - (L - 1) + j} (x zero before the sequence
-    starts; w[:, L - 1] multiplies the current token), at least float32."""
+    starts; w[:, L - 1] multiplies the current token: `causal_taps`), at
+    least float32."""
+    import jax
+
+    wide = wide_dtype(x.dtype)
+    return jax.nn.silu(causal_taps(x.astype(wide), w.astype(wide)))
+
+
+def gdn_conv_plain(x, taps, Hk: int, Hv: int, Dk: int, eps: float):
+    """X [B, T, 2 Hk Dk + 2 Hv Dv] = [q | k | v | z], Conv [2 Hk Dk + Hv Dv,
+    L] -> (q, k [B, Hk, T, Dk], v [B, Hk, Hv / Hk, T, Dv]):
+    `short_conv_silu` over [q | k | v], the l2 norm of a q and a k head (q
+    times Dk^-1/2 too), one rounding to X's dtype, the heads' split.  The
+    `gdn.conv` part of `gated_delta_rule` as plain jax.numpy, and the
+    oracle of ops/pallas_kernels/gdn_conv.py."""
     import jax
     import jax.numpy as jnp
 
-    T, L = x.shape[1], w.shape[1]
-    wide = wide_dtype(x.dtype)
-    padded = jnp.pad(x.astype(wide), ((0, 0), (L - 1, 0), (0, 0)))
-    return jax.nn.silu(sum(w[:, j].astype(wide) * padded[:, j:j + T]
-                           for j in range(L)))
+    B, T, width = x.shape
+    Dv = (width - 2 * Hk * Dk) // (2 * Hv)
+    mixed = 2 * Hk * Dk + Hv * Dv
+    qkv = short_conv_silu(x[..., :mixed], taps)
+    q, k = (qkv[..., n * Hk * Dk:(n + 1) * Hk * Dk].reshape(B, T, Hk, Dk)
+            for n in (0, 1))
+    unit = lambda a: a * jax.lax.rsqrt(                           # noqa: E731
+        jnp.sum(a * a, axis=-1, keepdims=True) + eps)
+    q = (unit(q) * Dk ** -0.5).astype(x.dtype).transpose(0, 2, 1, 3)
+    k = unit(k).astype(x.dtype).transpose(0, 2, 1, 3)
+    v = qkv[..., 2 * Hk * Dk:].astype(x.dtype).reshape(
+        B, T, Hk, Hv // Hk, Dv).transpose(0, 2, 3, 1, 4)
+    return q, k, v
 
 
 @register_op("gated_delta_rule")
@@ -339,11 +369,22 @@ def gated_delta_rule(ctx, ins, attrs):
     recomputed in its backward (jax.checkpoint): the vjp keeps q, k, v, g
     and beta, and of the scan's own at most the chunks' incoming states
     while that layer's backward runs.  `gated_delta_kernels_traced_total`
-    says which."""
+    says which.
+
+    Independently of that, on one TPU at heads of whole lane tiles, T in
+    whole row tiles and bf16 or float32 X, the convolution part is the
+    kernel pair of ops/pallas_kernels/gdn_conv.py under a `custom_vjp` of
+    its own: X's [q | k | v] columns read where the projection wrote them,
+    q, k and v written head-major in one pass and kept for the grad op
+    (XLA's CSE kept the same three), whose re-emission launches the
+    backward kernel alone; it writes ALL of dX, the output gate's dz in its
+    last columns.  Everywhere else `short_conv_silu` and the plain norm and
+    split.  `gated_delta_conv_kernels_traced_total` says which."""
     import jax
     import jax.numpy as jnp
 
     from .pallas_kernels import gated_delta as kernels
+    from .pallas_kernels import gdn_conv
     from .pallas_kernels._common import pallas_dispatch_ok
 
     x, ba, taps = ins["X"][0], ins["BA"][0], ins["Conv"][0]
@@ -360,53 +401,68 @@ def gated_delta_rule(ctx, ins, attrs):
         raise ValueError(
             f"gated_delta_rule: X {x.shape}, BA {ba.shape}, Conv "
             f"{taps.shape} at {Hk} key heads of {Dk} and {Hv} value heads")
-    take = (pallas_dispatch_ok(ctx)
-            and kernels.usable(T, chunk, Dk, Dv, x.dtype, G))
-    if not ctx.in_grad_replay():
+    on_tpu = pallas_dispatch_ok(ctx)
+    take = on_tpu and kernels.usable(T, chunk, Dk, Dv, x.dtype, G)
+    take_conv = on_tpu and gdn_conv.usable(T, Hk, Hv, Dk, Dv, taps.shape[1],
+                                           x.dtype)
+    replay = ctx.in_grad_replay()
+    if not replay:
         _MET_GDN.inc(key_heads=str(Hk), value_heads=str(Hv),
                      head_dim=str(Dk), chunk=str(chunk),
                      conv_taps=str(taps.shape[1]))
-    _MET_GDN_KERNELS.inc(op="grad" if ctx.in_grad_replay() else "fwd",
-                         path="pallas" if take else "xla")
+    for met, pallas in ((_MET_GDN_KERNELS, take), (_MET_GDN_CONV, take_conv)):
+        met.inc(op="grad" if replay else "fwd",
+                path="pallas" if pallas else "xla")
     wide = wide_dtype(x.dtype)
-    with part_scope("gdn.conv"):
-        qkv = short_conv_silu(x[..., :mixed], taps)
-        q, k = (qkv[..., n * Hk * Dk:(n + 1) * Hk * Dk].reshape(B, T, Hk, Dk)
-                for n in (0, 1))
-        unit = lambda a: a * jax.lax.rsqrt(                       # noqa: E731
-            jnp.sum(a * a, axis=-1, keepdims=True) + eps)
-        q = (unit(q) * Dk ** -0.5).astype(x.dtype).transpose(0, 2, 1, 3)
-        k = unit(k).astype(x.dtype).transpose(0, 2, 1, 3)
-        v = qkv[..., 2 * Hk * Dk:].astype(x.dtype).reshape(
-            B, T, Hk, G, Dv).transpose(0, 2, 3, 1, 4)
+    # what the forward emission kept for this re-emission, and what this
+    # forward emission keeps: {"conv": (q, k, v), "scan": (O, states, Tm)}
+    kept = ctx.kept_for_grad() or {}
+    keeps = not (ctx.is_test or replay)
+    saved = {}
+    if take_conv:       # opens the parts' scopes itself: z is norm_gate's
+        conv = gdn_conv.make_gdn_conv(Hk, Hv, Dk, eps)
+        if "conv" in kept:
+            q, k, v, z = conv.from_saved(x, taps, *kept["conv"])
+        else:
+            q, k, v, z = conv(x, taps)
+            if keeps:
+                saved["conv"] = (q, k, v)
+    else:
+        with part_scope("gdn.conv"):
+            q, k, v = gdn_conv_plain(x, taps, Hk, Hv, Dk, eps)
+        z = None
     with part_scope("gdn.gates"):
         heads = lambda a: a.astype(wide).reshape(                 # noqa: E731
             B, T, Hk, G).transpose(0, 2, 3, 1)
         beta = jax.nn.sigmoid(heads(ba[..., :Hv]))
         g = heads(-jnp.exp(ins["ALog"][0].astype(wide)) * jax.nn.softplus(
             ba[..., Hv:].astype(wide) + ins["DtBias"][0].astype(wide)))
-    saved = None
     with part_scope("gdn.scan"):
         if take:
             scan = kernels.make_gated_delta(chunk)
-            kept = ctx.kept_for_grad()
-            if kept is not None:
-                o = scan.from_saved(q, k, v, g, beta, *kept)
-            elif ctx.is_test or ctx.in_grad_replay():
-                o = scan(q, k, v, g, beta)
+            if "scan" in kept:
+                o = scan.from_saved(q, k, v, g, beta, *kept["scan"])
+            elif keeps:
+                saved["scan"] = scan.keeping(q, k, v, g, beta)
+                o = saved["scan"][0]
             else:
-                saved = scan.keeping(q, k, v, g, beta)
-                o = saved[0]
-            ctx.kernel_forward(reused=kept is not None)
+                o = scan(q, k, v, g, beta)
         else:
             o = jax.checkpoint(functools.partial(
                 gated_delta_chunked, chunk=chunk))(q, k, v, g, beta)
+    if take or take_conv:
+        # no kernel's forward launched again: every path taken found its own
+        ctx.kernel_forward(reused=all(
+            name in kept for name, on in (("conv", take_conv), ("scan", take))
+            if on))
     with part_scope("gdn.norm_gate"):
         o = rms(o, eps, (4,), ins["Norm"][0].astype(o.dtype))
         o = o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hv * Dv)
-        out = o * jax.nn.silu(x[..., mixed:].astype(o.dtype))
+        if z is None:
+            z = x[..., mixed:]
+        out = o * jax.nn.silu(z.astype(o.dtype))
     out = out.astype(x.dtype)
-    if saved is not None:
+    if saved:
         ctx.keep_for_grad(attrs, [out], saved)
     return {"Out": [out]}
 

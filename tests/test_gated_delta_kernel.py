@@ -14,6 +14,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops import sparse_linear_ops as slo
 from paddle_tpu.ops.pallas_kernels import gated_delta as K
+from paddle_tpu.ops.pallas_kernels import gdn_conv
 
 # a token's decay e^g: the state all but kept, and forgotten in a token or
 # two (a chunk's last decays underflow against its first)
@@ -283,30 +284,35 @@ def test_gated_delta_rule_takes_the_kernels_on_a_tpu(monkeypatch):
     """Where the trace targets one TPU, at whole lane tiles, the op's
     emitter launches the forward kernel ONCE and keeps O, the states and
     Tm, and its grad op's re-emission launches the reverse pass alone
-    (`executor_grad_kernel_forward_total` reused=1);
-    the numbers are the plain emission's; the counter names the path; the
+    (`executor_grad_kernel_forward_total` reused=1: the convolution's pair
+    of PR 51, taken at this shape too, kept its q, k and v as well);
+    the numbers are the plain emission's; the counters name the paths; the
     switch sends both emissions the plain way."""
     values, attrs, weight = _gdn_values(256, 1, 2, 128, 128)
+    families = ("gated_delta_kernels_traced_total",
+                "gated_delta_conv_kernels_traced_total")
     obs.REGISTRY.reset()
     want, ops = _gdn_step(values, attrs, weight)
     assert [op.type for op in ops].count("generic_grad") >= 1
-    assert _series("gated_delta_kernels_traced_total") == [
-        ({"op": "fwd", "path": "xla"}, 1.0),
-        ({"op": "grad", "path": "xla"}, 1.0)]
+    for family in families:
+        assert _series(family) == [({"op": "fwd", "path": "xla"}, 1.0),
+                                   ({"op": "grad", "path": "xla"}, 1.0)]
     assert _series("executor_grad_kernel_forward_total") == []
-    real_make = K.make_gated_delta
+    real_make, real_conv = K.make_gated_delta, gdn_conv.make_gdn_conv
     monkeypatch.setattr(reg.EmitContext, "target_platform",
                         lambda self: "tpu")
     launched = _spy_on_calls(monkeypatch)
     monkeypatch.setattr(K, "make_gated_delta",
                         lambda chunk: real_make(chunk, True))
+    monkeypatch.setattr(gdn_conv, "make_gdn_conv",
+                        lambda *a: real_conv(*a, True))
     real_make.cache_clear()
     obs.REGISTRY.reset()
     got, _ = _gdn_step(values, attrs, weight)
     assert launched == ["fwd_keep", "bwd"]
-    assert _series("gated_delta_kernels_traced_total") == [
-        ({"op": "fwd", "path": "pallas"}, 1.0),
-        ({"op": "grad", "path": "pallas"}, 1.0)]
+    for family in families:
+        assert _series(family) == [({"op": "fwd", "path": "pallas"}, 1.0),
+                                   ({"op": "grad", "path": "pallas"}, 1.0)]
     assert _series("executor_grad_kernel_forward_total") == [
         ({"op": "gated_delta_rule", "reused": "1"}, 1.0)]
     assert _series("gated_delta_layers_traced_total") == [

@@ -625,9 +625,10 @@ def test_qwen3_next_program_counts_what_it_traced(monkeypatch):
          ("rotary_dim", "4")): 1.0}
     # on the CPU the scan is the plain emission, forward and in the grad
     # op's re-emission, and no grad op is handed a kernel's kept result
-    assert series("gated_delta_kernels_traced_total") == {
-        (("op", "fwd"), ("path", "xla")): 3.0,
-        (("op", "grad"), ("path", "xla")): 3.0}
+    for family in ("gated_delta_kernels_traced_total",
+                   "gated_delta_conv_kernels_traced_total"):
+        assert series(family) == {(("op", "fwd"), ("path", "xla")): 3.0,
+                                  (("op", "grad"), ("path", "xla")): 3.0}
     assert "executor_grad_kernel_forward_total" not in fam or not any(
         ("op", "gated_delta_rule") in labels
         for labels in series("executor_grad_kernel_forward_total"))
@@ -636,14 +637,15 @@ def test_qwen3_next_program_counts_what_it_traced(monkeypatch):
 
 def test_gated_delta_net_layer_trains_through_the_kernels(monkeypatch):
     """A DeltaNet block at heads of 128 over two chunks of 128, one SGD
-    step of mean(out^2): where the trace targets one TPU the scan is the
-    kernel pair (interpreted here), the grad op's re-emission reuses the
-    forward op's kept O (`executor_grad_kernel_forward_total` reused=1:
-    what `kernel_forward_reruns` reads as 0) and the step's loss and
-    updated parameters are the plain emission's."""
+    step of mean(out^2): where the trace targets one TPU the scan and the
+    convolution are their kernel pairs (interpreted here), the grad op's
+    re-emission reuses the forward op's kept O and q, k, v
+    (`executor_grad_kernel_forward_total` reused=1: what
+    `kernel_forward_reruns` reads as 0) and the step's loss and updated
+    parameters are the plain emission's."""
     from paddle_tpu import observability as obs
     from paddle_tpu.ops import registry as reg
-    from paddle_tpu.ops.pallas_kernels import gated_delta
+    from paddle_tpu.ops.pallas_kernels import gated_delta, gdn_conv
 
     x = _r(1, 256, 32, seed=1).astype(np.float32)
 
@@ -669,20 +671,24 @@ def test_gated_delta_net_layer_trains_through_the_kernels(monkeypatch):
         return (float(got), [np.asarray(scope.find(p.name)) for p in
                              main.global_block().all_parameters()], series)
 
+    families = ("gated_delta_kernels_traced_total",
+                "gated_delta_conv_kernels_traced_total")
     loss, params, series = step()
-    assert series("gated_delta_kernels_traced_total") == {
-        (("op", "fwd"), ("path", "xla")): 1.0,
-        (("op", "grad"), ("path", "xla")): 1.0}
+    for family in families:
+        assert series(family) == {(("op", "fwd"), ("path", "xla")): 1.0,
+                                  (("op", "grad"), ("path", "xla")): 1.0}
     assert series("executor_grad_kernel_forward_total") == {}
-    real = gated_delta.make_gated_delta
+    real, real_conv = gated_delta.make_gated_delta, gdn_conv.make_gdn_conv
     monkeypatch.setattr(reg.EmitContext, "target_platform",
                         lambda self: "tpu")
     monkeypatch.setattr(gated_delta, "make_gated_delta",
                         lambda chunk: real(chunk, True))
+    monkeypatch.setattr(gdn_conv, "make_gdn_conv",
+                        lambda *a: real_conv(*a, True))
     kernel_loss, kernel_params, series = step()
-    assert series("gated_delta_kernels_traced_total") == {
-        (("op", "fwd"), ("path", "pallas")): 1.0,
-        (("op", "grad"), ("path", "pallas")): 1.0}
+    for family in families:
+        assert series(family) == {(("op", "fwd"), ("path", "pallas")): 1.0,
+                                  (("op", "grad"), ("path", "pallas")): 1.0}
     assert series("executor_grad_kernel_forward_total") == {
         (("op", "gated_delta_rule"), ("reused", "1")): 1.0}
     assert abs(kernel_loss - loss) <= 1e-5 * abs(loss)
