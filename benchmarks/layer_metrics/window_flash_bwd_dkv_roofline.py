@@ -1,0 +1,20 @@
+"""window_flash_bwd_dkv_roofline — the roofline share of ALL the
+`flash_bwd_dkv` calls of the traced window in a decoder that mixes window
+and full-span differential attention: see window_flash_fwd_roofline.py,
+whose `kernel_share` does the arithmetic (the least FLOPs and bytes of kind
+'bwd_dkv' from benchmarks/flops_phi4flash.py by the live pairs, every score
+once, over the kernel's device time in the trace)."""
+
+LAYER = "Pallas kernels"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "device_trace"
+MOVES = "train_samples_per_s"
+
+
+def read(run):
+    from harness import load_module
+
+    return load_module("layer_metrics",
+                       "window_flash_fwd_roofline").kernel_share(
+        run, "flash_bwd_dkv", "bwd_dkv")

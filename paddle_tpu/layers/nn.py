@@ -323,7 +323,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          rope_theta=None, out_param_attr=None,
                          num_kv_heads=None, qk_norm_per_head=False,
                          head_dim=None, block_diffusion=None,
-                         output_gate=False, rotary_dim=None):
+                         output_gate=False, rotary_dim=None, window=None,
+                         bias=False, differential=None, kv=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -357,6 +358,15 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     rotary turn takes the first so many columns of a head alone (a
     `partial_rotary_factor`: rotate-half inside them, their own
     frequencies) and the others pass unturned.
+    `window` w: a causal layer's token sees the w keys that end with itself
+    (key j iff 0 <= t - j < w; the attention op's `mask` "window").  `bias`:
+    the projections have one (True, or the attr of all of them).
+    `differential` (a dict; `_differential_attention` has its keys and the
+    equations): differential attention, two softmax maps a head pair and
+    their difference under a learned lambda, from ONE fused projection [q |
+    k | v]; with `kv` = the (K, V1, V2) another such layer's dict holds under
+    "made", a query-only projection that attends to that layer's keys and
+    values as they were computed there.
     `param_attr` is the Q, K and V projections', `out_param_attr` the
     output projection's.
 
@@ -391,8 +401,33 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     if rotary_dim is not None and rope_theta is None:
         raise ValueError("multi_head_attention: rotary_dim is the width of "
                          "the rotary turn: give rope_theta")
+    if window is not None and (not causal or block_diffusion):
+        raise ValueError("multi_head_attention: a sliding window is a "
+                         "causal layer's")
+    bias_attr = False if not bias else (bias if isinstance(bias, dict)
+                                        else None)
+    masked = {"causal": causal}
+    if window is not None:
+        masked.update(mask="window", window=int(window))
+    if differential is not None:
+        if (rope_theta is not None or qk_norm_epsilon is not None
+                or output_gate or block_diffusion):
+            raise ValueError("multi_head_attention: differential attention "
+                             "takes no rotary turn, QK-norm, output gate or "
+                             "block-diffusion mask")
+        from .sequence import propagate_length
+
+        merged = _differential_attention(
+            helper, queries, num_heads, kv_heads, head_dim, differential, kv,
+            masked, param_attr, bias_attr)
+        return propagate_length(queries, fc(
+            merged, D, num_flatten_dims=2, param_attr=out_param_attr,
+            bias_attr=bias_attr))
+    if kv is not None:
+        raise ValueError("multi_head_attention: `kv` hands a differential "
+                         "layer another's keys and values")
     q = fc(queries, (2 if output_gate else 1) * num_heads * head_dim,
-           num_flatten_dims=2, param_attr=param_attr, bias_attr=False)
+           num_flatten_dims=2, param_attr=param_attr, bias_attr=bias_attr)
     gate = None
     if output_gate:   # [q | gate], each num_heads * head_dim wide
         def half(n):
@@ -408,9 +443,9 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
             return out
         q, gate = half(0), half(1)
     k = fc(keys, kv_heads * head_dim, num_flatten_dims=2,
-           param_attr=param_attr, bias_attr=False)
+           param_attr=param_attr, bias_attr=bias_attr)
     v = fc(values, kv_heads * head_dim, num_flatten_dims=2,
-           param_attr=param_attr, bias_attr=False)
+           param_attr=param_attr, bias_attr=bias_attr)
     qk_norm = functools.partial(rms_norm, epsilon=qk_norm_epsilon,
                                 part="attn.qk_norm")
     if qk_norm_epsilon is not None and not qk_norm_per_head:
@@ -451,8 +486,7 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         return r
 
     wide = tuple(queries.shape[:-1]) + (num_heads * head_dim,)
-    sdpa_attrs = {"causal": causal, "sp_mode": sp_mode,
-                  "sp_schedule": sp_schedule}
+    sdpa_attrs = {**masked, "sp_mode": sp_mode, "sp_schedule": sp_schedule}
     if block_diffusion:
         sdpa_attrs.update({"mask": "block_diffusion",
                            "seq_len": int(block_diffusion[0]),
@@ -502,10 +536,192 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                    "part": "attn.gate"})
         merged = gated
     out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
-             bias_attr=False)
+             bias_attr=bias_attr)
     from .sequence import propagate_length
 
     return propagate_length(queries, out)
+
+
+def differential_lambda_init(layer_index: int) -> float:
+    """0.8 - 0.6 exp(-0.3 i) for the layer's index i in the WHOLE model
+    (Differential Transformer, arXiv:2410.05258; the published
+    `lambda_init_fn`)."""
+    import math
+
+    return 0.8 - 0.6 * math.exp(-0.3 * int(layer_index))
+
+
+def _differential_attention(helper, x, num_heads, kv_heads, head_dim, diff,
+                            kv, masked, param_attr, bias_attr):
+    """The core of a differential-attention layer (ops/attention_ops.py
+    `diff_attn_split`, `diff_attn_combine` have the equations) between its
+    input and its output projection: X [B, T, D] -> [B, T, num_heads *
+    head_dim].  ONE projection to [q | k | v] (to q alone where `kv` = (K,
+    V1, V2) hands in another layer's), the heads in pairs "(H two)", two
+    `scaled_dot_product_attention` calls of all query heads (pair's first,
+    then pair's second) under `masked`, once on (v1; v1) and once on (v2;
+    v2), and the combination under the part `attn.diff`.  `diff`:
+    "layer_index" (the layer's index in the whole model: lambda_init),
+    optionally "epsilon" (the RMSNorm's, 1e-5), "lambda_attr" (the four
+    lambda vectors': normal(0, 0.1) by default) and "gain_attr" (the
+    RMSNorm's gain [2 head_dim]: one by default).  The dict gains "made" =
+    (K, V1, V2) as this layer computed or received them and "result", the
+    combination's output.  Parameters in creation order: the projection
+    (and its bias), lambda_q1, lambda_k1, lambda_q2, lambda_k2 [head_dim],
+    the gain [2 head_dim]."""
+    heads = {"num_heads": int(num_heads), "num_kv_heads": int(kv_heads),
+             "head_dim": int(head_dim)}
+    B, T = x.shape[0], x.shape[1]
+    width = num_heads * head_dim + (0 if kv else 2 * kv_heads * head_dim)
+    proj = fc(x, width, num_flatten_dims=2, param_attr=param_attr,
+              bias_attr=bias_attr)
+    q = helper.create_tmp_variable(x.dtype,
+                                   shape=(B, num_heads, T, head_dim))
+    outs = {"Q": [q.name]}
+    if kv is None:
+        kv = tuple(helper.create_tmp_variable(
+            x.dtype, shape=(B, kv_heads, T, head_dim)) for _ in range(3))
+        outs.update({s: [v.name] for s, v in zip(("K", "V1", "V2"), kv)})
+    helper.append_op("diff_attn_split", inputs={"X": [proj.name]},
+                     outputs=outs, attrs={**heads, "part": "attn.diff"})
+    diff["made"] = kv
+    halves = []
+    for values in kv[1:]:
+        o = helper.create_tmp_variable(x.dtype,
+                                       shape=(B, num_heads, T, head_dim))
+        helper.append_op(
+            "scaled_dot_product_attention",
+            inputs={"Q": [q.name], "K": [kv[0].name], "V": [values.name]},
+            outputs={"Out": [o.name]}, attrs=dict(masked))
+        halves.append(o)
+    lam = diff.get("lambda_attr") or {
+        "initializer": NormalInitializer(scale=0.1)}
+    vectors = [helper.create_parameter(attr=lam, shape=[head_dim],
+                                       dtype=x.dtype) for _ in range(4)]
+    gain = _rms_gain(helper, 2 * head_dim, x.dtype, diff.get("gain_attr"))
+    out = helper.create_tmp_variable(
+        x.dtype, shape=(B, T, num_heads * head_dim))
+    helper.append_op(
+        "diff_attn_combine",
+        inputs={"O1": [halves[0].name], "O2": [halves[1].name],
+                "LambdaQ1": [vectors[0].name], "LambdaK1": [vectors[1].name],
+                "LambdaQ2": [vectors[2].name], "LambdaK2": [vectors[3].name],
+                "Gain": [gain.name]},
+        outputs={"Out": [out.name]},
+        attrs={"lambda_init": differential_lambda_init(diff["layer_index"]),
+               "epsilon": float(diff.get("epsilon", 1e-5)),
+               "part": "attn.diff"})
+    diff["result"] = out
+    return out
+
+
+def mamba(input, d_state=16, d_conv=4, expand=2, dt_rank=None,
+          param_attr=None, bias_attr=None, skip_attr=None, memory=None,
+          name=None):
+    """A Mamba (S6) mixer over [B, T, D] (arXiv:2312.00752, as the published
+    `Phi3Mamba` computes it; ops/ssm_ops.py `selective_scan` has the
+    equations): an input projection to [u' | z] (2 d_inner, d_inner =
+    `expand` x D), a causal depthwise convolution of `d_conv` taps with bias
+    + SiLU over u', a projection of the result to [r | B | C] (`dt_rank`,
+    ceil(D / 16) by default, + 2 `d_state`), the step sizes' projection of r
+    to d_inner, the selective scan over the [d_inner, d_state] state in
+    chunks of tokens (ops/ssm_ops.py SCAN_CHUNK), the gate y * SiLU(z) and
+    an output projection.  `memory`, a list, gains the
+    scan's result y [B, T, d_inner] (with the D term, BEFORE the gate: what a
+    `gated_memory_unit` of a later layer reads).  Nine parameters, in
+    creation order: W_in [D, 2 d_inner], the taps [d_inner, d_conv] (uniform
+    on +- d_conv^-1/2, torch's Conv1d default), their bias [d_inner]
+    (`bias_attr`; the same default), W_x [d_inner, dt_rank + 2 d_state],
+    W_dt [dt_rank, d_inner], dt's bias [d_inner] (the inverse softplus of a
+    log-uniform draw on [0.001, 0.1]), A_log [d_inner, d_state] (log(1 ..
+    d_state) a channel), D [d_inner] (`skip_attr`; one by default), W_out
+    [d_inner, D]; the projections have no bias.  The parts `ssm.in_proj`,
+    `ssm.conv`, `ssm.xdt`, `ssm.scan`, `ssm.gate_out` name its stages in a
+    trace."""
+    import math
+
+    helper = LayerHelper("mamba", name=name)
+    prog = helper.main_program
+    dim = input.shape[-1]
+    Di, N, L = int(expand) * dim, int(d_state), int(d_conv)
+    R = int(dt_rank) if dt_rank else -(-dim // 16)
+    project = functools.partial(fc, num_flatten_dims=2,
+                                param_attr=param_attr, bias_attr=False)
+    with prog.part_guard("ssm.in_proj"):
+        uz = project(input, 2 * Di)
+    bound = L ** -0.5
+    taps = helper.create_parameter(
+        attr={}, shape=[Di, L], dtype=input.dtype,
+        default_initializer=UniformInitializer(-bound, bound))
+    conv_bias = helper.create_parameter(
+        attr=bias_attr if isinstance(bias_attr, dict) else {}, shape=[Di],
+        dtype=input.dtype, is_bias=True,
+        default_initializer=UniformInitializer(-bound, bound))
+    wide = tuple(input.shape[:2]) + (Di,)
+    u = helper.create_tmp_variable(input.dtype, shape=wide)
+    helper.append_op(
+        "causal_conv_silu",
+        inputs={"X": [uz.name], "Filter": [taps.name],
+                "Bias": [conv_bias.name]},
+        outputs={"Out": [u.name]}, attrs={"part": "ssm.conv"})
+    with prog.part_guard("ssm.xdt"):
+        rbc = project(u, R + 2 * N)
+        r = helper.create_tmp_variable(
+            input.dtype, shape=tuple(input.shape[:2]) + (R,))
+        helper.append_op("slice", inputs={"Input": [rbc.name]},
+                         outputs={"Out": [r.name]},
+                         attrs={"axes": [2], "starts": [0], "ends": [R]})
+        dt = project(r, Di)
+    dt_bias = helper.create_parameter(
+        attr={}, shape=[Di], dtype=input.dtype, is_bias=True,
+        default_initializer=_step_bias_draw())
+    a_log = helper.create_parameter(
+        attr={}, shape=[Di, N], dtype=input.dtype,
+        default_initializer=_RowsOf([math.log(n) for n in range(1, N + 1)]))
+    skip = helper.create_parameter(
+        attr=skip_attr if isinstance(skip_attr, dict) else {}, shape=[Di],
+        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    y = helper.create_tmp_variable(input.dtype, shape=wide)
+    helper.append_op(
+        "selective_scan",
+        inputs={"U": [u.name], "Dt": [dt.name], "XProj": [rbc.name],
+                "ALog": [a_log.name], "D": [skip.name],
+                "DtBias": [dt_bias.name]},
+        outputs={"Out": [y.name]},
+        attrs={"dt_rank": R})
+    if memory is not None:
+        memory.append(y)
+    with prog.part_guard("ssm.gate_out"):
+        gated = helper.create_tmp_variable(input.dtype, shape=wide)
+        helper.append_op("silu_gate",
+                         inputs={"X": [y.name], "Gate": [uz.name]},
+                         outputs={"Out": [gated.name]}, attrs={})
+        out = project(gated, dim)
+    from .sequence import propagate_length
+
+    return propagate_length(input, out)
+
+
+def gated_memory_unit(input, memory, param_attr=None, name=None):
+    """A gated memory unit over [B, T, D] (SambaY, arXiv:2507.06607): out =
+    W_out (m * SiLU(W_in x)), m [B, T, d_inner] the MEMORY an earlier
+    layer's `mamba` left (its scan's result before the gate), W_in [D,
+    d_inner], W_out [d_inner, D], no bias.  No token mixing of its own: the
+    memory carries it."""
+    helper = LayerHelper("gated_memory_unit", name=name)
+    Di = memory.shape[-1]
+    gate = fc(input, Di, num_flatten_dims=2, param_attr=param_attr,
+              bias_attr=False)
+    gated = helper.create_tmp_variable(
+        input.dtype, shape=tuple(input.shape[:2]) + (Di,))
+    helper.append_op("silu_gate",
+                     inputs={"X": [memory.name], "Gate": [gate.name]},
+                     outputs={"Out": [gated.name]}, attrs={})
+    from .sequence import propagate_length
+
+    return propagate_length(input, fc(
+        gated, input.shape[-1], num_flatten_dims=2, param_attr=param_attr,
+        bias_attr=False))
 
 
 def block_diffusion_noise(tokens, token_noise, block_noise, block_length,
@@ -699,6 +915,39 @@ class _UniformThrough(Initializer):
                         attrs={"out_dtype": var.dtype})
 
 
+def _step_bias_draw(low=1e-3, high=1e-1):
+    """A step size's bias as Mamba draws it: the x with softplus(x) = a
+    log-uniform draw on [low, high] (log(exp(exp(u)) - 1), u uniform on the
+    logs); `mamba`'s and `gated_delta_net`'s dt bias."""
+    import math
+
+    return _UniformThrough(
+        math.log(low), math.log(high),
+        [("exp", {}), ("exp", {}), ("scale", {"bias": -1.0}), ("log", {})])
+
+
+class _RowsOf(Initializer):
+    """Every row of a [rows, len(values)] parameter is `values` (float32,
+    then cast): `mamba`'s A_log, log(1 .. d_state) a channel."""
+
+    def __init__(self, values):
+        self.values = [float(v) for v in values]
+
+    def __call__(self, var, block):
+        row = block.create_var(shape=(1, len(self.values)), dtype="float32")
+        block.append_op(
+            "assign_value", outputs={"Out": [row.name]},
+            attrs={"shape": [1, len(self.values)],
+                   "fp32_values": self.values})
+        rows = block.create_var(shape=var.shape, dtype="float32")
+        block.append_op("expand", inputs={"X": [row.name]},
+                        outputs={"Out": [rows.name]},
+                        attrs={"expand_times": [int(var.shape[0]), 1]})
+        block.append_op("cast", inputs={"X": [rows.name]},
+                        outputs={"Out": [var.name]},
+                        attrs={"out_dtype": var.dtype})
+
+
 def gated_delta_net(input, key_heads, value_heads, key_dim, value_dim,
                     conv_kernel=4, epsilon=1e-6, param_attr=None, name=None):
     """A gated-DeltaNet mixer over [B, T, D] (Gated Delta Networks,
@@ -718,8 +967,6 @@ def gated_delta_net(input, key_heads, value_heads, key_dim, value_dim,
     rule, which the published layer follows), the output norm's gain [Dv]
     (one), W_out [Hv Dv, D]; no bias.  The decay a token is exp(-exp(A_log) *
     softplus(a + dt_bias))."""
-    import math
-
     helper = LayerHelper("gated_delta_net", name=name)
     Hk, Hv, Dk, Dv = (int(n) for n in (key_heads, value_heads, key_dim,
                                        value_dim))
@@ -742,10 +989,7 @@ def gated_delta_net(input, key_heads, value_heads, key_dim, value_dim,
         default_initializer=_UniformThrough(1.0, 16.0, [("log", {})]))
     dt_bias = helper.create_parameter(
         attr={}, shape=[Hv], dtype=input.dtype,
-        default_initializer=_UniformThrough(
-            math.log(1e-3), math.log(1e-1),
-            [("exp", {}), ("exp", {}), ("scale", {"bias": -1.0}),
-             ("log", {})]))
+        default_initializer=_step_bias_draw())
     gain = _rms_gain(helper, Dv, input.dtype)
     out = helper.create_tmp_variable(
         input.dtype, shape=tuple(input.shape[:2]) + (Hv * Dv,))

@@ -57,7 +57,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                emb_init_seed=0, hyper=None, mtp=None, sparse=None,
                linear=None, residual_scale=None, emb_scale=None,
                logit_scale=None, delta=None, attention_gate=False,
-               rotary_dim=None):
+               rotary_dim=None, ssm=None, differential=None, window=None,
+               attention_bias=False, tie_embeddings=False, norm_attr=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -90,7 +91,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     query latent) and "yarn" (`layers.latent_attention`; rotary by
     construction: `rope_theta`, no position table).
     `layer_types` gives the token mixer layer by layer, `n_layers` of
-    five kinds: 'attention' (the kind `attention` names; everywhere by
+    eight kinds: 'attention' (the kind `attention` names; everywhere by
     default); 'conv', a gated short convolution, `conv` = {"kernel_size"}
     (`layers.gated_short_conv`); 'sparse_attention', block-top-k sparse
     attention WITHOUT a position, `sparse` = {"n_heads", "n_kv_heads",
@@ -111,6 +112,31 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     short convolution, no position, `delta` = {"key_heads", "value_heads",
     "key_dim", "value_dim"} and optionally "conv_kernel"
     (`layers.gated_delta_net`).
+    'mamba', a selective state-space mixer, no position, `ssm` = {} or any
+    of "d_state", "d_conv", "expand", "dt_rank", "bias_attr",
+    "skip_attr" (`layers.mamba`; the dict gains "memory": every such
+    layer's scan output, in order); 'gmu', a gated memory unit on the MEMORY
+    (the scan's result before its gate) of the nearest 'mamba' layer before
+    it (`layers.gated_memory_unit`); 'cross_attention', the attention
+    kind's queries on the keys and values of the nearest 'attention' layer
+    before it, as that layer computed them (a query-only projection;
+    differential attention only).  So a block may read a tensor an EARLIER
+    block made: under `remat` it leaves that block's recompute segment as
+    one of its outputs and enters the later one as an external, and its
+    gradient is the sum over the layers that read it.
+    `differential` = {} or any of "layer_indices" (each layer's index in the
+    whole model, which lambda_init is made from: a tower that is a cut of a
+    deeper one names the published indices), "epsilon", "lambda_attr",
+    "gain_attr" makes every 'multi_head' layer differential attention
+    (`layers.multi_head_attention(differential=)`); the dict gains
+    "results" = {layer: its combined heads before the output projection}.
+    `window` gives the 'attention' layers a sliding window: one width for
+    all, or a list of `n_layers` entries (None: the whole sequence).
+    `attention_bias`: the attention projections have a
+    bias (True, or the attr of all of them).  `tie_embeddings`: the head is
+    the embedding itself (logits = h E^T, ONE parameter with the sum of two
+    gradients), no head matrix.  `norm_attr` = {"gain": attr, "bias": attr}
+    for the block norms' and the final norm's parameters.
     `attention_gate` gives the layers that attend by 'multi_head' an output
     gate from the query projection's second half, `rotary_dim` turns the
     first so many columns of their heads alone
@@ -154,8 +180,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     the program's `random_seed`; 0: the program's)."""
     if norm not in ("layer_norm", "rms_norm"):
         raise ValueError(f"norm {norm!r}: use 'layer_norm' or 'rms_norm'")
-    if positions not in ("learned", "rope"):
-        raise ValueError(f"positions {positions!r}: use 'learned' or 'rope'")
+    if positions not in ("learned", "rope", "none"):
+        raise ValueError(f"positions {positions!r}: use 'learned', 'rope' or "
+                         f"'none' (no layer sees a position)")
     if ffn not in ("mlp", "gated_mlp", "moe"):
         raise ValueError(f"ffn {ffn!r}: use 'mlp', 'gated_mlp' or 'moe'")
     if attention not in ("multi_head", "latent"):
@@ -198,13 +225,76 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 block_diffusion["mask_id"],
                 t_min=block_diffusion.get("t_min", 0.0)))
 
+    if isinstance(window, int):
+        window = [window] * len(layer_types)
+    if differential is None and "cross_attention" in layer_types:
+        raise ValueError("decoder_lm: a 'cross_attention' layer reuses the "
+                         "keys and values of a differential-attention layer")
+    norm_attr = norm_attr or {}
+
     def normed(x):
         if norm == "rms_norm":
             return layers.rms_norm(x, begin_norm_axis=2,
-                                   epsilon=norm_epsilon)
-        return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_epsilon)
+                                   epsilon=norm_epsilon,
+                                   param_attr=norm_attr.get("gain"))
+        return layers.layer_norm(x, begin_norm_axis=2, epsilon=norm_epsilon,
+                                 param_attr=norm_attr.get("gain"),
+                                 bias_attr=norm_attr.get("bias"))
+
+    # what a later block reads of an earlier one: the nearest 'mamba'
+    # layer's memory, the nearest 'attention' layer's (K, V1, V2)
+    ssm = {} if ssm is None else ssm
+    shared = {"memory": ssm.setdefault("memory", []), "kv": None}
+
+    def attend(h, layer, kv=None):
+        diff = None
+        if differential is not None:
+            diff = {k: v for k, v in differential.items()
+                    if k in ("epsilon", "lambda_attr", "gain_attr")}
+            diff["layer_index"] = differential.get(
+                "layer_indices", range(len(layer_types)))[layer]
+        out = layers.multi_head_attention(
+            h, h, h, num_heads=n_heads, causal=bd is None,
+            param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
+            sp_schedule=sp_schedule,
+            qk_norm_epsilon=norm_epsilon if qk_norm else None,
+            qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
+            rope_theta=rope_theta if positions == "rope" else None,
+            **({"head_dim": head_dim} if head_dim else {}),
+            **({"block_diffusion": bd} if bd else {}),
+            **({"output_gate": True} if attention_gate else {}),
+            **({"rotary_dim": rotary_dim} if rotary_dim else {}),
+            **({"window": window[layer]}
+               if window and window[layer] and kv is None else {}),
+            **({"bias": attention_bias} if attention_bias else {}),
+            **({"differential": diff, "kv": kv} if diff is not None else {}))
+        if diff is not None:
+            differential.setdefault("results", {})[layer] = diff["result"]
+            if kv is None:
+                shared["kv"] = diff["made"]
+        return out
 
     def mix(h, layer):
+        prog = default_main_program()
+        if layer_types[layer] == "mamba":
+            with prog.part_guard("mixer.mamba"):
+                return layers.mamba(
+                    h, param_attr=attr, memory=shared["memory"],
+                    **{k: v for k, v in ssm.items() if k != "memory"})
+        if layer_types[layer] == "gmu":
+            if not shared["memory"]:
+                raise ValueError(f"decoder_lm: layer {layer} is a 'gmu' and "
+                                 f"no 'mamba' layer lies before it")
+            with prog.part_guard("mixer.gmu"):
+                return layers.gated_memory_unit(h, shared["memory"][-1],
+                                                param_attr=attr)
+        if layer_types[layer] == "cross_attention":
+            if shared["kv"] is None:
+                raise ValueError(f"decoder_lm: layer {layer} is a "
+                                 f"'cross_attention' and no 'attention' "
+                                 f"layer lies before it")
+            with prog.part_guard("attn.cross"):
+                return attend(h, layer, kv=shared["kv"])
         if layer_types[layer] == "conv":
             return layers.gated_short_conv(h, param_attr=attr, **conv)
         if layer_types[layer] == "sparse_attention":
@@ -232,17 +322,11 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
             return layers.latent_attention(
                 h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
                 param_attr=attr, **mla)
-        return layers.multi_head_attention(
-            h, h, h, num_heads=n_heads, causal=bd is None,
-            param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
-            sp_schedule=sp_schedule,
-            qk_norm_epsilon=norm_epsilon if qk_norm else None,
-            qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
-            rope_theta=rope_theta if positions == "rope" else None,
-            **({"head_dim": head_dim} if head_dim else {}),
-            **({"block_diffusion": bd} if bd else {}),
-            **({"output_gate": True} if attention_gate else {}),
-            **({"rotary_dim": rotary_dim} if rotary_dim else {}))
+        if differential is None and not window:
+            return attend(h, layer)
+        with default_main_program().part_guard(
+                "attn.window" if window and window[layer] else "attn.full"):
+            return attend(h, layer)
 
     def feed_forward(h, layer):
         if ffn == "mlp":
@@ -274,7 +358,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         "initializer": NormalInitializer(scale=emb_init_scale,
                                          seed=emb_init_seed)}
     head_attr = attr
-    if mtp is not None:   # the module looks up and scores by the same two
+    if mtp is not None or tie_embeddings:
+        # the module looks up and scores by the same two; a tied head IS the
+        # embedding: one name, one parameter
         emb_attr, head_attr = (
             dict(a or {}, name=unique_name.generate("decoder_lm." + what))
             for a, what in ((emb_attr, "embedding"), (head_attr, "head")))
@@ -337,9 +423,18 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     if logit_scale is not None:
         h = layers.scale(h, scale=float(logit_scale))
     prog = default_main_program()
+
+    def head(h):
+        if not tie_embeddings:
+            return layers.fc(h, vocab_size, num_flatten_dims=2,
+                             param_attr=head_attr, bias_attr=False)
+        table = prog.global_block().var(emb_attr["name"])
+        out = layers.matmul(h, table, transpose_y=True)
+        out.shape = tuple(h.shape[:-1]) + (vocab_size,)
+        return out
+
     with prog.part_guard("lm.head"):
-        logits = layers.fc(h, vocab_size, num_flatten_dims=2,
-                           param_attr=head_attr, bias_attr=False)
+        logits = head(h)
     if mtp is None:
         return logits
     with prog.part_guard("mtp.project"):
@@ -355,14 +450,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     with prog.part_guard("mtp.head"):
         h1 = normed(h1)
         with prog.part_guard("lm.head"):
-            mtp["logits"] = layers.fc(h1, vocab_size, num_flatten_dims=2,
-                                      param_attr=head_attr, bias_attr=False)
+            mtp["logits"] = head(h1)
     return logits
 
 
 # the token mixers `decoder_lm`'s `layer_types` names
 _MIXERS = ("attention", "conv", "sparse_attention", "linear_attention",
-           "gated_delta_net")
+           "gated_delta_net", "mamba", "gmu", "cross_attention")
 
 # decoder_lm's arguments that change the block's parameters or equations,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
@@ -373,7 +467,9 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "block_diffusion": None, "hyper": None, "mtp": None,
                "sparse": None, "linear": None, "residual_scale": None,
                "emb_scale": None, "logit_scale": None, "delta": None,
-               "attention_gate": False, "rotary_dim": None}
+               "attention_gate": False, "rotary_dim": None, "ssm": None,
+               "differential": None, "window": None, "attention_bias": False,
+               "tie_embeddings": False, "norm_attr": None}
 
 
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
@@ -1256,4 +1352,100 @@ def build_qwen3_next_lm_train_program(
     # fetch to hold exactly: seq_len * top_k a sequence
     layers.reduce_sum(shares[-1].counts)
     opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def phi4flash_layer_kinds(total_layers: int, sliding_window: int,
+                          mb_per_layer: int = 2):
+    """The published layer rule of `phi4flash` (SambaY, arXiv:2507.06607;
+    `modeling_phi4flash.py`) -> (`decoder_lm`'s kind of every layer, every
+    layer's window or None).  Layer i has a Mamba-kind mixer where i %
+    `mb_per_layer` == 0 and an attention-kind mixer otherwise.  The first
+    half is the self-decoder: 'mamba', or 'attention' under the sliding
+    window.  Layer L / 2 is a 'mamba' whose scan output is the MEMORY, layer
+    L / 2 + 1 FULL attention whose keys and values are kept; from L / 2 + 2
+    on the cross-decoder: a 'gmu' on the memory where the self-decoder had a
+    Mamba, 'cross_attention' on the kept keys and values where it attended."""
+    L = int(total_layers)
+    if L % 4 or mb_per_layer != 2:
+        raise ValueError(f"phi4flash: {L} layers at mb_per_layer "
+                         f"{mb_per_layer}; the rule runs whole periods of 2 "
+                         f"in both halves")
+    kinds, windows = [], []
+    for i in range(L):
+        recurrent = i % mb_per_layer == 0
+        if i < L // 2 + 2:
+            kinds.append("mamba" if recurrent else "attention")
+        else:
+            kinds.append("gmu" if recurrent else "cross_attention")
+        windows.append(int(sliding_window)
+                       if kinds[-1] == "attention" and i < L // 2 else None)
+    return kinds, windows
+
+
+def build_phi4flash_lm_train_program(
+        seq_len, vocab_size, dim, layer_indices, total_layers, n_heads,
+        n_kv_heads, dense_dim, sliding_window, d_state=16, d_conv=4, expand=2,
+        dt_rank=None, mb_per_layer=2, norm_epsilon=1e-5,
+        gain_range=None, bias_range=None, remat=True, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02):
+    """Phi-4-mini-flash-shaped decoder (`model_type` phi4flash: the SambaY
+    decoder-hybrid-decoder of arXiv:2507.06607) over the layers
+    `layer_indices` of its `total_layers`, each of the kind the published
+    rule gives that index (`phi4flash_layer_kinds`): pre-norm LayerNorm
+    blocks (gain and bias) whose token mixer is a Mamba selective-scan layer
+    (`d_state`, `d_conv`, `expand`, `dt_rank`), differential attention
+    (`n_heads` query heads on `n_kv_heads` key/value heads of dim / n_heads,
+    projections with bias)
+    under a window of `sliding_window` keys or over the whole sequence, a
+    gated memory unit on the memory of the Mamba layer at total_layers / 2,
+    or differential cross-attention on the keys and values of the
+    full-attention layer after it; a SiLU-gated MLP of `dense_dim` without
+    bias in every block; no position anywhere; a final LayerNorm and the
+    TIED embedding as the head over `vocab_size` rows.  The held layers
+    must hold the layer that makes what a held layer reads.  `gain_range`
+    (lo, hi) draws the norms' gains and the Mamba layers' D uniformly, and
+    `bias_range` every bias (the norms', the attention projections', the
+    convolution's; dt's has its own draw), instead of their defaults one
+    and zero: a checked program must not pass without them.  `remat` wraps
+    each block in `layers.recompute`.  Loss: next-token cross entropy;
+    Adam.  Returns the loss.  Feeds as `build_lm_train_program`.  The
+    program's last `assign` is the last windowed layer's attention result
+    [B, T, dim] (the combined heads before the output projection) and its
+    last `scale` (by one) the memory [B, T, expand x dim] of the last Mamba
+    layer: under `remat` both are made inside a segment's block, and a
+    fetch by op type looks in the program's own."""
+    from .. import optimizer as opt
+    from ..framework.initializer import UniformInitializer
+
+    kinds, windows = phi4flash_layer_kinds(total_layers, sliding_window,
+                                           mb_per_layer)
+    held = [int(i) for i in layer_indices]
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    gains = ({"initializer": UniformInitializer(*gain_range)}
+             if gain_range else None)
+    biases = ({"initializer": UniformInitializer(*bias_range)}
+              if bias_range else None)
+    diff = {"layer_indices": held, "epsilon": norm_epsilon,
+            "gain_attr": gains}
+    ssm = {"d_state": d_state, "d_conv": d_conv, "expand": expand,
+           "dt_rank": dt_rank, "bias_attr": biases,
+           "skip_attr": gains}
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(held), n_heads, max_len=seq_len,
+        dtype=dtype, remat=remat, norm="layer_norm",
+        norm_epsilon=norm_epsilon, positions="none", n_kv_heads=n_kv_heads,
+        layer_types=[kinds[i] for i in held],
+        window=[windows[i] for i in held], differential=diff,
+        attention_bias=biases or True, ssm=ssm,
+        ffn="gated_mlp", dense_dim=dense_dim, tie_embeddings=True,
+        norm_attr={"gain": gains, "bias": biases}, init_scale=init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    windowed = [n for n, i in enumerate(held) if windows[i]]
+    if windowed:
+        layers.assign(diff["results"][windowed[-1]])
+    if ssm["memory"]:
+        layers.scale(ssm["memory"][-1], scale=1.0)
     return loss

@@ -19,6 +19,7 @@ from . import ctc_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import llm_ops  # noqa: F401
 from . import sparse_linear_ops  # noqa: F401
+from . import ssm_ops  # noqa: F401
 from . import transformer_ops  # noqa: F401
 from . import pallas_kernels  # noqa: F401
 from . import optimizer_ops  # noqa: F401

@@ -32,8 +32,20 @@ _MET_ATTN_LAYERS = _MET.counter(
     "Q [B,H,T,D]; bthd: Q [B,T,H*D], as the projections leave it) and the "
     "path the emitter took (flash_packed: the Pallas kernels on [B,T,H*D] "
     "as it lies; flash: the kernels on [B,H,T,D]; flash_block_diffusion: "
-    "those under the block-diffusion mask; dense: XLA's fused softmax; "
+    "those under the block-diffusion mask; flash_window: under a sliding "
+    "window; dense: XLA's fused softmax; "
     "ring, alltoall: sequence parallel)")
+_MET_FLASH_CALLS = _MET.counter(
+    "flash_calls_total",
+    "calls of the flash kernels the op scaled_dot_product_attention made on "
+    "one TPU (forward emission; once a compile, not once a step), by the "
+    "mask the call runs under (causal; window: a sliding window's region; "
+    "block_diffusion; none)")
+_MET_DIFF_LAYERS = _MET.counter(
+    "differential_attention_layers_traced_total",
+    "differential-attention combinations traced (forward emission; once a "
+    "compile, not once a step), by the head pairs, the head width and the "
+    "layer's lambda_init")
 _MET_BD_LAYERS = _MET.counter(
     "block_diffusion_layers_traced_total",
     "scaled_dot_product_attention ops traced under the block-diffusion mask "
@@ -138,15 +150,36 @@ def block_diffusion_allowed(seq_len: int, block_length: int):
             | (~r_noisy & ~c_noisy & (c_block <= r_block)))
 
 
+def window_allowed(T: int, window: int):
+    """Allowed(t, j) [T, T] of a sliding window: token t sees key j iff 0
+    <= t - j < window.  What the dense path applies, and what the flash
+    kernels' region (flash_attention.sliding_window_mask) is tested
+    against."""
+    import jax.numpy as jnp
+
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    return (ahead >= 0) & (ahead < int(window))
+
+
 def _mask_attrs(attrs, T: int):
     """(seq_len, block_length) of an op whose `mask` attr is
-    'block_diffusion', else None ('' and 'none': no mask of its own)."""
+    'block_diffusion', ("window", w) of one whose mask is 'window' (attr
+    `window`; None where the window holds the whole sequence: the op is
+    then plainly causal), else None ('' and 'none': no mask of its own)."""
     kind = str(attrs.get("mask", "") or "none")
     if kind == "none":
         return None
+    if kind == "window":
+        w = int(attrs["window"])
+        if w < 1 or not bool(attrs.get("causal", False)):
+            raise ValueError(
+                f"scaled_dot_product_attention: a sliding window is causal "
+                f"and holds at least the token itself; got window {w}, "
+                f"causal {attrs.get('causal', False)}")
+        return ("window", w) if w < T else None
     if kind != "block_diffusion":
         raise ValueError(f"scaled_dot_product_attention: mask {kind!r}: "
-                         f"use 'block_diffusion'")
+                         f"use 'block_diffusion' or 'window'")
     L, b = int(attrs["seq_len"]), int(attrs["block_length"])
     if bool(attrs.get("causal", False)) or T != 2 * L or b < 1 or L % b:
         raise ValueError(
@@ -195,9 +228,13 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     keeps the XLA-fused dense path (GSPMD cannot partition the Mosaic
     call).  `mask`: (seq_len, block_length) of the block-diffusion mask,
     inside the same kernels, where blocks of 128 divide seq_len in whole
-    treads.  `scale`: the softmax scale where it is not 1 / sqrt(D) (YaRN's
-    temperature in latent attention), a positive float the three kernels
-    take as theirs.  -> None where it does not apply, else (out, saved).
+    treads; or ("window", w), a sliding window of w keys that ends with the
+    token (`causal` is then what the window is, and is not handed on: the
+    kernels' region is the staircase cut w columns back, and K blocks wholly
+    before a q block's windows are neither fetched nor computed).  `scale`:
+    the softmax scale where it is not 1 / sqrt(D) (YaRN's temperature in
+    latent attention), a positive float the three kernels take as theirs.
+    -> None where it does not apply, else (out, saved).
 
     Training goes through the custom_vjp pair (FlashAttention-2-style
     blockwise backward), which generic_grad's jax.vjp honors, and the
@@ -218,7 +255,8 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
         fits = (T % 128 == 0 and k.shape == q.shape and v.shape == q.shape
                 and q.shape[2] in (64 * heads, 128 * heads)
                 and q.shape[2] % 128 == 0)
-    if mask is not None:
+    window = mask is not None and mask[0] == "window"
+    if mask is not None and not window:
         fits = fits and mask[0] % 128 == 0 and 128 % mask[1] == 0
     if not fits:
         if T >= 4096:
@@ -240,8 +278,10 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     if scale is not None:
         layout["scale"] = float(scale)
     if mask is not None:
-        layout.update(mask=fa.block_diffusion_mask(*mask),
+        layout.update(mask=(fa.sliding_window_mask(T, mask[1]) if window
+                            else fa.block_diffusion_mask(*mask)),
                       block_q=fa.MASK_BLOCKS[0], block_k=fa.MASK_BLOCKS[1])
+        causal = causal and not window
     if ctx.is_test:
         return fa.flash_attention(q, k, v, causal=causal, **layout), None
     train = fa.make_flash_train(causal=causal, **layout)
@@ -283,7 +323,9 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     b: the T = 2L rows are the noised and the clean copy of L tokens and
     attend under `block_diffusion_allowed` (`causal` stays false): inside
     the flash kernels on one TPU, dense under the same Allowed everywhere
-    else, a mesh included."""
+    else, a mesh included.  `mask` = "window" with `window` w (and `causal`
+    true): token t sees key j iff 0 <= t - j < w (`window_allowed`), the
+    same two ways; a window that holds the sequence is plainly causal."""
     import jax.numpy as jnp
 
     from ..parallel import ring_attention as ra
@@ -294,7 +336,9 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     if layout not in ("bhtd", "bthd"):
         raise ValueError(f"layout {layout!r}: use 'bhtd' or 'bthd'")
     causal = bool(attrs.get("causal", False))
-    bd = _mask_attrs(attrs, q.shape[1 if layout == "bthd" else 2])
+    T = q.shape[1 if layout == "bthd" else 2]
+    bd = _mask_attrs(attrs, T)
+    windowed = bd is not None and bd[0] == "window"
     sp_mode = str(attrs.get("sp_mode", "ring"))
     mesh = getattr(ctx, "mesh", None)
     sp = bd is None and mesh is not None and axis_size(mesh, "sp") > 1
@@ -302,6 +346,10 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     def traced(path):
         if not ctx.in_grad_replay():
             _MET_ATTN_LAYERS.inc(layout=layout, path=path)
+            if path.startswith("flash"):
+                _MET_FLASH_CALLS.inc(mask=(
+                    "window" if windowed else "block_diffusion"
+                    if bd is not None else "causal" if causal else "none"))
 
     if layout == "bthd":
         heads = int(attrs["num_heads"])
@@ -330,7 +378,7 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         _MET_GQA_LAYERS.inc(q_heads=str(q.shape[1]),
                             kv_heads=str(k.shape[1]),
                             head_dim=str(q.shape[3]))
-    if bd is not None and not ctx.in_grad_replay():
+    if bd is not None and not windowed and not ctx.in_grad_replay():
         _MET_BD_LAYERS.inc(seq_len=str(bd[0]), block_length=str(bd[1]),
                            q_heads=str(q.shape[1]), kv_heads=str(k.shape[1]),
                            head_dim=str(q.shape[3]))
@@ -376,10 +424,12 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                 out, saved = got
             else:
                 out = ra.attention(
-                    q, repeated(k), repeated(v), causal=causal,
-                    allowed=bd and block_diffusion_allowed(*bd))
-        traced("dense" if got is None else
-               "flash" if bd is None else "flash_block_diffusion")
+                    q, repeated(k), repeated(v),
+                    causal=causal and not windowed,
+                    allowed=bd and (window_allowed(T, bd[1]) if windowed
+                                    else block_diffusion_allowed(*bd)))
+        traced("dense" if got is None else "flash" if bd is None else
+               "flash_window" if windowed else "flash_block_diffusion")
     if layout == "bthd":  # [B, H, T, Dv] -> [B, T, H * Dv]
         out = out.transpose(0, 2, 1, 3).reshape(
             out.shape[0], out.shape[2], -1)
@@ -417,6 +467,100 @@ def attention_output_gate(ctx, ins, attrs):
     wide = wide_dtype(x.dtype)
     out = x.astype(wide) * jax.nn.sigmoid(gate.astype(wide))
     return {"Out": [out.astype(x.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# Differential attention (arXiv:2410.05258, as Phi-4-mini-flash's attention
+# class computes it): heads in PAIRS, "(H two)": pair p is heads 2p (q1, k1,
+# v1) and 2p + 1 (q2, k2, v2); with P1 = softmax(q1 k1^T), P2 = softmax(q2
+# k2^T) and V = [v1 | v2], a pair's result is P1 V - lambda P2 V.  The flash
+# kernels take values no wider than keys, so the four products come from TWO
+# calls of all the query heads, in the order (q1 of every pair, then q2 of
+# every pair) on keys in the same order, once with (v1; v1) and once with
+# (v2; v2) as the values: every score is computed twice (the published code's
+# four calls do the same); `flash_score_elements_total` shows the doubling.
+
+
+def _pair_major(x, heads: int):
+    """[B, T, heads * D] with heads in pairs (2p, 2p + 1) -> [2, B, heads /
+    2, T, D]: the first of every pair, then the second."""
+    B, T, width = x.shape
+    return x.reshape(B, T, heads // 2, 2, width // heads).transpose(
+        3, 0, 2, 1, 4)
+
+
+@register_op("diff_attn_split")
+def diff_attn_split(ctx, ins, attrs):
+    """The heads of a differential-attention layer as its two flash calls
+    read them.  X [B, T, (Hq + 2 Hkv) * D] = [q | k | v] as ONE projection
+    leaves it (attrs `num_heads` Hq, `num_kv_heads` Hkv, both even, and
+    `head_dim` D) ->
+    Q [B, Hq, T, D] (q1 of the Hq / 2 pairs, then q2), K [B, Hkv, T, D]
+    (k1, then k2: query head h of Q reads key head h // (Hq / Hkv), its own
+    pair's half), V1 and V2 [B, Hkv, T, D] ((v1; v1) and (v2; v2)).  Where X
+    is [B, T, Hq * D] (a cross layer's query-only projection: its keys and
+    values come from the layer that made them) Q alone."""
+    import jax.numpy as jnp
+
+    x = ins["X"][0]
+    Hq, Hkv = int(attrs["num_heads"]), int(attrs["num_kv_heads"])
+    B, T, width = x.shape
+    if Hq % 2 or Hkv % 2 or (Hq // 2) % (Hkv // 2):
+        raise ValueError(f"diff_attn_split: {Hq} query heads on {Hkv} "
+                         f"key/value heads do not pair")
+    D = int(attrs["head_dim"])
+    if width not in (Hq * D, (Hq + 2 * Hkv) * D):
+        raise ValueError(f"diff_attn_split: X {x.shape} at {Hq} query and "
+                         f"{Hkv} key/value heads of {D}")
+
+    def joined(a):    # [2, B, h, T, D] -> [B, 2 h, T, D]
+        return jnp.concatenate([a[0], a[1]], axis=1)
+
+    q = joined(_pair_major(x[..., :Hq * D], Hq))
+    if width == Hq * D:
+        return {"Q": [q]}
+    k = joined(_pair_major(x[..., Hq * D:(Hq + Hkv) * D], Hkv))
+    v = _pair_major(x[..., (Hq + Hkv) * D:], Hkv)
+    return {"Q": [q], "K": [k],
+            "V1": [jnp.concatenate([v[0], v[0]], axis=1)],
+            "V2": [jnp.concatenate([v[1], v[1]], axis=1)]}
+
+
+@register_op("diff_attn_combine")
+def diff_attn_combine(ctx, ins, attrs):
+    """What differential attention does with its two softmax maps.  O1, O2
+    [B, H, T, D]: the two flash calls' results, heads (pair's first, then
+    pair's second) as `diff_attn_split` lays them: O1 = (P1 v1; P2 v1), O2 =
+    (P1 v2; P2 v2).  LambdaQ1, LambdaK1, LambdaQ2, LambdaK2 [D], Gain [2 D];
+    attrs `lambda_init`, `epsilon`.
+
+      lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init      (float32)
+      a = [P1 v1 - lambda P2 v1 | P1 v2 - lambda P2 v2]           [.., 2 D]
+      a = RMSNorm_{2 D}(a) * Gain * (1 - lambda_init)
+
+    -> Out [B, T, H * D]: pair p's 2 D columns laid back as heads 2p and
+    2p + 1, as the output projection reads them."""
+    import jax.numpy as jnp
+
+    from .llm_ops import rms, wide_dtype
+
+    o1, o2 = ins["O1"][0], ins["O2"][0]
+    B, H, T, D = o1.shape
+    init = float(attrs["lambda_init"])
+    eps = float(attrs.get("epsilon", 1e-5))
+    if not ctx.in_grad_replay():
+        _MET_DIFF_LAYERS.inc(pairs=str(H // 2), head_dim=str(D),
+                             lambda_init=f"{init:.4f}")
+    wide = wide_dtype(o1.dtype)
+    lq1, lk1, lq2, lk2 = (ins[s][0].astype(wide) for s in (
+        "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"))
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init
+    o1, o2 = o1.astype(wide), o2.astype(wide)
+    a = jnp.concatenate([o1[:, :H // 2] - lam * o1[:, H // 2:],
+                         o2[:, :H // 2] - lam * o2[:, H // 2:]], axis=-1)
+    a = rms(a, eps, (3,), ins["Gain"][0].astype(wide)) * (1.0 - init)
+    out = a.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+    return {"Out": [out.astype(ins["O1"][0].dtype)]}
 
 
 # ---------------------------------------------------------------------------

@@ -429,7 +429,9 @@ class _Stairs(NamedTuple):
     `rows` = [lo, hi) against the K columns `cols`, both in whole blocks.
     Row r, counted from rows[0], sees the columns c, counted from cols[0],
     up to (r // step) * step + reach: a staircase of `step` rows a tread;
-    from column 0 on, or, `band`, from (r // step) * step on.  What no
+    from column 0 on, or, `band`, from (r // step) * step on, or, with a
+    `window` of w columns, the w columns that end there (`low`: where a
+    row's columns begin, counted from its tread's first).  What no
     region of a mask (a tuple of these) holds is dead: neither fetched nor
     computed.  The schedule (_schedule), the walk of a block (_run_block),
     the clamps of the index maps (_kv_idx, _dkv_q_maps) and the mask
@@ -440,6 +442,16 @@ class _Stairs(NamedTuple):
     step: int = 1
     reach: int = 0
     band: bool = False
+    window: int = 0
+
+    @property
+    def low(self):
+        """The first column a row sees, counted from its tread's first: 0
+        in a band, `reach` less a window's other columns; None where a row
+        sees from the region's first column on."""
+        if self.band:
+            return 0
+        return self.reach - (self.window - 1) if self.window else None
 
 
 def causal_mask(T: int) -> tuple:
@@ -462,6 +474,17 @@ def block_diffusion_mask(seq_len: int, block_length: int) -> tuple:
     return (_Stairs((0, L), (0, L), b, b - 1, True),
             _Stairs((0, L), (L, 2 * L), b, -1),
             _Stairs((L, 2 * L), (L, 2 * L), b, b - 1))
+
+
+def sliding_window_mask(T: int, window: int) -> tuple:
+    """Position r sees the `window` positions that end with r (key j iff 0
+    <= r - j < window): one region, the whole square, the causal staircase
+    cut `window` columns back.  T window - window (window - 1) / 2 live
+    scores of T^2; a K block wholly before a q block's windows is dead."""
+    T, window = int(T), int(window)
+    if not 0 < window <= T:
+        raise ValueError(f"sliding window: {window} of {T} positions")
+    return (_Stairs((0, T), (0, T), window=window),)
 
 
 # The blocks a call under a mask of several regions asks for where its
@@ -550,12 +573,16 @@ def _live_k_block(mask, bq: int, bk: int, nk: int):
     nothing live in that region and fetches the block before it: one DMA
     too many, and nothing wrong, for _run_block skips by the step's own
     place.)"""
+    import jax.numpy as jnp
+
     def of_region(s, i, j):
         # the last row of q block i sees up to column `hi` of the square,
         # the first one, in a band, from `lo` on
         off = s.cols[0] - s.rows[0]
         hi = _moved((i + 1) * bq - (s.step - s.reach), off) // bk
         lo = _moved(i * bq, off) // bk if s.band else s.cols[0] // bk
+        if s.window:    # the first row's window begins `low` before it
+            lo = jnp.maximum(_moved(i * bq, off + s.low), s.cols[0]) // bk
         return _clamp(j, lo, hi, 0, nk - 1)
 
     def idx(i, j):
@@ -572,6 +599,8 @@ def _live_q_block(mask, bq: int, bk: int, nq: int):
     kernel: _live_k_block's twin along the other axis.  Under causal
     masking max(i, the first q block that attends K block j) (skip-early:
     a skipped step re-fetches a block already buffered)."""
+    import jax.numpy as jnp
+
     def of_region(s, j, i):
         # the first row of the square that sees K block j's first column
         # (a tread later where a row's own tread is hidden from it), and
@@ -580,6 +609,9 @@ def _live_q_block(mask, bq: int, bk: int, nq: int):
         lo = _moved(j * bk, off + (s.step if s.reach < 0 else 0)) // bq
         hi = (_moved((j + 1) * bk - 1, off) // bq if s.band
               else s.rows[1] // bq - 1)
+        if s.window:    # the last row whose window still holds its last
+            hi = jnp.minimum(_moved((j + 1) * bk - 1, off - s.low),
+                             s.rows[1] - 1) // bq
         return _clamp(i, lo, hi, 0, nq - 1)
 
     def idx(j, i):
@@ -683,7 +715,7 @@ def _stair_strips(d: int, bq: int, bk: int, sq: int, stairs) -> tuple:
     sees, both moved out to the lane grid where the blocks lie on it (a
     reach of -1 would end a strip at 124 columns); `tread` the _Tread
     that masks it, None where every row sees all of it."""
-    step, reach = stairs.step, stairs.reach
+    step, reach, low = stairs.step, stairs.reach, stairs.low
     if sq % step and step % sq:
         raise ValueError(f"flash attention: strips of {sq} rows and treads "
                          f"of {step} do not nest; use the dense path")
@@ -692,13 +724,14 @@ def _stair_strips(d: int, bq: int, bk: int, sq: int, stairs) -> tuple:
     for r0 in range(0, bq, sq):
         first = d + r0 // step * step          # the first row's tread
         last = d + (r0 + sq - 1) // step * step
-        lo = max(first, 0) if stairs.band else 0
+        lo = 0 if low is None else max(first + low, 0)
         hi = min(last + reach, bk - 1)
         if hi < 0 or lo > bk - 1:
             continue
         c0 = lo // grid * grid
         end = min(-(-(hi + 1) // grid) * grid, bk)
-        clear = first + reach >= end - 1 and not (stairs.band and last > c0)
+        clear = first + reach >= end - 1 and not (
+            low is not None and last + low > c0)
         tread = None
         if not clear:
             if r0 % step:  # a strip inside one tread is never crossed
@@ -706,7 +739,7 @@ def _stair_strips(d: int, bq: int, bk: int, sq: int, stairs) -> tuple:
                     f"flash attention: a strip of {sq} rows at {r0} off "
                     f"the lane grid inside a tread of {step}")
             tread = _Tread(d + r0 + reach - c0, step,
-                           reach if stairs.band else None)
+                           None if low is None else reach - low)
         out.append((r0, c0, end - c0, tread))
     return tuple(out)
 
@@ -767,7 +800,7 @@ def _schedule(T: int, bq: int, bk: int, sq: int, mask=None) -> _Plan:
         for q0 in range(s.rows[0], s.rows[1], bq):
             for k0 in range(s.cols[0], s.cols[1], bk):
                 d = (q0 - s.rows[0]) - (k0 - s.cols[0])
-                if not s.band and d + s.reach >= bk - 1:
+                if s.low is None and d + s.reach >= bk - 1:
                     full = d if full is None else min(full, d)
                     computed += bq * bk
                     continue

@@ -135,11 +135,15 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # PR 48: +2 (gated_delta_rule: Qwen3-Next's gated-DeltaNet core;
     # attention_output_gate: its gated attention's sigmoid gate), each
     # numerically checked in test_qwen3_next.py
-    assert len(diffable) == 161, (
+    # PR 52: +5 (selective_scan, causal_conv_silu, silu_gate: Phi-4-mini-
+    # flash's Mamba mixer and its gated memory unit; diff_attn_split,
+    # diff_attn_combine: its differential attention around the two flash
+    # calls), each numerically checked in test_phi4flash.py
+    assert len(diffable) == 166, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 161 - 11
+    assert len(checked) == 166 - 11
 
 
 import pytest  # noqa: E402

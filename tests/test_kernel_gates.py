@@ -44,6 +44,9 @@ MECHANISMS = {
     # plain emission there, by design
     "qwen3-next-80b-a3b": {"flash", "grouped_matmul", "segment_sum",
                            "gated_delta"},
+    # four attention layers of two flash calls each, two of them under the
+    # sliding window's region; the selective scan is plain XLA
+    "phi4-mini-flash": {"flash", "flash_window"},
 }
 
 
@@ -68,9 +71,11 @@ def _flash_gate(T, D, mask=None):
                                  defaults["block_k"].default, T,
                                  causal_head=D)
     else:
-        bq, bk = fa._snap_blocks(
-            *fa.MASK_BLOCKS, T,
-            unit=fa._mask_unit(fa.block_diffusion_mask(*mask), T))
+        regions = (fa.sliding_window_mask(T, mask[1]) if mask[0] == "window"
+                   else fa.block_diffusion_mask(*mask))
+        bq, bk = fa._snap_blocks(*fa.MASK_BLOCKS, T,
+                                 unit=fa._mask_unit(regions, T))
+        fa._check_mask(regions, T, bq, bk)
     return all(b % 128 == 0 and T % b == 0 for b in (bq, bk))
 
 
@@ -99,10 +104,13 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
             packed = op.attrs.get("layout") == "bthd"
             T = q[1] if packed else q[2]
             D = q[2] // op.attrs["num_heads"] if packed else q[3]
-            mask = (op.attrs.get("mask") and
-                    (op.attrs["seq_len"], op.attrs["block_length"]))
+            mask = op.attrs.get("mask") and (
+                ("window", op.attrs["window"])
+                if op.attrs["mask"] == "window"
+                else (op.attrs["seq_len"], op.attrs["block_length"]))
             assert _flash_gate(T, D, mask or None), (op.type, q)
-            passed.add("flash")
+            passed.add("flash_window" if mask and mask[0] == "window"
+                       else "flash")
             positions = max(positions, T)
         elif op.type == "latent_attention":
             T = shape(op, "X")[1]

@@ -459,7 +459,7 @@ def test_decoder_lm_refuses_unknown_mixers_and_norm_forms():
     fluid.reset()
     tokens = fluid.layers.data("tokens", shape=[8, 1], dtype="int64")
     for bad in ({"layer_types": ["conv"]},                 # 2 layers, 1 kind
-                {"layer_types": ["conv", "mamba"]},
+                {"layer_types": ["conv", "fourier"]},
                 {"qk_norm": "rows"}):
         with pytest.raises(ValueError, match="use "):
             tr.decoder_lm(tokens, 16, 8, 2, 2, max_len=8, **bad)

@@ -1,0 +1,459 @@
+"""Phi-4-mini-flash's mechanisms (PR 52), on the CPU in float32 at toy
+widths: the selective scan against a per-token loop and the reference
+file's recurrence, its two elementwise neighbours, the sliding window in the
+three flash kernels (interpret mode) and its schedule, differential
+attention's two ops, the published layer rule and the parameter count at
+published sizes.  tests/test_phi4flash_model.py holds the layers and the
+whole model to the reference file.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import observability as obs
+from paddle_tpu.models import transformer
+from paddle_tpu.ops import attention_ops, ssm_ops
+from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+from op_test import OpTestHarness
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402
+
+CONFIG = "phi4-mini-flash"
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _ref():
+    return harness.load_module("reference", CONFIG)
+
+
+def _r(*shape, lo=-1.0, hi=1.0, seed=0):
+    return np.random.RandomState(seed).uniform(lo, hi, shape)
+
+
+def _silu(x):
+    return x / (1 + np.exp(-x))
+
+
+# ---------------------------------------------------------------------------
+# the selective scan and the two passes beside it
+
+
+def _scan_case(T, Di=6, N=3, R=2, seed=0):
+    ins = {"U": _r(2, T, Di, seed=seed), "Dt": _r(2, T, Di, seed=seed + 1),
+           "XProj": _r(2, T, R + 2 * N, seed=seed + 2),
+           "ALog": _r(Di, N, lo=0.0, hi=1.5, seed=seed + 3),
+           "D": _r(Di, lo=0.5, hi=1.5, seed=seed + 4),
+           "DtBias": _r(Di, lo=-2.0, hi=0.0, seed=seed + 5)}
+    return ins, {"dt_rank": R}
+
+
+@pytest.fixture
+def chunks_of_four(monkeypatch):
+    """The op's constant chunk at a toy size, so that a toy sequence is
+    several chunks (it is read where the op is traced)."""
+    monkeypatch.setattr(ssm_ops, "SCAN_CHUNK", 4)
+
+
+def _scan_numpy(ins, attrs):
+    """The op from its docstring, token by token."""
+    u, dt, xp = ins["U"], ins["Dt"], ins["XProj"]
+    R = attrs["dt_rank"]
+    B, T, Di = u.shape
+    N = ins["ALog"].shape[1]
+    delta = np.log1p(np.exp(dt + ins["DtBias"]))
+    a = -np.exp(ins["ALog"])
+    out = np.zeros_like(u)
+    for b in range(B):
+        h = np.zeros((Di, N))
+        for t in range(T):
+            h = (np.exp(delta[b, t][:, None] * a) * h
+                 + (delta[b, t] * u[b, t])[:, None]
+                 * xp[b, t, R:R + N][None, :])
+            out[b, t] = h @ xp[b, t, R + N:] + ins["D"] * u[b, t]
+    return out
+
+
+@pytest.mark.parametrize("T", [12, 20])
+def test_selective_scan_output_and_grad(T, chunks_of_four):
+    """Three and five chunks of four tokens: the op against the per-token
+    loop (the state carried chunk to chunk, Delta's softplus with its bias,
+    B and C from XProj's columns, the D term), and every input's gradient,
+    through the chunks' `jax.checkpoint`, against central differences."""
+    ins, attrs = _scan_case(T)
+    h = OpTestHarness("selective_scan", ins, attrs)
+    h.check_output({"Out": _scan_numpy(ins, attrs)}, atol=1e-6)
+    h.check_grad(sorted(ins), max_relative_error=1e-2)
+
+
+def test_selective_scan_is_the_reference_recurrence_and_refuses(
+        chunks_of_four):
+    """The emission against the REFERENCE file's per-token scan on the same
+    numbers; chunks that do not divide the sequence and shapes that do not
+    add up are refused; the counter names the emission."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(3)
+    T, Di, N = 24, 5, 4
+    u, delta = rng.randn(1, T, Di), np.abs(rng.randn(1, T, Di)) * 0.3
+    a = -np.exp(rng.rand(N, Di))
+    b, c = rng.randn(1, T, N), rng.randn(1, T, N)
+    got = ssm_ops.selective_scan_chunked(*(jnp.asarray(x) for x in (
+        u, delta, a, b, c)), chunk=8)
+    want = _ref().selective_scan(*(jnp.asarray(x, jnp.float32) for x in (
+        u[0], delta[0], a.T, b[0], c[0])))
+    np.testing.assert_allclose(got[0], want, rtol=2e-5, atol=2e-6)
+    with pytest.raises(ValueError, match="do not divide"):
+        ssm_ops.selective_scan_chunked(*(jnp.asarray(x) for x in (
+            u, delta, a, b, c)), chunk=7)
+    ins, attrs = _scan_case(8)
+    ins["XProj"] = ins["XProj"][..., :-1]
+    with pytest.raises(Exception, match="selective_scan: U"):
+        OpTestHarness("selective_scan", ins, attrs).fetch()
+    obs.REGISTRY.reset()
+    OpTestHarness("selective_scan", *_scan_case(8)).fetch()
+    series = obs.REGISTRY.snapshot()["families"]["selective_scan_total"][
+        "series"]
+    assert [s["labels"] for s in series] == [
+        {"impl": "xla_chunked", "d_inner": "6", "d_state": "3",
+         "chunk": "4"}] and series[0]["value"] >= 1.0
+    obs.REGISTRY.reset()
+
+
+def test_causal_conv_silu_output_and_grad():
+    """torch's Conv1d tap order (the last tap on the current token), zeros
+    before the sequence, the bias inside the SiLU; X's further columns (the
+    gate z) are not read."""
+    x, w, b = _r(2, 7, 10, seed=1), _r(6, 4, seed=2), _r(6, seed=3)
+    T, L = 7, 4
+    padded = np.concatenate([np.zeros((2, L - 1, 6)), x[..., :6]], axis=1)
+    want = _silu(b + sum(w[:, j] * padded[:, j:j + T] for j in range(L)))
+    h = OpTestHarness("causal_conv_silu", {"X": x, "Filter": w, "Bias": b})
+    h.check_output({"Out": want}, atol=1e-6)
+    h.check_grad(["X", "Filter", "Bias"], max_relative_error=1e-2)
+
+
+def test_silu_gate_output_and_grad():
+    """The gate is the LAST columns of Gate: z of a Mamba's [u' | z]."""
+    x, gate = _r(2, 5, 6, seed=1), _r(2, 5, 12, lo=-3, hi=3, seed=2)
+    h = OpTestHarness("silu_gate", {"X": x, "Gate": gate})
+    h.check_output({"Out": x * _silu(gate[..., 6:])}, atol=1e-6)
+    h.check_grad(["X", "Gate"], max_relative_error=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# the sliding window in the flash kernels
+
+
+def _window(T, w):
+    t = np.arange(T)
+    return (t[:, None] - t[None, :] >= 0) & (t[:, None] - t[None, :] < w)
+
+
+def _dense(q, k, v, allowed):
+    import jax
+    import jax.numpy as jnp
+
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(a, group, 1) for a in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / q.shape[-1] ** 0.5
+    p = jax.nn.softmax(jnp.where(jnp.asarray(allowed), s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+WINDOW_CASES = {   # T, window, block_q, block_k, query heads a K/V head
+    "window_under_a_block": (96, 20, 32, 32, 1),
+    "window_of_a_block_and_groups": (128, 32, 32, 64, 2),
+    "window_over_a_block": (128, 48, 64, 32, 1),
+    "window_off_the_strips": (160, 33, 32, 32, 2),
+}
+SLOW_CASES = ("window_under_a_block", "window_over_a_block")
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(c, marks=pytest.mark.slow) if c in SLOW_CASES else c
+    for c in WINDOW_CASES])
+def test_flash_window_matches_dense_masked_attention(case):
+    """Forward, dq and dkv under the sliding window against dense attention
+    under `window_allowed`, T over two windows: a window under, of and over
+    a block, one that ends off the strips, and grouped heads (dkv adds the
+    group's heads into one dK, dV)."""
+    import jax
+    import jax.numpy as jnp
+
+    T, w, bq, bk, group = WINDOW_CASES[case]
+    allowed = _window(T, w)
+    np.testing.assert_array_equal(
+        np.asarray(attention_ops.window_allowed(T, w)), allowed)
+    with jax.enable_x64(False):
+        q = jnp.asarray(_r(1, 2 * group, T, 16, seed=1), jnp.float32)
+        k = jnp.asarray(_r(1, 2, T, 16, seed=2), jnp.float32)
+        v = jnp.asarray(_r(1, 2, T, 16, seed=3), jnp.float32)
+        do = jnp.asarray(_r(1, 2 * group, T, 16, seed=4), jnp.float32)
+        kw = dict(mask=fa.sliding_window_mask(T, w), interpret=True,
+                  block_q=bq, block_k=bk)
+        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+        want, vjp = jax.vjp(lambda *a: _dense(*a, allowed), q, k, v)
+        np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+        got = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
+        for name, a, b in zip(("dq", "dk", "dv"), got, vjp(do)):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("geometry", [
+    (8192, 512, 1024, 1024), (8192, 512, 512, 512), (256, 48, 64, 32),
+    (128, 32, 32, 64), (160, 33, 32, 32), (64, 64, 16, 16)])
+def test_flash_schedule_counts_what_the_window_keeps(geometry):
+    """`_schedule` under the window against a brute count position by
+    position: every live score lies in exactly one strip, a strip's _Tread
+    says exactly which of its elements are live, `computed` is what the
+    strips span, the visited blocks are those the window's formula gives
+    (block (i, j) iff -bq < q0 - k0 < w + bk - 1), and the index maps fetch
+    every live block and re-fetch a live one at a dead step."""
+    T, w, bq, bk = geometry
+    mask = fa.sliding_window_mask(T, w)
+    live = _window(T, w)
+    assert live.sum() == T * w - w * (w - 1) // 2
+    for sq in {fa._strip_rows(kernel, bq, bk) for kernel in KERNELS}:
+        plan = fa._schedule(T, bq, bk, sq, mask)
+        (part,) = plan.parts
+        assert part.full is None
+        walks = dict(part.walks)
+        assert set(walks) == {d for d in range(-T, T, np.gcd(bq, bk))
+                              if -bq < d < w + bk - 1 and any(
+                                  q0 - k0 == d for q0 in range(0, T, bq)
+                                  for k0 in range(0, T, bk))}
+        if T > 1024:    # the cell's size: the plan, not the square
+            continue
+        walked = np.zeros((T, T), np.int32)
+        kept = np.zeros((T, T), bool)
+        for q0 in range(0, T, bq):
+            for k0 in range(0, T, bk):
+                for r0, c0, width, tread in walks.get(q0 - k0, ()):
+                    at = (slice(q0 + r0, q0 + r0 + sq),
+                          slice(k0 + c0, k0 + c0 + width))
+                    walked[at] += 1
+                    if tread is None:
+                        kept[at] = True
+                        continue
+                    lead = (np.arange(width)[None, :]
+                            - np.arange(sq)[:, None])
+                    kept[at] = ((lead <= tread.ahead)
+                                & (lead >= tread.ahead - tread.span))
+        assert walked.max() == 1 and (walked[live] == 1).all()
+        assert (kept == live).all()
+        assert plan.computed == walked.sum()
+    nq, nk = T // bq, T // bk
+    visited = np.array([[-bq < i * bq - j * bk < w + bk - 1
+                         for j in range(nk)] for i in range(nq)])
+    if T <= 1024:
+        np.testing.assert_array_equal(
+            visited, live.reshape(nq, bq, nk, bk).any(axis=(1, 3)))
+    k_of = fa._live_k_block(mask, bq, bk, nk)
+    q_of = fa._live_q_block(mask, bq, bk, nq)
+    for i in range(nq):
+        got = [int(k_of(np.int32(i), np.int32(j))) for j in range(nk)]
+        assert all(g == j if visited[i, j] else visited[i, g]
+                   for j, g in enumerate(got)), (i, got)
+    for j in range(nk):
+        got = [int(q_of(np.int32(j), np.int32(i))) for i in range(nq)]
+        assert all(g == i if visited[i, j] else visited[g, j]
+                   for i, g in enumerate(got)), (j, got)
+    if geometry == (8192, 512, 1024, 1024):     # phi4flash_train_t8192
+        assert plan.computed / T ** 2 < 0.09 and visited.sum() == 15
+
+
+def test_the_window_leaves_the_other_regions_as_they_were():
+    assert fa.causal_mask(64) == (fa._Stairs((0, 64), (0, 64)),)
+    assert fa.causal_mask(64)[0].low is None
+    bd = fa.block_diffusion_mask(64, 4)
+    assert [s.low for s in bd] == [0, None, None]
+    assert all(s.window == 0 for s in bd)
+    assert fa.sliding_window_mask(64, 8)[0].low == -7
+    with pytest.raises(ValueError, match="sliding window"):
+        fa.sliding_window_mask(64, 65)
+
+
+def test_sdpa_window_attrs_the_dense_path_and_the_gate():
+    """The op's `mask` "window": dense under `window_allowed` on the CPU; a
+    window that holds the sequence is plainly causal; the flash gate hands
+    the kernels the window's region and not `causal`."""
+    import jax.numpy as jnp
+
+    q, k = _r(1, 4, 16, 8, seed=1), _r(1, 2, 16, 8, seed=2)
+    attrs = {"causal": True, "mask": "window", "window": 5}
+    (got,) = OpTestHarness("scaled_dot_product_attention",
+                           {"Q": q, "K": k, "V": k}, attrs).fetch()
+    np.testing.assert_allclose(got, _dense(*(jnp.asarray(a) for a in (
+        q, k, k)), _window(16, 5)), atol=1e-6)
+    (whole,) = OpTestHarness("scaled_dot_product_attention",
+                             {"Q": q, "K": k, "V": k},
+                             dict(attrs, window=16)).fetch()
+    (causal,) = OpTestHarness("scaled_dot_product_attention",
+                              {"Q": q, "K": k, "V": k},
+                              {"causal": True}).fetch()
+    np.testing.assert_allclose(whole, causal, atol=1e-7)
+    with pytest.raises(Exception, match="a sliding window is causal"):
+        OpTestHarness("scaled_dot_product_attention",
+                      {"Q": q, "K": k, "V": k},
+                      dict(attrs, causal=False)).fetch()
+
+    class Ctx:
+        mesh, is_test = None, True
+
+        def target_platform(self):
+            return "tpu"
+
+    took = []
+    real = fa.flash_attention
+    fa.flash_attention = lambda q, k, v, causal, mask=None, **kw: took.append(
+        (causal, mask, kw)) or v
+    try:
+        x = jnp.zeros((1, 4, 256, 64))
+        assert attention_ops.flash_single_chip(
+            Ctx(), x, x[:, :2], x[:, :2], True, mask=("window", 128))
+    finally:
+        fa.flash_attention = real
+    assert took == [(False, fa.sliding_window_mask(256, 128),
+                     {"block_q": fa.MASK_BLOCKS[0],
+                      "block_k": fa.MASK_BLOCKS[1]})]
+
+
+# ---------------------------------------------------------------------------
+# differential attention's two ops
+
+
+def test_diff_attn_split_output_and_grad():
+    """Heads in pairs "(H two)": the first of every pair, then the second;
+    the values once as (v1; v1) and once as (v2; v2); a query-only X gives
+    Q alone."""
+    B, T, Hq, Hkv, D = 2, 3, 4, 2, 2
+    x = _r(B, T, (Hq + 2 * Hkv) * D, seed=1)
+    attrs = {"num_heads": Hq, "num_kv_heads": Hkv, "head_dim": D}
+    q = x[..., :Hq * D].reshape(B, T, Hq, D).transpose(0, 2, 1, 3)
+    k = x[..., Hq * D:(Hq + Hkv) * D].reshape(B, T, Hkv, D).transpose(
+        0, 2, 1, 3)
+    v = x[..., (Hq + Hkv) * D:].reshape(B, T, Hkv, D).transpose(0, 2, 1, 3)
+    want = {"Q": q[:, [0, 2, 1, 3]], "K": k[:, [0, 1]],
+            "V1": v[:, [0, 0]], "V2": v[:, [1, 1]]}
+    h = OpTestHarness("diff_attn_split", {"X": x}, attrs,
+                      out_slots=["Q", "K", "V1", "V2"])
+    h.check_output(want, atol=1e-12)
+    for slot in want:
+        h.check_grad(["X"], output_slot=slot, max_relative_error=1e-2)
+    (alone,) = OpTestHarness("diff_attn_split", {"X": x[..., :Hq * D]}, attrs,
+                             out_slots=["Q"]).fetch()
+    np.testing.assert_array_equal(alone, want["Q"])
+    with pytest.raises(Exception, match="diff_attn_split: X"):
+        OpTestHarness("diff_attn_split", {"X": x[..., 1:]}, attrs,
+                      out_slots=["Q"]).fetch()
+
+
+def test_diff_attn_combine_output_and_grad():
+    B, H, T, D = 2, 4, 3, 2
+    o1, o2 = _r(B, H, T, D, seed=1), _r(B, H, T, D, seed=2)
+    lam = [_r(D, seed=s) for s in (3, 4, 5, 6)]
+    gain = _r(2 * D, lo=0.5, hi=1.5, seed=7)
+    init = 0.37
+    ins = {"O1": o1, "O2": o2, "LambdaQ1": lam[0], "LambdaK1": lam[1],
+           "LambdaQ2": lam[2], "LambdaK2": lam[3], "Gain": gain}
+    lm = np.exp(lam[0] @ lam[1]) - np.exp(lam[2] @ lam[3]) + init
+    a = np.concatenate([o1[:, :2] - lm * o1[:, 2:],
+                        o2[:, :2] - lm * o2[:, 2:]], axis=-1)
+    a = a / np.sqrt((a * a).mean(-1, keepdims=True) + 1e-5) * gain * (
+        1 - init)
+    h = OpTestHarness("diff_attn_combine", ins,
+                      {"lambda_init": init, "epsilon": 1e-5})
+    h.check_output({"Out": a.transpose(0, 2, 1, 3).reshape(B, T, H * D)},
+                   atol=1e-6)
+    h.check_grad(sorted(ins), max_relative_error=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# the published layer rule and the published size
+
+
+def test_layer_rule_at_the_published_depth():
+    """L = 32: 8 Mamba and 8 window layers in the self-decoder, the memory's
+    Mamba at 16, full attention at 17, 7 GMUs and 7 cross layers; the run
+    12-19 is the one run of 8 with every kind; the reference's rule is the
+    program's."""
+    kinds, windows = transformer.phi4flash_layer_kinds(32, 512)
+    first = list(zip(kinds[:16], windows[:16]))
+    assert first == [("mamba", None), ("attention", 512)] * 8
+    assert (kinds[16], windows[16]) == ("mamba", None)
+    assert (kinds[17], windows[17]) == ("attention", None)
+    assert kinds[18:] == ["gmu", "cross_attention"] * 7
+    assert windows[18:] == [None] * 14
+    assert kinds[12:20] == ["mamba", "attention", "mamba", "attention",
+                            "mamba", "attention", "gmu", "cross_attention"]
+    whole = lambda run: (  # noqa: E731
+        {"mamba", "gmu", "cross_attention"} <= set(run)
+        and any(k == "attention" and w for k, w in zip(run, windows[s:]))
+        and 16 in range(s, s + 8) and 17 in range(s, s + 8))
+    runs = []
+    for s in range(0, 32 - 7, 4):
+        if whole(kinds[s:s + 8]):
+            runs.append(s)
+    assert runs == [12]
+    cfg = harness.load_json("configs", CONFIG)
+    cfg["deployment"] = dict(cfg["deployment"], layers_held=list(range(32)))
+    assert [(k, w) for k, _, w in _ref().layer_kinds(cfg)] == list(
+        zip(kinds, windows))
+    with pytest.raises(ValueError, match="whole periods"):
+        transformer.phi4flash_layer_kinds(30, 512)
+
+
+def test_parameter_count_at_the_published_sizes():
+    """The issue's table, to 0.1 M a block, and 3.85 B in all: by building
+    the blocks' descs at the published widths (nothing is initialised)."""
+    fluid.reset()
+    cfg = harness.load_json("configs", CONFIG)
+    args = dict(cfg["train"]["args"], seq_len=128)
+    harness.resolve(cfg["train"]["builder"])(**args)
+    params = fluid.default_main_program().global_block().all_parameters()
+    sizes = [int(np.prod(p.shape)) for p in params]
+    assert sum(sizes) == 915_311_616                      # the cell's 915.3 M
+    layers, n = _ref().layout(cfg)
+    assert n == len(params)
+    block = {}
+    for (kind, index, window, at), nxt in zip(
+            layers, [l[3] for l in layers[1:]] + [n - 2]):
+        block.setdefault(kind, sum(sizes[at:nxt]) / 1e6)
+    assert abs(block["mamba"] - 119.9) < 0.1
+    assert abs(block["attention"] - 98.3) < 0.1
+    assert abs(block["gmu"] - 104.9) < 0.1
+    assert abs(block["cross_attention"] - 91.8) < 0.1
+    tied = 200064 * 2560 / 1e6
+    whole = (9 * block["mamba"] + 9 * block["attention"] + 7 * block["gmu"]
+             + 7 * block["cross_attention"] + tied) / 1e3
+    assert abs(whole - 3.85) < 0.01
+    assert sizes[0] == 25008 * 2560 and 25008 * 8 == 200064
+
+
+def test_the_serving_wiring_still_refuses_what_it_cannot_decode():
+    for key, value in (("ssm", None), ("differential", None),
+                       ("window", None), ("attention_bias", False),
+                       ("tie_embeddings", False), ("norm_attr", None)):
+        assert transformer._GPT2_BLOCK[key] == value
+    assert {"mamba", "gmu", "cross_attention"} <= set(transformer._MIXERS)
+    fluid.reset()
+    tokens = fluid.layers.data("tokens", shape=[8, 1], dtype="int64")
+    with pytest.raises(ValueError, match="no 'mamba' layer lies before"):
+        transformer.decoder_lm(tokens, 16, 8, 1, 2, 8, positions="none",
+                               layer_types=["gmu"])
+    with pytest.raises(ValueError, match="reuses the keys and values"):
+        transformer.decoder_lm(tokens, 16, 8, 1, 2, 8, positions="none",
+                               layer_types=["cross_attention"])

@@ -565,7 +565,8 @@ def test_gated_delta_net_layer_is_the_plain_version(monkeypatch):
 def test_decoder_lm_names_its_five_mixers():
     from paddle_tpu.models import transformer
 
-    assert transformer._MIXERS == (
+    # the five it had at PR 48; PR 52 appended three (test_phi4flash.py)
+    assert transformer._MIXERS[:5] == (
         "attention", "conv", "sparse_attention", "linear_attention",
         "gated_delta_net")
     fluid.reset()

@@ -485,7 +485,7 @@ def test_decoder_lm_names_its_four_mixers():
     with pytest.raises(ValueError, match="'attention', 'conv', "
                        "'sparse_attention', 'linear_attention'"):
         tr.decoder_lm(tokens, 32, 16, 2, 2, 16,
-                      layer_types=["attention", "mamba"])
+                      layer_types=["attention", "fourier"])
     with pytest.raises(ValueError, match="'mlp', 'gated_mlp' or 'moe'"):
         tr.decoder_lm(tokens, 32, 16, 2, 2, 16, ffn="swiglu")
     for kind in tr._MIXERS:
