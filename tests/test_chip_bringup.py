@@ -237,7 +237,10 @@ _TOY_PHASES = {
 @pytest.fixture(scope="module")
 def toy_phases():
     """Every smoke phase function once, in ONE subprocess: x64 off (this
-    process has it on), two host devices for the dp phase."""
+    process has it on), two host devices for the dp phase.  Its 50 s are
+    six steps' compiles (ResNet-18, the shallowest the phase takes, twice;
+    image 32, 16 and 8 compile alike), so XLA's CPU back end is told not to
+    optimise code that runs two or three steps: 36 s."""
     code = ("import json, jax\n"
             "assert not jax.config.jax_enable_x64\n"
             "import paddle_tpu as fluid, chip_smoke as cs\n"
@@ -245,7 +248,8 @@ def toy_phases():
             + "".join(f"print(json.dumps({call}), flush=True)\n"
                       for call in _TOY_PHASES.values()))
     out = _run(code, env={
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=2"})
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=2 "
+                     "--xla_backend_optimization_level=0"})
     assert out.returncode == 0, out.stderr[-3000:]
     recs = [json.loads(l) for l in out.stdout.splitlines()
             if l.startswith("{")]

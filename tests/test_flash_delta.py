@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _eqns
+from _kernel_refs import (_dense_masked as _dense, _eqns, _heads_last,
+                          _with_vjp)
 from paddle_tpu import observability as obs
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
@@ -45,20 +46,6 @@ def _allowed(T, mask):
     r_blk, c_blk = (r % L) // b, (c % L) // b
     return np.where(r < L, np.where(c < L, r_blk == c_blk, c_blk < r_blk),
                     (c >= L) & (c_blk <= r_blk))
-
-
-def _dense(q, k, v, allowed):
-    """Dense float32 attention on [B, H, T, D], K/V head h // group under
-    each query head, the scores outside `allowed` at -inf."""
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.float32(q.shape[-1] ** 0.5)
-    p = jax.nn.softmax(jnp.where(jnp.asarray(allowed), s, -jnp.inf), -1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
-
-
-def _heads_last(a):  # [B, H, T, D] -> [B, T, H * D]
-    return a.transpose(0, 2, 1, 3).reshape(a.shape[0], a.shape[2], -1)
 
 
 def _backward(case, monkeypatch):
@@ -129,7 +116,7 @@ def test_backward_on_its_own_delta_matches_dense_vjp(case, monkeypatch):
     (q, k, v, _o, do), allowed, layout, grads, _ = _backward(case,
                                                              monkeypatch)
     with jax.enable_x64(False):
-        want = jax.vjp(lambda *a: _dense(*a, allowed), q, k, v)[1](do)
+        _, want = _with_vjp(lambda *a: _dense(*a, allowed), do, q, k, v)
     tol = 2e-5 if case != "block_diffusion_mask" else 1e-4  # test_sdar's
     for name, got, ref in zip(("dq", "dk", "dv"), grads, want):
         if layout == "packed":

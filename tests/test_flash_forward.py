@@ -1,12 +1,14 @@
 """The flash forward in interpret mode (same code path as the chip): the
 raw-score running max, the logsumexp row, the carried state, the scale."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _dense_scaled, _eqns
+from _kernel_refs import _assert_named, _dense_scaled, _eqns
 
 
 # ---------------------------------------------------------------------------
@@ -35,12 +37,13 @@ PASS_BLOCKS = {
 }
 
 
-def _check_scaled(widths, blocks, causal, group, fwd=None, T=None):
-    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
-
+@functools.cache
+def _scaled_case(widths, blocks, causal, group):
+    """A case's operands and dense float32 attention's out, logsumexp, dq,
+    dk, dv on them: made once, for the case and for the mutant it is the
+    control of."""
     D, Dv, scale = PASS_WIDTHS[widths] if isinstance(widths, str) else widths
-    bT, bq, bk = PASS_BLOCKS[blocks] if isinstance(blocks, str) else blocks
-    T = T or bT
+    T, bq, bk = PASS_BLOCKS[blocks] if isinstance(blocks, str) else blocks
     B, Hkv = 1, 1
     H = Hkv * group
     rng = np.random.RandomState(34)
@@ -50,22 +53,27 @@ def _check_scaled(widths, blocks, causal, group, fwd=None, T=None):
     do = jnp.asarray(rng.randn(B, H, T, Dv).astype(np.float32))
     kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
               interpret=True)
-    out, lse = (fwd or fa.flash_attention_fwd)(q, k, v, **kw)
-    dq, dk, dv = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
     s = scale if scale is not None else 1.0 / D ** 0.5
-    (want_out, want_lse), vjp = jax.vjp(
+    (out, lse), vjp = jax.vjp(
         lambda *a: _dense_scaled(*a, causal, s), q, k, v)
-    want = vjp((do, jnp.zeros_like(want_lse)))
-    # ring attention merges partial outputs by this row: 1e-5, absolute
-    np.testing.assert_allclose(np.asarray(lse.reshape(B, H, T)),
-                               np.asarray(want_lse), atol=1e-5, rtol=0,
-                               err_msg="lse")
-    for name, got, ref in (("out", out, want_out), ("dq", dq, want[0]),
-                           ("dk", dk, want[1]), ("dv", dv, want[2]),
-                           ("nolse", fa.flash_attention(q, k, v, **kw),
-                            want_out)):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5, err_msg=name)
+    dq, dk, dv = vjp((do, jnp.zeros_like(lse)))
+    return (q, k, v, do), kw, dict(out=out, lse=lse, dq=dq, dk=dk, dv=dv)
+
+
+def _check_scaled(widths, blocks, causal, group, fwd=None):
+    """The kernels against the case's dense reference, each result named
+    in the failure; under another forward (`fwd`, a mutant's) the two
+    results the forward makes, and nothing of the backward."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    (q, k, v, do), kw, want = _scaled_case(widths, blocks, causal, group)
+    out, lse = (fwd or fa.flash_attention_fwd)(q, k, v, **kw)
+    got = dict(lse=lse.reshape(want["lse"].shape), out=out)
+    if fwd is None:
+        got.update(zip(("dq", "dk", "dv"), fa.flash_attention_bwd(
+            q, k, v, out, lse, do, **kw)))
+        got["nolse"] = fa.flash_attention(q, k, v, **kw)
+    _assert_named(got, want)
 
 
 @pytest.mark.parametrize("group", [1, 4], ids=["own_kv_head", "group_of_4"])
@@ -98,7 +106,7 @@ def test_flash_raw_score_logsumexp_mutant_fails(widths):
         m = raw.max(axis=-1).reshape(B * H, T)
         return out, lse - m * s + m
 
-    _check_scaled(widths, "offset_bq16_bk32", True, 1)
+    # the control is the case [widths-offset_bq16_bk32-causal-own_kv_head]
     with pytest.raises(AssertionError, match="lse"):
         _check_scaled(widths, "offset_bq16_bk32", True, 1, fwd=raw_lse)
 

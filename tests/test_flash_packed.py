@@ -2,12 +2,14 @@
 (same code path as the chip), and the [B, H, T, D] entry's jaxprs held to the
 parent's."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _dense_f32
+from _kernel_refs import _assert_named, _dense_f32, _heads_last
 
 
 # ---------------------------------------------------------------------------
@@ -28,40 +30,49 @@ def _heads_first(a, H):  # [B, T, H * D] -> [B, H, T, D]
     return a.reshape(a.shape[:2] + (H, -1)).transpose(0, 2, 1, 3)
 
 
-def _heads_last(a):  # [B, H, T, D] -> [B, T, H * D]
-    return a.transpose(0, 2, 1, 3).reshape(a.shape[0], a.shape[2], -1)
-
-
-def _check_packed(D, blocks, causal, fwd=None, bwd=None):
-    """out, lse, dq, dk, dv on [B, T, H * D] operands against the
-    [B, H, T, D] entry on the same numbers, each named in the failure, to
-    the tolerances the kernels are held to against dense attention."""
+@functools.cache
+def _packed_case(D, blocks, causal):
+    """A case's operands, the forward's (out, lse) on them, and what the
+    [B, H, T, D] entry, itself held to dense attention, gives on the same
+    numbers: made once, for the case and for the mutants it is the control
+    of."""
     from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
-    B, H = 2, 4
+    H = 4
     T, bq, bk = PACKED_BLOCKS[blocks]
-    q, k, v, do = _packed_operands(B, H, T, D)
+    ops = _packed_operands(2, H, T, D)
     kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
-    out, lse = (fwd or fa.flash_attention_fwd)(q, k, v, heads=H, **kw)
-    dq, dk, dv = (bwd or fa.flash_attention_bwd)(q, k, v, out, lse, do,
-                                                 heads=H, **kw)
-    q4, k4, v4, do4 = (_heads_first(a, H) for a in (q, k, v, do))
-    want_out, want_lse = fa.flash_attention_fwd(q4, k4, v4, **kw)
-    want = fa.flash_attention_bwd(q4, k4, v4, want_out, want_lse, do4, **kw)
-    assert out.shape == q.shape and lse.shape == (B * H, T)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
-                               atol=1e-5, rtol=0, err_msg="lse")
-    for name, got, ref in (("out", out, want_out), ("dq", dq, want[0]),
-                           ("dk", dk, want[1]), ("dv", dv, want[2]),
-                           ("nolse", fa.flash_attention(q, k, v, heads=H,
-                                                        **kw), want_out)):
-        np.testing.assert_allclose(np.asarray(got),
-                                   np.asarray(_heads_last(ref)),
-                                   atol=2e-5, rtol=2e-5, err_msg=name)
-    # and the old entry against dense attention, so both are right
-    dense_out, _ = _dense_f32(q4, k4, v4, causal)
-    np.testing.assert_allclose(np.asarray(want_out), np.asarray(dense_out),
+    q4, k4, v4, do4 = (_heads_first(a, H) for a in ops)
+    out4, lse = fa.flash_attention_fwd(q4, k4, v4, **kw)
+    grads = fa.flash_attention_bwd(q4, k4, v4, out4, lse, do4, **kw)
+    np.testing.assert_allclose(np.asarray(out4),
+                               np.asarray(_dense_f32(q4, k4, v4, causal)[0]),
                                atol=2e-5, rtol=2e-5)
+    want = dict(zip(("out", "dq", "dk", "dv"),
+                    map(_heads_last, (out4,) + grads)), lse=lse)
+    kw["heads"] = H
+    return ops, kw, fa.flash_attention_fwd(*ops[:3], **kw), want
+
+
+def _check_packed(D, blocks, causal, only=None):
+    """out, lse, dq, dk, dv on [B, T, H * D] operands against the
+    [B, H, T, D] entry on the same numbers, each named in the failure, to
+    the tolerances the kernels are held to against dense attention; or
+    `only` one of them, from the one call that makes it (a mutant's check:
+    the backward's on the case's own forward)."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    (q, k, v, do), kw, fwd, want = _packed_case(D, blocks, causal)
+    if only in ("out", "lse"):
+        fwd = fa.flash_attention_fwd(q, k, v, **kw)
+    got = dict(out=fwd[0], lse=fwd[1])
+    assert got["out"].shape == q.shape and got["lse"].shape == want["lse"].shape
+    if only not in got:
+        got.update(zip(("dq", "dk", "dv"),
+                       fa.flash_attention_bwd(q, k, v, *fwd, do, **kw)))
+    if only is None:
+        got["nolse"] = fa.flash_attention(q, k, v, **kw)
+    _assert_named({only: got[only]} if only else got, want)
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "whole"])
@@ -106,21 +117,15 @@ def test_flash_packed_wrong_half_of_a_pair_fails(mutant, monkeypatch):
             m.setattr(fa, helper, wrong[helper])
             return real_body(*refs, **kw)
 
-    _check_packed(64, "several_k_blocks", True)
-    def forget():
-        """The memoized calls hold the real bodies, and jit's own cache
-        the heads' shared walks (_shared): none before, none after."""
-        for memo in (fa._fwd_call, fa._bwd_calls, fa._shared):
-            memo.cache_clear()
-        jax.clear_caches()
-
-    forget()
+    _packed_case(64, "several_k_blocks", True)  # the layout case: the control
+    # the memoized calls hold the real bodies, and jit's own cache the
+    # heads' shared walks (_shared): the mutant's call is built beside them
+    for memo in ("_fwd_call", "_bwd_calls"):
+        monkeypatch.setattr(fa, memo, getattr(fa, memo).__wrapped__)
+    monkeypatch.setattr(fa, "_shared", lambda fn, *static: fn)
     monkeypatch.setattr(fa, body, mutated)
-    try:
-        with pytest.raises(AssertionError, match=fails):
-            _check_packed(64, "several_k_blocks", True)
-    finally:
-        forget()
+    with pytest.raises(AssertionError, match=fails):
+        _check_packed(64, "several_k_blocks", True, only=fails)
 
 
 @pytest.mark.parametrize("shape,heads", [((1, 64, 96), 1), ((1, 64, 192), 3),

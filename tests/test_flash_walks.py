@@ -2,12 +2,14 @@
 walks and their mutants, dkv's transposed tile, the schedule counts, and the
 two oldest dense comparisons."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _dense_f32, _eqns
+from _kernel_refs import _assert_named, _dense_f32, _eqns
 from paddle_tpu.ops.pallas_kernels.flash_attention import flash_attention
 from paddle_tpu.parallel.ring_attention import attention
 
@@ -93,28 +95,35 @@ def test_flash_backward_matches_dense(causal):
 # of q rows, each only as far as the diagonal reaches.
 
 
-def _check_walk(T, bq, bk, causal, seed=0):
-    """out, lse, dq, dk, dv of the three kernels against dense float32
-    attention and its gradients."""
-    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
-
+@functools.cache
+def _walk_case(T, causal, seed=0):
+    """Operands and dense float32 attention's out, lse, dq, dk, dv on them:
+    once a (T, causal), for every block geometry and mutant that reads
+    them."""
     B, H, D = 1, 2, 16
     rng = np.random.RandomState(seed)
     q, k, v, do = (jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
                    for _ in range(4))
+    (out, lse), vjp = jax.vjp(lambda *a: _dense_f32(*a, causal), q, k, v)
+    dq, dk, dv = vjp((do, jnp.zeros_like(lse)))
+    return (q, k, v, do), dict(out=out, lse=lse, dq=dq, dk=dk, dv=dv)
+
+
+def _check_walk(T, bq, bk, causal, forward_only=False):
+    """out, lse, dq, dk, dv of the three kernels against dense float32
+    attention and its gradients; `forward_only` the first two (a mutant of
+    the walk, which every kernel shares, fails in them)."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    (q, k, v, do), want = _walk_case(T, causal)
     kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
     out, lse = fa.flash_attention_fwd(q, k, v, **kw)
-    dq, dk, dv = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
-    want_out, want_lse = _dense_f32(q, k, v, causal)
-    grads = jax.vjp(lambda *a: _dense_f32(*a, causal)[0], q, k, v)[1](do)
-    got = dict(out=out, lse=lse.reshape(B, H, T), dq=dq, dk=dk, dv=dv,
-               nolse=fa.flash_attention(q, k, v, **kw))
-    want = dict(out=want_out, lse=want_lse, dq=grads[0], dk=grads[1],
-                dv=grads[2], nolse=want_out)
-    for name in got:
-        np.testing.assert_allclose(
-            np.asarray(got[name]), np.asarray(want[name]), atol=2e-5,
-            rtol=2e-5, err_msg=name)
+    got = dict(out=out, lse=lse.reshape(want["lse"].shape))
+    if not forward_only:
+        got.update(zip(("dq", "dk", "dv"), fa.flash_attention_bwd(
+            q, k, v, out, lse, do, **kw)))
+        got["nolse"] = fa.flash_attention(q, k, v, **kw)
+    _assert_named(got, want, lse=(2e-5, 2e-5))
 
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
@@ -295,10 +304,10 @@ def test_flash_causal_walk_mutants_fail(case, mutant, monkeypatch):
         return out[:-1] if len(out) > 1 else out
 
     T, bq, bk, causal, _rows = WALK_CASES[case]
-    _check_walk(T, bq, bk, causal)  # the walk as it is passes
+    # the walk as it is passes: test_flash_causal_walk_matches_dense[case]
     monkeypatch.setattr(fa, "_row_strips", strips)
-    with pytest.raises(AssertionError):
-        _check_walk(T, bq, bk, causal)
+    with pytest.raises(AssertionError, match="out"):
+        _check_walk(T, bq, bk, causal, forward_only=True)
 
 
 @pytest.mark.parametrize("geometry", [

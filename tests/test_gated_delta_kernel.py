@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops import sparse_linear_ops as slo
@@ -73,9 +74,8 @@ def test_gated_delta_kernels_match_the_plain_emission(dtype, T, chunk, G,
     *ops, do = _operands(2, G, T, 16, 8, jnp.dtype(dtype), decay)
     how = dict(interpret=True)
     with jax.enable_x64(False):
-        want, back = jax.vjp(
-            lambda *a: slo.gated_delta_chunked(*a, chunk=chunk), *ops)
-        grads = back(do)
+        want, grads = _with_vjp(
+            lambda *a: slo.gated_delta_chunked(*a, chunk=chunk), do, *ops)
         got = K.gated_delta_fwd(*ops, chunk, **how)
         mine = K.gated_delta_bwd(do, *ops, chunk, **how)
     assert got.dtype == jnp.float32
@@ -94,8 +94,7 @@ def test_gated_delta_kernels_match_the_recurrence(T, chunk, G, decay):
     state, the decay factors) is shared with the oracle."""
     *ops, do = _operands(2, G, T, 16, 8, jnp.float32, decay, seed=3)
     with jax.enable_x64(False):
-        want, back = jax.vjp(_recurrence, *ops)
-        grads = back(do)
+        want, grads = _with_vjp(_recurrence, do, *ops)
         got, mine = jax.vjp(K.make_gated_delta(chunk, True), *ops)
         mine = mine(do)
     _close(got, want, 1e-5)

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _kernel_refs import _with_vjp
 from test_gated_delta_kernel import _gdn_step, _gdn_values, _series
 
 from paddle_tpu import observability as obs
@@ -59,8 +60,7 @@ def test_gdn_conv_kernels_match_the_plain_emission(dtype, L, B):
     T, how = (96, HOW) if B == 1 else (48, dict(HOW, tile=16))
     x, w, cts = _operands(B, T, L, jnp.dtype(dtype))
     with jax.enable_x64(False):
-        want, back = jax.vjp(_plain, x, w)
-        gx, gw = back(cts)
+        want, (gx, gw) = _with_vjp(_plain, cts, x, w)
         got = K.gdn_conv_fwd(x, w, HK, HV, D, EPS, **how)
         dx, dw = K.gdn_conv_bwd(*cts, x, w, HK, HV, D, EPS, **how)
     for a, b in zip(got, want):

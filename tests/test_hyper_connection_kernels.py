@@ -50,8 +50,7 @@ def _hc_both(n, dtype, monkeypatch):
     _hc_interpreted(monkeypatch)
     out = {}
     with jax.enable_x64(False):
-        got = {}
-        for kernels in (False, True):
+        def both_ops(o, kernels):
             pre, pre_bwd = llm_ops._hc_pre(
                 n, HC_ATTRS["n_iters"], HC_ATTRS["eps"],
                 HC_ATTRS["norm_eps"], HC_ATTRS["clamp"], False, kernels)
@@ -63,10 +62,14 @@ def _hc_both(n, dtype, monkeypatch):
             dx1, dphi, dalpha, dbeta = pre_bwd(
                 o["x"], o["phi"], o["alpha"], o["beta"], proj, inv, o["du"],
                 o["dh_post"], o["dm"])
-            got[kernels] = dict(
+            return dict(
                 U=u, HPost=h_post, HRes=m, Proj=proj, Inv=inv, Out=new,
                 dX_post=dx2, dY=dy, dHPost=dh, dHRes=dm, dX_pre=dx1,
                 dPhi=dphi, dAlpha=dalpha, dBeta=dbeta)
+
+        # each path one program: op by op the plain one is 100 to compile
+        got = {kernels: jax.jit(both_ops, static_argnums=1)(o, kernels)
+               for kernels in (False, True)}
         for k in got[True]:
             a, b = got[True][k], got[False][k]
             assert a.shape == b.shape and a.dtype == b.dtype, k

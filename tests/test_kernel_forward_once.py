@@ -491,20 +491,15 @@ def test_aot_packed_backward_makes_its_own_delta(v5e, shape, heads):
 # AOT: the expert layer's backward kernels in OLMoE's real step
 
 
-def _peak_bytes(compiled) -> int:
-    ma = compiled.memory_analysis()
-    return (ma.argument_size_in_bytes + ma.temp_size_in_bytes
-            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
-
-
 def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
         v5e, monkeypatch):
     """`olmoe_train_t4096`'s step at the published widths (2 layers, 64
     experts of 1024, 4096 tokens with 8 experts each, bf16): the compiled
     HLO holds the 6 forward `ragged-dot` products XLA lowers itself and 12
     calls of the two Pallas kernels, no backward `ragged-dot`, and NO copy
-    or transpose of the stacked expert weights; it needs no more memory
-    than the same step with autodiff's transposes (the parent's)."""
+    or transpose of the stacked expert weights.  With the gate closed the
+    step as JAX hands it to XLA holds autodiff's six more `ragged_dot` a
+    layer (not compiled: 15 s, for a step no cell runs)."""
     from paddle_tpu.models.transformer import build_moe_lm_train_program
     from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
 
@@ -513,10 +508,10 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
                 n_heads=16, num_experts=64, expert_dim=1024, top_k=8,
                 dtype="bfloat16")
 
-    def compiled():
+    def lowered():
         fluid.reset()
         loss = build_moe_lm_train_program(**args)
-        return _lowered_step(loss, v5e, batch=1, seq_len=4096).compile()
+        return _lowered_step(loss, v5e, batch=1, seq_len=4096)
 
     def instructions(text, pattern):
         return re.findall(r"^\s*(?:ROOT )?%(" + pattern + r")[.\d]* = ",
@@ -526,8 +521,12 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     relayout = (r"^\s*%(?:copy|transpose)[.\d]* = " + stacked
                 + r"[^=\n]* (?:copy|transpose)\(")
 
-    change = compiled()
+    change = lowered()
+    # the forward's three a layer, and their re-emission in the grad op
     text = change.as_text()
+    assert text.count('"chlo.ragged_dot"(') == 6 * layers
+    assert gm.DLHS in text and gm.DRHS in text
+    text = change.compile().as_text()
     assert len(instructions(text, gm.DLHS)) == 3 * layers, "dlhs"
     assert len(instructions(text, gm.DRHS)) == 3 * layers, "drhs"
     assert len(instructions(text, "ragged-dot-none")) == 3 * layers
@@ -542,13 +541,9 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
 
     # the parent's step: the gate closed for these kernels alone
     monkeypatch.setattr(gm, "usable", lambda *shape: False)
-    parent = compiled()
-    text = parent.as_text()
-    assert len(instructions(text, "ragged-dot-none")) == 9 * layers
-    assert len(re.findall(relayout, text, re.M)) >= 2 * layers
-    print("AOT olmoe step peak_bytes: parent", _peak_bytes(parent),
-          "change", _peak_bytes(change))
-    assert _peak_bytes(change) <= _peak_bytes(parent)
+    text = lowered().as_text()
+    assert gm.DLHS not in text and gm.DRHS not in text
+    assert text.count('"chlo.ragged_dot"(') == 12 * layers
 
 
 SHARE_ROWS = "moe_share_rows_to_tokens_traced_total"

@@ -413,8 +413,8 @@ def test_lfm2_toy_tower_matches_the_reference_parameter_by_parameter():
             return jnp.mean(per), per
 
         with jax.default_matmul_precision("highest"):
-            (want, per), grads = jax.value_and_grad(total, has_aux=True)(
-                [ps[i] for i in trained])
+            (want, per), grads = jax.jit(jax.value_and_grad(
+                total, has_aux=True))([ps[i] for i in trained])
     np.testing.assert_allclose(got[0].reshape(()), want, rtol=1e-5)
     np.testing.assert_allclose(got[1].reshape(-1), per, rtol=1e-4,
                                atol=1e-5)

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _with_vjp
 from op_test import OpTestHarness
 
 
@@ -143,8 +144,8 @@ def test_qk_norm_and_rope_inside_multi_head_attention():
             for p in params]
     ref = lambda ps: _attention_reference(jnp.asarray(xv, jnp.float64), ps,
                                           H, 1e-5, 10000.0)
-    np.testing.assert_allclose(got[0], ref(vals), atol=2e-5)
-    want = jax.grad(lambda ps: jnp.mean(jnp.square(ref(ps))))(vals)
+    np.testing.assert_allclose(got[0], jax.jit(ref)(vals), atol=2e-5)
+    want = jax.jit(jax.grad(lambda ps: jnp.mean(jnp.square(ref(ps)))))(vals)
     for g, w, p in zip(got[1:], want, params):
         np.testing.assert_allclose(g, w, atol=2e-6, err_msg=p.name)
 
@@ -232,12 +233,12 @@ def test_head_norm_rope_plain_is_the_old_chain_in_float32(case):
         return llm_ops.head_norm_rope_plain(x, g, heads, eps, 100.0, period)
 
     with jax.enable_x64(False):
-        want, back = jax.vjp(chain, x, g)
-        got, back_plain = jax.vjp(plain, x, g)
+        dout = jnp.asarray(_r(B, heads, T, d, seed=9), jnp.float32)
+        want, back = _with_vjp(chain, dout, x, g)  # one program each
+        got, back_plain = _with_vjp(plain, dout, x, g)
         assert got.dtype == jnp.float32 and got.shape == (B, heads, T, d)
         np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
-        dout = jnp.asarray(_r(B, heads, T, d, seed=9), jnp.float32)
-        for a, b in zip(back_plain(dout), back(dout)):
+        for a, b in zip(back_plain, back):
             if b is not None and a is not None:
                 np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
 
@@ -363,7 +364,9 @@ def test_moe_dropless_sorted_path_against_dense(top_k, gated, act):
                         ["Out", "RouterLogits", "Counts"]).fetch()
     dense = lambda a: _moe_dense(a["X"], a["Gate"], a["WI"], a.get("WU"),
                                  a["WO"], top_k, act)
-    want = dense({k: jnp.asarray(v) for k, v in ins.items()})
+    # the dense layer and the gradients each one program: op by op they
+    # are 130 to compile
+    want = jax.jit(dense)({k: jnp.asarray(v) for k, v in ins.items()})
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
     assert float(np.sum(got[2])) == ins["X"].shape[0] * top_k  # dropless
@@ -382,8 +385,8 @@ def test_moe_dropless_sorted_path_against_dense(top_k, gated, act):
         return jnp.sum(out * out) + jnp.sum(jnp.sin(logits))
 
     a = {k: jnp.asarray(v) for k, v in ins.items()}
-    g_op = jax.grad(lambda a: loss(through_op, a))(a)
-    g_dense = jax.grad(lambda a: loss(dense, a))(a)
+    g_op = jax.jit(jax.grad(lambda a: loss(through_op, a)))(a)
+    g_dense = jax.jit(jax.grad(lambda a: loss(dense, a)))(a)
     for k in a:
         np.testing.assert_allclose(g_op[k], g_dense[k], rtol=1e-5,
                                    atol=1e-5, err_msg=k)

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import moe_ops, registry as reg
@@ -42,17 +43,15 @@ def test_flash_two_widths_matches_dense_forward_and_gradients(causal):
                                 block_k=64)
     out, lse = fa.flash_attention_fwd(q, k, v, causal=causal,
                                       interpret=True, block_q=32, block_k=64)
-    want = dense(q, k, v, causal=causal)
+    # dense attention and its gradients under `do`, one program
+    want, ref = _with_vjp(lambda *a: dense(*a, causal=causal), do, q, k, v)
     assert out.shape == (B, H, T, dv) and lse.shape == (B * H, T)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(
         fa.flash_attention(q, k, v, causal=causal, interpret=True,
                            block_q=32, block_k=64), want, rtol=2e-5,
         atol=2e-5)
-    loss = lambda f: (lambda q, k, v: jnp.sum(f(q, k, v) * do))
-    got = jax.grad(loss(train), (0, 1, 2))(q, k, v)
-    ref = jax.grad(loss(lambda q, k, v: dense(q, k, v, causal=causal)),
-                   (0, 1, 2))(q, k, v)
+    got = jax.vjp(train, q, k, v)[1](do)
     for g, r, shape in zip(got, ref, (q.shape, k.shape, v.shape)):
         assert g.shape == shape
         np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-4)

@@ -533,18 +533,30 @@ def test_sharded_checkpoint_roundtrip(tmp_path):
 def test_remat_composes_with_parallel_executor():
     """layers.recompute segments (the bench remat default) must lower and
     train under a dp-sharded mesh — the recompute op's sub-block traces
-    inside the pjit program."""
-    from paddle_tpu.models import resnet
+    inside the pjit program.  One segment around a two-conv block with its
+    batch norm: what models.resnet wraps in every residual block."""
+    import contextlib
 
     def losses(remat):
         fluid.reset()
-        avg_cost, _ = resnet.build_train_program(
-            batch_size=8, depth=18, class_dim=10, image_shape=(3, 32, 32),
-            dtype="float32", layout="NCHW", remat=remat)
+        image = fluid.layers.data(name="image", shape=[3, 8, 8],
+                                  dtype="float32")
+        label = fluid.layers.data(name="label", shape=[1], dtype="int64")
+        with (fluid.layers.recompute() if remat
+              else contextlib.nullcontext()):
+            h = fluid.layers.conv2d(image, 4, 3, padding=1, bias_attr=False)
+            h = fluid.layers.batch_norm(h, act="relu")
+            h = fluid.layers.conv2d(h, 4, 3, padding=1, act="relu")
+        avg_cost = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=h, size=10), label))
+        fluid.optimizer.Momentum(learning_rate=0.1,
+                                 momentum=0.9).minimize(avg_cost)
+        ops = fluid.default_main_program().global_block().ops
+        assert remat == any(op.type == "recompute" for op in ops)
         pe = ParallelExecutor(axes={"dp": 8})
         pe.run(fluid.default_startup_program())
         rng = np.random.RandomState(0)
-        feed = {"image": rng.rand(8, 3, 32, 32).astype(np.float32),
+        feed = {"image": rng.rand(8, 3, 8, 8).astype(np.float32),
                 "label": rng.randint(0, 10, (8, 1)).astype(np.int64)}
         return [float(np.asarray(pe.run(feed=feed,
                                         fetch_list=[avg_cost])[0]).item())
@@ -552,6 +564,7 @@ def test_remat_composes_with_parallel_executor():
 
     plain = losses(False)
     remat = losses(True)
+    assert plain[-1] < plain[0]
     np.testing.assert_allclose(remat, plain, rtol=1e-3)
 
 

@@ -21,6 +21,7 @@ from paddle_tpu.models import transformer
 from paddle_tpu.ops import attention_ops, ssm_ops
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
+from _kernel_refs import _dense_masked as _dense, _with_vjp
 from op_test import OpTestHarness
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -161,17 +162,6 @@ def _window(T, w):
     return (t[:, None] - t[None, :] >= 0) & (t[:, None] - t[None, :] < w)
 
 
-def _dense(q, k, v, allowed):
-    import jax
-    import jax.numpy as jnp
-
-    group = q.shape[1] // k.shape[1]
-    k, v = (jnp.repeat(a, group, 1) for a in (k, v))
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / q.shape[-1] ** 0.5
-    p = jax.nn.softmax(jnp.where(jnp.asarray(allowed), s, -1e30), axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
-
-
 WINDOW_CASES = {   # T, window, block_q, block_k, query heads a K/V head
     "window_under_a_block": (96, 20, 32, 32, 1),
     "window_of_a_block_and_groups": (128, 32, 32, 64, 2),
@@ -204,10 +194,11 @@ def test_flash_window_matches_dense_masked_attention(case):
         kw = dict(mask=fa.sliding_window_mask(T, w), interpret=True,
                   block_q=bq, block_k=bk)
         out, lse = fa.flash_attention_fwd(q, k, v, **kw)
-        want, vjp = jax.vjp(lambda *a: _dense(*a, allowed), q, k, v)
+        # the reference and its backward one program: op by op, 50
+        want, grads = _with_vjp(lambda *a: _dense(*a, allowed), do, q, k, v)
         np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
         got = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
-        for name, a, b in zip(("dq", "dk", "dv"), got, vjp(do)):
+        for name, a, b in zip(("dq", "dk", "dv"), got, grads):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
                                        err_msg=name)
 

@@ -75,9 +75,9 @@ def test_gated_delta_chunked_matches_the_recurrence(chunk, T, decay):
     v = _r(Hk, G, T, Dv, seed=3)
     g = -_r(Hk, G, T, lo=DECAYS[decay][0], hi=DECAYS[decay][1], seed=4)
     beta = _r(Hk, G, T, lo=0.05, hi=0.95, seed=5)
-    with jax.enable_x64(True):
-        got = gated_delta_chunked(
-            *(jnp.asarray(a[None]) for a in (q, k, v, g, beta)), chunk=chunk)
+    with jax.enable_x64(True):  # one program: op by op it is 40 to 60
+        got = jax.jit(lambda *a: gated_delta_chunked(*a, chunk=chunk))(
+            *(jnp.asarray(a[None]) for a in (q, k, v, g, beta)))
     assert got.dtype == jnp.float64
     np.testing.assert_allclose(np.asarray(got)[0],
                                _delta_numpy(q, k, v, g, beta), atol=1e-9)
@@ -466,9 +466,13 @@ def _moe_layer(held, shared_gate=True, E=32, k=4, H=8):
 def test_the_16_ranks_shares_add_up_to_the_uncut_layer():
     """32 experts over 16 ranks of 2: every rank routes all tokens over
     all 32 (softmax, top-4, renormalised) and computes the pairs on its
-    own two; the 16 partial sums, each WITHOUT the shared expert, plus the
+    own two; the partial sums, each WITHOUT the shared expert, plus the
     gated shared expert ONCE, are the uncut layer's result (`held` = all
-    32), which is the plain reference's expert block."""
+    32), which is the plain reference's expert block.  Summed here over
+    four shares that cover the 32: the first and the last rank's own two,
+    and the 28 between them in two shares (a share is a program to
+    compile, 3 s each; a wrong offset or count at either end or in the
+    middle moves the sum)."""
     import jax.numpy as jnp
 
     import harness
@@ -481,10 +485,10 @@ def test_the_16_ranks_shares_add_up_to_the_uncut_layer():
                                      (D, H), (D, H), (H, D), (D, 1)]
     zero_shared = {6: np.zeros((H, D), np.float32)}
     total = 0.0
-    for rank in range(16):
-        mine = slice(2 * rank, 2 * rank + 2)
+    for first, count in ((0, 2), (2, 14), (16, 14), (30, 2)):
+        mine = slice(first, first + count)
         part, _ = _run_layer(
-            _moe_layer((2 * rank, 2)), x,
+            _moe_layer((first, count)), x,
             {0: ps[0], 1: ps[1][mine], 2: ps[2][mine], 3: ps[3][mine],
              4: ps[4], 5: ps[5], 7: ps[7], **zero_shared})
         total = total + part

@@ -17,14 +17,21 @@ def _qkv(B=2, H=4, T=32, D=16, seed=0):
             rng.randn(B, H, T, D).astype(np.float32))
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_matches_dense(causal):
+def _jit(fn, *args, **kw):
+    """fn(*args, **kw) as ONE program, as a model's step holds it: run op
+    by op the ring is hundreds of programs to compile (410 for the zigzag
+    schedule's gradients), which was these tests' time."""
     import jax
 
+    return jax.jit(lambda *a: fn(*a, **kw))(*args)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_matches_dense(causal):
     mesh = make_mesh({"sp": 8})
     q, k, v = _qkv()
     dense = attention(q, k, v, causal=causal)
-    ring = ring_attention(q, k, v, mesh, causal=causal)
+    ring = _jit(ring_attention, q, k, v, mesh=mesh, causal=causal)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
 
@@ -42,8 +49,8 @@ def test_ring_gradient_matches_dense():
     def loss_ring(q, k, v):
         return jnp.sum(ring_attention(q, k, v, mesh, causal=True) ** 2)
 
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    gd = _jit(jax.grad(loss_dense, argnums=(0, 1, 2)), q, k, v)
+    gr = _jit(jax.grad(loss_ring, argnums=(0, 1, 2)), q, k, v)
     for a, b in zip(gd, gr):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=5e-4, rtol=5e-4)
@@ -54,7 +61,7 @@ def test_ring_with_dp_mesh():
     mesh = make_mesh({"dp": 2, "sp": 4})
     q, k, v = _qkv(B=4, T=16)
     dense = attention(q, k, v)
-    ring = ring_attention(q, k, v, mesh)
+    ring = _jit(ring_attention, q, k, v, mesh=mesh)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
 
@@ -102,7 +109,8 @@ def test_ulysses_matches_dense(causal):
     k = rng.randn(B, H, T, D).astype(np.float32)
     v = rng.randn(B, H, T, D).astype(np.float32)
     mesh = make_mesh({"sp": 8})
-    got = np.asarray(ulysses_attention(q, k, v, mesh, causal=causal))
+    got = np.asarray(_jit(ulysses_attention, q, k, v, mesh=mesh,
+                          causal=causal))
     want = np.asarray(attention(q, k, v, causal=causal))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -155,7 +163,8 @@ def test_ring_flash_matches_dense():
     q, k, v = _qkv(B=1, H=2, T=256, D=32)
     assert flash_ring_eligible(q, mesh, "sp", causal=False, is_train=False)
     dense = attention(q, k, v)
-    flash = ring_attention(q, k, v, mesh, use_flash=True, interpret=True)
+    flash = _jit(ring_attention, q, k, v, mesh=mesh, use_flash=True,
+                 interpret=True)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense),
                                atol=2e-4, rtol=2e-4)
 
@@ -174,8 +183,8 @@ def test_ulysses_flash_matches_dense_and_grads():
     assert flash_ulysses_eligible(q, mesh, "sp")
     for causal in (False, True):
         dense = attention(q, k, v, causal=causal)
-        flash = ulysses_attention(q, k, v, mesh, causal=causal,
-                                  use_flash=True, interpret=True)
+        flash = _jit(ulysses_attention, q, k, v, mesh=mesh, causal=causal,
+                     use_flash=True, interpret=True)
         np.testing.assert_allclose(np.asarray(flash), np.asarray(dense),
                                    atol=2e-4, rtol=2e-4)
 
@@ -187,8 +196,8 @@ def test_ulysses_flash_matches_dense_and_grads():
             q, k, v, mesh, causal=True, use_flash=True, is_train=True,
             interpret=True) ** 2)
 
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = _jit(jax.grad(loss_dense, argnums=(0, 1, 2)), q, k, v)
+    gf = _jit(jax.grad(loss_flash, argnums=(0, 1, 2)), q, k, v)
     for a, b in zip(gd, gf):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=2e-3, rtol=2e-3)
@@ -220,8 +229,8 @@ def test_ring_flash_causal_matches_dense():
     mesh = make_mesh({"sp": 2})
     q, k, v = _qkv(B=1, H=2, T=256, D=32)
     dense = attention(q, k, v, causal=True)
-    flash = ring_attention(q, k, v, mesh, causal=True, use_flash=True,
-                           interpret=True)
+    flash = _jit(ring_attention, q, k, v, mesh=mesh, causal=True,
+                 use_flash=True, interpret=True)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense),
                                atol=2e-4, rtol=2e-4)
 
@@ -245,10 +254,13 @@ def test_ring_flash_causal_train_matches_dense(causal):
             q, k, v, mesh, causal=causal, use_flash=True, is_train=True,
             interpret=True) ** 2)
 
-    assert np.allclose(loss_flash(q, k, v), loss_dense(q, k, v),
-                       rtol=2e-4)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    # the loss and the gradients from one program: the forward's kernels
+    # compile once (the compile is this test's time, at any T)
+    want, gd = _jit(jax.value_and_grad(loss_dense, argnums=(0, 1, 2)),
+                    q, k, v)
+    got, gf = _jit(jax.value_and_grad(loss_flash, argnums=(0, 1, 2)),
+                   q, k, v)
+    assert np.allclose(got, want, rtol=2e-4)
     for name, a, b in zip("qkv", gd, gf):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=2e-3, rtol=2e-3,
@@ -261,8 +273,8 @@ def test_zigzag_causal_ring_matches_dense():
     mesh = make_mesh({"sp": 2})
     q, k, v = _qkv(B=1, H=2, T=512, D=32)
     dense = attention(q, k, v, causal=True)
-    zig = ring_attention(q, k, v, mesh, causal=True, use_flash=True,
-                         schedule="zigzag", interpret=True)
+    zig = _jit(ring_attention, q, k, v, mesh=mesh, causal=True,
+               use_flash=True, schedule="zigzag", interpret=True)
     np.testing.assert_allclose(np.asarray(zig), np.asarray(dense),
                                atol=2e-4, rtol=2e-4)
 
@@ -285,9 +297,10 @@ def test_zigzag_training_grads_match_dense():
             q, k, v, mesh, causal=True, use_flash=True, is_train=True,
             schedule="zigzag", interpret=True) ** 2)
 
-    assert np.allclose(loss_zig(q, k, v), loss_dense(q, k, v), rtol=2e-4)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    gz = jax.grad(loss_zig, argnums=(0, 1, 2))(q, k, v)
+    want, gd = _jit(jax.value_and_grad(loss_dense, argnums=(0, 1, 2)),
+                    q, k, v)
+    got, gz = _jit(jax.value_and_grad(loss_zig, argnums=(0, 1, 2)), q, k, v)
+    assert np.allclose(got, want, rtol=2e-4)
     for name, a, b in zip("qkv", gd, gz):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=2e-3, rtol=2e-3,
@@ -317,9 +330,9 @@ def test_zigzag_pre_permuted_path():
     q, k, v = _qkv(B=1, H=2, T=512, D=32)
     perm, inv = zigzag_permutation(512, 2)
     zq, zk, zv = (np.take(a, perm, axis=2) for a in (q, k, v))
-    out = ring_attention(zq, zk, zv, mesh, causal=True, use_flash=True,
-                         schedule="zigzag", pre_permuted=True,
-                         interpret=True)
+    out = _jit(ring_attention, zq, zk, zv, mesh=mesh, causal=True,
+               use_flash=True, schedule="zigzag", pre_permuted=True,
+               interpret=True)
     out = np.take(np.asarray(out), inv, axis=2)
     dense = attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, np.asarray(dense), atol=2e-4,

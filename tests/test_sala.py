@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _with_vjp
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -72,8 +73,9 @@ def test_lightning_chunked_matches_the_recurrence(chunk):
         [2 ** (-8 * (h + 1) / 8) * (1 - 1 / 3 + 1e-5) for h in (2, 3, 4)])
     q, k, v = (_r(3, 24, 4, seed=s) for s in (1, 2, 3))
     with jax.enable_x64(True):
-        got = lightning_chunked(*(jnp.asarray(a[None]) for a in (q, k, v)),
-                                slopes=slopes, chunk=chunk)
+        got = jax.jit(lambda *a: lightning_chunked(  # op by op it is 40
+            *a, slopes=slopes, chunk=chunk))(
+                *(jnp.asarray(a[None]) for a in (q, k, v)))
     np.testing.assert_allclose(np.asarray(got)[0],
                                _lightning_numpy(q, k, v, slopes), atol=1e-9)
 
@@ -241,12 +243,11 @@ def test_sparse_flash_kernels_match_the_dense_mask():
             q, k, v, mask, D ** -0.5)
         w = jnp.asarray(rng.randn(*q.shape), jnp.float32)
         with jax.enable_x64(False):
-            np.testing.assert_allclose(sparse(q, k, v), dense(q, k, v),
-                                       atol=2e-5)
-            got = jax.grad(lambda *a: jnp.sum(sparse(*a) * w),
-                           argnums=(0, 1, 2))(q, k, v)
-            want = jax.grad(lambda *a: jnp.sum(dense(*a) * w),
-                            argnums=(0, 1, 2))(q, k, v)
+            # the output and the gradients of sum(output * w), one program
+            # a path: the forward's kernels compile once
+            out, got = _with_vjp(sparse, w, q, k, v)
+            ref, want = _with_vjp(dense, w, q, k, v)
+        np.testing.assert_allclose(out, ref, atol=2e-5)
         for g, x in zip(got, want):
             np.testing.assert_allclose(g, x, atol=2e-4)
     finally:

@@ -6,10 +6,13 @@ dense path, rotary positions with a period, a head size that is not hidden
 The toy tower against its plain reference, the mutants and the leak test
 are in tests/benchmarks/test_sdar_cell.py."""
 
+import functools
+
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _dense_masked as _dense, _with_vjp
 from op_test import OpTestHarness
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import attention_ops, llm_ops, moe_ops, registry as reg
@@ -30,17 +33,6 @@ def _allowed(L, b):
     return np.where(r < L,
                     np.where(c < L, r_blk == c_blk, c_blk < r_blk),
                     (c >= L) & (c_blk <= r_blk))
-
-
-def _dense(q, k, v, allowed):
-    import jax
-    import jax.numpy as jnp
-
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    p = jax.nn.softmax(jnp.where(jnp.asarray(allowed), s, -jnp.inf), -1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 # ---------------------------------------------------------------------------
@@ -80,16 +72,18 @@ def test_flash_block_diffusion_matches_dense_masked_attention(case):
         kw = dict(mask=fa.block_diffusion_mask(L, b), interpret=True,
                   block_q=bq, block_k=bk)
         out, lse = fa.flash_attention_fwd(q, k, v, **kw)
-        want, vjp = jax.vjp(lambda *a: _dense(*a, allowed), q, k, v)
+        # the reference and its backward one program: op by op, 60
+        want, grads = _with_vjp(lambda *a: _dense(*a, allowed), do, q, k, v)
         np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, 1)) / 4.0
         np.testing.assert_allclose(
-            lse.reshape(1, -1, T), jax.nn.logsumexp(
-                jnp.where(jnp.asarray(allowed), s, -jnp.inf), axis=-1),
+            lse.reshape(1, -1, T), jax.jit(lambda q, k: jax.nn.logsumexp(
+                jnp.where(jnp.asarray(allowed), jnp.einsum(
+                    "bhqd,bhkd->bhqk", q, jnp.repeat(k, group, 1)) / 4.0,
+                    -jnp.inf), axis=-1))(q, k),
             atol=3e-5, rtol=3e-5)
         got = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
         assert got[1].shape == k.shape and got[2].shape == v.shape
-        for name, a, w in zip(("dq", "dk", "dv"), got, vjp(do)):
+        for name, a, w in zip(("dq", "dk", "dv"), got, grads):
             np.testing.assert_allclose(a, w, atol=1e-4, rtol=1e-4,
                                        err_msg=name)
         if case != "eight_query_heads_on_one":
@@ -101,6 +95,25 @@ def test_flash_block_diffusion_matches_dense_masked_attention(case):
             assert np.asarray(a).tobytes() == np.asarray(w).tobytes()
 
 
+@functools.cache
+def _mutants_control():
+    """Operands where a strip of 4 rows holds two treads (L 64, b 2), dense
+    attention under Allowed on them, and the kernel as it is held to it:
+    once, for the three mutants."""
+    import jax
+    import jax.numpy as jnp
+
+    L, b = 64, 2
+    with jax.enable_x64(False):
+        q, k, v = (jnp.asarray(_rand((1, 1, 2 * L, 16), i)) for i in (1, 2, 3))
+        kw = dict(mask=fa.block_diffusion_mask(L, b), interpret=True,
+                  block_q=32, block_k=32)
+        want = _dense(q, k, v, _allowed(L, b))
+        np.testing.assert_allclose(fa.flash_attention(q, k, v, **kw), want,
+                                   atol=3e-5, rtol=3e-5)
+    return (q, k, v), kw, want
+
+
 @pytest.mark.parametrize("mutant", ["unmasked", "a_tread_late",
                                     "band_without_its_floor"])
 def test_flash_block_diffusion_mask_mutants_fail(mutant, monkeypatch):
@@ -108,7 +121,6 @@ def test_flash_block_diffusion_mask_mutants_fail(mutant, monkeypatch):
     diagonal that is only a staircase moves the output by far more than
     rounding."""
     import jax
-    import jax.numpy as jnp
 
     real = fa._stair_strips
 
@@ -122,19 +134,13 @@ def test_flash_block_diffusion_mask_mutants_fail(mutant, monkeypatch):
         return tuple((r0, c0, w, t and t._replace(span=None))
                      for r0, c0, w, t in out)
 
-    L, b = 64, 2       # strips of 4 rows: two treads a strip
+    (q, k, v), kw, want = _mutants_control()
+    monkeypatch.setattr(fa, "_stair_strips", strips)
+    # the memoized call holds the body that walked the real strips: the
+    # mutant's is built beside it
+    monkeypatch.setattr(fa, "_fwd_call", fa._fwd_call.__wrapped__)
     with jax.enable_x64(False):
-        q, k, v = (jnp.asarray(_rand((1, 1, 2 * L, 16), i)) for i in (1, 2, 3))
-        kw = dict(mask=fa.block_diffusion_mask(L, b), interpret=True,
-                  block_q=32, block_k=32)
-        want = _dense(q, k, v, _allowed(L, b))
-        fa._fwd_call.cache_clear()
-        np.testing.assert_allclose(fa.flash_attention(q, k, v, **kw), want,
-                                   atol=3e-5, rtol=3e-5)
-        monkeypatch.setattr(fa, "_stair_strips", strips)
-        fa._fwd_call.cache_clear()
         got = fa.flash_attention(q, k, v, **kw)
-        fa._fwd_call.cache_clear()
     assert np.abs(np.asarray(got) - np.asarray(want)).max() > 1e-2
 
 
