@@ -324,7 +324,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          num_kv_heads=None, qk_norm_per_head=False,
                          head_dim=None, block_diffusion=None,
                          output_gate=False, rotary_dim=None, window=None,
-                         bias=False, differential=None, kv=None):
+                         bias=False, differential=None, kv=None,
+                         positions=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -367,6 +368,11 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     k | v]; with `kv` = the (K, V1, V2) another such layer's dict holds under
     "made", a query-only projection that attends to that layer's keys and
     values as they were computed there.
+    `positions` ('rope' | 'none'): what a tower that chooses positions
+    layer by layer says of THIS layer, 'rope' with `rope_theta` and 'none'
+    without; it computes nothing and is written into the attention op's
+    desc, which counts the layer by (window, positions)
+    (`attention_layer_kinds_traced_total`).
     `param_attr` is the Q, K and V projections', `out_param_attr` the
     output projection's.
 
@@ -404,9 +410,16 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     if window is not None and (not causal or block_diffusion):
         raise ValueError("multi_head_attention: a sliding window is a "
                          "causal layer's")
+    if positions is not None and positions != (
+            "none" if rope_theta is None else "rope"):
+        raise ValueError(f"multi_head_attention: positions {positions!r} "
+                         f"with rope_theta {rope_theta!r}: 'rope' names a "
+                         f"layer that rotates, 'none' one that does not")
     bias_attr = False if not bias else (bias if isinstance(bias, dict)
                                         else None)
     masked = {"causal": causal}
+    if positions is not None:
+        masked["positions"] = positions
     if window is not None:
         masked.update(mask="window", window=int(window))
     if differential is not None:
@@ -1439,7 +1452,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
         param_attr=None, name=None, top_k=1, gated=False, dropless=False,
         initializer=None, held=None, scoring="softmax", select_bias=None,
         renormalise=False, routed_scale=1.0, buffer_rows=None,
-        shared_hidden=0, renorm_epsilon=None, shared_gate=False):
+        shared_hidden=0, renorm_epsilon=None, shared_gate=False,
+        router_input=None):
     """Mixture-of-experts FFN layer (beyond-reference — SURVEY.md §2.16 last
     row).  `input` [N, D] tokens -> [N, D].  Expert weights are stacked
     [E, D, H]/[E, H, D]; under a ParallelExecutor whose mesh has an 'ep'
@@ -1464,7 +1478,13 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     can overflow, by default), `shared_hidden` (> 0: one more gated
     expert of that width which every token passes, inside the same op) and
     `shared_gate` (that expert's result times sigmoid(x w), w [D, 1] one
-    more parameter after its three: Qwen's `shared_expert_gate`)."""
+    more parameter after its three: Qwen's `shared_expert_gate`).
+
+    `router_input` [N, D] (dropless and shares): the tensor the ROUTER
+    scores in `input`'s place, the op's `RouterX`.  The choice and the
+    weights come from it and their gradient goes to it; the experts still
+    compute `input`'s rows (SmallThinker: the router reads the attention's
+    normed input, the experts the second norm's)."""
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     d_model = input.shape[-1]
 
@@ -1489,6 +1509,11 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
     if share and not dropless:
         raise ValueError("layers.moe: a share of the experts (held) needs "
                          "dropless=True (ops/moe_ops.py)")
+    if router_input is not None and (
+            not dropless or tuple(router_input.shape) != tuple(input.shape)):
+        raise ValueError("layers.moe: router_input is a dropless layer's, "
+                         "one row a token of `input`'s shape "
+                         f"{tuple(input.shape)}")
     stacked = int(held[1]) if share else num_experts
     gate = weight([d_model, num_experts], d_model,
                   param_attr if isinstance(param_attr, dict) else None)
@@ -1512,6 +1537,8 @@ def moe(input, num_experts, d_hidden, capacity_factor=1.0, act="relu",
                                         stop_gradient=True)
     attrs.update({"top_k": int(top_k), "gated": bool(gated),
                   "dropless": True})
+    if router_input is not None:
+        ins["RouterX"] = [router_input.name]
     if not share:
         outs.update({"RouterLogits": [logits.name], "Counts": [counts.name]})
         helper.append_op("moe", inputs=ins, outputs=outs, attrs=attrs)

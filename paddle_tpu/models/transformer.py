@@ -68,8 +68,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
     The block's kinds, GPT-2's by default: `norm` 'layer_norm' or
     'rms_norm' (with `norm_epsilon`); `positions` 'learned' (a table added
-    to the embedding) or 'rope' (Q and K rotated per head, `rope_theta`,
-    in the layers that attend; no other layer sees a position); `qk_norm`
+    to the embedding), 'rope' (Q and K rotated per head, `rope_theta`,
+    in the layers that attend; no other layer sees a position), 'none', or
+    a LIST of `n_layers` entries 'rope' / 'none', the rule layer by layer
+    ('learned' is a whole tower's: the table is added once, before the first
+    block), so that a tower holds layers that rotate beside layers that see
+    no position (SmallThinker: RoPE under the window, none over the whole
+    sequence; `window` is chosen layer by layer the same way); `qk_norm`
     True (an RMSNorm on the whole Q and K projections) or 'head' (on each
     head, one gain for all query heads and one for all key heads);
     `n_kv_heads` (fewer key/value heads than `n_heads`, a divisor of it:
@@ -83,7 +88,14 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     losses (`moe_lm_loss`).  Further keys of `moe` ("held", "scoring",
     "select_bias", "renormalise", "routed_scale", "buffer_rows",
     "shared_hidden": `layers.moe`) make the block one chip's share of its
-    experts; it then appends the layer's `layers.MoeShare`.  The first
+    experts; it then appends the layer's `layers.MoeShare`.  The key
+    "router_input" of `moe` says which tensor the ROUTER scores: 'block'
+    (the default: the expert sub-layer's own normed input, which the
+    experts compute) or 'mixer', the FIRST sub-layer's normed input, the
+    token mixer's, of the same block (SmallThinker's router before
+    attention: `layers.moe(router_input=)`; the weights' gradient reaches
+    the first norm beside the mixer's; under `remat` both sub-layers lie in
+    one `layers.recompute` segment).  The first
     `dense_layers` blocks of an 'moe' tower have a SiLU-gated MLP of width
     `dense_dim` without bias instead (DeepSeek's `first_k_dense_replace`).
     `attention` 'multi_head' or 'latent' with `mla` = {"kv_rank",
@@ -180,9 +192,26 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     the program's `random_seed`; 0: the program's)."""
     if norm not in ("layer_norm", "rms_norm"):
         raise ValueError(f"norm {norm!r}: use 'layer_norm' or 'rms_norm'")
-    if positions not in ("learned", "rope", "none"):
-        raise ValueError(f"positions {positions!r}: use 'learned', 'rope' or "
-                         f"'none' (no layer sees a position)")
+    by_layer = isinstance(positions, (list, tuple))
+    if by_layer:
+        if len(positions) != n_layers or set(positions) - {"rope", "none"}:
+            raise ValueError(
+                f"positions {positions!r}: layer by layer, use {n_layers} "
+                f"of 'rope', 'none' ('learned' is a table added once, "
+                f"before the first block: a whole tower's)")
+        if block_diffusion is not None or mtp is not None:
+            raise ValueError("decoder_lm: block diffusion and the multi-"
+                             "token-prediction module run a tower with "
+                             "rotary positions in every layer")
+    elif positions not in ("learned", "rope", "none"):
+        raise ValueError(f"positions {positions!r}: use 'learned', 'rope', "
+                         f"'none' (no layer sees a position), or a list of "
+                         f"'rope' / 'none' a layer")
+    if moe is not None and moe.get("router_input", "block") not in (
+            "block", "mixer"):
+        raise ValueError(f"moe['router_input'] {moe['router_input']!r}: use "
+                         f"'block' (the expert sub-layer's own input) or "
+                         f"'mixer' (the token mixer's)")
     if ffn not in ("mlp", "gated_mlp", "moe"):
         raise ValueError(f"ffn {ffn!r}: use 'mlp', 'gated_mlp' or 'moe'")
     if attention not in ("multi_head", "latent"):
@@ -253,13 +282,15 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                     if k in ("epsilon", "lambda_attr", "gain_attr")}
             diff["layer_index"] = differential.get(
                 "layer_indices", range(len(layer_types)))[layer]
+        rule = positions[layer] if by_layer else positions
         out = layers.multi_head_attention(
             h, h, h, num_heads=n_heads, causal=bd is None,
             param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
             sp_schedule=sp_schedule,
             qk_norm_epsilon=norm_epsilon if qk_norm else None,
             qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
-            rope_theta=rope_theta if positions == "rope" else None,
+            rope_theta=rope_theta if rule == "rope" else None,
+            **({"positions": rule} if by_layer else {}),
             **({"head_dim": head_dim} if head_dim else {}),
             **({"block_diffusion": bd} if bd else {}),
             **({"output_gate": True} if attention_gate else {}),
@@ -328,7 +359,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 "attn.window" if window and window[layer] else "attn.full"):
             return attend(h, layer)
 
-    def feed_forward(h, layer):
+    def feed_forward(h, layer, mixer_input=None):
         if ffn == "mlp":
             m = layers.fc(h, dim * mlp_ratio, num_flatten_dims=2,
                           param_attr=attr, act="gelu")
@@ -343,7 +374,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         T = h.shape[1]
         kinds = {k: v for k, v in moe.items()
                  if k not in ("num_experts", "d_hidden", "top_k", "act",
-                              "gated")}
+                              "gated", "router_input")}
+        if moe.get("router_input") == "mixer":
+            kinds["router_input"] = layers.reshape(mixer_input, [-1, dim])
         got = layers.moe(
             layers.reshape(h, [-1, dim]), moe["num_experts"],
             moe["d_hidden"], act=moe.get("act", "silu"),
@@ -398,8 +431,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         return layers.elementwise_add(x, out)
 
     def block(x, layer):
-        x = sublayer(x, lambda h: mix(h, layer))
-        return sublayer(x, lambda h: feed_forward(h, layer))
+        seen = []   # the mixer's normed input, for a router that reads it
+
+        def mixer(h):
+            seen.append(h)
+            return mix(h, layer)
+        x = sublayer(x, mixer)
+        return sublayer(x, lambda h: feed_forward(h, layer, seen[0]))
 
     blk = (layers.recompute if remat else contextlib.nullcontext)
     if streams:
@@ -1345,6 +1383,67 @@ def build_qwen3_next_lm_train_program(
              "buffer_rows": buffer_rows,
              "shared_hidden": shared_experts * expert_dim,
              "shared_gate": True},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def build_smallthinker_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, rope_layout, n_heads,
+        n_kv_heads, head_dim, sliding_window, num_experts, expert_dim, top_k,
+        held_experts, first_expert=0, buffer_rows=None, dense_layers=0,
+        norm_epsilon=1e-6, rope_theta=1500000.0, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02, emb_init_scale=None):
+    """SmallThinker-shaped decoder (`model_type` smallthinker:
+    SmallThinker-21BA3B-Instruct) as ONE CHIP'S SHARE of an expert-parallel
+    deployment: RMSNorm pre-norm blocks; grouped-query attention, `n_heads`
+    query heads on `n_kv_heads` key/value heads of `head_dim` (its own
+    width), no bias, no QK-norm, by `layer_types` under a window of
+    `sliding_window` keys that ends with the token ('sliding_attention') or
+    over the whole sequence ('full_attention'), and by `rope_layout` (1 / 0
+    a layer) with rotate-half RoPE at `rope_theta` or with NO position at
+    all; in every block an expert layer whose ROUTER reads the attention's
+    normed input (`moe["router_input"]` 'mixer'), picks `top_k` of
+    `num_experts` on the logits and weighs them by the softmax over the
+    chosen (the softmax over all renormalised over the chosen: the same
+    numbers), and whose ReLU-gated experts, W_down(relu(W_gate g) * W_up g),
+    read the second norm's output g; of those experts this chip holds
+    `held_experts` from `first_expert` on and computes their part in a
+    buffer of `buffer_rows` rows; no shared expert, no dense layer
+    (`dense_layers` is 0; named for whoever reads `train.args`);
+    `vocab_size` is the slice of the vocabulary this chip embeds and
+    scores; untied head.  No block is a `layers.recompute` segment: the
+    cell's step fits without (PERF.md, PR 54).  Loss: next-token cross entropy, no auxiliary term; Adam.  Returns the
+    loss.  Feeds as `build_lm_train_program`."""
+    from .. import optimizer as opt
+
+    kinds = ("full_attention", "sliding_attention")
+    if (set(layer_types) - set(kinds) or len(rope_layout) != len(layer_types)
+            or set(rope_layout) - {0, 1} or dense_layers):
+        raise ValueError(f"layer_types {layer_types!r}: 'full_attention' or "
+                         f"'sliding_attention', each with its entry 0 / 1 of "
+                         f"rope_layout {rope_layout!r}, every block with "
+                         f"experts")
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types), n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions=["rope" if r else "none" for r in rope_layout],
+        rope_theta=rope_theta, n_kv_heads=n_kv_heads, head_dim=head_dim,
+        window=[int(sliding_window) if t == "sliding_attention" else None
+                for t in layer_types],
+        ffn="moe",
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "act": "relu", "router_input": "mixer",
+             "held": (first_expert, held_experts), "scoring": "softmax",
+             "renormalise": True, "buffer_rows": buffer_rows},
         router_outputs=shares, init_scale=init_scale,
         emb_init_scale=emb_init_scale)
     loss = lm_loss(logits, targets, dtype=dtype)

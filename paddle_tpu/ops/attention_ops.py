@@ -35,6 +35,14 @@ _MET_ATTN_LAYERS = _MET.counter(
     "those under the block-diffusion mask; flash_window: under a sliding "
     "window; dense: XLA's fused softmax; "
     "ring, alltoall: sequence parallel)")
+_MET_LAYER_KINDS = _MET.counter(
+    "attention_layer_kinds_traced_total",
+    "scaled_dot_product_attention ops traced whose desc states the layer's "
+    "position rule (attr `positions`, which `layers.multi_head_attention` "
+    "writes for a tower that chooses positions layer by layer; forward "
+    "emission; once a compile, not once a step), by the sliding window's "
+    "width (window; 0: the whole sequence) and the rule (positions: rope, "
+    "none)")
 _MET_FLASH_CALLS = _MET.counter(
     "flash_calls_total",
     "calls of the flash kernels the op scaled_dot_product_attention made on "
@@ -346,6 +354,10 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     def traced(path):
         if not ctx.in_grad_replay():
             _MET_ATTN_LAYERS.inc(layout=layout, path=path)
+            if attrs.get("positions"):
+                _MET_LAYER_KINDS.inc(
+                    window=str(bd[1] if windowed else 0),
+                    positions=str(attrs["positions"]))
             if path.startswith("flash"):
                 _MET_FLASH_CALLS.inc(mask=(
                     "window" if windowed else "block_diffusion"

@@ -64,6 +64,13 @@ _MET_ROWS_TO_TOKENS = _MET.counter(
     "autodiff's transpose, at generic_grad's re-emission) and by path "
     "(segment_sum: the kernel segment-sum-rows over token-sorted rows; "
     "scatter_add: XLA's)")
+_MET_ROUTER_INPUT = _MET.counter(
+    "moe_router_input_traced_total",
+    "dropless expert layers and shares traced (forward emission; once a "
+    "compile, not once a step), by the tensor their router scores: "
+    "source=block (the op's X, the rows the experts compute) or mixer (the "
+    "op's RouterX: another tensor, which `decoder_lm` fills with the token "
+    "mixer's normed input; SmallThinker's router before attention)")
 _MET_GROUPED_BWD = _MET.counter(
     "moe_grouped_backward_total",
     "grouped expert matmuls whose backward was traced (once a compile, not "
@@ -184,8 +191,9 @@ def _grouped_matmul(ctx, xs, w, counts):
     return lax.ragged_dot(xs, w, counts)
 
 
-def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act):
-    """-> (out [T, D], router logits [T, E] f32, counts [E] f32).
+def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act, router_x=None):
+    """-> (out [T, D], router logits [T, E] f32, counts [E] f32).  The
+    router scores `router_x` [T, D] where given, else x.
 
     Slot s = t * k + j is token t's j-th choice.  `order` lists the slots
     by expert (stable, so by token within an expert), `inv` is its
@@ -196,7 +204,8 @@ def _moe_dropless(ctx, x, gate_w, wi, wu, wo, top_k, act):
     T, D = x.shape
     n_exp = wi.shape[0]
     with part_scope("moe.route"):
-        logits, weights, experts = _route_top_k(x, gate_w, top_k)
+        logits, weights, experts = _route_top_k(
+            x if router_x is None else router_x, gate_w, top_k)
     with part_scope("moe.permute"):
         flat = experts.reshape(-1).astype(jnp.int32)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
@@ -251,10 +260,12 @@ def _route_scored(x, gate_w, bias, top_k, scoring, renormalise, scale,
 
 
 def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
-               rows, route):
+               rows, route, router_x=None):
     """-> (out [T, D], scores [T, E] f32, top-k weights [T, k] f32, counts
     [E] f32, held pairs [1] f32, dropped pairs [1] f32) for the experts
     [first, first + held) of E, held = wi.shape[0], E = gate_w.shape[1].
+    The router scores `router_x` [T, D] where given, else x: scores, choice
+    and weights come from it, the rows the experts compute from x.
 
     The (token, expert) pairs on held experts are sorted to the front, by
     expert (stable, so by token within one), and the first `rows` of them
@@ -284,8 +295,9 @@ def _moe_share(ctx, x, gate_w, bias, wi, wu, wo, shared, top_k, act, first,
     T, D = x.shape
     held, n_exp = wi.shape[0], gate_w.shape[1]
     with part_scope("moe.route"):
-        scores, weights, experts = _route_scored(x, gate_w, bias, top_k,
-                                                 **route)
+        scores, weights, experts = _route_scored(
+            x if router_x is None else router_x, gate_w, bias, top_k,
+            **route)
     wide = scores.dtype
     with part_scope("moe.permute"):
         flat = experts.reshape(-1).astype(jnp.int32)           # [T * k]
@@ -526,6 +538,11 @@ def moe(ctx, ins, attrs):
     (False; then WU [E, D, H] is an input too), capacity_factor unused;
     outputs Out, RouterLogits [T, E] float32 and Counts [E] float32 (the
     (token, expert) pairs each expert computed; they sum to T * top_k).
+    An optional input RouterX [T, D] is what the ROUTER reads in X's place
+    (SmallThinker's router reads the attention's input): scores, choice and
+    weights come from it and the weights' gradient goes to it; the rows the
+    experts compute, and their gradient, are X's.  Absent, the op traces as
+    it did before the input existed.
 
     With `dropless` and `first_expert` (a share; the module's docstring):
     Gate is [D, E] and WI / WU / WO stack the HELD experts [first_expert,
@@ -573,14 +590,21 @@ def moe(ctx, ins, attrs):
             raise ValueError(f"moe op: top_k {top_k} not in "
                              f"[1, {gate_w.shape[1]}]")
         wu = ins["WU"][0] if gated else None
+        router_x = ins["RouterX"][0] if ins.get("RouterX") else None
+        if router_x is not None and router_x.shape != x.shape:
+            raise ValueError(f"moe op: RouterX {router_x.shape} is not X's "
+                             f"{x.shape}: the router scores one row a token")
+        if not ctx.in_grad_replay():
+            _MET_ROUTER_INPUT.inc(
+                source="block" if router_x is None else "mixer")
         if "first_expert" in attrs:
             return _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo,
-                               top_k, act)
+                               top_k, act, router_x)
         if not ctx.in_grad_replay():
             _MET_MOE_LAYERS.inc(top_k=str(top_k), experts=str(n_exp),
                                 impl="ragged_dot")
         out, logits, counts = _moe_dropless(ctx, x, gate_w, wi, wu, wo,
-                                            top_k, act)
+                                            top_k, act, router_x)
         return {"Out": [out], "RouterLogits": [logits], "Counts": [counts]}
     if top_k != 1 or gated:
         raise ValueError(
@@ -606,7 +630,8 @@ def moe(ctx, ins, attrs):
     return {"Out": [out]}
 
 
-def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act):
+def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act,
+                router_x=None):
     """The `moe` op's share form: attrs and optional inputs to
     `_moe_share`, and its six outputs to their slots."""
     held, n_exp = wi.shape[0], gate_w.shape[1]
@@ -634,7 +659,8 @@ def _emit_share(ctx, ins, attrs, x, gate_w, wi, wu, wo, top_k, act):
         rows, {"scoring": scoring,
                "renormalise": bool(attrs.get("renormalise", False)),
                "scale": float(attrs.get("routed_scale", 1.0)),
-               "epsilon": float(attrs.get("renorm_epsilon", 1e-20))})
+               "epsilon": float(attrs.get("renorm_epsilon", 1e-20))},
+        router_x)
     return {"Out": [out], "RouterScores": [scores],
             "RouterWeights": [weights], "Counts": [counts],
             "HeldPairs": [pairs], "DroppedPairs": [dropped]}

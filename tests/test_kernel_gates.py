@@ -47,6 +47,11 @@ MECHANISMS = {
     # four attention layers of two flash calls each, two of them under the
     # sliding window's region; the selective scan is plain XLA
     "phi4-mini-flash": {"flash", "flash_window"},
+    # one full-span layer on the projections' layout (28 query heads on 4:
+    # its heads are split inside the op) and three under a 4096-key window
+    # of 16384 tokens; RoPE's kernel in the window layers alone
+    "smallthinker-21b-a3b": {"flash", "flash_window", "head_norm_rope",
+                             "grouped_matmul", "segment_sum"},
 }
 
 
