@@ -8,12 +8,16 @@ channel:
   h_t = exp(Delta_t A) * h_{t-1} + (Delta_t u_t) B_t^T;  y_t = h_t C_t + D u_t
 
 No [d, d] product is in it: every operation is elementwise over the state,
-so what bounds it is how often the state crosses HBM.  The emission runs in
-CHUNKS of tokens: a `lax.scan` over the chunks carries the float32 state,
-and a chunk's per-token states exist only while that chunk runs, forward or
-backward (the chunk's body is a `jax.checkpoint`: the vjp keeps one state a
-chunk and makes a chunk's states again from its incoming one).  Never a [T,
-d_inner, d_state] tensor.
+so what bounds it is how often the state crosses HBM.  Both emissions run
+in CHUNKS of tokens, keep one float32 state a chunk for the backward and
+make a chunk's per-token states again from its incoming one; never a [T,
+d_inner, d_state] tensor.  On one TPU, at whole tiles, the scan is the
+Pallas kernel pair of ops/pallas_kernels/selective_scan.py: the state stays
+in VMEM from the first chunk to the last and only U, Dt, B, C, Out and
+their gradients cross HBM.  Everywhere else (the CPU, float64, a mesh,
+`PADDLE_TPU_NO_FUSED_KERNELS`, other shapes) `selective_scan_chunked`: a
+`lax.scan` over the chunks whose body is a `jax.checkpoint` of a `lax.scan`
+over the tokens, the state through HBM once a token; the kernels' oracle.
 
 `causal_conv_silu` (the short convolution in front of the scan, a layer of
 its own here) and `silu_gate` (the output gate, and the gated memory unit's
@@ -30,16 +34,25 @@ from .registry import register_cost, register_op
 _MET_SCAN = _MET.counter(
     "selective_scan_total",
     "selective state-space scans traced (forward emission; once a compile, "
-    "not once a step), by the emission taken (impl: xla_chunked, a lax.scan "
-    "over chunks of tokens with the chunk's body under jax.checkpoint), the "
-    "inner width (d_inner), the state a channel (d_state) and the tokens a "
-    "chunk (chunk)")
+    "not once a step), by the emission taken (impl: pallas, the kernel pair "
+    "of ops/pallas_kernels/selective_scan.py with the state in VMEM; "
+    "xla_chunked, a lax.scan over chunks of tokens with the chunk's body "
+    "under jax.checkpoint), the inner width (d_inner), the state a channel "
+    "(d_state) and the tokens a chunk of that emission (chunk)")
+_MET_SCAN_KERNELS = _MET.counter(
+    "selective_scan_kernels_traced_total",
+    "emissions of the selective scan (once a compile, not once a step), by "
+    "the op that emits it (fwd: selective_scan; grad: a re-emission under a "
+    "grad op's jax.vjp, its own or its `layers.recompute` segment's) and "
+    "the path taken (pallas: the kernel pair of "
+    "ops/pallas_kernels/selective_scan.py; xla: selective_scan_chunked)")
 
-# Tokens a chunk of the op's scan: a constant, not a knob.  What the backward
-# keeps is one [d_inner, d_state] float32 state a chunk (T / SCAN_CHUNK x 327
-# KB at 5120 x 16) and, while one chunk's backward runs, that chunk's
-# per-token residuals.  On the v5e 32 and 128 were no faster at the cell's
-# shape (PERF.md section 6, PR 52).
+# Tokens a chunk of the plain emission's scan (the kernels' is their own
+# CHUNK): a constant, not a knob.  What the backward keeps is one [d_inner,
+# d_state] float32 state a chunk (T / SCAN_CHUNK x 327 KB at 5120 x 16) and,
+# while one chunk's backward runs, that chunk's per-token residuals.  On the
+# v5e 32 and 128 were no faster at the cell's shape (PERF.md section 6, PR
+# 52): a `while` iteration a token is the cost, which is why the kernels are.
 SCAN_CHUNK = 64
 
 
@@ -91,10 +104,24 @@ def selective_scan(ctx, ins, attrs):
     The state, Delta, the exponent and the sums are float32 (float64 for
     float64 inputs); one rounding to U's dtype at the end.  Out is the
     mixer's MEMORY: the scan's result with the D term, before any gate.
+
+    On one TPU, where U is bf16 or float32, the kernels' chunk divides T and
+    Di and N are whole tiles (`selective_scan.usable`), both parts are the
+    kernel pair of ops/pallas_kernels/selective_scan.py under one
+    `jax.custom_vjp` (Delta is made inside, from Dt in its own dtype): a
+    forward emission's one launch hands out Out and every chunk's incoming
+    state, kept beside the output (`ctx.keep_for_grad`), and its grad op's
+    re-emission differentiates through them as the reverse pass alone;
+    inside a `layers.recompute` segment's replay the one forward launch
+    under the vjp keeps the states for the reverse pass.  Everywhere else
     `selective_scan_chunked` in chunks of SCAN_CHUNK tokens (T a multiple
-    of it, or shorter); `selective_scan_total` says which emission ran."""
+    of it, or shorter).  `selective_scan_total` and
+    `selective_scan_kernels_traced_total` say which emission ran."""
     import jax
     import jax.numpy as jnp
+
+    from .pallas_kernels import selective_scan as kernels
+    from .pallas_kernels._common import pallas_dispatch_ok
 
     u, dt, xp = ins["U"][0], ins["Dt"][0], ins["XProj"][0]
     a_log, d, bias = ins["ALog"][0], ins["D"][0], ins["DtBias"][0]
@@ -105,9 +132,35 @@ def selective_scan(ctx, ins, attrs):
             or xp.shape != (B, T, R + 2 * N)):
         raise ValueError(f"selective_scan: U {u.shape}, Dt {dt.shape}, XProj "
                          f"{xp.shape}, ALog {a_log.shape} at dt_rank {R}")
-    if not ctx.in_grad_replay():
-        _MET_SCAN.inc(impl="xla_chunked", d_inner=str(Di), d_state=str(N),
-                      chunk=str(min(SCAN_CHUNK, T)))
+    take = pallas_dispatch_ok(ctx) and kernels.usable(T, kernels.CHUNK, Di,
+                                                      N, u.dtype)
+    replay = ctx.in_grad_replay()
+    if not replay:
+        _MET_SCAN.inc(impl="pallas" if take else "xla_chunked",
+                      d_inner=str(Di), d_state=str(N),
+                      chunk=str(kernels.CHUNK if take
+                                else min(SCAN_CHUNK, T)))
+    _MET_SCAN_KERNELS.inc(op="grad" if replay else "fwd",
+                          path="pallas" if take else "xla")
+    if take:
+        with part_scope("ssm.xdt"):
+            ops = (u, dt, xp[..., R:R + N], xp[..., R + N:], a_log, d, bias)
+        kept = ctx.kept_for_grad()
+        with part_scope("ssm.scan"):
+            scan = kernels.make_selective_scan()
+            if kept is not None:
+                out = scan.from_saved(*ops, *kept)
+            elif ctx.is_test:
+                out = kernels.selective_scan_fwd(*ops)
+            elif replay:
+                out = scan(*ops)
+            else:
+                out, states = scan.keeping(*ops)
+                ctx.keep_for_grad(attrs, [out], (out, states))
+        # a re-emission that was handed nothing (a recompute segment's
+        # replay) launches the one forward that keeps the states
+        ctx.kernel_forward(reused=kept is not None)
+        return {"Out": [out]}
     wide = wide_dtype(u.dtype)
     with part_scope("ssm.xdt"):
         delta = jax.nn.softplus(dt.astype(wide) + bias.astype(wide))

@@ -186,6 +186,98 @@ def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):
 
 
 # ---------------------------------------------------------------------------
+# the selective scan's pair (PR 55): one forward launch a trace of the layer
+
+
+def _step_kernels(fetch, feed):
+    """[(kernel, outputs)] of the Pallas calls in the step as the executor
+    hands it to XLA, less the dead ones (a call whose results feed nothing
+    goes, as XLA's DCE takes it: the primal pass of generic_grad's jax.vjp
+    over a `jax.checkpoint`), in program order: what a step LAUNCHES (a spy
+    on the Python calls also sees what is traced and never run)."""
+    import jax
+    from jax._src.interpreters import partial_eval as pe
+
+    from paddle_tpu.framework.core import np_dtype
+
+    main = fluid.default_main_program()
+    block = main.blocks[0]
+    exe = fluid.Executor(fluid.CPUPlace())
+    feed_vals = exe._prepare_feeds(block, feed)
+    compiled = exe._compile(main, 0, feed_vals, fetch)
+
+    def of_var(n):
+        v = block._find_var_recursive(n)
+        return jax.ShapeDtypeStruct(tuple(v.shape), np_dtype(v.dtype))
+
+    jaxpr = jax.make_jaxpr(compiled.fn)(
+        {n: of_var(n) for n in compiled.rw_state},
+        {n: of_var(n) for n in compiled.external_reads}, feed_vals,
+        jax.ShapeDtypeStruct((2,), np.uint32))
+
+    def eqns(j):
+        for e in j.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    live, _ = pe.dce_jaxpr(jaxpr.jaxpr, [True] * len(jaxpr.jaxpr.outvars))
+    return [(e.params["name"], len(e.outvars)) for e in eqns(live)
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("remat,reused", [(False, ("selective_scan", "1")),
+                                          (True, ("recompute", "0"))])
+def test_mamba_layers_reverse_pass_is_handed_the_kept_states(remat, reused,
+                                                             monkeypatch):
+    """A Mamba layer at toy size.  Alone, its grad op's re-emission is
+    handed what the forward op kept (Out and the chunks' states: two
+    outputs) and launches the reverse pass alone.  Inside a
+    `layers.recompute` segment the replay under the segment's vjp launches
+    the forward ONCE more, keeping the states, and the reverse pass is
+    handed those: never a forward of its own, never a third.  The
+    gradients are the plain emission's."""
+    import contextlib
+
+    from paddle_tpu.ops.pallas_kernels import selective_scan as ss
+
+    feed = {"x": np.random.RandomState(7).randn(2, 64, 64)
+            .astype(np.float32)}
+
+    def build():
+        fluid.reset()
+        x = fluid.layers.data("x", shape=[64, 64], dtype="float32")
+        with (fluid.layers.recompute if remat else contextlib.nullcontext)():
+            y = fluid.layers.mamba(x, d_state=8)
+        loss = fluid.layers.mean(y * y)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        block = fluid.default_main_program().global_block()
+        return [loss.name] + [p.name + "@GRAD"
+                              for p in block.all_parameters()]
+
+    def step():
+        fetch = build()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
+
+    want = step()
+    assert _counter() == {}
+    real_make = ss.make_selective_scan
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(ss, "make_selective_scan",
+                        lambda: real_make(ss.CHUNK, True))
+    got = step()
+    assert _counter() == {reused: 1.0}
+    assert len(got) == 10       # the loss and the mixer's nine parameters
+    for a, b in zip(got, want):
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max()
+    assert _step_kernels(build(), feed) == (
+        [(ss.FWD, 2)] * (2 if remat else 1) + [(ss.BWD, 6)])
+
+
+# ---------------------------------------------------------------------------
 # flash_score_elements_total: how much of the square the causal kernels do
 
 SCORES = "flash_score_elements_total"

@@ -6,9 +6,9 @@ is put to its kernel's own gate at the shapes its desc carries: the flash
 blocks snap (the kernels' defaults, which `knobs.flash_blocks` hands back
 where no variable is set), and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
-`hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable` and
-`gated_delta.usable` say yes.  Nothing compiles: milliseconds where the AOT
-tests of the same cells take minutes."""
+`hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable`,
+`gated_delta.usable` and `selective_scan.usable` say yes.  Nothing
+compiles: milliseconds where the AOT tests of the same cells take minutes."""
 
 import glob
 import importlib
@@ -24,7 +24,8 @@ from paddle_tpu.ops import sparse_linear_ops
 from paddle_tpu.ops.pallas_kernels import (flash_attention, gated_delta,
                                            grouped_matmul, head_norm_rope,
                                            hyper_connection, segment_sum,
-                                           short_conv, sparse_flash)
+                                           selective_scan, short_conv,
+                                           sparse_flash)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -45,8 +46,8 @@ MECHANISMS = {
     "qwen3-next-80b-a3b": {"flash", "grouped_matmul", "segment_sum",
                            "gated_delta"},
     # four attention layers of two flash calls each, two of them under the
-    # sliding window's region; the selective scan is plain XLA
-    "phi4-mini-flash": {"flash", "flash_window"},
+    # sliding window's region; three selective scans of 5120 channels
+    "phi4-mini-flash": {"flash", "flash_window", "selective_scan"},
     # one full-span layer on the projections' layout (28 query heads on 4:
     # its heads are split inside the op) and three under a 4096-key window
     # of 16384 tokens; RoPE's kernel in the window layers alone
@@ -132,6 +133,13 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
                 T, min(sparse_linear_ops.DELTA_CHUNK, T), Dk, Dv,
                 dtype(op, "X"), Hv // Hk), (T, Dk, Dv, Hv // Hk)
             passed.add("gated_delta")
+            positions = max(positions, T)
+        elif op.type == "selective_scan":
+            _, T, Di = shape(op, "U")
+            N = shape(op, "ALog")[1]
+            assert selective_scan.usable(T, selective_scan.CHUNK, Di, N,
+                                         dtype(op, "U")), (T, Di, N)
+            passed.add("selective_scan")
             positions = max(positions, T)
         elif op.type == "head_norm_rope" and "rotary_dim" not in op.attrs:
             _, T, width = shape(op, "X")
