@@ -244,12 +244,10 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     latent attention), a positive float the three kernels take as theirs.
     -> None where it does not apply, else (out, saved).
 
-    Training goes through the custom_vjp pair (FlashAttention-2-style
-    blockwise backward), which generic_grad's jax.vjp honors, and the
-    forward kernel runs once a layer: `saved` is the (out, lse) pair the
-    calling emitter keeps beside ITS outputs (`ctx.keep_for_grad`), and
-    its grad op's re-emission differentiates through them with no second
-    launch (`saved` is None there, and in inference)."""
+    Training goes through the kernel pair (FlashAttention-2-style
+    blockwise backward) by `ctx.run_pair`: `saved` is the (out, lse) pair
+    the calling emitter keeps beside ITS outputs (`ctx.keep_for_grad`),
+    None wherever nothing is to be kept."""
     from .pallas_kernels._common import pallas_dispatch_ok
 
     if not pallas_dispatch_ok(ctx):
@@ -290,18 +288,11 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
                             else fa.block_diffusion_mask(*mask)),
                       block_q=fa.MASK_BLOCKS[0], block_k=fa.MASK_BLOCKS[1])
         causal = causal and not window
-    if ctx.is_test:
+    if ctx.is_test:     # before the pair is made: an inference program
+        # builds no training function
         return fa.flash_attention(q, k, v, causal=causal, **layout), None
-    train = fa.make_flash_train(causal=causal, **layout)
-    kept = ctx.kept_for_grad()
-    saved = None
-    if kept is not None:
-        out = train.from_saved(q, k, v, *kept)
-    else:
-        out, lse = train.with_lse(q, k, v)
-        saved = (out, lse)
-    ctx.kernel_forward(reused=kept is not None)
-    return out, saved
+    return ctx.run_pair(fa.make_flash_train(causal=causal, **layout),
+                        (q, k, v))
 
 
 @register_op("scaled_dot_product_attention")

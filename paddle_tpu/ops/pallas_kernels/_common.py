@@ -1,6 +1,10 @@
-"""Shared plumbing for the fused recurrence kernels (lstm.py, gru.py):
-VMEM handle, padded-step mask, and the common eligibility gates — one
-place to adjust the VMEM budget or lane constraints for both."""
+"""What the Pallas kernel files share: the gates an emitter passes before it
+takes a kernel (`pallas_dispatch_ok`, `kernels_enabled`, `traced_path`), the
+kernel-pair protocol (`kernel_pair`: the `jax.custom_vjp` triple a forward op
+and its grad op's re-emission split between them, `EmitContext.run_pair`'s
+other half), and the recurrence kernels' plumbing (lstm.py, gru.py: the VMEM
+handle and budgets, the padded-step mask, the lane gate, the reversal within
+a row's length)."""
 
 from __future__ import annotations
 
@@ -47,6 +51,64 @@ def kernels_enabled() -> bool:
     import os
 
     return not os.environ.get("PADDLE_TPU_NO_FUSED_KERNELS")
+
+
+def traced_path(ctx, family, usable: bool) -> bool:
+    """Whether this emission takes its kernels: `pallas_dispatch_ok` and
+    the kernel file's own `usable(...)` answer.  Counts the emission in
+    `family`, a counter labelled {op, path}: op=fwd for a forward emission
+    and grad for generic_grad's re-emission of it, path=pallas or xla."""
+    take = pallas_dispatch_ok(ctx) and bool(usable)
+    family.inc(op="grad" if ctx.in_grad_replay() else "fwd",
+               path="pallas" if take else "xla")
+    return take
+
+
+def kernel_pair(operands: int, bare, forward, backward):
+    """A kernel file's launches as what a forward op and its grad op's
+    re-emission split between them (`EmitContext.run_pair` picks;
+    ops/registry.py has the protocol).  The file declares, beside the count
+    of its `operands`: `bare(*ops) -> out` for inference; `forward(*ops,
+    keep) -> (out, *residuals)`, which under the plain rule (`keep` False)
+    may keep all the same or return (out,) alone and make the residuals
+    again in the backward: its own business; `backward(ops, do, kept) ->
+    the operands' cotangents`, `kept` what the forward that ran returned.
+    The two open whatever `part_scope` a launch must run under, and the
+    file memoizes the pair (every trace must meet the same functions).  ->
+
+      pair(*ops) -> out        a `jax.custom_vjp` anything differentiates:
+                               a `layers.recompute` segment, a stage
+      pair.keeping(*ops) -> (out, *residuals)
+                               the residuals leave for `from_saved`, never
+                               as values a loss depends on: their
+                               cotangents are dropped
+      pair.from_saved(*ops, out, *residuals) -> out
+                               launches nothing forward, differentiates as
+                               the backward alone (what was kept gets no
+                               gradient)
+      pair.bare                `bare` itself"""
+    import jax
+
+    def rule(keep):
+        def fwd(*ops):
+            kept = tuple(forward(*ops, keep=keep))
+            return (kept if keep else kept[0]), (ops, kept)
+        return fwd
+
+    def bwd(res, do):
+        ops, kept = res
+        return tuple(backward(ops, do, kept))
+
+    pair = jax.custom_vjp(lambda *ops: forward(*ops, keep=False)[0])
+    pair.defvjp(rule(False), bwd)
+    keeping = jax.custom_vjp(lambda *ops: tuple(forward(*ops, keep=True)))
+    keeping.defvjp(rule(True), lambda res, cts: bwd(res, cts[0]))
+    from_saved = jax.custom_vjp(lambda *a: a[operands])
+    from_saved.defvjp(
+        lambda *a: (a[operands], (a[:operands], a[operands:])),
+        lambda res, do: bwd(res, do) + (None,) * len(res[1]))
+    pair.bare, pair.keeping, pair.from_saved = bare, keeping, from_saved
+    return pair
 
 
 def reverse_within_length(x, lengths, pad_fill=None):

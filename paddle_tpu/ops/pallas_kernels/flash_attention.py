@@ -1565,71 +1565,35 @@ _TRAIN_CACHE = {}
 def make_flash_train(causal: bool = False, scale=None, interpret=False,
                      block_q: int = 512, block_k: int = 1024, heads=None,
                      mask=None):
-    """custom_vjp fused attention for TRAINING (honored by generic_grad's
-    jax.vjp like the recurrence kernels).  Memoized per (causal, scale,
-    interpret, blocks, heads, mask): emitters call this on every
-    trace, and a fresh wrapper each time would defeat jit's
+    """Fused attention for TRAINING as a `kernel_pair` (_common.py; honored
+    by generic_grad's jax.vjp like the recurrence kernels).  Memoized per
+    (causal, scale, interpret, blocks, heads, mask): emitters call this on
+    every trace, and a fresh wrapper each time would defeat jit's
     function-identity caching (ADVICE r2).  `heads`: the operands are
     [B, T, heads * D] (_call_of).
 
-    The returned function carries the pair a forward op and its grad op
-    split between them, so the forward kernel runs once a layer and not
-    again when generic_grad re-emits the op under jax.vjp (two Mosaic
-    calls are not merged by XLA's CSE the way a re-emitted HLO forward
-    is): `.with_lse(q, k, v) -> (out, lse)` is the same forward handing
-    out its logsumexp, and `.from_saved(q, k, v, out, lse) -> out`
-    launches nothing forward and differentiates as the flash backward on
-    the saved pair.  scaled_dot_product_attention uses both."""
+    The returned function carries what a forward op and its grad op split
+    between them, so the forward kernel runs once a layer and not again
+    when generic_grad re-emits the op under jax.vjp (two Mosaic calls are
+    not merged by XLA's CSE the way a re-emitted HLO forward is):
+    `.keeping(q, k, v) -> (out, lse)`, `.with_lse` by the name its callers
+    use, is the same forward handing out its logsumexp, and
+    `.from_saved(q, k, v, out, lse) -> out` launches nothing forward and
+    differentiates as the flash backward on the saved pair; `.bare` is
+    `flash_attention`, the forward that writes no logsumexp.
+    scaled_dot_product_attention uses them (`ctx.run_pair`)."""
     key = (causal, scale, interpret, block_q, block_k, heads, mask)
     cached = _TRAIN_CACHE.get(key)
     if cached is not None:
         return cached
-    import jax
+    from ._common import kernel_pair
 
     kw = dict(causal=causal, scale=scale, interpret=interpret,
               block_q=block_q, block_k=block_k, heads=heads, mask=mask)
-
-    @jax.custom_vjp
-    def attn(q, k, v):
-        out, _ = flash_attention_fwd(q, k, v, **kw)
-        return out
-
-    def fwd(q, k, v):
-        out, lse = flash_attention_fwd(q, k, v, **kw)
-        return out, (q, k, v, out, lse)
-
-    def bwd(res, do):
-        q, k, v, out, lse = res
-        return flash_attention_bwd(q, k, v, out, lse, do, **kw)
-
-    attn.defvjp(fwd, bwd)
-
-    @jax.custom_vjp
-    def with_lse(q, k, v):
-        return flash_attention_fwd(q, k, v, **kw)
-
-    def with_lse_fwd(q, k, v):
-        out, lse = flash_attention_fwd(q, k, v, **kw)
-        return (out, lse), (q, k, v, out, lse)
-
-    def with_lse_bwd(res, cts):
-        # lse leaves as a residual for `from_saved`, never as a value the
-        # loss depends on: its cotangent is dropped
-        return bwd(res, cts[0])
-
-    with_lse.defvjp(with_lse_fwd, with_lse_bwd)
-
-    @jax.custom_vjp
-    def from_saved(q, k, v, out, lse):
-        return out
-
-    def from_saved_fwd(q, k, v, out, lse):
-        return out, (q, k, v, out, lse)
-
-    def from_saved_bwd(res, do):
-        return bwd(res, do) + (None, None)
-
-    from_saved.defvjp(from_saved_fwd, from_saved_bwd)
-    attn.with_lse, attn.from_saved = with_lse, from_saved
+    attn = kernel_pair(
+        3, lambda q, k, v: flash_attention(q, k, v, **kw),
+        lambda q, k, v, keep: flash_attention_fwd(q, k, v, **kw),
+        lambda ops, do, kept: flash_attention_bwd(*ops, *kept, do, **kw))
+    attn.with_lse = attn.keeping
     _TRAIN_CACHE[key] = attn
     return attn

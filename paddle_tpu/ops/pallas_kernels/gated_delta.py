@@ -23,8 +23,8 @@ and only q, k, v, the two gates, o and their gradients cross HBM.
                    heads in the step, rounded once), dv, and the rows of
                    d gamma and d beta.
 
-`make_gated_delta(chunk)` is the `jax.custom_vjp` over the pair, and what a
-forward op and its grad op split between them.  Nothing of size [C, C] or [N,
+`make_gated_delta(chunk)` is the `kernel_pair` (_common.py) over the two: what
+a forward op and its grad op split between them.  Nothing of size [C, C] or [N,
 Dk, Dv] has to live across a step: the plain function keeps q, k, v, g and
 beta, and its backward runs the forward kernel again with `keep=True` and no
 O (the states and Tm, 134 MB each a layer at the cell's shape, alive while
@@ -392,44 +392,18 @@ def gated_delta_bwd(do, q, k, v, g, beta, chunk, kept=None, *,
 
 @functools.lru_cache(maxsize=None)
 def make_gated_delta(chunk: int, interpret: bool = False):
-    """The scan as a `jax.custom_vjp` (q, k, v, g, beta) -> O, memoized a
-    chunk so that every trace meets the same function.  Its backward makes
-    the states and Tm again.  `.keeping(q, k, v, g, beta) -> (O, states,
-    Tm)` is the same forward handing them out, and `.from_saved(q, k, v, g,
-    beta, O, states, Tm) -> O` launches nothing forward and differentiates
-    as the reverse pass over them: what a forward op and its grad op's
-    re-emission split between them (`ctx.keep_for_grad`)."""
-    import jax
+    """The scan (q, k, v, g, beta) -> O as a `kernel_pair` (_common.py: the
+    differentiable pair, `.keeping -> (O, states, Tm)`, `.from_saved(...,
+    O, states, Tm)`), memoized a chunk so that every trace meets the same
+    function.  Under the plain rule the forward keeps nothing and the
+    backward makes the states and Tm again."""
+    from ._common import kernel_pair
 
     def forward(*ops, keep=False):
-        return gated_delta_fwd(*ops, chunk, keep=keep, interpret=interpret)
+        got = gated_delta_fwd(*ops, chunk, keep=keep, interpret=interpret)
+        return (got[0], *got[1]) if keep else (got,)
 
-    def backward(ops, do, kept=None):
-        return gated_delta_bwd(do, *ops, chunk, kept, interpret=interpret)
-
-    scan = jax.custom_vjp(forward)
-    scan.defvjp(lambda *ops: (forward(*ops), ops), backward)
-
-    @jax.custom_vjp
-    def keeping(*ops):
-        out, kept = forward(*ops, keep=True)
-        return (out, *kept)
-
-    def keeping_fwd(*ops):
-        out, *kept = keeping(*ops)
-        return (out, *kept), (ops, tuple(kept))
-
-    # the states and Tm leave as residuals for `from_saved`, never as
-    # values a loss depends on: their cotangents are dropped
-    keeping.defvjp(keeping_fwd, lambda res, cts: backward(res[0], cts[0],
-                                                          res[1]))
-
-    @jax.custom_vjp
-    def from_saved(q, k, v, g, beta, out, states, tm):
-        return out
-
-    from_saved.defvjp(
-        lambda *a: (a[5], (a[:5], a[6:])),
-        lambda res, do: backward(res[0], do, res[1]) + (None, None, None))
-    scan.keeping, scan.from_saved = keeping, from_saved
-    return scan
+    return kernel_pair(
+        5, lambda *ops: forward(*ops)[0], forward,
+        lambda ops, do, kept: gated_delta_bwd(
+            do, *ops, chunk, kept[1:] or None, interpret=interpret))

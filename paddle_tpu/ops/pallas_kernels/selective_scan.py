@@ -35,8 +35,8 @@ no transpose of one is left around the kernels.
 
 Precision is the configuration's: Delta, the exponent, the state and every
 sum are float32; U, Dt and dOut are widened tile by tile in VMEM and Out, dU
-and dDt rounded once.  `make_selective_scan()` is the `jax.custom_vjp` over
-the pair and what a forward op and its grad op split between them
+and dDt rounded once.  `make_selective_scan()` is the `kernel_pair` over the
+two (_common.py): what a forward op and its grad op split between them
 (`.keeping`, `.from_saved`, gated_delta.py's way).
 """
 
@@ -400,48 +400,17 @@ def selective_scan_bwd(do, u, dt, b, c, a_log, d, bias, states, *,
 
 @functools.lru_cache(maxsize=None)
 def make_selective_scan(chunk: int = CHUNK, interpret: bool = False):
-    """The scan as a `jax.custom_vjp` (U, Dt, B, C, ALog, D, DtBias) -> Out,
-    memoized so that every trace meets the same function.  Its forward is
-    the launch that keeps the chunks' states (differentiated or not: one
-    kernel body a training step to trace, and a `jax.checkpoint` traces the
-    primal beside the rule) and its backward the reverse pass over them.
-    `.keeping(...) -> (Out, states)` hands them out of a plain call, and
-    `.from_saved(..., Out, states) -> Out` launches nothing forward and
-    differentiates as the reverse pass: what a forward op and its grad
-    op's re-emission split between them (`ctx.keep_for_grad`)."""
-    import jax
+    """The scan (U, Dt, B, C, ALog, D, DtBias) -> Out as a `kernel_pair`
+    (_common.py: the differentiable pair, `.keeping -> (Out, states)`,
+    `.from_saved(..., Out, states)`), memoized so that every trace meets
+    the same function.  Its forward is the launch that keeps the chunks'
+    states, under the plain rule too (differentiated or not: one kernel
+    body a training step to trace, and a `jax.checkpoint` traces the primal
+    beside the rule), and its backward the reverse pass over them."""
+    from ._common import kernel_pair
 
     how = dict(chunk=chunk, interpret=interpret)
-
-    def backward(res, do):
-        ops, states = res
-        return selective_scan_bwd(do, *ops, states, **how)
-
-    def kept_forward(*ops):
-        out, states = selective_scan_fwd(*ops, keep=True, **how)
-        return out, (ops, states)
-
-    scan = jax.custom_vjp(lambda *ops: kept_forward(*ops)[0])
-    scan.defvjp(kept_forward, backward)
-
-    @jax.custom_vjp
-    def keeping(*ops):
-        return selective_scan_fwd(*ops, keep=True, **how)
-
-    def keeping_fwd(*ops):
-        out, states = keeping(*ops)
-        return (out, states), (ops, states)
-
-    # the states leave as residuals for `from_saved`, never as values a
-    # loss depends on: their cotangent is dropped
-    keeping.defvjp(keeping_fwd, lambda res, cts: backward(res, cts[0]))
-
-    @jax.custom_vjp
-    def from_saved(u, dt, b, c, a_log, d, bias, out, states):
-        return out
-
-    from_saved.defvjp(
-        lambda *a: (a[7], (a[:7], a[8])),
-        lambda res, do: backward(res, do) + (None, None))
-    scan.keeping, scan.from_saved = keeping, from_saved
-    return scan
+    return kernel_pair(
+        7, functools.partial(selective_scan_fwd, **how),
+        lambda *ops, keep: selective_scan_fwd(*ops, keep=True, **how),
+        lambda ops, do, kept: selective_scan_bwd(do, *ops, kept[1], **how))

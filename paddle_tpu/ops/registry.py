@@ -19,10 +19,27 @@ under ``jax.vjp`` and applies the output cotangents.  The recomputed forward
 subgraph is CSE'd/fused by XLA (or acts as rematerialization, which is usually a
 win on TPU where HBM bandwidth, not FLOPs, is the bottleneck).  Ops that want a
 cheaper analytic backward (using their saved outputs) register a custom grad
-emitter; stateful/optimizer ops register ``grad=None``.  A Pallas kernel is the
-exception to "CSE'd": two Mosaic calls are never merged, so an emitter that runs
-one keeps what its backward needs on the EmitContext (``keep_for_grad``) and the
-re-trace differentiates through that instead of launching the forward again.
+emitter; stateful/optimizer ops register ``grad=None``.
+
+A Pallas kernel is the exception to "CSE'd": two Mosaic calls are never merged,
+so a kernel's forward re-emitted under ``jax.vjp`` would run twice a step.  The
+kernel-pair protocol, in three places and no others:
+
+* the kernel file declares a pair (``pallas_kernels/_common.py kernel_pair``):
+  its operand count, its bare forward, ``forward(*ops, keep) -> (out,
+  *residuals)`` and ``backward(ops, do, kept)``, memoized by what the launches
+  are built from; the ``jax.custom_vjp`` triple (the differentiable pair,
+  ``.keeping``, ``.from_saved``) is derived there, once;
+* the emitter calls ``ctx.run_pair(pair, ops) -> (out, saved)``, which picks
+  among the four (a re-emission handed what was kept: ``from_saved``;
+  inference: the bare forward; a re-emission handed nothing, as a
+  ``layers.recompute`` segment's replay is: the plain pair; a forward emission:
+  ``keeping``), and then ``ctx.keep_for_grad(attrs, [ITS OWN output], saved)``
+  where ``saved`` is not None;
+* ``generic_grad`` hands ``saved`` back to the re-emission if the grad op
+  received that very output, and counts what ``run_pair`` reported in
+  ``executor_grad_kernel_forward_total{op, reused}``: ``reused="1"`` no forward
+  launched again, ``"0"`` at least one (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -232,9 +249,37 @@ class EmitContext:
         """An emitter that took a Pallas custom_vjp path says whether its
         grad op's re-emission `reused` kept results or launches the
         kernel's forward again; generic_grad counts it
-        (executor_grad_kernel_forward_total).  Nothing outside one."""
+        (executor_grad_kernel_forward_total): reused=1 where every kernel
+        of the re-emission found its own.  Nothing outside one."""
         if self._replay is not None:
-            self._replay.kernel_forward_reused = bool(reused)
+            self._replay.kernel_forward_reused = bool(reused) and (
+                self._replay.kernel_forward_reused is not False)
+
+    def run_pair(self, pair, ops, kept=None):
+        """Run a kernel pair (`pallas_kernels/_common.py kernel_pair`) on
+        `ops` the way this emission needs it -> (out, saved): the ONE place
+        that chooses (the module docstring has the four).  `saved` = (out,
+        *residuals) comes from a forward emission alone, for the emitter
+        to keep beside ITS OWN output (`keep_for_grad(attrs, [the op's
+        output], saved)`: the kernel's `out` is not always the op's), and
+        is None otherwise.  `kept`: what the re-emission was handed for
+        THIS pair where the op keeps several (`gated_delta_rule`'s dict;
+        () for nothing); by default all the op kept.  Reports
+        `kernel_forward`."""
+        if kept is None:
+            kept = self.kept_for_grad()
+        saved = None
+        if kept:
+            out = pair.from_saved(*ops, *kept)
+        elif self.is_test:
+            out = pair.bare(*ops)
+        elif self.in_grad_replay():
+            out = pair(*ops)
+        else:
+            saved = pair.keeping(*ops)
+            out = saved[0]
+        self.kernel_forward(reused=bool(kept))
+        return out, saved
 
     def target_platform(self) -> str:
         """Platform ('tpu'/'cpu'/...) of the device(s) this trace will run

@@ -359,11 +359,9 @@ def gated_delta_rule(ctx, ins, attrs):
 
     On one TPU, where Dk, Dv and the chunk are whole lane tiles and the
     chunk divides T, the scan is the kernel pair of
-    ops/pallas_kernels/gated_delta.py under one `jax.custom_vjp` (a chunk's
-    [C, C] and [C, d] tiles and the state in VMEM): the forward op's one
-    launch hands out O, every chunk's incoming state and Tm, kept beside
-    its output (`ctx.keep_for_grad`), and the grad op's re-emission
-    differentiates through them as the reverse pass alone.  Everywhere else
+    ops/pallas_kernels/gated_delta.py (a chunk's [C, C] and [C, d] tiles
+    and the state in VMEM) through `ctx.run_pair`: kept beside the op's
+    output are O, every chunk's incoming state and Tm.  Everywhere else
     (the CPU, a mesh, float64, other widths, T under a chunk,
     `PADDLE_TPU_NO_FUSED_KERNELS`) `gated_delta_chunked` as plain jax.numpy,
     recomputed in its backward (jax.checkpoint): the vjp keeps q, k, v, g
@@ -385,7 +383,7 @@ def gated_delta_rule(ctx, ins, attrs):
 
     from .pallas_kernels import gated_delta as kernels
     from .pallas_kernels import gdn_conv
-    from .pallas_kernels._common import pallas_dispatch_ok
+    from .pallas_kernels._common import traced_path
 
     x, ba, taps = ins["X"][0], ins["BA"][0], ins["Conv"][0]
     Hk, Hv = int(attrs["key_heads"]), int(attrs["value_heads"])
@@ -401,23 +399,19 @@ def gated_delta_rule(ctx, ins, attrs):
         raise ValueError(
             f"gated_delta_rule: X {x.shape}, BA {ba.shape}, Conv "
             f"{taps.shape} at {Hk} key heads of {Dk} and {Hv} value heads")
-    on_tpu = pallas_dispatch_ok(ctx)
-    take = on_tpu and kernels.usable(T, chunk, Dk, Dv, x.dtype, G)
-    take_conv = on_tpu and gdn_conv.usable(T, Hk, Hv, Dk, Dv, taps.shape[1],
-                                           x.dtype)
+    take = traced_path(ctx, _MET_GDN_KERNELS,
+                       kernels.usable(T, chunk, Dk, Dv, x.dtype, G))
+    take_conv = traced_path(ctx, _MET_GDN_CONV, gdn_conv.usable(
+        T, Hk, Hv, Dk, Dv, taps.shape[1], x.dtype))
     replay = ctx.in_grad_replay()
     if not replay:
         _MET_GDN.inc(key_heads=str(Hk), value_heads=str(Hv),
                      head_dim=str(Dk), chunk=str(chunk),
                      conv_taps=str(taps.shape[1]))
-    for met, pallas in ((_MET_GDN_KERNELS, take), (_MET_GDN_CONV, take_conv)):
-        met.inc(op="grad" if replay else "fwd",
-                path="pallas" if pallas else "xla")
     wide = wide_dtype(x.dtype)
     # what the forward emission kept for this re-emission, and what this
     # forward emission keeps: {"conv": (q, k, v), "scan": (O, states, Tm)}
     kept = ctx.kept_for_grad() or {}
-    keeps = not (ctx.is_test or replay)
     saved = {}
     if take_conv:       # opens the parts' scopes itself: z is norm_gate's
         conv = gdn_conv.make_gdn_conv(Hk, Hv, Dk, eps)
@@ -425,8 +419,9 @@ def gated_delta_rule(ctx, ins, attrs):
             q, k, v, z = conv.from_saved(x, taps, *kept["conv"])
         else:
             q, k, v, z = conv(x, taps)
-            if keeps:
+            if not (ctx.is_test or replay):
                 saved["conv"] = (q, k, v)
+        ctx.kernel_forward(reused="conv" in kept)
     else:
         with part_scope("gdn.conv"):
             q, k, v = gdn_conv_plain(x, taps, Hk, Hv, Dk, eps)
@@ -439,22 +434,14 @@ def gated_delta_rule(ctx, ins, attrs):
             ba[..., Hv:].astype(wide) + ins["DtBias"][0].astype(wide)))
     with part_scope("gdn.scan"):
         if take:
-            scan = kernels.make_gated_delta(chunk)
-            if "scan" in kept:
-                o = scan.from_saved(q, k, v, g, beta, *kept["scan"])
-            elif keeps:
-                saved["scan"] = scan.keeping(q, k, v, g, beta)
-                o = saved["scan"][0]
-            else:
-                o = scan(q, k, v, g, beta)
+            o, saved_scan = ctx.run_pair(kernels.make_gated_delta(chunk),
+                                         (q, k, v, g, beta),
+                                         kept=kept.get("scan", ()))
+            if saved_scan is not None:
+                saved["scan"] = saved_scan
         else:
             o = jax.checkpoint(functools.partial(
                 gated_delta_chunked, chunk=chunk))(q, k, v, g, beta)
-    if take or take_conv:
-        # no kernel's forward launched again: every path taken found its own
-        ctx.kernel_forward(reused=all(
-            name in kept for name, on in (("conv", take_conv), ("scan", take))
-            if on))
     with part_scope("gdn.norm_gate"):
         o = rms(o, eps, (4,), ins["Norm"][0].astype(o.dtype))
         o = o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hv * Dv)

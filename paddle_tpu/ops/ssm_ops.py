@@ -107,13 +107,9 @@ def selective_scan(ctx, ins, attrs):
 
     On one TPU, where U is bf16 or float32, the kernels' chunk divides T and
     Di and N are whole tiles (`selective_scan.usable`), both parts are the
-    kernel pair of ops/pallas_kernels/selective_scan.py under one
-    `jax.custom_vjp` (Delta is made inside, from Dt in its own dtype): a
-    forward emission's one launch hands out Out and every chunk's incoming
-    state, kept beside the output (`ctx.keep_for_grad`), and its grad op's
-    re-emission differentiates through them as the reverse pass alone;
-    inside a `layers.recompute` segment's replay the one forward launch
-    under the vjp keeps the states for the reverse pass.  Everywhere else
+    kernel pair of ops/pallas_kernels/selective_scan.py (Delta is made
+    inside, from Dt in its own dtype) through `ctx.run_pair`: kept beside
+    the output are Out and every chunk's incoming state.  Everywhere else
     `selective_scan_chunked` in chunks of SCAN_CHUNK tokens (T a multiple
     of it, or shorter).  `selective_scan_total` and
     `selective_scan_kernels_traced_total` say which emission ran."""
@@ -121,7 +117,7 @@ def selective_scan(ctx, ins, attrs):
     import jax.numpy as jnp
 
     from .pallas_kernels import selective_scan as kernels
-    from .pallas_kernels._common import pallas_dispatch_ok
+    from .pallas_kernels._common import traced_path
 
     u, dt, xp = ins["U"][0], ins["Dt"][0], ins["XProj"][0]
     a_log, d, bias = ins["ALog"][0], ins["D"][0], ins["DtBias"][0]
@@ -132,34 +128,20 @@ def selective_scan(ctx, ins, attrs):
             or xp.shape != (B, T, R + 2 * N)):
         raise ValueError(f"selective_scan: U {u.shape}, Dt {dt.shape}, XProj "
                          f"{xp.shape}, ALog {a_log.shape} at dt_rank {R}")
-    take = pallas_dispatch_ok(ctx) and kernels.usable(T, kernels.CHUNK, Di,
-                                                      N, u.dtype)
-    replay = ctx.in_grad_replay()
-    if not replay:
+    take = traced_path(ctx, _MET_SCAN_KERNELS,
+                       kernels.usable(T, kernels.CHUNK, Di, N, u.dtype))
+    if not ctx.in_grad_replay():
         _MET_SCAN.inc(impl="pallas" if take else "xla_chunked",
                       d_inner=str(Di), d_state=str(N),
                       chunk=str(kernels.CHUNK if take
                                 else min(SCAN_CHUNK, T)))
-    _MET_SCAN_KERNELS.inc(op="grad" if replay else "fwd",
-                          path="pallas" if take else "xla")
     if take:
         with part_scope("ssm.xdt"):
             ops = (u, dt, xp[..., R:R + N], xp[..., R + N:], a_log, d, bias)
-        kept = ctx.kept_for_grad()
         with part_scope("ssm.scan"):
-            scan = kernels.make_selective_scan()
-            if kept is not None:
-                out = scan.from_saved(*ops, *kept)
-            elif ctx.is_test:
-                out = kernels.selective_scan_fwd(*ops)
-            elif replay:
-                out = scan(*ops)
-            else:
-                out, states = scan.keeping(*ops)
-                ctx.keep_for_grad(attrs, [out], (out, states))
-        # a re-emission that was handed nothing (a recompute segment's
-        # replay) launches the one forward that keeps the states
-        ctx.kernel_forward(reused=kept is not None)
+            out, saved = ctx.run_pair(kernels.make_selective_scan(), ops)
+        if saved is not None:
+            ctx.keep_for_grad(attrs, [out], saved)
         return {"Out": [out]}
     wide = wide_dtype(u.dtype)
     with part_scope("ssm.xdt"):
