@@ -365,7 +365,7 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     `differential` (a dict; `_differential_attention` has its keys and the
     equations): differential attention, two softmax maps a head pair and
     their difference under a learned lambda, from ONE fused projection [q |
-    k | v]; with `kv` = the (K, V1, V2) another such layer's dict holds under
+    k | v]; with `kv` = the (K, V) another such layer's dict holds under
     "made", a query-only projection that attends to that layer's keys and
     values as they were computed there.
     `positions` ('rope' | 'none'): what a tower that chooses positions
@@ -570,15 +570,15 @@ def _differential_attention(helper, x, num_heads, kv_heads, head_dim, diff,
     `diff_attn_split`, `diff_attn_combine` have the equations) between its
     input and its output projection: X [B, T, D] -> [B, T, num_heads *
     head_dim].  ONE projection to [q | k | v] (to q alone where `kv` = (K,
-    V1, V2) hands in another layer's), the heads in pairs "(H two)", two
-    `scaled_dot_product_attention` calls of all query heads (pair's first,
-    then pair's second) under `masked`, once on (v1; v1) and once on (v2;
-    v2), and the combination under the part `attn.diff`.  `diff`:
+    V) hands in another layer's), the heads in pairs "(H two)", ONE
+    `scaled_dot_product_attention` of all query heads (pair's first, then
+    pair's second) under `masked` against values [v1 | v2], 2 head_dim
+    wide, and the combination under the part `attn.diff`.  `diff`:
     "layer_index" (the layer's index in the whole model: lambda_init),
     optionally "epsilon" (the RMSNorm's, 1e-5), "lambda_attr" (the four
     lambda vectors': normal(0, 0.1) by default) and "gain_attr" (the
     RMSNorm's gain [2 head_dim]: one by default).  The dict gains "made" =
-    (K, V1, V2) as this layer computed or received them and "result", the
+    (K, V) as this layer computed or received them and "result", the
     combination's output.  Parameters in creation order: the projection
     (and its bias), lambda_q1, lambda_k1, lambda_q2, lambda_k2 [head_dim],
     the gain [2 head_dim]."""
@@ -593,20 +593,18 @@ def _differential_attention(helper, x, num_heads, kv_heads, head_dim, diff,
     outs = {"Q": [q.name]}
     if kv is None:
         kv = tuple(helper.create_tmp_variable(
-            x.dtype, shape=(B, kv_heads, T, head_dim)) for _ in range(3))
-        outs.update({s: [v.name] for s, v in zip(("K", "V1", "V2"), kv)})
+            x.dtype, shape=(B, kv_heads, T, wide)) for wide in (
+                head_dim, 2 * head_dim))
+        outs.update({s: [v.name] for s, v in zip(("K", "V"), kv)})
     helper.append_op("diff_attn_split", inputs={"X": [proj.name]},
                      outputs=outs, attrs={**heads, "part": "attn.diff"})
     diff["made"] = kv
-    halves = []
-    for values in kv[1:]:
-        o = helper.create_tmp_variable(x.dtype,
-                                       shape=(B, num_heads, T, head_dim))
-        helper.append_op(
-            "scaled_dot_product_attention",
-            inputs={"Q": [q.name], "K": [kv[0].name], "V": [values.name]},
-            outputs={"Out": [o.name]}, attrs=dict(masked))
-        halves.append(o)
+    attended = helper.create_tmp_variable(
+        x.dtype, shape=(B, num_heads, T, 2 * head_dim))
+    helper.append_op(
+        "scaled_dot_product_attention",
+        inputs={"Q": [q.name], "K": [kv[0].name], "V": [kv[1].name]},
+        outputs={"Out": [attended.name]}, attrs=dict(masked))
     lam = diff.get("lambda_attr") or {
         "initializer": NormalInitializer(scale=0.1)}
     vectors = [helper.create_parameter(attr=lam, shape=[head_dim],
@@ -616,7 +614,7 @@ def _differential_attention(helper, x, num_heads, kv_heads, head_dim, diff,
         x.dtype, shape=(B, T, num_heads * head_dim))
     helper.append_op(
         "diff_attn_combine",
-        inputs={"O1": [halves[0].name], "O2": [halves[1].name],
+        inputs={"O": [attended.name],
                 "LambdaQ1": [vectors[0].name], "LambdaK1": [vectors[1].name],
                 "LambdaQ2": [vectors[2].name], "LambdaK2": [vectors[3].name],
                 "Gain": [gain.name]},

@@ -271,7 +271,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                                  bias_attr=norm_attr.get("bias"))
 
     # what a later block reads of an earlier one: the nearest 'mamba'
-    # layer's memory, the nearest 'attention' layer's (K, V1, V2)
+    # layer's memory, the nearest 'attention' layer's (K, V)
     ssm = {} if ssm is None else ssm
     shared = {"memory": ssm.setdefault("memory", []), "kv": None}
 

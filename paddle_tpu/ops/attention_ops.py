@@ -52,8 +52,9 @@ _MET_FLASH_CALLS = _MET.counter(
 _MET_DIFF_LAYERS = _MET.counter(
     "differential_attention_layers_traced_total",
     "differential-attention combinations traced (forward emission; once a "
-    "compile, not once a step), by the head pairs, the head width and the "
-    "layer's lambda_init")
+    "compile, not once a step), by the head pairs, the head width, the "
+    "width of the values its ONE attention call ran against (value_dim, "
+    "twice the head width: [v1 | v2]) and the layer's lambda_init")
 _MET_BD_LAYERS = _MET.counter(
     "block_diffusion_layers_traced_total",
     "scaled_dot_product_attention ops traced under the block-diffusion mask "
@@ -220,10 +221,12 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     [B,Hkv,T,Dv], where the trace targets one TPU and the shapes fit the
     kernel's contract: self-attention lengths, T tiles of 128, and heads of
     at most two lane tiles (256): the values' of one lane tile at most
-    (under queries and keys as wide, or wider: latent attention's carry
-    rotary columns beside them), or of exactly two under queries and keys
-    of two (256 / 256; no other width over 128 has been run through the
-    kernels).  Where the shapes do
+    (under queries and keys as wide, wider: latent attention's carry rotary
+    columns beside them, or narrower: differential attention's 128-wide [v1
+    | v2] under keys of 64), or of exactly two under queries and keys of
+    two (256 / 256).  The (D / Dv) run through the kernels on the chip: 64
+    / 64, 128 / 128, 192 / 128, 256 / 256 and, since PR 57, 64 / 128; no
+    other width over 128 has been.  Where the shapes do
     not fit, the caller's dense path runs, and at T >= 4096 one warning
     names the shape (a [H, T, T] float32 score tensor follows).  Two head
     counts: H query heads on Hkv key/value heads, H / Hkv on each
@@ -476,12 +479,14 @@ def attention_output_gate(ctx, ins, attrs):
 # Differential attention (arXiv:2410.05258, as Phi-4-mini-flash's attention
 # class computes it): heads in PAIRS, "(H two)": pair p is heads 2p (q1, k1,
 # v1) and 2p + 1 (q2, k2, v2); with P1 = softmax(q1 k1^T), P2 = softmax(q2
-# k2^T) and V = [v1 | v2], a pair's result is P1 V - lambda P2 V.  The flash
-# kernels take values no wider than keys, so the four products come from TWO
-# calls of all the query heads, in the order (q1 of every pair, then q2 of
-# every pair) on keys in the same order, once with (v1; v1) and once with
-# (v2; v2) as the values: every score is computed twice (the published code's
-# four calls do the same); `flash_score_elements_total` shows the doubling.
+# k2^T) and V = [v1 | v2], a pair's result is P1 V - lambda P2 V.  A layer is
+# ONE attention call of all the query heads, in the order (q1 of every pair,
+# then q2 of every pair) on keys in the same order, against values TWICE a
+# head wide: key/value head h of both halves carries its pair's [v1 | v2],
+# so the call's output is (P1 V; P2 V) and every score is computed once.
+# The flash kernels carry the keys' and the values' widths apart; 64 / 128
+# has been run on the chip (flash_attention.py's docstring has the probe: a
+# call costs what one at 64 / 64 does).
 
 
 def _pair_major(x, heads: int):
@@ -494,15 +499,16 @@ def _pair_major(x, heads: int):
 
 @register_op("diff_attn_split")
 def diff_attn_split(ctx, ins, attrs):
-    """The heads of a differential-attention layer as its two flash calls
-    read them.  X [B, T, (Hq + 2 Hkv) * D] = [q | k | v] as ONE projection
+    """The heads of a differential-attention layer as its one flash call
+    reads them.  X [B, T, (Hq + 2 Hkv) * D] = [q | k | v] as ONE projection
     leaves it (attrs `num_heads` Hq, `num_kv_heads` Hkv, both even, and
     `head_dim` D) ->
     Q [B, Hq, T, D] (q1 of the Hq / 2 pairs, then q2), K [B, Hkv, T, D]
     (k1, then k2: query head h of Q reads key head h // (Hq / Hkv), its own
-    pair's half), V1 and V2 [B, Hkv, T, D] ((v1; v1) and (v2; v2)).  Where X
-    is [B, T, Hq * D] (a cross layer's query-only projection: its keys and
-    values come from the layer that made them) Q alone."""
+    pair's half) and V [B, Hkv, T, 2 D]: key/value head h of BOTH halves
+    carries its pair's [v1 | v2].  Where X is [B, T, Hq * D] (a cross
+    layer's query-only projection: its keys and values come from the layer
+    that made them) Q alone."""
     import jax.numpy as jnp
 
     x = ins["X"][0]
@@ -523,22 +529,22 @@ def diff_attn_split(ctx, ins, attrs):
     if width == Hq * D:
         return {"Q": [q]}
     k = joined(_pair_major(x[..., Hq * D:(Hq + Hkv) * D], Hkv))
-    v = _pair_major(x[..., (Hq + Hkv) * D:], Hkv)
-    return {"Q": [q], "K": [k],
-            "V1": [jnp.concatenate([v[0], v[0]], axis=1)],
-            "V2": [jnp.concatenate([v[1], v[1]], axis=1)]}
+    # a pair's two value heads lie side by side in X: [B, Hkv / 2, T, 2 D]
+    v = x[..., (Hq + Hkv) * D:].reshape(B, T, Hkv // 2, 2 * D).transpose(
+        0, 2, 1, 3)
+    return {"Q": [q], "K": [k], "V": [jnp.concatenate([v, v], axis=1)]}
 
 
 @register_op("diff_attn_combine")
 def diff_attn_combine(ctx, ins, attrs):
-    """What differential attention does with its two softmax maps.  O1, O2
-    [B, H, T, D]: the two flash calls' results, heads (pair's first, then
-    pair's second) as `diff_attn_split` lays them: O1 = (P1 v1; P2 v1), O2 =
-    (P1 v2; P2 v2).  LambdaQ1, LambdaK1, LambdaQ2, LambdaK2 [D], Gain [2 D];
-    attrs `lambda_init`, `epsilon`.
+    """What differential attention does with its two softmax maps.  O [B,
+    H, T, 2 D]: the flash call's result, heads (pair's first, then pair's
+    second) as `diff_attn_split` lays them, against values [v1 | v2]: O =
+    (P1 [v1 | v2]; P2 [v1 | v2]).  LambdaQ1, LambdaK1, LambdaQ2, LambdaK2
+    [D], Gain [2 D]; attrs `lambda_init`, `epsilon`.
 
       lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init      (float32)
-      a = [P1 v1 - lambda P2 v1 | P1 v2 - lambda P2 v2]           [.., 2 D]
+      a = P1 [v1 | v2] - lambda P2 [v1 | v2]                      [.., 2 D]
       a = RMSNorm_{2 D}(a) * Gain * (1 - lambda_init)
 
     -> Out [B, T, H * D]: pair p's 2 D columns laid back as heads 2p and
@@ -547,23 +553,21 @@ def diff_attn_combine(ctx, ins, attrs):
 
     from .llm_ops import rms, wide_dtype
 
-    o1, o2 = ins["O1"][0], ins["O2"][0]
-    B, H, T, D = o1.shape
+    o = ins["O"][0]
+    B, H, T, Dv = o.shape
     init = float(attrs["lambda_init"])
     eps = float(attrs.get("epsilon", 1e-5))
     if not ctx.in_grad_replay():
-        _MET_DIFF_LAYERS.inc(pairs=str(H // 2), head_dim=str(D),
-                             lambda_init=f"{init:.4f}")
-    wide = wide_dtype(o1.dtype)
+        _MET_DIFF_LAYERS.inc(pairs=str(H // 2), head_dim=str(Dv // 2),
+                             value_dim=str(Dv), lambda_init=f"{init:.4f}")
+    wide = wide_dtype(o.dtype)
     lq1, lk1, lq2, lk2 = (ins[s][0].astype(wide) for s in (
         "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"))
     lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init
-    o1, o2 = o1.astype(wide), o2.astype(wide)
-    a = jnp.concatenate([o1[:, :H // 2] - lam * o1[:, H // 2:],
-                         o2[:, :H // 2] - lam * o2[:, H // 2:]], axis=-1)
+    a = o[:, :H // 2].astype(wide) - lam * o[:, H // 2:].astype(wide)
     a = rms(a, eps, (3,), ins["Gain"][0].astype(wide)) * (1.0 - init)
-    out = a.transpose(0, 2, 1, 3).reshape(B, T, H * D)
-    return {"Out": [out.astype(ins["O1"][0].dtype)]}
+    out = a.transpose(0, 2, 1, 3).reshape(B, T, H // 2 * Dv)
+    return {"Out": [out.astype(o.dtype)]}
 
 
 # ---------------------------------------------------------------------------

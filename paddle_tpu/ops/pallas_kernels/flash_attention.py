@@ -234,6 +234,36 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   where one K block holds the sequence, 0.3740; the q axis "parallel" or
   "arbitrary", the same to four digits (one core).
 
+- Values WIDER than keys: 64 / 128, differential attention's [v1 | v2]
+  under its keys (PR 57; nothing in the bodies changed: D and Dv were
+  apart everywhere already, `W, Wv`, the accumulator (bq, Wv), dv's scratch
+  (bk, Wv)).  Device ms a call from a trace, forward keeping the logsumexp
+  / dq / dkv, 10 calls each, q [1, 40, 8192, 64] on k [1, 20, 8192, 64],
+  bf16, (a) v [1, 20, 8192, 64], (b) v [1, 20, 8192, 128] (seed
+  5700000001):
+    causal, blocks (512, 1024)        (a) 6.553 7.220 8.481
+                                      (b) 6.631 7.333 8.472
+    sliding_window_mask(8192, 512), MASK_BLOCKS (1024, 1024)
+                                      (a) 2.159 1.685 1.991
+                                      (b) 2.177 1.795 1.991
+  (b) / (a) = 1.012 / 1.016 / 0.999 and 1.008 / 1.065 / 1.000: a 128-wide
+  `p v`, `dO v^T` and `p^T dO` are the passes the 64-wide ones were (a
+  64-wide result and a 64-deep contraction each cost the MXU a whole pass,
+  PR 36 above), the forward's per-row bookkeeping is per score row, and
+  the 64-lane operands were padded to 128 lanes in HBM already.  So a
+  layer's four products as ONE call cost what one of PR 52's two calls
+  cost.  Mosaic took the width at every pair of blocks tried; (b) at other
+  blocks, causal | window: (1024, 1024) 5.773 6.531 7.816 | the above;
+  (512, 1024) the above | 2.543 2.341 2.525; (512, 512) 10.221 8.254 9.574
+  | 3.302 3.138 3.490; (1024, 512) 10.483 7.095 8.478 | 2.561 2.315 2.642;
+  (256, 1024) 8.997 9.019 10.749 | 4.139 3.745 3.822; (a) reads within 2%
+  of (b) at each.  The causal call at (1024, 1024) is 8-13% under the
+  default (512, 1024) at BOTH widths at this shape: not taken here (the
+  default serves every causal caller; a change of it is its own issue).
+  Against dense float32 attention on the chip at T 1024 (bf16 operands,
+  window 192): out / dq / dk / dv within 0.0039 / 0.0045 / 0.0066 / 0.0043
+  of the largest element, what 64 / 64 reads.
+
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
 array's — a (1, bq) block over a (BH, T) array satisfies neither (first

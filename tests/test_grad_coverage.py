@@ -137,8 +137,8 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # numerically checked in test_qwen3_next.py
     # PR 52: +5 (selective_scan, causal_conv_silu, silu_gate: Phi-4-mini-
     # flash's Mamba mixer and its gated memory unit; diff_attn_split,
-    # diff_attn_combine: its differential attention around the two flash
-    # calls), each numerically checked in test_phi4flash.py
+    # diff_attn_combine: its differential attention around the flash call,
+    # one a layer since PR 57), each numerically checked in test_phi4flash.py
     assert len(diffable) == 166, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")

@@ -203,6 +203,43 @@ def test_flash_window_matches_dense_masked_attention(case):
                                        err_msg=name)
 
 
+TWO_WIDTH_CASES = {   # T, window (0: causal), block_q, block_k
+    "causal": (128, 0, 32, 64),
+    "window_under_a_block": (128, 20, 32, 32),
+    "window_over_a_block": (128, 48, 64, 32),
+}
+
+
+@pytest.mark.parametrize("case", list(TWO_WIDTH_CASES))
+def test_flash_values_twice_as_wide_as_keys(case):
+    """The flash pair at D 64 under Dv 128 (differential attention's [v1 |
+    v2] under its keys), 2 query heads a key/value head: forward and (dq,
+    dk, dv) against dense masked attention; the output and dv take the
+    values' width, dq and dk the keys'."""
+    import jax
+    import jax.numpy as jnp
+
+    T, w, bq, bk = TWO_WIDTH_CASES[case]
+    allowed = _window(T, w or T)
+    with jax.enable_x64(False):
+        q = jnp.asarray(_r(1, 4, T, 64, seed=1), jnp.float32)
+        k = jnp.asarray(_r(1, 2, T, 64, seed=2), jnp.float32)
+        v = jnp.asarray(_r(1, 2, T, 128, seed=3), jnp.float32)
+        do = jnp.asarray(_r(1, 4, T, 128, seed=4), jnp.float32)
+        kw = dict(interpret=True, block_q=bq, block_k=bk, **(
+            {"mask": fa.sliding_window_mask(T, w)} if w else
+            {"causal": True}))
+        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+        assert out.shape == (1, 4, T, 128) and lse.shape == (4, T)
+        want, grads = _with_vjp(lambda *a: _dense(*a, allowed), do, q, k, v)
+        np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+        got = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
+        assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+        for name, a, b in zip(("dq", "dk", "dv"), got, grads):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
+                                       err_msg=name)
+
+
 @pytest.mark.parametrize("geometry", [
     (8192, 512, 1024, 1024), (8192, 512, 512, 512), (256, 48, 64, 32),
     (128, 32, 32, 64), (160, 33, 32, 32), (64, 64, 16, 16)])
@@ -328,8 +365,8 @@ def test_sdpa_window_attrs_the_dense_path_and_the_gate():
 
 def test_diff_attn_split_output_and_grad():
     """Heads in pairs "(H two)": the first of every pair, then the second;
-    the values once as (v1; v1) and once as (v2; v2); a query-only X gives
-    Q alone."""
+    the values ONCE, 2 D wide: key/value head h of both halves carries its
+    pair's [v1 | v2]; a query-only X gives Q alone."""
     B, T, Hq, Hkv, D = 2, 3, 4, 2, 2
     x = _r(B, T, (Hq + 2 * Hkv) * D, seed=1)
     attrs = {"num_heads": Hq, "num_kv_heads": Hkv, "head_dim": D}
@@ -337,10 +374,12 @@ def test_diff_attn_split_output_and_grad():
     k = x[..., Hq * D:(Hq + Hkv) * D].reshape(B, T, Hkv, D).transpose(
         0, 2, 1, 3)
     v = x[..., (Hq + Hkv) * D:].reshape(B, T, Hkv, D).transpose(0, 2, 1, 3)
+    pair = np.concatenate([v[:, 0], v[:, 1]], axis=-1)[:, None]
     want = {"Q": q[:, [0, 2, 1, 3]], "K": k[:, [0, 1]],
-            "V1": v[:, [0, 0]], "V2": v[:, [1, 1]]}
+            "V": np.concatenate([pair, pair], axis=1)}
+    assert want["V"].shape == (B, Hkv, T, 2 * D)
     h = OpTestHarness("diff_attn_split", {"X": x}, attrs,
-                      out_slots=["Q", "K", "V1", "V2"])
+                      out_slots=["Q", "K", "V"])
     h.check_output(want, atol=1e-12)
     for slot in want:
         h.check_grad(["X"], output_slot=slot, max_relative_error=1e-2)
@@ -353,22 +392,30 @@ def test_diff_attn_split_output_and_grad():
 
 
 def test_diff_attn_combine_output_and_grad():
+    """O = (P1 [v1 | v2]; P2 [v1 | v2]) of ONE call: the difference of its
+    two halves is a pair's 2 D columns; the counter says how wide the
+    call's values were."""
     B, H, T, D = 2, 4, 3, 2
-    o1, o2 = _r(B, H, T, D, seed=1), _r(B, H, T, D, seed=2)
+    o = _r(B, H, T, 2 * D, seed=1)
     lam = [_r(D, seed=s) for s in (3, 4, 5, 6)]
     gain = _r(2 * D, lo=0.5, hi=1.5, seed=7)
     init = 0.37
-    ins = {"O1": o1, "O2": o2, "LambdaQ1": lam[0], "LambdaK1": lam[1],
+    ins = {"O": o, "LambdaQ1": lam[0], "LambdaK1": lam[1],
            "LambdaQ2": lam[2], "LambdaK2": lam[3], "Gain": gain}
     lm = np.exp(lam[0] @ lam[1]) - np.exp(lam[2] @ lam[3]) + init
-    a = np.concatenate([o1[:, :2] - lm * o1[:, 2:],
-                        o2[:, :2] - lm * o2[:, 2:]], axis=-1)
+    a = o[:, :2] - lm * o[:, 2:]
     a = a / np.sqrt((a * a).mean(-1, keepdims=True) + 1e-5) * gain * (
         1 - init)
+    counted = obs.REGISTRY.counter(
+        "differential_attention_layers_traced_total")
+    labels = dict(pairs="2", head_dim=str(D), value_dim=str(2 * D),
+                  lambda_init="0.3700")
+    before = counted.value(**labels)
     h = OpTestHarness("diff_attn_combine", ins,
                       {"lambda_init": init, "epsilon": 1e-5})
     h.check_output({"Out": a.transpose(0, 2, 1, 3).reshape(B, T, H * D)},
                    atol=1e-6)
+    assert counted.value(**labels) > before
     h.check_grad(sorted(ins), max_relative_error=1e-2)
 
 

@@ -198,6 +198,22 @@ def test_a_shared_tensors_gradient_is_the_sum_of_its_paths(toy, shared):
         assert not any(v.shape == (32, 48) for v in values)
 
 
+def _differential_tower(T, D=32):
+    """A window, a full and a cross differential-attention layer on one
+    input `x` (toy heads: 8 query on 4 key/value heads of 4) -> (the
+    layers' outputs, the (K, V) layer 19 read: layer 17's)."""
+    x = fluid.layers.data("x", shape=[T, D], dtype="float32")
+    outs, made = [], None
+    for index, window, cross in ((13, 16, False), (17, None, False),
+                                 (19, None, True)):
+        diff = {"layer_index": index}
+        outs.append(fluid.layers.multi_head_attention(
+            x, x, x, num_heads=8, num_kv_heads=4, causal=True, bias=True,
+            window=window, differential=diff, kv=made if cross else None))
+        made = diff["made"]
+    return outs, made
+
+
 def test_differential_attention_layers_against_the_reference():
     """`multi_head_attention(differential=)` for a window, a full and a
     cross layer against the reference's `differential_attention` on the
@@ -209,15 +225,7 @@ def test_differential_attention_layers_against_the_reference():
     cfg = _toy_config()
     T, D = 48, 32
     fluid.reset()
-    x = fluid.layers.data("x", shape=[T, D], dtype="float32")
-    outs, made = [], None
-    for index, window, cross in ((13, 16, False), (17, None, False),
-                                 (19, None, True)):
-        diff = {"layer_index": index}
-        outs.append(fluid.layers.multi_head_attention(
-            x, x, x, num_heads=8, num_kv_heads=4, causal=True, bias=True,
-            window=window, differential=diff, kv=made if cross else None))
-        made = diff["made"]
+    outs, _ = _differential_tower(T, D)
     main, startup = (fluid.default_main_program(),
                      fluid.default_startup_program())
     startup.random_seed = 7
@@ -246,6 +254,88 @@ def test_differential_attention_layers_against_the_reference():
         kept = kv
         np.testing.assert_allclose(got[n][0], want, rtol=2e-4, atol=2e-5,
                                    err_msg=str(index))
+
+
+def test_a_differential_layer_is_one_attention_call(monkeypatch):
+    """The toy model's program holds ONE `scaled_dot_product_attention` op
+    a differential layer, on values twice a head wide, and what layer 17
+    hands to layer 19 is (K, V).  A step of a window, a full and a cross
+    layer traced for a TPU (the kernels interpreted): one flash call a
+    layer, so the squares counted are half of two calls' a layer, and the
+    combination's counter says the values were 2 x head_dim wide."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import registry as reg
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    cfg = _toy_config(remat=False)
+    fluid.reset()
+    harness.resolve(cfg["train"]["builder"])(**cfg["train"]["args"])
+    block = fluid.default_main_program().global_block()
+    ops = [op for op in block.ops if not op.type.endswith("_grad")]
+    kinds = [kind for kind, *_ in _ref().layout(cfg)[0]]
+    layers = sum(kind.endswith("attention") for kind in kinds)
+    assert layers == 4
+    count = lambda kind: sum(op.type == kind for op in ops)  # noqa: E731
+    assert count("scaled_dot_product_attention") == layers
+    assert count("diff_attn_split") == count("diff_attn_combine") == layers
+    for op in ops:
+        if op.type == "scaled_dot_product_attention":
+            (v,), (out,) = op.input("V"), op.output("Out")
+            assert block.var(v).shape[-1] == 2 * 4   # [v1 | v2]
+            assert block.var(out).shape == (-1, 8, 64, 8)
+        if op.type == "diff_attn_combine":
+            assert set(op.inputs) == {
+                "O", "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2", "Gain"}
+    # three split ops make (K, V); the cross layer's makes Q alone
+    assert sorted(len(op.outputs) for op in ops
+                  if op.type == "diff_attn_split") == [1, 3, 3, 3]
+
+    T = 128     # the flash gate's tile
+    real = fa.make_flash_train
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(fa, "_TRAIN_CACHE", {})
+    monkeypatch.setattr(
+        fa, "make_flash_train", lambda **kw: real(**{
+            "block_q": 64, "block_k": 64, **kw, "interpret": True}))
+    feed = np.random.RandomState(3).randn(1, T, 32).astype(np.float32)
+
+    def step():
+        """The tower's first step under SGD -> (the loss and the layers'
+        outputs, what layer 17 handed on)."""
+        fluid.reset()
+        outs, made = _differential_tower(T)
+        loss = fluid.layers.mean(fluid.layers.sums(outs))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        return exe.run(feed={"x": feed}, fetch_list=[loss] + outs), made
+
+    flash, made = step()
+    assert [tuple(v.shape[1:]) for v in made] == [(4, T, 4), (4, T, 8)]
+    fam = obs.REGISTRY.snapshot()["families"]
+    series = lambda name: {tuple(sorted(s["labels"].items())): s["value"]  # noqa
+                           for s in fam[name]["series"]}
+    assert series("flash_calls_total") == {
+        (("mask", "causal"),): 2.0, (("mask", "window"),): 1.0}
+    assert series("attention_layers_traced_total") == {
+        (("layout", "bhtd"), ("path", "flash")): 2.0,
+        (("layout", "bhtd"), ("path", "flash_window")): 1.0}
+    squares = {dict(k)["kernel"]: v for k, v in series(
+        "flash_score_elements_total").items() if dict(k)["part"] == "square"}
+    two_calls_a_layer = 2 * 3 * 8 * T * T
+    assert squares == {kernel: two_calls_a_layer / 2 for kernel in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    counted = series("differential_attention_layers_traced_total")
+    assert len(counted) == 3 and all(
+        dict(k)["value_dim"] == "8" and dict(k)["head_dim"] == "4"
+        and dict(k)["pairs"] == "4" and v == 1.0
+        for k, v in counted.items())
+    # and the kernels' step is the dense path's
+    monkeypatch.undo()
+    dense, _ = step()
+    for a, b in zip(flash, dense):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
 
 
 def test_mamba_and_gmu_layers_against_the_reference():
