@@ -787,7 +787,8 @@ def gated_short_conv(input, kernel_size=3, param_attr=None, name=None):
 
 def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
                      v_dim, rope_theta=10000.0, epsilon=1e-5,
-                     param_attr=None, name=None, q_rank=None, yarn=None):
+                     param_attr=None, name=None, q_rank=None, yarn=None,
+                     rotary=True):
     """Causal multi-head latent attention over [B, T, D] (DeepSeek-V2's
     MLA; ops/llm_ops.py latent_attention has the equations): keys and
     values come from a latent of `kv_rank` columns with an RMSNorm of its
@@ -800,7 +801,16 @@ def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
     parameters.  `yarn` = {"factor", "original_max_position_embeddings",
     "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} (a published
     `rope_scaling` of type yarn) blends the rotary frequencies and sets
-    the softmax scale."""
+    the softmax scale.  `rotary` False (Kimi-Linear's `mla_use_nope`): no
+    position at all, the `qk_rope_dim` columns of the queries and the
+    shared key enter the scores unturned, the scale stays (`qk_nope_dim` +
+    `qk_rope_dim`)^-1/2, `rope_theta` is unread and `yarn` refused."""
+    if int(qk_rope_dim) < 1:
+        raise ValueError(f"latent_attention: qk_rope_dim {qk_rope_dim!r}: "
+                         f"the shared key has at least one column")
+    if yarn and not rotary:
+        raise ValueError("latent_attention: `yarn` scales the rotary "
+                         "frequencies, and `rotary` is False")
     helper = LayerHelper("latent_attention", name=name)
     D = input.shape[-1]
     attr = param_attr if isinstance(param_attr, dict) else {}
@@ -829,6 +839,8 @@ def latent_attention(input, num_heads, kv_rank, qk_nope_dim, qk_rope_dim,
     attrs = {"num_heads": int(num_heads), "qk_nope_dim": int(qk_nope_dim),
              "qk_rope_dim": int(qk_rope_dim), "v_dim": int(v_dim),
              "theta": float(rope_theta), "epsilon": float(epsilon)}
+    if not rotary:
+        attrs["rotary"] = False
     if yarn:
         attrs.update(
             yarn_factor=float(yarn["factor"]),
@@ -1018,6 +1030,72 @@ def gated_delta_net(input, key_heads, value_heads, key_dim, value_dim,
         return propagate_length(input, fc(
             out, input.shape[-1], num_flatten_dims=2, param_attr=param_attr,
             bias_attr=False))
+
+
+def kimi_delta_attention(input, num_heads, head_dim, conv_kernel=4,
+                         gate_rank=None, epsilon=1e-5, param_attr=None,
+                         name=None):
+    """A Kimi-Delta-Attention mixer over [B, T, D] (Kimi Linear,
+    arXiv:2510.26692, as the published `KimiDeltaAttention` computes it;
+    ops/sparse_linear_ops.py `kimi_delta_attention` has the equations):
+    three projections to q, k and v (`num_heads` heads of `head_dim`
+    each), each through a causal depthwise convolution of `conv_kernel`
+    taps + SiLU of its own, l2-normalised q and k, a log-decay a CHANNEL g =
+    -exp(A_log)[head] softplus((x W_fa) W_fb + dt_bias) through a
+    projection of rank `gate_rank` (`head_dim` by default), beta =
+    sigmoid(x W_b) a head, the delta rule under Diag(e^g) in chunks, a
+    per-head RMSNorm of the result times sigmoid((x W_ga) W_gb), and an
+    output projection.  No position enters.  Fifteen parameters, in
+    creation order: W_q, W_k, W_v [D, H Dh], W_fa [D, r], W_fb [r, H Dh],
+    W_b [D, H], W_ga [D, r], W_gb [r, H Dh], the taps of q, k and v [H Dh,
+    conv_kernel] (uniform on +- conv_kernel^-1/2, torch's Conv1d default,
+    whatever `param_attr` says), A_log [H] (log of uniform [1, 16)), dt_bias
+    [H Dh] (the inverse softplus of a log-uniform draw on [0.001, 0.1]:
+    Mamba-2's rule), the output norm's gain [Dh] (one), W_o [H Dh, D]; no
+    bias but dt_bias."""
+    helper = LayerHelper("kimi_delta_attention", name=name)
+    H, Dh, L = int(num_heads), int(head_dim), int(conv_kernel)
+    rank = Dh if gate_rank is None else int(gate_rank)
+    if H < 1 or Dh < 1 or L < 1 or rank < 1:
+        raise ValueError(f"kimi_delta_attention: {H} heads of {Dh}, "
+                         f"{L} taps, gate rank {rank}")
+    width = H * Dh
+    prog = helper.main_program
+    proj = lambda x, n: fc(x, n, num_flatten_dims=2,              # noqa: E731
+                           param_attr=param_attr, bias_attr=False)
+    with prog.part_guard("kda.project"):
+        q, k, v = (proj(input, width) for _ in range(3))
+        f = proj(proj(input, rank), width)
+        b = proj(input, H)
+        gate = proj(proj(input, rank), width)
+    bound = L ** -0.5
+    taps = [helper.create_parameter(
+        attr={}, shape=[width, L], dtype=input.dtype,
+        default_initializer=UniformInitializer(-bound, bound))
+        for _ in range(3)]
+    a_log = helper.create_parameter(
+        attr={}, shape=[H], dtype=input.dtype,
+        default_initializer=_UniformThrough(1.0, 16.0, [("log", {})]))
+    dt_bias = helper.create_parameter(
+        attr={}, shape=[width], dtype=input.dtype,
+        default_initializer=_step_bias_draw())
+    gain = _rms_gain(helper, Dh, input.dtype)
+    out = helper.create_tmp_variable(
+        input.dtype, shape=tuple(input.shape[:2]) + (width,))
+    helper.append_op(
+        "kimi_delta_attention",
+        inputs={"Q": [q.name], "K": [k.name], "V": [v.name], "F": [f.name],
+                "Beta": [b.name], "Gate": [gate.name],
+                "ConvQ": [taps[0].name], "ConvK": [taps[1].name],
+                "ConvV": [taps[2].name], "ALog": [a_log.name],
+                "DtBias": [dt_bias.name], "Norm": [gain.name]},
+        outputs={"Out": [out.name]},
+        attrs={"num_heads": H, "epsilon": float(epsilon),
+               "gate_rank": rank})
+    from .sequence import propagate_length
+
+    with prog.part_guard("kda.project"):
+        return propagate_length(input, proj(out, input.shape[-1]))
 
 
 SPARSE_DEFAULTS = {"kernel": 32, "stride": 16, "block": 64, "window": 2048,

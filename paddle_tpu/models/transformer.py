@@ -58,7 +58,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                linear=None, residual_scale=None, emb_scale=None,
                logit_scale=None, delta=None, attention_gate=False,
                rotary_dim=None, ssm=None, differential=None, window=None,
-               attention_bias=False, tie_embeddings=False, norm_attr=None):
+               attention_bias=False, tie_embeddings=False, norm_attr=None,
+               kda=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -100,10 +101,14 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     `dense_dim` without bias instead (DeepSeek's `first_k_dense_replace`).
     `attention` 'multi_head' or 'latent' with `mla` = {"kv_rank",
     "qk_nope_dim", "qk_rope_dim", "v_dim"} and optionally "q_rank" (a
-    query latent) and "yarn" (`layers.latent_attention`; rotary by
-    construction: `rope_theta`, no position table).
+    query latent), "yarn" and "rotary" (`layers.latent_attention`; rotary
+    by construction: `rope_theta`, no position table; with "rotary" False
+    no position at all).  Latent attention is chosen LAYER BY LAYER like
+    any kind of attention: the 'attention' entries of `layer_types` are
+    the layers that run it, beside whatever the other entries name
+    (Kimi-Linear: 'kda', 'kda', 'kda', 'attention').
     `layer_types` gives the token mixer layer by layer, `n_layers` of
-    eight kinds: 'attention' (the kind `attention` names; everywhere by
+    nine kinds: 'attention' (the kind `attention` names; everywhere by
     default); 'conv', a gated short convolution, `conv` = {"kernel_size"}
     (`layers.gated_short_conv`); 'sparse_attention', block-top-k sparse
     attention WITHOUT a position, `sparse` = {"n_heads", "n_kv_heads",
@@ -123,7 +128,10 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     heads' partial sum.  'gated_delta_net', the gated delta rule after a
     short convolution, no position, `delta` = {"key_heads", "value_heads",
     "key_dim", "value_dim"} and optionally "conv_kernel"
-    (`layers.gated_delta_net`).
+    (`layers.gated_delta_net`).  'kda', Kimi Delta Attention, the delta
+    rule whose state decays channel by channel, no position, `kda` =
+    {"n_heads", "head_dim"} and optionally "conv_kernel", "gate_rank"
+    (`layers.kimi_delta_attention`).
     'mamba', a selective state-space mixer, no position, `ssm` = {} or any
     of "d_state", "d_conv", "expand", "dt_rank", "bias_attr",
     "skip_attr" (`layers.mamba`; the dict gains "memory": every such
@@ -225,6 +233,15 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     if len(layer_types) != n_layers or set(layer_types) - set(_MIXERS):
         raise ValueError(f"layer_types {layer_types!r}: use {n_layers} of "
                          + ", ".join(repr(m) for m in _MIXERS))
+    if "kda" in layer_types:
+        sizes = dict({"conv_kernel": 4}, **(kda or {}))
+        sizes["gate_rank"] = sizes.get("gate_rank") or sizes.get("head_dim")
+        if any(not isinstance(sizes.get(k), int) or sizes[k] < 1
+               for k in ("n_heads", "head_dim", "conv_kernel", "gate_rank")):
+            raise ValueError(
+                f"decoder_lm: a 'kda' layer needs `kda` = whole numbers of "
+                f"n_heads and head_dim (and of conv_kernel and gate_rank, "
+                f"where given): {kda!r}")
     if hyper is not None and residual_scale is not None:
         raise ValueError("decoder_lm: `residual_scale` scales the one "
                          "residual stream; `hyper` has gates of its own")
@@ -349,6 +366,12 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         if layer_types[layer] == "gated_delta_net":
             return layers.gated_delta_net(h, epsilon=norm_epsilon,
                                           param_attr=attr, **delta)
+        if layer_types[layer] == "kda":
+            return layers.kimi_delta_attention(
+                h, kda["n_heads"], kda["head_dim"],
+                conv_kernel=kda.get("conv_kernel", 4),
+                gate_rank=kda.get("gate_rank"), epsilon=norm_epsilon,
+                param_attr=attr)
         if attention == "latent":
             return layers.latent_attention(
                 h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
@@ -494,7 +517,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
 # the token mixers `decoder_lm`'s `layer_types` names
 _MIXERS = ("attention", "conv", "sparse_attention", "linear_attention",
-           "gated_delta_net", "mamba", "gmu", "cross_attention")
+           "gated_delta_net", "mamba", "gmu", "cross_attention", "kda")
 
 # decoder_lm's arguments that change the block's parameters or equations,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
@@ -507,7 +530,7 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "emb_scale": None, "logit_scale": None, "delta": None,
                "attention_gate": False, "rotary_dim": None, "ssm": None,
                "differential": None, "window": None, "attention_bias": False,
-               "tie_embeddings": False, "norm_attr": None}
+               "tie_embeddings": False, "norm_attr": None, "kda": None}
 
 
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
@@ -1390,6 +1413,79 @@ def build_qwen3_next_lm_train_program(
     # fetch to hold exactly: seq_len * top_k a sequence
     layers.reduce_sum(shares[-1].counts)
     opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def build_kimi_linear_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, n_heads, kv_rank, qk_nope_dim,
+        qk_rope_dim, v_dim, linear_heads, linear_head_dim, conv_kernel,
+        dense_dim, num_experts, expert_dim, top_k, shared_experts,
+        held_experts, first_expert=0, buffer_rows=None, routed_scale=1.0,
+        dense_layers=1, gate_rank=None, norm_epsilon=1e-5,
+        bias_update_rate=1e-3, bias_init_scale=0.0, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02, emb_init_scale=None):
+    """Kimi-Linear-shaped decoder (`model_type` kimi_linear:
+    Kimi-Linear-48B-A3B-Instruct, arXiv:2510.26692) as ONE CHIP'S SHARE of
+    an expert-parallel deployment: RMSNorm pre-norm blocks whose token
+    mixer is, by `layer_types`, Kimi Delta Attention ('kda':
+    `linear_heads` heads of `linear_head_dim`, three causal depthwise
+    convolutions of `conv_kernel` taps + SiLU, gates through projections
+    of rank `gate_rank`, the delta rule under a decay a CHANNEL, a
+    sigmoid-gated per-head RMSNorm) or latent attention WITHOUT a rotary
+    turn ('full_attention': `n_heads` heads, a K/V latent of `kv_rank`,
+    queries and keys `qk_nope_dim` + `qk_rope_dim` wide with the last
+    `qk_rope_dim` columns of every key ONE shared key, unturned; no layer
+    sees a position); a SiLU-gated MLP of `dense_dim` in the first
+    `dense_layers` blocks and in the others Moonlight's expert layer: the
+    router scores all `num_experts` by sigmoid, chooses `top_k` by score +
+    bias, renormalises their weights and scales them by `routed_scale`; of
+    those experts this chip holds `held_experts` from `first_expert` on
+    and computes their part in a buffer of `buffer_rows` rows, beside one
+    shared expert of `shared_experts` x `expert_dim`; `vocab_size` is the
+    slice of the vocabulary this chip embeds and scores; no bias, untied
+    head.  Loss: next-token cross entropy, NO auxiliary term (the
+    published config has none); Adam; then every expert layer's selection
+    bias moves by `bias_update_rate` against its counts (it takes no
+    gradient; `bias_init_scale` as `build_mla_moe_lm_train_program`).  No
+    block is a `layers.recompute` segment: the cell's step fits without
+    (PERF.md, PR 58).  Returns the loss.  Feeds as
+    `build_lm_train_program`."""
+    from .. import optimizer as opt
+
+    kinds = {"kda": "kda", "full_attention": "attention"}
+    if set(layer_types) - set(kinds) or not 0 <= dense_layers < len(
+            layer_types):
+        raise ValueError(f"layer_types {layer_types!r}: 'kda' or "
+                         f"'full_attention', {dense_layers} dense layers "
+                         f"before at least one with experts")
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types), n_heads, max_len=seq_len,
+        dtype=dtype, norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions="none", attention="latent",
+        mla={"kv_rank": kv_rank, "qk_nope_dim": qk_nope_dim,
+             "qk_rope_dim": qk_rope_dim, "v_dim": v_dim, "rotary": False},
+        layer_types=[kinds[t] for t in layer_types],
+        kda={"n_heads": linear_heads, "head_dim": linear_head_dim,
+             "conv_kernel": conv_kernel, "gate_rank": gate_rank},
+        ffn="moe", dense_layers=dense_layers, dense_dim=dense_dim,
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "sigmoid", "renormalise": True,
+             "routed_scale": routed_scale, "buffer_rows": buffer_rows,
+             "select_bias": NormalInitializer(scale=bias_init_scale),
+             "shared_hidden": shared_experts * expert_dim},
+        router_outputs=shares, init_scale=init_scale,
+        emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    for s in shares:
+        layers.moe_bias_update(s.bias, s.counts, bias_update_rate)
     return loss
 
 

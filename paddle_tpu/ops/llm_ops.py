@@ -42,6 +42,12 @@ _MET_MLA_LAYERS = _MET.counter(
     "its values (v_dim) and the rank of the K/V latent (kv_rank)")
 
 
+_MET_MLA_POSITIONS = _MET.counter(
+    "latent_attention_positions_traced_total",
+    "latent attention layers traced (forward emission; once a compile, not "
+    "once a step), by what their shared key and the queries' last columns "
+    "see of a position (positions: rope, the rotate-half turn; none: "
+    "`rotary` false, Kimi-Linear's `mla_use_nope`)")
 _MET_MLA_QUERY_LATENTS = _MET.counter(
     "mla_query_latents_traced_total",
     "latent attention layers traced whose queries come from a latent of "
@@ -390,6 +396,10 @@ def latent_attention(ctx, ins, attrs):
     frequencies are YaRN's (`yarn_inv_freq`), cos and sin times
     mscale(yarn_mscale) / mscale(yarn_mscale_all_dim), and the softmax
     scale mscale(yarn_mscale_all_dim)^2 / sqrt(dn + dr) (`yarn_mscale`).
+    With `rotary` false (Kimi-Linear's `mla_use_nope`) NOTHING is turned:
+    q_pe and the shared k_pe enter the scores as the projections leave
+    them, no frequency table is made and `pdtpu.mla.rope` holds nothing;
+    the scale stays 1 / sqrt(dn + dr).
     The keys are dn + dr wide and the values dv: on one TPU the two-width
     flash kernels (attention_ops.flash_single_chip), elsewhere dense
     attention."""
@@ -408,9 +418,13 @@ def latent_attention(ctx, ins, attrs):
     B, T, _ = x.shape
     rank = wkva.shape[1] - dr
     latent = bool(ins.get("WQA"))
+    rotary = bool(attrs.get("rotary", True))
     factor = float(attrs.get("yarn_factor", 1.0))
     inv_freq = scale = None
     turn = 1.0
+    if factor != 1.0 and not rotary:
+        raise ValueError("latent_attention: YaRN scales the rotary "
+                         "frequencies, and `rotary` is false")
     if factor != 1.0:
         inv_freq = yarn_inv_freq(
             dr, theta, factor, int(attrs["yarn_original_max"]),
@@ -421,6 +435,7 @@ def latent_attention(ctx, ins, attrs):
     if not ctx.in_grad_replay():
         _MET_MLA_LAYERS.inc(qk_dim=str(dn + dr), v_dim=str(dv),
                             kv_rank=str(rank))
+        _MET_MLA_POSITIONS.inc(positions="rope" if rotary else "none")
         if latent:
             _MET_MLA_QUERY_LATENTS.inc(q_rank=str(ins["WQA"][0].shape[1]),
                                        yarn_factor=f"{factor:g}")
@@ -440,12 +455,18 @@ def latent_attention(ctx, ins, attrs):
         c = x @ wkva
         c_kv = rms(c[..., :rank], eps, (2,), ins["KVNorm"][0])
         kv = heads((c_kv @ wkvb).reshape(B, T, H, dn + dv))
-    with part_scope("mla.rope"):
-        k_pe = rope(c[:, None, :, rank:])                      # [B,1,T,dr]
-        q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_pe, (B, H, T, dr))], axis=-1)
-        v = kv[..., dn:]
+        if not rotary:      # the shared key as it lies, in every head
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                c[:, None, :, rank:], (B, H, T, dr))], axis=-1)
+            v = kv[..., dn:]
+    if rotary:
+        with part_scope("mla.rope"):
+            k_pe = rope(c[:, None, :, rank:])                  # [B,1,T,dr]
+            q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_pe, (B, H, T, dr))],
+                axis=-1)
+            v = kv[..., dn:]
     with part_scope("mla.attend"):
         got = flash_single_chip(ctx, q, k, v, True, scale=scale)
         attn, saved = got if got is not None else (

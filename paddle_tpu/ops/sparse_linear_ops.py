@@ -1,7 +1,7 @@
 """The token mixers of the sparse and linear-attention hybrid decoders
 (layers/nn.py `lightning_attention`, `gated_delta_net`,
-`block_sparse_attention`, `block_topk_select`; models/transformer.py
-`decoder_lm`).
+`kimi_delta_attention`, `block_sparse_attention`, `block_topk_select`;
+models/transformer.py `decoder_lm`).
 
 `lightning_attention`: linear attention with a constant decay a head
 (Lightning Attention-2, arXiv:2401.04658), run in chunks: O(T C), the
@@ -13,6 +13,12 @@ S_{t-1} + beta_t k_t (v_t - e^{g_t} S_{t-1}^T k_t)^T.  In chunks: a
 unit-lower-triangular inverse inside every chunk and a SEQUENTIAL scan over
 the chunks that carries the float32 [d, d] state; never a T x T matrix,
 never a state a token.
+
+`kimi_delta_attention`: Kimi Delta Attention (Kimi Linear,
+arXiv:2510.26692), the delta rule whose state decays CHANNEL BY CHANNEL: g_t
+is a vector over the key axis, S~ = Diag(e^{g_t}) S_{t-1}.  The chunked form
+no longer factors through one [C, C] decay mask; `kda_chunked` builds the
+two decayed score matrices from pieces whose every exponent is <= 0.
 
 `block_topk_select` and `block_sparse_attention`: InfLLM v2's trainable
 sparse attention (arXiv:2506.07900) in its parameter-free form: every
@@ -59,6 +65,11 @@ _MET_GDN_CONV = _MET.counter(
     "op's re-emission) and the path taken (pallas: the kernel pair of "
     "ops/pallas_kernels/gdn_conv.py; xla: short_conv_silu and the plain "
     "norm and split)")
+_MET_KDA = _MET.counter(
+    "kda_layers_traced_total",
+    "Kimi Delta Attention ops traced (forward emission; once a compile, not "
+    "once a step), by their heads, head width, the chunk the scan runs in, "
+    "the convolutions' taps and the rank of the gate projections")
 _MET_SPARSE = _MET.counter(
     "sparse_attention_layers_traced_total",
     "block-sparse attention ops traced (forward emission; once a compile, "
@@ -455,6 +466,231 @@ def gated_delta_rule(ctx, ins, attrs):
 
 
 # ---------------------------------------------------------------------------
+# Kimi Delta Attention: the delta rule with a log-decay a CHANNEL
+
+# Tokens a chunk of the scan, and the rows of a diagonal block whose decays
+# are taken pair by pair (`_decayed_scores`).  The published kernels' 64 and
+# 16.  PERF.md, PR 58, has the chip's readings at 64 and 128.
+KDA_CHUNK = 64
+KDA_SUB = 16
+
+
+def _block_diagonal(blocks):
+    """blocks [..., m, r, c] -> [..., m r, m c], block i at (i, i)."""
+    import jax.numpy as jnp
+
+    m, r, c = blocks.shape[-3:]
+    eye = jnp.eye(m, dtype=blocks.dtype)
+    out = blocks[..., :, :, None, :] * eye[:, None, :, None]
+    return out.reshape(blocks.shape[:-3] + (m * r, m * c))
+
+
+def _decayed_scores(rows, k, G, sub: int):
+    """For every X [..., C, D] of `rows`: M_ij = sum_d X_i[d] k_j[d] e^{G_i[d]
+    - G_j[d]} for i >= j and 0 above the diagonal, with G [..., C, D] a
+    cumulative log-decay (non-increasing down the rows).  NOT (X e^G)(k
+    e^-G)^T: e^{-G} overflows float32 inside one chunk wherever a channel
+    forgets fast.  No exponent taken here is positive:
+
+      a diagonal block of `sub` rows: the pairwise exponent G_i - G_j under
+        the mask, summed over d (the VPU; XLA fuses the [.., sub, sub, D]
+        exponentials into each row kind's reduction: sharing one product
+        between the kinds made it a 2 GB tensor a layer);
+      rows i in the SECOND half and columns j in the FIRST half of a block
+        of 2 sub, 4 sub, ... C rows: with G_ref the last row of the first
+        half, (X_i e^{G_i - G_ref}) . (k_j e^{G_ref - G_j}), both exponents
+        <= 0.  A level is ONE [C, D] x [D, C] product a chunk and row kind
+        (the MXU): the rows of first halves and the columns of second
+        halves enter as zeros, and what a row finds in ANOTHER block's
+        columns (finite: both factors are at most one) is masked away.
+
+    C / sub is a power of two."""
+    import jax.numpy as jnp
+
+    C, D = G.shape[-2:]
+    s = min(int(sub), C)
+    if C % s or (C // s) & (C // s - 1):
+        raise ValueError(f"kda: a chunk of {C} rows in diagonal blocks of "
+                         f"{s}")
+    lead = G.shape[:-2]
+    blk = lambda a: a.reshape(lead + (C // s, s, D))              # noqa: E731
+    Gb, kb = blk(G), blk(k)
+    i = jnp.arange(s)
+    e = jnp.exp(jnp.where((i[:, None] >= i[None, :])[..., None],
+                          Gb[..., :, None, :] - Gb[..., None, :, :],
+                          -jnp.inf))                     # [.., C/s, s, s, D]
+    out = [_block_diagonal(jnp.sum(
+        blk(x)[..., :, None, :] * e * kb[..., None, :, :], axis=-1))
+        for x in rows]
+    at = jnp.arange(C)
+    half = s
+    while half < C:
+        second = ((at // half) % 2 == 1)[:, None]                 # [C, 1]
+        pairs = lead + (C // (2 * half), 2 * half, D)
+        ref = jnp.broadcast_to(G.reshape(pairs)[..., half - 1:half, :],
+                               pairs).reshape(G.shape)
+        fall = jnp.exp(jnp.where(second, G - ref, -jnp.inf))
+        cols = jnp.swapaxes(
+            k * jnp.exp(jnp.where(second, -jnp.inf, ref - G)), -1, -2)
+        same = (at[:, None] // (2 * half)) == (at[None, :] // (2 * half))
+        out = [m + jnp.where(same, _product(x * fall, cols), 0.0)
+               for m, x in zip(out, rows)]
+        half *= 2
+    return out
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int, sub: int = KDA_SUB):
+    """Kimi Delta Attention's recurrence in chunks of `chunk` tokens (the
+    op's is KDA_CHUNK).  q, k [B, H, T, Dk] (k of unit length, q scaled), v
+    [B, H, T, Dv], g [B, H, T, Dk] (a token's log-decay a CHANNEL of the
+    key axis, <= 0) and beta [B, H, T].  Per head, from S = 0 [Dk, Dv]:
+
+      S~  = Diag(e^{g_t}) S_{t-1}
+      S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T;   o_t = S_t^T q_t
+
+    Inside a chunk, with G_i = sum_{j <= i} g_j [C, Dk] and the two decayed
+    score matrices of `_decayed_scores` (KK over rows k, QK over rows q):
+      A  = strict-lower(-beta_i KK_ij);  Tm = (I - A)^-1
+      U  = Tm (beta V);  W = Tm (beta K e^G);  K~ = K e^{G_C - G}
+    a chunk maps its incoming state by
+      S' = (Diag(e^{G_C}) - K~^T W) S + K~^T U,
+    one [Dk, Dk] x [Dk, Dv] product a step of the `lax.scan` over the
+    chunks, which hands out every chunk's INCOMING state; then, for all
+    chunks at once, V' = U - W S and O = (Q e^G) S + lower(QK) V'.  Every
+    exponent is <= 0.  Everything is float32 at HIGHEST precision
+    (float64 for float64 inputs: the numeric gradient checks), whatever
+    dtype q, k and v come in.  -> [B, H, T, Dv] float32.  With every
+    channel's gate equal it is `gated_delta_chunked`'s result."""
+    import jax
+    import jax.numpy as jnp
+
+    B, H, T, Dk = q.shape
+    Dv = v.shape[-1]
+    C = min(int(chunk), T)
+    if T % C:
+        raise ValueError(f"kda: chunks of {C} do not divide {T} tokens")
+    N = T // C
+    f32 = wide_dtype(q.dtype)
+    qc, kc = (a.astype(f32).reshape(B, H, N, C, Dk) for a in (q, k))
+    vc = v.astype(f32).reshape(B, H, N, C, Dv)
+    G = jnp.cumsum(g.astype(f32).reshape(B, H, N, C, Dk), axis=-2)
+    bc = beta.astype(f32).reshape(B, H, N, C, 1)
+    kk, qk = _decayed_scores((kc, qc), kc, G, sub)
+    i = jnp.arange(C)
+    a = jnp.where(i[:, None] > i[None, :], -bc * kk, 0.0)
+    tm = _unit_lower_inverse()(a)                          # [B, H, N, C, C]
+    decayed = jnp.exp(G)
+    u = _product(tm, vc * bc)
+    w = _product(tm, kc * decayed * bc)
+    last = G[..., -1:, :]                                  # [B, H, N, 1, Dk]
+    kt = jnp.swapaxes(kc * jnp.exp(last - G), -1, -2)
+    carry = (jnp.swapaxes(jnp.exp(last), -1, -2) * jnp.eye(Dk, dtype=f32)
+             - _product(kt, w))                            # [.., N, Dk, Dk]
+    fresh = _product(kt, u)                                # [.., N, Dk, Dv]
+
+    def step(s, chunk_maps):
+        m, b = chunk_maps
+        return _product(m, s) + b, s
+
+    _, states = jax.lax.scan(
+        step, jnp.zeros((B, H, Dk, Dv), f32),
+        (jnp.moveaxis(carry, 2, 0), jnp.moveaxis(fresh, 2, 0)))
+    states = jnp.moveaxis(states, 0, 2)                    # incoming
+    inner = u - _product(w, states)
+    out = _product(qc * decayed, states) + _product(qk, inner)
+    return out.reshape(B, H, T, Dv)
+
+
+@register_op("kimi_delta_attention")
+def kimi_delta_attention(ctx, ins, attrs):
+    """The core of a Kimi-Delta-Attention mixer between its projections
+    (Kimi Linear, arXiv:2510.26692): Q, K, V [B, T, H D] as three `fc`s
+    leave them, F [B, T, H D] the decay's low-rank projection, Beta [B, T,
+    H], Gate [B, T, H D] the output gate's low-rank projection, ConvQ,
+    ConvK, ConvV [H D, L] depthwise taps, ALog [H], DtBias [H D], Norm [D]
+    the output norm's gain; attrs `num_heads` H, `epsilon` (the output
+    norm's), `gate_rank` (for the counter alone).
+
+      q, k, v = SiLU(causal depthwise conv of Q, K, V), no bias;
+      q = l2norm(q) / sqrt(D), k = l2norm(k) per head    (pdtpu.kda.conv)
+      beta = sigmoid(Beta);
+      g = -exp(ALog)[head] softplus(F + DtBias), float32, a CHANNEL
+                                                         (pdtpu.kda.gates)
+      o = the delta rule under Diag(e^g) (kda_chunked, in chunks of
+          KDA_CHUNK tokens)                              (pdtpu.kda.scan)
+      Out = rmsnorm_head(o; Norm) * sigmoid(Gate)        (pdtpu.kda.norm_gate)
+
+    Plain jax.numpy everywhere (no kernel yet: ROADMAP.md S18).  The rule,
+    its norm and its gate run ONE HEAD AT A TIME (`lax.map` over the heads),
+    each head a `jax.checkpoint` of its own: the vjp keeps q, k, v, g, beta
+    and the gate, and of the rule's own what one head's chunks need while
+    that head's backward runs, after making its forward once more.  All 32
+    heads of 128 over 8192 tokens at once are 4.4 GB of chunk tensors,
+    which one chip does not have beside the step's weights, and ran slower
+    besides (PERF.md, PR 58: 77 ms a layer forward + backward for 47 head
+    by head).  The norm is INSIDE the checkpoint because its backward needs
+    o: outside, the grad op's re-emission ran the whole rule a third time
+    for it (a `while` is never merged with the forward op's)."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, f, b, gate = (ins[s][0] for s in ("Q", "K", "V", "F", "Beta",
+                                               "Gate"))
+    taps = [ins[s][0] for s in ("ConvQ", "ConvK", "ConvV")]
+    H = int(attrs["num_heads"])
+    eps = float(attrs.get("epsilon", 1e-5))
+    B, T, width = q.shape
+    D = width // max(H, 1)
+    same = (B, T, width)
+    if (H * D != width or any(a.shape != same for a in (k, v, f, gate))
+            or b.shape != (B, T, H)
+            or any(t.shape != (width, taps[0].shape[1]) for t in taps)
+            or ins["ALog"][0].shape != (H,)
+            or ins["DtBias"][0].shape != (width,)):
+        raise ValueError(
+            f"kimi_delta_attention: Q {q.shape}, K {k.shape}, V {v.shape}, "
+            f"F {f.shape}, Beta {b.shape}, Gate {gate.shape}, taps "
+            f"{[t.shape for t in taps]} at {H} heads")
+    chunk = min(KDA_CHUNK, T)
+    if not ctx.in_grad_replay():
+        _MET_KDA.inc(heads=str(H), head_dim=str(D), chunk=str(chunk),
+                     conv_taps=str(taps[0].shape[1]),
+                     gate_rank=str(attrs.get("gate_rank", 0)))
+    wide = wide_dtype(q.dtype)
+    heads = lambda a: a.reshape(B, T, H, D).transpose(0, 2, 1, 3)  # noqa
+    with part_scope("kda.conv"):
+        unit = lambda a: a * jax.lax.rsqrt(                       # noqa: E731
+            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        qh, kh, vh = (heads(short_conv_silu(a, t))
+                      for a, t in zip((q, k, v), taps))
+        qh = (unit(qh) * D ** -0.5).astype(q.dtype)
+        kh, vh = unit(kh).astype(q.dtype), vh.astype(q.dtype)
+    with part_scope("kda.gates"):
+        beta = jax.nn.sigmoid(b.astype(wide)).transpose(0, 2, 1)
+        rate = -jnp.exp(ins["ALog"][0].astype(wide))              # [H]
+        g = heads(jax.nn.softplus(f.astype(wide) + ins["DtBias"][0].astype(
+            wide))) * rate[None, :, None, None]
+    gain = ins["Norm"][0]
+
+    def head(q1, k1, v1, g1, b1, z1):
+        """One head, [B, T, D] each (beta [B, T]): the rule, its norm and
+        gate."""
+        with part_scope("kda.scan"):
+            o = kda_chunked(*(a[:, None] for a in (q1, k1, v1, g1, b1)),
+                            chunk=chunk, sub=KDA_SUB)[:, 0]
+        with part_scope("kda.norm_gate"):
+            o = rms(o, eps, (2,), gain.astype(o.dtype))
+            return (o * jax.nn.sigmoid(z1.astype(o.dtype))).astype(q.dtype)
+
+    out = jax.lax.map(
+        lambda a: jax.checkpoint(head)(*a),
+        tuple(jnp.moveaxis(a, 1, 0) for a in (qh, kh, vh, g, beta,
+                                              heads(gate))))
+    out = out.transpose(1, 2, 0, 3).reshape(B, T, width)     # from [H, B, T, D]
+    return {"Out": [out]}
+
+
+# ---------------------------------------------------------------------------
 # block-top-k selection
 
 
@@ -711,6 +947,20 @@ def _gated_delta_cost(ins, outs, attrs):
                                    + 6 * dk * dv)}
 
 
+def _kda_cost(ins, outs, attrs):
+    """Per head and token, the products a chunked delta rule needs
+    (benchmarks/flops_kimi.py `kda_cost` has them one by one): 4 C D + 2 C
+    D + 2 C D + 6 D D at Dk = Dv = D."""
+    q = ins.get("Q", [None])[0]
+    if q is None or len(q.shape) != 3:
+        return {}
+    b, t, width = q.shape
+    d = width // int(attrs["num_heads"])
+    c = min(KDA_CHUNK, t)
+    return {"flops": b * t * width * (8 * c + 6 * d)}
+
+
 register_cost("lightning_attention", _lightning_cost)
+register_cost("kimi_delta_attention", _kda_cost)
 register_cost("gated_delta_rule", _gated_delta_cost)
 register_cost("block_sparse_attention", _sparse_attention_cost)

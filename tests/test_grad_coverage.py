@@ -139,11 +139,13 @@ def test_every_differentiable_op_is_checked_or_excluded():
     # flash's Mamba mixer and its gated memory unit; diff_attn_split,
     # diff_attn_combine: its differential attention around the flash call,
     # one a layer since PR 57), each numerically checked in test_phi4flash.py
-    assert len(diffable) == 166, (
+    # PR 58: +1 (kimi_delta_attention: Kimi-Linear's delta rule under a decay
+    # a channel), numerically checked in test_kimi_linear.py
+    assert len(diffable) == 167, (
         f"differentiable-op count changed ({len(diffable)}): update the "
         f"pin AND give each new op a check or an exclusion")
     assert len(EXCLUDED) == 11
-    assert len(checked) == 166 - 11
+    assert len(checked) == 167 - 11
 
 
 import pytest  # noqa: E402

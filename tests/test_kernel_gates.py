@@ -53,6 +53,9 @@ MECHANISMS = {
     # of 16384 tokens; RoPE's kernel in the window layers alone
     "smallthinker-21b-a3b": {"flash", "flash_window", "head_norm_rope",
                              "grouped_matmul", "segment_sum"},
+    # four Kimi-Delta-Attention layers in plain XLA (no kernel yet: ROADMAP
+    # S18) and ONE latent-attention layer without a rotary turn
+    "kimi-linear-48b-a3b": {"flash", "grouped_matmul", "segment_sum"},
 }
 
 
@@ -134,6 +137,8 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
                 dtype(op, "X"), Hv // Hk), (T, Dk, Dv, Hv // Hk)
             passed.add("gated_delta")
             positions = max(positions, T)
+        elif op.type == "kimi_delta_attention":   # no kernel: its tokens
+            positions = max(positions, shape(op, "Q")[1])
         elif op.type == "selective_scan":
             _, T, Di = shape(op, "U")
             N = shape(op, "ALog")[1]
