@@ -1,4 +1,4 @@
-"""Dense references and jaxpr helpers shared by the flash kernels' test files."""
+"""References, jaxpr helpers and the startup run once, shared by test files."""
 
 import functools
 
@@ -30,12 +30,30 @@ def _assert_named(got, want, lse=(1e-5, 0)):
     failure, to 2e-5: "nolse" (the forward that makes no logsumexp) against
     "out", and the logsumexp row, by which ring attention merges partial
     outputs, to `lse` (atol, rtol): 1e-5, absolute."""
-    for name in got:
+    made = ("out", "lse", "dq", "dk", "dv", "nolse")   # a jit sorts a dict
+    for name in sorted(got, key=made.index):
         atol, rtol = lse if name == "lse" else (2e-5, 2e-5)
         np.testing.assert_allclose(
             np.asarray(got[name]),
             np.asarray(want["out" if name == "nolse" else name]),
             atol=atol, rtol=rtol, err_msg=name)
+
+
+def _flash_results(q, k, v, do, kw, lse_shape, fwd=None, backward=True):
+    """{out, lse} (and dq, dk, dv, nolse): a case's kernels as ONE program."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    @jax.jit
+    def kernels(q, k, v, do):
+        out, lse = (fwd or fa.flash_attention_fwd)(q, k, v, **kw)
+        got = dict(out=out, lse=lse.reshape(lse_shape))
+        if backward:
+            got.update(zip(("dq", "dk", "dv"), fa.flash_attention_bwd(
+                q, k, v, out, lse, do, **kw)))
+            got["nolse"] = fa.flash_attention(q, k, v, **kw)
+        return got
+
+    return kernels(q, k, v, do)
 
 
 def _dense_masked(q, k, v, allowed):
@@ -76,3 +94,100 @@ def _dense_scaled(q, k, v, causal, scale):
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
     return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v),
             jax.scipy.special.logsumexp(s, axis=-1))
+
+
+def _startup(exe, kept):
+    """Run the default startup program; or, where `kept` ({} at first) holds
+    what an earlier run of the SAME one wrote, set that and count the run not
+    made (the steps draw what they drew): it is 2 to 10 s to compile."""
+    import paddle_tpu as fluid
+    from paddle_tpu.framework.core import np_dtype
+
+    startup, scope = fluid.default_startup_program(), fluid.global_scope()
+    names = {n for op in startup.global_block().ops for n in op.output_names()}
+    if not kept:
+        exe.run(startup)    # (a draw's temporaries stay out of the scope)
+        kept.update({n: np.asarray(scope.find(n)) for n in names
+                     if scope.find(n) is not None})
+        return
+    assert set(kept) <= names
+    for name, value in kept.items():
+        var = startup.global_block().var(name)
+        assert (tuple(var.shape), np_dtype(var.dtype)) == (
+            value.shape, value.dtype), name     # the SAME program's draw
+        scope.set(name, jnp.asarray(value))
+    exe.restore_state({"step": exe.global_step + 1})
+
+
+def _run_layer(build, feeds, weights=None, seed=11):
+    """Build a program with `build(x)` -> out, set `weights` {index: array}
+    over the parameters in creation order, run -> (out, parameters)."""
+    import paddle_tpu as fluid
+
+    fluid.reset()
+    x = fluid.layers.data("x", shape=list(feeds.shape[1:]), dtype="float32")
+    out = build(x)
+    main, startup = (fluid.default_main_program(),
+                     fluid.default_startup_program())
+    main.random_seed = startup.random_seed = seed
+    exe = fluid.Executor(fluid.CPUPlace())
+    params = main.global_block().all_parameters()
+    if set(weights or ()) != set(range(len(params))):
+        exe.run(startup)    # else every draw would be overwritten: 2-3 s
+    scope = fluid.global_scope()
+    for i, w in (weights or {}).items():
+        scope.set(params[i].name, jnp.asarray(w, jnp.float32))
+    (got,) = exe.run(feed={"x": feeds}, fetch_list=[out])
+    return np.asarray(got), [np.asarray(scope.find(p.name)) for p in params]
+
+
+def _rand(shape, seed, scale=1.0):
+    return (np.random.RandomState(seed).standard_normal(shape)
+            * scale).astype("float32")
+
+
+def _r(*shape, lo=-1.0, hi=1.0, seed=0):
+    return np.random.RandomState(seed).uniform(lo, hi, shape)
+
+
+def _series(family):    # [(labels, value)] of a family, sorted by labels
+    from paddle_tpu import observability as obs
+
+    fam = obs.REGISTRY.snapshot()["families"].get(family)
+    return sorted(((s["labels"], s["value"])
+                   for s in (fam["series"] if fam else [])),
+                  key=lambda s: sorted(s[0].items()))
+
+
+def _inner_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _inner_eqns(sub)
+
+
+def _close(got, want, tol):
+    """Within `tol` of the largest entry."""
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+
+def _dot(a, b):
+    return jnp.dot(a, b.astype(jnp.float32), precision="highest")
+
+
+_LM_DRAWN = {}      # {a toy tower's sizes: what its startup program drew}
+
+
+def _build_lm(V=50, D=32, L=2, NH=2, ML=64, seed=11):     # serving's toy
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer
+
+    lm = transformer.DecoderLM(V, D, L, NH, max_len=ML, dtype="float32")
+    tokens = fluid.layers.data("tokens", shape=[ML, 1], dtype="int64")
+    logits = lm.logits(tokens)
+    fluid.default_main_program().random_seed = seed
+    exe = fluid.Executor(fluid.CPUPlace())
+    _startup(exe, _LM_DRAWN.setdefault((V, D, L, NH, ML), {}))
+    return lm, exe, logits

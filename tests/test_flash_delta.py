@@ -48,10 +48,15 @@ def _allowed(T, mask):
                     (c >= L) & (c_blk <= r_blk))
 
 
+_BACKWARD = {}      # a case's three kernels run once for its two tests
+
+
 def _backward(case, monkeypatch):
     """(q, k, v, o, dO on [B, H, T, D], the mask's Allowed, what
     flash_attention_bwd returned in the case's layout, the delta rows its
     dq call handed its dkv call)."""
+    if case in _BACKWARD:
+        return _BACKWARD[case]
     layout, (B, H, Hkv, T, D, Dv), (bq, bk), mask = DELTA_CASES[case]
     rng = np.random.RandomState(47)
     q, k, v, do = (jnp.asarray(rng.randn(*s).astype(np.float32)) for s in (
@@ -88,7 +93,8 @@ def _backward(case, monkeypatch):
     assert all(rows is m for m in made) and len(made) == (layout == "packed")
     o = out if layout != "packed" else out.reshape(B, T, H, Dv).transpose(
         0, 2, 1, 3)
-    return (q, k, v, o, do), _allowed(T, mask), layout, grads, rows
+    return _BACKWARD.setdefault(
+        case, ((q, k, v, o, do), _allowed(T, mask), layout, grads, rows))
 
 
 @pytest.mark.parametrize("case", list(DELTA_CASES))

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _assert_named, _dense_scaled, _eqns
+from _kernel_refs import (_assert_named, _dense_scaled, _eqns,
+                          _flash_results, _with_vjp)
 
 
 # ---------------------------------------------------------------------------
@@ -54,9 +55,9 @@ def _scaled_case(widths, blocks, causal, group):
     kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
               interpret=True)
     s = scale if scale is not None else 1.0 / D ** 0.5
-    (out, lse), vjp = jax.vjp(
-        lambda *a: _dense_scaled(*a, causal, s), q, k, v)
-    dq, dk, dv = vjp((do, jnp.zeros_like(lse)))
+    (out, lse), (dq, dk, dv) = _with_vjp(
+        lambda *a: _dense_scaled(*a, causal, s),
+        (do, jnp.zeros((B, H, T), jnp.float32)), q, k, v)
     return (q, k, v, do), kw, dict(out=out, lse=lse, dq=dq, dk=dk, dv=dv)
 
 
@@ -64,16 +65,9 @@ def _check_scaled(widths, blocks, causal, group, fwd=None):
     """The kernels against the case's dense reference, each result named
     in the failure; under another forward (`fwd`, a mutant's) the two
     results the forward makes, and nothing of the backward."""
-    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
-
     (q, k, v, do), kw, want = _scaled_case(widths, blocks, causal, group)
-    out, lse = (fwd or fa.flash_attention_fwd)(q, k, v, **kw)
-    got = dict(lse=lse.reshape(want["lse"].shape), out=out)
-    if fwd is None:
-        got.update(zip(("dq", "dk", "dv"), fa.flash_attention_bwd(
-            q, k, v, out, lse, do, **kw)))
-        got["nolse"] = fa.flash_attention(q, k, v, **kw)
-    _assert_named(got, want)
+    _assert_named(_flash_results(q, k, v, do, kw, want["lse"].shape, fwd,
+                                 backward=fwd is None), want)
 
 
 @pytest.mark.parametrize("group", [1, 4], ids=["own_kv_head", "group_of_4"])

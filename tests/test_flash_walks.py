@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _assert_named, _dense_f32, _eqns
+from _kernel_refs import (_assert_named, _dense_f32, _eqns, _flash_results,
+                          _with_vjp)
 from paddle_tpu.ops.pallas_kernels.flash_attention import flash_attention
 from paddle_tpu.parallel.ring_attention import attention
 
@@ -83,9 +84,9 @@ def test_flash_backward_matches_dense(causal):
 
     f = fa.make_flash_train(causal=causal, interpret=True)
     wv = jnp.cos(jnp.arange(D))
-    g1 = jax.grad(lambda *a: (f(*a) * wv).sum(), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda *a: (dense(*a) * wv).sum(),
-                  argnums=(0, 1, 2))(q, k, v)
+    g1, g2 = jax.jit(lambda *a: tuple(   # both ways as ONE program
+        jax.grad(lambda *a: (fn(*a) * wv).sum(), argnums=(0, 1, 2))(*a)
+        for fn in (f, dense)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
@@ -104,8 +105,9 @@ def _walk_case(T, causal, seed=0):
     rng = np.random.RandomState(seed)
     q, k, v, do = (jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
                    for _ in range(4))
-    (out, lse), vjp = jax.vjp(lambda *a: _dense_f32(*a, causal), q, k, v)
-    dq, dk, dv = vjp((do, jnp.zeros_like(lse)))
+    (out, lse), (dq, dk, dv) = _with_vjp(
+        lambda *a: _dense_f32(*a, causal),
+        (do, jnp.zeros((B, H, T), jnp.float32)), q, k, v)
     return (q, k, v, do), dict(out=out, lse=lse, dq=dq, dk=dk, dv=dv)
 
 
@@ -113,17 +115,11 @@ def _check_walk(T, bq, bk, causal, forward_only=False):
     """out, lse, dq, dk, dv of the three kernels against dense float32
     attention and its gradients; `forward_only` the first two (a mutant of
     the walk, which every kernel shares, fails in them)."""
-    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
-
     (q, k, v, do), want = _walk_case(T, causal)
     kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
-    out, lse = fa.flash_attention_fwd(q, k, v, **kw)
-    got = dict(out=out, lse=lse.reshape(want["lse"].shape))
-    if not forward_only:
-        got.update(zip(("dq", "dk", "dv"), fa.flash_attention_bwd(
-            q, k, v, out, lse, do, **kw)))
-        got["nolse"] = fa.flash_attention(q, k, v, **kw)
-    _assert_named(got, want, lse=(2e-5, 2e-5))
+    _assert_named(_flash_results(q, k, v, do, kw, want["lse"].shape,
+                                 backward=not forward_only), want,
+                  lse=(2e-5, 2e-5))
 
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
@@ -219,8 +215,9 @@ def test_flash_dkv_transposed_tile_matches_dense(causal, widths, geometry,
             for _ in range(2))
     v, do = (jnp.asarray(rng.randn(1, 2, T, Dv).astype(np.float32))
              for _ in range(2))
-    (out, lse), vjp = jax.vjp(lambda *a: _dense_f32(*a, causal), q, k, v)
-    want = vjp((do, jnp.zeros_like(lse)))
+    (out, lse), want = _with_vjp(
+        lambda *a: _dense_f32(*a, causal),
+        (do, jnp.zeros((1, 2, T), jnp.float32)), q, k, v)
     plan = None
     if causal:
         plan = fa._schedule(T, bq, bk, min(max(bq, bk) // strips, bq))

@@ -4,13 +4,15 @@ token-by-token recurrence, the inverse on a tile, the gate `usable`, the
 float32 the kernels hold, and the op's choice between the kernels and the
 plain emission with what its grad op's re-emission is handed."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _close, _inner_eqns, _series, _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops import sparse_linear_ops as slo
@@ -54,11 +56,9 @@ def _recurrence(q, k, v, g, beta):
     return jax.vmap(jax.vmap(per_value_head))(q, k, v, g, beta)
 
 
-def _close(got, want, tol):
-    """Within `tol` of the largest entry."""
-    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
-    assert got.shape == want.shape
-    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+@functools.cache
+def _chunked(chunk):    # ONE function a chunk: its program serves every decay
+    return lambda *a: slo.gated_delta_chunked(*a, chunk=chunk)
 
 
 @pytest.mark.parametrize("decay", list(DECAYS))
@@ -74,8 +74,7 @@ def test_gated_delta_kernels_match_the_plain_emission(dtype, T, chunk, G,
     *ops, do = _operands(2, G, T, 16, 8, jnp.dtype(dtype), decay)
     how = dict(interpret=True)
     with jax.enable_x64(False):
-        want, grads = _with_vjp(
-            lambda *a: slo.gated_delta_chunked(*a, chunk=chunk), do, *ops)
+        want, grads = _with_vjp(_chunked(chunk), do, *ops)
         got = K.gated_delta_fwd(*ops, chunk, **how)
         mine = K.gated_delta_bwd(do, *ops, chunk, **how)
     assert got.dtype == jnp.float32
@@ -181,13 +180,6 @@ def test_gated_delta_kernels_take_whole_tiles(T, chunk, Dk, Dv, dtype, group,
     assert K.usable(T, chunk, Dk, Dv, jnp.dtype(dtype), group) is want
 
 
-def _inner_eqns(jaxpr):
-    for e in jaxpr.eqns:
-        yield e
-        for sub in jax.core.jaxprs_in_params(e.params):
-            yield from _inner_eqns(sub)
-
-
 @pytest.mark.parametrize("which", CALLS)
 def test_gated_delta_kernels_keep_state_and_gates_in_float32(which):
     """On bf16 q, k, v the carried state (VMEM scratch), every decay, the
@@ -231,13 +223,6 @@ def test_gated_delta_kernels_keep_state_and_gates_in_float32(which):
 
 # ---------------------------------------------------------------------------
 # the op: which emission, counted; what the grad op's re-emission is handed
-
-
-def _series(family):
-    fam = obs.REGISTRY.snapshot()["families"].get(family)
-    return sorted(((s["labels"], s["value"])
-                   for s in (fam["series"] if fam else [])),
-                  key=lambda s: sorted(s[0].items()))
 
 
 def _gdn_step(values, attrs, weight):

@@ -5,6 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _kernel_refs import _with_vjp
+
 
 # ---------------------------------------------------------------------------
 # head_norm_rope (PR 38): Q or K from the projection's [B, T, H * D] to
@@ -47,23 +49,26 @@ def test_head_norm_rope_kernels_match_the_plain_emission(D, form, period):
         args = (x,) if g is None else (x, g)
         plain = lambda x, g=None: llm_ops.head_norm_rope_plain(  # noqa: E731
             x, g, kw["heads"], kw["eps"], kw["theta"], period)
-        want, back = jax.vjp(plain, *args)
+        want, grads = _with_vjp(plain, dout, *args)   # ONE program
         got = K.head_norm_rope(x, g, **kw, **blocks)
         dx, dg = K.head_norm_rope_bwd(dout, x, g, **kw, **blocks)
         assert got.shape == want.shape and got.dtype == want.dtype
         np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
-        grads = back(dout)
         np.testing.assert_allclose(dx, grads[0], rtol=1e-5, atol=1e-5)
         assert (dg is None) == (g is None)
         if g is not None:
             assert dg.shape == g.shape and dg.dtype == jnp.float32
             np.testing.assert_allclose(dg, grads[1], rtol=1e-5, atol=1e-3)
         B, T, _ = x.shape
+
+        @jax.jit
+        def chain(y, g):
+            if kw["eps"] is not None:
+                y = llm_ops.rms(y, kw["eps"], (3,), g)
+            return llm_ops.rotate_half(y, kw["theta"], period)
+
         y = x.reshape(B, T, kw["heads"], D).transpose(0, 2, 1, 3)
-        if kw["eps"] is not None:
-            y = llm_ops.rms(y, kw["eps"], (3,), g)
-        chain = llm_ops.rotate_half(y, kw["theta"], period)
-        np.testing.assert_allclose(got, chain, rtol=2e-6, atol=2e-6)
+        np.testing.assert_allclose(got, chain(y, g), rtol=2e-6, atol=2e-6)
 
 
 @pytest.mark.parametrize("D", [128, 64], ids=["heads_of_128", "pairs_of_64"])

@@ -6,7 +6,11 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _startup
 from paddle_tpu import layers
+
+
+_DRAWN = {}     # what `_build`'s startup program drew
 
 
 def _build(layout, dtype):
@@ -29,7 +33,7 @@ def test_fuse_batch_norm_matches_unfused(layout, dtype):
     out = _build(layout, dtype)
     prog = fluid.default_main_program()
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, _DRAWN.setdefault(dtype, {}))   # whatever the layout
 
     # non-trivial running stats: startup leaves mean=0/var=1, under which a
     # broken fold could pass by accident
@@ -75,7 +79,7 @@ def test_save_inference_model_fold_batch_norm_roundtrip(tmp_path):
     out = _build("NCHW", "float32")
     prog = fluid.default_main_program()
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, _DRAWN.setdefault("float32", {}))
     rng = np.random.RandomState(3)
     scope = fluid.global_scope()
     for op in prog.global_block().ops:

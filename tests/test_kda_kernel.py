@@ -5,13 +5,15 @@ channels against the scalar-gated rule, the gate `usable`, the float32 the
 kernels hold, what the pair launches, and the op's choice between the kernels
 and the plain emission."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _close, _inner_eqns, _series, _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops import sparse_linear_ops as slo
@@ -67,13 +69,6 @@ def _hm(grads):
     return _tm(grads)
 
 
-def _close(got, want, tol):
-    """Within `tol` of the largest entry."""
-    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
-    assert got.shape == want.shape
-    assert np.abs(got - want).max() <= tol * np.abs(want).max()
-
-
 @pytest.fixture
 def blocks_of_8(monkeypatch):
     """Diagonal blocks of 8 rows (the constant is a whole toy chunk), so
@@ -89,6 +84,11 @@ def blocks_of_two_tiles(monkeypatch):
     monkeypatch.setattr(K, "ROWS", 8)
 
 
+@functools.cache
+def _chunked(chunk):    # ONE function a chunk: its program serves every decay
+    return lambda *a: slo.kda_chunked(*a, chunk=chunk, sub=4)
+
+
 @pytest.mark.parametrize("dtype,T,chunk,decay", [
     (dtype, 64, 32, decay) for dtype in ("float32", "bfloat16")
     for decay in DECAYS] + [("float32", 32, 8, "mixed")])
@@ -102,8 +102,7 @@ def test_kda_kernels_match_the_plain_emission(dtype, T, chunk, decay,
     *ops, do = _operands(2, T, 16, 8, jnp.dtype(dtype), decay)
     how = dict(interpret=True)
     with jax.enable_x64(False):
-        want, grads = _with_vjp(
-            lambda *a: slo.kda_chunked(*a, chunk=chunk, sub=4), do, *ops)
+        want, grads = _with_vjp(_chunked(chunk), do, *ops)
         got = K.kda_fwd(*_tm(ops), chunk, **how)
         mine = _hm(K.kda_bwd(do, *_tm(ops), chunk, **how))
     assert got.dtype == jnp.float32
@@ -159,9 +158,9 @@ def test_kda_kernels_with_equal_channels_are_the_scalar_gated_rule(
     g = jnp.broadcast_to(g[..., :1], g.shape)
     with jax.enable_x64(False):
         got = K.kda_fwd(*_tm((q, k, v, g, beta)), 32, interpret=True)
-        want = slo.gated_delta_chunked(q, k, v[:, :, None], g[:, :, None, :,
-                                                              0],
-                                       beta[:, :, None], chunk=32)[:, :, 0]
+        want = jax.jit(lambda *a: slo.gated_delta_chunked(*a, chunk=32))(
+            q, k, v[:, :, None], g[:, :, None, :, 0],
+            beta[:, :, None])[:, :, 0]
     _close(got, want, 5e-6)
 
 
@@ -231,13 +230,6 @@ def test_kda_kernels_take_whole_tiles(T, chunk, D, dtype, want):
     assert K.usable(T, chunk, D, jnp.dtype(dtype)) is want
 
 
-def _inner_eqns(jaxpr):
-    for e in jaxpr.eqns:
-        yield e
-        for sub in jax.core.jaxprs_in_params(e.params):
-            yield from _inner_eqns(sub)
-
-
 @pytest.mark.parametrize("which", CALLS)
 def test_kda_kernels_hold_float32_at_highest(which, blocks_of_8):
     """On bf16 q, k, v the carried state (VMEM scratch), the gates, every
@@ -277,13 +269,6 @@ def test_kda_kernels_hold_float32_at_highest(which, blocks_of_8):
 
 # ---------------------------------------------------------------------------
 # the op: which emission, counted; what the grad op's re-emission launches
-
-
-def _series(family):
-    fam = obs.REGISTRY.snapshot()["families"].get(family)
-    return sorted(((s["labels"], s["value"])
-                   for s in (fam["series"] if fam else [])),
-                  key=lambda s: sorted(s[0].items()))
 
 
 def _kda_values(T, H, D, taps=4, seed=0):

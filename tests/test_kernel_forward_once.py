@@ -7,13 +7,13 @@ On the CPU the Pallas path is reached as tests/test_kernel_dispatch.py does
 it: the emit context claims a TPU target and the kernels run in interpret
 mode.  The AOT test compiles the real kernels for a described v5e."""
 
-import os
 import re
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _startup
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
@@ -101,18 +101,30 @@ def _step(remat=False):
     return [np.asarray(g) for g in got]
 
 
+_KEPT = {}
+
+
+def _kept_pair_step(launches):
+    """`_step()` as it is, once a path (a program to compile) -> (its
+    results, what it launched, the two counters after it)."""
+    if launches.path not in _KEPT:
+        _KEPT[launches.path] = (_step(), list(launches), _counter(),
+                                _paths())
+    return _KEPT[launches.path]
+
+
 def test_saved_pair_gives_the_fallbacks_bits(pallas_on_cpu, monkeypatch):
     """Loss and every parameter gradient are equal to the last bit with
     the kept pair and without it, and each path is the one it claims."""
-    with_pair = _step()
-    assert len(pallas_on_cpu) == 1, pallas_on_cpu
-    assert _counter() == {(SDPA, "1"): 1.0}
+    with_pair, launched, counted, paths = _kept_pair_step(pallas_on_cpu)
+    assert len(launched) == 1, launched
+    assert counted == {(SDPA, "1"): 1.0}
     # the layer's layout, the emitter's path, and the forward emission
     # alone counted (the grad op's re-emission adds nothing)
-    assert _paths() == {("bthd", pallas_on_cpu.path): 1.0}
+    assert paths == {("bthd", pallas_on_cpu.path): 1.0}
     packed = pallas_on_cpu.path == "flash_packed"
-    assert pallas_on_cpu[0] == ((2, T, DIM) if packed
-                                else (2, HEADS, T, DIM // HEADS))
+    assert launched[0] == ((2, T, DIM) if packed
+                           else (2, HEADS, T, DIM // HEADS))
 
     del pallas_on_cpu[:]
     # the table emptied before the grad op: nothing is ever kept
@@ -143,7 +155,7 @@ def test_pair_of_another_value_is_not_used(pallas_on_cpu, monkeypatch):
 def test_remat_grad_op_still_recomputes(pallas_on_cpu):
     """`__remat__` asks for the forward again in the backward: the kept
     pair is left alone, and the gradients are the same numbers."""
-    plain = _step()
+    plain = _kept_pair_step(pallas_on_cpu)[0]
     del pallas_on_cpu[:]
     remat = _step(remat=True)
     # jax.checkpoint traces the custom_vjp's primal as well as its rule
@@ -178,8 +190,8 @@ def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):
         out = attention(q, k, v, causal=True)
         return (out * out).sum()
 
-    got = jax.grad(through_op, argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(through_op, argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
     assert _counter() == {}  # no grad op, nothing to count
@@ -255,10 +267,11 @@ def test_mamba_layers_reverse_pass_is_handed_the_kept_states(remat, reused,
         return [loss.name] + [p.name + "@GRAD"
                               for p in block.all_parameters()]
 
+    drawn = {}
     def step():
         fetch = build()
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
+        _startup(exe, drawn)
         return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
 
     want = step()
@@ -350,19 +363,6 @@ def test_score_counter_counts_at_trace_time_only(pallas_on_cpu):
 
 # ---------------------------------------------------------------------------
 # AOT: the real kernels, compiled for a described v5e
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
 
 
 def _lowered_step(loss, device, batch, seq_len):

@@ -12,6 +12,7 @@ expert counted once, add up to the uncut layer), the mixer as a layer and
 ops/sparse_linear_ops.py, ops/llm_ops.py, layers/nn.py,
 models/transformer.py."""
 
+import functools
 import hashlib
 import os
 import sys
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _dot, _r, _run_layer
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -28,10 +30,6 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 CONFIG = "kimi-linear-48b-a3b"
-
-
-def _r(*shape, lo=-1.0, hi=1.0, seed=0):
-    return np.random.RandomState(seed).uniform(lo, hi, shape)
 
 
 # ---------------------------------------------------------------------------
@@ -172,12 +170,13 @@ def test_kda_with_equal_channels_is_the_scalar_gated_rule():
     q, k, v, _, beta = _case(64)
     g = -_r(2, 64, lo=1e-3, hi=1.6, seed=6)
     with jax.enable_x64(True):
-        got = kda_chunked(*(jnp.asarray(a[None]) for a in (
-            q, k, v, np.repeat(g[..., None], 8, -1), beta)), chunk=16, sub=4)
-        want = gated_delta_chunked(
+        got = jax.jit(lambda *a: kda_chunked(*a, chunk=16, sub=4))(*(
+            jnp.asarray(a[None]) for a in (
+                q, k, v, np.repeat(g[..., None], 8, -1), beta)))
+        want = jax.jit(lambda *a: gated_delta_chunked(*a, chunk=16))(
             jnp.asarray(q[None]), jnp.asarray(k[None]),
             jnp.asarray(v[None, :, None]), jnp.asarray(g[None, :, None]),
-            jnp.asarray(beta[None, :, None]), chunk=16)
+            jnp.asarray(beta[None, :, None]))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want)[:, :, 0],
                                atol=1e-12)
     with pytest.raises(ValueError, match="do not divide"):
@@ -304,27 +303,6 @@ def test_kimi_delta_attention_refuses_shapes_that_do_not_add_up(
 # latent attention without a rotary turn
 
 
-def _run_layer(build, feeds, weights=None, seed=11):
-    """Build a program with `build(x)` -> out, set `weights` {index: array}
-    over the parameters in creation order, run -> (out, parameters)."""
-    import jax.numpy as jnp
-
-    fluid.reset()
-    x = fluid.layers.data("x", shape=list(feeds.shape[1:]), dtype="float32")
-    out = build(x)
-    main, startup = (fluid.default_main_program(),
-                     fluid.default_startup_program())
-    main.random_seed = startup.random_seed = seed
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    params = main.global_block().all_parameters()
-    scope = fluid.global_scope()
-    for i, w in (weights or {}).items():
-        scope.set(params[i].name, jnp.asarray(w, jnp.float32))
-    (got,) = exe.run(feed={"x": feeds}, fetch_list=[out])
-    return np.asarray(got), [np.asarray(scope.find(p.name)) for p in params]
-
-
 def _toy_ref_cfg():
     return {"num_attention_heads": 2, "kv_lora_rank": 16,
             "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
@@ -333,12 +311,6 @@ def _toy_ref_cfg():
             "linear_attn_config": {"num_heads": 2, "head_dim": 8,
                                    "short_conv_kernel_size": 4},
             "share": {"first_expert": 0}}
-
-
-def _dot(a, b):
-    import jax.numpy as jnp
-
-    return jnp.dot(a, b.astype(jnp.float32), precision="highest")
 
 
 def test_latent_attention_without_a_turn_is_the_plain_version():
@@ -376,9 +348,10 @@ def test_latent_attention_without_a_turn_is_the_plain_version():
     assert "rotary" not in op.attrs        # the parent's op, attr for attr
     assert positions() == {"rope": 1.0}
     with jax.enable_x64(False):
-        plain = lambda mutant: np.asarray(ref.latent_attention(  # noqa: E731
-            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps], _toy_ref_cfg(),
-            mutant, _dot, lambda a: a))
+        plain = functools.cache(lambda mutant: np.asarray(jax.jit(  # noqa
+            lambda x, ps: ref.latent_attention(
+                x, ps, _toy_ref_cfg(), mutant, _dot, lambda a: a))(
+            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps])))
         np.testing.assert_allclose(got[0], plain(""), atol=2e-5)
         np.testing.assert_allclose(turned[0], plain("rope"), atol=2e-5)
         for mutant in ("rope", "no_kp", "sqrt128"):
@@ -542,14 +515,19 @@ def test_kimi_delta_attention_layer_is_the_plain_version():
     assert 1e-3 <= dt.min() and dt.max() <= 0.1 + 1e-6
     assert np.abs(ps[8]).max() <= 0.5 and np.all(ps[13] == 1.0)
     with jax.enable_x64(False):   # the reference is float32, as on the chip
-        plain = lambda mutant: np.asarray(ref.kda(  # noqa: E731
-            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps], _toy_ref_cfg(),
-            mutant, _dot)[0])
-        np.testing.assert_allclose(got[0], plain(""), atol=2e-5)
-        for mutant in ("gate_mean", "no_dt_bias", "no_a_log", "no_beta",
-                       "no_l2norm", "q_unscaled", "no_state", "no_conv_silu",
-                       "silu_gate", "taps_reversed"):
-            assert np.abs(plain(mutant) - got[0]).max() > 1e-4, mutant
+        mutants = ("gate_mean", "no_dt_bias", "no_a_log", "no_beta",
+                   "no_l2norm", "q_unscaled", "no_state", "no_conv_silu",
+                   "silu_gate", "taps_reversed")
+        # the mixer and its ten mutants as ONE program (eagerly each is a
+        # scan and fifty small programs to compile)
+        plain = jax.jit(lambda x, ps: {mutant: ref.kda(
+            x, ps, _toy_ref_cfg(), mutant, _dot)[0]
+            for mutant in ("",) + mutants})(
+            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps])
+        np.testing.assert_allclose(got[0], plain[""], atol=2e-5)
+        for mutant in mutants:
+            assert np.abs(np.asarray(plain[mutant]) - got[0]).max() > 1e-4, (
+                mutant)
     fluid.reset()
     v = fluid.layers.data("x", shape=[T, D], dtype="float32")
     with pytest.raises(ValueError, match="0 heads"):

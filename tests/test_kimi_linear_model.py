@@ -145,7 +145,7 @@ def toy_case():
                           jnp.float32) for p in params]
         tok = jax.random.randint(jax.random.PRNGKey(3), (1, 128), 0, V)
         tgt = jnp.roll(tok, -1, axis=1)
-        want = ref.check_fn(ps, tok, tgt, cfg)
+        want = jax.jit(lambda ps: ref.check_fn(ps, tok, tgt, cfg))(ps)
     return ref, cfg, ps, tok, tgt, want
 
 
@@ -171,12 +171,13 @@ def test_kimi_linear_reference_check_fails_what_it_must(toy_case, mutant):
 
     drv = harness.load_module("drivers", "train_executor")
     ref, cfg, ps, tok, tgt, want = toy_case
-    key = MUTANTS[mutant]   # a gradient's key costs that one gradient more
+    # the named key alone, under ONE jit: XLA drops what it does not need
+    key = MUTANTS[mutant]
+    grad_params = (int(key[5:]),) if key.startswith("grad_") else ()
     with jax.enable_x64(False):
-        got = ref.check_fn(ps, tok, tgt, cfg, mutant, grad_params=(
-            (int(key[5:]),) if key.startswith("grad_") else ()))
-    errors = drv.reference_errors(got, {k: want[k] for k in got},
-                                  ref.CENTERED)
+        got = jax.jit(lambda ps: {key: ref.check_fn(
+            ps, tok, tgt, cfg, mutant, grad_params=grad_params)[key]})(ps)
+    errors = drv.reference_errors(got, {key: want[key]}, ref.CENTERED)
     failed = {k for k, e in errors.items() if not e <= ref.TOL[k]}
     assert MUTANTS[mutant] in failed, errors
 

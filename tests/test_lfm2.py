@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _rand
 from op_test import OpTestHarness
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer as tr
@@ -22,11 +23,6 @@ from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
-
-
-def _rand(shape, seed, scale=1.0):
-    return (np.random.RandomState(seed).standard_normal(shape)
-            * scale).astype("float32")
 
 
 def _load(name, path):
@@ -75,16 +71,22 @@ def test_flash_gqa_matches_dense_with_repeated_kv(group, causal, dv, blocks):
         do = jnp.asarray(_rand((B, kv_heads * group, T, dv), 4))
         kw = dict(causal=causal, interpret=True, block_q=blocks[0],
                   block_k=blocks[1])
-        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+
+        @jax.jit    # the case's kernels and its reference: ONE program
+        def kernels(q, k, v, do):
+            want, vjp = jax.vjp(lambda q, k, v: _dense_gqa(q, k, v, causal),
+                                q, k, v)
+            return (fa.flash_attention_fwd(q, k, v, **kw),
+                    fa.flash_attention(q, k, v, **kw),
+                    jax.vjp(fa.make_flash_train(**kw), q, k, v)[1](do),
+                    want, vjp(do))
+
+        (out, lse), nolse, got, want, grads = kernels(q, k, v, do)
         assert out.shape == do.shape and lse.shape == (
             B * kv_heads * group, T)
-        want, vjp = jax.vjp(lambda q, k, v: _dense_gqa(q, k, v, causal),
-                            q, k, v)
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
-        np.testing.assert_allclose(fa.flash_attention(q, k, v, **kw), want,
-                                   rtol=2e-5, atol=2e-5)
-        got = jax.vjp(fa.make_flash_train(**kw), q, k, v)[1](do)
-        for g, r, like in zip(got, vjp(do), (q, k, v)):
+        np.testing.assert_allclose(nolse, want, rtol=2e-5, atol=2e-5)
+        for g, r, like in zip(got, grads, (q, k, v)):
             assert g.shape == like.shape
             np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5)
 

@@ -8,12 +8,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _r, _with_vjp
 from op_test import OpTestHarness
-
-
-def _r(*shape, lo=-1.0, hi=1.0, seed=0):
-    return np.random.RandomState(seed).uniform(lo, hi, shape)
 
 
 # ---------------------------------------------------------------------------

@@ -11,18 +11,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _rand, _startup, _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import moe_ops, registry as reg
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def _rand(shape, seed, scale=1.0):
-    return (np.random.RandomState(seed).standard_normal(shape)
-            * scale).astype("float32")
 
 
 # ---------------------------------------------------------------------------
@@ -240,10 +235,9 @@ def test_a_buffer_too_small_reports_what_it_dropped():
             (3, (4, D, H)), (4, (4, D, H)), (5, (4, H, D)))]
         ctx = reg.EmitContext(None, is_test=False)
         route = {"scoring": "softmax", "renormalise": False, "scale": 1.0}
-        roomy = moe_ops._moe_share(ctx, x, gate, None, *w, None, k, "silu",
-                                   2, T * k, route)
-        tight = moe_ops._moe_share(ctx, x, gate, None, *w, None, k, "silu",
-                                   2, 8, route)
+        roomy, tight = (jax.jit(lambda x, gate, *w: moe_ops._moe_share(
+            ctx, x, gate, None, *w, None, k, "silu", 2, rows, route))(
+                x, gate, *w) for rows in (T * k, 8))   # a program each
     held = float(roomy[4][0])
     assert held > 8 and float(roomy[5][0]) == 0.0
     assert float(tight[4][0]) == held and float(tight[5][0]) == held - 8
@@ -455,9 +449,9 @@ def test_rows_no_group_has_never_reach_a_result(monkeypatch):
                 ctx, x, gate, None, *w, None, k, "silu", 2, T * k,
                 route)[0] ** 2)
 
-        clean = jax.value_and_grad(loss, range(5))(x, gate, *w)
+        clean = jax.jit(jax.value_and_grad(loss, range(5)))(x, gate, *w)
         monkeypatch.setattr(moe_ops, "_grouped_matmul", dirty)
-        got = jax.value_and_grad(loss, range(5))(x, gate, *w)
+        got = jax.jit(jax.value_and_grad(loss, range(5)))(x, gate, *w)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(clean)):
         assert np.isfinite(np.asarray(a)).all()
@@ -471,7 +465,7 @@ def test_rows_no_group_has_never_reach_a_result(monkeypatch):
 SHARE_ROWS = "moe_share_rows_to_tokens_traced_total"
 
 
-def _share_step(dtype, buffer_rows, layers=2):
+def _share_step(dtype, buffer_rows, drawn, layers=2):
     """`layers` gated share layers (4 of 8 experts held from 2 on, sigmoid
     scores, a selection bias, a shared expert) under SGD, one step: the
     last layer's Out and RouterWeights, then the gradients of its input
@@ -505,7 +499,7 @@ def _share_step(dtype, buffer_rows, layers=2):
         p.name + "@GRAD" for p in params]
     exe = fluid.Executor(fluid.CPUPlace())
     main.random_seed = fluid.default_startup_program().random_seed = 41
-    exe.run(fluid.default_startup_program())
+    _startup(exe, drawn)    # the same draws under both paths
     feed = {"x": _rand((tokens, dim), 5).astype(dtype)}
     return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
 
@@ -531,8 +525,8 @@ def test_share_with_the_segment_sum_kernel_equals_the_scatter_add(
         return {(s[0]["op"], s[0]["path"]): s[1]
                 for s in _series(SHARE_ROWS)}
 
-    layers = 2
-    fallback = _share_step(dtype, buffer_rows, layers)
+    layers, drawn = 2, {}
+    fallback = _share_step(dtype, buffer_rows, drawn, layers)
     assert paths() == {("combine", "scatter_add"): float(layers),
                        ("permute_grad", "scatter_add"): float(layers)}
 
@@ -542,7 +536,7 @@ def test_share_with_the_segment_sum_kernel_equals_the_scatter_add(
     monkeypatch.setattr(ss, "ROW_TILE", 32)
     monkeypatch.setattr(ss, "segment_sum",
                         functools.partial(ss.segment_sum, interpret=True))
-    kernel = _share_step(dtype, buffer_rows, layers)
+    kernel = _share_step(dtype, buffer_rows, drawn, layers)
     assert paths() == {("combine", "segment_sum"): float(layers),
                        ("permute_grad", "segment_sum"): float(layers)}
     assert _series("executor_grad_kernel_forward_total") == []

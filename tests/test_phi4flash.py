@@ -21,7 +21,7 @@ from paddle_tpu.models import transformer
 from paddle_tpu.ops import attention_ops, ssm_ops
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
-from _kernel_refs import _dense_masked as _dense, _with_vjp
+from _kernel_refs import _dense_masked as _dense, _r, _with_vjp
 from op_test import OpTestHarness
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -37,10 +37,6 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 def _ref():
     return harness.load_module("reference", CONFIG)
-
-
-def _r(*shape, lo=-1.0, hi=1.0, seed=0):
-    return np.random.RandomState(seed).uniform(lo, hi, shape)
 
 
 def _silu(x):

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _startup
 from paddle_tpu.ops import ssm_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -245,15 +246,21 @@ def test_differential_attention_layers_against_the_reference():
     feed = rng.randn(1, T, D).astype(np.float32)
     got = exe.run(feed={"x": feed}, fetch_list=outs)
     dot = lambda a, b: jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)  # noqa
-    kept = None
-    for n, (index, window, cross) in enumerate(
-            ((13, 16, False), (17, None, False), (19, None, True))):
-        want, kv, _ = ref.differential_attention(
-            jnp.asarray(feed[0]), values[9 * n:9 * n + 9], cfg, index, window,
-            kept if cross else None, "", dot)
-        kept = kv
+    layers = ((13, 16, False), (17, None, False), (19, None, True))
+
+    @jax.jit    # the three layers as ONE program, not op by op
+    def wants(x, values):
+        kept, outs = None, []
+        for n, (index, window, cross) in enumerate(layers):
+            want, kept, _ = ref.differential_attention(
+                x, values[9 * n:9 * n + 9], cfg, index, window,
+                kept if cross else None, "", dot)
+            outs.append(want)
+        return outs
+
+    for n, want in enumerate(wants(jnp.asarray(feed[0]), values)):
         np.testing.assert_allclose(got[n][0], want, rtol=2e-4, atol=2e-5,
-                                   err_msg=str(index))
+                                   err_msg=str(layers[n][0]))
 
 
 def test_a_differential_layer_is_one_attention_call(monkeypatch):
@@ -300,6 +307,7 @@ def test_a_differential_layer_is_one_attention_call(monkeypatch):
             "block_q": 64, "block_k": 64, **kw, "interpret": True}))
     feed = np.random.RandomState(3).randn(1, T, 32).astype(np.float32)
 
+    drawn = {}      # the tower's weights: one draw for both paths
     def step():
         """The tower's first step under SGD -> (the loss and the layers'
         outputs, what layer 17 handed on)."""
@@ -308,7 +316,7 @@ def test_a_differential_layer_is_one_attention_call(monkeypatch):
         loss = fluid.layers.mean(fluid.layers.sums(outs))
         fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
+        _startup(exe, drawn)
         return exe.run(feed={"x": feed}, fetch_list=[loss] + outs), made
 
     flash, made = step()
@@ -374,10 +382,13 @@ def test_mamba_and_gmu_layers_against_the_reference():
     got = exe.run(feed={"x": feed}, fetch_list=[out, memory[0], gmu])
     dot = lambda a, b: jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)  # noqa
     ps = [jnp.asarray(v) for v in values]
-    want, y = ref.mamba_mixer(jnp.asarray(feed[0]), ps[:9], {}, "", dot)
+
+    @jax.jit    # the mixer (a scan) and the unit as ONE program
+    def wants(h, ps):
+        want, y = ref.mamba_mixer(h, ps[:9], {}, "", dot)
+        return want, y, dot(y * jax.nn.silu(dot(h, ps[9])), ps[10])
+
+    want, y, unit = wants(jnp.asarray(feed[0]), ps)
     np.testing.assert_allclose(got[0][0], want, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(got[1][0], y, rtol=2e-4, atol=2e-5)
-    h = jnp.asarray(feed[0])
-    np.testing.assert_allclose(
-        got[2][0], dot(y * jax.nn.silu(dot(h, ps[9])), ps[10]), rtol=2e-4,
-        atol=2e-5)
+    np.testing.assert_allclose(got[2][0], unit, rtol=2e-4, atol=2e-5)

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _dot, _r, _run_layer, _with_vjp
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -26,10 +27,6 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 CONFIG = "qwen3-next-80b-a3b"
-
-
-def _r(*shape, lo=-1.0, hi=1.0, seed=0):
-    return np.random.RandomState(seed).uniform(lo, hi, shape)
 
 
 # ---------------------------------------------------------------------------
@@ -281,27 +278,6 @@ def test_head_norm_rope_turns_the_first_columns_alone(rotary):
                       {"num_heads": heads, "rotary_dim": 18}).fetch()
 
 
-def _run_layer(build, feeds, weights=None, seed=11):
-    """Build a program with `build(x)` -> out, set `weights` {index: array}
-    over the parameters in creation order, run -> (out, parameters)."""
-    import jax.numpy as jnp
-
-    fluid.reset()
-    x = fluid.layers.data("x", shape=list(feeds.shape[1:]), dtype="float32")
-    out = build(x)
-    main, startup = (fluid.default_main_program(),
-                     fluid.default_startup_program())
-    main.random_seed = startup.random_seed = seed
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    params = main.global_block().all_parameters()
-    scope = fluid.global_scope()
-    for i, w in (weights or {}).items():
-        scope.set(params[i].name, jnp.asarray(w, jnp.float32))
-    (got,) = exe.run(feed={"x": feeds}, fetch_list=[out])
-    return np.asarray(got), [np.asarray(scope.find(p.name)) for p in params]
-
-
 def _toy_ref_cfg():
     return {"num_attention_heads": 4, "num_key_value_heads": 2,
             "head_dim": 16, "rms_norm_eps": 1e-6, "rope_theta": 100.0,
@@ -309,12 +285,6 @@ def _toy_ref_cfg():
             "linear_num_value_heads": 4, "linear_key_head_dim": 8,
             "linear_value_head_dim": 8, "linear_conv_kernel_dim": 4,
             "num_experts_per_tok": 4, "share": {"first_expert": 0}}
-
-
-def _dot(a, b):
-    import jax.numpy as jnp
-
-    return jnp.dot(a, b.astype(jnp.float32), precision="highest")
 
 
 def test_gated_attention_layer_is_the_plain_version():
@@ -341,15 +311,18 @@ def test_gated_attention_layer_is_the_plain_version():
     gates = [op for op in fluid.default_main_program().global_block().ops
              if op.attrs.get("part") == "attn.gate"]
     assert len(gates) == 3
-    want = ref.attention(jnp.asarray(x[0]), [jnp.asarray(p) for p in ps],
-                         _toy_ref_cfg(), "", _dot, lambda a: a)
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    import jax
+
+    # the layer and its two mutants as ONE program, not op by op
+    plain = jax.jit(lambda x, ps: {mutant: ref.attention(
+        x, ps, _toy_ref_cfg(), mutant, _dot, lambda a: a)
+        for mutant in ("", "no_out_gate", "full_rotary")})(
+        jnp.asarray(x[0]), [jnp.asarray(p) for p in ps])
+    np.testing.assert_allclose(got[0], plain[""], atol=2e-5)
     # without the gate it is another function, and so with a whole turn
     for mutant in ("no_out_gate", "full_rotary"):
-        other = ref.attention(jnp.asarray(x[0]),
-                              [jnp.asarray(p) for p in ps], _toy_ref_cfg(),
-                              mutant, _dot, lambda a: a)
-        assert np.abs(np.asarray(other) - got[0]).max() > 1e-3, mutant
+        assert np.abs(np.asarray(plain[mutant]) - got[0]).max() > 1e-3, (
+            mutant)
     with pytest.raises(ValueError, match="rotary_dim"):
         fluid.reset()
         v = fluid.layers.data("x", shape=[T, D], dtype="float32")
@@ -390,9 +363,9 @@ def test_flash_kernels_at_256_wide_heads_group_of_8():
     kw = dict(causal=True, block_q=16, block_k=32, interpret=True)
     out, lse = fa.flash_attention_fwd(q, k, v, **kw)
     dq, dk, dv = fa.flash_attention_bwd(q, k, v, out, lse, do, **kw)
-    want, vjp = jax.vjp(_dense_attention, q, k, v)
+    want, grads = _with_vjp(_dense_attention, do, q, k, v)
     for name, got, ref in zip(("out", "dq", "dk", "dv"),
-                              (out, dq, dk, dv), (want,) + vjp(do)):
+                              (out, dq, dk, dv), (want,) + grads):
         assert got.shape == ref.shape, name
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=3e-4, rtol=3e-4, err_msg=name)
@@ -553,13 +526,17 @@ def test_gated_delta_net_layer_is_the_plain_version(monkeypatch):
     import jax
 
     with jax.enable_x64(False):   # the reference is float32, as on the chip
-        plain = lambda mutant: np.asarray(ref.delta_net(  # noqa: E731
-            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps], _toy_ref_cfg(),
-            mutant, _dot)[0])
-        np.testing.assert_allclose(got[0], plain(""), atol=2e-5)
-        for mutant in ("no_state", "no_beta", "no_decay", "no_l2norm",
-                       "key_head_mod", "taps_reversed", "no_z_gate"):
-            assert np.abs(plain(mutant) - got[0]).max() > 1e-3, mutant
+        mutants = ("no_state", "no_beta", "no_decay", "no_l2norm",
+                   "key_head_mod", "taps_reversed", "no_z_gate")
+        # the mixer and its seven mutants as ONE program, not op by op
+        plain = jax.jit(lambda x, ps: {mutant: ref.delta_net(
+            x, ps, _toy_ref_cfg(), mutant, _dot)[0]
+            for mutant in ("",) + mutants})(
+            jnp.asarray(x[0]), [jnp.asarray(p) for p in ps])
+        np.testing.assert_allclose(got[0], plain[""], atol=2e-5)
+        for mutant in mutants:
+            assert np.abs(np.asarray(plain[mutant]) - got[0]).max() > 1e-3, (
+                mutant)
     with pytest.raises(ValueError, match="value heads"):
         fluid.reset()
         v = fluid.layers.data("x", shape=[T, D], dtype="float32")

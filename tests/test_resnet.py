@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _startup
 from paddle_tpu.models import resnet
 
 
@@ -132,6 +133,9 @@ def _chain_reference(x, gamma, beta, w, res, cot, relu, stride, eps=1e-5):
             "beta": dpre.sum(axis=(0, 1, 2)), "w": dw, "res": dpre}
 
 
+_CHAIN_DRAWN = {}
+
+
 @pytest.mark.parametrize("relu", [True, False], ids=["relu", "linear"])
 @pytest.mark.parametrize("residual", [True, False], ids=["res", "plain"])
 @pytest.mark.parametrize("stride", [1, 2])
@@ -173,7 +177,7 @@ def test_batch_norm_conv_chain_matches_float64_reference(ksize, stride,
     names = dict(zip(("gamma", "beta", "w"), grads))  # creation order
 
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, _CHAIN_DRAWN.setdefault(ksize, {}))   # 16 cases, 2 programs
     import jax.numpy as jnp
 
     for key, v in (("gamma", gamma), ("beta", beta), ("w", w)):

@@ -103,8 +103,8 @@ def test_pallas_lstm_fused_backward_matches_scan_grads():
         return inner
 
     fused_fn = lambda x, h0, c0, w: fused(x, h0, c0, w, lengths)
-    g1 = jax.grad(loss(fused_fn), argnums=(0, 1, 2, 3))(x, h0, c0, w)
-    g2 = jax.grad(loss(ref), argnums=(0, 1, 2, 3))(x, h0, c0, w)
+    g1, g2 = (jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2, 3)))(
+        x, h0, c0, w) for fn in (fused_fn, ref))     # a program each
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 
@@ -239,10 +239,9 @@ def test_pallas_gru_forward_and_backward_match_scan():
         np.asarray(fused(x, h0, w, lengths)), np.asarray(ref(x, h0, w)),
         atol=1e-5)
     wv = jnp.cos(jnp.arange(H))
-    g1 = jax.grad(lambda *a: (fused(*a, lengths) * wv).sum(),
-                  argnums=(0, 1, 2))(x, h0, w)
-    g2 = jax.grad(lambda *a: (ref(*a) * wv).sum(), argnums=(0, 1, 2))(
-        x, h0, w)
+    g1, g2 = (jax.jit(jax.grad(lambda *a, fn=fn: (fn(*a) * wv).sum(),
+                               argnums=(0, 1, 2)))(x, h0, w)
+              for fn in (lambda *a: fused(*a, lengths), ref))
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 

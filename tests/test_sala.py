@@ -15,17 +15,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _r, _run_layer, _with_vjp
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
-
-
-def _r(*shape, lo=-1.0, hi=1.0, seed=0):
-    return np.random.RandomState(seed).uniform(lo, hi, shape)
 
 
 # ---------------------------------------------------------------------------
@@ -337,27 +333,6 @@ def test_visit_tables_walk_every_live_pair_once():
 
 # ---------------------------------------------------------------------------
 # the layers: creation order, the share, the dense length
-
-
-def _run_layer(build, feeds, weights=None):
-    """Build a program with `build(x)` -> out, set `weights` {index: array}
-    over the parameters in creation order, run -> (out, parameters)."""
-    fluid.reset()
-    x = fluid.layers.data("x", shape=list(feeds.shape[1:]), dtype="float32")
-    out = build(x)
-    main, startup = (fluid.default_main_program(),
-                     fluid.default_startup_program())
-    main.random_seed = startup.random_seed = 11
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    params = main.global_block().all_parameters()
-    scope = fluid.global_scope()
-    import jax.numpy as jnp
-
-    for i, w in (weights or {}).items():
-        scope.set(params[i].name, jnp.asarray(w, jnp.float32))
-    (got,) = exe.run(feed={"x": feeds}, fetch_list=[out])
-    return np.asarray(got), [np.asarray(scope.find(p.name)) for p in params]
 
 
 SPARSE_TOY = dict(kernel=8, stride=4, block=16, window=32, init_blocks=1,

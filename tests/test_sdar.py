@@ -12,18 +12,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _dense_masked as _dense, _with_vjp
+from _kernel_refs import _dense_masked as _dense, _rand, _with_vjp
 from op_test import OpTestHarness
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import attention_ops, llm_ops, moe_ops, registry as reg
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-
-
-def _rand(shape, seed, scale=1.0):
-    return (np.random.RandomState(seed).standard_normal(shape)
-            * scale).astype("float32")
 
 
 def _allowed(L, b):

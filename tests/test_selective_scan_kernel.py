@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _inner_eqns, _series, _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops import ssm_ops
@@ -191,13 +191,6 @@ def test_tile_is_the_widest_that_divides_and_fits(Di, N, tile):
     assert not tile or 4 * K.CHUNK * N * tile <= K.STATES_BYTES
 
 
-def _inner_eqns(jaxpr):
-    for e in jaxpr.eqns:
-        yield e
-        for sub in jax.core.jaxprs_in_params(e.params):
-            yield from _inner_eqns(sub)
-
-
 @pytest.mark.parametrize("which", CALLS)
 def test_scan_kernels_keep_state_and_delta_in_float32(which):
     """On bf16 U, Dt and dOut the carried state (VMEM scratch), Delta,
@@ -233,13 +226,6 @@ def test_scan_kernels_keep_state_and_delta_in_float32(which):
 
 # ---------------------------------------------------------------------------
 # the op: which emission, counted; what the grad op's re-emission is handed
-
-
-def _series(family):
-    fam = obs.REGISTRY.snapshot()["families"].get(family)
-    return sorted(((s["labels"], s["value"])
-                   for s in (fam["series"] if fam else [])),
-                  key=lambda s: sorted(s[0].items()))
 
 
 def _scan_values(T, Di=128, N=8, R=4, seed=0):

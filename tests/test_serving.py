@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu.models import transformer
+from _kernel_refs import _build_lm
 from paddle_tpu.serving import (ContinuousBatchingScheduler, PageAllocator,
                                 PagedKVCache, PreemptiveScheduler,
                                 PrefixCache, Request, ServingEngine,
@@ -191,16 +191,6 @@ def test_scheduler_rejects_unadmittable_at_submit():
 
 # ---------------------------------------------------------------------------
 # engine tier: exact greedy parity against the full-prefix oracle
-
-
-def _build_lm(V=50, D=32, L=2, NH=2, ML=64, seed=11):
-    lm = transformer.DecoderLM(V, D, L, NH, max_len=ML, dtype="float32")
-    tokens = fluid.layers.data("tokens", shape=[ML, 1], dtype="int64")
-    logits = lm.logits(tokens)
-    fluid.default_main_program().random_seed = seed
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    return lm, exe, logits
 
 
 def _oracle(exe, logits, ML, prompt, gen):

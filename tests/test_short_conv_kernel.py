@@ -4,7 +4,6 @@ choice between them, its grad op, and the kernels compiled for a described
 v5e at the cell's shape."""
 
 import functools
-import os
 import re
 
 import jax
@@ -13,6 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import llm_ops
 from paddle_tpu.ops import registry as reg
@@ -51,8 +51,8 @@ def test_short_conv_kernels_match_the_plain_emission(tiling, taps):
     x, w, dout = _operands(2, T, D, taps)
     how = dict(interpret=True, tile=tile, cols=cols)
     with jax.enable_x64(False):
-        want, back = jax.vjp(llm_ops.gated_short_conv_plain, x, w)
-        gx, gw = back(dout)
+        want, (gx, gw) = _with_vjp(llm_ops.gated_short_conv_plain, dout,
+                                   x, w)
         got = K.short_conv_fwd(x, w, **how)
         dx, dw = K.short_conv_bwd(dout, x, w, **how)
     assert got.shape == want.shape and got.dtype == want.dtype
@@ -332,19 +332,6 @@ def test_short_conv_op_takes_the_kernels_on_a_tpu(monkeypatch):
 # ---------------------------------------------------------------------------
 # AOT: the two kernels alone, compiled for a described v5e at the cell's
 # shape (no whole step: tests/benchmarks/test_lfm2_cell.py compiles that)
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
 
 
 @pytest.mark.parametrize("kernel", [K.FWD, K.BWD])

@@ -13,11 +13,7 @@ import paddle_tpu as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import moe_ops, registry as reg
-
-
-def _rand(shape, seed, scale=1.0):
-    return (np.random.RandomState(seed).standard_normal(shape)
-            * scale).astype("float32")
+from _kernel_refs import _rand, _with_vjp
 
 
 def _series(family):
@@ -83,19 +79,20 @@ def test_moe_op_routes_on_router_x_forward_and_both_gradients(share):
             return emit(reg.EmitContext(None, is_test=False), ins,
                         attrs)["Out"][0]
 
-        got, back = jax.vjp(op, x, rx, gate)
-        want, ref = jax.vjp(
+        # each side with its backward as ONE program (op by op: 100)
+        got, back = _with_vjp(op, do, x, rx, gate)
+        want, ref = _with_vjp(
             lambda x, rx, gate: _oracle(x, rx, gate, wi, wu, wo, k, first,
-                                        share), x, rx, gate)
+                                        share), do, x, rx, gate)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
-        for g, r in zip(back(do), ref(do)):
+        for g, r in zip(back, ref):
             assert float(jnp.abs(r).max()) > 1e-3   # each path carries one
             np.testing.assert_allclose(g, r, rtol=2e-3, atol=2e-5)
-        own = op(x, rx, gate, with_rx=False)
+        own, own_want = jax.jit(lambda x, rx, gate: (
+            op(x, rx, gate, with_rx=False),
+            _oracle(x, x, gate, wi, wu, wo, k, first, share)))(x, rx, gate)
         assert not np.allclose(own, got, atol=1e-3)
-        np.testing.assert_allclose(
-            own, _oracle(x, x, gate, wi, wu, wo, k, first, share),
-            rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(own, own_want, rtol=2e-4, atol=2e-5)
     assert _series("moe_router_input_traced_total") == {
         (("source", "block"),): 1.0, (("source", "mixer"),): 1.0}
     obs.REGISTRY.reset()

@@ -171,7 +171,7 @@ def toy_case():
                           jnp.float32) for p in params]
         tok = jax.random.randint(jax.random.PRNGKey(3), (1, T), 0, 97)
         feed = (tok, jnp.roll(tok, -1, axis=1))
-        want = ref.check_fn(ps, *feed, cfg)
+        want = jax.jit(lambda ps: ref.check_fn(ps, *feed, cfg))(ps)
     return ref, cfg, ps, feed, want
 
 
@@ -193,9 +193,13 @@ def test_smallthinker_reference_check_fails_what_it_must(toy_case, mutant):
 
     drv = harness.load_module("drivers", "train_executor")
     ref, cfg, ps, feed, want = toy_case
+    # what is read below, under ONE jit: XLA drops what the other keys need
+    keys = {MUTANTS[mutant], "dropped_pairs", "routed_pairs"}
     with jax.enable_x64(False):
-        got = ref.check_fn(ps, *feed, cfg, mutant)
-    errors = drv.reference_errors(got, want, ref.CENTERED)
+        got = jax.jit(lambda ps: {k: v for k, v in ref.check_fn(
+            ps, *feed, cfg, mutant).items() if k in keys})(ps)
+    errors = drv.reference_errors(got, {k: want[k] for k in got},
+                                  ref.CENTERED)
     failed = {k for k, e in errors.items() if not e <= ref.TOL[k]}
     assert MUTANTS[mutant] in failed, errors
     if mutant == "dropped_pair":
@@ -227,6 +231,7 @@ def test_the_reference_is_the_published_block(toy_case):
     with jax.enable_x64(False):
         at = 20
         other = tok.at[0, at].set((tok[0, at] + 1) % 97)
-        moved = np.abs(np.asarray(ref.check_fn(ps, other, tgt, cfg)[
-            "token_loss"]) - np.asarray(want["token_loss"]))
+        # ONE program on both batches: equal to the bit where it is causal
+        loss_of = jax.jit(lambda t: ref.check_fn(ps, t, tgt, cfg)["token_loss"])
+        moved = np.abs(np.asarray(loss_of(other)) - np.asarray(loss_of(tok)))
     assert moved[:at].max() == 0.0 and moved[at:].min() > 0.0

@@ -4,10 +4,13 @@ the trained scope, and the pieces (time embedding, transposed-conv
 shapes) hold their contracts."""
 
 import numpy as np
-import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _startup
 from paddle_tpu.models import unet
+
+
+_DRAWN = {}     # the toy U-Net's first weights: two tests build that program
 
 
 def _toy_batch(n=16, size=8):
@@ -20,7 +23,7 @@ def test_ddpm_trains_and_samples():
         image_size=8, channels=1, base_ch=8, ch_mults=(1, 2),
         learning_rate=2e-3)
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, _DRAWN)
     sched = unet.ddpm_schedule(T=50)
     rng = np.random.RandomState(0)
     x0 = _toy_batch()
@@ -102,7 +105,7 @@ def test_ddim_sampler_deterministic_and_finite():
         image_size=8, channels=1, base_ch=8, ch_mults=(1, 2),
         learning_rate=2e-3)
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, _DRAWN)
     sched = unet.ddpm_schedule(T=50)
     rng = np.random.RandomState(1)
     for _ in range(5):

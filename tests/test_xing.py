@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _rand, _startup
 from op_test import OpTestHarness
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import llm_ops
@@ -185,11 +186,6 @@ def test_mutants_of_the_reference_break_the_cells_tolerances(toy, mutant,
 
 # ---------------------------------------------------------------------------
 # the ops alone
-
-
-def _rand(shape, seed, scale=1.0):
-    return (np.random.RandomState(seed).standard_normal(shape)
-            * scale).astype(np.float32)
 
 
 def test_hyper_connection_ops_in_plain_numpy():
@@ -445,6 +441,7 @@ def test_toy_step_is_the_same_under_both_paths(monkeypatch):
     feed = {"tokens": tok, "targets": np.roll(tok, -1, 1),
             "next_targets": np.roll(tok, -2, 1)}
 
+    drawn = {}
     def step():
         fluid.reset()
         loss = tr.build_hc_mla_moe_lm_train_program(**toy)
@@ -452,7 +449,7 @@ def test_toy_step_is_the_same_under_both_paths(monkeypatch):
                          fluid.default_startup_program())
         main.random_seed = startup.random_seed = 11
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        _startup(exe, drawn)
         hc = [p.name + "@GRAD" for p in main.global_block().all_parameters()
               if p.name.startswith("hyper_connection")]
         return [np.asarray(a) for a in exe.run(feed=feed,
