@@ -11,7 +11,7 @@ A leaf: imports only the standard library.
 from __future__ import annotations
 
 import os
-from typing import Optional, Tuple
+from typing import Optional
 
 
 def _env_int(var: str, what: str) -> Optional[int]:
@@ -28,16 +28,6 @@ def _env_int(var: str, what: str) -> Optional[int]:
         raise ValueError(
             f"{var}={val} must be a positive integer ({what})")
     return val
-
-
-def flash_blocks(block_q: int, block_k: int, T: int) -> Tuple[int, int]:
-    """Requested flash-attention (block_q, block_k) before snapping:
-    PADDLE_TPU_FLASH_BQ / PADDLE_TPU_FLASH_BK, else the caller's.
-    Alignment/divisor clamping stays in the kernel's ``_snap_block``
-    (a hint, never a shape constraint)."""
-    bq = _env_int("PADDLE_TPU_FLASH_BQ", "flash-attention q block")
-    bk = _env_int("PADDLE_TPU_FLASH_BK", "flash-attention k/v block")
-    return (bq or int(block_q), bk or int(block_k))
 
 
 def paged_page_size(default: int = 16) -> int:

@@ -199,9 +199,6 @@ def _mask_attrs(attrs, T: int):
     return L, b
 
 
-WIDE_BLOCKS = (1024, 1024)   # (block_q, block_k) of a call at 256-lane heads
-
-
 @functools.lru_cache(maxsize=None)
 def _warn_dense_once(q_shape, k_shape, v_shape):
     import logging
@@ -245,6 +242,9 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     before a q block's windows are neither fetched nor computed).  `scale`:
     the softmax scale where it is not 1 / sqrt(D) (YaRN's temperature in
     latent attention), a positive float the three kernels take as theirs.
+    No block size is named here, for any width or mask: the kernels choose
+    theirs from the call's shape (`flash_attention.call_blocks`, the one
+    rule, PR 62; `flash_call_blocks_total` says what a compile ran at).
     -> None where it does not apply, else (out, saved).
 
     Training goes through the kernel pair (FlashAttention-2-style
@@ -277,19 +277,11 @@ def flash_single_chip(ctx, q, k, v, causal: bool, heads=None, mask=None,
     from .pallas_kernels import flash_attention as fa
 
     layout = {} if heads is None else {"heads": heads}
-    if heads is None and v.shape[3] == 256:
-        # two lane tiles in q, k AND v: q blocks of 1024 (the kernels'
-        # default is 512) read 14.67 ms forward + backward where the
-        # default reads 15.57, at 16 query heads on 2 key/value heads of
-        # 256, T 8192 (PERF.md, PR 48, has the eight pairs tried); every
-        # narrower call keeps the default
-        layout.update(block_q=WIDE_BLOCKS[0], block_k=WIDE_BLOCKS[1])
     if scale is not None:
         layout["scale"] = float(scale)
     if mask is not None:
-        layout.update(mask=(fa.sliding_window_mask(T, mask[1]) if window
-                            else fa.block_diffusion_mask(*mask)),
-                      block_q=fa.MASK_BLOCKS[0], block_k=fa.MASK_BLOCKS[1])
+        layout["mask"] = (fa.sliding_window_mask(T, mask[1]) if window
+                          else fa.block_diffusion_mask(*mask))
         causal = causal and not window
     if ctx.is_test:     # before the pair is made: an inference program
         # builds no training function

@@ -243,7 +243,7 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   5700000001):
     causal, blocks (512, 1024)        (a) 6.553 7.220 8.481
                                       (b) 6.631 7.333 8.472
-    sliding_window_mask(8192, 512), MASK_BLOCKS (1024, 1024)
+    sliding_window_mask(8192, 512), blocks (1024, 1024)
                                       (a) 2.159 1.685 1.991
                                       (b) 2.177 1.795 1.991
   (b) / (a) = 1.012 / 1.016 / 0.999 and 1.008 / 1.065 / 1.000: a 128-wide
@@ -258,11 +258,86 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   | 3.302 3.138 3.490; (1024, 512) 10.483 7.095 8.478 | 2.561 2.315 2.642;
   (256, 1024) 8.997 9.019 10.749 | 4.139 3.745 3.822; (a) reads within 2%
   of (b) at each.  The causal call at (1024, 1024) is 8-13% under the
-  default (512, 1024) at BOTH widths at this shape: not taken here (the
-  default serves every causal caller; a change of it is its own issue).
+  default (512, 1024) at BOTH widths at this shape: taken by PR 62 (below).
   Against dense float32 attention on the chip at T 1024 (bf16 operands,
   window 192): out / dq / dk / dv within 0.0039 / 0.0045 / 0.0066 / 0.0043
   of the largest element, what 64 / 64 reads.
+
+- The blocks of a call, from ONE rule of its shape (PR 62; `call_blocks`,
+  which every entry point asks where its caller names none: no constant
+  beside it, no environment variable).  Four probes since PR 27 had read
+  q blocks of 1024 under the default's 512 and each changed its own caller
+  alone; this one ran every cell's largest call.  Device ms a call from a
+  trace, forward keeping the logsumexp / dq / dkv, each kernel alone, 10
+  calls, two rounds a pair in ONE process (the rounds agree to 0.001 ms),
+  bf16, operands of 50-270 MB, the process's first timed calls thrown
+  away, seed 6200000001; causal but where said:
+                                   (512, 1024)            (1024, 1024)
+    LFM2 32 on 8 of 64, T 8192     4.818 5.367 6.777      4.466 4.987 6.232
+    Phi-4-mini-flash 40 on 20 of 64 / 128, T 8192
+                                   6.631 7.332 8.472      5.773 6.531 7.816
+    OLMoE 16 of 128, T 4096        0.797 0.749 0.868      0.709 0.660 0.819
+    SmallThinker 28 on 4 of 128, T 16384
+                                   17.146 19.297 23.778   15.071 17.256 21.728
+    Moonlight 16 of 192 / 128, T 8192
+                                   3.453 4.348 4.748      3.091 4.008 4.603
+    Kimi-Linear 32 of 192 / 128, T 8192
+                                   7.128 8.956 9.676      6.380 8.238 9.258
+    Xing4 32 of 192 / 128, T 4096  1.997 2.297 2.501      1.776 2.099 2.433
+    no mask, 16 of 128, T 4096     1.039 1.161 1.517      0.959 1.109 1.467
+    [B, T, H * D] 16 of 64, B 4, T 4096
+                                   2.782 2.872 3.255      2.560 2.650 3.106
+    [B, T, H * D] 16 of 128, B 2, T 4096
+                                   1.638 1.767 1.811      1.446 1.531 1.659
+                                   (2048, 1024)           (1024, 2048)
+    LFM2                           4.183 4.747 6.105      4.064 4.776 6.102
+    Phi-4-mini-flash               5.282 6.118 7.664      5.383 6.293 7.658
+    OLMoE                          0.633 0.621 0.816      0.588 0.595 0.815
+    SmallThinker                   13.762 16.291 20.963   14.088 16.678 20.963
+    Moonlight                      2.779 3.868 4.570      out of VMEM (dkv)
+    Xing4                          1.554 2.032 2.414      out of VMEM (dkv)
+    Kimi-Linear                    out of VMEM (forward: 17.1 MB of 16)
+    256 / 256 (Qwen3-Next)         out of VMEM (forward; (1024, 1024) reads
+                                   3.966 4.687 5.943 as PR 48 left it)
+    no mask, 16 of 128, T 4096     0.917 1.084 1.442      0.927 1.085 1.443
+    [B, T, H * D] of 64            out of VMEM            out of VMEM
+    [B, T, H * D] of 128           1.279 1.399 1.645      1.184 1.372 1.643
+    window 4096 of 16384, 28 on 4 of 128 (1024, 1024: 10.888 9.061 11.230)
+                                   8.411 8.205 100.057    7.864 8.567 99.922
+    window 512 of 8192, 40 on 20 of 64 / 128 (1024, 1024: 2.177 1.795 1.989)
+                                   1.929 1.587 1.991      1.739 1.663 1.896
+    block diffusion 2L 8192, 32 on 4 of 128 (1024, 1024: 3.424 2.956 3.616;
+    strips of 128 rows)            3.247 2.721 26.799     3.123 2.722 26.983
+  (1024, 1024) is 7-13% / 7-12% / 3-9% under (512, 1024) at EVERY shape,
+  and where a head is one lane tile of bf16 in q, k and v a q block of
+  2048 takes 6-11% / 3-6% / 0.4-3.5% more off: the forward's per-row
+  bookkeeping and every kernel's per-step cost are paid half as often, and
+  K and V are fetched half as often.  Of the two tall pairs (2048, 1024) is
+  the rule's: the forward's result stays the (512, 1024) call's to the bit
+  at every shape above (a row's K blocks and their order are what they
+  were; a K block of 2048 moves the output by a bf16 ulp, 9.8e-4), it fits
+  wherever (1024, 2048) does, and the sums differ by under 1.5% either
+  way.  A block of 2048 is
+  walked in eight strips (`_STRIPS_A_SIDE`) of 256 rows, so its staircase
+  is coarser: 51.5625% of the square computed at T 8192 where strips of
+  128 compute 50.781; sixteen strips of 128 rows a side read LFM2 4.486
+  4.743 6.021, OLMoE 0.709 0.619 0.795, SmallThinker 14.306 16.272 20.832,
+  Phi-4-mini-flash 5.662 6.110 7.561 at (2048, 1024): dkv 1-3% better, the
+  forward 4-12% worse, the sum worse, NOT kept.  Float32 operands were
+  compiled for a described v5e, not run: (2048, 1024) is out of VMEM at
+  heads of 64 and of 128, (1024, 1024) fits up to 192 / 128 and is 0.2 MB
+  over at 256 / 256 (as the parent was there; (512, 1024) fits).  Do NOT
+  try again:
+  (512, 512), (1024, 512), (256, 1024) (PRs 37 and 57: all lose), (2048,
+  2048) and (4096, 1024) (out of VMEM), a block of 2048 rows or columns
+  under a mask of its own at heads of 128 (dkv eight times slower, at
+  strips of 256 and of 128 alike: the single-shot body of a wholly live
+  block, not the strips).  Left: blocks chosen per KERNEL ((1024, 2048) for
+  the forward alone reads OLMoE 0.588 against 0.633, LFM2 4.064 against
+  4.183); the window at heads of 64, which has no cliff ((1024, 2048) at
+  strips of 128: 1.958 1.546 1.693 against 2.177 1.795 1.990, 2 ms of
+  Phi-4-mini-flash's step); [B, T, H * D] at heads of 128, which no cell
+  runs at a long T.
 
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
@@ -288,6 +363,14 @@ _MET_SCORES = _MET.counter(
     "kernel: part=square the B*H*T*T of the call, part=computed those its "
     "schedule computes (dead blocks and the part of a strip beyond a "
     "staircase's reach left out)")
+
+
+_MET_BLOCKS = _MET.counter(
+    "flash_call_blocks_total",
+    "flash kernel calls traced (once a compile, not once a step), by kernel "
+    "and by the snapped (block_q, block_k) the call runs at: what "
+    "`call_blocks` gave it, or its caller's explicit `block_q=` / "
+    "`block_k=`")
 
 
 _MET_DELTA = _MET.counter(
@@ -344,19 +427,11 @@ def _snap_blocks(block_q: int, block_k: int, T: int,
     Interpret mode has no Mosaic tile contract (tests run tiny T/blocks
     there), so it keeps plain largest-divisor snapping.
 
-    The requested blocks resolve through paddle_tpu/knobs.py at trace
-    time: the PADDLE_TPU_FLASH_BQ/BK env vars (validated — garbage
-    raises a clear error — and still clamped to legal aligned divisors
-    below), else the argument defaults.
-
     `causal_head` is the head size of a causal call (0 for any other): on
     the chip such a call runs one block a head where one_block_a_head
     says so, whatever q block was asked for.  `unit` is the length the
     blocks of a call under a mask of several regions have to divide
     (_mask_unit), where T is not it."""
-    from ... import knobs
-
-    block_q, block_k = knobs.flash_blocks(block_q, block_k, T)
     tile = 1 if interpret else 128
     bq = _snap_block(block_q, unit or T, tile)
     bk = _snap_block(block_k, unit or T, tile)
@@ -515,16 +590,6 @@ def sliding_window_mask(T: int, window: int) -> tuple:
     if not 0 < window <= T:
         raise ValueError(f"sliding window: {window} of {T} positions")
     return (_Stairs((0, T), (0, T), window=window),)
-
-
-# The blocks a call under a mask of several regions asks for where its
-# caller has no wish of its own (attention_ops.flash_single_chip): under
-# the block-diffusion mask at 2L = 8192, 32 query heads on 4 of 128, each
-# kernel alone read (device ms a call, forward / dq / dkv; module docstring,
-# PR 37) 4.18 / 3.36 / 4.25 at the causal calls' (512, 1024), 3.43 / 2.95 /
-# 3.62 here; (2048, 1024) and (1024, 2048) give the forward and dq 8% more
-# and cost dkv a factor of EIGHT (28.6 ms), (2048, 2048) runs out of VMEM.
-MASK_BLOCKS = (1024, 1024)
 
 
 def _mask_unit(mask, T: int) -> int:
@@ -1156,6 +1221,7 @@ class _Call(NamedTuple):
     Dv: int      # and of v
     group: int   # query heads on each key/value head
     nb: int      # lane blocks across [B, T, H * D] operands; 0: [B, H, T, D]
+    size: int = 2    # bytes an element of q, k and v
 
 
 def _call_of(q, k, v, heads) -> _Call:
@@ -1165,7 +1231,8 @@ def _call_of(q, k, v, heads) -> _Call:
     to a block, each query head on a key/value head of its own."""
     if heads is None:
         B, H, T, D = q.shape
-        return _Call(B * H, T, D, v.shape[-1], _group(q, k, v), 0)
+        return _Call(B * H, T, D, v.shape[-1], _group(q, k, v), 0,
+                     q.dtype.itemsize)
     B, T, W = q.shape
     D = W // heads
     if (k.shape != q.shape or v.shape != q.shape or D * heads != W
@@ -1174,7 +1241,7 @@ def _call_of(q, k, v, heads) -> _Call:
             f"flash attention on [B, T, H * D] operands takes q, k and v "
             f"of one shape and heads of 64 (an even number) or 128; got "
             f"{q.shape}, {k.shape}, {v.shape} at {heads} heads")
-    return _Call(B * heads, T, D, D, 1, W // 128)
+    return _Call(B * heads, T, D, D, 1, W // 128, q.dtype.itemsize)
 
 
 def _heads_first(a, call: _Call):
@@ -1183,17 +1250,47 @@ def _heads_first(a, call: _Call):
     return a if call.nb else a.reshape(-1, call.T, a.shape[-1])
 
 
+def call_blocks(c: _Call, mask=None) -> tuple:
+    """The (block_q, block_k) a call of the three kernels asks for where
+    its caller names none: the ONE place they are chosen, from what the
+    call shows (its widths, the bytes of an element, the layout, a mask
+    of its own), never from a model's name, an attribute a user sets or the
+    environment; `_snap_block` then fits them to T.  What the v5e read
+    fastest at every cell's largest call (module docstring, PR 62):
+
+    - q blocks of 2048 rows on K blocks of 1024 where a head is one lane
+      tile of two-byte elements in q, k AND v, on [B, H, T, D], under the
+      causal diagonal or no mask (the two read alike): 16-24% / 13-18% /
+      6-12% (forward / dq / dkv) under the (512, 1024) every such call
+      ran at before;
+    - (1024, 1024) everywhere else: heads wider than a lane tile (192 / 128
+      and 256 / 256: a q block of 2048 runs out of VMEM), four-byte
+      elements (the same), [B, T, H * D] (two heads of 64 a block: the
+      same), and a mask of its own, whose dkv costs EIGHT times as much
+      beyond 1024 rows at heads of 128 (PRs 37 and 62).
+
+    Four-byte elements at 256 / 256 do not compile at (1024, 1024) (0.2 MB
+    over the VMEM limit for a described v5e, as under the parent's blocks
+    for that width): no caller has them, and `block_q=512` serves one."""
+    if (mask is None and not c.nb and c.size <= 2
+            and max(c.D, c.Dv) <= 128):
+        return 2048, 1024
+    return 1024, 1024
+
+
 def _blocks(c, causal, mask, block_q, block_k, interpret):
-    """(bq, bk) of a call, snapped; under a `mask` of its own (a tuple of
-    _Stairs, which excludes `causal`) to what its regions' edges allow."""
-    if mask is None:
-        return _snap_blocks(block_q, block_k, c.T, interpret,
-                            c.D if causal else 0)
-    if causal:
+    """(bq, bk) of a call, snapped: `call_blocks`' where the caller named
+    none (None); under a `mask` of its own (a tuple of _Stairs, which
+    excludes `causal`) to what its regions' edges allow."""
+    if mask is not None and causal:
         raise ValueError("flash attention: `causal` and a `mask` of its "
                          "own exclude each other")
+    if block_q is None or block_k is None:
+        rule_q, rule_k = call_blocks(c, mask)
+        block_q, block_k = block_q or rule_q, block_k or rule_k
     return _snap_blocks(block_q, block_k, c.T, interpret,
-                        unit=_mask_unit(mask, c.T))
+                        c.D if causal else 0,
+                        _mask_unit(mask, c.T) if mask is not None else 0)
 
 
 def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse,
@@ -1209,6 +1306,7 @@ def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse,
             f"max on raw scores, which takes a positive scale")
     plan = (_masked_plan("flash_fwd", c.BH, c.T, bq, bk, mask)
             if causal or mask else None)
+    _MET_BLOCKS.inc(1, kernel="flash_fwd", block_q=str(bq), block_k=str(bk))
     return _fwd_call(c.BH, c.T, c.D, bq, bk, plan, with_lse, q.dtype,
                      interpret, s, c.Dv, c.group, c.nb)(
         *(_heads_first(a, c) for a in (q, k, v)))
@@ -1227,7 +1325,7 @@ def _group(q, k, v) -> int:
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
-                    block_q: int = 512, block_k: int = 1024,
+                    block_q=None, block_k=None,
                     interpret: bool = False, heads=None, mask=None):
     """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv] → [B,H,T,Dv] (Dv = D
     but in latent attention, whose keys carry rotary columns its values
@@ -1241,7 +1339,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     causal half (`block_diffusion_mask`); what no region holds is neither
     fetched nor computed.
     block_q/block_k are performance hints, snapped down to divisors of T;
-    D ≤ 128 recommended (one lane tile)."""
+    left out, `call_blocks` chooses them from the call's shape."""
     out = _forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    False, heads, mask)
     return out.reshape(q.shape[:3] + v.shape[3:])
@@ -1428,8 +1526,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=512,
-                        block_k=1024, interpret=False, heads=None,
+def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
+                        block_k=None, interpret=False, heads=None,
                         mask=None):
     """Forward that also returns the per-row logsumexp (backward
     residual), [B * H, T] in either layout."""
@@ -1557,7 +1655,7 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
 
 
 def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
-                        block_q=512, block_k=1024, interpret=False,
+                        block_q=None, block_k=None, interpret=False,
                         heads=None, mask=None):
     import jax.numpy as jnp
 
@@ -1577,6 +1675,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
         dq_plan = _masked_plan("flash_bwd_dq", c.BH, c.T, bq, bk, mask)
         dkv_plan = _masked_plan("flash_bwd_dkv", c.BH, c.T, bq, bk, mask)
     _MET_DELTA.inc(1, where="dq" if c.nb else "xla")
+    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        _MET_BLOCKS.inc(1, kernel=kernel, block_q=str(bq), block_k=str(bk))
     dq_call, dkv_call = _bwd_calls(c.BH, c.T, c.D, bq, bk, dq_plan,
                                    dkv_plan, q.dtype, interpret, s, c.Dv,
                                    c.group, c.nb)
@@ -1593,8 +1693,7 @@ _TRAIN_CACHE = {}
 
 
 def make_flash_train(causal: bool = False, scale=None, interpret=False,
-                     block_q: int = 512, block_k: int = 1024, heads=None,
-                     mask=None):
+                     block_q=None, block_k=None, heads=None, mask=None):
     """Fused attention for TRAINING as a `kernel_pair` (_common.py; honored
     by generic_grad's jax.vjp like the recurrence kernels).  Memoized per
     (causal, scale, interpret, blocks, heads, mask): emitters call this on

@@ -168,8 +168,10 @@ def test_flash_packed_train_pair_differentiates(monkeypatch):
 
 # the [B, H, T, D] entry is "one head, D lanes" of the body that also walks
 # two heads a block: sha256 of the jaxprs it traces to (forward with and
-# without the logsumexp, backward, the train wrapper's vjp; causal, default
-# blocks as the chip snaps them) under this suite's conftest, computed by
+# without the logsumexp, backward, the train wrapper's vjp; causal, the
+# blocks (512, 1024) every causal call ran at until PR 62 gave them a rule
+# from the call's shape, as the chip snaps them) under this suite's conftest,
+# computed by
 # this function at `git archive 7380891`, the parent of PR 36 (PR 47 gave
 # the OTHER entry's dq a delta of its own making and left this one's
 # backward, XLA's delta included, as it was: the hashes did not move)
@@ -195,14 +197,15 @@ def _old_entry_jaxprs(shape) -> str:
     q, k, v, o = (sds(B, H, T, D), sds(B, Hkv, T, D), sds(B, Hkv, T, Dv),
                   sds(B, H, T, Dv))
     lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32)
-    train = fa.make_flash_train(causal=True)
+    kw = dict(causal=True, block_q=512, block_k=1024)
+    train = fa.make_flash_train(**kw)
     texts = [
         jax.make_jaxpr(lambda q, k, v: fa.flash_attention_fwd(
-            q, k, v, causal=True))(q, k, v),
+            q, k, v, **kw))(q, k, v),
         jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
-            q, k, v, causal=True))(q, k, v),
+            q, k, v, **kw))(q, k, v),
         jax.make_jaxpr(lambda q, k, v, o, l, do: fa.flash_attention_bwd(
-            q, k, v, o, l, do, causal=True))(q, k, v, o, lse, o),
+            q, k, v, o, l, do, **kw))(q, k, v, o, lse, o),
         jax.make_jaxpr(lambda q, k, v, do: jax.vjp(train, q, k, v)[1](do))(
             q, k, v, o)]
     return hashlib.sha256("\n".join(map(str, texts)).encode()).hexdigest()

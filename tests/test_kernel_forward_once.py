@@ -496,19 +496,22 @@ def test_aot_one_qk_prep_kernel_each_way_for_q_and_for_k(v5e, head_dim,
 
 # B, H, T, D, Dv (and, where K and V have fewer heads, Hkv) of the cells'
 # attention: GPT-2-medium at batch 8, OLMoE and Moonlight (keys 192 wide,
-# values 128) at 1, LFM2 (32 query heads on 8 key/value heads of 64) at 1;
-# and a short chunk of ring
+# values 128) at 1, LFM2 (32 query heads on 8 key/value heads of 64) at 1,
+# SmallThinker's full-span layer (28 on 4 of 128 at T 16384) and Xing4's 32
+# heads of 192 / 128 at T 4096; and a short chunk of ring
 # attention's, whose blocks are the whole dimension (one UNDER 128 long is
 # refused by Mosaic in all three kernels: a lane offset into the logsumexp
 # row it cannot prove aligned, at PR 31's parent as after it: PERF.md 7)
 @pytest.mark.parametrize("shape", [
     (8, 16, 1024, 64, 64), (1, 16, 4096, 128, 128), (1, 16, 8192, 192, 128),
-    (2, 4, 256, 64, 64), (1, 32, 8192, 64, 64, 8)],
+    (2, 4, 256, 64, 64), (1, 32, 8192, 64, 64, 8),
+    (1, 28, 16384, 128, 128, 4), (1, 32, 4096, 192, 128)],
     ids=["gpt2m_train_bs8", "olmoe_train_t4096", "moonlight_train_t8192",
-         "whole_dimension_blocks", "lfm2_train_t8192"])
+         "whole_dimension_blocks", "lfm2_train_t8192",
+         "smallthinker_train_t16384", "xing4_train_t4096"])
 def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
-    """The three kernels with their walks, bf16 under the default blocks
-    and x64 off as the chip runs them, through Mosaic for the described
+    """The three kernels with their walks, bf16 under the blocks the rule
+    gives the call (`call_blocks`) and x64 off as the chip runs them, through Mosaic for the described
     v5e: a strip's edge it cannot align (the lane offset into the
     (1, 1, T) logsumexp row, a sublane offset into a K block, dkv's
     [K rows, q rows] tile) fails here and not first on the chip."""
@@ -525,8 +528,10 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
     v = jax.ShapeDtypeStruct((B, kv_heads, T, Dv), jnp.bfloat16,
                              sharding=one)
     lse = jax.ShapeDtypeStruct((B * H, T), jnp.float32, sharding=one)
-    bq, bk = fa._snap_blocks(512, 1024, T, causal_head=D)
-    assert (bq, bk) == ((T, T) if T <= 1024 else (512, 1024))
+    call = fa._Call(B * H, T, D, Dv, H // kv_heads, 0)
+    bq, bk = fa._blocks(call, True, None, None, None, False)
+    assert (bq, bk) == ((T, T) if T <= 1024
+                        else fa.call_blocks(call))
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         plan = fa._schedule(T, bq, bk, fa._strip_rows(kernel, bq, bk))
         assert plan.computed <= T * T * 0.75

@@ -3,8 +3,8 @@ the check that they admit the benchmark's cells.  For each LM configuration
 of benchmarks/configs (read, never edited) the train program is built from
 its `train.args`, with no environment set, and every kernel-backed op of it
 is put to its kernel's own gate at the shapes its desc carries: the flash
-blocks snap (the kernels' defaults, which `knobs.flash_blocks` hands back
-where no variable is set), and
+blocks snap (those `flash_attention.call_blocks` gives the call's shape),
+and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
 `hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable`,
 `gated_delta.usable`, `kda.usable`, `kda_conv.usable` and
@@ -12,7 +12,6 @@ where no variable is set), and
 
 import glob
 import importlib
-import inspect
 import json
 import os
 
@@ -76,16 +75,13 @@ def _batch(name):
 def _flash_gate(T, D, mask=None):
     """The blocks a call on T positions runs with on the chip."""
     fa = flash_attention
+    call = fa._Call(1, T, D, min(D, 128), 1, 0)
     if mask is None:
-        defaults = inspect.signature(fa.flash_attention).parameters
-        bq, bk = fa._snap_blocks(defaults["block_q"].default,
-                                 defaults["block_k"].default, T,
-                                 causal_head=D)
+        bq, bk = fa._blocks(call, True, None, None, None, False)
     else:
         regions = (fa.sliding_window_mask(T, mask[1]) if mask[0] == "window"
                    else fa.block_diffusion_mask(*mask))
-        bq, bk = fa._snap_blocks(*fa.MASK_BLOCKS, T,
-                                 unit=fa._mask_unit(regions, T))
+        bq, bk = fa._blocks(call, False, regions, None, None, False)
         fa._check_mask(regions, T, bq, bk)
     return all(b % 128 == 0 and T % b == 0 for b in (bq, bk))
 

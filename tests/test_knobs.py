@@ -10,8 +10,6 @@ DEFAULT = 48
 
 # variable -> the reader's value with `DEFAULT` as the caller's default
 READ = {
-    "PADDLE_TPU_FLASH_BQ": lambda: knobs.flash_blocks(DEFAULT, 7, 512)[0],
-    "PADDLE_TPU_FLASH_BK": lambda: knobs.flash_blocks(7, DEFAULT, 512)[1],
     # through the serving tier's entry, which is what the engine calls
     "PADDLE_TPU_PAGE_SIZE": lambda: page_size_from_env(DEFAULT),
     "PADDLE_TPU_SPEC_K": lambda: knobs.speculation_k(DEFAULT),

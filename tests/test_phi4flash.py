@@ -350,9 +350,7 @@ def test_sdpa_window_attrs_the_dense_path_and_the_gate():
             Ctx(), x, x[:, :2], x[:, :2], True, mask=("window", 128))
     finally:
         fa.flash_attention = real
-    assert took == [(False, fa.sliding_window_mask(256, 128),
-                     {"block_q": fa.MASK_BLOCKS[0],
-                      "block_k": fa.MASK_BLOCKS[1]})]
+    assert took == [(False, fa.sliding_window_mask(256, 128), {})]
 
 
 # ---------------------------------------------------------------------------

@@ -420,9 +420,9 @@ def test_flash_single_chip_takes_wide_values_and_names_what_it_refuses(
     warned = [r.getMessage() for r in caplog.records
               if "flash kernels' contract" in r.getMessage()]
     assert len(warned) == 3 and "(1, 16, 8192, 192)" in warned[0]
-    # heads of two lane tiles in q, k and v take q blocks of 1024; latent
-    # attention's 192 / 128 keeps the kernels' defaults
-    assert seen == [(256, 256, 1024, 1024), (192, 128, None, None)]
+    # no width names a block: the kernels choose theirs from the call's
+    # shape (flash_attention.call_blocks; tests/test_flash_blocks.py)
+    assert seen == [(256, 256, None, None), (192, 128, None, None)]
 
 
 # ---------------------------------------------------------------------------

@@ -56,7 +56,7 @@ Classes of rot this repo has actually accumulated:
      scope behaviour).
 
   10. raw tuning-knob env reads outside ``paddle_tpu/knobs.py`` —
-     PADDLE_TPU_FLASH_BQ/BK, PADDLE_TPU_PAGE_SIZE and friends are read
+     PADDLE_TPU_PAGE_SIZE, PADDLE_TPU_SPEC_K and friends are read
      and VALIDATED in that one module.  A raw ``os.environ`` read of a
      knob-class name anywhere else lets garbage values int()-crash at
      trace time or fall back to a default in silence.  Line-anchored
@@ -289,7 +289,7 @@ def _check_ckpt_writes(root, dirpath, filenames, findings):
 # definition — extend it when a new tunable parameter gains an env
 # override (and route the read through knobs.py).
 _KNOB_ENV_RE = re.compile(
-    r"os\.environ\b[^\n]*PADDLE_TPU_(?:FLASH_|PAGE_SIZE"
+    r"os\.environ\b[^\n]*PADDLE_TPU_(?:PAGE_SIZE"
     r"|SPEC_K\b|SPEC_DRAFT_LAYERS|STEPS_PER_DISPATCH)")
 # plain assignments (and the matching teardown pop) are the EXPORT side
 # of the knob layer (a bench pinning its config so knobs.py resolves it
