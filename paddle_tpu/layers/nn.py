@@ -7,6 +7,7 @@ lowers to XLA in one piece."""
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import List, NamedTuple, Optional, Sequence, Union
 
@@ -325,7 +326,7 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          head_dim=None, block_diffusion=None,
                          output_gate=False, rotary_dim=None, window=None,
                          bias=False, differential=None, kv=None,
-                         positions=None):
+                         positions=None, yarn=None, qk_norm_attr=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -352,13 +353,24 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     block_length): the T = 2L rows are the noised and the clean copy of L
     tokens; they attend under the block-diffusion mask (`mask` attrs of
     scaled_dot_product_attention) and row r is rotated as position r mod L.
-    `output_gate`: the query projection is twice as wide, [q | gate], and
-    the attention's result is multiplied by sigmoid(gate) before the output
-    projection (Qwen3-Next's gated attention; the op
-    `attention_output_gate`, scope `pdtpu.attn.gate`).  `rotary_dim`: the
+    `output_gate` True: the query projection is twice as
+    wide, [q | gate], and the attention's result is multiplied by
+    sigmoid(gate) before the output projection (Qwen3-Next's gated
+    attention; the op `attention_output_gate`, scope `pdtpu.attn.gate`).
+    `output_gate` "head": the gate is ONE number a token and head, from a
+    projection of its own, W_g [D, num_heads] without bias, on the layer's
+    input (created after W_v; Laguna's `gating` per-head); the product, the
+    sigmoid and the multiply lie in `pdtpu.attn.gate`.  `rotary_dim`: the
     rotary turn takes the first so many columns of a head alone (a
     `partial_rotary_factor`: rotate-half inside them, their own
-    frequencies) and the others pass unturned.
+    frequencies) and the others pass unturned.  `yarn` = {"factor",
+    "original_max"} and optionally "beta_fast" (32), "beta_slow" (1),
+    "attention_factor" (0.1 ln(factor) + 1): the turning columns take
+    YaRN's frequencies at `rope_theta` and their cos and sin the attention
+    factor, so a score's turned part carries its square and the unturned
+    part nothing; the softmax scale stays head_dim^-1/2 (`head_norm_rope`'s
+    attrs; it needs `rope_theta`).  `qk_norm_attr`: the attr of the two
+    per-head gains.
     `window` w: a causal layer's token sees the w keys that end with itself
     (key j iff 0 <= t - j < w; the attention op's `mask` "window").  `bias`:
     the projections have one (True, or the attr of all of them).
@@ -407,6 +419,16 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     if rotary_dim is not None and rope_theta is None:
         raise ValueError("multi_head_attention: rotary_dim is the width of "
                          "the rotary turn: give rope_theta")
+    if yarn is not None and rope_theta is None:
+        raise ValueError("multi_head_attention: yarn scales the rotary "
+                         "turn's frequencies: give rope_theta")
+    if output_gate not in (False, True, "head"):
+        raise ValueError(f"multi_head_attention: output_gate "
+                         f"{output_gate!r}: use True (a gate a column, from "
+                         f"a doubled query projection) or 'head' (one a "
+                         f"head, a projection of its own)")
+    head_gate = output_gate == "head"
+    output_gate = output_gate is True
     if window is not None and (not causal or block_diffusion):
         raise ValueError("multi_head_attention: a sliding window is a "
                          "causal layer's")
@@ -424,7 +446,7 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         masked.update(mask="window", window=int(window))
     if differential is not None:
         if (rope_theta is not None or qk_norm_epsilon is not None
-                or output_gate or block_diffusion):
+                or output_gate or head_gate or block_diffusion):
             raise ValueError("multi_head_attention: differential attention "
                              "takes no rotary turn, QK-norm, output gate or "
                              "block-diffusion mask")
@@ -459,6 +481,10 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
            param_attr=param_attr, bias_attr=bias_attr)
     v = fc(values, kv_heads * head_dim, num_flatten_dims=2,
            param_attr=param_attr, bias_attr=bias_attr)
+    if head_gate:   # one number a token and head, from the layer's input
+        with helper.main_program.part_guard("attn.gate"):
+            gate = fc(queries, num_heads, num_flatten_dims=2,
+                      param_attr=param_attr, bias_attr=False)
     qk_norm = functools.partial(rms_norm, epsilon=qk_norm_epsilon,
                                 part="attn.qk_norm")
     if qk_norm_epsilon is not None and not qk_norm_per_head:
@@ -485,13 +511,21 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         if qk_norm_per_head:
             # named as the `rms_norm` layer's gain it was: saved models and
             # every later `rms_norm` keep their parameters' names
-            gain = _rms_gain(LayerHelper("rms_norm"), head_dim, x.dtype)
+            gain = _rms_gain(LayerHelper("rms_norm"), head_dim, x.dtype,
+                             qk_norm_attr)
             ins["Scale"] = [gain.name]
             attrs["epsilon"] = qk_norm_epsilon
         if block_diffusion:
             attrs["period"] = int(block_diffusion[0])
         if rotary_dim is not None:
             attrs["rotary_dim"] = int(rotary_dim)
+        if yarn is not None:   # the defaults are `llm_ops.yarn_rule`'s
+            attrs.update(yarn_factor=float(yarn["factor"]),
+                         yarn_original_max=int(yarn["original_max"]))
+            attrs.update({"yarn_" + k: float(yarn[k])
+                          for k in ("beta_fast", "beta_slow") if k in yarn})
+            if yarn.get("attention_factor") is not None:
+                attrs["attention_factor"] = float(yarn["attention_factor"])
         r = helper.create_tmp_variable(
             x.dtype, shape=(x.shape[0], heads, x.shape[1], head_dim))
         helper.append_op("head_norm_rope", inputs=ins,
@@ -539,14 +573,19 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          attrs={"shape": [0, 0, num_heads * head_dim]})
     if gate is not None:
         gated = helper.create_tmp_variable(queries.dtype, shape=wide)
-        helper.append_op(
-            "attention_output_gate",
-            inputs={"X": [merged.name], "Gate": [gate.name]},
-            outputs={"Out": [gated.name]},
-            attrs={"num_heads": num_heads, "num_kv_heads": kv_heads,
-                   "head_dim": head_dim,
-                   "rotary_dim": int(rotary_dim or head_dim),
-                   "part": "attn.gate"})
+        # a head gate's op takes its part from the guard, as W_g's product
+        # did: `pdtpu.attn.gate` INSIDE the layer's own part where a tower
+        # names one (`attn.window` / `attn.full`)
+        with (helper.main_program.part_guard("attn.gate") if head_gate
+              else contextlib.nullcontext()):
+            helper.append_op(
+                "attention_output_gate",
+                inputs={"X": [merged.name], "Gate": [gate.name]},
+                outputs={"Out": [gated.name]},
+                attrs={"num_heads": num_heads, "num_kv_heads": kv_heads,
+                       "head_dim": head_dim,
+                       "rotary_dim": int(rotary_dim or head_dim),
+                       **({} if head_gate else {"part": "attn.gate"})})
         merged = gated
     out = fc(merged, D, num_flatten_dims=2, param_attr=out_param_attr,
              bias_attr=bias_attr)

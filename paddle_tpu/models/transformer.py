@@ -59,7 +59,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                logit_scale=None, delta=None, attention_gate=False,
                rotary_dim=None, ssm=None, differential=None, window=None,
                attention_bias=False, tie_embeddings=False, norm_attr=None,
-               kda=None):
+               kda=None, yarn=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -158,9 +158,22 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     gradients), no head matrix.  `norm_attr` = {"gain": attr, "bias": attr}
     for the block norms' and the final norm's parameters.
     `attention_gate` gives the layers that attend by 'multi_head' an output
-    gate from the query projection's second half, `rotary_dim` turns the
-    first so many columns of their heads alone
+    gate: True, one number a column from the query projection's second
+    half, or 'head', one a token and HEAD from a projection of its own;
+    `rotary_dim` turns the first so many columns of their heads alone
     (`layers.multi_head_attention`).
+    `n_heads`, `rope_theta` and `rotary_dim` each take ONE value for the
+    tower or a LIST of `n_layers` entries, the 'multi_head' layer's own
+    (as `positions` and `window` do: a tower whose window layers have more
+    query heads than its full-span ones, another base, another width of
+    the turn; every head count a multiple of `n_kv_heads`, which the
+    layer holds; a `rotary_dim` entry None turns the whole head).  `yarn` = {"factor", "original_max"}
+    and optionally "beta_fast", "beta_slow", "attention_factor"
+    (`layers.multi_head_attention(yarn=)`), or a list of `n_layers`
+    entries, None where the layer turns by the plain rule (Laguna: 72
+    heads, theta 1e4 over all 128 columns under the window; 48 heads,
+    theta 5e5 with YaRN over 64 columns across the whole sequence).  With
+    `norm_attr` the per-head QK-norm's two gains take its "gain" too.
     `residual_scale` multiplies every sub-layer's result before it is
     added to the stream, `emb_scale` the embedding, `logit_scale` the
     final norm's result before the head (MiniCPM's `scale_depth` /
@@ -215,6 +228,14 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         raise ValueError(f"positions {positions!r}: use 'learned', 'rope', "
                          f"'none' (no layer sees a position), or a list of "
                          f"'rope' / 'none' a layer")
+    for name, value in (("n_heads", n_heads), ("rope_theta", rope_theta),
+                        ("rotary_dim", rotary_dim), ("yarn", yarn)):
+        if isinstance(value, (list, tuple)) and len(value) != n_layers:
+            raise ValueError(f"decoder_lm: {name} {value!r}: layer by "
+                             f"layer, use {n_layers} entries")
+    if attention_gate not in (False, True, "head"):
+        raise ValueError(f"attention_gate {attention_gate!r}: use True (a "
+                         f"gate a column) or 'head' (one a head)")
     if moe is not None and moe.get("router_input", "block") not in (
             "block", "mixer"):
         raise ValueError(f"moe['router_input'] {moe['router_input']!r}: use "
@@ -292,6 +313,10 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     ssm = {} if ssm is None else ssm
     shared = {"memory": ssm.setdefault("memory", []), "kv": None}
 
+    def at(value, layer):   # a tower's one value, or the layer's own
+        return (value[layer] if isinstance(value, (list, tuple))
+                else value)
+
     def attend(h, layer, kv=None):
         diff = None
         if differential is not None:
@@ -300,18 +325,22 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
             diff["layer_index"] = differential.get(
                 "layer_indices", range(len(layer_types)))[layer]
         rule = positions[layer] if by_layer else positions
+        turn, rule_of = at(rotary_dim, layer), at(yarn, layer)
         out = layers.multi_head_attention(
-            h, h, h, num_heads=n_heads, causal=bd is None,
+            h, h, h, num_heads=at(n_heads, layer), causal=bd is None,
             param_attr=attr, out_param_attr=attr, sp_mode=sp_mode,
             sp_schedule=sp_schedule,
             qk_norm_epsilon=norm_epsilon if qk_norm else None,
             qk_norm_per_head=qk_norm == "head", num_kv_heads=n_kv_heads,
-            rope_theta=rope_theta if rule == "rope" else None,
+            rope_theta=at(rope_theta, layer) if rule == "rope" else None,
             **({"positions": rule} if by_layer else {}),
             **({"head_dim": head_dim} if head_dim else {}),
             **({"block_diffusion": bd} if bd else {}),
-            **({"output_gate": True} if attention_gate else {}),
-            **({"rotary_dim": rotary_dim} if rotary_dim else {}),
+            **({"output_gate": attention_gate} if attention_gate else {}),
+            **({"rotary_dim": turn} if turn else {}),
+            **({"yarn": rule_of} if rule_of else {}),
+            **({"qk_norm_attr": norm_attr["gain"]}
+               if qk_norm == "head" and norm_attr.get("gain") else {}),
             **({"window": window[layer]}
                if window and window[layer] and kv is None else {}),
             **({"bias": attention_bias} if attention_bias else {}),
@@ -360,7 +389,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 layer_index=linear.get("layer_indices",
                                        range(n_layers))[layer],
                 total_layers=linear.get("total_layers", n_layers),
-                heads_held=linear.get("heads_held"), rope_theta=rope_theta,
+                heads_held=linear.get("heads_held"),
+                rope_theta=at(rope_theta, layer),
                 epsilon=norm_epsilon, chunk=linear.get("chunk", 256),
                 param_attr=attr, gain_attr=linear.get("gain_attr"))
         if layer_types[layer] == "gated_delta_net":
@@ -374,8 +404,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 param_attr=attr)
         if attention == "latent":
             return layers.latent_attention(
-                h, n_heads, rope_theta=rope_theta, epsilon=norm_epsilon,
-                param_attr=attr, **mla)
+                h, at(n_heads, layer), rope_theta=at(rope_theta, layer),
+                epsilon=norm_epsilon, param_attr=attr, **mla)
         if differential is None and not window:
             return attend(h, layer)
         with default_main_program().part_guard(
@@ -530,7 +560,8 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "emb_scale": None, "logit_scale": None, "delta": None,
                "attention_gate": False, "rotary_dim": None, "ssm": None,
                "differential": None, "window": None, "attention_bias": False,
-               "tie_embeddings": False, "norm_attr": None, "kda": None}
+               "tie_embeddings": False, "norm_attr": None, "kda": None,
+               "yarn": None}
 
 
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
@@ -649,6 +680,8 @@ class DecoderLM:
                if n not in before and isinstance(v, Parameter)]
         self._block = {k: kw[k] for k, default in _GPT2_BLOCK.items()
                        if kw.get(k, default) != default}
+        if isinstance(self.n_heads, (list, tuple)):   # a head count a layer
+            self._block["n_heads"] = self.n_heads
         want = 2 + self._PER_LAYER * self.n_layers + 3
         assert self._block or len(new) == want, (len(new), want)
         self._params = new
@@ -1542,6 +1575,95 @@ def build_smallthinker_lm_train_program(
              "renormalise": True, "buffer_rows": buffer_rows},
         router_outputs=shares, init_scale=init_scale,
         emb_init_scale=emb_init_scale)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    # the last layer's routed (token, expert) pairs over ALL experts, for a
+    # fetch to hold exactly: seq_len * top_k a sequence
+    layers.reduce_sum(shares[-1].counts)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
+    return loss
+
+
+def build_laguna_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, heads_per_layer, n_kv_heads,
+        head_dim, sliding_window, rope_parameters, dense_dim, num_experts,
+        expert_dim, top_k, shared_dim, held_experts, first_expert=0,
+        buffer_rows=None, dense_layers=1, shared_experts=1, routed_scale=2.5,
+        norm_epsilon=1e-6, gain_range=None, dtype="bfloat16",
+        learning_rate=3e-5, init_scale=0.02, emb_init_scale=None):
+    """Laguna-shaped decoder (`model_type` laguna: Laguna-S-2.1) as ONE
+    CHIP'S SHARE of an expert-parallel deployment: RMSNorm pre-norm blocks;
+    grouped-query attention whose QUERY head count is the layer's own
+    (`heads_per_layer`) on `n_kv_heads` key/value heads of `head_dim`, no
+    bias, an RMSNorm on each head of Q and K, by `layer_types` under a
+    window of `sliding_window` keys that ends with the token
+    ('sliding_attention') or over the whole sequence ('full_attention'),
+    turned by the rule `rope_parameters` gives its layer type (the
+    published dict: `rope_theta`, `partial_rotary_factor` of the head's
+    columns, first ones, `rope_type` 'default' or 'yarn' with `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    `attention_factor` on the turned columns' cos and sin), the result
+    times sigmoid of ONE gate a token and head from a projection of its own
+    (`attention_gate` 'head'); the first `dense_layers` blocks with a
+    SiLU-gated MLP of `dense_dim`, every other with an expert layer whose
+    router scores all `num_experts` by softmax in float32, chooses `top_k`,
+    renormalises their weights to sum to one and multiplies them by
+    `routed_scale`; of those experts this chip holds `held_experts` from
+    `first_expert` on and computes their part in a buffer of `buffer_rows`
+    rows, beside `shared_experts` shared experts of `shared_dim` as ONE
+    gated MLP of their joint width, times a per-token sigmoid gate (one of
+    1024 in Laguna-S; the count is named as the other shares' builders name
+    it, for whoever reads `train.args`); `vocab_size` is the slice of the
+    vocabulary this chip embeds and scores; untied head.  `gain_range`
+    (lo, hi) draws every norm's gain (the blocks', the heads' and the
+    final one) uniformly instead of at one: a checked program must not
+    pass without them.  No block is recomputed (the cell's step fits
+    without; PERF.md, PR 63).  Loss: next-token cross entropy, no
+    auxiliary term; Adam.  Returns the loss.  Feeds as
+    `build_lm_train_program`."""
+    from .. import optimizer as opt
+    from ..framework.initializer import UniformInitializer
+
+    n_layers = len(layer_types)
+    if (set(layer_types) - set(rope_parameters)
+            or set(layer_types) - {"full_attention", "sliding_attention"}
+            or len(heads_per_layer) != n_layers):
+        raise ValueError(f"layer_types {layer_types!r}: 'full_attention' or "
+                         f"'sliding_attention', each with its entry of "
+                         f"heads_per_layer {heads_per_layer!r} and its rule "
+                         f"in rope_parameters {sorted(rope_parameters)}")
+    rules = [rope_parameters[t] for t in layer_types]
+    turns = [int(round(head_dim * float(r.get("partial_rotary_factor", 1))))
+             for r in rules]
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    gains = ({"initializer": UniformInitializer(*gain_range)}
+             if gain_range else None)
+    shares = []
+    logits = decoder_lm(
+        tokens, vocab_size, dim, n_layers,
+        [int(h) for h in heads_per_layer], max_len=seq_len, dtype=dtype,
+        norm="rms_norm", norm_epsilon=norm_epsilon,
+        positions="rope", qk_norm="head", n_kv_heads=n_kv_heads,
+        head_dim=head_dim, attention_gate="head",
+        rope_theta=[float(r["rope_theta"]) for r in rules],
+        rotary_dim=[t if t != head_dim else None for t in turns],
+        yarn=[{"factor": r["factor"],
+               "original_max": r["original_max_position_embeddings"],
+               **{k: r[k] for k in ("beta_fast", "beta_slow",
+                                    "attention_factor") if k in r}}
+              if r.get("rope_type", "default") == "yarn" else None
+              for r in rules],
+        window=[int(sliding_window) if t == "sliding_attention" else None
+                for t in layer_types],
+        ffn="moe", dense_layers=dense_layers, dense_dim=dense_dim,
+        moe={"num_experts": num_experts, "d_hidden": expert_dim,
+             "top_k": top_k, "held": (first_expert, held_experts),
+             "scoring": "softmax", "renormalise": True,
+             "routed_scale": routed_scale, "buffer_rows": buffer_rows,
+             "shared_hidden": shared_experts * shared_dim,
+             "shared_gate": True},
+        router_outputs=shares, norm_attr={"gain": gains},
+        init_scale=init_scale, emb_init_scale=emb_init_scale)
     loss = lm_loss(logits, targets, dtype=dtype)
     # the last layer's routed (token, expert) pairs over ALL experts, for a
     # fetch to hold exactly: seq_len * top_k a sequence

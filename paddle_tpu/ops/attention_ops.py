@@ -440,31 +440,54 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 
 _MET_GATED_ATTN = _MET.counter(
     "gated_attention_layers_traced_total",
-    "attention output gates traced (forward emission; once a compile, not "
-    "once a step), by the layer's query heads, key/value heads, head width "
-    "and the columns of a head its rotary turn takes")
+    "attention output gates an ELEMENT traced (forward emission; once a "
+    "compile, not once a step), by the layer's query heads, key/value "
+    "heads, head width and the columns of a head its rotary turn takes")
+_MET_HEAD_GATES = _MET.counter(
+    "attention_head_gates_traced_total",
+    "attention output gates a HEAD traced (forward emission; once a "
+    "compile, not once a step), by the layer's query heads (heads) and the "
+    "gate's form (form: head, one number a token and HEAD from a "
+    "projection of its own; a gate an element counts in "
+    "gated_attention_layers_traced_total)")
 
 
 @register_op("attention_output_gate")
 def attention_output_gate(ctx, ins, attrs):
-    """Out = X * sigmoid(Gate), X and Gate [B, T, H * D]: the output gate
-    of a gated softmax attention (Qwen3-Next), between the heads' merge and
-    the output projection; float32 inside.  attrs `num_heads`,
-    `num_kv_heads`, `head_dim`, `rotary_dim` say which layer it gates (the
-    counter's labels)."""
+    """Out = X * sigmoid(Gate): the output gate of a gated softmax
+    attention, between the heads' merge and the output projection; float32
+    inside.  X [B, T, H * D]; Gate as X, one number a column (Qwen3-Next),
+    or [B, T, H], one a HEAD, which multiplies that head's D columns
+    (Laguna's `gating` per-head; its gradient is the sum over a head's
+    columns of dOut * X, times sigmoid'): Gate's last dimension says
+    which.  attrs `num_heads`, `num_kv_heads`, `head_dim`, `rotary_dim`
+    say which layer it gates (the counters' labels)."""
     import jax
 
     from .llm_ops import wide_dtype
 
     x, gate = ins["X"][0], ins["Gate"][0]
+    a_head = gate.shape != x.shape
+    if a_head and (gate.shape[:-1] != x.shape[:-1]
+                   or x.shape[-1] % gate.shape[-1]):
+        raise ValueError(f"attention_output_gate: Gate {gate.shape} is "
+                         f"neither X's shape {x.shape} nor one number a "
+                         f"token and head of it")
     if not ctx.in_grad_replay():
         label = lambda name: str(int(attrs.get(name, 0)))  # noqa: E731
-        _MET_GATED_ATTN.inc(
-            q_heads=label("num_heads"), kv_heads=label("num_kv_heads"),
-            head_dim=label("head_dim"), rotary_dim=label("rotary_dim"))
+        if a_head:
+            _MET_HEAD_GATES.inc(heads=str(gate.shape[-1]), form="head")
+        else:
+            _MET_GATED_ATTN.inc(
+                q_heads=label("num_heads"), kv_heads=label("num_kv_heads"),
+                head_dim=label("head_dim"), rotary_dim=label("rotary_dim"))
     wide = wide_dtype(x.dtype)
+    merged = x.shape
+    if a_head:  # [B, T, H, D] times [B, T, H, 1]
+        x, gate = x.reshape(gate.shape + (-1,)), gate[..., None]
     out = x.astype(wide) * jax.nn.sigmoid(gate.astype(wide))
-    return {"Out": [out.astype(x.dtype)]}
+    out = out.astype(x.dtype)
+    return {"Out": [out.reshape(merged) if a_head else out]}
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,14 @@ _MET_QK_PREP = _MET.counter(
     "pallas_packed: two heads of 64 a block; xla: plain jax.numpy), the "
     "head size (head_dim), the head count (heads) and the norm in front of "
     "the turn (norm: head, a per-head RMSNorm, or none)")
+_MET_ROPE_TABLES = _MET.counter(
+    "rope_tables_traced_total",
+    "head_norm_rope ops traced (forward emission; once a compile, not once "
+    "a step; one for Q and one for K a layer), by the rule their cos and "
+    "sin tables were made by (rule: default, theta ** (-2i / rotary_dim); "
+    "yarn: YaRN's blended frequencies, cos and sin times its attention "
+    "factor), the columns of a head that turn (rotary_dim) and the base "
+    "(theta)")
 _MET_MLA_LAYERS = _MET.counter(
     "mla_layers_traced_total",
     "latent attention layers traced (forward emission; once a compile, not "
@@ -151,6 +159,25 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
+def yarn_rule(attrs, dim: int, theta: float):
+    """(inv_freq [dim / 2] or None, factor) of an op whose desc may state
+    YaRN on its rotary turn: with the attr `yarn_factor` (and
+    `yarn_original_max`, `yarn_beta_fast`, `yarn_beta_slow`) the `dim`
+    turning columns take `yarn_inv_freq`'s frequencies and cos and sin are
+    multiplied by `attention_factor` (absent: 0.1 ln(factor) + 1,
+    transformers' default); without it (None, 1.0): the plain rule."""
+    if "yarn_factor" not in attrs:
+        return None, 1.0
+    factor = float(attrs["yarn_factor"])
+    inv_freq = yarn_inv_freq(
+        dim, theta, factor, int(attrs["yarn_original_max"]),
+        float(attrs.get("yarn_beta_fast", 32.0)),
+        float(attrs.get("yarn_beta_slow", 1.0)))
+    scale = attrs.get("attention_factor")
+    return inv_freq, (yarn_mscale(factor, 1.0) if scale is None
+                      else float(scale))
+
+
 def rotate_half(x, theta: float, period: int = 0, inv_freq=None):
     """X [..., T, D] with D even turned by position: the pair (x[i], x[i +
     D/2]) of position t by the angle t * theta ** (-2i / D), positions
@@ -192,16 +219,19 @@ def rope(ctx, ins, attrs):
 
 
 def head_norm_rope_plain(x, gain, heads: int, eps, theta: float,
-                         period: int = 0, rotary_dim: int = 0):
+                         period: int = 0, rotary_dim: int = 0,
+                         inv_freq=None, factor: float = 1.0):
     """X [B, T, heads * D] -> [B, heads, T, D]: per head and row an
     RMSNorm over the head's D columns where `eps` is given (times `gain`
     [D], one for all heads, where given), then the rotate-half turn at the
     row's position (`rotate_half`'s angles; with `rotary_dim` on the
     head's first so many columns alone, rotate-half inside them at their
     own frequencies theta ** (-2i / rotary_dim), the other columns
-    unturned), at least float32 from end to
-    end with ONE rounding to X's dtype.  What the kernels of
-    ops/pallas_kernels/head_norm_rope.py compute, in plain jax.numpy."""
+    unturned; with `inv_freq` [rotary_dim / 2] at those frequencies, and
+    cos and sin times `factor`: the turned columns alone carry it), at
+    least float32 from end to end with ONE rounding to X's dtype.  What
+    the kernels of ops/pallas_kernels/head_norm_rope.py compute, in plain
+    jax.numpy."""
     import jax
     import jax.numpy as jnp
 
@@ -216,8 +246,8 @@ def head_norm_rope_plain(x, gain, heads: int, eps, theta: float,
     if gain is not None:
         y = y * gain.astype(wide)
     R = int(rotary_dim) or D
-    cos, sin = (t[None, :, None, :] for t in tables(T, R, theta, period,
-                                                    dtype=wide))
+    cos, sin = (t[None, :, None, :] for t in tables(
+        T, R, theta, period, dtype=wide, inv_freq=inv_freq, factor=factor))
     turned = y if R == D else y[..., :R]
     out = turned * cos + jnp.roll(turned, R // 2, axis=-1) * sin
     if R < D:
@@ -252,8 +282,11 @@ def _qk_prep(ctx, ins, attrs):
                          f"of {D}")
     pack = kernels.pack_of(x.shape[1], D, heads,
                            x.dtype) if pallas_dispatch_ok(ctx) else 0
-    if rotary != D:   # the kernels turn whole heads: the plain emission
-        kw["rotary_dim"], pack = rotary, 0
+    if rotary != D:   # a partial turn: the kernels take 64 of 128 alone
+        kw["rotary_dim"] = rotary
+        pack = pack if kernels.turn_of(D, rotary) else 0
+    if "yarn_factor" in attrs:   # both emissions read `tables`
+        kw["inv_freq"], kw["factor"] = yarn_rule(attrs, rotary, kw["theta"])
     return x, gain, kw, pack
 
 
@@ -289,15 +322,20 @@ def head_norm_rope(ctx, ins, attrs):
     over the head's D columns (attr `epsilon`; absent: no norm) times
     Scale [D] (optional; ONE gain for all heads), then the rotate-half
     rotary turn (`rope`'s: attrs `theta`, `period`; with `rotary_dim` on
-    the first so many columns of a head alone).  At least float32
-    inside, one rounding at the end.  attrs: `num_heads`.
+    the first so many columns of a head alone; with `yarn_factor`,
+    `yarn_original_max`, `yarn_beta_fast`, `yarn_beta_slow` and
+    `attention_factor` at YaRN's frequencies over those columns, cos and
+    sin times the factor: `yarn_rule`; the unturned columns carry neither).
+    At least float32 inside, one rounding at the end.  attrs: `num_heads`.
+    `rope_tables_traced_total{rule, rotary_dim, theta}` says which tables
+    a compile made.
 
     On one TPU with heads of 128 or 64 lanes and T in 128s a Pallas kernel
     reads each head's column block where it lies and writes it where the
     flash kernels read it (ops/pallas_kernels/head_norm_rope.py);
-    everywhere else (the CPU, a mesh, other head sizes, a partial turn:
-    `qk_prep_layers_traced_total{path="xla"}` says so) plain jax.numpy
-    (`head_norm_rope_plain`)."""
+    everywhere else (the CPU, a mesh, other head sizes, a partial turn
+    other than 64 columns of 128: `qk_prep_layers_traced_total{path="xla"}`
+    says so) plain jax.numpy (`head_norm_rope_plain`)."""
     from .pallas_kernels import head_norm_rope as kernels
 
     x, gain, kw, pack = _qk_prep(ctx, ins, attrs)
@@ -306,6 +344,10 @@ def head_norm_rope(ctx, ins, attrs):
             path=("xla", "pallas", "pallas_packed")[pack],
             head_dim=str(x.shape[2] // kw["heads"]), heads=str(kw["heads"]),
             norm="none" if kw["eps"] is None else "head")
+        _MET_ROPE_TABLES.inc(
+            rule="yarn" if "inv_freq" in kw else "default",
+            rotary_dim=str(kw.get("rotary_dim") or x.shape[2] // kw["heads"]),
+            theta=f"{kw['theta']:g}")
     emit = kernels.head_norm_rope if pack else head_norm_rope_plain
     return {"Out": [emit(x, gain, **kw)]}
 

@@ -34,6 +34,14 @@ by 32 inside each half chosen by a lane select, the two halves written as
 two [tile, 64] blocks.  Heads are innermost in the grid, so a tile's cos
 and sin' rows (two float32 [T, 128] tables a call, made in XLA: `tables`)
 are fetched once a tile.
+
+**A partial turn** of 64 columns in a head of 128 (`rotary_dim`; Laguna's
+full-span layers, PR 63) is the same body: the partner inside the first 64
+lanes is the roll by 32 that a packed head of 64 takes (`turn` 2, while the
+row statistic and the blocks written stay the whole head's, `pack` 1), and
+the tables carry cos = 1 and sin' = 0 over the last 64 lanes, where the
+partner's value is multiplied away.  No other partial turn is taken
+(`turn_of`): the plain emission runs those.
 """
 
 from __future__ import annotations
@@ -57,22 +65,43 @@ def pack_of(T: int, D: int, heads: int, dtype) -> int:
     return 2 if D == 64 and heads % 2 == 0 else 0
 
 
+def turn_of(D: int, rotary_dim: int) -> int:
+    """How the lanes of a 128-lane block pair up for the rotary turn of
+    the first `rotary_dim` columns of a head of D (0: all of them): 1, a
+    roll by 64 (a whole head of 128); 2, by 32 inside each half (a whole
+    head of 64, two a block, or the first 64 columns of a head of 128); 0
+    where the kernels do not take the turn."""
+    R = int(rotary_dim) or D
+    if R == D:
+        return LANES // D if D in (64, LANES) else 0
+    return 2 if (D, R) == (LANES, 64) else 0
+
+
 def tables(T: int, D: int, theta: float, period: int = 0, dtype=None,
-           lanes: int = 0):
+           lanes: int = 0, inv_freq=None, factor: float = 1.0):
     """(cos, sin') [T, lanes or D]: row r holds the angles of position r
     (r mod `period` where given), t * theta ** (-2i / D) in both halves of
     a head, sin' negative in the first half; with `lanes` a multiple of D,
-    the head's columns side by side that often."""
+    the head's columns side by side that often.  `inv_freq` [D / 2] (a
+    trace-time constant: YaRN's blend, `llm_ops.yarn_inv_freq`) stands in
+    for the row theta gives; `factor` multiplies cos and sin (YaRN's
+    attention factor).  D is the width that TURNS: under a partial turn
+    the caller hands in `rotary_dim` and leaves the other columns alone."""
     import jax.numpy as jnp
 
     dtype = jnp.float32 if dtype is None else dtype
     half = D // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=dtype) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=dtype) / half)
+    else:
+        inv_freq = jnp.asarray(inv_freq, dtype)
     pos = jnp.arange(T)
     if period:
         pos = pos % period
     ang = pos.astype(dtype)[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     reps = max(lanes // D, 1)
     return (jnp.tile(jnp.concatenate([cos, cos], axis=1), (1, reps)),
             jnp.tile(jnp.concatenate([-sin, sin], axis=1), (1, reps)))
@@ -111,7 +140,7 @@ def _head_mean(a, pack: int):
     return jnp.where(low, lo, hi) * (1.0 / 64)
 
 
-def _fwd_body(*refs, eps, pack, hb, gain):
+def _fwd_body(*refs, eps, pack, hb, gain, turn):
     import jax
     import jax.numpy as jnp
 
@@ -125,7 +154,7 @@ def _fwd_body(*refs, eps, pack, hb, gain):
             y = y * jax.lax.rsqrt(_head_mean(y * y, pack) + eps)
         if gain:
             y = y * g
-        out = (y * cos + _partner(y, pack) * sin).astype(o_ref.dtype)
+        out = (y * cos + _partner(y, turn) * sin).astype(o_ref.dtype)
         if pack == 1:
             o_ref[j] = out
         else:
@@ -133,7 +162,7 @@ def _fwd_body(*refs, eps, pack, hb, gain):
             o_ref[2 * j + 1] = out[:, 64:]
 
 
-def _bwd_body(*refs, eps, pack, hb, gain):
+def _bwd_body(*refs, eps, pack, hb, gain, turn):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -149,7 +178,7 @@ def _bwd_body(*refs, eps, pack, hb, gain):
         else:
             do = jnp.concatenate([do_ref[2 * j], do_ref[2 * j + 1]],
                                  axis=1).astype(jnp.float32)
-        dy = do * cos - _partner(do, pack) * sin
+        dy = do * cos - _partner(do, turn) * sin
         dx = dy
         if eps is not None:
             x = x_ref[:, j * LANES:(j + 1) * LANES].astype(jnp.float32)
@@ -189,9 +218,11 @@ def _row_tile(T: int, tile: int, itemsize: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _calls(B, T, heads, D, dtype, eps, gain, interpret, tile, hb):
+def _calls(B, T, heads, D, dtype, eps, gain, interpret, tile, hb, turn):
     """(forward, backward) calls on X [B, T, heads * D]; memoized and
-    jitted, so every layer of a model shares one trace of each body."""
+    jitted, so every layer of a model shares one trace of each body.
+    `turn`: `turn_of`'s pairing of the lanes (the heads a block, `pack`,
+    but under a partial turn)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -207,7 +238,7 @@ def _calls(B, T, heads, D, dtype, eps, gain, interpret, tile, hb):
                          lambda b, i, h: (b, h, i, 0))
     table = pl.BlockSpec((tile, LANES), lambda b, i, h: (i, 0))
     row = [pl.BlockSpec((1, LANES), lambda b, i, h: (0, 0))] * bool(gain)
-    kw = dict(eps=eps, pack=pack, hb=hb, gain=gain)
+    kw = dict(eps=eps, pack=pack, hb=hb, gain=gain, turn=turn)
     fwd = pl.pallas_call(
         functools.partial(_fwd_body, **kw),
         grid=grid,
@@ -237,36 +268,48 @@ def _calls(B, T, heads, D, dtype, eps, gain, interpret, tile, hb):
     return jax.jit(fwd), jax.jit(bwd)
 
 
-def _prepared(x, gain, heads, eps, theta, period, interpret, tile, hb):
+def _prepared(x, gain, heads, eps, theta, period, interpret, tile, hb,
+              inv_freq=None, factor=1.0, rotary_dim=0):
     """((forward, backward) calls for X, their operands after X and dOut:
     the two tables and, where there is one, the gain as a [1, 128] row)."""
     import jax.numpy as jnp
 
     B, T, width = x.shape
     D = width // heads
-    cos, sin = tables(T, D, theta, period, lanes=LANES)
+    R = int(rotary_dim) or D
+    cos, sin = tables(T, R, theta, period, lanes=LANES if R == D else 0,
+                      inv_freq=inv_freq, factor=factor)
+    if R != D:   # the unturned lanes: times one, plus nothing
+        rest = jnp.zeros((T, D - R), cos.dtype)
+        cos, sin = (jnp.concatenate([cos, jnp.ones_like(rest)], axis=1),
+                    jnp.concatenate([sin, rest], axis=1))
     row = [] if gain is None else [jnp.tile(
         gain.astype(jnp.float32).reshape(1, D), (1, LANES // D))]
     calls = _calls(B, T, heads, D, str(x.dtype), eps, gain is not None,
-                   interpret, tile, hb)
+                   interpret, tile, hb, turn_of(D, R))
     return calls, [cos, sin] + row
 
 
-def head_norm_rope(x, gain, *, heads, eps, theta, period=0, interpret=False,
+def head_norm_rope(x, gain, *, heads, eps, theta, period=0, rotary_dim=0,
+                   inv_freq=None, factor=1.0, interpret=False,
                    tile=ROW_TILE, hb=HEAD_BLOCKS):
     """X [B, T, heads * D] -> Out [B, heads, T, D] (module docstring);
-    `eps` None for no norm, `gain` [D] or None (only with a norm)."""
+    `eps` None for no norm, `gain` [D] or None (only with a norm);
+    `rotary_dim` a partial turn `turn_of` takes; `inv_freq` and `factor` as
+    `tables` takes them (the kernels read the two tables and know nothing
+    of the rule that made them)."""
     (fwd, _), rest = _prepared(x, gain, heads, eps, theta, period, interpret,
-                               tile, hb)
+                               tile, hb, inv_freq, factor, rotary_dim)
     return fwd(x, *rest)
 
 
 def head_norm_rope_bwd(dout, x, gain, *, heads, eps, theta, period=0,
+                       rotary_dim=0, inv_freq=None, factor=1.0,
                        interpret=False, tile=ROW_TILE, hb=HEAD_BLOCKS):
     """dOut [B, heads, T, D], X [B, T, heads * D] -> (dX like X, dGain
     float32 [D] or None)."""
     (_, bwd), rest = _prepared(x, gain, heads, eps, theta, period, interpret,
-                               tile, hb)
+                               tile, hb, inv_freq, factor, rotary_dim)
     if gain is None:
         return bwd(dout, x, *rest), None
     dx, parts = bwd(dout, x, *rest)

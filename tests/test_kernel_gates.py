@@ -57,6 +57,12 @@ MECHANISMS = {
     # layer without a rotary turn
     "kimi-linear-48b-a3b": {"flash", "grouped_matmul", "segment_sum", "kda",
                             "kda_conv"},
+    # two full-span layers of 48 query heads and three of 72 under a
+    # 512-key window, all on 8 key/value heads; the heads' preparation by
+    # the kernel in every layer, the full layers' turn of 64 columns in 128
+    # (YaRN's tables) too
+    "laguna-s-2.1": {"flash", "flash_window", "head_norm_rope",
+                     "grouped_matmul", "segment_sum"},
 }
 
 
@@ -153,7 +159,10 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
                                          dtype(op, "U")), (T, Di, N)
             passed.add("selective_scan")
             positions = max(positions, T)
-        elif op.type == "head_norm_rope" and "rotary_dim" not in op.attrs:
+        elif op.type == "head_norm_rope" and (
+                "rotary_dim" not in op.attrs or head_norm_rope.turn_of(
+                    shape(op, "X")[2] // op.attrs["num_heads"],
+                    op.attrs["rotary_dim"])):
             _, T, width = shape(op, "X")
             heads = op.attrs["num_heads"]
             assert head_norm_rope.pack_of(T, width // heads, heads,
