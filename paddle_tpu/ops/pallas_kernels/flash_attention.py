@@ -6,9 +6,16 @@ INNERMOST grid dimension so the Pallas pipeline double-buffers the K/V
 block DMAs against the MXU GEMMs.  The online softmax (running max m,
 normalizer l, unnormalized accumulator) lives in VMEM scratch, initialized
 at the first K block and finalized into the output block at the last.
-Under a mask the K/V index maps CLAMP a dead block's fetch to a live one,
-so fully-masked blocks are never fetched, and `pl.when` skips their
-compute.
+Under a mask a fully-masked block is neither fetched nor computed, and
+where the mask allows it not even a grid step: under a mask of ONE region
+whose longest run of live blocks is shorter than the axis (a sliding
+window) the walked axis of the grid is that run's length, and step j of
+q block i is the j-th K block of i's own run (`_spans`, `_run_of`; PR 64,
+below).  Everywhere else (the causal diagonal, whose last q block sees every
+K block; a mask of several regions) the grid is the whole square of blocks,
+the K/V index maps CLAMP a dead step's fetch to a live block the row already
+holds, and `pl.when` skips its compute: such a step moves and computes
+nothing and still costs 0.15-0.3 us.
 
 A mask is a static description of a call's LIVE REGIONS (`_Stairs`: a
 rectangle of whole blocks and a staircase of `step` rows a tread inside
@@ -182,11 +189,9 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   us; 64 steps a head here, 24 of them live, against 128 and 48 at (512,
   1024)) and K blocks stay longer; beyond 1024 rows dkv's transposed tile
   no longer fits the registers (8 times slower) or the kernel the VMEM.
-  NOT tried: clean keys and noisy keys as two K ranges of one walk (a
-  grid of L / bk + 1 steps a q block and no dead quadrant: it would take
-  the 40 dead steps a head, ~14 us of 107, out of the forward and dq, and
-  wants index maps that are no clamp); blocks chosen per kernel ((2048,
-  1024) for the forward and dq alone: 0.5 ms a layer).
+  NOT tried: blocks chosen per kernel ((2048, 1024) for the forward and dq
+  alone: 0.5 ms a layer).  A grid over a q block's own K range: TAKEN for
+  masks of one region (PR 64, below), NOT for this one, see there.
 
 - Where the backward's delta = rowsum(dO * O) is made (PR 47).  XLA made
   it everywhere: a float32 product, a sum over a head's columns, and on
@@ -339,6 +344,66 @@ alone, B 8, H 16, T 1024, D 64, bf16; PERF.md, PR 27):
   Phi-4-mini-flash's step); [B, T, H * D] at heads of 128, which no cell
   runs at a long T.
 
+- The grid's walked axis as long as the mask makes it (PR 64; `_spans`,
+  `_run_of`, `_k_run`, `_q_run`).  Every call used to launch the whole
+  square of blocks, (heads, T / bq, T / bk) and for dkv (K/V heads, T / bk,
+  group x T / bq); under a window most of it is dead steps: 49 of 64 a
+  head at 512 keys of 8192, 186 of 256 at 4096 of 16384, blocks (1024,
+  1024).  Under a mask of ONE region every q block's live K blocks are one
+  run [lo, hi] (the arithmetic the clamps always did) and every K block's
+  live q blocks another, so the walked axis takes the longest run's
+  length (2 and 5 steps there, for 8 and 16) and step j fetches block lo +
+  j: arithmetic index maps, no scalar-prefetched table (sparse_flash.py
+  found a table's LENGTH in SMEM slows a kernel of the same grid).  A run
+  shorter than the span (the first q blocks, the last K blocks of a
+  sequence: 1 step a head at 512 keys, 10 at 4096) keeps spare steps; a
+  body reads its place as first + step, and `_run_block` finds nothing
+  live there by the block's offset.  For that the spare steps must stand
+  INSIDE the square: dkv's runs are cut short by the sequence's END, and
+  a q block past it has the offset of a live block elsewhere (the first
+  form, run held at its last block, gave wrong dk and dv in the tests), so
+  a walk begins at min(lo, whole - span) and its spare steps come first
+  there.  A row meets the same K blocks in the same order: out, logsumexp,
+  dq, dk, dv are the whole grid's TO THE BIT (tests/test_flash_span.py in
+  interpret mode; on the chip at the three shapes below), and
+  `flash_score_elements_total` counts what it counted.  Where the longest
+  run IS the axis (the causal diagonal, no mask, one K block a sequence,
+  a window over all but a block) the call traces to the parent's jaxpr
+  byte for byte (tests/test_flash_packed.py, tests/test_flash_span.py).
+  Device ms a call from a trace, forward keeping the logsumexp / dq / dkv,
+  each kernel alone at a cell's window call, 10 calls, two rounds in ONE
+  process (they agree to 0.001 ms), bf16, blocks (1024, 1024), the
+  process's first timed calls thrown away, seed 6400000001
+  (_scratch/flash_span_probe.py):
+                                   whole grid             spanned grid
+    Laguna-S 72 on 8 of 128, 512 keys of 8192 (3456 dead steps gone)
+                                   4.121 3.301 3.478      3.535 2.303 2.542
+    SmallThinker 28 on 4 of 128, 4096 keys of 16384 (4928)
+                                   11.061 9.214 11.175    9.831 7.704 9.986
+    Phi-4-mini-flash 40 on 20 of 64 / 128, 512 keys of 8192 (1920)
+                                   2.236 1.600 2.005      1.954 1.153 1.422
+  So a dead step costs the forward 0.15-0.25 us, dq 0.23-0.31 and dkv
+  0.24-0.30 (PR 37 took 0.35 from one forward): 14 / 30 / 27% of a
+  Laguna-S window call, 11 / 16 / 11% of a SmallThinker one.  What is left
+  of a window call is live work: at 512 keys the forward still stands at
+  about half its roof, and that is per-row bookkeeping on blocks a
+  quarter live, not the grid.  `flash_grid_steps_total{kernel, part}`
+  counts a traced call's steps and the live ones among them.
+  NOT taken: the block-diffusion mask's two runs a noisy q block (its
+  block diagonal, then its clean context) laid end to end.  It needs no
+  second body and no table, but (1) dkv gains nothing: the first clean K
+  block is seen by every q block, its run is the axis; (2) the runs'
+  lengths differ by q block, so a step's K block is a select on the
+  step (`j < len1(i)`) in the index map AND in the body, and the band of
+  clean rows walks another layout of the same axis; (3) the forward and
+  dq would shed 24 of 64 steps a head, 768 a call, 0.12-0.24 ms a kernel
+  by the costs above, 0.3-0.45 ms a layer of SDAR's 179 ms step.  Left for
+  a PR of its own; that call is on the whole grid as it was.  The
+  [B, T, H * D] layout takes no mask of its own and walks the whole axis.
+  To be probed AGAIN on the spanned grid before anyone asks for it:
+  blocks chosen per KERNEL under a window (PR 62's (1024, 2048) forward
+  gained largely by halving the dead steps that are gone now).
+
 The logsumexp residual rides a (1, 1, T) full-row block: Mosaic's tile
 contract wants the last two block dims (8,128)-divisible or equal to the
 array's — a (1, bq) block over a (BH, T) array satisfies neither (first
@@ -363,6 +428,16 @@ _MET_SCORES = _MET.counter(
     "kernel: part=square the B*H*T*T of the call, part=computed those its "
     "schedule computes (dead blocks and the part of a strip beyond a "
     "staircase's reach left out)")
+
+
+_MET_STEPS = _MET.counter(
+    "flash_grid_steps_total",
+    "grid steps of the masked flash kernel calls traced, causal or under a "
+    "mask of their own (once a compile, not once a step), by kernel: "
+    "part=grid the steps the call launches (heads x blocks of the outer "
+    "axis x steps of the walked one, `_spans`), part=live those whose block "
+    "holds a live score; the rest fetch and compute nothing and still cost "
+    "a step each")
 
 
 _MET_BLOCKS = _MET.counter(
@@ -658,6 +733,97 @@ def _clamp(x, lo, hi, first: int, last: int):
     return x
 
 
+def _bound(pick, a, b):
+    """The greater (`pick` max) or lesser (min) of a and b: Python's own
+    on two ints (the spans are counted at trace time, _spans), the traced
+    one in an index map."""
+    import jax.numpy as jnp
+
+    if isinstance(a, int) and isinstance(b, int):
+        return pick(a, b)
+    return (jnp.maximum if pick is max else jnp.minimum)(a, b)
+
+
+def _k_run(s, i, bq: int, bk: int):
+    """(lo, hi): the first and the last K block that holds a score q block
+    i sees in region `s`; `i` a grid index or an int."""
+    # the last row of q block i sees up to column `hi` of the square,
+    # the first one, in a band, from `lo` on
+    off = s.cols[0] - s.rows[0]
+    hi = _moved((i + 1) * bq - (s.step - s.reach), off) // bk
+    lo = _moved(i * bq, off) // bk if s.band else s.cols[0] // bk
+    if s.window:    # the first row's window begins `low` before it
+        lo = _bound(max, _moved(i * bq, off + s.low), s.cols[0]) // bk
+    return lo, hi
+
+
+def _q_run(s, j, bq: int, bk: int):
+    """(lo, hi): the first and the last q block that sees a score of K
+    block j in region `s`: _k_run's twin along the other axis."""
+    # the first row of the square that sees K block j's first column
+    # (a tread later where a row's own tread is hidden from it), and
+    # in a band the last that sees its last
+    off = s.rows[0] - s.cols[0]
+    lo = _moved(j * bk, off + (s.step if s.reach < 0 else 0)) // bq
+    hi = (_moved((j + 1) * bk - 1, off) // bq if s.band
+          else s.rows[1] // bq - 1)
+    if s.window:    # the last row whose window still holds its last
+        hi = _bound(min, _moved((j + 1) * bk - 1, off - s.low),
+                    s.rows[1] - 1) // bq
+    return lo, hi
+
+
+def _spans(mask, T: int, bq: int, bk: int, nb: int = 0) -> tuple:
+    """(span_k, span_q): how many steps the walked axis of a call's grids
+    takes, the forward's and dq's K steps a q block and dkv's q steps a
+    query head and K block.  A mask of ONE region (what `causal_mask` and
+    `sliding_window_mask` make) gives every q block one run of K blocks,
+    [lo, hi] of _k_run, and every K block one run of q blocks (_q_run):
+    the axis is as long as the longest run, and step j of it is block lo +
+    j (_run_of).  Under the causal diagonal the last q block sees every K
+    block and the first K block is seen by every q block, so the longest
+    run IS the axis and the call is the one it was; a window of 512 keys
+    at blocks of 1024 walks 2 steps of 8.  No mask, a mask of several
+    regions (`block_diffusion_mask`: two runs a noisy q block) and the
+    [B, T, H * D] layout (`nb`; it takes no mask of its own) walk the
+    whole axis."""
+    nq, nk = T // bq, T // bk
+    if mask is None or len(mask) != 1 or nb:
+        return nk, nq
+    (s,) = mask
+    longest = lambda run, n: max(  # noqa: E731
+        hi - lo + 1 for lo, hi in (run(s, x, bq, bk) for x in range(n)))
+    return longest(_k_run, nq), longest(_q_run, nk)
+
+
+def _run_of(run, mask, bq: int, bk: int, span: int, whole: int):
+    """(first, block) of a walked axis of `span` steps, `whole` blocks
+    long: x -> the block of its step 0 and (x, step) -> the block that
+    step fetches; (None, None) where the walk is the whole axis (the
+    clamps of _live_k_block and _live_q_block serve it then).  Step j of a
+    run [lo, hi] (`run`: _k_run or _q_run) is block lo + j; a run shorter
+    than the span (the first q blocks and the last K blocks of a sequence)
+    has steps to spare, after its end or, where the square ends with it,
+    before its beginning (step 0 at whole - span): a body reads its place
+    as first + step, always a block of the square, and finds nothing live
+    outside the run (_run_block goes by the block's offset from the
+    staircase, which is what says so); the fetch holds at the run's
+    nearest block, which a neighbouring step fetches anyway."""
+    if span == whole:
+        return None, None
+    (s,) = mask
+    start = lambda lo: _bound(min, lo, whole - span)  # noqa: E731
+
+    def first(x):
+        return start(run(s, x, bq, bk)[0])
+
+    def block(x, step):
+        lo, hi = run(s, x, bq, bk)
+        return _bound(min, _bound(max, start(lo) + step, lo), hi)
+
+    return first, block
+
+
 def _live_k_block(mask, bq: int, bk: int, nk: int):
     """(i, j) -> the K block that q block i's step j fetches: j itself
     where the block (i, j) is live, else the nearest live block of the
@@ -668,17 +834,8 @@ def _live_k_block(mask, bq: int, bk: int, nk: int):
     nothing live in that region and fetches the block before it: one DMA
     too many, and nothing wrong, for _run_block skips by the step's own
     place.)"""
-    import jax.numpy as jnp
-
     def of_region(s, i, j):
-        # the last row of q block i sees up to column `hi` of the square,
-        # the first one, in a band, from `lo` on
-        off = s.cols[0] - s.rows[0]
-        hi = _moved((i + 1) * bq - (s.step - s.reach), off) // bk
-        lo = _moved(i * bq, off) // bk if s.band else s.cols[0] // bk
-        if s.window:    # the first row's window begins `low` before it
-            lo = jnp.maximum(_moved(i * bq, off + s.low), s.cols[0]) // bk
-        return _clamp(j, lo, hi, 0, nk - 1)
+        return _clamp(j, *_k_run(s, i, bq, bk), 0, nk - 1)
 
     def idx(i, j):
         return _pick(i, [
@@ -694,20 +851,8 @@ def _live_q_block(mask, bq: int, bk: int, nq: int):
     kernel: _live_k_block's twin along the other axis.  Under causal
     masking max(i, the first q block that attends K block j) (skip-early:
     a skipped step re-fetches a block already buffered)."""
-    import jax.numpy as jnp
-
     def of_region(s, j, i):
-        # the first row of the square that sees K block j's first column
-        # (a tread later where a row's own tread is hidden from it), and
-        # in a band the last that sees its last
-        off = s.rows[0] - s.cols[0]
-        lo = _moved(j * bk, off + (s.step if s.reach < 0 else 0)) // bq
-        hi = (_moved((j + 1) * bk - 1, off) // bq if s.band
-              else s.rows[1] // bq - 1)
-        if s.window:    # the last row whose window still holds its last
-            hi = jnp.minimum(_moved((j + 1) * bk - 1, off - s.low),
-                             s.rows[1] - 1) // bq
-        return _clamp(i, lo, hi, 0, nq - 1)
+        return _clamp(i, *_q_run(s, j, bq, bk), 0, nq - 1)
 
     def idx(j, i):
         return _pick(j, [
@@ -718,17 +863,19 @@ def _live_q_block(mask, bq: int, bk: int, nq: int):
     return idx
 
 
-def _kv_idx(bq: int, bk: int, mask, group: int, nb: int = 0, T: int = 0):
+def _kv_idx(bq: int, bk: int, mask, group: int, nb: int = 0, T: int = 0,
+            run=None):
     """K/V index map of the forward and _dq_kernel (one map, so the
     masks' arithmetic cannot drift between them): query head b reads
     its group's K/V head, and under a mask (a tuple of _Stairs; None: every
     block is live) the fetch of a dead block CLAMPS to a live one
     (_live_k_block): the DMA for a skipped block is a re-fetch of an
     already-buffered index (i.e. free), halving HBM traffic under causal
-    masking."""
+    masking.  `run`: step j is the j-th block of q block i's own run
+    instead (_run_of)."""
     head, at = _kv_head(group), _tile_at(nb)
     if mask is not None:
-        live = _live_k_block(mask, bq, bk, T // bk)
+        live = run or _live_k_block(mask, bq, bk, T // bk)
 
         def idx(b, i, j):
             return at(head(b), live(i, j))
@@ -862,6 +1009,7 @@ class _Plan(NamedTuple):
     #                  a strip beyond the staircase's reach left out
     parts: tuple = ()  # a mask of several regions or treads: one _Part a
     #                  region, and `walks` empty; (): the causal diagonal
+    live: int = 0    # blocks of the grid that compute anything
 
 
 def _mask_of(plan, T: int):
@@ -875,21 +1023,23 @@ def _schedule(T: int, bq: int, bk: int, sq: int, mask=None) -> _Plan:
     """The _Plan of a [T, T] square under blocks (bq, bk), strips of sq;
     under the causal diagonal, or under `mask`."""
     if mask is None or mask == causal_mask(T):
-        walks, full, computed = {}, False, 0
+        walks, full, computed, live = {}, False, 0, 0
         for q0 in range(0, T, bq):
             for k0 in range(0, T, bk):
                 d = q0 - k0
                 if d <= -bq:
                     continue  # a block of the future
+                live += 1
                 if d >= bk - 1:
                     full = True
                     computed += bq * bk
                     continue
                 strips = walks.setdefault(d, _row_strips(d, bq, bk, sq))
                 computed += sum(sq * width for _, width, _ in strips)
-        return _Plan(sq, tuple(sorted(walks.items())), full, computed)
+        return _Plan(sq, tuple(sorted(walks.items())), full, computed,
+                     live=live)
     _check_mask(mask, T, bq, bk)
-    parts, computed = [], 0
+    parts, computed, live = [], 0, 0
     for s in mask:
         walks, full = {}, None
         for q0 in range(s.rows[0], s.rows[1], bq):
@@ -898,24 +1048,34 @@ def _schedule(T: int, bq: int, bk: int, sq: int, mask=None) -> _Plan:
                 if s.low is None and d + s.reach >= bk - 1:
                     full = d if full is None else min(full, d)
                     computed += bq * bk
+                    live += 1
                     continue
                 strips = walks.setdefault(
                     d, _stair_strips(d, bq, bk, sq, s))
                 computed += sum(sq * width for _, _, width, _ in strips)
+                live += bool(strips)
         parts.append(_Part(s, tuple(sorted(
             (d, w) for d, w in walks.items() if w)), full))
     return _Plan(sq, (), any(p.full is not None for p in parts), computed,
-                 tuple(parts))
+                 tuple(parts), live)
 
 
 def _masked_plan(kernel: str, bh: int, T: int, bq: int, bk: int,
-                 mask=None) -> _Plan:
+                 mask=None, nb: int = 0, pack: int = 1) -> _Plan:
     """The schedule of one masked call of `kernel` (the causal diagonal,
-    or `mask`), counted (flash_score_elements_total) when the call is
-    traced."""
+    or `mask`) on `bh` query heads, `pack` of them a grid step (_pack),
+    counted (flash_score_elements_total, flash_grid_steps_total) when the
+    call is traced."""
     plan = _schedule(T, bq, bk, _strip_rows(kernel, bq, bk), mask)
     _MET_SCORES.inc(bh * T * T, kernel=kernel, part="square")
     _MET_SCORES.inc(bh * plan.computed, kernel=kernel, part="computed")
+    # dkv walks span_q steps a QUERY head and K block, the others span_k a
+    # q block: heads x the outer axis' blocks x the walked axis' steps
+    span_k, span_q = _spans(_mask_of(plan, T), T, bq, bk, nb)
+    steps = (T // bk * span_q if kernel == "flash_bwd_dkv"
+             else T // bq * span_k)
+    _MET_STEPS.inc(bh // pack * steps, kernel=kernel, part="grid")
+    _MET_STEPS.inc(bh // pack * plan.live, kernel=kernel, part="live")
     return plan
 
 
@@ -1001,6 +1161,13 @@ def _run_block(d, bq: int, bk: int, plan, strip):
             when(rel == off)(functools.partial(stair_walk, strips))
 
 
+def _step_block(first, x, step):
+    """The block a body's walked axis stands at: its step where the axis
+    is all the blocks, the step-th of row (column) x's own run where the
+    grid walks that (`first`, _run_of)."""
+    return step if first is None else first(x) + step
+
+
 _LOG2E = 1.4426950408889634  # the forward's exponent is a power of two
 
 
@@ -1065,10 +1232,13 @@ def _lse_row(m, l, *, scale: float):
 
 
 def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-              scale: float, bq: int, bk: int, plan, pack: int = 1):
+              scale: float, bq: int, bk: int, plan, pack: int = 1,
+              first=None):
     """`plan` is None for a non-causal call, else the call's _Plan; `pack`
     the heads side by side in the blocks' lanes (_pack), each a walk of
-    its own through the same K/V tile in straight-line code.
+    its own through the same K/V tile in straight-line code; `first`: the
+    K block of q block i's step 0 where the grid walks the q block's own
+    run (_run_of), None where step j is K block j.
     `scratch` is the running max and normalizer of each head and the
     block's accumulator carried across K blocks, or nothing where the K
     block holds the whole sequence: a strip of q rows then meets all its
@@ -1086,7 +1256,7 @@ def _fwd_body(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
-    q0, k0 = qi * bq, kj * bk
+    q0, k0 = qi * bq, _step_block(first, qi, kj) * bk
     c = scale * _LOG2E
     whole = not scratch
     heads = range(pack)
@@ -1153,11 +1323,13 @@ def _fwd_nolse(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
-              Dv, group=1, nb=0):
+              Dv, group=1, nb=0, whole_grid=False):
     """The forward kernel's call on q [BH, T, D], k [BH / group, T, D] and
     v [BH / group, T, Dv] operands (the output is v's width and q's
     heads), or, `nb` lane blocks across (_tile_at), on [B, T, H * D]
-    operands; for both forward entry points.
+    operands; for both forward entry points.  The K axis of its grid is as
+    long as the call's mask makes it (_spans), or, `whole_grid` (the tests'
+    control), T / bk whatever the mask.
     Memoized and jitted: every layer of a model makes the same call, and
     one callable lets jit trace the kernel body and lower it to Mosaic
     once a step program instead of once a layer."""
@@ -1168,7 +1340,10 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
 
     pack, at = _pack(nb, D), _tile_at(nb)
     W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
-    kv_idx = _kv_idx(bq, bk, _mask_of(plan, T), group, nb, T)
+    mask = _mask_of(plan, T)
+    span = T // bk if whole_grid else _spans(mask, T, bq, bk, nb)[0]
+    first, run = _run_of(_k_run, mask, bq, bk, span, T // bk)
+    kv_idx = _kv_idx(bq, bk, mask, group, nb, T, run)
     q_idx = lambda g, i, j: at(g, i)
     in_specs = [
         pl.BlockSpec((1, bq, W), q_idx),
@@ -1186,8 +1361,8 @@ def _fwd_call(BH, T, D, bq, bk, plan, with_lse, dtype, interpret, scale,
     column = pltpu.VMEM((bq, 1), jnp.float32)
     return jax.jit(pl.pallas_call(
         functools.partial(kern, scale=scale, bq=bq, bk=bk, plan=plan,
-                          pack=pack),
-        grid=(BH // pack, T // bq, T // bk),
+                          pack=pack, first=first),
+        grid=(BH // pack, T // bq, span),
         in_specs=in_specs,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
@@ -1304,7 +1479,8 @@ def _forward(q, k, v, causal, scale, block_q, block_k, interpret, with_lse,
         raise ValueError(
             f"flash attention: scale {s!r}; the forward keeps its running "
             f"max on raw scores, which takes a positive scale")
-    plan = (_masked_plan("flash_fwd", c.BH, c.T, bq, bk, mask)
+    plan = (_masked_plan("flash_fwd", c.BH, c.T, bq, bk, mask, c.nb,
+                         _pack(c.nb, c.D))
             if causal or mask else None)
     _MET_BLOCKS.inc(1, kernel="flash_fwd", block_q=str(bq), block_k=str(bk))
     return _fwd_call(c.BH, c.T, c.D, bq, bk, plan, with_lse, q.dtype,
@@ -1386,8 +1562,10 @@ def _delta_column(do, o, lo):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, *refs, scale: float, bq: int,
-               bk: int, plan, pack: int = 1, makes_delta: bool = False):
-    """dq of a q block gathered over its K steps.  `refs`: the logsumexp
+               bk: int, plan, pack: int = 1, makes_delta: bool = False,
+               first=None):
+    """dq of a q block gathered over its K steps (`first`: as the
+    forward's).  `refs`: the logsumexp
     and delta rows, dq, the accumulator; or, `makes_delta`, O, the
     logsumexp rows, dq, the delta rows as a second OUTPUT, the accumulator:
     delta = rowsum(dO * O) is then made here, at the q block's first K
@@ -1406,7 +1584,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, *refs, scale: float, bq: int,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
-    q0, k0 = qi * bq, kj * bk
+    q0, k0 = qi * bq, _step_block(first, qi, kj) * bk
     tile = _shared(_dq_tile, "scale", "ahead")
 
     @pl.when(kj == 0)
@@ -1487,10 +1665,14 @@ def _dkv_tile(k, v, q, do, lse_ref, dv_sc, delta_ref, dk_sc, lo, cols, row,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                bq: int, bk: int, plan, group: int, pack: int = 1):
+                bq: int, bk: int, plan, group: int, pack: int = 1,
+                span: int = 0, first=None):
     """The last grid axis walks the q blocks of the `group` query heads
-    that share this K/V head, a head after the other (_dkv_q_maps): dk and
-    dv add up over all of it in the scratch and are written once."""
+    that share this K/V head, a head after the other, `span` steps each
+    (_dkv_q_maps): dk and dv add up over all of it in the scratch and are
+    written once.  `first`: the q block of K block j's step 0 where a head's
+    steps are the K block's own run (_run_of), None where they are all the
+    q blocks."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -1498,8 +1680,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     step = pl.program_id(2)
     steps = pl.num_programs(2)
     tile = _shared(_dkv_tile, "scale", "ahead")
-    # lse rides whole (1, 1, T) rows: T / bq q blocks a head, static
-    qi = step if group == 1 else step % (lse_ref.shape[2] // bq)
+    qi = _step_block(first, kj, step if group == 1 else step % span)
     q0, k0 = qi * bq, kj * bk
 
     @pl.when(step == 0)
@@ -1537,25 +1718,28 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
                                                                -1)
 
 
-@functools.lru_cache(maxsize=None)
-def _dkv_q_maps(T: int, bq: int, bk: int, mask, group: int, nb: int = 0):
+def _dkv_q_maps(T: int, bq: int, bk: int, mask, group: int, nb: int = 0,
+                span: int = 0, run=None):
     """(block map of q and dO, row map of lse and delta) of the dkv
-    kernel's grid (K/V head b, K block j, step i).  One query head on a
-    K/V head: step i is q block i.  `group` of them: the steps walk head b
-    * group's q blocks, then the next head's, so head b * group + i // nq
-    and q block i % nq.  Under a mask (a tuple of _Stairs) the q block
-    clamps to a live one of K block j (_live_q_block; under causal masking
-    the first that attends it)."""
+    kernel's grid (K/V head b, K block j, step i), a head's walk `span`
+    steps long (0: all T / bq q blocks).  One query head on a K/V head:
+    step i is q block i.  `group` of them: the steps walk head b * group's
+    q blocks, then the next head's, so head b * group + i // span and q
+    block i % span.  Under a mask (a tuple of _Stairs) the q block clamps
+    to a live one of K block j (_live_q_block; under causal masking the
+    first that attends it), or, `run`, a head's step is that step of K
+    block j's own run (_run_of)."""
     nq = T // bq
+    span = span or nq
     at = _tile_at(nb)
     if group == 1:
         head = lambda b, i: b
         block = lambda i: i
     else:
-        head = lambda b, i: b * group + i // nq
-        block = lambda i: i % nq
+        head = lambda b, i: b * group + i // span
+        block = lambda i: i % span
     if mask is not None:
-        live = _live_q_block(mask, bq, bk, nq)
+        live = run or _live_q_block(mask, bq, bk, nq)
 
         def q_idx(b, j, i):
             return at(head(b, i), live(j, block(i)))
@@ -1568,8 +1752,10 @@ def _dkv_q_maps(T: int, bq: int, bk: int, mask, group: int, nb: int = 0):
 
 @functools.lru_cache(maxsize=None)
 def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
-               Dv, group=1, nb=0):
-    """(dq call, dkv call), memoized and jitted like _fwd_call; dq leaves
+               Dv, group=1, nb=0, whole_grid=False):
+    """(dq call, dkv call), memoized and jitted like _fwd_call, their
+    walked axes as long as the mask makes them like its (_spans;
+    `whole_grid`: all the blocks); dq leaves
     as q, dk as k, dv as v, and lse and delta are (BH, 1, T) float32 rows.
     On q [BH, T, D], dO [BH, T, Dv], k [BH / group, T, D], v [BH / group,
     T, Dv] operands both take (q, k, v, dO, lse, delta).  On [B, T, H * D]
@@ -1585,8 +1771,12 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
     W, Wv = (128, 128) if nb else (D, Dv)  # lanes of a block
     row_spec = pl.BlockSpec((pack, 1, T), lambda b, i, j: (b, 0, 0))
     mask = _mask_of(dq_plan, T)
-    kv_idx = _kv_idx(bq, bk, mask, group, nb, T)
-    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, mask, group, nb)
+    span_k, span_q = ((T // bk, T // bq) if whole_grid
+                      else _spans(mask, T, bq, bk, nb))
+    first_k, run_k = _run_of(_k_run, mask, bq, bk, span_k, T // bk)
+    first_q, run_q = _run_of(_q_run, mask, bq, bk, span_q, T // bq)
+    kv_idx = _kv_idx(bq, bk, mask, group, nb, T, run_k)
+    q_idx, q_row_idx = _dkv_q_maps(T, bq, bk, mask, group, nb, span_q, run_q)
     q_row_spec = pl.BlockSpec((pack, 1, T), q_row_idx)
     BHkv = BH // group
 
@@ -1599,8 +1789,9 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
     in_dq = bool(nb)  # delta made inside dq: the module docstring, PR 47
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dq_plan, pack=pack, makes_delta=in_dq),
-        grid=(BH // pack, T // bq, T // bk),
+                          plan=dq_plan, pack=pack, makes_delta=in_dq,
+                          first=first_k),
+        grid=(BH // pack, T // bq, span_k),
         in_specs=[
             pl.BlockSpec((1, bq, W), rows),
             pl.BlockSpec((1, bk, W), kv_idx),
@@ -1629,8 +1820,9 @@ def _bwd_calls(BH, T, D, bq, bk, dq_plan, dkv_plan, dtype, interpret, scale,
     cols = lambda b, j, i: at(b, j)
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          plan=dkv_plan, group=group, pack=pack),
-        grid=(BHkv // pack, T // bk, group * (T // bq)),
+                          plan=dkv_plan, group=group, pack=pack,
+                          span=span_q, first=first_q),
+        grid=(BHkv // pack, T // bk, group * span_q),
         in_specs=[
             pl.BlockSpec((1, bq, W), q_idx),
             pl.BlockSpec((1, bk, W), cols),
@@ -1672,8 +1864,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=False, scale=None,
     lse3 = lse.reshape(c.BH, 1, c.T).astype(jnp.float32)
     dq_plan = dkv_plan = None
     if causal or mask:
-        dq_plan = _masked_plan("flash_bwd_dq", c.BH, c.T, bq, bk, mask)
-        dkv_plan = _masked_plan("flash_bwd_dkv", c.BH, c.T, bq, bk, mask)
+        dq_plan, dkv_plan = (
+            _masked_plan(kernel, c.BH, c.T, bq, bk, mask, c.nb,
+                         _pack(c.nb, c.D))
+            for kernel in ("flash_bwd_dq", "flash_bwd_dkv"))
     _MET_DELTA.inc(1, where="dq" if c.nb else "xla")
     for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
         _MET_BLOCKS.inc(1, kernel=kernel, block_q=str(bq), block_k=str(bk))
