@@ -504,7 +504,8 @@ def cmd_metrics(args) -> int:
 def cmd_trace(args) -> int:
     """Run a saved model under the tracer and write the Chrome/Perfetto
     trace-event JSON (open it at https://ui.perfetto.dev): the process's
-    start-up record (category `cold`), then the ring's spans."""
+    start-up record (category `cold`), then the ring's spans; a dispatch
+    of the step record the two do not hold would follow as `steady`."""
     obs = _telemetry_run(args)
     out = args.out or (os.path.basename(os.path.normpath(args.model))
                        + ".trace.json")
@@ -513,7 +514,9 @@ def cmd_trace(args) -> int:
     problems = obs.validate_chrome_trace(exported)
     n = len(exported["traceEvents"])
     cold = len(obs.TRACER.startup_events())
-    print(f"{out}: {n} events, {cold} of the start-up record"
+    rows = len(obs.TRACER.step_rows())
+    print(f"{out}: {n} events, {cold} of the start-up record, "
+          f"{rows} dispatches in the step record"
           + (f"; SCHEMA PROBLEMS: {problems}" if problems else ""))
     return 1 if problems else 0
 

@@ -37,6 +37,10 @@ from .scope import Scope, global_scope
 
 logger = logging.getLogger("paddle_tpu")
 
+# the last two fields of a dispatch's row of the step record where nothing
+# ran before its root (tracing.STEP_FIELDS: `t_distribute0`, `t_distribute1`)
+_NOT_DISTRIBUTED = (None, None)
+
 # counter handles resolved once (families survive REGISTRY.reset()):
 # these sit on the per-run hot path, where a per-step family lookup
 # (name regex + registry lock) would be pure overhead
@@ -290,6 +294,9 @@ class Executor:
         self._load_paths: Dict[tuple, tuple] = {}
         self._rng_keys: Dict[int, object] = {}  # seed -> base key (_rng_key)
         self._step = 0
+        # the stamps around what a subclass's run() did before the root of
+        # the dispatch it is about to make, for that dispatch's row
+        self._before_root = _NOT_DISTRIBUTED
         # subclasses running sharded over a mesh bypass single-device pinning
         self._pin_device = True
         # sharded subclasses need the step output pytree to match their
@@ -495,15 +502,29 @@ class Executor:
         `executor.fetch`.  All carry `step`, the executor's counter at
         the dispatch's first step.
 
-        A dispatch that finds no executable (`cold`) is the one a process
-        spends its start-up in: from that moment on its spans are cold
-        (observability/tracing.py: kept in the start-up record whatever
-        is switched on), the root with the program's `role`.  A steady
-        dispatch pays a branch on `cold` for it."""
+        What is recorded when (observability/tracing.py): the spans
+        inside a profiler session or with the ring on, and otherwise none
+        is built.  A dispatch that finds no executable (`cold`) is the one
+        a process spends its start-up in: from that moment on its spans
+        are cold, kept in the start-up record whatever is switched on,
+        the root with the program's `role`; a steady dispatch pays a
+        branch on `cold` for it.  And ALWAYS one row of the step record,
+        written as the root closes: `step`, `k`, the program's token,
+        `cold`, and four stamps of the tracer's clock, where the root
+        opens and closes and around the jitted call where
+        `executor.execute` stands (None where a raising dispatch never
+        came), then the two stamps `_before_root` holds (around
+        ParallelExecutor's `executor.distribute`; None elsewhere).  Four
+        clock reads, a tuple and an append are all a steady dispatch pays
+        for it."""
         import jax
 
         step = self._step
         block = program.blocks[block_id]
+        before, self._before_root = self._before_root, _NOT_DISTRIBUTED
+        cold = False
+        t_execute0 = t_execute1 = None
+        t_enter = _trace_now()
         with _TRC.span("executor.run", step=step, k=k,
                        program=program._cache_token) as sp_run:
             late_root = None  # the cold root, where sp_run is the no-op
@@ -568,11 +589,13 @@ class Executor:
                         rng = (key0, np.int32(first))
                 self._step += k
 
+                t_execute0 = _trace_now()
                 with _TRC.span("executor.execute", cold=cold, step=step,
                                cache_hit=not cold), \
                         self._device_scope():
                     fetches, new_state = compiled.fn(state_w, state_r,
                                                      feed_vals, *rng)
+                t_execute1 = _trace_now()
                 with _TRC.span("executor.writeback", cold=cold, step=step,
                                written=len(new_state)):
                     for n, v in new_state.items():
@@ -595,6 +618,9 @@ class Executor:
             finally:
                 if late_root is not None:
                     late_root.__exit__(*sys.exc_info())
+                _TRC.keep_step((step, k, program._cache_token, cold,
+                                t_enter, t_execute0, t_execute1,
+                                _trace_now()) + before)
 
     # ------------------------------------------------------------------
     def _device_scope(self):

@@ -1,5 +1,5 @@
-"""Structured step tracing: one span, three sinks, one clock (ISSUE 13,
-ISSUE 24, ISSUE 50).
+"""Structured step tracing: one span, three sinks, and beside them the
+step record; one clock (ISSUE 13, ISSUE 24, ISSUE 50, ISSUE 65).
 
 The executor, serving engine, and training service open spans around
 their phases (prepare vs execute vs donation, admission vs prefill-chunk
@@ -36,6 +36,21 @@ of the instrumentation that stays in the hot serving/executor paths at
 all times is that of asking ``TraceAnnotation.is_enabled()``, the flag a
 ``TraceMe`` itself checks (PERF.md, PR 24 and PR 50, have the numbers).
 
+The fourth thing the tracer keeps is no span: **the step record**, one
+ROW a dispatch of an executor, written whatever is switched on
+(``keep_step``, ``step_rows()``).  A row is a plain tuple of ``STEP_FIELDS``
+(the executor's ``step``, ``k``, the program's token, ``cold``, and four
+stamps of the clock: the root's two ends and the jitted call's two ends;
+under ``ParallelExecutor`` two more around what it does before the root),
+appended to a ``deque`` of ``STEP_CAPACITY`` rows without the lock, an id
+or a dict: what a steady dispatch pays for it is the clock reads, the
+tuple and the append (PERF.md, PR 65).  It is rows and not spans so that a
+steady dispatch with nothing switched on still builds no span, and it has
+no switch so that a process nobody prepared shows its last 4096
+dispatches after the fact: ``to_chrome()`` lays them (category ``steady``)
+behind the start-up record, a dispatch the ring or the start-up record
+holds too exported once.
+
 Every stamp (the ring's, the record's, the metrics registry's
 ``monotime``) is ``time.monotonic``, the clock the benchmark's harness
 reads too: a reader lays the record beside its own stamps by
@@ -65,6 +80,18 @@ now = _clock  # for a call site that stamps a `cold_event` itself
 # the start-up record's bound: a cold dispatch writes ~10 spans and an
 # event for every function JAX traces, lowers or compiles inside it
 COLD_CAPACITY = 4096
+# the step record's bound, in dispatches: at 100 ms a step the last seven
+# minutes, and half a megabyte of tuples
+STEP_CAPACITY = 4096
+# what a row of the step record holds, in its order: the executor's step
+# counter at the dispatch's first step, the steps it fused, the program's
+# cache token, whether it found no executable; absolute stamps of the
+# clock where the root `executor.run` opens, around the jitted call where
+# `executor.execute` stands, where the root closes (None where a raising
+# dispatch never came); under ParallelExecutor the two ends of
+# `executor.distribute` before the root (None elsewhere)
+STEP_FIELDS = ("step", "k", "program", "cold", "t_enter", "t_execute0",
+               "t_execute1", "t_exit", "t_distribute0", "t_distribute1")
 _ids = itertools.count(1)  # next() on a count is atomic under the GIL
 # whether a profiler session is recording: what a TraceMe asks itself
 # before it records, asked here before one is built
@@ -158,8 +185,9 @@ class _Span:
 
 
 class Tracer:
-    """Bounded-ring span recorder with a start-up record beside it and
-    Chrome trace-event export of both."""
+    """Bounded-ring span recorder with a start-up record and a step
+    record beside it (each a bounded deque of its own, so none rotates
+    another out) and Chrome trace-event export of the three."""
 
     def __init__(self, enabled: Optional[bool] = None,
                  capacity: int = 65536):
@@ -172,6 +200,8 @@ class Tracer:
         # of the process, which reset() keeps
         self._cold = collections.deque(maxlen=COLD_CAPACITY)
         self._process: List[dict] = []
+        # the step record: a tuple of STEP_FIELDS a dispatch
+        self._steps = collections.deque(maxlen=STEP_CAPACITY)
         self._lock = threading.Lock()
         self._local = threading.local()
         self._epoch = _clock()
@@ -224,6 +254,12 @@ class Tracer:
                 pass
         self._keep(name, t0, t1, args, process)
 
+    def keep_step(self, row: tuple):
+        """One dispatch's row of the step record (a tuple of STEP_FIELDS),
+        from the executor, whatever is switched on.  No lock: a deque's
+        append is atomic under the GIL."""
+        self._steps.append(row)
+
     def current(self) -> Optional[_Span]:
         """The innermost recorded span open on the calling thread, or
         None."""
@@ -265,28 +301,59 @@ class Tracer:
             return [dict(e) for e in self._process] + \
                 [dict(e) for e in self._cold]
 
+    def step_rows(self) -> List[dict]:
+        """The step record: a dict of STEP_FIELDS a dispatch, oldest
+        first, the stamps absolute seconds of `time.monotonic`."""
+        with self._lock:
+            rows = list(self._steps)
+        return [dict(zip(STEP_FIELDS, r)) for r in rows]
+
     def to_chrome(self) -> dict:
         """Chrome trace-event JSON object format — loadable by Perfetto
         (ui.perfetto.dev) and chrome://tracing: the start-up record's
-        events (category `cold`) ahead of the ring's, on one time axis
-        that starts at the earlier of the ring's epoch and the record's
-        first stamp; a cold span the ring holds too appears once."""
+        events (category `cold`), then the step record's dispatches
+        (category `steady`: `executor.run` over `executor.execute`, an
+        `executor.distribute` before it under ParallelExecutor; a row
+        keeps no thread, so they lie on a track of their own, tid 0),
+        then the ring's, on one time axis that starts at the earliest
+        stamp of the three; a cold span the ring holds too appears once,
+        and so does a dispatch whose root the ring or the start-up
+        record holds."""
         cold, ring = self.startup_events(), self.events()
-        base = min([self._epoch] + [e["t0"] for e in cold])
+        rows = self.step_rows()
+        base = min([self._epoch] + [e["t0"] for e in cold]
+                   + [r["t_distribute0"] or r["t_enter"] for r in rows])
         shift = (self._epoch - base) * 1e6
         out, ids = [], set()
+        roots: dict = {}  # step -> where a held root `executor.run` began
         for e in cold:
             t0, t1 = e.pop("t0"), e.pop("t1")
             e["ts"] = round((t0 - base) * 1e6, 3)
             e["dur"] = round((t1 - t0) * 1e6, 3)
             ids.add(e["args"]["id"])
             out.append(e)
-        for e in ring:
-            if e.get("args", {}).get("id") in ids:
-                continue
-            out.append(dict(e, ts=round(e["ts"] + shift, 3)) if shift
-                       else e)
-        return chrome_envelope(out)
+        ring = [dict(e, ts=round(e["ts"] + shift, 3)) if shift else e
+                for e in ring if e.get("args", {}).get("id") not in ids]
+        for e in out + ring:
+            if e["name"] == "executor.run":
+                roots.setdefault(e["args"].get("step"), []).append(e["ts"])
+        for r in rows:
+            lo, hi = ((r[k] - base) * 1e6 for k in ("t_enter", "t_exit"))
+            if any(lo - 1 <= ts <= hi for ts in roots.get(r["step"], ())):
+                continue  # its root is there already, with its children
+            args = {k: r[k] for k in STEP_FIELDS[:4]}
+            for name, a, b in (
+                    ("executor.distribute", "t_distribute0",
+                     "t_distribute1"),
+                    ("executor.run", "t_enter", "t_exit"),
+                    ("executor.execute", "t_execute0", "t_execute1")):
+                if r[a] is not None and r[b] is not None:
+                    out.append({
+                        "name": name, "cat": "steady", "ph": "X",
+                        "ts": round((r[a] - base) * 1e6, 3),
+                        "dur": round((r[b] - r[a]) * 1e6, 3),
+                        "pid": self._pid, "tid": 0, "args": args})
+        return chrome_envelope(out + ring)
 
     def export(self, path: str) -> str:
         obj = self.to_chrome()
@@ -310,6 +377,7 @@ class Tracer:
         with self._lock:
             self._ring.clear()
             self._cold.clear()
+            self._steps.clear()
         self._epoch = _clock()
 
 

@@ -31,7 +31,7 @@ import numpy as np
 from ..framework.core import np_dtype
 from ..framework.executor import Executor
 from ..framework.scope import global_scope
-from ..observability.tracing import TRACER as _TRC
+from ..observability.tracing import TRACER as _TRC, now as _trace_now
 from ..ops.registry import EmitContext
 from . import mesh as mesh_lib
 from .mesh import make_mesh
@@ -190,13 +190,15 @@ class ParallelExecutor(Executor):
         scope = scope if scope is not None else global_scope()
         block = program.blocks[block_id]
         # pre-shard all scope state the block touches: every call walks
-        # the block's ops, before (and so outside) Executor.run's spans.
+        # the block's ops, before (and so outside) Executor.run's spans
+        # (and inside the two stamps its row of the step record keeps).
         # A program version's first pass moves the state to its planned
         # shardings and is the one that compiles: a cold span
         cold = self._distributed.get(program._cache_token) != \
             program._version
         if cold:
             self._distributed[program._cache_token] = program._version
+        t_distribute0 = _trace_now()
         with _TRC.span("executor.distribute", cold=cold, step=self._step,
                        ops=len(block.ops)):
             names = set()
@@ -205,6 +207,8 @@ class ParallelExecutor(Executor):
                 names.update(op.output_names())
             self._distribute_state(
                 program, scope, [n for n in names if scope.has(n)])
+        # the step record's row of the dispatch that follows carries them
+        self._before_root = (t_distribute0, _trace_now())
         return super().run(program, feed, fetch_list, scope, return_numpy,
                            block_id, verify=verify, rng_step=rng_step,
                            steps_per_dispatch=steps_per_dispatch,
