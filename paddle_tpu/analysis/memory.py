@@ -298,7 +298,7 @@ def abstract_sizes(program, block_id: int, batch_size: int
     fall back to declared shapes); no device code runs."""
     import jax
 
-    from ..framework.executor import _lower_ops
+    from ..framework.executor import bind_lower_block
     from ..ops.registry import EmitContext, get_op_info, has_op
 
     from .verifier import _DESC_ONLY_TYPES, _abstract_seed, _UNKNOWN
@@ -341,8 +341,7 @@ def abstract_sizes(program, block_id: int, batch_size: int
                 info = get_op_info(op.type)
                 ctx = EmitContext(jax.random.PRNGKey(0), is_test=is_test,
                                   program=program)
-                ctx.lower_block = lambda idx, sub_env: _lower_ops(
-                    program.blocks[idx].ops, sub_env, ctx)
+                bind_lower_block(ctx, program)
                 outs_abs = jax.eval_shape(
                     lambda a: info.emit(ctx, a, attrs), ins)
             except Exception:

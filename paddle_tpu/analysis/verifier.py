@@ -581,7 +581,7 @@ def abstract_walk(program, block_id=0, batch_size=2):
     import jax
 
     from ..framework.core import canonical_dtype, np_dtype
-    from ..framework.executor import _lower_ops
+    from ..framework.executor import bind_lower_block
     from ..ops.registry import EmitContext, get_op_info
 
     block = program.blocks[block_id]
@@ -623,8 +623,7 @@ def abstract_walk(program, block_id=0, batch_size=2):
                 info = get_op_info(op.type)
                 ctx = EmitContext(jax.random.PRNGKey(0), is_test=is_test,
                                   program=program)
-                ctx.lower_block = lambda idx, sub_env: _lower_ops(
-                    program.blocks[idx].ops, sub_env, ctx)
+                bind_lower_block(ctx, program)
                 outs_abs = jax.eval_shape(
                     lambda a: info.emit(ctx, a, attrs), ins)
             except Exception:

@@ -8,7 +8,7 @@ jits internally.
 
 from __future__ import annotations
 
-from .framework.executor import Executor, _lower_ops
+from .framework.executor import Executor, _lower_ops, bind_lower_block
 from .framework.scope import global_scope
 from .ops.registry import EmitContext
 
@@ -41,8 +41,7 @@ def build_callable(program, fetch_list, scope=None, feed_names=None,
         env.update(feeds)
         ctx = EmitContext(jax.random.PRNGKey(rng_seed), is_test=is_test,
                           program=program)
-        ctx.lower_block = lambda idx, sub_env: _lower_ops(
-            program.blocks[idx].ops, sub_env, ctx)
+        bind_lower_block(ctx, program)
         _lower_ops(block.ops, env, ctx)
         if ctx.host_saves:
             raise NotImplementedError(

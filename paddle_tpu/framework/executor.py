@@ -878,15 +878,7 @@ class Executor:
             env.update(state_w)
             env.update({n: jax.numpy.asarray(v) for n, v in feeds.items()})
             ctx = self._emit_ctx(rng_key, is_test, program)
-
-            def lower_sub(idx, sub_env):
-                ctx.sub_depth += 1
-                try:
-                    return _lower_ops(program.blocks[idx].ops, sub_env, ctx)
-                finally:
-                    ctx.sub_depth -= 1
-
-            ctx.lower_block = lower_sub
+            bind_lower_block(ctx, program)
             _lower_ops(block.ops, env, ctx)
             fetches = {n: env[n] for n in fetch_names}
             # `save` ops: their traced values leave the program as reserved
@@ -1022,7 +1014,25 @@ def _lower_op(op, env, ctx):
     return outs
 
 
-def _lower_ops(ops, env, ctx):
+def bind_lower_block(ctx, program):
+    """Give `ctx` its `lower_block(idx, env, after_op=None) -> env`: what a
+    control-flow emitter lowers a sub-block of `program` with, for every
+    trace that lowers descs (the executors', `compiler.build_callable`'s, a
+    pipeline stage's, the analyses').  `after_op(op, env)` runs after each
+    op of that block has written its outputs, inside the op's own scope: a
+    `recompute` segment's replay puts the values it was handed in place of
+    the ones just made (ops/control_flow_ops.py)."""
+    def lower_sub(idx, sub_env, after_op=None):
+        ctx.sub_depth += 1
+        try:
+            return _lower_ops(program.blocks[idx].ops, sub_env, ctx, after_op)
+        finally:
+            ctx.sub_depth -= 1
+
+    ctx.lower_block = lower_sub
+
+
+def _lower_ops(ops, env, ctx, after_op=None):
     """Trace every op's emitter into the surrounding JAX trace, threading the
     SSA environment (name → traced array).  Each op is lowered inside its
     identity scope (`pdop__<type>__u<uid>`) and, where its desc names one,
@@ -1037,6 +1047,8 @@ def _lower_ops(ops, env, ctx):
         t0 = _monotime()
         with _attr.op_scope(op):
             _lower_op(op, env, ctx)
+            if after_op is not None:
+                after_op(op, env)
         spent = _monotime() - t0
         _MET_OP_EMIT_S.inc(max(spent - ctx.emit_nested_s, 0.0),
                            op=_attr.op_type(op))

@@ -310,18 +310,39 @@ def ifelse(cond_scalar: Variable, true_fn_block, false_fn_block,
 
 
 @contextlib.contextmanager
-def recompute():
-    """Rematerialization scope (TPU-first memory lever — jax.checkpoint):
-    ops built inside run normally forward, but their activations are NOT
-    kept for backward; the backward pass recomputes the segment from its
-    inputs.  Trades FLOPs for HBM exactly like `jax.checkpoint` because the
-    segment lowers as one checkpointed function (the generic vjp grad then
-    differentiates through it).
+def recompute(keep=()):
+    """Rematerialization scope (TPU-first memory lever): ops built inside
+    run normally forward, but their activations are NOT kept for backward;
+    the backward pass recomputes the segment from its inputs.  The segment
+    lowers as one `jax.checkpoint`ed function (the generic vjp grad then
+    differentiates through it), so it trades FLOPs for HBM like
+    `jax.checkpoint` does: every product of the segment runs twice a step.
 
         with fluid.layers.recompute():
             h = fluid.layers.fc(h, 1024, act="relu")
             h = fluid.layers.fc(h, 1024, act="relu")
-    """
+
+    All but what `keep` names: Variables (or names) of values made inside
+    the scope, which the step HOLDS from the forward to the backward, where
+    the replay uses each in place of making it.  The op that makes a kept
+    value (a wide product) then runs once a step and its bytes stay
+    resident: the trade taken back value by value, for a step that has
+    memory left.  `keep` is read when the scope closes, so a list may be
+    filled inside it:
+
+        kept = []
+        with fluid.layers.recompute(keep=kept):
+            up = fluid.layers.fc(h, 4096, bias_attr=False)
+            kept.append(up)
+            h = fluid.layers.fc(fluid.layers.relu(up), 1024)
+
+    A name that no op of the segment makes, or that several make, is an
+    error here.  This is the program's own protocol (ops/registry.py
+    `keep_for_grad`), not a `jax.checkpoint` policy: a policy saves from a
+    second primal pass, which behind a Pallas kernel XLA cannot merge with
+    the forward's (it then launches every kernel of the segment a third
+    time and makes the named values again).  Keeping a kernel's own output
+    saves nothing: the replay's kernel must run for its residuals."""
     program = default_main_program()
     sub = program.create_block()
     try:
@@ -349,10 +370,17 @@ def recompute():
             del sub.vars[n]
         # name collision with an outer var: keep the shadowing sub var in
         # place so sub-op metadata lookups still resolve to it
-    parent.append_op(
-        "recompute",
-        inputs={"X": list(ext)},
-        outputs={"Out": list(produced)},
-        attrs={"sub_block": sub.idx, "x_names": list(ext),
-               "out_names": list(produced)},
-    )
+    keep_names = [k.name if isinstance(k, Variable) else str(k)
+                  for k in keep]
+    made = [n for op in sub.ops for n in op.output_names()]
+    for n in keep_names:
+        if made.count(n) != 1 or keep_names.count(n) != 1:
+            raise ValueError(
+                f"recompute: keep names {n!r}, which {made.count(n)} ops "
+                f"of the segment make (one must, and be named once)")
+    attrs = {"sub_block": sub.idx, "x_names": list(ext),
+             "out_names": list(produced)}
+    if keep_names:
+        attrs["keep_names"] = keep_names
+    parent.append_op("recompute", inputs={"X": list(ext)},
+                     outputs={"Out": list(produced)}, attrs=attrs)

@@ -59,7 +59,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                logit_scale=None, delta=None, attention_gate=False,
                rotary_dim=None, ssm=None, differential=None, window=None,
                attention_bias=False, tie_embeddings=False, norm_attr=None,
-               kda=None, yarn=None):
+               kda=None, yarn=None, remat_keep=()):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -144,6 +144,11 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     block made: under `remat` it leaves that block's recompute segment as
     one of its outputs and enters the later one as an external, and its
     gradient is the sum over the layers that read it.
+    `remat_keep` names the products a block's segment HOLDS across to its
+    backward and so makes once a step (`layers.recompute(keep=)`): any of
+    'mlp.up', a gated MLP's two up-projections before the activation, and
+    'ssm.in_proj', a 'mamba' layer's [u' | z]; bytes a step with memory
+    left spends (2 x `dense_dim` and 2 x d_inner wide a token and block).
     `differential` = {} or any of "layer_indices" (each layer's index in the
     whole model, which lambda_init is made from: a tower that is a cut of a
     deeper one names the published indices), "epsilon", "lambda_attr",
@@ -351,13 +356,30 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 shared["kv"] = diff["made"]
         return out
 
+    if set(remat_keep) - {"mlp.up", "ssm.in_proj"}:
+        raise ValueError(f"decoder_lm: remat_keep {remat_keep!r}: 'mlp.up', "
+                         f"'ssm.in_proj'")
+    kept = []   # of the block being built: what its segment holds
+
+    def keep_products(kind, since, part=""):
+        """Under `remat_keep`'s `kind`: the results of the `mul` ops (of the
+        model part `part`) the block gained after its first `since` ops."""
+        if kind in remat_keep:
+            ops = default_main_program().current_block().ops[since:]
+            kept.extend(op.outputs["Out"][0] for op in ops
+                        if op.type == "mul"
+                        and op.attrs.get("part", "").endswith(part))
+
     def mix(h, layer):
         prog = default_main_program()
         if layer_types[layer] == "mamba":
+            since = len(prog.current_block().ops)
             with prog.part_guard("mixer.mamba"):
-                return layers.mamba(
+                out = layers.mamba(
                     h, param_attr=attr, memory=shared["memory"],
                     **{k: v for k, v in ssm.items() if k != "memory"})
+            keep_products("ssm.in_proj", since, part="ssm.in_proj")
+            return out
         if layer_types[layer] == "gmu":
             if not shared["memory"]:
                 raise ValueError(f"decoder_lm: layer {layer} is a 'gmu' and "
@@ -418,9 +440,11 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                           param_attr=attr, act="gelu")
             return layers.fc(m, dim, num_flatten_dims=2, param_attr=attr)
         if ffn == "gated_mlp" or layer < dense_layers:
+            since = len(default_main_program().current_block().ops)
             gate, up = (layers.fc(h, dense_dim, num_flatten_dims=2,
                                   param_attr=attr, bias_attr=False, act=a)
                         for a in ("silu", None))
+            keep_products("mlp.up", since)
             return layers.fc(layers.elementwise_mul(gate, up), dim,
                              num_flatten_dims=2, param_attr=attr,
                              bias_attr=False)
@@ -492,7 +516,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         x = sublayer(x, mixer)
         return sublayer(x, lambda h: feed_forward(h, layer, seen[0]))
 
-    blk = (layers.recompute if remat else contextlib.nullcontext)
+    def blk():
+        """The scope a block is built in: under `remat` a recompute segment
+        that holds what the block, once built, has put in `kept`."""
+        del kept[:]
+        return (layers.recompute(keep=kept) if remat
+                else contextlib.nullcontext())
+
     if streams:
         x = layers.hyper_connection_streams(x, streams)
     for layer in range(n_layers):
@@ -1705,7 +1735,8 @@ def build_phi4flash_lm_train_program(
         n_kv_heads, dense_dim, sliding_window, d_state=16, d_conv=4, expand=2,
         dt_rank=None, mb_per_layer=2, norm_epsilon=1e-5,
         gain_range=None, bias_range=None, remat=True, dtype="bfloat16",
-        learning_rate=3e-5, init_scale=0.02):
+        learning_rate=3e-5, init_scale=0.02,
+        remat_keep=("mlp.up", "ssm.in_proj")):
     """Phi-4-mini-flash-shaped decoder (`model_type` phi4flash: the SambaY
     decoder-hybrid-decoder of arXiv:2507.06607) over the layers
     `layer_indices` of its `total_layers`, each of the kind the published
@@ -1725,7 +1756,12 @@ def build_phi4flash_lm_train_program(
     `bias_range` every bias (the norms', the attention projections', the
     convolution's; dt's has its own draw), instead of their defaults one
     and zero: a checked program must not pass without them.  `remat` wraps
-    each block in `layers.recompute`.  Loss: next-token cross entropy;
+    each block in `layers.recompute`, and a segment HOLDS its `remat_keep`
+    (`decoder_lm`'s): the MLP's two up-projections in every block and a
+    Mamba layer's input projection, the widest products of the step, which
+    its backward would otherwise make again (at the published widths 168 MB
+    each in bf16 at 8192 tokens, 19 of them in layers 12-19: 3.2 GB of the
+    4.6 the step left free; PERF.md, PR 66).  Loss: next-token cross entropy;
     Adam.  Returns the loss.  Feeds as `build_lm_train_program`.  The
     program's last `assign` is the last windowed layer's attention result
     [B, T, dim] (the combined heads before the output projection) and its
@@ -1757,7 +1793,8 @@ def build_phi4flash_lm_train_program(
         window=[windows[i] for i in held], differential=diff,
         attention_bias=biases or True, ssm=ssm,
         ffn="gated_mlp", dense_dim=dense_dim, tie_embeddings=True,
-        norm_attr={"gain": gains, "bias": biases}, init_scale=init_scale)
+        norm_attr={"gain": gains, "bias": biases}, init_scale=init_scale,
+        remat_keep=remat_keep)
     loss = lm_loss(logits, targets, dtype=dtype)
     opt.Adam(learning_rate=learning_rate).minimize(loss)
     windowed = [n for n, i in enumerate(held) if windows[i]]

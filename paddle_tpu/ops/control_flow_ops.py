@@ -20,6 +20,9 @@ than WhileOp in practice."""
 
 from __future__ import annotations
 
+import functools
+
+from ..observability.metrics import REGISTRY as _MET
 from .registry import register_op
 
 
@@ -245,23 +248,82 @@ def create_array(ctx, ins, attrs):
         attrs.get("dtype", "float32")))]}
 
 
+_MET_KEPT = _MET.counter(
+    "recompute_kept_traced_total",
+    "what `layers.recompute(keep=...)` segments hold across to their "
+    "backward (once a compile, not once a step), by pass (forward: the "
+    "forward emission kept them beside its outputs; replay: the grad op's "
+    "re-emission was handed them and put each in place of the one it makes) "
+    "and unit (values, bytes); no series where no segment names anything")
+
+
+@functools.lru_cache(maxsize=None)
+def _in_place_of():
+    """in_place_of(made, held) -> held, its cotangent handed to `made`: the
+    op that made `made` is dead downstream (XLA removes it) while its
+    backward, which reads its operands and not its result, stands."""
+    import jax
+
+    @jax.custom_vjp
+    def in_place_of(made, held):
+        return held
+
+    in_place_of.defvjp(lambda made, held: (held, None),
+                       lambda _, g: (g, _jnp().zeros_like(g)))
+    return in_place_of
+
+
 @register_op("recompute")
 def recompute_op(ctx, ins, attrs):
     """Rematerialization segment (layers.recompute): the sub-block lowers
     as ONE `jax.checkpoint`-wrapped pure function of its externals, so the
     backward pass (generic vjp through this op) recomputes the segment's
-    activations instead of keeping them resident in HBM."""
+    activations instead of keeping them resident in HBM.
+
+    All but `keep_names`, the values its builder named: the forward emission
+    keeps them beside its outputs (`ctx.keep_for_grad`: they are among the
+    outputs, so this pins them and costs no copy), and a re-emission that
+    is handed them back passes them INTO the checkpointed function (its
+    arguments are what a checkpoint saves) and, after the op that makes
+    each name, puts the held value in its place.  A re-emission handed
+    nothing (another trace, a `__remat__` grad op) and a segment that names
+    nothing trace what they always did."""
     import jax
 
     sub_block = int(attrs["sub_block"])
     x_names = list(attrs["x_names"])
     out_names = list(attrs["out_names"])
+    keep_names = list(attrs.get("keep_names", ()))
+    held = ctx.take_kept_for_grad() if keep_names else None
 
     @jax.checkpoint
     def segment(*vals):
         env = dict(zip(x_names, vals))
-        ctx.lower_block(sub_block, env)
+        put = dict(zip(keep_names, vals[len(x_names):]))
+
+        def use_held(op, env):
+            for n in op.output_names():
+                if n in put:
+                    env[n] = _in_place_of()(env[n], put[n])
+
+        ctx.lower_block(sub_block, env, use_held if put else None)
         return tuple(env[n] for n in out_names)
 
-    outs = segment(*ins["X"])
+    if held:
+        outs = segment(*ins["X"], *(held[n] for n in keep_names))
+        _count_kept("replay", held.values())
+    else:
+        outs = segment(*ins["X"])
+        if keep_names and not (ctx.is_test or ctx.in_grad_replay()):
+            kept = {n: outs[out_names.index(n)] for n in keep_names}
+            ctx.keep_for_grad(attrs, outs, kept)
+            _count_kept("forward", kept.values())
     return {"Out": list(outs)}
+
+
+def _count_kept(which, values):
+    values = list(values)
+    for unit, n in (("values", len(values)),
+                    ("bytes", sum(v.size * v.dtype.itemsize
+                                  for v in values))):
+        _MET_KEPT.inc(n, unit=unit, **{"pass": which})

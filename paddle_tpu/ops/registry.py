@@ -40,6 +40,22 @@ kernel-pair protocol, in three places and no others:
   received that very output, and counts what ``run_pair`` reported in
   ``executor_grad_kernel_forward_total{op, reused}``: ``reused="1"`` no forward
   launched again, ``"0"`` at least one (docs/observability.md).
+
+``keep_for_grad`` has four callers: the three kinds of kernel emitter above
+(one pair, several under a dict, a pair whose output is not the op's) and the
+``recompute`` op (ops/control_flow_ops.py), which keeps no kernel's residuals
+but the VALUES its builder named (``layers.recompute(keep=...)``), as ``{name:
+value}`` beside all its outputs.  Its re-emission takes them with
+``take_kept_for_grad`` and lowers the segment's ops itself, so an emitter
+inside a replay is handed NOTHING (``kept_for_grad()`` is None there: what was
+kept is the segment's, not the inner op's, and the inner op's own forward
+emission kept under a uid no grad op asks for), runs its plain pair, and its
+``kernel_forward`` report still lands on the recompute grad op's count
+(``{op="recompute", reused="0"}``).  A ``jax.checkpoint`` policy
+(``save_only_these_names``) is NOT a substitute: it saves from the primal pass
+of the grad op's ``jax.vjp``, which XLA merges with the forward emission only
+where both are plain HLO, and behind a Mosaic call nothing is (PERF.md, PR 66:
+7 kernel launches and 8 products more, not 19 fewer).
 """
 
 from __future__ import annotations
@@ -244,6 +260,16 @@ class EmitContext:
         emission kept in this trace, else None (no grad op around the
         call, another trace, a `__remat__` grad op, or nothing kept)."""
         return self._replay.saved if self._replay is not None else None
+
+    def take_kept_for_grad(self):
+        """`kept_for_grad()` for an op whose re-emission lowers other ops
+        (the `recompute` op): what it kept is its alone, so the emitters it
+        lowers from here on are handed nothing, while their `kernel_forward`
+        reports still reach this grad op's count."""
+        held = self.kept_for_grad()
+        if held is not None:
+            self._replay.saved = None
+        return held
 
     def kernel_forward(self, reused: bool):
         """An emitter that took a Pallas custom_vjp path says whether its

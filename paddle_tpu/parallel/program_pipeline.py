@@ -35,7 +35,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from ..framework.executor import _lower_ops
+from ..framework.executor import _lower_ops, bind_lower_block
 from ..framework.scope import global_scope
 from ..ops.registry import EmitContext
 
@@ -257,8 +257,7 @@ class ProgramPipeline:
                 off += size
             ctx = EmitContext(key, is_test=False, program=self.program)
             ctx.mesh = self.mesh
-            ctx.lower_block = lambda idx, sub_env: _lower_ops(
-                self.program.blocks[idx].ops, sub_env, ctx)
+            bind_lower_block(ctx, self.program)
             _lower_ops(info.ops, env, ctx)
             if out_specs is None:
                 out = jnp.zeros((act_len,), jnp.float32)
