@@ -326,7 +326,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
                          head_dim=None, block_diffusion=None,
                          output_gate=False, rotary_dim=None, window=None,
                          bias=False, differential=None, kv=None,
-                         positions=None, yarn=None, qk_norm_attr=None):
+                         positions=None, yarn=None, qk_norm_attr=None,
+                         scale=None):
     """Transformer multi-head attention over [B, T, D] (beyond-reference:
     the 2018 reference's closest construct is v1 simple_attention).  QKV and
     output projections are fc ops (MXU GEMMs); the core runs
@@ -385,6 +386,10 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
     without; it computes nothing and is written into the attention op's
     desc, which counts the layer by (window, positions)
     (`attention_layer_kinds_traced_total`).
+    `scale`: the softmax scale where it is not head_dim^-1/2 (Granite's
+    `attention_multiplier`, 1 / 64 on heads of 64): the attention op's attr
+    `scale`, which every path of it takes; absent, the op's desc and trace
+    are what they were.
     `param_attr` is the Q, K and V projections', `out_param_attr` the
     output projection's.
 
@@ -444,6 +449,8 @@ def multi_head_attention(queries, keys, values, num_heads, causal=False,
         masked["positions"] = positions
     if window is not None:
         masked.update(mask="window", window=int(window))
+    if scale is not None:
+        masked["scale"] = float(scale)
     if differential is not None:
         if (rope_theta is not None or qk_norm_epsilon is not None
                 or output_gate or head_gate or block_diffusion):
@@ -747,6 +754,106 @@ def mamba(input, d_state=16, d_conv=4, expand=2, dt_rank=None,
                          inputs={"X": [y.name], "Gate": [uz.name]},
                          outputs={"Out": [gated.name]}, attrs={})
         out = project(gated, dim)
+    from .sequence import propagate_length
+
+    return propagate_length(input, out)
+
+
+def mamba2(input, n_heads, head_dim, d_state, n_groups=1, d_conv=4,
+           chunk=256, epsilon=1e-5, param_attr=None, bias_attr=None,
+           skip_attr=None, gain_attr=None, memory=None, name=None):
+    """A Mamba-2 mixer over [B, T, D] (state-space duality, arXiv:2405.21060,
+    as the published `GraniteMoeHybridMambaLayer` computes it; ops/ssm_ops.py
+    `ssd_scan` has the equations): ONE input projection to [z | xBC | dt] in
+    that order (d_inner = `n_heads` x `head_dim`; xBC = d_inner + 2
+    `n_groups` `d_state` columns; dt one a head), a causal depthwise
+    convolution of `d_conv` taps with bias + SiLU over ALL of xBC (x, B and C
+    alike), the scan with a scalar decay a head and token over a [`head_dim`,
+    `d_state`] state a head in chunks of `chunk` tokens, B and C shared by
+    the n_heads / n_groups heads of a group, the gate FIRST and then one
+    RMSNorm (`epsilon`) over each group's d_inner / n_groups columns, y *
+    SiLU(z) normed, and an output projection.  `memory`, a list, gains the
+    scan's result y [B, T, d_inner] (with the D term, BEFORE gate and norm).
+    Eight parameters, in creation order: W_in [D, 2 d_inner + 2 n_groups
+    d_state + n_heads], the taps [xBC, d_conv] (uniform on +- d_conv^-1/2,
+    torch's Conv1d default), their bias [xBC] (`bias_attr`; the same
+    default), dt's bias [n_heads] (the inverse softplus of a log-uniform draw
+    on [0.001, 0.1]), A_log [n_heads] (log of a uniform draw on [1, 16)), D
+    [n_heads] (`skip_attr`; one by default), the norm's gain [d_inner]
+    (`gain_attr`; one by default), W_out [d_inner, D]; the projections have
+    no bias.  The part `ssm.in_proj` names the input projection in a trace
+    (what `decoder_lm(remat_keep=)` holds), `ssd.conv`, `ssd.dt`, `ssd.scan`
+    and `ssd.norm` the core's stages."""
+    helper = LayerHelper("mamba2", name=name)
+    prog = helper.main_program
+    dim = input.shape[-1]
+    H, P, N, G, L = (int(v) for v in (n_heads, head_dim, d_state, n_groups,
+                                      d_conv))
+    if min(H, P, N, G, L) < 1 or H % G:
+        raise ValueError(f"mamba2: {H} heads of {P} on a state of {N} in {G} "
+                         f"groups under {L} taps: whole numbers, the heads a "
+                         f"multiple of the groups")
+    Di, xbc = H * P, H * P + 2 * G * N
+    project = functools.partial(fc, num_flatten_dims=2,
+                                param_attr=param_attr, bias_attr=False)
+    with prog.part_guard("ssm.in_proj"):
+        proj = project(input, Di + xbc + H)
+    bound = L ** -0.5
+    taps = helper.create_parameter(
+        attr={}, shape=[xbc, L], dtype=input.dtype,
+        default_initializer=UniformInitializer(-bound, bound))
+    conv_bias = helper.create_parameter(
+        attr=bias_attr if isinstance(bias_attr, dict) else {}, shape=[xbc],
+        dtype=input.dtype, is_bias=True,
+        default_initializer=UniformInitializer(-bound, bound))
+    lead = tuple(input.shape[:2])
+    conved = helper.create_tmp_variable(input.dtype, shape=lead + (xbc,))
+    helper.append_op(
+        "causal_conv_silu",
+        inputs={"X": [proj.name], "Filter": [taps.name],
+                "Bias": [conv_bias.name]},
+        outputs={"Out": [conved.name]},
+        attrs={"offset": Di, "part": "ssd.conv"})
+
+    def columns(of, start, width, part):
+        out = helper.create_tmp_variable(input.dtype, shape=lead + (width,))
+        helper.append_op("slice", inputs={"Input": [of.name]},
+                         outputs={"Out": [out.name]},
+                         attrs={"axes": [2], "starts": [start],
+                                "ends": [start + width], "part": part})
+        return out
+
+    x = columns(conved, 0, Di, "ssd.scan")
+    b = columns(conved, Di, G * N, "ssd.scan")
+    c = columns(conved, Di + G * N, G * N, "ssd.scan")
+    dt = columns(proj, Di + xbc, H, "ssd.dt")
+    dt_bias = helper.create_parameter(
+        attr={}, shape=[H], dtype=input.dtype, is_bias=True,
+        default_initializer=_step_bias_draw())
+    a_log = helper.create_parameter(
+        attr={}, shape=[H], dtype=input.dtype,
+        default_initializer=_UniformThrough(1.0, 16.0, [("log", {})]))
+    skip = helper.create_parameter(
+        attr=skip_attr if isinstance(skip_attr, dict) else {}, shape=[H],
+        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    y = helper.create_tmp_variable(input.dtype, shape=lead + (Di,))
+    helper.append_op(
+        "ssd_scan",
+        inputs={"X": [x.name], "B": [b.name], "C": [c.name], "Dt": [dt.name],
+                "ALog": [a_log.name], "D": [skip.name],
+                "DtBias": [dt_bias.name]},
+        outputs={"Out": [y.name]},
+        attrs={"heads": H, "groups": G, "chunk": int(chunk)})
+    if memory is not None:
+        memory.append(y)
+    gain = _rms_gain(helper, Di, input.dtype, gain_attr)
+    normed = helper.create_tmp_variable(input.dtype, shape=lead + (Di,))
+    helper.append_op(
+        "gated_rms_norm",
+        inputs={"X": [y.name], "Gate": [proj.name], "Scale": [gain.name]},
+        outputs={"Y": [normed.name]},
+        attrs={"epsilon": float(epsilon), "groups": G})
+    out = project(normed, dim)
     from .sequence import propagate_length
 
     return propagate_length(input, out)

@@ -16,6 +16,7 @@ gelu → +x), learned position embeddings, final LN, untied LM head.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 from .. import layers
 from ..framework import unique_name
@@ -59,7 +60,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                logit_scale=None, delta=None, attention_gate=False,
                rotary_dim=None, ssm=None, differential=None, window=None,
                attention_bias=False, tie_embeddings=False, norm_attr=None,
-               kda=None, yarn=None, remat_keep=()):
+               kda=None, yarn=None, remat_keep=(), attention_scale=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -108,7 +109,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     the layers that run it, beside whatever the other entries name
     (Kimi-Linear: 'kda', 'kda', 'kda', 'attention').
     `layer_types` gives the token mixer layer by layer, `n_layers` of
-    nine kinds: 'attention' (the kind `attention` names; everywhere by
+    ten kinds: 'attention' (the kind `attention` names; everywhere by
     default); 'conv', a gated short convolution, `conv` = {"kernel_size"}
     (`layers.gated_short_conv`); 'sparse_attention', block-top-k sparse
     attention WITHOUT a position, `sparse` = {"n_heads", "n_kv_heads",
@@ -135,7 +136,15 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     'mamba', a selective state-space mixer, no position, `ssm` = {} or any
     of "d_state", "d_conv", "expand", "dt_rank", "bias_attr",
     "skip_attr" (`layers.mamba`; the dict gains "memory": every such
-    layer's scan output, in order); 'gmu', a gated memory unit on the MEMORY
+    layer's scan output, in order); 'mamba2', a Mamba-2 mixer (a scalar
+    decay a head and token on a [head_dim, d_state] state a head, B and C
+    shared by a group of heads, a gated RMSNorm), no position, `ssm` =
+    {"n_heads", "head_dim", "d_state"} and optionally "n_groups" (1),
+    "d_conv" (4), "chunk" (the tokens a chunk of the scan's emission, 256),
+    "bias_attr", "skip_attr", "gain_attr" (`layers.mamba2`; its norm takes
+    `norm_epsilon`; the dict gains "memory" as for 'mamba'; a ValueError
+    where a size is missing or the heads are no multiple of the groups);
+    'gmu', a gated memory unit on the MEMORY
     (the scan's result before its gate) of the nearest 'mamba' layer before
     it (`layers.gated_memory_unit`); 'cross_attention', the attention
     kind's queries on the keys and values of the nearest 'attention' layer
@@ -147,8 +156,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     `remat_keep` names the products a block's segment HOLDS across to its
     backward and so makes once a step (`layers.recompute(keep=)`): any of
     'mlp.up', a gated MLP's two up-projections before the activation, and
-    'ssm.in_proj', a 'mamba' layer's [u' | z]; bytes a step with memory
-    left spends (2 x `dense_dim` and 2 x d_inner wide a token and block).
+    'ssm.in_proj', a 'mamba' layer's [u' | z] or a 'mamba2' layer's [z |
+    xBC | dt]; bytes a step with memory left spends (2 x `dense_dim` and 2
+    x d_inner wide a token and block).
     `differential` = {} or any of "layer_indices" (each layer's index in the
     whole model, which lambda_init is made from: a tower that is a cut of a
     deeper one names the published indices), "epsilon", "lambda_attr",
@@ -179,6 +189,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     heads, theta 1e4 over all 128 columns under the window; 48 heads,
     theta 5e5 with YaRN over 64 columns across the whole sequence).  With
     `norm_attr` the per-head QK-norm's two gains take its "gain" too.
+    `attention_scale` is the 'multi_head' layers' softmax scale where it is
+    not head_dim^-1/2 (Granite's `attention_multiplier`;
+    `layers.multi_head_attention(scale=)`).
     `residual_scale` multiplies every sub-layer's result before it is
     added to the stream, `emb_scale` the embedding, `logit_scale` the
     final norm's result before the head (MiniCPM's `scale_depth` /
@@ -268,6 +281,13 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 f"decoder_lm: a 'kda' layer needs `kda` = whole numbers of "
                 f"n_heads and head_dim (and of conv_kernel and gate_rank, "
                 f"where given): {kda!r}")
+    if "mamba2" in layer_types and any(
+            (ssm or {}).get(k) is None
+            for k in ("n_heads", "head_dim", "d_state")):
+        raise ValueError(
+            f"decoder_lm: a 'mamba2' layer needs `ssm` = n_heads, head_dim "
+            f"and d_state (`layers.mamba2` holds them to whole numbers, "
+            f"n_heads a multiple of n_groups): {ssm!r}")
     if hyper is not None and residual_scale is not None:
         raise ValueError("decoder_lm: `residual_scale` scales the one "
                          "residual stream; `hyper` has gates of its own")
@@ -349,6 +369,7 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
             **({"window": window[layer]}
                if window and window[layer] and kv is None else {}),
             **({"bias": attention_bias} if attention_bias else {}),
+            **({"scale": attention_scale} if attention_scale else {}),
             **({"differential": diff, "kv": kv} if diff is not None else {}))
         if diff is not None:
             differential.setdefault("results", {})[layer] = diff["result"]
@@ -372,10 +393,16 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
     def mix(h, layer):
         prog = default_main_program()
-        if layer_types[layer] == "mamba":
+        if layer_types[layer] in ("mamba", "mamba2"):
+            kind = layer_types[layer]
+            build = (layers.mamba if kind == "mamba" else functools.partial(
+                layers.mamba2, epsilon=norm_epsilon))
             since = len(prog.current_block().ops)
-            with prog.part_guard("mixer.mamba"):
-                out = layers.mamba(
+            # benchmarks/reduce/hlo_scopes.py reads a part's name as
+            # letters: "mixer.mamba2" would be read as "mixer.mamba"
+            with prog.part_guard("mixer.ssd" if kind == "mamba2"
+                                 else "mixer.mamba"):
+                out = build(
                     h, param_attr=attr, memory=shared["memory"],
                     **{k: v for k, v in ssm.items() if k != "memory"})
             keep_products("ssm.in_proj", since, part="ssm.in_proj")
@@ -577,7 +604,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
 
 # the token mixers `decoder_lm`'s `layer_types` names
 _MIXERS = ("attention", "conv", "sparse_attention", "linear_attention",
-           "gated_delta_net", "mamba", "gmu", "cross_attention", "kda")
+           "gated_delta_net", "mamba", "gmu", "cross_attention", "kda",
+           "mamba2")
 
 # decoder_lm's arguments that change the block's parameters or equations,
 # at GPT-2's values: the only block the decode ops (ops/transformer_ops.py
@@ -591,7 +619,7 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "attention_gate": False, "rotary_dim": None, "ssm": None,
                "differential": None, "window": None, "attention_bias": False,
                "tie_embeddings": False, "norm_attr": None, "kda": None,
-               "yarn": None}
+               "yarn": None, "attention_scale": None}
 
 
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
@@ -1800,6 +1828,71 @@ def build_phi4flash_lm_train_program(
     windowed = [n for n, i in enumerate(held) if windows[i]]
     if windowed:
         layers.assign(diff["results"][windowed[-1]])
+    if ssm["memory"]:
+        layers.scale(ssm["memory"][-1], scale=1.0)
+    return loss
+
+
+def build_granite_hybrid_lm_train_program(
+        seq_len, vocab_size, dim, layer_types, n_heads, n_kv_heads, dense_dim,
+        mamba_n_heads, mamba_d_head, mamba_d_state, mamba_n_groups=1,
+        mamba_d_conv=4, mamba_chunk=256, attention_multiplier=None,
+        embedding_multiplier=1.0, residual_multiplier=1.0, logits_scaling=1.0,
+        norm_epsilon=1e-5, gain_range=None, conv_bias_scale=None, remat=True,
+        dtype="bfloat16", learning_rate=3e-5, init_scale=0.02,
+        remat_keep=()):
+    """Granite-4.0-H-shaped decoder (`model_type` granitemoehybrid with no
+    routed expert): pre-norm RMSNorm blocks whose token mixer is, by the
+    published `layer_types`, 'mamba' (a Mamba-2 mixer: `mamba_n_heads` heads
+    of `mamba_d_head` on a state of `mamba_d_state`, B and C shared by the
+    heads of each of `mamba_n_groups` groups, `mamba_d_conv` taps over x, B
+    and C, a gated RMSNorm; the scan's emission in chunks of `mamba_chunk`
+    tokens) or 'attention' (`n_heads` query heads on `n_kv_heads` key/value
+    heads of dim / n_heads, no bias, NO position, softmax scale
+    `attention_multiplier`); a SiLU-gated MLP of `dense_dim` without bias in
+    every block; the embedding times `embedding_multiplier`, every
+    sub-layer's result times `residual_multiplier`, a final RMSNorm and the
+    TIED embedding as the head over `vocab_size` rows, the logits over
+    `logits_scaling`.  `gain_range` (lo, hi) draws the norms' gains (the
+    gated norm's too) and the Mamba layers' D uniformly and
+    `conv_bias_scale` the convolution's bias from normal(0, that), instead
+    of their defaults: a checked program must not pass without them.
+    `remat` wraps each block in `layers.recompute`, whose segment holds
+    `remat_keep` (`decoder_lm`'s).  Loss: next-token cross entropy; Adam.
+    Returns the loss.  Feeds as `build_lm_train_program`.  The program's
+    last `scale` (by one) is the scan's result [B, T, d_inner] of the last
+    'mamba' layer (with the D term, before gate and norm): under `remat` it
+    is made inside a segment's block, and a fetch by op type looks in the
+    program's own."""
+    from .. import optimizer as opt
+    from ..framework.initializer import UniformInitializer
+
+    kinds = {"mamba": "mamba2", "attention": "attention"}
+    if set(layer_types) - set(kinds):
+        raise ValueError(f"layer_types {layer_types!r}: 'mamba' or "
+                         f"'attention', as published")
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    gains = ({"initializer": UniformInitializer(*gain_range)}
+             if gain_range else None)
+    ssm = {"n_heads": mamba_n_heads, "head_dim": mamba_d_head,
+           "d_state": mamba_d_state, "n_groups": mamba_n_groups,
+           "d_conv": mamba_d_conv, "chunk": mamba_chunk,
+           "skip_attr": gains, "gain_attr": gains,
+           "bias_attr": ({"initializer": NormalInitializer(
+               scale=conv_bias_scale)} if conv_bias_scale else None)}
+    logits = decoder_lm(
+        tokens, vocab_size, dim, len(layer_types), n_heads, max_len=seq_len,
+        dtype=dtype, remat=remat, norm="rms_norm",
+        norm_epsilon=norm_epsilon, positions="none", n_kv_heads=n_kv_heads,
+        layer_types=[kinds[t] for t in layer_types], ssm=ssm,
+        attention_scale=attention_multiplier, ffn="gated_mlp",
+        dense_dim=dense_dim, tie_embeddings=True,
+        emb_scale=embedding_multiplier, residual_scale=residual_multiplier,
+        logit_scale=1.0 / logits_scaling, norm_attr={"gain": gains},
+        init_scale=init_scale, remat_keep=remat_keep)
+    loss = lm_loss(logits, targets, dtype=dtype)
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
     if ssm["memory"]:
         layers.scale(ssm["memory"][-1], scale=1.0)
     return loss

@@ -43,6 +43,12 @@ _MET_LAYER_KINDS = _MET.counter(
     "emission; once a compile, not once a step), by the sliding window's "
     "width (window; 0: the whole sequence) and the rule (positions: rope, "
     "none)")
+_MET_SOFTMAX_SCALE = _MET.counter(
+    "attention_softmax_scale_traced_total",
+    "scaled_dot_product_attention ops traced whose desc states a softmax "
+    "scale of its own (attr `scale`; forward emission; once a compile, not "
+    "once a step), by that scale: a layer whose scores are NOT over "
+    "sqrt(head_dim) (Granite's `attention_multiplier`)")
 _MET_FLASH_CALLS = _MET.counter(
     "flash_calls_total",
     "calls of the flash kernels the op scaled_dot_product_attention made on "
@@ -319,7 +325,12 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     the flash kernels on one TPU, dense under the same Allowed everywhere
     else, a mesh included.  `mask` = "window" with `window` w (and `causal`
     true): token t sees key j iff 0 <= t - j < w (`window_allowed`), the
-    same two ways; a window that holds the sequence is plainly causal."""
+    same two ways; a window that holds the sequence is plainly causal.
+
+    The attr `scale`: the scores' factor where it is not D^-1/2 (a positive
+    number; Granite's `attention_multiplier`), which every path (the flash
+    kernels, dense, ring, all-to-all) takes as its own, forward and
+    backward; absent, the op traces as it always did."""
     import jax.numpy as jnp
 
     from ..parallel import ring_attention as ra
@@ -336,10 +347,18 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     sp_mode = str(attrs.get("sp_mode", "ring"))
     mesh = getattr(ctx, "mesh", None)
     sp = bd is None and mesh is not None and axis_size(mesh, "sp") > 1
+    scale = attrs.get("scale")
+    if scale is not None:
+        scale = float(scale)
+        if not scale > 0:
+            raise ValueError(f"scaled_dot_product_attention: scale "
+                             f"{scale!r}: a positive number")
 
     def traced(path):
         if not ctx.in_grad_replay():
             _MET_ATTN_LAYERS.inc(layout=layout, path=path)
+            if scale is not None:
+                _MET_SOFTMAX_SCALE.inc(scale=repr(scale))
             if attrs.get("positions"):
                 _MET_LAYER_KINDS.inc(
                     window=str(bd[1] if windowed else 0),
@@ -355,7 +374,8 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         got = None
         if not sp and kv_heads == heads and bd is None:
             with part_scope("attn.attend"):
-                got = flash_single_chip(ctx, q, k, v, causal, heads=heads)
+                got = flash_single_chip(ctx, q, k, v, causal, heads=heads,
+                                        scale=scale)
         if got is not None:
             out, saved = got
             if saved is not None:
@@ -394,7 +414,8 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         if sp_mode == "alltoall":
             fl = on_tpu and ra.flash_ulysses_eligible(q, mesh, "sp")
             out = ra.ulysses_attention(q, k, v, mesh, axis_name="sp",
-                                       causal=causal, use_flash=fl,
+                                       causal=causal, scale=scale,
+                                       use_flash=fl,
                                        is_train=not ctx.is_test)
         elif sp_mode == "ring":
             fl = on_tpu and ra.flash_ring_eligible(
@@ -408,7 +429,8 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                 if not (fl and causal and t2 % 128 == 0):
                     sched = "plain"
             out = ra.ring_attention(q, k, v, mesh, axis_name="sp",
-                                    causal=causal, use_flash=fl,
+                                    causal=causal, scale=scale,
+                                    use_flash=fl,
                                     is_train=not ctx.is_test,
                                     schedule=sched)
         else:
@@ -417,13 +439,14 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         traced(sp_mode)
     else:
         with part_scope("attn.attend"):
-            got = flash_single_chip(ctx, q, k, v, causal, mask=bd)
+            got = flash_single_chip(ctx, q, k, v, causal, mask=bd,
+                                    scale=scale)
             if got is not None:
                 out, saved = got
             else:
                 out = ra.attention(
                     q, repeated(k), repeated(v),
-                    causal=causal and not windowed,
+                    causal=causal and not windowed, scale=scale,
                     allowed=bd and (window_allowed(T, bd[1]) if windowed
                                     else block_diffusion_allowed(*bd)))
         traced("dense" if got is None else "flash" if bd is None else

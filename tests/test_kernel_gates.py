@@ -63,6 +63,10 @@ MECHANISMS = {
     # (YaRN's tables) too
     "laguna-s-2.1": {"flash", "flash_window", "head_norm_rope",
                      "grouped_matmul", "segment_sum"},
+    # ONE attention layer on the projections' layout (32 query heads on 8:
+    # its heads are split inside the op, no position, scale 1/64); the nine
+    # Mamba-2 scans are plain XLA (`ssd_chunked`: no kernel, so no gate)
+    "granite-4.0-h-micro": {"flash"},
 }
 
 
