@@ -119,6 +119,42 @@ def _startup(exe, kept):
     exe.restore_state({"step": exe.global_step + 1})
 
 
+def _described_step(device, feed, fetch, stage="lower"):
+    """The default main program's step for `fetch`, lowered for `device` (a
+    described chip's) from shapes alone, x64 off as the chip compiles it
+    (as tests/benchmarks/test_benchmark.py `_aot`); or, `stage` "trace",
+    traced for it and no more: the counters are written there."""
+    import paddle_tpu as fluid
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.framework.core import np_dtype
+
+    class DescribedPlace(fluid.CPUPlace):
+        def jax_device(self):
+            return device
+
+    main = fluid.default_main_program()
+    block = main.blocks[0]
+    exe = fluid.Executor(DescribedPlace())
+    one = SingleDeviceSharding(device)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            tuple(shape), jax.dtypes.canonicalize_dtype(dtype), sharding=one)
+
+    def of_var(n):
+        v = block._find_var_recursive(n)
+        return sds(v.shape, np_dtype(v.dtype))
+
+    with jax.enable_x64(False):
+        feed_vals = exe._prepare_feeds(block, feed)
+        compiled = exe._compile(main, 0, feed_vals, fetch)
+        return getattr(compiled.fn, stage)(
+            {n: of_var(n) for n in compiled.rw_state},
+            {n: of_var(n) for n in compiled.external_reads},
+            {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
+            sds((2,), np.uint32))
+
+
 def _run_layer(build, feeds, weights=None, seed=11):
     """Build a program with `build(x)` -> out, set `weights` {index: array}
     over the parameters in creation order, run -> (out, parameters)."""
@@ -146,6 +182,25 @@ def _rand(shape, seed, scale=1.0):
             * scale).astype("float32")
 
 
+def _silu(x):   # numpy's
+    return x / (1 + np.exp(-x))
+
+
+def _f32(a):
+    return np.asarray(a.astype(jnp.float32))
+
+
+def _ctx(monkeypatch, platform="tpu", mesh=None):
+    """An emit context whose trace claims `platform` as its target."""
+    from paddle_tpu.ops import registry as reg
+
+    ctx = reg.EmitContext(None, is_test=False)
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: platform)
+    ctx.mesh = mesh
+    return ctx
+
+
 def _r(*shape, lo=-1.0, hi=1.0, seed=0):
     return np.random.RandomState(seed).uniform(lo, hi, shape)
 
@@ -157,6 +212,23 @@ def _series(family):    # [(labels, value)] of a family, sorted by labels
     return sorted(((s["labels"], s["value"])
                    for s in (fam["series"] if fam else [])),
                   key=lambda s: sorted(s[0].items()))
+
+
+def _by_labels(family, *keys):
+    """{a series' values of the labels `keys` (of one key: the value alone;
+    of none: its sorted (label, value) pairs): its count} of a counter
+    family, {} where nothing counted."""
+    from paddle_tpu import observability as obs
+
+    def key(labels):
+        if not keys:
+            return tuple(sorted(labels.items()))
+        return (labels[keys[0]] if len(keys) == 1
+                else tuple(labels[k] for k in keys))
+
+    fam = obs.REGISTRY.snapshot()["families"].get(family)
+    return {key(s["labels"]): s["value"]
+            for s in (fam["series"] if fam else [])}
 
 
 def _inner_eqns(jaxpr):

@@ -37,7 +37,29 @@ where the program stays the same, one program fewer buys all of it.  So:
     (each is a program to compile);
   * a whole-step AOT compile happens once a module and only for a step a
     cell runs; what the lowered text shows is read there.
-A test that hangs fails alone and by name: TEST_LIMIT_S below."""
+Since PR 68 (the driver's run of PR 67's tree was cut at 1470 s):
+  * a compile for a described v5e (the `v5e` fixture, `.lower().compile()`)
+    is `slow` outside tests/benchmarks, by an explicit mark: 18 ids were 244
+    of the suite's 6449 test-seconds.  The driver's chip run of every cell
+    guards what they guard at every PR (a kernel that no longer fits fails
+    its cell, a relayout or a second launch moves its readers); run them by
+    name after a kernel change.  A counter written where the step is TRACED
+    stays in tier-1 behind `.trace()` alone (3 s a whole step, 45 compiled);
+  * the driver's scheduler (xdist `loadfile`) hands files out by their COUNT
+    of tests, most first, not by name, and what starts in the run's last
+    tenth is all wall: a file of under ten tests costs under 40 s, or its
+    tests live in the sibling they belong to (Phi-4-mini-flash's model
+    tests: 7 ids, 104 s, started at 1000 of 1104 s).  No collection hook
+    sorts by a table of seconds: xdist sorts by count after it;
+  * compiler switches tried and closed, in user CPU seconds:
+    `--xla_cpu_parallel_codegen_split_count=1` 34.0 -> 35.1,
+    `--xla_llvm_disable_expensive_passes=true` 38.7 -> 40.7,
+    `--xla_cpu_use_thunk_runtime=false` 38.7 -> 39.9,
+    `--xla_backend_optimization_level=0` 38.7 -> 29.4 but 7% over the suite,
+    and a control stops failing under it; the persistent cache is never on
+    for the CPU (framework/executor.py `_point_jax_at_the_cache`).
+A test that hangs fails alone and by name: TEST_LIMIT_S below (600 still:
+tests/benchmarks' compiles set it)."""
 
 import os
 import signal

@@ -12,8 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _with_vjp
-from paddle_tpu import observability as obs
+from _kernel_refs import _by_labels, _with_vjp
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
@@ -110,14 +109,19 @@ def test_spanned_window_call_is_dense_attention_and_the_whole_grids_bits(
                 (heads, nq, span_k), (heads, nq, span_k),
                 (kv, nk, group * span_q)]))
 
-        got = {}
-        for whole, (fwd, dq, dkv) in calls.items():
-            out, lse3 = fwd(*flat[:3])
-            if not whole:   # one delta for both: only the grids differ
-                delta3 = (out * flat[3]).sum(-1).reshape(heads, 1, T)
-            dk, dv = dkv(*flat, lse3, delta3)
-            got[whole] = dict(out=out, lse=lse3, dk=dk, dv=dv,
-                              dq=dq(*flat, lse3, delta3))
+        @jax.jit    # ONE program, as _kernel_refs._flash_results
+        def kernels(*flat):
+            got = {}
+            for whole, (fwd, dq, dkv) in calls.items():
+                out, lse3 = fwd(*flat[:3])
+                if not whole:   # one delta for both: only the grids differ
+                    delta3 = (out * flat[3]).sum(-1).reshape(heads, 1, T)
+                dk, dv = dkv(*flat, lse3, delta3)
+                got[whole] = dict(out=out, lse=lse3, dk=dk, dv=dv,
+                                  dq=dq(*flat, lse3, delta3))
+            return got
+
+        got = kernels(*flat)
         for name, ref in got[True].items():
             assert (np.asarray(got[False][name]).tobytes()
                     == np.asarray(ref).tobytes()), name
@@ -196,9 +200,7 @@ def test_spans_by_hand(case):
 
 
 def _steps():
-    fam = obs.REGISTRY.snapshot()["families"].get("flash_grid_steps_total")
-    return {(s["labels"]["kernel"], s["labels"]["part"]): s["value"]
-            for s in (fam or {"series": []})["series"]}
+    return _by_labels("flash_grid_steps_total", "kernel", "part")
 
 
 # (B, query heads, K/V heads, T, D, Dv), under -> (grid, live) steps a call

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _with_vjp
+from _kernel_refs import _f32, _with_vjp
 from test_gated_delta_kernel import _gdn_step, _gdn_values, _series
 
 from paddle_tpu import observability as obs
@@ -37,10 +37,6 @@ def _plain(x, w):
     """The plain part and z, X's last columns: what the `custom_vjp`
     hands out."""
     return (*slo.gdn_conv_plain(x, w, HK, HV, D, EPS), x[..., MIXED:])
-
-
-def _f32(a):
-    return np.asarray(a.astype(jnp.float32))
 
 
 @pytest.mark.parametrize("B", [1, 2])

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from _kernel_refs import _by_labels, _ctx
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops import registry as reg
@@ -155,14 +156,6 @@ def test_a_visit_multiplies_the_half_its_rows_lie_in(monkeypatch, lo, hi,
 # the gate: what the code can see
 
 
-def _ctx(monkeypatch, platform="tpu", mesh=None):
-    ctx = reg.EmitContext(None, is_test=False)
-    monkeypatch.setattr(reg.EmitContext, "target_platform",
-                        lambda self: platform)
-    ctx.mesh = mesh
-    return ctx
-
-
 def _backward_jaxpr(product, x, w, counts, dy):
     import jax
 
@@ -231,9 +224,7 @@ def test_gate_open_takes_the_kernels(monkeypatch):
 
 
 def _backward_counter() -> dict:
-    fam = obs.REGISTRY.snapshot()["families"].get(BACKWARD)
-    return {s["labels"]["impl"]: s["value"]
-            for s in (fam["series"] if fam else [])}
+    return _by_labels(BACKWARD, "impl")
 
 
 @pytest.fixture

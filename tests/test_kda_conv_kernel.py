@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _kernel_refs import _with_vjp
+from _kernel_refs import _f32, _with_vjp
 from test_kda_kernel import (_kda_step, _kda_values, _series,
                              _spy_on_calls)
 
@@ -35,10 +35,6 @@ def _operands(B, T, L, dtype, seed=0):
 
 def _plain(q, k, v, *taps):
     return slo.kda_conv_plain(q, k, v, taps, H)
-
-
-def _f32(a):
-    return np.asarray(a.astype(jnp.float32))
 
 
 @pytest.mark.parametrize("B", [1, 2])

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _startup
+from _kernel_refs import _by_labels, _described_step, _startup
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
@@ -28,17 +28,11 @@ LAYERS = "attention_layers_traced_total"
 
 
 def _counter() -> dict:
-    """{(op, reused): count} of the counter's series."""
-    fam = obs.REGISTRY.snapshot()["families"].get(COUNTER)
-    return {(s["labels"]["op"], s["labels"]["reused"]): s["value"]
-            for s in (fam["series"] if fam else [])}
+    return _by_labels(COUNTER, "op", "reused")
 
 
 def _paths() -> dict:
-    """{(layout, path): layers} of attention_layers_traced_total."""
-    fam = obs.REGISTRY.snapshot()["families"].get(LAYERS)
-    return {(s["labels"]["layout"], s["labels"]["path"]): s["value"]
-            for s in (fam["series"] if fam else [])}
+    return _by_labels(LAYERS, "layout", "path")
 
 
 @pytest.fixture(params=sorted(WIDTHS))
@@ -297,10 +291,7 @@ SCORES = "flash_score_elements_total"
 
 
 def _scores() -> dict:
-    """{(kernel, part): elements} of the counter's series."""
-    fam = obs.REGISTRY.snapshot()["families"].get(SCORES)
-    return {(s["labels"]["kernel"], s["labels"]["part"]): s["value"]
-            for s in (fam["series"] if fam else [])}
+    return _by_labels(SCORES, "kernel", "part")
 
 
 def test_score_counter_is_the_schedules_geometry():
@@ -365,42 +356,10 @@ def test_score_counter_counts_at_trace_time_only(pallas_on_cpu):
 # AOT: the real kernels, compiled for a described v5e
 
 
-def _lowered_step(loss, device, batch, seq_len):
-    """The executor's step lowered for `device`, from shapes alone (as
-    tests/benchmarks/test_benchmark.py `_aot` compiles it)."""
-    import jax
-    from jax.sharding import SingleDeviceSharding
-
-    from paddle_tpu.framework.core import np_dtype
-
-    class DescribedPlace(fluid.CPUPlace):
-        def jax_device(self):
-            return device
-
-    main = fluid.default_main_program()
-    block = main.blocks[0]
-    exe = fluid.Executor(DescribedPlace())
-    one = SingleDeviceSharding(device)
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(
-            tuple(shape), jax.dtypes.canonicalize_dtype(dtype), sharding=one)
-
-    def of_var(n):
-        v = block._find_var_recursive(n)
-        return sds(v.shape, np_dtype(v.dtype))
-
+def _lowered_step(loss, device, batch, seq_len, stage="lower"):
     toks = np.zeros((batch, seq_len, 1), np.int64)
-    # the chip runs with x64 off: compile what the chip compiles
-    with jax.enable_x64(False):
-        feed_vals = exe._prepare_feeds(block, {"tokens": toks,
-                                               "targets": toks})
-        compiled = exe._compile(main, 0, feed_vals, [loss.name])
-        return compiled.fn.lower(
-            {n: of_var(n) for n in compiled.rw_state},
-            {n: of_var(n) for n in compiled.external_reads},
-            {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
-            sds((2,), np.uint32))
+    return _described_step(device, {"tokens": toks, "targets": toks},
+                           [loss.name], stage)
 
 
 def _kernel_calls(text):
@@ -420,6 +379,7 @@ def _kernel_calls(text):
     return kinds
 
 
+@pytest.mark.slow
 def test_aot_one_forward_kernel_a_layer(v5e):
     """A 2-layer LM at T 1024 and head size 64: the compiled step holds one
     forward, one dq and one dkv Mosaic call a layer (it held two forwards),
@@ -443,12 +403,11 @@ def test_aot_one_forward_kernel_a_layer(v5e):
     assert _counter() == {(SDPA, "1"): float(layers)}
     assert _paths() == {("bthd", "flash_packed"): float(layers)}
     # and each layer's dq kernel makes the backward's delta itself (PR 47)
-    fam = obs.REGISTRY.snapshot()["families"][
-        "flash_backward_delta_traced_total"]
-    assert {s["labels"]["where"]: s["value"] for s in fam["series"]} == {
+    assert _by_labels("flash_backward_delta_traced_total", "where") == {
         "dq": float(layers)}
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("head_dim,kv_heads,path", [
     (128, 1, "pallas"), (64, 2, "pallas_packed")],
     ids=["heads_of_128", "pairs_of_64"])
@@ -487,9 +446,7 @@ def test_aot_one_qk_prep_kernel_each_way_for_q_and_for_k(v5e, head_dim,
                      "head_norm_rope_bwd": 2 * layers, "flash_fwd": layers,
                      "flash_bwd_dq": layers, "flash_bwd_dkv": layers}, kinds
     assert _counter() == {(SDPA, "1"): float(layers)}
-    fam = obs.REGISTRY.snapshot()["families"]["qk_prep_layers_traced_total"]
-    assert {(s["labels"]["path"], s["labels"]["heads"]): s["value"]
-            for s in fam["series"]} == {
+    assert _by_labels("qk_prep_layers_traced_total", "path", "heads") == {
         (path, str(heads)): float(layers),
         (path, str(kv_heads)): float(layers)}
 
@@ -502,6 +459,7 @@ def test_aot_one_qk_prep_kernel_each_way_for_q_and_for_k(v5e, head_dim,
 # attention's, whose blocks are the whole dimension (one UNDER 128 long is
 # refused by Mosaic in all three kernels: a lane offset into the logsumexp
 # row it cannot prove aligned, at PR 31's parent as after it: PERF.md 7)
+@pytest.mark.slow
 @pytest.mark.parametrize("shape", [
     (8, 16, 1024, 64, 64), (1, 16, 4096, 128, 128), (1, 16, 8192, 192, 128),
     (2, 4, 256, 64, 64), (1, 32, 8192, 64, 64, 8),
@@ -556,6 +514,7 @@ def test_aot_the_walks_compile_at_the_cells_shapes(v5e, shape):
                 2 if name.startswith("flash_bwd") else 1), name
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("shape,heads", [((8, 1024, 1024), 16),
                                          ((1, 4096, 2048), 16)],
                          ids=["gpt2m_train_bs8", "heads_of_128_T4096"])
@@ -588,6 +547,42 @@ def test_aot_packed_backward_makes_its_own_delta(v5e, shape, heads):
 # AOT: the expert layer's backward kernels in OLMoE's real step
 
 
+OLMOE_LAYERS = 2
+OLMOE_STEP = dict(seq_len=4096, vocab_size=50304, dim=2048,
+                  n_layers=OLMOE_LAYERS, n_heads=16, num_experts=64,
+                  expert_dim=1024, top_k=8, dtype="bfloat16")
+
+
+def _olmoe_step(v5e, stage):
+    from paddle_tpu.models.transformer import build_moe_lm_train_program
+
+    fluid.reset()
+    loss = build_moe_lm_train_program(**OLMOE_STEP)
+    return _lowered_step(loss, v5e, batch=1, seq_len=4096, stage=stage)
+
+
+def _grouped_backward():
+    return _by_labels("moe_grouped_backward_total", "impl")
+
+
+def test_olmoe_step_traced_for_a_v5e_counts_its_backward_kernels(
+        v5e, monkeypatch):
+    """The slow test's step below, TRACED for the described v5e and no more
+    (PR 68): its counters are written there, so tier-1 keeps them; the
+    gate closed, every grouped backward is autodiff's."""
+    from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
+
+    _olmoe_step(v5e, "trace")
+    assert _grouped_backward() == {"pallas": 3.0 * OLMOE_LAYERS}
+    assert _counter() == {(SDPA, "1"): float(OLMOE_LAYERS)}
+    assert _paths() == {("bhtd", "flash"): float(OLMOE_LAYERS)}
+    obs.REGISTRY.reset()
+    monkeypatch.setattr(gm, "usable", lambda *shape: False)
+    _olmoe_step(v5e, "trace")
+    assert _grouped_backward() == {"ragged_dot": 3.0 * OLMOE_LAYERS}
+
+
+@pytest.mark.slow
 def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
         v5e, monkeypatch):
     """`olmoe_train_t4096`'s step at the published widths (2 layers, 64
@@ -597,18 +592,9 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     or transpose of the stacked expert weights.  With the gate closed the
     step as JAX hands it to XLA holds autodiff's six more `ragged_dot` a
     layer (not compiled: 15 s, for a step no cell runs)."""
-    from paddle_tpu.models.transformer import build_moe_lm_train_program
     from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
 
-    layers = 2
-    args = dict(seq_len=4096, vocab_size=50304, dim=2048, n_layers=layers,
-                n_heads=16, num_experts=64, expert_dim=1024, top_k=8,
-                dtype="bfloat16")
-
-    def lowered():
-        fluid.reset()
-        loss = build_moe_lm_train_program(**args)
-        return _lowered_step(loss, v5e, batch=1, seq_len=4096)
+    layers = OLMOE_LAYERS
 
     def instructions(text, pattern):
         return re.findall(r"^\s*(?:ROOT )?%(" + pattern + r")[.\d]* = ",
@@ -618,7 +604,7 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     relayout = (r"^\s*%(?:copy|transpose)[.\d]* = " + stacked
                 + r"[^=\n]* (?:copy|transpose)\(")
 
-    change = lowered()
+    change = _olmoe_step(v5e, "lower")
     # the forward's three a layer, and their re-emission in the grad op
     text = change.as_text()
     assert text.count('"chlo.ragged_dot"(') == 6 * layers
@@ -628,9 +614,7 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
     assert len(instructions(text, gm.DRHS)) == 3 * layers, "drhs"
     assert len(instructions(text, "ragged-dot-none")) == 3 * layers
     assert not re.findall(relayout, text, re.M)
-    fam = obs.REGISTRY.snapshot()["families"]
-    assert {s["labels"]["impl"]: s["value"] for s in fam[
-        "moe_grouped_backward_total"]["series"]} == {"pallas": 3.0 * layers}
+    assert _grouped_backward() == {"pallas": 3.0 * layers}
     assert _counter() == {(SDPA, "1"): float(layers)}  # flash's, no moe
     # RoPE stands between projection and attention: the old desc, the
     # kernels' [B, H, T, D] entry
@@ -638,7 +622,7 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
 
     # the parent's step: the gate closed for these kernels alone
     monkeypatch.setattr(gm, "usable", lambda *shape: False)
-    text = lowered().as_text()
+    text = _olmoe_step(v5e, "lower").as_text()
     assert gm.DLHS not in text and gm.DRHS not in text
     assert text.count('"chlo.ragged_dot"(') == 12 * layers
 
@@ -646,6 +630,47 @@ def test_aot_olmoe_step_backward_kernels_and_no_weight_relayout(
 SHARE_ROWS = "moe_share_rows_to_tokens_traced_total"
 
 
+SHARE_T, SHARE_DIM, SHARE_BUFFER = 2048, 2048, 3072
+SHARE_STEP = dict(seq_len=SHARE_T, vocab_size=1024, dim=SHARE_DIM,
+                  n_layers=2, n_heads=16, kv_rank=512, qk_nope_dim=128,
+                  qk_rope_dim=64, v_dim=128, dense_dim=1024, dense_layers=1,
+                  num_experts=64, expert_dim=1408, top_k=6, shared_experts=2,
+                  held_experts=8, buffer_rows=SHARE_BUFFER,
+                  routed_scale=2.446, dtype="bfloat16")
+
+
+def _share_step(v5e, stage):
+    from paddle_tpu.models.transformer import build_mla_moe_lm_train_program
+
+    fluid.reset()
+    loss = build_mla_moe_lm_train_program(**SHARE_STEP)
+    return _lowered_step(loss, v5e, batch=1, seq_len=SHARE_T, stage=stage)
+
+
+def _share_rows():
+    return _by_labels(SHARE_ROWS, "op", "path")
+
+
+def test_share_step_traced_for_a_v5e_counts_its_rows_to_tokens(
+        v5e, monkeypatch):
+    """The slow test's step below, traced alone: the kernel takes the
+    forward combine and the row gather's backward, the `moe` op launches no
+    kernel's forward again; the gate closed, both are XLA's scatter-adds."""
+    from paddle_tpu.ops.pallas_kernels import segment_sum as ss
+
+    _share_step(v5e, "trace")
+    assert _share_rows() == {("combine", "segment_sum"): 1.0,
+                             ("permute_grad", "segment_sum"): 1.0}
+    assert _counter() == {("latent_attention", "1"): 2.0}
+    monkeypatch.setattr(ss, "usable", lambda *shape: False)
+    obs.REGISTRY.reset()
+    _share_step(v5e, "trace")
+    assert _share_rows() == {("combine", "scatter_add"): 1.0,
+                             ("permute_grad", "scatter_add"): 1.0}
+    assert _counter() == {("latent_attention", "1"): 2.0}
+
+
+@pytest.mark.slow
 def test_aot_share_rows_leave_the_buffer_by_two_kernel_calls_a_layer(
         v5e, monkeypatch):
     """Moonlight's share at its published widths, cut to the dense layer
@@ -658,28 +683,11 @@ def test_aot_share_rows_leave_the_buffer_by_two_kernel_calls_a_layer(
     the kernel for both, and the `moe` op has no series among the grad
     ops that launched a kernel's forward again.  With the gate closed the
     same step holds the parent's two scatter-adds."""
-    from paddle_tpu.models.transformer import build_mla_moe_lm_train_program
     from paddle_tpu.ops.pallas_kernels import segment_sum as ss
 
-    t, dim, rows = 2048, 2048, 3072
-    args = dict(seq_len=t, vocab_size=1024, dim=dim, n_layers=2, n_heads=16,
-                kv_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_dim=128,
-                dense_dim=1024, dense_layers=1, num_experts=64,
-                expert_dim=1408, top_k=6, shared_experts=2, held_experts=8,
-                buffer_rows=rows, routed_scale=2.446, dtype="bfloat16")
-
-    def lowered():
-        fluid.reset()
-        loss = build_mla_moe_lm_train_program(**args)
-        return _lowered_step(loss, v5e, batch=1, seq_len=t)
-
-    def series():
-        fam = obs.REGISTRY.snapshot()["families"].get(SHARE_ROWS)
-        return {(s["labels"]["op"], s["labels"]["path"]): s["value"]
-                for s in (fam["series"] if fam else [])}
-
+    t, dim, rows = SHARE_T, SHARE_DIM, SHARE_BUFFER
     on_tokens = rf"tensor<{t}x{dim}x(?:f32|bf16)>"
-    text = lowered().compile().as_text()
+    text = _share_step(v5e, "lower").compile().as_text()
     calls = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sum(ss.NAME in name for name in calls) == 2, calls
@@ -687,19 +695,19 @@ def test_aot_share_rows_leave_the_buffer_by_two_kernel_calls_a_layer(
     assert not [line for line in text.splitlines() if re.search(
         rf" = (?:f32|bf16)\[{t},{dim}\][^=]* scatter\(", line)
         and re.search(r"pdtpu\.moe\.(?:combine|permute)", line)]
-    assert series() == {("combine", "segment_sum"): 1.0,
-                        ("permute_grad", "segment_sum"): 1.0}
+    assert _share_rows() == {("combine", "segment_sum"): 1.0,
+                             ("permute_grad", "segment_sum"): 1.0}
     # the attention op's kept pair, and nothing of the expert layer's
     assert _counter() == {("latent_attention", "1"): 2.0}
 
     # the gate closed: the step as JAX hands it to XLA (not compiled: 40 s)
     monkeypatch.setattr(ss, "usable", lambda *shape: False)
-    text = lowered().as_text()
+    text = _share_step(v5e, "lower").as_text()
     assert ss.NAME not in text
     # a scatter's last line: its region closes, then (operand, indices,
     # updates) -> result
     assert len(re.findall(
         rf"\}}\) : \({on_tokens}, tensor<{rows}x1xi32>, "
         rf"tensor<{rows}x{dim}x(?:f32|bf16)>\) -> {on_tokens}", text)) == 2
-    assert series() == {("combine", "scatter_add"): 1.0,
-                        ("permute_grad", "scatter_add"): 1.0}
+    assert _share_rows() == {("combine", "scatter_add"): 1.0,
+                             ("permute_grad", "scatter_add"): 1.0}

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _dot, _r, _run_layer
+from _kernel_refs import _by_labels, _dot, _r, _run_layer, _silu
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -213,10 +213,6 @@ def test_kda_chunked_is_float32_whatever_comes_in():
 
 # ---------------------------------------------------------------------------
 # the op
-
-
-def _silu(x):
-    return x / (1 + np.exp(-x))
 
 
 def _sigmoid(x):
@@ -602,9 +598,7 @@ def test_kimi_linear_program_counts_what_it_traced():
     assert losses[-1] < losses[0]
     after = np.asarray(fluid.global_scope().find(bias.name))
     assert np.abs(after - before).max() == pytest.approx(4e-3, rel=1e-3)
-    fam = obs.REGISTRY.snapshot()["families"]
-    series = lambda name: {tuple(sorted(s["labels"].items())): s["value"]  # noqa
-                           for s in fam[name]["series"]}
+    series = _by_labels
     assert series("kda_layers_traced_total") == {
         (("chunk", "64"), ("conv_taps", "4"), ("gate_rank", "8"),
          ("head_dim", "8"), ("heads", "2")): 4.0}

@@ -17,12 +17,8 @@ import paddle_tpu as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer
 
-from _kernel_refs import _dense_scaled, _r, _series
+from _kernel_refs import _dense_scaled, _r, _series, _silu
 from op_test import OpTestHarness
-
-
-def _silu(x):
-    return x / (1 + np.exp(-x))
 
 
 # ---------------------------------------------------------------------------

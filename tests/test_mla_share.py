@@ -504,6 +504,9 @@ def _share_step(dtype, buffer_rows, drawn, layers=2):
     return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
 
 
+SHARE_DRAWN = {}    # a dtype's startup program is its buffers' both: one draw
+
+
 @pytest.mark.parametrize("buffer_rows", [None, 96],
                          ids=["a_roomy_buffer", "a_buffer_that_drops_pairs"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -525,7 +528,7 @@ def test_share_with_the_segment_sum_kernel_equals_the_scatter_add(
         return {(s[0]["op"], s[0]["path"]): s[1]
                 for s in _series(SHARE_ROWS)}
 
-    layers, drawn = 2, {}
+    layers, drawn = 2, SHARE_DRAWN.setdefault(dtype, {})
     fallback = _share_step(dtype, buffer_rows, drawn, layers)
     assert paths() == {("combine", "scatter_add"): float(layers),
                        ("permute_grad", "scatter_add"): float(layers)}

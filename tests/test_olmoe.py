@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _startup
-from paddle_tpu import observability as obs
+from _kernel_refs import _by_labels, _startup
 from paddle_tpu.models import transformer as tr
 
 
@@ -121,6 +120,9 @@ def test_decoder_lm_refuses_unknown_kinds():
             tr.decoder_lm(tokens, 16, 8, 1, 2, max_len=8, **bad)
 
 
+TOY_DRAWN = {}      # the toy's startup program is 3 s to compile: one draw
+
+
 def _olmoe_toy(**over):
     args = dict(seq_len=16, vocab_size=31, dim=16, n_layers=2, n_heads=2,
                 num_experts=4, expert_dim=8, top_k=2, dtype="float32",
@@ -158,7 +160,7 @@ def test_olmoe_program_is_built_from_the_new_layers():
 
     # it trains: Adam through every new op, the loss falls on one batch
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, TOY_DRAWN)
     rng = np.random.RandomState(0)
     tok = rng.randint(0, 31, (1, 16, 1)).astype("int64")
     feed = {"tokens": tok, "targets": np.roll(tok, -1, axis=1)}
@@ -173,7 +175,7 @@ def test_moe_layer_counter_counts_forward_emissions_once():
     loss = _olmoe_toy()
     before = _moe_counter()
     exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+    _startup(exe, TOY_DRAWN)
     tok = np.zeros((1, 16, 1), "int64")
     exe.run(feed={"tokens": tok, "targets": tok}, fetch_list=[loss])
     got = {k: v - before.get(k, 0.0) for k, v in _moe_counter().items()}
@@ -181,10 +183,7 @@ def test_moe_layer_counter_counts_forward_emissions_once():
 
 
 def _moe_counter() -> dict:
-    fam = obs.REGISTRY.snapshot()["families"].get("moe_layers_traced_total")
-    return {(s["labels"]["top_k"], s["labels"]["experts"],
-             s["labels"]["impl"]): s["value"]
-            for s in (fam["series"] if fam else [])}
+    return _by_labels("moe_layers_traced_total", "top_k", "experts", "impl")
 
 
 def test_aux_losses_are_in_the_loss():

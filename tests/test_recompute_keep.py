@@ -6,32 +6,30 @@ makes, so the op that makes it runs once a step.
 On the CPU, in float32: the numbers are the plain segment's and the plain
 ops', bit for bit for a gated MLP and to rounding with a kernel pair in front
 of the kept product (the selective scan, interpreted, reached as
-tests/test_kernel_forward_once.py reaches it).  Compiled for a described v5e:
-the products leave the step and no Mosaic call is added, which is the case a
-`jax.checkpoint` policy fails on (the last test shows it in plain JAX)."""
+tests/test_kernel_forward_once.py reaches it; those two cases, three steps
+to compile, run from tests/test_phi4flash.py: a file of six tests starts
+last under the driver's scheduler, tests/conftest.py).  Compiled for a
+described v5e: the products leave the step and no Mosaic call is added, the
+case a `jax.checkpoint` policy fails on (the last test, in plain JAX)."""
 
 import contextlib
+import functools
 import re
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _startup
+from _kernel_refs import _by_labels, _described_step, _startup
 from paddle_tpu import observability as obs
-from paddle_tpu.ops import registry as reg
 
 KEPT = "recompute_kept_traced_total"
-REUSED = "executor_grad_kernel_forward_total"
 MODES = ("plain", "segment", "keep")
 B, T, DIM, WIDE = 2, 64, 64, 128
 
 
-def _series(name) -> dict:
-    """{labels' values in the order of their names: value} of a counter."""
-    fam = obs.REGISTRY.snapshot()["families"].get(name)
-    return {tuple(v for _, v in sorted(s["labels"].items())): s["value"]
-            for s in (fam["series"] if fam else [])}
+def _kept() -> dict:   # {(pass, unit): count}
+    return _by_labels(KEPT, "pass", "unit")
 
 
 def _gated_mlp(h, kept):
@@ -73,14 +71,20 @@ def _build(mode, mixer=None):
         p.name + "@GRAD" for p in block.all_parameters()]
 
 
-def _step(mode, mixer, drawn, feed):
+FEED = {"x": np.random.RandomState(66).randn(B, T, DIM).astype(np.float32)}
+DRAWN = {None: {}, "mamba": {}}     # a mixer's programs draw once a module
+
+
+def _step(mode, mixer):
     fetch = _build(mode, mixer)
     exe = fluid.Executor(fluid.CPUPlace())
-    _startup(exe, drawn)
-    return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
+    _startup(exe, DRAWN[mixer])
+    return [np.asarray(g) for g in exe.run(feed=FEED, fetch_list=fetch)]
 
 
-FEED = {"x": np.random.RandomState(66).randn(B, T, DIM).astype(np.float32)}
+# what a test's cases are held to, stepped once for them all: a step is a
+# program to compile (10 to 15 s with the interpreted scan)
+_want = functools.cache(_step)
 
 
 @pytest.mark.parametrize("mode", MODES[1:])
@@ -88,10 +92,9 @@ def test_a_gated_mlps_numbers_are_the_plain_ops_bit_for_bit(mode):
     """(a) The loss, the input's gradient and every parameter's: a segment,
     and a segment that keeps the two up-projections, against the same ops
     with no segment."""
-    drawn = {}
-    want = _step("plain", None, drawn, FEED)
+    want = _want("plain", None)
     obs.REGISTRY.reset()
-    got = _step(mode, None, drawn, FEED)
+    got = _step(mode, None)
     assert len(got) == len(want) == 5
     for a, b in zip(got, want):
         assert a.shape == b.shape and np.abs(b).max() > 0
@@ -100,56 +103,9 @@ def test_a_gated_mlps_numbers_are_the_plain_ops_bit_for_bit(mode):
     # values kept by the forward emission and used by the replay; nothing
     # where nothing is named
     size = 2.0 * B * T * WIDE * 4
-    assert _series(KEPT) == ({} if mode == "segment" else {
+    assert _kept() == ({} if mode == "segment" else {
         ("forward", "bytes"): size, ("forward", "values"): 2.0,
         ("replay", "bytes"): size, ("replay", "values"): 2.0})
-
-
-@pytest.fixture
-def scan_on_cpu(monkeypatch):
-    """Every trace claims a TPU target and the scan's kernels interpret;
-    returns what each `run_pair` of a re-emission found kept for it."""
-    from paddle_tpu.ops.pallas_kernels import selective_scan as ss
-
-    handed = []
-    real_make, real_run = ss.make_selective_scan, reg.EmitContext.run_pair
-
-    def spy_run(self, pair, ops, kept=None):
-        if self.in_grad_replay():
-            handed.append(self.kept_for_grad())
-        return real_run(self, pair, ops, kept)
-
-    monkeypatch.setattr(reg.EmitContext, "target_platform",
-                        lambda self: "tpu")
-    monkeypatch.setattr(ss, "make_selective_scan",
-                        lambda: real_make(ss.CHUNK, True))
-    monkeypatch.setattr(reg.EmitContext, "run_pair", spy_run)
-    return handed
-
-
-@pytest.mark.parametrize("mode", MODES[1:])
-def test_a_kernel_pair_in_front_of_the_kept_products(mode, scan_on_cpu):
-    """(b) A Mamba layer's scan (the kernel pair) between its kept input
-    projection and the MLP's kept products: the numbers are the segment's
-    without `keep=` and the plain ops', to float32's rounding (the held
-    values are the made ones bit for bit; XLA fuses a backward whose product
-    is dead otherwise, and without a segment the reverse pass reads the
-    forward's kept states, not a replay's); the scan's emitter inside the
-    replay is handed nothing of the segment's and launches its forward
-    again, counted on the recompute grad op as ever."""
-    drawn = {}
-    want = {m: _step(m, "mamba", drawn, FEED) for m in ("plain", "segment")}
-    del scan_on_cpu[:]
-    obs.REGISTRY.reset()
-    got = _step(mode, "mamba", drawn, FEED)
-    assert scan_on_cpu == [None]
-    assert _series(REUSED) == {("recompute", "0"): 1.0}
-    assert len(got) == 14       # loss, x, the mixer's nine, the MLP's three
-    for a, b, c in zip(got, want["segment"], want["plain"]):
-        assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max()
-        assert np.abs(a - c).max() <= 1e-5 * np.abs(c).max()
-    values = _series(KEPT).get(("replay", "values"))
-    assert values == (3.0 if mode == "keep" else None)
 
 
 def test_keep_names_survive_the_descs_round_trip_and_must_be_made():
@@ -179,7 +135,7 @@ def test_keep_names_survive_the_descs_round_trip_and_must_be_made():
     got = exe.run(loaded, feed=FEED, fetch_list=fetch)
     for a, b in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), b)
-    assert _series(KEPT)[("replay", "values")] == 2.0
+    assert _kept()[("replay", "values")] == 2.0
 
     for bad in (["nobody_makes_this"], lambda up: [up, up]):
         fluid.reset()
@@ -251,40 +207,10 @@ def test_phi4flash_builder_names_19_values_and_salas_names_none():
 
 
 def _compiled_step(device, fetch):
-    """The executor's step for `fetch` compiled for `device` from shapes
-    alone (tests/benchmarks/test_benchmark.py `_aot`) -> its text and
+    """The step for `fetch` compiled for `device` -> its text and
     `temp_bytes`."""
-    import jax
-    from jax.sharding import SingleDeviceSharding
-
-    from paddle_tpu.framework.core import np_dtype
-
-    class DescribedPlace(fluid.CPUPlace):
-        def jax_device(self):
-            return device
-
-    main = fluid.default_main_program()
-    block = main.blocks[0]
-    exe = fluid.Executor(DescribedPlace())
-    one = SingleDeviceSharding(device)
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(
-            tuple(shape), jax.dtypes.canonicalize_dtype(dtype), sharding=one)
-
-    def of_var(n):
-        v = block._find_var_recursive(n)
-        return sds(v.shape, np_dtype(v.dtype))
-
-    with jax.enable_x64(False):
-        feed_vals = exe._prepare_feeds(
-            block, {"x": np.zeros((AOT_B, AOT_T, AOT_DIM), np.float32)})
-        compiled = exe._compile(main, 0, feed_vals, fetch)
-        done = compiled.fn.lower(
-            {n: of_var(n) for n in compiled.rw_state},
-            {n: of_var(n) for n in compiled.external_reads},
-            {k: sds(v.shape, v.dtype) for k, v in feed_vals.items()},
-            sds((2,), np.uint32)).compile()
+    done = _described_step(device, {"x": np.zeros(
+        (AOT_B, AOT_T, AOT_DIM), np.float32)}, fetch).compile()
     return done.as_text(), done.memory_analysis().temp_size_in_bytes
 
 
@@ -292,6 +218,7 @@ AOT_B, AOT_T, AOT_DIM = 2, 1024, 256
 _PRODUCT = re.compile(r"= \S+ (?:convolution|dot)\(")
 
 
+@pytest.mark.slow
 def test_aot_kept_products_leave_the_step_and_no_kernel_is_added(
         v5e, monkeypatch):
     """(c) A Mamba layer and a gated MLP in one segment at [2, 1024, 256]:
@@ -315,7 +242,7 @@ def test_aot_kept_products_leave_the_step_and_no_kernel_is_added(
         assert len(re.findall(ss.FWD + r"[^\n]*tpu_custom_call", text)) \
             + len(re.findall(ss.BWD + r"[^\n]*tpu_custom_call", text)) \
             == read[mode][1] == 3, read
-    kept = _series(KEPT)
+    kept = _kept()
     assert kept[("replay", "values")] == kept[("forward", "values")] == 3.0
     (products, calls, temp), (products_k, calls_k, temp_k) = (
         read["segment"], read["keep"])
@@ -324,6 +251,7 @@ def test_aot_kept_products_leave_the_step_and_no_kernel_is_added(
     assert temp_k - temp <= kept[("forward", "bytes")], (read, kept)
 
 
+@pytest.mark.slow
 def test_aot_a_checkpoint_policy_launches_the_kernel_a_third_time(v5e):
     """Why not `jax.checkpoint(policy=save_only_these_names)`: in this
     framework the forward op's emission and the grad op's `jax.vjp` of the

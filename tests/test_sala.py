@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _r, _run_layer, _with_vjp
+from _kernel_refs import _by_labels, _r, _run_layer, _with_vjp
 from op_test import OpTestHarness
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -515,9 +515,7 @@ def test_sala_program_under_recompute_counts_what_it_traced():
         feed={"tokens": tok, "targets": np.roll(tok, -1, 1)},
         fetch_list=[loss])[0]).reshape(())) for _ in range(4)]
     assert losses[-1] < losses[0]
-    fam = obs.REGISTRY.snapshot()["families"]
-    series = lambda name: {tuple(sorted(s["labels"].items())): s["value"]  # noqa
-                           for s in fam[name]["series"]}
+    series = _by_labels
     assert series("sparse_attention_layers_traced_total") == {
         (("block", "16"), ("kv_heads", "1"), ("path", "dense_mask"),
          ("q_heads", "2")): 1.0}

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _dense_masked as _dense, _rand, _with_vjp
+from _kernel_refs import (_by_labels, _dense_masked as _dense, _rand,
+                          _with_vjp)
 from op_test import OpTestHarness
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import attention_ops, llm_ops, moe_ops, registry as reg
@@ -257,10 +258,7 @@ def test_flash_block_diffusion_counts_the_square_of_2L_and_refuses():
         kw = dict(mask=mask, interpret=True, block_q=32, block_k=32)
         out, lse = fa.flash_attention_fwd(q, k, k, **kw)
         fa.flash_attention_bwd(q, k, k, out, lse, out, **kw)
-        fam = obs.REGISTRY.snapshot()["families"][
-            "flash_score_elements_total"]
-        got = {(s["labels"]["kernel"], s["labels"]["part"]): s["value"]
-               for s in fam["series"]}
+        got = _by_labels("flash_score_elements_total", "kernel", "part")
         for kernel in KERNELS:
             assert got[kernel, "square"] == 8.0 * (2 * L) ** 2
             assert got[kernel, "computed"] == 8.0 * fa._schedule(

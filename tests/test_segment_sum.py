@@ -10,8 +10,8 @@ the AOT compile for a described v5e in tests/test_kernel_forward_once.py
 import numpy as np
 import pytest
 
+from _kernel_refs import _ctx
 from paddle_tpu.ops import moe_ops
-from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops.pallas_kernels import grouped_matmul as gm
 from paddle_tpu.ops.pallas_kernels import segment_sum as ss
 
@@ -228,14 +228,6 @@ def test_usable_shapes():
 
 # ---------------------------------------------------------------------------
 # the gate: what the code can see
-
-
-def _ctx(monkeypatch, platform="tpu", mesh=None):
-    ctx = reg.EmitContext(None, is_test=False)
-    monkeypatch.setattr(reg.EmitContext, "target_platform",
-                        lambda self: platform)
-    ctx.mesh = mesh
-    return ctx
 
 
 @pytest.mark.parametrize("refusal", [

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _with_vjp
+from _kernel_refs import _f32, _with_vjp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import llm_ops
 from paddle_tpu.ops import registry as reg
@@ -24,10 +24,6 @@ def _operands(B, T, D, L, dtype=jnp.float32, seed=0):
     return (jnp.asarray(rs.randn(B, T, 3 * D), dtype),
             jnp.asarray(rs.randn(D, L), jnp.float32),
             jnp.asarray(rs.randn(B, T, D), dtype))
-
-
-def _f32(a):
-    return np.asarray(a.astype(jnp.float32))
 
 
 # T, D, the rows a grid step, the lanes a chunk
@@ -334,6 +330,7 @@ def test_short_conv_op_takes_the_kernels_on_a_tpu(monkeypatch):
 # shape (no whole step: tests/benchmarks/test_lfm2_cell.py compiles that)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("kernel", [K.FWD, K.BWD])
 def test_short_conv_kernels_compile_for_a_v5e_at_the_cells_shape(kernel,
                                                                  v5e):

@@ -13,13 +13,7 @@ import paddle_tpu as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import moe_ops, registry as reg
-from _kernel_refs import _rand, _with_vjp
-
-
-def _series(family):
-    fam = obs.REGISTRY.snapshot()["families"].get(family, {"series": []})
-    return {tuple(sorted(s["labels"].items())): s["value"]
-            for s in fam["series"]}
+from _kernel_refs import _by_labels as _series, _rand, _with_vjp
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _rand, _startup
+from _kernel_refs import _by_labels as _series, _rand, _startup
 from op_test import OpTestHarness
 from paddle_tpu.models import transformer as tr
 from paddle_tpu.ops import llm_ops
@@ -287,14 +287,6 @@ def test_hyper_connection_post_and_sum_grads_are_the_numeric_ones():
         ["X", "Y", "HPost", "HRes"], max_relative_error=1e-2)
     OpTestHarness("hyper_connection_sum", {"X": ins["X"]}, {}).check_grad(
         ["X"], max_relative_error=1e-2)
-
-
-def _series(family):
-    from paddle_tpu import observability as obs
-
-    fam = obs.REGISTRY.snapshot()["families"].get(family)
-    return {tuple(sorted(s["labels"].items())): s["value"]
-            for s in (fam["series"] if fam else [])}
 
 
 def _hc_sublayer_step(x, y_gain, params, attrs):
