@@ -2,7 +2,7 @@
 
 The transpilers in this repo (`memory_optimization_transpiler`,
 `inference_transpiler`, `distributed/distribute_transpiler`,
-`parallel/transpiler`) all mutate `Program` descs; this package is the
+`parallel/partitioner`) all mutate `Program` descs; this package is the
 well-formedness layer between them — the role TVM's pass-infra validation
 and TensorFlow's pre-execution graph checks play (PAPERS.md).
 
@@ -11,21 +11,22 @@ and TensorFlow's pre-execution graph checks play (PAPERS.md).
     report.raise_if_errors()
 
 Layers:
-  dataflow.py  — def-use chains, happens-before graph, live intervals,
-                 donation state classes
+  (framework/dataflow.py, below this package because the executor runs it:
+                 def-use chains, happens-before graph, live intervals,
+                 donation state classes; its functions are re-exported here)
   verifier.py  — the PTV rule engine (stable IDs, severities, suppressions)
   contracts.py — verified-in/verified-out wrappers for the transpilers
   cost.py      — FLOPs/roofline model + predicted step time per chip spec
   memory.py    — static HBM-peak estimator (remat/donation/shard-aware)
-  sharding.py  — logical-axis rules, sharding propagation, reshard/
-                 conflict detection (PTV018-021), comm-aware roofline
+  sharding.py  — sharding propagation of a plan (parallel/partitioner.py
+                 makes one), reshard/conflict detection (PTV018-021),
+                 comm-aware roofline
   equivalence.py — translation validation: ProgramDesc canonicalizer,
                  structural/abstract/differential equivalence proofs
-                 (PTV022-024), plan equivalence for the partitioner
-                 collapse
+                 (PTV022-024)
 """
 
-from .dataflow import (  # noqa: F401
+from ..framework.dataflow import (  # noqa: F401
     dependency_graph,
     def_use,
     happens_before,
@@ -41,6 +42,7 @@ from .verifier import (  # noqa: F401
     VerificationError,
     verify_program,
 )
+from ..framework import executor as _executor
 from . import contracts  # noqa: F401
 from . import cost  # noqa: F401
 from . import memory  # noqa: F401
@@ -52,3 +54,6 @@ from .equivalence import (  # noqa: F401
     prove_equivalent,
     semantic_diff,
 )
+
+# what Executor.run(verify=) and PADDLE_TPU_VERIFY=1 call
+_executor.install_program_verifier(verify_program)

@@ -45,8 +45,9 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional
 
-from .verifier import (Finding, Report, VerificationError,
-                       env_verify_enabled, verify_program)
+from ..framework.executor import env_verify_enabled
+from ..ops.optimizer_ops import OPTIMIZE_OP_TYPES
+from .verifier import Finding, Report, VerificationError, verify_program
 
 _local = threading.local()
 
@@ -320,17 +321,11 @@ def verify_distribute_result(transpiler):
     _verify(transpiler.program, "distribute_transpile:out",
             fetch_names=grad_names, check_shapes=False)
     remaining = [op.type for b in transpiler.program.blocks for op in b.ops
-                 if op.type in _optimize_op_types()]
+                 if op.type in OPTIMIZE_OP_TYPES]
     if remaining:
         raise VerificationError("distribute_transpile:out", [Finding(
             "PTV014", f"optimizer ops {remaining} survived the split — "
             f"the pserver would double-apply updates")])
-
-
-def _optimize_op_types():
-    from ..distributed.distribute_transpiler import OPTIMIZE_OP_TYPES
-
-    return OPTIMIZE_OP_TYPES
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ from __future__ import annotations
 import os
 from typing import Dict, Optional
 
-from ..ops.registry import ShapeDtype, has_op, get_op_info
+from ..ops.registry import ShapeDtype, dtype_bytes, get_op_info, has_op
 from . import memory as _mem
 
 # Public per-chip peak numbers (dense bf16 matmul TFLOP/s, HBM GB/s and
@@ -135,7 +135,7 @@ def op_cost(block, op, batch_size: int = 64) -> dict:
         for n, sd in zip(names, ins[slot]):
             if n and n not in seen and sd is not None:
                 seen.add(n)
-                read += sd.size * _mem.dtype_bytes(sd.dtype)
+                read += sd.size * dtype_bytes(sd.dtype)
     written = 0
     out_elems = 0
     dtype = None
@@ -144,7 +144,7 @@ def op_cost(block, op, batch_size: int = 64) -> dict:
         for n, sd in zip(names, outs[slot]):
             if n and sd is not None:
                 known_out = True
-                written += sd.size * _mem.dtype_bytes(sd.dtype)
+                written += sd.size * dtype_bytes(sd.dtype)
                 out_elems += sd.size
                 if dtype is None and str(sd.dtype).startswith(
                         ("float", "bfloat")):

@@ -2,10 +2,9 @@
 thing as the original.
 
 Every desc-rewriting pass in this repo (memory_optimize, the conv+BN
-fold, the distribute split, io.prune, future fusion passes and the
-ROADMAP #2 partitioner collapse) so far ran under *invariant* contracts
-(analysis/contracts.py): the output is well-formed, specific properties
-hold.  Invariants bound the damage; they do not establish that the
+fold, the distribute split, io.prune, future fusion passes) so far ran
+under *invariant* contracts (analysis/contracts.py): the output is
+well-formed, specific properties hold.  Invariants bound the damage; they do not establish that the
 rewrite preserved semantics.  This module adds the classic compiler
 answer — translation validation (TVM validates graph rewrites against
 reference semantics; TensorFlow's graph transformations carry the same
@@ -43,17 +42,6 @@ Failures surface as verifier findings with stable IDs: PTV022
 subgraph / missed CSE, info — found during canonicalization and by
 `verify_program`), PTV024 (differential-test fetch divergence,
 error).  `python -m paddle_tpu diff a b` is the CLI face.
-
-**Plan equivalence** (`mode_plan_equivalence`) applies the same stance
-to sharding plans: for each dryrun parallelism mode
-(parallel/modes.py) the bespoke wiring's plan + propagated collective
-footprint (analysis/sharding.py) is compared against a logical-axis
-RULE declaration of the same mode (`LogicalPartitioner` +
-`standard_logical_axis_rules`).  A mode is PROVEN when specs and
-collective footprints agree; otherwise the report carries the concrete
-per-var spec diff and per-kind collective delta — the go/no-go
-artifact that de-risks collapsing the 11 modes into rule declarations
-(ROADMAP #2).
 """
 
 from __future__ import annotations
@@ -65,8 +53,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..framework import dataflow
 from ..framework.core import Program
-from . import dataflow
 
 # ---------------------------------------------------------------------------
 # canonical form
@@ -977,387 +965,3 @@ def prove_equivalent(before, after, feed_names=None, fetch_names=None, *,
         return EquivalenceProof(False, "differential", findings, diff,
                                 detail)
     return EquivalenceProof(True, "differential", findings, diff, detail)
-
-
-# ---------------------------------------------------------------------------
-# plan equivalence: archived bespoke mode wiring vs logical-axis rules
-
-
-def _norm_spec(sharding, ndim=None) -> tuple:
-    from .sharding import spec_of
-
-    spec = spec_of(sharding, ndim)
-    while spec and spec[-1] is None:
-        spec = spec[:-1]
-    return spec
-
-
-def _json_spec(spec) -> list:
-    """JSON-comparable form of a normalized spec (tuples -> lists)."""
-    return [list(e) if isinstance(e, tuple) else e for e in spec]
-
-
-def golden_mode_plans() -> Optional[dict]:
-    """The archived per-mode plans of the DELETED bespoke wiring
-    (parallel/mode_plans_golden.json, captured at the last commit where
-    it existed).  None when the archive is absent."""
-    import json
-    import os
-
-    from .. import parallel as _parallel
-
-    path = os.path.join(os.path.dirname(_parallel.__file__),
-                        "mode_plans_golden.json")
-    if not os.path.exists(path):
-        return None
-    with open(path) as f:
-        return json.load(f)
-
-
-def capture_golden_mode_plans(path: str, batch_size: int = 8) -> dict:
-    """Re-archive the CURRENT rule-driven plans as the golden baseline
-    (tools/hlo_analysis.py equiv --capture-golden).  Only legitimate
-    when the live sweep is 11/11 PROVEN against the existing golden —
-    the archive's whole point is to pin the deleted wiring's output, so
-    regeneration must be an explicit, reviewed act."""
-    import json
-
-    from ..parallel import modes as pmodes
-    from .sharding import propagate
-
-    doc = {
-        "_comment": (
-            "Archived per-mode sharding plans: the prove_equivalent "
-            "baseline for the deleted bespoke partitioner wiring "
-            "(ISSUE 19 / ROADMAP #1).  mode_plan_equivalence judges the "
-            "live rule-driven plan against these specs and collective "
-            "footprints.  Regenerate ONLY via `tools/hlo_analysis.py "
-            "equiv --capture-golden` after a PROVEN sweep."),
-        "modes": {},
-    }
-    for name in pmodes.MODE_NAMES:
-        mode, program, _loss = pmodes.build_mode(name)
-        mesh, plan, provenance = pmodes.mode_plan(mode, program)
-        block = program.global_block()
-        specs = {}
-        for var in sorted(plan):
-            v = block._find_var_recursive(var)
-            ndim = len(v.shape) if v is not None and v.shape else None
-            specs[var] = _json_spec(_norm_spec(plan.get(var), ndim))
-        ana = propagate(program, mesh=mesh, plan=plan,
-                        batch_size=batch_size, provenance=provenance)
-        doc["modes"][name] = {
-            "mesh": dict(mode.mesh_axes),
-            "batch_size": batch_size,
-            "specs": specs,
-            "provenance": {k: str(v) for k, v in provenance.items()},
-            "per_kind": ana.per_kind(),
-        }
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=1, sort_keys=True)
-        f.write("\n")
-    return doc
-
-
-def mode_plan_equivalence(name: str, batch_size: int = 8) -> dict:
-    """Prove one dryrun parallelism mode's live plan equal to the
-    archived output of the deleted bespoke wiring: per-var specs AND
-    the propagated collective footprint (kind -> count/bytes).
-
-    Three-way check (ROADMAP #1 prove_equivalent obligation for the
-    partitioner collapse, PTV022-024 stance):
-
-      1. live ParallelExecutor plan vs golden archive -> `spec_diffs`
-         (did deleting the wiring change any var's sharding?)
-      2. live executor plan vs a bare LogicalPartitioner over the same
-         rule table -> `executor_diffs` (is the executor really just
-         the rule table — any drift means bespoke logic regrew)
-      3. live propagated comm footprint vs archived footprint ->
-         `comm` delta (same collectives, same wire bytes)
-
-    Verdict "PROVEN" only when all three agree and the rule table had
-    no conflicts.  Without the archive (golden=False) the check
-    degrades to 2+3 live-vs-live."""
-    from ..parallel import modes as pmodes
-    from .sharding import propagate
-
-    mode, program, loss_name = pmodes.build_mode(name)
-    mesh, plan, provenance = pmodes.mode_plan(mode, program)
-    lp, lplan = pmodes.logical_plan(mode, program, mesh)
-
-    golden_doc = golden_mode_plans()
-    golden = None
-    if golden_doc is not None:
-        entry = golden_doc.get("modes", {}).get(name)
-        if entry is not None and entry.get("batch_size") == batch_size:
-            golden = entry
-
-    block = program.global_block()
-
-    def live_spec(p, var):
-        v = block._find_var_recursive(var)
-        ndim = len(v.shape) if v is not None and v.shape else None
-        return _json_spec(_norm_spec(p.get(var), ndim))
-
-    executor_diffs = []
-    for var in sorted(set(plan) | set(lplan)):
-        sa, sb = live_spec(plan, var), live_spec(lplan, var)
-        if sa != sb:
-            executor_diffs.append({
-                "var": var, "executor": sa, "logical": sb,
-                "rule": provenance.get(var, "axis rule"),
-            })
-
-    spec_diffs = []
-    if golden is not None:
-        gspecs = golden.get("specs", {})
-        gprov = golden.get("provenance", {})
-        for var in sorted(set(plan) | set(gspecs)):
-            sl = live_spec(plan, var)
-            sg = list(gspecs.get(var, []))
-            if sl != sg:
-                spec_diffs.append({
-                    "var": var, "bespoke": sg, "logical": sl,
-                    "bespoke_rule": gprov.get(var, "transpiler default"),
-                })
-
-    ana = propagate(program, mesh=mesh, plan=plan,
-                    batch_size=batch_size, provenance=provenance)
-    pk_l = ana.per_kind()
-    if golden is not None:
-        pk_b = {k: dict(v) for k, v in golden.get("per_kind", {}).items()}
-    else:
-        ana_b = propagate(program, mesh=mesh, plan=lplan,
-                          batch_size=batch_size)
-        pk_b = ana_b.per_kind()
-    comm_delta = {}
-    for kind in sorted(set(pk_b) | set(pk_l)):
-        b = pk_b.get(kind, {"count": 0, "bytes": 0})
-        l = pk_l.get(kind, {"count": 0, "bytes": 0})
-        if dict(b) != dict(l):
-            comm_delta[kind] = {
-                "bespoke": dict(b), "logical": dict(l),
-                "bytes_delta": int(b["bytes"]) - int(l["bytes"])}
-
-    proven = (not spec_diffs and not executor_diffs and not comm_delta
-              and not lp.conflicts)
-    return {
-        "mode": name,
-        "mesh": dict(mode.mesh_axes),
-        "verdict": "PROVEN" if proven else "DIVERGED",
-        "golden": golden is not None,
-        "spec_diffs": spec_diffs,
-        "executor_diffs": executor_diffs,
-        "rule_conflicts": list(lp.conflicts),
-        "comm": {"bespoke": pk_b, "logical": pk_l, "delta": comm_delta},
-        "pipeline": bool(mode.pipeline),
-    }
-
-
-def plan_equivalence_report(names: Optional[Sequence[str]] = None,
-                            batch_size: int = 8) -> List[dict]:
-    """The 11-mode plan-equivalence sweep (tools/hlo_analysis.py
-    `equiv` mode emits this as JSON)."""
-    from ..parallel import modes as pmodes
-
-    return [mode_plan_equivalence(n, batch_size=batch_size)
-            for n in (names or pmodes.MODE_NAMES)]
-
-
-def hybrid_parity_report(batch_size: int = 8) -> dict:
-    """2-slice simulated-DCN run vs single-slice, judged by the
-    differential oracle at BITWISE tolerance (rtol=atol=0).
-
-    Both sides run the same Momentum-MLP training step with
-    cross-replica weight-update sharding active (`zero_dp_states=True`,
-    arXiv:2004.13336): side A on a flat `{dp: 8}` mesh, side B on a
-    `make_hybrid_mesh({dp: 4}, {dcn_dp: 2})` multi-slice mesh whose
-    batch and state0 dims shard over the ``("dcn_dp", "dp")`` tuple.
-    Same 8 devices in the same order → XLA lowers identical collectives
-    → every fetch and every written state value (params AND sharded
-    velocities) must match bit-for-bit.  The record also publishes the
-    analyzer's predicted wire bytes per link class for both layouts —
-    the bench artifact for the ICI-reduce-scatter → DCN-all-reduce →
-    ICI-all-gather decomposition."""
-    from ..parallel import modes as pmodes
-    from ..parallel.mesh import make_hybrid_mesh
-    from ..parallel.parallel_executor import ParallelExecutor
-    from .sharding import comm_report, propagate, spec_of
-
-    pmodes.ensure_virtual_devices(8)
-    mode, program, loss_name = pmodes.build_mode("dp")
-    block = program.global_block()
-    feed_names = sorted(n for n, v in block.vars.items() if v.is_data)
-
-    exe_a = ParallelExecutor(axes={"dp": 8}, zero_dp_states=True)
-    mesh_b = make_hybrid_mesh({"dp": 4}, {"dcn_dp": 2})
-    exe_b = ParallelExecutor(mesh=mesh_b, zero_dp_states=True)
-
-    findings = differential_run(
-        program, program, feed_names, [loss_name],
-        batch_size=batch_size, rtol=0.0, atol=0.0,
-        executor_a=exe_a, executor_b=exe_b)
-
-    def link_report(exe):
-        prov: Dict[str, str] = {}
-        plan = exe.static_plan(program, provenance=prov)
-        ana = propagate(program, mesh=exe.mesh, plan=plan,
-                        batch_size=batch_size, provenance=prov)
-        rep = comm_report(ana)
-        return plan, {
-            "per_kind": ana.per_kind(),
-            "link_bytes": rep["link_bytes"],
-            "ici_time_s": rep["ici_time_s"],
-            "dcn_time_s": rep["dcn_time_s"],
-            "decomposed": [e["decomposed"] for e in rep["breakdown"]
-                           if "decomposed" in e],
-        }
-
-    plan_a, comm_a = link_report(exe_a)
-    plan_b, comm_b = link_report(exe_b)
-    velocity_specs = {
-        n: [list(e) if isinstance(e, tuple) else e
-            for e in spec_of(s)]
-        for n, s in sorted(plan_b.items()) if "velocity" in n}
-    return {
-        "analysis": "hybrid_parity",
-        "mesh_single": {"dp": 8},
-        "mesh_hybrid": {"dcn_dp": 2, "dp": 4},
-        "weight_update_sharding": True,
-        "bitwise": not findings,
-        "verdict": "PROVEN" if not findings else "DIVERGED",
-        "findings": [f.format() for f in findings],
-        "fetches": [loss_name],
-        "velocity_specs_hybrid": velocity_specs,
-        "comm": {"single": comm_a, "hybrid": comm_b},
-    }
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 20: fused K-step dispatch vs K sequential dispatches
-
-
-def _loop_models():
-    """The two loop-parity obligations: a Momentum-MLP (hidden layer +
-    velocity state, the smallest real training step) and the standing
-    small decoder LM (attention, layernorm, Adam moments — the stateful
-    stochastic program family step_loop must not perturb)."""
-    from ..framework import unique_name
-    from ..framework.core import Program, program_guard
-
-    def mlp():
-        import paddle_tpu as fluid
-
-        x = fluid.layers.data(name="x", shape=[16])
-        y = fluid.layers.data(name="y", shape=[1])
-        h = fluid.layers.fc(x, size=32, act="relu")
-        pred = fluid.layers.fc(h, size=1)
-        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
-        fluid.optimizer.Momentum(learning_rate=0.01,
-                                 momentum=0.9).minimize(loss)
-        return loss.name, ["x", "y"]
-
-    def small_lm():
-        from ..models import standing
-
-        feed, fetches, _bs = standing.build_small_lm()
-        return _name_of(fetches[0]), sorted(feed)
-
-    for kind, build in (("mlp", mlp), ("small_lm", small_lm)):
-        main, startup = Program(), Program()
-        with unique_name.guard(), program_guard(main, startup):
-            loss_name, feed_names = build()
-        yield kind, main, startup, loss_name, feed_names
-
-
-def _name_of(f):
-    return f if isinstance(f, str) else f.name
-
-
-def loop_parity_report(ks: Sequence[int] = (1, 2, 4, 8),
-                       batch_size: int = 4) -> dict:
-    """K-step fused dispatch (`Executor.run(steps_per_dispatch=K)`,
-    framework/step_loop.py) vs K sequential `run()` calls, judged at
-    BITWISE tolerance on every per-step fetch AND every written-back
-    state value (params, velocities, Adam moments).
-
-    Both sides start from an identical copy of the startup-initialized
-    state and see the same K deterministic feed batches (`build_feeds`
-    seeded per step); the sequential side pins `rng_step=i`, the fused
-    side `rng_step=0` with the on-device `fold_in(base, step0+i)`
-    stream — so agreement proves the fused loop IS K steps, RNG
-    included, not merely close.  The run_tests.sh `loop` gate consumes
-    the verdict (PROVEN required)."""
-    from ..analysis import dataflow
-    from ..framework.executor import Executor
-    from ..framework.place import CPUPlace
-    from ..framework.scope import Scope
-
-    cases = []
-    for kind, main, startup, loss_name, feed_names in _loop_models():
-        block = main.global_block()
-        ext, rw, written = dataflow.state_classes(block, feed_names)
-        exe = Executor(CPUPlace())
-        for k in ks:
-            k = int(k)
-            sa, sb = Scope(), Scope()
-            exe.run(startup, scope=sa, verify=False)
-            for n in set(ext) | set(rw):
-                v = sa.find(n)
-                if v is not None:
-                    sb.set(n, np.array(np.asarray(v)))
-            feeds = [build_feeds(main, feed_names, batch_size, seed=i)
-                     for i in range(k)]
-            # K=1 is the identity path (no stacking in, none out): its
-            # "parity" is plain run-to-run determinism
-            stacked = (feeds[0] if k == 1 else
-                       {n: np.stack([f[n] for f in feeds])
-                        for n in feed_names})
-            seq = [np.asarray(exe.run(main, feed=feeds[i],
-                                      fetch_list=[loss_name], scope=sb,
-                                      rng_step=i, verify=False)[0])
-                   for i in range(k)]
-            fused = np.asarray(exe.run(
-                main, feed=stacked, fetch_list=[loss_name], scope=sa,
-                rng_step=0, verify=False, steps_per_dispatch=k)[0])
-            findings = []
-            if k > 1 and tuple(fused.shape[:1]) != (k,):
-                findings.append(
-                    f"fetch {loss_name!r} not stacked (K, ...): "
-                    f"{fused.shape}")
-            for i in range(k):
-                a = fused[i] if k > 1 else fused
-                if a.shape != seq[i].shape or not np.array_equal(a, seq[i]):
-                    findings.append(
-                        f"fetch {loss_name!r} step {i} diverged: "
-                        f"fused={a!r} sequential={seq[i]!r}")
-            for n in written:
-                a, b = np.asarray(sa.find(n)), np.asarray(sb.find(n))
-                if a.shape != b.shape:
-                    findings.append(
-                        f"written state {n!r} shape diverged: "
-                        f"{a.shape} vs {b.shape}")
-                elif not np.array_equal(a, b):
-                    d = np.max(np.abs(a.astype(np.float64)
-                                      - b.astype(np.float64)))
-                    findings.append(
-                        f"written state {n!r} diverged after {k} steps: "
-                        f"max|a-b|={d:.3e}")
-            cases.append({
-                "model": kind, "k": k,
-                "fetches": [loss_name],
-                "written_state": len(written),
-                "bitwise": not findings,
-                "findings": findings,
-            })
-    all_ok = all(c["bitwise"] for c in cases)
-    return {
-        "analysis": "loop_parity",
-        "ks": [int(k) for k in ks],
-        "batch_size": int(batch_size),
-        "models": sorted({c["model"] for c in cases}),
-        "cases": cases,
-        "bitwise": all_ok,
-        "verdict": "PROVEN" if all_ok else "DIVERGED",
-        "findings": [f for c in cases for f in c["findings"]],
-    }

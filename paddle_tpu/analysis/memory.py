@@ -34,22 +34,14 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from . import dataflow
+from ..framework import dataflow
+from ..ops.registry import dtype_bytes
 
 
 def bind_shape(shape, batch_size: int):
     """-1/None dims (feed-time batch axes) bound to `batch_size`."""
     return tuple(batch_size if (s is None or int(s) < 0) else int(s)
                  for s in shape)
-
-
-def dtype_bytes(dtype) -> int:
-    from ..framework.core import np_dtype
-
-    try:
-        return int(np.dtype(np_dtype(dtype or "float32")).itemsize)
-    except Exception:
-        return 4
 
 
 def var_bytes(var, batch_size: int, divisor: int = 1) -> int:

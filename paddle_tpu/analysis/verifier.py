@@ -19,11 +19,10 @@ mode the reference's InferShape duplication invited).
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-from . import dataflow
+from ..framework import dataflow
 
 ERROR = "error"
 WARNING = "warning"
@@ -117,7 +116,7 @@ RULES: Dict[str, Rule] = {r.id: r for r in [
          "per-device residency by the axis size"),
     Rule("PTV021", "dcn-crossing-collective", WARNING,
          "a collective inside the inner step spans a DCN mesh axis "
-         "('dcn' name prefix, parallel/mesh.py): DCN bandwidth is ~10x "
+         "('dcn' name prefix, paddle_tpu/mesh.py): DCN bandwidth is ~10x "
          "below ICI, so per-step collectives must stay intra-slice"),
     Rule("PTV022", "transpiler-changed-semantics", ERROR,
          "translation validation refuted a rewrite: the canonical forms "
@@ -780,8 +779,3 @@ def verify_program(program, feed_names: Optional[Iterable[str]] = None,
              "vars": sum(len(b.vars) for b in program.blocks),
              "blocks": len(program.blocks)}
     return Report(kept, stats)
-
-
-def env_verify_enabled() -> bool:
-    """The PADDLE_TPU_VERIFY=1 gate (Executor.run / transpiler contracts)."""
-    return os.environ.get("PADDLE_TPU_VERIFY", "") not in ("", "0")

@@ -452,7 +452,7 @@ def _telemetry_run(args):
     now hold the run."""
     from . import observability as obs
     from .analysis import equivalence as eqv
-    from .analysis.dataflow import state_classes
+    from .framework.dataflow import state_classes
     from .framework.executor import Executor
     from .framework.place import CPUPlace
     from .framework.scope import Scope

@@ -24,6 +24,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..framework.core import Program, default_main_program
+from ..ops.optimizer_ops import OPTIMIZE_OP_TYPES
 from .pserver import ParameterClient
 
 # host-service update rules (pserver.py _OPTIMIZERS) reachable from the
@@ -41,10 +42,6 @@ _OP_TO_CFG = {
                        "beta2": float(a.get("beta2", 0.999)),
                        "epsilon": float(a.get("epsilon", 1e-8))},
 }
-
-OPTIMIZE_OP_TYPES = ("sgd", "momentum", "adagrad", "adam", "adamax",
-                     "adadelta", "decayed_adagrad", "proximal_gd",
-                     "proximal_adagrad", "ftrl", "rmsprop")
 
 
 def _static_lr(lr_var_name, startup_program=None):

@@ -49,7 +49,7 @@ def init_distributed(coordinator: Optional[str] = None,
 def global_mesh(axes=None):
     """Mesh over ALL processes' devices (jax.devices() is global after
     init_distributed)."""
-    from ..parallel.mesh import make_mesh
+    from ..mesh import make_mesh
 
     return make_mesh(axes)
 

@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..ops.registry import np_dtype  # noqa: F401  (emitters read it too)
 from . import unique_name
 
 # ---------------------------------------------------------------------------
@@ -67,14 +68,6 @@ def canonical_dtype(dtype) -> str:
     return _DTYPE_ALIASES[np.dtype(dtype).name]
 
 
-def np_dtype(dtype: str):
-    import jax.numpy as jnp
-
-    if dtype == "bfloat16":
-        return jnp.bfloat16
-    return np.dtype(dtype)
-
-
 # ---------------------------------------------------------------------------
 # Variable
 
@@ -113,32 +106,8 @@ class Variable:
         # never inferred from name prefixes)
         self.accumulator_for: Optional[str] = None
 
-    # -- python operator sugar (fluid exposes the same on Variable) ---------
-    def _binary(self, other, op_type, reverse=False):
-        from ..layers import math_helper
-
-        return math_helper.elementwise_binary(self, other, op_type, reverse)
-
-    def __add__(self, other):
-        return self._binary(other, "elementwise_add")
-
-    def __radd__(self, other):
-        return self._binary(other, "elementwise_add", reverse=True)
-
-    def __sub__(self, other):
-        return self._binary(other, "elementwise_sub")
-
-    def __rsub__(self, other):
-        return self._binary(other, "elementwise_sub", reverse=True)
-
-    def __mul__(self, other):
-        return self._binary(other, "elementwise_mul")
-
-    def __rmul__(self, other):
-        return self._binary(other, "elementwise_mul", reverse=True)
-
-    def __truediv__(self, other):
-        return self._binary(other, "elementwise_div")
+    # python operator sugar (+ - * /): layers/math_helper.py installs it at
+    # import, as the reference's layers/math_op_patch.py does
 
     def __repr__(self):
         return (

@@ -32,6 +32,7 @@ from ..observability.metrics import REGISTRY as _MET, monotime as _monotime
 from ..observability.tracing import TRACER as _TRC, now as _trace_now
 from ..ops.registry import EmitContext, get_op_info
 from .core import Program, Variable, canonical_dtype, np_dtype
+from .dataflow import state_classes
 from .place import Place, default_place
 from .scope import Scope, global_scope
 
@@ -77,6 +78,21 @@ _CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 # ops the lowerer skips: pure-desc markers with no computation
 _NOOP_TYPES = ("feed", "fetch")
+
+# the program verifier `Executor._verify_program` calls: `paddle_tpu.analysis`
+# (a layer above this one; `paddle_tpu/__init__` imports it) installs its
+# `verify_program` here at import
+_program_verifier = None
+
+
+def install_program_verifier(verify_program) -> None:
+    global _program_verifier
+    _program_verifier = verify_program
+
+
+def env_verify_enabled() -> bool:
+    """The PADDLE_TPU_VERIFY=1 gate (Executor.run / transpiler contracts)."""
+    return os.environ.get("PADDLE_TPU_VERIFY", "") not in ("", "0")
 
 
 class OpLoweringError(RuntimeError):
@@ -471,8 +487,6 @@ class Executor:
         scope = scope if scope is not None else global_scope()
 
         if verify is None:
-            from ..analysis.verifier import env_verify_enabled
-
             verify = env_verify_enabled()
         if verify:
             self._verify_program(program, block_id, sorted(feed),
@@ -698,8 +712,6 @@ class Executor:
         scope = scope if scope is not None else global_scope()
 
         if verify is None:
-            from ..analysis.verifier import env_verify_enabled
-
             verify = env_verify_enabled()
         if verify:
             self._verify_program(program, block_id, sorted(feed),
@@ -750,13 +762,15 @@ class Executor:
                tuple(feed_names), tuple(fetch_names))
         if key in self._verified:
             return
-        from ..analysis.verifier import verify_program
-
+        if _program_verifier is None:
+            raise RuntimeError(
+                "Executor.run(verify=True): no program verifier is "
+                "installed (importing paddle_tpu.analysis installs it)")
         # no fetches this call -> no fetch CONTEXT: [] would make the
         # dead-op rule treat every unfetched terminal op as dead weight
-        report = verify_program(program, feed_names=feed_names,
-                                fetch_names=fetch_names or None,
-                                block_id=block_id)
+        report = _program_verifier(program, feed_names=feed_names,
+                                   fetch_names=fetch_names or None,
+                                   block_id=block_id)
         for f in report.warnings:
             logger.warning("program verifier: %s", f.format())
         report.raise_if_errors("Executor.run")
@@ -850,10 +864,8 @@ class Executor:
         """Static pass over the desc: which names are read from the scope and
         which scope/persistable names the block writes (params updated by
         optimizer ops, BN stats, metric states).  The classification lives in
-        analysis/dataflow.state_classes so the donation-safety rules and the
-        HBM estimator price exactly the buffers this executor donates."""
-        from ..analysis.dataflow import state_classes
-
+        dataflow.state_classes so the donation-safety rules and the HBM
+        estimator price exactly the buffers this executor donates."""
         return state_classes(block, feed_names, skip_types=_NOOP_TYPES)
 
     def _emit_ctx(self, rng_key, is_test, program):

@@ -11,7 +11,7 @@ is the whole point (`analysis/cost.step_loop_cost` prices it).
 
 Bitwise contract: the fused loop is provably identical to K sequential
 `run()` calls on every fetch and every written-back state value
-(`analysis/equivalence.loop_parity_report`, gated in run_tests.sh).
+(`loop_parity_report` of tools/hlo_analysis.py, gated in run_tests.sh).
 That hinges on two choices here:
 
   * per-step keys are `fold_in(base, step0 + i)` — the SAME integer
@@ -30,6 +30,8 @@ from __future__ import annotations
 
 import warnings
 from typing import Dict, List, Sequence
+
+from . import dataflow
 
 # fetch_every modes: "all" stacks every step's fetches (K, ...); "last"
 # returns only the final step's (the common training case — loss curves
@@ -53,8 +55,6 @@ def safety_report(program, block_id: int = 0) -> dict:
     sequential dispatches (same results, none of the overhead
     amortization) — see docs/step_loop.md for the full list.
     """
-    from ..analysis import dataflow
-
     block = program.blocks[block_id]
     reasons: List[str] = []
     for i, op in enumerate(block.ops):

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from .ops.optimizer_ops import OPTIMIZE_OP_TYPES
+
 
 def _channel_axis(layout: str, ndim: int) -> int:
     return ndim - 1 if layout in ("NHWC", "NDHWC", "NLC") else 1
@@ -47,8 +49,6 @@ class InferenceTranspiler:
         # (executor.py) plus the full optimizer-op set: an unlisted
         # optimizer slipping through would bake running stats into a
         # program whose batch_norm executes with batch statistics
-        from .distributed.distribute_transpiler import OPTIMIZE_OP_TYPES
-
         block = program.blocks[block_id]
         for op in block.ops:
             if (op.type.endswith("_grad") or op.type == "generic_grad"

@@ -1,3 +1,4 @@
+from . import math_helper  # noqa: F401  (installs Variable's + - * /)
 from .nn import *  # noqa: F401,F403
 from .fluid_compat import *  # noqa: F401,F403
 from .ops import *  # noqa: F401,F403

@@ -573,7 +573,7 @@ def Print(input, first_n=-1, message=None, summarize=-1,
 def get_places(device_count=None, device_type=None):
     """fluid device.py get_places (get_places_op.cc:34): enumerate execution
     places.  Returns real Place objects — under the SPMD design the mesh
-    (parallel/mesh.py) is the multi-device story, so this is for surface
+    (paddle_tpu/mesh.py) is the multi-device story, so this is for surface
     parity and host-side iteration."""
     from ..framework.place import CPUPlace, TPUPlace, default_place
     import jax

@@ -1,8 +1,11 @@
-"""Python-operator sugar on Variables (fluid math_op_patch equivalent)."""
+"""Python-operator sugar on Variables (fluid math_op_patch equivalent):
+importing this module gives `Variable` its `+ - * /`."""
 
 from __future__ import annotations
 
 import numpy as np
+
+from ..framework.core import Variable
 
 
 def elementwise_binary(x, other, op_type, reverse=False):
@@ -27,3 +30,19 @@ def elementwise_binary(x, other, op_type, reverse=False):
         attrs={"axis": -1},
     )
     return out
+
+
+def _operator(op_type, reverse=False):
+    def method(self, other):
+        return elementwise_binary(self, other, op_type, reverse)
+
+    return method
+
+
+Variable.__add__ = _operator("elementwise_add")
+Variable.__radd__ = _operator("elementwise_add", reverse=True)
+Variable.__sub__ = _operator("elementwise_sub")
+Variable.__rsub__ = _operator("elementwise_sub", reverse=True)
+Variable.__mul__ = _operator("elementwise_mul")
+Variable.__rmul__ = _operator("elementwise_mul", reverse=True)
+Variable.__truediv__ = _operator("elementwise_div")

@@ -16,16 +16,21 @@ Axis names:
          analyzer prices its collectives at DCN bandwidth and PTV021
          flags inner-step collectives that cross it
 
-This module is the ONLY place in `paddle_tpu/parallel/` allowed to
-construct `PartitionSpec` literals (enforced by tools/repo_lint.py):
-every other module derives specs through `pspec`/`named`/`replicated`,
-so the sharding analyzer can trust that whatever plan it is handed was
-minted by rules, not ad-hoc tuples.
+This module is the ONLY place in `paddle_tpu/parallel/` and beside it
+allowed to construct `PartitionSpec` literals (enforced by
+tools/repo_lint.py): every other module derives specs through
+`pspec`/`named`/`replicated`, so the sharding analyzer can trust that
+whatever plan it is handed was minted by rules, not ad-hoc tuples.
+
+A leaf of the package (it imports nothing of it): emitters
+(ops/), the partitioner (parallel/) and the sharding analyzer
+(analysis/) all read a mesh's axis sizes and take a spec apart with the
+helpers at the end (`spec_of`, `entry_axes`, `spec_axes`, `spec_divisor`).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 
 def axis_size(mesh, name: str, default: int = 1) -> int:
@@ -136,3 +141,53 @@ def make_hybrid_mesh(ici_axes: Dict[str, int],
     # else simulated DCN: contiguous chunks stand in for slices
     arr = np.asarray(devices).reshape(sizes)
     return Mesh(arr, axis_names=names)
+
+
+# ---------------------------------------------------------------------------
+# taking a spec apart
+
+
+def spec_of(sharding, ndim: Optional[int] = None) -> tuple:
+    """Positional spec tuple from a NamedSharding / PartitionSpec /
+    tuple, padded with None to `ndim` when given."""
+    if sharding is None:
+        entries: tuple = ()
+    else:
+        spec = getattr(sharding, "spec", sharding)
+        try:
+            entries = tuple(spec)
+        except TypeError:
+            entries = ()
+    out = []
+    for e in entries:
+        if isinstance(e, (tuple, list)):
+            e = tuple(a for a in e if a) or None
+            if e is not None and len(e) == 1:
+                e = e[0]
+        out.append(e if e else None)
+    if ndim is not None:
+        out = (out + [None] * ndim)[:ndim]
+    return tuple(out)
+
+
+def entry_axes(entry) -> tuple:
+    if entry is None:
+        return ()
+    if isinstance(entry, (tuple, list)):
+        return tuple(entry)
+    return (entry,)
+
+
+def spec_axes(spec) -> tuple:
+    """Flat mesh-axis names a spec shards over, in dim order."""
+    out = []
+    for e in spec or ():
+        out.extend(entry_axes(e))
+    return tuple(out)
+
+
+def spec_divisor(spec, axis_sizes: Dict[str, int]) -> int:
+    d = 1
+    for a in spec_axes(spec):
+        d *= int(axis_sizes.get(a, 1))
+    return max(d, 1)

@@ -310,7 +310,7 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 
     Under a ParallelExecutor whose mesh has an 'sp' axis > 1, dispatches by
     the `sp_mode` attr: 'ring' (default — K/V chunks rotate over ICI,
-    memory O(T/S), parallel/ring_attention.py) or 'alltoall'
+    memory O(T/S), ops/ring_attention.py) or 'alltoall'
     (Ulysses-style — one all_to_all pair re-shards seq→heads, dense local
     attention; the better trade when heads >= sp and chunks are small).
     Otherwise dense flash-style softmax (XLA fuses it).
@@ -333,8 +333,8 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     backward; absent, the op traces as it always did."""
     import jax.numpy as jnp
 
-    from ..parallel import ring_attention as ra
-    from ..parallel.mesh import axis_size
+    from . import ring_attention as ra
+    from ..mesh import axis_size
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     layout = str(attrs.get("layout", "bhtd"))
@@ -1135,7 +1135,7 @@ def beam_search_generate(ctx, ins, attrs):
 # ---------------------------------------------------------------------------
 # analytic cost formulas (analysis/cost.py; mechanism in registry.py)
 
-from .registry import register_cost  # noqa: E402
+from .registry import dtype_bytes, register_cost  # noqa: E402
 
 
 def _sdpa_cost(ins, outs, attrs):
@@ -1264,7 +1264,6 @@ def _paged_page_copy_cost(ins, outs, attrs):
         return {}
     m = src.shape[0] if len(src.shape) >= 1 else 1
     n_layers, _, n_heads, page, dh = kpool.shape
-    from ..analysis.memory import dtype_bytes
     page_bytes = n_layers * n_heads * page * dh * dtype_bytes(kpool.dtype)
     return {"flops": 0, "bytes": 2 * 2 * m * page_bytes}
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 import functools
 
 from ..observability.metrics import REGISTRY as _MET
-from .registry import register_op
+from .registry import np_dtype, register_op
 
 
 def _jnp():
@@ -237,8 +237,6 @@ def array_read(ctx, ins, attrs):
 @register_op("create_array", grad=None)
 def create_array(ctx, ins, attrs):
     import jax.numpy as jnp
-
-    from ..framework.core import np_dtype
 
     shape = [int(s) for s in attrs["shape"]]  # [cap, ...]
     if any(s < 0 for s in shape):  # batch-dim element shape: size from Ref

@@ -447,7 +447,7 @@ def latent_attention(ctx, ins, attrs):
     attention."""
     import jax.numpy as jnp
 
-    from ..parallel.ring_attention import attention as dense_attention
+    from .ring_attention import attention as dense_attention
     from .attention_ops import flash_single_chip
 
     x = ins["X"][0]

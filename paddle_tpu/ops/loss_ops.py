@@ -480,7 +480,7 @@ def _swce_sharding(ctx, ins, outs, attrs):
     """Softmax-with-cross-entropy over a vocab-sharded logits tensor
     pays the log-softmax max+sum reductions over the sharded dim (two
     row-shaped all-reduces); row-sharded (batch) logits are free."""
-    from ..analysis.sharding import entry_axes
+    from ..mesh import entry_axes
 
     logits = ins.get("Logits", [None])[0]
     loss = outs.get("Loss", [None])[0]

@@ -607,7 +607,7 @@ def _batch_norm_sharding(ctx, ins, outs, attrs):
     """Training-mode batch statistics are means over the (sharded)
     batch: GSPMD all-reduces the per-channel mean and variance over the
     batch axes.  Channel-shaped buffers stay replicated."""
-    from ..analysis.sharding import entry_axes
+    from ..mesh import entry_axes
 
     x = ins.get("X", [None])[0]
     y = outs.get("Y", [None])[0]

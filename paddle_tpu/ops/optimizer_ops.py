@@ -22,6 +22,15 @@ _MET_UPDATE_BYTES = _MET.counter(
     "grad (read; apart, since a gradient fused into its consumer never "
     "touches HBM)")
 
+# the ops that apply a gradient to a parameter: what the pserver split moves
+# off the trainer (distributed/distribute_transpiler.py) and what marks a
+# program as a training program (inference_transpiler.py).  A list, not the
+# registrations below: `adam_beta_pow_update` and `average_accumulates`
+# update state of their own and stay where those two passes find them.
+OPTIMIZE_OP_TYPES = ("sgd", "momentum", "adagrad", "adam", "adamax",
+                     "adadelta", "decayed_adagrad", "proximal_gd",
+                     "proximal_adagrad", "ftrl", "rmsprop")
+
 
 def _jnp():
     import jax.numpy as jnp

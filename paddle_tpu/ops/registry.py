@@ -110,6 +110,24 @@ def register_op(type: str, emit: Callable = None, **kw):
     return _do
 
 
+def np_dtype(dtype: str):
+    """The numpy/JAX dtype of a canonical dtype string of the IR."""
+    import jax.numpy as jnp
+
+    if dtype == "bfloat16":
+        return jnp.bfloat16
+    return np.dtype(dtype)
+
+
+def dtype_bytes(dtype) -> int:
+    """Item size of a dtype (string or numpy/JAX dtype; None and unknown
+    names price as float32)."""
+    try:
+        return int(np.dtype(np_dtype(dtype or "float32")).itemsize)
+    except Exception:
+        return 4
+
+
 class ShapeDtype:
     """Static (shape, dtype) of one op operand, as the cost model sees it:
     batch dims already bound, dtype a canonical string.  The cost-fn

@@ -107,7 +107,7 @@ def _ring_flash_fwd(q, k, v, axis_name: str, S: int, scale: float,
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas_kernels import flash_attention as fa
+    from .pallas_kernels import flash_attention as fa
 
     my = lax.axis_index(axis_name)
     o_acc = jnp.zeros(q.shape, jnp.float32)
@@ -144,7 +144,7 @@ def _ring_flash_bwd(q, k, v, out, lse, do, axis_name: str, S: int,
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas_kernels import flash_attention as fa
+    from .pallas_kernels import flash_attention as fa
 
     my = lax.axis_index(axis_name)
     dq_acc = jnp.zeros(q.shape, jnp.float32)
@@ -219,7 +219,7 @@ def _ring_flash_zigzag_core(q, k, v, axis_name, S, scale, interpret):
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas_kernels import flash_attention as fa
+    from .pallas_kernels import flash_attention as fa
 
     my = lax.axis_index(axis_name)
     B, H, t2x2, D = q.shape
@@ -287,7 +287,7 @@ def _ring_flash_zigzag_bwd(q, k, v, out, lse, do, axis_name, S, scale,
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas_kernels import flash_attention as fa
+    from .pallas_kernels import flash_attention as fa
 
     my = lax.axis_index(axis_name)
     B, H, t2x2, D = q.shape
@@ -436,9 +436,9 @@ def flash_ring_eligible(q, mesh, axis_name: str, causal: bool,
     since r4; `causal`/`is_train` remain parameters so callers keep a
     single gate call site."""
     del causal, is_train  # supported; kept for call-site stability
-    from ..ops.pallas_kernels._common import kernels_enabled
+    from .pallas_kernels._common import kernels_enabled
 
-    from .mesh import axis_size
+    from ..mesh import axis_size
 
     if not kernels_enabled():
         return False
@@ -472,7 +472,7 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sp",
 
     from jax import shard_map
 
-    from .mesh import pspec as P
+    from ..mesh import pspec as P
 
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -483,7 +483,7 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sp",
             "schedule='zigzag' supports causal flash attention "
             "(use_flash=True, causal=True)")
     if use_flash:
-        from .mesh import axis_size
+        from ..mesh import axis_size
 
         S = axis_size(mesh, axis_name)
         if zigzag:
@@ -564,7 +564,7 @@ def _ulysses_body(q, k, v, axis_name: str, causal: bool, scale,
     vh = lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2,
                         tiled=True)
     if use_flash:
-        from ..ops.pallas_kernels import flash_attention as fa
+        from .pallas_kernels import flash_attention as fa
 
         if is_train:
             oh = fa.make_flash_train(causal=causal, scale=scale,
@@ -583,9 +583,9 @@ def flash_ulysses_eligible(q, mesh, axis_name: str) -> bool:
     """Static gate for flash-kernel Ulysses: after the head re-shard the
     local problem is full [B, H/S, T, D] attention, so the kernel's
     contract is just T % 128 == 0 and lane-width D (training included)."""
-    from ..ops.pallas_kernels._common import kernels_enabled
+    from .pallas_kernels._common import kernels_enabled
 
-    from .mesh import axis_size
+    from ..mesh import axis_size
 
     if not kernels_enabled():
         return False
@@ -607,7 +607,7 @@ def ulysses_attention(q, k, v, mesh, axis_name: str = "sp",
 
     from jax import shard_map
 
-    from .mesh import axis_size, pspec as P
+    from ..mesh import axis_size, pspec as P
 
     S = axis_size(mesh, axis_name)
     if q.shape[1] % S:

@@ -7,8 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..framework.core import np_dtype
-from .registry import register_op
+from .registry import np_dtype, register_op
 
 
 def _j():
@@ -453,7 +452,7 @@ def _lookup_table_sharding(ctx, ins, outs, attrs):
     is looked up masked-locally and the output all-reduced over that
     axis (the mp vocab path); a table sharded over the ids' own batch
     axis (FSDP) is all-gathered instead — the calibrated GSPMD pair."""
-    from ..analysis.sharding import entry_axes
+    from ..mesh import entry_axes
 
     w = ins.get("W", [None])[0]
     ids = ins.get("Ids", [None])[0]

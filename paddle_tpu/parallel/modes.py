@@ -5,9 +5,8 @@ imperative phases; every analysis tool that wants to reason about "the
 modes" (the sharding analyzer, tools/hlo_analysis.py comm mode, the CI
 gate in run_tests.sh) needs the same list without copy-pasting model
 code.  Each entry declares how to BUILD the mode's program and how the
-mode SHARDS it (mesh axes + ParallelExecutor flags) — the seed data for
-the ROADMAP #2 logical-axis partitioner refactor: when the modes
-collapse into rule declarations, this table is what they collapse into.
+mode SHARDS it (mesh axes + ParallelExecutor flags): the flags select rows
+of the partitioner's one rule table (parallel/partitioner.py).
 
 Programs are tiny (the dryrun shapes): the point is the sharding
 structure, not the math.  `build()` constructs into the CURRENT default
@@ -305,33 +304,6 @@ def ensure_virtual_devices(n: int = 8):
     return jax.devices()
 
 
-def logical_plan(mode: ParallelMode, program, mesh):
-    """(partitioner, plan): the LOGICAL-AXIS-RULE declaration of `mode`
-    — the same program sharded by `standard_logical_axis_rules` +
-    `LogicalPartitioner` name inference instead of the mode's bespoke
-    wiring.  The translation-validation engine
-    (analysis/equivalence.mode_plan_equivalence) compares this plan and
-    its propagated collective footprint against `mode_plan`'s: a mode
-    whose two plans agree is PROVEN ready for the ROADMAP #2 collapse;
-    a diverging mode's diff documents exactly which rule is missing
-    from the logical table (e.g. the ZeRO-1/FSDP dim-0 reshard, the
-    column-parallel >=128 width threshold)."""
-    from ..analysis.sharding import (LogicalPartitioner,
-                                     standard_logical_axis_rules)
-    from .mesh import mesh_axis_sizes
-
-    if dict(mode.mesh_axes) != mesh_axis_sizes(mesh):
-        raise ValueError(
-            f"mesh axes {mesh_axis_sizes(mesh)} do not match mode "
-            f"{mode.name!r} ({dict(mode.mesh_axes)}) — a mismatched "
-            f"pair would compare the wrong declaration")
-    kw = dict(mode.executor_kwargs)
-    lp = LogicalPartitioner(rules=standard_logical_axis_rules(
-        zero_dp_states=bool(kw.get("zero_dp_states")),
-        fsdp_params=bool(kw.get("fsdp_params"))))
-    return lp, lp.plan(program, mesh)
-
-
 def mode_plan(mode: ParallelMode, program, devices=None):
     """(mesh, plan, provenance) for one mode: the EFFECTIVE shardings
     its executor would constrain, from descs alone.  Pipeline modes
@@ -340,7 +312,7 @@ def mode_plan(mode: ParallelMode, program, devices=None):
     'dp')` — so the static plan declares the same batch-led feeds;
     stage-split params stay replicated in the plan and the analyzer
     prices the stage boundaries via the pipeline_stage markers)."""
-    from .mesh import make_mesh
+    from ..mesh import make_mesh
     from .parallel_executor import ParallelExecutor
 
     mesh = make_mesh(dict(mode.mesh_axes), devices=devices)

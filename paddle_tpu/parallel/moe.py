@@ -77,7 +77,7 @@ def build_moe_train_step(mesh, d_model: int, d_hidden: int, capacity: int,
     from jax.sharding import NamedSharding
     from jax import shard_map
 
-    from .mesh import pspec as P
+    from ..mesh import pspec as P
 
     @partial(shard_map, mesh=mesh,
              in_specs=({"wi": P("ep"), "wo": P("ep"), "gate": P()},

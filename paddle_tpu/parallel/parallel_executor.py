@@ -10,16 +10,16 @@ tensor-sharded weights ('mp'), replicated small state.  XLA GSPMD partitions
 the computation and emits ICI collectives (gradient all-reduce appears
 automatically from the replicated-param + sharded-batch math).
 
-Since the partitioner collapse (ROADMAP #1) the executor holds NO sharding
-logic of its own: the transpiler's logical-axis rule table produces every
-spec — including the ZeRO-1/FSDP dim-0 reshards that used to live here as
-`_maybe_zero_shard` — and the executor only applies the plan (device_put,
-in_shardings/out_shardings, donation).  The `zero_dp_states`/`fsdp_params`
-kwargs survive as rule-table flags (arXiv:2004.13336 cross-replica
-weight-update sharding: the optimizer step runs on the dim-0 shard and
-GSPMD all-gathers params once per step); the deleted wiring's behaviour is
-archived in parallel/mode_plans_golden.json and every mode's rule-driven
-plan is PROVEN equal to it by `analysis.equivalence.mode_plan_equivalence`.
+The executor holds NO sharding logic of its own: the partitioner's
+logical-axis rule table (parallel/partitioner.py) produces every spec —
+the ZeRO-1/FSDP dim-0 reshards included — and the executor only applies the
+plan (device_put, in_shardings/out_shardings, donation).  The
+`zero_dp_states`/`fsdp_params` kwargs are rule-table flags
+(arXiv:2004.13336 cross-replica weight-update sharding: the optimizer step
+runs on the dim-0 shard and GSPMD all-gathers params once per step).  The
+plans of the eleven modes of parallel/modes.py are pinned by the snapshot
+tests/fixtures/mode_plans_golden.json (tests/test_sharding.py,
+tests/test_equivalence.py).
 """
 
 from __future__ import annotations
@@ -33,9 +33,9 @@ from ..framework.executor import Executor
 from ..framework.scope import global_scope
 from ..observability.tracing import TRACER as _TRC, now as _trace_now
 from ..ops.registry import EmitContext
-from . import mesh as mesh_lib
-from .mesh import make_mesh
-from .transpiler import DistributeTranspiler, ShardingRules
+from .. import mesh as mesh_lib
+from ..mesh import make_mesh
+from .partitioner import DistributeTranspiler, ShardingRules
 
 
 class ParallelExecutor(Executor):
@@ -134,9 +134,7 @@ class ParallelExecutor(Executor):
         """The sharding of a leading-stacked (K, ...) feed block: the
         planned per-batch spec with the steps_per_dispatch dim
         unsharded in front (every device sees all K of its slices)."""
-        from .mesh import named
-
-        return named(sharding.mesh, None, *sharding.spec)
+        return mesh_lib.named(sharding.mesh, None, *sharding.spec)
 
     def _prepare_feeds(self, block, feed, stacked: bool = False):
         import jax

@@ -100,7 +100,7 @@ def build_pipeline_train_step(mesh, n_micro: int, width: int,
     from jax.sharding import NamedSharding
     from jax import shard_map
 
-    from .mesh import pspec as P
+    from ..mesh import pspec as P
 
     pp = mesh.shape["pp"]
     dp = mesh.shape.get("dp", 1)

@@ -182,7 +182,7 @@ class ProgramPipeline:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding
 
-        from .mesh import pspec as P
+        from ..mesh import pspec as P
 
         scope = scope or self.scope
         self._param_meta = []  # per stage: list of (name, shape, dtype, off)
@@ -285,7 +285,7 @@ class ProgramPipeline:
         import jax
         import jax.numpy as jnp
         from jax import lax, shard_map
-        from .mesh import pspec as P
+        from ..mesh import pspec as P
 
         batch = next(iter(feed_shapes.values()))[0]
         micro_bs = batch // self.n_micro

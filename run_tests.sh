@@ -22,15 +22,6 @@ JAX_PLATFORMS=cpu python tools/lint_smoke.py
 JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python -m paddle_tpu analyze --sharding > /dev/null
 
-# plan-equivalence gate (ISSUE 19): the 11-mode sweep must be 11/11
-# PROVEN against the archived bespoke plans (the prove_equivalent
-# obligation for the deleted partitioner wiring) — exits 1 on any
-# DIVERGED entry; desc-only, nothing compiles
-JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    python tools/hlo_analysis.py equiv > /dev/null \
-    || { echo "plan-equivalence gate failed: a mode DIVERGED from the \
-archived bespoke plan (rc=$?)"; exit 1; }
-
 # hybrid-mesh parity gate (ISSUE 19): 2-slice simulated-DCN training
 # step must match single-slice BITWISE (differential oracle, rtol=0)
 # with weight-update sharding active; also the bench artifact for

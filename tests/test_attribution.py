@@ -29,7 +29,7 @@ def _lowered_text(program, out_name):
     (framework/executor._lower_ops), with no flag set."""
     import jax
 
-    from paddle_tpu.analysis.dataflow import state_classes
+    from paddle_tpu.framework.dataflow import state_classes
     from paddle_tpu.framework.executor import _lower_ops
     from paddle_tpu.framework.scope import global_scope
     from paddle_tpu.ops.registry import EmitContext

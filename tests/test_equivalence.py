@@ -3,8 +3,8 @@ canonicalizer's algebra (idempotence, alpha/commutativity/order
 invariance), the three proof tiers, the save→load→canonicalize→prove
 round trip over the book models (ISSUE 10 satellite — the orphaned-var
 bug class PR 6 pruned by hand), the four transpiler proof obligations,
-the `paddle_tpu diff` CLI, and the 11-mode plan-equivalence report
-that gates the ROADMAP #2 partitioner collapse."""
+the `paddle_tpu diff` CLI, and the eleven modes' plans against their
+snapshot (tests/_mode_plans.py)."""
 
 import json
 import os
@@ -16,6 +16,9 @@ import paddle_tpu as fluid
 from paddle_tpu.analysis import equivalence as eqv
 from paddle_tpu.analysis import contracts
 from paddle_tpu.framework.core import Program
+from paddle_tpu.parallel import modes as pmodes
+
+from _mode_plans import mode_plan_against_snapshot
 
 
 def _train_mlp(prefix=""):
@@ -452,7 +455,7 @@ def test_sharding_plan_proof_program_unmutated():
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device test mesh")
     from paddle_tpu.parallel import make_mesh
-    from paddle_tpu.parallel.transpiler import (
+    from paddle_tpu.parallel.partitioner import (
         DistributeTranspiler as ShardingTranspiler)
 
     cost, prog = _train_mlp()
@@ -463,33 +466,29 @@ def test_sharding_plan_proof_program_unmutated():
 
 
 # ---------------------------------------------------------------------------
-# plan equivalence: the ROADMAP #2 go/no-go artifact
+# plan equivalence: every mode's plan against the snapshot
 
 
-def test_plan_equivalence_covers_all_modes():
-    """Every catalog mode gets a verdict; PROVEN modes have no diffs,
-    DIVERGED modes carry a concrete explanation (per-var spec diff with
+@pytest.mark.parametrize("name", pmodes.MODE_NAMES)
+def test_plan_equivalence_covers_all_modes(name):
+    """Every catalog mode gets a verdict; a PROVEN mode has no diffs, a
+    DIVERGED mode carries a concrete explanation (per-var spec diff with
     the bespoke rule's provenance, or a collective-footprint delta)."""
-    from paddle_tpu.parallel import modes as pmodes
-
-    report = eqv.plan_equivalence_report()
-    assert [r["mode"] for r in report] == list(pmodes.MODE_NAMES)
-    for r in report:
-        assert r["verdict"] in ("PROVEN", "DIVERGED")
-        if r["verdict"] == "PROVEN":
-            assert not r["spec_diffs"] and not r["comm"]["delta"]
-        else:
-            assert r["spec_diffs"] or r["comm"]["delta"] \
-                or r["rule_conflicts"]
-            for d in r["spec_diffs"]:
-                assert d["var"] and "bespoke" in d and "logical" in d
-                assert d["bespoke_rule"]
+    r = mode_plan_against_snapshot(name)
+    assert r["mode"] == name
+    assert r["verdict"] in ("PROVEN", "DIVERGED")
+    if r["verdict"] == "PROVEN":
+        assert not r["spec_diffs"] and not r["comm"]["delta"]
+    else:
+        assert r["spec_diffs"] or r["comm"]["delta"]
+        for d in r["spec_diffs"]:
+            assert d["var"] and "bespoke" in d and "logical" in d
+            assert d["bespoke_rule"]
     # ISSUE 19: the partitioner collapse is done — the floor is the
     # whole catalog, PROVEN against the golden archive of the deleted
     # bespoke wiring
-    assert all(r["verdict"] == "PROVEN" for r in report), \
-        [(r["mode"], r["verdict"]) for r in report]
-    assert all(r["golden"] for r in report)
+    assert r["verdict"] == "PROVEN", (r["mode"], r["verdict"])
+    assert r["golden"]
 
 
 def test_plan_equivalence_zero_fsdp_gap_closed():
@@ -504,33 +503,10 @@ def test_plan_equivalence_zero_fsdp_gap_closed():
     reopens_pr10_diff and test_fsdp_param_rule_removed_reopens_
     pr10_diff): remove the rule and the archived diff reappears."""
     for name in ("dp_mp", "fsdp"):
-        rec = eqv.mode_plan_equivalence(name)
+        rec = mode_plan_against_snapshot(name)
         assert rec["verdict"] == "PROVEN", (name, rec)
         assert rec["golden"], "golden archive missing"
-        assert not rec["executor_diffs"]  # executor tracks the table
         assert not rec["comm"]["delta"]   # gather-back bytes archived
-
-
-def test_hlo_analysis_equiv_mode_emits_json():
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    out = subprocess.run(
-        [sys.executable, "tools/hlo_analysis.py", "equiv", "--mode",
-         "dp"], capture_output=True, text=True, timeout=240,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env)
-    assert out.returncode == 0, out.stderr[-1500:]
-    lines = [json.loads(l) for l in out.stdout.splitlines()
-             if l.startswith("{")]
-    assert lines[0]["mode"] == "dp" and lines[0]["verdict"] == "PROVEN"
-    assert lines[-1]["analysis"] == "plan_equivalence_summary"
-
-
-test_hlo_analysis_equiv_mode_emits_json = pytest.mark.slow(
-    test_hlo_analysis_equiv_mode_emits_json)
 
 
 # ---------------------------------------------------------------------------

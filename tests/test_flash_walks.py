@@ -12,7 +12,7 @@ import pytest
 from _kernel_refs import (_assert_named, _dense_f32, _eqns, _flash_results,
                           _with_vjp)
 from paddle_tpu.ops.pallas_kernels.flash_attention import flash_attention
-from paddle_tpu.parallel.ring_attention import attention
+from paddle_tpu.ops.ring_attention import attention
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.parallel.ring_attention import attention
+from paddle_tpu.ops.ring_attention import attention
 
 
 def test_sdp_op_dispatches_flash_on_tpu_inference(monkeypatch):
@@ -39,7 +39,7 @@ def test_sdp_op_dispatches_flash_on_tpu_inference(monkeypatch):
         ctx, {"Q": [q], "K": [q], "V": [q]}, {"causal": True})["Out"][0]
     assert calls == [(1, 2, 128, 16)]
     # numerics match dense
-    from paddle_tpu.parallel.ring_attention import attention
+    from paddle_tpu.ops.ring_attention import attention
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(attention(q, q, q, causal=True)),
                                rtol=2e-5, atol=2e-5)

@@ -167,7 +167,7 @@ def test_forward_emission_alone_stays_differentiable(pallas_on_cpu):
     import jax.numpy as jnp
 
     from paddle_tpu.ops import attention_ops
-    from paddle_tpu.parallel.ring_attention import attention
+    from paddle_tpu.ops.ring_attention import attention
 
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray((rng.randn(1, 2, T, 16) * 0.3)

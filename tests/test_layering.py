@@ -7,7 +7,7 @@ a package may import of its siblings (packages and top-level modules of
 `paddle_tpu`).  `UP` is every edge that points the wrong way and is still
 there, each with the debt of ROADMAP.md that names it, held EXACTLY: a new
 one fails here, and so does a row whose edge has gone (delete the row).
-The next arrow that turns is a diff to this table.
+`UP` has been empty since PR 69.
 """
 
 import ast
@@ -19,46 +19,32 @@ PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "paddle_tpu")
 
 MAY = {
+    # leaves: the standard library and jax only
     "knobs": set(),
     "observability": set(),
-    "ops": {"knobs", "observability"},
+    "mesh": set(),
+    # emitters and kernels, ring attention among them
+    "ops": {"knobs", "observability", "mesh"},
+    # IR, executor, backward, dataflow (the state classes)
     "framework": {"knobs", "observability", "ops", "lod"},
     "layers": {"framework", "ops", "lod"},
     "models": {"framework", "layers", "lod", "nets", "optimizer"},
-    "analysis": {"framework", "ops", "memory_optimization_transpiler",
-                 "inference_transpiler"},
-    "parallel": {"observability", "framework", "ops", "models", "analysis"},
-    "distributed": {"observability", "framework", "analysis", "parallel",
-                    "io", "memory_optimization_transpiler"},
+    # verifier, cost, memory, propagation, equivalence, contracts
+    "analysis": {"mesh", "framework", "ops",
+                 "memory_optimization_transpiler", "inference_transpiler"},
+    # the partitioner (the ONE sharding rule), ParallelExecutor, modes,
+    # pipeline
+    "parallel": {"observability", "mesh", "framework", "ops", "models",
+                 "analysis"},
+    "distributed": {"observability", "mesh", "ops", "framework", "analysis",
+                    "parallel", "io", "memory_optimization_transpiler"},
     "serving": {"knobs", "observability", "framework", "layers", "analysis"},
 }
 
-UP = {
-    # D15: two emitters take `np_dtype` from framework/core.py
-    # (ops/tensor_ops.py, ops/control_flow_ops.py)
-    ("ops", "framework"),
-    # D15: emitters ask analysis/memory `dtype_bytes` and analysis/sharding
-    # `entry_axes` (attention_ops, tensor_ops, loss_ops, nn_ops)
-    ("ops", "analysis"),
-    # D15: ring attention and the mesh helpers live in parallel/ and are
-    # called from emitters (ops/attention_ops.py, ops/llm_ops.py)
-    ("ops", "parallel"),
-    # D15: the executor runs the verifier and the memory planner
-    # (framework/executor.py), the step loop reads analysis/dataflow
-    ("framework", "analysis"),
-    # D15: Variable's operators build layers (framework/core.py ->
-    # layers/math_helper)
-    ("framework", "layers"),
-    # D15: the sharding analysis and the equivalence proofs build meshes and
-    # run the partitioner (analysis/sharding.py, analysis/equivalence.py)
-    ("analysis", "parallel"),
-    # D15: the loop-parity proof builds models/standing's small LM
-    # (analysis/equivalence.py)
-    ("analysis", "models"),
-    # D15: the transpiler's contract reads its op types
-    # (analysis/contracts.py -> distributed/distribute_transpiler)
-    ("analysis", "distributed"),
-}
+# every edge that still points the wrong way, as (from, to) with the debt of
+# ROADMAP.md that names it.  Empty since PR 69 (D15); the mechanism stays: a
+# new up-edge fails here until it is either turned or written down.
+UP = set()
 
 
 def _modules(unit):

@@ -43,7 +43,7 @@ def _olmoe_helper():
 def _dense_gqa(q, k, v, causal):
     import jax.numpy as jnp
 
-    from paddle_tpu.parallel.ring_attention import attention as dense
+    from paddle_tpu.ops.ring_attention import attention as dense
 
     group = q.shape[1] // k.shape[1]
     return dense(q, jnp.repeat(k, group, axis=1),

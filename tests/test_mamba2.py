@@ -250,7 +250,7 @@ def test_attention_without_the_attr_is_the_parents_jaxpr_and_desc():
 
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops import registry as reg
-    from paddle_tpu.parallel import ring_attention as ra
+    from paddle_tpu.ops import ring_attention as ra
 
     ins, attrs = _sdpa_case()
     q, k, v = (jnp.asarray(ins[s], jnp.float32) for s in "QKV")

@@ -29,7 +29,7 @@ def test_flash_two_widths_matches_dense_forward_and_gradients(causal):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.parallel.ring_attention import attention as dense
+    from paddle_tpu.ops.ring_attention import attention as dense
 
     B, H, T, dqk, dv = 1, 2, 128, 48, 32
     q, k = (jnp.asarray(_rand((B, H, T, dqk), i)) for i in (1, 2))

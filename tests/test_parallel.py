@@ -196,8 +196,8 @@ def test_zero_dp_optimizer_state_sharding():
     — used to be exactly where the bespoke plan diverged from its
     logical-axis declaration.  The logical table now carries it as the
     ("state0", dp) family, the bespoke wiring is deleted, and the mode
-    is PROVEN against the archived plan (parallel/mode_plans_golden
-    .json; `tools/hlo_analysis.py equiv`, 11/11).  test_sharding.py::
+    is PROVEN against the archived plan (tests/fixtures/
+    mode_plans_golden.json; tests/_mode_plans.py).  test_sharding.py::
     test_zero_state_rule_removed_reopens_pr10_diff guards the rule:
     remove it and the archived diff reappears verbatim."""
     import jax
@@ -491,7 +491,7 @@ def test_sharded_checkpoint_roundtrip(tmp_path):
     hazard's rule ("ZeRO-1 accumulator reshard over 'dp' on dim 0") is
     now the ("state0", dp) logical family; the dp×mp mode it used to
     diverge on is PROVEN against the archived bespoke plan
-    (`tools/hlo_analysis.py equiv`, mode dp_mp) and mutation-guarded by
+    (tests/_mode_plans.py, mode dp_mp) and mutation-guarded by
     test_sharding.py::test_zero_state_rule_removed_reopens_pr10_diff."""
     from paddle_tpu.distributed import checkpoint as ckpt
 
@@ -785,7 +785,7 @@ def test_sharded_checkpoint_roundtrip_fsdp(tmp_path):
     hazard's rule ("FSDP/ZeRO-3 parameter shard over 'dp' on dim 0")
     is now the ("param0", dp) logical family; the fsdp mode it used to
     diverge on is PROVEN against the archived bespoke plan
-    (`tools/hlo_analysis.py equiv`, mode fsdp) and mutation-guarded by
+    (tests/_mode_plans.py, mode fsdp) and mutation-guarded by
     test_sharding.py::test_fsdp_param_rule_removed_reopens_pr10_diff."""
     from paddle_tpu.distributed import checkpoint as ckpt
 
@@ -834,9 +834,9 @@ def test_hybrid_two_slice_mesh_bitwise_parity():
 
     Isolated (PTV016 family): both executors donate dp-sharded
     optimizer state."""
-    from paddle_tpu.analysis import equivalence as eqv
+    from tools.hlo_analysis import hybrid_parity_report
 
-    rep = eqv.hybrid_parity_report(batch_size=8)
+    rep = hybrid_parity_report(batch_size=8)
     assert rep["verdict"] == "PROVEN", rep["findings"]
     assert rep["bitwise"] is True
     assert rep["weight_update_sharding"] is True

@@ -7,7 +7,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.parallel import ParallelExecutor, make_mesh
-from paddle_tpu.parallel.ring_attention import attention, ring_attention
+from paddle_tpu.ops.ring_attention import attention, ring_attention
 
 
 def _qkv(B=2, H=4, T=32, D=16, seed=0):
@@ -100,7 +100,7 @@ def test_ulysses_matches_dense(causal):
     """All-to-all sequence parallelism IS dense attention re-sharded: exact
     match (up to float assoc) with the dense reference."""
     from paddle_tpu.parallel import make_mesh
-    from paddle_tpu.parallel.ring_attention import attention, \
+    from paddle_tpu.ops.ring_attention import attention, \
         ulysses_attention
 
     rng = np.random.RandomState(0)
@@ -117,7 +117,7 @@ def test_ulysses_matches_dense(causal):
 
 def test_ulysses_rejects_indivisible_heads():
     from paddle_tpu.parallel import make_mesh
-    from paddle_tpu.parallel.ring_attention import ulysses_attention
+    from paddle_tpu.ops.ring_attention import ulysses_attention
 
     rng = np.random.RandomState(1)
     q = rng.randn(1, 3, 16, 4).astype(np.float32)  # 3 heads, sp=8
@@ -157,7 +157,7 @@ def test_transformer_block_trains_sp_alltoall():
 def test_ring_flash_matches_dense():
     """Flash-kernel ring path (per-chunk Pallas attention + logsumexp
     merge) vs dense — interpret mode on the CPU mesh."""
-    from paddle_tpu.parallel.ring_attention import flash_ring_eligible
+    from paddle_tpu.ops.ring_attention import flash_ring_eligible
 
     mesh = make_mesh({"sp": 2})
     q, k, v = _qkv(B=1, H=2, T=256, D=32)
@@ -175,7 +175,7 @@ def test_ulysses_flash_matches_dense_and_grads():
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.parallel.ring_attention import (flash_ulysses_eligible,
+    from paddle_tpu.ops.ring_attention import (flash_ulysses_eligible,
                                                     ulysses_attention)
 
     mesh = make_mesh({"sp": 2})
@@ -207,7 +207,7 @@ def test_flash_sp_eligibility_gates():
     """The static gates hold the kernel to its contract: non-tile chunks
     and wide heads fall back to dense; causal and training ring are
     eligible since r4 (static per-step schedule + ring-level vjp)."""
-    from paddle_tpu.parallel.ring_attention import (flash_ring_eligible,
+    from paddle_tpu.ops.ring_attention import (flash_ring_eligible,
                                                     flash_ulysses_eligible)
 
     mesh = make_mesh({"sp": 2})
@@ -324,7 +324,7 @@ def test_zigzag_contract_errors():
 def test_zigzag_pre_permuted_path():
     """A layer stack can amortize the layout gathers: permute once with
     zigzag_permutation, run with pre_permuted=True, invert once."""
-    from paddle_tpu.parallel.ring_attention import zigzag_permutation
+    from paddle_tpu.ops.ring_attention import zigzag_permutation
 
     mesh = make_mesh({"sp": 2})
     q, k, v = _qkv(B=1, H=2, T=512, D=32)
@@ -340,7 +340,7 @@ def test_zigzag_pre_permuted_path():
 
 
 def test_zigzag_permutation_roundtrip():
-    from paddle_tpu.parallel.ring_attention import zigzag_permutation
+    from paddle_tpu.ops.ring_attention import zigzag_permutation
 
     perm, inv = zigzag_permutation(16, 2)
     x = np.arange(16)

@@ -14,10 +14,11 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.analysis import sharding as ash
 from paddle_tpu.analysis import verify_program
-from paddle_tpu.analysis.sharding import (AxisNames, LogicalPartitioner,
-                                          logical_to_mesh_axes)
 from paddle_tpu.parallel import ParallelExecutor, ShardingRules, make_mesh
 from paddle_tpu.parallel import modes as pmodes
+from paddle_tpu.parallel import partitioner
+from paddle_tpu.parallel.partitioner import (AxisNames, LogicalPartitioner,
+                                             logical_to_mesh_axes)
 
 
 def _mesh8(axes=None):
@@ -118,7 +119,7 @@ def test_sharding_conflict_flagged_ptv018():
     no device assignment satisfies it."""
     mesh = _mesh8({"dp": 4, "mp": 2})
     cost, prog = _train_mlp()
-    from paddle_tpu.parallel.mesh import named
+    from paddle_tpu.mesh import named
 
     kw = dict(feed_names=["x", "y"], fetch_names=[cost.name],
               check_shapes=False)
@@ -139,7 +140,7 @@ def test_hot_loop_reshard_flagged_ptv019():
     with incompatible specs — the implicit gather is re-paid every
     step.  Feeds resharding once at distribution time stay exempt."""
     mesh = _mesh8({"dp": 4, "mp": 2})
-    from paddle_tpu.parallel.mesh import named
+    from paddle_tpu.mesh import named
 
     a = fluid.layers.data(name="a", shape=[16])
     b = fluid.layers.data(name="b", shape=[16])
@@ -282,7 +283,7 @@ def test_fsdp_gather_and_allreduce_bytes_exact():
     ana = ash.propagate(prog, plan=plan, batch_size=64)
     per = ana.per_kind()
     assert set(per) == {"all-gather", "all-reduce"}
-    from paddle_tpu.analysis.sharding import spec_axes
+    from paddle_tpu.mesh import spec_axes
 
     sharded = 0
     block = prog.global_block()
@@ -414,25 +415,24 @@ def test_mode_catalog_is_the_eleven_dryrun_modes():
 # ISSUE 19: rule-family mutation tests — rule present -> PROVEN against
 # the archived bespoke plans, rule removed -> the exact PR 10 diff
 # reappears.  The mutation swaps `standard_logical_axis_rules` for a
-# filtered table; both the executor's transpiler and the bare
-# LogicalPartitioner read it through late imports, so the two live
-# plans stay consistent and the divergence shows up ONLY against the
-# golden archive — exactly how a silently dropped rule would present.
+# filtered table where the partitioner reads it, so the divergence shows
+# up against the snapshot — exactly how a silently dropped rule would
+# present.
 
 
 def _mutate_rules(monkeypatch, mutate):
-    real = ash.standard_logical_axis_rules
+    real = partitioner.standard_logical_axis_rules
 
     def wrapped(*a, **kw):
         return mutate(list(real(*a, **kw)))
 
-    monkeypatch.setattr(ash, "standard_logical_axis_rules", wrapped)
+    monkeypatch.setattr(partitioner, "standard_logical_axis_rules", wrapped)
 
 
 def _equiv(name):
-    from paddle_tpu.analysis import equivalence as eqv
+    from _mode_plans import mode_plan_against_snapshot
 
-    return eqv.mode_plan_equivalence(name)
+    return mode_plan_against_snapshot(name)
 
 
 @pytest.mark.parametrize("name", ["dp_mp", "fsdp", "sp_ring", "emb_mp",
@@ -443,7 +443,7 @@ def test_rule_family_modes_proven_against_golden(name):
     modes ride the full 11/11 run_tests.sh gate)."""
     _mesh8()
     rec = _equiv(name)
-    assert rec["golden"], "parallel/mode_plans_golden.json missing"
+    assert rec["golden"], "tests/fixtures/mode_plans_golden.json missing"
     assert rec["verdict"] == "PROVEN", rec
 
 
@@ -458,7 +458,6 @@ def test_zero_state_rule_removed_reopens_pr10_diff(monkeypatch):
         if not (r[0] in ("state0", "param0") and r[1] is not None)])
     rec = _equiv("dp_mp")
     assert rec["verdict"] == "DIVERGED"
-    assert not rec["executor_diffs"]  # both live plans lost the rule
     vel = [d for d in rec["spec_diffs"] if "velocity" in d["var"]]
     assert vel, rec["spec_diffs"]
     for d in vel:
@@ -596,7 +595,7 @@ def test_hybrid_mesh_step_link_bytes_per_collective():
     matches the decomposition formula row by row (ICI vs DCN bytes per
     step, the ISSUE 19 exactness contract)."""
     _mesh8()
-    from paddle_tpu.parallel.mesh import make_hybrid_mesh
+    from paddle_tpu.mesh import make_hybrid_mesh
 
     mode, prog, _loss = pmodes.build_mode("dp")
     mesh = make_hybrid_mesh({"dp": 4}, {"dcn_dp": 2})
@@ -622,7 +621,7 @@ def test_hybrid_mesh_step_link_bytes_per_collective():
 
 def test_make_hybrid_mesh_shape_and_prefix_contract():
     _mesh8()
-    from paddle_tpu.parallel.mesh import (dcn_axes, make_hybrid_mesh,
+    from paddle_tpu.mesh import (dcn_axes, make_hybrid_mesh,
                                           mesh_axis_sizes)
 
     mesh = make_hybrid_mesh({"dp": 4}, {"dcn_dp": 2})

@@ -5,7 +5,7 @@ double-buffered input pipeline (`reader.decorator.prefetch`,
 `DataFeeder.feed_stacked` / `DeviceFeeder(steps=K)`), the
 `steps_per_dispatch` knob, and the `cost.step_loop_cost` amortization
 model.  The full PROVEN sweep (K∈{1,2,4,8} × {mlp, small_lm}) lives in
-`analysis.equivalence.loop_parity_report`, gated by run_tests.sh via
+`tools/hlo_analysis.py loop_parity_report`, gated by run_tests.sh via
 `tools/hlo_analysis.py loop`; these tests keep the contract pinned at
 unit scale."""
 
@@ -16,8 +16,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu.analysis import dataflow
 from paddle_tpu.analysis import equivalence as eqv
+from paddle_tpu.framework import dataflow
 from paddle_tpu.framework import step_loop
 from paddle_tpu.framework.scope import Scope
 from paddle_tpu.reader import decorator as rdec

@@ -34,17 +34,11 @@ Usage:
                                          # catalog + the lm_dp/lm_mp/
                                          # lm_fsdp acceptance trio); one
                                          # static-vs-actual JSON line each
-  python tools/hlo_analysis.py equiv [--mode NAME]
-                                         # plan-equivalence sweep
-                                         # (analysis/equivalence.py): each
-                                         # dryrun parallelism mode's
-                                         # bespoke plan + propagated
-                                         # collective footprint vs its
-                                         # logical-axis-rule declaration —
-                                         # the ROADMAP #2 go/no-go
-                                         # artifact; one JSON line per
-                                         # mode, desc-only (nothing
-                                         # compiles)
+  python tools/hlo_analysis.py hybrid   # 2-slice simulated-DCN mesh vs one
+                                         # slice, bitwise (8 virtual devices)
+  python tools/hlo_analysis.py loop [--ks 1,4]
+                                         # K fused steps vs K dispatches,
+                                         # bitwise on fetches and state
   python tools/hlo_analysis.py all   # bytes+collectives, JSON per line
 
 The workload runs in a re-exec'd child with XLA_FLAGS=--xla_dump_to so
@@ -548,7 +542,7 @@ def comm_static(name, batch_size=8):
     from paddle_tpu.analysis import sharding as ash
     from paddle_tpu.parallel import ParallelExecutor
     from paddle_tpu.parallel import modes as pmodes
-    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.mesh import make_mesh
 
     pmodes.ensure_virtual_devices(8)
     build, cfg, _, pipeline = _comm_mode_entry(name)
@@ -685,47 +679,205 @@ def analyze(mode: str, args) -> dict:
     return rec
 
 
-def run_equiv(args) -> None:
-    """The 11-mode plan-equivalence sweep: the live rule-driven plan vs
-    the archived output of the deleted bespoke wiring, one JSON line
-    per mode plus a summary line.  Desc-only (virtual devices, nothing
-    compiles), so it needs no chip.  Exits 1 on any DIVERGED entry: this is run_tests.sh's
-    fast-tier gate against the partitioner collapse regressing.
+# -------------------------------------------------- the two parity procedures
+def hybrid_parity_report(batch_size=8) -> dict:
+    """2-slice simulated-DCN run vs single-slice, judged by the
+    differential oracle at BITWISE tolerance (rtol=atol=0).
 
-    --capture-golden re-archives the CURRENT plans as
-    parallel/mode_plans_golden.json — only after a PROVEN sweep, so the
-    baseline can never be overwritten by a diverged state."""
-    from paddle_tpu.analysis import equivalence as eqv
+    Both sides run the same Momentum-MLP training step with
+    cross-replica weight-update sharding active (`zero_dp_states=True`,
+    arXiv:2004.13336): side A on a flat `{dp: 8}` mesh, side B on a
+    `make_hybrid_mesh({dp: 4}, {dcn_dp: 2})` multi-slice mesh whose
+    batch and state0 dims shard over the ``("dcn_dp", "dp")`` tuple.
+    Same 8 devices in the same order → XLA lowers identical collectives
+    → every fetch and every written state value (params AND sharded
+    velocities) must match bit-for-bit.  The record also publishes the
+    analyzer's predicted wire bytes per link class for both layouts —
+    the bench artifact for the ICI-reduce-scatter → DCN-all-reduce →
+    ICI-all-gather decomposition."""
+    from paddle_tpu.analysis.equivalence import differential_run
+    from paddle_tpu.analysis.sharding import comm_report, propagate
+    from paddle_tpu.mesh import make_hybrid_mesh, spec_of
+    from paddle_tpu.parallel import ParallelExecutor
     from paddle_tpu.parallel import modes as pmodes
 
     pmodes.ensure_virtual_devices(8)
-    names = [args.submode] if args.submode else list(pmodes.MODE_NAMES)
-    proven = 0
-    for name in names:
-        rec = eqv.mode_plan_equivalence(name)
-        rec["analysis"] = "plan_equivalence"
-        proven += rec["verdict"] == "PROVEN"
-        print(json.dumps(rec), flush=True)
-    diverged = len(names) - proven
-    print(json.dumps({"analysis": "plan_equivalence_summary",
-                      "modes": len(names), "proven": proven,
-                      "diverged": diverged}), flush=True)
-    if getattr(args, "capture_golden", False):
-        if diverged or args.submode:
-            print(json.dumps({
-                "analysis": "plan_equivalence_capture",
-                "error": "refusing to re-archive golden plans from a "
-                         "diverged or partial sweep"}), flush=True)
-            sys.exit(1)
-        import paddle_tpu.parallel as _parallel
+    mode, program, loss_name = pmodes.build_mode("dp")
+    block = program.global_block()
+    feed_names = sorted(n for n, v in block.vars.items() if v.is_data)
 
-        path = os.path.join(os.path.dirname(_parallel.__file__),
-                            "mode_plans_golden.json")
-        eqv.capture_golden_mode_plans(path)
-        print(json.dumps({"analysis": "plan_equivalence_capture",
-                          "path": path}), flush=True)
-    if diverged:
-        sys.exit(1)
+    exe_a = ParallelExecutor(axes={"dp": 8}, zero_dp_states=True)
+    mesh_b = make_hybrid_mesh({"dp": 4}, {"dcn_dp": 2})
+    exe_b = ParallelExecutor(mesh=mesh_b, zero_dp_states=True)
+
+    findings = differential_run(
+        program, program, feed_names, [loss_name],
+        batch_size=batch_size, rtol=0.0, atol=0.0,
+        executor_a=exe_a, executor_b=exe_b)
+
+    def link_report(exe):
+        prov = {}
+        plan = exe.static_plan(program, provenance=prov)
+        ana = propagate(program, mesh=exe.mesh, plan=plan,
+                        batch_size=batch_size, provenance=prov)
+        rep = comm_report(ana)
+        return plan, {
+            "per_kind": ana.per_kind(),
+            "link_bytes": rep["link_bytes"],
+            "ici_time_s": rep["ici_time_s"],
+            "dcn_time_s": rep["dcn_time_s"],
+            "decomposed": [e["decomposed"] for e in rep["breakdown"]
+                           if "decomposed" in e],
+        }
+
+    plan_a, comm_a = link_report(exe_a)
+    plan_b, comm_b = link_report(exe_b)
+    velocity_specs = {
+        n: [list(e) if isinstance(e, tuple) else e
+            for e in spec_of(s)]
+        for n, s in sorted(plan_b.items()) if "velocity" in n}
+    return {
+        "analysis": "hybrid_parity",
+        "mesh_single": {"dp": 8},
+        "mesh_hybrid": {"dcn_dp": 2, "dp": 4},
+        "weight_update_sharding": True,
+        "bitwise": not findings,
+        "verdict": "PROVEN" if not findings else "DIVERGED",
+        "findings": [f.format() for f in findings],
+        "fetches": [loss_name],
+        "velocity_specs_hybrid": velocity_specs,
+        "comm": {"single": comm_a, "hybrid": comm_b},
+    }
+
+
+# ISSUE 20: fused K-step dispatch vs K sequential dispatches
+
+
+def _loop_models():
+    """The two loop-parity obligations: a Momentum-MLP (hidden layer +
+    velocity state, the smallest real training step) and the standing
+    small decoder LM (attention, layernorm, Adam moments — the stateful
+    stochastic program family step_loop must not perturb)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.framework import unique_name
+    from paddle_tpu.framework.core import Program, program_guard
+
+    def mlp():
+        x = fluid.layers.data(name="x", shape=[16])
+        y = fluid.layers.data(name="y", shape=[1])
+        h = fluid.layers.fc(x, size=32, act="relu")
+        pred = fluid.layers.fc(h, size=1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        fluid.optimizer.Momentum(learning_rate=0.01,
+                                 momentum=0.9).minimize(loss)
+        return loss.name, ["x", "y"]
+
+    def small_lm():
+        from paddle_tpu.models import standing
+
+        feed, fetches, _bs = standing.build_small_lm()
+        return _name_of(fetches[0]), sorted(feed)
+
+    for kind, build in (("mlp", mlp), ("small_lm", small_lm)):
+        main, startup = Program(), Program()
+        with unique_name.guard(), program_guard(main, startup):
+            loss_name, feed_names = build()
+        yield kind, main, startup, loss_name, feed_names
+
+
+def _name_of(f):
+    return f if isinstance(f, str) else f.name
+
+
+def loop_parity_report(ks=(1, 2, 4, 8), batch_size=4) -> dict:
+    """K-step fused dispatch (`Executor.run(steps_per_dispatch=K)`,
+    framework/step_loop.py) vs K sequential `run()` calls, judged at
+    BITWISE tolerance on every per-step fetch AND every written-back
+    state value (params, velocities, Adam moments).
+
+    Both sides start from an identical copy of the startup-initialized
+    state and see the same K deterministic feed batches (`build_feeds`
+    seeded per step); the sequential side pins `rng_step=i`, the fused
+    side `rng_step=0` with the on-device `fold_in(base, step0+i)`
+    stream — so agreement proves the fused loop IS K steps, RNG
+    included, not merely close.  The run_tests.sh `loop` gate consumes
+    the verdict (PROVEN required)."""
+    import numpy as np
+
+    from paddle_tpu.analysis.equivalence import build_feeds
+    from paddle_tpu.framework import dataflow
+    from paddle_tpu.framework.executor import Executor
+    from paddle_tpu.framework.place import CPUPlace
+    from paddle_tpu.framework.scope import Scope
+
+    cases = []
+    for kind, main, startup, loss_name, feed_names in _loop_models():
+        block = main.global_block()
+        ext, rw, written = dataflow.state_classes(block, feed_names)
+        exe = Executor(CPUPlace())
+        for k in ks:
+            k = int(k)
+            sa, sb = Scope(), Scope()
+            exe.run(startup, scope=sa, verify=False)
+            for n in set(ext) | set(rw):
+                v = sa.find(n)
+                if v is not None:
+                    sb.set(n, np.array(np.asarray(v)))
+            feeds = [build_feeds(main, feed_names, batch_size, seed=i)
+                     for i in range(k)]
+            # K=1 is the identity path (no stacking in, none out): its
+            # "parity" is plain run-to-run determinism
+            stacked = (feeds[0] if k == 1 else
+                       {n: np.stack([f[n] for f in feeds])
+                        for n in feed_names})
+            seq = [np.asarray(exe.run(main, feed=feeds[i],
+                                      fetch_list=[loss_name], scope=sb,
+                                      rng_step=i, verify=False)[0])
+                   for i in range(k)]
+            fused = np.asarray(exe.run(
+                main, feed=stacked, fetch_list=[loss_name], scope=sa,
+                rng_step=0, verify=False, steps_per_dispatch=k)[0])
+            findings = []
+            if k > 1 and tuple(fused.shape[:1]) != (k,):
+                findings.append(
+                    f"fetch {loss_name!r} not stacked (K, ...): "
+                    f"{fused.shape}")
+            for i in range(k):
+                a = fused[i] if k > 1 else fused
+                if a.shape != seq[i].shape or not np.array_equal(a, seq[i]):
+                    findings.append(
+                        f"fetch {loss_name!r} step {i} diverged: "
+                        f"fused={a!r} sequential={seq[i]!r}")
+            for n in written:
+                a, b = np.asarray(sa.find(n)), np.asarray(sb.find(n))
+                if a.shape != b.shape:
+                    findings.append(
+                        f"written state {n!r} shape diverged: "
+                        f"{a.shape} vs {b.shape}")
+                elif not np.array_equal(a, b):
+                    d = np.max(np.abs(a.astype(np.float64)
+                                      - b.astype(np.float64)))
+                    findings.append(
+                        f"written state {n!r} diverged after {k} steps: "
+                        f"max|a-b|={d:.3e}")
+            cases.append({
+                "model": kind, "k": k,
+                "fetches": [loss_name],
+                "written_state": len(written),
+                "bitwise": not findings,
+                "findings": findings,
+            })
+    all_ok = all(c["bitwise"] for c in cases)
+    return {
+        "analysis": "loop_parity",
+        "ks": [int(k) for k in ks],
+        "batch_size": int(batch_size),
+        "models": sorted({c["model"] for c in cases}),
+        "cases": cases,
+        "bitwise": all_ok,
+        "verdict": "PROVEN" if all_ok else "DIVERGED",
+        "findings": [f for c in cases for f in c["findings"]],
+    }
 
 
 def run_hybrid(args) -> None:
@@ -733,9 +885,7 @@ def run_hybrid(args) -> None:
     run (flat dp=8 vs dcn_dp=2 x dp=4 with weight-update sharding) plus
     predicted wire bytes per link class — the ISSUE 19 bench artifact.
     Executes real jitted steps on 8 virtual CPU devices."""
-    from paddle_tpu.analysis import equivalence as eqv
-
-    rec = eqv.hybrid_parity_report()
+    rec = hybrid_parity_report()
     print(json.dumps(rec), flush=True)
     if rec["verdict"] != "PROVEN":
         sys.exit(1)
@@ -748,10 +898,8 @@ def run_loop(args) -> None:
     every per-step fetch AND all written state — the
     framework/step_loop.py contract.  Exits 1 unless every case is
     PROVEN — run_tests.sh's `loop` gate."""
-    from paddle_tpu.analysis import equivalence as eqv
-
     ks = tuple(int(k) for k in (args.ks or "1,4").split(","))
-    rec = eqv.loop_parity_report(ks=ks)
+    rec = loop_parity_report(ks=ks)
     print(json.dumps(rec), flush=True)
     if rec["verdict"] != "PROVEN":
         sys.exit(1)
@@ -772,7 +920,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("what", nargs="?", default="all",
                     choices=["bytes", "collectives", "peak", "roofline",
-                             "comm", "equiv", "hybrid", "loop", "all"])
+                             "comm", "hybrid", "loop", "all"])
     ap.add_argument("--child", default=None)
     ap.add_argument("--mode", dest="submode", default=None)
     ap.add_argument("--bs", type=int, default=32)
@@ -787,11 +935,6 @@ def main():
     ap.add_argument("--ks", default=None,
                     help="loop mode: comma-separated steps_per_dispatch "
                          "values to prove (default 1,4)")
-    ap.add_argument("--capture-golden", action="store_true",
-                    dest="capture_golden",
-                    help="equiv mode: after a fully PROVEN sweep, "
-                         "re-archive the live plans as "
-                         "parallel/mode_plans_golden.json")
     args = ap.parse_args()
 
     if args.child:
@@ -813,9 +956,6 @@ def main():
         return
     if args.what == "comm":
         run_comm(args)
-        return
-    if args.what == "equiv":
-        run_equiv(args)
         return
     if args.what == "hybrid":
         run_hybrid(args)

@@ -9,12 +9,12 @@ Classes of rot this repo has actually accumulated:
      the package tree that Python will not treat as a package;
   3. (retired with the jax-version shim it guarded; the numbers of the
      other rules are cited elsewhere and stay.)
-  4. ``PartitionSpec`` literals inside ``paddle_tpu/parallel/`` outside
-     ``mesh.py`` — specs must stay RULE-DERIVED (minted by
-     ``mesh.pspec``/``named``/``replicated``) so the sharding analyzer
+  4. ``PartitionSpec`` literals inside ``paddle_tpu/parallel/`` — specs
+     must stay RULE-DERIVED (minted by ``paddle_tpu/mesh.py``'s
+     ``pspec``/``named``/``replicated``) so the sharding analyzer
      (analysis/sharding.py) can trust every plan it is handed; an
      ad-hoc spec tuple in a mode file is exactly the bespoke wiring the
-     logical-axis refactor (ROADMAP #2) is collapsing.
+     one rule table (parallel/partitioner.py) replaced.
   5. page-table mutation outside the allocator API — the serving
      page table (``PagedKVCache.page_table``) caches an int64 feed view
      and backs the allocator's refcount accounting; a raw
@@ -81,10 +81,10 @@ _SKIP_DIRS = {".git", "__pycache__", "node_modules", ".venv"}
 _NO_INIT_OK = {"tests", "docs"}
 
 # the rule-derived-specs guard: PartitionSpec named (constructed OR
-# imported, aliasing included) anywhere in parallel/ except the mint
+# imported, aliasing included) anywhere in parallel/; the mint is
+# paddle_tpu/mesh.py, a leaf beside it
 _PARTITION_SPEC_RE = re.compile(r"\bPartition" + r"Spec\b(?!`)")
 _PARTITION_SPEC_DIR = os.path.join("paddle_tpu", "parallel")
-_PARTITION_SPEC_OK = os.path.join(_PARTITION_SPEC_DIR, "mesh.py")
 
 
 def _check_partition_spec(root, dirpath, filenames, findings):
@@ -96,15 +96,13 @@ def _check_partition_spec(root, dirpath, filenames, findings):
             continue
         path = os.path.join(dirpath, fname)
         rel = os.path.relpath(path, root)
-        if rel == _PARTITION_SPEC_OK:
-            continue
         try:
             with open(path, encoding="utf-8", errors="replace") as f:
                 for i, line in enumerate(f, 1):
                     if _PARTITION_SPEC_RE.search(line):
                         findings.append(
                             f"PartitionSpec literal in parallel/: "
-                            f"{rel}:{i} (mint specs via parallel/"
+                            f"{rel}:{i} (mint specs via paddle_tpu/"
                             f"mesh.py pspec()/named()/replicated() so "
                             f"they stay rule-derived)")
         except OSError:
