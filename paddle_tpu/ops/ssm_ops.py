@@ -7,7 +7,8 @@ d_state] with a step size for every token and CHANNEL: elementwise work, no
 matrix product, a kernel pair on the chip.  `ssd_scan` (Mamba-2; Granite 4.0
 H) runs a [head_dim, d_state] state a HEAD whose decay is ONE scalar a head
 and token, with B and C shared by a group of heads: in chunks it is matrix
-products (state-space duality), plain `jax.numpy` here.
+products (state-space duality), a kernel pair of its own on the chip and
+plain `jax.numpy` (`ssd_chunked`) everywhere else.
 
 `selective_scan`: Mamba's selective state-space scan (arXiv:2312.00752) over
 a DIAGONAL state [d_inner, d_state] with a step size for every token and
@@ -40,9 +41,14 @@ with Delta_t and A scalars a head.  `ssd_chunked` runs the dual form in
 chunks of Q tokens: inside a chunk a decayed [Q, Q] score tile a head (C B^T
 is one product for a group's heads), across chunks the float32 state
 through a `lax.scan`; every exponential is of a DIFFERENCE of cumulative
-log-decays (<= 0), never a quotient.  `gated_rms_norm` is the pass behind
-it: RMSNorm(y * SiLU(z)), the gate FIRST and one norm over a group's
-columns.
+log-decays (<= 0), never a quotient.  On one TPU, at whole tiles, the scan
+is the Pallas kernel pair of ops/pallas_kernels/ssd_scan.py: the same chunked
+form with a chunk's decay tiles, C B^T and every head's state in VMEM, Delta
+and A made inside, X, B, C and Dt read where the convolution and the
+projection left them.  Everywhere else (the CPU, float64, a mesh,
+`PADDLE_TPU_NO_FUSED_KERNELS`, other shapes) `ssd_chunked`, the kernels'
+oracle.  `gated_rms_norm` is the pass behind it: RMSNorm(y * SiLU(z)), the
+gate FIRST and one norm over a group's columns.
 """
 
 from __future__ import annotations
@@ -71,11 +77,20 @@ _MET_SCAN_KERNELS = _MET.counter(
 _MET_SSD = _MET.counter(
     "ssd_scan_total",
     "Mamba-2 (state-space duality) scans traced (forward emission; once a "
-    "compile, not once a step), by the emission taken (impl: xla_chunked, "
-    "`ssd_chunked`: the chunks' decayed score tiles as batched products, the "
-    "float32 state through a lax.scan over the chunks), the heads, a head's "
-    "width (head_dim), the state a head column (d_state), the groups that "
-    "share a B and C (groups) and the tokens a chunk (chunk)")
+    "compile, not once a step), by the emission taken (impl: pallas, the "
+    "kernel pair of ops/pallas_kernels/ssd_scan.py with a chunk's tiles and "
+    "the state in VMEM; xla_chunked, `ssd_chunked`: the chunks' decayed "
+    "score tiles as batched products, the float32 state through a lax.scan "
+    "over the chunks), the heads, a head's width (head_dim), the state a "
+    "head column (d_state), the groups that share a B and C (groups) and "
+    "the tokens a chunk of that emission (chunk)")
+_MET_SSD_KERNELS = _MET.counter(
+    "ssd_scan_kernels_traced_total",
+    "emissions of the Mamba-2 scan (once a compile, not once a step), by the "
+    "op that emits it (fwd: ssd_scan; grad: a re-emission under a grad op's "
+    "jax.vjp, its own or its `layers.recompute` segment's) and the path "
+    "taken (pallas: the kernel pair of ops/pallas_kernels/ssd_scan.py; xla: "
+    "ssd_chunked)")
 
 # Tokens a chunk of the plain emission's scan (the kernels' is their own
 # CHUNK): a constant, not a knob.  What the backward keeps is one [d_inner,
@@ -319,15 +334,27 @@ def ssd_scan(ctx, ins, attrs):
       Out_t[h] = S_t[h] C_t + D[h] x_t[h]                      (pdtpu.ssd.scan)
 
     S[h] is [P, N] float32 from zero; the decay is ONE scalar a head and
-    token.  `ssd_chunked` in chunks of `chunk` tokens (padded where the
-    chunk does not divide T): Delta, A, the exponents, the state and the
-    sums are float32 (float64 for float64 inputs), the products take X, B
-    and C in their own dtype; one rounding to X's dtype at the end.  Plain
-    jax.numpy with the generic vjp: inside a `layers.recompute` segment the
-    segment's replay is the scan's only second forward.  `ssd_scan_total`
-    says what ran."""
+    token.  Delta, A, the exponents, the state and the sums are float32
+    (float64 for float64 inputs), the products take X, B and C in their own
+    dtype; one rounding to X's dtype at the end.
+
+    On one TPU, where X is bf16 or float32, the kernels' chunk divides T, N
+    is whole lane tiles and a head is a lane tile or half of one
+    (`ssd_scan.usable`), both parts are the kernel pair of
+    ops/pallas_kernels/ssd_scan.py (Delta and A are made inside, from Dt in
+    its own dtype; its own CHUNK, the attr `chunk` is the plain emission's)
+    through `ctx.run_pair`: kept beside the output are Out and every chunk's
+    incoming state.  Everywhere else `ssd_chunked` in chunks of `chunk`
+    tokens (padded where the chunk does not divide T), plain jax.numpy with
+    the generic vjp: inside a `layers.recompute` segment the segment's
+    replay is the scan's only second forward on either path.
+    `ssd_scan_total` and `ssd_scan_kernels_traced_total` say which emission
+    ran."""
     import jax
     import jax.numpy as jnp
+
+    from .pallas_kernels import ssd_scan as kernels
+    from .pallas_kernels._common import traced_path
 
     x, b, c, dt = (ins[s][0] for s in ("X", "B", "C", "Dt"))
     a_log, d, bias = ins["ALog"][0], ins["D"][0], ins["DtBias"][0]
@@ -343,9 +370,21 @@ def ssd_scan(ctx, ins, attrs):
             f"ALog {a_log.shape}, D {d.shape}, DtBias {bias.shape} at {H} "
             f"heads in {G} groups, chunks of {chunk}")
     P, N = width // H, b.shape[2] // G
+    take = traced_path(
+        ctx, _MET_SSD_KERNELS,
+        b.dtype == x.dtype == c.dtype
+        and kernels.usable(T, kernels.CHUNK, H, P, N, G, x.dtype))
     if not ctx.in_grad_replay():
-        _MET_SSD.inc(impl="xla_chunked", heads=str(H), head_dim=str(P),
-                     d_state=str(N), groups=str(G), chunk=str(min(chunk, T)))
+        _MET_SSD.inc(impl="pallas" if take else "xla_chunked", heads=str(H),
+                     head_dim=str(P), d_state=str(N), groups=str(G),
+                     chunk=str(kernels.CHUNK if take else min(chunk, T)))
+    if take:
+        with part_scope("ssd.scan"):
+            out, saved = ctx.run_pair(kernels.make_ssd_scan(H, G),
+                                      (x, b, c, dt, a_log, d, bias))
+        if saved is not None:
+            ctx.keep_for_grad(attrs, [out], saved)
+        return {"Out": [out]}
     wide = wide_dtype(x.dtype)
     with part_scope("ssd.dt"):
         delta = jax.nn.softplus(dt.astype(wide) + bias.astype(wide))

@@ -231,6 +231,20 @@ def _by_labels(family, *keys):
             for s in (fam["series"] if fam else [])}
 
 
+def _spy_on_calls(monkeypatch, kernels, names):
+    """-> the list every launch of one of `kernels._calls`' jitted calls
+    (`names`, in its order) appends its name to."""
+    launched, real = [], kernels._calls
+
+    def calls(*a):
+        return tuple((lambda *x, name=name, call=call:
+                      (launched.append(name), call(*x))[1])
+                     for name, call in zip(names, real(*a)))
+
+    monkeypatch.setattr(kernels, "_calls", calls)
+    return launched
+
+
 def _inner_eqns(jaxpr):
     for e in jaxpr.eqns:
         yield e

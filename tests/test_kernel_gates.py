@@ -7,8 +7,8 @@ blocks snap (those `flash_attention.call_blocks` gives the call's shape),
 and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
 `hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable`,
-`gated_delta.usable`, `kda.usable`, `kda_conv.usable` and
-`selective_scan.usable` say yes.  Nothing compiles: milliseconds where the AOT tests of the same cells take minutes."""
+`gated_delta.usable`, `kda.usable`, `kda_conv.usable`,
+`selective_scan.usable` and `ssd_scan.usable` say yes.  Nothing compiles: milliseconds where the AOT tests of the same cells take minutes."""
 
 import glob
 import importlib
@@ -24,7 +24,8 @@ from paddle_tpu.ops.pallas_kernels import (flash_attention, gated_delta,
                                            grouped_matmul, head_norm_rope,
                                            hyper_connection, kda, kda_conv,
                                            segment_sum, selective_scan,
-                                           short_conv, sparse_flash)
+                                           short_conv, sparse_flash,
+                                           ssd_scan)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -64,9 +65,10 @@ MECHANISMS = {
     "laguna-s-2.1": {"flash", "flash_window", "head_norm_rope",
                      "grouped_matmul", "segment_sum"},
     # ONE attention layer on the projections' layout (32 query heads on 8:
-    # its heads are split inside the op, no position, scale 1/64); the nine
-    # Mamba-2 scans are plain XLA (`ssd_chunked`: no kernel, so no gate)
-    "granite-4.0-h-micro": {"flash"},
+    # its heads are split inside the op, no position, scale 1/64); nine
+    # Mamba-2 scans of 64 heads of 64 (pairs of heads a lane tile) on a
+    # state of 128, one group
+    "granite-4.0-h-micro": {"flash", "ssd_scan"},
 }
 
 
@@ -112,7 +114,7 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
     def dtype(op, slot):
         return jnp.dtype(block._find_var_recursive(op.inputs[slot][0]).dtype)
 
-    passed, positions = set(), 0
+    passed, positions, scans = set(), 0, 0
     # a `layers.recompute` segment's ops lie in a block of their own
     for op in [op for b in fluid.default_main_program().blocks
                for op in b.ops]:
@@ -163,6 +165,16 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
                                          dtype(op, "U")), (T, Di, N)
             passed.add("selective_scan")
             positions = max(positions, T)
+        elif op.type == "ssd_scan":
+            _, T, width = shape(op, "X")
+            H, G = op.attrs["heads"], op.attrs["groups"]
+            N = shape(op, "B")[2] // G
+            assert {dtype(op, s) for s in "XBC"} == {dtype(op, "X")}
+            assert ssd_scan.usable(T, ssd_scan.CHUNK, H, width // H, N, G,
+                                   dtype(op, "X")), (T, H, width, N, G)
+            scans += 1
+            passed.add("ssd_scan")
+            positions = max(positions, T)
         elif op.type == "head_norm_rope" and (
                 "rotary_dim" not in op.attrs or head_norm_rope.turn_of(
                     shape(op, "X")[2] // op.attrs["num_heads"],
@@ -200,6 +212,7 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
             assert hyper_connection.usable(n, T, C, dtype(op, "X")), (n, T, C)
             passed.add("hyper_connection")
     assert passed == MECHANISMS[name]
+    assert scans == (9 if name == "granite-4.0-h-micro" else 0)
 
 
 def test_every_lm_configuration_is_held():

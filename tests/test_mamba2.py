@@ -17,7 +17,7 @@ import paddle_tpu as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer
 
-from _kernel_refs import _dense_scaled, _r, _series, _silu
+from _kernel_refs import _dense_scaled, _r, _series, _silu, _spy_on_calls
 from op_test import OpTestHarness
 
 
@@ -134,6 +134,141 @@ def test_ssd_scan_counts_what_ran_and_refuses_what_does_not_add_up():
     ins, attrs = _scan_case(8, H=3)
     with pytest.raises(Exception, match="ssd_scan: X"):   # 3 heads, 2 groups
         OpTestHarness("ssd_scan", ins, dict(attrs, groups=2)).fetch()
+
+
+# the kernels' path (ops/pallas_kernels/ssd_scan.py, PR 70;
+# tests/test_ssd_scan_kernel.py holds the kernels themselves)
+
+KERNELS = "ssd_scan_kernels_traced_total"
+
+
+def _kernel_case(T, H=2, P=64, N=128, G=1, seed=0):
+    """`_scan_case` at shapes the kernels take, float32."""
+    ins, attrs = _scan_case(T, H=H, P=P, N=N, G=G, seed=seed)
+    return {k: v.astype("float32") for k, v in ins.items()}, attrs
+
+
+def _scan_step(ins, attrs):
+    """A program of the one op under mean(Out * weight), every input a
+    parameter -> Out and every input's gradient of one run."""
+    fluid.reset()
+    block = fluid.default_main_program().global_block()
+    for name, value in ins.items():
+        block.create_parameter(name=name, shape=value.shape, dtype="float32")
+    weight = _r(*ins["X"].shape, seed=11).astype("float32")
+    block.create_var(name="weight", shape=weight.shape, dtype="float32",
+                     stop_gradient=True)
+    out = block.create_var(name="out", dtype="float32", shape=weight.shape)
+    block.append_op("ssd_scan", inputs={slot: [slot] for slot in ins},
+                    outputs={"Out": ["out"]}, attrs=dict(attrs))
+    loss = fluid.layers.mean(fluid.layers.elementwise_mul(
+        out, block.var("weight")))
+    grads = dict((p.name, g.name) for p, g in fluid.append_backward(loss))
+    scope = fluid.global_scope()
+    for name, value in dict(ins, weight=weight).items():
+        scope.set(name, value)
+    got = fluid.Executor(fluid.CPUPlace()).run(
+        feed={}, fetch_list=["out"] + [grads[name] for name in ins])
+    return [np.asarray(a) for a in got]
+
+
+@pytest.fixture
+def kernels_in_interpret_mode(monkeypatch):
+    """The trace targets one TPU and the kernels run in interpret mode in
+    chunks of 16 tokens -> the list every launch appends its name to."""
+    from paddle_tpu.ops import registry as reg
+    from paddle_tpu.ops.pallas_kernels import ssd_scan as K
+
+    real_make = K.make_ssd_scan
+    launched = _spy_on_calls(monkeypatch, K, ("fwd", "bwd"))
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: "tpu")
+    monkeypatch.setattr(K, "CHUNK", 16)
+    monkeypatch.setattr(K, "make_ssd_scan",
+                        lambda H, G: real_make(H, G, 16, True))
+    real_make.cache_clear()
+    yield launched
+    real_make.cache_clear()
+
+
+def test_ssd_scan_takes_the_kernels_on_a_tpu(kernels_in_interpret_mode,
+                                             monkeypatch):
+    """Where the trace targets one TPU, at whole tiles, the op's emitter
+    launches the forward kernel ONCE, keeping the chunks' states, and its
+    grad op's re-emission launches the reverse pass alone
+    (`executor_grad_kernel_forward_total` reused=1); the numbers are the
+    plain emission's, which the switch sends both emissions back to."""
+    launched = kernels_in_interpret_mode
+    ins, attrs = _kernel_case(32)
+    labels = {"heads": "2", "head_dim": "64", "d_state": "128",
+              "groups": "1"}
+    obs.REGISTRY.reset()
+    got = _scan_step(ins, attrs)
+    assert launched == ["fwd", "bwd"]
+    assert _series(KERNELS) == [({"op": "fwd", "path": "pallas"}, 1.0),
+                                ({"op": "grad", "path": "pallas"}, 1.0)]
+    assert _series("ssd_scan_total") == [
+        (dict(labels, impl="pallas", chunk="16"), 1.0)]
+    assert _series("executor_grad_kernel_forward_total") == [
+        ({"op": "ssd_scan", "reused": "1"}, 1.0)]
+    del launched[:]
+    monkeypatch.setenv("PADDLE_TPU_NO_FUSED_KERNELS", "1")
+    obs.REGISTRY.reset()
+    want = _scan_step(ins, attrs)
+    assert launched == []
+    assert _series(KERNELS) == [({"op": "fwd", "path": "xla"}, 1.0),
+                                ({"op": "grad", "path": "xla"}, 1.0)]
+    assert _series("ssd_scan_total") == [
+        (dict(labels, impl="xla_chunked", chunk="4"), 1.0)]
+    assert _series("executor_grad_kernel_forward_total") == []
+    for a, b in zip(got, want):
+        assert np.abs(b).max() > 0
+        assert np.abs(a - b).max() <= 2e-5 * np.abs(b).max()
+
+
+@pytest.mark.parametrize("case,platform,mesh,shape,dtype,path", [
+    ("one_tpu", "tpu", None, (512, 4, 64, 128, 1), "bfloat16", "pallas"),
+    ("heads_of_128", "tpu", None, (512, 2, 128, 128, 2), "float32",
+     "pallas"),
+    ("the_cpu", "cpu", None, (512, 4, 64, 128, 1), "bfloat16", "xla"),
+    ("a_mesh", "tpu", object(), (512, 4, 64, 128, 1), "bfloat16", "xla"),
+    ("a_narrow_state", "tpu", None, (512, 4, 64, 64, 1), "bfloat16", "xla"),
+    ("a_pair_on_two_groups", "tpu", None, (512, 4, 64, 128, 4), "bfloat16",
+     "xla"),
+    ("heads_of_32", "tpu", None, (512, 4, 32, 128, 1), "bfloat16", "xla"),
+    ("off_the_chunks", "tpu", None, (520, 4, 64, 128, 1), "bfloat16", "xla"),
+    ("under_a_chunk", "tpu", None, (12, 4, 64, 128, 1), "float32", "xla"),
+    ("doubles", "tpu", None, (512, 4, 64, 128, 1), "float64", "xla")])
+def test_ssd_scan_dispatch_counts_the_path(case, platform, mesh, shape,
+                                           dtype, path, monkeypatch):
+    """One gate: one TPU, no mesh and a shape the kernels take; the
+    counters read the path of the forward emission (abstractly traced: no
+    kernel runs) and the chunk of the emission taken."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import registry as reg
+    from paddle_tpu.ops.pallas_kernels import ssd_scan as K
+
+    T, H, P, N, G = shape
+    values, attrs = _scan_case(T, H=H, P=P, N=N, G=G, chunk=128)
+    monkeypatch.setattr(reg.EmitContext, "target_platform",
+                        lambda self: platform)
+    ctx = reg.EmitContext(None, is_test=True)
+    ctx.mesh = mesh
+    obs.REGISTRY.reset()
+    with jax.enable_x64(dtype == "float64"):
+        ins = {slot: [jax.ShapeDtypeStruct(v.shape, jnp.dtype(dtype))]
+               for slot, v in values.items()}
+        out = jax.eval_shape(
+            lambda ins: reg.get_op_info("ssd_scan").emit(
+                ctx, ins, attrs)["Out"][0], ins)
+    assert out.shape == (1, T, H * P) and out.dtype == jnp.dtype(dtype)
+    assert _series(KERNELS) == [({"op": "fwd", "path": path}, 1.0)]
+    (labels, _), = _series("ssd_scan_total")
+    assert (labels["impl"], labels["chunk"]) == (
+        ("pallas", str(K.CHUNK)) if path == "pallas"
+        else ("xla_chunked", str(min(128, T))))
 
 
 # ---------------------------------------------------------------------------
@@ -286,17 +421,17 @@ def test_attention_without_the_attr_is_the_parents_jaxpr_and_desc():
 # the layer and the tower
 
 
-def _mixer_grads(segment: bool):
+def _mixer_grads(segment: bool, T=10, sizes=None):
     """The loss and every parameter's gradient of one `layers.mamba2` (two
-    groups, a chunk that does not divide T) inside or outside a
+    groups, a chunk that does not divide T; or `sizes`) inside or outside a
     `layers.recompute` segment, on the same seeded weights."""
     import contextlib
 
     fluid.reset()
-    x = fluid.layers.data("x", shape=[10, 16], dtype="float32")
+    x = fluid.layers.data("x", shape=[T, 16], dtype="float32")
+    sizes = sizes or dict(n_heads=4, head_dim=8, d_state=4, n_groups=2)
     with (fluid.layers.recompute() if segment else contextlib.nullcontext()):
-        y = fluid.layers.mamba2(x, n_heads=4, head_dim=8, d_state=4,
-                                n_groups=2, chunk=4)
+        y = fluid.layers.mamba2(x, chunk=4, **sizes)
     loss = fluid.layers.mean(fluid.layers.elementwise_mul(y, y))
     grads = fluid.append_backward(loss)
     main, startup = (fluid.default_main_program(),
@@ -304,7 +439,7 @@ def _mixer_grads(segment: bool):
     main.random_seed = startup.random_seed = 7
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)
-    out = exe.run(feed={"x": _r(2, 10, 16, seed=5).astype("float32")},
+    out = exe.run(feed={"x": _r(2, T, 16, seed=5).astype("float32")},
                   fetch_list=[loss] + [g for _, g in grads])
     return [p.shape for p, _ in grads], [np.asarray(o) for o in out]
 
@@ -322,6 +457,38 @@ def test_mamba2_layer_inside_a_recompute_segment_gives_the_same_gradients():
     assert all(np.abs(g).max() > 0 for g in plain[1:])
     for a, b in zip(plain, inside):
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-9)
+
+
+def test_mamba2_layer_in_a_segment_on_the_kernels_path(
+        kernels_in_interpret_mode, monkeypatch):
+    """On the kernels' path (interpret mode, two chunks of 16 tokens, a pair
+    of heads of 64) the layer outside a segment launches the forward once
+    and the reverse pass over what it kept; inside a segment the forward
+    emission, the replay's forward (handed nothing: the plain pair) and the
+    reverse pass.  The same loss and gradients either way, bit for bit, and
+    the plain emission's to float32's rounding."""
+    launched = kernels_in_interpret_mode
+    sizes = dict(n_heads=2, head_dim=64, d_state=128, n_groups=1)
+    obs.REGISTRY.reset()
+    _, outside = _mixer_grads(False, T=32, sizes=sizes)
+    assert launched == ["fwd", "bwd"]
+    del launched[:]
+    _, inside = _mixer_grads(True, T=32, sizes=sizes)
+    # (traces, not launches: differentiating the replay's plain pair traces
+    # its primal beside its rule, and the compiled step drops the one
+    # nothing reads)
+    assert launched.count("bwd") == 1 and launched.count("fwd") >= 2
+    assert _series(KERNELS) == [({"op": "fwd", "path": "pallas"}, 1.0),
+                                ({"op": "grad", "path": "pallas"}, 1.0)]
+    for a, b in zip(outside, inside):
+        np.testing.assert_array_equal(a, b)
+    del launched[:]
+    monkeypatch.setenv("PADDLE_TPU_NO_FUSED_KERNELS", "1")
+    _, plain = _mixer_grads(True, T=32, sizes=sizes)
+    assert launched == []
+    assert all(np.abs(g).max() > 0 for g in plain[1:])
+    for a, b in zip(inside, plain):
+        assert np.abs(a - b).max() <= 5e-5 * np.abs(b).max()
 
 
 def test_decoder_lm_knows_the_kind_and_refuses_what_is_missing():
