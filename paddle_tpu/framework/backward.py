@@ -13,9 +13,19 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Set
 
+from ..observability.metrics import REGISTRY as _MET
 from ..ops.registry import default_grad_maker, get_op_info
 from . import unique_name
 from .core import GRAD_SUFFIX, Parameter, Program, Variable, grad_var_name
+
+
+_MET_PARTS = _MET.counter(
+    "backward_grad_parts_total",
+    "parameters whose gradient `append_backward` finalizes, by the number "
+    "of `parts` it is the sum of (one a grad op that reads the parameter: "
+    "1 for most, 2 for a tied embedding, the passes of a looped tower for "
+    "the parameters every pass reads), counted where the backward is BUILT: "
+    "once an `append_backward`, never a step or a compile")
 
 
 def _compute_requires_grad(block, no_grad_set: Set[str],
@@ -119,6 +129,7 @@ def append_backward(
         gname = grad_var_name(name)
         if name in finalized:
             return gname
+        v = block._find_var_recursive(name)
         if len(parts) == 1:
             if parts[0] != gname:
                 _ensure_grad_var(block, name, gname)
@@ -127,13 +138,17 @@ def append_backward(
                 )
         else:
             _ensure_grad_var(block, name, gname)
+            # a parameter several ops read (a tied embedding, a looped
+            # tower's blocks): the parts' adds carry a part of their own, in
+            # the order the backward made the parts (the last reader's first)
             block.append_op(
-                "sum", inputs={"X": list(parts)}, outputs={"Out": [gname]}
+                "sum", inputs={"X": list(parts)}, outputs={"Out": [gname]},
+                attrs={"part": "grad.sum"} if isinstance(v, Parameter)
+                else None,
             )
         finalized.add(name)
         # v1 gradient_printer_evaluator support: vars tagged print_gradient
         # get a runtime print of their materialized grad
-        v = block._find_var_recursive(name)
         if v is not None and getattr(v, "print_gradient", False):
             block.append_op(
                 "print", inputs={"X": [gname]}, outputs={"Out": [gname]},
@@ -222,6 +237,7 @@ def append_backward(
         g = finalize(p.name)
         if g is not None:
             result.append((p, block.var(g)))
+            _MET_PARTS.inc(parts=str(len(pending[p.name])))
     # materialize grads of un-stopped feeds so they are fetchable
     feed_grads = 0
     for v in list(block.vars.values()):

@@ -7,9 +7,70 @@ contract as fluid."""
 
 from __future__ import annotations
 
+import contextlib
+
 from . import unique_name
-from .core import default_main_program, default_startup_program
+from .core import (canonical_dtype, default_main_program,
+                   default_startup_program)
 from .initializer import ConstantInitializer, XavierInitializer
+
+_SHARING = []   # the SharedParameters scopes in force, innermost last
+
+
+class SharedParameters:
+    """ONE set of parameters for layers that are built several times (a
+    looped tower's passes).  The first `with shared.scope():` builds as
+    ever and notes the name of every parameter a layer makes inside it, in
+    order; in each later scope `LayerHelper.create_parameter` makes nothing
+    and hands out the first's parameters BY THOSE NAMES in the same order:
+    the later ops read the one Parameter, which has one init op in the
+    startup program, one gradient (`append_backward` adds its parts) and
+    one entry wherever parameters are listed.  A later scope whose layers
+    ask for another shape or dtype than the first made, for a parameter
+    more, or for fewer by its end, is a ValueError: the passes are not one
+    stack of layers.  Temporaries keep their fresh `unique_name`s."""
+
+    def __init__(self):
+        self.names = []     # the first scope's parameters, as made
+        self._next = None   # None: the first scope (it records)
+
+    @contextlib.contextmanager
+    def scope(self):
+        first = self._next is None
+        if not first:
+            self._next = 0
+        _SHARING.append(self)
+        try:
+            yield
+        finally:
+            _SHARING.pop()
+        if not first and self._next != len(self.names):
+            raise ValueError(
+                f"shared parameters: a later pass read {self._next} of the "
+                f"{len(self.names)} parameters the first made (the next "
+                f"would be {self.names[self._next]!r})")
+        self._next = 0
+
+    def take(self, block, shape, dtype):
+        """Inside a later scope the first's next parameter, held to `shape`
+        and `dtype`; inside the first None (the caller makes one and
+        notes its name)."""
+        if self._next is None:
+            return None
+        if self._next == len(self.names):
+            raise ValueError(
+                f"shared parameters: a later pass makes a parameter "
+                f"{list(shape)} {dtype} that the first did not: the first "
+                f"made {len(self.names)}")
+        param = block.var(self.names[self._next])
+        if (tuple(param.shape) != tuple(int(s) for s in shape)
+                or param.dtype != canonical_dtype(dtype)):
+            raise ValueError(
+                f"shared parameters: a later pass asks for {list(shape)} "
+                f"{dtype} where the first made {param.name!r} "
+                f"{list(param.shape)} {param.dtype}")
+        self._next += 1
+        return param
 
 
 class LayerHelper:
@@ -37,10 +98,18 @@ class LayerHelper:
     # ------------------------------------------------------------------
     def create_parameter(self, attr=None, shape=None, dtype="float32",
                          is_bias=False, default_initializer=None):
+        sharing = _SHARING[-1] if _SHARING else None
+        if sharing is not None:
+            shared = sharing.take(self.block.program.global_block(), shape,
+                                  dtype)
+            if shared is not None:
+                return shared
         attr = dict(attr or {})
         name = attr.get("name") or unique_name.generate(
             self.name + (".b" if is_bias else ".w")
         )
+        if sharing is not None:
+            sharing.names.append(name)
         init = attr.get("initializer") or default_initializer
         if init is None:
             init = ConstantInitializer(0.0) if is_bias else XavierInitializer()

@@ -22,8 +22,16 @@ from .. import layers
 from ..framework import unique_name
 from ..framework.core import default_main_program
 from ..framework.initializer import NormalInitializer
-from ..framework.layer_helper import LayerHelper
+from ..framework.layer_helper import LayerHelper, SharedParameters
 from ..layers import fluid_compat
+from ..observability.metrics import REGISTRY as _MET
+
+_LOOP_PASSES = _MET.counter(
+    "decoder_lm_loop_passes_total",
+    "passes of a looped tower through its one set of blocks, final norm, "
+    "head and gate (`decoder_lm(loop=)`), counted where the program is "
+    "BUILT: once a `decoder_lm` call, never a step or a compile; no series "
+    "where no tower loops")
 
 
 def _positions(tokens, dim, max_len, dtype):
@@ -60,7 +68,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                logit_scale=None, delta=None, attention_gate=False,
                rotary_dim=None, ssm=None, differential=None, window=None,
                attention_bias=False, tie_embeddings=False, norm_attr=None,
-               kda=None, yarn=None, remat_keep=(), attention_scale=None):
+               kda=None, yarn=None, remat_keep=(), attention_scale=None,
+               sandwich=False, loop=None):
     """tokens [B, T, 1] int64 → logits [B, T, vocab_size].
 
     sp_mode/sp_schedule flow to scaled_dot_product_attention: on a mesh
@@ -224,6 +233,29 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     the tower's last kind; a final norm of its own and the tower's head.
     The dict gains "logits" [B, T, vocab_size]: of the token two positions
     on.  An expert block appends to `router_outputs` like the others.
+    `sandwich` True puts a second norm, with a gain of its own, on every
+    sub-layer's RESULT before it is added to the stream: x + norm_2(f(
+    norm_1(x))) (Ouro's four norms a block).
+    `loop` = {"passes": n} and optionally "exit_gate" (True) and "targets"
+    ([B, T, 1], the token after each position) makes the tower a LOOPED one
+    (Ouro, arXiv:2510.25741): the `n_layers` blocks, the final norm, the
+    head and the gate are built n times and are ONE set of parameters
+    (`framework.layer_helper.SharedParameters`: the later passes read the
+    first's by name; a parameter read n times gets the sum of n gradient
+    parts); pass t reads the final norm's result of pass t - 1 (the first
+    the embedding) at the same positions, and the tower returns the LAST
+    pass's logits.  The dict gains "logits", each pass's [B, T,
+    vocab_size]; with "exit_gate" "gate", each pass's lambda = sigmoid(h w
+    + b) [B, T, 1] in float32, h the pass's normed state; with "targets"
+    "token_loss", each pass's cross-entropy [B T, 1] in float32, what
+    `ouro_exit_loss` weighs.  Under `remat` a pass's head and its
+    cross-entropy are ONE `layers.recompute` segment, so that no pass's
+    logits are held across to the backward.  A pass's ops lie in the part
+    `loop.a`, `loop.b`, ... (the gate's in `loop.gate` inside it).  One
+    pass without a gate is the unlooped tower, op for op.  Not with `mtp`,
+    `block_diffusion`, `hyper`, `tie_embeddings` or a learned position
+    table (each holds a parameter or a stream the passes would not share
+    as built here).
     `init_scale` draws every matrix (embedding,
     projections, experts, head) from normal(0, init_scale) instead of each
     layer's default; `emb_init_scale` gives the token embedding a scale of
@@ -297,6 +329,17 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                              "runs on a next-token tower with rotary "
                              "positions")
         layer_types = list(layer_types) + [layer_types[-1]]
+    if loop is not None:
+        unbuilt = [name for name, on in (
+            ("mtp", mtp is not None),
+            ("block_diffusion", block_diffusion is not None),
+            ("hyper", hyper is not None), ("tie_embeddings", tie_embeddings),
+            ("positions='learned'", positions == "learned")) if on]
+        if unbuilt or not 1 <= int(loop["passes"]) <= 26:
+            raise ValueError(
+                f"decoder_lm: `loop` {loop!r} runs 1 to 26 passes through "
+                f"one set of blocks, a final norm, a head and a gate; it "
+                f"does not build " + ", ".join(unbuilt or ["that many"]))
     init = (NormalInitializer(scale=init_scale) if init_scale is not None
             else None)
     attr = {"initializer": init} if init is not None else None
@@ -514,8 +557,9 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     streams = int(hyper["streams"]) if hyper else 0
 
     def sublayer(x, f):
-        """x with one sub-layer's result: x + f(norm(x)), or under `hyper`
-        the hyper-connection around f."""
+        """x with one sub-layer's result: x + f(norm(x)) (under `sandwich`
+        x + norm(f(norm(x)))), or under `hyper` the hyper-connection around
+        f."""
         read = x
         if streams:
             read, h_post, h_res = layers.hyper_connection_pre(
@@ -526,6 +570,8 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
                 beta_attr={"initializer": hyper.get("beta_init")})
             hyper.setdefault("mixing", []).append(h_res)
         out = f(normed(read))
+        if sandwich:
+            out = normed(out)
         if residual_scale is not None:
             out = layers.scale(out, scale=float(residual_scale))
         if dropout_prob:
@@ -550,6 +596,50 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
         return (layers.recompute(keep=kept) if remat
                 else contextlib.nullcontext())
 
+    prog = default_main_program()
+
+    def head(h):
+        if not tie_embeddings:
+            return layers.fc(h, vocab_size, num_flatten_dims=2,
+                             param_attr=head_attr, bias_attr=False)
+        table = prog.global_block().var(emb_attr["name"])
+        out = layers.matmul(h, table, transpose_y=True)
+        out.shape = tuple(h.shape[:-1]) + (vocab_size,)
+        return out
+
+    if loop is not None:
+        shared = SharedParameters()
+        _LOOP_PASSES.inc(int(loop["passes"]))
+        targets = loop.get("targets")
+        loop["logits"] = []
+        if loop.get("exit_gate"):
+            loop["gate"] = []
+        if targets is not None:
+            loop["token_loss"] = []
+        for t in range(int(loop["passes"])):
+            with shared.scope(), prog.part_guard("loop." + chr(97 + t)):
+                for layer in range(n_layers):
+                    with blk():
+                        x = block(x, layer)
+                x = normed(x)   # what the next pass reads, and the gate
+                h = x if logit_scale is None else layers.scale(
+                    x, scale=float(logit_scale))
+                with (layers.recompute() if remat and targets is not None
+                      else contextlib.nullcontext()):
+                    with prog.part_guard("lm.head"):
+                        logits = head(h)
+                    if targets is not None:
+                        with prog.part_guard("lm.loss"):
+                            loop["token_loss"].append(
+                                _token_loss(logits, targets, dtype))
+                loop["logits"].append(logits)
+                if loop.get("exit_gate"):
+                    with prog.part_guard("loop.gate"):
+                        loop["gate"].append(layers.sigmoid(layers.cast(
+                            layers.fc(x, 1, num_flatten_dims=2,
+                                      param_attr=attr), "float32")))
+        return logits
+
     if streams:
         x = layers.hyper_connection_streams(x, streams)
     for layer in range(n_layers):
@@ -570,17 +660,6 @@ def decoder_lm(tokens, vocab_size, dim, n_layers, n_heads, max_len,
     h = normed(x)
     if logit_scale is not None:
         h = layers.scale(h, scale=float(logit_scale))
-    prog = default_main_program()
-
-    def head(h):
-        if not tie_embeddings:
-            return layers.fc(h, vocab_size, num_flatten_dims=2,
-                             param_attr=head_attr, bias_attr=False)
-        table = prog.global_block().var(emb_attr["name"])
-        out = layers.matmul(h, table, transpose_y=True)
-        out.shape = tuple(h.shape[:-1]) + (vocab_size,)
-        return out
-
     with prog.part_guard("lm.head"):
         logits = head(h)
     if mtp is None:
@@ -622,19 +701,24 @@ _GPT2_BLOCK = {"norm": "layer_norm", "positions": "learned",
                "yarn": None, "attention_scale": None}
 
 
+def _token_loss(logits, targets, dtype):
+    """Every token's cross-entropy [B T, 1] in float32: logits [B, T, V]
+    against targets [B, T, 1]."""
+    flat = layers.reshape(logits, [-1, logits.shape[-1]])
+    if dtype != "float32":
+        flat = layers.cast(flat, "float32")
+    return layers.softmax_with_cross_entropy(
+        flat, layers.reshape(targets, [-1, 1]))
+
+
 def lm_loss(logits, targets, dtype="float32", drop_last=0):
     """Next-token loss: logits [B, T, V] vs targets [B, T, 1] (already
     shifted by the data pipeline).  Softmax runs in f32 regardless of the
     model compute dtype.  `drop_last` leaves the last so many positions of
     every sequence out of the mean (targets shifted further than the
     sequence reaches: a multi-token-prediction module's)."""
-    V = logits.shape[-1]
     with default_main_program().part_guard("lm.loss"):
-        flat = layers.reshape(logits, [-1, V])
-        if dtype != "float32":
-            flat = layers.cast(flat, "float32")
-        tgt = layers.reshape(targets, [-1, 1])
-        per_token = layers.softmax_with_cross_entropy(flat, tgt)
+        per_token = _token_loss(logits, targets, dtype)
         if drop_last:
             T = int(logits.shape[1])
             helper = LayerHelper("kept_positions")
@@ -679,6 +763,51 @@ def block_diffusion_loss(logits, tokens, weight, dtype="float32"):
         scale = layers.mean(weight)
         objective = layers.mean(layers.elementwise_mul(per_token, weight))
         return objective, layers.elementwise_div(objective, scale)
+
+
+def ouro_exit_loss(loop, targets=None, dtype="float32", beta=0.1):
+    """The expected-exit objective of a looped tower (Ouro's Stage I,
+    arXiv:2510.25741: an evidence bound with a uniform prior over the exit
+    step) from what `decoder_lm` left in its `loop` dict, n >= 2 passes
+    with an exit gate: with lambda_t the gate and CE_t every token's
+    cross-entropy after pass t,
+
+      p_1 = lambda_1;  p_t = lambda_t prod_{j<t} (1 - lambda_j), 1 < t < n;
+      p_n = prod_{j<n} (1 - lambda_j)     (the last pass takes what is left:
+                                           its own gate weighs nothing)
+      objective = mean over tokens of sum_t p_t CE_t - beta H(p),
+      H(p) = -sum_t p_t log p_t
+
+    all in float32.  The cross-entropies are the dict's "token_loss" (made
+    beside each pass's head where `loop` held "targets"), else made here
+    from its "logits" and `targets`.  -> (objective [1], every pass's token
+    losses [B T, n], the exit distribution [B T, n]); the ops lie in the
+    part `loop.exit`."""
+    prog = default_main_program()
+    gates, ce = loop.get("gate") or (), loop.get("token_loss")
+    if len(gates) < 2:
+        raise ValueError("ouro_exit_loss: the exit distribution is over two "
+                         "passes or more, each with its gate (`loop` = "
+                         "{'passes': n, 'exit_gate': True})")
+    if not ce:
+        with prog.part_guard("lm.loss"):
+            ce = [_token_loss(lg, targets, dtype) for lg in loop["logits"]]
+    with prog.part_guard("loop.exit"):
+        probs, left = [], None   # left: prod (1 - lambda_j) so far
+        for lam in gates[:-1]:
+            lam = layers.reshape(lam, [-1, 1])
+            probs.append(lam if left is None
+                         else layers.elementwise_mul(lam, left))
+            stay = layers.scale(lam, scale=-1.0, bias=1.0)
+            left = stay if left is None else layers.elementwise_mul(left,
+                                                                    stay)
+        exit_probs = layers.concat(probs + [left], axis=1)
+        token_loss = layers.concat(ce, axis=1)
+        weighed = layers.elementwise_mul(exit_probs, layers.elementwise_add(
+            token_loss, layers.scale(layers.log(exit_probs),
+                                     scale=float(beta))))
+        objective = layers.mean(layers.reduce_sum(weighed, dim=1))
+    return objective, token_loss, exit_probs
 
 
 def moe_lm_loss(logits, targets, router_outputs, dtype="float32",
@@ -1895,4 +2024,48 @@ def build_granite_hybrid_lm_train_program(
     opt.Adam(learning_rate=learning_rate).minimize(loss)
     if ssm["memory"]:
         layers.scale(ssm["memory"][-1], scale=1.0)
+    return loss
+
+
+def build_decoder_lm_train_program(seq_len, learning_rate=3e-4,
+                                   exit_beta=0.1, gain_range=None, **tower):
+    """The GENERIC builder: a configuration whose tower `decoder_lm` can
+    describe names this one and writes the description FLAT into its
+    `train.args`, under `decoder_lm`'s own argument names (`vocab_size`,
+    `dim`, `n_layers`, `n_heads`, ... `loop`); `max_len` is `seq_len` and
+    `dtype` bf16 unless given.  `gain_range` (lo, hi) draws the norms' gains
+    uniformly instead of starting them at one (`norm_attr`: a checked
+    program must not pass without them).  Feeds as
+    `build_lm_train_program`; Adam at
+    `learning_rate`; returns the loss: the next-token cross entropy
+    (`lm_loss`), or for a looped tower with an exit gate (`loop` =
+    {"passes", "exit_gate": true}) the expected-exit objective at `exit_beta`
+    (`ouro_exit_loss`), each pass's head and cross-entropy built beside the
+    pass (one segment under `remat`).  The looped program's last `concat`
+    is then every pass's token losses [T, passes] and its last `assign` the
+    exit distribution [T, passes], for a fetch by op type.  A tower whose
+    loss has terms of its own (router losses, a multi-token-prediction
+    module, block diffusion) keeps the builder that knows them."""
+    from .. import optimizer as opt
+
+    tokens = layers.data("tokens", shape=[seq_len, 1], dtype="int64")
+    targets = layers.data("targets", shape=[seq_len, 1], dtype="int64")
+    tower.setdefault("dtype", "bfloat16")
+    if gain_range:
+        from ..framework.initializer import UniformInitializer
+
+        tower["norm_attr"] = {"gain": {"initializer": UniformInitializer(
+            *gain_range)}}
+    loop = tower.get("loop")
+    if loop is not None:   # the caller's dict stays as it was written
+        loop = tower["loop"] = dict(loop, **(
+            {"targets": targets} if loop.get("exit_gate") else {}))
+    logits = decoder_lm(tokens, max_len=seq_len, **tower)
+    if loop is not None and loop.get("exit_gate"):
+        loss, _, exit_probs = ouro_exit_loss(loop, dtype=tower["dtype"],
+                                             beta=exit_beta)
+        layers.assign(exit_probs)
+    else:
+        loss = lm_loss(logits, targets, dtype=tower["dtype"])
+    opt.Adam(learning_rate=learning_rate).minimize(loss)
     return loss

@@ -280,8 +280,10 @@ def _qk_prep(ctx, ins, attrs):
     if not 0 < rotary <= D or rotary % 2:
         raise ValueError(f"head_norm_rope: rotary_dim {rotary} of a head "
                          f"of {D}")
-    pack = kernels.pack_of(x.shape[1], D, heads,
-                           x.dtype) if pallas_dispatch_ok(ctx) else 0
+    # inside a sub-block (a `layers.recompute` segment) the backward is the
+    # block's own jax.vjp, and these kernels have a grad op, no custom_vjp
+    pack = kernels.pack_of(x.shape[1], D, heads, x.dtype) if (
+        pallas_dispatch_ok(ctx) and not ctx.sub_depth) else 0
     if rotary != D:   # a partial turn: the kernels take 64 of 128 alone
         kw["rotary_dim"] = rotary
         pack = pack if kernels.turn_of(D, rotary) else 0
@@ -333,9 +335,10 @@ def head_norm_rope(ctx, ins, attrs):
     On one TPU with heads of 128 or 64 lanes and T in 128s a Pallas kernel
     reads each head's column block where it lies and writes it where the
     flash kernels read it (ops/pallas_kernels/head_norm_rope.py);
-    everywhere else (the CPU, a mesh, other head sizes, a partial turn
-    other than 64 columns of 128: `qk_prep_layers_traced_total{path="xla"}`
-    says so) plain jax.numpy (`head_norm_rope_plain`)."""
+    everywhere else (the CPU, a mesh, inside a `layers.recompute` segment
+    or another sub-block, other head sizes, a partial turn other than 64
+    columns of 128: `qk_prep_layers_traced_total{path="xla"}` says so)
+    plain jax.numpy (`head_norm_rope_plain`)."""
     from .pallas_kernels import head_norm_rope as kernels
 
     x, gain, kw, pack = _qk_prep(ctx, ins, attrs)

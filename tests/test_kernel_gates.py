@@ -69,6 +69,12 @@ MECHANISMS = {
     # Mamba-2 scans of 64 heads of 64 (pairs of heads a lane tile) on a
     # state of 128, one group
     "granite-4.0-h-micro": {"flash", "ssd_scan"},
+    # 48 block applications of full causal attention at 16 heads of 128,
+    # every one inside a `layers.recompute` segment: the flash kernels run
+    # there (a custom_vjp); `head_norm_rope`'s 96 ops pass the SHAPES' gate
+    # and take the plain emission all the same (a grad op of its own, no
+    # custom_vjp: `ops/llm_ops.py` `_qk_prep`, ROADMAP D14 (b))
+    "ouro-2.6b": {"flash", "head_norm_rope"},
 }
 
 

@@ -28,7 +28,8 @@ MAY = {
     # IR, executor, backward, dataflow (the state classes)
     "framework": {"knobs", "observability", "ops", "lod"},
     "layers": {"framework", "ops", "lod"},
-    "models": {"framework", "layers", "lod", "nets", "optimizer"},
+    "models": {"observability", "framework", "layers", "lod", "nets",
+               "optimizer"},
     # verifier, cost, memory, propagation, equivalence, contracts
     "analysis": {"mesh", "framework", "ops",
                  "memory_optimization_transpiler", "inference_transpiler"},
