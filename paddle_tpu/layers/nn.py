@@ -768,7 +768,9 @@ def mamba2(input, n_heads, head_dim, d_state, n_groups=1, d_conv=4,
     that order (d_inner = `n_heads` x `head_dim`; xBC = d_inner + 2
     `n_groups` `d_state` columns; dt one a head), a causal depthwise
     convolution of `d_conv` taps with bias + SiLU over ALL of xBC (x, B and C
-    alike), the scan with a scalar decay a head and token over a [`head_dim`,
+    alike; ONE op whose `sections` are its three outputs x, B and C, each a
+    tensor of its own that the scan reads as written: no slice between the
+    two), the scan with a scalar decay a head and token over a [`head_dim`,
     `d_state`] state a head in chunks of `chunk` tokens, B and C shared by
     the n_heads / n_groups heads of a group, the gate FIRST and then one
     RMSNorm (`epsilon`) over each group's d_inner / n_groups columns, y *
@@ -807,26 +809,22 @@ def mamba2(input, n_heads, head_dim, d_state, n_groups=1, d_conv=4,
         dtype=input.dtype, is_bias=True,
         default_initializer=UniformInitializer(-bound, bound))
     lead = tuple(input.shape[:2])
-    conved = helper.create_tmp_variable(input.dtype, shape=lead + (xbc,))
+    # one output a section: the scan reads x, B and C as the convolution
+    # wrote them (on the chip each is an out spec of its kernel: no slices)
+    sections = [Di, G * N, G * N]
+    x, b, c = (helper.create_tmp_variable(input.dtype, shape=lead + (width,))
+               for width in sections)
     helper.append_op(
         "causal_conv_silu",
         inputs={"X": [proj.name], "Filter": [taps.name],
                 "Bias": [conv_bias.name]},
-        outputs={"Out": [conved.name]},
-        attrs={"offset": Di, "part": "ssd.conv"})
-
-    def columns(of, start, width, part):
-        out = helper.create_tmp_variable(input.dtype, shape=lead + (width,))
-        helper.append_op("slice", inputs={"Input": [of.name]},
-                         outputs={"Out": [out.name]},
-                         attrs={"axes": [2], "starts": [start],
-                                "ends": [start + width], "part": part})
-        return out
-
-    x = columns(conved, 0, Di, "ssd.scan")
-    b = columns(conved, Di, G * N, "ssd.scan")
-    c = columns(conved, Di + G * N, G * N, "ssd.scan")
-    dt = columns(proj, Di + xbc, H, "ssd.dt")
+        outputs={"Out": [x.name, b.name, c.name]},
+        attrs={"offset": Di, "sections": sections, "part": "ssd.conv"})
+    dt = helper.create_tmp_variable(input.dtype, shape=lead + (H,))
+    helper.append_op("slice", inputs={"Input": [proj.name]},
+                     outputs={"Out": [dt.name]},
+                     attrs={"axes": [2], "starts": [Di + xbc],
+                            "ends": [Di + xbc + H], "part": "ssd.dt"})
     dt_bias = helper.create_parameter(
         attr={}, shape=[H], dtype=input.dtype, is_bias=True,
         default_initializer=_step_bias_draw())

@@ -1,6 +1,7 @@
 """The state-space token mixers (layers/nn.py `mamba`, `mamba2`,
 `gated_memory_unit`; models/transformer.py `decoder_lm`).  TWO scans live
-here, and they share no code because they share no structure:
+here behind ONE short convolution, and the scans share no code because they
+share no structure:
 
 `selective_scan` (Mamba-1; Phi-4-mini-flash) runs a DIAGONAL state [d_inner,
 d_state] with a step size for every token and CHANNEL: elementwise work, no
@@ -28,9 +29,19 @@ their gradients cross HBM.  Everywhere else (the CPU, float64, a mesh,
 `lax.scan` over the chunks whose body is a `jax.checkpoint` of a `lax.scan`
 over the tokens, the state through HBM once a token; the kernels' oracle.
 
-`causal_conv_silu` (the short convolution in front of either scan, a layer
-of its own here) and `silu_gate` (the output gate, and the gated memory
-unit's whole mixer) are the two elementwise passes beside it.
+`causal_conv_silu` is the short convolution in front of either scan, an op
+of its own here: L causal depthwise taps, a bias and SiLU over a column
+range of the input projection's result (Mamba-1: u' of [u' | z]; Mamba-2:
+xBC of [z | xBC | dt], its `sections` x, B and C one output each, which
+`ssd_scan` reads as written).  On one TPU, at whole tiles, it is the Pallas
+kernel pair of ops/pallas_kernels/ssm_conv.py: X read where the projection
+wrote it, float32 in VMEM, one rounding, one pass forward and one back; as
+plain jax.numpy XLA ran the tap loop in float32 with a pad a tap, at a tenth
+of HBM's roof (PERF.md, PR 70 and 72).  Everywhere else (the CPU, float64, a
+mesh, `PADDLE_TPU_NO_FUSED_KERNELS`, other shapes) `llm_ops.causal_taps`,
+the repo's one plain tap loop and the kernels' oracle.  `silu_gate` (the
+output gate, and the gated memory unit's whole mixer) is the elementwise
+pass behind the Mamba-1 scan.
 
 `ssd_scan`: Mamba-2's scan (state-space duality, arXiv:2405.21060), from S =
 0 [P, N] a head:
@@ -91,6 +102,15 @@ _MET_SSD_KERNELS = _MET.counter(
     "jax.vjp, its own or its `layers.recompute` segment's) and the path "
     "taken (pallas: the kernel pair of ops/pallas_kernels/ssd_scan.py; xla: "
     "ssd_chunked)")
+
+_MET_CONV_KERNELS = _MET.counter(
+    "causal_conv_silu_kernels_traced_total",
+    "emissions of the Mamba mixers' short convolution (once a compile, not "
+    "once a step), by the op that emits it (fwd: causal_conv_silu; grad: a "
+    "re-emission under a grad op's jax.vjp, its own or its "
+    "`layers.recompute` segment's) and the path taken (pallas: the kernel "
+    "pair of ops/pallas_kernels/ssm_conv.py; xla: `llm_ops.causal_taps`, "
+    "the bias and SiLU as plain jax.numpy)")
 
 # Tokens a chunk of the plain emission's scan (the kernels' is their own
 # CHUNK): a constant, not a knob.  What the backward keeps is one [d_inner,
@@ -210,20 +230,53 @@ def causal_conv_silu(ctx, ins, attrs):
     x, B and C), Filter [C, L] (torch's Conv1d tap order: the LAST tap on
     the current token, zeros before the sequence: `llm_ops.causal_taps`,
     the repo's one plain tap loop), Bias [C] (optional).  At least float32
-    inside, X's dtype out."""
+    inside, X's dtype out.  With the attr `sections` (widths that sum to C)
+    Out is one variable a section, [B, T, width] each: a Mamba-2 mixer's x,
+    B and C, which its scan reads as the convolution wrote them.
+
+    On one TPU, where X is bf16 or float32, T is whole row tiles, `offset`
+    and every section are whole lane tiles and L <= 16 (`ssm_conv.usable`),
+    the op is the kernel pair of ops/pallas_kernels/ssm_conv.py through
+    `ctx.run_pair`: X read where the projection wrote it, each section
+    written row-major once, float32 in VMEM and one rounding; the pair
+    keeps nothing, so a grad op handed the forward's Out launches the
+    backward alone and a `layers.recompute` segment's replay the forward
+    once more.  Everywhere else (the CPU, float64, a mesh,
+    `PADDLE_TPU_NO_FUSED_KERNELS`, other shapes) the plain lines below, the
+    kernels' oracle.  `causal_conv_silu_kernels_traced_total` says which."""
     import jax
+
+    from .pallas_kernels import ssm_conv as kernels
+    from .pallas_kernels._common import traced_path
 
     x, w = ins["X"][0], ins["Filter"][0]
     width, at = w.shape[0], int(attrs.get("offset", 0))
-    if x.ndim != 3 or at < 0 or x.shape[-1] < at + width:
+    sections = tuple(int(s) for s in attrs.get("sections") or ())
+    if (x.ndim != 3 or at < 0 or x.shape[-1] < at + width
+            or (sections and (min(sections) < 1 or sum(sections) != width))):
         raise ValueError(f"causal_conv_silu: X {x.shape} under a Filter "
-                         f"{w.shape} at offset {at}")
+                         f"{w.shape} at offset {at}"
+                         + (f" in sections {sections}" if sections else ""))
+    bias = ins["Bias"][0] if ins.get("Bias") else None
+    widths = sections or (width,)
+    if traced_path(ctx, _MET_CONV_KERNELS, kernels.usable(
+            x.shape[1], x.shape[2], at, widths, w.shape[1], x.dtype)):
+        out, saved = ctx.run_pair(
+            kernels.make_ssm_conv(at, widths, bias is not None),
+            (x, w) + (() if bias is None else (bias,)))
+        if saved is not None:
+            ctx.keep_for_grad(attrs, list(out), saved)
+        return {"Out": list(out)}
     wide = wide_dtype(x.dtype)
     read = x[..., :width] if not at else x[..., at:at + width]
     pre = causal_taps(read.astype(wide), w.astype(wide))
-    if ins.get("Bias"):
-        pre = pre + ins["Bias"][0].astype(wide)
-    return {"Out": [jax.nn.silu(pre).astype(x.dtype)]}
+    if bias is not None:
+        pre = pre + bias.astype(wide)
+    out = jax.nn.silu(pre).astype(x.dtype)
+    if not sections:
+        return {"Out": [out]}
+    ends = [sum(sections[:i]) for i in range(1, len(sections))]
+    return {"Out": jax.numpy.split(out, ends, axis=-1)}
 
 
 @register_op("silu_gate")

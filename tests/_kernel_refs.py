@@ -245,6 +245,22 @@ def _spy_on_calls(monkeypatch, kernels, names):
     return launched
 
 
+def _conv_interpreted(monkeypatch):
+    """Where a test's trace claims a TPU, the short convolution in front of a
+    Mamba layer's scan takes its kernel pair too
+    (ops/pallas_kernels/ssm_conv.py): interpreted from here on -> the list
+    every launch of it appends "fwd" / "bwd" to."""
+    from paddle_tpu.ops.pallas_kernels import ssm_conv
+
+    real = ssm_conv.make_ssm_conv
+    launched = _spy_on_calls(monkeypatch, ssm_conv, ("fwd", "bwd"))
+    monkeypatch.setattr(ssm_conv, "make_ssm_conv",
+                        lambda at, sections, bias: real(at, sections, bias,
+                                                        True))
+    real.cache_clear()
+    return launched
+
+
 def _inner_eqns(jaxpr):
     for e in jaxpr.eqns:
         yield e

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from _kernel_refs import _by_labels, _described_step, _startup
+from _kernel_refs import (_by_labels, _conv_interpreted, _described_step,
+                          _startup)
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import registry as reg
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
@@ -232,8 +233,9 @@ def _step_kernels(fetch, feed):
             if e.primitive.name == "pallas_call"]
 
 
-@pytest.mark.parametrize("remat,reused", [(False, ("selective_scan", "1")),
-                                          (True, ("recompute", "0"))])
+@pytest.mark.parametrize("remat,reused", [
+    (False, {("selective_scan", "1"): 1.0, ("causal_conv_silu", "1"): 1.0}),
+    (True, {("recompute", "0"): 1.0})])
 def test_mamba_layers_reverse_pass_is_handed_the_kept_states(remat, reused,
                                                              monkeypatch):
     """A Mamba layer at toy size.  Alone, its grad op's re-emission is
@@ -241,11 +243,14 @@ def test_mamba_layers_reverse_pass_is_handed_the_kept_states(remat, reused,
     outputs) and launches the reverse pass alone.  Inside a
     `layers.recompute` segment the replay under the segment's vjp launches
     the forward ONCE more, keeping the states, and the reverse pass is
-    handed those: never a forward of its own, never a third.  The
-    gradients are the plain emission's."""
+    handed those: never a forward of its own, never a third.  The short
+    convolution in front of the scan goes the same way with nothing to keep
+    but its result: one forward launch a forward emission and a replay, one
+    backward.  The gradients are the plain emission's."""
     import contextlib
 
     from paddle_tpu.ops.pallas_kernels import selective_scan as ss
+    from paddle_tpu.ops.pallas_kernels import ssm_conv
 
     feed = {"x": np.random.RandomState(7).randn(2, 64, 64)
             .astype(np.float32)}
@@ -275,13 +280,15 @@ def test_mamba_layers_reverse_pass_is_handed_the_kept_states(remat, reused,
                         lambda self: "tpu")
     monkeypatch.setattr(ss, "make_selective_scan",
                         lambda: real_make(ss.CHUNK, True))
+    _conv_interpreted(monkeypatch)
     got = step()
-    assert _counter() == {reused: 1.0}
+    assert _counter() == reused
     assert len(got) == 10       # the loss and the mixer's nine parameters
     for a, b in zip(got, want):
         assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max()
-    assert _step_kernels(build(), feed) == (
-        [(ss.FWD, 2)] * (2 if remat else 1) + [(ss.BWD, 6)])
+    forwards = [(ssm_conv.FWD, 1), (ss.FWD, 2)] * (2 if remat else 1)
+    assert _step_kernels(build(), feed) == forwards + [
+        (ss.BWD, 6), (ssm_conv.BWD, 2)]
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ and
 `grouped_matmul.usable`, `segment_sum.usable`, `head_norm_rope.pack_of`,
 `hyper_connection.usable`, `sparse_flash.usable`, `short_conv.usable`,
 `gated_delta.usable`, `kda.usable`, `kda_conv.usable`,
-`selective_scan.usable` and `ssd_scan.usable` say yes.  Nothing compiles: milliseconds where the AOT tests of the same cells take minutes."""
+`selective_scan.usable`, `ssd_scan.usable` and `ssm_conv.usable` say yes.  Nothing compiles: milliseconds where the AOT tests of the same cells take minutes."""
 
 import glob
 import importlib
@@ -25,7 +25,7 @@ from paddle_tpu.ops.pallas_kernels import (flash_attention, gated_delta,
                                            hyper_connection, kda, kda_conv,
                                            segment_sum, selective_scan,
                                            short_conv, sparse_flash,
-                                           ssd_scan)
+                                           ssd_scan, ssm_conv)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -47,7 +47,9 @@ MECHANISMS = {
                            "gated_delta"},
     # four attention layers of two flash calls each, two of them under the
     # sliding window's region; three selective scans of 5120 channels
-    "phi4-mini-flash": {"flash", "flash_window", "selective_scan"},
+    # (the short convolution in front of each through ssm_conv.py's pair)
+    "phi4-mini-flash": {"flash", "flash_window", "selective_scan",
+                        "ssm_conv"},
     # one full-span layer on the projections' layout (28 query heads on 4:
     # its heads are split inside the op) and three under a 4096-key window
     # of 16384 tokens; RoPE's kernel in the window layers alone
@@ -67,8 +69,9 @@ MECHANISMS = {
     # ONE attention layer on the projections' layout (32 query heads on 8:
     # its heads are split inside the op, no position, scale 1/64); nine
     # Mamba-2 scans of 64 heads of 64 (pairs of heads a lane tile) on a
-    # state of 128, one group
-    "granite-4.0-h-micro": {"flash", "ssd_scan"},
+    # state of 128, one group, behind nine convolutions of 4352 columns at
+    # offset 4096 whose sections are the scan's x, B and C
+    "granite-4.0-h-micro": {"flash", "ssd_scan", "ssm_conv"},
     # 48 block applications of full causal attention at 16 heads of 128,
     # every one inside a `layers.recompute` segment: the flash kernels run
     # there (a custom_vjp); `head_norm_rope`'s 96 ops pass the SHAPES' gate
@@ -181,6 +184,14 @@ def test_cells_shapes_pass_the_kernels_gates(name, monkeypatch):
             scans += 1
             passed.add("ssd_scan")
             positions = max(positions, T)
+        elif op.type == "causal_conv_silu":
+            _, T, W = shape(op, "X")
+            C, L = shape(op, "Filter")
+            assert ssm_conv.usable(
+                T, W, op.attrs.get("offset", 0),
+                tuple(op.attrs.get("sections") or (C,)), L,
+                dtype(op, "X")), (T, W, C, L, op.attrs)
+            passed.add("ssm_conv")
         elif op.type == "head_norm_rope" and (
                 "rotary_dim" not in op.attrs or head_norm_rope.turn_of(
                     shape(op, "X")[2] // op.attrs["num_heads"],

@@ -17,7 +17,8 @@ import paddle_tpu as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer
 
-from _kernel_refs import _dense_scaled, _r, _series, _silu, _spy_on_calls
+from _kernel_refs import (_conv_interpreted, _dense_scaled, _r, _series,
+                          _silu, _spy_on_calls)
 from op_test import OpTestHarness
 
 
@@ -140,6 +141,7 @@ def test_ssd_scan_counts_what_ran_and_refuses_what_does_not_add_up():
 # tests/test_ssd_scan_kernel.py holds the kernels themselves)
 
 KERNELS = "ssd_scan_kernels_traced_total"
+CONV_KERNELS = "causal_conv_silu_kernels_traced_total"
 
 
 def _kernel_case(T, H=2, P=64, N=128, G=1, seed=0):
@@ -462,30 +464,51 @@ def test_mamba2_layer_inside_a_recompute_segment_gives_the_same_gradients():
 def test_mamba2_layer_in_a_segment_on_the_kernels_path(
         kernels_in_interpret_mode, monkeypatch):
     """On the kernels' path (interpret mode, two chunks of 16 tokens, a pair
-    of heads of 64) the layer outside a segment launches the forward once
-    and the reverse pass over what it kept; inside a segment the forward
-    emission, the replay's forward (handed nothing: the plain pair) and the
-    reverse pass.  The same loss and gradients either way, bit for bit, and
-    the plain emission's to float32's rounding."""
+    of heads of 64) the layer outside a segment launches the scan's forward
+    once and the reverse pass over what it kept, and the convolution's
+    forward once (three sections: x, B and C) and its backward, which the
+    grad op handed the forward's outputs launches alone; inside a segment
+    the forward emission, the replay's forward (handed nothing: the plain
+    pair) and the reverse pass, of both.  The same loss and gradients either
+    way, bit for bit but W_in's, and the plain emission's to float32's
+    rounding."""
     launched = kernels_in_interpret_mode
+    conv = _conv_interpreted(monkeypatch)
     sizes = dict(n_heads=2, head_dim=64, d_state=128, n_groups=1)
     obs.REGISTRY.reset()
     _, outside = _mixer_grads(False, T=32, sizes=sizes)
-    assert launched == ["fwd", "bwd"]
-    del launched[:]
+    assert launched == conv == ["fwd", "bwd"]
+    assert _series("executor_grad_kernel_forward_total") == [
+        ({"op": "causal_conv_silu", "reused": "1"}, 1.0),
+        ({"op": "ssd_scan", "reused": "1"}, 1.0)]
+    del launched[:], conv[:]
+    obs.REGISTRY.reset()
     _, inside = _mixer_grads(True, T=32, sizes=sizes)
     # (traces, not launches: differentiating the replay's plain pair traces
     # its primal beside its rule, and the compiled step drops the one
     # nothing reads)
-    assert launched.count("bwd") == 1 and launched.count("fwd") >= 2
-    assert _series(KERNELS) == [({"op": "fwd", "path": "pallas"}, 1.0),
-                                ({"op": "grad", "path": "pallas"}, 1.0)]
+    for calls in (launched, conv):
+        assert calls.count("bwd") == 1 and calls.count("fwd") >= 2
+    assert _series(KERNELS) == _series(CONV_KERNELS) == [
+        ({"op": "fwd", "path": "pallas"}, 1.0),
+        ({"op": "grad", "path": "pallas"}, 1.0)]
+    assert _series("executor_grad_kernel_forward_total") == [
+        ({"op": "recompute", "reused": "0"}, 1.0)]
+    # W_in's gradient is a product whose cotangent operand, the sum of the
+    # convolution's, the gate's and dt's column ranges, XLA's CPU backend
+    # fuses another way in a segment: the same numbers to the last bits
     for a, b in zip(outside, inside):
-        np.testing.assert_array_equal(a, b)
-    del launched[:]
+        if a.shape == (16, 128 + 384 + 2):
+            assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max()
+        else:
+            np.testing.assert_array_equal(a, b)
+    del launched[:], conv[:]
     monkeypatch.setenv("PADDLE_TPU_NO_FUSED_KERNELS", "1")
+    obs.REGISTRY.reset()
     _, plain = _mixer_grads(True, T=32, sizes=sizes)
-    assert launched == []
+    assert launched == conv == []
+    assert _series(CONV_KERNELS) == [({"op": "fwd", "path": "xla"}, 1.0),
+                                     ({"op": "grad", "path": "xla"}, 1.0)]
     assert all(np.abs(g).max() > 0 for g in plain[1:])
     for a, b in zip(inside, plain):
         assert np.abs(a - b).max() <= 5e-5 * np.abs(b).max()

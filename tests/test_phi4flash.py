@@ -28,8 +28,9 @@ from paddle_tpu.models import transformer
 from paddle_tpu.ops import attention_ops, ssm_ops
 from paddle_tpu.ops.pallas_kernels import flash_attention as fa
 
-from _kernel_refs import (_by_labels, _dense_masked as _dense, _r, _silu,
-                          _startup, _with_vjp)
+from _kernel_refs import (_by_labels, _conv_interpreted,
+                          _dense_masked as _dense, _r, _silu, _startup,
+                          _with_vjp)
 from op_test import OpTestHarness
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -857,8 +858,9 @@ def test_mamba_and_gmu_layers_against_the_reference():
 
 @pytest.fixture
 def scan_on_cpu(monkeypatch):
-    """Every trace claims a TPU target and the scan's kernels interpret;
-    returns what each `run_pair` of a re-emission found kept for it."""
+    """Every trace claims a TPU target and the scan's kernels interpret, and
+    the convolution's in front of it; returns what each `run_pair` of a
+    re-emission found kept for it."""
     from paddle_tpu.ops import registry as reg
     from paddle_tpu.ops.pallas_kernels import selective_scan as ss
 
@@ -874,6 +876,7 @@ def scan_on_cpu(monkeypatch):
                         lambda self: "tpu")
     monkeypatch.setattr(ss, "make_selective_scan",
                         lambda: real_make(ss.CHUNK, True))
+    _conv_interpreted(monkeypatch)
     monkeypatch.setattr(reg.EmitContext, "run_pair", spy_run)
     return handed
 
@@ -885,16 +888,17 @@ def test_a_kernel_pair_in_front_of_the_kept_products(mode, scan_on_cpu):
     without `keep=` and the plain ops', to float32's rounding (the held
     values are the made ones bit for bit; XLA fuses a backward whose product
     is dead otherwise, and without a segment the reverse pass reads the
-    forward's kept states, not a replay's); the scan's emitter inside the
-    replay is handed nothing of the segment's and launches its forward
-    again, counted on the recompute grad op as ever."""
+    forward's kept states, not a replay's); the convolution's emitter and
+    the scan's inside the replay are handed nothing of the segment's and
+    launch their forwards again, counted on the recompute grad op as
+    ever."""
     from test_recompute_keep import KEPT, _step, _want
 
     want = {m: _want(m, "mamba") for m in ("plain", "segment")}
     del scan_on_cpu[:]
     obs.REGISTRY.reset()
     got = _step(mode, "mamba")
-    assert scan_on_cpu == [None]
+    assert scan_on_cpu == [None, None]
     assert _by_labels("executor_grad_kernel_forward_total", "op",
                       "reused") == {("recompute", "0"): 1.0}
     assert len(got) == 14       # loss, x, the mixer's nine, the MLP's three

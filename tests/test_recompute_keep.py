@@ -224,11 +224,12 @@ def test_aot_kept_products_leave_the_step_and_no_kernel_is_added(
     """(c) A Mamba layer and a gated MLP in one segment at [2, 1024, 256]:
     with the three wide products kept the compiled step holds three
     products fewer, the same Mosaic calls (the scan's forward twice and its
-    reverse pass once: the replay's kernel still runs) and at most the kept
-    bytes more.  A `jax.checkpoint` policy naming the same values adds a
+    reverse pass once, and the short convolution's in front of it likewise:
+    the replay's kernels still run) and at most the kept bytes more.  A `jax.checkpoint` policy naming the same values adds a
     THIRD forward launch instead (the next test), which is why the segment
     keeps by the program's own protocol."""
     from paddle_tpu.ops.pallas_kernels import selective_scan as ss
+    from paddle_tpu.ops.pallas_kernels import ssm_conv
 
     monkeypatch.setitem(globals(), "T", AOT_T)
     monkeypatch.setitem(globals(), "DIM", AOT_DIM)
@@ -239,9 +240,12 @@ def test_aot_kept_products_leave_the_step_and_no_kernel_is_added(
         text, temp = _compiled_step(v5e, _build(mode, "mamba")[:1])
         read[mode] = (len(_PRODUCT.findall(text)),
                       text.count("tpu_custom_call"), temp)
-        assert len(re.findall(ss.FWD + r"[^\n]*tpu_custom_call", text)) \
-            + len(re.findall(ss.BWD + r"[^\n]*tpu_custom_call", text)) \
-            == read[mode][1] == 3, read
+        # by the instruction's own name (the scan's lines name the
+        # convolution's result among their operands)
+        launches = [len(re.findall(rf"%{k}[.\d]* = [^\n]*tpu_custom_call",
+                                   text))
+                    for k in (ss.FWD, ss.BWD, ssm_conv.FWD, ssm_conv.BWD)]
+        assert launches == [2, 1, 2, 1] and read[mode][1] == 6, read
     kept = _kept()
     assert kept[("replay", "values")] == kept[("forward", "values")] == 3.0
     (products, calls, temp), (products_k, calls_k, temp_k) = (
